@@ -132,7 +132,7 @@ class EngineOverloadedError(CalfkitError):
 class EngineWedgedError(CalfkitError):
     """The engine's dispatch-progress watchdog tripped (ISSUE 9): work was
     pending but no dispatch landed for ``RuntimeConfig.watchdog_stall_s``
-    — the BENCH-documented "wedged device grant" state.  Requests caught
+    — the "wedged device grant" state.  Requests caught
     in (or queued behind) the wedge are faulted with this instead of
     silently burning their deadlines.  Typed and RETRIABLE by contract:
     the caller observed no tokens from this engine, so the same call may

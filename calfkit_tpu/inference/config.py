@@ -129,7 +129,10 @@ class RuntimeConfig:
     # decode attention window buckets (each is one jit specialization);
     # sparse buckets = few compiles, dense = tighter HBM reads
     window_buckets: tuple[int, ...] = (256, 1024, 4096, 16384)
-    compilation_cache_dir: str | None = "~/.cache/calfkit_tpu_xla"
+    # persistent XLA compile cache, placed by compile_cache.py's one rule
+    # ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache); False only
+    # switches it off
+    compilation_cache: bool = True
     # automatic prefix caching (vLLM-APC analog): requests whose prompt
     # shares a full-page-aligned prefix with an earlier request reuse its
     # KV pages instead of re-prefilling them — the agent-serving win
@@ -195,7 +198,7 @@ class RuntimeConfig:
     max_out_blocks: int = 0
     # engine wedge watchdog (ISSUE 9): with work pending, no dispatch
     # landing for this many seconds (on the cancellation.wall_clock seam)
-    # declares the engine WEDGED — the BENCH r05 "hung device grant"
+    # declares the engine WEDGED — the "hung device grant"
     # state, where the decode thread blocks inside a device sync forever
     # and the scheduler loop with it.  Tripping dumps the flight
     # recorder, flips readiness (and the heartbeat advert) false, and

@@ -49,6 +49,7 @@ from calfkit_tpu.exceptions import (
     RunOrphanedError,
 )
 from calfkit_tpu.inference import model as M
+from calfkit_tpu.inference.compile_cache import enable_compile_cache
 from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
 from calfkit_tpu.observability import capacity, flightrec
 from calfkit_tpu.observability.metrics import (
@@ -169,46 +170,18 @@ def _engine_metrics(
     return out
 
 
-def _host_feature_tag() -> str:
-    """Fingerprint of the executing host's CPU feature set, mixed into the
-    persistent compilation-cache path.
-
-    XLA:CPU AOT artifacts embed the COMPILE machine's feature list; loading
-    one produced on a wider-featured host risks SIGILL (the stale
-    ``+amx-fp16`` cache warning in MULTICHIP_r05.json).  Keying the cache
-    directory by the host's own features makes cross-host artifact reuse
-    structurally impossible — a different machine simply compiles into its
-    own subdirectory.
-    """
-    import hashlib
-    import platform
-
-    feats = platform.machine() or "unknown"
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.lower().startswith(("flags", "features")):
-                    feats += " " + " ".join(
-                        sorted(line.split(":", 1)[-1].split())
-                    )
-                    break
-    except OSError:
-        pass  # non-Linux: the machine string alone still splits per-arch
-    return hashlib.blake2b(feats.encode(), digest_size=6).hexdigest()
-
-
 def _load_attn_profile() -> dict | None:
     """The attention-impl profile artifact (written by
     scripts/profile_attention.py --out on hardware): per-path winners that
-    ``attention_impl="auto"`` resolves with.  Location: $CALFKIT_ATTN_PROFILE,
-    else ~/.cache/calfkit_tpu_attn_profile.json.  Cached by (path, mtime)."""
+    ``attention_impl="auto"`` resolves with.  Location: $CALFKIT_ATTN_PROFILE
+    and nowhere else — unset, "auto" is "xla".  Cached by (path, mtime)."""
     global _ATTN_PROFILE_CACHE
     import json
     import os
 
-    path = os.environ.get("CALFKIT_ATTN_PROFILE") or os.path.expanduser(
-        "~/.cache/calfkit_tpu_attn_profile.json"
-    )
+    path = os.environ.get("CALFKIT_ATTN_PROFILE")
+    if not path:
+        return None
     try:
         key = (path, os.stat(path).st_mtime_ns)
     except OSError:
@@ -589,25 +562,10 @@ class InferenceEngine:
         self.runtime = runtime or RuntimeConfig()
         self.sampling = sampling or SamplingParams()
         rt = self.runtime
-        if rt.compilation_cache_dir:
+        if rt.compilation_cache:
             # persistent XLA cache: window/prefill specializations compile
-            # once per machine, not once per process.  The directory is
-            # keyed by the host's CPU features (_host_feature_tag): AOT
-            # artifacts from a differently-featured machine must never
-            # load here (SIGILL risk — MULTICHIP_r05 postmortem).
-            import os
-
-            try:
-                jax.config.update(
-                    "jax_compilation_cache_dir",
-                    os.path.join(
-                        os.path.expanduser(rt.compilation_cache_dir),
-                        f"host-{_host_feature_tag()}",
-                    ),
-                )
-                jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-            except Exception:  # noqa: BLE001 - cache is best-effort
-                logger.debug("persistent compilation cache unavailable")
+            # once per machine, not once per process
+            enable_compile_cache()
 
         self.mesh = mesh if mesh is not None else make_mesh(tp=rt.tp, dp=rt.dp)
         shardings = param_shardings(config, self.mesh)
@@ -616,7 +574,12 @@ class InferenceEngine:
                 "initializing random %s params (%.2fB)", config.name,
                 config.param_count / 1e9,
             )
-            params = M.init_params(config, jax.random.key(seed))
+            # born sharded: each device materializes only its own shard (a
+            # plain init would build the whole tree on the first device —
+            # 16 GB for Llama-3-8B bf16 — before place_params spread it)
+            params = jax.jit(
+                lambda key: M.init_params(config, key), out_shardings=shardings
+            )(jax.random.key(seed))
         if rt.quantization in ("int8", "int4"):
             from calfkit_tpu.inference.quant import (
                 align_quant_sharding_keys,
@@ -703,9 +666,11 @@ class InferenceEngine:
                 )
             n_pages = rt.pool_pages()
             pool_sh = pool_sharding(config, self.mesh)
-            pool_k, pool_v = M.make_page_pool(config, n_pages, rt.page_size)
-            self._k = jax.device_put(pool_k, pool_sh)
-            self._v = jax.device_put(pool_v, pool_sh)
+            # born sharded, like the params: never whole on one device
+            self._k, self._v = jax.jit(
+                lambda: M.make_page_pool(config, n_pages, rt.page_size),
+                out_shardings=(pool_sh, pool_sh),
+            )()
             self._tables = jnp.zeros((B, rt.pages_per_seq()), jnp.int32)
             self._page_alloc = PageAllocator(n_pages)
             # capacity observatory (ISSUE 19): the page-ownership mirror —
@@ -736,14 +701,10 @@ class InferenceEngine:
                     "(reuse shares pages between requests)"
                 )
             cache_sh = cache_sharding(config, self.mesh, B)
-            self._k = jax.device_put(
-                jnp.zeros(
-                    (config.n_layers, B, config.n_kv_heads, S, config.head_dim),
-                    jnp.dtype(config.dtype),
-                ),
-                cache_sh,
-            )
-            self._v = jax.device_put(jnp.zeros_like(self._k), cache_sh)
+            self._k, self._v = jax.jit(
+                lambda: M.make_empty_cache(config, B, S),
+                out_shardings=(cache_sh, cache_sh),
+            )()
         self._last = jnp.zeros((B,), jnp.int32)
         self._lens = jnp.zeros((B,), jnp.int32)
         self._host_lens = np.zeros((B,), np.int64)  # host mirror for windows

@@ -212,31 +212,23 @@ def prefill_attention(
     *,
     attn_impl: str = "xla",
 ) -> jax.Array:
-    """Prefill attention dispatch: the Pallas flash kernel when opted in
-    and the shapes are block-eligible, else the XLA einsum path.
+    """Prefill attention dispatch: the Pallas flash kernel when opted in,
+    else the XLA einsum path.
 
     The flash kernel never materializes the [Sq, Skv] score matrix, so
-    long-chunk prefill stays VMEM-resident; eligibility mirrors the
-    engine's power-of-two chunk/bucket grammar (see
-    pallas_attention.prefill_attention_pallas).
+    long-chunk prefill stays VMEM-resident.  A shape its block grammar
+    cannot tile raises ``pallas_attention.PallasShapeError`` while the jit
+    is traced — a kernel request is never quietly served by XLA.
     """
     if attn_impl.startswith("pallas"):
         from calfkit_tpu.inference.pallas_attention import (
-            PREFILL_BLOCK_Q,
-            PREFILL_KV_CHUNK,
             prefill_attention_pallas,
         )
 
-        Sq, Skv = q.shape[1], k_cache.shape[2]
-        if (
-            Sq % min(PREFILL_BLOCK_Q, Sq) == 0
-            and Skv % min(PREFILL_KV_CHUNK, Skv) == 0
-        ):
-
-            return prefill_attention_pallas(
-                q, k_cache, v_cache, q_pos, seq_lens,
-                interpret=attn_impl == "pallas_interpret",
-            )
+        return prefill_attention_pallas(
+            q, k_cache, v_cache, q_pos, seq_lens,
+            interpret=attn_impl == "pallas_interpret",
+        )
     return attention_xla(q, k_cache, v_cache, q_pos, seq_lens)
 
 

@@ -1,62 +1,303 @@
-"""Pallas TPU kernel for decode-time GQA attention over the main KV cache.
+"""Pallas TPU kernels for GQA attention over the KV cache.
 
-Why a kernel: the XLA einsum path maps GQA decode badly — per (batch, kv
-head) the score matmul is [G=8, hd=64] × [hd, W], a sliver of the 128×128
-MXU, and measured effective bandwidth over the cache was ~110 GB/s.  The
-kernel streams each (b, k) cache slice through VMEM once and fuses mask +
-softmax-statistics + weighted sum, so HBM traffic is exactly one read of
-K/V.
+Why kernels: the XLA einsum path maps GQA decode badly — per (batch, kv
+head) the score matmul is [G, hd] × [hd, W], a sliver of the 128×128 MXU.
+A kernel streams each (b, k) cache slice through VMEM once and fuses mask +
+softmax statistics + weighted sum, so HBM traffic is one read of K/V.
 
-The kernel returns *unnormalized* output plus the softmax statistics
-``(m, z)`` so the caller can fold in the fresh-token ring (tiny, handled in
-plain XLA) with the same logsumexp merge used by the XLA path — the kernel
-never needs to know about the ring.
+Three kernel bodies, one flash-accumulation core (:func:`_flash_update`):
 
-Grid: one program per (batch row, kv head).  The whole [W, hd] slice sits in
-VMEM (W=4096, hd=64, bf16 → 512 KB per operand; VMEM is ~16 MB), so no
-inner blocking is needed at current window sizes.
+- ragged over a dense window (:func:`ragged_attention_pallas`),
+- ragged through the block tables (:func:`ragged_attention_paged_pallas`),
+- prefill over the chunk-updated scratch (:func:`prefill_attention_pallas`).
 
-Validated in interpret mode on CPU (tests); opt-in on hardware via
-``RuntimeConfig(attention_impl="pallas")`` until profiled on a real chip.
+Single-query decode is the S=1 row of the ragged law, so
+:func:`decode_attention_pallas` / :func:`paged_decode_attention_pallas`
+call the ragged kernels with one query per row — the repair that made the
+old single-query bodies acceptable to the TPU compiler (per-row scalars in
+SMEM, kv streamed chunk by chunk, statistics in a layout whose last two
+block dims equal the array's) turned them into the ragged bodies line for
+line.
+
+The source kernels return *unnormalized* output plus the softmax statistics
+``(m, z)`` so the caller can fold in the fresh-token ring / verify chunk
+(tiny, plain XLA) with the logsumexp merge the XLA path uses.
+
+What the TPU lowering demands, and how every kernel here meets it:
+per-row scalars (lengths, starts, block tables, the layer index) ride
+``PrefetchScalarGridSpec`` into SMEM — a ``(1,)`` block of a ``[B]`` array is
+refused; every VMEM block's last two dims equal the array's or are
+(8, 128)-aligned; kv is a sequential grid axis with VMEM scratch carrying
+the statistics, so VMEM use is independent of the window.
+
+Status: every entry point AOT-compiles for a described v5e at
+TinyLlama-1.1B and Llama-3-8B widths (``tests/test_tpu_compile.py``) and
+agrees with interpret mode and the XLA path on CPU.  ``attention_impl="auto"``
+still resolves to XLA (see docs/inference.md); ``"pallas"`` opts in.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# (kernel, "compiled" | "interpreted") -> times TRACED: evidence for
+# chip_smoke.py / tests that a "pallas" path really built Mosaic kernels
+KERNEL_TRACES: collections.Counter = collections.Counter()
 
 
-def _decode_attn_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, z_ref):
-    """One (batch, kv-head) program: masked scores + softmax stats + PV."""
-    q = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
-    k = k_ref[0, 0].astype(jnp.float32)  # [W, hd]
-    v = v_ref[0, 0].astype(jnp.float32)  # [W, hd]
+def _note_trace(kernel: str, interpret: bool) -> None:
+    KERNEL_TRACES[kernel, "interpreted" if interpret else "compiled"] += 1
+
+
+# kv positions streamed per grid step of the dense ragged kernel (the
+# window is a power-of-two bucket, so divisibility holds; windows smaller
+# than this run as one chunk)
+RAGGED_KV_CHUNK = 512
+
+
+def _flash_init(acc, m_s, z_s) -> None:
+    acc[...] = jnp.zeros_like(acc)
+    m_s[...] = jnp.full_like(m_s, -1e30)
+    z_s[...] = jnp.zeros_like(z_s)
+
+
+def _flash_update(q, k, v, mask, acc, m_s, z_s) -> None:
+    """Fold one kv chunk into the running (acc, m, z) VMEM scratch.
+
+    q [R, hd] / k, v [C, hd] f32 values; mask [R, C] (True = attendable);
+    acc [R, hd], m_s / z_s [R, 1] refs carried across the kv grid axis."""
     scale = 1.0 / math.sqrt(q.shape[-1])
-
-    scores = jax.lax.dot_general(
+    scores = lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [G, W]
-    valid = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) < lens_ref[0]
-    scores = jnp.where(valid, scores, -1e30)
+    ) * scale  # [R, C]
+    scores = jnp.where(mask, scores, -1e30)
+    m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1, keepdims=True))
+    m_new = jnp.maximum(m_new, -1e29)  # all-masked rows stay finite
+    alpha = jnp.exp(m_s[...] - m_new)
+    pexp = jnp.exp(scores - m_new)
+    z_s[...] = z_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    acc[...] = acc[...] * alpha + lax.dot_general(
+        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_s[...] = m_new
 
-    m = jnp.max(scores, axis=-1, keepdims=True)  # [G, 1]
-    m = jnp.maximum(m, -1e29)  # fresh rows stay finite
-    p = jnp.exp(scores - m)
-    z = jnp.sum(p, axis=-1, keepdims=True)  # [G, 1]
-    o = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [G, hd] — unnormalized
 
-    o_ref[0, 0] = o
-    m_ref[0, 0] = m[:, 0]
-    z_ref[0, 0] = z[:, 0]
+# --------------------------------------------------------------------------- #
+# ragged unified attention: mixed decode / prefill-chunk / verify rows
+# (ISSUE 6; the Ragged Paged Attention shape, arXiv:2604.15464)
+# --------------------------------------------------------------------------- #
+
+
+def _ragged_step(start, kv_len, q_ref, k, v, o_ref, m_ref, z_ref, acc, m_s, z_s):
+    """One (batch row, kv head, kv chunk) program of either ragged kernel.
+
+    The q block carries ALL of a row's queries (S = the wave's padded
+    q_len — 1 for decode rows, chunk for prefill rows, k+1 for verify
+    rows), flattened to [S·G, hd] so one MXU matmul scores every
+    (query, group) pair against the kv chunk ``k``/``v`` [C, hd].  THE
+    ragged mask law (see inference/ragged.py): query j attends kv positions
+    < min(kv_len, start + j + 1).  Flash accumulation across the kv grid
+    axis (innermost, sequential on TPU) in VMEM scratch — the window
+    streams through VMEM exactly once for the whole multi-query block.
+    """
+    c = pl.program_id(2)
+    S, G, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
+    C = k.shape[0]
+
+    @pl.when(c == 0)
+    def _init():
+        _flash_init(acc, m_s, z_s)
+
+    q = q_ref[0, 0].astype(jnp.float32).reshape(S * G, hd)
+    kv_pos = c * C + lax.broadcasted_iota(jnp.int32, (S * G, C), 1)
+    j = lax.broadcasted_iota(jnp.int32, (S * G, C), 0) // G  # query index
+    mask = kv_pos < jnp.minimum(kv_len, start + j + 1)
+    _flash_update(
+        q, k.astype(jnp.float32), v.astype(jnp.float32), mask, acc, m_s, z_s
+    )
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _emit():
+        o_ref[0, 0] = acc[...].reshape(S, G, hd)
+        m_ref[0, 0] = m_s[...].reshape(S, G)
+        z_ref[0, 0] = z_s[...].reshape(S, G)
+
+
+def _ragged_attn_kernel(
+    starts_ref, lens_ref,  # scalar-prefetch (SMEM)
+    q_ref, k_ref, v_ref, o_ref, m_ref, z_ref, acc, m_s, z_s,
+):
+    b = pl.program_id(0)
+    _ragged_step(
+        starts_ref[b], lens_ref[b], q_ref, k_ref[0, 0], v_ref[0, 0],
+        o_ref, m_ref, z_ref, acc, m_s, z_s,
+    )
+
+
+def _ragged_paged_attn_kernel(
+    layer_ref, tables_ref, starts_ref, lens_ref,  # scalar-prefetch (SMEM)
+    q_ref, k_ref, v_ref, o_ref, m_ref, z_ref, acc, m_s, z_s,
+):
+    """Paged ragged program: the block table drives page DMA (it rides the
+    K/V index_map) and every one of the row's S queries scores against each
+    page as it streams through — nothing is gathered or materialized."""
+    b = pl.program_id(0)
+    _ragged_step(
+        starts_ref[b], lens_ref[b], q_ref, k_ref[0, 0, 0], v_ref[0, 0, 0],
+        o_ref, m_ref, z_ref, acc, m_s, z_s,
+    )
+
+
+def _ragged_out(B: int, K: int, S: int, G: int, hd: int):
+    """(out_specs, out_shape, scratch_shapes) shared by both ragged calls."""
+
+    def o_map(b, k, c, *_refs):
+        return (b, k, 0, 0, 0)
+
+    def stat_map(b, k, c, *_refs):
+        return (b, k, 0, 0)
+
+    return (
+        [
+            pl.BlockSpec((1, 1, S, G, hd), o_map),
+            # (S, G) are the array's own last two dims — the layout the
+            # TPU lowering accepts for a per-(row, head) statistic
+            pl.BlockSpec((1, 1, S, G), stat_map),
+            pl.BlockSpec((1, 1, S, G), stat_map),
+        ],
+        (
+            jax.ShapeDtypeStruct((B, K, S, G, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
+        ),
+        [
+            pltpu.VMEM((S * G, hd), jnp.float32),
+            pltpu.VMEM((S * G, 1), jnp.float32),
+            pltpu.VMEM((S * G, 1), jnp.float32),
+        ],
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+def ragged_attention_pallas(
+    q: jax.Array,  # [B, K, S, G, hd] kv-head-major ragged queries
+    k_cache: jax.Array,  # [B, K, W, hd]
+    v_cache: jax.Array,
+    q_starts: jax.Array,  # [B] absolute position of each row's query 0
+    kv_lens: jax.Array,  # [B] valid kv length each row may attend
+    *,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Ragged unified attention over a dense window → (o [B,K,S,G,hd] f32
+    unnormalized, m [B,K,S,G], z [B,K,S,G]) — one kernel serving decode
+    (S=1), prefill-chunk (S=chunk), and verify (S=k+1) rows through the
+    shared mask law; the logsumexp merge composes on its output."""
+    _note_trace("ragged", interpret)
+    B, K, S, G, hd = q.shape
+    W = k_cache.shape[2]
+    kv_chunk = min(RAGGED_KV_CHUNK, W)
+    if W % kv_chunk:
+        kv_chunk = W  # non-power-of-two window: stream it whole
+
+    out_specs, out_shape, scratch = _ragged_out(B, K, S, G, hd)
+    kv_spec = pl.BlockSpec(
+        (1, 1, kv_chunk, hd), lambda b, k, c, *_refs: (b, k, c, 0)
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, K, W // kv_chunk),
+        in_specs=[
+            pl.BlockSpec(
+                (1, 1, S, G, hd), lambda b, k, c, *_refs: (b, k, 0, 0, 0)
+            ),
+            kv_spec,
+            kv_spec,
+        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        _ragged_attn_kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(
+        q_starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
+        q, k_cache, v_cache,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("wpages", "interpret"))
+def ragged_attention_paged_pallas(
+    q: jax.Array,  # [B, K, S, G, hd]
+    pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing)
+    pool_v: jax.Array,
+    layer: jax.Array,  # scalar int32
+    tables: jax.Array,  # [B, Pmax] int32 block tables
+    q_starts: jax.Array,  # [B]
+    kv_lens: jax.Array,  # [B]
+    *,
+    wpages: int,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Ragged unified attention through the block tables → (o, m, z), the
+    paged analog of :func:`ragged_attention_pallas`.
+
+    Taking the full pool (not a sliced layer) matters: slicing
+    ``pool[layer]`` in XLA before a pallas_call would materialize a copy of
+    the layer's pages every (layer, step); here the layer index rides the
+    index_map and only the addressed pages move."""
+    _note_trace("ragged_paged", interpret)
+    B, K, S, G, hd = q.shape
+    page = pool_k.shape[3]
+
+    out_specs, out_shape, scratch = _ragged_out(B, K, S, G, hd)
+    kv_spec = pl.BlockSpec(
+        (1, 1, 1, page, hd),
+        lambda b, k, p, layer_ref, tables_ref, starts_ref, lens_ref: (
+            layer_ref[0], tables_ref[b, p], k, 0, 0
+        ),
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, K, wpages),
+        in_specs=[
+            pl.BlockSpec(
+                (1, 1, S, G, hd), lambda b, k, p, *_refs: (b, k, 0, 0, 0)
+            ),
+            kv_spec,
+            kv_spec,
+        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        _ragged_paged_attn_kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        tables.astype(jnp.int32),
+        q_starts.astype(jnp.int32),
+        kv_lens.astype(jnp.int32),
+        q, pool_k, pool_v,
+    )
+
+
+# --------------------------------------------------------------------------- #
+# single-query decode: the S=1 row of the ragged law (start = kv_len)
+# --------------------------------------------------------------------------- #
+
+
 def decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
     k_cache: jax.Array,  # [B, K, W, hd]
@@ -66,90 +307,13 @@ def decode_attention_pallas(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """→ (o [B,K,G,hd] f32 unnormalized, m [B,K,G] f32, z [B,K,G] f32)."""
-    from jax.experimental import pallas as pl
-
-    B, K, G, hd = q.shape
-    W = k_cache.shape[2]
-
-    grid = (B, K)
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, G), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, G), jnp.float32),
-    )
-    return pl.pallas_call(
-        _decode_attn_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, k: (b,)),  # lens: this row's scalar
-            pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, W, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, W, hd), lambda b, k: (b, k, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, k: (b, k, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, k: (b, k, 0)),
-        ),
-        out_shape=out_shapes,
+    o, m, z = ragged_attention_pallas(
+        q[:, :, None], k_cache, v_cache, base_lens, base_lens,
         interpret=interpret,
-    )(base_lens, q, k_cache, v_cache)
-
-
-def _paged_attn_kernel(
-    layer_ref, tables_ref, lens_ref,  # scalar-prefetch (SMEM)
-    q_ref, k_ref, v_ref,  # tensor blocks (VMEM)
-    o_ref, m_ref, z_ref,  # outputs
-    acc, m_s, z_s,  # VMEM scratch carried across the page grid dim
-):
-    """One (batch row, kv head, page) program with flash accumulation.
-
-    The page grid dimension is innermost (sequential on TPU), so the
-    VMEM scratch carries softmax statistics across a row's pages; the block
-    table is scalar-prefetched and drives the K/V BlockSpec index_map — each
-    program DMAs exactly one page, nothing is gathered/materialized.
-    """
-    import jax.lax as lax
-
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    page = k_ref.shape[3]
-
-    @pl.when(p == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, -1e30)
-        z_s[...] = jnp.zeros_like(z_s)
-
-    q = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
-    k = k_ref[0, 0, 0].astype(jnp.float32)  # [page, hd]
-    v = v_ref[0, 0, 0].astype(jnp.float32)  # [page, hd]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-
-    scores = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [G, page]
-    pos = p * page + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(pos < lens_ref[b], scores, -1e30)
-
-    m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1, keepdims=True))
-    m_new = jnp.maximum(m_new, -1e29)  # fresh rows stay finite
-    alpha = jnp.exp(m_s[...] - m_new)
-    pexp = jnp.exp(scores - m_new)
-    z_s[...] = z_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    acc[...] = acc[...] * alpha + lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    m_s[...] = m_new
-
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[0, 0] = acc[...]
-        m_ref[0, 0] = m_s[...][:, 0]
-        z_ref[0, 0] = z_s[...][:, 0]
+    return o[:, :, 0], m[:, :, 0], z[:, :, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("wpages", "interpret"))
 def paged_decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
     pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing)
@@ -161,66 +325,13 @@ def paged_decode_attention_pallas(
     wpages: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Paged decode attention: block tables drive page DMA via scalar
-    prefetch → (o unnormalized, m, z), same contract as the dense kernel.
-
-    Taking the full pool (not a sliced layer) matters: slicing
-    ``pool[layer]`` in XLA before a pallas_call would materialize a copy of
-    the layer's pages every (layer, step); here the layer index rides the
-    index_map and only the addressed pages move.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, K, G, hd = q.shape
-    page = pool_k.shape[3]
-
-    grid = (B, K, wpages)
-    kv_spec = pl.BlockSpec(
-        (1, 1, 1, page, hd),
-        lambda b, k, p, layer_ref, tables_ref, lens_ref: (
-            layer_ref[0], tables_ref[b, p], k, 0, 0
-        ),
+    """Paged decode attention → (o unnormalized, m, z), same contract as
+    the dense single-query entry point."""
+    o, m, z = ragged_attention_paged_pallas(
+        q[:, :, None], pool_k, pool_v, layer, tables, base_lens, base_lens,
+        wpages=wpages, interpret=interpret,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, G, hd),
-                lambda b, k, p, *_refs: (b, k, 0, 0),
-            ),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, 1, G, hd), lambda b, k, p, *_refs: (b, k, 0, 0)
-            ),
-            pl.BlockSpec((1, 1, G), lambda b, k, p, *_refs: (b, k, 0)),
-            pl.BlockSpec((1, 1, G), lambda b, k, p, *_refs: (b, k, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
-    )
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, K, G, hd), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, G), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, G), jnp.float32),
-    )
-    return pl.pallas_call(
-        _paged_attn_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        tables.astype(jnp.int32),
-        base_lens.astype(jnp.int32),
-        q, pool_k, pool_v,
-    )
+    return o[:, :, 0], m[:, :, 0], z[:, :, 0]
 
 
 def merged_paged_decode_attention_pallas(
@@ -282,255 +393,6 @@ def merged_decode_attention_pallas(
     o2, m2, z2 = ring_attention_source(qg, ring_k, ring_v, t)
     out = logsumexp_merge((o1, m1[..., None], z1[..., None]), (o2, m2, z2))
     return out.reshape(B, 1, H, hd).astype(q.dtype)
-
-
-# --------------------------------------------------------------------------- #
-# ragged unified attention: mixed decode / prefill-chunk / verify rows
-# (ISSUE 6; the Ragged Paged Attention shape, arXiv:2604.15464)
-# --------------------------------------------------------------------------- #
-
-# kv positions streamed per grid step of the dense ragged kernel (the
-# window is a power-of-two bucket, so divisibility holds; windows smaller
-# than this run as one chunk)
-RAGGED_KV_CHUNK = 512
-
-
-def _ragged_attn_kernel(
-    starts_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, z_ref,
-    acc, m_s, z_s,
-):
-    """One (batch row, kv head, kv chunk) program of the ragged kernel.
-
-    The q block carries ALL of a row's queries (S = the wave's padded
-    q_len — 1 for decode rows, chunk for prefill rows, k+1 for verify
-    rows), flattened to [S·G, hd] so one MXU matmul scores every
-    (query, group) pair against the kv chunk.  THE ragged mask law (see
-    inference/ragged.py): query j attends kv positions
-    < min(kv_len, start + j + 1).  Flash accumulation across the kv grid
-    dimension in VMEM scratch — the window streams through VMEM exactly
-    once for the whole multi-query block, which is the amortization the
-    per-position decomposition paid S times for.
-    """
-    import jax.lax as lax
-
-    c = pl.program_id(2)
-    S, G, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    C = k_ref.shape[2]
-
-    @pl.when(c == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, -1e30)
-        z_s[...] = jnp.zeros_like(z_s)
-
-    q = q_ref[0, 0].astype(jnp.float32).reshape(S * G, hd)
-    k = k_ref[0, 0].astype(jnp.float32)  # [C, hd]
-    v = v_ref[0, 0].astype(jnp.float32)
-    scale = 1.0 / math.sqrt(hd)
-
-    scores = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [S*G, C]
-    kv_pos = c * C + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    j = lax.broadcasted_iota(jnp.int32, scores.shape, 0) // G  # query index
-    limit = jnp.minimum(lens_ref[0], starts_ref[0] + j + 1)
-    scores = jnp.where(kv_pos < limit, scores, -1e30)
-
-    m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1, keepdims=True))
-    m_new = jnp.maximum(m_new, -1e29)  # padding queries stay finite
-    alpha = jnp.exp(m_s[...] - m_new)
-    pexp = jnp.exp(scores - m_new)
-    z_s[...] = z_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    acc[...] = acc[...] * alpha + lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_s[...] = m_new
-
-    @pl.when(c == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[0, 0] = acc[...].reshape(S, G, hd)
-        m_ref[0, 0] = m_s[...].reshape(S, G)
-        z_ref[0, 0] = z_s[...].reshape(S, G)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ragged_attention_pallas(
-    q: jax.Array,  # [B, K, S, G, hd] kv-head-major ragged queries
-    k_cache: jax.Array,  # [B, K, W, hd]
-    v_cache: jax.Array,
-    q_starts: jax.Array,  # [B] absolute position of each row's query 0
-    kv_lens: jax.Array,  # [B] valid kv length each row may attend
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Ragged unified attention over a dense window → (o [B,K,S,G,hd] f32
-    unnormalized, m [B,K,S,G], z [B,K,S,G]) — one kernel serving decode
-    (S=1), prefill-chunk (S=chunk), and verify (S=k+1) rows through the
-    shared mask law; same source contract as the single-query kernel so
-    the logsumexp merge composes unchanged."""
-    B, K, S, G, hd = q.shape
-    W = k_cache.shape[2]
-    kv_chunk = min(RAGGED_KV_CHUNK, W)
-    if W % kv_chunk:
-        kv_chunk = W  # non-power-of-two window: stream it whole
-
-    grid = (B, K, W // kv_chunk)
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, K, S, G, hd), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
-    )
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pl.pallas_call(
-        _ragged_attn_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, k, c: (b,)),  # q_starts
-            pl.BlockSpec((1,), lambda b, k, c: (b,)),  # kv_lens
-            pl.BlockSpec((1, 1, S, G, hd), lambda b, k, c: (b, k, 0, 0, 0)),
-            pl.BlockSpec((1, 1, kv_chunk, hd), lambda b, k, c: (b, k, c, 0)),
-            pl.BlockSpec((1, 1, kv_chunk, hd), lambda b, k, c: (b, k, c, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, S, G, hd), lambda b, k, c: (b, k, 0, 0, 0)),
-            pl.BlockSpec((1, 1, S, G), lambda b, k, c: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, S, G), lambda b, k, c: (b, k, 0, 0)),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((S * G, hd), jnp.float32),
-            pltpu.VMEM((S * G, 1), jnp.float32),
-            pltpu.VMEM((S * G, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(
-        q_starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
-        q, k_cache, v_cache,
-    )
-
-
-def _ragged_paged_attn_kernel(
-    layer_ref, tables_ref, starts_ref, lens_ref,  # scalar-prefetch (SMEM)
-    q_ref, k_ref, v_ref,  # tensor blocks (VMEM)
-    o_ref, m_ref, z_ref,  # outputs
-    acc, m_s, z_s,  # VMEM scratch carried across the page grid dim
-):
-    """Paged ragged program: the block table drives page DMA (scalar
-    prefetch, like the single-query paged kernel) and every one of the
-    row's S queries scores against each page as it streams through — one
-    page read amortized over the whole ragged block."""
-    import jax.lax as lax
-
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    S, G, hd = q_ref.shape[2], q_ref.shape[3], q_ref.shape[4]
-    page = k_ref.shape[3]
-
-    @pl.when(p == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, -1e30)
-        z_s[...] = jnp.zeros_like(z_s)
-
-    q = q_ref[0, 0].astype(jnp.float32).reshape(S * G, hd)
-    k = k_ref[0, 0, 0].astype(jnp.float32)  # [page, hd]
-    v = v_ref[0, 0, 0].astype(jnp.float32)
-    scale = 1.0 / math.sqrt(hd)
-
-    scores = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [S*G, page]
-    kv_pos = p * page + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    j = lax.broadcasted_iota(jnp.int32, scores.shape, 0) // G
-    limit = jnp.minimum(lens_ref[b], starts_ref[b] + j + 1)
-    scores = jnp.where(kv_pos < limit, scores, -1e30)
-
-    m_new = jnp.maximum(m_s[...], jnp.max(scores, axis=-1, keepdims=True))
-    m_new = jnp.maximum(m_new, -1e29)
-    alpha = jnp.exp(m_s[...] - m_new)
-    pexp = jnp.exp(scores - m_new)
-    z_s[...] = z_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    acc[...] = acc[...] * alpha + lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_s[...] = m_new
-
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[0, 0] = acc[...].reshape(S, G, hd)
-        m_ref[0, 0] = m_s[...].reshape(S, G)
-        z_ref[0, 0] = z_s[...].reshape(S, G)
-
-
-@functools.partial(jax.jit, static_argnames=("wpages", "interpret"))
-def ragged_attention_paged_pallas(
-    q: jax.Array,  # [B, K, S, G, hd]
-    pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing)
-    pool_v: jax.Array,
-    layer: jax.Array,  # scalar int32
-    tables: jax.Array,  # [B, Pmax] int32 block tables
-    q_starts: jax.Array,  # [B]
-    kv_lens: jax.Array,  # [B]
-    *,
-    wpages: int,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Ragged unified attention through the block tables → (o, m, z), the
-    paged analog of :func:`ragged_attention_pallas` (same full-pool
-    no-materialization contract as the single-query paged kernel)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, K, S, G, hd = q.shape
-    page = pool_k.shape[3]
-
-    grid = (B, K, wpages)
-    kv_spec = pl.BlockSpec(
-        (1, 1, 1, page, hd),
-        lambda b, k, p, layer_ref, tables_ref, starts_ref, lens_ref: (
-            layer_ref[0], tables_ref[b, p], k, 0, 0
-        ),
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, S, G, hd), lambda b, k, p, *_refs: (b, k, 0, 0, 0)
-            ),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, 1, S, G, hd), lambda b, k, p, *_refs: (b, k, 0, 0, 0)
-            ),
-            pl.BlockSpec((1, 1, S, G), lambda b, k, p, *_refs: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, S, G), lambda b, k, p, *_refs: (b, k, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((S * G, hd), jnp.float32),
-            pltpu.VMEM((S * G, 1), jnp.float32),
-            pltpu.VMEM((S * G, 1), jnp.float32),
-        ],
-    )
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, K, S, G, hd), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
-        jax.ShapeDtypeStruct((B, K, S, G), jnp.float32),
-    )
-    return pl.pallas_call(
-        _ragged_paged_attn_kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        tables.astype(jnp.int32),
-        q_starts.astype(jnp.int32),
-        kv_lens.astype(jnp.int32),
-        q, pool_k, pool_v,
-    )
 
 
 # --------------------------------------------------------------------------- #
@@ -619,66 +481,61 @@ PREFILL_BLOCK_Q = 128
 PREFILL_KV_CHUNK = 512
 
 
+class PallasShapeError(ValueError):
+    """``attention_impl="pallas"`` was asked for shapes the kernel's block
+    grammar cannot tile.  Raised while the jit is traced — a kernel request
+    is never quietly served by the XLA path."""
+
+
+def prefill_blocks(
+    Sq: int, Skv: int,
+    block_q: int = PREFILL_BLOCK_Q, kv_chunk: int = PREFILL_KV_CHUNK,
+) -> tuple[int, int]:
+    """(block_q, kv_chunk) for a prefill of Sq queries over Skv cache
+    positions, or :class:`PallasShapeError` when they do not tile."""
+    block_q = min(block_q, Sq)
+    kv_chunk = min(kv_chunk, Skv)
+    if Sq % block_q or Skv % kv_chunk:
+        raise PallasShapeError(
+            f"pallas prefill attention cannot tile Sq={Sq} by "
+            f"block_q={block_q} and Skv={Skv} by kv_chunk={kv_chunk}: pick "
+            f"a prefill_chunk / bucket that is a multiple of "
+            f"{PREFILL_BLOCK_Q} / {PREFILL_KV_CHUNK} (or smaller than it), "
+            'or attention_impl="xla"'
+        )
+    return block_q, kv_chunk
+
+
 def _prefill_attn_kernel(
-    qpos_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, *, kv_chunk: int
+    lens_ref,  # scalar-prefetch (SMEM)
+    qpos_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, z_s,
 ):
-    """One (batch, kv-head, q-block) program: flash accumulation over kv.
+    """One (batch, kv-head, q-block, kv-chunk) program: flash accumulation
+    over the kv grid axis; the scores are [BQ, kv_chunk] per query group —
+    never the full [Sq, Skv] matrix the XLA path materializes."""
+    b = pl.program_id(0)
+    c = pl.program_id(3)
+    G, BQ = q_ref.shape[2], q_ref.shape[3]
+    C = k_ref.shape[2]
 
-    The whole [Skv, hd] K/V slice for this (b, k) sits in VMEM (≤ ~1 MB at
-    Skv=4096); the scores for each kv chunk are [BQ, kv_chunk] per query
-    group — never the full [Sq, Skv] matrix the XLA path materializes.
-    """
-    q_all = q_ref[0, 0].astype(jnp.float32)  # [G, BQ, hd]
-    k_all = k_ref[0, 0].astype(jnp.float32)  # [Skv, hd]
-    v_all = v_ref[0, 0].astype(jnp.float32)  # [Skv, hd]
-    q_pos = qpos_ref[0]  # [BQ] absolute positions of this q block
-    kv_len = lens_ref[0]  # scalar: valid kv for this row
-    G, BQ, hd = q_all.shape
-    Skv = k_all.shape[0]
-    scale = 1.0 / math.sqrt(hd)
-    n_chunks = Skv // kv_chunk
+    @pl.when(c == 0)
+    def _init():
+        _flash_init(acc, m_s, z_s)
 
-    def chunk_body(ci, carry):
-        m, z, acc = carry  # [G,BQ,1], [G,BQ,1], [G,BQ,hd]
-        start = ci * kv_chunk
-        k_c = jax.lax.dynamic_slice_in_dim(k_all, start, kv_chunk, 0)
-        v_c = jax.lax.dynamic_slice_in_dim(v_all, start, kv_chunk, 0)
-        kv_pos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (BQ, kv_chunk), 1
-        )
-        mask = (kv_pos <= q_pos[:, None]) & (kv_pos < kv_len)  # [BQ, kv_chunk]
-
-        new_m, new_z, new_acc = [], [], []
-        for g in range(G):  # static unroll: G is 1-8
-            scores = jax.lax.dot_general(
-                q_all[g], k_c, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [BQ, kv_chunk]
-            scores = jnp.where(mask, scores, -1e30)
-            m_c = jnp.maximum(m[g], jnp.max(scores, axis=-1, keepdims=True))
-            m_c = jnp.maximum(m_c, -1e29)  # all-masked chunks stay finite
-            alpha = jnp.exp(m[g] - m_c)
-            p = jnp.exp(scores - m_c)  # [BQ, kv_chunk]
-            new_z.append(z[g] * alpha + jnp.sum(p, axis=-1, keepdims=True))
-            new_acc.append(
-                acc[g] * alpha
-                + jax.lax.dot_general(
-                    p, v_c, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-            new_m.append(m_c)
-        return (
-            jnp.stack(new_m), jnp.stack(new_z), jnp.stack(new_acc)
+    k = k_ref[0, 0].astype(jnp.float32)  # [C, hd]
+    v = v_ref[0, 0].astype(jnp.float32)
+    kv_pos = c * C + lax.broadcasted_iota(jnp.int32, (BQ, C), 1)
+    # qpos block is [BQ, 1]: absolute positions of this q block
+    mask = (kv_pos <= qpos_ref[0]) & (kv_pos < lens_ref[b])
+    for g in range(G):  # static unroll: G is 1-8
+        _flash_update(
+            q_ref[0, 0, g].astype(jnp.float32), k, v, mask,
+            acc.at[g], m_s.at[g], z_s.at[g],
         )
 
-    init = (
-        jnp.full((G, BQ, 1), -1e30, jnp.float32),
-        jnp.zeros((G, BQ, 1), jnp.float32),
-        jnp.zeros((G, BQ, hd), jnp.float32),
-    )
-    m, z, acc = jax.lax.fori_loop(0, n_chunks, chunk_body, init)
-    o_ref[0, 0] = acc / jnp.maximum(z, 1e-30)
+    @pl.when(c == pl.num_programs(3) - 1)
+    def _emit():
+        o_ref[0, 0] = acc[...] / jnp.maximum(z_s[...], 1e-30)
 
 
 @functools.partial(
@@ -699,42 +556,50 @@ def prefill_attention_pallas(
 
     Requires ``Sq % block_q == 0`` (or ``Sq < block_q``, which shrinks the
     block) and ``Skv % kv_chunk == 0`` (ditto); the engine's power-of-two
-    prefill chunks and window buckets satisfy both.  Callers should fall
-    back to the XLA path otherwise (see ``model.prefill_attention``).
+    prefill chunks and window buckets satisfy both.  Anything else raises
+    :class:`PallasShapeError` at trace time.
     """
     B, Sq, H, hd = q.shape
     K, Skv = k_cache.shape[1], k_cache.shape[2]
     G = H // K
-    block_q = min(block_q, Sq)
-    kv_chunk = min(kv_chunk, Skv)
-    if Sq % block_q or Skv % kv_chunk:
-        raise ValueError(
-            f"prefill_attention_pallas: Sq={Sq} %% block_q={block_q} and "
-            f"Skv={Skv} %% kv_chunk={kv_chunk} must be 0"
-        )
-    nq = Sq // block_q
+    block_q, kv_chunk = prefill_blocks(Sq, Skv, block_q, kv_chunk)
+    _note_trace("prefill", interpret)
 
     # [B, Sq, H, hd] -> [B, K, G, Sq, hd]: kv-head-major query layout
     qg = q.reshape(B, Sq, K, G, hd).transpose(0, 2, 3, 1, 4)
-
-    out = pl.pallas_call(
-        functools.partial(_prefill_attn_kernel, kv_chunk=kv_chunk),
-        grid=(B, K, nq),
+    q_spec = pl.BlockSpec(
+        (1, 1, G, block_q, hd), lambda b, k, qi, c, *_refs: (b, k, 0, qi, 0)
+    )
+    kv_spec = pl.BlockSpec(
+        (1, 1, kv_chunk, hd), lambda b, k, qi, c, *_refs: (b, k, c, 0)
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, K, Sq // block_q, Skv // kv_chunk),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, k, qi: (b, qi)),  # q_pos
-            pl.BlockSpec((1,), lambda b, k, qi: (b,)),  # seq_lens
+            # positions as [B, Sq, 1]: a (block_q, 1) tail is a legal block
+            # where a (1, block_q) block of [B, Sq] is not
             pl.BlockSpec(
-                (1, 1, G, block_q, hd), lambda b, k, qi: (b, k, 0, qi, 0)
+                (1, block_q, 1), lambda b, k, qi, c, *_refs: (b, qi, 0)
             ),
-            pl.BlockSpec((1, 1, Skv, hd), lambda b, k, qi: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, Skv, hd), lambda b, k, qi: (b, k, 0, 0)),
+            q_spec,
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, G, block_q, hd), lambda b, k, qi: (b, k, 0, qi, 0)
-        ),
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((G, block_q, hd), jnp.float32),
+            pltpu.VMEM((G, block_q, 1), jnp.float32),
+            pltpu.VMEM((G, block_q, 1), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        _prefill_attn_kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, Sq, hd), jnp.float32),
         interpret=interpret,
-    )(q_pos, seq_lens, qg, k_cache, v_cache)
+    )(seq_lens.astype(jnp.int32), q_pos.astype(jnp.int32)[..., None], qg,
+      k_cache, v_cache)
 
     # [B, K, G, Sq, hd] -> [B, Sq, H, hd]
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)

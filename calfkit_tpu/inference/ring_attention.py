@@ -31,33 +31,13 @@ Design notes (tpu-first, not a port):
 from __future__ import annotations
 
 import functools
-import inspect
 import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.8
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, **kwargs):
-    if "check_rep" in kwargs:
-        kwargs[_CHECK_KW] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
-
 
 def ring_attention(
     q: jax.Array,  # [B, S, H, hd]
@@ -90,7 +70,7 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec, len_spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     def ring(q_blk, k_blk, v_blk, lens):
         # q_blk: [B, S/sp, H, hd] — this device's query block (resident)
@@ -307,7 +287,7 @@ def context_parallel_attention(
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, len_spec),
         out_specs=(out_spec, out_spec, out_spec),
-        check_rep=False,
+        check_vma=False,
     )
     def cp(qr, kb, vb, lens):
         from calfkit_tpu.inference.model import masked_attention_source

@@ -45,6 +45,24 @@ class ByteTokenizer:
         return data.decode("utf-8", errors="replace")
 
 
+class IdTokenizer:
+    """Renders EVERY generated id as visible text — for random-weights runs
+    (``bench.py``, ``chip_smoke.py``): such a model emits mostly ids the
+    byte tokenizer drops, the decoded text comes out empty, and no token
+    step is ever streamed."""
+
+    pad_id, bos_id, eos_id = 0, 1, 2
+
+    def __init__(self, vocab_size: int = 32000):
+        self.vocab_size = vocab_size
+
+    def encode(self, text: str) -> list[int]:
+        return [3 + (b % 250) for b in text.encode("utf-8")]
+
+    def decode(self, ids: list[int]) -> str:
+        return "".join(f" t{i}" for i in ids)
+
+
 class HFTokenizer:
     """transformers AutoTokenizer over a LOCAL directory (zero egress)."""
 

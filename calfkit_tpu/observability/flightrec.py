@@ -168,10 +168,14 @@ _SIGUSR2_INSTALLED = False
 
 
 def default_dump_dir() -> str:
-    """Where fault/SIGUSR2 dumps land: ``$CALFKIT_FLIGHTREC_DIR`` else
-    ``~/.cache/calfkit_tpu/flightrec``."""
-    return os.environ.get("CALFKIT_FLIGHTREC_DIR") or os.path.expanduser(
-        "~/.cache/calfkit_tpu/flightrec"
+    """Where fault/SIGUSR2 dumps land: ``$CALFKIT_FLIGHTREC_DIR`` else the
+    fixed git-ignored ``<checkout>/.flightrec`` — nothing is written
+    around the checkout."""
+    return os.environ.get("CALFKIT_FLIGHTREC_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        ))),
+        ".flightrec",
     )
 
 

@@ -1,0 +1,718 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the serving path starts on the chip.
+
+    python3 chip_smoke.py              # one TPU chip (what the driver runs)
+    python3 chip_smoke.py --chips 4    # the tensor-parallel path, four chips
+
+One process (a chip belongs to one process).  Every phase prints one JSON
+object; any failing phase makes the exit code non-zero.  The LAST line of
+stdout is ``{"ok": true, "device": {...}}`` only when every phase passed on
+a TPU — without one the script exits non-zero and prints no result.
+
+Default phases (one chip, TinyLlama-1.1B at its published width and depth,
+bf16, random weights from ``--seed``):
+
+- serve  — Worker/Agent/JaxLocalModelClient behind the in-repo Kafka-wire
+  broker (built here from native/*.cpp), Client.execute()/start(): single
+  requests, a token stream, a concurrent burst, an abandoned stream; then
+  the engine must be drained (all slots and pages back).
+- agree  — the engine's greedy tokens vs a plain full-recompute
+  ``model.forward`` wherever the reference's top-2 logit margin exceeds
+  ``--margin``.
+- pallas — the same engine configuration with ``attention_impl="pallas"``:
+  kernels compiled by Mosaic (never interpreted, never XLA), same check.
+- kernels — every Pallas kernel body, compiled, against its XLA reference
+  at TinyLlama-1.1B and Llama-3-8B widths.
+
+``--rehearse`` shrinks everything and uses interpret mode on the CPU; it
+can never print ``"ok": true``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import contextlib
+import functools
+import gc
+import json
+import os
+import subprocess
+import sys
+import time
+from typing import NoReturn
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, ROOT)
+
+BUILD_DIR = os.path.join(ROOT, ".build", "native")  # git-ignored
+COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+_compile_s = [0.0]  # seconds JAX spent tracing, lowering and compiling
+
+
+def _on_duration(event: str, secs: float, **_: object) -> None:
+    if event in COMPILE_EVENTS:
+        _compile_s[0] += secs
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def die(code: int, message: str) -> NoReturn:
+    print(f"chip_smoke: {message}", file=sys.stderr, flush=True)
+    sys.exit(code)
+
+
+# --------------------------------------------------------------------------- #
+# set-up helpers
+# --------------------------------------------------------------------------- #
+
+
+def build_broker() -> str:
+    """Build kafkad from native/*.cpp into a git-ignored directory and point
+    the spawn helper at it — the run depends on no committed binary."""
+    subprocess.run(
+        ["make", "-C", os.path.join(ROOT, "native"), f"BIN={BUILD_DIR}",
+         f"{BUILD_DIR}/kafkad", f"{BUILD_DIR}/libcrc32c.so"],
+        check=True, stdout=subprocess.DEVNULL,
+    )
+    os.environ["CALFKIT_KAFKAD"] = os.path.join(BUILD_DIR, "kafkad")
+    os.environ["CALFKIT_CRC32C"] = os.path.join(BUILD_DIR, "libcrc32c.so")
+    return os.environ["CALFKIT_KAFKAD"]
+
+
+def cache_entries(path: str) -> int:
+    try:
+        return len(os.listdir(path))
+    except OSError:
+        return 0
+
+
+def device_info() -> dict:
+    import jax
+
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind, "count": len(d)}
+
+
+def memory(devices) -> list[dict]:
+    out = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        out.append({
+            "id": d.id,
+            "bytes_in_use": stats.get("bytes_in_use"),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        })
+    return out
+
+
+def attn_impls(engine) -> dict:
+    paths = (
+        ("prefill", None), ("decode", None), ("paged_decode", None),
+        ("ragged", "decode"), ("paged_ragged", "paged_decode"),
+    )
+    return {p: engine._resolved_attn_impl(p, fallback=f) for p, f in paths}
+
+
+def prompts_for(vocab: int, lengths: tuple[int, ...], seed: int) -> list[list[int]]:
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(3, vocab, size=n)] for n in lengths]
+
+
+# --------------------------------------------------------------------------- #
+# serve: the normal entry points over the Kafka wire path
+# --------------------------------------------------------------------------- #
+
+
+async def settle_drained(engine, free_pages: int, timeout: float = 60.0) -> None:
+    """Bounded wait for in-flight retirement, then THE no-leak oracle."""
+    from calfkit_tpu.sim.chaos import assert_engine_drained
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        try:
+            assert_engine_drained(engine, free_pages)
+            return
+        except AssertionError:
+            await asyncio.sleep(0.05)
+    assert_engine_drained(engine, free_pages)
+
+
+async def serve_phase(name: str, model, *, singles: int, burst: int) -> dict:
+    """Drive ``model`` (a JaxLocalModelClient) through Worker + Client over
+    a freshly spawned kafkad.  Raises on any failure — no other transport is
+    tried."""
+    from calfkit_tpu.client import Client
+    from calfkit_tpu.mesh.kafka_wire import KafkaWireMesh, spawn_kafkad
+    from calfkit_tpu.nodes import Agent
+    from calfkit_tpu.worker import Worker
+
+    def is_token(event) -> bool:
+        return getattr(getattr(event, "step", None), "kind", "") == "token"
+
+    t_phase = time.perf_counter()
+    c_phase = _compile_s[0]
+    proc = spawn_kafkad(0)
+    try:
+        url = f"127.0.0.1:{proc.kafkad_port}"
+        mesh, client_mesh = KafkaWireMesh(url), KafkaWireMesh(url)
+        await client_mesh.start()
+        try:
+            t0 = time.perf_counter()
+            await model.start()  # param init + placement + engine thread
+            start_s = time.perf_counter() - t0
+            engine = model._engine
+            free_pages = engine._page_alloc.free_pages
+            agent = Agent("smoke_agent", model=model, stream_tokens=True)
+            async with Worker([agent], mesh=mesh, owns_transport=True):
+                client = Client.connect(client_mesh)
+                gateway = client.agent("smoke_agent")
+
+                # a handful of single requests (the first pays the compiles)
+                single_s = []
+                for i in range(singles):
+                    t0 = time.perf_counter()
+                    result = await gateway.execute(
+                        f"single request {i} " + "x" * (17 * i), timeout=900
+                    )
+                    single_s.append(round(time.perf_counter() - t0, 3))
+                    assert str(result.output).strip(), "empty single output"
+
+                # one token-streaming request, read to its end
+                handle = await gateway.start("stream me some tokens", timeout=900)
+                streamed = 0
+                async for event in handle.stream():
+                    streamed += is_token(event)
+                result = await handle.result(timeout=900)
+                assert streamed > 0, "no token step streamed"
+                assert str(result.output).strip(), "empty streamed output"
+
+                # a concurrent burst wider than the batch: continuous
+                # batching fills every slot and retired slots are reused
+                before = engine.stats.decode_tokens
+                t0 = time.perf_counter()
+                results = await asyncio.gather(*[
+                    gateway.execute(
+                        f"burst {i} " + "y" * (11 * (i % 7)), timeout=900
+                    )
+                    for i in range(burst)
+                ])
+                burst_s = time.perf_counter() - t0
+                assert all(str(r.output).strip() for r in results), (
+                    "a burst request returned nothing"
+                )
+                burst_tokens = engine.stats.decode_tokens - before
+
+                # one stream abandoned mid-way: the cancel must reach the
+                # engine and its slot and pages must come back
+                handle = await gateway.start(
+                    "abandon this one " + "z" * 40, timeout=900
+                )
+                seen = 0
+                async for event in handle.stream():
+                    seen += is_token(event)
+                    if seen >= 2:
+                        break
+                await handle.cancel()
+                await settle_drained(engine, free_pages)
+                await client.close()
+        finally:
+            await client_mesh.stop()
+    finally:
+        proc.terminate()
+        with contextlib.suppress(Exception):
+            proc.wait(timeout=5)
+
+    stats = engine.stats
+    slots = engine.runtime.max_batch_size
+    assert burst <= slots or stats.mean_occupancy > 0, "no batching observed"
+    compile_s = _compile_s[0] - c_phase
+    wall_s = time.perf_counter() - t_phase
+    return {
+        "phase": name,
+        "ok": True,
+        "transport": "kafkad-wire",
+        "model": engine.config.name,
+        "params": engine.config.param_count,
+        "n_layers": engine.config.n_layers,
+        "quantization": engine.runtime.quantization,
+        "tp": engine.runtime.tp,
+        "attention_impl": attn_impls(engine),
+        "engine_start_s": round(start_s, 2),
+        "compile_s": round(compile_s, 2),
+        "wall_s": round(wall_s, 2),
+        "serving_s": round(max(0.0, wall_s - compile_s), 2),
+        "single_request_s": single_s,
+        "streamed_token_steps": streamed,
+        "burst": {
+            "requests": burst, "slots": slots, "seconds": round(burst_s, 3),
+            "decode_tokens": burst_tokens,
+            "slot_reuse": burst > slots,
+        },
+        "abandoned_after_token_steps": seen,
+        "drained": {"free_slots": len(engine._free),
+                    "free_pages": engine._page_alloc.free_pages},
+        "tokens_produced": stats.decode_tokens,
+        "decode_dispatches": stats.decode_dispatches,
+        "unified_dispatches": stats.unified_dispatches,
+        "mean_batch_occupancy": round(stats.mean_occupancy, 3),
+    }
+
+
+# --------------------------------------------------------------------------- #
+# agree: engine greedy tokens vs a plain full-recompute forward
+# --------------------------------------------------------------------------- #
+
+
+async def greedy_tokens(engine, prompts: list[list[int]], n: int) -> list[list[int]]:
+    async def one(prompt):
+        return [t async for t in engine.generate(prompt, max_new_tokens=n)]
+
+    return list(await asyncio.gather(*[one(p) for p in prompts]))
+
+
+@functools.lru_cache(maxsize=None)
+def reference_fn(config):
+    """ONE plain ``model.forward`` (XLA attention, no engine, no cache reuse)
+    over a whole padded sequence → (argmax, top-2 margin) per position.
+    Cached per config so both agreement phases share one compile."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import model as M
+
+    @jax.jit
+    def ref(params, tokens, n):  # tokens [1, P]
+        P = tokens.shape[1]
+        cache = M.make_empty_cache(config, 1, P)
+        pos = jnp.arange(P, dtype=jnp.int32)[None]
+        logits, _ = M.forward(params, config, tokens, pos, cache, n[None])
+        top2 = jax.lax.top_k(logits[0].astype(jnp.float32), 2)
+        return top2[1][:, 0], top2[0][:, 0] - top2[0][:, 1]
+
+    return ref
+
+
+def reference_check(
+    params, config, prompts, outputs, margin: float, pad_to: int
+) -> dict:
+    """Teacher-forced agreement: the reference recomputes every position of
+    prompt + generated tokens; wherever its top-2 logit margin exceeds
+    ``margin`` the engine's token must be its argmax."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    ref = reference_fn(config)
+    compared = equal = total = 0
+    for prompt, out in zip(prompts, outputs):
+        seq = prompt + out
+        tokens = np.zeros((1, pad_to), np.int32)
+        tokens[0, : len(seq)] = seq
+        arg, gap = ref(params, jnp.asarray(tokens), jnp.int32(len(seq)))
+        arg, gap = np.asarray(arg), np.asarray(gap)
+        assert np.isfinite(gap[: len(seq)]).all(), "non-finite reference logits"
+        for i, tok in enumerate(out):
+            at = len(prompt) - 1 + i  # position whose logits chose out[i]
+            total += 1
+            if gap[at] > margin:
+                compared += 1
+                equal += int(arg[at] == tok)
+    return {"positions": total, "compared": compared, "equal": equal,
+            "margin": margin}
+
+
+def count_equal(xs: list[list[int]], ys: list[list[int]]) -> int:
+    return sum(int(a == b) for x, y in zip(xs, ys) for a, b in zip(x, y))
+
+
+async def agree_phase(name, engine, prompts, new_tokens, margin, pad_to) -> tuple[dict, list]:
+    t0, c0 = time.perf_counter(), _compile_s[0]
+    outputs = await greedy_tokens(engine, prompts, new_tokens)
+    assert all(len(o) == new_tokens for o in outputs), "short generation"
+    check = reference_check(
+        engine.params, engine.config, prompts, outputs, margin, pad_to
+    )
+    ok = check["compared"] > 0 and check["equal"] == check["compared"]
+    return {
+        "phase": name, "ok": ok, **check,
+        "prompt_lens": [len(p) for p in prompts],
+        "attention_impl": attn_impls(engine),
+        "compile_s": round(_compile_s[0] - c0, 2),
+        "seconds": round(time.perf_counter() - t0, 2),
+    }, outputs
+
+
+# --------------------------------------------------------------------------- #
+# kernels: every Pallas kernel body, compiled, against its XLA reference
+# --------------------------------------------------------------------------- #
+
+
+def kernels_phase(seed: int, interpret: bool) -> dict:
+    """The serving run above selects the paged and prefill kernels only; here
+    all three bodies (ragged dense, ragged paged, prefill) run at
+    TinyLlama-1.1B and Llama-3-8B widths — S=1 decode rows and S=5 verify
+    rows, two kv chunks, two q blocks — and must match the XLA reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from calfkit_tpu.inference import model as M
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    t0, c0 = time.perf_counter(), _compile_s[0]
+    B, W, page = (2, 128, 16) if interpret else (8, 1024, 64)
+    Sq = 64 if interpret else 256
+    wpages = W // page
+    rng = np.random.default_rng(seed)
+    worst: dict[str, float] = {}
+
+    def close(name, got, want):
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
+        worst[name] = max(worst.get(name, 0.0), err)
+
+    for K, G, hd in ((4, 8, 64), (8, 4, 128)):
+        key = jax.random.split(jax.random.key(seed + hd), 6)
+        bf = jnp.bfloat16
+        kc = jax.random.normal(key[0], (B, K, W, hd), bf)
+        vc = jax.random.normal(key[1], (B, K, W, hd), bf)
+        # the same K/V laid out as pages (row b's page p is pool page 1+b*wpages+p)
+        to_pool = lambda c: jnp.concatenate([  # noqa: E731
+            jnp.zeros((1, K, page, hd), bf),
+            c.reshape(B, K, wpages, page, hd).transpose(0, 2, 1, 3, 4)
+             .reshape(B * wpages, K, page, hd),
+        ])[None]
+        pool_k, pool_v = to_pool(kc), to_pool(vc)
+        tables = jnp.asarray(
+            1 + np.arange(B * wpages, dtype=np.int32).reshape(B, wpages)
+        )
+        lens = jnp.asarray(rng.integers(1, W - 8, size=B), jnp.int32)
+        for S in (1, 5):
+            q = jax.random.normal(key[2], (B, S, K * G, hd), bf)
+            qk = q.reshape(B, S, K, G, hd).transpose(0, 2, 1, 3, 4)
+            want = M.ragged_attention_xla(q, kc, vc, lens, lens + S)
+
+            def norm(o, m, z):  # [B,K,S,G,hd] -> [B,S,H,hd]
+                out = o / jnp.maximum(z[..., None], 1e-30)
+                return out.transpose(0, 2, 1, 3, 4).reshape(B, S, K * G, hd)
+
+            close(f"ragged-dense/S{S}", norm(*PA.ragged_attention_pallas(
+                qk, kc, vc, lens, lens + S, interpret=interpret)), want)
+            close(f"ragged-paged/S{S}", norm(*PA.ragged_attention_paged_pallas(
+                qk, pool_k, pool_v, jnp.int32(0), tables, lens, lens + S,
+                wpages=wpages, interpret=interpret)), want)
+        q = jax.random.normal(key[3], (B, Sq, K * G, hd), bf)
+        offset = W - Sq - 8
+        pos = offset + jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32), (B, Sq))
+        plens = jnp.full((B,), offset + Sq, jnp.int32)
+        close("prefill", PA.prefill_attention_pallas(
+            q, kc, vc, pos, plens, interpret=interpret),
+            M.attention_xla(q, kc, vc, pos, plens))
+    tol = 3e-2  # bf16 inputs and outputs, values O(1)
+    return {
+        "phase": "kernels", "ok": all(e < tol for e in worst.values()),
+        "max_abs_err_vs_xla": {k: round(v, 5) for k, v in worst.items()},
+        "tolerance": tol, "widths": ["tinyllama-1.1b", "llama-3-8b"],
+        "mode": "interpreted" if interpret else "compiled",
+        "compile_s": round(_compile_s[0] - c0, 2),
+        "seconds": round(time.perf_counter() - t0, 2),
+    }
+
+
+# --------------------------------------------------------------------------- #
+# the runs
+# --------------------------------------------------------------------------- #
+
+
+def sizes(rehearse: bool) -> dict:
+    if rehearse:
+        return dict(preset="debug", seq=256, chunk=32, page=16, bs=4, burst=6,
+                    singles=2, new_tokens=8, agree_lens=(5, 20, 40),
+                    agree_new=8, pad_to=64)
+    return dict(preset="tinyllama-1.1b", seq=1024, chunk=128, page=64, bs=16,
+                burst=24, singles=4, new_tokens=24, agree_lens=(12, 70, 200),
+                agree_new=48, pad_to=256)
+
+
+def serving_runtime(sz: dict, impl: str, **kw):
+    """The serving defaults the bench uses: paged KV, chunked prefill (the
+    ragged-wave substrate), overlap dispatch and ragged waves on."""
+    from calfkit_tpu.inference.config import RuntimeConfig
+
+    return RuntimeConfig(
+        max_batch_size=sz["bs"], max_seq_len=sz["seq"],
+        prefill_chunk=sz["chunk"], page_size=sz["page"],
+        kv_layout="paged", chunked_prefill=True, attention_impl=impl, **kw,
+    )
+
+
+async def run_one_chip(args, sz: dict) -> bool:
+    import jax
+
+    from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference.client import JaxLocalModelClient
+    from calfkit_tpu.inference.config import preset
+    from calfkit_tpu.inference.tokenizer import IdTokenizer
+
+    config = preset(sz["preset"], max_seq_len=sz["seq"])
+    pallas = "pallas_interpret" if args.rehearse else "pallas"
+    ok = True
+    prompts = prompts_for(config.vocab_size, sz["agree_lens"], args.seed)
+    xla_outputs = None
+    for phase, impl in (("serve", "auto"), ("pallas", pallas)):
+        PA.KERNEL_TRACES.clear()
+        model = JaxLocalModelClient(
+            config=config, runtime=serving_runtime(sz, impl),
+            tokenizer=IdTokenizer(config.vocab_size),
+            max_new_tokens=sz["new_tokens"], seed=args.seed,
+        )
+        row = await serve_phase(
+            phase, model, singles=sz["singles"], burst=sz["burst"]
+        )
+        engine = model._engine
+        agree, outputs = await agree_phase(
+            "agree" if impl == "auto" else "pallas-agree", engine, prompts,
+            sz["agree_new"], args.margin, sz["pad_to"],
+        )
+        if impl == "auto":
+            xla_outputs = outputs
+        else:
+            traces = {f"{k}:{mode}": n for (k, mode), n in PA.KERNEL_TRACES.items()}
+            want = "interpreted" if args.rehearse else "compiled"
+            row["kernel_traces"] = agree["kernel_traces"] = traces
+            kernels_ok = bool(traces) and all(
+                key.endswith(want) for key in traces
+            ) and all(v == pallas for v in row["attention_impl"].values())
+            agree["kernels_all_" + want] = kernels_ok
+            agree["equal_to_xla_engine"] = count_equal(xla_outputs, outputs)
+            agree["ok"] = agree["ok"] and kernels_ok
+        row["memory"] = agree["memory"] = memory(jax.devices()[:1])
+        emit(row)
+        emit(agree)
+        ok = ok and row["ok"] and agree["ok"]
+        await model.stop()
+        del model, engine
+        gc.collect()  # the next engine's weights must not sit beside these
+    row = kernels_phase(args.seed, interpret=args.rehearse)
+    emit(row)
+    return ok and row["ok"]
+
+
+async def run_llama8b_int8(args) -> bool:
+    """Optional, builder-run: the north-star shape on one chip."""
+    import jax
+
+    from calfkit_tpu.inference.client import JaxLocalModelClient
+    from calfkit_tpu.inference.config import preset
+    from calfkit_tpu.inference.engine import InferenceEngine
+    from calfkit_tpu.inference.quant import random_quantized_params_host
+    from calfkit_tpu.inference.tokenizer import IdTokenizer
+
+    config = preset("llama-3-8b", max_seq_len=1024)
+    sz = dict(bs=16, seq=1024, chunk=128, page=64)
+    engine = InferenceEngine(
+        config, serving_runtime(sz, "auto", quantization="int8"),
+        params=random_quantized_params_host(config, seed=args.seed),
+    )
+    model = JaxLocalModelClient(
+        engine=engine, tokenizer=IdTokenizer(config.vocab_size),
+        max_new_tokens=16,
+    )
+    row = await serve_phase("serve-llama8b-int8", model, singles=2, burst=20)
+    row["memory"] = memory(jax.devices()[:1])
+    emit(row)
+    await model.stop()
+    return row["ok"]
+
+
+async def run_four_chips(args) -> bool:
+    """Only the tensor-parallel path and what it is compared with."""
+    import jax
+
+    from calfkit_tpu.inference.client import JaxLocalModelClient
+    from calfkit_tpu.inference.config import preset
+    from calfkit_tpu.inference.engine import InferenceEngine
+    from calfkit_tpu.inference.sharding import make_mesh
+    from calfkit_tpu.inference.tokenizer import IdTokenizer
+
+    if args.rehearse:
+        base = preset("debug", max_seq_len=256, n_kv_heads=4)
+        sz = dict(bs=4, seq=256, chunk=32, page=16)
+        full_depth, cut_depth, lens, new, pad_to = 2, 1, (5, 20, 40), 8, 64
+        serve, serve_new = dict(singles=1, burst=6), 8
+    else:
+        base = preset("llama-3-8b", max_seq_len=1024)
+        sz = dict(bs=16, seq=1024, chunk=128, page=64)
+        full_depth, cut_depth, lens, new, pad_to = 32, 4, (12, 70, 200), 32, 256
+        serve, serve_new = dict(singles=2, burst=20), 16
+    devices = jax.devices()
+    ok = True
+
+    # ---- tp=4 at full depth: 16 GB of bf16 weights, more than one chip holds
+    from dataclasses import replace
+
+    config = replace(base, n_layers=full_depth)
+    model = JaxLocalModelClient(
+        config=config, runtime=serving_runtime(sz, "auto", tp=4),
+        tokenizer=IdTokenizer(config.vocab_size),
+        max_new_tokens=serve_new, seed=args.seed,
+    )
+    row = await serve_phase("serve-tp4", model, **serve)
+    row["memory"] = memory(devices[:4])
+    used = [m["bytes_in_use"] for m in row["memory"]]
+    if all(u is not None for u in used):
+        # sharded must be SHOWN: every device holds a real share and none
+        # holds (nearly) the whole tree
+        weights = 2 * config.param_count
+        row["weights_bytes"] = weights
+        row["sharded"] = min(used) > 0.15 * weights and max(used) < 0.6 * weights
+        row["ok"] = row["ok"] and row["sharded"]
+    emit(row)
+    ok = ok and row["ok"]
+    await model.stop()
+    del model
+    gc.collect()
+
+    # ---- the comparison: same widths at a depth one chip holds, tp=4 vs tp=1
+    config = replace(base, n_layers=cut_depth)
+    prompts = prompts_for(config.vocab_size, lens, args.seed)
+    outs = {}
+    for tp in (4, 1):
+        rt = serving_runtime(sz, "auto", tp=tp)
+        engine = InferenceEngine(
+            config, rt, mesh=make_mesh(tp=tp, devices=devices[:tp]),
+            seed=args.seed,
+        )
+        await engine.start()
+        agree, outs[tp] = await agree_phase(
+            f"agree-tp{tp}", engine, prompts, new, args.margin, pad_to
+        )
+        agree["n_layers"] = cut_depth
+        agree["memory"] = memory(devices[:4])
+        emit(agree)
+        ok = ok and agree["ok"]
+        await engine.stop()
+        del engine
+        gc.collect()
+    emit({"phase": "tp4-vs-tp1", "ok": True, "positions": len(lens) * new,
+          "equal_tokens": count_equal(outs[4], outs[1]),
+          "note": "free-running streams part at the first near-tie; the "
+                  "margin rule above is the pass/fail check"})
+
+    if args.long_context:
+        ok = await run_long_context(args, base, full_depth) and ok
+    return ok
+
+
+async def run_long_context(args, base, depth: int) -> bool:
+    """One request through the ring-attention lane over all four devices."""
+    from dataclasses import replace
+
+    from calfkit_tpu.inference.config import RuntimeConfig
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    seq = 256 if args.rehearse else 1024
+    config = replace(base, n_layers=1 if args.rehearse else 4, max_seq_len=seq)
+    engine = InferenceEngine(config, RuntimeConfig(
+        max_batch_size=2, max_seq_len=seq, prefill_chunk=seq // 4, tp=4,
+        long_context=True, long_new_cap=16,
+    ), seed=args.seed)
+    await engine.start()
+    t0 = time.perf_counter()
+    prompt = prompts_for(config.vocab_size, (3 * seq,), args.seed)[0]
+    out = [t async for t in engine.generate(prompt, max_new_tokens=8)]
+    await engine.stop()
+    row = {"phase": "long-context", "ok": len(out) == 8,
+           "prompt_len": len(prompt), "tokens": len(out),
+           "seconds": round(time.perf_counter() - t0, 2)}
+    emit(row)
+    return row["ok"]
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--margin", type=float, default=0.25, help=(
+        "top-2 logit margin (f32 logits of the bf16 reference) above which "
+        "the engine's token must equal the reference argmax"
+    ))
+    ap.add_argument("--rehearse", action="store_true", help=(
+        "tiny sizes + interpret mode on the CPU; never prints ok:true"
+    ))
+    ap.add_argument("--llama8b-int8", action="store_true", help=(
+        "one chip: also serve llama-3-8b int8 + paged KV (builder-run)"
+    ))
+    ap.add_argument("--long-context", action="store_true", help=(
+        "with --chips 4: also one request through the ring-attention lane"
+    ))
+    args = ap.parse_args()
+
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if args.chips == 4:
+            os.environ["XLA_FLAGS"] = (
+                os.environ.get("XLA_FLAGS", "")
+                + " --xla_force_host_platform_device_count=4"
+            ).strip()
+    try:
+        import jax
+
+        import calfkit_tpu  # noqa: F401 - the repo must be around this file
+        from calfkit_tpu.inference.compile_cache import enable_compile_cache
+    except ImportError as e:
+        die(3, f"cannot import the program: {e}")
+    try:
+        device = device_info()
+    except RuntimeError as e:
+        die(2, f"JAX found no backend: {e}")
+    if device["platform"] != "tpu" and not args.rehearse:
+        die(2, f"no TPU: jax.devices()[0].platform == {device['platform']!r}")
+    if device["count"] < args.chips:
+        die(2, f"--chips {args.chips} needs {args.chips} devices, "
+               f"found {device['count']}")
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    cache_dir = enable_compile_cache()
+    cache_before = cache_entries(cache_dir)
+    t0 = time.perf_counter()
+    broker = build_broker()
+    emit({"phase": "setup", "ok": True, "device": device,
+          "broker": os.path.relpath(broker, ROOT),
+          "broker_build_s": round(time.perf_counter() - t0, 2),
+          "compile_cache_dir": cache_dir,
+          "compile_cache_entries_before": cache_before,
+          "rehearsal": args.rehearse, "seed": args.seed})
+
+    async def run() -> bool:
+        if args.chips == 4:
+            return await run_four_chips(args)
+        ok = await run_one_chip(args, sizes(args.rehearse))
+        if args.llama8b_int8:
+            ok = await run_llama8b_int8(args) and ok
+        return ok
+
+    ok = asyncio.run(run())
+    after = cache_entries(cache_dir)
+    emit({"phase": "summary", "ok": ok, "wall_s": round(time.perf_counter() - t0, 1),
+          "compile_s": round(_compile_s[0], 1),
+          "compile_cache_dir": cache_dir,
+          "compile_cache_entries_after": after,
+          "compile_cache_written": after > cache_before})
+    if not ok:
+        die(1, "a phase failed")
+    if args.rehearse:
+        emit({"ok": False, "rehearsal_passed": True, "device": device})
+        return
+    emit({"ok": True, "device": device})
+
+
+if __name__ == "__main__":
+    main()

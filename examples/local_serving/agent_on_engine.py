@@ -22,14 +22,9 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax  # noqa: E402
-
 # pin only the DEFAULT: an explicit JAX_PLATFORMS (e.g. tpu on real
-# hardware) wins — some images' sitecustomize ignores the env var, so
-# the config.update mirrors whatever the env resolved to
-jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+# hardware) wins
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from calfkit_tpu import Agent, Client, InMemoryMesh, Worker  # noqa: E402
 from calfkit_tpu.inference.client import JaxLocalModelClient  # noqa: E402

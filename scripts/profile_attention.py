@@ -440,26 +440,16 @@ def main() -> None:
     ap.add_argument("--impls", default="xla,pallas")
     ap.add_argument("--out", default=None, help=(
         "write the per-path winner artifact here (the engine's "
-        "attention_impl='auto' reads it via $CALFKIT_ATTN_PROFILE or "
-        "~/.cache/calfkit_tpu_attn_profile.json)"
-    ))
-    ap.add_argument("--install", action="store_true", help=(
-        "also copy the artifact to ~/.cache/calfkit_tpu_attn_profile.json "
-        "so auto picks it up on this machine"
+        "attention_impl='auto' reads it via $CALFKIT_ATTN_PROFILE)"
     ))
     args = ap.parse_args()
     impls = args.impls.split(",")
 
     import jax
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.expanduser("~/.cache/calfkit_tpu_xla"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        pass
+    from calfkit_tpu.inference.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     platform = jax.devices()[0].platform
     print(f"# platform={platform} devices={len(jax.devices())}",
@@ -492,7 +482,7 @@ def main() -> None:
         profile_ragged_paged("llama-3-8b", B=32, wpages=4, S=5, page=64,
                              impls=impls, n_layers=4, rows=rows)
 
-    if args.out or args.install:
+    if args.out:
         verdict = {
             "platform": platform,
             "captured_at": time.strftime(
@@ -501,20 +491,11 @@ def main() -> None:
             "winners": compute_winners(rows),
             "rows": rows,
         }
-        payload = json.dumps(verdict, indent=1)
-        targets = []
-        if args.out:
-            targets.append(args.out)
-        if args.install:
-            targets.append(
-                os.path.expanduser("~/.cache/calfkit_tpu_attn_profile.json")
-            )
-        for target in targets:
-            os.makedirs(os.path.dirname(os.path.abspath(target)), exist_ok=True)
-            with open(target, "w") as f:
-                f.write(payload)
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            f.write(json.dumps(verdict, indent=1))
         print(json.dumps({"winners": verdict["winners"],
-                          "written": targets}))
+                          "written": [args.out]}))
 
 
 if __name__ == "__main__":

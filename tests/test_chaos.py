@@ -1565,7 +1565,7 @@ class TestFailoverChaos:
 
 class TestWedgeWatchdog:
     """The engine wedge watchdog (ISSUE 9): a scripted hung device grant
-    (the decode thread blocks mid-dispatch, exactly the BENCH r05 state)
+    (the decode thread blocks mid-dispatch, a device that never answers)
     converts to typed RETRIABLE faults within the threshold, readiness
     flips false, the flight recorder dumps — and a late landing
     un-wedges the engine with zero leaked slots or pages."""

@@ -293,6 +293,10 @@ class TestFaultDump:
         await engine.stop()
 
     async def test_fault_dump_writes_into_env_dir(self, tmp_path, monkeypatch):
+        # unset: one fixed directory inside the checkout, never the home
+        monkeypatch.delenv("CALFKIT_FLIGHTREC_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert flightrec.default_dump_dir() == os.path.join(repo, ".flightrec")
         monkeypatch.setenv("CALFKIT_FLIGHTREC_DIR", str(tmp_path / "sub"))
         assert flightrec.default_dump_dir() == str(tmp_path / "sub")
         fr = FlightRecorder(8, label="envdir")

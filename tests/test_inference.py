@@ -1469,27 +1469,46 @@ class TestPallasPrefillAttention:
                 q, kc, kc, q_pos, jnp.array([130], jnp.int32), interpret=True
             )
 
-    def test_dispatch_falls_back_to_xla_when_ineligible(self):
-        import numpy as np
-
-        from calfkit_tpu.inference.model import (
-            attention_xla,
-            prefill_attention,
-        )
+    @pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
+    def test_dispatch_raises_when_ineligible(self, impl):
+        """A kernel request on a shape the block grammar cannot tile is a
+        named error at trace time — never a quiet hand-over to XLA."""
+        from calfkit_tpu.inference.model import prefill_attention
+        from calfkit_tpu.inference.pallas_attention import PallasShapeError
 
         B, Sq, H, K, hd, Skv = 1, 130, 4, 4, 64, 256  # Sq not blockable
-        ks = jax.random.split(jax.random.key(7), 3)
-        q = jax.random.normal(ks[0], (B, Sq, H, hd), jnp.float32)
-        kc = jax.random.normal(ks[1], (B, K, Skv, hd), jnp.float32)
-        vc = jax.random.normal(ks[2], (B, K, Skv, hd), jnp.float32)
+        q = jnp.zeros((B, Sq, H, hd), jnp.float32)
+        kc = jnp.zeros((B, K, Skv, hd), jnp.float32)
         q_pos = jnp.broadcast_to(jnp.arange(Sq), (B, Sq))
         lens = jnp.array([Sq], jnp.int32)
-        out = prefill_attention(q, kc, vc, q_pos, lens,
-                                attn_impl="pallas_interpret")
-        np.testing.assert_allclose(
-            np.asarray(attention_xla(q, kc, vc, q_pos, lens), np.float32),
-            np.asarray(out, np.float32), atol=1e-5, rtol=1e-5,
-        )
+        with pytest.raises(PallasShapeError, match="block_q"):
+            jax.jit(
+                lambda *a: prefill_attention(*a, attn_impl=impl)
+            ).lower(q, kc, kc, q_pos, lens)
+
+    def test_engine_jit_build_raises_when_ineligible(self):
+        """Through the engine: a prefill bucket the kernel cannot tile
+        (576 = 9 x 64 is not a multiple of the 512 kv chunk) fails when the
+        prefill jit is traced."""
+        from calfkit_tpu.inference.pallas_attention import PallasShapeError
+
+        engine = InferenceEngine(CFG, RuntimeConfig(
+            max_batch_size=2, max_seq_len=1024, prefill_chunk=64,
+            attention_impl="pallas_interpret",
+        ))
+        B = 2
+        fn = engine._prefill_jit(576, 1)
+        i32 = jnp.int32
+        with pytest.raises(PallasShapeError, match="kv_chunk"):
+            fn.lower(
+                engine.params, engine._k, engine._v,
+                jnp.zeros((B,), i32), jnp.zeros((B,), i32),
+                jnp.zeros((1, 576), i32), jnp.zeros((1,), i32),
+                jnp.ones((1,), i32),
+                engine._slot_keys, engine._temp, engine._top_k, engine._top_p,
+                jnp.zeros((1,), i32), jnp.zeros((1,), jnp.float32),
+                jnp.zeros((1,), i32), jnp.ones((1,), jnp.float32),
+            )
 
 
 class TestAttnAutoResolution:

@@ -61,6 +61,19 @@ async def _gen(engine, prompt, n, **kw):
     return [t async for t in engine.generate(prompt, max_new_tokens=n, **kw)]
 
 
+def _settled(engine, total_free: int) -> bool:
+    """Settle predicate for a drained engine.  The decode thread nulls
+    ``_pend`` BEFORE ``_free_deferred`` returns the slot and pages, so the
+    free list and the page pool are part of the condition: settling on
+    ``_pend``/``_active`` alone observes a state that is consistent one
+    tick later (same law as tests/test_chaos.py ``_drained``)."""
+    return (
+        engine._pend is None and not engine._active
+        and len(engine._free) == engine.runtime.max_batch_size
+        and engine._page_alloc.free_pages == total_free
+    )
+
+
 async def _serve_all(params, runtime, jobs):
     """Run ``jobs`` = [(prompt, max_new, kwargs), ...] concurrently on a
     fresh engine; returns the per-job token streams."""
@@ -347,7 +360,7 @@ class TestCancellationMidFlight:
             # let the scheduler reap + drain the in-flight dispatch
             for _ in range(50):
                 await asyncio.sleep(0.02)
-                if engine._pend is None and not engine._active:
+                if _settled(engine, total_free):
                     break
             assert not engine._active
             assert engine._pend is None
@@ -602,9 +615,7 @@ class TestShedExpireParity:
                     await active
                 with pytest.raises(DeadlineExceededError):
                     await queued
-                await settle(
-                    lambda: not engine._active and engine._pend is None
-                )
+                await settle(lambda: _settled(engine, total_free))
                 assert_engine_drained(engine, total_free)
                 assert engine.stats.expired_requests == 2
                 assert engine.stats.cancelled_requests == 0

@@ -553,37 +553,6 @@ class TestPerfGate:
             assert set(entry) == set(scenario.gated)
 
 
-# ------------------------------------------------- bench staleness stamp
-class TestBenchStaleStamp:
-    """ISSUE 11 satellite: a cache file stamped ``stale_reason`` can
-    never again be reported as current, no matter what the sha diff
-    says — and the committed r05 artifacts carry the stamp."""
-
-    def test_stamped_cache_forces_stale(self, monkeypatch, capsys):
-        sys.path.insert(0, REPO)
-        import bench
-
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        monkeypatch.setattr(
-            bench, "_probe_accelerator",
-            lambda timeout_s=120: (False, "no chip answered", "absent"),
-        )
-        # even if the code diff says "clean", the stamp wins
-        monkeypatch.setattr(bench, "_cache_is_stale_code", lambda c: False)
-        bench.main()
-        out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert out["status"] == "stale"
-        assert "STALE" in out["error"]
-
-    def test_r05_artifacts_are_stamped(self):
-        for name in ("BENCH_TPU_CACHE.json", "BENCH_r05.json"):
-            with open(os.path.join(REPO, name)) as f:
-                doc = json.load(f)
-            assert doc["status"] == "stale", name
-            reason = doc["stale_reason"]
-            assert reason["code"] and reason["detail"], name
-
-
 # ----------------------------------------------------------------- shim
 class TestChaosShim:
     def test_legacy_imports_still_resolve(self):

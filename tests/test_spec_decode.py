@@ -443,7 +443,12 @@ class TestDraftModelSeam:
         )
         await base.start()
         await spec.start()
-        prompt = [2, 4, 6, 8]
+        # a prompt whose 16 greedy steps all have a top-2 logit margin
+        # >= 0.03: "exact" is only defined away from near-ties — the verify
+        # program (k+1 positions) and the decode program (1 position) are
+        # different XLA programs, and [2, 4, 6, 8] hits a 0.001 margin at
+        # token 5 that the two round differently on the installed JAX
+        prompt = [1, 2, 3, 4]
         want = await _gen(base, prompt, 16)
         got = await _gen(spec, prompt, 16)
         assert got == want

@@ -1,0 +1,148 @@
+"""AOT compiles of the Pallas attention entry points for a DESCRIBED v5e.
+
+The TPU compiler is installed without a chip: it compiles for a topology
+that is described, not attached (on-chip-measurement guide, section 2).
+Interpret mode cannot see what the Mosaic lowering refuses — block shapes,
+SMEM scalars, VMEM limits — so every entry point the serving path can
+select is compiled here at TinyLlama-1.1B and Llama-3-8B widths.  Nothing
+runs; a pass is a compile, never a chip run.
+
+Rules this file keeps: the topology is described inside a module-scoped
+fixture (never at import, in a skipif, in parametrize arguments or in
+conftest.py) because only one process may load the TPU library and every
+xdist worker imports every test file; shapes and shardings are built in
+fixtures/tests; compiles happen in the test's own process; the persistent
+compile cache is off around them (such a compile can be written to it but
+not read back without a chip).
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+# (K kv heads, G query heads per kv head, head_dim)
+WIDTHS = {"tinyllama-1.1b": (4, 8, 64), "llama-3-8b": (8, 4, 128)}
+B, W, PAGE, N_PAGES, LAYERS = 64, 1024, 64, 257, 2
+WPAGES = W // PAGE
+SPEC_S = 5  # verify: k + 1 queries at the default k = 4
+CHUNK_S = 128  # one prefill chunk
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _entry_points(K: int, G: int, hd: int, shape):
+    """name -> (fn, abstract args) for every serving-path entry point."""
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    H = K * G
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    lens = shape((B,), i32)
+    dense = (shape((B, K, W, hd), bf16),) * 2
+    pool = (shape((LAYERS, N_PAGES, K, PAGE, hd), bf16),) * 2
+    layer, tables = shape((), i32), shape((B, WPAGES), i32)
+    ring = (shape((8, B, K, hd), bf16),) * 2  # decode_steps_per_dispatch = 8
+    chunk = (shape((SPEC_S, B, K, hd), bf16),) * 2
+    q1 = shape((B, 1, H, hd), bf16)
+    q_spec = shape((B, SPEC_S, H, hd), bf16)
+    q_ragged = shape((B, K, CHUNK_S, G, hd), bf16)
+    R = 8  # one admission wave
+    return {
+        "decode-dense": (
+            PA.merged_decode_attention_pallas,
+            (q1, *dense, *ring, lens, shape((), i32)),
+        ),
+        "decode-paged": (
+            lambda *a: PA.merged_paged_decode_attention_pallas(
+                *a, wpages=WPAGES
+            ),
+            (q1, *pool, layer, tables, *ring, lens, shape((), i32)),
+        ),
+        "verify-dense": (
+            PA.verify_attention_pallas, (q_spec, *dense, *chunk, lens),
+        ),
+        "verify-paged": (
+            lambda *a: PA.verify_attention_paged_pallas(*a, wpages=WPAGES),
+            (q_spec, *pool, layer, tables, *chunk, lens),
+        ),
+        "ragged-chunk-dense": (
+            PA.ragged_attention_pallas, (q_ragged, *dense, lens, lens),
+        ),
+        "ragged-chunk-paged": (
+            lambda *a: PA.ragged_attention_paged_pallas(*a, wpages=WPAGES),
+            (q_ragged, *pool, layer, tables, lens, lens),
+        ),
+        "prefill-chunk": (
+            PA.prefill_attention_pallas,
+            (
+                shape((R, CHUNK_S, H, hd), bf16),
+                shape((R, K, W, hd), bf16), shape((R, K, W, hd), bf16),
+                shape((R, CHUNK_S), i32), shape((R,), i32),
+            ),
+        ),
+    }
+
+
+ENTRY_POINTS = (
+    "decode-dense", "decode-paged", "verify-dense", "verify-paged",
+    "ragged-chunk-dense", "ragged-chunk-paged", "prefill-chunk",
+)
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+def test_entry_point_compiles_for_v5e(
+    widths, entry, one_chip, no_persistent_cache
+):
+    import jax
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fn, args = _entry_points(*WIDTHS[widths], shape)[entry]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # the kernel is IN the program: compiled by Mosaic, not interpreted and
+    # not replaced by an XLA fallback
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_entry_point_list_is_complete():
+    """Every name parametrized above exists, and nothing is left out."""
+    import jax.numpy as jnp
+
+    built = _entry_points(4, 8, 64, lambda dims, dtype: (dims, jnp.dtype(dtype)))
+    assert set(built) == set(ENTRY_POINTS)
