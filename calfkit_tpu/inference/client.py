@@ -714,6 +714,9 @@ class JaxLocalModelClient(ModelClient):
             # contextvar — generate() resolves None/corrupt to the default
             # class via the one degradation law (qos.resolve_priority)
             priority=qos.current_priority.get(),
+            # the queue wait is measured where it happens: the engine ends
+            # an engine.queue span under this request's prefill span
+            trace=prefill_span.context if prefill_span is not None else None,
         )
         stream_exc: BaseException | None = None
         try:

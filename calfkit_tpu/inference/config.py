@@ -214,8 +214,8 @@ class RuntimeConfig:
     # journal of scheduler events (admission, waves, page alloc/free,
     # spec/overlap dispatches, retirement, faults).  Rounds up to a power
     # of two; dumps to JSONL on engine fault / SIGUSR2 / the /flightrec
-    # endpoint; appends are O(1) lock-free (< the 2% telemetry bar, see
-    # OBS_OVERHEAD.json).  0 disables recording entirely.
+    # endpoint; appends are O(1) lock-free (what they cost a dispatch on
+    # the chip's host: PERF.md section 6).  0 disables recording entirely.
     flightrec_events: int = 4096
     # capacity observatory (ISSUE 19): capacity (samples) of the engine's
     # occupancy timeline ring — one numeric sample per dispatch landing
@@ -226,7 +226,7 @@ class RuntimeConfig:
     # disables the sampler entirely — page ATTRIBUTION (the ledger behind
     # stats_snapshot()["capacity"] and the advert's headroom fields) is
     # always on for paged engines: it rides the existing alloc/free/evict
-    # sites at O(1) and stays under the 2% bar (OBS_OVERHEAD.json).
+    # sites at O(1).
     capacity_samples: int = 0
     # weight-only quantization: "int8" halves decode HBM traffic and fits
     # Llama-3-8B on one 16 GB chip; "int4" (packed nibbles, group-128

@@ -52,6 +52,7 @@ from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference.compile_cache import enable_compile_cache
 from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
 from calfkit_tpu.observability import capacity, flightrec
+from calfkit_tpu.observability.trace import TRACER, Span, TraceContext
 from calfkit_tpu.observability.metrics import (
     INTER_TOKEN_BUCKETS_MS,
     REGISTRY,
@@ -73,6 +74,38 @@ from calfkit_tpu.inference.sharding import (
 logger = logging.getLogger(__name__)
 
 _DONE = object()
+
+# the dispatch loop's phases and the admission ledger's reasons, by the
+# EngineStats field that accumulates each (ISSUE 24); the text is the
+# /metrics help line
+_SECONDS_HELP = {
+    "phase_reap_s": "dispatch loop: the per-pass sweeps (cancels, deadlines, orphans, stalls)",
+    "phase_admit_s": "dispatch loop: wave formation, page reservation, activation",
+    "phase_handoff_s": "dispatch loop: the hop to the tick thread and back",
+    "phase_prep_s": "dispatch loop: host-side dispatch inputs",
+    "phase_enqueue_s": "dispatch loop: the jit call, to its return",
+    "phase_sync_s": "dispatch loop: blocked on the device",
+    "phase_fanout_s": "dispatch loop: after the sync (fan-out, retirement, frees)",
+    "phase_idle_s": "dispatch loop: awaiting work",
+    "starved_s": "rows active and nothing in flight: landing to next enqueue",
+    "blocked_slots_s": "queue head held: no free slot",
+    "blocked_pages_s": "queue head held: page allocation came back short",
+    "blocked_wave_s": "queue head held: an admission wave in flight",
+    "blocked_budget_s": "queue head held: ragged token budget, wave trim or bucket",
+    "empty_slot_queued_s": "slot-seconds: free slots while a request was queued",
+}
+_SECONDS_FIELDS = tuple(_SECONDS_HELP)
+PHASES = tuple(f for f in _SECONDS_FIELDS if f.startswith("phase_"))
+REAP, ADMIT, HANDOFF, PREP, ENQUEUE, SYNC, FANOUT, IDLE = PHASES
+# "phase_reap_s" -> "engine.reap": the host annotation on the profiler's clock
+_PHASE_ANNOTATION = {p: "engine." + p[len("phase_"):-len("_s")] for p in PHASES}
+BLOCKED = tuple(f for f in _SECONDS_FIELDS if f.startswith("blocked_"))
+NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
+# the EngineStats fields folded into /metrics counters once a dispatch
+_SYNCED_FIELDS = (
+    "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
+    "overlap_wasted_tokens", *_SECONDS_FIELDS,
+)
 
 _ATTN_PROFILE_CACHE: "tuple[tuple, dict | None] | None" = None
 
@@ -167,6 +200,12 @@ def _engine_metrics(
             "requests holding a slot (summed across the process's engines)",
         ),
     )
+    for name in _SECONDS_FIELDS:
+        # "phase_sync_s" -> calfkit_engine_phase_sync_seconds_total
+        out[name] = reg.counter(
+            f"calfkit_engine_{name[:-len('_s')]}_seconds_total",
+            _SECONDS_HELP[name],
+        )
     return out
 
 
@@ -214,6 +253,7 @@ def _deliver_batch(deliveries: "list[tuple[asyncio.Queue, list]]") -> None:
         queue.put_nowait(items)
 
 
+@jax.named_scope("finalize")
 def _finalize_wave_math(
     cfg, paged, sampled,
     k, v, sk, sv, last, lens, slots, true_lens, last_logits,
@@ -252,7 +292,8 @@ def _finalize_wave_math(
         subs = jax.vmap(jax.random.fold_in)(wave_keys, true_lens)
         firsts = sample_slots(last_logits, subs, w_temp, w_top_k, w_top_p)
     else:
-        firsts = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            firsts = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
     last = last.at[slots].set(firsts)
     lens = lens.at[slots].set(true_lens)
     return k, v, tables, last, lens, slot_keys, temp, top_k, top_p, firsts
@@ -339,6 +380,15 @@ class GenRequest:
     # string, like corr: ledger appends never format.
     run: "str | None" = None
     started_at: float = field(default_factory=time.perf_counter)
+    # admission, measured where the wait happens (ISSUE 24): the moment a
+    # slot was granted, the wave it was granted in, and the admission
+    # ledger's four blocked_*_s counters as they stood at submit — their
+    # growth until the grant says what held the queue longest meanwhile
+    granted_at: "float | None" = None
+    wave_rows: int = 0
+    wave_bucket: int = 0
+    blocked_at_submit: "tuple[float, ...] | None" = None
+    blocked_on: str = "none"
     # the request's live _retire_heap entry ([bound, seq, request] list);
     # cleared at retirement so the heap stops pinning this object's
     # prompt/queue memory (r3 advisor finding)
@@ -426,10 +476,39 @@ class EngineStats:
     # faster.  A fold, not a counter: it never enters _COUNTER_FIELDS /
     # window deltas.
     dispatch_ewma_ms: float = 0.0
+    # the dispatch loop's exclusive phase clock (ISSUE 24): host seconds
+    # by what the ONE thread of control (serve loop -> tick thread -> serve
+    # loop) was doing.  At any moment the loop is in exactly one phase, so
+    # the eight sum to the loop's wall time.  See :meth:`enter`.
+    phase_reap_s: float = 0.0  # per-pass sweeps: cancels, deadlines, orphans, stalls
+    phase_admit_s: float = 0.0  # wave formation, page reservation, activation
+    phase_handoff_s: float = 0.0  # the asyncio.to_thread hop, both ways
+    phase_prep_s: float = 0.0  # host-side dispatch inputs
+    phase_enqueue_s: float = 0.0  # the jit call, to its return
+    phase_sync_s: float = 0.0  # blocked on the device
+    phase_fanout_s: float = 0.0  # after the sync: fan-out, retirement, frees
+    phase_idle_s: float = 0.0  # awaiting work
+    # not a phase: with rows active, seconds from a landing to the next
+    # enqueue while nothing was in flight (Σ dispatch_gap_ms observations)
+    starved_s: float = 0.0
+    # the admission-blocked ledger: seconds the head of the queue waited,
+    # by what held it, and the free-slot integral while anyone queued
+    blocked_slots_s: float = 0.0  # no free slot
+    blocked_pages_s: float = 0.0  # page allocation came back short
+    blocked_wave_s: float = 0.0  # an admission wave already in flight
+    blocked_budget_s: float = 0.0  # ragged token budget / wave trim / bucket
+    empty_slot_queued_s: float = 0.0  # slot-seconds
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
     # delta readers would steal each other's intervals.
     _window: Any = field(default=None, repr=False, compare=False)
+    # the open intervals of the three clocks above, each ONE tuple so a
+    # reader on another thread never sees a name without its start:
+    # (phase field, since, annotation), (blocked field, since),
+    # (free slots, since)
+    _phase: Any = field(default=None, repr=False, compare=False)
+    _blocked: Any = field(default=None, repr=False, compare=False)
+    _empty: Any = field(default=None, repr=False, compare=False)
 
     _COUNTER_FIELDS = (
         "prefill_tokens", "decode_tokens", "decode_dispatches",
@@ -444,7 +523,11 @@ class EngineStats:
         "prefix_evictions", "alloc_stalls",
         "interactive_shed", "batch_shed",
         "interactive_expired", "batch_expired",
+        *_SECONDS_FIELDS,
     )
+    # what the heartbeat advert's window carries: the phase clock and the
+    # admission ledger go to /metrics and ``counters()`` only
+    _ADVERT_FIELDS = _COUNTER_FIELDS[: -len(_SECONDS_FIELDS)]
 
     # EWMA smoothing for dispatch_ewma_ms: ~5-dispatch memory — fresh
     # enough to react inside one heartbeat interval, smooth enough that
@@ -462,11 +545,82 @@ class EngineStats:
             a = self.EWMA_ALPHA
             self.dispatch_ewma_ms = a * sample_ms + (1.0 - a) * prev
 
+    def enter(self, phase: "str | None") -> float:
+        """Switch the loop's phase clock: close the open phase (its
+        seconds to its counter, its ``engine.<phase>`` annotation ended)
+        and open ``phase`` (None: the loop has ended).  Returns the one
+        ``perf_counter`` read, for callers that need the moment.  The
+        annotation puts the same interval on the profiler's clock
+        whenever anyone's profile is running, and is a no-op otherwise;
+        it may end on another thread than it began on (the profiler
+        files it under the thread that ended it)."""
+        now = time.perf_counter()
+        prev = self._phase
+        if prev is not None:
+            name, since, annotation = prev
+            if name == phase:
+                return now
+            setattr(self, name, getattr(self, name) + (now - since))
+            annotation.__exit__(None, None, None)
+        if phase is None:
+            self._phase = None
+        else:
+            annotation = jax.profiler.TraceAnnotation(_PHASE_ANNOTATION[phase])
+            annotation.__enter__()
+            self._phase = (phase, now, annotation)
+        return now
+
+    def note_blocked(
+        self, reason: "str | None", free_slots: int, now: float,
+        queued_since: "float | None" = None,
+    ) -> None:
+        """The admission ledger, once a pass: what holds the head of the
+        queue from ``now`` on (a ``blocked_*_s`` field; None: nobody is
+        queued) and how many slots stand free meanwhile.  The interval
+        that ends here goes to the reason that held through it.  A queue
+        found non-empty for the first time has been so since its head
+        was submitted (``queued_since``), between two passes."""
+        blocked, empty = self._blocked, self._empty
+        if blocked is not None:
+            setattr(self, blocked[0], getattr(self, blocked[0]) + (now - blocked[1]))
+            self.empty_slot_queued_s += empty[0] * (now - empty[1])
+        elif queued_since is not None:
+            now = min(now, queued_since)
+        if reason is None:
+            self._blocked = self._empty = None
+        else:
+            self._blocked = (reason, now)
+            self._empty = (free_slots, now)
+
+    def blocked_now(self, now: float) -> "tuple[float, ...]":
+        """The four ``blocked_*_s`` counters with the open interval
+        counted up to ``now`` (serve-loop context)."""
+        out = [getattr(self, f) for f in BLOCKED]
+        blocked = self._blocked
+        if blocked is not None:
+            out[BLOCKED.index(blocked[0])] += now - blocked[1]
+        return tuple(out)
+
     def counters(self) -> dict:
         """Every cumulative counter as a plain dict (occupancy_hist as a
-        copied list) — the windowing substrate."""
-        out: dict = {f: getattr(self, f) for f in self._COUNTER_FIELDS}
+        copied list) — the windowing substrate.  The open intervals of
+        the phase clock and of the admission ledger are counted up to
+        now, so a difference of two snapshots covers exactly the time
+        between them."""
+        for _ in range(4):  # the tick thread may switch phase meanwhile
+            phase = self._phase
+            out: dict = {f: getattr(self, f) for f in self._COUNTER_FIELDS}
+            if self._phase is phase:
+                break
         out["occupancy_hist"] = list(self.occupancy_hist)
+        now = time.perf_counter()
+        blocked, empty = self._blocked, self._empty
+        if phase is not None:
+            out[phase[0]] += now - phase[1]
+        if blocked is not None:
+            out[blocked[0]] += now - blocked[1]
+        if empty is not None:
+            out["empty_slot_queued_s"] += empty[0] * (now - empty[1])
         return out
 
     def snapshot_and_delta(self) -> "tuple[dict, dict]":
@@ -480,11 +634,11 @@ class EngineStats:
         now = time.monotonic()
         cur = self.counters()
         prev, prev_t = self._window or (
-            {f: 0 for f in self._COUNTER_FIELDS} | {"occupancy_hist": [0, 0, 0, 0]},
+            {f: 0 for f in self._ADVERT_FIELDS} | {"occupancy_hist": [0, 0, 0, 0]},
             None,
         )
         delta: dict = {
-            f: cur[f] - prev[f] for f in self._COUNTER_FIELDS
+            f: cur[f] - prev[f] for f in self._ADVERT_FIELDS
         }
         delta["occupancy_hist"] = [
             a - b for a, b in zip(cur["occupancy_hist"], prev["occupancy_hist"])
@@ -785,6 +939,9 @@ class InferenceEngine:
         # engine fault (journal dump + teardown)
         self._chaos: Any = None
         self._inflight: dict | None = None  # chunked-prefill wave in flight
+        # the admission ledger's reason (a BLOCKED field) left by the last
+        # _form_wave: what held the head of the queue out of that wave
+        self._held_by: str = NO_SLOT
         # requests whose (non-chunked) admission prefill is running in
         # to_thread: otherwise they live only in a local during the JIT
         # compile + prefill — exactly when an early cancel or deadline
@@ -872,11 +1029,7 @@ class InferenceEngine:
         # /metrics exposition; both are observed, each O(1))
         self._own_registry = MetricsRegistry()
         self.latency = _engine_metrics(self._own_registry, histograms_only=True)
-        self._counted = {
-            "decode_tokens": 0, "prefill_tokens": 0,
-            "spec_proposed": 0, "spec_accepted": 0,
-            "overlap_wasted_tokens": 0,
-        }
+        self._counted = dict.fromkeys(_SYNCED_FIELDS, 0)
         self._counted_lock = threading.Lock()
         # self-cleaning gauge aggregation: an engine abandoned without
         # stop() must not pin its last active count into the process
@@ -968,6 +1121,7 @@ class InferenceEngine:
         cfg = self.config
         attn_impl = self._resolved_attn_impl("decode")
 
+        @jax.named_scope("decode_loop")
         def decode(params, k, v, last, lens, active, done_prev,
                    stop_table, hard_end, slot_keys, temp, top_k, top_p):
             # ring-buffer decode: the main cache is READ-ONLY during the
@@ -1006,7 +1160,8 @@ class InferenceEngine:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
                     nxt = sample_slots(logits[:, -1], subs, temp, top_k, top_p)
                 else:
-                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, last)
                 return (ring, nxt), nxt
 
@@ -1048,6 +1203,7 @@ class InferenceEngine:
         cfg = self.config
         attn_impl = self._resolved_attn_impl("paged_decode")
 
+        @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
                    stop_table, hard_end, slot_keys, temp, top_k, top_p):
             # rows that retired in the still-in-flight previous dispatch
@@ -1076,7 +1232,8 @@ class InferenceEngine:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
                     nxt = sample_slots(logits[:, -1], subs, temp, top_k, top_p)
                 else:
-                    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    with jax.named_scope("sample"):
+                        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, last)
                 return (ring, nxt), nxt
 
@@ -1115,6 +1272,7 @@ class InferenceEngine:
         # ragged profile rows, falling back to the legacy decode verdict
         attn_impl = self._resolved_attn_impl("ragged", fallback="decode")
 
+        @jax.named_scope("verify")
         def verify(params, k, v, last, lens, active, drafts, ndraft,
                    stop_table, hard_end, slot_keys, temp, top_k, top_p):
             kw = k[:, :, :, :window]
@@ -1160,6 +1318,7 @@ class InferenceEngine:
             "paged_ragged", fallback="paged_decode"
         )
 
+        @jax.named_scope("verify")
         def verify(params, k, v, tables, last, lens, active, drafts,
                    ndraft, stop_table, hard_end, slot_keys, temp, top_k,
                    top_p):
@@ -1287,10 +1446,11 @@ class InferenceEngine:
                 jnp.zeros((cfg.n_layers, R, cfg.n_kv_heads, P, cfg.head_dim), v.dtype),
             )
             pos = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (R, P))
-            logits, (sk, sv) = M.forward(
-                params, cfg, tokens, pos, scratch,
-                jnp.full((R,), P, jnp.int32), attn_impl=attn_impl,
-            )
+            with jax.named_scope("prefill"):
+                logits, (sk, sv) = M.forward(
+                    params, cfg, tokens, pos, scratch,
+                    jnp.full((R,), P, jnp.int32), attn_impl=attn_impl,
+                )
             idx = jnp.clip(true_lens - 1, 0, P - 1)
             last_logits = jnp.take_along_axis(
                 logits, idx[:, None, None], axis=1
@@ -1330,6 +1490,7 @@ class InferenceEngine:
         cfg = self.config
         attn_impl = self._resolved_attn_impl("prefill")
 
+        @jax.named_scope("chunk_loop")
         def chunk_step(params, sk, sv, tokens_chunk, offset):
             R = tokens_chunk.shape[0]
             pos = offset + jnp.broadcast_to(
@@ -1411,6 +1572,7 @@ class InferenceEngine:
         cfg = self.config
         page = self.runtime.page_size
 
+        @jax.named_scope("seed_scratch")
         def seed(pool_k, pool_v, ids):
             def gather(pool_side):
                 g = pool_side[:, ids]  # [L, R, n, K, page, hd]
@@ -1550,6 +1712,7 @@ class InferenceEngine:
         deadline: float | None = None,
         lease: "tuple[str, float] | None" = None,
         priority: "str | None" = None,
+        trace: "TraceContext | None" = None,
     ) -> AsyncIterator[int]:
         """Submit a prompt; yields generated token ids as they decode.
 
@@ -1586,6 +1749,11 @@ class InferenceEngine:
         queued batch request (oldest lease beat first) instead of being
         shed, and the deadline/orphan reapers take batch before
         interactive at equal expiry.
+
+        ``trace`` is the caller's span context (ISSUE 24): with it the
+        engine records an ``engine.queue`` span, child of that context,
+        from this submit to the moment the request is granted its slot
+        (attrs ``blocked_on``, ``bucket``, ``wave_rows``).
         """
         req_priority = qos.resolve_priority(priority)
         if not self._running:
@@ -1687,11 +1855,12 @@ class InferenceEngine:
                     "are ignored for this request"
                 )
             self._shed_if_full("long", len(self._long_pending), request)
+            queue_span = self._open_queue_span(request, trace)
             self._long_pending.append(request)
             self._submit_deadline(request)
             self._submit_lease(request)
             self._wake.set()
-            inner = self._consume(request)
+            inner = self._consume(request, queue_span)
             try:
                 async for item in inner:
                     yield item
@@ -1730,11 +1899,12 @@ class InferenceEngine:
             len(self._pending) + len(self._carry) + len(self._admitting),
             request,
         )
+        queue_span = self._open_queue_span(request, trace)
         self._pending.append(request)
         self._submit_deadline(request)
         self._submit_lease(request)
         self._wake.set()
-        inner = self._consume(request)
+        inner = self._consume(request, queue_span)
         try:
             async for item in inner:
                 yield item
@@ -2276,13 +2446,46 @@ class InferenceEngine:
                 limit=self.runtime.max_out_blocks,
             )
 
-    async def _consume(self, request: GenRequest) -> AsyncIterator[int]:
+    def _open_queue_span(
+        self, request: GenRequest, trace: "TraceContext | None"
+    ) -> "Span | None":
+        """A traced request joins the queue: its ``engine.queue`` span
+        starts, and the admission ledger is marked for ``blocked_on``."""
+        if trace is None:
+            return None
+        request.blocked_at_submit = self.stats.blocked_now(time.perf_counter())
+        return TRACER.start_span(
+            "engine.queue", parent=trace, kind="engine",
+            emitter=f"engine/{self.config.name}",
+        )
+
+    def _end_queue_span(self, span: "Span", request: GenRequest) -> None:
+        """End the request's ``engine.queue`` span at the moment its slot
+        was granted.  Called from the CONSUMER's context once the first
+        block arrives, so the span reaches the hop's sink like the
+        caller's own; the moments are the scheduler's.  A request that
+        was never granted a slot (shed, expired or abandoned in the
+        queue) ends now, cancelled."""
+        if request.granted_at is None:
+            span.end(status="cancelled", blocked_on=request.blocked_on)
+        else:
+            span.end(
+                at=request.granted_at, blocked_on=request.blocked_on,
+                bucket=request.wave_bucket, wave_rows=request.wave_rows,
+            )
+
+    async def _consume(
+        self, request: GenRequest, queue_span: "Span | None" = None
+    ) -> AsyncIterator[int]:
         """Drain a queued request's tokens; abandoning the iterator flags
         cancellation for the scheduler to reap (both lanes share this)."""
         done = False
         try:
             while True:
                 item = await request.out.get()
+                if queue_span is not None:
+                    self._end_queue_span(queue_span, request)
+                    queue_span = None
                 if item is _DONE:
                     done = True
                     self._raise_terminal(request)
@@ -2297,6 +2500,8 @@ class InferenceEngine:
                     continue
                 yield item
         finally:
+            if queue_span is not None:
+                self._end_queue_span(queue_span, request)
             if not done:
                 request.cancelled = True
                 self._cancel_dirty = True
@@ -2304,8 +2509,10 @@ class InferenceEngine:
 
     # ------------------------------------------------------------ scheduler
     async def _serve(self) -> None:
+        stats = self.stats
         try:
             while self._running:
+                stats.enter(REAP)
                 if self._chaos is not None:
                     self._chaos("tick")
                 self._drain_deferred_cancels()
@@ -2325,6 +2532,7 @@ class InferenceEngine:
                             not self._pending and not self._carry
                             and not self._long_pending and self._long is None
                         ):
+                            stats.enter(IDLE)
                             await self._wake.wait()
                     continue
                 if self.runtime.chunked_prefill:
@@ -2333,7 +2541,7 @@ class InferenceEngine:
                     progressed = await self._admit()
                 progressed |= await self._advance_long()
                 if self._active:
-                    await asyncio.to_thread(
+                    await self._offload(
                         self._spec_decode_tick
                         if self._drafter is not None
                         else self._decode_tick
@@ -2342,13 +2550,14 @@ class InferenceEngine:
                     # every participant retired/cancelled while a dispatch
                     # was still in flight: land it (discarding pad tokens)
                     # so the deferred slot/page frees actually happen
-                    await asyncio.to_thread(self._drain_decode)
+                    await self._offload(self._drain_decode)
                 elif not progressed and self._inflight is None:
                     self._wake.clear()
                     if (
                         not self._pending and not self._carry
                         and not self._long_pending and self._long is None
                     ):
+                        stats.enter(IDLE)
                         await self._wake.wait()
         except Exception as exc:  # noqa: BLE001
             logger.exception("inference engine scheduler crashed")
@@ -2368,6 +2577,43 @@ class InferenceEngine:
             except Exception:  # noqa: BLE001
                 logger.exception("flight-recorder fault dump failed")
             self._finish_all()
+        finally:
+            # the loop has ended: close the open phase and the ledger
+            stats.note_blocked(None, 0, stats.enter(None))
+
+    async def _offload(self, tick: Any, *args: Any) -> Any:
+        """Run one tick on a worker thread.  The phase clock follows the
+        one thread of control: ``handoff`` from here to the tick's first
+        line, and from its return to the coroutine's next phase."""
+        self.stats.enter(HANDOFF)
+        return await asyncio.to_thread(self._run_tick, tick, *args)
+
+    def _run_tick(self, tick: Any, *args: Any) -> Any:
+        self.stats.enter(PREP)
+        try:
+            return tick(*args)
+        finally:
+            self.stats.enter(HANDOFF)
+
+    def _note_admission(self, now: float, attempted: bool) -> None:
+        """The admission ledger, once a pass: with a request still queued,
+        what stopped this pass's attempt to admit it (``_form_wave`` left
+        the reason in ``_held_by``), or that none could be made because a
+        wave is in flight.  Length checks only: cancelled entries were
+        reaped at the top of the pass."""
+        head = self._carry[0] if self._carry else (
+            self._pending[0] if self._pending else None
+        )
+        if head is None:
+            reason = None
+        elif attempted:
+            reason = self._held_by
+        else:
+            reason = WAVE_IN_FLIGHT
+        self.stats.note_blocked(
+            reason, len(self._free), now,
+            head.started_at if head is not None else None,
+        )
 
     def _drain_deferred_cancels(self) -> None:
         """Re-run cancel matches that lost the snapshot race (serve-loop
@@ -2577,6 +2823,7 @@ class InferenceEngine:
         admission control, no mid-flight OOM).  None when nothing can be
         admitted right now."""
         if not self._free or self._peek_pending() is None:
+            self._held_by = NO_SLOT
             return None
 
         def bucket_of(req: GenRequest) -> int:
@@ -2600,13 +2847,23 @@ class InferenceEngine:
                 flightrec.EV_PREFIX_ACQ, wave[0].corr, -1,
                 len(wave[0].shared_pages),
             )
-        while (
-            len(wave) < len(self._free)
-            and len(wave) < self.runtime.max_prefill_wave
-            and len(wave) < width_cap
-            and (peeked := self._peek_pending()) is not None
-            and bucket_of(peeked) == wave_bucket
-        ):
+        # what holds the head of the queue once this wave is formed: the
+        # reason the loop below stops at, unless a later step (the trims,
+        # a short page allocation) sends someone back to the front
+        self._held_by = OVER_BUDGET  # the width cap, a bucket or reuse mismatch
+        while True:
+            if len(wave) >= len(self._free):
+                self._held_by = NO_SLOT
+                break
+            if len(wave) >= self.runtime.max_prefill_wave:
+                self._held_by = WAVE_IN_FLIGHT  # the wave is full: the next one
+                break
+            if (
+                len(wave) >= width_cap
+                or (peeked := self._peek_pending()) is None
+                or bucket_of(peeked) != wave_bucket
+            ):
+                break
             # one offset per wave: only requests whose reuse TRIMS to the
             # head's length batch together (an identical-prompt burst —
             # the headline workload — batches fully once page 1 lands)
@@ -2634,6 +2891,8 @@ class InferenceEngine:
         keep = 1
         while keep * 2 <= len(wave):
             keep *= 2
+        if keep < len(wave):
+            self._held_by = OVER_BUDGET  # the power-of-two trim
         for trimmed in wave[keep:]:  # balance formation-time acquisitions
             self._drop_reuse_plan(trimmed)
         self._carry = wave[keep:] + self._carry
@@ -2648,6 +2907,7 @@ class InferenceEngine:
                 need -= len(shared)
                 pages = self._alloc_with_eviction(slot, need, request.corr)
                 if pages is None:
+                    self._held_by = NO_PAGES
                     self._free.append(slot)
                     # EVERY carried member's acquisition must be undone,
                     # or its refcount leaks and the pages become
@@ -2692,7 +2952,31 @@ class InferenceEngine:
         self._journal.append(
             flightrec.EV_WAVE_FORM, None, -1, len(wave), wave_bucket
         )
+        self._note_granted(wave, wave_bucket)
         return wave, wave_bucket
+
+    def _note_granted(self, wave: "list[GenRequest]", bucket: int) -> None:
+        """The moment the wave's requests got their slots: the end of
+        their ``engine.queue`` span.  ``blocked_on`` is the ledger reason
+        that grew most while the request waited (traced requests only:
+        the others carry no mark)."""
+        now = time.perf_counter()
+        stats = self.stats
+        mark: "tuple[float, ...] | None" = None
+        for request in wave:
+            request.granted_at = now
+            request.wave_rows = len(wave)
+            request.wave_bucket = bucket
+            if request.blocked_at_submit is None:
+                continue
+            if mark is None:
+                mark = stats.blocked_now(now)
+            held = [m - b for m, b in zip(mark, request.blocked_at_submit)]
+            longest = max(held)
+            request.blocked_on = (
+                BLOCKED[held.index(longest)][len("blocked_"):-len("_s")]
+                if longest > 0.0 else "none"
+            )
 
     def _activate_wave(self, wave: list[GenRequest]) -> None:
         for request in wave:
@@ -2731,15 +3015,18 @@ class InferenceEngine:
 
     async def _admit(self) -> bool:
         admitted = False
+        now = self.stats.enter(ADMIT)
         while (formed := self._form_wave()) is not None:
             wave, wave_bucket = formed
             self._admitting = wave
             try:
-                await asyncio.to_thread(self._prefill_wave, wave, wave_bucket)
+                await self._offload(self._prefill_wave, wave, wave_bucket)
             finally:
                 self._admitting = []
+            self.stats.enter(ADMIT)
             self._activate_wave(wave)
             admitted = True
+        self._note_admission(now, True)
         return admitted
 
     # ------------------------------------------------- long-context lane
@@ -2775,11 +3062,12 @@ class InferenceEngine:
         if not self.runtime.long_context:
             return False
         if self._long is not None:
-            await asyncio.to_thread(self._long_decode_tick)
+            await self._offload(self._long_decode_tick)
             return True
         if self._long_inflight is not None:
-            await asyncio.to_thread(self._advance_long_prefill)
+            await self._offload(self._advance_long_prefill)
             return True
+        self.stats.enter(ADMIT)
         request = None
         while self._long_pending:
             candidate = self._long_pending.popleft()
@@ -2794,6 +3082,8 @@ class InferenceEngine:
         self._journal.append(
             flightrec.EV_ADMIT_LONG, request.corr, -1, len(request.prompt)
         )
+        request.granted_at = time.perf_counter()  # the lane is its slot
+        request.wave_rows = 1
         if self.runtime.chunked_prefill:
             # resumable: one chunk per scheduler pass, short decode ticks
             # run between chunks (same latency bound as the short lane)
@@ -2801,7 +3091,7 @@ class InferenceEngine:
             return True
         self._admitting = [request]
         try:
-            await asyncio.to_thread(self._long_prefill, request)
+            await self._offload(self._long_prefill, request)
         finally:
             self._admitting = []
         return True
@@ -2859,12 +3149,14 @@ class InferenceEngine:
         padded = self._long_padded(n)
         tokens = np.zeros((1, padded), np.int32)
         tokens[0, :n] = request.prompt
-        started = time.perf_counter()
+        started = self.stats.enter(ENQUEUE)
         last_logits, (k_prefix, v_prefix) = prefill_sequence_parallel(
             self.params, self.config, jnp.asarray(tokens), mesh,
             seq_lens=jnp.asarray([n], jnp.int32),
         )
+        self.stats.enter(SYNC)
         first = int(np.asarray(jnp.argmax(last_logits[0])))
+        self.stats.enter(FANOUT)
         self._install_long_state(
             request, (k_prefix, v_prefix), n, first, started
         )
@@ -2919,6 +3211,7 @@ class InferenceEngine:
         chunk, idx = inf["chunk"], inf["idx"]
         sk, sv = inf["scratch"]
         tok_chunk = jnp.asarray(inf["tokens"][:, idx * chunk:(idx + 1) * chunk])
+        self.stats.enter(ENQUEUE)
         sk, sv, logits = self._chunk_jit(chunk, 1)(
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk)
         )
@@ -2929,7 +3222,9 @@ class InferenceEngine:
         # last prompt-covering chunk: the final valid position lives here
         n = inf["true_len"]
         local = (n - 1) - (inf["n_chunks"] - 1) * chunk
+        self.stats.enter(SYNC)
         first = int(np.asarray(jnp.argmax(logits[0, local])))
+        self.stats.enter(FANOUT)
         self._long_inflight = None
         self._install_long_state(
             request, (sk, sv), n, first, inf["started"]
@@ -2956,7 +3251,7 @@ class InferenceEngine:
                 self.runtime.decode_steps_per_dispatch,
                 state["cap"] - state["t"],
             )
-            started = time.perf_counter()
+            started = self.stats.enter(ENQUEUE)
             toks, last, fresh = decode_sp_dispatch(
                 self.params, self.config, state["last"], state["prefix"],
                 jnp.asarray([state["prefix_len"]], jnp.int32),
@@ -2976,7 +3271,7 @@ class InferenceEngine:
         if landing is None:
             return  # first overlapped pass: launch only
         block = self._sync_host(landing["toks"])[0]  # host sync per dispatch
-        now = time.perf_counter()
+        now = self.stats.enter(FANOUT)
         start = landing["started"]
         last_sync = state.get("synced_at")
         if last_sync is not None and last_sync > start:
@@ -3138,6 +3433,7 @@ class InferenceEngine:
         ]
         if self._paged:
             args += self._paged_wave_args(wave, bucket)
+        self.stats.enter(ENQUEUE)
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
@@ -3146,8 +3442,9 @@ class InferenceEngine:
             self._tables = tables
         # sync BEFORE timing: with async dispatch, fn() returns before the
         # device runs — prefill_ms must be real latency, not enqueue time
+        self.stats.enter(SYNC)
         firsts = np.asarray(firsts)
-        elapsed_ms = (time.perf_counter() - started) * 1000.0
+        elapsed_ms = (self.stats.enter(FANOUT) - started) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
 
     # --------------------------------------------------- chunked admission
@@ -3158,13 +3455,18 @@ class InferenceEngine:
         latency is bounded by one chunk instead of a whole bucket.  This
         is the LEGACY (bifurcated) lane — with ragged waves on, the chunk
         instead rides the decode dispatch (:meth:`_ragged_pass`)."""
-        if self._inflight is None:
+        now = self.stats.enter(ADMIT)
+        attempted = self._inflight is None
+        if attempted:
             formed = self._form_wave()
-            if formed is None:
-                return False
-            self._start_inflight_wave(*formed)
-        finished = await asyncio.to_thread(self._advance_inflight)
+            if formed is not None:
+                self._start_inflight_wave(*formed)
+        self._note_admission(now, attempted)
+        if self._inflight is None:
+            return False
+        finished = await self._offload(self._advance_inflight)
         if finished:
+            self.stats.enter(ADMIT)
             wave = self._inflight["wave"]
             self._inflight = None
             self._activate_wave(wave)
@@ -3224,6 +3526,7 @@ class InferenceEngine:
         tok_chunk = jnp.asarray(
             inf["arrays"]["tokens"][:, idx * chunk:(idx + 1) * chunk]
         )
+        self.stats.enter(ENQUEUE)
         sk, sv, logits = self._chunk_jit(chunk, R)(
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk)
         )
@@ -3258,17 +3561,21 @@ class InferenceEngine:
         ]
         if self._paged:
             args += self._paged_wave_args(wave, bucket)
+        # the landing's inputs count as its enqueue: the launch that came
+        # just before it left the clock in that phase
+        self.stats.enter(ENQUEUE)
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
         ) = fn(*args)
         if self._paged:
             self._tables = tables
+        self.stats.enter(SYNC)
         # blocking-ok: the prefill wave's designated LANDING sync — first
         # tokens must reach the host here for delivery and real TTFT
         # attribution; this is the admission lane's _sync_host analog
         firsts = np.asarray(firsts)  # sync before timing (real latency)
-        elapsed_ms = (time.perf_counter() - inf["started"]) * 1000.0
+        elapsed_ms = (self.stats.enter(FANOUT) - inf["started"]) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
         if self._prefix is not None:
             for request in wave:
@@ -3289,17 +3596,21 @@ class InferenceEngine:
         admission), then advance decode + chunk through one fused tick.
         Returns False only when there was nothing at all to do."""
         progressed = False
-        if self._inflight is None:
+        now = self.stats.enter(ADMIT)
+        attempted = self._inflight is None
+        if attempted:
             formed = self._form_wave()
             if formed is not None:
                 self._start_inflight_wave(*formed)
                 progressed = True
+        self._note_admission(now, attempted)
         if (
             self._active or self._inflight is not None
             or self._pend is not None
         ):
-            finished = await asyncio.to_thread(self._ragged_tick)
+            finished = await self._offload(self._ragged_tick)
             if finished:
+                self.stats.enter(ADMIT)
                 wave = self._inflight["wave"]
                 self._inflight = None
                 self._activate_wave(wave)
@@ -3392,14 +3703,14 @@ class InferenceEngine:
         tok_chunk = jnp.asarray(
             inf["arrays"]["tokens"][:, idx * chunk:(idx + 1) * chunk]
         )
-        self._observe_gap()
+        started = self.stats.enter(ENQUEUE)
+        self._observe_gap(started)
         self._journal.append(
             flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
         )
         self._journal.append(
             flightrec.EV_RAGGED_WAVE, None, -1, len(self._active), R
         )
-        started = time.perf_counter()
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
             sk, sv, logits,
@@ -3507,7 +3818,9 @@ class InferenceEngine:
         """THE designated device→host sync point of the dispatch loop —
         scripts/lint_hotpath.py bans blocking syncs everywhere else in the
         overlap-critical functions, so the double-buffering can't silently
-        regress to one-sync-per-launch."""
+        regress to one-sync-per-launch.  The phase clock reads ``sync``
+        from here until the caller enters ``fanout``."""
+        self.stats.enter(SYNC)
         if isinstance(arrays, tuple):
             # blocking-ok: THE designated sync point (see docstring)
             return tuple(np.asarray(a) for a in arrays)
@@ -3573,20 +3886,21 @@ class InferenceEngine:
             )
         return self._retire_dev
 
-    def _observe_gap(self) -> None:
+    def _observe_gap(self, now: float) -> None:
         """The dispatch-gap bubble, observed immediately BEFORE each jit
-        enqueue (after args prep — the device is idle through that prep
-        too, so observing at tick entry would under-report): zero while a
+        enqueue (``now``: the moment the clock entered ``enqueue``, after
+        args prep — the device is idle through that prep too, so
+        observing at tick entry would under-report): zero while a
         dispatch is already in flight (the device never idled), else the
-        host-side span since the previous dispatch landed.  Reset across
-        idle periods — an empty engine waiting for work is not a bubble."""
+        host-side span since the previous dispatch landed, which also
+        adds up in ``starved_s``.  Reset across idle periods — an empty
+        engine waiting for work is not a bubble."""
         if self._pend is not None:
             self._observe("dispatch_gap_ms", 0.0)
         elif self._last_sync_t is not None:
-            self._observe(
-                "dispatch_gap_ms",
-                (time.perf_counter() - self._last_sync_t) * 1000.0,
-            )
+            gap = now - self._last_sync_t
+            self.stats.starved_s += gap
+            self._observe("dispatch_gap_ms", gap * 1000.0)
 
     def _launch_decode(self) -> None:
         """Enqueue the next decode dispatch — NO host sync.  The previous
@@ -3597,11 +3911,11 @@ class InferenceEngine:
         args, window, steps, sampled = self._decode_args()
         if steps < self.runtime.decode_steps_per_dispatch:
             self.stats.short_dispatches += 1
-        self._observe_gap()
+        started = self.stats.enter(ENQUEUE)
+        self._observe_gap(started)
         self._journal.append(
             flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
         )
-        started = time.perf_counter()
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
         ) = self._decode_jit(window, steps, sampled)(*args)
@@ -3644,7 +3958,7 @@ class InferenceEngine:
         block, n_valid, done = self._sync_host(
             (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"])
         )
-        now = time.perf_counter()
+        now = self.stats.enter(FANOUT)
         # exclusive wall: the launch happened before the PREVIOUS sync
         # returned, so clip to the span this dispatch alone occupied —
         # decode_time_s must keep approximating device-busy time, not
@@ -3717,19 +4031,19 @@ class InferenceEngine:
         overlapped path must produce byte-identical token streams; keep
         this oracle intact."""
         args, window, steps, sampled = self._decode_args()
-        self._observe_gap()
+        started = self.stats.enter(ENQUEUE)
+        self._observe_gap(started)
         self._journal.append(
             flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
         )
-        started = time.perf_counter()
         self._k, self._v, self._last, self._lens, toks, _n_valid, _done = (
             self._decode_jit(window, steps, sampled)(*args)
         )
         for slot in self._active:
             self._host_lens[slot] += steps
         block = self._sync_host(toks)  # [steps, B] — THE host sync per dispatch
-        elapsed = time.perf_counter() - started
-        self._last_sync_t = time.perf_counter()
+        self._last_sync_t = self.stats.enter(FANOUT)
+        elapsed = self._last_sync_t - started
         self._note_dispatch(elapsed, steps)
         self._journal.append(flightrec.EV_DISPATCH_LAND, None, -1, steps, 0)
         if steps < self.runtime.decode_steps_per_dispatch:
@@ -3859,8 +4173,7 @@ class InferenceEngine:
         both run this — an unlocked read-inc-write would double-count."""
         m, counted, stats = self.metrics, self._counted, self.stats
         with self._counted_lock:
-            for key in ("decode_tokens", "prefill_tokens", "spec_proposed",
-                        "spec_accepted", "overlap_wasted_tokens"):
+            for key in _SYNCED_FIELDS:
                 value = getattr(stats, key)
                 if value != counted[key]:
                     m[key].inc(value - counted[key])
@@ -3923,11 +4236,11 @@ class InferenceEngine:
             not self._effective_sampling(r).is_greedy
             for r in self._active.values()
         )
-        self._observe_gap()  # just before enqueue: drafting is prep too
+        started = self.stats.enter(ENQUEUE)
+        self._observe_gap(started)  # just before enqueue: drafting is prep too
         self._journal.append(
             flightrec.EV_DISPATCH_LAUNCH, None, -1, S, len(self._active)
         )
-        started = time.perf_counter()
         args = [self.params, self._k, self._v]
         if self._paged:
             args.append(self._tables)
@@ -3950,8 +4263,8 @@ class InferenceEngine:
         out_toks, emitted, n_valid, done = self._sync_host(
             (out_toks, emitted, n_valid, done)
         )  # [B, S] + retirement arrays — THE host sync
-        elapsed = time.perf_counter() - started
-        self._last_sync_t = time.perf_counter()
+        self._last_sync_t = self.stats.enter(FANOUT)
+        elapsed = self._last_sync_t - started
         # clock: one verify forward ≈ one decode step of wall time; the
         # heap horizon only drives the non-spec short-dispatch lever, so
         # a coarse clock is fine here.  Inter-token latency, however, must

@@ -144,11 +144,12 @@ def attn_qkv(
     Shared by prefill, decode, and the sequence-parallel ring — ONE place
     for the projection math.
     """
-    h = rms_norm(x, lp["attn_norm"], eps)
-    q = jnp.einsum("bsd,dnh->bsnh", h, _w(lp["wq"]))
-    k = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wk"]))
-    v = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wv"]))
-    return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, lp["attn_norm"], eps)
+        q = jnp.einsum("bsd,dnh->bsnh", h, _w(lp["wq"]))
+        k = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wk"]))
+        v = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wv"]))
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
 def attn_out_mlp(
@@ -158,20 +159,25 @@ def attn_out_mlp(
     eps: float,
 ) -> jax.Array:
     """The block's back half: output projection + residual + SwiGLU MLP."""
-    x = x + jnp.einsum("bsnh,nhd->bsd", attn, _w(lp["wo"]))
-    h = rms_norm(x, lp["mlp_norm"], eps)
-    gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"]))
-    up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"]))
-    return x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, _w(lp["w_down"]))
+    with jax.named_scope("attn_out"):
+        x = x + jnp.einsum("bsnh,nhd->bsd", attn, _w(lp["wo"]))
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], eps)
+        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"]))
+        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"]))
+        return x + jnp.einsum(
+            "bsf,fd->bsd", jax.nn.silu(gate) * up, _w(lp["w_down"])
+        )
 
 
 def lm_logits(x: jax.Array, params: Params, eps: float) -> jax.Array:
     """Final norm + (tied or untied) LM head."""
-    x = rms_norm(x, params["final_norm"], eps)
-    head = params.get("lm_head")
-    if head is None:
-        return jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    return jnp.einsum("bsd,dv->bsv", x, _w(head))
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], eps)
+        head = params.get("lm_head")
+        if head is None:
+            return jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        return jnp.einsum("bsd,dv->bsv", x, _w(head))
 
 
 def attention_xla(
@@ -203,6 +209,7 @@ def attention_xla(
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def prefill_attention(
     q: jax.Array,  # [B, Sq, H, hd]
     k_cache: jax.Array,  # [B, K, Skv, hd]
@@ -410,6 +417,7 @@ def decode_step_ring(
     )
 
 
+@jax.named_scope("attention")
 def _merged_decode_attention(
     q: jax.Array,  # [B, 1, H, hd]
     k_cache: jax.Array,  # [B, K, W, hd] main pages (stale within dispatch)
@@ -589,6 +597,7 @@ def ragged_attention_source(
     return o1, m1, z1
 
 
+@jax.named_scope("attention")
 def ragged_attention_xla(
     q: jax.Array,  # [B, S, H, hd] ragged queries (padded to the wave max)
     k_cache: jax.Array,  # [B, K, W, hd]
@@ -655,6 +664,7 @@ def verify_chunk_source(
     return o2, m2, z2
 
 
+@jax.named_scope("attention")
 def _verify_merged_attention(
     q: jax.Array,  # [B, S, H, hd] the chunk's queries
     k_cache: jax.Array,  # [B, K, W, hd] main cache window (read-only)
@@ -764,6 +774,7 @@ def verify_step_ring_paged(
     )
 
 
+@jax.named_scope("kv_write")
 def consolidate_ring(
     kv_cache: tuple[jax.Array, jax.Array],  # [L, B, K, S, hd] (donated)
     ring: tuple[jax.Array, jax.Array],  # [L, T, B, K, hd]
@@ -797,6 +808,7 @@ def consolidate_ring(
     return write(k_pages, ring_k), write(v_pages, ring_v)
 
 
+@jax.named_scope("kv_write")
 def _insert_chunk(
     cache: jax.Array,  # [B, K, Smax, hd]
     chunk: jax.Array,  # [B, S, K, hd]
@@ -838,6 +850,7 @@ def make_page_pool(
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+@jax.named_scope("gather_window")
 def gather_window_paged(
     pool_layer: jax.Array,  # [N, K, page, hd] one layer's pages
     tables: jax.Array,  # [B, Pmax] int32 block tables
@@ -901,6 +914,7 @@ def decode_step_ring_paged(
     )
 
 
+@jax.named_scope("kv_write")
 def consolidate_ring_paged(
     pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] (donated)
     ring: tuple[jax.Array, jax.Array],  # [L, T, B, K, hd]
@@ -946,6 +960,7 @@ def consolidate_ring_paged(
     return write(pool_k, ring_k), write(pool_v, ring_v)
 
 
+@jax.named_scope("kv_write")
 def write_prefill_pages(
     pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] (donated)
     scratch: tuple[jax.Array, jax.Array],  # [L, R, K, P, hd] prefill K/V

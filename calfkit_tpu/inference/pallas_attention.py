@@ -229,6 +229,7 @@ def ragged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="ragged_attention",
     )(
         q_starts.astype(jnp.int32), kv_lens.astype(jnp.int32),
         q, k_cache, v_cache,
@@ -284,6 +285,7 @@ def ragged_attention_paged_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="ragged_paged_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         tables.astype(jnp.int32),
@@ -334,6 +336,7 @@ def paged_decode_attention_pallas(
     return o[:, :, 0], m[:, :, 0], z[:, :, 0]
 
 
+@jax.named_scope("attention")
 def merged_paged_decode_attention_pallas(
     q: jax.Array,  # [B, 1, H, hd]
     pool_k: jax.Array,  # [L, N, K, page, hd]
@@ -366,6 +369,7 @@ def merged_paged_decode_attention_pallas(
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def merged_decode_attention_pallas(
     q: jax.Array,  # [B, 1, H, hd]
     k_cache: jax.Array,  # [B, K, W, hd]
@@ -400,6 +404,7 @@ def merged_decode_attention_pallas(
 # --------------------------------------------------------------------------- #
 
 
+@jax.named_scope("attention")
 def verify_attention_pallas(
     q: jax.Array,  # [B, S, H, hd] the verify chunk's queries
     k_cache: jax.Array,  # [B, K, W, hd] main-cache window
@@ -437,6 +442,7 @@ def verify_attention_pallas(
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def verify_attention_paged_pallas(
     q: jax.Array,  # [B, S, H, hd]
     pool_k: jax.Array,  # [L, N, K, page, hd]
@@ -598,6 +604,7 @@ def prefill_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, Sq, hd), jnp.float32),
         interpret=interpret,
+        name="prefill_attention",
     )(seq_lens.astype(jnp.int32), q_pos.astype(jnp.int32)[..., None], qg,
       k_cache, v_cache)
 

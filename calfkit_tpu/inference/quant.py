@@ -79,6 +79,7 @@ def is_quantized4(leaf: Any) -> bool:
     return isinstance(leaf, dict) and "scale" in leaf and q4_key_of(leaf) is not None
 
 
+@jax.named_scope("dequant")
 def dequant(leaf: Any, dtype: Any = jnp.bfloat16) -> jax.Array:
     """The read-side seam: plain arrays pass through.  The multiply runs in
     f32 (the scale's storage precision) and casts once — XLA fuses the

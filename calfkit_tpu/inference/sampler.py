@@ -41,6 +41,7 @@ class SamplingParams:
         return self.temperature <= 0.0
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jax.Array,  # [B, V] (last-token logits)
     key: jax.Array,
@@ -101,6 +102,7 @@ def filtered_logits(
     return jnp.where(scaled < threshold, -jnp.inf, scaled)
 
 
+@jax.named_scope("sample")
 def sample_slots(
     logits: jax.Array,  # [B, V] (last-token logits)
     keys: jax.Array,  # [B] stacked typed PRNG keys (one stream per slot)
@@ -163,6 +165,7 @@ def retire_mask_slots(
     return jnp.where(active, n_valid, 0), done & active
 
 
+@jax.named_scope("sample")
 def spec_accept_slots(
     logits: jax.Array,  # [B, S, V] verify logits (S = k_spec + 1)
     drafts: jax.Array,  # [B, S-1] i32 drafted candidate tokens

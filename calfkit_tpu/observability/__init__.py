@@ -14,7 +14,10 @@ Four pieces:
   request by ``ck timeline``.
 - :mod:`~calfkit_tpu.observability.http` — the optional asyncio endpoint:
   ``/metrics``, ``/healthz`` (liveness), ``/readyz`` (readiness probe),
-  ``/flightrec``.
+  ``/flightrec``, ``/capacity``, ``/profile``.
+- :mod:`~calfkit_tpu.observability.devtrace` — a few seconds of
+  ``jax.profiler`` reduced to device seconds by the program's named
+  scopes and idle gaps by the engine's phase (``GET /profile``).
 - :mod:`~calfkit_tpu.observability.runledger` — run-scoped observability
   (ISSUE 17): the client-side per-run attempt ledger behind
   ``handle.run_report()`` and the compacted ``mesh.runs`` export, plus

@@ -1,0 +1,344 @@
+"""An operator's look at the device: a few seconds of ``jax.profiler``,
+reduced to where the device's time went and what the host was doing
+whenever the device stood idle.
+
+    from calfkit_tpu.observability import devtrace
+    devtrace.capture(8.0)          # or GET /profile?seconds=8 on MetricsServer
+
+``capture`` starts the profiler into a temporary directory, sleeps, stops
+it, reduces the ``.xplane.pb`` and deletes it.  The reduction rests on
+two things the program puts on the record (ISSUE 24):
+
+- ``jax.named_scope`` names in every jit body (``SCOPES`` below).  The
+  profiler keeps an operation's scope path in the ``tf_op`` stat of its
+  event METADATA, which ``jax.profiler.ProfileData`` does not hand out
+  (it gives an event's own stats only), so the file is read here as
+  protobuf wire format, with the standard library alone.
+- ``engine.<phase>`` host annotations from the engine's phase clock
+  (``EngineStats.enter``): exclusive, so every idle nanosecond of the
+  device falls under at most one of them.
+
+A program loaded from a persistent compile cache that another build
+filled carries THAT build's scope names (JAX leaves operation metadata
+out of the cache key): after an upgrade from a build without names,
+capture from an empty cache directory.
+
+Two stages, so that the arithmetic is testable without a chip:
+``read_trace(path)`` reads the file into plain tuples, ``reduce_trace(...)`` is pure
+Python over them.
+"""
+
+from __future__ import annotations
+
+import bisect
+import glob
+import os
+import re
+import shutil
+import tempfile
+import threading
+import time
+from collections import defaultdict
+from typing import Any, Iterator
+
+__all__ = ["SCOPES", "CaptureBusy", "capture", "read_trace", "reduce_trace"]
+
+# every jax.named_scope the program opens (tests/test_devtrace.py holds
+# the sources to this list); anything else in an operation's path is
+# JAX's own (jit(...), while, body, the primitive)
+SCOPES = frozenset({
+    # engine.py jit bodies
+    "decode_loop", "chunk_loop", "verify", "finalize", "seed_scratch", "prefill",
+    # model.py, quant.py, sampler.py
+    "gather_window", "qkv", "attention", "attn_out", "mlp", "lm_head",
+    "kv_write", "dequant", "sample",
+})
+UNSCOPED = "(unscoped)"
+UNATTRIBUTED = "unattributed"
+HOST_PREFIX = "engine."
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+MAX_SECONDS = 60.0
+
+Op = tuple  # (plane, name, scope path, start_ns, duration_ns)
+Module = tuple  # (plane, name, start_ns, duration_ns)
+Host = tuple  # (name, start_ns, duration_ns)
+
+
+class CaptureBusy(RuntimeError):
+    """A capture of this process is already running."""
+
+
+_capturing = threading.Lock()
+
+
+def capture(seconds: float) -> dict:
+    """Trace ``seconds`` of whatever the process is doing and reduce it.
+    Blocks for that long (call it from a thread).  A second capture while
+    one runs raises :class:`CaptureBusy`; where someone else's profile is
+    active (a benchmark's traced run), nothing is started and the result
+    says so: ``{"captured": False, "reason": ...}``."""
+    import jax
+
+    seconds = float(seconds)
+    if not 0.0 < seconds <= MAX_SECONDS:
+        raise ValueError(f"seconds must be in (0, {MAX_SECONDS:g}], got {seconds!r}")
+    if not _capturing.acquire(blocking=False):
+        raise CaptureBusy("a capture is already running")
+    trace_dir = tempfile.mkdtemp(prefix="calfkit-devtrace-")
+    clock = time.perf_counter
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # host spans only where annotated
+        t0 = clock()
+        try:
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+        except RuntimeError as exc:  # "Only one profile may be run at a time."
+            return {"captured": False, "reason": str(exc)}
+        t1 = clock()
+        try:
+            time.sleep(seconds)
+        finally:
+            t2 = clock()
+            jax.profiler.stop_trace()
+        t3 = clock()
+        paths = sorted(glob.glob(
+            os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        if not paths:
+            return {"captured": False, "reason": "the profiler wrote no .xplane.pb"}
+        events = read_trace(paths[-1])
+        t4 = clock()
+        out = reduce_trace(*events, t2 - t1)
+        # what the capture itself took, beside the window: the process is
+        # slowed while the profiler starts and stops and the file is read
+        out["took_s"] = {"start": t1 - t0, "stop": t3 - t2, "read": t4 - t3,
+                         "reduce": clock() - t4}
+        out["trace_bytes"] = os.path.getsize(paths[-1])
+        return {"captured": True, **out}
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        _capturing.release()
+
+
+# ------------------------------------------------------------- reading
+def _varint(buf: Any, i: int) -> tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _fields(buf: Any) -> Iterator[tuple[int, Any]]:
+    """(field number, value) of one protobuf message: an int for a
+    varint, a memoryview for anything with a length or a fixed width."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            value, i = _varint(buf, i)
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            value, i = buf[i:i + size], i + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, i = buf[i:i + size], i + size
+        else:
+            raise ValueError(f"protobuf wire type {wire} is not read here")
+        yield key >> 3, value
+
+
+def _text(view: Any) -> str:
+    return bytes(view).decode("utf-8", "replace")
+
+
+def _map_entry(buf: Any) -> tuple[int, Any]:
+    key, value = 0, b""
+    for field, v in _fields(buf):
+        if field == 1:
+            key = v
+        elif field == 2:
+            value = v
+    return key, value
+
+
+def scope_path(tf_op: str) -> str:
+    """``jit(ragged_paged)/decode_loop/while/body/qkv/dot_general:`` ->
+    ``decode_loop/qkv``: the program's scopes, in order."""
+    return "/".join(part for part in tf_op.rstrip(":").split("/") if part in SCOPES)
+
+
+def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
+    """Device operations with their scope paths, device modules, and the
+    host's ``engine.*`` annotations, from one ``.xplane.pb``.
+
+    XSpace.planes=1; XPlane: name=2 lines=3 event_metadata=4
+    stat_metadata=5; XLine: name=2 timestamp_ns=3 events=4; XEvent:
+    metadata_id=1 offset_ps=2 duration_ps=3; XEventMetadata: name=2
+    stats=5; XStat: metadata_id=1 str_value=5 ref_value=7;
+    XStatMetadata: name=2."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    ops: list[Op] = []
+    modules: list[Module] = []
+    host: list[Host] = []
+    for field, plane in _fields(space):
+        if field != 1:
+            continue
+        name, lines, event_md, stat_md = "", [], [], []
+        for f2, v in _fields(plane):
+            if f2 == 2:
+                name = _text(v)
+            elif f2 == 3:
+                lines.append(v)
+            elif f2 == 4:
+                event_md.append(v)
+            elif f2 == 5:
+                stat_md.append(v)
+        device = bool(DEVICE_PLANE.match(name))
+        stat_names: dict[int, str] = {}
+        for entry in stat_md:
+            key, value = _map_entry(entry)
+            stat_names[key] = next(
+                (_text(v) for f3, v in _fields(value) if f3 == 2), "")
+        tf_op = {k for k, v in stat_names.items() if v == "tf_op"}
+        events: dict[int, tuple[str, str]] = {}  # metadata id -> (name, scope path)
+        for entry in event_md:
+            key, value = _map_entry(entry)
+            ev_name, scope = "", ""
+            for f3, v in _fields(value):
+                if f3 == 2:
+                    ev_name = _text(v)
+                elif f3 == 5 and device:
+                    stat = dict(_fields(v))
+                    if stat.get(1) in tf_op:
+                        scope = scope_path(
+                            _text(stat[5]) if 5 in stat else stat_names.get(stat.get(7), ""))
+            if device or ev_name.startswith(HOST_PREFIX):
+                events[key] = (ev_name, scope)
+        if not events:
+            continue  # a plane with nothing of ours
+        for line in lines:
+            line_name, t0_ns, raw = "", 0, []
+            for f3, v in _fields(line):
+                if f3 == 2:
+                    line_name = _text(v)
+                elif f3 == 3:
+                    t0_ns = v
+                elif f3 == 4:
+                    raw.append(v)
+            if device and line_name not in (OPS_LINE, MODULES_LINE):
+                continue
+            for ev in raw:
+                if ev[0] == 0x08 and _varint(ev, 1)[0] not in events:
+                    continue  # (field 1 comes first: most host events end here)
+                meta = dict(_fields(ev))
+                known = events.get(meta.get(1, 0))
+                if known is None:
+                    continue
+                start = t0_ns + meta.get(2, 0) // 1000
+                duration = meta.get(3, 0) // 1000
+                if not device:
+                    host.append((known[0], start, duration))
+                elif line_name == OPS_LINE:
+                    ops.append((name, known[0], known[1], start, duration))
+                else:
+                    modules.append((name, known[0], start, duration))
+    return ops, modules, host
+
+
+# ------------------------------------------------------------ reducing
+def _own_time(ops: list[Op]) -> tuple[list[tuple[Op, int]], int, list[tuple[int, int]]]:
+    """For one device's operations: each with its own nanoseconds (its
+    duration less the operations nested in it, so a ``while`` and its
+    body are not counted twice), the busy nanoseconds (the union of the
+    outermost intervals) and the gaps between them."""
+    ordered = sorted(ops, key=lambda e: (e[3], -e[4]))
+    child_ns = [0] * len(ordered)
+    stack: list[int] = []
+    busy, gaps, busy_end = 0, [], None
+    for i, e in enumerate(ordered):
+        start, end = e[3], e[3] + e[4]
+        while stack and ordered[stack[-1]][3] + ordered[stack[-1]][4] <= start:
+            stack.pop()
+        if stack:
+            child_ns[stack[-1]] += e[4]
+        else:  # an outermost operation: part of the busy union
+            if busy_end is None or start > busy_end:
+                if busy_end is not None:
+                    gaps.append((busy_end, start))
+                busy += end - start
+                busy_end = end
+            elif end > busy_end:
+                busy += end - busy_end
+                busy_end = end
+        stack.append(i)
+    own = [(e, max(0, e[4] - child_ns[i])) for i, e in enumerate(ordered)]
+    return own, busy, gaps
+
+
+def _gaps_by_phase(gaps: list[tuple[int, int]], host: list[Host]) -> dict[str, float]:
+    """Idle nanoseconds by the ``engine.<phase>`` annotation they fall
+    under.  The phases are exclusive, so a gap is split exactly; what no
+    annotation covers is ``unattributed``."""
+    spans = sorted((s, s + d, n) for n, s, d in host if d > 0)
+    starts = [s for s, _, _ in spans]
+    acc: dict[str, float] = defaultdict(float)
+    for a, b in gaps:
+        covered = 0
+        i = max(0, bisect.bisect_right(starts, a) - 1)
+        while i < len(spans) and spans[i][0] < b:
+            overlap = min(b, spans[i][1]) - max(a, spans[i][0])
+            if overlap > 0:
+                acc[spans[i][2]] += overlap / 1e9
+                covered += overlap
+            i += 1
+        if b - a > covered:
+            acc[UNATTRIBUTED] += (b - a - covered) / 1e9
+    return dict(acc)
+
+
+def _sorted(acc: dict[str, float]) -> dict[str, float]:
+    return dict(sorted(acc.items(), key=lambda kv: -kv[1]))
+
+
+def reduce_trace(ops: list[Op], modules: list[Module], host: list[Host], window_s: float) -> dict:
+    """Device numbers of one captured window of ``window_s`` seconds, as
+    the mean over the devices seen; the idle gaps are the first device's."""
+    planes = sorted({e[0] for e in ops})
+    out: dict[str, Any] = {"devices": len(planes), "window_s": window_s}
+    if not planes:
+        return out
+    busy_s = 0.0
+    depth1: dict[str, float] = defaultdict(float)
+    depth2: dict[str, float] = defaultdict(float)
+    first_gaps: list[tuple[int, int]] = []
+    for plane in planes:
+        own, busy, gaps = _own_time([e for e in ops if e[0] == plane])
+        busy_s += busy / 1e9 / len(planes)
+        if plane == planes[0]:
+            first_gaps = gaps
+        for e, ns in own:
+            parts = e[2].split("/") if e[2] else [UNSCOPED]
+            depth1[parts[0]] += ns / 1e9 / len(planes)
+            depth2["/".join(parts[:2])] += ns / 1e9 / len(planes)
+    by_module: dict[str, float] = defaultdict(float)
+    for m in modules:
+        by_module[m[1].split("(", 1)[0].strip()] += m[3] / 1e9 / len(planes)
+    gap_s = _gaps_by_phase(first_gaps, host)
+    out.update(
+        busy_s=busy_s,
+        idle_pct=100.0 * (1.0 - busy_s / window_s) if window_s > 0 else None,
+        scope_s=_sorted(depth1),
+        scope2_s=_sorted(depth2),
+        unscoped_pct=100.0 * depth1.get(UNSCOPED, 0.0) / busy_s if busy_s else None,
+        module_s=_sorted(by_module),
+        gap_s=_sorted(gap_s),
+        gap_unattributed_pct=(
+            100.0 * gap_s.get(UNATTRIBUTED, 0.0) / sum(gap_s.values()) if gap_s else None),
+    )
+    return out

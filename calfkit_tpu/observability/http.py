@@ -18,7 +18,13 @@ responder serving:
 - ``GET /capacity`` — on-demand JSONL dump of every registered capacity
   sampler (:mod:`calfkit_tpu.observability.capacity`): the occupancy
   timeline ring plus the live page-attribution breakdown in the meta
-  header.
+  header;
+- ``GET /profile?seconds=N`` — N seconds of ``jax.profiler`` over whatever
+  the process is doing, reduced by
+  :mod:`calfkit_tpu.observability.devtrace` to JSON: device busy and idle
+  share, device seconds by the program's named scopes and by XLA module,
+  idle gaps by the engine phase that covers them.  The request lasts N
+  seconds; a second one meanwhile gets ``409``.
 
 This is an OPTIONAL operator convenience — nothing in the serving path
 depends on it — so every failure mode closes the offending connection and
@@ -28,8 +34,10 @@ keeps listening.
 from __future__ import annotations
 
 import asyncio
+import json
 import logging
 from typing import Any, Callable
+from urllib.parse import parse_qs
 
 from calfkit_tpu.observability.metrics import MetricsRegistry, metrics_text
 
@@ -153,6 +161,24 @@ class MetricsServer:
             return text.encode("utf-8"), "200 OK", "application/x-ndjson"
         return b"not found\n", "404 Not Found", "text/plain"
 
+    async def _profile(self, query: str) -> "tuple[bytes, str, str]":
+        """``/profile``: the capture blocks for its seconds, so it runs on
+        a thread; the seconds come from outside and are checked there."""
+        from calfkit_tpu.observability import devtrace
+
+        try:
+            seconds = float(parse_qs(query).get("seconds", ["3"])[-1])
+            result = await asyncio.to_thread(devtrace.capture, seconds)
+        except ValueError as exc:
+            return f"{exc}\n".encode("utf-8"), "400 Bad Request", "text/plain"
+        except devtrace.CaptureBusy as exc:
+            return f"{exc}\n".encode("utf-8"), "409 Conflict", "text/plain"
+        return (
+            (json.dumps(result) + "\n").encode("utf-8"),
+            "200 OK",
+            "application/json",
+        )
+
     async def _handle(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
@@ -171,7 +197,11 @@ class MetricsServer:
                 drained += len(line)
                 if line in (b"\r\n", b"\n", b"") or drained > _MAX_REQUEST_BYTES:
                     break
-            body, status, ctype = self._respond(path.split("?", 1)[0])
+            path, _, query = path.partition("?")
+            if path == "/profile":
+                body, status, ctype = await self._profile(query)
+            else:
+                body, status, ctype = self._respond(path)
             writer.write(
                 (
                     f"HTTP/1.0 {status}\r\n"

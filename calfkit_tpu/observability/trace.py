@@ -134,8 +134,14 @@ class Span:
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
 
-    def end(self, status: str | None = None, **attrs: Any) -> SpanRecord | None:
-        """Finish + export; returns the record (None on double-end)."""
+    def end(
+        self, status: str | None = None, *, at: float | None = None,
+        **attrs: Any,
+    ) -> SpanRecord | None:
+        """Finish + export; returns the record (None on double-end).
+        ``at`` is the ``time.perf_counter`` moment the operation really
+        ended, for a span closed later than that (the engine ends a
+        request's queue span from the consumer's context)."""
         if self._ended:
             return None
         self._ended = True
@@ -143,6 +149,7 @@ class Span:
             if status is not None:
                 self.status = status
             self.attrs.update(attrs)
+            end = time.perf_counter() if at is None else at
             record = SpanRecord(
                 trace_id=self.context.trace_id,
                 span_id=self.context.span_id,
@@ -151,7 +158,7 @@ class Span:
                 kind=self.kind,
                 emitter=self.emitter,
                 start_s=self.start_s,
-                duration_ms=(time.perf_counter() - self._t0) * 1000.0,
+                duration_ms=max(0.0, end - self._t0) * 1000.0,
                 status=self.status,
                 attrs=self.attrs,
             )

@@ -5,7 +5,7 @@ inside the decode/verify jits), so every script that stubs the jit boundary
 must mirror that contract or its engine never finishes a request.  One numpy
 copy here instead of one per script — a change to the retirement semantics
 updates a single reference implementation, and the committed artifacts
-(SCHED_OVERHEAD_r*.json, OVERLAP.json, OBS_OVERHEAD.json, SPEC_DECODE.json)
+(SCHED_OVERHEAD_r*.json, OVERLAP.json, SPEC_DECODE.json)
 cannot silently keep passing against a contract the engine dropped.
 
 These benches configure no stop tokens, so only the hard-bound half of
