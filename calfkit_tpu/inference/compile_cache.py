@@ -7,6 +7,19 @@
   key, so nothing in it is derived from the host, a pid, a temp name or
   the time — a directory that moves never hits.
 
+- either way, MLIR locations carry ONE frame of the Python traceback, not
+  ten (``jax_traceback_in_locations_limit``).  A Pallas kernel is
+  serialized WITH its locations into the custom call that the cache key
+  hashes, and a jitted entry point is traced once a process, by whichever
+  program needs it first: with ten frames in, that caller's stack became
+  part of every later program's key, and about a third of the engine's
+  programs missed a warm cache in some runs and not in others (PERF.md
+  section 6, PR 25).  One frame is the kernel's own line, the same from
+  every caller.  (Switching tracebacks off altogether,
+  ``jax_include_full_tracebacks_in_locations``, would do too, but JAX
+  0.9.0 then drops the name stack from every operation's ``op_name``: the
+  device trace loses its scopes.)
+
 Failures are not swallowed: a cache that cannot be enabled is a broken
 installation, not a best-effort nicety.
 """
@@ -24,10 +37,11 @@ CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Apply the rule; return the directory JAX's persistent cache uses."""
+    import jax
+
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     placed = os.environ.get(CACHE_ENV)
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     return CACHE_DIR

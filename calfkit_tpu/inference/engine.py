@@ -102,9 +102,12 @@ _PHASE_ANNOTATION = {p: "engine." + p[len("phase_"):-len("_s")] for p in PHASES}
 BLOCKED = tuple(f for f in _SECONDS_FIELDS if f.startswith("blocked_"))
 NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # the EngineStats fields folded into /metrics counters once a dispatch
+# counters that go to /metrics and ``counters()`` only, never on the
+# heartbeat advert's window
+_LOCAL_FIELDS = ("decode_pages_live", "decode_pages_window", *_SECONDS_FIELDS)
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
-    "overlap_wasted_tokens", *_SECONDS_FIELDS,
+    "overlap_wasted_tokens", *_LOCAL_FIELDS,
 )
 
 _ATTN_PROFILE_CACHE: "tuple[tuple, dict | None] | None" = None
@@ -194,6 +197,16 @@ def _engine_metrics(
             "calfkit_engine_overlap_wasted_tokens_total",
             "pad tokens discarded by one-dispatch-late retirement "
             "(overlapped execution)",
+        ),
+        decode_pages_live=reg.counter(
+            "calfkit_engine_decode_pages_live_total",
+            "paged decode steps: KV pages the active rows hold "
+            "(what a read in place touches)",
+        ),
+        decode_pages_window=reg.counter(
+            "calfkit_engine_decode_pages_window_total",
+            "paged decode steps: rows in the program x the window bucket's "
+            "pages (what the XLA window gather copies)",
         ),
         active_requests=reg.gauge(
             "calfkit_engine_active_requests",
@@ -498,6 +511,13 @@ class EngineStats:
     blocked_wave_s: float = 0.0  # an admission wave already in flight
     blocked_budget_s: float = 0.0  # ragged token budget / wave trim / bucket
     empty_slot_queued_s: float = 0.0  # slot-seconds
+    # the paged decode read, a sum over decode steps from the host mirror
+    # of the row lengths (no device sync): the pages the active rows hold,
+    # ceil(len / page) each, beside rows in the program x the window
+    # bucket's pages.  live / window by difference is the share of the XLA
+    # window gather's bytes that a read in place still moves.
+    decode_pages_live: int = 0
+    decode_pages_window: int = 0
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
     # delta readers would steal each other's intervals.
@@ -523,11 +543,12 @@ class EngineStats:
         "prefix_evictions", "alloc_stalls",
         "interactive_shed", "batch_shed",
         "interactive_expired", "batch_expired",
-        *_SECONDS_FIELDS,
+        *_LOCAL_FIELDS,
     )
-    # what the heartbeat advert's window carries: the phase clock and the
-    # admission ledger go to /metrics and ``counters()`` only
-    _ADVERT_FIELDS = _COUNTER_FIELDS[: -len(_SECONDS_FIELDS)]
+    # what the heartbeat advert's window carries: the phase clock, the
+    # admission ledger and the page sums go to /metrics and ``counters()``
+    # only
+    _ADVERT_FIELDS = _COUNTER_FIELDS[: -len(_LOCAL_FIELDS)]
 
     # EWMA smoothing for dispatch_ewma_ms: ~5-dispatch memory — fresh
     # enough to react inside one heartbeat interval, smooth enough that
@@ -1060,9 +1081,19 @@ class InferenceEngine:
         """Resolve ``attention_impl`` for one jit path (``prefill`` /
         ``decode`` / ``paged_decode`` / ``ragged`` / ``paged_ragged``).
 
-        "auto" is EVIDENCE-BASED (VERDICT r3 item 8): it reads the profile
+        ``paged_decode`` under "auto" is decided by what the engine can
+        OBSERVE (PERF.md section 6, PR 25: measured on the v5e): the
+        Pallas kernel that reads each row's live pages in place when the
+        backend is a TPU, one device holds the model (``tp == 1`` and
+        ``dp == 1``: a ``pallas_call`` under GSPMD needs a ``shard_map``
+        over the KV heads first) and the head and page shapes are the
+        kernel's
+        (:func:`pallas_attention.paged_decode_in_place_ok`); else XLA.
+
+        The other paths under "auto" are EVIDENCE-BASED (VERDICT r3 item
+        8): they read the profile
         artifact ``scripts/profile_attention.py --out`` writes on hardware
-        and flips to the per-path winner, but only when the artifact's
+        and flip to the per-path winner, but only when the artifact's
         platform matches the live backend (a TPU verdict must not steer a
         CPU run and vice versa).  No artifact, or no verdict for this path
         → the ``fallback`` path's winner (the ragged multi-query paths
@@ -1072,12 +1103,27 @@ class InferenceEngine:
         impl = self.runtime.attention_impl
         if impl != "auto":
             return impl
-        verdict = _load_attn_profile()
-        if not verdict:
-            return "xla"
         try:
             platform = jax.devices()[0].platform
         except Exception:  # noqa: BLE001 - backend probe must not break jit build
+            return "xla"
+        if path == "paged_decode":
+            from calfkit_tpu.inference.pallas_attention import (
+                paged_decode_in_place_ok,
+            )
+
+            in_place = (
+                platform == "tpu"
+                and self._paged
+                and self.mesh.size == 1
+                and paged_decode_in_place_ok(
+                    self.config.head_dim, self.runtime.page_size,
+                    self.config.dtype,
+                )
+            )
+            return "pallas" if in_place else "xla"
+        verdict = _load_attn_profile()
+        if not verdict:
             return "xla"
         if verdict.get("platform") != platform:
             return "xla"
@@ -1226,7 +1272,7 @@ class InferenceEngine:
                 ring, last = carry
                 logits, ring = M.decode_step_ring_paged(
                     params, cfg, last[:, None], (k, v), tables, ring, t,
-                    lens, wpages=wpages, attn_impl=attn_impl,
+                    lens, wpages=wpages, attn_impl=attn_impl, active=active,
                 )
                 if sampled:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
@@ -3833,9 +3879,12 @@ class InferenceEngine:
         steps, sampled).  Pure host work — no device sync."""
         active_mask = np.zeros((self.runtime.max_batch_size,), bool)
         needed = 1
+        page = self.runtime.page_size
+        live_pages = 0
         for slot in self._active:
             active_mask[slot] = True
             needed = max(needed, self._host_lens[slot])
+            live_pages += -(-int(self._host_lens[slot]) // page)
         # the ring covers in-dispatch growth; the window only needs to cover
         # what's already in the main cache
         window = self._window_bucket(int(needed))
@@ -3856,6 +3905,11 @@ class InferenceEngine:
             not self._effective_sampling(r).is_greedy
             for r in self._active.values()
         )
+        if self._paged:
+            self.stats.decode_pages_live += live_pages * steps
+            self.stats.decode_pages_window += (
+                self.runtime.max_batch_size * -(-window // page) * steps
+            )
         prev = self._pend
         done_prev = prev["done_dev"] if prev is not None else self._done_zero
         stop_table, hard_end = self._retire_args()

@@ -858,9 +858,13 @@ def gather_window_paged(
 ) -> jax.Array:
     """Materialize each row's window from its pages → [B, K, wp·page, hd].
 
-    The XLA fallback read path: one gather per (layer, step) — correct
-    everywhere, but doubles attention HBM traffic vs the Pallas paged
-    kernel, which DMAs pages in place.
+    The XLA read path: one gather per (layer, step) of EVERY row's whole
+    window bucket, used or not, occupied slot or not — read, written and
+    read again by the attention.  Correct everywhere: the CPU path, the
+    ``tp > 1`` path, the verify and ragged S > 1 programs, and the parity
+    reference of the Pallas paged decode kernel, which reads each row's
+    live pages in place instead (on the v5e the gather was 48% of device
+    time in the benchmark's cell: PERF.md sections 5 and 6).
     """
     B = tables.shape[0]
     page = pool_layer.shape[2]
@@ -880,6 +884,7 @@ def decode_step_ring_paged(
     base_lens: jax.Array,  # [B]
     wpages: int,  # static: window bucket in pages
     attn_impl: str = "xla",
+    active: jax.Array | None = None,  # [B] bool; None: every row reads
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """One decode step reading KV through the block tables.
 
@@ -887,6 +892,11 @@ def decode_step_ring_paged(
     main-cache read differs.  The pool is a scan *invariant* (closed over,
     indexed per layer), never a carry — its bytes move once per read, not
     per scan round-trip.
+
+    The Pallas read follows each row's length, so a row that is not
+    ``active`` (its token is discarded by the caller) is given length 0
+    there and costs no page; the XLA read gathers every row's window
+    whatever it holds and takes no notice of ``active``.
     """
     pool_k, pool_v = pool
 
@@ -896,8 +906,12 @@ def decode_step_ring_paged(
                 merged_paged_decode_attention_pallas,
             )
 
+            read_lens = (
+                base_lens if active is None
+                else jnp.where(active, base_lens, 0)
+            )
             return merged_paged_decode_attention_pallas(
-                q, pool_k, pool_v, i, tables, rk, rv, base_lens, t,
+                q, pool_k, pool_v, i, tables, rk, rv, read_lens, t,
                 wpages=wpages, interpret=attn_impl == "pallas_interpret",
             )
         kl = lax.dynamic_index_in_dim(pool_k, i, 0, keepdims=False)

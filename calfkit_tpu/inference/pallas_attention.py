@@ -1,23 +1,26 @@
 """Pallas TPU kernels for GQA attention over the KV cache.
 
 Why kernels: the XLA einsum path maps GQA decode badly — per (batch, kv
-head) the score matmul is [G, hd] × [hd, W], a sliver of the 128×128 MXU.
-A kernel streams each (b, k) cache slice through VMEM once and fuses mask +
+head) the score matmul is [G, hd] × [hd, W], a sliver of the 128×128 MXU —
+and its paged read copies every row's whole window out of the pool before
+attending it.  A kernel streams the cache through VMEM once and fuses mask +
 softmax statistics + weighted sum, so HBM traffic is one read of K/V.
 
-Three kernel bodies, one flash-accumulation core (:func:`_flash_update`):
+Four kernel bodies:
 
 - ragged over a dense window (:func:`ragged_attention_pallas`),
 - ragged through the block tables (:func:`ragged_attention_paged_pallas`),
-- prefill over the chunk-updated scratch (:func:`prefill_attention_pallas`).
+- prefill over the chunk-updated scratch (:func:`prefill_attention_pallas`),
+  these three on one flash-accumulation core (:func:`_flash_update`);
+- single-query paged decode that reads each row's LIVE pages in place
+  (:func:`paged_decode_attention_pallas`): one program a row, a loop over
+  that row's own pages with double-buffered whole-slab copies out of the
+  pool in HBM, bf16 operands into the MXU.  Its work follows the row
+  lengths, not the window bucket.
 
-Single-query decode is the S=1 row of the ragged law, so
-:func:`decode_attention_pallas` / :func:`paged_decode_attention_pallas`
-call the ragged kernels with one query per row — the repair that made the
-old single-query bodies acceptable to the TPU compiler (per-row scalars in
-SMEM, kv streamed chunk by chunk, statistics in a layout whose last two
-block dims equal the array's) turned them into the ragged bodies line for
-line.
+Dense single-query decode is the S=1 row of the ragged law
+(:func:`decode_attention_pallas`), and so is paged decode at a head or page
+shape outside :func:`paged_decode_in_place_ok`.
 
 The source kernels return *unnormalized* output plus the softmax statistics
 ``(m, z)`` so the caller can fold in the fresh-token ring / verify chunk
@@ -27,13 +30,18 @@ What the TPU lowering demands, and how every kernel here meets it:
 per-row scalars (lengths, starts, block tables, the layer index) ride
 ``PrefetchScalarGridSpec`` into SMEM — a ``(1,)`` block of a ``[B]`` array is
 refused; every VMEM block's last two dims equal the array's or are
-(8, 128)-aligned; kv is a sequential grid axis with VMEM scratch carrying
-the statistics, so VMEM use is independent of the window.
+(8, 128)-aligned; in the ragged and prefill kernels kv is a sequential grid
+axis with VMEM scratch carrying the statistics, so VMEM use is independent
+of the window.
 
 Status: every entry point AOT-compiles for a described v5e at
-TinyLlama-1.1B and Llama-3-8B widths (``tests/test_tpu_compile.py``) and
+TinyLlama-1.1B and Llama-3-8B widths, the paged decode read also at
+Mistral-7B's and InternLM2-1.8B's (``tests/test_tpu_compile.py``), and
 agrees with interpret mode and the XLA path on CPU.  ``attention_impl="auto"``
-still resolves to XLA (see docs/inference.md); ``"pallas"`` opts in.
+selects the paged decode read in place on a TPU (one device, heads of whole
+lane tiles: ``InferenceEngine._resolved_attn_impl``; PERF.md section 6, PR 25
+has the chip's numbers) and XLA for every other path (docs/inference.md);
+``"pallas"`` opts in everywhere.
 """
 
 from __future__ import annotations
@@ -316,6 +324,133 @@ def decode_attention_pallas(
     return o[:, :, 0], m[:, :, 0], z[:, :, 0]
 
 
+def paged_decode_in_place_ok(head_dim: int, page: int, dtype) -> bool:
+    """Whether :func:`_paged_decode_kernel` can take these shapes on a TPU:
+    a page slab is copied and flattened whole, so the head is whole lane
+    tiles (128) and the page whole sublane tiles of the cache's dtype (8
+    rows of 32 bits: 16 for bf16).  What fails this runs the S = 1 row of
+    the ragged paged kernel under ``"pallas"`` and XLA under ``"auto"``."""
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return head_dim % 128 == 0 and page % sublanes == 0
+
+
+# pages of one row folded per compute block of the paged decode kernel
+# (tuned on the chip, PERF.md section 6): the scores of a block are one
+# [H, PAGES_PER_BLOCK * K * page] product
+PAGED_DECODE_PAGES_PER_BLOCK = 2
+
+
+def _paged_decode_kernel(
+    layer_ref, tables_ref, lens_ref,  # scalar-prefetch (SMEM)
+    q_ref, pool_k, pool_v,  # q block in VMEM; the pools stay in HBM
+    o_ref, m_ref, z_ref,
+    kbuf, vbuf, sems,
+    *, wpages: int, group: int,
+):
+    """One ROW of a paged decode step: a loop over that row's own live
+    pages, ``ceil(len / page)`` of them, each fetched as its whole
+    ``[K, page, hd]`` slab (contiguous in the pool) by a double-buffered
+    async copy.  Nothing past the row's length is read or computed; a row
+    of length 0 starts no copy at all.
+
+    All K heads of a block are scored in ONE product: q is the row's
+    ``[H, hd]`` (H = K * G, a whole sublane tile where G alone is not),
+    the block's K slab is ``[P * K * page, hd]``, and a static
+    block-diagonal mask keeps query head h on the columns of its own kv
+    head.  The masked columns weigh exactly 0 in ``p``, so the PV product
+    over the same flattened axis is each head's own weighted sum.
+    """
+    b = pl.program_id(0)
+    P, K, page, hd = kbuf.shape[1:]
+    H = q_ref.shape[1]
+    C = P * K * page
+    layer = layer_ref[0]
+    kv_len = lens_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(kv_len, page), wpages)
+    n_blocks = pl.cdiv(n_pages, P)
+
+    def copies(blk, slot, i):
+        n = tables_ref[b, blk * P + i]
+        return (
+            pltpu.make_async_copy(
+                pool_k.at[layer, n], kbuf.at[slot, i], sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                pool_v.at[layer, n], vbuf.at[slot, i], sems.at[1, slot]
+            ),
+        )
+
+    def for_live_pages(blk, slot, act):
+        for i in range(P):  # static: a partial last block skips its tail
+            @pl.when(blk * P + i < n_pages)
+            def _():
+                for dma in copies(blk, slot, i):
+                    act(dma)
+
+    if P > 1:
+        # a partial last block leaves buffer pages no copy ever wrote:
+        # their columns are masked (p = 0), and 0 x garbage must stay 0
+        @pl.when(b == 0)
+        def _clear():
+            vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for_live_pages(0, 0, lambda dma: dma.start())
+
+    q = q_ref[0]  # [H, hd], the cache's dtype
+    scale = 1.0 / math.sqrt(hd)
+    col = lax.broadcasted_iota(jnp.int32, (H, C), 1)
+    row = lax.broadcasted_iota(jnp.int32, (H, C), 0)
+    own_head = (col // page) % K == row // group
+    col_pos = (col // (K * page)) * page + col % page  # position in block
+
+    def block(blk, carry):
+        m_prev, z_prev, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            for_live_pages(blk + 1, 1 - slot, lambda dma: dma.start())
+
+        for_live_pages(blk, slot, lambda dma: dma.wait())
+        k = kbuf[slot].reshape(C, hd)
+        v = vbuf[slot].reshape(C, hd)
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [H, C]
+        mask = own_head & (col_pos < kv_len - blk * (P * page))
+        s = jnp.where(mask, s, -1e30)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # p in the cache's dtype before the PV product, z from the rounded
+        # p: the law of model.masked_attention_source
+        p = jnp.exp(s - m_new).astype(v.dtype)
+        z_new = z_prev * alpha + jnp.sum(
+            p.astype(jnp.float32), axis=-1, keepdims=True
+        )
+        acc = acc * alpha + lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return m_new, z_new, acc
+
+    m, z, acc = lax.fori_loop(
+        0, n_blocks, block,
+        (
+            # the -1e29 floor of a fully masked row is where m starts
+            jnp.full((H, 1), -1e29, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, hd), jnp.float32),
+        ),
+    )
+    o_ref[0] = acc
+    m_ref[0] = m
+    z_ref[0] = z
+
+
+@functools.partial(
+    jax.jit, static_argnames=("wpages", "interpret", "pages_per_block")
+)
 def paged_decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
     pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing)
@@ -326,14 +461,74 @@ def paged_decode_attention_pallas(
     *,
     wpages: int,
     interpret: bool = False,
+    pages_per_block: int = PAGED_DECODE_PAGES_PER_BLOCK,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Paged decode attention → (o unnormalized, m, z), same contract as
-    the dense single-query entry point."""
-    o, m, z = ragged_attention_paged_pallas(
-        q[:, :, None], pool_k, pool_v, layer, tables, base_lens, base_lens,
-        wpages=wpages, interpret=interpret,
+    the dense single-query entry point.
+
+    Reads each row's LIVE pages in place (:func:`_paged_decode_kernel`):
+    the pool goes in whole and stays in HBM, the grid is the rows alone,
+    and the work of a row follows ``base_lens[b]`` — ``wpages`` is only the
+    static upper bound, so one kernel serves every window bucket.  K, V
+    and q meet the MXU in the cache's dtype with float32 accumulation."""
+    B, K, G, hd = q.shape
+    H = K * G
+    page = pool_k.shape[3]
+    if not paged_decode_in_place_ok(hd, page, pool_k.dtype):
+        o, m, z = ragged_attention_paged_pallas(
+            q[:, :, None], pool_k, pool_v, layer, tables, base_lens,
+            base_lens, wpages=wpages, interpret=interpret,
+        )
+        return o[:, :, 0], m[:, :, 0], z[:, :, 0]
+    _note_trace("paged_decode", interpret)
+    P = max(1, min(pages_per_block, wpages))
+    kernel = functools.partial(_paged_decode_kernel, wpages=wpages, group=G)
+
+    def row_map(b, *_refs):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, hd), row_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, H, hd), row_map),
+            pl.BlockSpec((1, H, 1), row_map),
+            pl.BlockSpec((1, H, 1), row_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, P, K, page, hd), pool_k.dtype),
+            pltpu.VMEM((2, P, K, page, hd), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
     )
-    return o[:, :, 0], m[:, :, 0], z[:, :, 0]
+    o, m, z = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=(
+            jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            # rows in order: the scratch cleared by row 0 serves them all
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        tables.astype(jnp.int32),
+        base_lens.astype(jnp.int32),
+        q.reshape(B, H, hd).astype(pool_k.dtype), pool_k, pool_v,
+    )
+    return (
+        o.reshape(B, K, G, hd), m.reshape(B, K, G), z.reshape(B, K, G)
+    )
 
 
 @jax.named_scope("attention")

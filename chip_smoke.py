@@ -21,8 +21,14 @@ bf16, random weights from ``--seed``):
   ``--margin``.
 - pallas — the same engine configuration with ``attention_impl="pallas"``:
   kernels compiled by Mosaic (never interpreted, never XLA), same check.
+- serve-wide — heads of 128 (Llama-3-8B's widths, 4 layers) under
+  ``attention_impl="auto"``: on a chip ``paged_decode`` must resolve to
+  ``pallas`` and the kernel that reads live pages in place must have been
+  compiled by Mosaic; same checks.  (TinyLlama's heads are 64 wide, outside
+  that kernel's shape rule: its ``auto`` stays on XLA.)
 - kernels — every Pallas kernel body, compiled, against its XLA reference
-  at TinyLlama-1.1B and Llama-3-8B widths.
+  at TinyLlama-1.1B and Llama-3-8B widths; the paged decode read in place
+  at Mistral-7B's widths, batch and window.
 
 ``--rehearse`` shrinks everything and uses interpret mode on the CPU; it
 can never print ``"ok": true``.
@@ -357,9 +363,10 @@ async def agree_phase(name, engine, prompts, new_tokens, margin, pad_to) -> tupl
 
 def kernels_phase(seed: int, interpret: bool) -> dict:
     """The serving run above selects the paged and prefill kernels only; here
-    all three bodies (ragged dense, ragged paged, prefill) run at
-    TinyLlama-1.1B and Llama-3-8B widths — S=1 decode rows and S=5 verify
-    rows, two kv chunks, two q blocks — and must match the XLA reference."""
+    the ragged dense, ragged paged and prefill bodies run at TinyLlama-1.1B
+    and Llama-3-8B widths — S=1 decode rows and S=5 verify rows, two kv
+    chunks, two q blocks — and the paged decode read in place at Mistral-7B's
+    widths, and each must match the XLA reference."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -415,11 +422,35 @@ def kernels_phase(seed: int, interpret: bool) -> dict:
         close("prefill", PA.prefill_attention_pallas(
             q, kc, vc, pos, plens, interpret=interpret),
             M.attention_xla(q, kc, vc, pos, plens))
+    # the paged decode read in place at Mistral-7B's widths: 32 rows, the
+    # 2048 window, row lengths around page edges, a row that reads nothing
+    K, G, hd = 8, 4, 128
+    B, W = (4, 128) if interpret else (32, 2048)
+    wpages = W // page
+    key = jax.random.split(jax.random.key(seed + 7), 3)
+    pool_k = jax.random.normal(key[0], (2, 1 + B * wpages, K, page, hd), bf)
+    pool_v = jax.random.normal(key[1], (2, 1 + B * wpages, K, page, hd), bf)
+    tables = jnp.asarray(1 + np.arange(B * wpages, dtype=np.int32).reshape(B, wpages))
+    lens = rng.integers(1, W, size=B)
+    lens[:4] = (0, page - 1, page + 1, W)
+    lens = jnp.asarray(lens, jnp.int32)
+    q = jax.random.normal(key[2], (B, K, G, hd), bf)
+    o, m, z = PA.paged_decode_attention_pallas(
+        q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=wpages,
+        interpret=interpret)
+    o_x, m_x, z_x = M.masked_attention_source(
+        q, M.gather_window_paged(pool_k[1], tables, wpages),
+        M.gather_window_paged(pool_v[1], tables, wpages),
+        jnp.arange(W)[None, :] < lens[:, None])
+    close("paged-decode-in-place", o / jnp.maximum(z[..., None], 1e-30),
+          o_x / jnp.maximum(z_x, 1e-30))
+    close("paged-decode-in-place/m", m, m_x[..., 0])
     tol = 3e-2  # bf16 inputs and outputs, values O(1)
     return {
         "phase": "kernels", "ok": all(e < tol for e in worst.values()),
         "max_abs_err_vs_xla": {k: round(v, 5) for k, v in worst.items()},
-        "tolerance": tol, "widths": ["tinyllama-1.1b", "llama-3-8b"],
+        "tolerance": tol,
+        "widths": ["tinyllama-1.1b", "llama-3-8b", "mistral-7b (paged decode)"],
         "mode": "interpreted" if interpret else "compiled",
         "compile_s": round(_compile_s[0] - c0, 2),
         "seconds": round(time.perf_counter() - t0, 2),
@@ -439,6 +470,21 @@ def sizes(rehearse: bool) -> dict:
     return dict(preset="tinyllama-1.1b", seq=1024, chunk=128, page=64, bs=16,
                 burst=24, singles=4, new_tokens=24, agree_lens=(12, 70, 200),
                 agree_new=48, pad_to=256)
+
+
+def wide_config(sz: dict, rehearse: bool):
+    """Heads of 128, the shape the paged decode kernel reads in place:
+    Llama-3-8B's widths cut to 4 layers (the debug preset widened, in a
+    rehearsal)."""
+    from dataclasses import replace
+
+    from calfkit_tpu.inference.config import preset
+
+    if rehearse:
+        return replace(preset("debug", max_seq_len=sz["seq"]), name="debug-wide",
+                       d_model=256, n_heads=2, n_kv_heads=1)
+    return replace(preset("llama-3-8b", max_seq_len=sz["seq"]),
+                   name="llama-3-8b/4-layers", n_layers=4)
 
 
 def serving_runtime(sz: dict, impl: str, **kw):
@@ -464,9 +510,15 @@ async def run_one_chip(args, sz: dict) -> bool:
     config = preset(sz["preset"], max_seq_len=sz["seq"])
     pallas = "pallas_interpret" if args.rehearse else "pallas"
     ok = True
-    prompts = prompts_for(config.vocab_size, sz["agree_lens"], args.seed)
     xla_outputs = None
-    for phase, impl in (("serve", "auto"), ("pallas", pallas)):
+    want = "interpreted" if args.rehearse else "compiled"
+    # a CPU has no "auto" that selects a kernel: the rehearsal asks for it
+    wide_impl = pallas if args.rehearse else "auto"
+    for phase, agree_name, impl, config in (
+        ("serve", "agree", "auto", config),
+        ("pallas", "pallas-agree", pallas, config),
+        ("serve-wide", "wide-agree", wide_impl, wide_config(sz, args.rehearse)),
+    ):
         PA.KERNEL_TRACES.clear()
         model = JaxLocalModelClient(
             config=config, runtime=serving_runtime(sz, impl),
@@ -478,20 +530,29 @@ async def run_one_chip(args, sz: dict) -> bool:
         )
         engine = model._engine
         agree, outputs = await agree_phase(
-            "agree" if impl == "auto" else "pallas-agree", engine, prompts,
+            agree_name, engine,
+            prompts_for(config.vocab_size, sz["agree_lens"], args.seed),
             sz["agree_new"], args.margin, sz["pad_to"],
         )
-        if impl == "auto":
+        if phase == "serve":
             xla_outputs = outputs
         else:
             traces = {f"{k}:{mode}": n for (k, mode), n in PA.KERNEL_TRACES.items()}
-            want = "interpreted" if args.rehearse else "compiled"
             row["kernel_traces"] = agree["kernel_traces"] = traces
-            kernels_ok = bool(traces) and all(
-                key.endswith(want) for key in traces
-            ) and all(v == pallas for v in row["attention_impl"].values())
-            agree["kernels_all_" + want] = kernels_ok
-            agree["equal_to_xla_engine"] = count_equal(xla_outputs, outputs)
+            kernels_ok = bool(traces) and all(key.endswith(want) for key in traces)
+            if phase == "pallas":
+                kernels_ok = kernels_ok and all(
+                    v == pallas for v in row["attention_impl"].values()
+                )
+                agree["kernels_all_" + want] = kernels_ok
+                agree["equal_to_xla_engine"] = count_equal(xla_outputs, outputs)
+            else:
+                kernels_ok = (
+                    kernels_ok
+                    and row["attention_impl"]["paged_decode"] == pallas
+                    and traces.get(f"paged_decode:{want}", 0) > 0
+                )
+                agree["paged_decode_in_place_" + want] = kernels_ok
             agree["ok"] = agree["ok"] and kernels_ok
         row["memory"] = agree["memory"] = memory(jax.devices()[:1])
         emit(row)

@@ -78,6 +78,38 @@ class TestCompileCacheRule:
         assert not re.search(r"\d", tail)
         assert os.uname().nodename not in compile_cache.CACHE_DIR
 
+    @pytest.mark.parametrize("placed", ["/x", None])
+    def test_locations_carry_one_frame_not_a_traceback(
+        self, monkeypatch, cache_dir_updates, placed
+    ):
+        """A Pallas kernel's locations are part of the bytes the cache key
+        hashes: ten frames of traceback there made the key depend on which
+        program traced the kernel first (tests/test_tpu_compile.py holds
+        the bytes to it).  The name stack, which the device trace reads its
+        scopes from, stays."""
+        import jax.numpy as jnp
+
+        option = "jax_traceback_in_locations_limit"
+        before = getattr(jax.config, option)
+        if placed:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            jax.config.update(option, 10)
+            compile_cache.enable_compile_cache()
+            assert getattr(jax.config, option) == 1
+
+            @jax.named_scope("decode_loop")
+            def scoped(x):
+                with jax.named_scope("mlp"):
+                    return jnp.dot(x, x)
+
+            text = jax.jit(scoped).lower(jnp.ones((4, 4))).compile().as_text()
+            assert "decode_loop/mlp/dot_general" in text
+        finally:
+            jax.config.update(option, before)
+
     def test_directory_is_git_ignored(self):
         with open(os.path.join(REPO, ".gitignore")) as f:
             assert ".jax_cache/" in f.read().split()

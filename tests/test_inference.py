@@ -1527,12 +1527,22 @@ class TestAttnAutoResolution:
         artifact = tmp_path / "attn.json"
         artifact.write_text(json.dumps({
             "platform": platform,
-            "winners": {"decode": "pallas_interpret", "paged_decode": "xla"},
+            "winners": {
+                "decode": "pallas_interpret", "ragged": "xla",
+                # the artifact no longer decides this path (PR 25): what
+                # the engine can observe does, and this is a CPU
+                "paged_decode": "pallas_interpret",
+            },
         }))
         monkeypatch.setenv("CALFKIT_ATTN_PROFILE", str(artifact))
         engine = InferenceEngine(CFG, self._rt())
         assert engine._resolved_attn_impl("decode") == "pallas_interpret"
+        assert engine._resolved_attn_impl("ragged", fallback="decode") == "xla"
         assert engine._resolved_attn_impl("paged_decode") == "xla"
+        # the paged verify program still falls back to the artifact's row
+        assert engine._resolved_attn_impl(
+            "paged_ragged", fallback="paged_decode"
+        ) == "pallas_interpret"
         # no verdict for this path -> the safe default
         assert engine._resolved_attn_impl("prefill") == "xla"
 
@@ -1567,6 +1577,52 @@ class TestAttnAutoResolution:
         monkeypatch.setenv("CALFKIT_ATTN_PROFILE", "/nonexistent/attn.json")
         engine = InferenceEngine(CFG, self._rt())
         assert engine._resolved_attn_impl("decode") == "xla"
+
+    # (what differs from the eligible engine, the answer under "auto")
+    @pytest.mark.parametrize(
+        "platform,over,wide,want",
+        [
+            ("tpu", {}, True, "pallas"),
+            ("cpu", {}, True, "xla"),
+            ("gpu", {}, True, "xla"),
+            ("tpu", {"kv_layout": "dense"}, True, "xla"),
+            ("tpu", {"tp": 2}, True, "xla"),
+            ("tpu", {"dp": 2}, True, "xla"),
+            ("tpu", {}, False, "xla"),  # heads 16 wide: no whole lane tile
+            ("tpu", {"page_size": 4}, True, "xla"),  # under a sublane tile
+            ("tpu", {"attention_impl": "xla"}, True, "xla"),
+            ("tpu", {"attention_impl": "pallas_interpret"}, False,
+             "pallas_interpret"),
+        ],
+    )
+    def test_paged_decode_auto_follows_what_the_engine_observes(
+        self, monkeypatch, platform, over, wide, want
+    ):
+        """``paged_decode`` under "auto": the kernel that reads live pages
+        in place on a TPU, paged, one device, eligible head and page
+        shape; XLA otherwise.  No artifact and no environment decide it."""
+        from dataclasses import replace
+        from types import SimpleNamespace
+
+        monkeypatch.setenv("CALFKIT_ATTN_PROFILE", "/nonexistent/attn.json")
+        config = (
+            replace(CFG, d_model=512, n_heads=4, n_kv_heads=2) if wide else CFG
+        )
+        rt = RuntimeConfig(**{
+            "max_batch_size": 2, "max_seq_len": 128, "prefill_chunk": 16,
+            "kv_layout": "paged", "page_size": 16, **over,
+        })
+        engine = InferenceEngine(config, rt)
+        devices = jax.devices()
+        monkeypatch.setattr(
+            jax, "devices",
+            lambda *a: [SimpleNamespace(platform=platform)] if not a else devices,
+        )
+        assert engine._resolved_attn_impl("paged_decode") == want
+        # the other paths keep the artifact's rule: none here, so XLA
+        if rt.attention_impl == "auto":
+            assert engine._resolved_attn_impl("decode") == "xla"
+            assert engine._resolved_attn_impl("paged_ragged") == "xla"
 
     def test_compute_winners_requires_sweep(self):
         """Pallas must beat XLA on EVERY config of a path (with margin) to

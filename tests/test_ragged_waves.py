@@ -18,6 +18,7 @@ The correctness contract under test:
 """
 
 import asyncio
+from dataclasses import replace
 
 import pytest
 
@@ -56,8 +57,8 @@ async def _gen(engine, prompt, n, **kw):
     return [t async for t in engine.generate(prompt, max_new_tokens=n, **kw)]
 
 
-async def _serve_all(params, runtime, jobs):
-    engine = InferenceEngine(CFG, runtime, params=params)
+async def _serve_all(params, runtime, jobs, config=CFG):
+    engine = InferenceEngine(config, runtime, params=params)
     await engine.start()
     try:
         return await asyncio.gather(
@@ -475,3 +476,113 @@ class TestRaggedAccounting:
 
         codes = [e[2] for e in engine._journal._ring if e is not None]
         assert flightrec.EV_RAGGED_WAVE in codes
+
+
+# ------------------------------------------- paged decode read in place
+# the debug preset's heads are 16 wide: the kernel that reads live pages in
+# place wants whole lane tiles, so these engines get heads of 128 (G = 2)
+WIDE = replace(CFG, name="debug-wide", d_model=256, n_heads=2, n_kv_heads=1)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return M.init_params(WIDE, jax.random.key(1), dtype=jnp.float32)
+
+
+async def _serve_wide(wide_params, jobs, **rt_over):
+    return await _serve_all(
+        wide_params, _rt(kv_layout="paged", ragged_waves=True, **rt_over),
+        jobs, config=WIDE,
+    )
+
+
+SAMPLED = SamplingParams(temperature=0.9, top_k=12)
+IN_PLACE_JOBS = {
+    # more requests than slots: waves ride live dispatches, rows retire
+    # and free their pages while others decode, prompts span page edges
+    "greedy": [
+        (list(range(1 + i, 20 + 3 * i)), 5 + 3 * i, {}) for i in range(7)
+    ],
+    "seeded-sampled": [
+        ([1, 2, 3], 9, dict(sampling=SAMPLED, seed=7)),
+        (list(range(4, 40)), 6, dict(sampling=SAMPLED, seed=11)),
+        ([7, 8], 11, dict(sampling=SamplingParams(temperature=0.6), seed=3)),
+        ([9, 1], 7, {}),  # a greedy row in the sampled batch
+        (list(range(2, 19)), 8, dict(sampling=SAMPLED, seed=5)),
+    ],
+}
+
+
+class TestPagedDecodeInPlace:
+    @pytest.mark.parametrize("jobs", sorted(IN_PLACE_JOBS))
+    async def test_token_parity_with_the_xla_gather(self, wide_params, jobs):
+        """paged + chunked + overlap + ragged: the kernel's streams are the
+        XLA gather path's, token for token, and it was the new body that
+        ran (not the ragged S = 1 row that other head shapes fall to)."""
+        from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
+
+        before = KERNEL_TRACES["paged_decode", "interpreted"]
+        ragged_before = KERNEL_TRACES["ragged_paged", "interpreted"]
+        want, xla = await _serve_wide(
+            wide_params, IN_PLACE_JOBS[jobs], attention_impl="xla"
+        )
+        assert KERNEL_TRACES["paged_decode", "interpreted"] == before
+        got, pal = await _serve_wide(
+            wide_params, IN_PLACE_JOBS[jobs],
+            attention_impl="pallas_interpret",
+        )
+        assert got == want
+        assert all(len(s) == n for s, (_, n, _) in zip(got, IN_PLACE_JOBS[jobs]))
+        # traced at most once a process for these shapes (the entry point
+        # is a jit of its own), and never for the ragged S = 1 row
+        assert KERNEL_TRACES["paged_decode", "interpreted"] > 0
+        assert KERNEL_TRACES["ragged_paged", "interpreted"] == ragged_before
+        assert pal._ragged and pal.stats.unified_dispatches > 0
+        # the selector, not the test: "auto" on this CPU stays on XLA
+        assert xla._resolved_attn_impl("paged_decode") == "xla"
+
+    async def test_page_sums_by_difference(self, wide_params):
+        """``decode_pages_live`` / ``decode_pages_window`` over a scripted
+        run: one row of a known length, dispatch by dispatch."""
+        engine = InferenceEngine(
+            WIDE,
+            _rt(kv_layout="paged", max_batch_size=4, window_buckets=(64, 128)),
+            params=wide_params,
+        )
+        await engine.start()
+        try:
+            zero = engine.stats.counters()
+            assert zero["decode_pages_live"] == zero["decode_pages_window"] == 0
+            # the process's registry is shared by every engine of the run
+            exported = engine.metrics["decode_pages_live"].value
+            # 20 prompt tokens, page 16: 2 pages live from the first step;
+            # the first token comes from the prefill, 12 more from three
+            # 4-step dispatches at lengths 20, 24, 28 (2 pages each) in
+            # the 64-token window (4 pages x 4 rows)
+            out = await _gen(engine, list(range(1, 21)), 13)
+            assert len(out) == 13
+            mid = engine.stats.counters()
+            steps = 4 * (mid["decode_dispatches"] - zero["decode_dispatches"])
+            assert steps >= 12
+            assert mid["decode_pages_live"] == 2 * steps
+            assert mid["decode_pages_window"] == 4 * 4 * steps
+            # a longer row in a wider window, by difference
+            await _gen(engine, list(range(1, 71)), 5)  # 70 tokens: 5 pages
+            end = engine.stats.counters()
+            steps = 4 * (end["decode_dispatches"] - mid["decode_dispatches"])
+            assert end["decode_pages_live"] - mid["decode_pages_live"] == 5 * steps
+            assert (
+                end["decode_pages_window"] - mid["decode_pages_window"]
+                == 4 * 8 * steps
+            )
+            engine._sync_metric_counters()
+            assert (
+                engine.metrics["decode_pages_live"].value - exported
+                == end["decode_pages_live"]
+            )
+        finally:
+            await engine.stop()
+
+    async def test_dense_layout_counts_no_pages(self, params):
+        (_,), engine = await _serve_all(params, _rt(), [([1, 2, 3], 6, {})])
+        assert engine.stats.decode_pages_window == 0

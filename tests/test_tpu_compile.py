@@ -26,6 +26,13 @@ import pytest
 WIDTHS = {"tinyllama-1.1b": (4, 8, 64), "llama-3-8b": (8, 4, 128)}
 B, W, PAGE, N_PAGES, LAYERS = 64, 1024, 64, 257, 2
 WPAGES = W // PAGE
+# the paged decode read of the benchmark's configurations, at their own
+# batch and widest window: (K, G, head_dim, B, W, pages, layers); the kernel
+# that reads live pages in place is what "auto" selects for them on a chip
+DECODE_PAGED_WIDTHS = {
+    "mistral-7b-v0.3": (8, 4, 128, 32, 2048, 513, 32),
+    "internlm2-1.8b": (8, 2, 128, 64, 4096, 897, 24),
+}
 SPEC_S = 5  # verify: k + 1 queries at the default k = 4
 CHUNK_S = 128  # one prefill chunk
 
@@ -138,6 +145,89 @@ def test_entry_point_compiles_for_v5e(
     # the kernel is IN the program: compiled by Mosaic, not interpreted and
     # not replaced by an XLA fallback
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("widths", sorted(DECODE_PAGED_WIDTHS))
+def test_paged_decode_in_place_compiles_for_v5e(
+    widths, one_chip, no_persistent_cache
+):
+    """The merged paged decode read as ``decode_step_ring_paged`` calls it,
+    with the whole pool of the configuration as the kernel's HBM operand."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    K, G, hd, rows, window, pages, layers = DECODE_PAGED_WIDTHS[widths]
+    assert PA.paged_decode_in_place_ok(hd, PAGE, jnp.bfloat16)
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = (shape((layers, pages, K, PAGE, hd), bf16),) * 2
+    ring = (shape((8, rows, K, hd), bf16),) * 2
+    before = PA.KERNEL_TRACES["paged_decode", "compiled"]
+    compiled = jax.jit(
+        lambda *a: PA.merged_paged_decode_attention_pallas(
+            *a, wpages=window // PAGE
+        )
+    ).lower(
+        shape((rows, 1, K * G, hd), bf16), *pool, shape((), i32),
+        shape((rows, window // PAGE), i32), *ring, shape((rows,), i32),
+        shape((), i32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the body of its own, not the ragged kernel's S = 1 row
+    assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
+
+
+def test_kernel_bytes_do_not_depend_on_the_caller(
+    one_chip, no_persistent_cache, monkeypatch
+):
+    """The serialized kernel is hashed into the persistent cache's key, and
+    a jitted entry point is traced once a process, from whichever program
+    calls it first.  Under the compile-cache rule its bytes are the same
+    from a shallow and from a deep, differently scoped call stack."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference.compile_cache import enable_compile_cache
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    K, G, hd, rows, window, pages, layers = DECODE_PAGED_WIDTHS["mistral-7b-v0.3"]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    args = (
+        shape((rows, K, G, hd), bf16),
+        *(shape((layers, pages, K, PAGE, hd), bf16),) * 2, shape((), i32),
+        shape((rows, window // PAGE), i32), shape((rows,), i32),
+    )
+
+    def f(*a):
+        return PA.paged_decode_attention_pallas(*a, wpages=window // PAGE)
+
+    def deep(*a, depth=4):
+        if depth:
+            return deep(*a, depth=depth - 1)
+        with jax.named_scope("another_program"):
+            return f(*a)
+
+    deep.__name__ = deep.__qualname__ = "f"  # one module name for both
+
+    def lowered(fn):
+        PA.paged_decode_attention_pallas.clear_cache()  # trace it from HERE
+        return jax.jit(fn).lower(*args).as_text()
+
+    option = "jax_traceback_in_locations_limit"
+    before = getattr(jax.config, option)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent")  # no update
+    try:
+        enable_compile_cache()  # with ten frames in, the two differ
+        assert lowered(f) == lowered(deep)
+    finally:
+        jax.config.update(option, before)
 
 
 def test_entry_point_list_is_complete():
