@@ -36,9 +36,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Any
 
-from benchmarks import reference, trace_reduce, weights
+from benchmarks import trace_reduce
 from benchmarks.manifest import ROOT, Cell, load_peaks, unregistered
 from benchmarks.metrics import Sample, end_to_end, percentile
+from benchmarks.reference import agreement
 from benchmarks.tokenizer import BenchTokenizer, count_tokens
 from benchmarks.traffic import Req, Traffic
 
@@ -52,6 +53,10 @@ REQUIET_S = 5.0  # ... and after this long, once a window was abandoned for one
 RUN_LIMIT_S, FIRST_RUN_LIMIT_S, COLD_AFTER_S = 360.0, 1200.0, 240.0
 RAMP_CAP_S = 300.0
 TEARDOWN_S = 10.0
+# an open loop's ramp-in comes in blocks of this length, and its window opens
+# where one ends (EDGE_S before, so that the next does not begin): every
+# window then starts in the same state, however many were given up before it
+BLOCK_S, EDGE_S = 10.0, 0.05
 now = time.perf_counter
 
 
@@ -88,32 +93,9 @@ class Compiles:
         return self.stamps[-1][0] if self.stamps else -math.inf
 
 
-def model_and_runtime(config: dict, rehearse: bool):
-    """The program's ModelConfig and RuntimeConfig from a configuration
-    file.  Only what the file states is set; the rest is as defaulted."""
-    from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
-
-    runtime = dict(config["runtime"])
-    sizes = {
-        "vocab_size": config["vocab_size"], "d_model": config["hidden_size"],
-        "n_layers": config["num_hidden_layers"], "n_heads": config["num_attention_heads"],
-        "n_kv_heads": config["num_key_value_heads"], "d_ff": config["intermediate_size"],
-    }
-    if rehearse:  # CPU rehearsal: toy widths, every length divided by scale
-        sizes.update(config["rehearsal"]["model"])
-        runtime.update(config["rehearsal"]["runtime"])
-        runtime["compilation_cache"] = False
-    if "window_buckets" in runtime:
-        runtime["window_buckets"] = tuple(runtime["window_buckets"])
-    model = ModelConfig(
-        name=config["name"], rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]), max_seq_len=runtime["max_seq_len"],
-        dtype=config["precision"]["activations"],
-        tie_embeddings=bool(config["tie_word_embeddings"]), **sizes,
-    )
-    if model.head_dim != (config.get("head_dim") or model.head_dim) and not rehearse:
-        raise ValueError("head_dim of the file differs from hidden_size / heads")
-    return model, RuntimeConfig(**runtime)
+def fold_seed(seed: int) -> int:
+    """``--seed`` can pass 2**31; JAX keys take 32 signed bits."""
+    return int(seed) % (2**31 - 9)
 
 
 def broker():
@@ -154,6 +136,7 @@ class Run:
         self.trace, self.rehearse, self.t_process = trace, rehearse, t_process
         self.chips = cell.chips
         self.config = cell.config
+        self.arch = cell.arch  # the kind of model: description, weights, reference, counts
         scale = cell.config["rehearsal"]["scale"] if rehearse else 1
         self.traffic = Traffic(cell.traffic, cell.params, seed, scale)
         self.compiles = Compiles()
@@ -172,6 +155,7 @@ class Run:
         self.moved = asyncio.Event()  # ... or was abandoned, and none is open yet
         self.abandoned: list[dict] = []  # windows given up for a compile event
         self.profiling = False
+        self.block_start = math.inf  # open loop: when the ramp block now playing began
         self._tasks: set[asyncio.Task] = set()
 
     # ------------------------------------------------------------ building
@@ -181,16 +165,12 @@ class Run:
         from calfkit_tpu.inference.engine import InferenceEngine
         from calfkit_tpu.inference.sharding import make_mesh
 
-        self.model_config, self.runtime = model_and_runtime(self.config, self.rehearse)
+        self.model_config, self.runtime = self.arch.model(self.config, self.rehearse)
         rt = self.runtime
         self.devices = jax.devices()[: self.chips]
         mesh = make_mesh(tp=rt.tp, dp=rt.dp, devices=self.devices)
-        seed = weights.fold_seed(self.seed)
-        params = None
-        if rt.quantization == "int8":
-            params = weights.int8_params(self.model_config, mesh, seed)
-        elif rt.quantization is not None:
-            raise ValueError(f"no initialiser for quantization {rt.quantization!r}")
+        seed = fold_seed(self.seed)
+        params = self.arch.params(self.model_config, rt, mesh, seed)  # None: the engine's own
         self.engine = InferenceEngine(self.model_config, rt, params=params, mesh=mesh, seed=seed)
         jax.block_until_ready(self.engine.params)
         self.tokenizer = BenchTokenizer(self.model_config.vocab_size)
@@ -214,7 +194,7 @@ class Run:
         if any(len(o) != spec["new_tokens"] for o in outputs):
             return {"ok": False, "why": "short generation"}
         return await asyncio.to_thread(
-            reference.agreement, self.engine.params, self.model_config, prompts,
+            agreement, self.arch.forward_top2, self.engine.params, self.model_config, prompts,
             outputs, float(spec["margin"]), int(spec["min_compared"]),
         )
 
@@ -260,10 +240,12 @@ class Run:
         waiting, and the rows of the wave before, 9 tokens each, are always
         about to retire).  Multi-chunk buckets meet both a fresh and a
         carried prefill scratch on the way; a closed loop with as many
-        callers as slots also gets ``warm_starved``.  Programs only an IDLE engine
-        runs (a chunk with no decode row beside it, the first dispatch after
-        idleness), and whatever else this script misses, are left to the
-        ramp-in, which lasts until no program has compiled for ``QUIET_S``."""
+        callers as slots also gets ``warm_starved``; an open loop, whose engine
+        falls idle between arrivals, gets every bucket and wave width once
+        more on an IDLE engine (a chunk with no decode row beside it is a
+        program of its own).  Whatever else this script misses (the first
+        dispatch after idleness) is left to the ramp-in, which lasts until
+        no program has compiled for ``QUIET_S``."""
         rt, tr = self.runtime, self.traffic
         chunk, steps = rt.prefill_chunk, rt.decode_steps_per_dispatch
         lo, hi = tr.prompt_range()
@@ -354,6 +336,11 @@ class Run:
                 for task in [*anchors, *running]:
                     with contextlib.suppress(asyncio.CancelledError, Exception):
                         await task
+        if tr.loop == "open":  # the engine idle, each time: the wave's chunks run alone
+            for rows in waves:
+                for b in buckets:
+                    await asyncio.gather(*await admitted([plain(b) for _ in range(rows)], 2))
+                    dispatched += rows
         return {"buckets": buckets, "waves": waves, "windows": windows,
                 "requests": dispatched}
 
@@ -432,6 +419,13 @@ class Run:
         task.add_done_callback(self._tasks.discard)
         return task
 
+    def _block_edge(self, t: float) -> float:
+        """Open loop: the first end of a ramp block at or after ``t``."""
+        if self.traffic.loop != "open" or self.block_start == math.inf:
+            return t
+        blocks = max(1, math.ceil((t + EDGE_S - self.block_start) / BLOCK_S))
+        return self.block_start + blocks * BLOCK_S - EDGE_S
+
     def _sample(self, req: Req, due: float) -> Sample:
         sample = Sample(due=due, budget=req.out_tokens, prompt_tokens=req.prompt_tokens)
         self.everything.append(sample)  # the window's are picked by `due` at the end
@@ -446,19 +440,19 @@ class Run:
                 await asyncio.wait_for(event.wait(), max(0.0, t - now()))
             return event.is_set()
 
-        block, block_s = 0, 10.0
+        block, attempt = 0, 0
         while True:
             while not self.opened.is_set():
-                start = now()
-                for req in self.traffic.ramp_block(block, block_s):
+                start = self.block_start = now()
+                for req in self.traffic.ramp_block(block, BLOCK_S):
                     if await flips(self.opened, start + req.due_s):
                         break
                     self._spawn(self.issue(req, now(), self._sample(req, now())))
-                await flips(self.opened, start + block_s)
+                await flips(self.opened, start + BLOCK_S)
                 block += 1
             t0 = self.t0
             self.lateness.clear()
-            for req in self.traffic.open_schedule(self.seconds):
+            for req in self.traffic.open_schedule(self.seconds, attempt=attempt):
                 due = t0 + req.due_s
                 if await flips(self.moved, due):
                     break
@@ -466,6 +460,7 @@ class Run:
                 self._spawn(self.issue(req, due, self._sample(req, due)))
             else:
                 return
+            attempt += 1
 
     async def caller(self, index: int) -> None:
         for req in self.traffic.caller_stream(index):
@@ -490,17 +485,11 @@ class Run:
             await asyncio.sleep(0.1)
 
     def snapshot(self) -> dict:
-        stats = self.engine.stats.counters()
-        hist = self.engine.latency["queue_wait_ms"]
-        return {"stats": stats, "queue_counts": list(hist._counts),
-                "queue_buckets": list(hist.buckets)}
+        return self.engine.stats.counters()
 
     @staticmethod
     def delta(a: dict, b: dict) -> dict:
-        out = {k: b["stats"][k] - a["stats"][k] for k in b["stats"] if k != "occupancy_hist"}
-        out["queue_counts"] = [y - x for x, y in zip(a["queue_counts"], b["queue_counts"])]
-        out["queue_buckets"] = b["queue_buckets"]
-        return out
+        return {k: b[k] - a[k] for k in b if k != "occupancy_hist"}
 
     async def profile(self) -> None:
         """Trace a few seconds in the middle of the window."""
@@ -526,11 +515,6 @@ class Run:
             path = trace_reduce.find_xplane(trace_dir)
             events = await asyncio.to_thread(trace_reduce.load_events, path)
             self.trace_reduced = trace_reduce.reduce(events, t_b - t_a)
-            out_dir = os.path.join(ROOT, "chiprun_out")
-            if os.path.isdir(out_dir):  # the builder's runs: a look by hand
-                with open(os.path.join(out_dir, f"trace_{self.cell.name}.json"), "w") as f:
-                    json.dump({"describe": trace_reduce.describe(path),
-                               "events_head": events[:4000]}, f)
         finally:
             import shutil
 
@@ -611,8 +595,9 @@ class Run:
                    [asyncio.ensure_future(self.caller(i)) for i in range(tr.callers())])
         while True:
             while now() < open_by:
-                quiet_at = (self.compiles.last() + REQUIET_S if self.abandoned
-                            else max(ramp_from, self.compiles.last()) + QUIET_S)
+                quiet_at = self._block_edge(
+                    self.compiles.last() + REQUIET_S if self.abandoned
+                    else max(ramp_from, self.compiles.last()) + QUIET_S)
                 if now() >= quiet_at:
                     break
                 await asyncio.sleep(min(0.25, quiet_at - now()))
@@ -689,18 +674,32 @@ class Run:
         failed = [s for s in samples if not s.ok]
         in_window, in_window_s = self.compiles.between(self.t0, self.t_end)
         finished = [s for s in samples if s.done is not None and s.error is None]
-        faults = [why for why, bad in (
-            ("the agreement check failed", not agree.get("ok")),
-            (f"{in_window} compile events in the window", in_window != 0),
-            ("a finished request returned other than its budget of tokens",
-             any(s.tokens != s.budget for s in finished)),
-            ("a finished request's token events do not add up to its final text",
-             any(not s.text_ok for s in finished)),
-            ("no request was due in the window", not samples),
-        ) if bad]
+        short = sum(1 for s in finished if s.tokens != s.budget)
+        garbled = sum(1 for s in finished if not s.text_ok)
+        # every number compared, beside its limit: (what, value, rule, limit)
+        decided = agree.get("compared", 0)
+        compared = [
+            ("agreement: checks cut short (a short generation, a logit not finite)",
+             int("why" in agree or not agree.get("finite", True)), "==", 0),
+            ("agreement: positions decided by the margin", decided, ">=",
+             agree.get("min_compared", 1)),
+            ("agreement: decided positions where the served token is not the reference's",
+             decided - agree.get("equal", 0), "==", 0),
+            ("compile events in the window", in_window, "==", 0),
+            ("finished requests that returned other than their budget of tokens", short, "==", 0),
+            ("finished requests whose token events do not add up to their final text", garbled,
+             "==", 0),
+            ("requests due in the window", len(samples), ">=", 1),
+        ]
+        faults = []
+        for what, value, rule, limit in compared:  # the run's last lines on stderr
+            passes = value == limit if rule == "==" else value >= limit
+            row = f"{what}: {value} (limit {rule} {limit})"
+            print(f"benchmarks/run.py: {'ok  ' if passes else 'FAIL'} {row}", file=sys.stderr)
+            if not passes:
+                faults.append(row)
         correct = not faults
-        if faults:  # the result line says only `false`: the reason goes to stderr
-            print(f"benchmarks/run.py: incorrect: {'; '.join(faults)}", file=sys.stderr, flush=True)
+        print(f"benchmarks/run.py: correct={str(correct).lower()}", file=sys.stderr, flush=True)
         prompts = [s.realised_prompt_tokens or s.prompt_tokens for s in samples]
         everything = end_to_end(samples, self.t0, self.seconds, self.chips, setup_s,
                                 self.everything)

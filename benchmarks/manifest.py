@@ -9,6 +9,7 @@ gives it:
     benchmarks/cells/<cell>.json             the cell's fixed rate or callers
     benchmarks/layer_metrics/<metric>.json   unit, layer, moves, reader
     benchmarks/readers/<reader>.py           read(ctx) -> float | None
+    benchmarks/architectures/<name>.py       the kind of model a configuration is
 
 The harness knows no cell, model or metric by name.
 """
@@ -28,6 +29,11 @@ ROOT = os.path.dirname(HERE)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# what an architecture file gives (benchmarks/README.md says what each takes
+# and returns); a configuration that names none is of the first kind there was
+ARCHITECTURE = ("model", "params", "forward_top2", "weight_bytes", "state_bytes_per_token",
+                "decode_step", "prefill_chunk")
+DEFAULT_ARCHITECTURE = "dense-gqa"
 
 
 class ManifestError(ValueError):
@@ -79,6 +85,7 @@ class Cell:
     traffic_name: str
     traffic: dict
     params: dict  # benchmarks/cells/<cell>.json: rate_rps or callers
+    arch: Any = None  # the module benchmarks/architectures/<config's architecture>.py
     end_to_end: tuple[Metric, ...] = field(default_factory=tuple)
     per_layer: tuple[Metric, ...] = field(default_factory=tuple)
 
@@ -112,19 +119,35 @@ def load_manifest(root: str = ROOT) -> dict:
     return manifest
 
 
-def load_reader(name: str, here: str = HERE) -> Callable[[Any], Any]:
-    """The reader module benchmarks/readers/<name>.py, by file (a later PR
-    adds a reader by adding a file; nothing imports it by name)."""
-    check_name(name, "reader")
-    path = os.path.join(here, "readers", f"{name}.py")
+def _load_module(kind: str, name: str, here: str) -> Any:
+    """The module benchmarks/<kind>s/<name>.py, by file (a later PR adds
+    one by adding a file; nothing imports it by name)."""
+    check_name(name, kind)
+    path = os.path.join(here, f"{kind}s", f"{name}.py")
     if not os.path.isfile(path):
-        raise ManifestError(f"unknown reader {name!r}: no {os.path.relpath(path, ROOT)}")
-    spec = importlib.util.spec_from_file_location(f"benchmarks_reader_{name}", path)
+        raise ManifestError(f"unknown {kind} {name!r}: no {os.path.relpath(path, ROOT)}")
+    spec = importlib.util.spec_from_file_location(f"benchmarks_{kind}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
+    return module
+
+
+def load_reader(name: str, here: str = HERE) -> Callable[[Any], Any]:
+    module = _load_module("reader", name, here)
     if not callable(getattr(module, "read", None)):
         raise ManifestError(f"reader {name!r} has no read(ctx)")
     return module.read
+
+
+def load_architecture(name: str, here: str = HERE) -> Any:
+    """The architecture a configuration names: the program's model
+    description, the seeded weights, the plain reference and the
+    operation counts of one kind of model, as one module."""
+    module = _load_module("architecture", name, here)
+    missing = [f for f in ARCHITECTURE if not callable(getattr(module, f, None))]
+    if missing:
+        raise ManifestError(f"architecture {name!r} lacks {', '.join(missing)}")
+    return module
 
 
 def _in_cell(entry: dict, cell_name: str) -> bool:
@@ -148,6 +171,7 @@ def resolve_cell(manifest: dict, cell_name: str, root: str = ROOT) -> Cell:
     traffic_name = check_name(row.get("traffic"), "traffic")
     traffic = _load_json(os.path.join(here, "traffic", f"{traffic_name}.json"), "traffic")
     params = _load_json(os.path.join(here, "cells", f"{cell_name}.json"), "cell")
+    arch = load_architecture(config.get("architecture", DEFAULT_ARCHITECTURE), here)
 
     e2e = tuple(
         Metric(m["name"], m["unit"], m["better"], m["source"])
@@ -178,7 +202,7 @@ def resolve_cell(manifest: dict, cell_name: str, root: str = ROOT) -> Cell:
             load_reader(spec.get("reader", m["name"]), here),
         ))
     return Cell(cell_name, row["chips"], row["config"], config, traffic_name,
-                traffic, params, e2e, tuple(layer))
+                traffic, params, arch, e2e, tuple(layer))
 
 
 def unregistered(cell: Cell, root: str = ROOT) -> list[Metric]:

@@ -6,9 +6,14 @@ modules: a dispatch is ``jit_decode`` (decode steps only) or
 wave being admitted riding along); the others carry prefill alone.  Until
 the program names its scopes the trace cannot split a ragged program into
 its decode and its chunk part, so the step time below holds both.
+
+What work a step is comes from the cell's architecture (``ctx.arch``:
+``decode_step``, ``prefill_chunk``, ``weight_bytes``); the roofline that
+turns work into least seconds is shared.
 """
 
-from benchmarks import opcount, trace_reduce
+from benchmarks import trace_reduce
+from benchmarks.opcount import least_seconds as roofline
 
 DISPATCH_MODULES = [r"^jit_decode$", r"^jit_ragged_"]
 PREFILL_MODULES = [r"^jit_ragged_", r"^jit_chunk_step$", r"^jit_finalize$", r"^jit_seed$",
@@ -56,13 +61,13 @@ def least_seconds(ctx):
     rows = c["decode_tokens"] / steps
     prompt = sum(s.prompt_tokens for s in seen) / len(seen)
     context = prompt + sum(s.tokens for s in seen) / len(seen) / 2.0
-    step = opcount.decode_step(ctx.config, rows, context, ctx.chips)
-    total, _ = opcount.least_seconds(step, ctx.peaks)
+    step = ctx.arch.decode_step(ctx.config, rows, context, ctx.chips)
+    total, _ = roofline(step, ctx.peaks)
     total *= steps
     tokens = c.get("prefill_tokens", 0)
     if tokens > 0:  # as tokens / prompt prompts of the mean length, weights once a chunk dispatch
-        work = opcount.prefill_chunk(ctx.config, tokens / prompt, prompt, 0, ctx.chips)
-        work["bytes"] += opcount.weight_bytes(ctx.config) / ctx.chips * max(
+        work = ctx.arch.prefill_chunk(ctx.config, tokens / prompt, prompt, 0, ctx.chips)
+        work["bytes"] += ctx.arch.weight_bytes(ctx.config) / ctx.chips * max(
             c.get("unified_dispatches", 1) - 1, 0)
-        total += opcount.least_seconds(work, ctx.peaks)[0]
+        total += roofline(work, ctx.peaks)[0]
     return total

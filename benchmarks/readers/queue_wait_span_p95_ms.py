@@ -1,6 +1,7 @@
 """Admission: submit-to-slot-grant wait of the requests due in the window,
 from the ``engine.queue`` spans the engine measures where the wait
-happens.  Exact, beside the bucketed ``queue_wait_p95_ms``."""
+happens, exact (the bucketed reading of the engine's histogram, 18-23% high,
+went with PR 26)."""
 
 from benchmarks.metrics import percentile
 

@@ -1,7 +1,7 @@
 """The four readers of the engine's phase clock, admission ledger and
 ``engine.queue`` span over a made-up run: what each computes, that each
 returns nothing (and does not raise) against a program that keeps no
-such counter or span, and that the manifest carries the two registered."""
+such counter or span, and that the manifest carries each with the metric it moves."""
 
 from types import SimpleNamespace
 
@@ -68,16 +68,20 @@ def test_no_dispatch_in_the_interval_reads_as_nothing():
     assert READ["host_work_per_dispatch_ms"](run(dict(COUNTERS, decode_dispatches=0))) is None
 
 
-def test_the_manifest_carries_the_two_registered_and_logs_the_other_two():
+def test_the_manifest_carries_all_four_each_moving_what_its_file_says():
+    """Registered where the cell reports the end-to-end metric it moves
+    (PR 26 made delivered tokens/s and first-token time such metrics),
+    logged ``recorded-only`` where it does not."""
     man = M.load_manifest(M.ROOT)
-    assert [m["name"] for m in man["per_layer"]][-2:] == list(NAMES[:2])
     cell = M.resolve_cell(man, CELL, M.ROOT)
     registered = {m.name: m for m in cell.per_layer}
-    for name in NAMES[:2]:
-        assert registered[name].moves == "tpot_p95_ms"
-        assert registered[name].layer == "admission and batching"
-        assert registered[name].source == "program_counter"
-    logged = {m.name: m for m in M.unregistered(cell, M.ROOT)}
-    assert logged["empty_slot_queued_pct"].moves == "out_tok_s_per_chip"
-    assert logged["queue_wait_span_p95_ms"].moves == "ttft_p95_ms"
-    assert logged["queue_wait_span_p95_ms"].source == "program_span"
+    known = {**{m.name: m for m in M.unregistered(cell, M.ROOT)}, **registered}
+    reported = {m.name for m in cell.end_to_end}
+    moves = dict(zip(NAMES, ("tpot_p95_ms", "tpot_p95_ms", "out_tok_s_per_chip", "ttft_p95_ms")))
+    for name in NAMES:
+        assert known[name].moves == moves[name]
+        assert known[name].layer == "admission and batching"
+        assert name not in registered or moves[name] in reported
+    assert {"engine_starved_pct", "host_work_per_dispatch_ms"} <= set(registered)
+    assert known["queue_wait_span_p95_ms"].source == "program_span"
+    assert known["empty_slot_queued_pct"].source == "program_counter"

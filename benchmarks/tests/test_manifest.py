@@ -182,10 +182,10 @@ def test_metrics_with_a_file_and_no_entry_are_recorded_only():
         assert all(callable(m.read) and m.moves for m in others)
 
 
-def test_agreement_needs_enough_decided_positions(monkeypatch):
+def test_agreement_needs_enough_decided_positions():
     import numpy as np
 
-    from benchmarks import reference
+    from benchmarks.reference import agreement
 
     prompts, outputs = [[5, 6, 7]], [[1, 2, 3, 4]]
     def fake(gaps):
@@ -196,7 +196,117 @@ def test_agreement_needs_enough_decided_positions(monkeypatch):
             gap[0, 2:6] = gaps
             return arg, gap
         return forward
-    monkeypatch.setattr(reference, "forward_top2", fake([1.0, 1.0, 1.0, 0.1]))
-    got = reference.agreement(None, None, prompts, outputs, 0.25, min_compared=3)
+    forward = fake([1.0, 1.0, 1.0, 0.1])
+    got = agreement(forward, None, None, prompts, outputs, 0.25, min_compared=3)
     assert got["compared"] == 3 and got["ok"]
-    assert not reference.agreement(None, None, prompts, outputs, 0.25, min_compared=4)["ok"]
+    assert not agreement(forward, None, None, prompts, outputs, 0.25, min_compared=4)["ok"]
+
+
+TOY = '''"""Architecture toy: logits = embed[token] @ head, no mixing of positions."""
+import numpy as np
+
+
+def model(config, rehearse):
+    return dict(vocab=config["vocab_size"], width=config["hidden_size"]), None
+
+
+def params(model, runtime, mesh, seed):
+    rng = np.random.default_rng(seed)
+    return {"embed": rng.standard_normal((model["vocab"], model["width"])),
+            "head": rng.standard_normal((model["width"], model["vocab"]))}
+
+
+def forward_top2(params, model, tokens, lens):
+    logits = params["embed"][tokens] @ params["head"]
+    order = np.sort(logits, axis=-1)
+    return logits.argmax(-1), order[..., -1] - order[..., -2]
+
+
+def weight_bytes(config):
+    return 4.0 * config["hidden_size"] * config["vocab_size"]
+
+
+def state_bytes_per_token(config):
+    return 0.0
+
+
+def decode_step(config, rows, mean_context, chips=1):
+    return {"flops": 2.0 * rows * weight_bytes(config) / 4 / chips,
+            "bytes": weight_bytes(config) / chips}
+
+
+def prefill_chunk(config, rows, chunk, offset, chips=1):
+    return decode_step(config, rows * chunk, 0, chips)
+'''
+
+
+def test_an_architecture_is_added_by_new_files_alone(copy):
+    """An architecture file, a configuration that names it and a cell, in
+    a temporary copy: it resolves, the shared margin rule runs through its
+    reference, the shared roofline through its counts; no file that was
+    there is touched, and a file that lacks a function is refused."""
+    import numpy as np
+
+    from benchmarks.opcount import least_seconds
+    from benchmarks.reference import agreement
+
+    b = copy / "benchmarks"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    (b / "architectures" / "toy.py").write_text(TOY)
+    (b / "configs" / "toy-two-matrix.json").write_text(json.dumps({
+        "name": "toy-two-matrix", "architecture": "toy", "vocab_size": 50, "hidden_size": 8}))
+    (b / "cells" / "toy-two-matrix.batch-closed.json").write_text(json.dumps({"callers": 2}))
+
+    def edit(man):
+        man["configs"].append({"name": "toy-two-matrix", "source": "https://example.org/toy",
+                               "file": "benchmarks/configs/toy-two-matrix.json",
+                               "reduced": [], "why": "a test"})
+        man["workloads"].append({"name": "toy-two-matrix.batch-closed",
+                                 "config": "toy-two-matrix", "traffic": "batch-closed",
+                                 "chips": 1, "why": "a test"})
+    man = _rewrite(copy, edit)
+    cell = M.resolve_cell(man, "toy-two-matrix.batch-closed", str(copy))
+    arch = cell.arch
+    assert arch.__file__ == str(b / "architectures" / "toy.py")
+    model, _ = arch.model(cell.config, False)
+    params = arch.params(model, None, None, 7)
+    prompts = [[3, 4, 5], [6, 7]]
+    outputs = []  # "the engine": greedy through the same two matrices
+    for p in prompts:
+        arg, _ = arch.forward_top2(params, model, np.asarray([[p[-1], 0, 0]]), None)
+        first = int(arg[0, 0])
+        second = int(arch.forward_top2(params, model, np.asarray([[first]]), None)[0][0, 0])
+        outputs.append([first, second])
+    got = agreement(arch.forward_top2, params, model, prompts, outputs, 0.0, min_compared=4)
+    assert got["ok"] and got["compared"] == got["equal"] == 4
+    outputs[1][1] = (outputs[1][1] + 1) % 50  # one served token altered: not the reference's
+    assert not agreement(arch.forward_top2, params, model, prompts, outputs, 0.0, 4)["ok"]
+    seconds, bound = least_seconds(arch.decode_step(cell.config, 4, 100), M.load_peaks("TPU v5 lite"))
+    assert bound == "bytes" and seconds == pytest.approx(1600 / 819e9)
+    assert all(p.read_bytes() == data for p, data in before.items())  # nothing edited
+    # the cells that were there still resolve, through the architecture no file of theirs names
+    old = M.resolve_cell(man, man["workloads"][0]["name"], str(copy))
+    assert old.arch.__file__.endswith("dense-gqa.py") and "architecture" not in old.config
+
+    (b / "architectures" / "toy.py").write_text(TOY.replace("def prefill_chunk", "def prefil"))
+    with pytest.raises(M.ManifestError, match="architecture 'toy' lacks prefill_chunk"):
+        M.resolve_cell(man, "toy-two-matrix.batch-closed", str(copy))
+    (b / "architectures" / "toy.py").unlink()
+    with pytest.raises(M.ManifestError, match="unknown architecture 'toy'"):
+        M.resolve_cell(man, "toy-two-matrix.batch-closed", str(copy))
+
+
+def test_every_architecture_loads_and_the_yardstick_has_not_moved():
+    """What tier-1 cannot hold while only the benchmark's own directories
+    may change (PERF.md, Open questions): every architecture file loads
+    whole, and the bytes a Mistral step streams (the int8 matrices and
+    head: what dispatch_roofline has divided by since PR 23) stand."""
+    here = os.path.join(ROOT, "benchmarks")
+    names = [f[:-3] for f in os.listdir(os.path.join(here, "architectures")) if f.endswith(".py")]
+    assert M.DEFAULT_ARCHITECTURE in names
+    for name in names:
+        M.load_architecture(name)
+    man = M.load_manifest(ROOT)
+    cell = M.resolve_cell(man, "mistral-7b-v0.3-int8.batch-closed", ROOT)
+    assert cell.arch.weight_bytes(cell.config) == 7_113_539_584
+    assert cell.arch.state_bytes_per_token(cell.config) == 131_072

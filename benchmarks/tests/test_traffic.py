@@ -134,3 +134,17 @@ def test_ramp_blocks_are_the_same_process_and_deterministic():
     assert abs(sum(len(b) for b in blocks) / 30.0 - 8.0) < 2.5
     again = T.Traffic(chat, {"rate_rps": 8.0}, 9).ramp_block(1)
     assert [(r.due_s, r.prompt) for r in again] == [(r.due_s, r.prompt) for r in blocks[1]]
+
+
+def test_a_window_opened_anew_sends_the_same_sizes_in_other_text():
+    """After a window is given up the schedule starts over: the same sizes
+    at the same instants, and no prompt of the first attempt again (the
+    prefix cache would serve it from the pages the first attempt left)."""
+    spec = mix("chat-open")
+    first = T.Traffic(spec, {"rate_rps": 4.0}, 11).open_schedule(30.0)
+    again = T.Traffic(spec, {"rate_rps": 4.0}, 11).open_schedule(30.0, attempt=1)
+    assert [(r.due_s, r.prompt_tokens, r.out_tokens) for r in first] == \
+           [(r.due_s, r.prompt_tokens, r.out_tokens) for r in again]
+    assert not {r.prompt for r in first} & {r.prompt for r in again}
+    assert [r.prompt for r in first] == \
+           [r.prompt for r in T.Traffic(spec, {"rate_rps": 4.0}, 11).open_schedule(30.0)]

@@ -206,12 +206,15 @@ class Traffic:
         rng.shuffle(outs)
         return list(zip(prompts, outs))
 
-    def open_schedule(self, seconds: float, start_s: float = 0.0) -> list[Req]:
-        """The requests due in [start_s, seconds), in order of due time."""
+    def open_schedule(self, seconds: float, start_s: float = 0.0, attempt: int = 0) -> list[Req]:
+        """The requests due in [start_s, seconds), in order of due time.
+        ``attempt`` counts the windows opened before this one and given up:
+        the same sizes at the same instants, in OTHER text, or the prefix
+        cache would serve the second window from the first one's pages."""
         times = arrival_times(self.spec["arrivals"], float(self.params["rate_rps"]),
                               max(seconds, 0.0))
         sizes = self._pairs(len(times), "open")
-        rng = random.Random(f"{self.seed}/text")
+        rng = random.Random(f"{self.seed}/text/{attempt}" if attempt else f"{self.seed}/text")
         return [self.single(rng, p, o, due_s=t)
                 for t, (p, o) in zip(times, sizes) if t >= start_s]
 
