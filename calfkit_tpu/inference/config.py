@@ -9,9 +9,27 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 
 
+ATTENTION, MAMBA = "attention", "mamba"
+
+
+class UnsupportedWithRecurrentLayers(ValueError):
+    """A runtime option that a model with recurrent (Mamba-2) layers cannot
+    be served under yet.  Raised when the engine is built, never later:
+    there is no silent fallback to a path that would drop the state."""
+
+
 @dataclass(frozen=True)
 class ModelConfig:
-    """A Llama-family decoder architecture description."""
+    """A decoder architecture description.
+
+    Without ``layer_types`` it is the Llama-family decoder it always was
+    (RMSNorm, rotary GQA attention, SwiGLU) and builds the same programs.
+    With ``layer_types`` (one of ``"attention"`` / ``"mamba"`` per layer)
+    it is a hybrid stack in the GraniteMoeHybrid sense: every layer keeps
+    the SwiGLU MLP, the mixer before it is attention or Mamba-2, and the
+    ``mamba_*`` sizes, the position rule, the attention scale and the four
+    multipliers below apply.
+    """
 
     name: str = "debug"
     vocab_size: int = 32000
@@ -25,26 +43,135 @@ class ModelConfig:
     max_seq_len: int = 2048
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
+    # ---- hybrid stacks (all defaults = the dense decoder, unchanged) ----
+    # one entry per layer, "attention" or "mamba"; () = every layer attends
+    layer_types: tuple[str, ...] = ()
+    # Mamba-2 mixer sizes (HF names: mamba_n_heads, mamba_d_head,
+    # mamba_d_state, mamba_n_groups, mamba_d_conv, mamba_chunk_size);
+    # d_inner = mamba_n_heads x mamba_d_head
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256  # the SSD block of the chunked scan
+    # the SSM state's type: float32 because the recurrence is carried over
+    # the whole sequence and a bfloat16 state rounds at every step
+    state_dtype: str = "float32"
+    position_embedding: str = "rope"  # "rope" | "none"
+    # attention scores are scaled by this; None = 1/sqrt(head_dim)
+    attention_multiplier: float | None = None
+    embedding_multiplier: float = 1.0  # x = embed[tokens] * this
+    residual_multiplier: float = 1.0  # x = x + this * block(norm(x))
+    logits_scaling: float = 1.0  # logits = head(x) / this
+
+    def __post_init__(self) -> None:
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"n_layers is {self.n_layers}"
+                )
+            unknown = set(self.layer_types) - {ATTENTION, MAMBA}
+            if unknown:
+                raise ValueError(f"unknown layer types {sorted(unknown)}")
+            if MAMBA not in self.layer_types:
+                raise ValueError(
+                    "layer_types without a mamba layer is the dense decoder: "
+                    "leave it empty"
+                )
+            if not (self.mamba_n_heads and self.mamba_d_head and self.mamba_d_state):
+                raise ValueError("mamba layers need mamba_n_heads/d_head/d_state")
+            if self.mamba_n_heads % self.mamba_n_groups:
+                raise ValueError("mamba_n_groups must divide mamba_n_heads")
+        elif (
+            self.position_embedding != "rope"
+            or self.attention_multiplier is not None
+            or (self.embedding_multiplier, self.residual_multiplier,
+                self.logits_scaling) != (1.0, 1.0, 1.0)
+        ):
+            raise ValueError(
+                "position_embedding, attention_multiplier and the three "
+                "multipliers belong to a hybrid stack (layer_types)"
+            )
+        if self.position_embedding not in ("rope", "none"):
+            raise ValueError(
+                f"unknown position_embedding {self.position_embedding!r}"
+            )
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     @property
+    def recurrent(self) -> bool:
+        """Does a sequence carry state besides K and V (Mamba-2 layers)?"""
+        return MAMBA in self.layer_types
+
+    @property
+    def n_mamba_layers(self) -> int:
+        return sum(t == MAMBA for t in self.layer_types)
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that keep K and V: all of them unless ``layer_types``
+        says otherwise."""
+        return self.n_layers - self.n_mamba_layers
+
+    @property
+    def layer_period(self) -> tuple[str, ...]:
+        """The shortest pattern ``layer_types`` repeats: the stack scans
+        over its repeats, so compile time follows the period, not the depth."""
+        types = self.layer_types
+        for p in range(1, len(types) + 1):
+            if len(types) % p == 0 and types == types[:p] * (len(types) // p):
+                return types[:p]
+        return types
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def mamba_d_in_proj(self) -> int:
+        """Width of the fused input projection: z | xBC | dt."""
+        return self.mamba_d_inner + self.mamba_conv_dim + self.mamba_n_heads
+
+    def recurrent_state_bytes(self, rows: int) -> int:
+        """Bytes ``rows`` sequences' SSM and conv state take on the device."""
+        itemsize = {"float32": 4, "bfloat16": 2}
+        ssm = self.mamba_n_heads * self.mamba_d_head * self.mamba_d_state
+        conv = self.mamba_conv_dim * (self.mamba_d_conv - 1)
+        return self.n_mamba_layers * rows * (
+            ssm * itemsize[self.state_dtype] + conv * itemsize.get(self.dtype, 2)
+        )
+
+    @property
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning)."""
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        per_layer = (
-            # attention: q, k, v, o
+        attention = (
+            # q, k, v, o
             self.d_model * self.n_heads * self.head_dim
             + 2 * self.d_model * self.n_kv_heads * self.head_dim
             + self.n_heads * self.head_dim * self.d_model
-            # mlp: gate, up, down
-            + 3 * self.d_model * self.d_ff
-            # norms
-            + 2 * self.d_model
         )
-        return embed + self.n_layers * per_layer + self.d_model
+        # mlp: gate, up, down; the two norms
+        mlp = 3 * self.d_model * self.d_ff + 2 * self.d_model
+        total = embed + self.n_layers * mlp + self.n_kv_layers * attention + self.d_model
+        if self.recurrent:
+            total += self.n_mamba_layers * (
+                self.d_model * self.mamba_d_in_proj
+                + self.mamba_d_inner * self.d_model
+                + self.mamba_conv_dim * (self.mamba_d_conv + 1)
+                + 3 * self.mamba_n_heads
+                + self.mamba_d_inner
+            )
+        return total
 
 
 @dataclass(frozen=True)
@@ -285,6 +412,33 @@ PRESETS: dict[str, ModelConfig] = {
         d_ff=14336,
         rope_theta=500000.0,
         max_seq_len=8192,
+    ),
+    # IBM Granite 4.0-H Micro (HF: ibm-granite/granite-4.0-h-micro,
+    # GraniteMoeHybrid with no routed experts): 36 Mamba-2 layers and 4
+    # attention layers without rotary embedding, the shared MLP in each
+    "granite-4.0-h-micro": ModelConfig(
+        name="granite-4.0-h-micro",
+        vocab_size=100352,
+        d_model=2048,
+        n_layers=40,
+        n_heads=32,
+        n_kv_heads=8,
+        d_ff=8192,
+        norm_eps=1e-5,
+        max_seq_len=131072,
+        tie_embeddings=True,
+        layer_types=((MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4) * 4,
+        mamba_n_heads=64,
+        mamba_d_head=64,
+        mamba_d_state=128,
+        mamba_n_groups=1,
+        mamba_d_conv=4,
+        mamba_chunk_size=256,
+        position_embedding="none",
+        attention_multiplier=0.015625,
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_scaling=8.0,
     ),
 }
 

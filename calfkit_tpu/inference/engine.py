@@ -50,7 +50,12 @@ from calfkit_tpu.exceptions import (
 )
 from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference.compile_cache import enable_compile_cache
-from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
+from calfkit_tpu.inference.config import (
+    ModelConfig,
+    RuntimeConfig,
+    UnsupportedWithRecurrentLayers,
+)
+from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.observability import capacity, flightrec
 from calfkit_tpu.observability.trace import TRACER, Span, TraceContext
 from calfkit_tpu.observability.metrics import (
@@ -104,7 +109,10 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # the EngineStats fields folded into /metrics counters once a dispatch
 # counters that go to /metrics and ``counters()`` only, never on the
 # heartbeat advert's window
-_LOCAL_FIELDS = ("decode_pages_live", "decode_pages_window", *_SECONDS_FIELDS)
+_LOCAL_FIELDS = (
+    "decode_pages_live", "decode_pages_window",
+    "state_rows_landed", "prefix_reuse_declined_recurrent", *_SECONDS_FIELDS,
+)
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
     "overlap_wasted_tokens", *_LOCAL_FIELDS,
@@ -208,6 +216,21 @@ def _engine_metrics(
             "paged decode steps: rows in the program x the window bucket's "
             "pages (what the XLA window gather copies)",
         ),
+        state_rows_landed=reg.counter(
+            "calfkit_engine_state_rows_landed_total",
+            "slots whose recurrent (SSM and conv) state an admission wave "
+            "overwrote at landing",
+        ),
+        prefix_reuse_declined_recurrent=reg.counter(
+            "calfkit_engine_prefix_reuse_declined_recurrent_total",
+            "requests whose cached prefix was not reused because the model "
+            "carries recurrent state (pages hold no state at their boundary)",
+        ),
+        recurrent_state_bytes=reg.gauge(
+            "calfkit_engine_recurrent_state_bytes",
+            "device bytes reserved for the slots' recurrent state "
+            "(the last engine built)",
+        ),
         active_requests=reg.gauge(
             "calfkit_engine_active_requests",
             "requests holding a slot (summed across the process's engines)",
@@ -266,6 +289,12 @@ def _deliver_batch(deliveries: "list[tuple[asyncio.Queue, list]]") -> None:
         queue.put_nowait(items)
 
 
+def _some(x: Any) -> tuple:
+    """``(x,)``, or ``()`` for None: an optional argument or result that a
+    program without it never sees."""
+    return () if x is None else (x,)
+
+
 @jax.named_scope("finalize")
 def _finalize_wave_math(
     cfg, paged, sampled,
@@ -273,6 +302,7 @@ def _finalize_wave_math(
     slot_keys, temp, top_k, top_p,
     seeds, w_temp, w_top_k, w_top_p,
     tables, page_rows, scatter_ids,
+    state=None, wstate=None,
 ):
     """The wave-landing math shared by single-shot and chunked prefill:
     scatter scratch K/V into the cache (rows or pages), install per-slot
@@ -309,7 +339,15 @@ def _finalize_wave_math(
             firsts = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
     last = last.at[slots].set(firsts)
     lens = lens.at[slots].set(true_lens)
-    return k, v, tables, last, lens, slot_keys, temp, top_k, top_p, firsts
+    out = (k, v, tables, last, lens, slot_keys, temp, top_k, top_p, firsts)
+    if state is None:
+        return out
+    # the wave's recurrent state lands in its slots as its pages do: the
+    # whole of a slot's state is overwritten, nothing of the last tenant stays
+    with jax.named_scope("state_land"):
+        (ssm, conv), (w_ssm, w_conv) = state, wstate
+        state = (ssm.at[:, slots].set(w_ssm), conv.at[:, :, slots].set(w_conv))
+    return (*out, state)
 
 
 @dataclass
@@ -335,6 +373,7 @@ class GenRequest:
     reuse_len: int = 0
     shared_pages: list[int] = field(default_factory=list)
     page_hashes: list = field(default_factory=list)
+    reuse_declined: bool = False  # counted once, however often it is replanned
     slot: int = -1
     generated: int = 0
     prefill_ms: float = 0.0
@@ -518,6 +557,14 @@ class EngineStats:
     # window gather's bytes that a read in place still moves.
     decode_pages_live: int = 0
     decode_pages_window: int = 0
+    # a second kind of per-sequence state (models with recurrent layers):
+    # slots whose SSM and conv state a wave overwrote at landing; requests
+    # whose cached prefix went unused because reused pages carry no state
+    # at their boundary; and, a gauge, the device bytes the slots' state
+    # reserves (0 for a model without such layers)
+    state_rows_landed: int = 0
+    prefix_reuse_declined_recurrent: int = 0
+    recurrent_state_bytes: int = 0
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
     # delta readers would steal each other's intervals.
@@ -634,6 +681,7 @@ class EngineStats:
             if self._phase is phase:
                 break
         out["occupancy_hist"] = list(self.occupancy_hist)
+        out["recurrent_state_bytes"] = self.recurrent_state_bytes  # a gauge
         now = time.perf_counter()
         blocked, empty = self._blocked, self._empty
         if phase is not None:
@@ -743,6 +791,29 @@ class InferenceEngine:
             enable_compile_cache()
 
         self.mesh = mesh if mesh is not None else make_mesh(tp=rt.tp, dp=rt.dp)
+        # a model with recurrent layers carries per-slot state beside its
+        # KV; what cannot keep that state right yet is refused HERE, with
+        # its reason, and never served by a path that would drop it
+        self._recurrent = config.recurrent
+        if self._recurrent:
+            refused = {
+                "speculative": (rt.speculative is not None,
+                                "a rejected draft needs the state rolled back, and there "
+                                "is no state snapshot to roll back to"),
+                "tp > 1": (rt.tp > 1 or self.mesh.size > 1,
+                           "the Mamba leaves and the per-slot state have no sharding "
+                           "over a mesh of more than one device"),
+                "quantization": (rt.quantization is not None,
+                                 "the Mamba leaves have no scales"),
+                "long_context": (rt.long_context,
+                                 "the sequence-parallel lane carries no recurrent state"),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise UnsupportedWithRecurrentLayers(
+                        f"{config.name} has recurrent (Mamba-2) layers: "
+                        f"RuntimeConfig {option} is not supported with them ({why})"
+                    )
         shardings = param_shardings(config, self.mesh)
         if params is None:
             logger.info(
@@ -880,6 +951,23 @@ class InferenceEngine:
                 lambda: M.make_empty_cache(config, B, S),
                 out_shardings=(cache_sh, cache_sh),
             )()
+        # the second kind of per-sequence state: per slot, every Mamba
+        # layer's SSM and conv state (None for a model without such layers).
+        # Threaded and donated through the decode and landing programs like
+        # the KV; a slot's state is wholly overwritten when a wave lands in it
+        self._state: Any = None
+        if self._recurrent:
+            from calfkit_tpu.inference.sharding import replicated
+
+            rep_sh = replicated(self.mesh)
+            self._state = jax.jit(
+                lambda: make_recurrent_state(config, B),
+                out_shardings=(rep_sh, rep_sh),
+            )()
+            logger.info(
+                "recurrent state: %d slots x %d Mamba layers (%.2f GB)",
+                B, config.n_mamba_layers, config.recurrent_state_bytes(B) / 1e9,
+            )
         self._last = jnp.zeros((B,), jnp.int32)
         self._lens = jnp.zeros((B,), jnp.int32)
         self._host_lens = np.zeros((B,), np.int64)  # host mirror for windows
@@ -1010,6 +1098,8 @@ class InferenceEngine:
         self._progress_at = cancellation.wall_clock()
         self._watchdog_task: asyncio.Task[None] | None = None
         self.stats = EngineStats()
+        if self._recurrent:
+            self.stats.recurrent_state_bytes = config.recurrent_state_bytes(B)
         # flight recorder: the ring journal every scheduler decision point
         # appends to (admission, waves, page alloc/free, spec/overlap
         # dispatches, deferred retirement, faults).  Appends are O(1)
@@ -1038,12 +1128,17 @@ class InferenceEngine:
             config, rt.quantization
         )
         self._hbm_ctx = rt.max_seq_len / 2.0
+        self._hbm_state = capacity.recurrent_bytes_per_token(config)
+        self._ledger.recurrent_state_bytes = (
+            config.recurrent_state_bytes(B) if self._recurrent else 0
+        )
         # mesh cancel fan-out: a `cancel` record arriving at any node in
         # the process reaches this engine's request abandonment
         cancellation.register_cancel_target(self)
         # latency telemetry: process-registry instruments + the sync
         # cursors that turn cumulative stats into counter increments
         self.metrics = _engine_metrics()
+        self.metrics["recurrent_state_bytes"].set(self.stats.recurrent_state_bytes)
         # per-ENGINE latency histograms: the advert's percentiles must
         # attribute to THIS engine, not blend every engine in the process
         # (the process-registry instruments above stay shared for the
@@ -1154,7 +1249,7 @@ class InferenceEngine:
             return fn
         fn = jax.jit(
             self._decode_fn_dense(window, steps, sampled),
-            donate_argnums=(1, 2),
+            donate_argnums=(1, 2, 13) if self._recurrent else (1, 2),
         )
         self._decode_jits[(window, steps, sampled)] = fn
         return fn
@@ -1169,7 +1264,8 @@ class InferenceEngine:
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, last, lens, active, done_prev,
-                   stop_table, hard_end, slot_keys, temp, top_k, top_p):
+                   stop_table, hard_end, slot_keys, temp, top_k, top_p,
+                   state=None):
             # ring-buffer decode: the main cache is READ-ONLY during the
             # scan; fresh K/V goes to a dense ring, consolidated once below.
             # The attention window is sliced ONCE per dispatch (a loop
@@ -1184,20 +1280,21 @@ class InferenceEngine:
             vw = v[:, :, :, :window]
             ring = (
                 jnp.zeros(
-                    (cfg.n_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
+                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
                     k.dtype,
                 ),
                 jnp.zeros(
-                    (cfg.n_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
+                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
                     v.dtype,
                 ),
             )
 
             def step(carry, t):
-                ring, last = carry
-                logits, ring = M.decode_step_ring(
+                ring, last, *st = carry
+                logits, ring, *st = M.decode_step_ring(
                     params, cfg, last[:, None], (kw, vw), ring, t, lens,
                     attn_impl=attn_impl,
+                    **({"state": st[0], "active": active} if st else {}),
                 )
                 if sampled:
                     # per-(request, position) streams: deterministic for a
@@ -1209,10 +1306,10 @@ class InferenceEngine:
                     with jax.named_scope("sample"):
                         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, last)
-                return (ring, nxt), nxt
+                return (ring, nxt, *st), nxt
 
-            (ring, last), toks = lax.scan(
-                step, (ring, last), jnp.arange(steps)
+            (ring, last, *st), toks = lax.scan(
+                step, (ring, last, *_some(state)), jnp.arange(steps)
             )
             k, v = M.consolidate_ring((k, v), ring, lens)
             new_lens = jnp.where(active, lens + steps, lens)
@@ -1222,7 +1319,7 @@ class InferenceEngine:
             n_valid, done = retire_mask_slots(
                 toks.T, stop_table, hard_end - lens, active
             )
-            return k, v, last, new_lens, toks, n_valid, done  # toks [steps, B]
+            return (k, v, last, new_lens, toks, n_valid, done, *st)  # toks [steps, B]
 
         return decode
 
@@ -1238,7 +1335,7 @@ class InferenceEngine:
             return fn
         fn = jax.jit(
             self._decode_fn_paged(wpages, steps, sampled),
-            donate_argnums=(1, 2),
+            donate_argnums=(1, 2, 14) if self._recurrent else (1, 2),
         )
         self._decode_jits[(wpages, steps, sampled, "paged")] = fn
         return fn
@@ -1251,7 +1348,8 @@ class InferenceEngine:
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
-                   stop_table, hard_end, slot_keys, temp, top_k, top_p):
+                   stop_table, hard_end, slot_keys, temp, top_k, top_p,
+                   state=None):
             # rows that retired in the still-in-flight previous dispatch
             # are frozen out here (and their consolidation writes routed
             # to the trash page) by the device-side done-mask chain
@@ -1259,20 +1357,21 @@ class InferenceEngine:
             B = last.shape[0]
             ring = (
                 jnp.zeros(
-                    (cfg.n_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
+                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
                     k.dtype,
                 ),
                 jnp.zeros(
-                    (cfg.n_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
+                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
                     v.dtype,
                 ),
             )
 
             def step(carry, t):
-                ring, last = carry
-                logits, ring = M.decode_step_ring_paged(
+                ring, last, *st = carry
+                logits, ring, *st = M.decode_step_ring_paged(
                     params, cfg, last[:, None], (k, v), tables, ring, t,
                     lens, wpages=wpages, attn_impl=attn_impl, active=active,
+                    **({"state": st[0]} if st else {}),
                 )
                 if sampled:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
@@ -1281,10 +1380,10 @@ class InferenceEngine:
                     with jax.named_scope("sample"):
                         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, last)
-                return (ring, nxt), nxt
+                return (ring, nxt, *st), nxt
 
-            (ring, last), toks = lax.scan(
-                step, (ring, last), jnp.arange(steps)
+            (ring, last, *st), toks = lax.scan(
+                step, (ring, last, *_some(state)), jnp.arange(steps)
             )
             k2, v2 = M.consolidate_ring_paged(
                 (k, v), ring, tables, lens, active
@@ -1293,7 +1392,7 @@ class InferenceEngine:
             n_valid, done = retire_mask_slots(
                 toks.T, stop_table, hard_end - lens, active
             )
-            return k2, v2, last, new_lens, toks, n_valid, done
+            return (k2, v2, last, new_lens, toks, n_valid, done, *st)
 
         return decode
 
@@ -1484,18 +1583,21 @@ class InferenceEngine:
             slot_keys, temp, top_k, top_p,  # [B] engine state
             seeds, w_temp, w_top_k, w_top_p,  # [R] wave values
             tables=None, page_rows=None, scatter_ids=None,  # paged only
+            state=None,  # models with recurrent layers only
         ):
             # tokens: [R, bucket]; slots/true_lens: [R]
             R, P = tokens.shape
             scratch = (
-                jnp.zeros((cfg.n_layers, R, cfg.n_kv_heads, P, cfg.head_dim), k.dtype),
-                jnp.zeros((cfg.n_layers, R, cfg.n_kv_heads, P, cfg.head_dim), v.dtype),
+                jnp.zeros((cfg.n_kv_layers, R, cfg.n_kv_heads, P, cfg.head_dim), k.dtype),
+                jnp.zeros((cfg.n_kv_layers, R, cfg.n_kv_heads, P, cfg.head_dim), v.dtype),
             )
             pos = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (R, P))
             with jax.named_scope("prefill"):
-                logits, (sk, sv) = M.forward(
+                logits, (sk, sv), *wstate = M.forward(
                     params, cfg, tokens, pos, scratch,
                     jnp.full((R,), P, jnp.int32), attn_impl=attn_impl,
+                    **({} if state is None else {
+                        "state": make_recurrent_state(cfg, R), "n_valid": true_lens}),
                 )
             idx = jnp.clip(true_lens - 1, 0, P - 1)
             last_logits = jnp.take_along_axis(
@@ -1507,9 +1609,10 @@ class InferenceEngine:
                 slot_keys, temp, top_k, top_p,
                 seeds, w_temp, w_top_k, w_top_p,
                 tables, page_rows, scatter_ids,
+                state, *wstate,
             )
 
-        fn = jax.jit(prefill, donate_argnums=(1, 2, 3, 4))
+        fn = jax.jit(prefill, donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
         self._prefill_jits[(bucket, rows, sampled)] = fn
         return fn
 
@@ -1521,7 +1624,10 @@ class InferenceEngine:
         fn = self._prefill_jits.get(("chunk", chunk, rows))
         if fn is not None:
             return fn
-        fn = jax.jit(self._chunk_fn(chunk), donate_argnums=(1, 2))
+        fn = jax.jit(
+            self._chunk_fn(chunk),
+            donate_argnums=(1, 2, 5) if self._recurrent else (1, 2),
+        )
         self._prefill_jits[("chunk", chunk, rows)] = fn
         return fn
 
@@ -1537,17 +1643,25 @@ class InferenceEngine:
         attn_impl = self._resolved_attn_impl("prefill")
 
         @jax.named_scope("chunk_loop")
-        def chunk_step(params, sk, sv, tokens_chunk, offset):
+        def chunk_step(params, sk, sv, tokens_chunk, offset,
+                       wstate=None, true_lens=None):
             R = tokens_chunk.shape[0]
             pos = offset + jnp.broadcast_to(
                 jnp.arange(chunk, dtype=jnp.int32), (R, chunk)
             )
             lens = jnp.full((R,), offset + chunk, jnp.int32)
-            logits, (sk, sv) = M.forward(
+            # the wave's recurrent state rides from chunk to chunk beside
+            # its KV scratch; a row's padding (past its true length) must
+            # not move it, so the mixer is told how much of the chunk is
+            # the row's own
+            logits, (sk, sv), *wstate = M.forward(
                 params, cfg, tokens_chunk, pos, (sk, sv), lens,
                 attn_impl=attn_impl,
+                **({} if wstate is None else {
+                    "state": wstate,
+                    "n_valid": jnp.clip(true_lens - offset, 0, chunk)}),
             )
-            return sk, sv, logits  # logits [R, chunk, V]
+            return (sk, sv, logits, *wstate)  # logits [R, chunk, V]
 
         return chunk_step
 
@@ -1577,15 +1691,22 @@ class InferenceEngine:
                 params, k, v, tables, last, lens, active, done_prev,
                 stop_table, hard_end, slot_keys, temp, top_k, top_p,
                 sk, sv, tokens_chunk, offset,
+                state=None, wstate=None, true_lens=None,
             ):
-                sk, sv, logits = chunk_fn(params, sk, sv, tokens_chunk, offset)
+                # out: (.., [state]); wave: (sk, sv, logits, [wstate])
+                wave = chunk_fn(params, sk, sv, tokens_chunk, offset, wstate, true_lens)
                 out = decode_fn(
                     params, k, v, tables, last, lens, active, done_prev,
                     stop_table, hard_end, slot_keys, temp, top_k, top_p,
+                    state,
                 )
-                return (*out, sk, sv, logits)
+                return (*out, *wave)
 
-            fn = jax.jit(ragged_paged, donate_argnums=(1, 2, 14, 15))
+            fn = jax.jit(
+                ragged_paged,
+                donate_argnums=(1, 2, 14, 15, 18, 19) if self._recurrent
+                else (1, 2, 14, 15),
+            )
         else:
             decode_fn = self._decode_fn_dense(window, steps, sampled)
 
@@ -1593,15 +1714,21 @@ class InferenceEngine:
                 params, k, v, last, lens, active, done_prev,
                 stop_table, hard_end, slot_keys, temp, top_k, top_p,
                 sk, sv, tokens_chunk, offset,
+                state=None, wstate=None, true_lens=None,
             ):
-                sk, sv, logits = chunk_fn(params, sk, sv, tokens_chunk, offset)
+                wave = chunk_fn(params, sk, sv, tokens_chunk, offset, wstate, true_lens)
                 out = decode_fn(
                     params, k, v, last, lens, active, done_prev,
                     stop_table, hard_end, slot_keys, temp, top_k, top_p,
+                    state,
                 )
-                return (*out, sk, sv, logits)
+                return (*out, *wave)
 
-            fn = jax.jit(ragged_dense, donate_argnums=(1, 2, 13, 14))
+            fn = jax.jit(
+                ragged_dense,
+                donate_argnums=(1, 2, 13, 14, 17, 18) if self._recurrent
+                else (1, 2, 13, 14),
+            )
         self._decode_jits[key] = fn
         return fn
 
@@ -1628,7 +1755,7 @@ class InferenceEngine:
                 )
 
             shape = (
-                cfg.n_layers, rows, cfg.n_kv_heads, bucket, cfg.head_dim
+                cfg.n_kv_layers, rows, cfg.n_kv_heads, bucket, cfg.head_dim
             )
             sk = jnp.zeros(shape, pool_k.dtype)
             sv = jnp.zeros(shape, pool_v.dtype)
@@ -1657,6 +1784,7 @@ class InferenceEngine:
             slot_keys, temp, top_k, top_p,
             seeds, w_temp, w_top_k, w_top_p,
             tables=None, page_rows=None, scatter_ids=None,
+            state=None, wstate=None,
         ):
             # logits index local to the final chunk
             idx = jnp.clip(true_lens - 1 - (bucket - chunk), 0, chunk - 1)
@@ -1669,13 +1797,14 @@ class InferenceEngine:
                 slot_keys, temp, top_k, top_p,
                 seeds, w_temp, w_top_k, w_top_p,
                 tables, page_rows, scatter_ids,
+                state, wstate,
             )
 
         # donate the cache (k/v alias their outputs); sk/sv have NO
         # same-shaped output to alias into, so donating them only emits
         # "donated buffers were not usable" warnings — peak HBM at landing
         # (cache + scratch) already equals the chunk-step peak either way
-        fn = jax.jit(finalize, donate_argnums=(0, 1, 4, 5))
+        fn = jax.jit(finalize, donate_argnums=(0, 1, 4, 5), donate_argnames=("state",))
         self._prefill_jits[("final", bucket, rows, sampled)] = fn
         return fn
 
@@ -2800,6 +2929,16 @@ class InferenceEngine:
         matched = self._prefix.lookup(request.page_hashes)
         if not matched:
             return 0
+        if self._recurrent:
+            # THE one place a model with recurrent layers declines reuse:
+            # cached pages hold K and V, not the SSM and conv state at
+            # their boundary, and the chunk lane cannot resume at an offset
+            # without that state.  Reuse by state snapshot is the mechanism
+            # that would lift this; until then the prompt is prefilled whole.
+            if not request.reuse_declined:
+                request.reuse_declined = True
+                self.stats.prefix_reuse_declined_recurrent += 1
+            return 0
         chunk = min(rt.prefill_chunk, bucket)
         align = ps * chunk // math.gcd(ps, chunk)
         candidate = min(
@@ -3392,6 +3531,25 @@ class InferenceEngine:
             w_temp=w_temp, w_top_k=w_top_k, w_top_p=w_top_p, sampled=sampled,
         )
 
+    def _state_kw(self, wstate: Any = None) -> dict:
+        """The landing programs' recurrent-state arguments (by name: the
+        paged and dense landings differ in what comes before them)."""
+        if not self._recurrent:
+            return {}
+        return {"state": self._state, **({} if wstate is None else {"wstate": wstate})}
+
+    def _wave_state_args(self, inf: dict) -> list:
+        """A chunk program's recurrent-state arguments: the wave's state
+        so far and its rows' true lengths."""
+        if not self._recurrent:
+            return []
+        return [inf["wstate"], jnp.asarray(inf["arrays"]["true_lens"])]
+
+    def _note_state_landed(self, landed: list, wave: "list[GenRequest]") -> None:
+        if landed:
+            self._state = landed[0]
+            self.stats.state_rows_landed += len(wave)
+
     def _sampling_state_args(self, arrays: dict) -> list:
         return [
             self._slot_keys,
@@ -3483,7 +3641,9 @@ class InferenceEngine:
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
-        ) = fn(*args)
+            *landed,
+        ) = fn(*args, **self._state_kw())
+        self._note_state_landed(landed, wave)
         if self._paged:
             self._tables = tables
         # sync BEFORE timing: with async dispatch, fn() returns before the
@@ -3528,7 +3688,7 @@ class InferenceEngine:
         cfg = self.config
         R = len(wave)
         scratch_shape = (
-            cfg.n_layers, R, cfg.n_kv_heads, bucket, cfg.head_dim
+            cfg.n_kv_layers, R, cfg.n_kv_heads, bucket, cfg.head_dim
         )
         dtype = self._k.dtype
         reuse = wave[0].reuse_len  # uniform across the wave
@@ -3556,6 +3716,9 @@ class InferenceEngine:
             n_chunks=-(-bucket // chunk), idx=reuse // chunk,
             arrays=self._wave_arrays(wave, bucket),
             scratch=scratch,
+            # the wave's recurrent state, carried from chunk to chunk
+            # beside the scratch (a fresh sequence's: zeros)
+            wstate=make_recurrent_state(cfg, R) if self._recurrent else None,
             started=time.perf_counter(),
         )
 
@@ -3573,10 +3736,12 @@ class InferenceEngine:
             inf["arrays"]["tokens"][:, idx * chunk:(idx + 1) * chunk]
         )
         self.stats.enter(ENQUEUE)
-        sk, sv, logits = self._chunk_jit(chunk, R)(
-            self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk)
+        sk, sv, logits, *wstate = self._chunk_jit(chunk, R)(
+            self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk),
+            *self._wave_state_args(inf),
         )
         inf["scratch"] = (sk, sv)
+        inf["wstate"] = wstate[0] if wstate else None
         inf["idx"] = idx + 1
         self._journal.append(
             flightrec.EV_PREFILL_CHUNK, None, -1, inf["idx"], inf["n_chunks"]
@@ -3613,7 +3778,9 @@ class InferenceEngine:
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
-        ) = fn(*args)
+            *landed,
+        ) = fn(*args, **self._state_kw(inf["wstate"]))
+        self._note_state_landed(landed, wave)
         if self._paged:
             self._tables = tables
         self.stats.enter(SYNC)
@@ -3757,12 +3924,20 @@ class InferenceEngine:
         self._journal.append(
             flightrec.EV_RAGGED_WAVE, None, -1, len(self._active), R
         )
+        # a model with recurrent layers: the slots' state goes in after the
+        # chunk's arguments and comes back after ``done``; the wave's state
+        # comes back last
+        res = list(self._ragged_jit(window, steps, sampled, chunk, R)(
+            *args, sk, sv, tok_chunk, jnp.int32(idx * chunk),
+            *_some(self._state), *self._wave_state_args(inf),
+        ))
+        if self._recurrent:
+            inf["wstate"] = res.pop()
+            self._state = res.pop(7)
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
             sk, sv, logits,
-        ) = self._ragged_jit(window, steps, sampled, chunk, R)(
-            *args, sk, sv, tok_chunk, jnp.int32(idx * chunk)
-        )
+        ) = res
         inf["scratch"] = (sk, sv)
         inf["idx"] = idx + 1
         self._journal.append(
@@ -3972,7 +4147,10 @@ class InferenceEngine:
         )
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
-        ) = self._decode_jit(window, steps, sampled)(*args)
+            *state,
+        ) = self._decode_jit(window, steps, sampled)(*args, *_some(self._state))
+        if state:
+            self._state = state[0]
         self._stage_pend(toks, n_valid, done, steps, started)
 
     def _stage_pend(
@@ -4090,9 +4268,12 @@ class InferenceEngine:
         self._journal.append(
             flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
         )
-        self._k, self._v, self._last, self._lens, toks, _n_valid, _done = (
-            self._decode_jit(window, steps, sampled)(*args)
-        )
+        (
+            self._k, self._v, self._last, self._lens, toks, _n_valid, _done,
+            *state,
+        ) = self._decode_jit(window, steps, sampled)(*args, *_some(self._state))
+        if state:
+            self._state = state[0]
         for slot in self._active:
             self._host_lens[slot] += steps
         block = self._sync_host(toks)  # [steps, B] — THE host sync per dispatch
@@ -4186,7 +4367,7 @@ class InferenceEngine:
                 float(denom) * rows,
                 capacity.hbm_bytes_per_token(
                     self._hbm_constants, self._hbm_ctx, max(rows, 1)
-                ),
+                ) + self._hbm_state,
             )
         self._observe("decode_dispatch_ms", elapsed * 1000.0)
         # the advert's many-router tiebreak signal (ISSUE 10 satellite):

@@ -14,6 +14,20 @@ Weight name mapping (HF → ours):
     model.layers.{i}.{input,post_attention}_layernorm.weight → norms
     model.norm.weight                              → final_norm
     lm_head.weight                                 → lm_head [D, V]
+
+``model_type`` ``granitemoehybrid`` (no routed experts) loads into the
+hybrid tree of :mod:`calfkit_tpu.inference.model` (layer N is the n-th
+attention or Mamba layer by ``layer_types``):
+    model.layers.{N}.self_attn.{q,k,v,o}_proj.weight → layers.attn.wq/wk/wv/wo
+    model.layers.{N}.mamba.in_proj.weight [z|xBC|dt, D] → layers.mamba.w_in (as it is)
+    model.layers.{N}.mamba.conv1d.weight [C, 1, d_conv] → conv_w [d_conv, C]; .bias → conv_b
+    model.layers.{N}.mamba.{A_log,D,dt_bias}          → float32 leaves
+    model.layers.{N}.mamba.norm.weight, out_proj.weight → norm, w_out (transposed)
+    model.layers.{N}.shared_mlp.input_linear.weight [2F, D], gate first
+                                                      → layers.mlp.w_gate, w_up (split, transposed)
+    model.layers.{N}.shared_mlp.output_linear.weight  → layers.mlp.w_down
+    model.layers.{N}.input_layernorm / post_attention_layernorm
+                                → attn_norm or mixer_norm / layers.mlp.mlp_norm
 """
 
 from __future__ import annotations
@@ -32,6 +46,8 @@ logger = logging.getLogger(__name__)
 
 def config_from_hf(path: str | Path) -> ModelConfig:
     raw = json.loads((Path(path) / "config.json").read_text())
+    if raw.get("model_type") == "granitemoehybrid":
+        return _granite_hybrid_config(raw, str(path))
     return ModelConfig(
         name=raw.get("_name_or_path", str(path)),
         vocab_size=raw["vocab_size"],
@@ -44,6 +60,48 @@ def config_from_hf(path: str | Path) -> ModelConfig:
         norm_eps=raw.get("rms_norm_eps", 1e-5),
         max_seq_len=raw.get("max_position_embeddings", 2048),
         tie_embeddings=raw.get("tie_word_embeddings", False),
+    )
+
+
+class RoutedExpertsUnsupported(ValueError):
+    """A checkpoint with routed experts: ``model.py``'s MLP runs none."""
+
+
+def _granite_hybrid_config(raw: dict, path: str) -> ModelConfig:
+    """GraniteMoeHybrid's ``config.json`` -> the hybrid description."""
+    if raw.get("num_local_experts", 0):
+        raise RoutedExpertsUnsupported(
+            f"{path}: num_local_experts = {raw['num_local_experts']}: routed "
+            "experts are not supported (the shared MLP alone is)"
+        )
+    if raw.get("position_embedding_type", "nope") not in ("nope", "rope"):
+        raise ValueError(f"unknown position_embedding_type {raw['position_embedding_type']!r}")
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=raw["vocab_size"],
+        d_model=raw["hidden_size"],
+        n_layers=raw["num_hidden_layers"],
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw.get("num_key_value_heads", raw["num_attention_heads"]),
+        d_ff=raw["shared_intermediate_size"],
+        rope_theta=raw.get("rope_theta", 10000.0),
+        norm_eps=raw.get("rms_norm_eps", 1e-5),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=raw.get("tie_word_embeddings", False),
+        layer_types=tuple(raw["layer_types"]),
+        mamba_n_heads=raw["mamba_n_heads"],
+        mamba_d_head=raw["mamba_d_head"],
+        mamba_d_state=raw["mamba_d_state"],
+        mamba_n_groups=raw.get("mamba_n_groups", 1),
+        mamba_d_conv=raw.get("mamba_d_conv", 4),
+        mamba_chunk_size=raw.get("mamba_chunk_size", 256),
+        position_embedding=(
+            "none" if raw.get("position_embedding_type", "nope") == "nope" else "rope"
+        ),
+        attention_multiplier=raw.get("attention_multiplier"),
+        embedding_multiplier=float(raw.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(raw.get("residual_multiplier", 1.0)),
+        logits_scaling=float(raw.get("logits_scaling", 1.0)),
     )
 
 
@@ -116,6 +174,10 @@ def _build_params(
 
     D, H, K, hd = config.d_model, config.n_heads, config.n_kv_heads, config.head_dim
     L = config.n_layers
+    if config.layer_types:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with Mamba layers")
+        return _build_hybrid_params(config, shardings, get)
     _quant_axes: dict[str, tuple[int, ...]] = {}
     _bits = 8 if quantize == "int8" else 4
     if quantize in ("int8", "int4"):
@@ -221,3 +283,59 @@ def _build_params(
         )
     logger.info("loaded %s params", config.name)
     return params
+
+
+def _build_hybrid_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The hybrid tree from HF GraniteMoeHybrid names and fused layouts."""
+    import jax
+
+    from calfkit_tpu.inference.config import ATTENTION
+
+    D, H, K, hd, F = (config.d_model, config.n_heads, config.n_kv_heads, config.head_dim,
+                      config.d_ff)
+    dtype = np.dtype(config.dtype)
+    attn_at = [i for i, t in enumerate(config.layer_types) if t == ATTENTION]
+    mamba_at = [i for i, t in enumerate(config.layer_types) if t != ATTENTION]
+
+    def stack(layers: list[int], name: str, transform: Any, as_type: Any = dtype) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in layers]
+        ).astype(as_type)
+
+    everywhere = list(range(config.n_layers))
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight").astype(dtype),
+        "layers": {
+            "attn": {
+                "wq": stack(attn_at, "self_attn.q_proj.weight", lambda w: w.T.reshape(D, H, hd)),
+                "wk": stack(attn_at, "self_attn.k_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wv": stack(attn_at, "self_attn.v_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wo": stack(attn_at, "self_attn.o_proj.weight", lambda w: w.T.reshape(H, hd, D)),
+                "attn_norm": stack(attn_at, "input_layernorm.weight", lambda w: w),
+            },
+            "mamba": {
+                "w_in": stack(mamba_at, "mamba.in_proj.weight", lambda w: w),
+                # HF's depthwise conv1d weight is [C, 1, d_conv]; ours is tap-major
+                "conv_w": stack(mamba_at, "mamba.conv1d.weight", lambda w: w[:, 0, :].T),
+                "conv_b": stack(mamba_at, "mamba.conv1d.bias", lambda w: w),
+                "A_log": stack(mamba_at, "mamba.A_log", lambda w: w, np.float32),
+                "D": stack(mamba_at, "mamba.D", lambda w: w, np.float32),
+                "dt_bias": stack(mamba_at, "mamba.dt_bias", lambda w: w, np.float32),
+                "norm": stack(mamba_at, "mamba.norm.weight", lambda w: w),
+                "w_out": stack(mamba_at, "mamba.out_proj.weight", lambda w: w.T),
+                "mixer_norm": stack(mamba_at, "input_layernorm.weight", lambda w: w),
+            },
+            "mlp": {
+                # input_linear is [2F, D]: the gate's rows first, then up's
+                "w_gate": stack(everywhere, "shared_mlp.input_linear.weight", lambda w: w[:F].T),
+                "w_up": stack(everywhere, "shared_mlp.input_linear.weight", lambda w: w[F:].T),
+                "w_down": stack(everywhere, "shared_mlp.output_linear.weight", lambda w: w.T),
+                "mlp_norm": stack(everywhere, "post_attention_layernorm.weight", lambda w: w),
+            },
+        },
+        "final_norm": get("model.norm.weight").astype(dtype),
+    }
+    if not config.tie_embeddings:
+        tree["lm_head"] = get("lm_head.weight").T.astype(dtype)
+    logger.info("loaded %s params", config.name)
+    return jax.tree.map(jax.device_put, tree, shardings)

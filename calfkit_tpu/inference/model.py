@@ -18,6 +18,16 @@ Weight layout (per layer, stacked on axis 0 across layers):
     mlp:  w_gate/w_up [L, D, F], w_down [L, F, D]
     norms: attn_norm/mlp_norm [L, D]
     top:   embed [V, D], final_norm [D], lm_head [D, V] (absent when tied)
+
+A hybrid stack (``config.layer_types``: Mamba-2 layers beside attention,
+the SwiGLU MLP in every layer) keeps its layers in three stacked groups,
+``layers = {"attn": wq wk wv wo attn_norm [La, ..], "mamba": see mamba.py
+[Lm, ..], "mlp": w_gate w_up w_down mlp_norm [L, ..]}``, and runs them under
+ONE ``lax.scan`` over the repeats of the layer period (``_hybrid_stack``),
+the period's layers unrolled inside the body.  Only the attention layers
+keep K and V (``config.n_kv_layers``); the Mamba layers' conv and SSM state
+travels as ``state = (ssm [Lm, B, H, P, N], conv [Lm, d_conv-1, B, C])``.
+A description without ``layer_types`` never reaches that code.
 """
 
 from __future__ import annotations
@@ -30,7 +40,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from calfkit_tpu.inference.config import ModelConfig
+from calfkit_tpu.inference.config import ATTENTION, ModelConfig
+from calfkit_tpu.inference.mamba import (
+    init_mamba_params,
+    mamba_chunk,
+    mamba_step,
+)
 from calfkit_tpu.inference.quant import dequant as _w
 
 Params = dict[str, Any]
@@ -56,9 +71,44 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
     )
     keys = jax.random.split(key, 8)
 
-    def norm_init(k, shape, fan_in):
-        scale = 1.0 / math.sqrt(fan_in)
+    def norm_init(k, shape, fan_in, gain=1.0):
+        scale = gain / math.sqrt(fan_in)
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    if config.layer_types:
+        # A hybrid stack: with a TIED head, embedding_multiplier 12 and
+        # residual_multiplier 0.22, matrices at 1/sqrt(fan_in) leave the
+        # stream nearly equal to the input token's embedding, and the
+        # argmax is that token again whatever the layers compute.  So the
+        # matrices that write to the stream are drawn 1/residual_multiplier
+        # larger: every layer's update is then as large a share of the
+        # stream as in a decoder without the multiplier, and the layers
+        # decide the logits.
+        La, out = config.n_kv_layers, 1.0 / config.residual_multiplier
+        mamba = init_mamba_params(config, jax.random.split(keys[1])[0], dtype)
+        mamba["w_out"] = (mamba["w_out"].astype(jnp.float32) * out).astype(dtype)
+        return {
+            "embed": norm_init(keys[0], (V, D), D),
+            "layers": {
+                "attn": {
+                    "wq": norm_init(keys[1], (La, D, H, hd), D),
+                    "wk": norm_init(keys[2], (La, D, K, hd), D),
+                    "wv": norm_init(keys[3], (La, D, K, hd), D),
+                    "wo": norm_init(keys[4], (La, H, hd, D), H * hd, out),
+                    "attn_norm": jnp.ones((La, D), dtype),
+                },
+                "mamba": mamba,
+                "mlp": {
+                    "w_gate": norm_init(keys[5], (L, D, F), D),
+                    "w_up": norm_init(keys[6], (L, D, F), D),
+                    "w_down": norm_init(keys[7], (L, F, D), F, out),
+                    "mlp_norm": jnp.ones((L, D), dtype),
+                },
+            },
+            "final_norm": jnp.ones((D,), dtype),
+            **({} if config.tie_embeddings else {
+                "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
+        }
 
     params: Params = {
         "embed": norm_init(keys[0], (V, D), D),
@@ -135,21 +185,49 @@ def _gqa_scores_mask(
 def attn_qkv(
     x: jax.Array,  # [B, S, D]
     lp: Params,  # one layer's params
-    cos: jax.Array,
-    sin: jax.Array,
+    cos: jax.Array | None,
+    sin: jax.Array | None,
     eps: float,
+    q_scale: float | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The block's attention front half: norm → QKV projections → rope.
 
     Shared by prefill, decode, and the sequence-parallel ring — ONE place
-    for the projection math.
+    for the projection math.  ``cos`` None: no rotary embedding.
+    ``q_scale`` multiplies the queries, for a model whose scores are not
+    scaled by 1/sqrt(head_dim): every attention core keeps that one law
+    and the queries carry the ratio (:func:`_q_scale`).
     """
     with jax.named_scope("qkv"):
         h = rms_norm(x, lp["attn_norm"], eps)
         q = jnp.einsum("bsd,dnh->bsnh", h, _w(lp["wq"]))
         k = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wk"]))
         v = jnp.einsum("bsd,dkh->bskh", h, _w(lp["wv"]))
+        if q_scale is not None:
+            q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
+        if cos is None:
+            return q, k, v
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _q_scale(config: ModelConfig) -> float | None:
+    """``attention_multiplier`` over the cores' own 1/sqrt(head_dim)."""
+    if config.attention_multiplier is None:
+        return None
+    return float(config.attention_multiplier) * math.sqrt(config.head_dim)
+
+
+def _positions_tables(config: ModelConfig, positions: jax.Array):
+    if config.position_embedding == "none":
+        return None, None
+    return rope_tables(positions, config.head_dim, config.rope_theta)
+
+
+def _embed(params: Params, config: ModelConfig, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]  # [B, S, D] gather
+    if config.embedding_multiplier != 1.0:
+        x = (x.astype(jnp.float32) * config.embedding_multiplier).astype(x.dtype)
+    return x
 
 
 def attn_out_mlp(
@@ -157,27 +235,103 @@ def attn_out_mlp(
     attn: jax.Array,  # [B, S, H, hd]
     lp: Params,
     eps: float,
+    residual: float = 1.0,
 ) -> jax.Array:
     """The block's back half: output projection + residual + SwiGLU MLP."""
     with jax.named_scope("attn_out"):
-        x = x + jnp.einsum("bsnh,nhd->bsd", attn, _w(lp["wo"]))
+        x = _add(x, jnp.einsum("bsnh,nhd->bsd", attn, _w(lp["wo"])), residual)
+    return mlp_residual(x, lp, eps, residual)
+
+
+def _add(x: jax.Array, update: jax.Array, residual: float) -> jax.Array:
+    """``x + residual * update`` (the multiplier applied in float32)."""
+    if residual == 1.0:
+        return x + update
+    return x + (update.astype(jnp.float32) * residual).astype(x.dtype)
+
+
+def mlp_residual(x: jax.Array, lp: Params, eps: float, residual: float = 1.0) -> jax.Array:
     with jax.named_scope("mlp"):
         h = rms_norm(x, lp["mlp_norm"], eps)
         gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"]))
         up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"]))
-        return x + jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(gate) * up, _w(lp["w_down"])
+        return _add(
+            x,
+            jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up, _w(lp["w_down"])),
+            residual,
         )
 
 
-def lm_logits(x: jax.Array, params: Params, eps: float) -> jax.Array:
-    """Final norm + (tied or untied) LM head."""
+def lm_logits(
+    x: jax.Array, params: Params, eps: float, scaling: float = 1.0
+) -> jax.Array:
+    """Final norm + (tied or untied) LM head; ``scaling`` divides the logits."""
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], eps)
         head = params.get("lm_head")
         if head is None:
-            return jnp.einsum("bsd,vd->bsv", x, params["embed"])
-        return jnp.einsum("bsd,dv->bsv", x, _w(head))
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, _w(head))
+        if scaling != 1.0:
+            logits = (logits.astype(jnp.float32) / scaling).astype(logits.dtype)
+        return logits
+
+
+def _hybrid_stack(
+    config: ModelConfig,
+    layers: Params,
+    x: jax.Array,
+    carry: Any,
+    attn_layer: Any,  # (carry, x, lp, ia) -> (carry, attn [B, S, H, hd])
+    mamba_layer: Any,  # (carry, h, lp, im) -> (carry, y [B, S, D])
+) -> tuple[jax.Array, Any]:
+    """Run a hybrid stack: ONE ``lax.scan`` over the repeats of the layer
+    period, the period's layers unrolled in its body, so compile time
+    follows the period and not the depth.  ``ia`` / ``im`` are the layer's
+    index among the attention / Mamba layers (traced), which is where its
+    K and V / its recurrent state live in ``carry``.  The decode step and
+    the prefill chunk differ only in the two callbacks."""
+    eps, rm = config.norm_eps, config.residual_multiplier
+    period = config.layer_period
+    n = config.n_layers // len(period)
+    a_per = period.count(ATTENTION)
+    m_per = len(period) - a_per
+
+    def layer(group: str, i: jax.Array) -> Params:
+        # ONE layer's leaves, sliced from the whole stack where they are
+        # used: XLA reads such a slice in place, inside the matmul that
+        # takes it.  Scanning over the period's layers as ``xs`` instead
+        # made it copy a period's weights before every use (compiled for
+        # the v5e: 1.5 GB written and read again a period).
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), layers[group]
+        )
+
+    def body(c, p):
+        x, carry = c
+        ja = jm = 0
+        for j, kind in enumerate(period):
+            mlp_lp = layer("mlp", p * len(period) + j)
+            if kind == ATTENTION:
+                ia = p * a_per + ja
+                lp = {**layer("attn", ia), **mlp_lp}
+                carry, attn = attn_layer(carry, x, lp, ia)
+                x = attn_out_mlp(x, attn, lp, eps, rm)
+                ja += 1
+            else:
+                im = p * m_per + jm
+                lp = layer("mamba", im)
+                with jax.named_scope("mamba"):
+                    h = rms_norm(x, lp["mixer_norm"], eps)
+                    carry, y = mamba_layer(carry, h, lp, im)
+                    x = _add(x, y, rm)
+                x = mlp_residual(x, mlp_lp, eps, rm)
+                jm += 1
+        return (x, carry), None
+
+    (x, carry), _ = lax.scan(body, (x, carry), jnp.arange(n, dtype=jnp.int32))
+    return x, carry
 
 
 def attention_xla(
@@ -255,7 +409,9 @@ def forward(
     unroll: bool = False,  # static: python layer loop (the decode hot path)
     attn_impl: str = "xla",  # static: "xla" | "pallas" | "pallas_interpret"
     insert_at: jax.Array | None = None,  # [B] explicit per-row write offset
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv) of the rows
+    n_valid: jax.Array | None = None,  # hybrid: [B] positions of the chunk that are the row's own
+) -> Any:
     """Run the decoder over a token chunk, updating the cache functionally.
 
     Works for prefill (S = prompt chunk) and decode (S = 1) alike; the
@@ -269,19 +425,57 @@ def forward(
     in-place in the donated cache (bytes ∝ chunk) instead of round-tripping
     a full 2×[B,K,S,hd] page per layer through a scan carry (measured ~2x
     end-to-end decode slowdown).  Returns (logits, new_cache).
+
+    A hybrid stack also takes the rows' recurrent ``state`` as the chunk
+    before left it and how many of the chunk's positions are each row's
+    own (``n_valid``; the rest is padding, which moves no state), and
+    returns (logits, new_cache, new_state).
     """
     eps = config.norm_eps
-    x = params["embed"][tokens]  # [B, S, D] gather
-    cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
     if insert_at is None:
         # default: the chunk is fully valid and ends at seq_lens.  An
         # explicit insert_at serves RAGGED chunks (speculative draft
         # catch-up: per-row valid lengths shorter than the padded width)
         insert_at = seq_lens - tokens.shape[1]  # where this chunk lands
-
-    layer_params = params["layers"]
     k_pages, v_pages = kv_cache  # [L, B, K, Smax, hd]
     W = attn_window or k_pages.shape[3]
+    if config.layer_types:
+        x = _embed(params, config, tokens)
+        cos, sin = _positions_tables(config, positions)
+        q_scale = _q_scale(config)
+        if n_valid is None:
+            n_valid = jnp.full(tokens.shape[:1], tokens.shape[1], jnp.int32)
+
+        def attn_layer(carry, x, lp, ia):
+            k_all, v_all, st = carry
+            q, k, v = attn_qkv(x, lp, cos, sin, eps, q_scale)
+            k_page = _insert_chunk(
+                lax.dynamic_index_in_dim(k_all, ia, 0, keepdims=False), k, insert_at)
+            v_page = _insert_chunk(
+                lax.dynamic_index_in_dim(v_all, ia, 0, keepdims=False), v, insert_at)
+            attn = prefill_attention(
+                q, k_page[:, :, :W], v_page[:, :, :W], positions, seq_lens,
+                attn_impl=attn_impl,
+            )
+            k_all = lax.dynamic_update_index_in_dim(k_all, k_page, ia, 0)
+            v_all = lax.dynamic_update_index_in_dim(v_all, v_page, ia, 0)
+            return (k_all, v_all, st), attn
+
+        def mamba_layer(carry, h, lp, im):
+            k_all, v_all, st = carry
+            y, st = mamba_chunk(h, lp, st, im, n_valid, config)
+            return (k_all, v_all, st), y
+
+        x, (new_k, new_v, state) = _hybrid_stack(
+            config, params["layers"], x, (k_pages, v_pages, state),
+            attn_layer, mamba_layer,
+        )
+        logits = lm_logits(x, params, eps, config.logits_scaling)
+        return logits, (new_k, new_v), state
+
+    x = params["embed"][tokens]  # [B, S, D] gather
+    cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
+    layer_params = params["layers"]
 
     def layer_math(x, lp, k_page, v_page):
         """One block given this layer's cache page; returns (x, k, v chunk).
@@ -330,7 +524,9 @@ def _decode_step_with_ring(
     base_lens: jax.Array,  # [B]
     attn_source: Any,  # (i, q, ring_k_i, ring_v_i) -> attn [B, 1, H, hd]
     scan_xs: Any,  # extra per-layer scan inputs threaded to attn_source
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
+    active: jax.Array | None = None,  # hybrid: rows whose state advances
+) -> Any:
     """The shared decode-step transformer body (ring-buffer scheme).
 
     Why a ring: per-token scatters into the main cache cost ~10ms/step on
@@ -349,12 +545,51 @@ def _decode_step_with_ring(
     closed-over invariants (no carry round-trip), only the small ring
     travels in the carry.  An unrolled python loop has the same memory
     pattern but compiles ~10x slower for deep models.
+
+    A hybrid stack threads the slots' recurrent ``state`` through the same
+    scan (each Mamba layer reads and rewrites its own slice in place; rows
+    that are not ``active`` keep theirs) and returns it third.
     """
     eps = config.norm_eps
     positions = (base_lens + t)[:, None]  # [B, 1] absolute position
+    ring_k, ring_v = ring
+    if config.layer_types:
+        x = _embed(params, config, tokens)
+        cos, sin = _positions_tables(config, positions)
+        q_scale = _q_scale(config)
+
+        def attn_layer(carry, x, lp, ia):
+            ring_k, ring_v, st = carry
+            q, k, v = attn_qkv(x, lp, cos, sin, eps, q_scale)
+            slab = k[:, 0].astype(ring_k.dtype)[None, None]
+            ring_k = lax.dynamic_update_slice(ring_k, slab, (ia, t, 0, 0, 0))
+            slab = v[:, 0].astype(ring_v.dtype)[None, None]
+            ring_v = lax.dynamic_update_slice(ring_v, slab, (ia, t, 0, 0, 0))
+            extra = None if scan_xs is None else jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, ia, 0, keepdims=False), scan_xs
+            )
+            attn = attn_source(
+                ia, q,
+                lax.dynamic_index_in_dim(ring_k, ia, 0, keepdims=False),
+                lax.dynamic_index_in_dim(ring_v, ia, 0, keepdims=False),
+                extra,
+            )
+            return (ring_k, ring_v, st), attn
+
+        def mamba_layer(carry, h, lp, im):
+            ring_k, ring_v, st = carry
+            y, st = mamba_step(h, lp, st, im, active, config)
+            return (ring_k, ring_v, st), y
+
+        x, (ring_k, ring_v, state) = _hybrid_stack(
+            config, params["layers"], x, (ring_k, ring_v, state),
+            attn_layer, mamba_layer,
+        )
+        logits = lm_logits(x, params, eps, config.logits_scaling)
+        return logits, (ring_k, ring_v), state
+
     x = params["embed"][tokens]
     cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
-    ring_k, ring_v = ring
 
     def layer_body(carry, inputs):
         x, ring_k, ring_v, i = carry
@@ -393,7 +628,9 @@ def decode_step_ring(
     base_lens: jax.Array,  # [B] kv length at dispatch start (main cache)
     attn_window: int | None = None,
     attn_impl: str = "xla",  # static: "xla" | "pallas" | "pallas_interpret"
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
+    active: jax.Array | None = None,
+) -> Any:
     """One decode step over the dense [L, B, K, S, hd] cache layout."""
     k_pages, v_pages = kv_cache
     W = attn_window or k_pages.shape[3]
@@ -413,7 +650,7 @@ def decode_step_ring(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source,
-        (k_pages, v_pages),
+        (k_pages, v_pages), state, active,
     )
 
 
@@ -829,7 +1066,7 @@ def make_empty_cache(
     config: ModelConfig, batch: int, max_seq: int, dtype: Any = None
 ) -> tuple[jax.Array, jax.Array]:
     dtype = dtype or jnp.dtype(config.dtype)
-    shape = (config.n_layers, batch, config.n_kv_heads, max_seq, config.head_dim)
+    shape = (config.n_kv_layers, batch, config.n_kv_heads, max_seq, config.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -844,7 +1081,7 @@ def make_page_pool(
     """KV page pool [L, N, K, page, hd]; page 0 is the trash page."""
     dtype = dtype or jnp.dtype(config.dtype)
     shape = (
-        config.n_layers, num_pages, config.n_kv_heads, page_size,
+        config.n_kv_layers, num_pages, config.n_kv_heads, page_size,
         config.head_dim,
     )
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -885,7 +1122,8 @@ def decode_step_ring_paged(
     wpages: int,  # static: window bucket in pages
     attn_impl: str = "xla",
     active: jax.Array | None = None,  # [B] bool; None: every row reads
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
+    state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
+) -> Any:
     """One decode step reading KV through the block tables.
 
     Shares the transformer body with :func:`decode_step_ring`; only the
@@ -924,7 +1162,8 @@ def decode_step_ring_paged(
         )
 
     return _decode_step_with_ring(
-        params, config, tokens, ring, t, base_lens, attn_source, None
+        params, config, tokens, ring, t, base_lens, attn_source, None,
+        state, active,
     )
 
 

@@ -26,6 +26,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from calfkit_tpu.inference.config import ModelConfig
 
 Params = dict[str, Any]
+# the leaves of one stacked group of Mamba-2 layers (inference/mamba.py)
+MAMBA_LEAVES = ("w_in", "conv_w", "conv_b", "A_log", "D", "dt_bias", "norm", "w_out",
+                "mixer_norm")
 
 
 def make_mesh(
@@ -67,20 +70,39 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
     def ns(dims: list[tuple[int, str | None]]) -> NamedSharding:
         return NamedSharding(mesh, _spec(mesh, dims))
 
-    L = (config.n_layers, None)
-    shardings: Params = {
-        "embed": ns([(V, "tp"), (D, None)]),
-        "layers": {
+    def attention(L: tuple) -> Params:
+        return {
             "wq": ns([L, (D, None), (H, "tp"), (hd, None)]),
             "wk": ns([L, (D, None), (K, "tp"), (hd, None)]),
             "wv": ns([L, (D, None), (K, "tp"), (hd, None)]),
             "wo": ns([L, (H, "tp"), (hd, None), (D, None)]),
+            "attn_norm": ns([L, (D, None)]),
+        }
+
+    def mlp(L: tuple) -> Params:
+        return {
             "w_gate": ns([L, (D, None), (F, "tp")]),
             "w_up": ns([L, (D, None), (F, "tp")]),
             "w_down": ns([L, (F, "tp"), (D, None)]),
-            "attn_norm": ns([L, (D, None)]),
             "mlp_norm": ns([L, (D, None)]),
-        },
+        }
+
+    L = (config.n_layers, None)
+    if config.layer_types:
+        # a hybrid stack (see model.py): the attention and MLP groups keep
+        # the dense layout's specs; the Mamba leaves are replicated (one
+        # device holds them whole: the engine refuses such a model on a
+        # mesh of more than one device until they have a layout over tp)
+        layers: Params = {
+            "attn": attention((config.n_kv_layers, None)),
+            "mamba": dict.fromkeys(MAMBA_LEAVES, NamedSharding(mesh, P())),
+            "mlp": mlp(L),
+        }
+    else:
+        layers = {**attention(L), **mlp(L)}
+    shardings: Params = {
+        "embed": ns([(V, "tp"), (D, None)]),
+        "layers": layers,
         "final_norm": ns([(D, None)]),
     }
     if not config.tie_embeddings:
@@ -95,7 +117,7 @@ def cache_sharding(config: ModelConfig, mesh: Mesh, batch: int) -> NamedSharding
         _spec(
             mesh,
             [
-                (config.n_layers, None),
+                (config.n_kv_layers, None),
                 (batch, "dp"),
                 (config.n_kv_heads, "tp"),
                 (1, None),
@@ -118,7 +140,7 @@ def pool_sharding(config: ModelConfig, mesh: Mesh) -> NamedSharding:
         _spec(
             mesh,
             [
-                (config.n_layers, None),
+                (config.n_kv_layers, None),
                 (1, None),
                 (config.n_kv_heads, "tp"),
                 (1, None),

@@ -68,6 +68,7 @@ __all__ = [
     "hbm_constants",
     "lane_kind",
     "parse_dump",
+    "recurrent_bytes_per_token",
     "samplers",
 ]
 
@@ -125,6 +126,7 @@ class PageLedger:
         "_resident",
         "evicted_pages",
         "alloc_stalls",
+        "recurrent_state_bytes",
     )
 
     def __init__(self, pages_total: int):
@@ -141,6 +143,10 @@ class PageLedger:
         self._resident = 0  # chain pages resident (any refcount)
         self.evicted_pages = 0  # cumulative pages reclaimed under pressure
         self.alloc_stalls = 0  # cumulative allocs that needed eviction
+        # device bytes reserved for per-slot recurrent state, beside the
+        # pages (a model with Mamba layers; set once by the engine).  Not
+        # pages: it does not grow with a sequence and is never short
+        self.recurrent_state_bytes = 0
 
     # ----------------------------------------------------------- mutations
     @hotpath
@@ -280,6 +286,7 @@ class PageLedger:
             "prefix_resident_pages": self._resident,
             "evicted_pages": self.evicted_pages,
             "alloc_stalls": self.alloc_stalls,
+            "recurrent_state_bytes": self.recurrent_state_bytes,
             "by_owner": [
                 {"corr": corr, "run": run, "lane": lane, "pages": n}
                 for corr, run, lane, n in owners[:top]
@@ -539,14 +546,26 @@ def hbm_constants(model: Any, quantization: "str | None" = None) -> "tuple[float
     ``_perf_model`` roofline constants, precomputed once so the
     per-dispatch sample pays two multiply-adds, not a model walk.
     Weight stream: params x dtype width (int8 halves it, int4 quarters);
-    KV read: 2 (K+V) x layers x kv-heads x head_dim x 2 bytes."""
+    KV read: 2 (K+V) x layers that keep K and V x kv-heads x head_dim x
+    2 bytes (a hybrid stack's Mamba layers keep none: their state is
+    :func:`recurrent_bytes_per_token`'s)."""
     weight_bytes = float(model.param_count) * {
         "int8": 1.0, "int4": 0.5,
     }.get(quantization, 2.0)
-    kv_per_token = (
-        2.0 * model.n_layers * model.n_kv_heads * model.head_dim * 2.0
-    )
+    kv_layers = getattr(model, "n_kv_layers", model.n_layers)
+    kv_per_token = 2.0 * kv_layers * model.n_kv_heads * model.head_dim * 2.0
     return weight_bytes, kv_per_token
+
+
+@no_wallclock
+def recurrent_bytes_per_token(model: Any) -> float:
+    """Bytes of recurrent state a decoded token moves: the row's SSM and
+    conv state read AND written once (0.0 for a model without such
+    layers).  Does not grow with the context and is not shared by a
+    batch, so it adds to :func:`hbm_bytes_per_token` as it stands."""
+    if not getattr(model, "recurrent", False):
+        return 0.0
+    return 2.0 * float(model.recurrent_state_bytes(1))
 
 
 @no_wallclock

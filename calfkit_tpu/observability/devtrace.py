@@ -52,6 +52,9 @@ SCOPES = frozenset({
     # model.py, quant.py, sampler.py
     "gather_window", "qkv", "attention", "attn_out", "mlp", "lm_head",
     "kv_write", "dequant", "sample",
+    # mamba.py (inside "mamba", which model.py opens around the mixer) and
+    # the landing of a wave's recurrent state in its slots
+    "mamba", "in_proj", "conv", "ssm", "gate_norm", "out_proj", "state_land",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

@@ -1,0 +1,426 @@
+"""Mamba-2 layers beside attention in one stack (granite-4.0-h-micro's kind).
+
+Toy widths on the CPU, seeded random weights, LOGITS compared and never
+sampled tokens (with random weights the largest logit changes on rounding).
+The other side of every comparison is the benchmark's plain reference,
+``benchmarks/architectures/granite-hybrid.py``: float32, the recurrence as
+a ``lax.scan`` over positions, no chunked form, no cache.
+
+Each tolerance is written with its reason where it is set.  The weights and
+activations here are float32, so that the tolerances can be tight enough
+for the control (e): the same run with the SSM state held in bfloat16 has
+to FAIL the tolerance that the float32 state passes.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import json
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.manifest import load_architecture
+from calfkit_tpu.inference import mamba as mm
+from calfkit_tpu.inference import model as M
+from calfkit_tpu.inference.config import (
+    ModelConfig,
+    RuntimeConfig,
+    SpecConfig,
+    UnsupportedWithRecurrentLayers,
+    preset,
+)
+from calfkit_tpu.inference.engine import InferenceEngine
+
+ARCH = load_architecture("granite-hybrid")
+
+# two periods of (mamba, mamba, attention): every kind of layer twice, the
+# multipliers and the position rule of the real model, float32 throughout
+TOY = ModelConfig(
+    name="toy-hybrid", vocab_size=128, d_model=32, n_layers=6, n_heads=4, n_kv_heads=2,
+    d_ff=64, layer_types=("mamba", "mamba", "attention") * 2,
+    mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16, mamba_n_groups=2, mamba_d_conv=4,
+    mamba_chunk_size=8, dtype="float32", position_embedding="none",
+    attention_multiplier=0.25, embedding_multiplier=12.0, residual_multiplier=0.22,
+    logits_scaling=8.0, tie_embeddings=True, max_seq_len=1024,
+)
+# float32 against float32: the two sides differ in the ORDER of sums (the
+# chunked scan against the recurrence, bucketed attention against whole
+# rows).  Two readings set the limit, over 512 generated positions of the
+# run in (e), logits up to 0.54 in size: the float32 state reads 2.4e-7 at
+# the worst position, the bfloat16 state 3.7e-3 (and passes 1e-4 at its
+# 27th step).  2e-5 stands a factor of 80 above the first and 180 below
+# the second.
+LOGIT_TOL = 2e-5
+
+
+def runtime(**kw) -> RuntimeConfig:
+    base = dict(
+        max_batch_size=2, max_seq_len=128, kv_layout="paged", page_size=8,
+        chunked_prefill=True, prefill_chunk=16, window_buckets=(32, 128),
+        compilation_cache=False, max_prefill_wave=2, decode_steps_per_dispatch=4,
+    )
+    base.update(kw)
+    return RuntimeConfig(**base)
+
+
+def prompt_of(n: int, seed: int = 0) -> list[int]:
+    return [int(t) for t in np.random.default_rng(seed).integers(3, TOY.vocab_size, n)]
+
+
+class Spy:
+    """Records every ``lm_logits`` a program computes, in order: the
+    engine gives out tokens, and these tests compare logits."""
+
+    def __init__(self, monkeypatch):
+        self.seen: list[np.ndarray] = []
+        original = M.lm_logits
+
+        def spied(x, params, eps, scaling=1.0):
+            logits = original(x, params, eps, scaling)
+            jax.debug.callback(lambda l: self.seen.append(np.asarray(l)), logits, ordered=True)
+            return logits
+
+        monkeypatch.setattr(M, "lm_logits", spied)
+
+    def of_request(self, prompt: list[int], out: list[int], chunk: int) -> np.ndarray:
+        """The logits that chose ``out``: the prompt's last position from
+        the chunk that held it, then one row of each decode step: the row
+        whose argmax chain is the served tokens."""
+        chunks = [s for s in self.seen if s.shape[1] == chunk]
+        steps = [s for s in self.seen if s.shape[1] == 1]
+        last = len(prompt) - 1
+        rows = [chunks[last // chunk][0, last % chunk]]
+        slot = next(
+            b for b in range(steps[0].shape[0])
+            if all(int(np.argmax(steps[i][b, 0])) == out[i + 1] for i in range(len(out) - 1))
+        )
+        rows += [steps[i][slot, 0] for i in range(len(out) - 1)]
+        return np.stack(rows)
+
+
+def serve(engine_args: tuple, requests: list[tuple[list[int], int]], sequential: bool = True):
+    """Outputs of ``requests`` (prompt, max_new_tokens) through one engine."""
+    async def run():
+        engine = InferenceEngine(*engine_args, seed=3)
+        await engine.start()
+        try:
+            async def one(prompt, n):
+                return [t async for t in engine.generate(prompt, max_new_tokens=n)]
+
+            if sequential:
+                outs = [await one(p, n) for p, n in requests]
+            else:
+                outs = list(await asyncio.gather(*[one(p, n) for p, n in requests]))
+            return outs, engine.params, engine.stats.counters()
+        finally:
+            await engine.stop()
+
+    return asyncio.run(run())
+
+
+def reference_logits(params, config: ModelConfig, seq: list[int]) -> np.ndarray:
+    tokens = np.asarray([seq], np.int32)
+    return ARCH.forward_logits(params, config, tokens, np.asarray([len(seq)], np.int32))[0]
+
+
+# ----------------------------------------------------------------- (a)
+def test_full_forward_agrees_with_the_reference():
+    """The program's whole forward (one chunk, zero state) against the
+    reference, at every position of two ragged rows."""
+    params = M.init_params(TOY, jax.random.key(1))
+    rng = np.random.default_rng(2)
+    tokens = rng.integers(3, TOY.vocab_size, (2, 40)).astype(np.int32)
+    lens = np.asarray([40, 27], np.int32)
+    pos = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), (2, 40))
+    logits, _, _ = M.forward(
+        params, TOY, jnp.asarray(tokens), pos, M.make_empty_cache(TOY, 2, 40),
+        jnp.full((2,), 40, jnp.int32), state=mm.make_recurrent_state(TOY, 2),
+        n_valid=jnp.asarray(lens),
+    )
+    want = ARCH.forward_logits(params, TOY, tokens, lens)
+    for r in range(2):
+        got = np.asarray(logits[r, : lens[r]])
+        assert np.abs(got - want[r, : lens[r]]).max() < LOGIT_TOL
+
+
+# ----------------------------------------------------------------- (b)
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch):
+    """Paged, chunked with a chunk (16) smaller than the prompt (37) and an
+    SSD block (8) smaller than the chunk; 21 generated tokens cross five
+    dispatches of four steps.  Every generated position's logits against
+    the reference's full forward of prompt + output."""
+    spy = Spy(monkeypatch)
+    prompt = prompt_of(37)
+    (out,), params, counters = serve((TOY, runtime()), [(prompt, 21)])
+    got = spy.of_request(prompt, out, 16)
+    want = reference_logits(params, TOY, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() < LOGIT_TOL
+    assert counters["state_rows_landed"] == 1
+    assert counters["recurrent_state_bytes"] == TOY.recurrent_state_bytes(2)
+
+
+def test_the_prompt_s_logits_agree_chunk_by_chunk(monkeypatch):
+    """All 37 prompt positions, from the three chunks that carried the
+    state between them."""
+    spy = Spy(monkeypatch)
+    prompt = prompt_of(37, seed=5)
+    (out,), params, _ = serve((TOY, runtime()), [(prompt, 2)])
+    chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
+    want = reference_logits(params, TOY, prompt + out)[: len(prompt)]
+    assert np.abs(chunks - want).max() < LOGIT_TOL
+
+
+# ----------------------------------------------------------------- (c)
+def test_chunked_scan_agrees_with_the_recurrence_on_ragged_rows():
+    """Rows of 32, 20 and 0 own positions in one chunk of 32 (blocks of 8):
+    outputs at the own positions and both states equal the one-token
+    recurrence's, and padding moves neither state."""
+    lp = jax.tree.map(lambda a: a[0], mm.init_mamba_params(TOY, jax.random.key(0), jnp.float32))
+    lp["conv_b"] = 0.1 * jax.random.normal(jax.random.key(5), lp["conv_b"].shape)
+    h = jax.random.normal(jax.random.key(1), (3, 32, TOY.d_model))
+    # one layer's slice of a stacked state that is not zero, so that
+    # "untouched" says something
+    state = tuple(s[:1] + jax.random.normal(jax.random.key(2 + i), s[:1].shape)
+                  for i, s in enumerate(mm.make_recurrent_state(TOY, 3)))
+    (ssm, conv), im = (s[0] for s in state), jnp.int32(0)
+    n_valid = jnp.asarray([32, 20, 0])
+    out, (ssm_c, conv_c) = mm.mamba_chunk(h, lp, state, im, n_valid, TOY)
+    ssm_c, conv_c = ssm_c[0], conv_c[0]
+    st, outs = state, []
+    for t in range(32):
+        o, st = mm.mamba_step(h[:, t:t + 1], lp, st, im, t < n_valid, TOY)
+        outs.append(o)
+    (s, cv), outs = (a[0] for a in st), jnp.concatenate(outs, axis=1)
+    for b, n in enumerate([32, 20, 0]):
+        # float32 sums in two orders, states of order 1: measured 1.4e-6 at most
+        assert np.abs(out[b, :n] - outs[b, :n]).max(initial=0.0) < 1e-5
+        assert np.abs(ssm_c[b] - s[b]).max() < 1e-5
+        assert np.abs(conv_c[:, b] - cv[:, b]).max() < 1e-5
+    assert np.array_equal(ssm_c[2], ssm[2]) and np.array_equal(conv_c[:, 2], conv[:, 2])
+    # two chunks of 16 carry the state to where one of 32 arrives
+    _, half = mm.mamba_chunk(h[:, :16], lp, state, im, jnp.clip(n_valid, 0, 16), TOY)
+    _, (s2, c2) = mm.mamba_chunk(h[:, 16:], lp, half, im, jnp.clip(n_valid - 16, 0, 16), TOY)
+    assert np.abs(s2[0] - ssm_c).max() < 1e-5 and np.abs(c2[0] - conv_c).max() < 1e-5
+
+
+# ----------------------------------------------------------------- (d)
+def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
+    """One slot: the second request lands where the first one's state
+    still lies.  Its logits are those of an engine that never served the
+    first: the landing overwrites the whole of a slot's state."""
+    first, second = prompt_of(29, seed=1), prompt_of(21, seed=2)
+    spy = Spy(monkeypatch)
+    (_, out), _, counters = serve((TOY, runtime(max_batch_size=1)), [(first, 9), (second, 9)])
+    reused = [s for s in spy.seen][-8:]
+    assert counters["state_rows_landed"] == 2
+    spy.seen.clear()
+    (fresh_out,), _, _ = serve((TOY, runtime(max_batch_size=1)), [(second, 9)])
+    fresh = spy.seen[-8:]
+    assert out == fresh_out
+    for a, b in zip(reused, fresh):
+        assert np.array_equal(a, b)  # the same program on the same numbers
+
+
+def test_a_frozen_row_s_state_is_bit_equal_across_a_dispatch():
+    """Row 1 is not active: a decode dispatch leaves its SSM and conv
+    state bit for bit, while row 0's moves."""
+    engine = InferenceEngine(TOY, runtime(), seed=3)
+    ssm, conv = engine._state
+    engine._state = (
+        ssm + jax.random.normal(jax.random.key(1), ssm.shape),
+        conv + jax.random.normal(jax.random.key(2), conv.shape),
+    )
+    before = jax.tree.map(np.asarray, engine._state)
+    args, window, steps, sampled = engine._decode_args()
+    args[6] = jnp.asarray([True, False])  # the active mask of the paged decode program
+    *_, (ssm2, conv2) = engine._decode_jit(window, steps, sampled)(*args, engine._state)
+    assert np.array_equal(np.asarray(ssm2)[:, 1], before[0][:, 1])
+    assert np.array_equal(np.asarray(conv2)[:, :, 1], before[1][:, :, 1])
+    assert not np.array_equal(np.asarray(ssm2)[:, 0], before[0][:, 0])
+
+
+# ----------------------------------------------------------------- (e)
+def test_control_a_bfloat16_state_fails_the_tolerance(monkeypatch):
+    """The tolerance of (b) would catch a lower precision than the file
+    states: with the SSM state held in bfloat16 (float32 weights and
+    activations as before) the same comparison fails within 512 steps."""
+    config = replace(TOY, state_dtype="bfloat16")
+    spy = Spy(monkeypatch)
+    prompt = prompt_of(37)
+    rt = runtime(max_seq_len=1024, window_buckets=(128, 1024))
+    (out,), params, _ = serve((config, rt), [(prompt, 512)])
+    got = spy.of_request(prompt, out, 16)
+    want = reference_logits(params, TOY, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    assert np.abs(got - want).max() > LOGIT_TOL
+    spy.seen.clear()
+    (out32,), params, _ = serve((TOY, rt), [(prompt, 512)])  # and the stated precision passes
+    got = spy.of_request(prompt, out32, 16)
+    want = reference_logits(params, TOY, prompt + out32)[len(prompt) - 1:][: len(out32)]
+    assert np.abs(got - want).max() < LOGIT_TOL
+
+
+# ----------------------------------------------------------------- (f)
+def hf_tensors(params, c: ModelConfig) -> dict[str, np.ndarray]:
+    """The toy tree in HF GraniteMoeHybrid's names and fused layouts."""
+    def f(a):
+        return np.ascontiguousarray(np.asarray(a, np.float32))
+
+    D, H, K, hd = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    L = params["layers"]
+    out = {"model.embed_tokens.weight": f(params["embed"]),
+           "model.norm.weight": f(params["final_norm"])}
+    ia = im = 0
+    for i, kind in enumerate(c.layer_types):
+        pre = f"model.layers.{i}."
+        out[pre + "shared_mlp.input_linear.weight"] = f(np.concatenate(
+            [np.asarray(L["mlp"]["w_gate"][i]).T, np.asarray(L["mlp"]["w_up"][i]).T]))
+        out[pre + "shared_mlp.output_linear.weight"] = f(np.asarray(L["mlp"]["w_down"][i]).T)
+        out[pre + "post_attention_layernorm.weight"] = f(L["mlp"]["mlp_norm"][i])
+        if kind == "attention":
+            a = jax.tree.map(lambda x: np.asarray(x[ia]), L["attn"])
+            out[pre + "self_attn.q_proj.weight"] = f(a["wq"].reshape(D, H * hd).T)
+            out[pre + "self_attn.k_proj.weight"] = f(a["wk"].reshape(D, K * hd).T)
+            out[pre + "self_attn.v_proj.weight"] = f(a["wv"].reshape(D, K * hd).T)
+            out[pre + "self_attn.o_proj.weight"] = f(a["wo"].reshape(H * hd, D).T)
+            out[pre + "input_layernorm.weight"] = f(a["attn_norm"])
+            ia += 1
+        else:
+            m = jax.tree.map(lambda x: np.asarray(x[im]), L["mamba"])
+            out[pre + "mamba.in_proj.weight"] = f(m["w_in"])
+            out[pre + "mamba.conv1d.weight"] = f(m["conv_w"].T[:, None, :])
+            out[pre + "mamba.conv1d.bias"] = f(m["conv_b"])
+            for name in ("A_log", "D", "dt_bias"):
+                out[pre + "mamba." + name] = f(m[name])
+            out[pre + "mamba.norm.weight"] = f(m["norm"])
+            out[pre + "mamba.out_proj.weight"] = f(m["w_out"].T)
+            out[pre + "input_layernorm.weight"] = f(m["mixer_norm"])
+            im += 1
+    return out
+
+
+HF_CONFIG = {
+    "model_type": "granitemoehybrid", "vocab_size": 128, "hidden_size": 32,
+    "num_hidden_layers": 6, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "shared_intermediate_size": 64, "intermediate_size": 64, "num_local_experts": 0,
+    "layer_types": ["mamba", "mamba", "attention"] * 2, "mamba_n_heads": 4,
+    "mamba_d_head": 16, "mamba_d_state": 16, "mamba_n_groups": 2, "mamba_d_conv": 4,
+    "mamba_chunk_size": 8, "mamba_expand": 2, "position_embedding_type": "nope",
+    "attention_multiplier": 0.25, "embedding_multiplier": 12, "residual_multiplier": 0.22,
+    "logits_scaling": 8, "tie_word_embeddings": True, "rms_norm_eps": 1e-5,
+    "max_position_embeddings": 1024,
+}
+
+
+def test_a_checkpoint_in_hf_names_loads_to_the_tree_the_reference_agrees_with(tmp_path):
+    from safetensors.numpy import save_file
+
+    from calfkit_tpu.inference.loader import config_from_hf, load_params
+    from calfkit_tpu.inference.sharding import make_mesh, param_shardings
+
+    params = M.init_params(TOY, jax.random.key(4))
+    (tmp_path / "config.json").write_text(json.dumps(HF_CONFIG))
+    save_file(hf_tensors(params, TOY), str(tmp_path / "model.safetensors"))
+    config = replace(config_from_hf(tmp_path), name=TOY.name, dtype="float32")
+    assert config == TOY
+    loaded = load_params(tmp_path, config, param_shardings(config, make_mesh()))
+    assert jax.tree.structure(loaded) == jax.tree.structure(params)
+    for got, want in zip(jax.tree.leaves(loaded), jax.tree.leaves(params)):
+        assert got.dtype == want.dtype and np.array_equal(np.asarray(got), np.asarray(want))
+    # and the tree that came through HF's fused layouts serves what the reference computes
+    tokens = np.asarray([prompt_of(24, seed=9)], np.int32)
+    lens = np.asarray([24], np.int32)
+    logits, _, _ = M.forward(
+        loaded, config, jnp.asarray(tokens), jnp.arange(24, dtype=jnp.int32)[None],
+        M.make_empty_cache(config, 1, 24), jnp.asarray(lens),
+        state=mm.make_recurrent_state(config, 1),
+    )
+    assert np.abs(np.asarray(logits) - ARCH.forward_logits(loaded, config, tokens, lens)).max() \
+        < LOGIT_TOL
+
+
+# ----------------------------------------------------------------- (g)
+@pytest.mark.parametrize("option, kwargs", [
+    ("speculative", {"speculative": SpecConfig()}),
+    ("tp > 1", {"tp": 2}),
+    ("quantization", {"quantization": "int8"}),
+    ("long_context", {"long_context": True}),
+])
+def test_what_cannot_keep_the_state_is_refused_at_construction(option, kwargs):
+    with pytest.raises(UnsupportedWithRecurrentLayers, match=option):
+        InferenceEngine(TOY, runtime(**kwargs))
+
+
+def test_routed_experts_are_refused_by_the_loader(tmp_path):
+    from calfkit_tpu.inference.loader import RoutedExpertsUnsupported, config_from_hf
+
+    (tmp_path / "config.json").write_text(json.dumps({**HF_CONFIG, "num_local_experts": 8}))
+    with pytest.raises(RoutedExpertsUnsupported, match="num_local_experts"):
+        config_from_hf(tmp_path)
+
+
+# ------------------------------------------------- the rest of the contract
+def test_a_cached_prefix_is_declined_and_counted():
+    """With the prefix cache on, a model with recurrent layers plans no
+    reuse: the second, identical prompt is prefilled whole, counted once,
+    and answers what the first did."""
+    prompt = prompt_of(40, seed=3)
+    (a, b), _, counters = serve((TOY, runtime(prefix_cache=True)), [(prompt, 6), (prompt, 6)])
+    assert a == b
+    assert counters["prefix_reuse_declined_recurrent"] == 1
+    assert counters["prefix_hits"] == 0 and counters["prefix_reused_tokens"] == 0
+    assert counters["state_rows_landed"] == 2
+
+
+def test_the_new_counters_reach_metrics():
+    from calfkit_tpu.observability.metrics import metrics_text
+
+    serve((TOY, runtime()), [(prompt_of(20), 3)])
+    text = metrics_text()
+    for name in ("calfkit_engine_state_rows_landed_total",
+                 "calfkit_engine_prefix_reuse_declined_recurrent_total",
+                 "calfkit_engine_recurrent_state_bytes"):
+        assert name in text
+
+
+@pytest.mark.parametrize("layout, chunked", [("dense", False), ("dense", True), ("paged", False)])
+def test_the_other_layouts_serve_the_same_logits(monkeypatch, layout, chunked):
+    """The dense KV layout and single-shot prefill thread the state too."""
+    spy = Spy(monkeypatch)
+    prompt = prompt_of(23, seed=7)
+    rt = runtime(kv_layout=layout, chunked_prefill=chunked)
+    (out,), params, _ = serve((TOY, rt), [(prompt, 7)])
+    steps = [s for s in spy.seen if s.shape[1] == 1]
+    want = reference_logits(params, TOY, prompt + out)
+    slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
+    for i in range(len(out) - 1):
+        assert np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max() < LOGIT_TOL
+
+
+def test_concurrent_rows_do_not_touch_each_other_s_state(monkeypatch):
+    """Two requests of different lengths decode side by side (one wave of
+    two ragged rows, then one dispatch for both): each one's tokens are
+    what it gets alone."""
+    a, b = prompt_of(37, seed=11), prompt_of(18, seed=12)
+    (alone_a,), _, _ = serve((TOY, runtime()), [(a, 10)])
+    (alone_b,), _, _ = serve((TOY, runtime()), [(b, 14)])
+    together, _, _ = serve((TOY, runtime()), [(a, 10), (b, 14)], sequential=False)
+    assert together == [alone_a, alone_b]
+
+
+def test_the_dense_description_is_what_it_was():
+    """No ``layer_types``: the tree, the KV layers and the parameter count
+    of the Llama-family decoder."""
+    c = preset("debug")
+    assert not c.recurrent and c.n_kv_layers == c.n_layers and c.layer_types == ()
+    assert set(M.init_params(c, jax.random.key(0))["layers"]) == {
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "attn_norm", "mlp_norm"}
+    assert preset("llama-3-8b").param_count == 8030261248
+    g = preset("granite-4.0-h-micro")
+    assert g.layer_period == ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    assert (g.n_mamba_layers, g.n_kv_layers) == (36, 4)
+    assert g.recurrent_state_bytes(64) == 4831838208 + 60162048

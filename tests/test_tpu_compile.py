@@ -181,6 +181,51 @@ def test_paged_decode_in_place_compiles_for_v5e(
     assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
 
 
+def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
+    one_chip, no_persistent_cache
+):
+    """One Mamba-2 layer's decode step at granite-4.0-h-micro's widths and
+    the benchmark cell's 64 rows, on a stacked state of three layers: the
+    program's temporaries stay under ONE layer's state (134 MB), so the
+    update is written in place and no copy of the state is made.  With 36
+    layers a copy is 4.8 GB, and the cell's 16 GB chip has no room for it."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import mamba as mm
+    from calfkit_tpu.inference.config import preset
+
+    config = preset("granite-4.0-h-micro")
+    rows, layers = 64, 3
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    leaves = abstract(jax.eval_shape(
+        lambda k: jax.tree.map(
+            lambda a: a[0], mm.init_mamba_params(config, k, jnp.bfloat16)),
+        jax.random.key(0),
+    ))
+    state = abstract(jax.eval_shape(
+        lambda: tuple(s[:layers] for s in mm.make_recurrent_state(config, rows))
+    ))
+    compiled = jax.jit(
+        lambda h, lp, st, im, active: mm.mamba_step(h, lp, st, im, active, config),
+        donate_argnums=(2,),
+    ).lower(
+        jax.ShapeDtypeStruct((rows, 1, config.d_model), jnp.bfloat16, sharding=one_chip),
+        leaves, state,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    memory = compiled.memory_analysis()
+    one_layer = config.recurrent_state_bytes(rows) // config.n_mamba_layers
+    assert memory.alias_size_in_bytes >= layers * one_layer  # the state goes out where it came in
+    assert memory.temp_size_in_bytes < one_layer
+
+
 def test_kernel_bytes_do_not_depend_on_the_caller(
     one_chip, no_persistent_cache, monkeypatch
 ):
