@@ -1182,8 +1182,9 @@ class InferenceEngine:
         backend is a TPU, one device holds the model (``tp == 1`` and
         ``dp == 1``: a ``pallas_call`` under GSPMD needs a ``shard_map``
         over the KV heads first) and the head and page shapes are the
-        kernel's
-        (:func:`pallas_attention.paged_decode_in_place_ok`); else XLA.
+        kernel's (:func:`pallas_attention.paged_decode_in_place_ok`: a
+        head of whole lane tiles, or one that divides a lane tile, such
+        as 64); else XLA.
 
         The other paths under "auto" are EVIDENCE-BASED (VERDICT r3 item
         8): they read the profile
@@ -1345,6 +1346,7 @@ class InferenceEngine:
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
         attn_impl = self._resolved_attn_impl("paged_decode")
+        from calfkit_tpu.inference.pallas_attention import lane_dense_pool
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
@@ -1365,11 +1367,18 @@ class InferenceEngine:
                     v.dtype,
                 ),
             )
+            pool = (k, v)
+            if attn_impl.startswith("pallas"):
+                # the kernel's view of a pool of heads narrower than a lane
+                # tile is a relayout of both sides: made HERE, once a
+                # dispatch (the pool is a constant of the step loop and of
+                # the layer scan), never per step.  Other heads: the pool.
+                pool = (lane_dense_pool(k), lane_dense_pool(v))
 
             def step(carry, t):
                 ring, last, *st = carry
                 logits, ring, *st = M.decode_step_ring_paged(
-                    params, cfg, last[:, None], (k, v), tables, ring, t,
+                    params, cfg, last[:, None], pool, tables, ring, t,
                     lens, wpages=wpages, attn_impl=attn_impl, active=active,
                     **({"state": st[0]} if st else {}),
                 )

@@ -1101,7 +1101,8 @@ def gather_window_paged(
     ``tp > 1`` path, the verify and ragged S > 1 programs, and the parity
     reference of the Pallas paged decode kernel, which reads each row's
     live pages in place instead (on the v5e the gather was 48% of device
-    time in the benchmark's cell: PERF.md sections 5 and 6).
+    time in the Mistral cell and a quarter in granite's: PERF.md sections
+    5 and 6).
     """
     B = tables.shape[0]
     page = pool_layer.shape[2]
@@ -1134,7 +1135,10 @@ def decode_step_ring_paged(
     The Pallas read follows each row's length, so a row that is not
     ``active`` (its token is discarded by the caller) is given length 0
     there and costs no page; the XLA read gathers every row's window
-    whatever it holds and takes no notice of ``active``.
+    whatever it holds and takes no notice of ``active``.  For the Pallas
+    read ``pool`` may be the kernel's view of the pool
+    (:func:`pallas_attention.lane_dense_pool`), which a caller that loops
+    over steps makes once, outside its loop.
     """
     pool_k, pool_v = pool
 

@@ -16,7 +16,9 @@ Four kernel bodies:
   (:func:`paged_decode_attention_pallas`): one program a row, a loop over
   that row's own pages with double-buffered whole-slab copies out of the
   pool in HBM, bf16 operands into the MXU.  Its work follows the row
-  lengths, not the window bucket.
+  lengths, not the window bucket.  A head that divides a lane tile (64)
+  is read ``f = 128 / hd`` positions a lane row, through a lane-dense view
+  of the pool (:func:`lane_dense_pool`) that a caller in a loop makes once.
 
 Dense single-query decode is the S=1 row of the ragged law
 (:func:`decode_attention_pallas`), and so is paged decode at a head or page
@@ -36,12 +38,13 @@ of the window.
 
 Status: every entry point AOT-compiles for a described v5e at
 TinyLlama-1.1B and Llama-3-8B widths, the paged decode read also at
-Mistral-7B's and InternLM2-1.8B's (``tests/test_tpu_compile.py``), and
-agrees with interpret mode and the XLA path on CPU.  ``attention_impl="auto"``
-selects the paged decode read in place on a TPU (one device, heads of whole
-lane tiles: ``InferenceEngine._resolved_attn_impl``; PERF.md section 6, PR 25
-has the chip's numbers) and XLA for every other path (docs/inference.md);
-``"pallas"`` opts in everywhere.
+Mistral-7B's, InternLM2-1.8B's and granite-4.0-h-micro's
+(``tests/test_tpu_compile.py``), and agrees with interpret mode and the XLA
+path on CPU.  ``attention_impl="auto"`` selects the paged decode read in
+place on a TPU (one device, heads of whole lane tiles or of a width that
+divides one: ``InferenceEngine._resolved_attn_impl``; PERF.md section 6,
+PRs 25 and 28, has the chip's numbers) and XLA for every other path
+(docs/inference.md); ``"pallas"`` opts in everywhere.
 """
 
 from __future__ import annotations
@@ -324,14 +327,47 @@ def decode_attention_pallas(
     return o[:, :, 0], m[:, :, 0], z[:, :, 0]
 
 
+def paged_decode_lane_pack(head_dim: int) -> int:
+    """Positions of one kv head that share a 128-lane row in the paged decode
+    kernel's view of the pool: ``128 / head_dim`` for a head that divides a
+    lane tile, 1 for every other head (whole lane tiles read as they lie)."""
+    return 128 // head_dim if 128 % head_dim == 0 else 1
+
+
 def paged_decode_in_place_ok(head_dim: int, page: int, dtype) -> bool:
     """Whether :func:`_paged_decode_kernel` can take these shapes on a TPU:
-    a page slab is copied and flattened whole, so the head is whole lane
-    tiles (128) and the page whole sublane tiles of the cache's dtype (8
-    rows of 32 bits: 16 for bf16).  What fails this runs the S = 1 row of
-    the ragged paged kernel under ``"pallas"`` and XLA under ``"auto"``."""
+    a page slab is copied and flattened whole, so a slab row is whole lane
+    tiles (128: a head of whole tiles, or ``f`` positions of a head that
+    divides one, :func:`paged_decode_lane_pack`) and the page, ``f``
+    positions a row, whole sublane tiles of the cache's dtype (8 rows of 32
+    bits: 16 for bf16).  What fails this runs the S = 1 row of the ragged
+    paged kernel under ``"pallas"`` and XLA under ``"auto"``."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    return head_dim % 128 == 0 and page % sublanes == 0
+    f = paged_decode_lane_pack(head_dim)
+    return (f * head_dim) % 128 == 0 and page % (f * sublanes) == 0
+
+
+def lane_dense_pool(pool_side: jax.Array) -> jax.Array:
+    """One side of the pool as the paged decode kernel reads it:
+    ``[L, N, K, page / f, f * hd]``, row r of a page holding positions
+    ``f * r .. f * r + f - 1`` side by side.
+
+    At ``f == 1``, and for shapes outside :func:`paged_decode_in_place_ok`,
+    this IS the pool (the same array, no operation).  For a narrower head it
+    is a relayout.  A ``[L, N, K, page, 64]`` array does not lie row-major
+    and lane-dense in a TPU's HBM: compiled for the v5e, the engine's
+    programs hold the pool with the page INDEX minor-most, the row-major
+    layout Mosaic asks of an operand pads the last dimension to 128 lanes,
+    and a ``[K, page, 64]`` slab cannot be sliced out of that (PERF.md
+    section 6, PR 28).  The copy reads and writes the whole side, so a
+    caller in a loop makes it ONCE, outside: the engine does, per dispatch
+    (``InferenceEngine._decode_fn_paged``).  No scope of its own: XLA's copy
+    keeps none, and a device trace lists it under ``(unscoped) copy``."""
+    L, N, K, page, hd = pool_side.shape
+    f = paged_decode_lane_pack(hd)
+    if f == 1 or not paged_decode_in_place_ok(hd, page, pool_side.dtype):
+        return pool_side
+    return pool_side.reshape(L, N, K, page // f, f * hd)
 
 
 # pages of one row folded per compute block of the paged decode kernel
@@ -345,13 +381,13 @@ def _paged_decode_kernel(
     q_ref, pool_k, pool_v,  # q block in VMEM; the pools stay in HBM
     o_ref, m_ref, z_ref,
     kbuf, vbuf, sems,
-    *, wpages: int, group: int,
+    *, wpages: int, group: int, pack: int,
 ):
     """One ROW of a paged decode step: a loop over that row's own live
     pages, ``ceil(len / page)`` of them, each fetched as its whole
-    ``[K, page, hd]`` slab (contiguous in the pool) by a double-buffered
-    async copy.  Nothing past the row's length is read or computed; a row
-    of length 0 starts no copy at all.
+    ``[K, page / f, f * hd]`` slab (contiguous in the pool) by a
+    double-buffered async copy.  Nothing past the row's length is read or
+    computed; a row of length 0 starts no copy at all.
 
     All K heads of a block are scored in ONE product: q is the row's
     ``[H, hd]`` (H = K * G, a whole sublane tile where G alone is not),
@@ -359,11 +395,21 @@ def _paged_decode_kernel(
     block-diagonal mask keeps query head h on the columns of its own kv
     head.  The masked columns weigh exactly 0 in ``p``, so the PV product
     over the same flattened axis is each head's own weighted sum.
+
+    ``pack`` (f) > 1 is a head narrower than a lane tile, read through
+    :func:`lane_dense_pool`: a slab row holds f positions side by side, and
+    the row's queries come f times over, copy j with q in lane block j and
+    zeros elsewhere.  Copy j's scores are then those of positions
+    ``f * c + j`` and lane block j of its PV rows their weighted sum (the
+    other blocks are dropped): the same body at ``f * H`` rows and 128
+    lanes, and the f partial results folded by the logsumexp law at the end.
     """
     b = pl.program_id(0)
-    P, K, page, hd = kbuf.shape[1:]
-    H = q_ref.shape[1]
-    C = P * K * page
+    P, K, rows, lanes = kbuf.shape[1:]  # a slab: rows of pack positions
+    page = rows * pack
+    H = q_ref.shape[1]  # pack copies of the row's query heads
+    heads = H // pack
+    C = P * K * rows
     layer = layer_ref[0]
     kv_len = lens_ref[b]
     n_pages = jnp.minimum(pl.cdiv(kv_len, page), wpages)
@@ -398,12 +444,14 @@ def _paged_decode_kernel(
     def _first():
         for_live_pages(0, 0, lambda dma: dma.start())
 
-    q = q_ref[0]  # [H, hd], the cache's dtype
-    scale = 1.0 / math.sqrt(hd)
+    q = q_ref[0]  # [H, lanes], the cache's dtype
+    scale = 1.0 / math.sqrt(lanes // pack)  # the law of the REAL head
     col = lax.broadcasted_iota(jnp.int32, (H, C), 1)
     row = lax.broadcasted_iota(jnp.int32, (H, C), 0)
-    own_head = (col // page) % K == row // group
-    col_pos = (col // (K * page)) * page + col % page  # position in block
+    own_head = (col // rows) % K == (row if pack == 1 else row % heads) // group
+    col_pos = (col // (K * rows)) * rows + col % rows  # slab row in block
+    if pack > 1:
+        col_pos = col_pos * pack + row // heads  # copy j: positions f*c + j
 
     def block(blk, carry):
         m_prev, z_prev, acc = carry
@@ -414,8 +462,8 @@ def _paged_decode_kernel(
             for_live_pages(blk + 1, 1 - slot, lambda dma: dma.start())
 
         for_live_pages(blk, slot, lambda dma: dma.wait())
-        k = kbuf[slot].reshape(C, hd)
-        v = vbuf[slot].reshape(C, hd)
+        k = kbuf[slot].reshape(C, lanes)
+        v = vbuf[slot].reshape(C, lanes)
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [H, C]
@@ -440,12 +488,39 @@ def _paged_decode_kernel(
             # the -1e29 floor of a fully masked row is where m starts
             jnp.full((H, 1), -1e29, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, hd), jnp.float32),
+            jnp.zeros((H, lanes), jnp.float32),
         ),
     )
+    if pack > 1:
+        # fold the copies (model.logsumexp_merge, unnormalized): copy j's
+        # weighted sum stays in ITS lane block, for the caller to add up
+        parts = [slice(j * heads, (j + 1) * heads) for j in range(pack)]
+        m_all = functools.reduce(jnp.maximum, [m[part] for part in parts])
+        lane_block = lax.broadcasted_iota(
+            jnp.int32, (heads, lanes), 1
+        ) // (lanes // pack)
+        z_all = jnp.zeros_like(m_all)
+        o_all = jnp.zeros((heads, lanes), jnp.float32)
+        for j, part in enumerate(parts):
+            w = jnp.exp(m[part] - m_all)
+            z_all = z_all + z[part] * w
+            o_all = jnp.where(lane_block == j, acc[part] * w, o_all)
+        m, z, acc = m_all, z_all, o_all
     o_ref[0] = acc
     m_ref[0] = m
     z_ref[0] = z
+
+
+def _lane_block_copies(q: jax.Array, f: int) -> jax.Array:
+    """``[B, H, hd]`` → ``[B, f * H, f * hd]``: f copies of a row's queries,
+    copy j's q in lane block j and zeros elsewhere (``f == 1``: q itself)."""
+    if f == 1:
+        return q
+    B, H, hd = q.shape
+    eye = jnp.eye(f, dtype=q.dtype)
+    return (q[:, None, :, None, :] * eye[None, :, None, :, None]).reshape(
+        B, f * H, f * hd
+    )
 
 
 @functools.partial(
@@ -453,8 +528,8 @@ def _paged_decode_kernel(
 )
 def paged_decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
-    pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing)
-    pool_v: jax.Array,
+    pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing),
+    pool_v: jax.Array,  # or its lane_dense_pool view
     layer: jax.Array,  # scalar int32 — which layer's pages to read
     tables: jax.Array,  # [B, Pmax] int32 block tables
     base_lens: jax.Array,  # [B]
@@ -470,11 +545,17 @@ def paged_decode_attention_pallas(
     the pool goes in whole and stays in HBM, the grid is the rows alone,
     and the work of a row follows ``base_lens[b]`` — ``wpages`` is only the
     static upper bound, so one kernel serves every window bucket.  K, V
-    and q meet the MXU in the cache's dtype with float32 accumulation."""
+    and q meet the MXU in the cache's dtype with float32 accumulation.
+
+    A head narrower than a lane tile is read through
+    :func:`lane_dense_pool`.  A caller in a loop passes that view, made
+    outside the loop; a pool passed as it lies is viewed here, per call."""
     B, K, G, hd = q.shape
     H = K * G
-    page = pool_k.shape[3]
-    if not paged_decode_in_place_ok(hd, page, pool_k.dtype):
+    pool_k, pool_v = lane_dense_pool(pool_k), lane_dense_pool(pool_v)
+    rows, lanes = pool_k.shape[3:]
+    f = lanes // hd
+    if not paged_decode_in_place_ok(hd, rows * f, pool_k.dtype):
         o, m, z = ragged_attention_paged_pallas(
             q[:, :, None], pool_k, pool_v, layer, tables, base_lens,
             base_lens, wpages=wpages, interpret=interpret,
@@ -482,7 +563,9 @@ def paged_decode_attention_pallas(
         return o[:, :, 0], m[:, :, 0], z[:, :, 0]
     _note_trace("paged_decode", interpret)
     P = max(1, min(pages_per_block, wpages))
-    kernel = functools.partial(_paged_decode_kernel, wpages=wpages, group=G)
+    kernel = functools.partial(
+        _paged_decode_kernel, wpages=wpages, group=G, pack=f
+    )
 
     def row_map(b, *_refs):
         return (b, 0, 0)
@@ -491,18 +574,18 @@ def paged_decode_attention_pallas(
         num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, hd), row_map),
+            pl.BlockSpec((1, f * H, lanes), row_map),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, hd), row_map),
+            pl.BlockSpec((1, H, lanes), row_map),
             pl.BlockSpec((1, H, 1), row_map),
             pl.BlockSpec((1, H, 1), row_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, P, K, page, hd), pool_k.dtype),
-            pltpu.VMEM((2, P, K, page, hd), pool_v.dtype),
+            pltpu.VMEM((2, P, K, rows, lanes), pool_k.dtype),
+            pltpu.VMEM((2, P, K, rows, lanes), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
@@ -510,7 +593,7 @@ def paged_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((B, H, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, lanes), jnp.float32),
             jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ),
@@ -524,8 +607,11 @@ def paged_decode_attention_pallas(
         jnp.asarray(layer, jnp.int32).reshape(1),
         tables.astype(jnp.int32),
         base_lens.astype(jnp.int32),
-        q.reshape(B, H, hd).astype(pool_k.dtype), pool_k, pool_v,
+        _lane_block_copies(q.reshape(B, H, hd).astype(pool_k.dtype), f),
+        pool_k, pool_v,
     )
+    if f > 1:
+        o = o.reshape(B, H, f, hd).sum(axis=2)  # the copies' lane blocks
     return (
         o.reshape(B, K, G, hd), m.reshape(B, K, G), z.reshape(B, K, G)
     )

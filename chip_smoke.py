@@ -22,13 +22,13 @@ bf16, random weights from ``--seed``):
 - pallas — the same engine configuration with ``attention_impl="pallas"``:
   kernels compiled by Mosaic (never interpreted, never XLA), same check.
 - serve-wide — heads of 128 (Llama-3-8B's widths, 4 layers) under
-  ``attention_impl="auto"``: on a chip ``paged_decode`` must resolve to
-  ``pallas`` and the kernel that reads live pages in place must have been
-  compiled by Mosaic; same checks.  (TinyLlama's heads are 64 wide, outside
-  that kernel's shape rule: its ``auto`` stays on XLA.)
+  ``attention_impl="auto"``; same checks.  Under ``auto`` on a chip, here
+  and in *serve* (TinyLlama's heads of 64, two positions a lane row),
+  ``paged_decode`` must resolve to ``pallas`` and the kernel that reads
+  live pages in place must have been compiled by Mosaic.
 - kernels — every Pallas kernel body, compiled, against its XLA reference
   at TinyLlama-1.1B and Llama-3-8B widths; the paged decode read in place
-  at Mistral-7B's widths, batch and window.
+  at Mistral-7B's and granite-4.0-h-micro's widths, batch and window.
 
 ``--rehearse`` shrinks everything and uses interpret mode on the CPU; it
 can never print ``"ok": true``.
@@ -422,35 +422,42 @@ def kernels_phase(seed: int, interpret: bool) -> dict:
         close("prefill", PA.prefill_attention_pallas(
             q, kc, vc, pos, plens, interpret=interpret),
             M.attention_xla(q, kc, vc, pos, plens))
-    # the paged decode read in place at Mistral-7B's widths: 32 rows, the
-    # 2048 window, row lengths around page edges, a row that reads nothing
-    K, G, hd = 8, 4, 128
-    B, W = (4, 128) if interpret else (32, 2048)
-    wpages = W // page
-    key = jax.random.split(jax.random.key(seed + 7), 3)
-    pool_k = jax.random.normal(key[0], (2, 1 + B * wpages, K, page, hd), bf)
-    pool_v = jax.random.normal(key[1], (2, 1 + B * wpages, K, page, hd), bf)
-    tables = jnp.asarray(1 + np.arange(B * wpages, dtype=np.int32).reshape(B, wpages))
-    lens = rng.integers(1, W, size=B)
-    lens[:4] = (0, page - 1, page + 1, W)
-    lens = jnp.asarray(lens, jnp.int32)
-    q = jax.random.normal(key[2], (B, K, G, hd), bf)
-    o, m, z = PA.paged_decode_attention_pallas(
-        q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=wpages,
-        interpret=interpret)
-    o_x, m_x, z_x = M.masked_attention_source(
-        q, M.gather_window_paged(pool_k[1], tables, wpages),
-        M.gather_window_paged(pool_v[1], tables, wpages),
-        jnp.arange(W)[None, :] < lens[:, None])
-    close("paged-decode-in-place", o / jnp.maximum(z[..., None], 1e-30),
-          o_x / jnp.maximum(z_x, 1e-30))
-    close("paged-decode-in-place/m", m, m_x[..., 0])
+    # the paged decode read in place at Mistral-7B's widths (32 rows) and
+    # granite-4.0-h-micro's (64 rows, heads of 64: two positions a lane
+    # row): the 2048 window, row lengths around page edges, a row that
+    # reads nothing
+    for name, (K, G, hd, rows) in {
+        "": (8, 4, 128, 32), "/hd64": (8, 4, 64, 64),
+    }.items():
+        B, W = (4, 128) if interpret else (rows, 2048)
+        wpages = W // page
+        key = jax.random.split(jax.random.key(seed + 7), 3)
+        pool_k = jax.random.normal(key[0], (2, 1 + B * wpages, K, page, hd), bf)
+        pool_v = jax.random.normal(key[1], (2, 1 + B * wpages, K, page, hd), bf)
+        tables = jnp.asarray(
+            1 + np.arange(B * wpages, dtype=np.int32).reshape(B, wpages))
+        lens = rng.integers(1, W, size=B)
+        lens[:4] = (0, page - 1, page + 1, W)
+        lens = jnp.asarray(lens, jnp.int32)
+        q = jax.random.normal(key[2], (B, K, G, hd), bf)
+        o, m, z = PA.paged_decode_attention_pallas(
+            q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=wpages,
+            interpret=interpret)
+        o_x, m_x, z_x = M.masked_attention_source(
+            q, M.gather_window_paged(pool_k[1], tables, wpages),
+            M.gather_window_paged(pool_v[1], tables, wpages),
+            jnp.arange(W)[None, :] < lens[:, None])
+        close(f"paged-decode-in-place{name}",
+              o / jnp.maximum(z[..., None], 1e-30),
+              o_x / jnp.maximum(z_x, 1e-30))
+        close(f"paged-decode-in-place{name}/m", m, m_x[..., 0])
     tol = 3e-2  # bf16 inputs and outputs, values O(1)
     return {
         "phase": "kernels", "ok": all(e < tol for e in worst.values()),
         "max_abs_err_vs_xla": {k: round(v, 5) for k, v in worst.items()},
         "tolerance": tol,
-        "widths": ["tinyllama-1.1b", "llama-3-8b", "mistral-7b (paged decode)"],
+        "widths": ["tinyllama-1.1b", "llama-3-8b",
+                   "mistral-7b, granite-4.0-h-micro (paged decode)"],
         "mode": "interpreted" if interpret else "compiled",
         "compile_s": round(_compile_s[0] - c0, 2),
         "seconds": round(time.perf_counter() - t0, 2),
@@ -510,7 +517,7 @@ async def run_one_chip(args, sz: dict) -> bool:
     config = preset(sz["preset"], max_seq_len=sz["seq"])
     pallas = "pallas_interpret" if args.rehearse else "pallas"
     ok = True
-    xla_outputs = None
+    auto_outputs = None
     want = "interpreted" if args.rehearse else "compiled"
     # a CPU has no "auto" that selects a kernel: the rehearsal asks for it
     wide_impl = pallas if args.rehearse else "auto"
@@ -535,8 +542,8 @@ async def run_one_chip(args, sz: dict) -> bool:
             sz["agree_new"], args.margin, sz["pad_to"],
         )
         if phase == "serve":
-            xla_outputs = outputs
-        else:
+            auto_outputs = outputs
+        if impl != "auto" or not args.rehearse:  # a CPU's "auto" is XLA
             traces = {f"{k}:{mode}": n for (k, mode), n in PA.KERNEL_TRACES.items()}
             row["kernel_traces"] = agree["kernel_traces"] = traces
             kernels_ok = bool(traces) and all(key.endswith(want) for key in traces)
@@ -545,7 +552,7 @@ async def run_one_chip(args, sz: dict) -> bool:
                     v == pallas for v in row["attention_impl"].values()
                 )
                 agree["kernels_all_" + want] = kernels_ok
-                agree["equal_to_xla_engine"] = count_equal(xla_outputs, outputs)
+                agree["equal_to_auto_engine"] = count_equal(auto_outputs, outputs)
             else:
                 kernels_ok = (
                     kernels_ok

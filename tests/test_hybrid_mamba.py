@@ -424,3 +424,72 @@ def test_the_dense_description_is_what_it_was():
     assert g.layer_period == ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
     assert (g.n_mamba_layers, g.n_kv_layers) == (36, 4)
     assert g.recurrent_state_bytes(64) == 4831838208 + 60162048
+
+
+# ------------------------------------- heads of 64: the paged decode read in place
+# the toy at granite's head width: two positions of a kv head a lane row in
+# the kernel's view of the pool, a float32 page of 16 a view of 8 rows
+TOY64 = replace(TOY, name="toy-hybrid-64", d_model=256)
+assert TOY64.head_dim == 64
+
+
+def test_heads_of_64_serve_the_same_tokens_through_the_kernel():
+    """``pallas_interpret`` against ``xla`` on one engine configuration: more
+    requests than slots, so chunks ride live dispatches (the ragged
+    program's decode loop) and rows decode on alone (the decode program);
+    the tokens are equal and the kernel that reads live pages in place was
+    traced: not the ragged kernel's S = 1 row."""
+    from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
+
+    rt = dict(page_size=16, window_buckets=(64, 128))
+    requests = [(prompt_of(9 + 13 * i, seed=20 + i), 6 + 5 * i) for i in range(4)]
+    want, _, _ = serve((TOY64, runtime(**rt)), requests, sequential=False)
+    before = KERNEL_TRACES["paged_decode", "interpreted"]
+    ragged_before = KERNEL_TRACES["ragged_paged", "interpreted"]
+    got, _, counters = serve(
+        (TOY64, runtime(attention_impl="pallas_interpret", **rt)), requests, sequential=False)
+    assert got == want
+    assert [len(out) for out in got] == [n for _, n in requests]
+    assert KERNEL_TRACES["paged_decode", "interpreted"] > before
+    assert KERNEL_TRACES["ragged_paged", "interpreted"] == ragged_before
+    assert 0 < counters["unified_dispatches"] < counters["decode_dispatches"]
+    assert 0 < counters["decode_pages_live"] < counters["decode_pages_window"]
+
+
+@pytest.mark.parametrize(
+    "platform,devices,config,page,want",
+    [
+        pytest.param("tpu", 1, TOY64, 16, "pallas", id="tpu-float32-page16"),
+        pytest.param("tpu", 1, replace(TOY64, dtype="bfloat16"), 32, "pallas", id="tpu-bf16-page32"),
+        # a view of 8 rows of bf16, of 4 rows of float32: under a sublane tile
+        pytest.param("tpu", 1, replace(TOY64, dtype="bfloat16"), 16, "xla", id="tpu-bf16-page16"),
+        pytest.param("tpu", 1, TOY64, 8, "xla", id="tpu-float32-page8"),
+        # heads of 8: sixteen positions a lane row want pages of 128
+        pytest.param("tpu", 1, TOY, 8, "xla", id="tpu-heads-of-8"),
+        pytest.param("tpu", 2, TOY64, 16, "xla", id="tpu-two-devices"),
+        pytest.param("cpu", 1, TOY64, 16, "xla", id="cpu"),
+    ],
+)
+def test_the_paged_decode_read_is_selected_by_platform_and_shape(
+    monkeypatch, platform, devices, config, page, want
+):
+    """``_resolved_attn_impl("paged_decode")`` under "auto" answers from the
+    platform, the mesh's size, the head, the page and the cache's dtype:
+    a dense model of the same head and page gets the hybrid's answer."""
+    from types import SimpleNamespace
+
+    real = jax.devices()
+    dense = ModelConfig(
+        name="toy-dense", vocab_size=128, d_model=config.d_model, n_layers=2,
+        n_heads=config.n_heads, n_kv_heads=config.n_kv_heads, d_ff=64, dtype=config.dtype,
+        max_seq_len=1024,
+    )
+    answers = []
+    for c in (config, dense):
+        engine = InferenceEngine(c, runtime(page_size=page, prefill_chunk=32, window_buckets=(128,)))
+        monkeypatch.setattr(engine, "mesh", SimpleNamespace(size=devices))
+        monkeypatch.setattr(
+            jax, "devices", lambda *a: [SimpleNamespace(platform=platform)] if not a else real)
+        answers.append(engine._resolved_attn_impl("paged_decode"))
+        monkeypatch.setattr(jax, "devices", lambda *a: real)
+    assert answers == [want, want]

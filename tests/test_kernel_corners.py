@@ -184,10 +184,13 @@ class TestNodeDecodeFloor:
 
 # --------------------------------------------------------------------------- #
 # the paged decode attention kernel (pallas_attention._paged_decode_kernel),
-# interpret mode, at Mistral's head shape: K 8, G 4, hd 128, page 64
+# interpret mode, at the head shapes of Mistral (K 8, G 4, hd 128), granite
+# (K 8, G 4, hd 64) and TinyLlama (K 4, G 8, hd 64); page 64.  Heads of 64
+# are read two positions a lane row (pallas_attention.lane_dense_pool).
 # --------------------------------------------------------------------------- #
 
-PD_K, PD_G, PD_HD, PD_PAGE, PD_WPAGES, PD_PMAX = 8, 4, 128, 64, 4, 6
+PD_WIDTHS = {"mistral": (8, 4, 128), "granite": (8, 4, 64), "tinyllama": (4, 8, 64)}
+PD_PAGE, PD_WPAGES, PD_PMAX = 64, 4, 6
 PD_WINDOW = PD_WPAGES * PD_PAGE
 
 # name -> (row lengths, rows that are inactive, (row, row) sharing a table)
@@ -197,6 +200,7 @@ PAGED_DECODE_CASES = {
     "page-minus-1": ([PD_PAGE - 1], (), None),
     "page": ([PD_PAGE], (), None),
     "page-plus-1": ([PD_PAGE + 1], (), None),
+    "odd": ([33, 191], (), None),
     "full-window": ([PD_WINDOW], (), None),
     "mixed": ([0, 1, PD_PAGE - 1, PD_PAGE, PD_PAGE + 1, PD_WINDOW, 130, 17], (), None),
     "inactive-row-on-trash-page": ([70, 100, 9], (1,), None),
@@ -204,7 +208,7 @@ PAGED_DECODE_CASES = {
 }
 
 
-def _paged_decode_case(name: str, dtype, seed: int = 0):
+def _paged_decode_case(name: str, dtype, seed: int = 0, widths: str = "mistral"):
     """(q, pool_k, pool_v, tables, lens, live) for one named case: every
     row's pages are its own (page 0 is the trash page), an inactive row's
     table is all trash and its length 0 as ``decode_step_ring_paged``
@@ -212,6 +216,7 @@ def _paged_decode_case(name: str, dtype, seed: int = 0):
     import jax.numpy as jnp
     import numpy as np
 
+    K, G, hd = PD_WIDTHS[widths]
     lens, inactive, shared = PAGED_DECODE_CASES[name]
     lens = list(lens)
     B = len(lens)
@@ -231,10 +236,10 @@ def _paged_decode_case(name: str, dtype, seed: int = 0):
     live = np.zeros((n_pages,), bool)
     for b, n in enumerate(lens):
         live[tables[b, : -(-n // PD_PAGE)]] = True
-    shape = (2, n_pages, PD_K, PD_PAGE, PD_HD)
+    shape = (2, n_pages, K, PD_PAGE, hd)
     pool_k = rng.standard_normal(shape).astype(np.float32)
     pool_v = rng.standard_normal(shape).astype(np.float32)
-    q = jnp.asarray(rng.standard_normal((B, PD_K, PD_G, PD_HD)), dtype)
+    q = jnp.asarray(rng.standard_normal((B, K, G, hd)), dtype)
     return (
         q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lens, jnp.int32),
         live,
@@ -266,12 +271,13 @@ def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
 class TestPagedDecodeKernelCorners:
     @pytest.mark.parametrize("pages_per_block", [1, 2])
     @pytest.mark.parametrize("case", sorted(PAGED_DECODE_CASES))
-    def test_matches_gathered_window(self, case, pages_per_block):
+    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
+    def test_matches_gathered_window(self, widths, case, pages_per_block):
         import jax.numpy as jnp
         import numpy as np
 
         q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            case, jnp.float32
+            case, jnp.float32, widths=widths
         )
         got, want = _paged_decode_both(
             q, jnp.asarray(pool_k), jnp.asarray(pool_v), tables, lens,
@@ -290,14 +296,15 @@ class TestPagedDecodeKernelCorners:
         assert (np.asarray(got[2])[empty] == 0).all()
 
     @pytest.mark.parametrize("case", ["mixed", "shared-table"])
-    def test_bf16_operands_f32_accumulation(self, case):
+    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
+    def test_bf16_operands_f32_accumulation(self, widths, case):
         """The configuration's precision: bf16 q, K, V into the products,
         float32 scores, statistics and accumulator."""
         import jax.numpy as jnp
         import numpy as np
 
         q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            case, jnp.bfloat16
+            case, jnp.bfloat16, widths=widths
         )
         got, want = _paged_decode_both(
             q, jnp.asarray(pool_k, jnp.bfloat16),
@@ -317,7 +324,8 @@ class TestPagedDecodeKernelCorners:
     @pytest.mark.parametrize(
         "case", ["mixed", "inactive-row-on-trash-page", "shared-table"]
     )
-    def test_dead_pages_are_never_read(self, case, pages_per_block):
+    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
+    def test_dead_pages_are_never_read(self, widths, case, pages_per_block):
         """Every page no row's length reaches — the trash page, the tail of
         each table, the unused pages of the pool, both layers' — is NaN;
         the result is finite and equal to the clean pool's.  The XLA
@@ -326,7 +334,7 @@ class TestPagedDecodeKernelCorners:
         import numpy as np
 
         q, pool_k, pool_v, tables, lens, live = _paged_decode_case(
-            case, jnp.float32, seed=5
+            case, jnp.float32, seed=5, widths=widths
         )
         dirty_k, dirty_v = pool_k.copy(), pool_v.copy()
         dirty_k[:, ~live] = np.nan
@@ -353,9 +361,17 @@ class TestPagedDecodeKernelCorners:
             (128, 64, "bfloat16", True),
             (128, 16, "bfloat16", True),
             (256, 8, "float32", True),
-            (64, 64, "bfloat16", False),  # half a lane tile (TinyLlama)
+            (64, 64, "bfloat16", True),  # two positions a lane row (TinyLlama)
+            (64, 32, "bfloat16", True),  # ... on a packed page of 16 rows
+            (32, 64, "bfloat16", True),  # four positions a lane row
+            (64, 16, "float32", True),
             (128, 8, "bfloat16", False),  # half a packed sublane tile
             (128, 16, "int8", False),
+            (64, 16, "bfloat16", False),  # a packed page under a sublane tile
+            (64, 8, "float32", False),
+            (96, 64, "bfloat16", False),  # a head that does not divide 128
+            (80, 64, "float32", False),
+            (192, 64, "bfloat16", False),  # nor is whole lane tiles
         ],
     )
     def test_shape_rule(self, head_dim, page, dtype, ok):
@@ -365,8 +381,47 @@ class TestPagedDecodeKernelCorners:
 
         assert paged_decode_in_place_ok(head_dim, page, dtype) is ok
 
+    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
+    def test_a_view_made_by_the_caller_is_read_as_it_lies(self, widths):
+        """The engine makes ``lane_dense_pool`` once a dispatch and hands it
+        down: the kernel's result is bit for bit that of the pool as it
+        lies.  For whole lane tiles, and outside the shape rule, the view
+        IS the pool: the same array, no operation."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from calfkit_tpu.inference import pallas_attention as PA
+
+        q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
+            "mixed", jnp.bfloat16, widths=widths
+        )
+        pool_k = jnp.asarray(pool_k, jnp.bfloat16)
+        pool_v = jnp.asarray(pool_v, jnp.bfloat16)
+        view_k, view_v = PA.lane_dense_pool(pool_k), PA.lane_dense_pool(pool_v)
+        hd = pool_k.shape[-1]
+        if hd % 128 == 0:
+            assert view_k is pool_k and view_v is pool_v
+        else:
+            f = PA.paged_decode_lane_pack(hd)
+            assert view_k.shape == (*pool_k.shape[:3], PD_PAGE // f, 128)
+            # row r of a page: positions f * r .. f * r + f - 1 side by side
+            np.testing.assert_array_equal(
+                np.asarray(view_k[1, 2, 3, 5], np.float32),
+                np.asarray(pool_k[1, 2, 3, 5 * f:(5 + 1) * f], np.float32).ravel(),
+            )
+        kw = dict(wpages=PD_WPAGES, interpret=True)
+        got = PA.paged_decode_attention_pallas(
+            q, view_k, view_v, jnp.int32(1), tables, lens, **kw)
+        want = PA.paged_decode_attention_pallas(
+            q, pool_k, pool_v, jnp.int32(1), tables, lens, **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        small = jnp.zeros((1, 3, 2, 16, 64), jnp.bfloat16)  # outside the rule
+        assert PA.lane_dense_pool(small) is small
+
     def test_other_shapes_keep_the_ragged_row(self):
-        """hd 64 is outside the shape rule: explicit "pallas" still runs a
+        """hd 64 on pages of 8 (a packed page of 4 rows, under a sublane
+        tile) is outside the shape rule: explicit "pallas" still runs a
         kernel there, the S = 1 row of the ragged paged one."""
         import jax.numpy as jnp
         import numpy as np

@@ -32,6 +32,8 @@ WPAGES = W // PAGE
 DECODE_PAGED_WIDTHS = {
     "mistral-7b-v0.3": (8, 4, 128, 32, 2048, 513, 32),
     "internlm2-1.8b": (8, 2, 128, 64, 4096, 897, 24),
+    # heads of 64: two positions a lane row (pallas_attention.lane_dense_pool)
+    "granite-4.0-h-micro": (8, 4, 64, 64, 2048, 1281, 4),
 }
 SPEC_S = 5  # verify: k + 1 queries at the default k = 4
 CHUNK_S = 128  # one prefill chunk
@@ -179,6 +181,77 @@ def test_paged_decode_in_place_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
     # the body of its own, not the ragged kernel's S = 1 row
     assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Compiled HLO text → computation name → its instruction lines; the
+    entry computation under ``"ENTRY"``."""
+    import re
+
+    out: dict[str, list[str]] = {}
+    name = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
+    one_chip, no_persistent_cache
+):
+    """The decode DISPATCH program (``_decode_fn_paged``, 8 steps) of a toy
+    hybrid at heads of 64, compiled for the described v5e: the kernel is in
+    it, no ``gather_window`` scope is, and the lane-dense view of the pool
+    is made at most once a side, in the entry computation: outside the
+    step loop and the layer scan, where the pool is a constant."""
+    import re
+
+    import jax
+
+    from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    config = ModelConfig(
+        name="toy-hybrid-64", vocab_size=128, d_model=512, n_layers=3, n_heads=8,
+        n_kv_heads=2, d_ff=128, layer_types=("mamba", "mamba", "attention"),
+        mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16, mamba_n_groups=2,
+        mamba_d_conv=4, mamba_chunk_size=8, dtype="bfloat16",
+        position_embedding="none", attention_multiplier=0.125, max_seq_len=256,
+    )
+    page, steps = 32, 8
+    engine = InferenceEngine(config, RuntimeConfig(
+        max_batch_size=4, max_seq_len=256, kv_layout="paged", page_size=page,
+        chunked_prefill=True, prefill_chunk=32, window_buckets=(256,),
+        compilation_cache=False, decode_steps_per_dispatch=steps,
+        attention_impl="pallas",
+    ))
+    assert config.head_dim == 64
+    args, window, _, sampled = engine._decode_args()
+    abstract = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (*args, engine._state),
+    )
+    hlo = jax.jit(
+        engine._decode_fn_paged(window // page, steps, sampled),
+        donate_argnums=(1, 2, 14),
+    ).lower(*abstract).compile().as_text()
+    assert "tpu_custom_call" in hlo and "paged_decode_attention" in hlo
+    assert "gather_window" not in hlo
+    L, N, K, _, hd = engine._k.shape
+    view = re.compile(rf"= bf16\[{L},{N},{K},{page * hd // 128},128\]\S* (\w[\w\-]*)\(")
+    made = {
+        name: [m.group(1) for m in map(view.search, lines)
+               if m and m.group(1) not in ("bitcast", "parameter", "get-tuple-element")]
+        for name, lines in _computations(hlo).items()
+    }
+    assert 1 <= len(made.pop("ENTRY")) <= 2  # K and V, once a dispatch
+    assert not any(made.values()), made  # never in a loop's body
 
 
 def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
