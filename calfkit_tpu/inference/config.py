@@ -239,7 +239,13 @@ class RuntimeConfig:
     # whole bucket (vLLM-style chunked prefill; inter-token latency of
     # active streams stays bounded by one chunk + one tick)
     chunked_prefill: bool = False
-    attention_impl: str = "auto"  # auto | xla | pallas | pallas_interpret
+    # what the PAGED DECODE READ runs, the one attention computation with a
+    # Pallas kernel (all else is XLA): "auto" takes the kernel on a TPU,
+    # paged KV, one device, a head and page shape it reads in place, else
+    # XLA; "xla" is the reference; "pallas" / "pallas_interpret" are for
+    # tests and bring-up and are refused outside that rule
+    # (InferenceEngine._resolved_attn_impl)
+    attention_impl: str = "auto"
     # long-context lane: prompts that cannot fit a short-lane slot
     # (len >= max_seq_len) are served via sequence-parallel ring prefill
     # over an `sp` mesh of ALL the engine's devices + context-parallel

@@ -118,9 +118,6 @@ _SYNCED_FIELDS = (
     "overlap_wasted_tokens", *_LOCAL_FIELDS,
 )
 
-_ATTN_PROFILE_CACHE: "tuple[tuple, dict | None] | None" = None
-
-
 # process-wide active-request aggregation: the shared gauge must report
 # the SUM across live engines, not the last dispatching engine's count
 # (updated per dispatch; entries removed at engine stop / GC).  The lock
@@ -243,38 +240,6 @@ def _engine_metrics(
             _SECONDS_HELP[name],
         )
     return out
-
-
-def _load_attn_profile() -> dict | None:
-    """The attention-impl profile artifact (written by
-    scripts/profile_attention.py --out on hardware): per-path winners that
-    ``attention_impl="auto"`` resolves with.  Location: $CALFKIT_ATTN_PROFILE
-    and nowhere else — unset, "auto" is "xla".  Cached by (path, mtime)."""
-    global _ATTN_PROFILE_CACHE
-    import json
-    import os
-
-    path = os.environ.get("CALFKIT_ATTN_PROFILE")
-    if not path:
-        return None
-    try:
-        key = (path, os.stat(path).st_mtime_ns)
-    except OSError:
-        return None
-    if _ATTN_PROFILE_CACHE is not None and _ATTN_PROFILE_CACHE[0] == key:
-        return _ATTN_PROFILE_CACHE[1]
-    try:
-        # blocking-ok: jit-specialization build path — runs once per shape
-        # bucket when a new jit is traced (result cached by path+mtime),
-        # never per decode tick
-        with open(path) as f:
-            verdict = json.load(f)
-        if not isinstance(verdict, dict):
-            verdict = None
-    except (OSError, json.JSONDecodeError):
-        verdict = None
-    _ATTN_PROFILE_CACHE = (key, verdict)
-    return verdict
 
 
 @hotpath
@@ -861,8 +826,8 @@ class InferenceEngine:
             )
         if rt.attention_impl not in ("auto", "xla", "pallas", "pallas_interpret"):
             raise ValueError(
-                f"unsupported attention_impl {rt.attention_impl!r} "
-                "(auto | xla | pallas | pallas_interpret)"
+                f"unsupported attention_impl {rt.attention_impl!r}: the "
+                "paged decode read is auto | xla | pallas | pallas_interpret"
             )
         if rt.max_prefill_wave < 1:
             raise ValueError("max_prefill_wave must be >= 1")
@@ -894,6 +859,7 @@ class InferenceEngine:
                 f"unsupported kv_layout {rt.kv_layout!r} (dense | paged)"
             )
         self._paged = rt.kv_layout == "paged"
+        self._attn_impl = self._resolved_attn_impl()
         if self._paged:
             from calfkit_tpu.inference.paged import PageAllocator
             from calfkit_tpu.inference.sharding import pool_sharding
@@ -1170,64 +1136,56 @@ class InferenceEngine:
             )
 
     # ------------------------------------------------------------ jit build
-    def _resolved_attn_impl(
-        self, path: str = "decode", fallback: "str | None" = None
-    ) -> str:
-        """Resolve ``attention_impl`` for one jit path (``prefill`` /
-        ``decode`` / ``paged_decode`` / ``ragged`` / ``paged_ragged``).
+    def _resolved_attn_impl(self) -> str:
+        """Which implementation the PAGED DECODE READ uses: the one
+        attention computation that has a kernel.  Decided HERE and nowhere
+        else, once at construction (``self._attn_impl``), from what the
+        engine can observe (PERF.md section 6, PRs 25 and 28: measured on
+        the v5e).  Under "auto": the Pallas kernel that reads each row's
+        live pages in place when the backend is a TPU, KV is paged, one
+        device holds the model (``tp == 1`` and ``dp == 1``: a
+        ``pallas_call`` under GSPMD needs a ``shard_map`` over the KV heads
+        first) and the head and page shapes are the kernel's
+        (:func:`pallas_attention.paged_decode_in_place_ok`: a head of whole
+        lane tiles, or one that divides a lane tile, such as 64); else XLA,
+        the reference.
 
-        ``paged_decode`` under "auto" is decided by what the engine can
-        OBSERVE (PERF.md section 6, PR 25: measured on the v5e): the
-        Pallas kernel that reads each row's live pages in place when the
-        backend is a TPU, one device holds the model (``tp == 1`` and
-        ``dp == 1``: a ``pallas_call`` under GSPMD needs a ``shard_map``
-        over the KV heads first) and the head and page shapes are the
-        kernel's (:func:`pallas_attention.paged_decode_in_place_ok`: a
-        head of whole lane tiles, or one that divides a lane tile, such
-        as 64); else XLA.
-
-        The other paths under "auto" are EVIDENCE-BASED (VERDICT r3 item
-        8): they read the profile
-        artifact ``scripts/profile_attention.py --out`` writes on hardware
-        and flip to the per-path winner, but only when the artifact's
-        platform matches the live backend (a TPU verdict must not steer a
-        CPU run and vice versa).  No artifact, or no verdict for this path
-        → the ``fallback`` path's winner (the ragged multi-query paths
-        fall back to their legacy single-query twin, so a pre-ragged
-        artifact keeps steering), else XLA, the safe default.
-        "pallas"/"pallas_interpret" opt in explicitly everywhere."""
+        "pallas" / "pallas_interpret" are for tests and bring-up: they
+        waive the platform test alone.  Outside the rest of the rule there
+        is no kernel to build, and the engine is refused."""
         impl = self.runtime.attention_impl
-        if impl != "auto":
-            return impl
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:  # noqa: BLE001 - backend probe must not break jit build
+        explicit = impl.startswith("pallas")
+        if not explicit and not (
+            impl == "auto" and jax.devices()[0].platform == "tpu"
+        ):
             return "xla"
-        if path == "paged_decode":
-            from calfkit_tpu.inference.pallas_attention import (
-                paged_decode_in_place_ok,
-            )
+        from calfkit_tpu.inference.pallas_attention import (
+            PallasShapeError,
+            paged_decode_in_place_ok,
+        )
 
-            in_place = (
-                platform == "tpu"
-                and self._paged
-                and self.mesh.size == 1
-                and paged_decode_in_place_ok(
-                    self.config.head_dim, self.runtime.page_size,
-                    self.config.dtype,
-                )
+        in_rule = (
+            self._paged
+            and self.mesh.size == 1
+            and paged_decode_in_place_ok(
+                self.config.head_dim, self.runtime.page_size, self.config.dtype
             )
-            return "pallas" if in_place else "xla"
-        verdict = _load_attn_profile()
-        if not verdict:
-            return "xla"
-        if verdict.get("platform") != platform:
-            return "xla"
-        winners = verdict.get("winners") or {}
-        winner = winners.get(path)
-        if winner is None and fallback is not None:
-            winner = winners.get(fallback)
-        return winner if winner in ("xla", "pallas", "pallas_interpret") else "xla"
+        )
+        if impl == "auto":
+            return "pallas" if in_rule else "xla"
+        if not in_rule:
+            raise PallasShapeError(
+                f"attention_impl={impl!r} names the paged decode kernel, "
+                "which takes kv_layout='paged', one device (tp == dp == 1) "
+                "and a head and page of whole tiles "
+                "(pallas_attention.paged_decode_in_place_ok); this engine "
+                f"has kv_layout={self.runtime.kv_layout!r} on "
+                f"{self.mesh.size} device(s), head_dim="
+                f"{self.config.head_dim}, page_size="
+                f"{self.runtime.page_size}, {jnp.dtype(self.config.dtype).name}"
+                ': use "auto" or "xla"'
+            )
+        return impl
 
     def _window_bucket(self, needed: int) -> int:
         """Smallest configured window ≥ needed (cap max_seq): the decode
@@ -1261,7 +1219,6 @@ class InferenceEngine:
         two compile the identical subgraph (ragged-on parity is structural,
         not coincidental)."""
         cfg = self.config
-        attn_impl = self._resolved_attn_impl("decode")
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, last, lens, active, done_prev,
@@ -1294,7 +1251,6 @@ class InferenceEngine:
                 ring, last, *st = carry
                 logits, ring, *st = M.decode_step_ring(
                     params, cfg, last[:, None], (kw, vw), ring, t, lens,
-                    attn_impl=attn_impl,
                     **({"state": st[0], "active": active} if st else {}),
                 )
                 if sampled:
@@ -1345,7 +1301,7 @@ class InferenceEngine:
         """The paged decode dispatch body (untraced) — see
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
-        attn_impl = self._resolved_attn_impl("paged_decode")
+        attn_impl = self._attn_impl
         from calfkit_tpu.inference.pallas_attention import lane_dense_pool
 
         @jax.named_scope("decode_loop")
@@ -1421,10 +1377,6 @@ class InferenceEngine:
         if fn is not None:
             return fn
         cfg = self.config
-        # the verify dispatch runs the RAGGED multi-query kernel (one
-        # window read for all S positions) — "auto" resolves it on the
-        # ragged profile rows, falling back to the legacy decode verdict
-        attn_impl = self._resolved_attn_impl("ragged", fallback="decode")
 
         @jax.named_scope("verify")
         def verify(params, k, v, last, lens, active, drafts, ndraft,
@@ -1433,7 +1385,7 @@ class InferenceEngine:
             vw = v[:, :, :, :window]
             tokens = jnp.concatenate([last[:, None], drafts], axis=1)
             logits, ring = M.verify_step_ring(
-                params, cfg, tokens, (kw, vw), lens, attn_impl=attn_impl
+                params, cfg, tokens, (kw, vw), lens
             )
             out_toks, emitted = spec_accept_slots(
                 logits, drafts, ndraft, lens, slot_keys, temp, top_k,
@@ -1468,9 +1420,6 @@ class InferenceEngine:
         if fn is not None:
             return fn
         cfg = self.config
-        attn_impl = self._resolved_attn_impl(
-            "paged_ragged", fallback="paged_decode"
-        )
 
         @jax.named_scope("verify")
         def verify(params, k, v, tables, last, lens, active, drafts,
@@ -1478,8 +1427,7 @@ class InferenceEngine:
                    top_p):
             tokens = jnp.concatenate([last[:, None], drafts], axis=1)
             logits, ring = M.verify_step_ring_paged(
-                params, cfg, tokens, (k, v), tables, lens,
-                wpages=wpages, attn_impl=attn_impl,
+                params, cfg, tokens, (k, v), tables, lens, wpages=wpages
             )
             out_toks, emitted = spec_accept_slots(
                 logits, drafts, ndraft, lens, slot_keys, temp, top_k,
@@ -1585,7 +1533,6 @@ class InferenceEngine:
         if fn is not None:
             return fn
         cfg = self.config
-        attn_impl = self._resolved_attn_impl("prefill")
 
         def prefill(
             params, k, v, last, lens, tokens, slots, true_lens,
@@ -1604,7 +1551,7 @@ class InferenceEngine:
             with jax.named_scope("prefill"):
                 logits, (sk, sv), *wstate = M.forward(
                     params, cfg, tokens, pos, scratch,
-                    jnp.full((R,), P, jnp.int32), attn_impl=attn_impl,
+                    jnp.full((R,), P, jnp.int32),
                     **({} if state is None else {
                         "state": make_recurrent_state(cfg, R), "n_valid": true_lens}),
                 )
@@ -1649,7 +1596,6 @@ class InferenceEngine:
         per-row positions/lens ARE the (kind, start, q_len, kv_len)
         descriptor, serialized as arrays)."""
         cfg = self.config
-        attn_impl = self._resolved_attn_impl("prefill")
 
         @jax.named_scope("chunk_loop")
         def chunk_step(params, sk, sv, tokens_chunk, offset,
@@ -1665,7 +1611,6 @@ class InferenceEngine:
             # the row's own
             logits, (sk, sv), *wstate = M.forward(
                 params, cfg, tokens_chunk, pos, (sk, sv), lens,
-                attn_impl=attn_impl,
                 **({} if wstate is None else {
                     "state": wstate,
                     "n_valid": jnp.clip(true_lens - offset, 0, chunk)}),

@@ -10,8 +10,8 @@ TPU-first design decisions (NOT a port of any torch modeling file):
   time is O(1) in depth and XLA schedules one fused layer body;
 - KV cache updates are functional (``dynamic_update_slice``) — the engine
   owns cache buffers and threads them through jit;
-- attention is GQA with a pluggable core: the XLA einsum path (fallback,
-  differentiable, CPU-testable) or the Pallas paged kernel (decode hot path).
+- attention is GQA on XLA einsums (the reference, CPU-testable); the ONE
+  kernel is the Pallas paged decode read (:func:`decode_step_ring_paged`).
 
 Weight layout (per layer, stacked on axis 0 across layers):
     attn: wq [L, D, H, hd], wk/wv [L, D, K, hd], wo [L, H, hd, D]
@@ -344,7 +344,7 @@ def attention_xla(
     """GQA attention over the cache, masked by position/length.
 
     The XLA path: one batched einsum pair the compiler fuses tightly; used
-    for prefill everywhere and decode when the Pallas kernel is off.
+    for prefill, chunks and the long-context lane.
     The cache is kv-head-major ([B, K, S, hd]) so each head's scan over S is
     a contiguous HBM stream, and accumulation is fp32 via
     ``preferred_element_type`` — the bf16 cache is never materialized as an
@@ -363,34 +363,8 @@ def attention_xla(
     return out.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
-@jax.named_scope("attention")
-def prefill_attention(
-    q: jax.Array,  # [B, Sq, H, hd]
-    k_cache: jax.Array,  # [B, K, Skv, hd]
-    v_cache: jax.Array,
-    q_pos: jax.Array,  # [B, Sq]
-    seq_lens: jax.Array,  # [B]
-    *,
-    attn_impl: str = "xla",
-) -> jax.Array:
-    """Prefill attention dispatch: the Pallas flash kernel when opted in,
-    else the XLA einsum path.
-
-    The flash kernel never materializes the [Sq, Skv] score matrix, so
-    long-chunk prefill stays VMEM-resident.  A shape its block grammar
-    cannot tile raises ``pallas_attention.PallasShapeError`` while the jit
-    is traced — a kernel request is never quietly served by XLA.
-    """
-    if attn_impl.startswith("pallas"):
-        from calfkit_tpu.inference.pallas_attention import (
-            prefill_attention_pallas,
-        )
-
-        return prefill_attention_pallas(
-            q, k_cache, v_cache, q_pos, seq_lens,
-            interpret=attn_impl == "pallas_interpret",
-        )
-    return attention_xla(q, k_cache, v_cache, q_pos, seq_lens)
+# the scope a device trace groups a prefill or chunk layer's attention by
+prefill_attention = jax.named_scope("attention")(attention_xla)
 
 
 # --------------------------------------------------------------------------- #
@@ -407,7 +381,6 @@ def forward(
     seq_lens: jax.Array,  # [B] kv length AFTER inserting this chunk
     attn_window: int | None = None,  # static: attend only cache[..., :W, :]
     unroll: bool = False,  # static: python layer loop (the decode hot path)
-    attn_impl: str = "xla",  # static: "xla" | "pallas" | "pallas_interpret"
     insert_at: jax.Array | None = None,  # [B] explicit per-row write offset
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv) of the rows
     n_valid: jax.Array | None = None,  # hybrid: [B] positions of the chunk that are the row's own
@@ -454,8 +427,7 @@ def forward(
             v_page = _insert_chunk(
                 lax.dynamic_index_in_dim(v_all, ia, 0, keepdims=False), v, insert_at)
             attn = prefill_attention(
-                q, k_page[:, :, :W], v_page[:, :, :W], positions, seq_lens,
-                attn_impl=attn_impl,
+                q, k_page[:, :, :W], v_page[:, :, :W], positions, seq_lens
             )
             k_all = lax.dynamic_update_index_in_dim(k_all, k_page, ia, 0)
             v_all = lax.dynamic_update_index_in_dim(v_all, v_page, ia, 0)
@@ -486,8 +458,7 @@ def forward(
         k_page = _insert_chunk(k_page, k, insert_at)
         v_page = _insert_chunk(v_page, v, insert_at)
         attn = prefill_attention(
-            q, k_page[:, :, :W], v_page[:, :, :W], positions, seq_lens,
-            attn_impl=attn_impl,
+            q, k_page[:, :, :W], v_page[:, :, :W], positions, seq_lens
         )
         return attn_out_mlp(x, attn, lp, eps), k_page, v_page
 
@@ -627,7 +598,6 @@ def decode_step_ring(
     t: jax.Array,  # scalar: this dispatch's step index (ring write slot)
     base_lens: jax.Array,  # [B] kv length at dispatch start (main cache)
     attn_window: int | None = None,
-    attn_impl: str = "xla",  # static: "xla" | "pallas" | "pallas_interpret"
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,
 ) -> Any:
@@ -637,16 +607,9 @@ def decode_step_ring(
 
     def attn_source(i, q, rk, rv, extra):
         k_page, v_page = extra
-        attn_args = (q, k_page[:, :, :W], v_page[:, :, :W], rk, rv, base_lens, t)
-        if attn_impl.startswith("pallas"):
-            from calfkit_tpu.inference.pallas_attention import (
-                merged_decode_attention_pallas,
-            )
-
-            return merged_decode_attention_pallas(
-                *attn_args, interpret=attn_impl == "pallas_interpret"
-            )
-        return _merged_decode_attention(*attn_args)
+        return _merged_decode_attention(
+            q, k_page[:, :, :W], v_page[:, :, :W], rk, rv, base_lens, t
+        )
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source,
@@ -868,7 +831,7 @@ def ragged_attention_paged_xla(
     """Ragged attention through the block tables (XLA reference): gather
     each row's window, then the shared ragged mask law — mixed decode /
     prefill-chunk / verify rows served against the paged KV cache in one
-    call (the Pallas kernel DMAs pages instead of gathering)."""
+    call."""
     return ragged_attention_xla(
         q,
         gather_window_paged(pool_layer_k, tables, wpages),
@@ -883,8 +846,7 @@ def verify_chunk_source(
     ring_v: jax.Array,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The verify chunk's self-attention source → (o, m, z): query j
-    attends chunk slots 0..j (slot j IS its own token).  Shared by the
-    XLA verify path and the Pallas ragged-kernel merge."""
+    attends chunk slots 0..j (slot j IS its own token)."""
     S = qg.shape[1]
     scale = 1.0 / math.sqrt(qg.shape[-1])
     s2 = _einsum_f32("bskgh,tbkh->bkgst", qg, ring_k) * scale
@@ -940,7 +902,6 @@ def verify_step_ring(
     tokens: jax.Array,  # [B, S] fed tokens
     kv_cache: tuple[jax.Array, jax.Array],  # window-sliced, READ-ONLY here
     base_lens: jax.Array,  # [B]
-    attn_impl: str = "xla",
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Speculative verify over the dense cache layout → (logits [B, S, V],
     chunk ring [L, S, B, K, hd] ×2 for :func:`consolidate_ring`)."""
@@ -949,22 +910,6 @@ def verify_step_ring(
 
     def attn_source(i, q, rk, rv, extra):
         k_page, v_page = extra
-        if attn_impl.startswith("pallas"):
-            # host/interim fallback: the single-query merged kernel applied
-            # per chunk position — ring slot validity (0..t) IS the
-            # within-chunk causal mask, so t=j gives query j's semantics
-            # exactly.  A true multi-query kernel (the ragged-paged-
-            # attention direction, PAPERS.md arXiv:2604.15464) would read
-            # the window once instead of S times; this keeps the Pallas
-            # lane correct until that kernel lands.
-            from calfkit_tpu.inference.pallas_attention import (
-                verify_attention_pallas,
-            )
-
-            return verify_attention_pallas(
-                q, k_page, v_page, rk, rv, base_lens,
-                interpret=attn_impl == "pallas_interpret",
-            )
         return _verify_merged_attention(q, k_page, v_page, rk, rv, base_lens)
 
     return _verify_step_with_ring(
@@ -981,22 +926,12 @@ def verify_step_ring_paged(
     tables: jax.Array,  # [B, Pmax]
     base_lens: jax.Array,  # [B]
     wpages: int,  # static: window bucket in pages
-    attn_impl: str = "xla",
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """Speculative verify reading KV through the block tables → (logits,
     chunk ring for :func:`consolidate_ring_paged`)."""
     pool_k, pool_v = pool
 
     def attn_source(i, q, rk, rv, extra):
-        if attn_impl.startswith("pallas"):
-            from calfkit_tpu.inference.pallas_attention import (
-                verify_attention_paged_pallas,
-            )
-
-            return verify_attention_paged_pallas(
-                q, pool_k, pool_v, i, tables, rk, rv, base_lens,
-                wpages=wpages, interpret=attn_impl == "pallas_interpret",
-            )
         kl = lax.dynamic_index_in_dim(pool_k, i, 0, keepdims=False)
         vl = lax.dynamic_index_in_dim(pool_v, i, 0, keepdims=False)
         return _verify_merged_attention(

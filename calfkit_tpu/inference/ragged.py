@@ -1,7 +1,7 @@
 """Ragged unified prefill+decode wave math (ISSUE 6).
 
 Pure host-side helpers for the engine's ragged wave scheduler: per-row
-query descriptors for the unified attention kernel, and the token-budget
+query descriptors for the unified attention law, and the token-budget
 arithmetic that decides how much pending prefill a half-empty decode wave
 may absorb.  No jax imports — these run at wave-formation time on the
 event loop and inside the dispatch-thread packing loop, both of which
@@ -10,7 +10,7 @@ keeping the module dependency-free also keeps it trivially typeable
 (it sits under the real mypy gate with the rest of ``inference.*``).
 
 The descriptor vocabulary mirrors Ragged Paged Attention (PAPERS.md,
-arXiv:2604.15464): one kernel invocation consumes a batch whose rows mix
+arXiv:2604.15464): one invocation consumes a batch whose rows mix
 
 - ``decode`` rows — q_len = 1, one fresh query at position ``start``;
 - ``prefill`` rows — q_len = chunk, queries at ``start .. start+chunk``;
@@ -21,7 +21,7 @@ positions ``< min(kv_len, start + j + 1)`` — causal within the row's own
 fresh span, bounded by the row's valid cache length.
 
 :class:`RaggedRow` / :func:`build_descriptors` are the SPEC vocabulary:
-tests pin the kernels' mask law against descriptors built here
+tests pin the attention sources' mask law against descriptors built here
 (``tests/test_ragged_waves.py`` — the executable definition of what a
 mixed wave means), and formation-time tooling can reason in rows.  The
 engine's hot path ships the ``(q_starts, q_lens, kv_lens)`` arrays

@@ -438,20 +438,18 @@ def test_heads_of_64_serve_the_same_tokens_through_the_kernel():
     requests than slots, so chunks ride live dispatches (the ragged
     program's decode loop) and rows decode on alone (the decode program);
     the tokens are equal and the kernel that reads live pages in place was
-    traced: not the ragged kernel's S = 1 row."""
+    traced."""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     rt = dict(page_size=16, window_buckets=(64, 128))
     requests = [(prompt_of(9 + 13 * i, seed=20 + i), 6 + 5 * i) for i in range(4)]
     want, _, _ = serve((TOY64, runtime(**rt)), requests, sequential=False)
     before = KERNEL_TRACES["paged_decode", "interpreted"]
-    ragged_before = KERNEL_TRACES["ragged_paged", "interpreted"]
     got, _, counters = serve(
         (TOY64, runtime(attention_impl="pallas_interpret", **rt)), requests, sequential=False)
     assert got == want
     assert [len(out) for out in got] == [n for _, n in requests]
     assert KERNEL_TRACES["paged_decode", "interpreted"] > before
-    assert KERNEL_TRACES["ragged_paged", "interpreted"] == ragged_before
     assert 0 < counters["unified_dispatches"] < counters["decode_dispatches"]
     assert 0 < counters["decode_pages_live"] < counters["decode_pages_window"]
 
@@ -473,7 +471,7 @@ def test_heads_of_64_serve_the_same_tokens_through_the_kernel():
 def test_the_paged_decode_read_is_selected_by_platform_and_shape(
     monkeypatch, platform, devices, config, page, want
 ):
-    """``_resolved_attn_impl("paged_decode")`` under "auto" answers from the
+    """``_resolved_attn_impl()`` under "auto" answers from the
     platform, the mesh's size, the head, the page and the cache's dtype:
     a dense model of the same head and page gets the hybrid's answer."""
     from types import SimpleNamespace
@@ -490,6 +488,6 @@ def test_the_paged_decode_read_is_selected_by_platform_and_shape(
         monkeypatch.setattr(engine, "mesh", SimpleNamespace(size=devices))
         monkeypatch.setattr(
             jax, "devices", lambda *a: [SimpleNamespace(platform=platform)] if not a else real)
-        answers.append(engine._resolved_attn_impl("paged_decode"))
+        answers.append(engine._resolved_attn_impl())
         monkeypatch.setattr(jax, "devices", lambda *a: real)
     assert answers == [want, want]

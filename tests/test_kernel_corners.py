@@ -2,9 +2,7 @@
 isolation, node-name validation, task-identity forwarding, node-side decode
 floor (reference analogs: tests/test_co_tenant_tool_isolation.py,
 test_node_id_validation.py, test_task_header_forwarding.py,
-test_decode_floor.py); and the corners of the paged decode ATTENTION kernel
-(PR 25): row lengths around a page edge, rows that read nothing, shared
-tables, and the proof that a dead page is never read."""
+test_decode_floor.py)."""
 
 import pytest
 
@@ -180,264 +178,3 @@ class TestNodeDecodeFloor:
                                                           timeout=10)
             assert result.output == "alive"
             await client.close()
-
-
-# --------------------------------------------------------------------------- #
-# the paged decode attention kernel (pallas_attention._paged_decode_kernel),
-# interpret mode, at the head shapes of Mistral (K 8, G 4, hd 128), granite
-# (K 8, G 4, hd 64) and TinyLlama (K 4, G 8, hd 64); page 64.  Heads of 64
-# are read two positions a lane row (pallas_attention.lane_dense_pool).
-# --------------------------------------------------------------------------- #
-
-PD_WIDTHS = {"mistral": (8, 4, 128), "granite": (8, 4, 64), "tinyllama": (4, 8, 64)}
-PD_PAGE, PD_WPAGES, PD_PMAX = 64, 4, 6
-PD_WINDOW = PD_WPAGES * PD_PAGE
-
-# name -> (row lengths, rows that are inactive, (row, row) sharing a table)
-PAGED_DECODE_CASES = {
-    "len-0": ([0], (), None),
-    "len-1": ([1], (), None),
-    "page-minus-1": ([PD_PAGE - 1], (), None),
-    "page": ([PD_PAGE], (), None),
-    "page-plus-1": ([PD_PAGE + 1], (), None),
-    "odd": ([33, 191], (), None),
-    "full-window": ([PD_WINDOW], (), None),
-    "mixed": ([0, 1, PD_PAGE - 1, PD_PAGE, PD_PAGE + 1, PD_WINDOW, 130, 17], (), None),
-    "inactive-row-on-trash-page": ([70, 100, 9], (1,), None),
-    "shared-table": ([150, 150, 40], (), (0, 1)),
-}
-
-
-def _paged_decode_case(name: str, dtype, seed: int = 0, widths: str = "mistral"):
-    """(q, pool_k, pool_v, tables, lens, live) for one named case: every
-    row's pages are its own (page 0 is the trash page), an inactive row's
-    table is all trash and its length 0 as ``decode_step_ring_paged``
-    hands it down, a shared table is one row's copied to the other."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    K, G, hd = PD_WIDTHS[widths]
-    lens, inactive, shared = PAGED_DECODE_CASES[name]
-    lens = list(lens)
-    B = len(lens)
-    rng = np.random.default_rng(seed)
-    n_pages = 1 + sum(-(-n // PD_PAGE) for n in lens) + 3  # + unused pages
-    tables = np.zeros((B, PD_PMAX), np.int32)
-    nxt = 1
-    for b, n in enumerate(lens):
-        if b in inactive:
-            lens[b] = 0
-            continue
-        for p in range(-(-n // PD_PAGE)):
-            tables[b, p] = nxt
-            nxt += 1
-    if shared is not None:
-        tables[shared[1]] = tables[shared[0]]
-    live = np.zeros((n_pages,), bool)
-    for b, n in enumerate(lens):
-        live[tables[b, : -(-n // PD_PAGE)]] = True
-    shape = (2, n_pages, K, PD_PAGE, hd)
-    pool_k = rng.standard_normal(shape).astype(np.float32)
-    pool_v = rng.standard_normal(shape).astype(np.float32)
-    q = jnp.asarray(rng.standard_normal((B, K, G, hd)), dtype)
-    return (
-        q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lens, jnp.int32),
-        live,
-    )
-
-
-def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
-    """The kernel (layer 1 of the whole pool) beside the XLA law it
-    replaces: ``masked_attention_source`` over ``gather_window_paged``."""
-    import jax.numpy as jnp
-
-    from calfkit_tpu.inference import model as M
-    from calfkit_tpu.inference.pallas_attention import (
-        paged_decode_attention_pallas,
-    )
-
-    got = paged_decode_attention_pallas(
-        q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=PD_WPAGES,
-        interpret=True, pages_per_block=pages_per_block,
-    )
-    valid = jnp.arange(PD_WINDOW)[None, :] < lens[:, None]
-    o, m, z = M.masked_attention_source(
-        q, M.gather_window_paged(pool_k[1], tables, PD_WPAGES),
-        M.gather_window_paged(pool_v[1], tables, PD_WPAGES), valid,
-    )
-    return got, (o, m[..., 0], z[..., 0])
-
-
-class TestPagedDecodeKernelCorners:
-    @pytest.mark.parametrize("pages_per_block", [1, 2])
-    @pytest.mark.parametrize("case", sorted(PAGED_DECODE_CASES))
-    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_matches_gathered_window(self, widths, case, pages_per_block):
-        import jax.numpy as jnp
-        import numpy as np
-
-        q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            case, jnp.float32, widths=widths
-        )
-        got, want = _paged_decode_both(
-            q, jnp.asarray(pool_k), jnp.asarray(pool_v), tables, lens,
-            pages_per_block=pages_per_block,
-        )
-        for name, g, w in zip("omz", got, want):
-            # one pass over the window against a block at a time: the
-            # same sums in another order
-            np.testing.assert_allclose(
-                np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-5,
-                err_msg=f"{case}: {name} diverged",
-            )
-        # a row that reads nothing stays finite at the floor
-        empty = np.asarray(lens) == 0
-        assert (np.asarray(got[1])[empty] == np.float32(-1e29)).all()
-        assert (np.asarray(got[2])[empty] == 0).all()
-
-    @pytest.mark.parametrize("case", ["mixed", "shared-table"])
-    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_bf16_operands_f32_accumulation(self, widths, case):
-        """The configuration's precision: bf16 q, K, V into the products,
-        float32 scores, statistics and accumulator."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            case, jnp.bfloat16, widths=widths
-        )
-        got, want = _paged_decode_both(
-            q, jnp.asarray(pool_k, jnp.bfloat16),
-            jnp.asarray(pool_v, jnp.bfloat16), tables, lens,
-            pages_per_block=2,
-        )
-        assert all(a.dtype == jnp.float32 for a in got)
-        norm = lambda o, m, z: np.asarray(o / jnp.maximum(z[..., None], 1e-30))
-        # p is rounded to bf16 against a running maximum here and against
-        # the row's own there: agreement to bf16's 8 bits, not float32's
-        np.testing.assert_allclose(norm(*got), norm(*want), atol=2e-2)
-        np.testing.assert_allclose(
-            np.asarray(got[1]), np.asarray(want[1]), rtol=1e-5
-        )
-
-    @pytest.mark.parametrize("pages_per_block", [1, 2, 4])
-    @pytest.mark.parametrize(
-        "case", ["mixed", "inactive-row-on-trash-page", "shared-table"]
-    )
-    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_dead_pages_are_never_read(self, widths, case, pages_per_block):
-        """Every page no row's length reaches — the trash page, the tail of
-        each table, the unused pages of the pool, both layers' — is NaN;
-        the result is finite and equal to the clean pool's.  The XLA
-        gather reads them all (and masks them), so it gets the clean pool."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        q, pool_k, pool_v, tables, lens, live = _paged_decode_case(
-            case, jnp.float32, seed=5, widths=widths
-        )
-        dirty_k, dirty_v = pool_k.copy(), pool_v.copy()
-        dirty_k[:, ~live] = np.nan
-        dirty_v[:, ~live] = np.nan
-        dirty_k[0] = dirty_v[0] = np.nan  # another layer's pages
-        got, _ = _paged_decode_both(
-            q, jnp.asarray(dirty_k), jnp.asarray(dirty_v), tables, lens,
-            pages_per_block=pages_per_block,
-        )
-        clean, want = _paged_decode_both(
-            q, jnp.asarray(pool_k), jnp.asarray(pool_v), tables, lens,
-            pages_per_block=pages_per_block,
-        )
-        for g, c, w in zip(got, clean, want):
-            assert np.isfinite(np.asarray(g)).all()
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(c))
-            np.testing.assert_allclose(
-                np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-5
-            )
-
-    @pytest.mark.parametrize(
-        "head_dim,page,dtype,ok",
-        [
-            (128, 64, "bfloat16", True),
-            (128, 16, "bfloat16", True),
-            (256, 8, "float32", True),
-            (64, 64, "bfloat16", True),  # two positions a lane row (TinyLlama)
-            (64, 32, "bfloat16", True),  # ... on a packed page of 16 rows
-            (32, 64, "bfloat16", True),  # four positions a lane row
-            (64, 16, "float32", True),
-            (128, 8, "bfloat16", False),  # half a packed sublane tile
-            (128, 16, "int8", False),
-            (64, 16, "bfloat16", False),  # a packed page under a sublane tile
-            (64, 8, "float32", False),
-            (96, 64, "bfloat16", False),  # a head that does not divide 128
-            (80, 64, "float32", False),
-            (192, 64, "bfloat16", False),  # nor is whole lane tiles
-        ],
-    )
-    def test_shape_rule(self, head_dim, page, dtype, ok):
-        from calfkit_tpu.inference.pallas_attention import (
-            paged_decode_in_place_ok,
-        )
-
-        assert paged_decode_in_place_ok(head_dim, page, dtype) is ok
-
-    @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_a_view_made_by_the_caller_is_read_as_it_lies(self, widths):
-        """The engine makes ``lane_dense_pool`` once a dispatch and hands it
-        down: the kernel's result is bit for bit that of the pool as it
-        lies.  For whole lane tiles, and outside the shape rule, the view
-        IS the pool: the same array, no operation."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from calfkit_tpu.inference import pallas_attention as PA
-
-        q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            "mixed", jnp.bfloat16, widths=widths
-        )
-        pool_k = jnp.asarray(pool_k, jnp.bfloat16)
-        pool_v = jnp.asarray(pool_v, jnp.bfloat16)
-        view_k, view_v = PA.lane_dense_pool(pool_k), PA.lane_dense_pool(pool_v)
-        hd = pool_k.shape[-1]
-        if hd % 128 == 0:
-            assert view_k is pool_k and view_v is pool_v
-        else:
-            f = PA.paged_decode_lane_pack(hd)
-            assert view_k.shape == (*pool_k.shape[:3], PD_PAGE // f, 128)
-            # row r of a page: positions f * r .. f * r + f - 1 side by side
-            np.testing.assert_array_equal(
-                np.asarray(view_k[1, 2, 3, 5], np.float32),
-                np.asarray(pool_k[1, 2, 3, 5 * f:(5 + 1) * f], np.float32).ravel(),
-            )
-        kw = dict(wpages=PD_WPAGES, interpret=True)
-        got = PA.paged_decode_attention_pallas(
-            q, view_k, view_v, jnp.int32(1), tables, lens, **kw)
-        want = PA.paged_decode_attention_pallas(
-            q, pool_k, pool_v, jnp.int32(1), tables, lens, **kw)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-        small = jnp.zeros((1, 3, 2, 16, 64), jnp.bfloat16)  # outside the rule
-        assert PA.lane_dense_pool(small) is small
-
-    def test_other_shapes_keep_the_ragged_row(self):
-        """hd 64 on pages of 8 (a packed page of 4 rows, under a sublane
-        tile) is outside the shape rule: explicit "pallas" still runs a
-        kernel there, the S = 1 row of the ragged paged one."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from calfkit_tpu.inference import pallas_attention as PA
-
-        rng = np.random.default_rng(2)
-        pool = jnp.asarray(rng.standard_normal((1, 5, 2, 8, 64)), jnp.float32)
-        q = jnp.asarray(rng.standard_normal((2, 2, 4, 64)), jnp.float32)
-        tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
-        lens = jnp.asarray([11, 8], jnp.int32)
-        before = PA.KERNEL_TRACES["ragged_paged", "interpreted"]
-        o, m, z = PA.paged_decode_attention_pallas(
-            q, pool, pool, jnp.int32(0), tables, lens, wpages=2,
-            interpret=True,
-        )
-        assert PA.KERNEL_TRACES["ragged_paged", "interpreted"] == before + 1
-        assert o.shape == (2, 2, 4, 64) and m.shape == z.shape == (2, 2, 4)
-        assert np.isfinite(np.asarray(o)).all()

@@ -9,7 +9,7 @@ The correctness contract under test:
 - KERNEL MATH: the ragged attention law (query j attends kv positions
   < min(kv_len, start + j + 1)) serves decode (q_len=1), prefill-chunk
   (q_len=chunk), and verify (q_len=k+1) rows identically to the
-  per-kind reference paths, XLA and Pallas-interpret alike;
+  per-kind reference paths;
 - ACCOUNTING: absorbed prefill rows count as dispatch participants
   (mean_batch_occupancy is the unified-wave fill metric), absorbed chunk
   tokens and unified dispatches surface through ``EngineStats`` /
@@ -159,87 +159,6 @@ class TestRaggedAttentionMath:
                 rtol=1e-5, atol=1e-5,
                 err_msg=f"row kind {row.kind_name} diverged",
             )
-
-    def test_pallas_ragged_matches_xla(self):
-        from calfkit_tpu.inference.pallas_attention import (
-            ragged_attention_pallas,
-        )
-
-        q, kc, vc = self._mixed()
-        B, S, H, hd = q.shape
-        K = kc.shape[1]
-        G = H // K
-        starts = jnp.asarray([4, 9, 0], jnp.int32)
-        kv_lens = jnp.asarray([9, 9 + S, 5], jnp.int32)
-        want = M.ragged_attention_xla(q, kc, vc, starts, kv_lens)
-        qg = jnp.transpose(q.reshape(B, S, K, G, hd), (0, 2, 1, 3, 4))
-        o, m, z = ragged_attention_pallas(
-            qg, kc, vc, starts, kv_lens, interpret=True
-        )
-        got = jnp.transpose(
-            o / jnp.maximum(z[..., None], 1e-30), (0, 2, 1, 3, 4)
-        ).reshape(B, S, H, hd)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
-        )
-
-    def test_pallas_ragged_paged_matches_xla(self):
-        from calfkit_tpu.inference.pallas_attention import (
-            ragged_attention_paged_pallas,
-        )
-
-        rng = np.random.default_rng(3)
-        B, K, G, hd, S = 3, 2, 4, 8, 4
-        H = K * G
-        page, N, L, wp = 8, 13, 2, 4
-        q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
-        pool_k = jnp.asarray(
-            rng.standard_normal((L, N, K, page, hd)), jnp.float32
-        )
-        pool_v = jnp.asarray(
-            rng.standard_normal((L, N, K, page, hd)), jnp.float32
-        )
-        tables = jnp.asarray(rng.integers(1, N, (B, 6)), jnp.int32)
-        starts = jnp.asarray([7, 0, 12], jnp.int32)
-        kv_lens = jnp.asarray([7, S, 12 + S], jnp.int32)
-        want = M.ragged_attention_paged_xla(
-            q, pool_k[1], pool_v[1], tables, starts, kv_lens, wpages=wp
-        )
-        qg = jnp.transpose(q.reshape(B, S, K, G, hd), (0, 2, 1, 3, 4))
-        o, m, z = ragged_attention_paged_pallas(
-            qg, pool_k, pool_v, jnp.int32(1), tables, starts, kv_lens,
-            wpages=wp, interpret=True,
-        )
-        got = jnp.transpose(
-            o / jnp.maximum(z[..., None], 1e-30), (0, 2, 1, 3, 4)
-        ).reshape(B, S, H, hd)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4
-        )
-
-    def test_verify_pallas_single_call_matches_xla(self):
-        """The spec-verify Pallas lane now rides ONE ragged-kernel call;
-        it must match the XLA merged path."""
-        from calfkit_tpu.inference.pallas_attention import (
-            verify_attention_pallas,
-        )
-
-        rng = np.random.default_rng(7)
-        B, K, G, hd, W, S = 2, 2, 4, 8, 32, 4
-        H = K * G
-        q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
-        kc = jnp.asarray(rng.standard_normal((B, K, W, hd)), jnp.float32)
-        vc = jnp.asarray(rng.standard_normal((B, K, W, hd)), jnp.float32)
-        ring_k = jnp.asarray(rng.standard_normal((S, B, K, hd)), jnp.float32)
-        ring_v = jnp.asarray(rng.standard_normal((S, B, K, hd)), jnp.float32)
-        base = jnp.asarray([7, 12], jnp.int32)
-        want = M._verify_merged_attention(q, kc, vc, ring_k, ring_v, base)
-        got = verify_attention_pallas(
-            q, kc, vc, ring_k, ring_v, base, interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
-        )
 
 
 # ------------------------------------------------------------- budget math
@@ -517,12 +436,10 @@ class TestPagedDecodeInPlace:
     @pytest.mark.parametrize("jobs", sorted(IN_PLACE_JOBS))
     async def test_token_parity_with_the_xla_gather(self, wide_params, jobs):
         """paged + chunked + overlap + ragged: the kernel's streams are the
-        XLA gather path's, token for token, and it was the new body that
-        ran (not the ragged S = 1 row that other head shapes fall to)."""
+        XLA gather path's, token for token, and the kernel ran."""
         from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
         before = KERNEL_TRACES["paged_decode", "interpreted"]
-        ragged_before = KERNEL_TRACES["ragged_paged", "interpreted"]
         want, xla = await _serve_wide(
             wide_params, IN_PLACE_JOBS[jobs], attention_impl="xla"
         )
@@ -534,12 +451,10 @@ class TestPagedDecodeInPlace:
         assert got == want
         assert all(len(s) == n for s, (_, n, _) in zip(got, IN_PLACE_JOBS[jobs]))
         # traced at most once a process for these shapes (the entry point
-        # is a jit of its own), and never for the ragged S = 1 row
+        # is a jit of its own)
         assert KERNEL_TRACES["paged_decode", "interpreted"] > 0
-        assert KERNEL_TRACES["ragged_paged", "interpreted"] == ragged_before
         assert pal._ragged and pal.stats.unified_dispatches > 0
-        # the selector, not the test: "auto" on this CPU stays on XLA
-        assert xla._resolved_attn_impl("paged_decode") == "xla"
+        assert (xla._attn_impl, pal._attn_impl) == ("xla", "pallas_interpret")
 
     async def test_page_sums_by_difference(self, wide_params):
         """``decode_pages_live`` / ``decode_pages_window`` over a scripted

@@ -239,18 +239,6 @@ class TestSpecGreedyParity:
         await base.stop()
         await spec.stop()
 
-    async def test_pallas_interpret_verify(self, params):
-        """The Pallas verify fallback (per-position kernel decomposition)
-        produces the same greedy tokens as the XLA verify."""
-        spec_kw = dict(speculative=SpecConfig(k=3))
-        await self._parity(
-            params,
-            _rt(),
-            _rt(attention_impl="pallas_interpret", **spec_kw),
-            prompts=([1, 5, 9],),
-            n=10,
-        )
-
     async def test_wave_shrinks_near_max_seq(self, params):
         """Rows near max_seq must shrink the verify wave instead of
         letting chunk writes clamp backward over valid history."""

@@ -1,11 +1,13 @@
-"""AOT compiles of the Pallas attention entry points for a DESCRIBED v5e.
+"""AOT compiles of the Pallas attention kernel for a DESCRIBED v5e.
 
 The TPU compiler is installed without a chip: it compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2).
 Interpret mode cannot see what the Mosaic lowering refuses — block shapes,
-SMEM scalars, VMEM limits — so every entry point the serving path can
-select is compiled here at TinyLlama-1.1B and Llama-3-8B widths.  Nothing
-runs; a pass is a compile, never a chip run.
+SMEM scalars, VMEM limits — so the one kernel the serving path can select,
+the paged decode read in place, is compiled here at the widths of every
+preset and benchmark configuration that takes it, alone and inside the
+dispatch programs of the benchmark's cells.  Nothing runs; a pass is a
+compile, never a chip run.
 
 Rules this file keeps: the topology is described inside a module-scoped
 fixture (never at import, in a skipif, in parametrize arguments or in
@@ -22,21 +24,19 @@ import os
 
 import pytest
 
-# (K kv heads, G query heads per kv head, head_dim)
-WIDTHS = {"tinyllama-1.1b": (4, 8, 64), "llama-3-8b": (8, 4, 128)}
-B, W, PAGE, N_PAGES, LAYERS = 64, 1024, 64, 257, 2
-WPAGES = W // PAGE
-# the paged decode read of the benchmark's configurations, at their own
-# batch and widest window: (K, G, head_dim, B, W, pages, layers); the kernel
-# that reads live pages in place is what "auto" selects for them on a chip
+PAGE = 64
+# the paged decode read: (K, G, head_dim, B, W, pages, layers).  The
+# benchmark's configurations at their own batch and widest window (the
+# kernel is what "auto" selects for them on a chip), and the two presets
+# chip_smoke.py serves
 DECODE_PAGED_WIDTHS = {
     "mistral-7b-v0.3": (8, 4, 128, 32, 2048, 513, 32),
     "internlm2-1.8b": (8, 2, 128, 64, 4096, 897, 24),
     # heads of 64: two positions a lane row (pallas_attention.lane_dense_pool)
     "granite-4.0-h-micro": (8, 4, 64, 64, 2048, 1281, 4),
+    "tinyllama-1.1b": (4, 8, 64, 64, 1024, 257, 2),
+    "llama-3-8b": (8, 4, 128, 64, 1024, 257, 2),
 }
-SPEC_S = 5  # verify: k + 1 queries at the default k = 4
-CHUNK_S = 128  # one prefill chunk
 
 
 @pytest.fixture(scope="module")
@@ -72,83 +72,6 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _entry_points(K: int, G: int, hd: int, shape):
-    """name -> (fn, abstract args) for every serving-path entry point."""
-    import jax.numpy as jnp
-
-    from calfkit_tpu.inference import pallas_attention as PA
-
-    H = K * G
-    bf16, i32 = jnp.bfloat16, jnp.int32
-    lens = shape((B,), i32)
-    dense = (shape((B, K, W, hd), bf16),) * 2
-    pool = (shape((LAYERS, N_PAGES, K, PAGE, hd), bf16),) * 2
-    layer, tables = shape((), i32), shape((B, WPAGES), i32)
-    ring = (shape((8, B, K, hd), bf16),) * 2  # decode_steps_per_dispatch = 8
-    chunk = (shape((SPEC_S, B, K, hd), bf16),) * 2
-    q1 = shape((B, 1, H, hd), bf16)
-    q_spec = shape((B, SPEC_S, H, hd), bf16)
-    q_ragged = shape((B, K, CHUNK_S, G, hd), bf16)
-    R = 8  # one admission wave
-    return {
-        "decode-dense": (
-            PA.merged_decode_attention_pallas,
-            (q1, *dense, *ring, lens, shape((), i32)),
-        ),
-        "decode-paged": (
-            lambda *a: PA.merged_paged_decode_attention_pallas(
-                *a, wpages=WPAGES
-            ),
-            (q1, *pool, layer, tables, *ring, lens, shape((), i32)),
-        ),
-        "verify-dense": (
-            PA.verify_attention_pallas, (q_spec, *dense, *chunk, lens),
-        ),
-        "verify-paged": (
-            lambda *a: PA.verify_attention_paged_pallas(*a, wpages=WPAGES),
-            (q_spec, *pool, layer, tables, *chunk, lens),
-        ),
-        "ragged-chunk-dense": (
-            PA.ragged_attention_pallas, (q_ragged, *dense, lens, lens),
-        ),
-        "ragged-chunk-paged": (
-            lambda *a: PA.ragged_attention_paged_pallas(*a, wpages=WPAGES),
-            (q_ragged, *pool, layer, tables, lens, lens),
-        ),
-        "prefill-chunk": (
-            PA.prefill_attention_pallas,
-            (
-                shape((R, CHUNK_S, H, hd), bf16),
-                shape((R, K, W, hd), bf16), shape((R, K, W, hd), bf16),
-                shape((R, CHUNK_S), i32), shape((R,), i32),
-            ),
-        ),
-    }
-
-
-ENTRY_POINTS = (
-    "decode-dense", "decode-paged", "verify-dense", "verify-paged",
-    "ragged-chunk-dense", "ragged-chunk-paged", "prefill-chunk",
-)
-
-
-@pytest.mark.parametrize("entry", ENTRY_POINTS)
-@pytest.mark.parametrize("widths", sorted(WIDTHS))
-def test_entry_point_compiles_for_v5e(
-    widths, entry, one_chip, no_persistent_cache
-):
-    import jax
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    fn, args = _entry_points(*WIDTHS[widths], shape)[entry]
-    compiled = jax.jit(fn).lower(*args).compile()
-    # the kernel is IN the program: compiled by Mosaic, not interpreted and
-    # not replaced by an XLA fallback
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("widths", sorted(DECODE_PAGED_WIDTHS))
 def test_paged_decode_in_place_compiles_for_v5e(
     widths, one_chip, no_persistent_cache
@@ -178,8 +101,9 @@ def test_paged_decode_in_place_compiles_for_v5e(
         shape((rows, window // PAGE), i32), *ring, shape((rows,), i32),
         shape((), i32),
     ).compile()
+    # the kernel is IN the program: compiled by Mosaic, not interpreted and
+    # not replaced by an XLA fallback
     assert "tpu_custom_call" in compiled.as_text()
-    # the body of its own, not the ragged kernel's S = 1 row
     assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
 
 
@@ -252,6 +176,106 @@ def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
     }
     assert 1 <= len(made.pop("ENTRY")) <= 2  # K and V, once a dispatch
     assert not any(made.values()), made  # never in a loop's body
+
+
+# a benchmark cell's configuration -> layers kept (granite: one period of
+# its stack, nine Mamba-2 layers around one attention layer)
+CELL_LAYERS = {"mistral-7b-v0.3-int8": 2, "granite-4.0-h-micro": 10}
+
+
+@pytest.fixture(scope="module")
+def cell_engine():
+    """name -> the engine of a benchmark configuration at its published
+    WIDTHS and its cell's runtime, depth cut; the kernel asked for by name,
+    as "auto" resolves it on a chip (this process sees a CPU)."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    built = {}
+
+    def build(name):
+        if name not in built:
+            here = os.path.dirname(manifest.__file__)
+            with open(os.path.join(here, "configs", name + ".json")) as f:
+                described = json.load(f)
+            arch = manifest.load_architecture(
+                described.get("architecture", "dense-gqa"), here)
+            config, runtime = arch.model(described, False)
+            layers = CELL_LAYERS[name]
+            config = replace(
+                config, n_layers=layers,
+                **({"layer_types": config.layer_types[:layers]}
+                   if config.layer_types else {}),
+            )
+            built[name] = InferenceEngine(config, replace(
+                runtime, compilation_cache=False, attention_impl="pallas"))
+        return built[name]
+
+    return build
+
+
+def _dispatch_programs(engine, sharding):
+    """{"decode": (jitted fn, abstract args), "ragged": ...}: the paged
+    decode dispatch, and the ragged program that carries one chunk of a
+    two-row wave beside it, at the engine's widest window."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    args, window, steps, sampled = engine._decode_args()
+    rows, chunk = 2, rt.prefill_chunk
+    scratch = jax.ShapeDtypeStruct(
+        (cfg.n_kv_layers, rows, cfg.n_kv_heads, 2 * chunk, cfg.head_dim), engine._k.dtype)
+    wave = [scratch, scratch, jax.ShapeDtypeStruct((rows, chunk), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)]
+    state = wave_state = ()
+    if engine._recurrent:
+        state = (engine._state,)
+        wave_state = (
+            engine._state, jax.eval_shape(lambda: make_recurrent_state(cfg, rows)),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+        )
+    return {
+        "decode": (engine._decode_jit(window, steps, sampled), abstract((*args, *state))),
+        "ragged": (
+            engine._ragged_jit(window, steps, sampled, chunk, rows),
+            abstract((*args, *wave, *wave_state)),
+        ),
+    }
+
+
+@pytest.mark.parametrize(
+    "cell,program",
+    [
+        ("mistral-7b-v0.3-int8", "decode"),
+        ("mistral-7b-v0.3-int8", "ragged"),
+        ("granite-4.0-h-micro", "ragged"),
+    ],
+)
+def test_cell_dispatch_program_holds_one_kernel_on_v5e(
+    cell, program, cell_engine, one_chip, no_persistent_cache
+):
+    """A dispatch program of a benchmark cell, compiled for the described
+    v5e: exactly ONE kernel is in it, the paged decode read of the decode
+    loop, and no window is gathered there; the chunk that rides along in a
+    ragged program reads through XLA.  (granite's decode dispatch is the
+    test above.)"""
+    fn, args = _dispatch_programs(cell_engine(cell), one_chip)[program]
+    hlo = fn.lower(*args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attention" in hlo
+    assert "gather_window" not in hlo
+    if program == "ragged":
+        assert "chunk_loop/" in hlo and "decode_loop/" in hlo
 
 
 def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
@@ -349,8 +373,18 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
 
 def test_entry_point_list_is_complete():
-    """Every name parametrized above exists, and nothing is left out."""
-    import jax.numpy as jnp
+    """The kernel module's entry points are the two compiled above (the
+    merged read calls the plain one) around ONE ``pallas_call``: one added
+    without a compile of its own fails here."""
+    import inspect
 
-    built = _entry_points(4, 8, 64, lambda dims, dtype: (dims, jnp.dtype(dtype)))
-    assert set(built) == set(ENTRY_POINTS)
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    entries = {
+        name for name, fn in vars(PA).items()
+        if name.endswith("_pallas") and callable(fn)
+    }
+    assert entries == {
+        "paged_decode_attention_pallas", "merged_paged_decode_attention_pallas",
+    }
+    assert inspect.getsource(PA).count("pl.pallas_call(") == 1

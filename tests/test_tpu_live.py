@@ -10,7 +10,6 @@ serving path starts on one.
 from __future__ import annotations
 
 import os
-import time
 
 import pytest
 
@@ -84,55 +83,11 @@ class TestChipSmoke:
         await dense.stop()
         await paged.stop()
 
-    def test_pallas_decode_kernel_on_chip(self):
-        """The dense Pallas kernel compiles + matches XLA on hardware, and
-        its per-call time is recorded (the profile that decides 'auto')."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from calfkit_tpu.inference.model import _merged_decode_attention
-        from calfkit_tpu.inference.pallas_attention import (
-            merged_decode_attention_pallas,
-        )
-
-        _chip()
-        B, K, G, hd, W, T = 8, 4, 8, 64, 1024, 8
-        ks = jax.random.split(jax.random.key(11), 5)
-        q = jax.random.normal(ks[0], (B, 1, K * G, hd), jnp.bfloat16)
-        kc = jax.random.normal(ks[1], (B, K, W, hd), jnp.bfloat16)
-        vc = jax.random.normal(ks[2], (B, K, W, hd), jnp.bfloat16)
-        rk = jax.random.normal(ks[3], (T, B, K, hd), jnp.bfloat16)
-        rv = jax.random.normal(ks[4], (T, B, K, hd), jnp.bfloat16)
-        lens = jnp.full((B,), W - 7, jnp.int32)
-        t = jnp.int32(3)
-
-        ref = _merged_decode_attention(q, kc, vc, rk, rv, lens, t)
-        out = merged_decode_attention_pallas(q, kc, vc, rk, rv, lens, t)
-        np.testing.assert_allclose(
-            np.asarray(jnp.float32(ref)), np.asarray(jnp.float32(out)),
-            atol=2e-2, rtol=2e-2,
-        )
-
-        def timed(fn, n=20):
-            np.asarray(jnp.float32(fn()).sum())  # warm
-            start = time.perf_counter()
-            for _ in range(n):
-                np.asarray(jnp.float32(fn()).sum())  # forced fetch per call
-            return (time.perf_counter() - start) / n * 1000.0
-
-        xla_ms = timed(lambda: _merged_decode_attention(q, kc, vc, rk, rv, lens, t))
-        pallas_ms = timed(
-            lambda: merged_decode_attention_pallas(q, kc, vc, rk, rv, lens, t)
-        )
-        print(f"\ndecode attention B={B} W={W}: xla {xla_ms:.2f} ms/call, "
-              f"pallas {pallas_ms:.2f} ms/call")
-
 
 @requires_tpu_env
 class TestRound4FeaturesOnChip:
     """Round-4 features under real hardware: the kafka-wire mesh carrying
-    a chip-backed engine, the artifact-driven attention auto-flip, and
+    a chip-backed engine, `auto` taking the paged decode kernel, and
     the long-context sp lane on the accelerator."""
 
     async def test_agent_on_chip_over_kafka_wire(self):
@@ -179,38 +134,29 @@ class TestRound4FeaturesOnChip:
             proc.terminate()
             proc.wait(timeout=5)
 
-    async def test_attn_auto_flip_serves_on_chip(self, tmp_path, monkeypatch):
-        """A TPU-platform profile artifact flips `auto` to pallas for the
-        decode path and the engine still serves correct greedy tokens —
-        the full auto-resolution pipeline exercised on hardware."""
-        import json
-
-        import jax
+    async def test_attn_auto_serves_through_the_kernel_on_chip(self):
+        """On a chip `auto` takes the paged decode kernel for a paged
+        engine with eligible heads, and the engine serves the explicit XLA
+        engine's greedy tokens: the one selection exercised on hardware."""
+        from dataclasses import replace
 
         from calfkit_tpu.inference.config import RuntimeConfig, preset
         from calfkit_tpu.inference.engine import InferenceEngine
 
-        _chip()
-        platform = jax.devices()[0].platform
+        if _chip()[0].platform != "tpu":
+            pytest.skip("the kernel is a TPU kernel")
+        wide = replace(preset("debug"), d_model=256, n_heads=2, n_kv_heads=1)
         kw = dict(max_batch_size=2, max_seq_len=128, prefill_chunk=16,
-                  decode_steps_per_dispatch=8)
-        # baseline: explicit XLA
-        monkeypatch.setenv("CALFKIT_ATTN_PROFILE", "/nonexistent.json")
+                  decode_steps_per_dispatch=8, kv_layout="paged", page_size=16)
         xla_engine = InferenceEngine(
-            preset("debug"), RuntimeConfig(attention_impl="xla", **kw), seed=3
+            wide, RuntimeConfig(attention_impl="xla", **kw), seed=3
         )
         await xla_engine.start()
         prompt = list(range(3, 40))
         want = [t async for t in xla_engine.generate(prompt, max_new_tokens=12)]
         await xla_engine.stop()
-        # artifact-resolved: auto -> pallas for decode on this platform
-        artifact = tmp_path / "attn.json"
-        artifact.write_text(json.dumps({
-            "platform": platform, "winners": {"decode": "pallas"},
-        }))
-        monkeypatch.setenv("CALFKIT_ATTN_PROFILE", str(artifact))
-        auto_engine = InferenceEngine(preset("debug"), RuntimeConfig(**kw), seed=3)
-        assert auto_engine._resolved_attn_impl("decode") == "pallas"
+        auto_engine = InferenceEngine(wide, RuntimeConfig(**kw), seed=3)
+        assert auto_engine._attn_impl == "pallas"
         await auto_engine.start()
         got = [t async for t in auto_engine.generate(prompt, max_new_tokens=12)]
         await auto_engine.stop()
