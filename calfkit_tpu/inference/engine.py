@@ -860,6 +860,7 @@ class InferenceEngine:
             )
         self._paged = rt.kv_layout == "paged"
         self._attn_impl = self._resolved_attn_impl()
+        self._ssm_impl = self._resolved_ssm_impl()
         if self._paged:
             from calfkit_tpu.inference.paged import PageAllocator
             from calfkit_tpu.inference.sharding import pool_sharding
@@ -1187,6 +1188,38 @@ class InferenceEngine:
             )
         return impl
 
+    def _resolved_ssm_impl(self) -> str:
+        """Which implementation a Mamba layer's DECODE STEP uses for its
+        pass over the SSM state: the other computation that has a kernel.
+        Decided HERE, once at construction (``self._ssm_impl``), under the
+        same ``attention_impl`` values as the paged decode read.  Under
+        "auto": the Pallas kernel that updates the state and reads ``y``
+        out of it in one pass, in place, when the backend is a TPU, one
+        device holds the model, the model has Mamba layers and its state is
+        the kernel's (:func:`pallas_ssm.ssm_step_in_place_ok`: float32,
+        ``mamba_d_state`` whole lane tiles, ``mamba_d_head`` whole sublane
+        tiles); else the XLA body of ``mamba.mamba_step``, the reference
+        (PERF.md section 6, PR 30: measured on the v5e).
+
+        "pallas" / "pallas_interpret" waive the platform test alone, as
+        they do for the read; they NAME the attention kernel, so a state
+        outside the rule is served by XLA and not refused."""
+        impl = self.runtime.attention_impl
+        c = self.config
+        if not self._recurrent or impl == "xla" or (
+            impl == "auto" and jax.devices()[0].platform != "tpu"
+        ):
+            return "xla"
+        from calfkit_tpu.inference.pallas_ssm import ssm_step_in_place_ok
+
+        in_rule = self.mesh.size == 1 and ssm_step_in_place_ok(
+            c.mamba_n_heads, c.mamba_n_groups, c.mamba_d_head, c.mamba_d_state,
+            c.state_dtype,
+        )
+        if not in_rule:
+            return "xla"
+        return "pallas" if impl == "auto" else impl
+
     def _window_bucket(self, needed: int) -> int:
         """Smallest configured window ≥ needed (cap max_seq): the decode
         attention scan only reads this prefix of the cache, and each bucket
@@ -1219,6 +1252,7 @@ class InferenceEngine:
         two compile the identical subgraph (ragged-on parity is structural,
         not coincidental)."""
         cfg = self.config
+        ssm_impl = self._ssm_impl
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, last, lens, active, done_prev,
@@ -1251,7 +1285,8 @@ class InferenceEngine:
                 ring, last, *st = carry
                 logits, ring, *st = M.decode_step_ring(
                     params, cfg, last[:, None], (kw, vw), ring, t, lens,
-                    **({"state": st[0], "active": active} if st else {}),
+                    **({"state": st[0], "active": active, "ssm_impl": ssm_impl}
+                       if st else {}),
                 )
                 if sampled:
                     # per-(request, position) streams: deterministic for a
@@ -1301,7 +1336,7 @@ class InferenceEngine:
         """The paged decode dispatch body (untraced) — see
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
-        attn_impl = self._attn_impl
+        attn_impl, ssm_impl = self._attn_impl, self._ssm_impl
         from calfkit_tpu.inference.pallas_attention import lane_dense_pool
 
         @jax.named_scope("decode_loop")
@@ -1336,7 +1371,7 @@ class InferenceEngine:
                 logits, ring, *st = M.decode_step_ring_paged(
                     params, cfg, last[:, None], pool, tables, ring, t,
                     lens, wpages=wpages, attn_impl=attn_impl, active=active,
-                    **({"state": st[0]} if st else {}),
+                    **({"state": st[0], "ssm_impl": ssm_impl} if st else {}),
                 )
                 if sampled:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)

@@ -138,6 +138,31 @@ def _layer_of(stacked: jax.Array, im: jax.Array) -> jax.Array:
     return lax.dynamic_index_in_dim(stacked, im, 0, keepdims=False)
 
 
+def ssm_step_xla(
+    all_ssm: jax.Array,  # [Lm, B, H, P, N] the stacked state
+    im: jax.Array,  # which layer's slice
+    decay: jax.Array,  # [B, G, E] exp(dt A)
+    dtx: jax.Array,  # [B, G, E, P] dt x
+    Bm: jax.Array,  # [B, G, N]
+    Cm: jax.Array,  # [B, G, N]
+    active: jax.Array | None,  # [B] bool; None: every row advances
+) -> tuple[jax.Array, jax.Array]:
+    """The decode step's pass over layer ``im``'s SSM state, in XLA -> (y
+    [B, G, E, P] float32 without the skip term, the state).  The update is
+    fused into the in-place write; the readout is a second fusion that
+    reads the slice again.  The reference ``pallas_ssm.ssm_step_pallas`` is
+    held to, argument for argument."""
+    ssm = _layer_of(all_ssm, im)
+    B, G, E, P = dtx.shape
+    S = ssm.astype(jnp.float32).reshape(B, G, E, P, ssm.shape[-1])
+    S = S * decay[..., None, None] + dtx[..., None] * Bm[:, :, None, None, :]
+    y = jnp.einsum("bgepn,bgn->bgep", S, Cm, precision=_HI)
+    new_ssm = S.reshape(ssm.shape).astype(ssm.dtype)
+    if active is not None:
+        new_ssm = jnp.where(active[:, None, None, None], new_ssm, ssm)
+    return y, lax.dynamic_update_index_in_dim(all_ssm, new_ssm, im, 0)
+
+
 def mamba_step(
     h: jax.Array,  # [B, 1, D] the normed stream
     lp: Params,  # one Mamba layer's leaves
@@ -145,11 +170,15 @@ def mamba_step(
     im: jax.Array,  # which Mamba layer this is: its slice of ``state``
     active: jax.Array | None,  # [B] bool; None: every row advances
     config: ModelConfig,
+    ssm_impl: str = "xla",  # InferenceEngine._resolved_ssm_impl: "xla" | "pallas" | "pallas_interpret"
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """One token through the mixer -> (out [B, 1, D], state).  The layer's
     slice of the stacked state is read and rewritten INSIDE the ``conv``
-    and ``ssm`` scopes: XLA fuses the update into the in-place write, and
-    the device time of touching the state has to read under those names."""
+    and ``ssm`` scopes, and the device time of touching the state has to
+    read under those names.  The SSM state's pass is the XLA body below
+    (the update fused into the in-place write, the readout a second fusion
+    that reads the slice again), which is the reference, or the kernel that
+    does both in one pass (``pallas_ssm.ssm_step_pallas``)."""
     c = config
     B = h.shape[0]
     all_ssm, all_conv = state
@@ -165,19 +194,21 @@ def mamba_step(
             new_conv = jnp.where(active[None, :, None], new_conv, conv)
         all_conv = lax.dynamic_update_index_in_dim(all_conv, new_conv, im, 0)
     with jax.named_scope("ssm"):
-        ssm = _layer_of(all_ssm, im)
         x, Bm, Cm = _split_xbc(xbc_act, c)  # [B, G, E, P], [B, G, N]
         dt, A = _head_terms(dt_raw, lp, c)  # [B, G, E], [G, E]
         G, E = A.shape
-        S = ssm.astype(jnp.float32).reshape(B, G, E, c.mamba_d_head, c.mamba_d_state)
-        decay = jnp.exp(dt * A)[..., None, None]
-        S = S * decay + (dt[..., None] * x)[..., None] * Bm[:, :, None, None, :]
-        y = jnp.einsum("bgepn,bgn->bgep", S, Cm, precision=_HI)
+        decay = jnp.exp(dt * A)
+        dtx = dt[..., None] * x
+        if ssm_impl.startswith("pallas"):
+            from calfkit_tpu.inference.pallas_ssm import ssm_step_pallas
+
+            y, all_ssm = ssm_step_pallas(
+                all_ssm, im, decay, dtx, Bm, Cm, active,
+                interpret=ssm_impl == "pallas_interpret",
+            )
+        else:
+            y, all_ssm = ssm_step_xla(all_ssm, im, decay, dtx, Bm, Cm, active)
         y = y + lp["D"].astype(jnp.float32).reshape(G, E)[None, :, :, None] * x
-        new_ssm = S.reshape(ssm.shape).astype(ssm.dtype)
-        if active is not None:
-            new_ssm = jnp.where(active[:, None, None, None], new_ssm, ssm)
-        all_ssm = lax.dynamic_update_index_in_dim(all_ssm, new_ssm, im, 0)
     out = _gate_out(y.reshape(B, c.mamba_d_inner), z, lp, c, h.dtype)
     return out[:, None], (all_ssm, all_conv)
 

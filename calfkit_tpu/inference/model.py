@@ -497,6 +497,7 @@ def _decode_step_with_ring(
     scan_xs: Any,  # extra per-layer scan inputs threaded to attn_source
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,  # hybrid: rows whose state advances
+    ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
 ) -> Any:
     """The shared decode-step transformer body (ring-buffer scheme).
 
@@ -549,7 +550,7 @@ def _decode_step_with_ring(
 
         def mamba_layer(carry, h, lp, im):
             ring_k, ring_v, st = carry
-            y, st = mamba_step(h, lp, st, im, active, config)
+            y, st = mamba_step(h, lp, st, im, active, config, ssm_impl)
             return (ring_k, ring_v, st), y
 
         x, (ring_k, ring_v, state) = _hybrid_stack(
@@ -600,6 +601,7 @@ def decode_step_ring(
     attn_window: int | None = None,
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,
+    ssm_impl: str = "xla",
 ) -> Any:
     """One decode step over the dense [L, B, K, S, hd] cache layout."""
     k_pages, v_pages = kv_cache
@@ -613,7 +615,7 @@ def decode_step_ring(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source,
-        (k_pages, v_pages), state, active,
+        (k_pages, v_pages), state, active, ssm_impl,
     )
 
 
@@ -1059,6 +1061,7 @@ def decode_step_ring_paged(
     attn_impl: str = "xla",
     active: jax.Array | None = None,  # [B] bool; None: every row reads
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
+    ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
 ) -> Any:
     """One decode step reading KV through the block tables.
 
@@ -1102,7 +1105,7 @@ def decode_step_ring_paged(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source, None,
-        state, active,
+        state, active, ssm_impl,
     )
 
 

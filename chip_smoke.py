@@ -23,15 +23,21 @@ bf16, random weights from ``--seed``):
   asked for by name, same check.
 - serve-wide — heads of 128 (Llama-3-8B's widths, 4 layers) under
   ``attention_impl="auto"``; same checks.
-- kernels — the one Pallas kernel, the paged decode read in place,
-  compiled, against the XLA gather it replaces at Mistral-7B's and
-  granite-4.0-h-micro's widths, batch and window.
+- hybrid-xla, hybrid — one period of granite-4.0-h-micro (nine Mamba-2
+  layers around one attention layer, published widths) under
+  ``attention_impl="xla"`` and under ``"auto"``: the same agreement check
+  against a full recompute, so the positions the margin decides are equal
+  in the two; under ``auto`` BOTH kernels must have been built, compiled
+  (the paged decode read and the SSM step's one pass over the state).
+- kernels — the paged decode read in place, compiled, against the XLA
+  gather it replaces at Mistral-7B's and granite-4.0-h-micro's widths,
+  batch and window.
 
 In every serving phase on a chip (TinyLlama's heads of 64, two positions a
 lane row, under ``auto`` and by name; heads of 128 under ``auto``) the
 paged decode read must have resolved to ``pallas`` and ``KERNEL_TRACES``
 must hold that kernel alone, compiled by Mosaic: never interpreted, and no
-other kernel built.
+other kernel built (a dense model has no other to build).
 
 ``--rehearse`` shrinks everything and uses interpret mode on the CPU; it
 can never print ``"ok": true``.
@@ -295,7 +301,12 @@ def reference_fn(config):
         P = tokens.shape[1]
         cache = M.make_empty_cache(config, 1, P)
         pos = jnp.arange(P, dtype=jnp.int32)[None]
-        logits, _ = M.forward(params, config, tokens, pos, cache, n[None])
+        hybrid = {}
+        if config.recurrent:  # zero state in, the padding moves none
+            from calfkit_tpu.inference.mamba import make_recurrent_state
+
+            hybrid = {"state": make_recurrent_state(config, 1), "n_valid": n[None]}
+        logits, *_ = M.forward(params, config, tokens, pos, cache, n[None], **hybrid)
         top2 = jax.lax.top_k(logits[0].astype(jnp.float32), 2)
         return top2[1][:, 0], top2[0][:, 0] - top2[0][:, 1]
 
@@ -352,7 +363,7 @@ async def agree_phase(name, engine, prompts, new_tokens, margin, pad_to) -> tupl
 
 
 # --------------------------------------------------------------------------- #
-# kernels: the one Pallas kernel, compiled, against its XLA reference
+# kernels: the paged decode read, compiled, against its XLA reference
 # --------------------------------------------------------------------------- #
 
 
@@ -472,6 +483,80 @@ def serving_runtime(sz: dict, impl: str, **kw):
     )
 
 
+def hybrid_config(sz: dict, rehearse: bool):
+    """One period of granite-4.0-h-micro's stack at its published widths
+    (in a rehearsal a toy inside both kernels' rules: attention heads of
+    64, a float32 state of 32 heads of 8 in two groups, d_state 128)."""
+    from dataclasses import replace
+
+    from calfkit_tpu.inference.config import ModelConfig, preset
+
+    if rehearse:
+        return ModelConfig(
+            name="debug-hybrid", vocab_size=256, d_model=256, n_layers=3, n_heads=4,
+            n_kv_heads=2, d_ff=128, layer_types=("mamba", "mamba", "attention"),
+            mamba_n_heads=32, mamba_d_head=8, mamba_d_state=128, mamba_n_groups=2,
+            mamba_d_conv=4, mamba_chunk_size=8, dtype="float32", position_embedding="none",
+            attention_multiplier=0.125, logits_scaling=8.0, max_seq_len=sz["seq"],
+        )
+    base = preset("granite-4.0-h-micro", max_seq_len=sz["seq"])
+    period = len(base.layer_period)
+    return replace(base, name=base.name + "/one-period", n_layers=period,
+                   layer_types=base.layer_types[:period])
+
+
+async def run_hybrid(args, sz: dict) -> bool:
+    """The hybrid stack through the engine under "xla" and under the
+    kernels: each agrees with the full recompute wherever the margin
+    decides (so the decided positions are equal in the two), and under the
+    kernels both were built as asked."""
+    import jax
+
+    from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference.engine import InferenceEngine
+    from calfkit_tpu.inference.pallas_ssm import ssm_step_pallas
+
+    config = hybrid_config(sz, args.rehearse)
+    # a CPU has no "auto" that selects a kernel: the rehearsal asks by name
+    resolved, want = ("pallas_interpret", "interpreted") if args.rehearse else ("pallas", "compiled")
+    kernels = resolved if args.rehearse else "auto"
+    prompts = prompts_for(config.vocab_size, sz["agree_lens"], args.seed)
+    ok, xla_outputs = True, None
+    for phase, impl in (("hybrid-xla", "xla"), ("hybrid", kernels)):
+        # each entry point is a jit of its own, traced once a process a shape
+        PA.paged_decode_attention_pallas.clear_cache()
+        ssm_step_pallas.clear_cache()
+        PA.KERNEL_TRACES.clear()
+        engine = InferenceEngine(config, serving_runtime(sz, impl), seed=args.seed)
+        await engine.start()
+        try:
+            row, outputs = await agree_phase(
+                phase, engine, prompts, sz["agree_new"],
+                args.margin / config.logits_scaling, sz["pad_to"],
+            )
+        finally:
+            await engine.stop()
+        row["ssm_impl"] = engine._ssm_impl
+        row["kernel_traces"] = {f"{k}:{mode}": n for (k, mode), n in PA.KERNEL_TRACES.items()}
+        if impl == "xla":
+            xla_outputs = outputs
+            built_ok = not row["kernel_traces"] and engine._ssm_impl == "xla"
+        else:
+            row["equal_to_xla_engine"] = count_equal(xla_outputs, outputs)
+            built_ok = (
+                engine._ssm_impl == resolved
+                and set(row["kernel_traces"]) == {f"paged_decode:{want}", f"ssm_step:{want}"}
+            )
+        row["kernels_built_as_asked"] = built_ok
+        row["ok"] = row["ok"] and built_ok
+        row["memory"] = memory(jax.devices()[:1])
+        emit(row)
+        ok = ok and row["ok"]
+        del engine
+        gc.collect()
+    return ok
+
+
 async def run_one_chip(args, sz: dict) -> bool:
     import jax
 
@@ -514,7 +599,7 @@ async def run_one_chip(args, sz: dict) -> bool:
         if impl != "auto" or not args.rehearse:  # a CPU's "auto" is XLA
             traces = {f"{k}:{mode}": n for (k, mode), n in PA.KERNEL_TRACES.items()}
             row["kernel_traces"] = agree["kernel_traces"] = traces
-            # the one kernel, built as asked, and no other
+            # a dense model's one kernel, built as asked, and no other
             kernels_ok = (
                 row["attention_impl"] == pallas
                 and set(traces) == {f"paged_decode:{want}"}
@@ -530,6 +615,7 @@ async def run_one_chip(args, sz: dict) -> bool:
         await model.stop()
         del model, engine
         gc.collect()  # the next engine's weights must not sit beside these
+    ok = await run_hybrid(args, sz) and ok
     row = kernels_phase(args.seed, interpret=args.rehearse)
     emit(row)
     return ok and row["ok"]

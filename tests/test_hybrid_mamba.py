@@ -55,6 +55,30 @@ TOY = ModelConfig(
 # 27th step).  2e-5 stands a factor of 80 above the first and 180 below
 # the second.
 LOGIT_TOL = 2e-5
+# the toy inside BOTH kernels' rules, for the ``pallas_interpret`` cases: heads
+# of 64 on pages of 16 for the paged decode read, and a state of whole tiles
+# (32 heads of 8 in two groups, 128 lines a group, d_state 128) for the SSM step
+TOYK = replace(
+    TOY, name="toy-hybrid-tiles", d_model=256, mamba_n_heads=32, mamba_d_head=8,
+    mamba_d_state=128,
+)
+# impl -> (the configuration, its runtime overrides)
+KERNEL_CASES = {
+    "xla": (TOY, {}),
+    "pallas_interpret": (TOYK, {"attention_impl": "pallas_interpret", "page_size": 16}),
+}
+
+
+def ssm_kernel_traces(fresh: bool = False) -> int:
+    """Times the SSM step kernel was traced (interpreted).  Its entry point
+    is a jit of its own, traced once a process a shape: ``fresh`` clears
+    that, so that the next engine on the same shapes counts again."""
+    from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
+    from calfkit_tpu.inference.pallas_ssm import ssm_step_pallas
+
+    if fresh:
+        ssm_step_pallas.clear_cache()
+    return KERNEL_TRACES["ssm_step", "interpreted"]
 
 
 def runtime(**kw) -> RuntimeConfig:
@@ -148,20 +172,26 @@ def test_full_forward_agrees_with_the_reference():
 
 
 # ----------------------------------------------------------------- (b)
-def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch):
+@pytest.mark.parametrize("impl", sorted(KERNEL_CASES))
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch, impl):
     """Paged, chunked with a chunk (16) smaller than the prompt (37) and an
     SSD block (8) smaller than the chunk; 21 generated tokens cross five
     dispatches of four steps.  Every generated position's logits against
-    the reference's full forward of prompt + output."""
+    the reference's full forward of prompt + output.  Under
+    ``pallas_interpret`` the decode steps' pass over the SSM state is the
+    kernel's (and the paged decode read the other kernel's)."""
+    config, over = KERNEL_CASES[impl]
     spy = Spy(monkeypatch)
     prompt = prompt_of(37)
-    (out,), params, counters = serve((TOY, runtime()), [(prompt, 21)])
+    before = ssm_kernel_traces(fresh=True)
+    (out,), params, counters = serve((config, runtime(**over)), [(prompt, 21)])
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, TOY, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    want = reference_logits(params, config, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
     assert counters["state_rows_landed"] == 1
-    assert counters["recurrent_state_bytes"] == TOY.recurrent_state_bytes(2)
+    assert counters["recurrent_state_bytes"] == config.recurrent_state_bytes(2)
+    assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
 
 def test_the_prompt_s_logits_agree_chunk_by_chunk(monkeypatch):
@@ -226,10 +256,14 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
         assert np.array_equal(a, b)  # the same program on the same numbers
 
 
-def test_a_frozen_row_s_state_is_bit_equal_across_a_dispatch():
+@pytest.mark.parametrize("impl", sorted(KERNEL_CASES))
+def test_a_frozen_row_s_state_is_bit_equal_across_a_dispatch(impl):
     """Row 1 is not active: a decode dispatch leaves its SSM and conv
-    state bit for bit, while row 0's moves."""
-    engine = InferenceEngine(TOY, runtime(), seed=3)
+    state bit for bit, while row 0's moves.  (The kernel neither reads nor
+    writes such a row.)"""
+    config, over = KERNEL_CASES[impl]
+    engine = InferenceEngine(config, runtime(**over), seed=3)
+    assert engine._ssm_impl == impl
     ssm, conv = engine._state
     engine._state = (
         ssm + jax.random.normal(jax.random.key(1), ssm.shape),
@@ -245,23 +279,31 @@ def test_a_frozen_row_s_state_is_bit_equal_across_a_dispatch():
 
 
 # ----------------------------------------------------------------- (e)
-def test_control_a_bfloat16_state_fails_the_tolerance(monkeypatch):
+@pytest.mark.parametrize("impl", sorted(KERNEL_CASES))
+def test_control_a_bfloat16_state_fails_the_tolerance(monkeypatch, impl):
     """The tolerance of (b) would catch a lower precision than the file
     states: with the SSM state held in bfloat16 (float32 weights and
-    activations as before) the same comparison fails within 512 steps."""
-    config = replace(TOY, state_dtype="bfloat16")
+    activations as before) the same comparison fails within 512 steps.
+    Under ``pallas_interpret`` the float32 state takes the kernel and
+    passes; the bfloat16 state is outside the kernel's rule (a float32 pass
+    or none), reads through XLA, and fails as it does there."""
+    float32, over = KERNEL_CASES[impl]
+    config = replace(float32, state_dtype="bfloat16")
     spy = Spy(monkeypatch)
     prompt = prompt_of(37)
-    rt = runtime(max_seq_len=1024, window_buckets=(128, 1024))
+    rt = runtime(max_seq_len=1024, window_buckets=(128, 1024), **over)
+    assert InferenceEngine(config, rt)._ssm_impl == "xla"
     (out,), params, _ = serve((config, rt), [(prompt, 512)])
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, TOY, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    want = reference_logits(params, float32, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert np.abs(got - want).max() > LOGIT_TOL
     spy.seen.clear()
-    (out32,), params, _ = serve((TOY, rt), [(prompt, 512)])  # and the stated precision passes
+    before = ssm_kernel_traces(fresh=True)
+    (out32,), params, _ = serve((float32, rt), [(prompt, 512)])  # and the stated precision passes
     got = spy.of_request(prompt, out32, 16)
-    want = reference_logits(params, TOY, prompt + out32)[len(prompt) - 1:][: len(out32)]
+    want = reference_logits(params, float32, prompt + out32)[len(prompt) - 1:][: len(out32)]
     assert np.abs(got - want).max() < LOGIT_TOL
+    assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
 
 # ----------------------------------------------------------------- (f)

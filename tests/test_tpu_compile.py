@@ -1,13 +1,13 @@
-"""AOT compiles of the Pallas attention kernel for a DESCRIBED v5e.
+"""AOT compiles of the two Pallas kernels for a DESCRIBED v5e.
 
 The TPU compiler is installed without a chip: it compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2).
 Interpret mode cannot see what the Mosaic lowering refuses — block shapes,
-SMEM scalars, VMEM limits — so the one kernel the serving path can select,
-the paged decode read in place, is compiled here at the widths of every
-preset and benchmark configuration that takes it, alone and inside the
-dispatch programs of the benchmark's cells.  Nothing runs; a pass is a
-compile, never a chip run.
+SMEM scalars, VMEM limits — so the two kernels the serving path can select,
+the paged decode read in place and the Mamba-2 decode step's pass over the
+SSM state, are compiled here at the widths of every preset and benchmark
+configuration that takes them, alone and inside the dispatch programs of
+the benchmark's cells.  Nothing runs; a pass is a compile, never a chip run.
 
 Rules this file keeps: the topology is described inside a module-scoped
 fixture (never at import, in a skipif, in parametrize arguments or in
@@ -254,38 +254,59 @@ def _dispatch_programs(engine, sharding):
 
 
 @pytest.mark.parametrize(
-    "cell,program",
+    "cell,program,ssm_kernels",
     [
-        ("mistral-7b-v0.3-int8", "decode"),
-        ("mistral-7b-v0.3-int8", "ragged"),
-        ("granite-4.0-h-micro", "ragged"),
+        ("mistral-7b-v0.3-int8", "decode", 0),
+        ("mistral-7b-v0.3-int8", "ragged", 0),
+        ("granite-4.0-h-micro", "decode", 9),
+        ("granite-4.0-h-micro", "ragged", 9),
     ],
 )
-def test_cell_dispatch_program_holds_one_kernel_on_v5e(
-    cell, program, cell_engine, one_chip, no_persistent_cache
+def test_cell_dispatch_program_holds_its_kernels_on_v5e(
+    cell, program, ssm_kernels, cell_engine, one_chip, no_persistent_cache
 ):
     """A dispatch program of a benchmark cell, compiled for the described
-    v5e: exactly ONE kernel is in it, the paged decode read of the decode
-    loop, and no window is gathered there; the chunk that rides along in a
-    ragged program reads through XLA.  (granite's decode dispatch is the
-    test above.)"""
-    fn, args = _dispatch_programs(cell_engine(cell), one_chip)[program]
-    hlo = fn.lower(*args).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
-    assert "paged_decode_attention" in hlo
+    v5e: ONE paged decode read of the decode loop, no window gathered
+    there, and, where the model has them, one SSM step kernel a Mamba layer
+    of the period (nine in granite's), each under the ``ssm`` scope; the
+    chunk that rides along in a ragged program reads and scans through XLA.
+    The state goes out where it came in, and the program's temporaries stay
+    under TWO layers' state: no copy of the stacked state is made."""
+    engine = cell_engine(cell)
+    assert engine._ssm_impl == ("pallas" if ssm_kernels else "xla")
+    fn, args = _dispatch_programs(engine, one_chip)[program]
+    compiled = fn.lower(*args).compile()
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 + ssm_kernels
+    assert sum("paged_decode_attention" in line for line in kernels) == 1
+    assert sum("/mamba/ssm/jit(ssm_step_pallas)/ssm/pallas_call" in line
+               for line in kernels) == ssm_kernels
     assert "gather_window" not in hlo
     if program == "ragged":
         assert "chunk_loop/" in hlo and "decode_loop/" in hlo
+    if ssm_kernels:
+        memory = compiled.memory_analysis()
+        state_bytes = engine.config.recurrent_state_bytes(engine.runtime.max_batch_size)
+        assert memory.alias_size_in_bytes >= state_bytes
+        if program == "decode":
+            assert memory.temp_size_in_bytes < 2 * state_bytes // engine.config.n_mamba_layers
 
 
+@pytest.mark.parametrize("ssm_impl", ["xla", "pallas"])
 def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
-    one_chip, no_persistent_cache
+    ssm_impl, one_chip, no_persistent_cache
 ):
     """One Mamba-2 layer's decode step at granite-4.0-h-micro's widths and
     the benchmark cell's 64 rows, on a stacked state of three layers: the
     program's temporaries stay under ONE layer's state (134 MB), so the
     update is written in place and no copy of the state is made.  With 36
-    layers a copy is 4.8 GB, and the cell's 16 GB chip has no room for it."""
+    layers a copy is 4.8 GB, and the cell's 16 GB chip has no room for it.
+    With the kernel the compiled step holds ONE ``tpu_custom_call``, under
+    the ``ssm`` scope, and no fusion or copy of the state's size at all:
+    the state is touched by the kernel alone."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
@@ -309,7 +330,7 @@ def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
         lambda: tuple(s[:layers] for s in mm.make_recurrent_state(config, rows))
     ))
     compiled = jax.jit(
-        lambda h, lp, st, im, active: mm.mamba_step(h, lp, st, im, active, config),
+        lambda h, lp, st, im, active: mm.mamba_step(h, lp, st, im, active, config, ssm_impl),
         donate_argnums=(2,),
     ).lower(
         jax.ShapeDtypeStruct((rows, 1, config.d_model), jnp.bfloat16, sharding=one_chip),
@@ -321,23 +342,29 @@ def test_mamba_decode_step_updates_the_state_in_place_on_v5e(
     one_layer = config.recurrent_state_bytes(rows) // config.n_mamba_layers
     assert memory.alias_size_in_bytes >= layers * one_layer  # the state goes out where it came in
     assert memory.temp_size_in_bytes < one_layer
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == (ssm_impl == "pallas")
+    # an operation that makes a layer's slice or the whole state (bitcasts,
+    # parameters and tuple plumbing make nothing)
+    H, P, N = config.mamba_n_heads, config.mamba_d_head, config.mamba_d_state
+    state_sized = re.compile(
+        rf"= f32\[(?:{layers},)?{rows},(?:{H},{P}|{H * P}),{N}\]\S* (\w[\w\-]*)\(")
+    made = [m.group(1) for m in map(state_sized.search, hlo.splitlines())
+            if m and m.group(1) not in ("bitcast", "parameter", "get-tuple-element")]
+    if ssm_impl == "pallas":
+        # the kernel's result is a tuple: the whole stacked state and y
+        assert "ssm/jit(ssm_step_pallas)/ssm/pallas_call" in kernels[0]
+        assert f"(f32[{layers},{rows},{H * P},{N}]" in kernels[0]
+        assert made == [], made
+    else:
+        assert "copy" not in made and "fusion" in made, made
 
 
-def test_kernel_bytes_do_not_depend_on_the_caller(
-    one_chip, no_persistent_cache, monkeypatch
-):
-    """The serialized kernel is hashed into the persistent cache's key, and
-    a jitted entry point is traced once a process, from whichever program
-    calls it first.  Under the compile-cache rule its bytes are the same
-    from a shallow and from a deep, differently scoped call stack."""
-    import jax
+def _paged_decode_entry(shape):
     import jax.numpy as jnp
 
     from calfkit_tpu.inference import pallas_attention as PA
-    from calfkit_tpu.inference.compile_cache import enable_compile_cache
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     K, G, hd, rows, window, pages, layers = DECODE_PAGED_WIDTHS["mistral-7b-v0.3"]
     bf16, i32 = jnp.bfloat16, jnp.int32
@@ -346,9 +373,41 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
         *(shape((layers, pages, K, PAGE, hd), bf16),) * 2, shape((), i32),
         shape((rows, window // PAGE), i32), shape((rows,), i32),
     )
+    return (PA.paged_decode_attention_pallas,
+            lambda *a: PA.paged_decode_attention_pallas(*a, wpages=window // PAGE), args)
 
-    def f(*a):
-        return PA.paged_decode_attention_pallas(*a, wpages=window // PAGE)
+
+def _ssm_step_entry(shape):
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_ssm as PS
+
+    layers, rows, H, P, N = 3, 64, 64, 64, 128  # granite-4.0-h-micro's state
+    f32 = jnp.float32
+    args = (
+        shape((layers, rows, H, P, N), f32), shape((), jnp.int32), shape((rows, 1, H), f32),
+        shape((rows, 1, H, P), f32), shape((rows, 1, N), f32), shape((rows, 1, N), f32),
+        shape((rows,), jnp.bool_),
+    )
+    return PS.ssm_step_pallas, lambda *a: PS.ssm_step_pallas(*a), args
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ssm_step"])
+def test_kernel_bytes_do_not_depend_on_the_caller(
+    kernel, one_chip, no_persistent_cache, monkeypatch
+):
+    """The serialized kernel is hashed into the persistent cache's key, and
+    a jitted entry point is traced once a process, from whichever program
+    calls it first.  Under the compile-cache rule its bytes are the same
+    from a shallow and from a deep, differently scoped call stack."""
+    import jax
+
+    from calfkit_tpu.inference.compile_cache import enable_compile_cache
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry}[kernel](shape)
 
     def deep(*a, depth=4):
         if depth:
@@ -356,10 +415,10 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
         with jax.named_scope("another_program"):
             return f(*a)
 
-    deep.__name__ = deep.__qualname__ = "f"  # one module name for both
+    f.__name__ = f.__qualname__ = deep.__name__ = deep.__qualname__ = "f"  # one module name for both
 
     def lowered(fn):
-        PA.paged_decode_attention_pallas.clear_cache()  # trace it from HERE
+        entry.clear_cache()  # trace it from HERE
         return jax.jit(fn).lower(*args).as_text()
 
     option = "jax_traceback_in_locations_limit"
@@ -367,24 +426,36 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent")  # no update
     try:
         enable_compile_cache()  # with ten frames in, the two differ
+        assert "tpu_custom_call" in lowered(f)
         assert lowered(f) == lowered(deep)
     finally:
         jax.config.update(option, before)
 
 
 def test_entry_point_list_is_complete():
-    """The kernel module's entry points are the two compiled above (the
-    merged read calls the plain one) around ONE ``pallas_call``: one added
-    without a compile of its own fails here."""
+    """The kernel modules' entry points are the three compiled above (the
+    merged read calls the plain one), each module around ONE
+    ``pallas_call``, and no other module of the package makes one: a kernel
+    added without a compile of its own fails here."""
+    import glob
     import inspect
 
     from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference import pallas_ssm as PS
 
-    entries = {
-        name for name, fn in vars(PA).items()
-        if name.endswith("_pallas") and callable(fn)
-    }
-    assert entries == {
+    def entries(module):
+        return {name for name, fn in vars(module).items()
+                if name.endswith("_pallas") and callable(fn)}
+
+    assert entries(PA) == {
         "paged_decode_attention_pallas", "merged_paged_decode_attention_pallas",
     }
+    assert entries(PS) == {"ssm_step_pallas"}
     assert inspect.getsource(PA).count("pl.pallas_call(") == 1
+    assert inspect.getsource(PS).count("pl.pallas_call(") == 1
+    with_kernels = sorted(
+        os.path.basename(path)
+        for path in glob.glob(os.path.join(os.path.dirname(PA.__file__), "*.py"))
+        if "pl.pallas_call(" in open(path).read()
+    )
+    assert with_kernels == ["pallas_attention.py", "pallas_ssm.py"]
