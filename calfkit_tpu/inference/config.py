@@ -18,6 +18,13 @@ class UnsupportedWithRecurrentLayers(ValueError):
     there is no silent fallback to a path that would drop the state."""
 
 
+class UnsupportedWithLatentAttention(ValueError):
+    """A runtime option that a model with latent attention (one latent a
+    token in place of K and V per head) and routed experts cannot be served
+    under yet.  Raised when the engine is built, never later: there is no
+    silent fallback to a path that would read the latent as K and V."""
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """A decoder architecture description.
@@ -29,6 +36,12 @@ class ModelConfig:
     the SwiGLU MLP, the mixer before it is attention or Mamba-2, and the
     ``mamba_*`` sizes, the position rule, the attention scale and the four
     multipliers below apply.
+    With ``kv_lora_rank`` it is a DeepSeek-V3-style stack: every layer's
+    mixer is latent attention (MLA: what a token leaves in the cache is ONE
+    latent of ``kv_lora_rank + qk_rope_head_dim`` numbers a layer, not K and
+    V per head), and with ``n_routed_experts`` every layer after the first
+    ``first_k_dense`` replaces the SwiGLU of ``d_ff`` by routed experts of
+    ``moe_d_ff`` and one shared expert of ``n_shared_experts x moe_d_ff``.
     """
 
     name: str = "debug"
@@ -64,8 +77,56 @@ class ModelConfig:
     embedding_multiplier: float = 1.0  # x = embed[tokens] * this
     residual_multiplier: float = 1.0  # x = x + this * block(norm(x))
     logits_scaling: float = 1.0  # logits = head(x) / this
+    # ---- latent attention (HF names; all defaults = K and V per head) ----
+    # q = h W_q -> n_heads x (qk_nope | qk_rope); [c | k_rope] = h W_kva ->
+    # kv_lora_rank | qk_rope, ONE a token; c = rmsnorm(c); [k_nope | v] =
+    # c W_kvb -> n_heads x (qk_nope | v_head_dim); rope on the rope parts
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # HF builds kv_a_layernorm with its class default, not rms_norm_eps
+    kv_norm_eps: float = 1e-6
+    # ---- routed experts (HF names; all defaults = one SwiGLU a layer) ----
+    n_routed_experts: int = 0
+    n_experts_per_tok: int = 0  # num_experts_per_tok
+    n_shared_experts: int = 0  # ONE shared SwiGLU of this many x moe_d_ff
+    moe_d_ff: int = 0  # moe_intermediate_size
+    first_k_dense: int = 0  # first_k_dense_replace: leading layers of d_ff
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self) -> None:
+        if self.kv_lora_rank:
+            if self.layer_types:
+                raise ValueError("latent attention in a hybrid stack is not described")
+            if not (self.qk_nope_head_dim and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent attention needs qk_nope_head_dim/qk_rope_head_dim/v_head_dim"
+                )
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        elif self.n_routed_experts:
+            raise ValueError(
+                "routed experts are described for the latent-attention stack "
+                "(kv_lora_rank) alone"
+            )
+        if self.n_routed_experts:
+            if not (0 < self.n_experts_per_tok <= self.n_routed_experts and self.moe_d_ff):
+                raise ValueError("routed experts need n_experts_per_tok and moe_d_ff")
+            if not 0 <= self.first_k_dense < self.n_layers:
+                raise ValueError("first_k_dense must leave at least one expert layer")
+            if (self.scoring_func, self.topk_method) != ("sigmoid", "noaux_tc"):
+                raise ValueError(
+                    f"router {self.scoring_func!r}/{self.topk_method!r}: only "
+                    "sigmoid scores with noaux_tc selection are described"
+                )
+            if (self.n_group, self.topk_group) != (1, 1):
+                raise ValueError("group-limited routing (n_group > 1) is not described")
         if self.layer_types:
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
@@ -101,7 +162,49 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
+        """Width of a query (and key) head."""
+        if self.latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def latent(self) -> bool:
+        """Is what a token leaves in the cache one latent (MLA)?"""
+        return self.kv_lora_rank > 0
+
+    @property
+    def moe(self) -> bool:
+        return self.n_routed_experts > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_k_dense if self.moe else 0
+
+    @property
+    def n_dense_layers(self) -> int:
+        """Layers whose FFN is the one SwiGLU of ``d_ff``."""
+        return self.n_layers - self.n_moe_layers
+
+    # What the cache keeps of a token, a layer: two arrays ("sides") of
+    # ``cache_heads x cache_dims[side]`` numbers.  K and V per head, or the
+    # two parts of the one latent that every head shares: c (kv_lora_rank,
+    # key AND value) and k_rope (qk_rope_head_dim), kept apart so that the
+    # wide part is whole lane tiles (576 = 4.5 x 128 is not).  The page pool,
+    # the decode ring, the prefill scratch and the page accounting read
+    # these, never ``n_kv_heads x head_dim``.
+    @property
+    def cache_heads(self) -> int:
+        return 1 if self.latent else self.n_kv_heads
+
+    @property
+    def cache_dims(self) -> tuple[int, int]:
+        if self.latent:
+            return self.kv_lora_rank, self.qk_rope_head_dim
+        return self.head_dim, self.head_dim
+
+    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Bytes one token adds to the cache over all its layers."""
+        return self.n_kv_layers * self.cache_heads * sum(self.cache_dims) * itemsize
 
     @property
     def recurrent(self) -> bool:
@@ -154,6 +257,24 @@ class ModelConfig:
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning)."""
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
+        if self.latent:
+            H, r = self.n_heads, self.kv_lora_rank
+            attention = (
+                self.d_model * H * self.head_dim  # W_q
+                + self.d_model * (r + self.qk_rope_head_dim) + r  # W_kva, its norm
+                + r * H * (self.qk_nope_head_dim + self.v_head_dim)  # W_kvb
+                + H * self.v_head_dim * self.d_model  # W_o
+            )
+            dense = 3 * self.d_model * self.d_ff
+            expert = 3 * self.d_model * self.moe_d_ff
+            moe = (
+                self.n_routed_experts * (self.d_model + 1)  # gate, its bias
+                + (self.n_routed_experts + self.n_shared_experts) * expert
+            )
+            return (
+                embed + self.d_model + self.n_layers * (attention + 2 * self.d_model)
+                + self.n_dense_layers * dense + self.n_moe_layers * moe
+            )
         attention = (
             # q, k, v, o
             self.d_model * self.n_heads * self.head_dim
@@ -445,6 +566,33 @@ PRESETS: dict[str, ModelConfig] = {
         embedding_multiplier=12.0,
         residual_multiplier=0.22,
         logits_scaling=8.0,
+    ),
+    # Kimi-VL-A3B-Instruct's language decoder (HF: moonshotai/
+    # Kimi-VL-A3B-Instruct, text_config: DeepseekV3ForCausalLM at
+    # Moonlight's widths): latent attention in every layer, one dense layer,
+    # then 64 routed experts with 6 a token and one shared expert of 2 x 1408.
+    # Text only: the vision tower is not described here.
+    "kimi-vl-a3b-instruct": ModelConfig(
+        name="kimi-vl-a3b-instruct",
+        vocab_size=163840,
+        d_model=2048,
+        n_layers=27,
+        n_heads=16,
+        n_kv_heads=16,
+        d_ff=11264,
+        rope_theta=800000.0,
+        norm_eps=1e-5,
+        max_seq_len=131072,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        n_routed_experts=64,
+        n_experts_per_tok=6,
+        n_shared_experts=2,
+        moe_d_ff=1408,
+        first_k_dense=1,
+        routed_scaling_factor=2.446,
     ),
 }
 

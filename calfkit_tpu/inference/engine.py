@@ -53,9 +53,11 @@ from calfkit_tpu.inference.compile_cache import enable_compile_cache
 from calfkit_tpu.inference.config import (
     ModelConfig,
     RuntimeConfig,
+    UnsupportedWithLatentAttention,
     UnsupportedWithRecurrentLayers,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
+from calfkit_tpu.inference.moe import moe_stats_init
 from calfkit_tpu.observability import capacity, flightrec
 from calfkit_tpu.observability.trace import TRACER, Span, TraceContext
 from calfkit_tpu.observability.metrics import (
@@ -111,7 +113,9 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # heartbeat advert's window
 _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
-    "state_rows_landed", "prefix_reuse_declined_recurrent", *_SECONDS_FIELDS,
+    "state_rows_landed", "prefix_reuse_declined_recurrent",
+    "moe_assignments", "moe_expert_tokens_max", "moe_expert_tokens_mean",
+    "moe_experts_hit", *_SECONDS_FIELDS,
 )
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
@@ -228,6 +232,33 @@ def _engine_metrics(
             "device bytes reserved for the slots' recurrent state "
             "(the last engine built)",
         ),
+        moe_assignments=reg.counter(
+            "calfkit_engine_moe_assignments_total",
+            "token-expert pairs the expert layers computed for REAL tokens "
+            "(experts per token x expert layers x tokens; a chunk's padded "
+            "positions and a decode step's inactive rows are computed and "
+            "not counted)",
+        ),
+        moe_expert_tokens_max=reg.counter(
+            "calfkit_engine_moe_expert_tokens_max_total",
+            "tokens of the busiest expert, summed over expert layers and "
+            "dispatches (a wave's chunks count as one dispatch)",
+        ),
+        moe_expert_tokens_mean=reg.counter(
+            "calfkit_engine_moe_expert_tokens_mean_total",
+            "tokens of the mean expert, summed the same way: max / mean is "
+            "the routing's imbalance",
+        ),
+        moe_experts_hit=reg.counter(
+            "calfkit_engine_moe_experts_hit_total",
+            "distinct experts a decode step had to read, summed over expert "
+            "layers and steps",
+        ),
+        latent_cache_bytes=reg.gauge(
+            "calfkit_engine_latent_cache_bytes",
+            "device bytes reserved for the latent (MLA) page pool "
+            "(the last engine built; 0 for a model that keeps K and V)",
+        ),
         active_requests=reg.gauge(
             "calfkit_engine_active_requests",
             "requests holding a slot (summed across the process's engines)",
@@ -258,6 +289,18 @@ def _some(x: Any) -> tuple:
     """``(x,)``, or ``()`` for None: an optional argument or result that a
     program without it never sees."""
     return () if x is None else (x,)
+
+
+def _carried_kw(state: Any, moe: Any, carried: list, ssm_impl: str) -> dict:
+    """What a decode step takes besides the cache, out of its scan's carry:
+    a hybrid's recurrent state first, then a routed-expert model's counters
+    (each only where the program was given one)."""
+    carried, kw = list(carried), {}
+    if state is not None:
+        kw.update(state=carried.pop(0), ssm_impl=ssm_impl)
+    if moe is not None:
+        kw["moe"] = carried.pop(0)
+    return kw
 
 
 @jax.named_scope("finalize")
@@ -530,6 +573,17 @@ class EngineStats:
     state_rows_landed: int = 0
     prefix_reuse_declined_recurrent: int = 0
     recurrent_state_bytes: int = 0
+    # routed experts (0 for a model without them): token-expert pairs
+    # computed for real tokens; the busiest and the mean expert's tokens,
+    # summed over expert layers and dispatches (their ratio is the
+    # routing's imbalance); distinct experts the decode steps had to read,
+    # summed over layers and steps.  And, a gauge, the device bytes of a
+    # latent (MLA) page pool.
+    moe_assignments: int = 0
+    moe_expert_tokens_max: int = 0
+    moe_expert_tokens_mean: float = 0.0
+    moe_experts_hit: int = 0
+    latent_cache_bytes: int = 0
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
     # delta readers would steal each other's intervals.
@@ -647,6 +701,7 @@ class EngineStats:
                 break
         out["occupancy_hist"] = list(self.occupancy_hist)
         out["recurrent_state_bytes"] = self.recurrent_state_bytes  # a gauge
+        out["latent_cache_bytes"] = self.latent_cache_bytes  # a gauge
         now = time.perf_counter()
         blocked, empty = self._blocked, self._empty
         if phase is not None:
@@ -779,6 +834,31 @@ class InferenceEngine:
                         f"{config.name} has recurrent (Mamba-2) layers: "
                         f"RuntimeConfig {option} is not supported with them ({why})"
                     )
+        # a model with latent attention keeps ONE latent a token where the
+        # others keep K and V per head, and its experts are leaves of their
+        # own; what has no code for either yet is refused HERE, with its reason
+        self._moe = config.moe
+        if config.latent:
+            refused = {
+                "speculative": (rt.speculative is not None,
+                                "the verify programs attend K and V pairs of heads"),
+                "tp > 1 / dp > 1": (rt.tp > 1 or rt.dp > 1 or self.mesh.size > 1,
+                                    "the latent pool and the expert leaves have no sharding "
+                                    "over a mesh of more than one device"),
+                "quantization": (rt.quantization is not None,
+                                 "the latent and expert leaves have no scales"),
+                "long_context": (rt.long_context,
+                                 "the sequence-parallel lane attends K and V per head"),
+                "kv_layout='dense'": (rt.kv_layout == "dense",
+                                      "the dense rows hold K and V per head; the latent "
+                                      "is served from pages"),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise UnsupportedWithLatentAttention(
+                        f"{config.name} has latent attention (MLA): RuntimeConfig "
+                        f"{option} is not supported with it ({why})"
+                    )
         shardings = param_shardings(config, self.mesh)
         if params is None:
             logger.info(
@@ -880,6 +960,8 @@ class InferenceEngine:
             n_pages = rt.pool_pages()
             pool_sh = pool_sharding(config, self.mesh)
             # born sharded, like the params: never whole on one device
+            # the pool is a pair: K and V, or the two parts (c, k_rope) of the
+            # one latent a token of a latent-attention model leaves behind
             self._k, self._v = jax.jit(
                 lambda: M.make_page_pool(config, n_pages, rt.page_size),
                 out_shardings=(pool_sh, pool_sh),
@@ -902,9 +984,9 @@ class InferenceEngine:
 
                 self._prefix = PrefixCache()
             logger.info(
-                "paged KV pool: %d pages x %d tokens (%.2f GB)",
-                n_pages, rt.page_size,
-                2 * self._k.size * self._k.dtype.itemsize / 1e9,
+                "paged %s pool: %d pages x %d tokens (%.2f GB)",
+                "latent" if config.latent else "KV", n_pages, rt.page_size,
+                (self._k.nbytes + self._v.nbytes) / 1e9,
             )
         else:
             self._prefix = None
@@ -1067,6 +1149,12 @@ class InferenceEngine:
         self.stats = EngineStats()
         if self._recurrent:
             self.stats.recurrent_state_bytes = config.recurrent_state_bytes(B)
+        if config.latent:
+            self.stats.latent_cache_bytes = self._k.nbytes + self._v.nbytes
+        # a dispatch of a model with routed experts carries their counters
+        # beside whatever state it carries (moe.py): zeros in, the
+        # dispatch's counts out, read at the landing's one sync
+        self._moe_zero = moe_stats_init(config) if self._moe else None
         # flight recorder: the ring journal every scheduler decision point
         # appends to (admission, waves, page alloc/free, spec/overlap
         # dispatches, deferred retirement, faults).  Appends are O(1)
@@ -1106,6 +1194,7 @@ class InferenceEngine:
         # cursors that turn cumulative stats into counter increments
         self.metrics = _engine_metrics()
         self.metrics["recurrent_state_bytes"].set(self.stats.recurrent_state_bytes)
+        self.metrics["latent_cache_bytes"].set(self.stats.latent_cache_bytes)
         # per-ENGINE latency histograms: the advert's percentiles must
         # attribute to THIS engine, not blend every engine in the process
         # (the process-registry instruments above stay shared for the
@@ -1156,6 +1245,18 @@ class InferenceEngine:
         is no kernel to build, and the engine is refused."""
         impl = self.runtime.attention_impl
         explicit = impl.startswith("pallas")
+        if self.config.latent:
+            # the kernel reads K and V pairs of heads and computes no MLA:
+            # a latent pool is read by XLA (the absorbed form), and says so
+            if explicit:
+                from calfkit_tpu.inference.pallas_attention import PallasShapeError
+
+                raise PallasShapeError(
+                    f"attention_impl={impl!r} names the paged decode kernel, which "
+                    "reads K and V pairs of heads; this model's pool is one latent a "
+                    'token (MLA) and is read by XLA: use "auto" or "xla"'
+                )
+            return "xla"
         if not explicit and not (
             impl == "auto" and jax.devices()[0].platform == "tpu"
         ):
@@ -1270,16 +1371,7 @@ class InferenceEngine:
             B = last.shape[0]
             kw = k[:, :, :, :window]
             vw = v[:, :, :, :window]
-            ring = (
-                jnp.zeros(
-                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
-                    k.dtype,
-                ),
-                jnp.zeros(
-                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
-                    v.dtype,
-                ),
-            )
+            ring = M.sides_like((k, v), (cfg.n_kv_layers, steps, B, cfg.cache_heads))
 
             def step(carry, t):
                 ring, last, *st = carry
@@ -1342,22 +1434,13 @@ class InferenceEngine:
         @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
                    stop_table, hard_end, slot_keys, temp, top_k, top_p,
-                   state=None):
+                   state=None, moe=None):
             # rows that retired in the still-in-flight previous dispatch
             # are frozen out here (and their consolidation writes routed
             # to the trash page) by the device-side done-mask chain
             active = active & jnp.logical_not(done_prev)
             B = last.shape[0]
-            ring = (
-                jnp.zeros(
-                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
-                    k.dtype,
-                ),
-                jnp.zeros(
-                    (cfg.n_kv_layers, steps, B, cfg.n_kv_heads, cfg.head_dim),
-                    v.dtype,
-                ),
-            )
+            ring = M.sides_like((k, v), (cfg.n_kv_layers, steps, B, cfg.cache_heads))
             pool = (k, v)
             if attn_impl.startswith("pallas"):
                 # the kernel's view of a pool of heads narrower than a lane
@@ -1371,7 +1454,7 @@ class InferenceEngine:
                 logits, ring, *st = M.decode_step_ring_paged(
                     params, cfg, last[:, None], pool, tables, ring, t,
                     lens, wpages=wpages, attn_impl=attn_impl, active=active,
-                    **({"state": st[0], "ssm_impl": ssm_impl} if st else {}),
+                    **_carried_kw(state, moe, st, ssm_impl),
                 )
                 if sampled:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
@@ -1383,7 +1466,7 @@ class InferenceEngine:
                 return (ring, nxt, *st), nxt
 
             (ring, last, *st), toks = lax.scan(
-                step, (ring, last, *_some(state)), jnp.arange(steps)
+                step, (ring, last, *_some(state), *_some(moe)), jnp.arange(steps)
             )
             k2, v2 = M.consolidate_ring_paged(
                 (k, v), ring, tables, lens, active
@@ -1575,33 +1658,34 @@ class InferenceEngine:
             seeds, w_temp, w_top_k, w_top_p,  # [R] wave values
             tables=None, page_rows=None, scatter_ids=None,  # paged only
             state=None,  # models with recurrent layers only
+            moe=None,  # models with routed experts only: zeroed counters
         ):
             # tokens: [R, bucket]; slots/true_lens: [R]
             R, P = tokens.shape
-            scratch = (
-                jnp.zeros((cfg.n_kv_layers, R, cfg.n_kv_heads, P, cfg.head_dim), k.dtype),
-                jnp.zeros((cfg.n_kv_layers, R, cfg.n_kv_heads, P, cfg.head_dim), v.dtype),
-            )
+            scratch = M.sides_like((k, v), (cfg.n_kv_layers, R, cfg.cache_heads, P))
             pos = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (R, P))
             with jax.named_scope("prefill"):
                 logits, (sk, sv), *wstate = M.forward(
                     params, cfg, tokens, pos, scratch,
                     jnp.full((R,), P, jnp.int32),
-                    **({} if state is None else {
-                        "state": make_recurrent_state(cfg, R), "n_valid": true_lens}),
+                    **({} if state is None else {"state": make_recurrent_state(cfg, R)}),
+                    **({} if moe is None else {"moe": moe}),
+                    **({} if state is None and moe is None else {"n_valid": true_lens}),
                 )
+            if moe is not None:  # the wave's expert counters leave last
+                moe = wstate.pop()
             idx = jnp.clip(true_lens - 1, 0, P - 1)
             last_logits = jnp.take_along_axis(
                 logits, idx[:, None, None], axis=1
             )[:, 0]
-            return _finalize_wave_math(
+            return (*_finalize_wave_math(
                 cfg, paged, sampled,
                 k, v, sk, sv, last, lens, slots, true_lens, last_logits,
                 slot_keys, temp, top_k, top_p,
                 seeds, w_temp, w_top_k, w_top_p,
                 tables, page_rows, scatter_ids,
                 state, *wstate,
-            )
+            ), *_some(moe))
 
         fn = jax.jit(prefill, donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
         self._prefill_jits[(bucket, rows, sampled)] = fn
@@ -1634,7 +1718,7 @@ class InferenceEngine:
 
         @jax.named_scope("chunk_loop")
         def chunk_step(params, sk, sv, tokens_chunk, offset,
-                       wstate=None, true_lens=None):
+                       wstate=None, true_lens=None, wmoe=None):
             R = tokens_chunk.shape[0]
             pos = offset + jnp.broadcast_to(
                 jnp.arange(chunk, dtype=jnp.int32), (R, chunk)
@@ -1644,10 +1728,13 @@ class InferenceEngine:
             # its KV scratch; a row's padding (past its true length) must
             # not move it, so the mixer is told how much of the chunk is
             # the row's own
+            # (a model with routed experts: the wave's expert counters ride
+            # the same way, and count the row's own positions alone)
             logits, (sk, sv), *wstate = M.forward(
                 params, cfg, tokens_chunk, pos, (sk, sv), lens,
-                **({} if wstate is None else {
-                    "state": wstate,
+                **({} if wstate is None else {"state": wstate}),
+                **({} if wmoe is None else {"moe": wmoe}),
+                **({} if wstate is None and wmoe is None else {
                     "n_valid": jnp.clip(true_lens - offset, 0, chunk)}),
             )
             return (sk, sv, logits, *wstate)  # logits [R, chunk, V]
@@ -1680,14 +1767,14 @@ class InferenceEngine:
                 params, k, v, tables, last, lens, active, done_prev,
                 stop_table, hard_end, slot_keys, temp, top_k, top_p,
                 sk, sv, tokens_chunk, offset,
-                state=None, wstate=None, true_lens=None,
+                state=None, wstate=None, true_lens=None, moe=None, wmoe=None,
             ):
-                # out: (.., [state]); wave: (sk, sv, logits, [wstate])
-                wave = chunk_fn(params, sk, sv, tokens_chunk, offset, wstate, true_lens)
+                # out: (.., [state], [moe]); wave: (sk, sv, logits, [wstate], [wmoe])
+                wave = chunk_fn(params, sk, sv, tokens_chunk, offset, wstate, true_lens, wmoe)
                 out = decode_fn(
                     params, k, v, tables, last, lens, active, done_prev,
                     stop_table, hard_end, slot_keys, temp, top_k, top_p,
-                    state,
+                    state, moe,
                 )
                 return (*out, *wave)
 
@@ -1743,11 +1830,8 @@ class InferenceEngine:
                     L, R, K, n * ps, hd
                 )
 
-            shape = (
-                cfg.n_kv_layers, rows, cfg.n_kv_heads, bucket, cfg.head_dim
-            )
-            sk = jnp.zeros(shape, pool_k.dtype)
-            sv = jnp.zeros(shape, pool_v.dtype)
+            sk, sv = M.sides_like(
+                (pool_k, pool_v), (cfg.n_kv_layers, rows, cfg.cache_heads, bucket))
             sk = sk.at[:, :, :, : n_pages * page].set(gather(pool_k))
             sv = sv.at[:, :, :, : n_pages * page].set(gather(pool_v))
             return sk, sv
@@ -3534,10 +3618,45 @@ class InferenceEngine:
             return []
         return [inf["wstate"], jnp.asarray(inf["arrays"]["true_lens"])]
 
+    def _moe_kw(self, inf: "dict | None" = None, decode: bool = True) -> dict:
+        """A program's expert-counter arguments, by name (a hybrid's state
+        goes by place before them): zeroed counters for its decode steps
+        (never donated: the same zeros every dispatch) and, for a chunk of
+        the wave ``inf``, the wave's counters so far with its rows' true
+        lengths."""
+        if not self._moe:
+            return {}
+        kw = {"moe": self._moe_zero} if decode else {}
+        if inf is not None:
+            kw["wmoe"] = inf["wmoe"]
+            if not self._recurrent:
+                kw["true_lens"] = jnp.asarray(inf["arrays"]["true_lens"])
+        return kw
+
     def _note_state_landed(self, landed: list, wave: "list[GenRequest]") -> None:
         if landed:
             self._state = landed[0]
             self.stats.state_rows_landed += len(wave)
+
+    def _keep_carried(self, came_back: list) -> Any:
+        """What a decode program returns after ``done``: the slots'
+        recurrent state, kept here, then its expert counters, handed back
+        (they ride the pend to its landing)."""
+        came_back = list(came_back)
+        if self._recurrent:
+            self._state = came_back.pop(0)
+        return came_back.pop(0) if self._moe else None
+
+    def _note_moe(self, counts: Any, hit: Any, decode: bool = False) -> None:
+        """Fold one dispatch's expert counters (already on their way to the
+        host with what the landing syncs) into the stats."""
+        counts = np.asarray(counts)  # blocking-ok: computed before the sync that just landed
+        stats = self.stats
+        stats.moe_assignments += int(counts.sum())
+        stats.moe_expert_tokens_max += int(counts.max(axis=1).sum())
+        stats.moe_expert_tokens_mean += float(counts.mean(axis=1).sum())
+        if decode:
+            stats.moe_experts_hit += int(hit)
 
     def _sampling_state_args(self, arrays: dict) -> list:
         return [
@@ -3631,7 +3750,8 @@ class InferenceEngine:
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
             *landed,
-        ) = fn(*args, **self._state_kw())
+        ) = fn(*args, **self._state_kw(), **({"moe": self._moe_zero} if self._moe else {}))
+        moe = landed.pop() if self._moe else None
         self._note_state_landed(landed, wave)
         if self._paged:
             self._tables = tables
@@ -3639,6 +3759,8 @@ class InferenceEngine:
         # device runs — prefill_ms must be real latency, not enqueue time
         self.stats.enter(SYNC)
         firsts = np.asarray(firsts)
+        if moe is not None:
+            self._note_moe(*moe)
         elapsed_ms = (self.stats.enter(FANOUT) - started) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
 
@@ -3676,10 +3798,7 @@ class InferenceEngine:
         chunk = min(self.runtime.prefill_chunk, bucket)
         cfg = self.config
         R = len(wave)
-        scratch_shape = (
-            cfg.n_kv_layers, R, cfg.n_kv_heads, bucket, cfg.head_dim
-        )
-        dtype = self._k.dtype
+        scratch_shape = (cfg.n_kv_layers, R, cfg.cache_heads, bucket)
         reuse = wave[0].reuse_len  # uniform across the wave
         if reuse:
             # seed the scratch with the cached prefix K/V (each row's
@@ -3696,10 +3815,7 @@ class InferenceEngine:
             self.stats.prefix_hits += len(wave)
             self.stats.prefix_reused_tokens += reuse * len(wave)
         else:
-            scratch = (
-                jnp.zeros(scratch_shape, dtype),
-                jnp.zeros(scratch_shape, dtype),
-            )
+            scratch = M.sides_like((self._k, self._v), scratch_shape)
         self._inflight = dict(
             wave=wave, bucket=bucket, chunk=chunk,
             n_chunks=-(-bucket // chunk), idx=reuse // chunk,
@@ -3708,6 +3824,7 @@ class InferenceEngine:
             # the wave's recurrent state, carried from chunk to chunk
             # beside the scratch (a fresh sequence's: zeros)
             wstate=make_recurrent_state(cfg, R) if self._recurrent else None,
+            wmoe=self._moe_zero,  # the wave's expert counters (None without experts)
             started=time.perf_counter(),
         )
 
@@ -3727,10 +3844,13 @@ class InferenceEngine:
         self.stats.enter(ENQUEUE)
         sk, sv, logits, *wstate = self._chunk_jit(chunk, R)(
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk),
-            *self._wave_state_args(inf),
+            *self._wave_state_args(inf), **self._moe_kw(inf, decode=False),
         )
         inf["scratch"] = (sk, sv)
-        inf["wstate"] = wstate[0] if wstate else None
+        if self._recurrent:
+            inf["wstate"] = wstate.pop(0)
+        if self._moe:
+            inf["wmoe"] = wstate.pop(0)
         inf["idx"] = idx + 1
         self._journal.append(
             flightrec.EV_PREFILL_CHUNK, None, -1, inf["idx"], inf["n_chunks"]
@@ -3777,6 +3897,8 @@ class InferenceEngine:
         # tokens must reach the host here for delivery and real TTFT
         # attribution; this is the admission lane's _sync_host analog
         firsts = np.asarray(firsts)  # sync before timing (real latency)
+        if self._moe:  # the wave's chunks ran before the landing just synced
+            self._note_moe(*inf["wmoe"])
         elapsed_ms = (self.stats.enter(FANOUT) - inf["started"]) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
         if self._prefix is not None:
@@ -3918,11 +4040,13 @@ class InferenceEngine:
         # comes back last
         res = list(self._ragged_jit(window, steps, sampled, chunk, R)(
             *args, sk, sv, tok_chunk, jnp.int32(idx * chunk),
-            *_some(self._state), *self._wave_state_args(inf),
+            *_some(self._state), *self._wave_state_args(inf), **self._moe_kw(inf),
         ))
+        if self._moe:
+            inf["wmoe"] = res.pop()
         if self._recurrent:
             inf["wstate"] = res.pop()
-            self._state = res.pop(7)
+        moe = self._keep_carried([res.pop(7) for _ in range(self._recurrent + self._moe)])
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
             sk, sv, logits,
@@ -3934,7 +4058,7 @@ class InferenceEngine:
         )
         self.stats.prefill_absorbed_tokens += R * chunk
         self.stats.unified_dispatches += 1
-        self._stage_pend(toks, n_valid, done, steps, started, extra_rows=R)
+        self._stage_pend(toks, n_valid, done, steps, started, extra_rows=R, moe=moe)
         if inf["idx"] == inf["n_chunks"]:
             return self._finalize_inflight(logits)
         return False
@@ -4137,14 +4261,13 @@ class InferenceEngine:
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
             *state,
-        ) = self._decode_jit(window, steps, sampled)(*args, *_some(self._state))
-        if state:
-            self._state = state[0]
-        self._stage_pend(toks, n_valid, done, steps, started)
+        ) = self._decode_jit(window, steps, sampled)(
+            *args, *_some(self._state), **self._moe_kw())
+        self._stage_pend(toks, n_valid, done, steps, started, moe=self._keep_carried(state))
 
     def _stage_pend(
         self, toks: Any, n_valid: Any, done: Any, steps: int,
-        started: float, extra_rows: int = 0,
+        started: float, extra_rows: int = 0, moe: Any = None,
     ) -> None:
         """Record a just-enqueued dispatch as the in-flight pend (host
         lens advance + the landing's snapshot) — ONE copy shared by the
@@ -4163,6 +4286,7 @@ class InferenceEngine:
             slot_set=set(self._active.keys()),
             deferred=[],
             extra_rows=extra_rows,
+            moe_dev=moe,  # (counts, hit) of a model with routed experts
         )
 
     def _land_decode(self, pend: dict) -> "list[tuple[asyncio.Queue, list]]":
@@ -4176,9 +4300,12 @@ class InferenceEngine:
         flight can touch them.  Returns the deliveries — the CALLER posts
         them, possibly after draining an all-zombie follow-up, so a
         consumer never observes completion before accounting settles."""
-        block, n_valid, done = self._sync_host(
-            (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"])
+        block, n_valid, done, *moe = self._sync_host(
+            (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"],
+             *(pend["moe_dev"] or ()))
         )
+        if moe:
+            self._note_moe(*moe, decode=True)
         now = self.stats.enter(FANOUT)
         # exclusive wall: the launch happened before the PREVIOUS sync
         # returned, so clip to the span this dispatch alone occupied —
@@ -4260,12 +4387,14 @@ class InferenceEngine:
         (
             self._k, self._v, self._last, self._lens, toks, _n_valid, _done,
             *state,
-        ) = self._decode_jit(window, steps, sampled)(*args, *_some(self._state))
-        if state:
-            self._state = state[0]
+        ) = self._decode_jit(window, steps, sampled)(
+            *args, *_some(self._state), **self._moe_kw())
+        moe = self._keep_carried(state)
         for slot in self._active:
             self._host_lens[slot] += steps
         block = self._sync_host(toks)  # [steps, B] — THE host sync per dispatch
+        if moe is not None:
+            self._note_moe(*moe, decode=True)
         self._last_sync_t = self.stats.enter(FANOUT)
         elapsed = self._last_sync_t - started
         self._note_dispatch(elapsed, steps)

@@ -28,6 +28,25 @@ attention or Mamba layer by ``layer_types``):
     model.layers.{N}.shared_mlp.output_linear.weight  → layers.mlp.w_down
     model.layers.{N}.input_layernorm / post_attention_layernorm
                                 → attn_norm or mixer_norm / layers.mlp.mlp_norm
+
+``model_type`` ``deepseek_v3``, and ``kimi_vl`` (its ``text_config``; tensor
+names under ``language_model.``, the vision tower's tensors skipped and
+counted with one :class:`VisionTowerSkipped` notice), load into the
+latent-attention tree (``q_lora_rank`` null):
+    self_attn.q_proj.weight [H (dn + dr), D]      → layers.attn.wq [D, H, dn + dr]
+    self_attn.kv_a_proj_with_mqa.weight [r + dr, D] → w_kva [D, r + dr]
+    self_attn.kv_a_layernorm.weight               → kv_norm
+    self_attn.kv_b_proj.weight [H (dn + dv), r]   → w_uk [r, H, dn] | w_uv [r, H, dv]
+    self_attn.o_proj.weight                       → wo [H, dv, D]
+    mlp.{gate,up,down}_proj.weight (leading dense layers) → layers.dense.*
+    mlp.gate.weight [E, D], .e_score_correction_bias → layers.moe.router [D, E], router_bias
+    mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [E, ..]
+    mlp.shared_experts.{gate,up,down}_proj.weight → layers.moe.s_gate/s_up/s_down
+HF's rotary embedding for this family pairs ADJACENT columns of the rope
+part (``rope_interleave``) and ``model.apply_rope`` pairs the two halves:
+the rope columns of ``W_q`` (each head's last ``dr``) and of ``W_kva`` (its
+last ``dr``) are permuted ONCE here, evens first, so that the same rotation
+gives the same scores.
 """
 
 from __future__ import annotations
@@ -44,10 +63,23 @@ from calfkit_tpu.inference.config import ModelConfig
 logger = logging.getLogger(__name__)
 
 
+class VisionTowerSkipped(UserWarning):
+    """A checkpoint's vision-tower tensors were left on disk: the language
+    decoder is loaded and serves text alone."""
+
+
+# tensor names of a multimodal checkpoint's language decoder start with this
+_LANGUAGE_PREFIX = "language_model."
+
+
 def config_from_hf(path: str | Path) -> ModelConfig:
     raw = json.loads((Path(path) / "config.json").read_text())
     if raw.get("model_type") == "granitemoehybrid":
         return _granite_hybrid_config(raw, str(path))
+    if raw.get("model_type") == "kimi_vl":
+        return _deepseek_config(raw["text_config"], str(path))
+    if raw.get("model_type") == "deepseek_v3":
+        return _deepseek_config(raw, str(path))
     return ModelConfig(
         name=raw.get("_name_or_path", str(path)),
         vocab_size=raw["vocab_size"],
@@ -105,6 +137,45 @@ def _granite_hybrid_config(raw: dict, path: str) -> ModelConfig:
     )
 
 
+def _deepseek_config(raw: dict, path: str) -> ModelConfig:
+    """DeepseekV3's ``config.json`` (or Kimi-VL's ``text_config``) -> the
+    latent-attention description."""
+    for key, only in (("q_lora_rank", None), ("rope_scaling", None)):
+        if raw.get(key) is not only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    if raw.get("moe_layer_freq", 1) != 1:
+        raise ValueError(f"{path}: moe_layer_freq = {raw['moe_layer_freq']} is not supported")
+    experts = raw.get("n_routed_experts") or 0
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=raw["vocab_size"],
+        d_model=raw["hidden_size"],
+        n_layers=raw["num_hidden_layers"],
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw.get("num_key_value_heads", raw["num_attention_heads"]),
+        d_ff=raw["intermediate_size"],
+        rope_theta=raw.get("rope_theta", 10000.0),
+        norm_eps=raw.get("rms_norm_eps", 1e-6),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=raw.get("tie_word_embeddings", False),
+        kv_lora_rank=raw["kv_lora_rank"],
+        qk_nope_head_dim=raw["qk_nope_head_dim"],
+        qk_rope_head_dim=raw["qk_rope_head_dim"],
+        v_head_dim=raw["v_head_dim"],
+        n_routed_experts=experts,
+        n_experts_per_tok=raw.get("num_experts_per_tok", 0) if experts else 0,
+        n_shared_experts=(raw.get("n_shared_experts") or 0) if experts else 0,
+        moe_d_ff=raw.get("moe_intermediate_size", 0) if experts else 0,
+        first_k_dense=raw.get("first_k_dense_replace", 0) if experts else 0,
+        routed_scaling_factor=float(raw.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func=raw.get("scoring_func", "sigmoid"),
+        topk_method=raw.get("topk_method", "noaux_tc"),
+        n_group=raw.get("n_group", 1),
+        topk_group=raw.get("topk_group", 1),
+    )
+
+
 def _open_safetensors(path: Path) -> dict[str, Any]:
     """name -> lazy tensor getter across all shards."""
     from safetensors import safe_open  # ships with transformers
@@ -151,8 +222,20 @@ def load_params(
     files = _open_safetensors(path)
     handles: dict[Path, Any] = {}
 
+    prefix = ""
+    if any(name.startswith(_LANGUAGE_PREFIX) for name in files):
+        prefix = _LANGUAGE_PREFIX
+        skipped = sum(1 for name in files if not name.startswith(prefix))
+        if skipped:
+            import warnings
+
+            warnings.warn(VisionTowerSkipped(
+                f"{path}: {skipped} tensors outside {prefix!r} (a vision tower and its "
+                "projector) were not loaded: the language decoder serves text alone"
+            ), stacklevel=2)
+
     def get(name: str) -> np.ndarray:
-        f = files[name]
+        f = files[name := prefix + name]
         if f not in handles:
             handles[f] = safe_open(str(f), framework="np").__enter__()
         return handles[f].get_tensor(name)
@@ -178,6 +261,10 @@ def _build_params(
         if quantize is not None:
             raise ValueError("no quantized load for a model with Mamba layers")
         return _build_hybrid_params(config, shardings, get)
+    if config.latent:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with latent attention and experts")
+        return _build_latent_params(config, shardings, get)
     _quant_axes: dict[str, tuple[int, ...]] = {}
     _bits = 8 if quantize == "int8" else 4
     if quantize in ("int8", "int4"):
@@ -338,4 +425,79 @@ def _build_hybrid_params(config: ModelConfig, shardings: dict[str, Any], get: An
     if not config.tie_embeddings:
         tree["lm_head"] = get("lm_head.weight").T.astype(dtype)
     logger.info("loaded %s params", config.name)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_latent_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The latent-attention tree from HF DeepseekV3 names (module text)."""
+    import jax
+
+    c = config
+    D, H, r, dn, dr, dv = (c.d_model, c.n_heads, c.kv_lora_rank, c.qk_nope_head_dim,
+                           c.qk_rope_head_dim, c.v_head_dim)
+    dtype = np.dtype(c.dtype)
+    nd = c.n_dense_layers
+    # adjacent pairs -> halves: the even columns first, then the odd ones
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    def rope_last(w: np.ndarray, start: int) -> np.ndarray:
+        return np.concatenate([w[..., :start], w[..., start:][..., halves]], axis=-1)
+
+    def stack(layers: Any, name: str, transform: Any, as_type: Any = dtype) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in layers]
+        ).astype(as_type)
+
+    def experts(layers: Any, name: str) -> np.ndarray:
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T
+                      for e in range(c.n_routed_experts)])
+            for i in layers
+        ]).astype(dtype)
+
+    everywhere, dense_at, moe_at = range(c.n_layers), range(nd), range(nd, c.n_layers)
+    kv_b = stack(everywhere, "self_attn.kv_b_proj.weight", lambda w: w.T.reshape(r, H, dn + dv))
+    layers: dict[str, Any] = {
+        "attn": {
+            "wq": stack(everywhere, "self_attn.q_proj.weight",
+                        lambda w: rope_last(w.T.reshape(D, H, dn + dr), dn)),
+            "w_kva": stack(everywhere, "self_attn.kv_a_proj_with_mqa.weight",
+                           lambda w: rope_last(w.T, r)),
+            "kv_norm": stack(everywhere, "self_attn.kv_a_layernorm.weight", lambda w: w),
+            "w_uk": np.ascontiguousarray(kv_b[..., :dn]),
+            "w_uv": np.ascontiguousarray(kv_b[..., dn:]),
+            "wo": stack(everywhere, "self_attn.o_proj.weight", lambda w: w.T.reshape(H, dv, D)),
+            "attn_norm": stack(everywhere, "input_layernorm.weight", lambda w: w),
+        },
+        "dense": {
+            "w_gate": stack(dense_at, "mlp.gate_proj.weight", lambda w: w.T),
+            "w_up": stack(dense_at, "mlp.up_proj.weight", lambda w: w.T),
+            "w_down": stack(dense_at, "mlp.down_proj.weight", lambda w: w.T),
+            "mlp_norm": stack(dense_at, "post_attention_layernorm.weight", lambda w: w),
+        },
+    }
+    if c.moe:
+        layers["moe"] = {
+            "router": stack(moe_at, "mlp.gate.weight", lambda w: w.T),
+            "router_bias": stack(moe_at, "mlp.gate.e_score_correction_bias", lambda w: w,
+                                 np.float32),
+            "w_gate": experts(moe_at, "gate_proj"),
+            "w_up": experts(moe_at, "up_proj"),
+            "w_down": experts(moe_at, "down_proj"),
+            "mlp_norm": stack(moe_at, "post_attention_layernorm.weight", lambda w: w),
+        }
+        if c.n_shared_experts:
+            layers["moe"].update(
+                s_gate=stack(moe_at, "mlp.shared_experts.gate_proj.weight", lambda w: w.T),
+                s_up=stack(moe_at, "mlp.shared_experts.up_proj.weight", lambda w: w.T),
+                s_down=stack(moe_at, "mlp.shared_experts.down_proj.weight", lambda w: w.T),
+            )
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight").astype(dtype),
+        "layers": layers,
+        "final_norm": get("model.norm.weight").astype(dtype),
+    }
+    if not c.tie_embeddings:
+        tree["lm_head"] = get("lm_head.weight").T.astype(dtype)
+    logger.info("loaded %s params", c.name)
     return jax.tree.map(jax.device_put, tree, shardings)

@@ -28,6 +28,27 @@ the period's layers unrolled inside the body.  Only the attention layers
 keep K and V (``config.n_kv_layers``); the Mamba layers' conv and SSM state
 travels as ``state = (ssm [Lm, B, H, P, N], conv [Lm, d_conv-1, B, C])``.
 A description without ``layer_types`` never reaches that code.
+
+A latent-attention stack (``config.kv_lora_rank``: DeepSeek-V3's MLA, with
+routed experts after the leading dense layers when ``n_routed_experts``)
+keeps its layers in up to three stacked groups,
+``layers = {"attn": wq w_kva kv_norm w_uk w_uv wo attn_norm [L, ..],
+"dense": w_gate w_up w_down mlp_norm [Ld, ..], "moe": see moe.py [Lm, ..]}``,
+runs the leading dense layers unrolled and the expert layers under ONE
+``lax.scan`` (``_latent_stack``).  What a token leaves behind there is
+``[c | k_rope]`` after the norm and the rotation, ``kv_lora_rank +
+qk_rope_head_dim`` numbers a layer and nothing else.  Every cache here (page
+pool, decode ring, prefill scratch) is a pair of arrays of the layout
+``[.., heads, .., width]``: K and V per head, or, for a latent model, the
+latent's two parts ``(c, k_rope)`` with heads 1 (apart, so that the wide
+part is whole lane tiles), and the functions that move cache bytes map over
+the pair whatever its widths.  The mixer has two algebras that
+must agree: *expanded* in ``forward`` (prefill and chunks: ``k_nope`` and
+``v`` expanded from the window's ``c``, the published form) and *absorbed* in
+the decode step (``W_uk`` folded into the query and ``W_uv`` applied after
+the read: 16 query heads against ONE key of 512 + 64 numbers whose first
+512 are also the value).  A description without ``kv_lora_rank`` never reaches
+that code either.
 """
 
 from __future__ import annotations
@@ -46,6 +67,7 @@ from calfkit_tpu.inference.mamba import (
     mamba_chunk,
     mamba_step,
 )
+from calfkit_tpu.inference.moe import init_moe_params, moe_ffn
 from calfkit_tpu.inference.quant import dequant as _w
 
 Params = dict[str, Any]
@@ -74,6 +96,38 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
     def norm_init(k, shape, fan_in, gain=1.0):
         scale = gain / math.sqrt(fan_in)
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    if config.latent:
+        r, dn, dr, dv = (config.kv_lora_rank, config.qk_nope_head_dim,
+                         config.qk_rope_head_dim, config.v_head_dim)
+        Ld = config.n_dense_layers
+        akeys = jax.random.split(keys[1], 5)
+        layers: Params = {
+            "attn": {
+                "wq": norm_init(akeys[0], (L, D, H, dn + dr), D),
+                "w_kva": norm_init(akeys[1], (L, D, r + dr), D),
+                "kv_norm": jnp.ones((L, r), dtype),
+                "w_uk": norm_init(akeys[2], (L, r, H, dn), r),
+                "w_uv": norm_init(akeys[3], (L, r, H, dv), r),
+                "wo": norm_init(akeys[4], (L, H, dv, D), H * dv),
+                "attn_norm": jnp.ones((L, D), dtype),
+            },
+            "dense": {
+                "w_gate": norm_init(keys[5], (Ld, D, F), D),
+                "w_up": norm_init(keys[6], (Ld, D, F), D),
+                "w_down": norm_init(keys[7], (Ld, F, D), F),
+                "mlp_norm": jnp.ones((Ld, D), dtype),
+            },
+        }
+        if config.moe:
+            layers["moe"] = init_moe_params(config, keys[2], dtype)
+        return {
+            "embed": norm_init(keys[0], (V, D), D),
+            "layers": layers,
+            "final_norm": jnp.ones((D,), dtype),
+            **({} if config.tie_embeddings else {
+                "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
+        }
 
     if config.layer_types:
         # A hybrid stack: with a TIED head, embedding_multiplier 12 and
@@ -334,6 +388,224 @@ def _hybrid_stack(
     return x, carry
 
 
+# --------------------------------------------------------------------------- #
+# latent attention (MLA) and the stack it lives in
+# --------------------------------------------------------------------------- #
+
+
+def mla_project(
+    x: jax.Array,  # [B, S, D]
+    lp: Params,  # one layer's attention leaves
+    cos: jax.Array,
+    sin: jax.Array,
+    config: ModelConfig,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The mixer's front half → (q_nope [B,S,H,dn], q_rope [B,S,H,dr]
+    rotated, c [B,S,r], k_rope [B,S,dr]): ``c`` AFTER the norm and
+    ``k_rope`` AFTER the rotation, which is what the cache keeps of a token."""
+    c = config
+    r, dn = c.kv_lora_rank, c.qk_nope_head_dim
+    h = rms_norm(x, lp["attn_norm"], c.norm_eps)
+    with jax.named_scope("q_proj"):
+        q = jnp.einsum("bsd,dnh->bsnh", h, lp["wq"])
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+    with jax.named_scope("kv_latent"):
+        kva = jnp.einsum("bsd,dc->bsc", h, lp["w_kva"])
+        latent = rms_norm(kva[..., :r], lp["kv_norm"], c.kv_norm_eps)
+        k_rope = apply_rope(kva[..., None, r:], cos, sin)[:, :, 0]
+    return q_nope, q_rope, latent, k_rope
+
+
+@jax.named_scope("attention")
+def mla_attention_expanded(
+    q_nope: jax.Array,  # [B, Sq, H, dn]
+    q_rope: jax.Array,  # [B, Sq, H, dr]
+    c_w: jax.Array,  # [B, W, r] the rows' cached latents ...
+    k_rope: jax.Array,  # [B, W, dr] ... and their rotated rope keys
+    lp: Params,
+    q_pos: jax.Array,  # [B, Sq]
+    seq_lens: jax.Array,  # [B]
+    config: ModelConfig,
+) -> jax.Array:
+    """The published form: ``k_nope`` and ``v`` of every head expanded from
+    the window's ``c``, the one ``k_rope`` beside every head's ``k_nope``,
+    scores over ``sqrt(dn + dr)`` → [B, Sq, H, dv]."""
+    k_nope = jnp.einsum("bwc,cnh->bwnh", c_w, lp["w_uk"])
+    v = jnp.einsum("bwc,cnh->bwnh", c_w, lp["w_uv"])
+    scores = (
+        _einsum_f32("bqnh,bwnh->bnqw", q_nope, k_nope)
+        + _einsum_f32("bqnh,bwh->bnqw", q_rope, k_rope)
+    ) / math.sqrt(config.head_dim)
+    mask = _gqa_scores_mask(q_pos, c_w.shape[1], seq_lens)
+    scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(c_w.dtype)
+    return _einsum_f32("bnqw,bwnh->bqnh", probs, v).astype(q_nope.dtype)
+
+
+def mla_absorb_query(q_nope: jax.Array, lp: Params) -> jax.Array:
+    """``q_nope W_uk^T`` → [B, S, H, r]: the query against ``c`` itself."""
+    with jax.named_scope("absorb"):
+        return jnp.einsum("bsnh,cnh->bsnc", q_nope, lp["w_uk"])
+
+
+def mla_absorb_out(o_lat: jax.Array, lp: Params) -> jax.Array:
+    """The read gives ``sum_t P_t c_t``; ``W_uv`` takes it to the heads'
+    values → [B, S, H, dv]."""
+    with jax.named_scope("absorb"):
+        return jnp.einsum("bsnc,cnh->bsnh", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"])
+
+
+@jax.named_scope("attention")
+def mla_merged_decode_attention(
+    q_lat: jax.Array,  # [B, 1, H, r]
+    q_rope: jax.Array,  # [B, 1, H, dr]
+    window: tuple[jax.Array, jax.Array],  # ([B, 1, W, r], [B, 1, W, dr]) main pages
+    ring: tuple[jax.Array, jax.Array],  # ([T, B, 1, r], [T, B, 1, dr]) this layer's ring
+    base_lens: jax.Array,  # [B]
+    t: jax.Array,  # current step (ring slots 0..t valid)
+    scale: float,  # 1 / sqrt(dn + dr): the scores' law, whatever is absorbed
+) -> jax.Array:
+    """The absorbed read → ``sum_t P_t c_t`` [B, 1, H, r] float32: every
+    head scores ONE key a token, ``q_lat . c + q_rope . k_rope``, and the
+    value is ``c`` again; softmax over (main cache ⊕ ring) by the same
+    two-source logsumexp merge as :func:`_merged_decode_attention`."""
+    (c_w, r_w), (c_r, r_r) = window, ring
+    q_lat, q_rope = q_lat[:, 0], q_rope[:, 0]  # [B, H, ..]
+    c_w, r_w, c_r, r_r = c_w[:, 0], r_w[:, 0], c_r[:, :, 0], r_r[:, :, 0]
+
+    s1 = (_einsum_f32("bhc,bwc->bhw", q_lat, c_w) + _einsum_f32("bhr,bwr->bhw", q_rope, r_w)) * scale
+    valid1 = jnp.arange(c_w.shape[1])[None, :] < base_lens[:, None]
+    s1 = jnp.where(valid1[:, None, :], s1, -1e30)
+    m1 = jnp.maximum(jnp.max(s1, axis=-1, keepdims=True), -1e29)  # empty rows stay finite
+    p1 = jnp.exp(s1 - m1).astype(c_w.dtype)
+    z1 = jnp.sum(p1.astype(jnp.float32), axis=-1, keepdims=True)
+    o1 = _einsum_f32("bhw,bwc->bhc", p1, c_w)
+
+    s2 = (_einsum_f32("bhc,tbc->bht", q_lat, c_r) + _einsum_f32("bhr,tbr->bht", q_rope, r_r)) * scale
+    s2 = jnp.where((jnp.arange(c_r.shape[0]) <= t)[None, None, :], s2, -1e30)
+    m2 = jnp.max(s2, axis=-1, keepdims=True)
+    p2 = jnp.exp(s2 - m2).astype(c_r.dtype)
+    z2 = jnp.sum(p2.astype(jnp.float32), axis=-1, keepdims=True)
+    o2 = _einsum_f32("bht,tbc->bhc", p2, c_r)
+    return logsumexp_merge((o1, m1, z1), (o2, m2, z2))[:, None]
+
+
+def _latent_stack(
+    config: ModelConfig,
+    layers: Params,
+    x: jax.Array,
+    carry: Any,
+    mixer: Any,  # (carry, x, lp, i) -> (carry, attn [B, S, H, dv])
+    stats: Any,  # moe.py's counters, or None
+    valid: jax.Array | None,  # [B, S] bool: the tokens that are real
+) -> tuple[jax.Array, Any, Any]:
+    """Run a latent-attention stack: the leading dense layers unrolled,
+    then ONE ``lax.scan`` over the expert layers, so compile time does not
+    follow the depth.  ``i`` is the layer's index in the stack, which is
+    where its latent lives in ``carry``.  The decode step and the prefill
+    chunk differ only in ``mixer``."""
+    eps = config.norm_eps
+    nd = config.n_dense_layers
+
+    def layer(group: str, i: Any) -> Params:
+        # one layer's leaves sliced where they are used (see _hybrid_stack)
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), layers[group]
+        )
+
+    def attend(carry, x, i):
+        lp = layer("attn", i)
+        with jax.named_scope("mla"):
+            carry, attn = mixer(carry, x, lp, i)
+            with jax.named_scope("attn_out"):
+                x = x + jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
+        return carry, x
+
+    for i in range(nd):
+        carry, x = attend(carry, x, i)
+        x = mlp_residual(x, layer("dense", i), eps)
+    if not config.moe:
+        return x, carry, stats
+
+    def body(c, m):
+        x, carry, stats = c
+        carry, x = attend(carry, x, nd + m)
+        lp = layer("moe", m)
+        with jax.named_scope("mlp"):
+            y, stats = moe_ffn(rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m)
+        return (x + y, carry, stats), None
+
+    (x, carry, stats), _ = lax.scan(
+        body, (x, carry, stats), jnp.arange(config.n_moe_layers, dtype=jnp.int32)
+    )
+    return x, carry, stats
+
+
+def _rope_dim_tables(config: ModelConfig, positions: jax.Array):
+    return rope_tables(positions, config.qk_rope_head_dim, config.rope_theta)
+
+
+def _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W, insert_at,
+                    stats, n_valid):
+    """``forward`` for a latent-attention stack (the expanded algebra)."""
+    # kv_cache: ([L, B, 1, Smax, r], [L, B, 1, Smax, dr])
+    x = params["embed"][tokens]
+    cos, sin = _rope_dim_tables(config, positions)
+    valid = None
+    if n_valid is not None:
+        valid = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] < n_valid[:, None]
+
+    def mixer(cache, x, lp, i):
+        q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
+        pages = tuple(
+            _insert_chunk(lax.dynamic_index_in_dim(side, i, 0, keepdims=False),
+                          part[:, :, None, :], insert_at)
+            for side, part in zip(cache, fresh))
+        attn = mla_attention_expanded(
+            q_nope, q_rope, pages[0][:, 0, :W], pages[1][:, 0, :W], lp, positions, seq_lens,
+            config)
+        cache = tuple(lax.dynamic_update_index_in_dim(side, page, i, 0)
+                      for side, page in zip(cache, pages))
+        return cache, attn
+
+    x, cache, stats = _latent_stack(
+        config, params["layers"], x, tuple(kv_cache), mixer, stats, valid)
+    logits = lm_logits(x, params, config.norm_eps)
+    if stats is None:
+        return logits, cache
+    return logits, cache, stats
+
+
+def _latent_decode_step(params, config, tokens, ring, t, base_lens, attn_source, stats,
+                        active):
+    """One decode step of a latent-attention stack (the absorbed algebra):
+    the fresh latent goes to the ring, ``attn_source`` reads (main cache ⊕
+    ring) with ``c`` as key AND value."""
+    # ring: ([L, T, B, 1, r], [L, T, B, 1, dr])
+    positions = (base_lens + t)[:, None]
+    x = params["embed"][tokens]
+    cos, sin = _rope_dim_tables(config, positions)
+    valid = None if active is None else active[:, None]
+
+    def mixer(ring, x, lp, i):
+        q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
+        ring = tuple(
+            lax.dynamic_update_slice(
+                side, part[:, 0].astype(side.dtype)[None, None, :, None, :], (i, t, 0, 0, 0))
+            for side, part in zip(ring, fresh))
+        o_lat = attn_source(
+            i, (mla_absorb_query(q_nope, lp), q_rope),
+            *(lax.dynamic_index_in_dim(side, i, 0, keepdims=False) for side in ring), None)
+        return ring, mla_absorb_out(o_lat, lp)
+
+    x, ring, stats = _latent_stack(
+        config, params["layers"], x, tuple(ring), mixer, stats, valid)
+    logits = lm_logits(x, params, config.norm_eps)
+    if stats is None:
+        return logits, ring
+    return logits, ring, stats
+
+
 def attention_xla(
     q: jax.Array,  # [B, Sq, H, hd]
     k_cache: jax.Array,  # [B, K, Skv, hd]  (kv-head-major: contiguous scans)
@@ -384,6 +656,7 @@ def forward(
     insert_at: jax.Array | None = None,  # [B] explicit per-row write offset
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv) of the rows
     n_valid: jax.Array | None = None,  # hybrid: [B] positions of the chunk that are the row's own
+    moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters (moe.py)
 ) -> Any:
     """Run the decoder over a token chunk, updating the cache functionally.
 
@@ -402,7 +675,9 @@ def forward(
     A hybrid stack also takes the rows' recurrent ``state`` as the chunk
     before left it and how many of the chunk's positions are each row's
     own (``n_valid``; the rest is padding, which moves no state), and
-    returns (logits, new_cache, new_state).
+    returns (logits, new_cache, new_state).  A stack with routed experts
+    takes its counters (``moe``: ``moe.moe_stats_init``) beside that, counts
+    the positions that are the rows' own (``n_valid``) and returns them last.
     """
     eps = config.norm_eps
     if insert_at is None:
@@ -412,6 +687,9 @@ def forward(
         insert_at = seq_lens - tokens.shape[1]  # where this chunk lands
     k_pages, v_pages = kv_cache  # [L, B, K, Smax, hd]
     W = attn_window or k_pages.shape[3]
+    if config.latent:
+        return _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W,
+                               insert_at, moe, n_valid)
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = _positions_tables(config, positions)
@@ -498,6 +776,7 @@ def _decode_step_with_ring(
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,  # hybrid: rows whose state advances
     ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
+    moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
 ) -> Any:
     """The shared decode-step transformer body (ring-buffer scheme).
 
@@ -520,11 +799,16 @@ def _decode_step_with_ring(
 
     A hybrid stack threads the slots' recurrent ``state`` through the same
     scan (each Mamba layer reads and rewrites its own slice in place; rows
-    that are not ``active`` keep theirs) and returns it third.
+    that are not ``active`` keep theirs) and returns it third; a stack with
+    routed experts counts its ``active`` rows' choices into ``moe`` and
+    returns that last.
     """
     eps = config.norm_eps
     positions = (base_lens + t)[:, None]  # [B, 1] absolute position
     ring_k, ring_v = ring
+    if config.latent:
+        return _latent_decode_step(
+            params, config, tokens, ring, t, base_lens, attn_source, moe, active)
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = _positions_tables(config, positions)
@@ -602,6 +886,7 @@ def decode_step_ring(
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,
     ssm_impl: str = "xla",
+    moe: tuple[jax.Array, jax.Array] | None = None,
 ) -> Any:
     """One decode step over the dense [L, B, K, S, hd] cache layout."""
     k_pages, v_pages = kv_cache
@@ -615,7 +900,7 @@ def decode_step_ring(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source,
-        (k_pages, v_pages), state, active, ssm_impl,
+        (k_pages, v_pages), state, active, ssm_impl, moe,
     )
 
 
@@ -999,12 +1284,27 @@ def _insert_chunk(
     return jax.vmap(one)(cache, chunk, offsets)
 
 
+def cache_sides(config: ModelConfig, lead: tuple, dtype: Any) -> tuple[jax.Array, jax.Array]:
+    """A zeroed cache ``[*lead, width]``: the pair (K, V), or the latent's
+    two parts (c, k_rope) for a model whose token leaves one latent behind."""
+    k, v = config.cache_dims
+    return jnp.zeros((*lead, k), dtype), jnp.zeros((*lead, v), dtype)
+
+
+def sides_like(cache: tuple, lead: tuple) -> tuple[jax.Array, jax.Array]:
+    """Zeroed arrays ``[*lead, width]``, one for each side of ``cache``, of
+    that side's width and type (a decode ring or a prefill scratch beside
+    a pool)."""
+    k, v = cache
+    return (jnp.zeros((*lead, k.shape[-1]), k.dtype), jnp.zeros((*lead, v.shape[-1]), v.dtype))
+
+
 def make_empty_cache(
     config: ModelConfig, batch: int, max_seq: int, dtype: Any = None
 ) -> tuple[jax.Array, jax.Array]:
     dtype = dtype or jnp.dtype(config.dtype)
-    shape = (config.n_kv_layers, batch, config.n_kv_heads, max_seq, config.head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return cache_sides(
+        config, (config.n_kv_layers, batch, config.cache_heads, max_seq), dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -1015,13 +1315,12 @@ def make_empty_cache(
 def make_page_pool(
     config: ModelConfig, num_pages: int, page_size: int, dtype: Any = None
 ) -> tuple[jax.Array, jax.Array]:
-    """KV page pool [L, N, K, page, hd]; page 0 is the trash page."""
+    """KV page pool [L, N, K, page, hd] x 2; page 0 is the trash page.  A
+    latent model's is [L, N, 1, page, r] and [L, N, 1, page, dr]: the two
+    parts of the one latent, and no K or V per head at all."""
     dtype = dtype or jnp.dtype(config.dtype)
-    shape = (
-        config.n_kv_layers, num_pages, config.n_kv_heads, page_size,
-        config.head_dim,
-    )
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return cache_sides(
+        config, (config.n_kv_layers, num_pages, config.cache_heads, page_size), dtype)
 
 
 @jax.named_scope("gather_window")
@@ -1062,6 +1361,7 @@ def decode_step_ring_paged(
     active: jax.Array | None = None,  # [B] bool; None: every row reads
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
+    moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
 ) -> Any:
     """One decode step reading KV through the block tables.
 
@@ -1079,6 +1379,20 @@ def decode_step_ring_paged(
     over steps makes once, outside its loop.
     """
     pool_k, pool_v = pool
+
+    def latent_source(i, q, ring_c, ring_r, extra):
+        window = tuple(
+            gather_window_paged(
+                lax.dynamic_index_in_dim(side, i, 0, keepdims=False), tables, wpages)
+            for side in pool)
+        return mla_merged_decode_attention(
+            *q, window, (ring_c, ring_r), base_lens, t, 1.0 / math.sqrt(config.head_dim))
+
+    if config.latent:
+        return _decode_step_with_ring(
+            params, config, tokens, ring, t, base_lens, latent_source, None,
+            state, active, moe=moe,
+        )
 
     def attn_source(i, q, rk, rv, extra):
         if attn_impl.startswith("pallas"):
@@ -1130,10 +1444,8 @@ def consolidate_ring_paged(
     freed its pages yet (one-dispatch-late retirement frees them only
     after this dispatch lands).
     """
-    pool_k, pool_v = pool
-    ring_k, ring_v = ring
-    T = ring_k.shape[1]
-    page = pool_k.shape[3]
+    T = ring[0].shape[1]
+    page = pool[0].shape[3]
 
     pos = base_lens[:, None] + jnp.arange(T)[None, :]  # [B, T]
     logical = pos // page  # which table entry
@@ -1152,7 +1464,7 @@ def consolidate_ring_paged(
         vals = jnp.transpose(r, (2, 1, 0, 3, 4)).astype(pool_side.dtype)
         return pool_side.at[:, page_ids, :, offsets].set(vals)
 
-    return write(pool_k, ring_k), write(pool_v, ring_v)
+    return write(pool[0], ring[0]), write(pool[1], ring[1])
 
 
 @jax.named_scope("kv_write")
@@ -1162,16 +1474,15 @@ def write_prefill_pages(
     page_ids: jax.Array,  # [R, P // page] int32 destination pages
 ) -> tuple[jax.Array, jax.Array]:
     """Scatter whole prefill pages into the pool (page-granular writes)."""
-    pool_k, pool_v = pool
-    sk, sv = scratch
-    L, R, K, P, hd = sk.shape
-    page = pool_k.shape[3]
+    L, R, K, P, _ = scratch[0].shape
+    page = pool[0].shape[3]
     npg = P // page
 
     def write(pool_side: jax.Array, s: jax.Array) -> jax.Array:
         # [L, R, K, np*page, hd] -> [L, R, np, K, page, hd] -> [L, R*np, ...]
+        hd = s.shape[-1]
         blocks = s.reshape(L, R, K, npg, page, hd).transpose(0, 1, 3, 2, 4, 5)
         blocks = blocks.reshape(L, R * npg, K, page, hd).astype(pool_side.dtype)
         return pool_side.at[:, page_ids.reshape(-1)].set(blocks)
 
-    return write(pool_k, sk), write(pool_v, sv)
+    return write(pool[0], scratch[0]), write(pool[1], scratch[1])

@@ -1,0 +1,200 @@
+"""The routed-expert FFN of a DeepSeek-V3-style layer, inside the ``mlp`` scope.
+
+    s = sigmoid(float32(h) W_g)                    E scores a token, float32
+    chosen = top-k of (s + b)                      b: e_score_correction_bias
+    w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor
+    y = sum_e w_e E_e(h) + Shared(h)               every E_e a SwiGLU of moe_d_ff
+
+The bias moves the CHOICE and never the weights; the weights are the
+unbiased scores, normalised over the chosen (``norm_topk_prob``) and scaled
+once.  Every token routed to an expert is computed by it: there is no
+capacity and no dropped assignment, under any imbalance.
+
+The expert products take one of two forms, chosen from the shapes alone
+(:func:`dense_form`), and the two must agree.  Each wins on its own side:
+one scan over the 6 expert layers of 64 experts of 2048 x 1408, 6 a token,
+on a v5e, in ms a layer (the gate, the products and the shared expert;
+PERF.md section 6, PR 31):
+
+    tokens      8     64    256    512   1024   2048   4096
+    dense    1.53   1.54   1.72   3.24   6.42  12.78  26.90
+    grouped  4.91   6.67   8.85   9.38  10.15  12.01  15.81
+
+- *dense* (decode steps, and chunks to ``_DENSE_MAX_TOKENS`` tokens): every
+  expert for every row, times a weight that is zero outside the chosen.
+  To some hundreds of rows it costs what reading the experts costs (1.1 GB
+  a layer: 1.35 ms at 819 GB/s), and it needs no sort or gather;
+- *grouped* (wider chunks): the token-expert pairs sorted by expert and
+  ``lax.ragged_dot`` over the groups, so only the pairs routed are
+  multiplied (the v5e's compiler lowers it to one kernel over the sorted
+  rows).  It pays 5-6 ms a layer however few the rows, and 64/6 times
+  fewer FLOPs than the dense form from there.
+
+Layout (stacked on axis 0 over the expert layers):
+    router [Lm, D, E]            the gate, in the activations' type; its
+                                 product is float32 at HIGHEST precision
+    router_bias [Lm, E] float32  e_score_correction_bias
+    w_gate, w_up [Lm, E, D, Fe]; w_down [Lm, E, Fe, D]      routed experts
+    s_gate, s_up [Lm, D, Fs]; s_down [Lm, Fs, D]            the shared expert
+    mlp_norm [Lm, D]
+
+Counters (``stats``; what :class:`EngineStats` sums as ``moe_*``): a pair
+``(counts [Lm, E] int32, hit [] int32)`` that a dispatch carries through
+its steps and returns beside its tokens.  ``counts[m, e]`` is the tokens
+layer ``m`` sent to expert ``e``; ``hit`` the distinct experts a call had
+to read, summed over layers (and, by the caller, over steps).  Only REAL
+tokens count (``valid``): a padded position of a chunk and an inactive row
+of a decode step are computed, as padding is everywhere in this program,
+and counted nowhere.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from calfkit_tpu.inference.config import ModelConfig
+
+Params = dict[str, Any]
+_HI = lax.Precision.HIGHEST  # the gate's float32 product: no bf16 passes
+# the dense form's limit in tokens: the two forms' measured times cross
+# between 1,024 and 2,048 (the table above), and no shape lies between
+_DENSE_MAX_TOKENS = 1536
+
+
+def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
+    """Random expert-layer leaves: every matrix at 1/sqrt(fan_in), the
+    norm at 1, ``router_bias`` (``e_score_correction_bias``) zero, as an
+    untrained model has it."""
+    c = config
+    Lm, D, E, Fe = c.n_moe_layers, c.d_model, c.n_routed_experts, c.moe_d_ff
+    Fs = c.n_shared_experts * Fe
+    keys = jax.random.split(key, 8)
+
+    def mat(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
+
+    out = {
+        "router": mat(keys[0], (Lm, D, E), D),
+        "router_bias": jnp.zeros((Lm, E), jnp.float32),
+        "w_gate": mat(keys[2], (Lm, E, D, Fe), D),
+        "w_up": mat(keys[3], (Lm, E, D, Fe), D),
+        "w_down": mat(keys[4], (Lm, E, Fe, D), Fe),
+        "mlp_norm": jnp.ones((Lm, D), dtype),
+    }
+    if Fs:
+        out.update(
+            s_gate=mat(keys[5], (Lm, D, Fs), D),
+            s_up=mat(keys[6], (Lm, D, Fs), D),
+            s_down=mat(keys[7], (Lm, Fs, D), Fs),
+        )
+    return out
+
+
+def moe_stats_init(config: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """Zeroed counters of one dispatch (see the module's text)."""
+    return (jnp.zeros((config.n_moe_layers, config.n_routed_experts), jnp.int32),
+            jnp.zeros((), jnp.int32))
+
+
+def route(
+    h: jax.Array,  # [T, D]
+    lp: Params,
+    config: ModelConfig,
+) -> tuple[jax.Array, jax.Array]:
+    """The gate → (chosen [T, k] int32, weights [T, k] float32): the
+    product, the scores and the top-k in float32, as published."""
+    c = config
+    logits = jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
+        precision=_HI, preferred_element_type=jnp.float32,
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = lax.top_k(scores + lp["router_bias"].astype(jnp.float32), c.n_experts_per_tok)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)  # the UNBIASED scores
+    if c.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * c.routed_scaling_factor
+
+
+def dense_form(tokens: int, config: ModelConfig) -> bool:
+    """Which form the expert products take for ``tokens`` rows: dense to
+    where the two measured times cross, grouped beyond."""
+    return tokens <= _DENSE_MAX_TOKENS
+
+
+def _swiglu(h: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array) -> jax.Array:
+    return (jax.nn.silu(h @ gate) * (h @ up)) @ down
+
+
+def experts_dense(h: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Params) -> jax.Array:
+    """Every expert on every row, times a weight that is zero outside the
+    chosen → [T, D].  The weight goes onto the hidden activation, so the
+    down projection contracts experts and width in ONE product."""
+    with jax.named_scope("group"):
+        gates = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E] float32
+    with jax.named_scope("experts"):
+        g = jnp.einsum("td,edf->etf", h, lp["w_gate"])
+        u = jnp.einsum("td,edf->etf", h, lp["w_up"])
+        act = jax.nn.silu(g) * u  # [E, T, Fe]
+    with jax.named_scope("combine"):
+        act = (act.astype(jnp.float32) * gates.T[:, :, None]).astype(h.dtype)
+        return jnp.einsum("etf,efd->td", act, lp["w_down"])
+
+
+def experts_grouped(
+    h: jax.Array, chosen: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Params
+) -> jax.Array:
+    """Only the token-expert pairs routed: sorted by expert, one ragged
+    product a projection over the groups, unsorted, weighted, summed over a
+    token's experts → [T, D].  A group is as long as its expert's tokens
+    are many: nothing is cut to a capacity."""
+    T, k = chosen.shape
+    with jax.named_scope("group"):
+        flat = chosen.reshape(T * k)
+        order = jnp.argsort(flat, stable=True)  # sorted pair -> flat pair
+        sizes = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [E] pairs an expert
+        rows = h[order // k]  # [T k, D]
+    with jax.named_scope("experts"):
+        act = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)) * lax.ragged_dot(
+            rows, lp["w_up"], sizes)
+        out = lax.ragged_dot(act, lp["w_down"], sizes)  # [T k, D], sorted
+    with jax.named_scope("combine"):
+        back = jnp.argsort(order)  # flat pair -> sorted pair
+        out = out[back].reshape(T, k, -1).astype(jnp.float32)
+        return jnp.sum(out * weights[..., None], axis=1).astype(h.dtype)
+
+
+def moe_ffn(
+    h: jax.Array,  # [B, S, D], normed
+    lp: Params,  # ONE expert layer's leaves
+    config: ModelConfig,
+    stats: "tuple[jax.Array, jax.Array] | None" = None,
+    valid: jax.Array | None = None,  # [B, S] bool: the real tokens
+    m: Any = 0,  # this layer's index among the expert layers (traced)
+) -> tuple[jax.Array, Any]:
+    """``sum_e w_e E_e(h) + Shared(h)`` → ([B, S, D], stats)."""
+    B, S, D = h.shape
+    E = config.n_routed_experts
+    flat = h.reshape(B * S, D)
+    with jax.named_scope("moe"):
+        with jax.named_scope("router"):
+            chosen, weights = route(flat, lp, config)
+            onehot = chosen[..., None] == jnp.arange(E, dtype=jnp.int32)  # [T, k, E]
+            if stats is not None:
+                real = onehot if valid is None else onehot & valid.reshape(-1, 1, 1)
+                tokens = jnp.sum(real, axis=(0, 1), dtype=jnp.int32)  # [E]
+                counts, hit = stats
+                stats = (counts.at[m].add(tokens), hit + jnp.sum(tokens > 0, dtype=jnp.int32))
+        if dense_form(B * S, config):
+            y = experts_dense(flat, onehot, weights, lp)
+        else:
+            y = experts_grouped(flat, chosen, onehot, weights, lp)
+        if "s_gate" in lp:
+            with jax.named_scope("shared"):
+                y = y + _swiglu(flat, lp["s_gate"], lp["s_up"], lp["s_down"])
+    return y.reshape(B, S, D), stats

@@ -29,6 +29,11 @@ Params = dict[str, Any]
 # the leaves of one stacked group of Mamba-2 layers (inference/mamba.py)
 MAMBA_LEAVES = ("w_in", "conv_w", "conv_b", "A_log", "D", "dt_bias", "norm", "w_out",
                 "mixer_norm")
+# the leaves of a latent-attention stack's groups (inference/model.py, moe.py)
+LATENT_ATTN_LEAVES = ("wq", "w_kva", "kv_norm", "w_uk", "w_uv", "wo", "attn_norm")
+MLP_LEAVES = ("w_gate", "w_up", "w_down", "mlp_norm")
+MOE_LEAVES = ("router", "router_bias", *MLP_LEAVES)
+SHARED_EXPERT_LEAVES = ("s_gate", "s_up", "s_down")
 
 
 def make_mesh(
@@ -88,7 +93,19 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         }
 
     L = (config.n_layers, None)
-    if config.layer_types:
+    if config.latent:
+        # a latent-attention stack (see model.py): every leaf replicated.  One
+        # device holds them whole: the engine refuses such a model on a mesh
+        # of more than one device until the experts are held by share
+        whole = NamedSharding(mesh, P())
+        layers = {
+            "attn": dict.fromkeys(LATENT_ATTN_LEAVES, whole),
+            "dense": dict.fromkeys(MLP_LEAVES, whole),
+        }
+        if config.moe:
+            layers["moe"] = dict.fromkeys(
+                MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole)
+    elif config.layer_types:
         # a hybrid stack (see model.py): the attention and MLP groups keep
         # the dense layout's specs; the Mamba leaves are replicated (one
         # device holds them whole: the engine refuses such a model on a
@@ -119,9 +136,9 @@ def cache_sharding(config: ModelConfig, mesh: Mesh, batch: int) -> NamedSharding
             [
                 (config.n_kv_layers, None),
                 (batch, "dp"),
-                (config.n_kv_heads, "tp"),
+                (config.cache_heads, "tp"),
                 (1, None),
-                (config.head_dim, None),
+                (1, None),
             ],
         ),
     )
@@ -142,9 +159,9 @@ def pool_sharding(config: ModelConfig, mesh: Mesh) -> NamedSharding:
             [
                 (config.n_kv_layers, None),
                 (1, None),
-                (config.n_kv_heads, "tp"),
+                (config.cache_heads, "tp"),
                 (1, None),
-                (config.head_dim, None),
+                (1, None),
             ],
         ),
     )

@@ -548,10 +548,15 @@ def hbm_constants(model: Any, quantization: "str | None" = None) -> "tuple[float
     Weight stream: params x dtype width (int8 halves it, int4 quarters);
     KV read: 2 (K+V) x layers that keep K and V x kv-heads x head_dim x
     2 bytes (a hybrid stack's Mamba layers keep none: their state is
-    :func:`recurrent_bytes_per_token`'s)."""
+    :func:`recurrent_bytes_per_token`'s), or what the description says a
+    token leaves in the cache (``kv_bytes_per_token``: ONE latent a layer
+    for a model with latent attention)."""
     weight_bytes = float(model.param_count) * {
         "int8": 1.0, "int4": 0.5,
     }.get(quantization, 2.0)
+    described = getattr(model, "kv_bytes_per_token", None)
+    if described is not None:
+        return weight_bytes, float(described(2))
     kv_layers = getattr(model, "n_kv_layers", model.n_layers)
     kv_per_token = 2.0 * kv_layers * model.n_kv_heads * model.head_dim * 2.0
     return weight_bytes, kv_per_token

@@ -55,6 +55,11 @@ SCOPES = frozenset({
     # mamba.py (inside "mamba", which model.py opens around the mixer) and
     # the landing of a wave's recurrent state in its slots
     "mamba", "in_proj", "conv", "ssm", "gate_norm", "out_proj", "state_land",
+    # model.py's latent-attention mixer ("mla" around it; "attention",
+    # "attn_out" and "gather_window" keep their names inside) and moe.py's
+    # expert layer ("moe" inside "mlp")
+    "mla", "q_proj", "kv_latent", "absorb",
+    "moe", "router", "group", "experts", "combine", "shared",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

@@ -124,3 +124,245 @@ def test_the_new_readers_read_nothing_where_there_is_nothing_to_read():
     assert ssm_pct(hybrid) == pytest.approx(45.0)
     # 40 steps x 2 x 64 rows x 76.4 MB at 819 GB/s = 0.478 s over 0.7 s measured
     assert roofline(hybrid) == pytest.approx(100 * 40 * 2 * 4892000256 / 819e9 / 0.7)
+
+
+# ------------------------------------------- kimi-vl-a3b-instruct (PR 31)
+KIMI, KIMI_CELL = "kimi-vl-a3b-instruct", "kimi-vl-a3b-instruct.history-closed"
+
+
+def test_kimi_s_counts_are_what_a_hand_reckons():
+    arch = M.load_architecture("deepseek-mla-moe")
+    config = config_file(KIMI)
+    assert config["reduced"] == ["num_hidden_layers"]
+    assert (config["num_hidden_layers"], config["published"]["num_hidden_layers"]) == (7, 27)
+    assert (config["n_routed_experts"], config["num_experts_per_tok"],
+            config["n_shared_experts"], config["vocab_size"]) == (64, 6, 2, 163840)
+    weights = arch.weight_bytes(config)
+    assert f"{weights / 1e9:.3g}" == "8.53"
+    assert config["hbm"]["weights_bytes"] == weights == 2 * config["parameters"]
+    assert arch.state_bytes_per_token(config) == 7 * 576 * 2 == 8064 \
+        == config["hbm"]["kv_bytes_per_token"]
+    # one decode step over 64 rows of 1,300 tokens, by hand (bfloat16):
+    attention = 2048 * 3072 + 2048 * 576 + 512 * 4096 + 2048 * 2048  # 13.76M a layer
+    expert = 3 * 2048 * 1408  # 8.65M
+    outside = 7 * attention + 3 * 2048 * 11264 + 6 * (2048 * 64 + 2 * expert) + 2048 * 163840
+    hit = 64 * (1 - (1 - 6 / 64) ** 64)  # 63.9 of 64 under even routing
+    by_hand = 2 * (outside + 6 * hit * expert) + 64 * 1300 * 8064
+    assert 8.4e9 < by_hand < 8.6e9  # the 8.5 GB of the issue
+    step = arch.decode_step(config, 64, 1300, 1)
+    assert abs(step["bytes"] - by_hand) / by_hand < 0.01
+    assert 0.76 < 2 * 6 * hit * expert / step["bytes"] < 0.80  # the experts: 78% of a step's bytes
+    assert arch.experts_hit(config, 64) == pytest.approx(63.9, abs=0.05)
+    assert arch.experts_hit(config, 8) == pytest.approx(35, abs=1)  # not 64
+    few = arch.decode_step(config, 8, 1300, 1)
+    assert few["bytes"] < 0.6 * step["bytes"]
+    # a chunk multiplies the tokens ROUTED, never every expert: 2 x the
+    # 6 + 2 experts and the rest a token, far under 2 x every parameter
+    chunk = arch.prefill_chunk(config, 4, 512, 0, 1)
+    active = outside + 6 * 6 * expert
+    assert 2 * active * 2048 < chunk["flops"] < 1.15 * 2 * active * 2048
+    assert chunk["flops"] < 0.5 * 2 * (weights / 2) * 2048
+
+
+def test_the_program_s_description_of_kimi_is_the_file_s():
+    from dataclasses import replace
+
+    from calfkit_tpu.inference.config import preset
+
+    arch = M.load_architecture("deepseek-mla-moe")
+    config = config_file(KIMI)
+    described, runtime = arch.model(config, False)
+    want = replace(preset(KIMI), n_layers=7, max_seq_len=4096)
+    fields = {f: getattr(described, f) for f in want.__dataclass_fields__}
+    assert type(want)(**{**fields, "name": want.name}) == want  # every field of the program's
+    assert (described.agreement_margin, described.agreement_new_tokens, described.routing_tie) == (
+        config["agreement"]["margin"], config["agreement"]["new_tokens"],
+        config["agreement"]["routing_tie"])  # and, beside them, what forward_top2 reads
+    assert described.param_count == config["parameters"] == 4_263_151_488
+    assert preset(KIMI).param_count == config["published_parameters"]
+    assert described.kv_norm_eps == 1e-6 != described.norm_eps
+    assert (runtime.max_batch_size, runtime.kv_layout, runtime.chunked_prefill,
+            runtime.prefix_cache, runtime.max_prefill_wave) == (64, "paged", True, True, 4)
+    assert runtime.pool_pages() == 64 * 64 + 1 == config["hbm"]["pool_pages"] + 1
+    assert config["hbm"]["pool_bytes"] == 4097 * 64 * 8064
+    toy, toy_runtime = arch.model(config, True)
+    assert toy.moe and toy.latent and toy_runtime.max_batch_size == 8
+
+
+def test_kimi_s_reference_lists_every_choice_of_experts_within_the_tie():
+    """``_routings``: a token whose k-th expert leads the next by more than
+    the tie has ONE routing; one in doubt on each side of the line has two;
+    one inside and two outside three; and a crowd is given up (its top k
+    alone is kept)."""
+    import numpy as np
+
+    arch = M.load_architecture("deepseek-mla-moe")
+    scores = np.asarray([
+        [0.9, 0.8, 0.5, 0.4, 0.3, 0.2, 0.1, 0.0],      # clear: {0, 1}
+        [0.9, 0.8, 0.799, 0.4, 0.3, 0.2, 0.1, 0.0],    # 1 or 2 beside 0
+        [0.9, 0.8, 0.799, 0.798, 0.3, 0.2, 0.1, 0.0],  # 1, 2 or 3 beside 0
+        [0.8] * 8,                                     # all in doubt: 28 ways
+    ], np.float32)
+    parent, chosen, first, crowded = arch._routings(scores, 2, 0.004)
+    sets = [{tuple(np.flatnonzero(c)) for c, p in zip(chosen, parent) if p == n} for n in range(4)]
+    assert sets[0] == {(0, 1)} and sets[1] == {(0, 1), (0, 2)}
+    assert sets[2] == {(0, 1), (0, 2), (0, 3)}
+    assert crowded.tolist() == [False, False, False, True] and len(sets[3]) == 1
+    assert (chosen.sum(-1) == 2).all()
+    assert [int(first[parent == n].sum()) for n in range(4)] == [1, 1, 1, 1]
+    assert {tuple(np.flatnonzero(c)) for c in chosen[first & (parent == 1)]} == {(0, 1)}
+
+
+def _greedy(params, toy, prompts, n, route=None):
+    """``n`` tokens the PROGRAM's full forward serves after each of
+    ``prompts``, greedily (float32, toy size), under another gate if
+    ``route`` is one."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from calfkit_tpu.inference import model as program
+    from calfkit_tpu.inference import moe
+
+    S = max(len(p) for p in prompts) + n
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (1, S))
+    right = moe.route
+    if route is not None:
+        moe.route = lambda h, lp, c: route(right, h, lp, c)
+    try:
+        forward = jax.jit(lambda tokens: program.forward(
+            params, toy, tokens, pos, program.make_empty_cache(toy, 1, S),
+            jnp.full((1,), S, jnp.int32))[0])
+        outs = []
+        for prompt in prompts:
+            seq = list(prompt)
+            for _ in range(n):  # causal: the padding after a position moves nothing before it
+                tokens = np.zeros((1, S), np.int32)
+                tokens[0, :len(seq)] = seq
+                seq.append(int(np.argmax(np.asarray(forward(jnp.asarray(tokens)))[0, len(seq) - 1])))
+            outs.append(seq[len(prompt):])
+        return outs
+    finally:
+        moe.route = right
+
+
+def test_kimi_s_reference_follows_a_near_tie_and_catches_a_wrong_gate():
+    """The rule of the architecture file at toy size, float32 on both sides.
+    A program whose gate sees scores off by LESS than the tie (what a
+    bfloat16 stream does to a float32 gate) serves tokens the reference
+    accepts at every position it decides, some of them under another
+    routing than its own; the margin rule alone (tie 0) fails the same
+    tokens.  A program that leaves the bias out of the choice serves
+    tokens that no admitted routing gives, and fails."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.reference import agreement
+    from calfkit_tpu.inference.config import RuntimeConfig
+    from calfkit_tpu.inference.sharding import make_mesh
+
+    arch = M.load_architecture("deepseek-mla-moe")
+    config = config_file(KIMI)
+    toy, _ = arch.model(config, True)
+    toy = dataclasses.replace(toy, dtype="float32", agreement_new_tokens=24, routing_tie=0.02,
+                              agreement_margin=0.25)
+    params = arch.params(toy, RuntimeConfig(), make_mesh(tp=1, dp=1, devices=jax.devices()[:1]), 5)
+    bias = np.asarray(params["layers"]["moe"]["router_bias"])
+    assert 0.01 < np.abs(bias).mean() < 0.05 and np.abs(bias).max() <= 0.05  # NOT zero
+    # 8 experts' scores lie eight times further apart than 64 experts': a bias that is to
+    # move a choice as often as it does at the real size is that much larger
+    params["layers"]["moe"]["router_bias"] = params["layers"]["moe"]["router_bias"] * 8.0
+    assert 0.9 < float(jnp.std(params["embed"])) < 1.1  # the token's own row at unit scale
+
+    def jitter(right, h, lp, c):  # scores off by up to 0.009: under half the tie either way
+        noise = jax.random.uniform(jax.random.key(0), lp["router_bias"].shape, jnp.float32,
+                                   -0.009, 0.009)
+        chosen, _ = right(h, {**lp, "router_bias": lp["router_bias"] + noise}, c)
+        scores = jax.nn.sigmoid(h @ lp["router"])
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        return chosen, w / w.sum(-1, keepdims=True) * c.routed_scaling_factor
+
+    def no_bias(right, h, lp, c):
+        return right(h, {**lp, "router_bias": jnp.zeros_like(lp["router_bias"])}, c)
+
+    rng = np.random.default_rng(1)
+    prompts = [[int(t) for t in rng.integers(3, toy.vocab_size, n)] for n in (9, 14, 20, 27)]
+
+    def check(route, described):
+        outs = _greedy(params, toy, prompts, 24, route)
+        return agreement(arch.forward_top2, params, described, prompts, outs,
+                         described.agreement_margin, 8)
+
+    near = check(jitter, toy)
+    assert near["ok"] and near["compared"] >= 24, near
+    alone = check(jitter, dataclasses.replace(toy, routing_tie=0.0))
+    assert alone["compared"] > alone["equal"], alone  # the margin rule alone fails the same tokens
+    wrong = check(no_bias, toy)
+    assert not wrong["ok"] and wrong["compared"] - wrong["equal"] >= 3, wrong
+    right = check(None, toy)
+    assert right["ok"] and right["compared"] >= near["compared"] - 8, right
+
+
+def test_kimi_s_readers_read_what_their_files_say_and_nothing_elsewhere():
+    """On a program or an architecture without the scopes and counters
+    (the parent commit, the dense and hybrid cells) the four new readers
+    return None and do not raise; on a made-up traced run they read what a
+    hand reckons."""
+    from types import SimpleNamespace
+
+    read = {n: M.load_reader(n) for n in (
+        "moe_device_pct", "moe_expert_roofline", "mla_cache_roofline", "moe_expert_load_ratio")}
+    parent = SimpleNamespace(
+        trace_reduced={"busy_s": 2.0, "by_scope": {"decode_loop/mlp": 1.5, "(unscoped)": 0.5}},
+        trace_counters={"decode_tokens": 100, "decode_dispatches": 5, "short_dispatches": 0,
+                        "decode_pages_live": 900.0},
+        counters={"window": {"decode_tokens": 100}},
+        arch=M.load_architecture("dense-gqa"), config={}, chips=1, model_config=SimpleNamespace(),
+        runtime=SimpleNamespace(decode_steps_per_dispatch=8, page_size=64), peaks={})
+    assert all(reader(parent) is None for reader in read.values())
+    untraced = SimpleNamespace(trace_reduced=None, trace_counters=None, counters={}, arch=None)
+    assert all(reader(untraced) is None for reader in read.values())
+    config = config_file(KIMI)
+    steps, rows = 40, 64
+    run = SimpleNamespace(
+        trace_reduced={"busy_s": 2.0, "by_scope": {
+            "decode_loop/mlp/moe/experts": 0.7, "decode_loop/mlp/moe/router": 0.1,
+            "chunk_loop/mlp/moe/experts": 0.2, "decode_loop/mla/attention": 0.2,
+            "decode_loop/mla/gather_window": 0.25, "decode_loop/mla/absorb": 0.05,
+            "decode_loop/mla/q_proj": 0.1, "chunk_loop/mla/attention": 0.3}},
+        trace_counters={"decode_tokens": rows * steps, "decode_dispatches": 5,
+                        "short_dispatches": 0, "moe_experts_hit": 63 * 6 * steps,
+                        "decode_pages_live": rows * steps * 21.0},
+        counters={"window": {"moe_expert_tokens_max": 300, "moe_expert_tokens_mean": 200.0}},
+        arch=M.load_architecture("deepseek-mla-moe"), config=config, chips=1,
+        model_config=SimpleNamespace(n_moe_layers=6, n_layers=7),
+        runtime=SimpleNamespace(decode_steps_per_dispatch=8, page_size=64),
+        peaks=M.load_peaks("TPU v5 lite"))
+    assert read["moe_device_pct"](run) == pytest.approx(50.0)
+    assert read["moe_expert_load_ratio"](run) == pytest.approx(1.5)
+    expert = 3 * 2048 * 1408 * 2  # bytes
+    layer_step = (63 * expert + 2 * expert + 2048 * 64 * 2) / 819e9
+    assert read["moe_expert_roofline"](run) == pytest.approx(100 * layer_step * 6 * steps / 0.8)
+    tokens = rows * steps * 21 * 64
+    assert read["mla_cache_roofline"](run) == pytest.approx(100 * 7 * tokens * 576 * 2 / 819e9 / 0.5)
+    assert read["moe_expert_roofline"](run) < 100 and read["mla_cache_roofline"](run) < 100
+
+
+def test_the_manifest_carries_kimi_s_cell_and_its_four_metrics():
+    cell = M.resolve_cell(MANIFEST, KIMI_CELL, M.ROOT)
+    assert cell.chips == 1 and cell.params == {"callers": 64}
+    assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
+    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [KIMI_CELL]}
+    assert set(own) == {"moe_device_pct", "moe_expert_roofline", "mla_cache_roofline",
+                        "moe_expert_load_ratio"}
+    assert {own[n]["moves"] for n in own} == {"tpot_p95_ms", "out_tok_s_per_chip"}
+    assert own["moe_expert_load_ratio"]["source"] == "program_counter"
+    registered = {m.name for m in cell.per_layer}
+    assert set(own) <= registered and "ssm_state_roofline" not in registered
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
+    law = cell.traffic["prompt_tokens"]
+    assert (law["median"], law["sigma"], law["min"], law["max"]) == (1024, 0.6, 256, 3072)
+    assert cell.traffic["output_tokens"]["values"] == [128, 256, 512]

@@ -459,3 +459,85 @@ def test_entry_point_list_is_complete():
         if "pl.pallas_call(" in open(path).read()
     )
     assert with_kernels == ["pallas_attention.py", "pallas_ssm.py"]
+
+
+# ---------------------------------------------------------------------------
+# A latent pool and routed experts (kimi-vl-a3b-instruct): no Pallas kernel of
+# this repo's, the XLA absorbed read in the decode step, the TPU compiler's
+# own ragged-dot kernel for a chunk's grouped expert products
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.slow  # 2.7 GB of weights and two whole-program compiles on every core: 50 s that
+# starve the timing-bound engine tests of a tier-1 run's other workers; the offline lane runs it
+def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
+    """The decode dispatch and the ragged program of the cell's configuration
+    at its published WIDTHS and runtime (2 of its 7 layers: the dense one and
+    one expert layer, all 64 experts), compiled for the described v5e: the
+    decode step reads the latent through ``gather_window`` under ``mla`` and
+    multiplies every expert (no custom call at all); the chunk that rides
+    along groups its tokens when it is wider than ``moe._DENSE_MAX_TOKENS``
+    (two rows of 1,024: three ``ragged-dot`` kernels an expert layer; one
+    row takes the dense form, as the decode step does: the chip's readings
+    in ``moe.py``); the two parts of the pool go out where they came in."""
+    import json
+    from dataclasses import replace
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "kimi-vl-a3b-instruct.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    engine = InferenceEngine(
+        replace(config, n_layers=2), replace(runtime, compilation_cache=False))
+    assert engine._attn_impl == "xla" and engine._ssm_impl == "xla"
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    args, window, steps, sampled = engine._decode_args()
+    assert window == 4096 == rt.max_seq_len
+    rows, chunk = 2, rt.prefill_chunk
+    scratch = [jax.ShapeDtypeStruct((cfg.n_kv_layers, rows, 1, 2 * chunk, width), engine._k.dtype)
+               for width in cfg.cache_dims]
+    wave = [*scratch, jax.ShapeDtypeStruct((rows, chunk), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)]
+    zero = engine._moe_zero
+    decode = engine._decode_jit(window, steps, sampled).lower(
+        *abstract(args), moe=abstract(zero)).compile()
+    hlo = decode.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in hlo
+    assert "decode_loop/" in hlo and "/mla/gather_window" in hlo and "/mlp/moe/experts" in hlo
+    pool_bytes = engine._k.nbytes + engine._v.nbytes
+    assert decode.memory_analysis().alias_size_in_bytes >= pool_bytes
+    ragged = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
+        *abstract((*args, *wave)), moe=abstract(zero), wmoe=abstract(zero),
+        true_lens=abstract(jax.ShapeDtypeStruct((rows,), jnp.int32))).compile()
+    hlo = ragged.as_text()
+    # the compiler's kernel carries its own op name, "ragged-dot-none", and NOT
+    # the scope path it was called under: a device trace shows it unscoped
+    # (PERF.md section 7), so it is told here by its operands: all 64 experts
+    grouped = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line and "= bf16[" in line
+               and "ragged-dot-none" in line]
+    assert len(grouped) == 3  # gate, up, down of the one expert layer
+    assert all("bf16[64,2048,1408]" in line or "bf16[64,1408,2048]" in line for line in grouped)
+    assert "decode_loop/" in hlo and "chunk_loop/" in hlo
+    one = [jax.ShapeDtypeStruct((a.shape[0], 1, *a.shape[2:]), a.dtype) for a in scratch]
+    narrow = engine._ragged_jit(window, steps, sampled, chunk, 1).lower(
+        *abstract((*args, *one, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
+                   jax.ShapeDtypeStruct((), jnp.int32))),
+        moe=abstract(zero), wmoe=abstract(zero),
+        true_lens=abstract(jax.ShapeDtypeStruct((1,), jnp.int32))).compile()
+    assert "ragged-dot" not in narrow.as_text()  # 1,024 tokens: the dense form
+    print("temporaries, bytes: decode", decode.memory_analysis().temp_size_in_bytes,
+          "ragged x2", ragged.memory_analysis().temp_size_in_bytes,
+          "ragged x1", narrow.memory_analysis().temp_size_in_bytes)
