@@ -1230,61 +1230,52 @@ class InferenceEngine:
         """Which implementation the PAGED DECODE READ uses: the one
         attention computation that has a kernel.  Decided HERE and nowhere
         else, once at construction (``self._attn_impl``), from what the
-        engine can observe (PERF.md section 6, PRs 25 and 28: measured on
-        the v5e).  Under "auto": the Pallas kernel that reads each row's
+        engine can observe (PERF.md section 6, PRs 25, 28 and 32: measured
+        on the v5e).  Under "auto": the Pallas kernel that reads each row's
         live pages in place when the backend is a TPU, KV is paged, one
         device holds the model (``tp == 1`` and ``dp == 1``: a
         ``pallas_call`` under GSPMD needs a ``shard_map`` over the KV heads
-        first) and the head and page shapes are the kernel's
-        (:func:`pallas_attention.paged_decode_in_place_ok`: a head of whole
-        lane tiles, or one that divides a lane tile, such as 64); else XLA,
-        the reference.
+        first) and the shapes are the kernel's; else XLA, the reference.
+
+        WHICH kernel, and so which shapes, follows from the model
+        (``config.latent``), for the pool is another thing: K and V pairs of
+        heads (:func:`pallas_attention.paged_decode_in_place_ok`: a head of
+        whole lane tiles, or one that divides a lane tile, such as 64), or
+        one latent a token read in the absorbed form
+        (:func:`pallas_attention.latent_decode_in_place_ok`: a latent of
+        whole lane tiles beside a rope part that divides one, such as
+        512 | 64).
 
         "pallas" / "pallas_interpret" are for tests and bring-up: they
         waive the platform test alone.  Outside the rest of the rule there
         is no kernel to build, and the engine is refused."""
         impl = self.runtime.attention_impl
-        explicit = impl.startswith("pallas")
-        if self.config.latent:
-            # the kernel reads K and V pairs of heads and computes no MLA:
-            # a latent pool is read by XLA (the absorbed form), and says so
-            if explicit:
-                from calfkit_tpu.inference.pallas_attention import PallasShapeError
-
-                raise PallasShapeError(
-                    f"attention_impl={impl!r} names the paged decode kernel, which "
-                    "reads K and V pairs of heads; this model's pool is one latent a "
-                    'token (MLA) and is read by XLA: use "auto" or "xla"'
-                )
-            return "xla"
-        if not explicit and not (
+        if not impl.startswith("pallas") and not (
             impl == "auto" and jax.devices()[0].platform == "tpu"
         ):
             return "xla"
-        from calfkit_tpu.inference.pallas_attention import (
-            PallasShapeError,
-            paged_decode_in_place_ok,
-        )
+        from calfkit_tpu.inference import pallas_attention as PA
 
-        in_rule = (
-            self._paged
-            and self.mesh.size == 1
-            and paged_decode_in_place_ok(
-                self.config.head_dim, self.runtime.page_size, self.config.dtype
-            )
-        )
+        c, page = self.config, self.runtime.page_size
+        if c.latent:
+            shapes_ok = PA.latent_decode_in_place_ok(
+                c.kv_lora_rank, c.qk_rope_head_dim, page, c.dtype)
+            shapes = (f"a latent of {c.kv_lora_rank} | {c.qk_rope_head_dim} "
+                      "(pallas_attention.latent_decode_in_place_ok)")
+        else:
+            shapes_ok = PA.paged_decode_in_place_ok(c.head_dim, page, c.dtype)
+            shapes = f"head_dim={c.head_dim} (pallas_attention.paged_decode_in_place_ok)"
+        in_rule = self._paged and self.mesh.size == 1 and shapes_ok
         if impl == "auto":
             return "pallas" if in_rule else "xla"
         if not in_rule:
-            raise PallasShapeError(
+            raise PA.PallasShapeError(
                 f"attention_impl={impl!r} names the paged decode kernel, "
                 "which takes kv_layout='paged', one device (tp == dp == 1) "
-                "and a head and page of whole tiles "
-                "(pallas_attention.paged_decode_in_place_ok); this engine "
-                f"has kv_layout={self.runtime.kv_layout!r} on "
-                f"{self.mesh.size} device(s), head_dim="
-                f"{self.config.head_dim}, page_size="
-                f"{self.runtime.page_size}, {jnp.dtype(self.config.dtype).name}"
+                "and a page slab of whole tiles; this engine has "
+                f"kv_layout={self.runtime.kv_layout!r} on "
+                f"{self.mesh.size} device(s), {shapes}, page_size={page}, "
+                f"{jnp.dtype(c.dtype).name}"
                 ': use "auto" or "xla"'
             )
         return impl
@@ -1429,7 +1420,7 @@ class InferenceEngine:
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
         attn_impl, ssm_impl = self._attn_impl, self._ssm_impl
-        from calfkit_tpu.inference.pallas_attention import lane_dense_pool
+        from calfkit_tpu.inference.pallas_attention import lane_dense_pool, latent_rope_view
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
@@ -1444,10 +1435,14 @@ class InferenceEngine:
             pool = (k, v)
             if attn_impl.startswith("pallas"):
                 # the kernel's view of a pool of heads narrower than a lane
-                # tile is a relayout of both sides: made HERE, once a
-                # dispatch (the pool is a constant of the step loop and of
-                # the layer scan), never per step.  Other heads: the pool.
-                pool = (lane_dense_pool(k), lane_dense_pool(v))
+                # tile is a relayout of both sides, and of a latent pool one
+                # of its narrow rope side alone: made HERE, once a dispatch
+                # (the pool is a constant of the step loop and of the layer
+                # scan), never per step.  Other heads: the pool.
+                pool = (
+                    (k, latent_rope_view(v)) if cfg.latent
+                    else (lane_dense_pool(k), lane_dense_pool(v))
+                )
 
             def step(carry, t):
                 ring, last, *st = carry

@@ -469,25 +469,50 @@ def mla_merged_decode_attention(
     head scores ONE key a token, ``q_lat . c + q_rope . k_rope``, and the
     value is ``c`` again; softmax over (main cache ⊕ ring) by the same
     two-source logsumexp merge as :func:`_merged_decode_attention`."""
-    (c_w, r_w), (c_r, r_r) = window, ring
     q_lat, q_rope = q_lat[:, 0], q_rope[:, 0]  # [B, H, ..]
-    c_w, r_w, c_r, r_r = c_w[:, 0], r_w[:, 0], c_r[:, :, 0], r_r[:, :, 0]
+    source1 = mla_window_attention_source(q_lat, q_rope, window, base_lens, scale)
+    source2 = mla_ring_attention_source(q_lat, q_rope, ring, t, scale)
+    return logsumexp_merge(source1, source2)[:, None]
 
+
+def mla_window_attention_source(
+    q_lat: jax.Array,  # [B, H, r]
+    q_rope: jax.Array,  # [B, H, dr]
+    window: tuple[jax.Array, jax.Array],  # ([B, 1, W, r], [B, 1, W, dr]) main pages
+    base_lens: jax.Array,  # [B]
+    scale: float,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The absorbed read's main-cache source over gathered windows →
+    (o unnormalized, m, z): the law the latent decode kernel
+    (``pallas_attention._latent_decode_kernel``) keeps, in its roundings."""
+    c_w, r_w = window[0][:, 0], window[1][:, 0]
     s1 = (_einsum_f32("bhc,bwc->bhw", q_lat, c_w) + _einsum_f32("bhr,bwr->bhw", q_rope, r_w)) * scale
     valid1 = jnp.arange(c_w.shape[1])[None, :] < base_lens[:, None]
     s1 = jnp.where(valid1[:, None, :], s1, -1e30)
     m1 = jnp.maximum(jnp.max(s1, axis=-1, keepdims=True), -1e29)  # empty rows stay finite
     p1 = jnp.exp(s1 - m1).astype(c_w.dtype)
     z1 = jnp.sum(p1.astype(jnp.float32), axis=-1, keepdims=True)
-    o1 = _einsum_f32("bhw,bwc->bhc", p1, c_w)
+    return _einsum_f32("bhw,bwc->bhc", p1, c_w), m1, z1
 
+
+def mla_ring_attention_source(
+    q_lat: jax.Array,  # [B, H, r]
+    q_rope: jax.Array,  # [B, H, dr]
+    ring: tuple[jax.Array, jax.Array],  # ([T, B, 1, r], [T, B, 1, dr]) this layer's ring
+    t: jax.Array,  # ring slots 0..t valid
+    scale: float,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The absorbed read's fresh-token source (tiny: T ≤ steps a dispatch) →
+    (o unnormalized, m, z): shared by the XLA and Pallas merged reads, as
+    :func:`ring_attention_source` is for K and V."""
+    c_r, r_r = ring[0][:, :, 0], ring[1][:, :, 0]
     s2 = (_einsum_f32("bhc,tbc->bht", q_lat, c_r) + _einsum_f32("bhr,tbr->bht", q_rope, r_r)) * scale
     s2 = jnp.where((jnp.arange(c_r.shape[0]) <= t)[None, None, :], s2, -1e30)
     m2 = jnp.max(s2, axis=-1, keepdims=True)
     p2 = jnp.exp(s2 - m2).astype(c_r.dtype)
     z2 = jnp.sum(p2.astype(jnp.float32), axis=-1, keepdims=True)
     o2 = _einsum_f32("bht,tbc->bhc", p2, c_r)
-    return logsumexp_merge((o1, m1, z1), (o2, m2, z2))[:, None]
+    return o2, m2, z2
 
 
 def _latent_stack(
@@ -1333,12 +1358,14 @@ def gather_window_paged(
 
     The XLA read path: one gather per (layer, step) of EVERY row's whole
     window bucket, used or not, occupied slot or not — read, written and
-    read again by the attention.  Correct everywhere: the CPU path, the
+    read again by the attention.  Correct everywhere, for K and V pairs
+    and for the two sides of a latent pool alike: the CPU path, the
     ``tp > 1`` path, the verify and ragged S > 1 programs, and the parity
-    reference of the Pallas paged decode kernel, which reads each row's
-    live pages in place instead (on the v5e the gather was 48% of device
-    time in the Mistral cell and a quarter in granite's: PERF.md sections
-    5 and 6).
+    reference of the two Pallas decode kernels, which read each row's live
+    pages in place instead.  No decode step of a benchmark cell runs it on
+    a chip since PR 32 (on the v5e the gather was 48% of device time in
+    the Mistral cell, a quarter in granite's, and with the layer slice a
+    third in Kimi's: PERF.md section 6, PRs 25, 28 and 32).
     """
     B = tables.shape[0]
     page = pool_layer.shape[2]
@@ -1370,23 +1397,38 @@ def decode_step_ring_paged(
     indexed per layer), never a carry — its bytes move once per read, not
     per scan round-trip.
 
-    The Pallas read follows each row's length, so a row that is not
-    ``active`` (its token is discarded by the caller) is given length 0
-    there and costs no page; the XLA read gathers every row's window
-    whatever it holds and takes no notice of ``active``.  For the Pallas
-    read ``pool`` may be the kernel's view of the pool
-    (:func:`pallas_attention.lane_dense_pool`), which a caller that loops
-    over steps makes once, outside its loop.
+    A Pallas read (K and V pairs, or a latent pool's absorbed read: two
+    kernels, one rule) follows each row's length and takes the layer as an
+    INDEX into the pool, so a row that is not ``active`` (its token is
+    discarded by the caller) is given length 0 there and costs no page, and
+    no layer is sliced out of the pool; the XLA read slices the layer,
+    gathers every row's window whatever it holds and takes no notice of
+    ``active``.  For a Pallas read ``pool`` may be the kernel's view of the
+    pool (:func:`pallas_attention.lane_dense_pool`, or for a latent pool
+    :func:`pallas_attention.latent_rope_view` of its rope side), which a
+    caller that loops over steps makes once, outside its loop.
     """
     pool_k, pool_v = pool
 
+    def read_lens():  # what a Pallas read walks: nothing of a row not active
+        return base_lens if active is None else jnp.where(active, base_lens, 0)
+
     def latent_source(i, q, ring_c, ring_r, extra):
+        scale = 1.0 / math.sqrt(config.head_dim)
+        if attn_impl.startswith("pallas"):
+            from calfkit_tpu.inference.pallas_attention import (
+                merged_latent_decode_attention_pallas,
+            )
+
+            return merged_latent_decode_attention_pallas(
+                *q, pool_k, pool_v, i, tables, (ring_c, ring_r), read_lens(), t,
+                scale=scale, wpages=wpages, interpret=attn_impl == "pallas_interpret",
+            )
         window = tuple(
             gather_window_paged(
                 lax.dynamic_index_in_dim(side, i, 0, keepdims=False), tables, wpages)
             for side in pool)
-        return mla_merged_decode_attention(
-            *q, window, (ring_c, ring_r), base_lens, t, 1.0 / math.sqrt(config.head_dim))
+        return mla_merged_decode_attention(*q, window, (ring_c, ring_r), base_lens, t, scale)
 
     if config.latent:
         return _decode_step_with_ring(
@@ -1400,12 +1442,8 @@ def decode_step_ring_paged(
                 merged_paged_decode_attention_pallas,
             )
 
-            read_lens = (
-                base_lens if active is None
-                else jnp.where(active, base_lens, 0)
-            )
             return merged_paged_decode_attention_pallas(
-                q, pool_k, pool_v, i, tables, rk, rv, read_lens, t,
+                q, pool_k, pool_v, i, tables, rk, rv, read_lens(), t,
                 wpages=wpages, interpret=attn_impl == "pallas_interpret",
             )
         kl = lax.dynamic_index_in_dim(pool_k, i, 0, keepdims=False)

@@ -1,43 +1,60 @@
-"""The Pallas TPU kernel for paged single-query decode attention.
+"""The Pallas TPU kernels for paged single-query decode attention.
 
-Why a kernel: the XLA paged read copies every row's whole window out of the
+Why kernels: the XLA paged read copies every row's whole window out of the
 pool before attending it (``model.gather_window_paged``), whatever the row
-holds.  The kernel reads each row's LIVE pages in place: one program a row,
+holds.  A kernel reads each row's LIVE pages in place: one program a row,
 a loop over that row's own pages with double-buffered whole-slab copies out
 of the pool in HBM, bf16 operands into the MXU, mask + softmax statistics +
 weighted sum fused.  Its work follows the row lengths, not the window
-bucket.  A head that divides a lane tile (64) is read ``f = 128 / hd``
-positions a lane row, through a lane-dense view of the pool
-(:func:`lane_dense_pool`) that a caller in a loop makes once.
+bucket.
 
-It is the ONE attention kernel of the system.  Every other attention
-computation (prefill, prefill chunks, speculative verify, dense decode,
-the long-context lane) is the XLA source in ``model.py``: the kernels that
-once stood beside this one ran in no measured cell or lost where they were
-measured (PERF.md section 6, PRs 25 and 29).
+There are TWO bodies, because the two kinds of pool ask for different
+things of one page:
 
-:func:`paged_decode_attention_pallas` returns *unnormalized* output plus the
-softmax statistics ``(m, z)`` so the caller can fold in the fresh-token ring
-(tiny, plain XLA) with the logsumexp merge the XLA path uses.
+- :func:`_paged_decode_kernel` reads K and V pairs of kv heads under a
+  block-diagonal head mask (a dense or hybrid model's pool).  A head that
+  divides a lane tile (64) is read ``f = 128 / hd`` positions a lane row,
+  through a lane-dense view of the pool (:func:`lane_dense_pool`) that a
+  caller in a loop makes once.
+- :func:`_latent_decode_kernel` reads a LATENT (MLA) pool in the absorbed
+  form: every head scores ONE key a token, ``q_lat . c + q_rope . k_rope``,
+  and the value is ``c`` again, taken from the buffer the scores just read,
+  so a token's latent crosses HBM → VMEM once a layer a step.  The ``c``
+  side is read as it lies; the narrow rope side through
+  :func:`latent_rope_view`, made once a dispatch.
 
-What the TPU lowering demands, and how the kernel meets it: per-row scalars
+Every other attention computation (prefill, prefill chunks, speculative
+verify, dense decode, the long-context lane) is the XLA source in
+``model.py``: the kernels that once stood beside these ran in no measured
+cell or lost where they were measured (PERF.md section 6, PRs 25 and 29).
+
+Both entry points (:func:`paged_decode_attention_pallas`,
+:func:`latent_decode_attention_pallas`) return *unnormalized* output plus
+the softmax statistics ``(m, z)`` so the caller can fold in the fresh-token
+ring (tiny, plain XLA) with the logsumexp merge the XLA path uses.
+
+What the TPU lowering demands, and how the kernels meet it: per-row scalars
 (lengths, block tables, the layer index) ride ``PrefetchScalarGridSpec``
 into SMEM, a ``(1,)`` block of a ``[B]`` array being refused; every VMEM
 block's last two dims equal the array's or are (8, 128)-aligned; the pool
 stays in HBM (``memory_space=pl.ANY``) and a page slab is copied whole, so
-a slab is whole tiles (:func:`paged_decode_in_place_ok`).
+a slab is whole tiles (:func:`paged_decode_in_place_ok`,
+:func:`latent_decode_in_place_ok`).
 
-Who chooses it: ``InferenceEngine._resolved_attn_impl``, once at
-construction, and nowhere else.  ``attention_impl="auto"`` selects it on a
-TPU, paged KV, one device, a head and page shape the kernel takes; else the
-XLA read, which is the reference.  ``"pallas"`` / ``"pallas_interpret"``
-exist for tests and bring-up: they waive the platform test alone, and an
-engine outside the rest of the rule is refused with :class:`PallasShapeError`.
+Who chooses them: ``InferenceEngine._resolved_attn_impl``, once at
+construction, and nowhere else; WHICH body follows from what the engine
+observes in its model, ``config.latent``.  ``attention_impl="auto"``
+selects the kernel on a TPU, paged KV, one device, a shape its rule takes;
+else the XLA read, which is the reference.  ``"pallas"`` /
+``"pallas_interpret"`` exist for tests and bring-up: they waive the
+platform test alone, and an engine outside the rest of the rule is refused
+with :class:`PallasShapeError`.
 
-Status: AOT-compiles for a described v5e at TinyLlama-1.1B's, Llama-3-8B's,
-Mistral-7B's, InternLM2-1.8B's and granite-4.0-h-micro's widths
-(``tests/test_tpu_compile.py``) and agrees with interpret mode and the XLA
-path on CPU; PERF.md section 6, PRs 25 and 28, has the chip's numbers.
+Status: both AOT-compile for a described v5e, the first at TinyLlama-1.1B's,
+Llama-3-8B's, Mistral-7B's, InternLM2-1.8B's and granite-4.0-h-micro's
+widths, the second at Kimi-VL-A3B's (``tests/test_tpu_compile.py``), and
+agree with interpret mode and the XLA path on CPU; PERF.md section 6, PRs
+25, 28 and 32, has the chip's numbers.
 """
 
 from __future__ import annotations
@@ -62,12 +79,12 @@ def _note_trace(kernel: str, interpret: bool) -> None:
 
 
 class PallasShapeError(ValueError):
-    """The kernel was asked for outside its rule: explicitly
+    """A kernel was asked for outside its rule: explicitly
     (``attention_impl="pallas"`` / ``"pallas_interpret"``) on an engine
-    that is not paged, spans more than one device or has a head or page
-    shape outside :func:`paged_decode_in_place_ok` (refused at
-    construction), or by a direct call with such a shape.  A kernel request
-    is never quietly served by another path."""
+    that is not paged, spans more than one device or has a shape outside
+    :func:`paged_decode_in_place_ok` (:func:`latent_decode_in_place_ok` for
+    a latent pool; refused at construction), or by a direct call with such
+    a shape.  A kernel request is never quietly served by another path."""
 
 
 def paged_decode_lane_pack(head_dim: int) -> int:
@@ -392,3 +409,302 @@ def merged_paged_decode_attention_pallas(
     o2, m2, z2 = ring_attention_source(qg, ring_k, ring_v, t)
     out = logsumexp_merge((o1, m1[..., None], z1[..., None]), (o2, m2, z2))
     return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
+# --------------------------------------------------------------------------- #
+# a latent (MLA) pool, read in the absorbed form
+# --------------------------------------------------------------------------- #
+
+
+def latent_decode_in_place_ok(kv_lora_rank: int, rope_dim: int, page: int, dtype) -> bool:
+    """Whether :func:`_latent_decode_kernel` can take these shapes on a TPU:
+    a page's ``c`` slab ``[page, r]`` is copied whole as it lies, so ``r`` is
+    whole lane tiles; its rope slab is copied out of
+    :func:`latent_rope_view`, ``f`` parts of the page side by side
+    (:func:`paged_decode_lane_pack` of the rope width: 2 at 64), so a row of
+    the view is whole lane tiles and a PART of the page, ``page / f``
+    positions, whole sublane tiles of the cache's dtype: the kernel slices
+    the ``c`` slab into the same parts.  What fails this reads through XLA
+    under ``"auto"`` and is refused under an explicit ``"pallas"``."""
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    f = paged_decode_lane_pack(rope_dim)
+    return (
+        kv_lora_rank % 128 == 0
+        and (f * rope_dim) % 128 == 0
+        and page % (f * sublanes) == 0
+    )
+
+
+def latent_rope_view(rope_side: jax.Array) -> jax.Array:
+    """The rope side of a latent pool as the latent decode kernel reads it:
+    ``[L, N, 1, page / f, f * dr]``, row r of a page holding positions
+    ``r, r + page / f, ..`` side by side: PART j of the page in lane block
+    j.  (Not :func:`lane_dense_pool`'s neighbours side by side: the kernel
+    meets part j with rows ``j * page / f ..`` of the ``c`` slab, a slice
+    of whole tiles, where neighbours would ask for every f-th row.)
+
+    A ``[.., page, 64]`` array does not lie row-major and lane-dense in a
+    TPU's HBM (:func:`lane_dense_pool` has why), so this is a copy of the
+    whole side, an eighth of the pool, and a caller in a loop makes it ONCE,
+    outside: the engine does, per dispatch
+    (``InferenceEngine._decode_fn_paged``).  The ``c`` side, eight ninths of
+    the pool, is never relaid.  At ``f == 1``, and for shapes outside the
+    rule, this IS the side."""
+    L, N, one, page, dr = rope_side.shape
+    f = paged_decode_lane_pack(dr)
+    if f == 1 or page % f:
+        return rope_side
+    parts = rope_side.reshape(L, N, one, f, page // f, dr)
+    return jnp.swapaxes(parts, 3, 4).reshape(L, N, one, page // f, f * dr)
+
+
+# pages of one row folded per compute block of the latent decode kernel
+# (tuned on the chip, PERF.md section 6, PR 32)
+LATENT_DECODE_PAGES_PER_BLOCK = 8
+
+
+def _latent_decode_kernel(
+    layer_ref, tables_ref, lens_ref,  # scalar-prefetch (SMEM)
+    ql_ref, qr_ref, pool_c, pool_r,  # q blocks in VMEM; the pool stays in HBM
+    o_ref, m_ref, z_ref,
+    cbuf, rbuf, sems,
+    *, wpages: int, scale: float,
+):
+    """One ROW of a latent decode step: a loop over that row's own live
+    pages, ``ceil(len / page)`` of them, each fetched as its ``c`` slab
+    ``[page, r]`` and its rope slab ``[page / f, f * dr]`` by
+    double-buffered async copies.  Nothing past the row's length is read or
+    computed; a row of length 0 starts no copy at all.
+
+    All heads score a block at once, part by part: part j of a block is
+    positions ``j * page / f ..`` of each of its pages, rows of whole tiles
+    of the ``c`` buffer, and lane block j of the rope buffer's rows, which
+    ``qr`` copy j (``q_rope`` in lane block j, zeros elsewhere) picks out.
+    The parts share ONE running max and sum (a softmax does not care for
+    the order of its columns), and a part's value product reads the ``c``
+    rows its scores just read.
+    """
+    b = pl.program_id(0)
+    P, page, r = cbuf.shape[1:]
+    rows, lanes = rbuf.shape[2:]  # a part of a page; f rope keys a row
+    f = page // rows
+    H = ql_ref.shape[1]
+    C = P * rows
+    layer = layer_ref[0]
+    kv_len = lens_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(kv_len, page), wpages)
+    n_blocks = pl.cdiv(n_pages, P)
+
+    def copies(blk, slot, i):
+        n = tables_ref[b, blk * P + i]
+        return (
+            pltpu.make_async_copy(
+                pool_c.at[layer, n, 0], cbuf.at[slot, i], sems.at[0, slot]
+            ),
+            pltpu.make_async_copy(
+                pool_r.at[layer, n, 0], rbuf.at[slot, i], sems.at[1, slot]
+            ),
+        )
+
+    def for_live_pages(blk, slot, act):
+        # a loop, not P copies of the body: the kernel is lowered anew in
+        # every program that carries decode steps (PERF.md section 6, PR 32)
+        def page(i, carry):
+            for dma in copies(blk, slot, i):
+                act(dma)
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(P, n_pages - blk * P), page, 0)
+
+    if P > 1:
+        # a partial last block leaves buffer pages no copy ever wrote:
+        # their columns are masked (p = 0), and 0 x garbage must stay 0
+        @pl.when(b == 0)
+        def _clear():
+            cbuf[...] = jnp.zeros_like(cbuf)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        for_live_pages(0, 0, lambda dma: dma.start())
+
+    ql = ql_ref[0]  # [H, r], the cache's dtype
+    qr = qr_ref[0]  # [f * H, lanes]: copy j's q_rope in lane block j
+    col = lax.broadcasted_iota(jnp.int32, (H, C), 1)
+    col_pos = (col // rows) * page + col % rows  # part 0's position in block
+
+    def block(blk, carry):
+        m_prev, z_prev, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            for_live_pages(blk + 1, 1 - slot, lambda dma: dma.start())
+
+        for_live_pages(blk, slot, lambda dma: dma.wait())
+        kr = rbuf[slot].reshape(C, lanes)
+        live = kv_len - blk * (P * page)
+        parts = []
+        for j in range(f):  # static
+            c = cbuf[slot, :, j * rows:(j + 1) * rows, :].reshape(C, r)
+            s = (
+                lax.dot_general(
+                    ql, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+                )
+                + lax.dot_general(
+                    qr[j * H:(j + 1) * H], kr, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            ) * scale  # [H, C]
+            parts.append((c, jnp.where(col_pos + j * rows < live, s, -1e30)))
+        m_new = functools.reduce(
+            jnp.maximum, [jnp.max(s, axis=-1, keepdims=True) for _, s in parts], m_prev
+        )
+        alpha = jnp.exp(m_prev - m_new)
+        z_new, acc = z_prev * alpha, acc * alpha
+        for c, s in parts:
+            # p in the cache's dtype before the value product, z from the
+            # rounded p: the law of model.mla_merged_decode_attention
+            p = jnp.exp(s - m_new).astype(c.dtype)
+            z_new = z_new + jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
+            acc = acc + lax.dot_general(
+                p, c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+        return m_new, z_new, acc
+
+    m, z, acc = lax.fori_loop(
+        0, n_blocks, block,
+        (
+            # the -1e29 floor of a fully masked row is where m starts
+            jnp.full((H, 1), -1e29, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, r), jnp.float32),
+        ),
+    )
+    o_ref[0] = acc
+    m_ref[0] = m
+    z_ref[0] = z
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "wpages", "interpret", "pages_per_block")
+)
+def latent_decode_attention_pallas(
+    q_lat: jax.Array,  # [B, H, r] the absorbed query, against c itself
+    q_rope: jax.Array,  # [B, H, dr]
+    pool_c: jax.Array,  # [L, N, 1, page, r] the WHOLE c side, as it lies
+    pool_r: jax.Array,  # [L, N, 1, page, dr] the rope side, or its latent_rope_view
+    layer: jax.Array,  # scalar int32 — which layer's pages to read
+    tables: jax.Array,  # [B, Pmax] int32 block tables
+    base_lens: jax.Array,  # [B]
+    *,
+    scale: float,  # 1 / sqrt(dn + dr): the scores' law, whatever is absorbed
+    wpages: int,
+    interpret: bool = False,
+    pages_per_block: int = LATENT_DECODE_PAGES_PER_BLOCK,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The absorbed read of the main cache → (o [B,H,r] f32 unnormalized,
+    m [B,H] f32, z [B,H] f32): the first source of
+    ``model.mla_merged_decode_attention``.
+
+    Reads each row's LIVE pages in place (:func:`_latent_decode_kernel`):
+    both sides go in whole and stay in HBM, the layer is an index, the grid
+    is the rows alone, and the work of a row follows ``base_lens[b]``:
+    ``wpages`` is only the static upper bound.  ``c``, ``k_rope`` and the
+    queries meet the MXU in the cache's dtype with float32 accumulation.
+
+    A caller in a loop passes the rope side's :func:`latent_rope_view`,
+    made outside the loop; a side passed as it lies is viewed here, per call."""
+    B, H, r = q_lat.shape
+    dr = q_rope.shape[-1]
+    page = pool_c.shape[3]
+    if not latent_decode_in_place_ok(r, dr, page, pool_c.dtype):
+        raise PallasShapeError(
+            f"the latent decode kernel copies a page slab whole: a latent of "
+            f"{r} | {dr} on pages of {page} {pool_c.dtype} positions is not "
+            "whole (8, 128) tiles (latent_decode_in_place_ok)"
+        )
+    if pool_r.shape[3] == page:
+        pool_r = latent_rope_view(pool_r)
+    rows, lanes = pool_r.shape[3:]
+    f = lanes // dr
+    _note_trace("latent_decode", interpret)
+    P = max(1, min(pages_per_block, wpages))
+    kernel = functools.partial(_latent_decode_kernel, wpages=wpages, scale=scale)
+
+    def row_map(b, *_refs):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, r), row_map),
+            pl.BlockSpec((1, f * H, lanes), row_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, H, r), row_map),
+            pl.BlockSpec((1, H, 1), row_map),
+            pl.BlockSpec((1, H, 1), row_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, P, page, r), pool_c.dtype),
+            pltpu.VMEM((2, P, rows, lanes), pool_r.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    o, m, z = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=(
+            jax.ShapeDtypeStruct((B, H, r), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            # rows in order: the scratch cleared by row 0 serves them all
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        tables.astype(jnp.int32),
+        base_lens.astype(jnp.int32),
+        q_lat.astype(pool_c.dtype),
+        _lane_block_copies(q_rope.astype(pool_r.dtype), f),
+        pool_c, pool_r,
+    )
+    return o, m[..., 0], z[..., 0]
+
+
+@jax.named_scope("attention")
+def merged_latent_decode_attention_pallas(
+    q_lat: jax.Array,  # [B, 1, H, r]
+    q_rope: jax.Array,  # [B, 1, H, dr]
+    pool_c: jax.Array,  # [L, N, 1, page, r]
+    pool_r: jax.Array,  # the rope side, or its latent_rope_view
+    layer: jax.Array,  # scalar int32
+    tables: jax.Array,  # [B, Pmax]
+    ring: tuple[jax.Array, jax.Array],  # ([T, B, 1, r], [T, B, 1, dr]) this layer's ring
+    base_lens: jax.Array,  # [B]
+    t: jax.Array,
+    *,
+    scale: float,
+    wpages: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """Drop-in for ``model.mla_merged_decode_attention`` over a gathered
+    window → ``sum_t P_t c_t`` [B, 1, H, r] float32: the main-cache source
+    from the kernel, the (tiny) ring folded in via the same logsumexp merge
+    in plain XLA."""
+    from calfkit_tpu.inference.model import logsumexp_merge, mla_ring_attention_source
+
+    q_lat, q_rope = q_lat[:, 0], q_rope[:, 0]
+    o1, m1, z1 = latent_decode_attention_pallas(
+        q_lat, q_rope, pool_c, pool_r, layer, tables, base_lens,
+        scale=scale, wpages=wpages, interpret=interpret,
+    )
+    source2 = mla_ring_attention_source(q_lat, q_rope, ring, t, scale)
+    return logsumexp_merge((o1, m1[..., None], z1[..., None]), source2)[:, None]

@@ -520,13 +520,27 @@ def test_what_has_no_latent_or_expert_path_is_refused_at_construction(option, kw
         InferenceEngine(TOY, runtime(**kwargs))
 
 
-def test_the_paged_decode_kernel_is_not_for_a_latent_pool():
-    from calfkit_tpu.inference.pallas_attention import PallasShapeError
+@pytest.mark.parametrize("latent, page, built", [
+    ((128, 64), 16, True),  # whole lane tiles | half of one, pages of two parts of a tile
+    ((32, 8), 8, False),  # this file's toy latent
+], ids=["inside-the-rule", "outside-the-rule"])
+def test_the_paged_decode_kernel_is_not_for_a_latent_pool(latent, page, built):
+    """The kernel of K and V pairs never reads a latent pool.  An explicit
+    request builds the LATENT decode kernel where the shapes are in its
+    rule (``pallas_attention.latent_decode_in_place_ok``) and is refused
+    outside it; "auto" on this CPU reads through XLA either way."""
+    from calfkit_tpu.inference import pallas_attention as PA
 
-    engine = InferenceEngine(TOY, runtime())
+    config = replace(TOY, kv_lora_rank=latent[0], qk_rope_head_dim=latent[1])
+    engine = InferenceEngine(config, runtime(page_size=page))
     assert (engine._attn_impl, engine._ssm_impl) == ("xla", "xla")
-    with pytest.raises(PallasShapeError, match="one latent a token"):
-        InferenceEngine(TOY, runtime(attention_impl="pallas_interpret"))
+    if not built:
+        with pytest.raises(PA.PallasShapeError, match="latent_decode_in_place_ok"):
+            InferenceEngine(config, runtime(page_size=page, attention_impl="pallas_interpret"))
+        return
+    # what it then builds and serves: tests/test_latent_decode_attention.py
+    engine = InferenceEngine(config, runtime(page_size=page, attention_impl="pallas_interpret"))
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "xla")
 
 
 @pytest.mark.parametrize("fields, message", [

@@ -1,4 +1,5 @@
-"""The ONE attention kernel and the ONE place that chooses it.
+"""The paged decode kernel of K and V pairs and the ONE place that chooses it
+(a latent pool's body: tests/test_latent_decode_attention.py).
 
 - The paged decode ATTENTION kernel's corners (PRs 25 and 28;
   ``pallas_attention._paged_decode_kernel``, interpret mode): row lengths

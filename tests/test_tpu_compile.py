@@ -1,11 +1,12 @@
-"""AOT compiles of the two Pallas kernels for a DESCRIBED v5e.
+"""AOT compiles of the three Pallas kernels for a DESCRIBED v5e.
 
 The TPU compiler is installed without a chip: it compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2).
 Interpret mode cannot see what the Mosaic lowering refuses — block shapes,
-SMEM scalars, VMEM limits — so the two kernels the serving path can select,
-the paged decode read in place and the Mamba-2 decode step's pass over the
-SSM state, are compiled here at the widths of every preset and benchmark
+SMEM scalars, VMEM limits — so the three kernels the serving path can
+select, the paged decode read in place (of K and V pairs, and of a latent
+pool in the absorbed form) and the Mamba-2 decode step's pass over the SSM
+state, are compiled here at the widths of every preset and benchmark
 configuration that takes them, alone and inside the dispatch programs of
 the benchmark's cells.  Nothing runs; a pass is a compile, never a chip run.
 
@@ -105,6 +106,55 @@ def test_paged_decode_in_place_compiles_for_v5e(
     # not replaced by an XLA fallback
     assert "tpu_custom_call" in compiled.as_text()
     assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
+
+
+# the latent decode read: (H, r, dr, B, W, pages, layers), the benchmark's
+# one latent configuration at its cell's batch and window
+DECODE_LATENT_WIDTHS = {"kimi-vl-a3b-instruct": (16, 512, 64, 64, 4096, 4097, 7)}
+
+
+def _latent_decode_args(shape, widths="kimi-vl-a3b-instruct"):
+    """Abstract arguments of ``latent_decode_attention_pallas`` as the decode
+    step calls it: the c side as it lies, the rope side's view."""
+    import jax.numpy as jnp
+
+    H, r, dr, rows, window, pages, layers = DECODE_LATENT_WIDTHS[widths]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return (
+        shape((rows, H, r), bf16), shape((rows, H, dr), bf16),
+        shape((layers, pages, 1, PAGE, r), bf16),
+        shape((layers, pages, 1, PAGE // 2, 2 * dr), bf16), shape((), i32),
+        shape((rows, window // PAGE), i32), shape((rows,), i32),
+    ), {"scale": (128 + dr) ** -0.5, "wpages": window // PAGE}
+
+
+def test_latent_decode_in_place_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The merged latent decode read as ``decode_step_ring_paged`` calls it
+    for Kimi-VL-A3B's cell, both sides of the configuration's whole pool as
+    the kernel's HBM operands: the rope side through its view, the c side,
+    1.88 GB, as it lies (nothing of its size is made)."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    H, r, dr, rows, window, pages, layers = DECODE_LATENT_WIDTHS["kimi-vl-a3b-instruct"]
+    assert PA.latent_decode_in_place_ok(r, dr, PAGE, jnp.bfloat16)
+    (q_lat, q_rope, c, rope, layer, tables, lens), static = _latent_decode_args(shape)
+    ring = (shape((8, rows, 1, r), jnp.bfloat16), shape((8, rows, 1, dr), jnp.bfloat16))
+    before = PA.KERNEL_TRACES["latent_decode", "compiled"]
+    compiled = jax.jit(
+        lambda ql, qr, c, rope, layer, tables, rc, rr, lens, t:
+        PA.merged_latent_decode_attention_pallas(
+            ql[:, None], qr[:, None], c, rope, layer, tables, (rc, rr), lens, t, **static)
+    ).lower(q_lat, q_rope, c, rope, layer, tables, *ring, lens, shape((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "latent_decode_attention" in hlo
+    assert PA.KERNEL_TRACES["latent_decode", "compiled"] == before + 1
+    assert compiled.memory_analysis().temp_size_in_bytes < c.size  # half the c side's bytes
 
 
 def _computations(hlo: str) -> dict[str, list[str]]:
@@ -392,7 +442,15 @@ def _ssm_step_entry(shape):
     return PS.ssm_step_pallas, lambda *a: PS.ssm_step_pallas(*a), args
 
 
-@pytest.mark.parametrize("kernel", ["paged_decode", "ssm_step"])
+def _latent_decode_entry(shape):
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    args, static = _latent_decode_args(shape)
+    return (PA.latent_decode_attention_pallas,
+            lambda *a: PA.latent_decode_attention_pallas(*a, **static), args)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode", "ssm_step", "latent_decode"])
 def test_kernel_bytes_do_not_depend_on_the_caller(
     kernel, one_chip, no_persistent_cache, monkeypatch
 ):
@@ -407,7 +465,8 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry}[kernel](shape)
+    entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry,
+                      "latent_decode": _latent_decode_entry}[kernel](shape)
 
     def deep(*a, depth=4):
         if depth:
@@ -433,10 +492,10 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
 
 def test_entry_point_list_is_complete():
-    """The kernel modules' entry points are the three compiled above (the
-    merged read calls the plain one), each module around ONE
-    ``pallas_call``, and no other module of the package makes one: a kernel
-    added without a compile of its own fails here."""
+    """The kernel modules' entry points are the five compiled above (a
+    merged read calls the plain one), ONE ``pallas_call`` a kernel body,
+    and no other module of the package makes one: a kernel added without a
+    compile of its own fails here."""
     import glob
     import inspect
 
@@ -449,9 +508,10 @@ def test_entry_point_list_is_complete():
 
     assert entries(PA) == {
         "paged_decode_attention_pallas", "merged_paged_decode_attention_pallas",
+        "latent_decode_attention_pallas", "merged_latent_decode_attention_pallas",
     }
     assert entries(PS) == {"ssm_step_pallas"}
-    assert inspect.getsource(PA).count("pl.pallas_call(") == 1
+    assert inspect.getsource(PA).count("pl.pallas_call(") == 2
     assert inspect.getsource(PS).count("pl.pallas_call(") == 1
     with_kernels = sorted(
         os.path.basename(path)
@@ -462,9 +522,9 @@ def test_entry_point_list_is_complete():
 
 
 # ---------------------------------------------------------------------------
-# A latent pool and routed experts (kimi-vl-a3b-instruct): no Pallas kernel of
-# this repo's, the XLA absorbed read in the decode step, the TPU compiler's
-# own ragged-dot kernel for a chunk's grouped expert products
+# A latent pool and routed experts (kimi-vl-a3b-instruct): the latent decode
+# read in place in the decode step, the TPU compiler's own ragged-dot kernel
+# for a chunk's grouped expert products
 # ---------------------------------------------------------------------------
 
 
@@ -473,14 +533,20 @@ def test_entry_point_list_is_complete():
 def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
     """The decode dispatch and the ragged program of the cell's configuration
     at its published WIDTHS and runtime (2 of its 7 layers: the dense one and
-    one expert layer, all 64 experts), compiled for the described v5e: the
-    decode step reads the latent through ``gather_window`` under ``mla`` and
-    multiplies every expert (no custom call at all); the chunk that rides
-    along groups its tokens when it is wider than ``moe._DENSE_MAX_TOKENS``
-    (two rows of 1,024: three ``ragged-dot`` kernels an expert layer; one
-    row takes the dense form, as the decode step does: the chip's readings
-    in ``moe.py``); the two parts of the pool go out where they came in."""
+    one expert layer, all 64 experts), with the kernel resolved as "auto"
+    resolves it on a chip, compiled for the described v5e: the decode step
+    reads the latent through the latent decode kernel under
+    ``mla/attention`` (two calls: the unrolled dense layer and the expert
+    layers' scan), gathers NO window and slices no layer out of the pool
+    there, and multiplies every expert; the rope side's view is made once a
+    dispatch, in the entry computation, and nothing of the c side's size is
+    made in a loop's body; the chunk that rides along groups its tokens when
+    it is wider than ``moe._DENSE_MAX_TOKENS`` (two rows of 1,024: three
+    ``ragged-dot`` kernels an expert layer; one row takes the dense form, as
+    the decode step does: the chip's readings in ``moe.py``); the two parts
+    of the pool go out where they came in."""
     import json
+    import re
     from dataclasses import replace
 
     import jax
@@ -494,13 +560,22 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
         described = json.load(f)
     arch = manifest.load_architecture(described["architecture"], here)
     config, runtime = arch.model(described, False)
+    assert InferenceEngine(
+        replace(config, n_layers=2), replace(runtime, compilation_cache=False)
+    )._attn_impl == "xla"  # "auto" on this process's CPU: the reference path
     engine = InferenceEngine(
-        replace(config, n_layers=2), replace(runtime, compilation_cache=False))
-    assert engine._attn_impl == "xla" and engine._ssm_impl == "xla"
+        replace(config, n_layers=2),
+        replace(runtime, compilation_cache=False, attention_impl="pallas"))
+    assert engine._attn_impl == "pallas" and engine._ssm_impl == "xla"
 
     def abstract(tree):
         return jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    def own_kernels(hlo):
+        return [line for line in hlo.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line
+                and "latent_decode_attention" in line]
 
     rt, cfg = engine.runtime, engine.config
     args, window, steps, sampled = engine._decode_args()
@@ -514,8 +589,29 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     decode = engine._decode_jit(window, steps, sampled).lower(
         *abstract(args), moe=abstract(zero)).compile()
     hlo = decode.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in hlo
-    assert "decode_loop/" in hlo and "/mla/gather_window" in hlo and "/mlp/moe/experts" in hlo
+    kernels = own_kernels(hlo)
+    assert len(kernels) == 2 and all("/mla/attention/" in line for line in kernels), kernels
+    assert all("decode_loop/" in line for line in kernels)
+    assert "gather_window" not in hlo and "/mlp/moe/experts" in hlo
+    # the rope side's view: once a dispatch, in the entry computation; the c
+    # side: no operation of a loop's body makes an array of its size or of
+    # ONE LAYER's (the XLA read's dynamic-slice copy)
+    L, N, _, page, r = engine._k.shape
+    dr = engine._v.shape[-1]
+    view = re.compile(rf"= bf16\[{L},{N},1,{page // 2},{2 * dr}\]\S* (\w[\w\-]*)\(")
+    c_side = re.compile(rf"= bf16\[(?:{L},)?{N},1,{page},{r}\]\S* (\w[\w\-]*)\(")
+    plumbing = ("bitcast", "parameter", "get-tuple-element")
+    for pattern, in_entry in ((view, 1), (c_side, None)):
+        made = {
+            name: [m.group(1) for m in map(pattern.search, lines)
+                   if m and m.group(1) not in plumbing]
+            for name, lines in _computations(hlo).items()
+        }
+        entry = made.pop("ENTRY")
+        if in_entry is not None:
+            assert len(entry) == in_entry, entry
+        assert not any(
+            ops for name, ops in made.items() if "while" in name or "body" in name), made
     pool_bytes = engine._k.nbytes + engine._v.nbytes
     assert decode.memory_analysis().alias_size_in_bytes >= pool_bytes
     ragged = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
@@ -531,6 +627,11 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     assert len(grouped) == 3  # gate, up, down of the one expert layer
     assert all("bf16[64,2048,1408]" in line or "bf16[64,1408,2048]" in line for line in grouped)
     assert "decode_loop/" in hlo and "chunk_loop/" in hlo
+    assert len(own_kernels(hlo)) == 2
+    # the chunk still reads its rows' windows through XLA (the reference
+    # path, S > 1); the decode steps beside it gather nothing
+    assert not any("decode_loop/" in line and "gather_window" in line
+                   for line in hlo.splitlines())
     one = [jax.ShapeDtypeStruct((a.shape[0], 1, *a.shape[2:]), a.dtype) for a in scratch]
     narrow = engine._ragged_jit(window, steps, sampled, chunk, 1).lower(
         *abstract((*args, *one, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
