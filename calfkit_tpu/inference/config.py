@@ -6,15 +6,17 @@ any device work happens).
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field, replace
 
 
-ATTENTION, MAMBA = "attention", "mamba"
+ATTENTION, MAMBA, GDN = "attention", "mamba", "gdn"
+RECURRENT_KINDS = (MAMBA, GDN)
 
 
 class UnsupportedWithRecurrentLayers(ValueError):
-    """A runtime option that a model with recurrent (Mamba-2) layers cannot
-    be served under yet.  Raised when the engine is built, never later:
+    """A runtime option that a model with recurrent (Mamba-2 or Gated
+    DeltaNet) layers cannot be served under yet.  Raised when the engine is built, never later:
     there is no silent fallback to a path that would drop the state."""
 
 
@@ -42,6 +44,16 @@ class ModelConfig:
     V per head), and with ``n_routed_experts`` every layer after the first
     ``first_k_dense`` replaces the SwiGLU of ``d_ff`` by routed experts of
     ``moe_d_ff`` and one shared expert of ``n_shared_experts x moe_d_ff``.
+    With ``"gdn"`` among ``layer_types`` it is a Qwen3-Next-style hybrid:
+    Gated DeltaNet (linear attention: a delta-rule state of ``gdn_d_k x
+    gdn_d_v`` numbers a value head, the ``gdn_*`` sizes) beside gated
+    attention (``attn_head_dim``, ``qk_norm``, ``partial_rotary_factor``,
+    ``attn_output_gate``), norms that multiply by ``1 + w``
+    (``norm_plus_one``), and with ``n_routed_experts`` the expert block as
+    EVERY layer's FFN.  There ``n_routed_experts`` is the experts THIS
+    device holds: ``[expert_first, expert_first + n_routed_experts)`` of the
+    ``n_experts_total`` the gate scores (a share of an expert-parallel
+    layer; what an absent expert would add to a token is left out).
     """
 
     name: str = "debug"
@@ -99,6 +111,28 @@ class ModelConfig:
     topk_method: str = "noaux_tc"
     n_group: int = 1
     topk_group: int = 1
+    # the shared expert's output times sigmoid(h . shared_gate) (Qwen3-Next)
+    shared_expert_gate: bool = False
+    # experts held by share: the gate scores n_experts_total (0 = the
+    # n_routed_experts held, i.e. all), this device holds those from
+    # expert_first on
+    n_experts_total: int = 0
+    expert_first: int = 0
+    # ---- Gated DeltaNet beside gated attention (all defaults = as before) ----
+    # a "gdn" layer: gdn_n_k_heads key heads of gdn_d_k, gdn_n_v_heads value
+    # heads of gdn_d_v (HF: linear_num_key_heads, linear_key_head_dim,
+    # linear_num_value_heads, linear_value_head_dim, linear_conv_kernel_dim)
+    gdn_n_k_heads: int = 0
+    gdn_n_v_heads: int = 0
+    gdn_d_k: int = 0
+    gdn_d_v: int = 0
+    gdn_d_conv: int = 4
+    gdn_chunk_size: int = 64  # the block of the chunkwise prefill
+    attn_head_dim: int = 0  # a head's width where it is not d_model // n_heads
+    partial_rotary_factor: float = 1.0  # rotary on the FIRST this share of a head
+    qk_norm: bool = False  # RMSNorm over each query and key head before the rotation
+    attn_output_gate: bool = False  # W_q gives q | gate a head; out = o * sigmoid(gate)
+    norm_plus_one: bool = False  # every RMSNorm of the stack multiplies by (1 + w)
 
     def __post_init__(self) -> None:
         if self.kv_lora_rank:
@@ -110,41 +144,72 @@ class ModelConfig:
                 )
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
-        elif self.n_routed_experts:
+        elif self.n_routed_experts and GDN not in self.layer_types:
             raise ValueError(
                 "routed experts are described for the latent-attention stack "
-                "(kv_lora_rank) alone"
+                "(kv_lora_rank) and for the Gated DeltaNet hybrid (layer_types "
+                'with "gdn") alone'
             )
         if self.n_routed_experts:
-            if not (0 < self.n_experts_per_tok <= self.n_routed_experts and self.moe_d_ff):
+            if not (0 < self.n_experts_per_tok <= self.experts_scored and self.moe_d_ff):
                 raise ValueError("routed experts need n_experts_per_tok and moe_d_ff")
             if not 0 <= self.first_k_dense < self.n_layers:
                 raise ValueError("first_k_dense must leave at least one expert layer")
-            if (self.scoring_func, self.topk_method) != ("sigmoid", "noaux_tc"):
+            if (self.scoring_func, self.topk_method) not in (
+                    ("sigmoid", "noaux_tc"), ("softmax", "greedy")):
                 raise ValueError(
                     f"router {self.scoring_func!r}/{self.topk_method!r}: only "
-                    "sigmoid scores with noaux_tc selection are described"
+                    "sigmoid scores with noaux_tc selection and softmax scores "
+                    "with greedy selection are described"
                 )
             if (self.n_group, self.topk_group) != (1, 1):
                 raise ValueError("group-limited routing (n_group > 1) is not described")
+            if not 0 <= self.expert_first <= self.experts_scored - self.n_routed_experts:
+                raise ValueError(
+                    f"experts held [{self.expert_first}, "
+                    f"{self.expert_first + self.n_routed_experts}) are not among "
+                    f"the {self.experts_scored} the gate scores"
+                )
+            if self.layer_types and self.first_k_dense:
+                raise ValueError("a hybrid stack's expert block is every layer's FFN")
+        elif self.n_experts_total or self.expert_first or self.shared_expert_gate:
+            raise ValueError(
+                "n_experts_total, expert_first and shared_expert_gate belong to "
+                "routed experts (n_routed_experts)"
+            )
         if self.layer_types:
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"n_layers is {self.n_layers}"
                 )
-            unknown = set(self.layer_types) - {ATTENTION, MAMBA}
+            unknown = set(self.layer_types) - {ATTENTION, MAMBA, GDN}
             if unknown:
                 raise ValueError(f"unknown layer types {sorted(unknown)}")
-            if MAMBA not in self.layer_types:
+            kinds = set(self.layer_types) & set(RECURRENT_KINDS)
+            if not kinds:
                 raise ValueError(
-                    "layer_types without a mamba layer is the dense decoder: "
-                    "leave it empty"
+                    "layer_types without a mamba or gdn layer is the dense "
+                    "decoder: leave it empty"
                 )
-            if not (self.mamba_n_heads and self.mamba_d_head and self.mamba_d_state):
-                raise ValueError("mamba layers need mamba_n_heads/d_head/d_state")
-            if self.mamba_n_heads % self.mamba_n_groups:
-                raise ValueError("mamba_n_groups must divide mamba_n_heads")
+            if len(kinds) > 1:
+                raise ValueError("mamba and gdn layers in one stack are not described")
+            if MAMBA in kinds:
+                if not (self.mamba_n_heads and self.mamba_d_head and self.mamba_d_state):
+                    raise ValueError("mamba layers need mamba_n_heads/d_head/d_state")
+                if self.mamba_n_heads % self.mamba_n_groups:
+                    raise ValueError("mamba_n_groups must divide mamba_n_heads")
+            else:
+                if not (self.gdn_n_k_heads and self.gdn_n_v_heads and self.gdn_d_k
+                        and self.gdn_d_v):
+                    raise ValueError("gdn layers need gdn_n_k_heads/n_v_heads/d_k/d_v")
+                if self.gdn_n_v_heads % self.gdn_n_k_heads:
+                    raise ValueError("gdn_n_k_heads must divide gdn_n_v_heads")
+                if not self.n_routed_experts:
+                    raise ValueError(
+                        "a Gated DeltaNet hybrid's FFN is the expert block "
+                        "(n_routed_experts): one SwiGLU a layer is not described"
+                    )
         elif (
             self.position_embedding != "rope"
             or self.attention_multiplier is not None
@@ -159,13 +224,29 @@ class ModelConfig:
             raise ValueError(
                 f"unknown position_embedding {self.position_embedding!r}"
             )
+        if GDN not in self.layer_types and (
+            self.attn_head_dim or self.qk_norm or self.attn_output_gate
+            or self.norm_plus_one or self.partial_rotary_factor != 1.0
+        ):
+            raise ValueError(
+                "attn_head_dim, qk_norm, attn_output_gate, norm_plus_one and "
+                'partial_rotary_factor belong to the Gated DeltaNet hybrid '
+                '(layer_types with "gdn")'
+            )
+        if self.rotary_dim % 2:
+            raise ValueError("the rotated part of a head must be even (rotary pairs)")
 
     @property
     def head_dim(self) -> int:
         """Width of a query (and key) head."""
         if self.latent:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading part of a head the rotary embedding turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def latent(self) -> bool:
@@ -175,6 +256,16 @@ class ModelConfig:
     @property
     def moe(self) -> bool:
         return self.n_routed_experts > 0
+
+    @property
+    def experts_scored(self) -> int:
+        """Experts the gate scores: all of the layer's, held here or not."""
+        return self.n_experts_total or self.n_routed_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Does this device hold only some of the experts the gate scores?"""
+        return self.experts_scored > self.n_routed_experts
 
     @property
     def n_moe_layers(self) -> int:
@@ -208,18 +299,32 @@ class ModelConfig:
 
     @property
     def recurrent(self) -> bool:
-        """Does a sequence carry state besides K and V (Mamba-2 layers)?"""
-        return MAMBA in self.layer_types
+        """Does a sequence carry state besides K and V (Mamba-2 or Gated
+        DeltaNet layers)?"""
+        return any(t in RECURRENT_KINDS for t in self.layer_types)
+
+    @property
+    def gdn(self) -> bool:
+        """Are the recurrent layers Gated DeltaNet (else Mamba-2)?"""
+        return GDN in self.layer_types
+
+    @property
+    def recurrent_kind(self) -> str:
+        return "Gated DeltaNet" if self.gdn else "Mamba-2"
 
     @property
     def n_mamba_layers(self) -> int:
         return sum(t == MAMBA for t in self.layer_types)
 
     @property
+    def n_recurrent_layers(self) -> int:
+        return sum(t in RECURRENT_KINDS for t in self.layer_types)
+
+    @property
     def n_kv_layers(self) -> int:
         """Layers that keep K and V: all of them unless ``layer_types``
         says otherwise."""
-        return self.n_layers - self.n_mamba_layers
+        return self.n_layers - self.n_recurrent_layers
 
     @property
     def layer_period(self) -> tuple[str, ...]:
@@ -244,14 +349,45 @@ class ModelConfig:
         """Width of the fused input projection: z | xBC | dt."""
         return self.mamba_d_inner + self.mamba_conv_dim + self.mamba_n_heads
 
-    def recurrent_state_bytes(self, rows: int) -> int:
-        """Bytes ``rows`` sequences' SSM and conv state take on the device."""
-        itemsize = {"float32": 4, "bfloat16": 2}
-        ssm = self.mamba_n_heads * self.mamba_d_head * self.mamba_d_state
-        conv = self.mamba_conv_dim * (self.mamba_d_conv - 1)
-        return self.n_mamba_layers * rows * (
-            ssm * itemsize[self.state_dtype] + conv * itemsize.get(self.dtype, 2)
+    @property
+    def gdn_key_dim(self) -> int:
+        return self.gdn_n_k_heads * self.gdn_d_k
+
+    @property
+    def gdn_value_dim(self) -> int:
+        return self.gdn_n_v_heads * self.gdn_d_v
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels the causal conv runs over: q | k | v."""
+        return 2 * self.gdn_key_dim + self.gdn_value_dim
+
+    @property
+    def gdn_d_in_proj(self) -> int:
+        """Width of the fused input projection: q | k | v | z | b | a."""
+        return self.gdn_conv_dim + self.gdn_value_dim + 2 * self.gdn_n_v_heads
+
+    def recurrent_state_shapes(self, rows: int) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """Shapes of the state pair ``rows`` sequences carry: the matrix
+        state ``[L, rows, heads, d_head, d_state]`` (Mamba-2's SSM state, or
+        the delta rule's ``S`` of d_k x d_v a value head) and the conv tail
+        ``[L, d_conv - 1, rows, channels]``."""
+        if self.gdn:
+            return (
+                (self.n_recurrent_layers, rows, self.gdn_n_v_heads, self.gdn_d_k, self.gdn_d_v),
+                (self.n_recurrent_layers, self.gdn_d_conv - 1, rows, self.gdn_conv_dim),
+            )
+        return (
+            (self.n_mamba_layers, rows, self.mamba_n_heads, self.mamba_d_head,
+             self.mamba_d_state),
+            (self.n_mamba_layers, self.mamba_d_conv - 1, rows, self.mamba_conv_dim),
         )
+
+    def recurrent_state_bytes(self, rows: int) -> int:
+        """Bytes ``rows`` sequences' matrix and conv state take on the device."""
+        itemsize = {"float32": 4, "bfloat16": 2}
+        matrix, conv = (math.prod(shape) for shape in self.recurrent_state_shapes(rows))
+        return matrix * itemsize[self.state_dtype] + conv * itemsize.get(self.dtype, 2)
 
     @property
     def param_count(self) -> int:
@@ -276,11 +412,27 @@ class ModelConfig:
                 + self.n_dense_layers * dense + self.n_moe_layers * moe
             )
         attention = (
-            # q, k, v, o
-            self.d_model * self.n_heads * self.head_dim
+            # q (and its output gate), k, v, o
+            self.d_model * self.n_heads * self.head_dim * (2 if self.attn_output_gate else 1)
             + 2 * self.d_model * self.n_kv_heads * self.head_dim
             + self.n_heads * self.head_dim * self.d_model
+            + (2 * self.head_dim if self.qk_norm else 0)
         )
+        if self.gdn:
+            mixer = (
+                self.d_model * self.gdn_d_in_proj + self.gdn_value_dim * self.d_model
+                + self.gdn_conv_dim * self.gdn_d_conv + 2 * self.gdn_n_v_heads + self.gdn_d_v
+            )
+            expert = 3 * self.d_model * self.moe_d_ff
+            ffn = (
+                self.d_model * self.experts_scored  # the gate scores every expert
+                + (self.n_routed_experts + self.n_shared_experts) * expert
+                + (self.d_model if self.shared_expert_gate else 0)
+            )
+            return (
+                embed + self.d_model + self.n_layers * (ffn + 2 * self.d_model)
+                + self.n_kv_layers * attention + self.n_recurrent_layers * mixer
+            )
         # mlp: gate, up, down; the two norms
         mlp = 3 * self.d_model * self.d_ff + 2 * self.d_model
         total = embed + self.n_layers * mlp + self.n_kv_layers * attention + self.d_model
@@ -593,6 +745,78 @@ PRESETS: dict[str, ModelConfig] = {
         moe_d_ff=1408,
         first_k_dense=1,
         routed_scaling_factor=2.446,
+    ),
+    # Qwen3-Next-80B-A3B-Instruct's language model (HF: Qwen/
+    # Qwen3-Next-80B-A3B-Instruct, qwen3_next): 36 Gated DeltaNet layers and
+    # 12 gated-attention layers (period L L L A), 512 routed experts with 10
+    # a token and one gated shared expert in EVERY layer.  The multi-token
+    # prediction module is not described here.  All 512 experts: what one
+    # device holds of them is n_routed_experts / expert_first of a share.
+    "qwen3-next-80b-a3b-instruct": ModelConfig(
+        name="qwen3-next-80b-a3b-instruct",
+        vocab_size=151936,
+        d_model=2048,
+        n_layers=48,
+        n_heads=16,
+        n_kv_heads=2,
+        d_ff=5120,
+        rope_theta=10000000.0,
+        norm_eps=1e-6,
+        max_seq_len=262144,
+        layer_types=((GDN,) * 3 + (ATTENTION,)) * 12,
+        gdn_n_k_heads=16,
+        gdn_n_v_heads=32,
+        gdn_d_k=128,
+        gdn_d_v=128,
+        gdn_d_conv=4,
+        attn_head_dim=256,
+        partial_rotary_factor=0.25,
+        qk_norm=True,
+        attn_output_gate=True,
+        norm_plus_one=True,
+        n_routed_experts=512,
+        n_experts_per_tok=10,
+        n_shared_experts=1,
+        moe_d_ff=512,
+        scoring_func="softmax",
+        topk_method="greedy",
+        shared_expert_gate=True,
+    ),
+    # the same kind at toy size, for the tests: 2 periods of L L L A, 8
+    # experts scored of which this device holds 4 (share 1 of 2)
+    "debug-gdn-moe": ModelConfig(
+        name="debug-gdn-moe",
+        vocab_size=128,
+        d_model=32,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=64,
+        rope_theta=10000000.0,
+        norm_eps=1e-6,
+        max_seq_len=256,
+        dtype="float32",
+        layer_types=((GDN,) * 3 + (ATTENTION,)) * 2,
+        gdn_n_k_heads=2,
+        gdn_n_v_heads=4,
+        gdn_d_k=8,
+        gdn_d_v=8,
+        gdn_d_conv=4,
+        gdn_chunk_size=8,
+        attn_head_dim=16,
+        partial_rotary_factor=0.25,
+        qk_norm=True,
+        attn_output_gate=True,
+        norm_plus_one=True,
+        n_routed_experts=4,
+        n_experts_total=8,
+        expert_first=4,
+        n_experts_per_tok=3,
+        n_shared_experts=1,
+        moe_d_ff=16,
+        scoring_func="softmax",
+        topk_method="greedy",
+        shared_expert_gate=True,
     ),
 }
 

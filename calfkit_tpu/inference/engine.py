@@ -114,8 +114,8 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "state_rows_landed", "prefix_reuse_declined_recurrent",
-    "moe_assignments", "moe_expert_tokens_max", "moe_expert_tokens_mean",
-    "moe_experts_hit", *_SECONDS_FIELDS,
+    "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
+    "moe_expert_tokens_mean", "moe_experts_hit", *_SECONDS_FIELDS,
 )
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
@@ -238,6 +238,12 @@ def _engine_metrics(
             "(experts per token x expert layers x tokens; a chunk's padded "
             "positions and a decode step's inactive rows are computed and "
             "not counted)",
+        ),
+        moe_assignments_absent=reg.counter(
+            "calfkit_engine_moe_assignments_absent_total",
+            "token-expert pairs of REAL tokens whose expert another device "
+            "holds (experts held by share: the gate chose it, this device "
+            "left its part out); 0 where every expert is held",
         ),
         moe_expert_tokens_max=reg.counter(
             "calfkit_engine_moe_expert_tokens_max_total",
@@ -574,12 +580,15 @@ class EngineStats:
     prefix_reuse_declined_recurrent: int = 0
     recurrent_state_bytes: int = 0
     # routed experts (0 for a model without them): token-expert pairs
-    # computed for real tokens; the busiest and the mean expert's tokens,
+    # computed for real tokens (the HELD experts' alone, and beside them the
+    # pairs whose expert another device holds, where the experts are held
+    # by share); the busiest and the mean expert's tokens,
     # summed over expert layers and dispatches (their ratio is the
     # routing's imbalance); distinct experts the decode steps had to read,
     # summed over layers and steps.  And, a gauge, the device bytes of a
     # latent (MLA) page pool.
     moe_assignments: int = 0
+    moe_assignments_absent: int = 0
     moe_expert_tokens_max: int = 0
     moe_expert_tokens_mean: float = 0.0
     moe_experts_hit: int = 0
@@ -815,29 +824,40 @@ class InferenceEngine:
         # KV; what cannot keep that state right yet is refused HERE, with
         # its reason, and never served by a path that would drop it
         self._recurrent = config.recurrent
+        self._moe = config.moe
         if self._recurrent:
+            kind = config.recurrent_kind
             refused = {
                 "speculative": (rt.speculative is not None,
                                 "a rejected draft needs the state rolled back, and there "
                                 "is no state snapshot to roll back to"),
                 "tp > 1": (rt.tp > 1 or self.mesh.size > 1,
-                           "the Mamba leaves and the per-slot state have no sharding "
+                           f"the {kind} leaves and the per-slot state have no sharding "
                            "over a mesh of more than one device"),
                 "quantization": (rt.quantization is not None,
-                                 "the Mamba leaves have no scales"),
+                                 f"the {kind} leaves have no scales"),
                 "long_context": (rt.long_context,
                                  "the sequence-parallel lane carries no recurrent state"),
             }
+            if config.gdn:  # experts in the hybrid stack, held by share or whole
+                refused = {
+                    "dp > 1": (rt.dp > 1,
+                               "the expert leaves and the per-slot state have no sharding "
+                               "over a mesh of more than one device"),
+                    **refused,
+                    "kv_layout='dense'": (rt.kv_layout == "dense",
+                                          "the dense decode programs thread no expert "
+                                          "counters; the model is served from pages"),
+                }
             for option, (asked, why) in refused.items():
                 if asked:
                     raise UnsupportedWithRecurrentLayers(
-                        f"{config.name} has recurrent (Mamba-2) layers: "
+                        f"{config.name} has recurrent ({kind}) layers: "
                         f"RuntimeConfig {option} is not supported with them ({why})"
                     )
         # a model with latent attention keeps ONE latent a token where the
         # others keep K and V per head, and its experts are leaves of their
         # own; what has no code for either yet is refused HERE, with its reason
-        self._moe = config.moe
         if config.latent:
             refused = {
                 "speculative": (rt.speculative is not None,
@@ -1014,8 +1034,9 @@ class InferenceEngine:
                 out_shardings=(rep_sh, rep_sh),
             )()
             logger.info(
-                "recurrent state: %d slots x %d Mamba layers (%.2f GB)",
-                B, config.n_mamba_layers, config.recurrent_state_bytes(B) / 1e9,
+                "recurrent state: %d slots x %d %s layers (%.2f GB)",
+                B, config.n_recurrent_layers, config.recurrent_kind,
+                config.recurrent_state_bytes(B) / 1e9,
             )
         self._last = jnp.zeros((B,), jnp.int32)
         self._lens = jnp.zeros((B,), jnp.int32)
@@ -1155,6 +1176,11 @@ class InferenceEngine:
         # beside whatever state it carries (moe.py): zeros in, the
         # dispatch's counts out, read at the landing's one sync
         self._moe_zero = moe_stats_init(config) if self._moe else None
+        # tokens sent to each HELD expert of each expert layer since the start
+        # (what the scalar moe_* counters are sums of): moe_expert_counts()
+        self._moe_counts = (
+            np.zeros((config.n_moe_layers, config.n_routed_experts), np.int64)
+            if self._moe else None)
         # flight recorder: the ring journal every scheduler decision point
         # appends to (admission, waves, page alloc/free, spec/overlap
         # dispatches, deferred retirement, faults).  Appends are O(1)
@@ -1295,9 +1321,18 @@ class InferenceEngine:
 
         "pallas" / "pallas_interpret" waive the platform test alone, as
         they do for the read; they NAME the attention kernel, so a state
-        outside the rule is served by XLA and not refused."""
+        outside the rule is served by XLA and not refused.
+
+        A Gated DeltaNet layer's state (``config.gdn``) is ALWAYS "xla":
+        the kernel computes Mamba-2's step (``S = a S + x (x) B``, ``y = S
+        C``), not the delta rule, whose update needs ``S^T k`` of the
+        decayed state before it can write.  Its pass is
+        ``gdn.delta_step_xla`` under the ``gdn/state`` scope, where a
+        kernel of its own would land."""
         impl = self.runtime.attention_impl
         c = self.config
+        if c.gdn:
+            return "xla"
         if not self._recurrent or impl == "xla" or (
             impl == "auto" and jax.devices()[0].platform != "tpu"
         ):
@@ -3642,12 +3677,31 @@ class InferenceEngine:
             self._state = came_back.pop(0)
         return came_back.pop(0) if self._moe else None
 
-    def _note_moe(self, counts: Any, hit: Any, decode: bool = False) -> None:
+    def moe_expert_counts(self) -> "np.ndarray | None":
+        """[expert layers, held experts] int64: the REAL tokens each held
+        expert of each layer was sent since the engine started (a copy; None
+        without routed experts).  Which experts are hot, layer by layer; the
+        scalar ``moe_*`` counters are sums over it."""
+        return None if self._moe_counts is None else self._moe_counts.copy()
+
+    def recurrent_state(self) -> "tuple[jax.Array, jax.Array] | None":
+        """The slots' recurrent state as it stands, ``(matrix [layers, slots,
+        ..], conv [layers, taps - 1, slots, channels])`` on the device (None
+        for a model without recurrent layers).  A slot keeps the state its
+        last sequence left until a wave lands in it, so a finished
+        sequence's state can be read back and compared.  Read it on an IDLE
+        engine: a dispatch in flight holds the arrays donated."""
+        return self._state
+
+    def _note_moe(self, counts: Any, hit: Any, absent: Any = 0, decode: bool = False) -> None:
         """Fold one dispatch's expert counters (already on their way to the
-        host with what the landing syncs) into the stats."""
+        host with what the landing syncs) into the stats; ``absent`` is
+        there where the experts are held by share."""
         counts = np.asarray(counts)  # blocking-ok: computed before the sync that just landed
+        self._moe_counts += counts
         stats = self.stats
         stats.moe_assignments += int(counts.sum())
+        stats.moe_assignments_absent += int(absent)
         stats.moe_expert_tokens_max += int(counts.max(axis=1).sum())
         stats.moe_expert_tokens_mean += float(counts.mean(axis=1).sum())
         if decode:

@@ -42,6 +42,26 @@ latent-attention tree (``q_lora_rank`` null):
     mlp.gate.weight [E, D], .e_score_correction_bias → layers.moe.router [D, E], router_bias
     mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [E, ..]
     mlp.shared_experts.{gate,up,down}_proj.weight → layers.moe.s_gate/s_up/s_down
+``model_type`` ``qwen3_next`` loads into the Gated DeltaNet hybrid's tree
+(layer N is the n-th attention or DeltaNet layer by ``full_attention_interval``;
+``mtp.*``, the multi-token-prediction module, skipped and counted with one
+:class:`MtpSkipped` notice):
+    linear_attn.in_proj_qkvz.weight, in_proj_ba.weight → layers.gdn.w_in, ONE
+        matrix with rows q | k | v | z | b | a (HF interleaves them per KEY
+        head: q, k, its value heads' v, their z; b and a likewise)
+    linear_attn.conv1d.weight [C, 1, d_conv]      → conv_w [d_conv, C]
+    linear_attn.{A_log,dt_bias}                   → float32 leaves
+    linear_attn.norm.weight, out_proj.weight      → norm, w_out (transposed)
+    self_attn.q_proj.weight [H 2 hd, D]           → layers.attn.wq [D, H, 2 hd] (q | gate a head)
+    self_attn.{k,v,o}_proj, {q,k}_norm.weight     → wk, wv, wo, q_norm, k_norm
+    mlp.gate.weight [E, D]                        → layers.moe.router [D, E] (ALL the experts)
+    mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [held, ..]
+    mlp.shared_expert.{gate,up,down}_proj.weight, shared_expert_gate.weight
+                                                  → s_gate/s_up/s_down, shared_gate [D]
+A SHARE ``(r, s)`` (``config_from_hf(path, share=...)``) loads what device
+``r`` of ``s`` that share a layer holds: experts ``[r E/s, (r+1) E/s)`` (the
+gate whole) and rows ``[r V/s, (r+1) V/s)`` of the embedding and the head.
+
 HF's rotary embedding for this family pairs ADJACENT columns of the rope
 part (``rope_interleave``) and ``model.apply_rope`` pairs the two halves:
 the rope columns of ``W_q`` (each head's last ``dr``) and of ``W_kva`` (its
@@ -68,12 +88,25 @@ class VisionTowerSkipped(UserWarning):
     decoder is loaded and serves text alone."""
 
 
+class MtpSkipped(UserWarning):
+    """A checkpoint's multi-token-prediction module (``mtp.*``) was left on
+    disk: no program here drafts from it."""
+
+
 # tensor names of a multimodal checkpoint's language decoder start with this
 _LANGUAGE_PREFIX = "language_model."
+_MTP_PREFIX = "mtp."
 
 
-def config_from_hf(path: str | Path) -> ModelConfig:
+def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> ModelConfig:
+    """``share`` ``(r, s)``: the description of what device ``r`` of the ``s``
+    that share a layer holds (``qwen3_next`` alone: its experts and its rows
+    of the vocabulary)."""
     raw = json.loads((Path(path) / "config.json").read_text())
+    if raw.get("model_type") == "qwen3_next":
+        return _qwen3_next_config(raw, str(path), share)
+    if share is not None:
+        raise ValueError(f"{path}: a share is described for qwen3_next alone")
     if raw.get("model_type") == "granitemoehybrid":
         return _granite_hybrid_config(raw, str(path))
     if raw.get("model_type") == "kimi_vl":
@@ -176,6 +209,52 @@ def _deepseek_config(raw: dict, path: str) -> ModelConfig:
     )
 
 
+def _qwen3_next_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
+    """Qwen3-Next's ``config.json`` -> the Gated DeltaNet hybrid's description."""
+    from calfkit_tpu.inference.config import ATTENTION, GDN
+
+    for key, only in (("decoder_sparse_step", 1), ("mlp_only_layers", []),
+                      ("rope_scaling", None), ("use_sliding_window", False)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    E, V = raw["num_experts"], raw["vocab_size"]
+    rank, of = share or (0, 1)
+    if not 0 <= rank < of or E % of or V % of:
+        raise ValueError(f"{path}: share {share} does not divide {E} experts and {V} rows")
+    every = raw.get("full_attention_interval", 4)
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=V // of,
+        d_model=raw["hidden_size"],
+        n_layers=raw["num_hidden_layers"],
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"],
+        d_ff=raw["intermediate_size"],
+        rope_theta=raw.get("rope_theta", 10000.0),
+        norm_eps=raw.get("rms_norm_eps", 1e-6),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=raw.get("tie_word_embeddings", False),
+        layer_types=tuple(ATTENTION if (i + 1) % every == 0 else GDN
+                          for i in range(raw["num_hidden_layers"])),
+        gdn_n_k_heads=raw["linear_num_key_heads"],
+        gdn_n_v_heads=raw["linear_num_value_heads"],
+        gdn_d_k=raw["linear_key_head_dim"],
+        gdn_d_v=raw["linear_value_head_dim"],
+        gdn_d_conv=raw.get("linear_conv_kernel_dim", 4),
+        attn_head_dim=raw["head_dim"],
+        partial_rotary_factor=float(raw.get("partial_rotary_factor", 1.0)),
+        qk_norm=True, attn_output_gate=True, norm_plus_one=True,
+        n_routed_experts=E // of,
+        n_experts_total=E if of > 1 else 0,
+        expert_first=rank * (E // of),
+        n_experts_per_tok=raw["num_experts_per_tok"],
+        n_shared_experts=raw["shared_expert_intermediate_size"] // raw["moe_intermediate_size"],
+        moe_d_ff=raw["moe_intermediate_size"],
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func="softmax", topk_method="greedy", shared_expert_gate=True,
+    )
+
+
 def _open_safetensors(path: Path) -> dict[str, Any]:
     """name -> lazy tensor getter across all shards."""
     from safetensors import safe_open  # ships with transformers
@@ -234,6 +313,15 @@ def load_params(
                 "projector) were not loaded: the language decoder serves text alone"
             ), stacklevel=2)
 
+    mtp = sum(1 for name in files if name.startswith(_MTP_PREFIX))
+    if mtp:
+        import warnings
+
+        warnings.warn(MtpSkipped(
+            f"{path}: {mtp} tensors under {_MTP_PREFIX!r} (the multi-token-prediction "
+            "module) were not loaded: no program drafts from it"
+        ), stacklevel=2)
+
     def get(name: str) -> np.ndarray:
         f = files[name := prefix + name]
         if f not in handles:
@@ -257,6 +345,10 @@ def _build_params(
 
     D, H, K, hd = config.d_model, config.n_heads, config.n_kv_heads, config.head_dim
     L = config.n_layers
+    if config.gdn:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with Gated DeltaNet layers")
+        return _build_gdn_params(config, shardings, get)
     if config.layer_types:
         if quantize is not None:
             raise ValueError("no quantized load for a model with Mamba layers")
@@ -425,6 +517,92 @@ def _build_hybrid_params(config: ModelConfig, shardings: dict[str, Any], get: An
     if not config.tie_embeddings:
         tree["lm_head"] = get("lm_head.weight").T.astype(dtype)
     logger.info("loaded %s params", config.name)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_gdn_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The Gated DeltaNet hybrid's tree from HF Qwen3-Next names (module
+    text); of a share, the experts and the vocabulary rows it holds."""
+    import jax
+
+    from calfkit_tpu.inference.config import ATTENTION
+
+    c = config
+    D, H, K, hd = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    Hk, Hv, dk, dv = c.gdn_n_k_heads, c.gdn_n_v_heads, c.gdn_d_k, c.gdn_d_v
+    per = Hv // Hk  # value heads a key head
+    dtype = np.dtype(c.dtype)
+    attn_at = [i for i, t in enumerate(c.layer_types) if t == ATTENTION]
+    gdn_at = [i for i, t in enumerate(c.layer_types) if t != ATTENTION]
+    everywhere = range(c.n_layers)
+    rank = c.expert_first // c.n_routed_experts
+    rows = slice(rank * c.vocab_size, (rank + 1) * c.vocab_size)
+
+    def stack(layers: Any, name: str, transform: Any, as_type: Any = dtype) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in layers]
+        ).astype(as_type)
+
+    def w_in(i: int) -> np.ndarray:
+        # per key head q | k | its value heads' v | their z, and b | a -> q | k | v | z | b | a
+        qkvz = get(f"model.layers.{i}.linear_attn.in_proj_qkvz.weight").reshape(
+            Hk, 2 * dk + 2 * per * dv, D)
+        ba = get(f"model.layers.{i}.linear_attn.in_proj_ba.weight").reshape(Hk, 2 * per, D)
+        parts = (qkvz[:, :dk], qkvz[:, dk:2 * dk], qkvz[:, 2 * dk:2 * dk + per * dv],
+                 qkvz[:, 2 * dk + per * dv:], ba[:, :per], ba[:, per:])
+        return np.concatenate([p.reshape(-1, D) for p in parts])
+
+    def experts(name: str) -> np.ndarray:
+        held = range(c.expert_first, c.expert_first + c.n_routed_experts)
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T for e in held])
+            for i in everywhere
+        ]).astype(dtype)
+
+    q_out = hd * (2 if c.attn_output_gate else 1)
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight")[rows].astype(dtype),
+        "layers": {
+            "attn": {
+                "wq": stack(attn_at, "self_attn.q_proj.weight",
+                            lambda w: w.T.reshape(D, H, q_out)),
+                "wk": stack(attn_at, "self_attn.k_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wv": stack(attn_at, "self_attn.v_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wo": stack(attn_at, "self_attn.o_proj.weight", lambda w: w.T.reshape(H, hd, D)),
+                "attn_norm": stack(attn_at, "input_layernorm.weight", lambda w: w),
+                "q_norm": stack(attn_at, "self_attn.q_norm.weight", lambda w: w),
+                "k_norm": stack(attn_at, "self_attn.k_norm.weight", lambda w: w),
+            },
+            "gdn": {
+                "w_in": np.stack([w_in(i) for i in gdn_at]).astype(dtype),
+                # HF's depthwise conv1d weight is [C, 1, d_conv]; ours is tap-major
+                "conv_w": stack(gdn_at, "linear_attn.conv1d.weight", lambda w: w[:, 0, :].T),
+                "A_log": stack(gdn_at, "linear_attn.A_log", lambda w: w, np.float32),
+                "dt_bias": stack(gdn_at, "linear_attn.dt_bias", lambda w: w, np.float32),
+                "norm": stack(gdn_at, "linear_attn.norm.weight", lambda w: w),
+                "w_out": stack(gdn_at, "linear_attn.out_proj.weight", lambda w: w.T),
+                "mixer_norm": stack(gdn_at, "input_layernorm.weight", lambda w: w),
+            },
+            "moe": {
+                "router": stack(everywhere, "mlp.gate.weight", lambda w: w.T),
+                "w_gate": experts("gate_proj"),
+                "w_up": experts("up_proj"),
+                "w_down": experts("down_proj"),
+                "s_gate": stack(everywhere, "mlp.shared_expert.gate_proj.weight", lambda w: w.T),
+                "s_up": stack(everywhere, "mlp.shared_expert.up_proj.weight", lambda w: w.T),
+                "s_down": stack(everywhere, "mlp.shared_expert.down_proj.weight", lambda w: w.T),
+                "shared_gate": stack(everywhere, "mlp.shared_expert_gate.weight",
+                                     lambda w: w.reshape(D)),
+                "mlp_norm": stack(everywhere, "post_attention_layernorm.weight", lambda w: w),
+            },
+        },
+        "final_norm": get("model.norm.weight").astype(dtype),
+    }
+    if not c.tie_embeddings:
+        tree["lm_head"] = get("lm_head.weight")[rows].T.astype(dtype)
+    logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
+                c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
+                rows.start, rows.stop - 1)
     return jax.tree.map(jax.device_put, tree, shardings)
 
 

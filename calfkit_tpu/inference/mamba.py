@@ -80,16 +80,11 @@ def init_mamba_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params
 
 def make_recurrent_state(config: ModelConfig, rows: int) -> tuple[jax.Array, jax.Array]:
     """Zeroed (ssm, conv) state for ``rows`` sequences: what a sequence
-    that has seen no token carries."""
-    c = config
-    ssm = jnp.zeros(
-        (c.n_mamba_layers, rows, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state),
-        jnp.dtype(c.state_dtype),
-    )
-    conv = jnp.zeros(
-        (c.n_mamba_layers, c.mamba_d_conv - 1, rows, c.mamba_conv_dim), jnp.dtype(c.dtype)
-    )
-    return ssm, conv
+    that has seen no token carries.  A Gated DeltaNet layer's pair has the
+    same layout (its matrix state is the delta rule's ``S``: gdn.py)."""
+    matrix, conv = config.recurrent_state_shapes(rows)
+    return (jnp.zeros(matrix, jnp.dtype(config.state_dtype)),
+            jnp.zeros(conv, jnp.dtype(config.dtype)))
 
 
 def _in_proj(h: jax.Array, lp: Params, c: ModelConfig):

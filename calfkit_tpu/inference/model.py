@@ -29,6 +29,15 @@ keep K and V (``config.n_kv_layers``); the Mamba layers' conv and SSM state
 travels as ``state = (ssm [Lm, B, H, P, N], conv [Lm, d_conv-1, B, C])``.
 A description without ``layer_types`` never reaches that code.
 
+A Gated DeltaNet hybrid (``"gdn"`` among ``layer_types``: Qwen3-Next) runs
+through the same ``_hybrid_stack``, with ``layers = {"attn": wq (q | gate a
+head) wk wv wo attn_norm q_norm k_norm [La, ..], "gdn": see gdn.py [Lg, ..],
+"moe": see moe.py [L, ..]}``: the recurrent mixer is the delta rule on the
+same state pair, the attention mixer is gated (per-head q and k norms, the
+rotation on the leading part of a head, ``o * sigmoid(gate)``), every norm
+multiplies by ``1 + w``, and EVERY layer's FFN is the expert block, its
+counters threaded through the scan as ``_latent_stack`` threads them.
+
 A latent-attention stack (``config.kv_lora_rank``: DeepSeek-V3's MLA, with
 routed experts after the leading dense layers when ``n_routed_experts``)
 keeps its layers in up to three stacked groups,
@@ -62,6 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from calfkit_tpu.inference.config import ATTENTION, ModelConfig
+from calfkit_tpu.inference.gdn import gdn_chunk, gdn_step, init_gdn_params
 from calfkit_tpu.inference.mamba import (
     init_mamba_params,
     mamba_chunk,
@@ -129,6 +139,33 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
                 "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
         }
 
+    if config.gdn:
+        # a Gated DeltaNet hybrid: norms that multiply by (1 + w) start at
+        # w = 0; W_q gives q | gate a head when the output is gated
+        La = config.n_kv_layers
+        one = jnp.zeros if config.norm_plus_one else jnp.ones
+        q_out = hd * (2 if config.attn_output_gate else 1)
+        layers = {
+            "attn": {
+                "wq": norm_init(keys[1], (La, D, H, q_out), D),
+                "wk": norm_init(keys[2], (La, D, K, hd), D),
+                "wv": norm_init(keys[3], (La, D, K, hd), D),
+                "wo": norm_init(keys[4], (La, H, hd, D), H * hd),
+                "attn_norm": one((La, D), dtype),
+                **({"q_norm": one((La, hd), dtype), "k_norm": one((La, hd), dtype)}
+                   if config.qk_norm else {}),
+            },
+            "gdn": init_gdn_params(config, jax.random.split(keys[1])[0], dtype),
+            "moe": init_moe_params(config, keys[5], dtype),
+        }
+        return {
+            "embed": norm_init(keys[0], (V, D), D),
+            "layers": layers,
+            "final_norm": one((D,), dtype),
+            **({} if config.tie_embeddings else {
+                "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
+        }
+
     if config.layer_types:
         # A hybrid stack: with a TIED head, embedding_multiplier 12 and
         # residual_multiplier 0.22, matrices at 1/sqrt(fan_in) leave the
@@ -189,12 +226,16 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
 # --------------------------------------------------------------------------- #
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float, plus_one: bool = False) -> jax.Array:
+    """``plus_one``: the norm multiplies by ``1 + w`` (``config.norm_plus_one``)."""
     orig_dtype = x.dtype
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     normed = x32 * lax.rsqrt(var + eps)
-    return (normed * weight.astype(jnp.float32)).astype(orig_dtype)
+    weight = weight.astype(jnp.float32)
+    if plus_one:
+        weight = 1.0 + weight
+    return (normed * weight).astype(orig_dtype)
 
 
 def rope_tables(
@@ -264,6 +305,45 @@ def attn_qkv(
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
 
+def gated_attn_qkv(
+    x: jax.Array,  # [B, S, D]
+    lp: Params,  # one gated-attention layer's leaves
+    cos: jax.Array,
+    sin: jax.Array,
+    config: ModelConfig,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None]:
+    """The gated attention's front half (Qwen3-Next) -> (q, k, v, gate):
+    norm -> ``[q | gate]`` a head from W_q, K, V -> RMSNorm over each query
+    and key head -> the rotation on the FIRST ``rotary_dim`` of a head, the
+    rest left as it is.  ``gate`` [B, S, H, hd] is the output gate's logits
+    (None without one): :func:`attn_out_gate` applies it after the read."""
+    c = config
+    eps, plus, hd, rot = c.norm_eps, c.norm_plus_one, c.head_dim, c.rotary_dim
+    with jax.named_scope("qkv"):
+        h = rms_norm(x, lp["attn_norm"], eps, plus)
+        q = jnp.einsum("bsd,dnh->bsnh", h, lp["wq"])
+        k = jnp.einsum("bsd,dkh->bskh", h, lp["wk"])
+        v = jnp.einsum("bsd,dkh->bskh", h, lp["wv"])
+        q, gate = (q[..., :hd], q[..., hd:]) if c.attn_output_gate else (q, None)
+    if c.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, lp["q_norm"], eps, plus)
+            k = rms_norm(k, lp["k_norm"], eps, plus)
+    if cos is not None:
+        q = jnp.concatenate([apply_rope(q[..., :rot], cos, sin), q[..., rot:]], axis=-1)
+        k = jnp.concatenate([apply_rope(k[..., :rot], cos, sin), k[..., rot:]], axis=-1)
+    return q, k, v, gate
+
+
+def attn_out_gate(attn: jax.Array, gate: jax.Array | None) -> jax.Array:
+    """``o * sigmoid(gate)`` (float32), before the output projection."""
+    if gate is None:
+        return attn
+    with jax.named_scope("out_gate"):
+        return (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
+            attn.dtype)
+
+
 def _q_scale(config: ModelConfig) -> float | None:
     """``attention_multiplier`` over the cores' own 1/sqrt(head_dim)."""
     if config.attention_multiplier is None:
@@ -271,10 +351,17 @@ def _q_scale(config: ModelConfig) -> float | None:
     return float(config.attention_multiplier) * math.sqrt(config.head_dim)
 
 
+def _hybrid_qkv(x, lp, cos, sin, config: ModelConfig):
+    """A hybrid stack's attention front half -> (q, k, v, gate or None)."""
+    if config.gdn:
+        return gated_attn_qkv(x, lp, cos, sin, config)
+    return (*attn_qkv(x, lp, cos, sin, config.norm_eps, _q_scale(config)), None)
+
+
 def _positions_tables(config: ModelConfig, positions: jax.Array):
     if config.position_embedding == "none":
         return None, None
-    return rope_tables(positions, config.head_dim, config.rope_theta)
+    return rope_tables(positions, config.rotary_dim, config.rope_theta)
 
 
 def _embed(params: Params, config: ModelConfig, tokens: jax.Array) -> jax.Array:
@@ -317,11 +404,11 @@ def mlp_residual(x: jax.Array, lp: Params, eps: float, residual: float = 1.0) ->
 
 
 def lm_logits(
-    x: jax.Array, params: Params, eps: float, scaling: float = 1.0
+    x: jax.Array, params: Params, eps: float, scaling: float = 1.0, plus_one: bool = False
 ) -> jax.Array:
     """Final norm + (tied or untied) LM head; ``scaling`` divides the logits."""
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], eps)
+        x = rms_norm(x, params["final_norm"], eps, plus_one)
         head = params.get("lm_head")
         if head is None:
             logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
@@ -332,6 +419,15 @@ def lm_logits(
         return logits
 
 
+def _layer(group: Params, i: Any) -> Params:
+    """ONE layer's leaves, sliced from a stacked group where they are used:
+    XLA reads such a slice in place, inside the matmul that takes it.
+    Scanning over the period's layers as ``xs`` instead made it copy a
+    period's weights before every use (compiled for the v5e: 1.5 GB written
+    and read again a period)."""
+    return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), group)
+
+
 def _hybrid_stack(
     config: ModelConfig,
     layers: Params,
@@ -339,43 +435,40 @@ def _hybrid_stack(
     carry: Any,
     attn_layer: Any,  # (carry, x, lp, ia) -> (carry, attn [B, S, H, hd])
     mamba_layer: Any,  # (carry, h, lp, im) -> (carry, y [B, S, D])
-) -> tuple[jax.Array, Any]:
+    stats: Any = None,  # moe.py's counters (a stack with routed experts), or None
+    valid: jax.Array | None = None,  # [B, S] bool: the tokens that are real
+) -> tuple[jax.Array, Any, Any]:
     """Run a hybrid stack: ONE ``lax.scan`` over the repeats of the layer
     period, the period's layers unrolled in its body, so compile time
     follows the period and not the depth.  ``ia`` / ``im`` are the layer's
-    index among the attention / Mamba layers (traced), which is where its
-    K and V / its recurrent state live in ``carry``.  The decode step and
-    the prefill chunk differ only in the two callbacks."""
+    index among the attention / recurrent (Mamba-2 or Gated DeltaNet)
+    layers (traced), which is where its K and V / its recurrent state live
+    in ``carry``.  The decode step and the prefill chunk differ only in the
+    two callbacks.  A stack whose FFN is the expert block (``config.moe``)
+    threads the experts' counters through the scan and returns them third."""
     eps, rm = config.norm_eps, config.residual_multiplier
     period = config.layer_period
     n = config.n_layers // len(period)
     a_per = period.count(ATTENTION)
     m_per = len(period) - a_per
-
-    def layer(group: str, i: jax.Array) -> Params:
-        # ONE layer's leaves, sliced from the whole stack where they are
-        # used: XLA reads such a slice in place, inside the matmul that
-        # takes it.  Scanning over the period's layers as ``xs`` instead
-        # made it copy a period's weights before every use (compiled for
-        # the v5e: 1.5 GB written and read again a period).
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), layers[group]
-        )
+    if config.gdn:
+        return _gdn_hybrid_stack(
+            config, layers, x, carry, attn_layer, mamba_layer, stats, valid)
 
     def body(c, p):
         x, carry = c
         ja = jm = 0
         for j, kind in enumerate(period):
-            mlp_lp = layer("mlp", p * len(period) + j)
+            mlp_lp = _layer(layers["mlp"], p * len(period) + j)
             if kind == ATTENTION:
                 ia = p * a_per + ja
-                lp = {**layer("attn", ia), **mlp_lp}
+                lp = {**_layer(layers["attn"], ia), **mlp_lp}
                 carry, attn = attn_layer(carry, x, lp, ia)
                 x = attn_out_mlp(x, attn, lp, eps, rm)
                 ja += 1
             else:
                 im = p * m_per + jm
-                lp = layer("mamba", im)
+                lp = _layer(layers["mamba"], im)
                 with jax.named_scope("mamba"):
                     h = rms_norm(x, lp["mixer_norm"], eps)
                     carry, y = mamba_layer(carry, h, lp, im)
@@ -385,7 +478,50 @@ def _hybrid_stack(
         return (x, carry), None
 
     (x, carry), _ = lax.scan(body, (x, carry), jnp.arange(n, dtype=jnp.int32))
-    return x, carry
+    return x, carry, stats
+
+
+def _gdn_hybrid_stack(config, layers, x, carry, attn_layer, gdn_layer, stats, valid):
+    """:func:`_hybrid_stack` for a Gated DeltaNet hybrid: the same one scan
+    over the period's repeats with its layers unrolled in the body; the
+    norms multiply by ``1 + w``, the recurrent mixer reads under ``gdn``, and
+    every layer's FFN is the expert block -> (x, carry, stats)."""
+    eps, plus = config.norm_eps, config.norm_plus_one
+    period = config.layer_period
+    a_per = period.count(ATTENTION)
+    m_per = len(period) - a_per
+
+    def body(c, p):
+        x, carry, stats = c
+        ja = jm = 0
+        for j, kind in enumerate(period):
+            il = p * len(period) + j
+            if kind == ATTENTION:
+                ia = p * a_per + ja
+                lp = _layer(layers["attn"], ia)
+                carry, attn = attn_layer(carry, x, lp, ia)
+                with jax.named_scope("attn_out"):
+                    x = x + jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
+                ja += 1
+            else:
+                im = p * m_per + jm
+                lp = _layer(layers["gdn"], im)
+                with jax.named_scope("gdn"):
+                    carry, y = gdn_layer(
+                        carry, rms_norm(x, lp["mixer_norm"], eps, plus), lp, im)
+                    x = x + y
+                jm += 1
+            lp = _layer(layers["moe"], il)
+            with jax.named_scope("mlp"):
+                y, stats = moe_ffn(
+                    rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, il)
+                x = x + y
+        return (x, carry, stats), None
+
+    (x, carry, stats), _ = lax.scan(
+        body, (x, carry, stats),
+        jnp.arange(config.n_layers // len(period), dtype=jnp.int32))
+    return x, carry, stats
 
 
 # --------------------------------------------------------------------------- #
@@ -532,14 +668,8 @@ def _latent_stack(
     eps = config.norm_eps
     nd = config.n_dense_layers
 
-    def layer(group: str, i: Any) -> Params:
-        # one layer's leaves sliced where they are used (see _hybrid_stack)
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), layers[group]
-        )
-
     def attend(carry, x, i):
-        lp = layer("attn", i)
+        lp = _layer(layers["attn"], i)
         with jax.named_scope("mla"):
             carry, attn = mixer(carry, x, lp, i)
             with jax.named_scope("attn_out"):
@@ -548,14 +678,14 @@ def _latent_stack(
 
     for i in range(nd):
         carry, x = attend(carry, x, i)
-        x = mlp_residual(x, layer("dense", i), eps)
+        x = mlp_residual(x, _layer(layers["dense"], i), eps)
     if not config.moe:
         return x, carry, stats
 
     def body(c, m):
         x, carry, stats = c
         carry, x = attend(carry, x, nd + m)
-        lp = layer("moe", m)
+        lp = _layer(layers["moe"], m)
         with jax.named_scope("mlp"):
             y, stats = moe_ffn(rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m)
         return (x + y, carry, stats), None
@@ -718,13 +848,12 @@ def forward(
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = _positions_tables(config, positions)
-        q_scale = _q_scale(config)
         if n_valid is None:
             n_valid = jnp.full(tokens.shape[:1], tokens.shape[1], jnp.int32)
 
         def attn_layer(carry, x, lp, ia):
             k_all, v_all, st = carry
-            q, k, v = attn_qkv(x, lp, cos, sin, eps, q_scale)
+            q, k, v, gate = _hybrid_qkv(x, lp, cos, sin, config)
             k_page = _insert_chunk(
                 lax.dynamic_index_in_dim(k_all, ia, 0, keepdims=False), k, insert_at)
             v_page = _insert_chunk(
@@ -734,19 +863,23 @@ def forward(
             )
             k_all = lax.dynamic_update_index_in_dim(k_all, k_page, ia, 0)
             v_all = lax.dynamic_update_index_in_dim(v_all, v_page, ia, 0)
-            return (k_all, v_all, st), attn
+            return (k_all, v_all, st), attn_out_gate(attn, gate)
 
         def mamba_layer(carry, h, lp, im):
             k_all, v_all, st = carry
-            y, st = mamba_chunk(h, lp, st, im, n_valid, config)
+            chunk = gdn_chunk if config.gdn else mamba_chunk
+            y, st = chunk(h, lp, st, im, n_valid, config)
             return (k_all, v_all, st), y
 
-        x, (new_k, new_v, state) = _hybrid_stack(
+        valid = None
+        if config.moe:
+            valid = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] < n_valid[:, None]
+        x, (new_k, new_v, state), moe = _hybrid_stack(
             config, params["layers"], x, (k_pages, v_pages, state),
-            attn_layer, mamba_layer,
+            attn_layer, mamba_layer, moe, valid,
         )
-        logits = lm_logits(x, params, eps, config.logits_scaling)
-        return logits, (new_k, new_v), state
+        logits = lm_logits(x, params, eps, config.logits_scaling, config.norm_plus_one)
+        return (logits, (new_k, new_v), state, *(() if moe is None else (moe,)))
 
     x = params["embed"][tokens]  # [B, S, D] gather
     cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
@@ -837,11 +970,10 @@ def _decode_step_with_ring(
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = _positions_tables(config, positions)
-        q_scale = _q_scale(config)
 
         def attn_layer(carry, x, lp, ia):
             ring_k, ring_v, st = carry
-            q, k, v = attn_qkv(x, lp, cos, sin, eps, q_scale)
+            q, k, v, gate = _hybrid_qkv(x, lp, cos, sin, config)
             slab = k[:, 0].astype(ring_k.dtype)[None, None]
             ring_k = lax.dynamic_update_slice(ring_k, slab, (ia, t, 0, 0, 0))
             slab = v[:, 0].astype(ring_v.dtype)[None, None]
@@ -855,19 +987,23 @@ def _decode_step_with_ring(
                 lax.dynamic_index_in_dim(ring_v, ia, 0, keepdims=False),
                 extra,
             )
-            return (ring_k, ring_v, st), attn
+            return (ring_k, ring_v, st), attn_out_gate(attn, gate)
 
         def mamba_layer(carry, h, lp, im):
             ring_k, ring_v, st = carry
-            y, st = mamba_step(h, lp, st, im, active, config, ssm_impl)
+            if config.gdn:  # its pass over the state is XLA (engine._resolved_ssm_impl)
+                y, st = gdn_step(h, lp, st, im, active, config)
+            else:
+                y, st = mamba_step(h, lp, st, im, active, config, ssm_impl)
             return (ring_k, ring_v, st), y
 
-        x, (ring_k, ring_v, state) = _hybrid_stack(
+        valid = active[:, None] if config.moe and active is not None else None
+        x, (ring_k, ring_v, state), moe = _hybrid_stack(
             config, params["layers"], x, (ring_k, ring_v, state),
-            attn_layer, mamba_layer,
+            attn_layer, mamba_layer, moe, valid,
         )
-        logits = lm_logits(x, params, eps, config.logits_scaling)
-        return logits, (ring_k, ring_v), state
+        logits = lm_logits(x, params, eps, config.logits_scaling, config.norm_plus_one)
+        return (logits, (ring_k, ring_v), state, *(() if moe is None else (moe,)))
 
     x = params["embed"][tokens]
     cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
@@ -1457,7 +1593,7 @@ def decode_step_ring_paged(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source, None,
-        state, active, ssm_impl,
+        state, active, ssm_impl, moe,
     )
 
 

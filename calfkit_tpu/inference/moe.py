@@ -20,7 +20,7 @@ PERF.md section 6, PR 31):
     dense    1.53   1.54   1.72   3.24   6.42  12.78  26.90
     grouped  4.91   6.67   8.85   9.38  10.15  12.01  15.81
 
-- *dense* (decode steps, and chunks to ``_DENSE_MAX_TOKENS`` tokens): every
+- *dense* (decode steps, and chunks to the limit :func:`dense_form` gives): every
   expert for every row, times a weight that is zero outside the chosen.
   To some hundreds of rows it costs what reading the experts costs (1.1 GB
   a layer: 1.35 ms at 819 GB/s), and it needs no sort or gather;
@@ -31,15 +31,60 @@ PERF.md section 6, PR 31):
   fewer FLOPs than the dense form from there.
 
 Layout (stacked on axis 0 over the expert layers):
-    router [Lm, D, E]            the gate, in the activations' type; its
+    router [Lm, D, E scored]     the gate, in the activations' type; its
                                  product is float32 at HIGHEST precision
-    router_bias [Lm, E] float32  e_score_correction_bias
-    w_gate, w_up [Lm, E, D, Fe]; w_down [Lm, E, Fe, D]      routed experts
+    router_bias [Lm, E] float32  e_score_correction_bias (noaux_tc alone)
+    w_gate, w_up [Lm, E, D, Fe]; w_down [Lm, E, Fe, D]      routed experts (the E held)
     s_gate, s_up [Lm, D, Fs]; s_down [Lm, Fs, D]            the shared expert
+    shared_gate [Lm, D]          the shared expert's sigmoid gate (shared_expert_gate)
     mlp_norm [Lm, D]
 
+A Qwen3-Next-style layer (``scoring_func`` "softmax") differs in the gate and
+in the shared expert, and may hold its experts by SHARE:
+
+    p = softmax(float32(h) W_g)                    over ALL the experts scored
+    chosen = top-k of p;  w = p[chosen] / sum p[chosen]      (norm_topk_prob)
+    y = sum_{e held} w_e E_e(h) + sigmoid(h . w_sg) Shared(h)
+
+The gate scores every expert of the layer (``config.experts_scored``) and a
+token's k are chosen among all of them; the products run over the experts
+THIS device holds (``[expert_first, expert_first + n_routed_experts)``), so
+what an absent expert would add to a token is left out, in both forms: the
+other shares' devices add theirs, and the weights are NOT renormalised over
+the held.  The two forms at that model's shape (128 held of 512 scored,
+2048 x 512, 10 a token; one scan over 8 layers on a v5e, ms a layer, as
+above; PERF.md section 6, PR 33):
+
+    tokens      8     64    256    512   1024   2048   4096
+    dense    1.11   1.12   1.28   2.32   4.59   9.14  18.92
+    grouped  2.67   3.48   5.08   5.25   5.80   6.76   9.76
+
+Alone the two cross between 1,024 and 2,048 tokens here too.  But a dense
+CHUNK has a cost that no timing of the product alone shows.  Compiled for
+the v5e, this model's ragged program (64 rows in its decode steps, a chunk
+of 1,024 beside them) with the chunk in the dense form copies the WHOLE of
+``w_gate`` and ``w_up``, every layer, into the chunk product's layout (D
+minor: ``bf16[8,128,2048,512]{2,3,1,0}``, in the entry computation) once a
+dispatch: 2 x 2.15 GB of temporaries and ~10 ms of traffic, against the
+1.2 ms a layer the dense form saves at 1,024.  The first model's program at
+the same two token counts copies nothing (its one-row ragged program
+compiled at all 7 layers: 2.44 GB of temporaries, no array of a stack's
+shape but the scan's slice of a layer).  Which of the two a shape gets is
+the compiler's choice and shows in a compile alone, so the limit is set
+the safe way round:
+
+- to ``_DENSE_MAX_TOKENS`` (512) every shape takes the dense form: there it
+  wins by a factor of two or more in both tables, and it is every decode
+  step's form;
+- from there to where the two forms' times cross the dense form saves 1-4
+  ms a layer and may cost a copy of two stacks, so a shape takes it only if
+  it is listed in ``_DENSE_TO_THE_CROSSING``: its forms TIMED on the chip
+  and its ragged program COMPILED at full depth without that copy.
+
 Counters (``stats``; what :class:`EngineStats` sums as ``moe_*``): a pair
-``(counts [Lm, E] int32, hit [] int32)`` that a dispatch carries through
+``(counts [Lm, E] int32, hit [] int32)``, with a third ``absent [] int32``
+(the real tokens' assignments to experts held elsewhere) when the experts
+are held by share, that a dispatch carries through
 its steps and returns beside its tokens.  ``counts[m, e]`` is the tokens
 layer ``m`` sent to expert ``e``; ``hit`` the distinct experts a call had
 to read, summed over layers (and, by the caller, over steps).  Only REAL
@@ -61,9 +106,12 @@ from calfkit_tpu.inference.config import ModelConfig
 
 Params = dict[str, Any]
 _HI = lax.Precision.HIGHEST  # the gate's float32 product: no bf16 passes
-# the dense form's limit in tokens: the two forms' measured times cross
-# between 1,024 and 2,048 (the table above), and no shape lies between
-_DENSE_MAX_TOKENS = 1536
+# the dense form's limit in tokens for ANY shape: the decode steps' rows and
+# narrow chunks (the module's text)
+_DENSE_MAX_TOKENS = 512
+# (experts held, hidden, expert width) -> the limit of a shape that was timed
+# and compiled: the first table's crossing, between 1,024 and 2,048
+_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536}
 
 
 def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
@@ -79,26 +127,30 @@ def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
         return (jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
     out = {
-        "router": mat(keys[0], (Lm, D, E), D),
-        "router_bias": jnp.zeros((Lm, E), jnp.float32),
+        "router": mat(keys[0], (Lm, D, c.experts_scored), D),
         "w_gate": mat(keys[2], (Lm, E, D, Fe), D),
         "w_up": mat(keys[3], (Lm, E, D, Fe), D),
         "w_down": mat(keys[4], (Lm, E, Fe, D), Fe),
-        "mlp_norm": jnp.ones((Lm, D), dtype),
+        "mlp_norm": jnp.zeros((Lm, D), dtype) if c.norm_plus_one else jnp.ones((Lm, D), dtype),
     }
+    if c.topk_method == "noaux_tc":
+        out["router_bias"] = jnp.zeros((Lm, E), jnp.float32)
     if Fs:
         out.update(
             s_gate=mat(keys[5], (Lm, D, Fs), D),
             s_up=mat(keys[6], (Lm, D, Fs), D),
             s_down=mat(keys[7], (Lm, Fs, D), Fs),
         )
+    if c.shared_expert_gate:
+        out["shared_gate"] = mat(keys[1], (Lm, D), D)
     return out
 
 
-def moe_stats_init(config: ModelConfig) -> tuple[jax.Array, jax.Array]:
+def moe_stats_init(config: ModelConfig) -> tuple[jax.Array, ...]:
     """Zeroed counters of one dispatch (see the module's text)."""
-    return (jnp.zeros((config.n_moe_layers, config.n_routed_experts), jnp.int32),
-            jnp.zeros((), jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    counts = jnp.zeros((config.n_moe_layers, config.n_routed_experts), jnp.int32)
+    return (counts, zero, zero) if config.expert_share else (counts, zero)
 
 
 def route(
@@ -113,6 +165,12 @@ def route(
         "td,de->te", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
         precision=_HI, preferred_element_type=jnp.float32,
     )
+    if c.scoring_func == "softmax":  # over ALL the experts scored; no bias, no scaling
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = lax.top_k(probs, c.n_experts_per_tok)
+        if c.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return chosen.astype(jnp.int32), weights * c.routed_scaling_factor
     scores = jax.nn.sigmoid(logits)
     _, chosen = lax.top_k(scores + lp["router_bias"].astype(jnp.float32), c.n_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)  # the UNBIASED scores
@@ -123,8 +181,10 @@ def route(
 
 def dense_form(tokens: int, config: ModelConfig) -> bool:
     """Which form the expert products take for ``tokens`` rows: dense to
-    where the two measured times cross, grouped beyond."""
-    return tokens <= _DENSE_MAX_TOKENS
+    ``_DENSE_MAX_TOKENS``, and to where the two measured times cross for a
+    shape measured AND compiled (the module's text); grouped beyond."""
+    shape = (config.n_routed_experts, config.d_model, config.moe_d_ff)
+    return tokens <= _DENSE_TO_THE_CROSSING.get(shape, _DENSE_MAX_TOKENS)
 
 
 def _swiglu(h: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array) -> jax.Array:
@@ -147,15 +207,23 @@ def experts_dense(h: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Param
 
 
 def experts_grouped(
-    h: jax.Array, chosen: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Params
+    h: jax.Array, chosen: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Params,
+    share: bool = False,
 ) -> jax.Array:
     """Only the token-expert pairs routed: sorted by expert, one ragged
     product a projection over the groups, unsorted, weighted, summed over a
     token's experts → [T, D].  A group is as long as its expert's tokens
-    are many: nothing is cut to a capacity."""
+    are many: nothing is cut to a capacity.  Of experts held by ``share``,
+    ``onehot`` [T, k, E held] is all zero for a pair whose expert is held
+    elsewhere: those pairs sort behind the last group, which no group's
+    product reaches, and are left out of the sum over a token's experts."""
     T, k = chosen.shape
     with jax.named_scope("group"):
-        flat = chosen.reshape(T * k)
+        if share:
+            held = jnp.any(onehot, axis=-1)  # [T, k]
+            flat = jnp.where(held, jnp.argmax(onehot, axis=-1), onehot.shape[-1]).reshape(T * k)
+        else:
+            flat = chosen.reshape(T * k)
         order = jnp.argsort(flat, stable=True)  # sorted pair -> flat pair
         sizes = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [E] pairs an expert
         rows = h[order // k]  # [T k, D]
@@ -166,7 +234,10 @@ def experts_grouped(
     with jax.named_scope("combine"):
         back = jnp.argsort(order)  # flat pair -> sorted pair
         out = out[back].reshape(T, k, -1).astype(jnp.float32)
-        return jnp.sum(out * weights[..., None], axis=1).astype(h.dtype)
+        out = out * weights[..., None]
+        if share:
+            out = jnp.where(held[..., None], out, 0.0)
+        return jnp.sum(out, axis=1).astype(h.dtype)
 
 
 def moe_ffn(
@@ -180,21 +251,34 @@ def moe_ffn(
     """``sum_e w_e E_e(h) + Shared(h)`` → ([B, S, D], stats)."""
     B, S, D = h.shape
     E = config.n_routed_experts
+    share = config.expert_share
     flat = h.reshape(B * S, D)
     with jax.named_scope("moe"):
         with jax.named_scope("router"):
             chosen, weights = route(flat, lp, config)
-            onehot = chosen[..., None] == jnp.arange(E, dtype=jnp.int32)  # [T, k, E]
+            # one-hot over the HELD experts only: an absent one matches none
+            onehot = chosen[..., None] == (  # [T, k, E]
+                jnp.arange(E, dtype=jnp.int32) + config.expert_first if share
+                else jnp.arange(E, dtype=jnp.int32))
             if stats is not None:
                 real = onehot if valid is None else onehot & valid.reshape(-1, 1, 1)
                 tokens = jnp.sum(real, axis=(0, 1), dtype=jnp.int32)  # [E]
-                counts, hit = stats
+                counts, hit, *absent = stats
                 stats = (counts.at[m].add(tokens), hit + jnp.sum(tokens > 0, dtype=jnp.int32))
+                if share:
+                    n_real = B * S if valid is None else jnp.sum(valid, dtype=jnp.int32)
+                    stats = (*stats, absent[0] + n_real * chosen.shape[-1] - jnp.sum(tokens))
         if dense_form(B * S, config):
             y = experts_dense(flat, onehot, weights, lp)
         else:
-            y = experts_grouped(flat, chosen, onehot, weights, lp)
+            y = experts_grouped(flat, chosen, onehot, weights, lp, share)
         if "s_gate" in lp:
             with jax.named_scope("shared"):
-                y = y + _swiglu(flat, lp["s_gate"], lp["s_up"], lp["s_down"])
+                shared = _swiglu(flat, lp["s_gate"], lp["s_up"], lp["s_down"])
+                if "shared_gate" in lp:
+                    gate = jax.nn.sigmoid(jnp.einsum(
+                        "td,d->t", flat.astype(jnp.float32),
+                        lp["shared_gate"].astype(jnp.float32), precision=_HI))
+                    shared = (shared.astype(jnp.float32) * gate[:, None]).astype(shared.dtype)
+                y = y + shared
     return y.reshape(B, S, D), stats

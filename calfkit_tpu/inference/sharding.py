@@ -31,6 +31,9 @@ MAMBA_LEAVES = ("w_in", "conv_w", "conv_b", "A_log", "D", "dt_bias", "norm", "w_
                 "mixer_norm")
 # the leaves of a latent-attention stack's groups (inference/model.py, moe.py)
 LATENT_ATTN_LEAVES = ("wq", "w_kva", "kv_norm", "w_uk", "w_uv", "wo", "attn_norm")
+# the leaves of one stacked group of Gated DeltaNet layers (inference/gdn.py)
+GDN_LEAVES = ("w_in", "conv_w", "A_log", "dt_bias", "norm", "w_out", "mixer_norm")
+GATED_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
 MLP_LEAVES = ("w_gate", "w_up", "w_down", "mlp_norm")
 MOE_LEAVES = ("router", "router_bias", *MLP_LEAVES)
 SHARED_EXPERT_LEAVES = ("s_gate", "s_up", "s_down")
@@ -105,6 +108,21 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         if config.moe:
             layers["moe"] = dict.fromkeys(
                 MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole)
+    elif config.gdn:
+        # a Gated DeltaNet hybrid (see model.py): every leaf replicated, as
+        # the latent stack's are and for its reason (the engine refuses
+        # such a model on a mesh of more than one device: a share of the
+        # experts is what ONE device holds, config.expert_first)
+        whole = NamedSharding(mesh, P())
+        layers = {
+            "attn": dict.fromkeys(
+                GATED_ATTN_LEAVES[: None if config.qk_norm else -2], whole),
+            "gdn": dict.fromkeys(GDN_LEAVES, whole),
+            "moe": dict.fromkeys(
+                ("router", *MLP_LEAVES)
+                + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ())
+                + (("shared_gate",) if config.shared_expert_gate else ()), whole),
+        }
     elif config.layer_types:
         # a hybrid stack (see model.py): the attention and MLP groups keep
         # the dense layout's specs; the Mamba leaves are replicated (one

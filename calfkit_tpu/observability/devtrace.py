@@ -60,6 +60,11 @@ SCOPES = frozenset({
     # expert layer ("moe" inside "mlp")
     "mla", "q_proj", "kv_latent", "absorb",
     "moe", "router", "group", "experts", "combine", "shared",
+    # gdn.py (inside "gdn", which model.py opens around a Gated DeltaNet
+    # mixer: "in_proj", "conv", "gate_norm", "out_proj" as Mamba-2's, and
+    # "state" for the delta rule's pass over S) and the gated attention's
+    # own steps beside "qkv" and "attention"
+    "gdn", "state", "qk_norm", "out_gate",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

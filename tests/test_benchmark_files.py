@@ -355,7 +355,8 @@ def test_the_manifest_carries_kimi_s_cell_and_its_four_metrics():
     cell = M.resolve_cell(MANIFEST, KIMI_CELL, M.ROOT)
     assert cell.chips == 1 and cell.params == {"callers": 64}
     assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
-    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [KIMI_CELL]}
+    own = {m["name"]: m for m in MANIFEST["per_layer"]  # those that came with the cell
+           if m.get("workloads", [None])[0] == KIMI_CELL}
     assert set(own) == {"moe_device_pct", "moe_expert_roofline", "mla_cache_roofline",
                         "moe_expert_load_ratio"}
     assert {own[n]["moves"] for n in own} == {"tpot_p95_ms", "out_tok_s_per_chip"}
@@ -366,3 +367,211 @@ def test_the_manifest_carries_kimi_s_cell_and_its_four_metrics():
     law = cell.traffic["prompt_tokens"]
     assert (law["median"], law["sigma"], law["min"], law["max"]) == (1024, 0.6, 256, 3072)
     assert cell.traffic["output_tokens"]["values"] == [128, 256, 512]
+
+
+# --------------------------------- qwen3-next-80b-a3b-instruct (PR 33)
+QWEN, QWEN_CELL = "qwen3-next-80b-a3b-instruct", "qwen3-next-80b-a3b-instruct.history-closed"
+
+
+def test_qwen_s_counts_are_what_a_hand_reckons():
+    """The cut's bytes as ISSUE 33 reckons them, and the counts of THIS
+    chip's share: the held experts hit, the state read and written, the
+    live K and V."""
+    arch = M.load_architecture("qwen3-next-gdn-moe")
+    config = config_file(QWEN)
+    assert arch.weight_bytes(config) == 2 * config["parameters"] == 7_334_502_656
+    assert arch.weight_bytes(config) == config["hbm"]["weights_bytes"]
+    expert = 3 * 2048 * 512
+    assert config["hbm"]["experts_bytes"] == 8 * 128 * expert * 2 == 6_442_450_944
+    assert arch.state_bytes_per_token(config) == config["hbm"]["kv_bytes_per_token"] == 4096
+    assert arch.recurrent_state_bytes(config, 1) == 12_877_824
+    assert arch.recurrent_state_bytes(config, 64) == config["hbm"]["recurrent_state_bytes"]
+    step = arch.recurrent_state_step(config, 56)
+    assert step["bytes"] == 2 * 56 * 12_877_824 and step["flops"] == 8 * 56 * 6 * 32 * 128 * 128
+    assert arch.experts_hit(config, 64) == pytest.approx(128 * (1 - (1 - 10 / 512) ** 64))
+    assert 91 < arch.experts_hit(config, 64) < 93
+    layer = arch.expert_layer_step(config, 64, 92.0)
+    gate = 2048 * 512 + 2048
+    assert layer["bytes"] == (92 * expert + expert + gate) * 2
+    assert layer["flops"] == 2 * 64 * (2.5 * expert + expert + gate)  # 10 x 128 / 512 lie here
+    whole = arch.decode_step(config, 64, 1400)
+    assert whole["bytes"] < arch.weight_bytes(config) + 2 * 64 * 12_877_824 + 4096 * 64 * 1400
+    assert whole["bytes"] > 0.6 * arch.weight_bytes(config)  # 92 of 128 experts, no embedding
+    chunk = arch.prefill_chunk(config, 4, 1024, 0)
+    assert chunk["flops"] > 2 * 4096 * 8 * 3.5 * expert and chunk["bytes"] > 0
+
+
+def test_the_program_s_description_of_qwen_is_the_file_s():
+    arch = M.load_architecture("qwen3-next-gdn-moe")
+    config = config_file(QWEN)
+    described, runtime = arch.model(config, False)
+    assert described.param_count == config["parameters"] == 3_667_251_328
+    assert config["published"] == {"num_hidden_layers": 48, "num_experts": 512,
+                                   "vocab_size": 151936}
+    assert "4 chips share a layer" in config["deployment"]
+    assert sorted(config["reduced"]) == ["num_experts", "num_hidden_layers", "vocab_size"]
+    assert (described.n_routed_experts, described.experts_scored, described.expert_first,
+            described.n_experts_per_tok) == (128, 512, 0, 10)
+    assert described.layer_types == ("gdn", "gdn", "gdn", "attention") * 2
+    assert (described.head_dim, described.rotary_dim, described.n_heads, described.n_kv_heads) == (
+        256, 64, 16, 2)
+    assert described.state_dtype == "float32" and described.dtype == "bfloat16"
+    assert described.recurrent_state_bytes(64) == config["hbm"]["recurrent_state_bytes"]
+    assert (runtime.max_batch_size, runtime.max_seq_len, runtime.prefill_chunk,
+            runtime.max_prefill_wave, runtime.prefix_cache) == (64, 4096, 1024, 4, False)
+    assert runtime.pool_pages() * 64 * 4096 == 4097 * config["hbm"]["page_bytes"]
+    from calfkit_tpu.inference.config import preset
+
+    published = preset("qwen3-next-80b-a3b-instruct")
+    assert published.param_count == config["published_parameters"]
+    toy, _ = arch.model(config, True)
+    assert toy.expert_share and toy.layer_types == described.layer_types
+
+
+def test_qwen_s_reference_opens_no_branch_for_a_tie_among_absent_experts():
+    """``routing_tie.routings`` with the share (the rule's one helper file,
+    which ``qwen3-next-gdn-moe.py`` imports): a doubt between two experts of
+    which one is held here has two routings; the same doubt between two
+    experts that are both held elsewhere has one."""
+    import numpy as np
+
+    from benchmarks import routing_tie
+
+    logits = np.asarray([
+        [0.9, 0.8, 0.799, 0.4, 0.3, 0.2, 0.1, 0.0],    # 1 or 2 beside 0: both held (0-3)
+        [0.1, 0.0, 0.2, 0.3, 0.9, 0.8, 0.799, 0.4],    # 5 or 6 beside 4: all absent
+        [0.8, 0.0, 0.2, 0.3, 0.9, 0.1, 0.799, 0.4],    # 0 (held) or 6 (absent) beside 4
+    ], np.float32)
+    parent, chosen, first, crowded = routing_tie.routings(logits, 2, 0.004, (0, 4))
+    sets = [{tuple(np.flatnonzero(c)) for c, p in zip(chosen, parent) if p == n} for n in range(3)]
+    assert sets == [{(0, 1), (0, 2)}, {(4, 5)}, {(0, 4), (4, 6)}]
+    assert not crowded.any() and [int(first[parent == n].sum()) for n in range(3)] == [1, 1, 1]
+
+
+@pytest.mark.parametrize("fault", ["jitter_inside_the_tie", "renormalised_over_the_held",
+                                   "shared_gate_left_out", "none"])
+def test_qwen_s_reference_follows_a_near_tie_and_catches_a_wrong_layer(fault):
+    """The rule of the architecture file at toy size, float32 on both sides,
+    through DeltaNet and attention layers alike.  A program whose gate sees
+    logits off by LESS than the tie serves tokens the reference accepts at
+    every position it decides; a program that renormalises the weights over
+    the held experts, or leaves the shared expert's gate out, serves tokens
+    that no admitted routing gives, and fails."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.reference import agreement
+    from calfkit_tpu.inference import model as program
+    from calfkit_tpu.inference import moe
+    from calfkit_tpu.inference.config import RuntimeConfig
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+    from calfkit_tpu.inference.sharding import make_mesh
+
+    arch = M.load_architecture("qwen3-next-gdn-moe")
+    toy, _ = arch.model(config_file(QWEN), True)
+    toy = dataclasses.replace(toy, dtype="float32", agreement_new_tokens=24, routing_tie=0.05,
+                              agreement_margin=0.25)
+    params = arch.params(toy, RuntimeConfig(), make_mesh(tp=1, dp=1, devices=jax.devices()[:1]), 5)
+    assert 0.9 < float(jnp.std(params["embed"])) < 1.1  # the token's own row at unit scale
+    assert 0.02 < float(jnp.abs(params["final_norm"]).mean()) < 0.1  # w of (1 + w), NOT zero
+    right, right_ffn = moe.route, program.moe_ffn
+
+    def jitter(h, lp, c):  # logits off by up to 0.02: under half the tie either way
+        noise = jax.random.uniform(jax.random.key(0), (c.experts_scored,), jnp.float32, -0.02, 0.02)
+        logits = h.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+        _, chosen = jax.lax.top_k(logits + noise, c.n_experts_per_tok)
+        w = jnp.take_along_axis(jax.nn.softmax(logits, -1), chosen, axis=-1)
+        return chosen.astype(jnp.int32), w / w.sum(-1, keepdims=True)
+
+    def over_held(h, lp, c):
+        chosen, w = right(h, lp, c)
+        held = (chosen >= c.expert_first) & (chosen < c.expert_first + c.n_routed_experts)
+        kept = jnp.where(held, w, 0.0)
+        return chosen, kept / jnp.maximum(kept.sum(-1, keepdims=True), 1e-20)
+
+    if fault == "jitter_inside_the_tie":
+        moe.route = jitter
+    elif fault == "renormalised_over_the_held":
+        moe.route = over_held
+    elif fault == "shared_gate_left_out":
+        program.moe_ffn = lambda h, lp, *a, **kw: right_ffn(
+            h, {n: w for n, w in lp.items() if n != "shared_gate"}, *a, **kw)
+    rng = np.random.default_rng(1)
+    prompts = [[int(t) for t in rng.integers(3, toy.vocab_size, n)] for n in (9, 14, 20, 27)]
+    S = 27 + 24
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (1, S))
+    try:
+        forward = jax.jit(lambda tokens: program.forward(
+            params, toy, tokens, pos, program.make_empty_cache(toy, 1, S),
+            jnp.full((1,), S, jnp.int32), state=make_recurrent_state(toy, 1))[0])
+        outs = []
+        for prompt in prompts:
+            seq = list(prompt)
+            for _ in range(24):  # causal: the padding after a position moves nothing before it
+                tokens = np.zeros((1, S), np.int32)
+                tokens[0, :len(seq)] = seq
+                seq.append(int(np.argmax(np.asarray(forward(jnp.asarray(tokens)))[0, len(seq) - 1])))
+            outs.append(seq[len(prompt):])
+    finally:
+        moe.route, program.moe_ffn = right, right_ffn
+    result = agreement(arch.forward_top2, params, toy, prompts, outs, toy.agreement_margin, 8)
+    if fault in ("none", "jitter_inside_the_tie"):
+        assert result["ok"] and result["compared"] >= 24, result
+    else:
+        assert not result["ok"] and result["compared"] - result["equal"] >= 3, result
+
+
+def test_the_moe_readers_take_qwen_s_architecture_as_they_stand():
+    """The three ``moe_*`` readers read the new cell through
+    ``expert_layer_step`` and the counters' names, unedited; the share's
+    least time counts the HELD experts hit and stays under 100%."""
+    from types import SimpleNamespace
+
+    read = {n: M.load_reader(n) for n in (
+        "moe_device_pct", "moe_expert_roofline", "moe_expert_load_ratio", "ssm_state_roofline",
+        "mla_cache_roofline")}
+    steps, rows = 40, 56
+    run = SimpleNamespace(
+        trace_reduced={"busy_s": 2.0, "by_scope": {
+            "decode_loop/mlp/moe/experts": 0.5, "decode_loop/mlp/moe/combine": 0.3,
+            "chunk_loop/mlp/moe/experts": 0.2, "decode_loop/gdn/state": 0.3},
+            "own_by_op": {"(unscoped) ragged-dot-none": 0.2}},
+        trace_counters={"decode_tokens": rows * steps, "decode_dispatches": 5,
+                        "short_dispatches": 0, "moe_experts_hit": 85 * 8 * steps,
+                        "decode_pages_live": rows * steps * 21.0},
+        counters={"window": {"moe_expert_tokens_max": 300, "moe_expert_tokens_mean": 100.0}},
+        arch=M.load_architecture("qwen3-next-gdn-moe"), config=config_file(QWEN), chips=1,
+        model_config=SimpleNamespace(n_moe_layers=8, n_layers=8),
+        runtime=SimpleNamespace(decode_steps_per_dispatch=8, page_size=64),
+        peaks=M.load_peaks("TPU v5 lite"))
+    assert read["moe_device_pct"](run) == pytest.approx(60.0)
+    assert read["moe_expert_load_ratio"](run) == pytest.approx(3.0)
+    expert = 3 * 2048 * 512 * 2
+    layer_step = (85 * expert + expert + (2048 * 512 + 2048) * 2) / 819e9
+    assert read["moe_expert_roofline"](run) == pytest.approx(100 * layer_step * 8 * steps / 0.8)
+    assert read["moe_expert_roofline"](run) < 100
+    # granite's and Kimi's own readers find nothing of theirs in this cell
+    assert read["ssm_state_roofline"](run) is None and read["mla_cache_roofline"](run) is None
+
+
+def test_the_manifest_carries_qwen_s_cell_and_its_two_metrics():
+    cell = M.resolve_cell(MANIFEST, QWEN_CELL, M.ROOT)
+    assert cell.chips == 1 and cell.params == {"callers": 64}
+    assert cell.traffic_name == "history-closed"
+    assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
+    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [QWEN_CELL]}
+    assert set(own) == {"gdn_device_pct", "gdn_state_roofline"}
+    assert {own[n]["moves"] for n in own} == {"tpot_p95_ms"}
+    registered = {m.name for m in cell.per_layer}
+    assert {"gdn_device_pct", "gdn_state_roofline", "moe_device_pct", "moe_expert_roofline",
+            "moe_expert_load_ratio", "dispatch_roofline", "hbm_peak_gb", "batch_occupancy_pct",
+            "empty_slot_queued_pct", "kv_pages_peak_pct"} <= registered
+    assert not {"ssm_state_roofline", "ssm_device_pct", "mla_cache_roofline"} & registered
+    assert [m["name"] for m in MANIFEST["per_layer"]][-2:] == ["gdn_device_pct", "gdn_state_roofline"]
+    assert MANIFEST["workloads"][-1]["name"] == QWEN_CELL and len(MANIFEST["workloads"]) == 4
+    entry = MANIFEST["configs"][-1]
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0

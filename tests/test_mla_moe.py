@@ -113,8 +113,8 @@ class Spy:
         self.seen: list[np.ndarray] = []
         original = M.lm_logits
 
-        def spied(x, params, eps, scaling=1.0):
-            logits = original(x, params, eps, scaling)
+        def spied(x, params, eps, *rest):
+            logits = original(x, params, eps, *rest)
             jax.debug.callback(lambda l: self.seen.append(np.asarray(l)), logits, ordered=True)
             return logits
 

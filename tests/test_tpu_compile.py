@@ -36,6 +36,9 @@ DECODE_PAGED_WIDTHS = {
     # heads of 64: two positions a lane row (pallas_attention.lane_dense_pool)
     "granite-4.0-h-micro": (8, 4, 64, 64, 2048, 1281, 4),
     "tinyllama-1.1b": (4, 8, 64, 64, 1024, 257, 2),
+    # a head of 256 (two lane tiles) with 8 query heads a KV head: the two
+    # gated-attention layers of the cut (PR 33), first run through the kernel here
+    "qwen3-next-80b-a3b-instruct": (2, 8, 256, 64, 4096, 4097, 2),
     "llama-3-8b": (8, 4, 128, 64, 1024, 257, 2),
 }
 
@@ -541,7 +544,7 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     there, and multiplies every expert; the rope side's view is made once a
     dispatch, in the entry computation, and nothing of the c side's size is
     made in a loop's body; the chunk that rides along groups its tokens when
-    it is wider than ``moe._DENSE_MAX_TOKENS`` (two rows of 1,024: three
+    it is wider than ``moe.dense_form``'s limit for this shape, 1,536 (two rows of 1,024: three
     ``ragged-dot`` kernels an expert layer; one row takes the dense form, as
     the decode step does: the chip's readings in ``moe.py``); the two parts
     of the pool go out where they came in."""
@@ -642,3 +645,139 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     print("temporaries, bytes: decode", decode.memory_analysis().temp_size_in_bytes,
           "ragged x2", ragged.memory_analysis().temp_size_in_bytes,
           "ragged x1", narrow.memory_analysis().temp_size_in_bytes)
+
+
+# Gated DeltaNet beside gated attention, the experts held by share
+# (qwen3-next-80b-a3b-instruct): the paged decode read of a head of 256 in
+# the two attention layers, the state's pass as XLA in place, the expert
+# stacks read where they lie
+# ---------------------------------------------------------------------------
+
+
+def _gdn_cell_engine(held: int | None = None):
+    """The engine of the cell's configuration at its published WIDTHS, its 8
+    layers (TWO periods: a scan that could copy a period's weights) and its
+    runtime; ``held`` experts of the 512 where the test has no use for all
+    128 (the gate keeps its 512 outputs)."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "qwen3-next-80b-a3b-instruct.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    assert config.layer_types == ("gdn", "gdn", "gdn", "attention") * 2
+    if held is not None:
+        config = replace(config, n_routed_experts=held)
+    assert InferenceEngine(
+        replace(config, n_layers=4, layer_types=config.layer_types[:4], n_routed_experts=8),
+        replace(runtime, compilation_cache=False, max_batch_size=2, num_kv_pages=129),
+    )._attn_impl == "xla"  # "auto" on this process's CPU: the reference path
+    engine = InferenceEngine(
+        config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "xla")
+    return engine
+
+
+def _gdn_decode_checks(engine, compiled):
+    """One paged decode read (the period's one attention layer, in the
+    scan's body) under ``attention``; no window gathered; the state's pass
+    under ``gdn/state`` and no Pallas kernel of its own; the stacked state
+    and the pool go out where they came in; NO copy of an expert stack, of
+    a layer of it, or of the stacked state (the temporaries are under one
+    layer's state plus the pool's layout copy around the consolidation
+    scatter, which every paged cell pays)."""
+    import re
+
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "paged_decode_attention" in kernels[0], kernels
+    assert "/attention/" in kernels[0] and "decode_loop/" in kernels[0]
+    assert "gather_window" not in hlo and "/gdn/state/" in hlo and "/mlp/moe/experts" in hlo
+    cfg, rt = engine.config, engine.runtime
+    E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
+    stacks = re.compile(
+        rf"= bf16\[(?:{cfg.n_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
+    assert not stacks.search(hlo)
+    memory = compiled.memory_analysis()
+    state_bytes = cfg.recurrent_state_bytes(rt.max_batch_size)
+    pool_bytes = engine._k.nbytes + engine._v.nbytes
+    assert memory.alias_size_in_bytes >= state_bytes + pool_bytes
+    assert memory.temp_size_in_bytes < state_bytes // cfg.n_recurrent_layers + pool_bytes
+    return memory
+
+
+def test_gdn_expert_cell_decode_program_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The decode dispatch of the new cell at its published widths, 8 layers
+    and 64 slots (8 of the 128 held experts: the products' shapes but not
+    6 GB of them), compiled for the described v5e."""
+    import jax
+
+    engine = _gdn_cell_engine(held=8)
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    args, window, steps, sampled = engine._decode_args()
+    assert window == 4096 == engine.runtime.max_seq_len
+    compiled = engine._decode_jit(window, steps, sampled).lower(
+        *abstract(args), state=abstract(engine._state), moe=abstract(engine._moe_zero)).compile()
+    memory = _gdn_decode_checks(engine, compiled)
+    print("temporaries, bytes: decode (8 held experts)", memory.temp_size_in_bytes)
+
+
+@pytest.mark.slow  # 7.3 GB of weights and four whole-program compiles on every core (2 min): the
+# offline lane runs it, as it runs the latent cell's; PERF.md section 6, PR 33 has its readings
+def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
+    """The decode dispatch and the ragged programs (a wave of 1, 2 and 4
+    rows of 1,024) of the new cell at its FULL size: all 128 held experts of
+    8 layers.  No period's weights are copied: a one-row chunk in the dense
+    form copied the whole of ``w_gate`` and ``w_up`` into another layout
+    (2 x 2.15 GB a dispatch), which is why chunks of this shape take the
+    grouped form; the chunk's delta rule solves its triangular systems; and
+    arguments and temporaries together leave the 16 GB chip 3 GB of room."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import moe
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+
+    engine = _gdn_cell_engine()
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    args, window, steps, sampled = engine._decode_args()
+    zero = engine._moe_zero
+    decode = engine._decode_jit(window, steps, sampled).lower(
+        *abstract(args), state=abstract(engine._state), moe=abstract(zero)).compile()
+    report = {"decode": _gdn_decode_checks(engine, decode).temp_size_in_bytes}
+    chunk = rt.prefill_chunk
+    assert not moe.dense_form(chunk, cfg) and moe.dense_form(rt.max_batch_size, cfg)
+    for rows in (1, 2, 4):
+        scratch = [jax.ShapeDtypeStruct(
+            (cfg.n_kv_layers, rows, cfg.cache_heads, 2 * chunk, width), engine._k.dtype)
+            for width in cfg.cache_dims]
+        wave = [*scratch, jax.ShapeDtypeStruct((rows, chunk), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32)]
+        ragged = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
+            *abstract((*args, *wave)), state=abstract(engine._state),
+            wstate=abstract(jax.eval_shape(lambda: make_recurrent_state(cfg, rows))),
+            true_lens=abstract(jax.ShapeDtypeStruct((rows,), jnp.int32)),
+            moe=abstract(zero), wmoe=abstract(zero)).compile()
+        hlo = ragged.as_text()
+        assert "decode_loop/" in hlo and "chunk_loop/" in hlo and "ragged-dot" in hlo
+        assert "chunk_loop/" in hlo and "/gdn/state/" in hlo
+        assert not any("copy(" in line and "bf16[8,128,2048,512]" in line.split("copy(")[0]
+                       for line in hlo.splitlines())
+        memory = ragged.memory_analysis()
+        report[f"ragged x{rows}"] = memory.temp_size_in_bytes
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9
+    print("temporaries, bytes:", report)
