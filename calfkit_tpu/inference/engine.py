@@ -57,7 +57,7 @@ from calfkit_tpu.inference.config import (
     UnsupportedWithRecurrentLayers,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
-from calfkit_tpu.inference.moe import moe_stats_init
+from calfkit_tpu.inference.moe import dense_form, moe_stats_init
 from calfkit_tpu.observability import capacity, flightrec
 from calfkit_tpu.observability.trace import TRACER, Span, TraceContext
 from calfkit_tpu.observability.metrics import (
@@ -115,7 +115,8 @@ _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "state_rows_landed", "prefix_reuse_declined_recurrent",
     "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
-    "moe_expert_tokens_mean", "moe_experts_hit", *_SECONDS_FIELDS,
+    "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
+    "moe_dense_chunks", *_SECONDS_FIELDS,
 )
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
@@ -259,6 +260,16 @@ def _engine_metrics(
             "calfkit_engine_moe_experts_hit_total",
             "distinct experts a decode step had to read, summed over expert "
             "layers and steps",
+        ),
+        moe_grouped_chunks=reg.counter(
+            "calfkit_engine_moe_grouped_chunks_total",
+            "chunk dispatches whose expert products took the grouped form "
+            "(rows x chunk tokens past moe.dense_form's limit for the shape)",
+        ),
+        moe_dense_chunks=reg.counter(
+            "calfkit_engine_moe_dense_chunks_total",
+            "chunk dispatches whose expert products took the dense form, as "
+            "every decode step's do",
         ),
         latent_cache_bytes=reg.gauge(
             "calfkit_engine_latent_cache_bytes",
@@ -585,13 +596,17 @@ class EngineStats:
     # by share); the busiest and the mean expert's tokens,
     # summed over expert layers and dispatches (their ratio is the
     # routing's imbalance); distinct experts the decode steps had to read,
-    # summed over layers and steps.  And, a gauge, the device bytes of a
+    # summed over layers and steps; chunk dispatches by the form
+    # ``moe.dense_form`` gave their rows x chunk tokens (counted on the host
+    # at enqueue, from shapes).  And, a gauge, the device bytes of a
     # latent (MLA) page pool.
     moe_assignments: int = 0
     moe_assignments_absent: int = 0
     moe_expert_tokens_max: int = 0
     moe_expert_tokens_mean: float = 0.0
     moe_experts_hit: int = 0
+    moe_grouped_chunks: int = 0
+    moe_dense_chunks: int = 0
     latent_cache_bytes: int = 0
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
@@ -3653,11 +3668,16 @@ class InferenceEngine:
         goes by place before them): zeroed counters for its decode steps
         (never donated: the same zeros every dispatch) and, for a chunk of
         the wave ``inf``, the wave's counters so far with its rows' true
-        lengths."""
+        lengths.  Called once for every chunk it enqueues, so the chunk's
+        form is counted here, from the shapes alone."""
         if not self._moe:
             return {}
         kw = {"moe": self._moe_zero} if decode else {}
         if inf is not None:
+            if dense_form(len(inf["wave"]) * inf["chunk"], self.config):
+                self.stats.moe_dense_chunks += 1
+            else:
+                self.stats.moe_grouped_chunks += 1
             kw["wmoe"] = inf["wmoe"]
             if not self._recurrent:
                 kw["true_lens"] = jnp.asarray(inf["arrays"]["true_lens"])

@@ -514,7 +514,8 @@ def _gdn_hybrid_stack(config, layers, x, carry, attn_layer, gdn_layer, stats, va
             lp = _layer(layers["moe"], il)
             with jax.named_scope("mlp"):
                 y, stats = moe_ffn(
-                    rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, il)
+                    rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, il,
+                    layers["moe"])
                 x = x + y
         return (x, carry, stats), None
 
@@ -687,7 +688,8 @@ def _latent_stack(
         carry, x = attend(carry, x, nd + m)
         lp = _layer(layers["moe"], m)
         with jax.named_scope("mlp"):
-            y, stats = moe_ffn(rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m)
+            y, stats = moe_ffn(
+                rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m, layers["moe"])
         return (x + y, carry, stats), None
 
     (x, carry, stats), _ = lax.scan(

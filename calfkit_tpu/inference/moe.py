@@ -14,11 +14,11 @@ The expert products take one of two forms, chosen from the shapes alone
 (:func:`dense_form`), and the two must agree.  Each wins on its own side:
 one scan over the 6 expert layers of 64 experts of 2048 x 1408, 6 a token,
 on a v5e, in ms a layer (the gate, the products and the shared expert;
-PERF.md section 6, PR 31):
+PERF.md section 6, PRs 31 and 34):
 
     tokens      8     64    256    512   1024   2048   4096
-    dense    1.53   1.54   1.72   3.24   6.42  12.78  26.90
-    grouped  4.91   6.67   8.85   9.38  10.15  12.01  15.81
+    dense    1.55   1.56   1.73   3.23   6.40  12.78  26.80
+    grouped  1.80   3.86   5.66   6.07   6.83   8.60  12.41
 
 - *dense* (decode steps, and chunks to the limit :func:`dense_form` gives): every
   expert for every row, times a weight that is zero outside the chosen.
@@ -27,8 +27,15 @@ PERF.md section 6, PR 31):
 - *grouped* (wider chunks): the token-expert pairs sorted by expert and
   ``lax.ragged_dot`` over the groups, so only the pairs routed are
   multiplied (the v5e's compiler lowers it to one kernel over the sorted
-  rows).  It pays 5-6 ms a layer however few the rows, and 64/6 times
-  fewer FLOPs than the dense form from there.
+  rows), 64/6 times fewer FLOPs than the dense form.  The kernel takes the
+  expert STACK, flattened to ``[Lm E, ., .]``, and the groups of every
+  layer, all empty but this layer's (:func:`experts_grouped`): a kernel of
+  the compiler's own reads no ``dynamic-slice`` in place, so a layer
+  sliced out of the stack was written out and read again before every
+  product.  The ``grouped`` rows of PRs 31 and 33 (4.91 .. 15.81 here, 2.67
+  .. 9.76 below) held that copy, 2.7 and 2.0 ms a layer (1.1 and 0.81 GB
+  each way at 819 GB/s) whatever the tokens; the 1,024 or 384 groups cost
+  the kernel under 2% against one layer's experts handed to it whole.
 
 Layout (stacked on axis 0 over the expert layers):
     router [Lm, D, E scored]     the gate, in the activations' type; its
@@ -53,14 +60,17 @@ what an absent expert would add to a token is left out, in both forms: the
 other shares' devices add theirs, and the weights are NOT renormalised over
 the held.  The two forms at that model's shape (128 held of 512 scored,
 2048 x 512, 10 a token; one scan over 8 layers on a v5e, ms a layer, as
-above; PERF.md section 6, PR 33):
+above; PERF.md section 6, PRs 33 and 34):
 
     tokens      8     64    256    512   1024   2048   4096
-    dense    1.11   1.12   1.28   2.32   4.59   9.14  18.92
-    grouped  2.67   3.48   5.08   5.25   5.80   6.76   9.76
+    dense    1.11   1.12   1.28   2.32   4.59   9.13  18.92
+    grouped  0.26   1.08   2.68   2.86   3.41   4.35   7.31
 
-Alone the two cross between 1,024 and 2,048 tokens here too.  But a dense
-CHUNK has a cost that no timing of the product alone shows.  Compiled for
+Without the copy the two cross near 512 tokens at this shape and near
+1,024 at the first (the limits below were set on the PR 31 and 33 rows and
+are not moved by this reading: a change of form is a change of program,
+timed in its cell).  And a dense CHUNK has a cost that no timing of the
+product alone shows.  Compiled for
 the v5e, this model's ragged program (64 rows in its decode steps, a chunk
 of 1,024 beside them) with the chunk in the dense form copies the WHOLE of
 ``w_gate`` and ``w_up``, every layer, into the chunk product's layout (D
@@ -73,11 +83,14 @@ shape but the scan's slice of a layer).  Which of the two a shape gets is
 the compiler's choice and shows in a compile alone, so the limit is set
 the safe way round:
 
-- to ``_DENSE_MAX_TOKENS`` (512) every shape takes the dense form: there it
-  wins by a factor of two or more in both tables, and it is every decode
-  step's form;
-- from there to where the two forms' times cross the dense form saves 1-4
-  ms a layer and may cost a copy of two stacks, so a shape takes it only if
+- to ``_DENSE_MAX_TOKENS`` (512) every shape takes the dense form: from 256
+  rows to 512 it wins in both tables, at a decode step's 64 rows it wins
+  (2.5x) or ties, and it is every decode step's form (at 8 rows the grouped
+  form without its copy reads few experts and is the faster: not used, a
+  decode step has all its slots' rows);
+- from there to where the two forms' times crossed when the limits were set
+  (the grouped form with its layer copy) the dense form may cost a copy of
+  two stacks, so a shape takes it only if
   it is listed in ``_DENSE_TO_THE_CROSSING``: its forms TIMED on the chip
   and its ragged program COMPILED at full depth without that copy.
 
@@ -206,8 +219,17 @@ def experts_dense(h: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Param
         return jnp.einsum("etf,efd->td", act, lp["w_down"])
 
 
+def _stack_dot(x: jax.Array, stack: jax.Array, sizes_all: jax.Array) -> jax.Array:
+    """One ragged product over the FLATTENED stack ``[Lm E, ., .]``: the
+    reshape is free and nothing is sliced, so the kernel reads the experts
+    where they lie."""
+    return lax.ragged_dot(x, stack.reshape(-1, *stack.shape[2:]), sizes_all)
+
+
 def experts_grouped(
-    h: jax.Array, chosen: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Params,
+    h: jax.Array, chosen: jax.Array, onehot: jax.Array, weights: jax.Array,
+    stack: Params,  # the STACKED leaves: w_gate, w_up [Lm, E, D, Fe]; w_down [Lm, E, Fe, D]
+    m: Any,  # this layer's index in the stack (traced)
     share: bool = False,
 ) -> jax.Array:
     """Only the token-expert pairs routed: sorted by expert, one ragged
@@ -216,8 +238,15 @@ def experts_grouped(
     are many: nothing is cut to a capacity.  Of experts held by ``share``,
     ``onehot`` [T, k, E held] is all zero for a pair whose expert is held
     elsewhere: those pairs sort behind the last group, which no group's
-    product reaches, and are left out of the sum over a token's experts."""
+    product reaches, and are left out of the sum over a token's experts.
+
+    The products take the whole stack and ``m``: the groups are the
+    ``Lm E`` experts of every layer, all empty but this layer's ``E``, so
+    no array of a layer's experts is made (a slice of the stack handed to
+    the compiler's kernel is a COPY: 0.8-1.1 GB a layer written and read
+    again, the module's text)."""
     T, k = chosen.shape
+    Lm, E = stack["w_gate"].shape[:2]
     with jax.named_scope("group"):
         if share:
             held = jnp.any(onehot, axis=-1)  # [T, k]
@@ -226,11 +255,13 @@ def experts_grouped(
             flat = chosen.reshape(T * k)
         order = jnp.argsort(flat, stable=True)  # sorted pair -> flat pair
         sizes = jnp.sum(onehot, axis=(0, 1), dtype=jnp.int32)  # [E] pairs an expert
+        sizes_all = lax.dynamic_update_slice(
+            jnp.zeros((Lm * E,), jnp.int32), sizes, (jnp.asarray(m, jnp.int32) * E,))
         rows = h[order // k]  # [T k, D]
     with jax.named_scope("experts"):
-        act = jax.nn.silu(lax.ragged_dot(rows, lp["w_gate"], sizes)) * lax.ragged_dot(
-            rows, lp["w_up"], sizes)
-        out = lax.ragged_dot(act, lp["w_down"], sizes)  # [T k, D], sorted
+        act = jax.nn.silu(_stack_dot(rows, stack["w_gate"], sizes_all)) * _stack_dot(
+            rows, stack["w_up"], sizes_all)
+        out = _stack_dot(act, stack["w_down"], sizes_all)  # [T k, D], sorted
     with jax.named_scope("combine"):
         back = jnp.argsort(order)  # flat pair -> sorted pair
         out = out[back].reshape(T, k, -1).astype(jnp.float32)
@@ -247,8 +278,11 @@ def moe_ffn(
     stats: "tuple[jax.Array, jax.Array] | None" = None,
     valid: jax.Array | None = None,  # [B, S] bool: the real tokens
     m: Any = 0,  # this layer's index among the expert layers (traced)
+    stack: Params | None = None,  # the STACKED group ``lp`` is layer ``m`` of
 ) -> tuple[jax.Array, Any]:
-    """``sum_e w_e E_e(h) + Shared(h)`` → ([B, S, D], stats)."""
+    """``sum_e w_e E_e(h) + Shared(h)`` → ([B, S, D], stats).  The grouped
+    products read ``stack`` at ``m`` where it lies (:func:`experts_grouped`);
+    without one, ``lp`` is a stack of one layer."""
     B, S, D = h.shape
     E = config.n_routed_experts
     share = config.expert_share
@@ -271,7 +305,9 @@ def moe_ffn(
         if dense_form(B * S, config):
             y = experts_dense(flat, onehot, weights, lp)
         else:
-            y = experts_grouped(flat, chosen, onehot, weights, lp, share)
+            if stack is None:
+                stack, m = {n: lp[n][None] for n in ("w_gate", "w_up", "w_down")}, 0
+            y = experts_grouped(flat, chosen, onehot, weights, stack, m, share)
         if "s_gate" in lp:
             with jax.named_scope("shared"):
                 shared = _swiglu(flat, lp["s_gate"], lp["s_up"], lp["s_down"])

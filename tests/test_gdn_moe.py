@@ -29,6 +29,7 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
+from tests import _moe_stack
 from tests._gdn_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
     ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, forward, prompt_of, reference_logits,
     runtime, seeded, serve,
@@ -180,6 +181,16 @@ def test_a_share_s_two_forms_agree_with_each_other_and_the_reference(monkeypatch
     normed = M.rms_norm(x, lp["mlp_norm"], c.norm_eps, True)
     want = experts(x, params["layers"]["moe"], jnp.int32(0)) - x
     assert np.abs(np.asarray(moe.moe_ffn(normed, lp, c, None, None, 0)[0]) - np.asarray(want)).max() < 1e-5
+
+
+@pytest.mark.parametrize("m", range(_moe_stack.LAYERS))
+@pytest.mark.parametrize("case", [*_moe_stack.ROUTINGS, "every_pair_absent"])
+def test_a_share_s_grouped_experts_read_their_layer_out_of_the_stack(case, m):
+    """The same over experts held by SHARE (4 of 8, from the fifth): the
+    pairs whose expert is held elsewhere sort behind the LAST of the stack's
+    groups, not behind this layer's, and no group's product reaches them;
+    with every pair absent the routed part is exactly zero."""
+    _moe_stack.check(TOY, case, m)
 
 
 def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen():

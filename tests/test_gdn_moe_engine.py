@@ -83,6 +83,23 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
     assert counters["state_rows_landed"] == 1 and counters["latent_cache_bytes"] == 0
 
 
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+def test_every_chunk_of_a_served_prompt_is_counted_by_its_form(monkeypatch, form):
+    """Three chunks of 16 under a prompt of 37: past the limit (8 tokens at
+    toy size) each counts as grouped, as every chunk of the Qwen3-Next cell
+    does; under a limit of 4,096 as dense.  Counted at enqueue from shapes."""
+    from calfkit_tpu.observability.metrics import metrics_text
+
+    if form == "dense":
+        monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
+    _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+    assert (counters["moe_grouped_chunks"], counters["moe_dense_chunks"]) == (
+        (3, 0) if form == "grouped" else (0, 3))
+    text = metrics_text()
+    assert "calfkit_engine_moe_grouped_chunks_total" in text
+    assert "calfkit_engine_moe_dense_chunks_total" in text
+
+
 def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(monkeypatch):
     """Three requests one after another through two slots (the third lands
     in a slot the first left), then two at once: each served as alone."""

@@ -227,6 +227,26 @@ def test_the_three_older_kinds_trace_the_programs_the_parent_traced(monkeypatch,
         assert hashlib.sha256(text.encode()).hexdigest() == TRACED_AT_THE_PARENT[kind][name], name
 
 
+# ... and of the ragged programs whose two-row chunk takes the GROUPED form
+# (32 tokens past the toy limit of 8), as PR 34 traces them: the products
+# over the flattened stack, the layer as the one run of groups that is not
+# empty.  The parent's differed (a ragged product on the sliced layer).
+TRACED_SINCE_PR_34 = {
+    "gdn-moe": "880c5825c14e8a6051add5a4ba15d6376112a0cdb92f743851084ff89e080ef7",
+    "latent-moe": "ffac121351b5c1c15d2b5dfd2dc8d70087a0dfa28ad726deb8fd12c43a0ae3d3",
+}
+
+
+@pytest.mark.parametrize("kind", sorted(TRACED_SINCE_PR_34))
+def test_a_grouped_chunk_s_ragged_program_is_what_pr_34_traced(kind):
+    config = {"gdn-moe": TOY, "latent-moe": LATENT}[kind]
+    engine = InferenceEngine(config, runtime(attention_impl="xla"))
+    assert not moe.dense_form(2 * engine.runtime.prefill_chunk, config)
+    text = str(_programs(engine)["ragged"])
+    assert "ragged_dot" in text and "dynamic_update_slice" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == TRACED_SINCE_PR_34[kind]
+
+
 def test_the_new_kind_s_programs_name_their_scopes():
     text = {name: jaxpr.pretty_print(name_stack=True) for name, jaxpr in _programs(
         InferenceEngine(TOY, runtime(attention_impl="xla"))).items()}

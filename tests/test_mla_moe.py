@@ -40,6 +40,7 @@ from calfkit_tpu.inference.config import (
     preset,
 )
 from calfkit_tpu.inference.engine import InferenceEngine
+from tests import _moe_stack
 
 ARCH = load_architecture("deepseek-mla-moe")
 
@@ -268,6 +269,16 @@ def test_grouped_experts_equal_every_expert_masked(monkeypatch, skewed):
         for j in range(2)
     ) + (jax.nn.silu(flat @ lp["s_gate"]) * (flat @ lp["s_up"])) @ lp["s_down"]
     assert np.abs(np.asarray(grouped).reshape(96, -1) - np.asarray(plain)).max() < 1e-5
+
+
+@pytest.mark.parametrize("m", range(_moe_stack.LAYERS))
+@pytest.mark.parametrize("case", _moe_stack.ROUTINGS)
+def test_grouped_experts_read_their_layer_out_of_the_stack(case, m):
+    """The grouped products take the STACKED leaves and the layer's index
+    (every expert held): each layer of three gives what the parent's form
+    gave on the sliced layer, bit for bit, and what the dense form gives;
+    with a group of 0, and a first and a last group of every token."""
+    _moe_stack.check(replace(TOY, n_layers=1 + _moe_stack.LAYERS), case, m)
 
 
 def test_padding_and_inactive_rows_are_computed_and_not_counted():
@@ -639,6 +650,35 @@ def test_the_dense_and_hybrid_descriptions_are_what_they_were():
     held = replace(k, n_layers=7)
     assert held.param_count == 4263151488 and held.kv_bytes_per_token() == 8064
     assert (held.n_dense_layers, held.n_moe_layers) == (1, 6)
+
+
+@pytest.mark.parametrize("rows, grouped", [(1, False), (2, True), (4, True)])
+def test_a_chunk_dispatch_is_counted_by_the_form_its_shape_gives_it(rows, grouped):
+    """``moe_grouped_chunks`` / ``moe_dense_chunks``: one count for every
+    chunk the engine enqueues (riding a decode dispatch, or alone), from the
+    wave's rows x the chunk's length alone; a decode dispatch counts nothing.
+    At toy size the limit is 16 tokens: a one-row chunk of 16 is dense, as
+    Kimi's one-row chunk of 1,024 is under its limit of 1,536."""
+    engine = InferenceEngine(TOY, runtime(max_batch_size=4, max_prefill_wave=4), seed=3, params=seeded())
+    inf = {"wave": [None] * rows, "chunk": 16, "wmoe": engine._moe_zero,
+           "arrays": {"true_lens": np.full(rows, 16, np.int32)}}
+    assert moe.dense_form(rows * 16, TOY) != grouped
+    assert set(engine._moe_kw(inf)) == {"moe", "wmoe", "true_lens"}  # riding a decode dispatch
+    assert set(engine._moe_kw(inf, decode=False)) == {"wmoe", "true_lens"}  # alone
+    assert set(engine._moe_kw()) == {"moe"}  # decode steps alone: no chunk
+    counters = engine.stats.counters()
+    assert (counters["moe_grouped_chunks"], counters["moe_dense_chunks"]) == (
+        (2, 0) if grouped else (0, 2))
+
+
+def test_a_served_prompt_s_one_row_chunks_count_as_dense():
+    from calfkit_tpu.observability.metrics import metrics_text
+
+    _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+    assert (counters["moe_grouped_chunks"], counters["moe_dense_chunks"]) == (0, 3)  # 37 in chunks of 16
+    text = metrics_text()
+    assert "calfkit_engine_moe_grouped_chunks_total" in text
+    assert "calfkit_engine_moe_dense_chunks_total" in text
 
 
 def test_the_new_counters_reach_metrics_and_capacity():

@@ -179,6 +179,40 @@ def _computations(hlo: str) -> dict[str, list[str]]:
     return out
 
 
+def _made_in_loops(hlo: str, shapes: tuple[str, ...]) -> dict[str, list[str]]:
+    """Loop-body computation → the operations in it whose RESULT is an array
+    of one of ``shapes`` (``"bf16[64,2048,1408]"``), plumbing left out: what a
+    ``dynamic-slice`` of a stack handed to a kernel of the compiler's own
+    compiles to, a layer-sized fusion written once and read again.  A loop's
+    body is what a ``while`` names as its ``body`` and what that calls
+    (a fusion's own computation is no buffer: its result in the caller is)."""
+    import re
+
+    computations = _computations(hlo)
+    bodies = set(re.findall(r"\bbody=%?([\w.\-]+)", hlo))
+    grew = True
+    while grew:  # ... and the computations those call, fusions left out
+        called = {
+            name
+            for body in bodies
+            for line in computations.get(body, ())
+            if " fusion(" not in line
+            for name in re.findall(r"\b(?:to_apply|calls|body|condition)=%?([\w.\-]+)", line)
+        }
+        grew = not called <= bodies
+        bodies |= called
+    assert bodies and bodies <= set(computations), bodies
+    pattern = re.compile(
+        r"= (?:%s)\S* (\w[\w\-]*)\(" % "|".join(re.escape(shape) for shape in shapes))
+    plumbing = ("bitcast", "parameter", "get-tuple-element")
+    made = {
+        name: [m.group(1) for m in map(pattern.search, computations[name])
+               if m and m.group(1) not in plumbing]
+        for name in bodies
+    }
+    return {name: ops for name, ops in made.items() if ops}
+
+
 def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
     one_chip, no_persistent_cache
 ):
@@ -535,8 +569,9 @@ def test_entry_point_list_is_complete():
 # starve the timing-bound engine tests of a tier-1 run's other workers; the offline lane runs it
 def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
     """The decode dispatch and the ragged program of the cell's configuration
-    at its published WIDTHS and runtime (2 of its 7 layers: the dense one and
-    one expert layer, all 64 experts), with the kernel resolved as "auto"
+    at its published WIDTHS and runtime (3 of its 7 layers: the dense one and
+    TWO expert layers, all 64 experts: a stack from which a layer can be
+    sliced), with the kernel resolved as "auto"
     resolves it on a chip, compiled for the described v5e: the decode step
     reads the latent through the latent decode kernel under
     ``mla/attention`` (two calls: the unrolled dense layer and the expert
@@ -546,8 +581,12 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     made in a loop's body; the chunk that rides along groups its tokens when
     it is wider than ``moe.dense_form``'s limit for this shape, 1,536 (two rows of 1,024: three
     ``ragged-dot`` kernels an expert layer; one row takes the dense form, as
-    the decode step does: the chip's readings in ``moe.py``); the two parts
-    of the pool go out where they came in."""
+    the decode step does: the chip's readings in ``moe.py``), and since PR 34
+    those kernels take the STACK of the expert layers, flattened, so that NO
+    operation of a loop's body makes an array of a layer's experts (the
+    parent's program held three such fusions, 1.1 GB a layer written and read
+    again) and the program's temporaries did not grow; the two parts of the
+    pool go out where they came in."""
     import json
     import re
     from dataclasses import replace
@@ -564,10 +603,10 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     arch = manifest.load_architecture(described["architecture"], here)
     config, runtime = arch.model(described, False)
     assert InferenceEngine(
-        replace(config, n_layers=2), replace(runtime, compilation_cache=False)
+        replace(config, n_layers=3), replace(runtime, compilation_cache=False)
     )._attn_impl == "xla"  # "auto" on this process's CPU: the reference path
     engine = InferenceEngine(
-        replace(config, n_layers=2),
+        replace(config, n_layers=3),
         replace(runtime, compilation_cache=False, attention_impl="pallas"))
     assert engine._attn_impl == "pallas" and engine._ssm_impl == "xla"
 
@@ -627,8 +666,12 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     grouped = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line and "= bf16[" in line
                and "ragged-dot-none" in line]
-    assert len(grouped) == 3  # gate, up, down of the one expert layer
-    assert all("bf16[64,2048,1408]" in line or "bf16[64,1408,2048]" in line for line in grouped)
+    assert len(grouped) == 3  # gate, up, down in the body of the expert layers' scan
+    # their operand: the two layers' stack, flattened, never a slice of it
+    assert all("bf16[128,2048,1408]" in line or "bf16[128,1408,2048]" in line for line in grouped)
+    assert not _made_in_loops(hlo, ("bf16[64,2048,1408]", "bf16[64,1408,2048]"))
+    # the parent's program at this size (PR 33's tree, this test at 3 layers): 1,198,723,584 B
+    assert ragged.memory_analysis().temp_size_in_bytes <= 1_198_723_584
     assert "decode_loop/" in hlo and "chunk_loop/" in hlo
     assert len(own_kernels(hlo)) == 2
     # the chunk still reads its rows' windows through XLA (the reference
@@ -739,8 +782,14 @@ def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persiste
     8 layers.  No period's weights are copied: a one-row chunk in the dense
     form copied the whole of ``w_gate`` and ``w_up`` into another layout
     (2 x 2.15 GB a dispatch), which is why chunks of this shape take the
-    grouped form; the chunk's delta rule solves its triangular systems; and
-    arguments and temporaries together leave the 16 GB chip 3 GB of room."""
+    grouped form, whose three products take the stacks FLATTENED to
+    ``[8 x 128, ., .]`` and the layer as the one run of groups that is not
+    empty (PR 34): no operation of a loop's body makes an array of a layer's
+    experts, where the parent's ``chunk_loop`` held three such fusions (0.81 GB
+    a layer written and read again: 13% of the cell's busy time), and the
+    ragged programs' temporaries did not grow; the chunk's delta rule solves
+    its triangular systems; and arguments and temporaries together leave the
+    16 GB chip 3 GB of room."""
     import jax
     import jax.numpy as jnp
 
@@ -777,7 +826,11 @@ def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persiste
         assert "chunk_loop/" in hlo and "/gdn/state/" in hlo
         assert not any("copy(" in line and "bf16[8,128,2048,512]" in line.split("copy(")[0]
                        for line in hlo.splitlines())
+        assert not _made_in_loops(hlo, ("bf16[128,2048,512]", "bf16[128,512,2048]"))
         memory = ragged.memory_analysis()
         report[f"ragged x{rows}"] = memory.temp_size_in_bytes
+        # the parent's programs at this size (PR 33's tree, this test)
+        assert memory.temp_size_in_bytes <= {
+            1: 2_765_331_968, 2: 2_825_830_912, 4: 3_188_042_752}[rows]
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9
     print("temporaries, bytes:", report)
