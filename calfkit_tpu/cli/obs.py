@@ -132,9 +132,10 @@ def render_stats_table(records: "Iterable[EngineStatsRecord]") -> str:
             f"{lat.get('ttft_p50', 0):.0f}/{lat.get('ttft_p99', 0):.0f}"
             if lat else "-"
         )
-        # overlapped execution health: the p99 inter-dispatch device-idle
-        # bubble (should sit at ~0 with overlap on) and the pad tokens
-        # one-dispatch-late retirement discarded
+        # overlapped execution health: the p99 device-idle bubble before a
+        # dispatch (0 while a program stays queued; what a drained pipeline
+        # shows after a wave's landing sync is the host's work to the next
+        # enqueue) and the pad tokens one-dispatch-late retirement discarded
         gap = (
             f"{lat.get('dispatch_gap_p99', 0):.2f}"
             if "dispatch_gap_p99" in lat else "-"
@@ -533,13 +534,14 @@ def render_timeline(events: "list[dict]", correlation_id: str) -> str:
     payload (labels from ``flightrec.ARG_LABELS``), and a ``(batch)``
     marker on wave/dispatch events borrowed from the request's active
     window (they covered its slot but carry no correlation id)."""
-    from calfkit_tpu.observability.flightrec import ARG_LABELS
+    from calfkit_tpu.observability.flightrec import ARG_LABELS, SEQ_EVENTS
 
     if not events:
         return "no events"
     t0 = min(e.get("t_s", 0.0) for e in events)
     span_ms = (max(e.get("t_s", 0.0) for e in events) - t0) * 1000.0
-    slot = next((e["slot"] for e in events if e.get("slot", -1) >= 0), -1)
+    slot = next((e["slot"] for e in events
+                 if e.get("slot", -1) >= 0 and e.get("event") not in SEQ_EVENTS), -1)
     lines = [
         f"timeline {correlation_id}  —  {len(events)} events"
         + (f", slot {slot}" if slot >= 0 else "")
@@ -554,6 +556,8 @@ def render_timeline(events: "list[dict]", correlation_id: str) -> str:
             for label, key in zip(labels, ("a", "b"))
             if label
         )
+        if name in SEQ_EVENTS and e.get("slot", -1) >= 0:
+            payload = f"seq={e['slot']}  {payload}"
         note = e.get("note")
         if note:
             payload = (payload + "  " if payload else "") + f"note={note}"

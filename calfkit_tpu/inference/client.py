@@ -732,6 +732,9 @@ class JaxLocalModelClient(ModelClient):
                         decode_span = TRACER.start_span(
                             "engine.decode", parent=gen_span.context,
                             kind="engine", emitter=gen_span.emitter,
+                            # the dispatches that carried this request's
+                            # decode are those numbered past this one
+                            attrs={"first_seq": getattr(self._engine, "proved_seq", 0)},
                         )
                 # the first token is emitted immediately; later ones batch
                 # on the re-decode cadence
@@ -758,6 +761,11 @@ class JaxLocalModelClient(ModelClient):
             stream_exc = exc
             raise
         finally:
+            # the stream has ended: its last token arrived in the block that
+            # carried the end, so THIS is the moment the decode phase ends
+            # (closing below takes the engine's lock and a scheduler pass)
+            ended = time.perf_counter()
+            last_seq = getattr(self._engine, "proved_seq", 0)
             # a break above abandons the stream; close NOW (not at GC) so
             # the engine reclaims the slot at its next tick
             await token_stream.aclose()
@@ -774,7 +782,8 @@ class JaxLocalModelClient(ModelClient):
                 prefill_span.end(status=status)
             if decode_span is not None:
                 decode_span.end(
-                    status=status, generated_tokens=len(generated)
+                    status=status, at=ended, generated_tokens=len(generated),
+                    last_seq=last_seq,
                 )
             if gen_span is not None:
                 gen_span.end(

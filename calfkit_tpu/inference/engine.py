@@ -29,6 +29,7 @@ import logging
 import math
 import threading
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, AsyncIterator
@@ -59,7 +60,7 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.moe import dense_form, moe_stats_init
 from calfkit_tpu.observability import capacity, flightrec
-from calfkit_tpu.observability.trace import TRACER, Span, TraceContext
+from calfkit_tpu.observability.trace import TRACER, Span, TraceContext, detach_spans
 from calfkit_tpu.observability.metrics import (
     INTER_TOKEN_BUCKETS_MS,
     REGISTRY,
@@ -94,12 +95,15 @@ _SECONDS_HELP = {
     "phase_sync_s": "dispatch loop: blocked on the device",
     "phase_fanout_s": "dispatch loop: after the sync (fan-out, retirement, frees)",
     "phase_idle_s": "dispatch loop: awaiting work",
-    "starved_s": "rows active and nothing in flight: landing to next enqueue",
+    "starved_s": "rows active or a wave in flight and the device known empty: "
+                 "the sync that drained it to the next enqueue",
     "blocked_slots_s": "queue head held: no free slot",
     "blocked_pages_s": "queue head held: page allocation came back short",
     "blocked_wave_s": "queue head held: an admission wave in flight",
     "blocked_budget_s": "queue head held: ragged token budget, wave trim or bucket",
     "empty_slot_queued_s": "slot-seconds: free slots while a request was queued",
+    "program_build_s": "calls in which JAX built a program (a jit key's first use, or "
+                       "arguments of another kind under it): their seconds",
 }
 _SECONDS_FIELDS = tuple(_SECONDS_HELP)
 PHASES = tuple(f for f in _SECONDS_FIELDS if f.startswith("phase_"))
@@ -113,7 +117,8 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # heartbeat advert's window
 _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
-    "state_rows_landed", "prefix_reuse_declined_recurrent",
+    "prefix_reuse_declined_recurrent",
+    "pipeline_drains", "pipeline_drains_wave", "programs_built",
     "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
     "moe_dense_chunks", *_SECONDS_FIELDS,
@@ -132,6 +137,19 @@ _SYNCED_FIELDS = (
 # letting telemetry fault serving.
 _ACTIVE_BY_ENGINE: dict[int, int] = {}
 _ACTIVE_LOCK = threading.Lock()
+
+
+# every live engine, for ``GET /programs`` (weak: an abandoned engine goes)
+_ENGINES: "weakref.WeakSet[InferenceEngine]" = weakref.WeakSet()
+
+
+def programs_of_all_engines() -> "list[dict]":
+    """``InferenceEngine.programs()`` of every live engine of the process."""
+    return [
+        {"engine": e.config.name, "enqueued": e._enq_seq, "proved": e._done_seq,
+         "programs": e.programs()}
+        for e in list(_ENGINES)
+    ]
 
 
 def _drop_engine_active(key: int) -> None:
@@ -218,15 +236,25 @@ def _engine_metrics(
             "paged decode steps: rows in the program x the window bucket's "
             "pages (what the XLA window gather copies)",
         ),
-        state_rows_landed=reg.counter(
-            "calfkit_engine_state_rows_landed_total",
-            "slots whose recurrent (SSM and conv) state an admission wave "
-            "overwrote at landing",
-        ),
         prefix_reuse_declined_recurrent=reg.counter(
             "calfkit_engine_prefix_reuse_declined_recurrent_total",
             "requests whose cached prefix was not reused because the model "
             "carries recurrent state (pages hold no state at their boundary)",
+        ),
+        pipeline_drains=reg.counter(
+            "calfkit_engine_pipeline_drains_total",
+            "host syncs that left the device known empty (every program "
+            "enqueued proved complete) while rows were active or a wave was "
+            "in flight",
+        ),
+        pipeline_drains_wave=reg.counter(
+            "calfkit_engine_pipeline_drains_wave_total",
+            "those of them that were an admission wave's landing sync",
+        ),
+        programs_built=reg.counter(
+            "calfkit_engine_programs_built_total",
+            "calls in which JAX built a program: a jit key's first use, or "
+            "arguments of another kind under it (GET /programs lists them)",
         ),
         recurrent_state_bytes=reg.gauge(
             "calfkit_engine_recurrent_state_bytes",
@@ -300,6 +328,51 @@ def _deliver_batch(deliveries: "list[tuple[asyncio.Queue, list]]") -> None:
     event-loop churn on the serving hot path."""
     for queue, items in deliveries:
         queue.put_nowait(items)
+
+
+class _Program:
+    """One entry of the engine's jit caches: the jitted function, and the
+    account the engine keeps of it.  Calling it IS the enqueue: the
+    engine's ``_enq_seq`` goes up by one (the program's number on the
+    device's in-order queue).  A call in which JAX BUILT something is kept
+    apart (trace + lower + compile, or a load from the compile cache: JAX
+    does all of it inside the call): the key's first use, and every later
+    call that met arguments of another kind under the same key (an
+    uncommitted array where a program's output was: JAX specializes again,
+    and only the count of what it holds under the function shows it).
+    Everything else (``lower``, ...) is the jitted function's own."""
+
+    __slots__ = ("engine", "family", "key", "fn", "builds", "build_s", "first_seq",
+                 "built_seq", "built_s", "uses")
+
+    def __init__(self, engine: "InferenceEngine", family: str, key: tuple, fn: Any):
+        self.engine, self.family, self.key, self.fn = engine, family, key, fn
+        self.builds = 0  # specializations JAX holds under the key
+        self.build_s = 0.0  # the seconds of the calls that built them
+        self.first_seq: "int | None" = None
+        self.built_seq: "int | None" = None  # the newest such call, and its seconds
+        self.built_s = 0.0
+        self.uses = 0
+
+    def __call__(self, *args: Any, **kw: Any) -> Any:
+        engine = self.engine
+        engine._enq_seq += 1
+        self.uses += 1
+        began = time.perf_counter()
+        out = self.fn(*args, **kw)
+        if self.fn._cache_size() != self.builds:
+            self.builds = self.fn._cache_size()
+            self.built_s = time.perf_counter() - began
+            self.built_seq = engine._enq_seq
+            if self.first_seq is None:
+                self.first_seq = self.built_seq
+            self.build_s += self.built_s
+            engine.stats.programs_built += 1
+            engine.stats.program_build_s += self.built_s
+        return out
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self.fn, name)
 
 
 def _some(x: Any) -> tuple:
@@ -565,9 +638,24 @@ class EngineStats:
     phase_sync_s: float = 0.0  # blocked on the device
     phase_fanout_s: float = 0.0  # after the sync: fan-out, retirement, frees
     phase_idle_s: float = 0.0  # awaiting work
-    # not a phase: with rows active, seconds from a landing to the next
-    # enqueue while nothing was in flight (Σ dispatch_gap_ms observations)
+    # not a phase: with rows active or a wave in flight, seconds from the
+    # sync that left the device known empty to the next enqueue
+    # (Σ dispatch_gap_ms observations).  "Empty" is judged by the device's
+    # queue (every program enqueued proved complete by a host sync), not by
+    # the host's bookkeeping of what it has landed
     starved_s: float = 0.0
+    # the syncs that left it so, and those of them that were a wave's
+    # landing sync (the launch that carried a wave's last chunk goes
+    # straight on into the landing, which waits for everything queued)
+    pipeline_drains: int = 0
+    pipeline_drains_wave: int = 0
+    # calls in which JAX built a program (a jit key's first use, or a later
+    # one with arguments of another kind under it): how many, and their
+    # seconds (trace + lower + compile, or a cache load; ``enqueue`` holds
+    # them too and cannot tell them apart).  ``InferenceEngine.programs()``
+    # is the table
+    programs_built: int = 0
+    program_build_s: float = 0.0
     # the admission-blocked ledger: seconds the head of the queue waited,
     # by what held it, and the free-slot integral while anyone queued
     blocked_slots_s: float = 0.0  # no free slot
@@ -583,11 +671,9 @@ class EngineStats:
     decode_pages_live: int = 0
     decode_pages_window: int = 0
     # a second kind of per-sequence state (models with recurrent layers):
-    # slots whose SSM and conv state a wave overwrote at landing; requests
-    # whose cached prefix went unused because reused pages carry no state
-    # at their boundary; and, a gauge, the device bytes the slots' state
-    # reserves (0 for a model without such layers)
-    state_rows_landed: int = 0
+    # requests whose cached prefix went unused because reused pages carry
+    # no state at their boundary; and, a gauge, the device bytes the slots'
+    # state reserves (0 for a model without such layers)
     prefix_reuse_declined_recurrent: int = 0
     recurrent_state_bytes: int = 0
     # routed experts (0 for a model without them): token-expert pairs
@@ -656,7 +742,7 @@ class EngineStats:
             a = self.EWMA_ALPHA
             self.dispatch_ewma_ms = a * sample_ms + (1.0 - a) * prev
 
-    def enter(self, phase: "str | None") -> float:
+    def enter(self, phase: "str | None", seq: "int | None" = None) -> float:
         """Switch the loop's phase clock: close the open phase (its
         seconds to its counter, its ``engine.<phase>`` annotation ended)
         and open ``phase`` (None: the loop has ended).  Returns the one
@@ -664,7 +750,12 @@ class EngineStats:
         annotation puts the same interval on the profiler's clock
         whenever anyone's profile is running, and is a no-op otherwise;
         it may end on another thread than it began on (the profiler
-        files it under the thread that ended it)."""
+        files it under the thread that ended it).  ``seq`` rides the
+        annotation as metadata (its name stays ``engine.<phase>``): the
+        number of the first program an ``enqueue`` is about to put on the
+        device's queue, or of the program a ``sync`` waits for, which is
+        what joins the host's clock to the device's module runs
+        (``devtrace.reduce_trace``)."""
         now = time.perf_counter()
         prev = self._phase
         if prev is not None:
@@ -676,7 +767,11 @@ class EngineStats:
         if phase is None:
             self._phase = None
         else:
-            annotation = jax.profiler.TraceAnnotation(_PHASE_ANNOTATION[phase])
+            name = _PHASE_ANNOTATION[phase]
+            annotation = (
+                jax.profiler.TraceAnnotation(name) if seq is None
+                else jax.profiler.TraceAnnotation(name, seq=seq)
+            )
             annotation.__enter__()
             self._phase = (phase, now, annotation)
         return now
@@ -1079,6 +1174,23 @@ class InferenceEngine:
         # deferred to its landing
         self._pend: "dict | None" = None
         self._last_sync_t: "float | None" = None
+        # the device-queue account: every program the dispatch loop enqueues
+        # gets a number (``_Program.__call__``), and every designated host
+        # sync records the number whose output it read (``_landed``); on one
+        # device's in-order stream everything before it is complete too, so
+        # the device is KNOWN EMPTY exactly when the two are equal
+        self._enq_seq = 0
+        self._done_seq = 0
+        self._emitter = f"engine/{config.name}"
+        # the moment of the sync that left it so (None: a program is queued,
+        # or the engine stands idle), the start of that sync's wait, the
+        # dispatches enqueued and not yet proved complete (the open
+        # ``engine.dispatch`` spans, oldest first) and the moment the last
+        # one was
+        self._empty_at: "float | None" = None
+        self._sync_began = 0.0
+        self._unproved: "deque[dict]" = deque(maxlen=8)  # overlap keeps at most two
+        self._proved_at = 0.0
         # per-slot sampling state: one decode dispatch serves mixed settings
         # (row-wise knobs are data, not jit specializations)
         self._slot_keys = jax.random.split(jax.random.key(seed + 2), B)
@@ -1247,9 +1359,8 @@ class InferenceEngine:
         # self-cleaning gauge aggregation: an engine abandoned without
         # stop() must not pin its last active count into the process
         # gauge (stop() also clears eagerly and re-sets the gauge)
-        import weakref
-
         weakref.finalize(self, _drop_engine_active, id(self))
+        _ENGINES.add(self)
 
         self._decode_jits: dict[tuple, Any] = {}  # (window, steps, ...)
         self._prefill_jits: dict[tuple, Any] = {}
@@ -1362,6 +1473,34 @@ class InferenceEngine:
             return "xla"
         return "pallas" if impl == "auto" else impl
 
+    def _keep_program(self, cache: dict, family: str, key: tuple, fn: Any) -> "_Program":
+        program = cache[key] = _Program(self, family, key, fn)
+        return program
+
+    def programs(self) -> "list[dict]":
+        """The jit caches as a table, one row a key: the family, the key as
+        the engine holds it (window or its pages, steps, sampled, chunk,
+        rows, bucket ...), how many times JAX built under it (``builds``: 0,
+        never called; more than 1, the key met arguments of another kind and
+        was specialized again without any new key) and the seconds of those
+        calls (trace + lower + compile, or a cache load), the program's
+        number on the device's queue at its first call and at its newest
+        build, and its uses.  ``GET /programs`` serves it; what built in a
+        ramp-in is the row whose ``built_seq`` is high."""
+        return [
+            {"family": p.family, "key": list(p.key), "builds": p.builds, "build_s": p.build_s,
+             "first_seq": p.first_seq, "built_seq": p.built_seq, "uses": p.uses}
+            for cache in (self._decode_jits, self._prefill_jits)
+            for p in list(cache.values())
+        ]
+
+    @property
+    def proved_seq(self) -> int:
+        """The number of the newest program a host sync has proved
+        complete: what a request's ``engine.decode`` span records at its
+        first and last token, to join the ``engine.dispatch`` spans between."""
+        return self._done_seq
+
     def _window_bucket(self, needed: int) -> int:
         """Smallest configured window ≥ needed (cap max_seq): the decode
         attention scan only reads this prefix of the cache, and each bucket
@@ -1385,8 +1524,7 @@ class InferenceEngine:
             self._decode_fn_dense(window, steps, sampled),
             donate_argnums=(1, 2, 13) if self._recurrent else (1, 2),
         )
-        self._decode_jits[(window, steps, sampled)] = fn
-        return fn
+        return self._keep_program(self._decode_jits, "decode", (window, steps, sampled), fn)
 
     def _decode_fn_dense(self, window: int, steps: int, sampled: bool) -> Any:
         """The dense decode dispatch BODY (untraced): shared verbatim by
@@ -1462,8 +1600,8 @@ class InferenceEngine:
             self._decode_fn_paged(wpages, steps, sampled),
             donate_argnums=(1, 2, 14) if self._recurrent else (1, 2),
         )
-        self._decode_jits[(wpages, steps, sampled, "paged")] = fn
-        return fn
+        return self._keep_program(
+            self._decode_jits, "decode", (wpages, steps, sampled, "paged"), fn)
 
     def _decode_fn_paged(self, wpages: int, steps: int, sampled: bool) -> Any:
         """The paged decode dispatch body (untraced) — see
@@ -1572,8 +1710,7 @@ class InferenceEngine:
             )
 
         fn = jax.jit(verify, donate_argnums=(1, 2))
-        self._decode_jits[key] = fn
-        return fn
+        return self._keep_program(self._decode_jits, "verify", key, fn)
 
     def _verify_jit_paged(self, window: int, S: int, sampled: bool) -> Any:
         page = self.runtime.page_size
@@ -1619,8 +1756,7 @@ class InferenceEngine:
             )
 
         fn = jax.jit(verify, donate_argnums=(1, 2))
-        self._decode_jits[key] = fn
-        return fn
+        return self._keep_program(self._decode_jits, "verify", key, fn)
 
     def _short_steps(self) -> int:
         """Dispatch length while a waiting admission could actually unblock:
@@ -1733,8 +1869,7 @@ class InferenceEngine:
             ), *_some(moe))
 
         fn = jax.jit(prefill, donate_argnums=(1, 2, 3, 4), donate_argnames=("state",))
-        self._prefill_jits[(bucket, rows, sampled)] = fn
-        return fn
+        return self._keep_program(self._prefill_jits, "prefill", (bucket, rows, sampled), fn)
 
     # ------------------------------------------------- chunked prefill jits
     def _chunk_jit(self, chunk: int, rows: int) -> Any:
@@ -1748,8 +1883,7 @@ class InferenceEngine:
             self._chunk_fn(chunk),
             donate_argnums=(1, 2, 5) if self._recurrent else (1, 2),
         )
-        self._prefill_jits[("chunk", chunk, rows)] = fn
-        return fn
+        return self._keep_program(self._prefill_jits, "chunk", ("chunk", chunk, rows), fn)
 
     def _chunk_fn(self, chunk: int) -> Any:
         """The prefill-chunk body (untraced): shared verbatim by the
@@ -1850,8 +1984,7 @@ class InferenceEngine:
                 donate_argnums=(1, 2, 13, 14, 17, 18) if self._recurrent
                 else (1, 2, 13, 14),
             )
-        self._decode_jits[key] = fn
-        return fn
+        return self._keep_program(self._decode_jits, "ragged", key, fn)
 
     def _seed_scratch_jit(self, bucket: int, n_pages: int, rows: int) -> Any:
         """Fresh chunk-lane scratch with every row's first ``n_pages``
@@ -1881,9 +2014,7 @@ class InferenceEngine:
             sv = sv.at[:, :, :, : n_pages * page].set(gather(pool_v))
             return sk, sv
 
-        fn = jax.jit(seed)
-        self._prefill_jits[key] = fn
-        return fn
+        return self._keep_program(self._prefill_jits, "seed", key, jax.jit(seed))
 
     def _finalize_jit(self, bucket: int, rows: int, sampled: bool) -> Any:
         """The chunked wave's landing: scatter the finished scratch into the
@@ -1923,8 +2054,8 @@ class InferenceEngine:
         # "donated buffers were not usable" warnings — peak HBM at landing
         # (cache + scratch) already equals the chunk-step peak either way
         fn = jax.jit(finalize, donate_argnums=(0, 1, 4, 5), donate_argnames=("state",))
-        self._prefill_jits[("final", bucket, rows, sampled)] = fn
-        return fn
+        return self._keep_program(
+            self._prefill_jits, "finalize", ("final", bucket, rows, sampled), fn)
 
     # ------------------------------------------------------------ lifecycle
     async def start(self) -> None:
@@ -2749,7 +2880,7 @@ class InferenceEngine:
         request.blocked_at_submit = self.stats.blocked_now(time.perf_counter())
         return TRACER.start_span(
             "engine.queue", parent=trace, kind="engine",
-            emitter=f"engine/{self.config.name}",
+            emitter=self._emitter,
         )
 
     def _end_queue_span(self, span: "Span", request: GenRequest) -> None:
@@ -2803,6 +2934,9 @@ class InferenceEngine:
     # ------------------------------------------------------------ scheduler
     async def _serve(self) -> None:
         stats = self.stats
+        # the loop's own spans (one a dispatch) belong to no hop: were the
+        # engine started inside one, its sink must not collect them for ever
+        detach_spans()
         try:
             while self._running:
                 stats.enter(REAP)
@@ -2825,6 +2959,7 @@ class InferenceEngine:
                             not self._pending and not self._carry
                             and not self._long_pending and self._long is None
                         ):
+                            self._empty_at = None  # an idle engine is not a bubble
                             stats.enter(IDLE)
                             await self._wake.wait()
                     continue
@@ -2850,6 +2985,7 @@ class InferenceEngine:
                         not self._pending and not self._carry
                         and not self._long_pending and self._long is None
                     ):
+                        self._empty_at = None
                         stats.enter(IDLE)
                         await self._wake.wait()
         except Exception as exc:  # noqa: BLE001
@@ -3683,10 +3819,9 @@ class InferenceEngine:
                 kw["true_lens"] = jnp.asarray(inf["arrays"]["true_lens"])
         return kw
 
-    def _note_state_landed(self, landed: list, wave: "list[GenRequest]") -> None:
+    def _note_state_landed(self, landed: list) -> None:
         if landed:
             self._state = landed[0]
-            self.stats.state_rows_landed += len(wave)
 
     def _keep_carried(self, came_back: list) -> Any:
         """What a decode program returns after ``done``: the slots'
@@ -3814,23 +3949,24 @@ class InferenceEngine:
         ]
         if self._paged:
             args += self._paged_wave_args(wave, bucket)
-        self.stats.enter(ENQUEUE)
+        self.stats.enter(ENQUEUE, self._enq_seq + 1)
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
             *landed,
         ) = fn(*args, **self._state_kw(), **({"moe": self._moe_zero} if self._moe else {}))
+        seq = self._enq_seq
         moe = landed.pop() if self._moe else None
-        self._note_state_landed(landed, wave)
+        self._note_state_landed(landed)
         if self._paged:
             self._tables = tables
         # sync BEFORE timing: with async dispatch, fn() returns before the
         # device runs — prefill_ms must be real latency, not enqueue time
-        self.stats.enter(SYNC)
+        self._sync_began = self.stats.enter(SYNC, seq)
         firsts = np.asarray(firsts)
         if moe is not None:
             self._note_moe(*moe)
-        elapsed_ms = (self.stats.enter(FANOUT) - started) * 1000.0
+        elapsed_ms = (self._landed(seq, wave=True) - started) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
 
     # --------------------------------------------------- chunked admission
@@ -3910,7 +4046,9 @@ class InferenceEngine:
         tok_chunk = jnp.asarray(
             inf["arrays"]["tokens"][:, idx * chunk:(idx + 1) * chunk]
         )
-        self.stats.enter(ENQUEUE)
+        started = self.stats.enter(ENQUEUE, self._enq_seq + 1)
+        if self._empty_at is not None:  # the chunk is the first program after a drain
+            self._observe_gap(started)
         sk, sv, logits, *wstate = self._chunk_jit(chunk, R)(
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk),
             *self._wave_state_args(inf), **self._moe_kw(inf, decode=False),
@@ -3952,23 +4090,24 @@ class InferenceEngine:
             args += self._paged_wave_args(wave, bucket)
         # the landing's inputs count as its enqueue: the launch that came
         # just before it left the clock in that phase
-        self.stats.enter(ENQUEUE)
+        self.stats.enter(ENQUEUE, self._enq_seq + 1)
         (
             self._k, self._v, tables, self._last, self._lens,
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
             *landed,
         ) = fn(*args, **self._state_kw(inf["wstate"]))
-        self._note_state_landed(landed, wave)
+        seq = self._enq_seq
+        self._note_state_landed(landed)
         if self._paged:
             self._tables = tables
-        self.stats.enter(SYNC)
+        self._sync_began = self.stats.enter(SYNC, seq)
         # blocking-ok: the prefill wave's designated LANDING sync — first
         # tokens must reach the host here for delivery and real TTFT
         # attribution; this is the admission lane's _sync_host analog
         firsts = np.asarray(firsts)  # sync before timing (real latency)
         if self._moe:  # the wave's chunks ran before the landing just synced
             self._note_moe(*inf["wmoe"])
-        elapsed_ms = (self.stats.enter(FANOUT) - inf["started"]) * 1000.0
+        elapsed_ms = (self._landed(seq, wave=True) - inf["started"]) * 1000.0
         self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
         if self._prefix is not None:
             for request in wave:
@@ -4096,21 +4235,19 @@ class InferenceEngine:
         tok_chunk = jnp.asarray(
             inf["arrays"]["tokens"][:, idx * chunk:(idx + 1) * chunk]
         )
-        started = self.stats.enter(ENQUEUE)
-        self._observe_gap(started)
-        self._journal.append(
-            flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
-        )
+        started, queued = self._open_launch(steps)
         self._journal.append(
             flightrec.EV_RAGGED_WAVE, None, -1, len(self._active), R
         )
         # a model with recurrent layers: the slots' state goes in after the
         # chunk's arguments and comes back after ``done``; the wave's state
         # comes back last
-        res = list(self._ragged_jit(window, steps, sampled, chunk, R)(
+        program = self._ragged_jit(window, steps, sampled, chunk, R)
+        res = list(program(
             *args, sk, sv, tok_chunk, jnp.int32(idx * chunk),
             *_some(self._state), *self._wave_state_args(inf), **self._moe_kw(inf),
         ))
+        seq = self._note_launch("ragged", program, steps, started, queued, R, R * chunk)
         if self._moe:
             inf["wmoe"] = res.pop()
         if self._recurrent:
@@ -4127,8 +4264,10 @@ class InferenceEngine:
         )
         self.stats.prefill_absorbed_tokens += R * chunk
         self.stats.unified_dispatches += 1
-        self._stage_pend(toks, n_valid, done, steps, started, extra_rows=R, moe=moe)
+        self._stage_pend(toks, n_valid, done, steps, started, seq, extra_rows=R, moe=moe)
         if inf["idx"] == inf["n_chunks"]:
+            # the wave's landing sync is about to prove this dispatch too
+            self._unproved[-1]["wave_landed"] = 1
             return self._finalize_inflight(logits)
         return False
 
@@ -4217,13 +4356,15 @@ class InferenceEngine:
             if deliveries:
                 self._loop.call_soon_threadsafe(_deliver_batch, deliveries)
 
-    def _sync_host(self, arrays: Any) -> Any:
+    def _sync_host(self, arrays: Any, seq: "int | None" = None) -> Any:
         """THE designated device→host sync point of the dispatch loop —
         scripts/lint_hotpath.py bans blocking syncs everywhere else in the
         overlap-critical functions, so the double-buffering can't silently
         regress to one-sync-per-launch.  The phase clock reads ``sync``
-        from here until the caller enters ``fanout``."""
-        self.stats.enter(SYNC)
+        from here until the caller enters ``fanout`` (:meth:`_landed`, where
+        ``seq``, the number of the program that made ``arrays``, is booked
+        as proved; the long lane's stream keeps no such account)."""
+        self._sync_began = self.stats.enter(SYNC, seq)
         if isinstance(arrays, tuple):
             # blocking-ok: THE designated sync point (see docstring)
             return tuple(np.asarray(a) for a in arrays)
@@ -4301,17 +4442,94 @@ class InferenceEngine:
         """The dispatch-gap bubble, observed immediately BEFORE each jit
         enqueue (``now``: the moment the clock entered ``enqueue``, after
         args prep — the device is idle through that prep too, so
-        observing at tick entry would under-report): zero while a
-        dispatch is already in flight (the device never idled), else the
-        host-side span since the previous dispatch landed, which also
-        adds up in ``starved_s``.  Reset across idle periods — an empty
+        observing at tick entry would under-report): the host-side span
+        since the sync that left the device known empty, which also adds up
+        in ``starved_s``; zero while a program is still queued (the device
+        never idled).  "Queued" is the device's queue (``_enq_seq`` against
+        ``_done_seq``), not what the host has yet to land: a wave's landing
+        sync proves the dispatch it rode AND the one before it, which the
+        host lands only afterwards.  Reset across idle periods — an empty
         engine waiting for work is not a bubble."""
-        if self._pend is not None:
+        empty_at = self._empty_at
+        if empty_at is None:
             self._observe("dispatch_gap_ms", 0.0)
-        elif self._last_sync_t is not None:
-            gap = now - self._last_sync_t
-            self.stats.starved_s += gap
-            self._observe("dispatch_gap_ms", gap * 1000.0)
+        else:
+            self._empty_at = None
+            self.stats.starved_s += now - empty_at
+            self._observe("dispatch_gap_ms", (now - empty_at) * 1000.0)
+
+    def _open_launch(self, steps: int) -> "tuple[float, int]":
+        """The clock's switch to ``enqueue`` for a dispatch's launch, ONE
+        copy for the four launches: the moment, and how many programs stand
+        enqueued and unproved before this one.  The gap is observed and the
+        journal told here, immediately before the jit call."""
+        queued = self._enq_seq - self._done_seq
+        started = self.stats.enter(ENQUEUE, self._enq_seq + 1)
+        self._observe_gap(started)
+        self._journal.append(
+            flightrec.EV_DISPATCH_LAUNCH, None, self._enq_seq + 1, steps, len(self._active)
+        )
+        return started, queued
+
+    def _landed(self, seq: int, wave: bool = False) -> float:
+        """The clock's switch from ``sync`` to ``fanout`` after a designated
+        sync on the output of program ``seq``; returns the moment.  Books
+        what the sync proved: ``_done_seq``, the ``engine.dispatch`` span of
+        every dispatch up to ``seq`` (ended HERE: its own landing, or an
+        earlier sync on a later program) and, where nothing is queued
+        behind it, a drain.  ``wave``: an admission wave's landing sync.
+        A sync on a program already proved (the host landing a dispatch
+        that a wave's landing sync covered) proves nothing new."""
+        now = self.stats.enter(FANOUT)
+        if seq <= self._done_seq:
+            return now
+        self._done_seq = seq
+        unproved = self._unproved
+        if unproved and unproved[0]["seq"] <= seq:
+            wait_ms = (now - self._sync_began) * 1000.0
+            while unproved and unproved[0]["seq"] <= seq:
+                self._end_dispatch_span(unproved.popleft(), now, seq, wait_ms)
+        if seq == self._enq_seq and (wave or self._active):
+            # nothing queued behind it, and work to go on with: a drain
+            self._empty_at = now
+            self.stats.pipeline_drains += 1
+            self.stats.pipeline_drains_wave += wave
+        return now
+
+    def _end_dispatch_span(self, d: dict, now: float, by: int, wait_ms: float) -> None:
+        """One ``engine.dispatch`` span, recorded whole at the sync that
+        proved the dispatch complete (``by``: that sync's program; dispatches
+        that share it ended together, and only their sum is known).  A root
+        span: a dispatch serves many requests.  ``exclusive_ms`` is the wall
+        this dispatch alone occupied: its end less the later of its enqueue
+        and the previous dispatch's end."""
+        began = d.pop("started")
+        exclusive_ms = (now - max(began, self._proved_at)) * 1000.0
+        self._proved_at = now
+        if not TRACER.enabled:
+            return
+        TRACER.start_span(
+            "engine.dispatch", kind="engine", emitter=self._emitter, attrs=d, at=began,
+        ).end(at=now, proved_by=by, wait_ms=wait_ms, exclusive_ms=exclusive_ms)
+
+    def _note_launch(
+        self, kind: str, program: "_Program", steps: int, started: float,
+        queued_behind: int, chunk_rows: int = 0, chunk_tokens: int = 0,
+    ) -> int:
+        """A dispatch was just enqueued: open its ``engine.dispatch`` span
+        (plain numbers, kept until the sync that proves it) and return its
+        number.  ``queued_behind``: programs enqueued and not proved
+        complete BEFORE this one (0: the device was known empty)."""
+        seq = self._enq_seq
+        built = getattr(program, "built_seq", None) == seq  # (a test's stand-in has none)
+        self._unproved.append(dict(
+            seq=seq, kind=kind, steps=steps, rows=len(self._active),
+            chunk_rows=chunk_rows, chunk_tokens=chunk_tokens, wave_landed=0,
+            first_use=int(built), build_ms=program.built_s * 1000.0 if built else 0.0,
+            queued_behind=queued_behind,
+            enqueue_ms=(time.perf_counter() - started) * 1000.0, started=started,
+        ))
+        return seq
 
     def _launch_decode(self) -> None:
         """Enqueue the next decode dispatch — NO host sync.  The previous
@@ -4322,21 +4540,19 @@ class InferenceEngine:
         args, window, steps, sampled = self._decode_args()
         if steps < self.runtime.decode_steps_per_dispatch:
             self.stats.short_dispatches += 1
-        started = self.stats.enter(ENQUEUE)
-        self._observe_gap(started)
-        self._journal.append(
-            flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
-        )
+        started, queued = self._open_launch(steps)
+        program = self._decode_jit(window, steps, sampled)
         (
             self._k, self._v, self._last, self._lens, toks, n_valid, done,
             *state,
-        ) = self._decode_jit(window, steps, sampled)(
-            *args, *_some(self._state), **self._moe_kw())
-        self._stage_pend(toks, n_valid, done, steps, started, moe=self._keep_carried(state))
+        ) = program(*args, *_some(self._state), **self._moe_kw())
+        seq = self._note_launch("decode", program, steps, started, queued)
+        self._stage_pend(
+            toks, n_valid, done, steps, started, seq, moe=self._keep_carried(state))
 
     def _stage_pend(
         self, toks: Any, n_valid: Any, done: Any, steps: int,
-        started: float, extra_rows: int = 0, moe: Any = None,
+        started: float, seq: int, extra_rows: int = 0, moe: Any = None,
     ) -> None:
         """Record a just-enqueued dispatch as the in-flight pend (host
         lens advance + the landing's snapshot) — ONE copy shared by the
@@ -4351,6 +4567,7 @@ class InferenceEngine:
             done_dev=done,
             steps=steps,
             started=started,
+            seq=seq,  # its number on the device's queue
             participants=list(self._active.items()),
             slot_set=set(self._active.keys()),
             deferred=[],
@@ -4371,11 +4588,12 @@ class InferenceEngine:
         consumer never observes completion before accounting settles."""
         block, n_valid, done, *moe = self._sync_host(
             (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"],
-             *(pend["moe_dev"] or ()))
+             *(pend["moe_dev"] or ())),
+            pend["seq"],
         )
         if moe:
             self._note_moe(*moe, decode=True)
-        now = self.stats.enter(FANOUT)
+        now = self._landed(pend["seq"])
         # exclusive wall: the launch happened before the PREVIOUS sync
         # returned, so clip to the span this dispatch alone occupied —
         # decode_time_s must keep approximating device-busy time, not
@@ -4415,7 +4633,7 @@ class InferenceEngine:
         if wasted:
             self.stats.overlap_wasted_tokens += wasted
         self._journal.append(
-            flightrec.EV_DISPATCH_LAND, None, -1, steps, wasted
+            flightrec.EV_DISPATCH_LAND, None, pend["seq"], steps, wasted
         )
         self._free_deferred(pend)
         if not self._active:
@@ -4448,26 +4666,23 @@ class InferenceEngine:
         overlapped path must produce byte-identical token streams; keep
         this oracle intact."""
         args, window, steps, sampled = self._decode_args()
-        started = self.stats.enter(ENQUEUE)
-        self._observe_gap(started)
-        self._journal.append(
-            flightrec.EV_DISPATCH_LAUNCH, None, -1, steps, len(self._active)
-        )
+        started, queued = self._open_launch(steps)
+        program = self._decode_jit(window, steps, sampled)
         (
             self._k, self._v, self._last, self._lens, toks, _n_valid, _done,
             *state,
-        ) = self._decode_jit(window, steps, sampled)(
-            *args, *_some(self._state), **self._moe_kw())
+        ) = program(*args, *_some(self._state), **self._moe_kw())
+        seq = self._note_launch("decode", program, steps, started, queued)
         moe = self._keep_carried(state)
         for slot in self._active:
             self._host_lens[slot] += steps
-        block = self._sync_host(toks)  # [steps, B] — THE host sync per dispatch
+        block = self._sync_host(toks, seq)  # [steps, B] — THE host sync per dispatch
         if moe is not None:
             self._note_moe(*moe, decode=True)
-        self._last_sync_t = self.stats.enter(FANOUT)
+        self._last_sync_t = self._landed(seq)
         elapsed = self._last_sync_t - started
         self._note_dispatch(elapsed, steps)
-        self._journal.append(flightrec.EV_DISPATCH_LAND, None, -1, steps, 0)
+        self._journal.append(flightrec.EV_DISPATCH_LAND, None, seq, steps, 0)
         if steps < self.runtime.decode_steps_per_dispatch:
             self.stats.short_dispatches += 1
         # fan tokens out with ONE event-loop marshal per dispatch: a
@@ -4658,11 +4873,7 @@ class InferenceEngine:
             not self._effective_sampling(r).is_greedy
             for r in self._active.values()
         )
-        started = self.stats.enter(ENQUEUE)
-        self._observe_gap(started)  # just before enqueue: drafting is prep too
-        self._journal.append(
-            flightrec.EV_DISPATCH_LAUNCH, None, -1, S, len(self._active)
-        )
+        started, queued = self._open_launch(S)  # drafting was prep too
         args = [self.params, self._k, self._v]
         if self._paged:
             args.append(self._tables)
@@ -4678,14 +4889,16 @@ class InferenceEngine:
             self._top_k,
             self._top_p,
         ]
+        program = self._verify_jit(window, S, sampled)
         (
             self._k, self._v, self._last, self._lens, out_toks, emitted,
             n_valid, done,
-        ) = self._verify_jit(window, S, sampled)(*args)
+        ) = program(*args)
+        seq = self._note_launch("verify", program, S, started, queued)
         out_toks, emitted, n_valid, done = self._sync_host(
-            (out_toks, emitted, n_valid, done)
+            (out_toks, emitted, n_valid, done), seq
         )  # [B, S] + retirement arrays — THE host sync
-        self._last_sync_t = self.stats.enter(FANOUT)
+        self._last_sync_t = self._landed(seq)
         elapsed = self._last_sync_t - started
         # clock: one verify forward ≈ one decode step of wall time; the
         # heap horizon only drives the non-spec short-dispatch lever, so

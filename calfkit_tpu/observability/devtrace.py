@@ -17,6 +17,13 @@ two things the program puts on the record (ISSUE 24):
 - ``engine.<phase>`` host annotations from the engine's phase clock
   (``EngineStats.enter``): exclusive, so every idle nanosecond of the
   device falls under at most one of them.
+- the ``seq`` those of ``enqueue`` and ``sync`` carry as metadata (ISSUE
+  36): the number the engine gave the program it was about to enqueue, or
+  was waiting for.  It joins the host's clock to the device's module runs,
+  which tells an idle gap in front of a program the host had ALREADY
+  enqueued (``queued``: launch latency, input transfer) from one in front
+  of a program it had not (``drained``: the host was late), and gives
+  ``dispatches``: one row a program, by its number.
 
 A program loaded from a persistent compile cache that another build
 filled carries THAT build's scope names (JAX leaves operation metadata
@@ -73,9 +80,16 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 MAX_SECONDS = 60.0
 
+# the XLA modules of the programs the engine numbers (``engine._Program``:
+# the jitted functions' own names; tests/test_devtrace.py holds them to it)
+PROGRAM_MODULE = re.compile(
+    r"^jit_(decode|ragged_paged|ragged_dense|verify|finalize|chunk_step|seed|prefill)$")
+ENQUEUE, SYNC = HOST_PREFIX + "enqueue", HOST_PREFIX + "sync"
+QUEUED, DRAINED, UNJOINED = "queued", "drained", "unjoined"
+
 Op = tuple  # (plane, name, scope path, start_ns, duration_ns)
 Module = tuple  # (plane, name, start_ns, duration_ns)
-Host = tuple  # (name, start_ns, duration_ns)
+Host = tuple  # (name, start_ns, duration_ns[, seq])
 
 
 class CaptureBusy(RuntimeError):
@@ -219,6 +233,7 @@ def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
             stat_names[key] = next(
                 (_text(v) for f3, v in _fields(value) if f3 == 2), "")
         tf_op = {k for k, v in stat_names.items() if v == "tf_op"}
+        seq_stat = {k for k, v in stat_names.items() if v == "seq"}
         events: dict[int, tuple[str, str]] = {}  # metadata id -> (name, scope path)
         for entry in event_md:
             key, value = _map_entry(entry)
@@ -256,12 +271,24 @@ def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
                 start = t0_ns + meta.get(2, 0) // 1000
                 duration = meta.get(3, 0) // 1000
                 if not device:
-                    host.append((known[0], start, duration))
+                    seq = _seq_of(ev, seq_stat)
+                    host.append((known[0], start, duration, *(() if seq is None else (seq,))))
                 elif line_name == OPS_LINE:
                     ops.append((name, known[0], known[1], start, duration))
                 else:
                     modules.append((name, known[0], start, duration))
     return ops, modules, host
+
+
+def _seq_of(event: Any, seq_stat: set) -> "int | None":
+    """The ``seq`` an annotation carries (XEvent.stats=4; XStat:
+    metadata_id=1 uint64_value=3 int64_value=4), or None."""
+    for field, v in _fields(event) if seq_stat else ():
+        if field == 4:
+            stat = dict(_fields(v))
+            if stat.get(1) in seq_stat:
+                return stat.get(4, stat.get(3))
+    return None
 
 
 # ------------------------------------------------------------ reducing
@@ -298,7 +325,7 @@ def _gaps_by_phase(gaps: list[tuple[int, int]], host: list[Host]) -> dict[str, f
     """Idle nanoseconds by the ``engine.<phase>`` annotation they fall
     under.  The phases are exclusive, so a gap is split exactly; what no
     annotation covers is ``unattributed``."""
-    spans = sorted((s, s + d, n) for n, s, d in host if d > 0)
+    spans = sorted((h[1], h[1] + h[2], h[0]) for h in host if h[2] > 0)
     starts = [s for s, _, _ in spans]
     acc: dict[str, float] = defaultdict(float)
     for a, b in gaps:
@@ -313,6 +340,79 @@ def _gaps_by_phase(gaps: list[tuple[int, int]], host: list[Host]) -> dict[str, f
         if b - a > covered:
             acc[UNATTRIBUTED] += (b - a - covered) / 1e9
     return dict(acc)
+
+
+def _join_programs(modules: list[Module], host: list[Host]) -> "list[tuple[int, Module]]":
+    """The engine's number of each program run of one device, in order.
+    The device runs the engine's programs in the order it enqueued them, so
+    run ``i`` is program ``offset + i`` and only the offset is unknown.  Two
+    things bound it: the run of a program starts after the ``enqueue``
+    annotation that carries its number began, and has ended when the
+    ``sync`` annotation that carries its number ends.  The offset that
+    breaks fewest of them wins (none, on a whole capture); [] where no
+    annotation carries a number (a program without the account)."""
+    runs = sorted((m for m in modules if PROGRAM_MODULE.match(m[1].split("(", 1)[0].strip())),
+                  key=lambda m: m[2])
+    marks = [h for h in host if len(h) > 3 and h[0] in (ENQUEUE, SYNC)]
+    if not runs or not marks:
+        return []
+    ends = [m[2] + m[3] for m in runs]
+    candidates = set()
+    for name, start, duration, seq in marks:
+        if name == SYNC:  # the last run complete when the sync returned
+            i = bisect.bisect_right(ends, start + duration) - 1
+            candidates.update(seq - j for j in (i - 1, i, i + 1))
+
+    def broken(offset: int) -> int:
+        n = 0
+        for name, start, duration, seq in marks:
+            i = seq - offset
+            if 0 <= i < len(runs):
+                n += runs[i][2] < start if name == ENQUEUE else ends[i] > start + duration
+        return n
+
+    if not candidates:
+        return []
+    offset = min(sorted(candidates), key=broken)
+    return [(offset + i, m) for i, m in enumerate(runs)]
+
+
+def _dispatch_rows(
+    joined: "list[tuple[int, Module]]", gaps: list[tuple[int, int]], host: list[Host],
+) -> "tuple[list[dict], dict[str, float], dict[str, float]]":
+    """One row a program run (its number, module, device seconds, the idle
+    gap in front of it and that gap's class), the idle seconds by class,
+    and the ``drained`` ones by ``engine.<phase>``.  A gap is ``queued``
+    where the ``enqueue`` phase that put the next program on the queue had
+    ENDED when the gap began, ``drained`` where it had not (the host had
+    yet to enqueue it, or was in the call), ``unjoined`` where no numbered
+    program follows it in the capture."""
+    phases = sorted((h[3], h[1] + h[2]) for h in host if len(h) > 3 and h[0] == ENQUEUE)
+    firsts = [seq for seq, _ in phases]
+    starts = [m[2] for _, m in joined]
+    by_class: dict[str, float] = defaultdict(float)
+    drained: list[tuple[int, int]] = []
+    before: dict[int, list] = {}  # seq -> [idle seconds in front of it, class of their first]
+    for a, b in gaps:
+        i = bisect.bisect_left(starts, a)  # the next numbered program to start
+        kind = UNJOINED
+        if i < len(joined):
+            seq = joined[i][0]
+            j = bisect.bisect_right(firsts, seq) - 1  # the phase that enqueued it
+            if j >= 0:
+                kind = QUEUED if phases[j][1] <= a else DRAINED
+            # an eager operation of the host's (a fresh scratch's zeros) may
+            # split the idle in front of a program: the pieces add up
+            before.setdefault(seq, [0.0, kind])[0] += (b - a) / 1e9
+        by_class[kind] += (b - a) / 1e9
+        if kind == DRAINED:
+            drained.append((a, b))
+    rows = []
+    for seq, m in joined:
+        idle_s, kind = before.get(seq, (0.0, None))
+        rows.append({"seq": seq, "module": m[1].split("(", 1)[0].strip(),
+                     "device_s": m[3] / 1e9, "gap_before_s": idle_s, "gap": kind})
+    return rows, dict(by_class), _gaps_by_phase(drained, host)
 
 
 def _sorted(acc: dict[str, float]) -> dict[str, float]:
@@ -343,6 +443,8 @@ def reduce_trace(ops: list[Op], modules: list[Module], host: list[Host], window_
     for m in modules:
         by_module[m[1].split("(", 1)[0].strip()] += m[3] / 1e9 / len(planes)
     gap_s = _gaps_by_phase(first_gaps, host)
+    dispatches, gap_class_s, gap_drained_s = _dispatch_rows(
+        _join_programs([m for m in modules if m[0] == planes[0]], host), first_gaps, host)
     out.update(
         busy_s=busy_s,
         idle_pct=100.0 * (1.0 - busy_s / window_s) if window_s > 0 else None,
@@ -353,5 +455,10 @@ def reduce_trace(ops: list[Op], modules: list[Module], host: list[Host], window_
         gap_s=_sorted(gap_s),
         gap_unattributed_pct=(
             100.0 * gap_s.get(UNATTRIBUTED, 0.0) / sum(gap_s.values()) if gap_s else None),
+        # the same idle seconds by what stood on the device's queue, the
+        # drained ones by phase, and one row a numbered program run
+        gap_class_s=_sorted(gap_class_s),
+        gap_drained_s=_sorted(gap_drained_s),
+        dispatches=dispatches,
     )
     return out

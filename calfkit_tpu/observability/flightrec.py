@@ -72,8 +72,8 @@ EV_PAGE_FREE = 7  # a slot's page reservation freed
 EV_PAGE_EVICT = 8  # prefix-cache eviction ran      a=pages_needed
 EV_PREFIX_ACQ = 9  # shared-prefix pages acquired   a=pages
 EV_PREFIX_REL = 10  # shared-prefix pages released  a=pages
-EV_DISPATCH_LAUNCH = 11  # decode dispatch enqueued a=steps b=rows
-EV_DISPATCH_LAND = 12  # decode dispatch synced     a=steps b=wasted
+EV_DISPATCH_LAUNCH = 11  # decode dispatch enqueued a=steps b=rows    slot=its seq
+EV_DISPATCH_LAND = 12  # decode dispatch synced     a=steps b=wasted  slot=its seq
 EV_SPEC_TICK = 13  # speculative verify dispatch    a=proposed b=emitted
 EV_RETIRE = 14  # request retired (resources freed) a=generated
 EV_RETIRE_DEFER = 15  # retired; frees deferred to the in-flight landing
@@ -158,6 +158,10 @@ _BATCH_EVENTS = {
 }
 # slot-scoped events included when their slot matches the request's
 _SLOT_EVENTS = {"PAGE_FREE", "SLOT_FREE"}
+# a dispatch holds no slot: the field carries its number on the device's
+# queue (the engine's ``_enq_seq``, the ``seq`` of its ``engine.dispatch``
+# span), so a timeline and a wedge dump name the dispatch the trace names
+SEQ_EVENTS = {"DISPATCH_LAUNCH", "DISPATCH_LAND"}
 
 
 # process-wide registry of live journals: what SIGUSR2 and the /flightrec

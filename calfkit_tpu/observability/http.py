@@ -24,7 +24,14 @@ responder serving:
   :mod:`calfkit_tpu.observability.devtrace` to JSON: device busy and idle
   share, device seconds by the program's named scopes and by XLA module,
   idle gaps by the engine phase that covers them.  The request lasts N
-  seconds; a second one meanwhile gets ``409``.
+  seconds; a second one meanwhile gets ``409``.  Since ISSUE 36 also the
+  idle seconds by what stood on the device's queue (``gap_class_s``:
+  ``queued`` / ``drained``), the drained ones by phase and ``dispatches``,
+  one row a numbered program run;
+- ``GET /programs`` — every live engine's jit caches as a table
+  (:meth:`InferenceEngine.programs`): family, key, how often JAX built
+  under it and the seconds that took (compile or cache load), its number
+  on the device's queue at its first call and newest build, uses.
 
 This is an OPTIONAL operator convenience — nothing in the serving path
 depends on it — so every failure mode closes the offending connection and
@@ -36,6 +43,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import sys
 from typing import Any, Callable
 from urllib.parse import parse_qs
 
@@ -159,6 +167,13 @@ class MetricsServer:
                     "text/plain",
                 )
             return text.encode("utf-8"), "200 OK", "application/x-ndjson"
+        if path == "/programs":
+            # only a process that built an engine has the module (and JAX)
+            module = sys.modules.get("calfkit_tpu.inference.engine")
+            tables = module.programs_of_all_engines() if module else []
+            if not tables:
+                return b"no engines registered\n", "404 Not Found", "text/plain"
+            return (json.dumps(tables) + "\n").encode("utf-8"), "200 OK", "application/json"
         return b"not found\n", "404 Not Found", "text/plain"
 
     async def _profile(self, query: str) -> "tuple[bytes, str, str]":

@@ -40,6 +40,7 @@ __all__ = [
     "TRACER",
     "current_context",
     "collect_spans",
+    "detach_spans",
     "release_spans",
 ]
 
@@ -95,6 +96,14 @@ def collect_spans() -> "tuple[list[SpanRecord], Token]":
     return sink, _span_sink.set(sink)
 
 
+def detach_spans() -> None:
+    """From here on, THIS context has no hop-local sink: for a long-lived
+    task that was started inside a hop (its context is a copy of the
+    hop's) and ends spans of its own, which belong to no hop (the engine's
+    dispatch loop; the ring still gets them)."""
+    _span_sink.set(None)
+
+
 def release_spans(token: Token) -> None:
     try:
         _span_sink.reset(token)
@@ -119,6 +128,7 @@ class Span:
         kind: str = "internal",
         emitter: str = "",
         attrs: dict[str, Any] | None = None,
+        at: float | None = None,
     ):
         self._tracer = tracer
         self.name = name
@@ -129,6 +139,9 @@ class Span:
         self.status = "ok"
         self.start_s = time.time()
         self._t0 = time.perf_counter()
+        if at is not None:  # begun earlier than it is recorded (see ``end``)
+            self.start_s -= self._t0 - at
+            self._t0 = at
         self._ended = False
 
     def set_attr(self, key: str, value: Any) -> None:
@@ -189,10 +202,14 @@ class Tracer:
         kind: str = "internal",
         emitter: str = "",
         attrs: dict[str, Any] | None = None,
+        at: float | None = None,
     ) -> Span:
         """New span.  With ``parent``, the child joins that trace; without,
         a new trace is minted (``trace_id`` pins it — the client passes the
-        correlation id so trace lookup needs no extra bookkeeping)."""
+        correlation id so trace lookup needs no extra bookkeeping).  ``at``
+        is the ``time.perf_counter`` moment the operation really began, for
+        a span recorded later than that (the engine records a dispatch's
+        span at the sync that proves it complete)."""
         if parent is not None:
             context = TraceContext(
                 trace_id=parent.trace_id,
@@ -205,7 +222,8 @@ class Tracer:
                 span_id=new_span_id(),
             )
         return Span(
-            self, name, context=context, kind=kind, emitter=emitter, attrs=attrs
+            self, name, context=context, kind=kind, emitter=emitter, attrs=attrs,
+            at=at,
         )
 
     def export(self, record: SpanRecord) -> None:

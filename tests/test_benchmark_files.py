@@ -570,7 +570,9 @@ def test_the_manifest_carries_qwen_s_cell_and_its_two_metrics():
             "moe_expert_load_ratio", "dispatch_roofline", "hbm_peak_gb", "batch_occupancy_pct",
             "empty_slot_queued_pct", "kv_pages_peak_pct"} <= registered
     assert not {"ssm_state_roofline", "ssm_device_pct", "mla_cache_roofline"} & registered
-    assert [m["name"] for m in MANIFEST["per_layer"]][-2:] == ["gdn_device_pct", "gdn_state_roofline"]
+    names = [m["name"] for m in MANIFEST["per_layer"]]  # appended in PR 33; PR 36's four came after
+    at = names.index("gdn_device_pct")
+    assert names[at:at + 2] == ["gdn_device_pct", "gdn_state_roofline"] and at == 16
     assert MANIFEST["workloads"][-1]["name"] == QWEN_CELL and len(MANIFEST["workloads"]) == 4
     entry = MANIFEST["configs"][-1]
     assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]

@@ -189,7 +189,7 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
     want = reference_logits(params, config, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
-    assert counters["state_rows_landed"] == 1
+    assert counters["pipeline_drains_wave"] == 1
     assert counters["recurrent_state_bytes"] == config.recurrent_state_bytes(2)
     assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
@@ -247,7 +247,7 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
     spy = Spy(monkeypatch)
     (_, out), _, counters = serve((TOY, runtime(max_batch_size=1)), [(first, 9), (second, 9)])
     reused = [s for s in spy.seen][-8:]
-    assert counters["state_rows_landed"] == 2
+    assert counters["pipeline_drains_wave"] == 2
     spy.seen.clear()
     (fresh_out,), _, _ = serve((TOY, runtime(max_batch_size=1)), [(second, 9)])
     fresh = spy.seen[-8:]
@@ -415,7 +415,7 @@ def test_a_cached_prefix_is_declined_and_counted():
     assert a == b
     assert counters["prefix_reuse_declined_recurrent"] == 1
     assert counters["prefix_hits"] == 0 and counters["prefix_reused_tokens"] == 0
-    assert counters["state_rows_landed"] == 2
+    assert counters["pipeline_drains_wave"] == 2
 
 
 def test_the_new_counters_reach_metrics():
@@ -423,7 +423,7 @@ def test_the_new_counters_reach_metrics():
 
     serve((TOY, runtime()), [(prompt_of(20), 3)])
     text = metrics_text()
-    for name in ("calfkit_engine_state_rows_landed_total",
+    for name in ("calfkit_engine_pipeline_drains_wave_total",
                  "calfkit_engine_prefix_reuse_declined_recurrent_total",
                  "calfkit_engine_recurrent_state_bytes"):
         assert name in text
