@@ -134,7 +134,8 @@ def render_stats_table(records: "Iterable[EngineStatsRecord]") -> str:
         )
         # overlapped execution health: the p99 device-idle bubble before a
         # dispatch (0 while a program stays queued; what a drained pipeline
-        # shows after a wave's landing sync is the host's work to the next
+        # shows, after the landing sync of a wave that had no dispatch to
+        # ride or after a lockstep sync, is the host's work to the next
         # enqueue) and the pad tokens one-dispatch-late retirement discarded
         gap = (
             f"{lat.get('dispatch_gap_p99', 0):.2f}"

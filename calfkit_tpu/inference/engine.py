@@ -118,8 +118,8 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "prefix_reuse_declined_recurrent",
-    "pipeline_drains", "pipeline_drains_wave", "programs_built",
-    "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
+    "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
+    "programs_built", "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
     "moe_dense_chunks", *_SECONDS_FIELDS,
 )
@@ -249,7 +249,15 @@ def _engine_metrics(
         ),
         pipeline_drains_wave=reg.counter(
             "calfkit_engine_pipeline_drains_wave_total",
-            "those of them that were an admission wave's landing sync",
+            "those of them that were an admission wave's landing sync: a wave "
+            "that landed with no dispatch to ride (an engine with no active "
+            "rows, the legacy and speculative lanes)",
+        ),
+        wave_landings_deferred=reg.counter(
+            "calfkit_engine_wave_landings_deferred_total",
+            "admission waves whose first tokens came down with the landing of "
+            "the dispatch that carried their last chunk, a later dispatch "
+            "already queued behind it: no drain",
         ),
         programs_built=reg.counter(
             "calfkit_engine_programs_built_total",
@@ -645,10 +653,15 @@ class EngineStats:
     # the host's bookkeeping of what it has landed
     starved_s: float = 0.0
     # the syncs that left it so, and those of them that were a wave's
-    # landing sync (the launch that carried a wave's last chunk goes
-    # straight on into the landing, which waits for everything queued)
+    # landing sync: a wave that landed with no dispatch to ride (onto an
+    # engine with no active rows; the legacy and speculative lanes)
     pipeline_drains: int = 0
     pipeline_drains_wave: int = 0
+    # the waves whose first tokens came down with the landing of the dispatch
+    # that carried their last chunk, the next dispatch already queued behind
+    # it: the landings that were NOT a drain.  The engaged share is
+    # deferred / (deferred + pipeline_drains_wave)
+    wave_landings_deferred: int = 0
     # calls in which JAX built a program (a jit key's first use, or a later
     # one with arguments of another kind under it): how many, and their
     # seconds (trace + lower + compile, or a cache load; ``enqueue`` holds
@@ -1774,10 +1787,14 @@ class InferenceEngine:
         return min(remaining, seq_room)
 
     def _track_retirement(self, request: GenRequest) -> None:
-        """Register an activated request's bound-retirement horizon."""
+        """Register an activated request's bound-retirement horizon.  A row
+        activated with its wave's landing still riding a dispatch has its
+        first token on the device yet (``generated`` 0): that token counts,
+        so the horizon is the one ``_record_token`` will retire it at."""
         with self._retire_lock:
             entry = [
-                self._decode_clock + self._retirement_bound(request),
+                self._decode_clock + self._retirement_bound(request)
+                - (request.generated == 0),
                 next(self._retire_seq),
                 request,
             ]
@@ -2097,8 +2114,9 @@ class InferenceEngine:
         """Terminate every waiter: active slots AND still-queued requests
         (a queued request left without _DONE hangs its generate() forever)."""
         if self._pend is not None:
-            # abandon the in-flight dispatch; its deferred frees must
-            # still run or the slots/pages leak into the next start()
+            # abandon the in-flight dispatch (and a wave's landing riding
+            # it: its rows are active, and end below); its deferred frees
+            # must still run or the slots/pages leak into the next start()
             self._free_deferred(self._pend)
             self._pend = None
         for request in list(self._active.values()):
@@ -3421,7 +3439,10 @@ class InferenceEngine:
         for request in wave:
             # a request can retire DURING its own prefill (first token
             # was a stop, or max_new_tokens == 1): _record_token already
-            # freed its slot and set slot = -1 — don't resurrect it
+            # freed its slot and set slot = -1 — don't resurrect it.  (A
+            # wave whose landing rides a dispatch has no first tokens down
+            # yet: such a row is activated, rides the next dispatch, and
+            # retires at that landing by the deferred path.)
             if request.slot == -1:
                 continue
             if request.cancelled:
@@ -3900,10 +3921,15 @@ class InferenceEngine:
         self, wave: list[GenRequest], true_lens: np.ndarray,
         firsts: np.ndarray, elapsed_ms: float,
     ) -> None:
-        """Host side of the wave landing: stats, host-mirror lens, and the
-        first-token emission — batched into ONE event-loop marshal for the
-        whole wave.  The device-side last/lens scatter happens inside the
-        prefill jit (``_finalize_wave_math``)."""
+        """Host side of the wave landing: stats and the first-token
+        emission — batched into ONE event-loop marshal for the whole wave.
+        The device-side last/lens scatter happens inside the prefill jit
+        (``_finalize_wave_math``); the host's mirror of the lens followed
+        its enqueue (:meth:`_mirror_wave_lens`).  Where the landing rode a
+        dispatch the wave's rows are already active and in the NEXT
+        dispatch: a row that retires here (a first token that is a stop,
+        ``max_new_tokens == 1``) takes ``_retire_slot``'s deferred path,
+        and that dispatch's column for it is discarded."""
         deliveries: list[tuple[asyncio.Queue, list]] = []
         self._note_progress()  # a wave landing is watchdog progress
         self._observe("prefill_ms", elapsed_ms)
@@ -3922,14 +3948,37 @@ class InferenceEngine:
             ttft_ms = (now - request.started_at) * 1000.0
             self._observe("ttft_ms", ttft_ms)
             self._observe("queue_wait_ms", max(0.0, ttft_ms - elapsed_ms))
-            # the prompt occupies [0, true_len); decode inserts from true_len
-            self._host_lens[request.slot] = int(true_lens[r])
             items: list = []
             self._record_token(request, int(firsts[r]), items)
             if items:
                 deliveries.append((request.out, items))
         if deliveries:
             self._loop.call_soon_threadsafe(_deliver_batch, deliveries)
+
+    def _mirror_wave_lens(self, wave: list[GenRequest], true_lens: np.ndarray) -> None:
+        """The host's mirror of the lens a landing program was just handed
+        to write: the prompt occupies [0, true_len), decode inserts from
+        true_len.  It follows the ENQUEUE, as ``_stage_pend``'s advance does:
+        the rows of a wave whose landing rides a dispatch are in the next
+        launch's window arithmetic before their first tokens are down."""
+        for r, request in enumerate(wave):
+            if request.slot != -1:
+                self._host_lens[request.slot] = int(true_lens[r])
+
+    def _wave_landed(self, landing: dict, firsts: np.ndarray, wmoe: Any, now: float) -> None:
+        """What follows the sync that brought a chunked wave's first tokens
+        down, ONE copy for the landing that was a sync of its own and the
+        one that rode a dispatch's (``landing``: what ``_finalize_inflight``
+        kept; ``now``: the moment ``_landed`` booked that sync): the wave's
+        expert counters, first-token delivery and prefix registration."""
+        if wmoe:  # the wave's chunks ran before the program just synced
+            self._note_moe(*wmoe)
+        wave = landing["wave"]
+        self._land_wave(
+            wave, landing["true_lens"], firsts, (now - landing["started"]) * 1000.0)
+        if self._prefix is not None:
+            for request in wave:
+                self._register_prefix_pages(request)
 
     def _prefill_wave(self, wave: list[GenRequest], bucket: int) -> None:
         R = len(wave)
@@ -3960,6 +4009,7 @@ class InferenceEngine:
         self._note_state_landed(landed)
         if self._paged:
             self._tables = tables
+        self._mirror_wave_lens(wave, arrays["true_lens"])
         # sync BEFORE timing: with async dispatch, fn() returns before the
         # device runs — prefill_ms must be real latency, not enqueue time
         self._sync_began = self.stats.enter(SYNC, seq)
@@ -4033,11 +4083,13 @@ class InferenceEngine:
             started=time.perf_counter(),
         )
 
-    def _advance_inflight(self) -> bool:
+    def _advance_inflight(self, ride: "dict | None" = None) -> bool:
         """Run one chunk of the inflight wave in its OWN device invocation
         (the legacy lane, and the ragged lane's fallback when the token
-        budget refuses absorption); finalize after the last.  Returns True
-        when the wave landed."""
+        budget refuses absorption); finalize after the last.  ``ride``: the
+        pend of a decode dispatch staged in THIS tick, for the wave's
+        landing to ride (:meth:`_finalize_inflight`).  Returns True when the
+        wave landed, or will with ``ride``'s landing."""
         inf = self._inflight
         chunk = inf["chunk"]
         R = len(inf["wave"])
@@ -4064,15 +4116,29 @@ class InferenceEngine:
         )
         if inf["idx"] < inf["n_chunks"]:
             return False
-        return self._finalize_inflight(logits)
+        return self._finalize_inflight(logits, ride)
 
-    def _finalize_inflight(self, logits: Any) -> bool:
-        """The chunked wave's landing (last chunk done): finalize jit,
-        first-token sync, prefix registration.  One host sync per WAVE —
-        shared by the legacy and ragged lanes.  ``logits`` is the final
-        chunk's output, passed through (never stored on the inflight
-        dict — a [R, chunk, vocab] buffer pinned between ticks would
-        double transient logits HBM on large-vocab configs)."""
+    def _finalize_inflight(self, logits: Any, ride: "dict | None" = None) -> bool:
+        """The chunked wave's landing (last chunk done): the finalize jit,
+        then the wave's first tokens — shared by the legacy and ragged
+        lanes.  ``logits`` is the final chunk's output, passed through
+        (never stored on the inflight dict — a [R, chunk, vocab] buffer
+        pinned between ticks would double transient logits HBM on
+        large-vocab configs).
+
+        ``ride`` is the pend of a dispatch staged in THIS tick (the fused
+        launch that carried the last chunk, or the plain decode launch its
+        own invocation followed).  With one, NO sync here: what the landing
+        needs hangs on that pend, the first tokens come down in the same
+        ``_sync_host`` as its token block one tick later
+        (:meth:`_land_decode`), with the next dispatch already queued, and
+        the serve loop activates the wave meanwhile — its rows decode from
+        the ``last`` the finalize program wrote, pure device dataflow, as
+        ``done_prev`` is.  A sync here would return onto an EMPTY device and
+        leave it so for a whole dispatch's worth of host work.  Without one
+        (a wave onto an engine with no active rows, the legacy chunked lane,
+        the drafter's lockstep lane) there is nothing to keep the device
+        busy behind the landing, and it stays the one host sync per wave."""
         inf = self._inflight
         wave, bucket = inf["wave"], inf["bucket"]
         arrays = inf["arrays"]
@@ -4100,18 +4166,25 @@ class InferenceEngine:
         self._note_state_landed(landed)
         if self._paged:
             self._tables = tables
+        self._mirror_wave_lens(wave, arrays["true_lens"])
+        landing = dict(
+            wave=wave, true_lens=arrays["true_lens"], firsts=firsts, seq=seq,
+            started=inf["started"], wmoe=inf["wmoe"],
+        )
+        if ride is not None:
+            ride["landing"] = landing
+            # the finalize program writes these rows' slots and pages: until
+            # it is proved, a row that retires frees them at ``ride``'s landing
+            ride["slot_set"].update(r.slot for r in wave if r.slot != -1)
+            # the dispatch whose landing brings the wave's first tokens
+            self._unproved[-1]["wave_landed"] = 1
+            return True
         self._sync_began = self.stats.enter(SYNC, seq)
-        # blocking-ok: the prefill wave's designated LANDING sync — first
-        # tokens must reach the host here for delivery and real TTFT
-        # attribution; this is the admission lane's _sync_host analog
+        # blocking-ok: the designated LANDING sync of a wave with no dispatch
+        # to ride — nothing else will bring its first tokens to the host for
+        # delivery and real TTFT attribution
         firsts = np.asarray(firsts)  # sync before timing (real latency)
-        if self._moe:  # the wave's chunks ran before the landing just synced
-            self._note_moe(*inf["wmoe"])
-        elapsed_ms = (self._landed(seq, wave=True) - inf["started"]) * 1000.0
-        self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
-        if self._prefix is not None:
-            for request in wave:
-                self._register_prefix_pages(request)
+        self._wave_landed(landing, firsts, landing["wmoe"], self._landed(seq, wave=True))
         return True
 
     # ------------------------------------------------- ragged unified waves
@@ -4154,8 +4227,11 @@ class InferenceEngine:
         """One tick of the unified lane (decode-thread context): launch
         the fused (or decode-only) dispatch, then land the previous one —
         the same double-buffered shape as :meth:`_decode_tick`, with the
-        admission wave riding the launch.  Returns True when the inflight
-        wave landed (the serve loop activates it)."""
+        admission wave riding the launch and its first tokens the landing.
+        Returns True when the inflight wave's finalize program is enqueued
+        (the serve loop activates the wave): its first tokens are down
+        already where there was no dispatch to ride, and come down with
+        THIS tick's dispatch, at the next tick's landing, where there was."""
         if self._drafter is not None:
             # speculation stays lockstep (the host drafter needs landed
             # history to propose), so there is no launch to fuse the
@@ -4218,13 +4294,15 @@ class InferenceEngine:
         wave is in flight and the token budget admits it, else plain
         decode (with the over-budget chunk advancing in its own
         invocation so admission never starves).  NO host sync anywhere on
-        this path; the fused outputs ride ``self._pend`` to the next
-        tick's landing exactly like a plain overlapped launch."""
+        this path, the landing of a wave whose last chunk it carries
+        included: the fused outputs, and that wave's first tokens, ride
+        ``self._pend`` to the next tick's landing exactly like a plain
+        overlapped launch."""
         inf = self._inflight
         if inf is None or not self._absorb_fits():
             self._launch_decode()
             if inf is not None:
-                return self._advance_inflight()
+                return self._advance_inflight(self._pend)
             return False
         args, window, steps, sampled = self._decode_args()
         if steps < self.runtime.decode_steps_per_dispatch:
@@ -4266,9 +4344,7 @@ class InferenceEngine:
         self.stats.unified_dispatches += 1
         self._stage_pend(toks, n_valid, done, steps, started, seq, extra_rows=R, moe=moe)
         if inf["idx"] == inf["n_chunks"]:
-            # the wave's landing sync is about to prove this dispatch too
-            self._unproved[-1]["wave_landed"] = 1
-            return self._finalize_inflight(logits)
+            return self._finalize_inflight(logits, self._pend)
         return False
 
     def _register_prefix_pages(self, request: GenRequest) -> None:
@@ -4446,10 +4522,11 @@ class InferenceEngine:
         since the sync that left the device known empty, which also adds up
         in ``starved_s``; zero while a program is still queued (the device
         never idled).  "Queued" is the device's queue (``_enq_seq`` against
-        ``_done_seq``), not what the host has yet to land: a wave's landing
-        sync proves the dispatch it rode AND the one before it, which the
-        host lands only afterwards.  Reset across idle periods — an empty
-        engine waiting for work is not a bubble."""
+        ``_done_seq``), not what the host has yet to land: the landing sync
+        of a wave with no dispatch to ride proves every program before it;
+        one that rides a dispatch is that dispatch's own landing and finds
+        the next one queued.  Reset across idle periods — an empty engine
+        waiting for work is not a bubble."""
         empty_at = self._empty_at
         if empty_at is None:
             self._observe("dispatch_gap_ms", 0.0)
@@ -4477,9 +4554,12 @@ class InferenceEngine:
         what the sync proved: ``_done_seq``, the ``engine.dispatch`` span of
         every dispatch up to ``seq`` (ended HERE: its own landing, or an
         earlier sync on a later program) and, where nothing is queued
-        behind it, a drain.  ``wave``: an admission wave's landing sync.
-        A sync on a program already proved (the host landing a dispatch
-        that a wave's landing sync covered) proves nothing new."""
+        behind it, a drain.  ``wave``: the sync brought an admission wave's
+        first tokens down (``seq`` its finalize program): a drain where it
+        was a sync of its own, a deferred landing where it rode a dispatch's
+        and found the next one queued.  A sync on a program already proved
+        (the host landing a dispatch that a wave's landing sync covered)
+        proves nothing new."""
         now = self.stats.enter(FANOUT)
         if seq <= self._done_seq:
             return now
@@ -4494,6 +4574,8 @@ class InferenceEngine:
             self._empty_at = now
             self.stats.pipeline_drains += 1
             self.stats.pipeline_drains_wave += wave
+        elif wave:
+            self.stats.wave_landings_deferred += 1
         return now
 
     def _end_dispatch_span(self, d: dict, now: float, by: int, wait_ms: float) -> None:
@@ -4573,6 +4655,9 @@ class InferenceEngine:
             deferred=[],
             extra_rows=extra_rows,
             moe_dev=moe,  # (counts, hit) of a model with routed experts
+            # a wave whose finalize program was enqueued straight behind
+            # this dispatch: what its landing needs (_finalize_inflight)
+            landing=None,
         )
 
     def _land_decode(self, pend: dict) -> "list[tuple[asyncio.Queue, list]]":
@@ -4585,15 +4670,26 @@ class InferenceEngine:
         their deferred slot/page frees released now that nothing in
         flight can touch them.  Returns the deliveries — the CALLER posts
         them, possibly after draining an all-zombie follow-up, so a
-        consumer never observes completion before accounting settles."""
-        block, n_valid, done, *moe = self._sync_host(
-            (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"],
-             *(pend["moe_dev"] or ())),
-            pend["seq"],
-        )
-        if moe:
-            self._note_moe(*moe, decode=True)
-        now = self._landed(pend["seq"])
+        consumer never observes completion before accounting settles.
+
+        A wave's landing riding this dispatch (``pend["landing"]``) comes
+        down in the SAME sync, on its finalize program, and is fanned out
+        FIRST: the wave's rows are in the dispatch after this one, so a
+        request's first token is recorded and delivered before any later
+        one of its own."""
+        landing, moe_dev = pend["landing"], pend["moe_dev"] or ()
+        arrays = (pend["toks_dev"], pend["n_valid_dev"], pend["done_dev"], *moe_dev)
+        seq = pend["seq"]
+        if landing is not None:
+            arrays += (landing["firsts"], *(landing["wmoe"] or ()))
+            seq = landing["seq"]
+        block, n_valid, done, *rest = self._sync_host(arrays, seq)
+        if moe_dev:
+            self._note_moe(*rest[:len(moe_dev)], decode=True)
+        now = self._landed(seq, wave=landing is not None)
+        if landing is not None:
+            firsts, *wmoe = rest[len(moe_dev):]
+            self._wave_landed(landing, firsts, wmoe, now)
         # exclusive wall: the launch happened before the PREVIOUS sync
         # returned, so clip to the span this dispatch alone occupied —
         # decode_time_s must keep approximating device-busy time, not

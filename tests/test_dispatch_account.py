@@ -3,9 +3,11 @@ enqueues has a number, every designated sync records the number it proved
 complete, and ``starved_s``, ``pipeline_drains`` and the ``engine.dispatch``
 span are read off those two integers.
 
-(a) a wave's last chunk rides a dispatch: the launch goes on into the wave's
-    landing sync, which waits for everything queued.  The device is empty
-    from there to the next enqueue, and the account says so;
+(a) a wave's last chunk rides a dispatch: the wave's first tokens come down
+    with that dispatch's landing (ISSUE 37), the next one queued behind it:
+    no drain, nothing starved.  A wave onto an engine with no active rows
+    has nothing to ride: its landing is a sync of its own, the device is
+    empty from there to the next enqueue, and the account says so;
 (b) a steady decode-only overlap: one program always queued, nothing starved;
 (c) lockstep: every dispatch drained;
 (d) ``programs()`` counts each jit key once, an engine its own.
@@ -27,8 +29,8 @@ from calfkit_tpu.observability.trace import TRACER, TraceContext, current_contex
 CFG = preset("debug")
 LONG = list(range(3, 23))  # 20 tokens: a bucket of 32, two chunks of 16
 SHORT = list(range(3, 13))  # 10 tokens: one chunk, so it is its wave's last
-ACCOUNT = ("starved_s", "pipeline_drains", "pipeline_drains_wave", "decode_dispatches",
-           "programs_built", "program_build_s")
+ACCOUNT = ("starved_s", "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
+           "decode_dispatches", "programs_built", "program_build_s")
 
 
 @pytest.fixture(scope="module")
@@ -70,7 +72,7 @@ async def _until(condition) -> None:
 
 
 class TestAWaveRidesADispatch:
-    async def test_the_landing_drains_the_device_and_the_account_says_so(self, params):
+    async def test_the_landing_rides_the_dispatch_and_nothing_drains(self, params):
         engine = InferenceEngine(CFG, _rt(), params=params)
         await engine.start()
         try:
@@ -90,25 +92,26 @@ class TestAWaveRidesADispatch:
         finally:
             await engine.stop()
         grew = _grew(before, after)
-        assert grew["pipeline_drains_wave"] == 1 and grew["programs_built"] == 0
+        # the wave's first tokens came down with the dispatch that carried its
+        # last chunk, the next dispatch queued behind it: no drain, nothing starved
+        assert grew["wave_landings_deferred"] == 1 and grew["pipeline_drains_wave"] == 0
+        assert grew["pipeline_drains"] == 0 and grew["starved_s"] == 0.0
+        assert grew["programs_built"] == 0
         spans = _dispatch_spans()
         (rode,) = [s for s in spans if s.attrs["wave_landed"]]
         assert rode.attrs["kind"] == "ragged" and rode.attrs["chunk_rows"] == 1
         assert rode.attrs["chunk_tokens"] == 16
-        # the landing's sync proved it, on the finalize program that followed it
+        # its own landing proved it, by the sync on the finalize program behind it
         assert rode.attrs["proved_by"] == rode.attrs["seq"] + 1
         after_it = next(s for s in spans if s.attrs["seq"] > rode.attrs["seq"])
-        assert after_it.attrs["queued_behind"] == 0
-        # ... and the device stood empty from that sync to the next enqueue:
-        # host time, all of it booked
-        idle_s = after_it.start_s - (rode.start_s + rode.duration_ms / 1e3)
-        assert idle_s > 0
-        assert grew["starved_s"] >= idle_s - 1e-3
-        assert grew["pipeline_drains"] >= grew["pipeline_drains_wave"]
+        assert after_it.attrs["queued_behind"] >= 1
+        # ... and was enqueued BEFORE that landing: the device had it to go on with
+        assert after_it.start_s < rode.start_s + rode.duration_ms / 1e3
+        assert all(s.attrs["queued_behind"] >= 1 for s in spans if s.attrs["seq"] > spans[0].attrs["seq"])
 
-    async def test_the_host_landing_a_dispatch_already_proved_proves_nothing_new(self, params):
-        """After the wave's landing sync the host still lands the dispatch
-        before it: that sync returns at once and is no second drain."""
+    async def test_every_dispatch_is_proved_in_order_and_once(self, params):
+        """One wave lands on an engine with no active rows (a sync of its
+        own: a drain), the next rides a dispatch (deferred: none)."""
         engine = InferenceEngine(CFG, _rt(), params=params)
         TRACER.clear()
         await engine.start()
@@ -124,8 +127,52 @@ class TestAWaveRidesADispatch:
         assert ends == sorted(ends)  # proved in order, each once
         assert len({s.attrs["seq"] for s in spans}) == len(spans)
         counters = engine.stats.counters()
-        assert counters["pipeline_drains_wave"] == 2  # the two waves, no more
+        assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (1, 1)
+        assert sum(s.attrs["wave_landed"] for s in spans) == 1  # the one that rode
         assert engine._done_seq == engine._enq_seq and not engine._unproved
+
+    async def test_a_wave_onto_an_engine_with_no_active_rows_still_drains_once(self, params):
+        """Nothing to ride: the landing stays a sync of its own, the device
+        is empty from there to the first decode dispatch, and the account
+        says so."""
+        engine = InferenceEngine(CFG, _rt(), params=params)
+        await engine.start()
+        try:
+            await _gen(engine, LONG, 8)  # compiles
+            TRACER.clear()
+            before = _account(engine)
+            await _gen(engine, LONG, 8)
+            after = _account(engine)
+        finally:
+            await engine.stop()
+        grew = _grew(before, after)
+        assert (grew["pipeline_drains_wave"], grew["wave_landings_deferred"]) == (1, 0)
+        assert grew["starved_s"] > 0.0
+        spans = _dispatch_spans()
+        assert spans[0].attrs["queued_behind"] == 0 and not any(
+            s.attrs["wave_landed"] for s in spans)
+
+    async def test_a_chunk_in_its_own_invocation_rides_the_decode_dispatch_before_it(self, params):
+        """The token budget refuses the fused launch: the chunk and the
+        finalize program follow a plain decode dispatch, and the wave's
+        landing rides THAT."""
+        engine = InferenceEngine(CFG, _rt(ragged_token_budget=8), params=params)
+        TRACER.clear()
+        await engine.start()
+        try:
+            first = asyncio.ensure_future(_gen(engine, LONG, 48))
+            await _until(lambda: engine._active and engine.stats.decode_dispatches
+                         and engine._pend is not None)
+            assert len(await _gen(engine, SHORT, 6)) == 6
+            await first
+        finally:
+            await engine.stop()
+        counters = engine.stats.counters()
+        assert counters["unified_dispatches"] == 0
+        assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (1, 1)
+        (rode,) = [s for s in _dispatch_spans() if s.attrs["wave_landed"]]
+        # the chunk and the finalize program behind the decode dispatch
+        assert rode.attrs["kind"] == "decode" and rode.attrs["proved_by"] == rode.attrs["seq"] + 2
 
 
 class TestSteadyOverlap:

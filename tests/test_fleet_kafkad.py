@@ -282,8 +282,12 @@ class TestOrphanReapOverKafka:
             client_mesh = KafkaWireMesh(f"127.0.0.1:{broker_port}")
             await client_mesh.start()
             agent = Agent("leased", model=model)
+            # lanes are crc32(task key) % max_workers and serial within a
+            # lane: at the default 8, two of the three sends share a lane one
+            # run in three, the second waits out the first's whole turn, and
+            # "all three in the engine at once" never comes
             async with Worker(
-                [agent], mesh=worker_mesh, owns_transport=True
+                [agent], mesh=worker_mesh, owns_transport=True, max_workers=512
             ):
                 ttl = 1.0
                 client = Client.connect(client_mesh, lease_ttl=ttl)

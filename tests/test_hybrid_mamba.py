@@ -189,7 +189,8 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
     want = reference_logits(params, config, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
-    assert counters["pipeline_drains_wave"] == 1
+    # one wave, onto an engine with no active rows: nothing to ride, its landing is a sync
+    assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (1, 0)
     assert counters["recurrent_state_bytes"] == config.recurrent_state_bytes(2)
     assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
@@ -247,7 +248,7 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
     spy = Spy(monkeypatch)
     (_, out), _, counters = serve((TOY, runtime(max_batch_size=1)), [(first, 9), (second, 9)])
     reused = [s for s in spy.seen][-8:]
-    assert counters["pipeline_drains_wave"] == 2
+    assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (2, 0)
     spy.seen.clear()
     (fresh_out,), _, _ = serve((TOY, runtime(max_batch_size=1)), [(second, 9)])
     fresh = spy.seen[-8:]
@@ -415,7 +416,7 @@ def test_a_cached_prefix_is_declined_and_counted():
     assert a == b
     assert counters["prefix_reuse_declined_recurrent"] == 1
     assert counters["prefix_hits"] == 0 and counters["prefix_reused_tokens"] == 0
-    assert counters["pipeline_drains_wave"] == 2
+    assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (2, 0)
 
 
 def test_the_new_counters_reach_metrics():
@@ -424,6 +425,7 @@ def test_the_new_counters_reach_metrics():
     serve((TOY, runtime()), [(prompt_of(20), 3)])
     text = metrics_text()
     for name in ("calfkit_engine_pipeline_drains_wave_total",
+                 "calfkit_engine_wave_landings_deferred_total",
                  "calfkit_engine_prefix_reuse_declined_recurrent_total",
                  "calfkit_engine_recurrent_state_bytes"):
         assert name in text
