@@ -134,6 +134,17 @@ def render_messages(
     return header + "\n".join(lines) + "\n<|assistant|>\n"
 
 
+def _pools_by_kind(stats: Any) -> dict:
+    """The capacity breakdown's ``by_kind`` of a model with window layers
+    (nothing for any other): each pool's pages reserved by live rows and in all."""
+    if not stats.kv_pages_window_total:
+        return {}
+    return {"by_kind": {
+        kind: {"pages_in_use": getattr(stats, f"kv_pages_{kind}_in_use"),
+               "pages_total": getattr(stats, f"kv_pages_{kind}_total")}
+        for kind in ("global", "window")}}
+
+
 class JaxLocalModelClient(ModelClient):
     """Local inference over a JAX device mesh.
 
@@ -474,7 +485,9 @@ class JaxLocalModelClient(ModelClient):
             "prefix_resident_pages": engine._ledger.prefix_resident_pages,
             "evictions_window": stats.prefix_evictions,
             "alloc_stalls": stats.alloc_stalls,
-            "capacity": engine._ledger.breakdown(),
+            # (pools by cache kind: the one ledger above counts both in a layer's
+            # pages; "by_kind" reads the two pools apart, in their own pages)
+            "capacity": {**engine._ledger.breakdown(), **_pools_by_kind(stats)},
             "capacity_samples": engine._sampler.counts(),
         }
         try:

@@ -10,14 +10,29 @@ import math
 from dataclasses import dataclass, field, replace
 
 
-ATTENTION, MAMBA, GDN = "attention", "mamba", "gdn"
+ATTENTION, MAMBA, GDN, WINDOW = "attention", "mamba", "gdn", "window"
 RECURRENT_KINDS = (MAMBA, GDN)
+# What a layer of each kind leaves behind of a sequence, i.e. the cache kind
+# the engine has to hold for it: "global" = K and V of EVERY token, in pages
+# a row keeps for its whole life; "window" = K and V of the last
+# ``sliding_window`` tokens, in a ring of pages a row that it writes over
+# (gives back) as it grows; "state" = a recurrent state of fixed size a slot.
+# A new kind of layer is a row here and a mixer in model.py.
+CACHE_KINDS = {ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state"}
 
 
 class UnsupportedWithRecurrentLayers(ValueError):
     """A runtime option that a model with recurrent (Mamba-2 or Gated
     DeltaNet) layers cannot be served under yet.  Raised when the engine is built, never later:
     there is no silent fallback to a path that would drop the state."""
+
+
+class UnsupportedWithWindowLayers(ValueError):
+    """A runtime option that a model with sliding-window attention layers
+    (K and V pages by cache kind: a ring of pages a row for the window
+    layers) cannot be served under yet.  Raised when the engine is built,
+    never later: there is no silent fallback to a path that knows no lower
+    bound and would attend, or keep, what the window has left behind."""
 
 
 class UnsupportedWithLatentAttention(ValueError):
@@ -54,6 +69,15 @@ class ModelConfig:
     device holds: ``[expert_first, expert_first + n_routed_experts)`` of the
     ``n_experts_total`` the gate scores (a share of an expert-parallel
     layer; what an absent expert would add to a token is left out).
+    With ``"window"`` among ``layer_types`` it is a Cohere2-MoE-style stack:
+    sliding-window GQA layers (a query sees the last ``sliding_window``
+    keys; their K and V live in a RING of pages a row, ``CACHE_KINDS``)
+    beside global ones, the position rule by kind
+    (``position_embedding="rope_window"``: rotary on the window layers, none
+    on the global), the block the description names (``norm``,
+    ``parallel_block``), and the expert block as every layer's FFN, held by
+    share as above, its shared experts summed or averaged
+    (``shared_expert_combine``).
     """
 
     name: str = "debug"
@@ -83,7 +107,8 @@ class ModelConfig:
     # the SSM state's type: float32 because the recurrence is carried over
     # the whole sequence and a bfloat16 state rounds at every step
     state_dtype: str = "float32"
-    position_embedding: str = "rope"  # "rope" | "none"
+    # "rope" | "none" | "rope_window" (rotary on the window layers, none on the global)
+    position_embedding: str = "rope"
     # attention scores are scaled by this; None = 1/sqrt(head_dim)
     attention_multiplier: float | None = None
     embedding_multiplier: float = 1.0  # x = embed[tokens] * this
@@ -133,6 +158,14 @@ class ModelConfig:
     qk_norm: bool = False  # RMSNorm over each query and key head before the rotation
     attn_output_gate: bool = False  # W_q gives q | gate a head; out = o * sigmoid(gate)
     norm_plus_one: bool = False  # every RMSNorm of the stack multiplies by (1 + w)
+    # ---- window layers beside global ones (all defaults = as before) ----
+    # a "window" layer's query at position i sees key j iff i - sliding_window < j <= i
+    sliding_window: int = 0
+    norm: str = "rms"  # "rms" | "layer" (mean and variance, a weight and no bias)
+    parallel_block: bool = False  # x + Attn(h) + FFN(h), h = norm(x): ONE norm a layer
+    # how the n_shared_experts' outputs meet: "sum" (one SwiGLU of n x moe_d_ff)
+    # or "average" (that sum over n)
+    shared_expert_combine: str = "sum"
 
     def __post_init__(self) -> None:
         if self.kv_lora_rank:
@@ -144,11 +177,12 @@ class ModelConfig:
                 )
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
-        elif self.n_routed_experts and GDN not in self.layer_types:
+        elif self.n_routed_experts and not {GDN, WINDOW} & set(self.layer_types):
             raise ValueError(
                 "routed experts are described for the latent-attention stack "
-                "(kv_lora_rank) and for the Gated DeltaNet hybrid (layer_types "
-                'with "gdn") alone'
+                "(kv_lora_rank), for the Gated DeltaNet hybrid (layer_types "
+                'with "gdn") and for the window stack (layer_types with '
+                '"window") alone'
             )
         if self.n_routed_experts:
             if not (0 < self.n_experts_per_tok <= self.experts_scored and self.moe_d_ff):
@@ -156,11 +190,11 @@ class ModelConfig:
             if not 0 <= self.first_k_dense < self.n_layers:
                 raise ValueError("first_k_dense must leave at least one expert layer")
             if (self.scoring_func, self.topk_method) not in (
-                    ("sigmoid", "noaux_tc"), ("softmax", "greedy")):
+                    ("sigmoid", "noaux_tc"), ("softmax", "greedy"), ("sigmoid", "greedy")):
                 raise ValueError(
                     f"router {self.scoring_func!r}/{self.topk_method!r}: only "
-                    "sigmoid scores with noaux_tc selection and softmax scores "
-                    "with greedy selection are described"
+                    "sigmoid scores with noaux_tc or greedy selection and softmax "
+                    "scores with greedy selection are described"
                 )
             if (self.n_group, self.topk_group) != (1, 1):
                 raise ValueError("group-limited routing (n_group > 1) is not described")
@@ -172,29 +206,43 @@ class ModelConfig:
                 )
             if self.layer_types and self.first_k_dense:
                 raise ValueError("a hybrid stack's expert block is every layer's FFN")
-        elif self.n_experts_total or self.expert_first or self.shared_expert_gate:
+        elif (self.n_experts_total or self.expert_first or self.shared_expert_gate
+              or self.shared_expert_combine != "sum"):
             raise ValueError(
-                "n_experts_total, expert_first and shared_expert_gate belong to "
-                "routed experts (n_routed_experts)"
+                "n_experts_total, expert_first, shared_expert_gate and "
+                "shared_expert_combine belong to routed experts (n_routed_experts)"
             )
+        if self.shared_expert_combine not in ("sum", "average"):
+            raise ValueError(f"unknown shared_expert_combine {self.shared_expert_combine!r}")
         if self.layer_types:
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers, "
                     f"n_layers is {self.n_layers}"
                 )
-            unknown = set(self.layer_types) - {ATTENTION, MAMBA, GDN}
+            unknown = set(self.layer_types) - set(CACHE_KINDS)
             if unknown:
                 raise ValueError(f"unknown layer types {sorted(unknown)}")
             kinds = set(self.layer_types) & set(RECURRENT_KINDS)
-            if not kinds:
+            if not kinds and WINDOW not in self.layer_types:
                 raise ValueError(
-                    "layer_types without a mamba or gdn layer is the dense "
-                    "decoder: leave it empty"
+                    "layer_types without a mamba, gdn or window layer is the "
+                    "dense decoder: leave it empty"
                 )
             if len(kinds) > 1:
                 raise ValueError("mamba and gdn layers in one stack are not described")
-            if MAMBA in kinds:
+            if WINDOW in self.layer_types:
+                if kinds:
+                    raise ValueError(
+                        "window layers beside recurrent layers are not described")
+                if self.sliding_window < 1:
+                    raise ValueError("window layers need sliding_window")
+                if not self.n_routed_experts:
+                    raise ValueError(
+                        "a window stack's FFN is the expert block "
+                        "(n_routed_experts): one SwiGLU a layer is not described"
+                    )
+            elif MAMBA in kinds:
                 if not (self.mamba_n_heads and self.mamba_d_head and self.mamba_d_state):
                     raise ValueError("mamba layers need mamba_n_heads/d_head/d_state")
                 if self.mamba_n_heads % self.mamba_n_groups:
@@ -220,18 +268,30 @@ class ModelConfig:
                 "position_embedding, attention_multiplier and the three "
                 "multipliers belong to a hybrid stack (layer_types)"
             )
-        if self.position_embedding not in ("rope", "none"):
+        if self.position_embedding not in ("rope", "none", "rope_window"):
             raise ValueError(
                 f"unknown position_embedding {self.position_embedding!r}"
             )
+        if WINDOW not in self.layer_types and (
+            self.sliding_window or self.parallel_block or self.norm != "rms"
+            or self.position_embedding == "rope_window"
+        ):
+            raise ValueError(
+                "sliding_window, parallel_block, norm='layer' and "
+                "position_embedding='rope_window' belong to the window stack "
+                '(layer_types with "window")'
+            )
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm {self.norm!r}")
         if GDN not in self.layer_types and (
-            self.attn_head_dim or self.qk_norm or self.attn_output_gate
+            self.qk_norm or self.attn_output_gate
             or self.norm_plus_one or self.partial_rotary_factor != 1.0
+            or (self.attn_head_dim and WINDOW not in self.layer_types)
         ):
             raise ValueError(
                 "attn_head_dim, qk_norm, attn_output_gate, norm_plus_one and "
                 'partial_rotary_factor belong to the Gated DeltaNet hybrid '
-                '(layer_types with "gdn")'
+                '(layer_types with "gdn"; attn_head_dim to the window stack too)'
             )
         if self.rotary_dim % 2:
             raise ValueError("the rotated part of a head must be even (rotary pairs)")
@@ -327,6 +387,40 @@ class ModelConfig:
         return self.n_layers - self.n_recurrent_layers
 
     @property
+    def windowed(self) -> bool:
+        """Does the stack have sliding-window layers (pages by cache kind)?"""
+        return WINDOW in self.layer_types
+
+    @property
+    def window_layer_ids(self) -> tuple[int, ...]:
+        """The window layers' indices among the layers that keep K and V
+        (where their rows lie in the prefill scratch and the decode ring)."""
+        kv = [t for t in self.layer_types if t not in RECURRENT_KINDS]
+        return tuple(i for i, t in enumerate(kv) if t == WINDOW)
+
+    @property
+    def global_layer_ids(self) -> tuple[int, ...]:
+        kv = [t for t in self.layer_types if t not in RECURRENT_KINDS]
+        return tuple(i for i, t in enumerate(kv) if t != WINDOW)
+
+    @property
+    def n_window_layers(self) -> int:
+        return len(self.window_layer_ids)
+
+    @property
+    def n_global_layers(self) -> int:
+        """Layers whose K and V of EVERY token are kept."""
+        return self.n_kv_layers - self.n_window_layers
+
+    def window_ring_pages(self, page_size: int, ahead: int) -> int:
+        """Pages in a row's ring of a window layer: the window, what one
+        dispatch writes ``ahead`` of the newest key that is read (its decode
+        steps; a prompt's chunks stay in the prefill scratch until they land),
+        and one page more, because the window's oldest key and the newest
+        write each lie anywhere in their page."""
+        return -(-(self.sliding_window + ahead) // page_size) + 1
+
+    @property
     def layer_period(self) -> tuple[str, ...]:
         """The shortest pattern ``layer_types`` repeats: the stack scans
         over its repeats, so compile time follows the period, not the depth."""
@@ -418,6 +512,14 @@ class ModelConfig:
             + self.n_heads * self.head_dim * self.d_model
             + (2 * self.head_dim if self.qk_norm else 0)
         )
+        if self.windowed:
+            expert = 3 * self.d_model * self.moe_d_ff
+            ffn = (
+                self.d_model * self.experts_scored
+                + (self.n_routed_experts + self.n_shared_experts) * expert
+            )
+            norms = self.d_model * (1 if self.parallel_block else 2)
+            return embed + self.d_model + self.n_layers * (attention + ffn + norms)
         if self.gdn:
             mixer = (
                 self.d_model * self.gdn_d_in_proj + self.gdn_value_dim * self.d_model
@@ -817,6 +919,69 @@ PRESETS: dict[str, ModelConfig] = {
         scoring_func="softmax",
         topk_method="greedy",
         shared_expert_gate=True,
+    ),
+    # command-a-plus-05-2026's text decoder (HF: CohereLabs/
+    # command-a-plus-05-2026, cohere2_moe): 24 sliding-window layers (rotary on
+    # interleaved pairs, window 4096) and 8 global layers without positions
+    # (period W W W G), one LayerNorm and a parallel block a layer, 128
+    # sigmoid-routed experts with 8 a token and 4 shared experts averaged in
+    # EVERY layer.  All 128 experts: what one device holds is a share.
+    "command-a-plus-05-2026": ModelConfig(
+        name="command-a-plus-05-2026",
+        vocab_size=262144,
+        d_model=4096,
+        n_layers=32,
+        n_heads=128,
+        n_kv_heads=8,
+        d_ff=4096,
+        rope_theta=50000.0,
+        norm_eps=1e-5,
+        max_seq_len=131072,
+        tie_embeddings=True,
+        layer_types=((WINDOW,) * 3 + (ATTENTION,)) * 8,
+        position_embedding="rope_window",
+        attn_head_dim=128,
+        sliding_window=4096,
+        norm="layer",
+        parallel_block=True,
+        n_routed_experts=128,
+        n_experts_per_tok=8,
+        n_shared_experts=4,
+        moe_d_ff=4096,
+        scoring_func="sigmoid",
+        topk_method="greedy",
+        shared_expert_combine="average",
+    ),
+    # the same kind at toy size, for the tests: 2 periods of W W W G, a window
+    # of 24, 8 experts scored of which this device holds 4 (share 0 of 2)
+    "debug-window-moe": ModelConfig(
+        name="debug-window-moe",
+        vocab_size=128,
+        d_model=32,
+        n_layers=8,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=16,
+        rope_theta=50000.0,
+        norm_eps=1e-5,
+        max_seq_len=256,
+        dtype="float32",
+        tie_embeddings=True,
+        layer_types=((WINDOW,) * 3 + (ATTENTION,)) * 2,
+        position_embedding="rope_window",
+        attn_head_dim=8,
+        sliding_window=24,
+        norm="layer",
+        parallel_block=True,
+        n_routed_experts=4,
+        n_experts_total=8,
+        expert_first=0,
+        n_experts_per_tok=3,
+        n_shared_experts=2,
+        moe_d_ff=16,
+        scoring_func="sigmoid",
+        topk_method="greedy",
+        shared_expert_combine="average",
     ),
 }
 

@@ -56,6 +56,7 @@ from calfkit_tpu.inference.config import (
     RuntimeConfig,
     UnsupportedWithLatentAttention,
     UnsupportedWithRecurrentLayers,
+    UnsupportedWithWindowLayers,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.moe import dense_form, moe_stats_init
@@ -117,7 +118,8 @@ NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # heartbeat advert's window
 _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
-    "prefix_reuse_declined_recurrent",
+    "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
+    "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
     "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
     "programs_built", "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
@@ -235,6 +237,46 @@ def _engine_metrics(
             "calfkit_engine_decode_pages_window_total",
             "paged decode steps: rows in the program x the window bucket's "
             "pages (what the XLA window gather copies)",
+        ),
+        prefix_reuse_declined_window=reg.counter(
+            "calfkit_engine_prefix_reuse_declined_window_total",
+            "requests whose prefix was not looked up for reuse because the model "
+            "has window layers (a ring entry is written over as its row grows, so "
+            "no page of such a layer is ever registered)",
+        ),
+        decode_window_tokens_read=reg.counter(
+            "calfkit_engine_decode_window_tokens_read_total",
+            "decode steps: rows x min(len, sliding_window) x window layers: the "
+            "keys and values a step has to read of the layers that keep a window "
+            "(an ATTENTION window; decode_pages_window above means a decode "
+            "context bucket)",
+        ),
+        decode_global_tokens_read=reg.counter(
+            "calfkit_engine_decode_global_tokens_read_total",
+            "decode steps: rows x len x global layers, of a model with window "
+            "layers beside them",
+        ),
+        window_pages_given_back=reg.counter(
+            "calfkit_engine_window_pages_given_back_total",
+            "ring pages of window layers written over while their row lived (and "
+            "pages of a prompt longer than the ring that never landed)",
+        ),
+        kv_pages_global_in_use=reg.gauge(
+            "calfkit_engine_kv_pages_global_in_use",
+            "pages of the global pool (layers that keep every token) reserved by "
+            "live rows (the last engine that moved; 0 without window layers)",
+        ),
+        kv_pages_window_in_use=reg.gauge(
+            "calfkit_engine_kv_pages_window_in_use",
+            "pages of the window pool (a ring a row) reserved by live rows",
+        ),
+        kv_pages_global_total=reg.gauge(
+            "calfkit_engine_kv_pages_global_total",
+            "allocatable pages of the global pool (pools by cache kind)",
+        ),
+        kv_pages_window_total=reg.gauge(
+            "calfkit_engine_kv_pages_window_total",
+            "allocatable pages of the window pool",
         ),
         prefix_reuse_declined_recurrent=reg.counter(
             "calfkit_engine_prefix_reuse_declined_recurrent_total",
@@ -383,6 +425,13 @@ class _Program:
         return getattr(self.fn, name)
 
 
+def _layer_kinds(cfg: ModelConfig) -> "tuple | None":
+    """For pools by cache kind: which rows of the prefill scratch and of the
+    fresh-token ring (all K and V layers, stack order) belong to the global
+    and which to the window pool."""
+    return (cfg.global_layer_ids, cfg.window_layer_ids) if cfg.windowed else None
+
+
 def _some(x: Any) -> tuple:
     """``(x,)``, or ``()`` for None: an optional argument or result that a
     program without it never sees."""
@@ -420,8 +469,9 @@ def _finalize_wave_math(
     R = slots.shape[0]
     P = sk.shape[3]
     if paged:
-        k, v = M.write_prefill_pages((k, v), (sk, sv), scatter_ids)
-        tables = tables.at[slots].set(page_rows)
+        k, v = M.write_prefill_pages((k, v), (sk, sv), scatter_ids, _layer_kinds(cfg))
+        # (pools by kind: the tables and the wave's rows are pairs)
+        tables = jax.tree.map(lambda table, rows: table.at[slots].set(rows), tables, page_rows)
     else:
         for r in range(R):  # R is small & static: unrolled row scatter
             k = lax.dynamic_update_slice_in_dim(
@@ -478,6 +528,8 @@ class GenRequest:
     # prefix of ``pages``, and the prompt's full-page chain hashes
     reuse_len: int = 0
     shared_pages: list[int] = field(default_factory=list)
+    # pools by cache kind: the row's ring of window pages (``pages``: its global pages)
+    ring_pages: list[int] = field(default_factory=list)
     page_hashes: list = field(default_factory=list)
     reuse_declined: bool = False  # counted once, however often it is replanned
     slot: int = -1
@@ -689,6 +741,21 @@ class EngineStats:
     # state reserves (0 for a model without such layers)
     prefix_reuse_declined_recurrent: int = 0
     recurrent_state_bytes: int = 0
+    # pages by cache kind (a model with window layers; 0 without): requests
+    # whose prefix reuse was declined; sums over decode steps, from the host
+    # mirror of the row lengths, of the keys and values a step has to read,
+    # rows x min(len, W) x window layers and rows x len x global layers (a
+    # window here is the model's ATTENTION window: ``decode_pages_window``
+    # above means a decode context bucket); ring pages written over while
+    # their row lived; and, gauges, each pool's pages reserved and in all
+    prefix_reuse_declined_window: int = 0
+    decode_window_tokens_read: int = 0
+    decode_global_tokens_read: int = 0
+    window_pages_given_back: int = 0
+    kv_pages_global_in_use: int = 0
+    kv_pages_window_in_use: int = 0
+    kv_pages_global_total: int = 0
+    kv_pages_window_total: int = 0
     # routed experts (0 for a model without them): token-expert pairs
     # computed for real tokens (the HELD experts' alone, and beside them the
     # pairs whose expert another device holds, where the experts are held
@@ -834,6 +901,9 @@ class EngineStats:
         out["occupancy_hist"] = list(self.occupancy_hist)
         out["recurrent_state_bytes"] = self.recurrent_state_bytes  # a gauge
         out["latent_cache_bytes"] = self.latent_cache_bytes  # a gauge
+        for gauge in ("kv_pages_global_in_use", "kv_pages_window_in_use",
+                      "kv_pages_global_total", "kv_pages_window_total"):
+            out[gauge] = getattr(self, gauge)
         now = time.perf_counter()
         blocked, empty = self._blocked, self._empty
         if phase is not None:
@@ -1002,6 +1072,38 @@ class InferenceEngine:
                         f"{config.name} has latent attention (MLA): RuntimeConfig "
                         f"{option} is not supported with it ({why})"
                     )
+        # a model with window layers keeps its pages BY CACHE KIND (a ring of
+        # pages a row for the window layers) and reads them under a lower
+        # bound; what knows neither yet is refused HERE, with its reason
+        self._windowed = config.windowed
+        if self._windowed:
+            refused = {
+                "tp > 1 / dp > 1": (rt.tp > 1 or rt.dp > 1 or self.mesh.size > 1,
+                                    "the two pools and the expert leaves have no sharding "
+                                    "over a mesh of more than one device, and a layer held "
+                                    "by share has no exchange"),
+                "quantization": (rt.quantization is not None,
+                                 "the expert leaves have no scales"),
+                "speculative": (rt.speculative is not None,
+                                "the verify programs know no lower bound and write a "
+                                "chunk straight to pages, past what a ring leaves room for"),
+                "long_context": (rt.long_context,
+                                 "the sequence-parallel lane knows no lower bound"),
+                "kv_layout='dense'": (rt.kv_layout == "dense",
+                                      "dense rows keep every token of every layer: the "
+                                      "window layers are served from rings of pages"),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise UnsupportedWithWindowLayers(
+                        f"{config.name} has sliding-window layers: RuntimeConfig "
+                        f"{option} is not supported with them ({why})"
+                    )
+            if rt.decode_steps_per_dispatch > config.sliding_window:
+                raise UnsupportedWithWindowLayers(
+                    f"{config.name}: decode_steps_per_dispatch "
+                    f"({rt.decode_steps_per_dispatch}) is more than the window "
+                    f"({config.sliding_window}): a dispatch's fresh tokens are read whole")
         shardings = param_shardings(config, self.mesh)
         if params is None:
             logger.info(
@@ -1105,19 +1207,45 @@ class InferenceEngine:
             # born sharded, like the params: never whole on one device
             # the pool is a pair: K and V, or the two parts (c, k_rope) of the
             # one latent a token of a latent-attention model leaves behind
-            self._k, self._v = jax.jit(
-                lambda: M.make_page_pool(config, n_pages, rt.page_size),
-                out_shardings=(pool_sh, pool_sh),
-            )()
-            self._tables = jnp.zeros((B, rt.pages_per_seq()), jnp.int32)
-            self._page_alloc = PageAllocator(n_pages)
-            # capacity observatory (ISSUE 19): the page-ownership mirror —
-            # maintained O(1) at every alloc/free/evict site below, always
-            # on for paged engines (attribution is the headroom advert's
-            # substrate; the SAMPLER below is the opt-in part)
-            self._ledger = capacity.PageLedger(n_pages - 1)
+            if self._windowed:
+                # pages BY CACHE KIND: each side of the pool, the block tables
+                # and the allocator are pairs (global, window).  A row's window
+                # table is a ring of ``_ring_pages`` entries that it writes over
+                # as it grows; the window pool holds every slot's ring, so what
+                # a wave can wait for is global pages (a short request takes a
+                # shorter ring)
+                from calfkit_tpu.inference.paged import PagesByKind
+
+                self._ring_pages = config.window_ring_pages(
+                    rt.page_size, rt.decode_steps_per_dispatch)
+                n_window = B * self._ring_pages + 1
+                self._k, self._v = jax.jit(
+                    lambda: M.make_page_pool(
+                        config, n_pages, rt.page_size, window_pages=n_window),
+                    out_shardings=((pool_sh, pool_sh), (pool_sh, pool_sh)),
+                )()
+                self._tables = (jnp.zeros((B, rt.pages_per_seq()), jnp.int32),
+                                jnp.zeros((B, self._ring_pages), jnp.int32))
+                # ONE ledger for both pools, in pages of equal bytes: a
+                # LAYER's page (a global page is n_global_layers of them, a
+                # window page n_window_layers)
+                self._page_alloc = PagesByKind(
+                    n_pages, n_window, (config.n_global_layers, config.n_window_layers))
+                self._ledger = capacity.PageLedger(self._page_alloc.num_pages - 1)
+            else:
+                self._k, self._v = jax.jit(
+                    lambda: M.make_page_pool(config, n_pages, rt.page_size),
+                    out_shardings=(pool_sh, pool_sh),
+                )()
+                self._tables = jnp.zeros((B, rt.pages_per_seq()), jnp.int32)
+                self._page_alloc = PageAllocator(n_pages)
+                # capacity observatory (ISSUE 19): the page-ownership mirror —
+                # maintained O(1) at every alloc/free/evict site below, always
+                # on for paged engines (attribution is the headroom advert's
+                # substrate; the SAMPLER below is the opt-in part)
+                self._ledger = capacity.PageLedger(n_pages - 1)
             self._prefix: Any = None
-            if rt.prefix_cache:
+            if rt.prefix_cache and not self._windowed:
                 if not rt.chunked_prefill:
                     raise ValueError(
                         "prefix_cache=True requires chunked_prefill=True "
@@ -1129,7 +1257,7 @@ class InferenceEngine:
             logger.info(
                 "paged %s pool: %d pages x %d tokens (%.2f GB)",
                 "latent" if config.latent else "KV", n_pages, rt.page_size,
-                (self._k.nbytes + self._v.nbytes) / 1e9,
+                sum(side.nbytes for side in jax.tree.leaves((self._k, self._v))) / 1e9,
             )
         else:
             self._prefix = None
@@ -1312,6 +1440,9 @@ class InferenceEngine:
             self.stats.recurrent_state_bytes = config.recurrent_state_bytes(B)
         if config.latent:
             self.stats.latent_cache_bytes = self._k.nbytes + self._v.nbytes
+        if self._windowed:
+            self.stats.kv_pages_global_total, self.stats.kv_pages_window_total = (
+                a.num_pages - 1 for a in self._page_alloc.by_kind)
         # a dispatch of a model with routed experts carries their counters
         # beside whatever state it carries (moe.py): zeros in, the
         # dispatch's counts out, read at the landing's one sync
@@ -1361,6 +1492,8 @@ class InferenceEngine:
         self.metrics = _engine_metrics()
         self.metrics["recurrent_state_bytes"].set(self.stats.recurrent_state_bytes)
         self.metrics["latent_cache_bytes"].set(self.stats.latent_cache_bytes)
+        self.metrics["kv_pages_global_total"].set(self.stats.kv_pages_global_total)
+        self.metrics["kv_pages_window_total"].set(self.stats.kv_pages_window_total)
         # per-ENGINE latency histograms: the advert's percentiles must
         # attribute to THIS engine, not blend every engine in the process
         # (the process-registry instruments above stay shared for the
@@ -1642,7 +1775,7 @@ class InferenceEngine:
                 # scan), never per step.  Other heads: the pool.
                 pool = (
                     (k, latent_rope_view(v)) if cfg.latent
-                    else (lane_dense_pool(k), lane_dense_pool(v))
+                    else (jax.tree.map(lane_dense_pool, k), jax.tree.map(lane_dense_pool, v))
                 )
 
             def step(carry, t):
@@ -1665,7 +1798,7 @@ class InferenceEngine:
                 step, (ring, last, *_some(state), *_some(moe)), jnp.arange(steps)
             )
             k2, v2 = M.consolidate_ring_paged(
-                (k, v), ring, tables, lens, active
+                (k, v), ring, tables, lens, active, _layer_kinds(cfg)
             )
             new_lens = jnp.where(active, lens + steps, lens)
             n_valid, done = retire_mask_slots(
@@ -2327,7 +2460,7 @@ class InferenceEngine:
             # wait (and starve everything behind it) forever
             reserve = self._reserve_pages(request, self._bucket_of(len(prompt)))
             usable = self._page_alloc.num_pages - 1
-            if reserve > usable:
+            if not self._page_alloc.fits(reserve):  # (pools by kind: pool by pool)
                 raise InferenceError(
                     f"request needs {reserve} KV pages but the pool only has "
                     f"{usable}; lower max_new_tokens or raise num_kv_pages"
@@ -3158,23 +3291,43 @@ class InferenceEngine:
                 return request
         return None
 
-    def _reserve_pages(self, request: GenRequest, bucket: int) -> int:
+    def _reserve_pages(self, request: GenRequest, bucket: int) -> "int | tuple[int, int]":
         """Pages a request needs for its whole life: the prefill writes whole
         bucket pages, decode grows to (prompt + max_new), capped by the
-        sequence limit."""
+        sequence limit.  With pools by cache kind, the pair (global pages,
+        window pages): the second is the row's RING, or all its positions'
+        pages where those are fewer, and never grows with the row."""
         from calfkit_tpu.inference.paged import pages_needed
 
         rt = self.runtime
         total = min(
             len(request.prompt) + request.max_new_tokens + 1, rt.max_seq_len
         )
-        return min(
+        need = min(
             max(
                 pages_needed(bucket, rt.page_size),
                 pages_needed(total, rt.page_size),
             ),
             rt.pages_per_seq(),
         )
+        if self._windowed:
+            # (a prefill lands whole pages of the row's OWN tokens alone in a
+            # ring, so the bucket does not count here)
+            return need, min(self._ring_pages, pages_needed(total, rt.page_size))
+        return need
+
+    def _free_pages(self, slot: int) -> None:
+        """Return a slot's page reservation: of both kinds, where the pools
+        come by cache kind."""
+        self._page_alloc.free(slot)
+        self._ledger.free(slot)
+        self._note_pools_in_use()
+
+    def _note_pools_in_use(self) -> None:
+        """The two pools' gauges, from the allocators themselves."""
+        if self._windowed:
+            stats, (g, w) = self.stats, self._page_alloc.by_kind
+            stats.kv_pages_global_in_use, stats.kv_pages_window_in_use = g.in_use, w.in_use
 
     def _plan_prefix_reuse(self, request: GenRequest, bucket: int) -> int:
         """Longest cached, alignment-safe prompt prefix for ``request``
@@ -3188,6 +3341,16 @@ class InferenceEngine:
         first token samples from the last chunk's logits)."""
         request.reuse_len = 0
         request.shared_pages = []
+        if self._windowed and self.runtime.prefix_cache and not request.reuse_declined:
+            # THE one place a model with window layers declines reuse: a
+            # registered page of a window layer is a ring entry that its row
+            # writes over as it grows, so no page of such a layer outlives
+            # its window for a later prompt to share (``self._prefix`` is
+            # None for such a model: nothing is ever registered).  Reuse over
+            # window pages needs the chunk lane to rebuild the ring from the
+            # global layers' prefix; until then the prompt is prefilled whole
+            request.reuse_declined = True
+            self.stats.prefix_reuse_declined_window += 1
         if self._prefix is None:
             return 0
         rt = self.runtime
@@ -3265,6 +3428,12 @@ class InferenceEngine:
             self.stats.prefix_evictions += freed
             pages = self._page_alloc.alloc(slot, n)
         return pages
+
+    def _take_slot(self) -> int:
+        """A free slot: the one freed last; with pools by cache kind the one
+        that has been free LONGEST, as its pages are (``PagesByKind``), so that
+        a retired row's tables and pages stand while others are free."""
+        return self._free.pop(0) if self._windowed else self._free.pop()
 
     def _bucket_of(self, prompt_len: int) -> int:
         rt = self.runtime
@@ -3358,10 +3527,11 @@ class InferenceEngine:
             # the tail of an unservable wave waits at the queue front
             granted: list[GenRequest] = []
             for i, request in enumerate(wave):
-                slot = self._free.pop()
+                slot = self._take_slot()
                 need = self._reserve_pages(request, wave_bucket)
                 shared = request.shared_pages  # acquired at formation
-                need -= len(shared)
+                if not self._windowed:  # (no reuse over window pages: no shared pages)
+                    need -= len(shared)
                 pages = self._alloc_with_eviction(slot, need, request.corr)
                 if pages is None:
                     self._held_by = NO_PAGES
@@ -3374,13 +3544,20 @@ class InferenceEngine:
                     self._carry = wave[i:] + self._carry
                     break
                 request.slot = slot
+                held = len(pages)
+                if self._windowed:
+                    # the global pages, and beside them the row's ring; the
+                    # ledger counts both in pages of equal bytes
+                    pages, request.ring_pages = pages
+                    held = self._page_alloc.layer_pages(len(pages), len(request.ring_pages))
+                    self._note_pools_in_use()
                 request.pages = shared + pages
                 self._journal.append(
                     flightrec.EV_PAGE_ALLOC, request.corr, slot,
                     len(request.pages), len(shared),
                 )
                 self._ledger.alloc(
-                    slot, len(pages), request.corr, request.run,
+                    slot, held, request.corr, request.run,
                     capacity.lane_kind(request.history),
                 )
                 granted.append(request)
@@ -3395,8 +3572,7 @@ class InferenceEngine:
                 self._journal.append(
                     flightrec.EV_PAGE_FREE, request.corr, request.slot
                 )
-                self._page_alloc.free(request.slot)
-                self._ledger.free(request.slot)
+                self._free_pages(request.slot)
                 self._free.append(request.slot)
                 request.slot = -1
                 request.pages = []
@@ -3405,7 +3581,7 @@ class InferenceEngine:
             wave = wave[:keep]
         else:
             for request in wave:
-                request.slot = self._free.pop()
+                request.slot = self._take_slot()
         self._journal.append(
             flightrec.EV_WAVE_FORM, None, -1, len(wave), wave_bucket
         )
@@ -3860,6 +4036,31 @@ class InferenceEngine:
         scalar ``moe_*`` counters are sums over it."""
         return None if self._moe_counts is None else self._moe_counts.copy()
 
+    def window_ring(self, layer: int = 0) -> "jax.Array | None":
+        """The keys of ONE window layer as every slot's ring of pages holds
+        them now, ``[slots, K, ring pages x page, hd]`` (entry ``r`` of a row:
+        the newest position ``p = r`` mod the ring's tokens that the row has
+        written), or None for a model without window layers.  A retired
+        row's ring stands until a wave lands in its slot or its pages are
+        taken again, which is after the other free slots and pages have gone
+        round (both are granted oldest-first): what a check of what the
+        served rows LEFT BEHIND reads
+        (``benchmarks/architectures/cohere2-moe-swa.py``)."""
+        if not self._windowed:
+            return None
+        return M.gather_window_paged(self._k[1][layer], self._tables[1], self._ring_pages)
+
+    def global_keys(self, slot: int, layer: int = 0) -> "jax.Array | None":
+        """The keys of ONE global layer (its index among the global layers) as
+        ONE slot's global pages hold them now, ``[K, pages a sequence x page,
+        hd]``, position ``p`` at entry ``p``; None for a model without window
+        layers.  One slot at a time: every slot's would be a copy of the whole
+        pool.  Stands after retirement as ``window_ring`` does."""
+        if not self._windowed:
+            return None
+        table = self._tables[0][slot:slot + 1]
+        return M.gather_window_paged(self._k[0][layer], table, table.shape[1])[0]
+
     def recurrent_state(self) -> "tuple[jax.Array, jax.Array] | None":
         """The slots' recurrent state as it stands, ``(matrix [layers, slots,
         ..], conv [layers, taps - 1, slots, channels])`` on the device (None
@@ -3915,7 +4116,24 @@ class InferenceEngine:
                 scatter_ids[r, : request.reuse_len // self.runtime.page_size] = (
                     TRASH_PAGE
                 )
-        return [self._tables, jnp.asarray(page_rows), jnp.asarray(scatter_ids)]
+        if not self._windowed:
+            return [self._tables, jnp.asarray(page_rows), jnp.asarray(scatter_ids)]
+        # pools by kind: beside the global rows, each row's RING and where the
+        # scratch's pages of the window layers land in it: page j of the
+        # prompt in entry j % ring, and only the last ``ring`` pages that hold
+        # the row's own tokens (an older page would be written over by a
+        # newer one in the same scatter; a page past the prompt is padding)
+        ring = self._ring_pages
+        ring_rows = np.zeros((R, ring), np.int32)
+        ring_ids = np.full((R, npg), TRASH_PAGE, np.int32)
+        for r, request in enumerate(wave):
+            ring_rows[r] = table_row(request.ring_pages, ring)
+            last = (len(request.prompt) - 1) // page
+            self.stats.window_pages_given_back += max(0, last + 1 - ring)
+            landing = np.arange(max(0, last - len(request.ring_pages) + 1), last + 1)
+            ring_ids[r, landing] = ring_rows[r, landing % ring]
+        return [self._tables, (jnp.asarray(page_rows), jnp.asarray(ring_rows)),
+                (jnp.asarray(scatter_ids), jnp.asarray(ring_ids))]
 
     def _land_wave(
         self, wave: list[GenRequest], true_lens: np.ndarray,
@@ -4455,10 +4673,14 @@ class InferenceEngine:
         needed = 1
         page = self.runtime.page_size
         live_pages = 0
+        window_tokens = global_tokens = 0
         for slot in self._active:
             active_mask[slot] = True
             needed = max(needed, self._host_lens[slot])
             live_pages += -(-int(self._host_lens[slot]) // page)
+            if self._windowed:
+                global_tokens += int(self._host_lens[slot])
+                window_tokens += min(int(self._host_lens[slot]), self.config.sliding_window)
         # the ring covers in-dispatch growth; the window only needs to cover
         # what's already in the main cache
         window = self._window_bucket(int(needed))
@@ -4484,6 +4706,10 @@ class InferenceEngine:
             self.stats.decode_pages_window += (
                 self.runtime.max_batch_size * -(-window // page) * steps
             )
+            if self._windowed:  # (a step later reads a token more a row: not counted)
+                cfg = self.config
+                self.stats.decode_window_tokens_read += window_tokens * steps * cfg.n_window_layers
+                self.stats.decode_global_tokens_read += global_tokens * steps * cfg.n_global_layers
         prev = self._pend
         done_prev = prev["done_dev"] if prev is not None else self._done_zero
         stop_table, hard_end = self._retire_args()
@@ -4606,6 +4832,9 @@ class InferenceEngine:
         built = getattr(program, "built_seq", None) == seq  # (a test's stand-in has none)
         self._unproved.append(dict(
             seq=seq, kind=kind, steps=steps, rows=len(self._active),
+            **({"kv_pages_global_in_use": self.stats.kv_pages_global_in_use,
+                "kv_pages_window_in_use": self.stats.kv_pages_window_in_use}
+               if self._windowed else {}),
             chunk_rows=chunk_rows, chunk_tokens=chunk_tokens, wave_landed=0,
             first_use=int(built), build_ms=program.built_s * 1000.0 if built else 0.0,
             queued_behind=queued_behind,
@@ -4641,6 +4870,12 @@ class InferenceEngine:
         plain and fused launches, so the two lanes' retirement
         bookkeeping cannot drift.  ``extra_rows`` counts absorbed
         prefill rows (occupancy participants landed with the dispatch)."""
+        if self._windowed:
+            page, ring = self.runtime.page_size, self._ring_pages
+            for slot in self._active:  # pages the rows' rings start anew in this dispatch
+                old = (int(self._host_lens[slot]) - 1) // page
+                self.stats.window_pages_given_back += max(
+                    0, (int(self._host_lens[slot]) + steps - 1) // page - max(old, ring - 1))
         for slot in self._active:
             self._host_lens[slot] += steps
         self._pend = dict(
@@ -4750,8 +4985,7 @@ class InferenceEngine:
                 self._ledger.release(shared)
             if self._paged:
                 self._journal.append(flightrec.EV_PAGE_FREE, corr, slot)
-                self._page_alloc.free(slot)
-                self._ledger.free(slot)
+                self._free_pages(slot)
             self._free.append(slot)
             self._journal.append(flightrec.EV_SLOT_FREE, corr, slot)
 
@@ -4911,6 +5145,9 @@ class InferenceEngine:
                 if value != counted[key]:
                     m[key].inc(value - counted[key])
                     counted[key] = value
+            if self._windowed:
+                m["kv_pages_global_in_use"].set(stats.kv_pages_global_in_use)
+                m["kv_pages_window_in_use"].set(stats.kv_pages_window_in_use)
 
     @hotpath
     def _spec_decode_tick(self) -> None:
@@ -5095,8 +5332,7 @@ class InferenceEngine:
             self._journal.append(
                 flightrec.EV_PAGE_FREE, request.corr, request.slot
             )
-            self._page_alloc.free(request.slot)
-            self._ledger.free(request.slot)
+            self._free_pages(request.slot)
         self._free.append(request.slot)
         self._journal.append(
             flightrec.EV_SLOT_FREE, request.corr, request.slot

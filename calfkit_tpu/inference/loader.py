@@ -58,6 +58,24 @@ latent-attention tree (``q_lora_rank`` null):
     mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [held, ..]
     mlp.shared_expert.{gate,up,down}_proj.weight, shared_expert_gate.weight
                                                   → s_gate/s_up/s_down, shared_gate [D]
+``model_type`` ``cohere2_moe`` (command-a-plus; its text decoder, tensor
+names under ``language_model.`` where the checkpoint has a vision tower,
+whose tensors are skipped and counted with one :class:`VisionTowerSkipped`
+notice) loads into the window stack's tree (ONE norm a layer: the parallel
+block).  The expert block's names are taken as the other expert checkpoints
+here have them (``mlp.gate``, ``mlp.experts.{e}``), the shared experts' as
+``mlp.shared_experts.{j}``, one module an expert:
+    self_attn.{q,k}_proj.weight [N hd, D]         → layers.attn.wq, wk [D, N, hd], each head's
+        columns from the published interleaved pairs (x_0, y_0, x_1, y_1, ..) to
+        halves (x_0 .. | y_0 ..): ``model.apply_rope`` pairs the two halves, so the
+        same rotation gives the same scores (a permutation: changes no product)
+    self_attn.{v,o}_proj.weight                   → wv, wo
+    input_layernorm.weight                        → attn_norm (a weight, no bias)
+    mlp.gate.weight [E, D]                        → layers.moe.router [D, E] (ALL the experts)
+    mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [held, ..]
+    mlp.shared_experts.{j}.{gate,up,down}_proj.weight → s_gate, s_up [D, n Fe] (columns
+        side by side), s_down [n Fe, D] (rows stacked): ONE SwiGLU, averaged by one scale
+    model.norm.weight, model.embed_tokens.weight  → final_norm, embed (tied: no lm_head)
 A SHARE ``(r, s)`` (``config_from_hf(path, share=...)``) loads what device
 ``r`` of ``s`` that share a layer holds: experts ``[r E/s, (r+1) E/s)`` (the
 gate whole) and rows ``[r V/s, (r+1) V/s)`` of the embedding and the head.
@@ -105,8 +123,10 @@ def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> 
     raw = json.loads((Path(path) / "config.json").read_text())
     if raw.get("model_type") == "qwen3_next":
         return _qwen3_next_config(raw, str(path), share)
+    if raw.get("model_type") == "cohere2_moe":
+        return _cohere2_moe_config(raw.get("text_config", raw), str(path), share)
     if share is not None:
-        raise ValueError(f"{path}: a share is described for qwen3_next alone")
+        raise ValueError(f"{path}: a share is described for qwen3_next and cohere2_moe alone")
     if raw.get("model_type") == "granitemoehybrid":
         return _granite_hybrid_config(raw, str(path))
     if raw.get("model_type") == "kimi_vl":
@@ -255,6 +275,54 @@ def _qwen3_next_config(raw: dict, path: str, share: "tuple[int, int] | None") ->
     )
 
 
+def _cohere2_moe_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
+    """Cohere2-MoE's ``config.json`` -> the window stack's description."""
+    from calfkit_tpu.inference.config import ATTENTION, WINDOW
+
+    for key, only in (("use_parallel_block", True), ("use_qk_norm", False),
+                      ("first_k_dense_replace", 0), ("rotary_pct", 1),
+                      ("position_embedding_type", "rope_gptj"),
+                      ("expert_selection_fn", "sigmoid"), ("logit_scale", 1),
+                      ("shared_expert_combination_strategy", "average"),
+                      ("tie_word_embeddings", True), ("attention_bias", False)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    E, V, L = raw["num_experts"], raw["vocab_size"], raw["num_hidden_layers"]
+    rank, of = share or (0, 1)
+    if not 0 <= rank < of or E % of or V % of:
+        raise ValueError(f"{path}: share {share} does not divide {E} experts and {V} rows")
+    every = raw.get("layer_switch", 4)
+    kinds = {"sliding_attention": WINDOW, "full_attention": ATTENTION}
+    types = raw.get("layer_types") or [
+        "full_attention" if (i + 1) % every == 0 else "sliding_attention" for i in range(L)]
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=V // of,
+        d_model=raw["hidden_size"],
+        n_layers=L,
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"],
+        d_ff=raw["intermediate_size"],
+        rope_theta=float(raw.get("rope_theta", 10000.0)),
+        norm_eps=float(raw.get("layer_norm_eps", 1e-5)),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=True,
+        layer_types=tuple(kinds[t] for t in types[:L]),
+        position_embedding="rope_window",
+        attn_head_dim=raw["head_dim"],
+        sliding_window=raw["sliding_window"],
+        norm="layer", parallel_block=True,
+        n_routed_experts=E // of,
+        n_experts_total=E if of > 1 else 0,
+        expert_first=rank * (E // of),
+        n_experts_per_tok=raw["num_experts_per_tok"],
+        n_shared_experts=raw["num_shared_experts"],
+        moe_d_ff=raw["intermediate_size"],
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func="sigmoid", topk_method="greedy", shared_expert_combine="average",
+    )
+
+
 def _open_safetensors(path: Path) -> dict[str, Any]:
     """name -> lazy tensor getter across all shards."""
     from safetensors import safe_open  # ships with transformers
@@ -349,6 +417,10 @@ def _build_params(
         if quantize is not None:
             raise ValueError("no quantized load for a model with Gated DeltaNet layers")
         return _build_gdn_params(config, shardings, get)
+    if config.windowed:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with window layers and experts")
+        return _build_window_params(config, shardings, get)
     if config.layer_types:
         if quantize is not None:
             raise ValueError("no quantized load for a model with Mamba layers")
@@ -600,6 +672,69 @@ def _build_gdn_params(config: ModelConfig, shardings: dict[str, Any], get: Any) 
     }
     if not c.tie_embeddings:
         tree["lm_head"] = get("lm_head.weight")[rows].T.astype(dtype)
+    logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
+                c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
+                rows.start, rows.stop - 1)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_window_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The window stack's tree from HF Cohere2-MoE names (module text); of a
+    share, the experts and the rows of the tied vocabulary it holds."""
+    import jax
+
+    c = config
+    D, H, K, hd = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    dtype = np.dtype(c.dtype)
+    everywhere = range(c.n_layers)
+    rank = c.expert_first // c.n_routed_experts
+    rows = slice(rank * c.vocab_size, (rank + 1) * c.vocab_size)
+    halves = np.concatenate([np.arange(0, hd, 2), np.arange(1, hd, 2)])  # pairs -> halves
+
+    def stack(name: str, transform: Any) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in everywhere]).astype(dtype)
+
+    def experts(name: str) -> np.ndarray:
+        held = range(c.expert_first, c.expert_first + c.n_routed_experts)
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T for e in held])
+            for i in everywhere
+        ]).astype(dtype)
+
+    def shared(name: str, axis: int) -> np.ndarray:
+        # n modules -> the ONE SwiGLU of n x moe_d_ff: gate and up columns side by side, down rows stacked
+        return np.stack([
+            np.concatenate([
+                get(f"model.layers.{i}.mlp.shared_experts.{j}.{name}.weight").T
+                for j in range(c.n_shared_experts)], axis=axis)
+            for i in everywhere
+        ]).astype(dtype)
+
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight")[rows].astype(dtype),
+        "layers": {
+            "attn": {
+                "wq": stack("self_attn.q_proj.weight",
+                            lambda w: w.T.reshape(D, H, hd)[..., halves]),
+                "wk": stack("self_attn.k_proj.weight",
+                            lambda w: w.T.reshape(D, K, hd)[..., halves]),
+                "wv": stack("self_attn.v_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wo": stack("self_attn.o_proj.weight", lambda w: w.T.reshape(H, hd, D)),
+                "attn_norm": stack("input_layernorm.weight", lambda w: w),
+            },
+            "moe": {
+                "router": stack("mlp.gate.weight", lambda w: w.T),
+                "w_gate": experts("gate_proj"),
+                "w_up": experts("up_proj"),
+                "w_down": experts("down_proj"),
+                "s_gate": shared("gate_proj", 1),
+                "s_up": shared("up_proj", 1),
+                "s_down": shared("down_proj", 0),
+            },
+        },
+        "final_norm": get("model.norm.weight").astype(dtype),
+    }
     logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
                 c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
                 rows.start, rows.stop - 1)

@@ -38,6 +38,24 @@ rotation on the leading part of a head, ``o * sigmoid(gate)``), every norm
 multiplies by ``1 + w``, and EVERY layer's FFN is the expert block, its
 counters threaded through the scan as ``_latent_stack`` threads them.
 
+A window stack (``"window"`` among ``layer_types``: Cohere2-MoE,
+command-a-plus) keeps ``layers = {"attn": wq wk wv wo attn_norm [L, ..],
+"moe": see moe.py [L, ..]}`` and runs under ONE ``lax.scan`` over the period's
+repeats (``_window_stack``): ONE LayerNorm a layer, whose output the
+attention and the expert block both read (the parallel block), rotary on the
+window layers and no position at all on the global ones.  Its caches come BY
+KIND (``config.CACHE_KINDS``): every paged thing of such a model is a pair
+``(global, window)``: each side of the pool ``([Lg, Ng, K, page, hd], [Lw,
+Nw, K, page, hd])``, the block tables ``([B, Pg], [B, R])``, a wave's
+destination pages.  A row's window table is a RING of ``R`` pages: position
+``p`` of a window layer lies in table entry ``(p // page) % R``, so a row
+that grows writes over (gives back) what its window has left behind, and a
+read takes a LOWER bound beside the causal one (``_window_ring_valid``, the
+window form of the Pallas decode kernel, ``blocked_attention``).  The
+prefill scratch and the decode ring of fresh tokens stay one pair over all
+the layers, in stack order.  A description without a window layer never
+reaches that code.
+
 A latent-attention stack (``config.kv_lora_rank``: DeepSeek-V3's MLA, with
 routed experts after the leading dense layers when ``n_routed_experts``)
 keeps its layers in up to three stacked groups,
@@ -70,7 +88,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from calfkit_tpu.inference.config import ATTENTION, ModelConfig
+from calfkit_tpu.inference.config import ATTENTION, WINDOW, ModelConfig
 from calfkit_tpu.inference.gdn import gdn_chunk, gdn_step, init_gdn_params
 from calfkit_tpu.inference.mamba import (
     init_mamba_params,
@@ -131,6 +149,27 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
         }
         if config.moe:
             layers["moe"] = init_moe_params(config, keys[2], dtype)
+        return {
+            "embed": norm_init(keys[0], (V, D), D),
+            "layers": layers,
+            "final_norm": jnp.ones((D,), dtype),
+            **({} if config.tie_embeddings else {
+                "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
+        }
+
+    if config.windowed:
+        layers = {
+            "attn": {
+                "wq": norm_init(keys[1], (L, D, H, hd), D),
+                "wk": norm_init(keys[2], (L, D, K, hd), D),
+                "wv": norm_init(keys[3], (L, D, K, hd), D),
+                "wo": norm_init(keys[4], (L, H, hd, D), H * hd),
+                "attn_norm": jnp.ones((L, D), dtype),
+            },
+            "moe": init_moe_params(config, keys[5], dtype),
+        }
+        if config.parallel_block:  # ONE norm a layer
+            layers["moe"].pop("mlp_norm")
         return {
             "embed": norm_init(keys[0], (V, D), D),
             "layers": layers,
@@ -236,6 +275,15 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float, plus_one: bool = False
     if plus_one:
         weight = 1.0 + weight
     return (normed * weight).astype(orig_dtype)
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """``w * (x - mean) / sqrt(var + eps)`` in float32: a weight and NO bias
+    (Cohere's LayerNorm; not an RMSNorm: the mean goes)."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(x.dtype)
 
 
 def rope_tables(
@@ -404,11 +452,15 @@ def mlp_residual(x: jax.Array, lp: Params, eps: float, residual: float = 1.0) ->
 
 
 def lm_logits(
-    x: jax.Array, params: Params, eps: float, scaling: float = 1.0, plus_one: bool = False
+    x: jax.Array, params: Params, eps: float, scaling: float = 1.0, plus_one: bool = False,
+    norm: str = "rms",
 ) -> jax.Array:
     """Final norm + (tied or untied) LM head; ``scaling`` divides the logits."""
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], eps, plus_one)
+        if norm == "layer":
+            x = layer_norm(x, params["final_norm"], eps)
+        else:
+            x = rms_norm(x, params["final_norm"], eps, plus_one)
         head = params.get("lm_head")
         if head is None:
             logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
@@ -797,6 +849,274 @@ prefill_attention = jax.named_scope("attention")(attention_xla)
 
 
 # --------------------------------------------------------------------------- #
+# window layers beside global ones (Cohere2-MoE): the stack and its reads
+# --------------------------------------------------------------------------- #
+
+# the scope a device trace groups each kind's attention core by, under
+# ``attention``: ``decode_loop/.../attention/window`` and ``.../attention/global``
+def _kind_scope(kind: str) -> Any:
+    return jax.named_scope("window") if kind == WINDOW else jax.named_scope("global")
+
+
+# keys a chunk's attention holds scores for at once (blocked_attention)
+CHUNK_KEY_BLOCK = 512
+
+
+def _window_ring_valid(
+    ring_tokens: int,  # R x page: the positions a row's ring of pages holds
+    base_lens: jax.Array,  # [B] keys written so far (positions 0 .. base - 1)
+    q_pos: jax.Array,  # [B] the query's position
+    window: int,
+) -> jax.Array:
+    """Which entries of a row's window ring a query may attend -> [B, R page].
+
+    Entry ``r`` holds the NEWEST position ``p < base`` with ``p = r (mod R
+    page)``: the ring is written in order, so what lay there before is what
+    the window has given back.  Attendable iff such a ``p`` exists and lies
+    inside the lower bound, ``p > q_pos - window`` (the causal bound holds by
+    construction: ``p < base <= q_pos``)."""
+    r = jnp.arange(ring_tokens, dtype=jnp.int32)[None, :]
+    newest = r + ring_tokens * ((base_lens[:, None] - 1 - r) // ring_tokens)  # < 0: never written
+    return (newest >= 0) & (newest > q_pos[:, None] - window)
+
+
+def blocked_attention(
+    q: jax.Array,  # [B, S, H, hd]
+    k_cache: jax.Array,  # [B, K, P, hd] (the scratch of one layer)
+    v_cache: jax.Array,
+    q_pos: jax.Array,  # [B, S] absolute positions of the queries
+    seq_lens: jax.Array,  # [B] valid kv per row
+    window: int = 0,  # > 0: query i sees key j iff i - window < j <= i
+    block: int = 0,  # keys a block; 0: CHUNK_KEY_BLOCK
+) -> jax.Array:
+    """A chunk's GQA attention a KEY BLOCK at a time with the running
+    maximum (the flash law of :func:`logsumexp_merge`): at 128 query heads
+    the scores of every head over a whole context are ``128 x chunk x
+    context x 4 B`` (8.6 GB for 1,024 x 16,384), so only ``block`` keys'
+    scores exist at once.  The loop runs over the blocks that CAN be seen:
+    to the longest row's length, and for a window layer from the block that
+    holds the first query's lower bound, so a window layer's chunk reads
+    ``window + chunk`` keys whatever the context.  Same roundings as
+    :func:`attention_xla` (scores and statistics float32, ``p`` in the
+    cache's type before the PV product)."""
+    B, S, H, hd = q.shape
+    K, P = k_cache.shape[1:3]
+    kb = math.gcd(P, block or CHUNK_KEY_BLOCK)
+    qg = q.reshape(B, S, K, H // K, hd)
+    scale = 1.0 / math.sqrt(hd)
+    hi = (jnp.max(seq_lens) + kb - 1) // kb
+    lo = jnp.maximum(jnp.min(q_pos) - window + 1, 0) // kb if window else 0
+
+    def body(j, carry):
+        m, z, o = carry
+        kj = lax.dynamic_slice_in_dim(k_cache, j * kb, kb, axis=2)
+        vj = lax.dynamic_slice_in_dim(v_cache, j * kb, kb, axis=2)
+        s = _einsum_f32("bskgh,bkwh->bkgsw", qg, kj) * scale
+        kv_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)[None, None, :]
+        valid = (kv_pos <= q_pos[:, :, None]) & (kv_pos < seq_lens[:, None, None])
+        if window:
+            valid = valid & (kv_pos > q_pos[:, :, None] - window)
+        s = jnp.where(valid[:, None, None, :, :], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new).astype(k_cache.dtype)
+        z = z * alpha + jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
+        o = o * alpha + _einsum_f32("bkgsw,bkwh->bkgsh", p, vj)
+        return m_new, z, o
+
+    lead = (B, K, H // K, S)
+    m, z, o = lax.fori_loop(lo, hi, body, (
+        jnp.full((*lead, 1), -1e29, jnp.float32),  # a fully masked query stays finite
+        jnp.zeros((*lead, 1), jnp.float32),
+        jnp.zeros((*lead, hd), jnp.float32),
+    ))
+    out = o / jnp.maximum(z, 1e-30)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(q.dtype)
+
+
+def _window_qkv(h, lp, cos, sin):
+    """The two kinds' shared front half on the layer's ONE normed input:
+    plain GQA projections, no bias, no q/k norm; the rotation over the whole
+    head where the kind has one (``cos`` None: none)."""
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dnh->bsnh", h, lp["wq"])
+        k = jnp.einsum("bsd,dkh->bskh", h, lp["wk"])
+        v = jnp.einsum("bsd,dkh->bskh", h, lp["wv"])
+        if cos is None:
+            return q, k, v
+        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+
+def _window_stack(
+    config: ModelConfig,
+    layers: Params,
+    x: jax.Array,
+    carry: Any,
+    attn_layer: Any,  # (carry, kind, q, k, v, il, ik) -> (carry, attn [B, S, H, hd])
+    positions: jax.Array,  # [B, S]
+    stats: Any,
+    valid: jax.Array | None,
+) -> tuple[jax.Array, Any, Any]:
+    """Run a window stack: ONE ``lax.scan`` over the repeats of the layer
+    period (W W W G), its layers unrolled in the body.  The block is the one
+    the description names: ``config.norm`` (LayerNorm or RMSNorm) and, with
+    ``config.parallel_block``, ``h = norm(x); x = x + Attn(h) + FFN(h)`` (ONE
+    norm, one residual add); else the sequential block with its second norm.
+    ``il`` is the layer's index in the stack (its rows in the scratch and the
+    fresh-token ring), ``ik`` its index among the layers of its KIND (its
+    pages in that kind's pool).  The decode step and the prefill chunk differ
+    only in ``attn_layer``."""
+    eps = config.norm_eps
+    period = config.layer_period
+    norm = {"layer": layer_norm, "rms": rms_norm}[config.norm]
+    cos, sin = rope_tables(positions, config.rotary_dim, config.rope_theta)
+    rotary = {WINDOW: True, ATTENTION: config.position_embedding == "rope"}
+
+    def body(c, p):
+        x, carry, stats = c
+        seen = {WINDOW: 0, ATTENTION: 0}
+        for j, kind in enumerate(period):
+            il = p * len(period) + j
+            ik = p * period.count(kind) + seen[kind]
+            seen[kind] += 1
+            lp, mp = _layer(layers["attn"], il), _layer(layers["moe"], il)
+            h = norm(x, lp["attn_norm"], eps)
+            q, k, v = _window_qkv(h, lp, *((cos, sin) if rotary[kind] else (None, None)))
+            carry, attn = attn_layer(carry, kind, q, k, v, il, ik)
+            with jax.named_scope("attn_out"):
+                a = jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
+            if not config.parallel_block:
+                x = x + a
+                h = norm(x, mp["mlp_norm"], eps)
+            with jax.named_scope("mlp"):
+                y, stats = moe_ffn(h, mp, config, stats, valid, il, layers["moe"])
+            x = x + a + y if config.parallel_block else x + y
+        return (x, carry, stats), None
+
+    (x, carry, stats), _ = lax.scan(
+        body, (x, carry, stats),
+        jnp.arange(config.n_layers // len(period), dtype=jnp.int32))
+    return x, carry, stats
+
+
+def _window_forward(params, config, tokens, positions, kv_cache, seq_lens, insert_at,
+                    stats, n_valid):
+    """``forward`` for a window stack: a prefill or a chunk against the
+    wave's scratch ``[L, B, K, P, hd]`` (every layer, every position: a
+    window layer's ring is filled from it when the wave lands)."""
+    x = params["embed"][tokens]
+    valid = None
+    if n_valid is not None:
+        valid = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] < n_valid[:, None]
+
+    def attn_layer(cache, kind, q, k, v, il, ik):
+        k_all, v_all = cache
+        k_page = _insert_chunk(lax.dynamic_index_in_dim(k_all, il, 0, keepdims=False), k, insert_at)
+        v_page = _insert_chunk(lax.dynamic_index_in_dim(v_all, il, 0, keepdims=False), v, insert_at)
+        with jax.named_scope("attention"), _kind_scope(kind):
+            attn = blocked_attention(
+                q, k_page, v_page, positions, seq_lens,
+                window=config.sliding_window if kind == WINDOW else 0)
+        return (lax.dynamic_update_index_in_dim(k_all, k_page, il, 0),
+                lax.dynamic_update_index_in_dim(v_all, v_page, il, 0)), attn
+
+    x, cache, stats = _window_stack(
+        config, params["layers"], x, tuple(kv_cache), attn_layer, positions, stats, valid)
+    logits = lm_logits(x, params, config.norm_eps, norm=config.norm)
+    if stats is None:
+        return logits, cache
+    return logits, cache, stats
+
+
+def _window_decode_step(params, config, tokens, ring, t, base_lens, attn_source, stats, active):
+    """One decode step of a window stack: the fresh K and V go to the
+    dispatch's ring of fresh tokens (all layers, stack order), and
+    ``attn_source(kind, ik, q, ring_k_i, ring_v_i)`` reads (that kind's pages
+    + the fresh tokens)."""
+    positions = (base_lens + t)[:, None]
+    x = params["embed"][tokens]
+    valid = None if active is None else active[:, None]
+
+    def attn_layer(ring, kind, q, k, v, il, ik):
+        ring_k, ring_v = ring
+        ring_k = lax.dynamic_update_slice(
+            ring_k, k[:, 0].astype(ring_k.dtype)[None, None], (il, t, 0, 0, 0))
+        ring_v = lax.dynamic_update_slice(
+            ring_v, v[:, 0].astype(ring_v.dtype)[None, None], (il, t, 0, 0, 0))
+        attn = attn_source(
+            kind, ik, q,
+            lax.dynamic_index_in_dim(ring_k, il, 0, keepdims=False),
+            lax.dynamic_index_in_dim(ring_v, il, 0, keepdims=False))
+        return (ring_k, ring_v), attn
+
+    x, ring, stats = _window_stack(
+        config, params["layers"], x, tuple(ring), attn_layer, positions, stats, valid)
+    logits = lm_logits(x, params, config.norm_eps, norm=config.norm)
+    if stats is None:
+        return logits, ring
+    return logits, ring, stats
+
+
+def _kind_decode_attention(kind, q, main_source, ring_k, ring_v, t):
+    """(a kind's pages + the fresh tokens) merged, under ``attention/<kind>``."""
+    B, _, H, hd = q.shape
+    K = ring_k.shape[2]
+    with jax.named_scope("attention"), _kind_scope(kind):
+        qg = q.reshape(B, K, H // K, hd)
+        out = logsumexp_merge(main_source(qg), ring_attention_source(qg, ring_k, ring_v, t))
+        return out.reshape(B, 1, H, hd).astype(q.dtype)
+
+
+def _window_decode_step_paged(params, config, tokens, pool, tables, ring, t, base_lens,
+                              wpages, attn_impl, active, moe):
+    """:func:`decode_step_ring_paged` for a window stack: the pool's sides
+    and the tables are pairs by kind.  A global layer reads its row's pages
+    ``0 .. ceil(len / page)`` as every model does; a window layer reads its
+    row's RING: every entry that holds a position inside ``(q - W, q]``."""
+    (kg, kw), (vg, vw) = pool
+    tg, tw = tables
+    W = config.sliding_window
+    pallas, interpret = attn_impl.startswith("pallas"), attn_impl == "pallas_interpret"
+    read_lens = base_lens if active is None else jnp.where(active, base_lens, 0)
+    q_pos = base_lens + t
+
+    def attn_source(kind, ik, q, rk, rv):
+        if pallas:
+            from calfkit_tpu.inference.pallas_attention import paged_decode_attention_pallas
+
+            def main(qg):
+                if kind == WINDOW:
+                    o, m, z = paged_decode_attention_pallas(
+                        qg, kw, vw, ik, tw, read_lens, wpages=tw.shape[1], interpret=interpret,
+                        window_starts=jnp.maximum(q_pos - W + 1, 0))
+                else:
+                    o, m, z = paged_decode_attention_pallas(
+                        qg, kg, vg, ik, tg, read_lens, wpages=wpages, interpret=interpret)
+                return o, m[..., None], z[..., None]
+        elif kind == WINDOW:
+            def main(qg):
+                k_ring = gather_window_paged(
+                    lax.dynamic_index_in_dim(kw, ik, 0, keepdims=False), tw, tw.shape[1])
+                v_ring = gather_window_paged(
+                    lax.dynamic_index_in_dim(vw, ik, 0, keepdims=False), tw, tw.shape[1])
+                return masked_attention_source(
+                    qg, k_ring, v_ring, _window_ring_valid(k_ring.shape[2], base_lens, q_pos, W))
+        else:
+            def main(qg):
+                k_win = gather_window_paged(
+                    lax.dynamic_index_in_dim(kg, ik, 0, keepdims=False), tg, wpages)
+                v_win = gather_window_paged(
+                    lax.dynamic_index_in_dim(vg, ik, 0, keepdims=False), tg, wpages)
+                return masked_attention_source(
+                    qg, k_win, v_win, jnp.arange(k_win.shape[2])[None, :] < base_lens[:, None])
+        return _kind_decode_attention(kind, q, main, rk, rv, t)
+
+    return _window_decode_step(
+        params, config, tokens, ring, t, base_lens, attn_source, moe, active)
+
+
+
+# --------------------------------------------------------------------------- #
 # the transformer
 # --------------------------------------------------------------------------- #
 
@@ -846,6 +1166,9 @@ def forward(
     W = attn_window or k_pages.shape[3]
     if config.latent:
         return _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W,
+                               insert_at, moe, n_valid)
+    if config.windowed:
+        return _window_forward(params, config, tokens, positions, kv_cache, seq_lens,
                                insert_at, moe, n_valid)
     if config.layer_types:
         x = _embed(params, config, tokens)
@@ -1458,7 +1781,7 @@ def sides_like(cache: tuple, lead: tuple) -> tuple[jax.Array, jax.Array]:
     """Zeroed arrays ``[*lead, width]``, one for each side of ``cache``, of
     that side's width and type (a decode ring or a prefill scratch beside
     a pool)."""
-    k, v = cache
+    k, v = (side[0] if isinstance(side, tuple) else side for side in cache)  # by kind: any
     return (jnp.zeros((*lead, k.shape[-1]), k.dtype), jnp.zeros((*lead, v.shape[-1]), v.dtype))
 
 
@@ -1476,12 +1799,27 @@ def make_empty_cache(
 
 
 def make_page_pool(
-    config: ModelConfig, num_pages: int, page_size: int, dtype: Any = None
-) -> tuple[jax.Array, jax.Array]:
-    """KV page pool [L, N, K, page, hd] x 2; page 0 is the trash page.  A
-    latent model's is [L, N, 1, page, r] and [L, N, 1, page, dr]: the two
-    parts of the one latent, and no K or V per head at all."""
+    config: ModelConfig, num_pages: int, page_size: int, dtype: Any = None,
+    window_pages: int = 0,
+) -> tuple[Any, Any]:
+    """The page pools, BY CACHE KIND, as a pair of sides; page 0 of every
+    pool is its trash page.
+
+    - K and V of every token (a dense or hybrid model's attention layers):
+      ``[L, N, K, page, hd]`` x 2.
+    - one latent a token (MLA): ``[L, N, 1, page, r]`` and ``[L, N, 1, page,
+      dr]``, the two parts of the latent, and no K or V per head at all.
+    - a model with window layers (``config.windowed``): each side is a pair
+      ``(global [Lg, N, K, page, hd], window [Lw, window_pages, K, page,
+      hd])``: ``num_pages`` pages for the layers that keep every token,
+      ``window_pages`` for the layers that keep a ring of pages a row."""
     dtype = dtype or jnp.dtype(config.dtype)
+    if config.windowed:
+        kg, vg = cache_sides(
+            config, (config.n_global_layers, num_pages, config.cache_heads, page_size), dtype)
+        kw, vw = cache_sides(
+            config, (config.n_window_layers, window_pages, config.cache_heads, page_size), dtype)
+        return (kg, kw), (vg, vw)
     return cache_sides(
         config, (config.n_kv_layers, num_pages, config.cache_heads, page_size), dtype)
 
@@ -1546,6 +1884,10 @@ def decode_step_ring_paged(
     :func:`pallas_attention.latent_rope_view` of its rope side), which a
     caller that loops over steps makes once, outside its loop.
     """
+    if config.windowed:
+        return _window_decode_step_paged(
+            params, config, tokens, pool, tables, ring, t, base_lens, wpages, attn_impl,
+            active, moe)
     pool_k, pool_v = pool
 
     def read_lens():  # what a Pallas read walks: nothing of a row not active
@@ -1606,6 +1948,7 @@ def consolidate_ring_paged(
     tables: jax.Array,  # [B, Pmax]
     base_lens: jax.Array,  # [B]
     active: jax.Array,  # [B] bool — inactive rows scatter to the trash page
+    layer_kinds: Any = None,  # pages by kind: (global_layer_ids, window_layer_ids)
 ) -> tuple[jax.Array, jax.Array]:
     """Write the dispatch's ring tokens through the block tables.
 
@@ -1620,6 +1963,8 @@ def consolidate_ring_paged(
     freed its pages yet (one-dispatch-late retirement frees them only
     after this dispatch lands).
     """
+    if isinstance(tables, tuple):  # pages by cache kind: (global, window)
+        return _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds)
     T = ring[0].shape[1]
     page = pool[0].shape[3]
 
@@ -1633,14 +1978,36 @@ def consolidate_ring_paged(
     page_ids = jnp.where(active[:, None] & in_range, page_ids, 0)
     offsets = pos % page  # [B, T]
 
-    # advanced indexing: pool[:, idx, :, off] with idx/off of shape [B, T] —
-    # the index arrays are NON-adjacent, so numpy semantics move their
-    # broadcast dims to the FRONT: values must be [B, T, L, K, hd]
-    def write(pool_side: jax.Array, r: jax.Array) -> jax.Array:
-        vals = jnp.transpose(r, (2, 1, 0, 3, 4)).astype(pool_side.dtype)
-        return pool_side.at[:, page_ids, :, offsets].set(vals)
+    return (_write_tokens(pool[0], ring[0], page_ids, offsets),
+            _write_tokens(pool[1], ring[1], page_ids, offsets))
 
-    return write(pool[0], ring[0]), write(pool[1], ring[1])
+
+def _write_tokens(pool_side, r, page_ids, offsets):
+    """``r`` [L, T, B, K, hd] into ``pool_side`` at (page, offset) [B, T].
+    Advanced indexing: pool[:, idx, :, off] with idx/off of shape [B, T]: the
+    index arrays are NON-adjacent, so numpy semantics move their broadcast
+    dims to the FRONT: values must be [B, T, L, K, hd]."""
+    vals = jnp.transpose(r, (2, 1, 0, 3, 4)).astype(pool_side.dtype)
+    return pool_side.at[:, page_ids, :, offsets].set(vals)
+
+
+def _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds):
+    """:func:`consolidate_ring_paged` for pools by kind: the global layers'
+    tokens go through the global table as ever; the window layers' land IN
+    THE RING, table entry ``(position // page) % R``, over what the window
+    has left behind."""
+    (kg, kw), (vg, vw) = pool
+    tg, tw = tables
+    gl, wl = (jnp.asarray(ids, jnp.int32) for ids in layer_kinds)
+    rk, rv = ring
+    kg, vg = consolidate_ring_paged((kg, vg), (rk[gl], rv[gl]), tg, base_lens, active)
+    T, page, R = rk.shape[1], kw.shape[3], tw.shape[1]
+    pos = base_lens[:, None] + jnp.arange(T)[None, :]  # [B, T]
+    page_ids = jnp.take_along_axis(tw, (pos // page) % R, axis=1)
+    page_ids = jnp.where(active[:, None], page_ids, 0)
+    offsets = pos % page
+    return ((kg, _write_tokens(kw, rk[wl], page_ids, offsets)),
+            (vg, _write_tokens(vw, rv[wl], page_ids, offsets)))
 
 
 @jax.named_scope("kv_write")
@@ -1648,8 +2015,19 @@ def write_prefill_pages(
     pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] (donated)
     scratch: tuple[jax.Array, jax.Array],  # [L, R, K, P, hd] prefill K/V
     page_ids: jax.Array,  # [R, P // page] int32 destination pages
+    layer_kinds: Any = None,  # pages by kind: (global_layer_ids, window_layer_ids)
 ) -> tuple[jax.Array, jax.Array]:
-    """Scatter whole prefill pages into the pool (page-granular writes)."""
+    """Scatter whole prefill pages into the pool (page-granular writes).
+    Pools by kind take ``page_ids`` as a pair too: the global layers' pages
+    of the scratch go to the row's global pages, the window layers' to its
+    ring, where the caller has named the trash page for every page of the
+    scratch but the last ``R`` that hold the row's own tokens."""
+    if isinstance(page_ids, tuple):
+        (kg, kw), (vg, vw) = pool
+        gl, wl = (jnp.asarray(ids, jnp.int32) for ids in layer_kinds)
+        kg, vg = write_prefill_pages((kg, vg), (scratch[0][gl], scratch[1][gl]), page_ids[0])
+        kw, vw = write_prefill_pages((kw, vw), (scratch[0][wl], scratch[1][wl]), page_ids[1])
+        return (kg, kw), (vg, vw)
     L, R, K, P, _ = scratch[0].shape
     page = pool[0].shape[3]
     npg = P // page

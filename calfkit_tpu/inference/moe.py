@@ -58,7 +58,12 @@ token's k are chosen among all of them; the products run over the experts
 THIS device holds (``[expert_first, expert_first + n_routed_experts)``), so
 what an absent expert would add to a token is left out, in both forms: the
 other shares' devices add theirs, and the weights are NOT renormalised over
-the held.  The two forms at that model's shape (128 held of 512 scored,
+the held.  A Cohere2-MoE-style layer (command-a-plus: ``scoring_func``
+"sigmoid" with ``topk_method`` "greedy") is the first gate without its bias
+(``s = sigmoid``, the k largest ``s`` chosen, ``w = s / sum of the chosen``),
+held by share the same way, and its ``n_shared_experts`` are AVERAGED
+(``shared_expert_combine``): the one shared SwiGLU of ``n x moe_d_ff`` times
+``1 / n``.  The two forms at Qwen3-Next's shape (128 held of 512 scored,
 2048 x 512, 10 a token; one scan over 8 layers on a v5e, ms a layer, as
 above; PERF.md section 6, PRs 33 and 34):
 
@@ -185,7 +190,9 @@ def route(
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
         return chosen.astype(jnp.int32), weights * c.routed_scaling_factor
     scores = jax.nn.sigmoid(logits)
-    _, chosen = lax.top_k(scores + lp["router_bias"].astype(jnp.float32), c.n_experts_per_tok)
+    # "greedy" (Cohere2-MoE): the k largest scores themselves, no bias on the choice
+    pick = scores + lp["router_bias"].astype(jnp.float32) if "router_bias" in lp else scores
+    _, chosen = lax.top_k(pick, c.n_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)  # the UNBIASED scores
     if c.norm_topk_prob:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -316,5 +323,10 @@ def moe_ffn(
                         "td,d->t", flat.astype(jnp.float32),
                         lp["shared_gate"].astype(jnp.float32), precision=_HI))
                     shared = (shared.astype(jnp.float32) * gate[:, None]).astype(shared.dtype)
+                if config.shared_expert_combine == "average":
+                    # the n shared experts ARE one SwiGLU of n x moe_d_ff (columns
+                    # side by side, the down rows stacked): their mean is one scale
+                    shared = (shared.astype(jnp.float32) / config.n_shared_experts).astype(
+                        shared.dtype)
                 y = y + shared
     return y.reshape(B, S, D), stats

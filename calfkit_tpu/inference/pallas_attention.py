@@ -138,10 +138,8 @@ PAGED_DECODE_PAGES_PER_BLOCK = 2
 
 def _paged_decode_kernel(
     layer_ref, tables_ref, lens_ref,  # scalar-prefetch (SMEM)
-    q_ref, pool_k, pool_v,  # q block in VMEM; the pools stay in HBM
-    o_ref, m_ref, z_ref,
-    kbuf, vbuf, sems,
-    *, wpages: int, group: int, pack: int,
+    *refs,  # [starts_ref (the window form alone),] q_ref, pool_k, pool_v, outs, scratch
+    wpages: int, group: int, pack: int, ring: bool = False,
 ):
     """One ROW of a paged decode step: a loop over that row's own live
     pages, ``ceil(len / page)`` of them, each fetched as its whole
@@ -163,7 +161,18 @@ def _paged_decode_kernel(
     ``f * c + j`` and lane block j of its PV rows their weighted sum (the
     other blocks are dropped): the same body at ``f * H`` rows and 128
     lanes, and the f partial results folded by the logsumexp law at the end.
+
+    ``ring`` is the WINDOW form (a window layer's pool: the row's table is a
+    ring of ``wpages`` entries, position ``p`` in entry ``(p // page) %
+    wpages``): a fourth scalar array gives each row the first position its
+    query may see, the walk starts at the page that holds it and wraps
+    around the table, and that page's head is masked, so the work follows
+    ``min(len, window)``.  Without it nothing of this is traced: the kernel
+    of a model without window layers is the one it was.
     """
+    if ring:
+        starts_ref, *refs = refs
+    q_ref, pool_k, pool_v, o_ref, m_ref, z_ref, kbuf, vbuf, sems = refs
     b = pl.program_id(0)
     P, K, rows, lanes = kbuf.shape[1:]  # a slab: rows of pack positions
     page = rows * pack
@@ -172,11 +181,17 @@ def _paged_decode_kernel(
     C = P * K * rows
     layer = layer_ref[0]
     kv_len = lens_ref[b]
+    if ring:
+        # positions seen: [start, len); the walk and the mask count from the
+        # first page of them
+        first = starts_ref[b] // page
+        start = starts_ref[b] - first * page
+        kv_len = kv_len - first * page
     n_pages = jnp.minimum(pl.cdiv(kv_len, page), wpages)
     n_blocks = pl.cdiv(n_pages, P)
 
     def copies(blk, slot, i):
-        n = tables_ref[b, blk * P + i]
+        n = tables_ref[b, (first + blk * P + i) % wpages if ring else blk * P + i]
         return (
             pltpu.make_async_copy(
                 pool_k.at[layer, n], kbuf.at[slot, i], sems.at[0, slot]
@@ -228,6 +243,8 @@ def _paged_decode_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [H, C]
         mask = own_head & (col_pos < kv_len - blk * (P * page))
+        if ring:
+            mask = mask & (col_pos >= start - blk * (P * page))
         s = jnp.where(mask, s, -1e30)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -297,9 +314,16 @@ def paged_decode_attention_pallas(
     wpages: int,
     interpret: bool = False,
     pages_per_block: int = PAGED_DECODE_PAGES_PER_BLOCK,
+    window_starts: jax.Array | None = None,  # [B]: the WINDOW form (see below)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Paged decode attention → (o [B,K,G,hd] f32 unnormalized, m [B,K,G]
     f32, z [B,K,G] f32).
+
+    ``window_starts`` selects the kernel's window form for a window layer's
+    pool: ``tables`` [B, wpages] is then each row's RING of pages and row
+    ``b`` attends positions ``window_starts[b] <= p < base_lens[b]`` (the
+    caller's lower bound, ``max(0, q - W + 1)``).  None: the form every
+    other model has, the same program as before there was a window.
 
     Reads each row's LIVE pages in place (:func:`_paged_decode_kernel`):
     the pool goes in whole and stays in HBM, the grid is the rows alone,
@@ -323,15 +347,16 @@ def paged_decode_attention_pallas(
         )
     _note_trace("paged_decode", interpret)
     P = max(1, min(pages_per_block, wpages))
+    ring = window_starts is not None
     kernel = functools.partial(
-        _paged_decode_kernel, wpages=wpages, group=G, pack=f
+        _paged_decode_kernel, wpages=wpages, group=G, pack=f, **({"ring": True} if ring else {})
     )
 
     def row_map(b, *_refs):
         return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4 if ring else 3,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, f * H, lanes), row_map),
@@ -367,6 +392,7 @@ def paged_decode_attention_pallas(
         jnp.asarray(layer, jnp.int32).reshape(1),
         tables.astype(jnp.int32),
         base_lens.astype(jnp.int32),
+        *((jnp.minimum(window_starts, base_lens).astype(jnp.int32),) if ring else ()),
         _lane_block_copies(q.reshape(B, H, hd).astype(pool_k.dtype), f),
         pool_k, pool_v,
     )

@@ -108,6 +108,20 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         if config.moe:
             layers["moe"] = dict.fromkeys(
                 MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole)
+    elif config.windowed:
+        # a window stack (see model.py): every leaf replicated, as the latent
+        # stack's are and for its reason (one device holds its SHARE of the
+        # experts and of the tied vocabulary whole: the engine refuses such a
+        # model on a mesh of more than one device); the parallel block has
+        # ONE norm a layer
+        whole = NamedSharding(mesh, P())
+        layers = {
+            "attn": dict.fromkeys(("wq", "wk", "wv", "wo", "attn_norm"), whole),
+            "moe": dict.fromkeys(
+                ("router", "w_gate", "w_up", "w_down")
+                + (() if config.parallel_block else ("mlp_norm",))
+                + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole),
+        }
     elif config.gdn:
         # a Gated DeltaNet hybrid (see model.py): every leaf replicated, as
         # the latent stack's are and for its reason (the engine refuses

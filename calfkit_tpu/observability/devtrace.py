@@ -67,6 +67,9 @@ SCOPES = frozenset({
     # expert layer ("moe" inside "mlp")
     "mla", "q_proj", "kv_latent", "absorb",
     "moe", "router", "group", "experts", "combine", "shared",
+    # a window stack's attention cores by layer kind, inside "attention"
+    # (model.py's _kind_scope): the sliding-window layers' and the global ones'
+    "window", "global",
     # gdn.py (inside "gdn", which model.py opens around a Gated DeltaNet
     # mixer: "in_proj", "conv", "gate_norm", "out_proj" as Mamba-2's, and
     # "state" for the delta rule's pass over S) and the gated attention's

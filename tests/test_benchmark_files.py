@@ -573,7 +573,189 @@ def test_the_manifest_carries_qwen_s_cell_and_its_two_metrics():
     names = [m["name"] for m in MANIFEST["per_layer"]]  # appended in PR 33; PR 36's four came after
     at = names.index("gdn_device_pct")
     assert names[at:at + 2] == ["gdn_device_pct", "gdn_state_roofline"] and at == 16
-    assert MANIFEST["workloads"][-1]["name"] == QWEN_CELL and len(MANIFEST["workloads"]) == 4
-    entry = MANIFEST["configs"][-1]
+    assert MANIFEST["workloads"][3]["name"] == QWEN_CELL and len(MANIFEST["workloads"]) >= 4
+    entry = MANIFEST["configs"][3]
     assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
+
+
+# ------------------------------------------------ command-a-plus-05-2026 (PR 38)
+COHERE, COHERE_CELL = "command-a-plus-05-2026", "command-a-plus-05-2026.longdoc-closed"
+
+
+def test_command_a_plus_s_counts_are_what_a_hand_reckons():
+    """The cut's bytes as ISSUE 38 reckons them, and the counts of THIS
+    chip's share: a window layer's keys and values count min(context, W)
+    tokens whatever the program reads."""
+    arch = M.load_architecture("cohere2-moe-swa")
+    config = config_file(COHERE)
+    expert = 3 * 4096 * 4096
+    attn = 2 * 4096 * 128 * 128 + 2 * 4096 * 8 * 128
+    assert attn == 142_606_336 and expert == 50_331_648
+    hbm = config["hbm"]
+    assert arch.weight_bytes(config) == 2 * config["parameters"] == hbm["weights_bytes"]
+    assert round(hbm["weights_bytes"] / 1e9, 2) == 9.47
+    assert hbm["experts_bytes"] == 4 * 16 * expert * 2 == 6_442_450_944
+    assert hbm["shared_experts_bytes"] == 4 * 4 * expert * 2 and hbm["attention_bytes"] == 4 * attn * 2
+    assert hbm["embedding_bytes"] == 32768 * 4096 * 2
+    assert arch.state_bytes_per_token(config) == hbm["kv_bytes_per_token_per_layer"] == 4096
+    assert hbm["window_ring_pages_per_slot"] == 66 and hbm["window_pool_pages"] == 32 * 66
+    assert hbm["window_pool_bytes"] == 3 * (32 * 66 + 1) * 64 * 4096
+    assert hbm["global_pool_bytes"] == config["runtime"]["num_kv_pages"] * 64 * 4096
+    assert hbm["one_pool_for_every_layer_bytes"] > 16e9 - hbm["weights_bytes"]  # what does not fit
+    assert arch.experts_hit(config, 32) == pytest.approx(16 * (1 - (1 - 8 / 128) ** 32))
+    assert 13.9 < arch.experts_hit(config, 32) < 14.1
+    layer = arch.expert_layer_step(config, 32, 14.0)
+    gate = 4096 * 128
+    assert layer["bytes"] == (14 * expert + 4 * expert + gate) * 2
+    assert layer["flops"] == 2 * 32 * (1.0 * expert + 4 * expert + gate)  # 8 x 16 / 128 lie here
+    ring = arch.window_layers_step(config, 32, 3 * 32 * 4096.0)
+    assert ring["bytes"] == 3 * 32 * 4096 * 4096 and ring["flops"] == 4 * 128 * 128 * 3 * 32 * 4096
+    short, long = (arch.decode_step(config, 32, n) for n in (4096, 12000))
+    # past the window only the ONE global layer's keys and values grow
+    assert long["bytes"] - short["bytes"] == 32 * (12000 - 4096) * 4096
+    assert long["flops"] - short["flops"] == 4 * 128 * 128 * 32 * (12000 - 4096)
+    early, late = (arch.prefill_chunk(config, 1, 2048, at) for at in (4096, 12288))
+    assert late["flops"] - early["flops"] == 4 * 128 * 128 * 2048 * (12288 - 4096)
+    assert 3.0e9 < early["flops"] / 2048 < 5.5e9  # a prompt token: ~3.2 GFLOP of products + attention
+
+
+def test_the_program_s_description_of_command_a_plus_is_the_file_s():
+    arch = M.load_architecture("cohere2-moe-swa")
+    config = config_file(COHERE)
+    described, runtime = arch.model(config, False)
+    assert described.param_count == config["parameters"] == 4_733_292_544
+    assert config["published"] == {"num_hidden_layers": 32, "num_experts": 128,
+                                   "vocab_size": 262144}
+    assert "8 chips share a layer" in config["deployment"]
+    assert config["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert len(config["layer_types"]) == 32  # kept whole, as published
+    assert (described.n_routed_experts, described.experts_scored, described.expert_first,
+            described.n_experts_per_tok, described.n_shared_experts) == (16, 128, 0, 8, 4)
+    assert described.layer_types == ("window", "window", "window", "attention")
+    assert (described.head_dim, described.rotary_dim, described.n_heads, described.n_kv_heads,
+            described.sliding_window, described.moe_d_ff) == (128, 128, 128, 8, 4096, 4096)
+    assert (described.norm, described.parallel_block, described.position_embedding,
+            described.shared_expert_combine, described.scoring_func, described.topk_method,
+            described.tie_embeddings) == (
+        "layer", True, "rope_window", "average", "sigmoid", "greedy", True)
+    assert (runtime.max_batch_size, runtime.max_seq_len, runtime.prefill_chunk,
+            runtime.max_prefill_wave, runtime.prefix_cache, runtime.window_buckets) == (
+        32, 18432, 2048, 1, False, (18432,))
+    assert described.window_ring_pages(runtime.page_size, runtime.decode_steps_per_dispatch) == 66
+    from calfkit_tpu.inference.config import preset
+
+    published = preset("command-a-plus-05-2026")
+    assert published.param_count == config["published_parameters"]
+    for field in ("d_model", "n_heads", "n_kv_heads", "head_dim", "moe_d_ff", "sliding_window",
+                  "n_experts_per_tok", "n_shared_experts", "experts_scored", "rope_theta",
+                  "norm_eps"):
+        assert getattr(described, field) == getattr(published, field), field
+    toy, toy_runtime = arch.model(config, True)
+    assert toy.expert_share and toy.layer_types == described.layer_types
+    assert toy.sliding_window * config["rehearsal"]["scale"] == described.sliding_window
+    with pytest.raises(ValueError, match="use_parallel_block"):
+        arch.model({**config, "use_parallel_block": False}, False)
+
+
+def test_the_catalog_s_numbers_are_the_file_s():
+    """Every number of the published config under its own key, but the three
+    cuts (the driver holds the file to the catalog the same way)."""
+    config = config_file(COHERE)
+    published = {
+        "head_dim": 128, "hidden_size": 4096, "intermediate_size": 4096, "layer_norm_eps": 1e-05,
+        "layer_switch": 4, "logit_scale": 1, "max_position_embeddings": 200000,
+        "num_attention_heads": 128, "num_experts_per_tok": 8, "num_key_value_heads": 8,
+        "num_shared_experts": 4, "prefix_dense_intermediate_size": 16384, "rope_theta": 50000,
+        "rotary_pct": 1, "sliding_window": 4096, "first_k_dense_replace": 0,
+    }
+    assert {k: config[k] for k in published} == published
+    assert (config["num_hidden_layers"], config["num_experts"], config["vocab_size"]) == (
+        4, 16, 32768)
+
+
+def test_the_window_readers_read_what_their_files_say_and_nothing_elsewhere():
+    from types import SimpleNamespace
+
+    read = {n: M.load_reader(n) for n in (
+        "swa_device_pct", "swa_cache_roofline", "kv_pages_given_back_pct", "moe_device_pct",
+        "moe_expert_roofline")}
+    arch, config = M.load_architecture("cohere2-moe-swa"), config_file(COHERE)
+    steps, rows = 40, 10
+    window_tokens = 3 * rows * 4096 * steps
+    counters = {"decode_tokens": rows * steps, "decode_dispatches": 5, "short_dispatches": 0,
+                "moe_experts_hit": 9 * 4 * steps, "decode_window_tokens_read": window_tokens,
+                "decode_global_tokens_read": rows * 9000 * steps}
+    run = SimpleNamespace(
+        trace_reduced={"busy_s": 2.0, "by_scope": {
+            "decode_loop/attention/window": 0.1, "chunk_loop/attention/window": 0.5,
+            "decode_loop/attention/global": 0.2, "decode_loop/mlp/moe/experts": 0.4,
+            "chunk_loop/window/attention": 9.0}, "own_by_op": {}},
+        trace_counters=counters, counters={"window": counters}, arch=arch, config=config, chips=1,
+        model_config=arch.model(config, False)[0],
+        runtime=SimpleNamespace(decode_steps_per_dispatch=8, page_size=64),
+        peaks=M.load_peaks("TPU v5 lite"))
+    assert read["swa_device_pct"](run) == pytest.approx(30.0)
+    least = window_tokens * 4096 / 819e9  # bytes bound: 16 query heads a KV head at one query
+    assert read["swa_cache_roofline"](run) == pytest.approx(100 * least / 0.1)
+    assert 0 < read["swa_cache_roofline"](run) < 100
+    # 4 layers x 9,000 tokens against 9,000 + 3 x 4,096
+    assert read["kv_pages_given_back_pct"](run) == pytest.approx(
+        100 * (4 * 9000 - 9000 - 3 * 4096) / (4 * 9000))
+    assert read["moe_device_pct"](run) == pytest.approx(20.0)
+    assert 0 < read["moe_expert_roofline"](run) < 100
+    # a program without the scope or the counters (the parent, every other cell)
+    older = SimpleNamespace(**{**vars(run), "trace_counters": {"decode_tokens": 400},
+                               "counters": {"window": {"decode_tokens": 400}},
+                               "trace_reduced": {"busy_s": 2.0, "by_scope": {
+                                   "decode_loop/attention": 0.4}, "own_by_op": {}}})
+    assert all(read[n](older) is None for n in (
+        "swa_device_pct", "swa_cache_roofline", "kv_pages_given_back_pct"))
+    untraced = SimpleNamespace(**{**vars(run), "trace_reduced": None, "trace_counters": None})
+    assert read["swa_device_pct"](untraced) is None and read["swa_cache_roofline"](untraced) is None
+
+
+def test_the_manifest_carries_command_a_plus_s_cell_and_its_two_metrics():
+    cell = M.resolve_cell(MANIFEST, COHERE_CELL, M.ROOT)
+    assert cell.chips == 1 and cell.params == {"callers": 32}
+    assert cell.traffic_name == "longdoc-closed"
+    law = cell.traffic["prompt_tokens"]
+    assert (law["law"], law["median"], law["sigma"], law["min"], law["max"]) == (
+        "lognormal", 8192, 0.5, 4097, 16000)
+    assert cell.traffic["output_tokens"] == {
+        "law": "choice", "values": [128, 256, 512], "weights": [0.3, 0.4, 0.3]}
+    # delivered tokens/s judges a cell only where the slots bound it (PERF.md section 2):
+    # here the prefill lane does (occupancy 44-47%), ~27 requests fall into a window, and
+    # the driver's two sets of six runs spread 4.1% and 6.7%, past half the 10% bound
+    assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "setup_s"}
+    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [COHERE_CELL]}
+    assert {n: own[n]["moves"] for n in own} == {
+        "swa_device_pct": "tpot_p95_ms", "swa_cache_roofline": "tpot_p95_ms"}
+    registered = {m.name for m in cell.per_layer}
+    assert {*own, "moe_device_pct", "moe_expert_roofline", "dispatch_roofline"} <= registered
+    assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline"} & registered
+    assert [m["name"] for m in MANIFEST["per_layer"]][-2:] == list(own)
+    assert MANIFEST["workloads"][-1]["name"] == COHERE_CELL and len(MANIFEST["workloads"]) == 5
+    entry = MANIFEST["configs"][-1]
+    assert entry["name"] == COHERE and len(MANIFEST["configs"]) == 5
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
+    for listed in ("moe_device_pct", "moe_expert_roofline"):
+        metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
+        assert metric["workloads"][-1] == COHERE_CELL, listed
+
+
+def test_what_moves_tokens_a_second_is_recorded_in_command_a_plus_s_cell_and_judges_nothing():
+    """Every metric that moves ``out_tok_s_per_chip`` keeps its file and its reader, so a
+    traced run of the cell logs it (``recorded-only``); none is in the cell's result."""
+    cell = M.resolve_cell(MANIFEST, COHERE_CELL, M.ROOT)
+    registered = {m.name for m in cell.per_layer}
+    logged = {m.name: m for m in M.unregistered(cell)}
+    for name in ("kv_pages_given_back_pct", "batch_occupancy_pct", "empty_slot_queued_pct",
+                 "kv_pages_peak_pct", "hbm_peak_gb", "moe_expert_load_ratio"):
+        assert name not in registered and logged[name].moves == "out_tok_s_per_chip", name
+        assert callable(logged[name].read)
+    for metric in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        if metric["name"] == "out_tok_s_per_chip" or metric.get("moves") == "out_tok_s_per_chip":
+            assert COHERE_CELL not in metric["workloads"], metric["name"]
+    assert "kv_pages_given_back_pct" not in {m["name"] for m in MANIFEST["per_layer"]}

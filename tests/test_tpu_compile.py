@@ -40,6 +40,9 @@ DECODE_PAGED_WIDTHS = {
     # gated-attention layers of the cut (PR 33), first run through the kernel here
     "qwen3-next-80b-a3b-instruct": (2, 8, 256, 64, 4096, 4097, 2),
     "llama-3-8b": (8, 4, 128, 64, 1024, 257, 2),
+    # 16 query heads a KV head at a head of 128: the ONE global layer of the cut
+    # (PR 38); its three window layers take the kernel's window form (below)
+    "command-a-plus-05-2026": (8, 16, 128, 32, 18432, 6145, 1),
 }
 
 
@@ -833,4 +836,137 @@ def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persiste
         assert memory.temp_size_in_bytes <= {
             1: 2_765_331_968, 2: 2_825_830_912, 4: 3_188_042_752}[rows]
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9
+    print("temporaries, bytes:", report)
+
+
+# Window layers beside global ones, pages by cache kind (command-a-plus-05-2026):
+# the decode read's WINDOW form over a ring of pages a row, and the cell's
+# dispatch programs with a chunk's attention a key block at a time
+# ---------------------------------------------------------------------------
+
+
+def test_paged_decode_window_form_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The kernel's window form as a window layer's decode read calls it at
+    the cell's widths: 3 layers' pool of every slot's ring (66 pages a row),
+    the fourth scalar array, the walk around the ring."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    K, G, hd, rows, ring, layers = 8, 16, 128, 32, 66, 3
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = (shape((layers, rows * ring + 1, K, PAGE, hd), bf16),) * 2
+    before = PA.KERNEL_TRACES["paged_decode", "compiled"]
+    compiled = jax.jit(
+        lambda q, k, v, layer, tables, lens, starts: PA.paged_decode_attention_pallas(
+            q, k, v, layer, tables, lens, wpages=ring, window_starts=starts)
+    ).lower(
+        shape((rows, K, G, hd), bf16), *pool, shape((), i32), shape((rows, ring), i32),
+        shape((rows,), i32), shape((rows,), i32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
+
+
+def _window_cell_engine(held: int | None = None):
+    """The engine of the cell's configuration at its published WIDTHS, its 4
+    layers (one period W W W G) and its runtime; ``held`` experts of the 16
+    where the test has no use for 6.4 GB of them (the gate keeps its 128
+    outputs)."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "command-a-plus-05-2026.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    assert config.layer_types == ("window", "window", "window", "attention")
+    if held is not None:
+        config = replace(config, n_routed_experts=held)
+    engine = InferenceEngine(
+        config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
+    assert engine._attn_impl == "pallas" and engine._ring_pages == 66
+    return engine
+
+
+def _window_cell_programs(engine, one_chip, buckets):
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    args, window, steps, sampled = engine._decode_args()
+    assert window == 18432 == rt.max_seq_len
+    zero = engine._moe_zero
+    decode = engine._decode_jit(window, steps, sampled).lower(
+        *abstract(args), moe=abstract(zero)).compile()
+    hlo = decode.as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    # the period's four decode reads in the scan's body: three of the window form, one global
+    assert len(kernels) == 4 and all("paged_decode_attention" in k for k in kernels)
+    assert sum("/attention/window/" in k for k in kernels) == 3
+    assert sum("/attention/global/" in k for k in kernels) == 1
+    assert "gather_window" not in hlo and "/mlp/moe/experts" in hlo
+    pools = sum(a.nbytes for a in jax.tree.leaves((engine._k, engine._v)))
+    assert decode.memory_analysis().alias_size_in_bytes >= pools
+    report = {"decode": decode.memory_analysis().temp_size_in_bytes}
+    chunk = rt.prefill_chunk
+    for bucket in buckets:
+        scratch = [jax.ShapeDtypeStruct((cfg.n_kv_layers, 1, cfg.cache_heads, bucket, w),
+                                        jnp.bfloat16) for w in cfg.cache_dims]
+        wave = [*scratch, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32)]
+        ragged = engine._ragged_jit(window, steps, sampled, chunk, 1).lower(
+            *abstract((*args, *wave)), true_lens=abstract(jax.ShapeDtypeStruct((1,), jnp.int32)),
+            moe=abstract(zero), wmoe=abstract(zero)).compile()
+        text = ragged.as_text()
+        assert "decode_loop/" in text and "chunk_loop/" in text and "ragged-dot" in text
+        assert all(re.search(rf'chunk_loop/[^"]*attention/{kind}/', text)
+                   for kind in ("window", "global"))
+        memory = ragged.memory_analysis()
+        report[f"ragged {bucket}"] = memory.temp_size_in_bytes
+        # a chunk's attention a key block at a time: no scores of 128 heads over a context
+        assert memory.temp_size_in_bytes < 2.5e9
+        yield_text = text
+    return report, yield_text
+
+
+def test_window_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
+    """The decode dispatch and the widest bucket's ragged program of the new
+    cell at its published widths, 4 layers and 32 slots (2 of the 16 held
+    experts: the products' shapes but not 6 GB of them), compiled for the
+    described v5e: four kernels in the decode loop, the pools go out where
+    they came in, the ragged program's temporaries under 2.5 GB."""
+    engine = _window_cell_engine(held=2)
+    report, _ = _window_cell_programs(engine, one_chip, (16384,))
+    print("temporaries, bytes (2 held experts):", report)
+
+
+@pytest.mark.slow  # 9.5 GB of weights and three whole-program compiles on every core: the offline
+# lane runs it, as it runs the other expert cells'; PERF.md section 6, PR 38 has its readings
+def test_window_cell_programs_at_full_size_copy_no_expert_stack(one_chip, no_persistent_cache):
+    """The same at the cell's FULL size, all 16 held experts of 100.7 MB in
+    4 layers, the narrowest and the widest bucket: no operation of a loop's
+    body makes an array of a layer's experts and no stack is copied into
+    another layout (PR 33's finding at Qwen3-Next's shape: a dense chunk can
+    make the compiler copy whole expert stacks; every chunk here is grouped),
+    and arguments and temporaries together fit the 16 GB chip."""
+    engine = _window_cell_engine()
+    report, text = _window_cell_programs(engine, one_chip, (6144, 16384))
+    assert not any("copy(" in line and "bf16[4,16,4096,4096]" in line.split("copy(")[0]
+                   for line in text.splitlines())
+    assert not _made_in_loops(text, ("bf16[16,4096,4096]",))
     print("temporaries, bytes:", report)
