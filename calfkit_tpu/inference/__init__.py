@@ -2,8 +2,9 @@
 remote HTTPS APIs (SURVEY.md §1 layer 4, §2.3).
 
 Compute path: JAX/XLA with GSPMD tensor-parallel sharding over a device mesh;
-three Pallas kernels (the paged decode read in place, of K and V pairs and of a latent
-pool; a hybrid model's SSM step in one pass over its state); a continuous-batching engine that
+four Pallas kernels (the paged decode read in place, of K and V pairs and of a latent
+pool; a hybrid model's SSM step in one pass over its state; a window stack's prefill chunk
+with its scores kept in VMEM); a continuous-batching engine that
 the Worker drives from Kafka-partition consumption.
 
 Import is lazy at the package boundary: nothing here pulls in jax until an

@@ -113,6 +113,12 @@ REAP, ADMIT, HANDOFF, PREP, ENQUEUE, SYNC, FANOUT, IDLE = PHASES
 _PHASE_ANNOTATION = {p: "engine." + p[len("phase_"):-len("_s")] for p in PHASES}
 BLOCKED = tuple(f for f in _SECONDS_FIELDS if f.startswith("blocked_"))
 NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
+# a window stack's prefill chunks, by what their attention HAS to compute and
+# what walks it (EngineStats has what each counts)
+CHUNK_ATTN_FIELDS = (
+    "chunk_attn_pairs_window", "chunk_attn_pairs_global",
+    "chunk_attn_key_blocks_visited", "chunk_attn_key_blocks_dense",
+)
 # the EngineStats fields folded into /metrics counters once a dispatch
 # counters that go to /metrics and ``counters()`` only, never on the
 # heartbeat advert's window
@@ -120,7 +126,7 @@ _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
     "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
-    "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
+    *CHUNK_ATTN_FIELDS, "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
     "programs_built", "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
     "moe_dense_chunks", *_SECONDS_FIELDS,
@@ -143,6 +149,16 @@ _ACTIVE_LOCK = threading.Lock()
 
 # every live engine, for ``GET /programs`` (weak: an abandoned engine goes)
 _ENGINES: "weakref.WeakSet[InferenceEngine]" = weakref.WeakSet()
+
+
+def chunk_attention_of_all_engines() -> dict:
+    """The chunk attention counters (``CHUNK_ATTN_FIELDS``) summed over the
+    live engines of the process: what ``devtrace.capture`` takes before and
+    after its window, so that ``GET /profile`` holds the needed pairs
+    beside the device seconds under ``chunk_loop/.../attention``."""
+    return {
+        f: sum(getattr(e.stats, f) for e in list(_ENGINES)) for f in CHUNK_ATTN_FIELDS
+    }
 
 
 def programs_of_all_engines() -> "list[dict]":
@@ -255,6 +271,27 @@ def _engine_metrics(
             "calfkit_engine_decode_global_tokens_read_total",
             "decode steps: rows x len x global layers, of a model with window "
             "layers beside them",
+        ),
+        chunk_attn_pairs_window=reg.counter(
+            "calfkit_engine_chunk_attn_pairs_window_total",
+            "prefill chunks of a model with window layers: the (query, key) pairs "
+            "the chunk's own positions must attend, sum of min(q + 1, "
+            "sliding_window) x window layers (host arithmetic at launch)",
+        ),
+        chunk_attn_pairs_global=reg.counter(
+            "calfkit_engine_chunk_attn_pairs_global_total",
+            "the same for the global layers: sum of (q + 1) x global layers",
+        ),
+        chunk_attn_key_blocks_visited=reg.counter(
+            "calfkit_engine_chunk_attn_key_blocks_visited_total",
+            "(query tile, key block) steps the chunk attention kernel's bounds "
+            "walk for a launched chunk, over rows and layers of both kinds",
+        ),
+        chunk_attn_key_blocks_dense=reg.counter(
+            "calfkit_engine_chunk_attn_key_blocks_dense_total",
+            "the same steps as the key-block loop of model.blocked_attention "
+            "walks them (every tile from the chunk's first block to its last): "
+            "visited / dense is what the per-tile bounds save",
         ),
         window_pages_given_back=reg.counter(
             "calfkit_engine_window_pages_given_back_total",
@@ -752,6 +789,17 @@ class EngineStats:
     decode_window_tokens_read: int = 0
     decode_global_tokens_read: int = 0
     window_pages_given_back: int = 0
+    # a window stack's prefill chunks (0 for every other model), host
+    # arithmetic at launch (``_note_chunk_attention``): the (query, key)
+    # pairs the chunk's OWN positions must attend, ``sum_q min(q + 1, W)`` a
+    # window layer and ``sum_q (q + 1)`` a global one, over that kind's
+    # layers: the needed work, whatever computes it; and the (query tile,
+    # key block) steps the kernel's bounds walk against those the key-block
+    # loop walks for the same chunk (all layers)
+    chunk_attn_pairs_window: int = 0
+    chunk_attn_pairs_global: int = 0
+    chunk_attn_key_blocks_visited: int = 0
+    chunk_attn_key_blocks_dense: int = 0
     kv_pages_global_in_use: int = 0
     kv_pages_window_in_use: int = 0
     kv_pages_global_total: int = 0
@@ -1186,6 +1234,7 @@ class InferenceEngine:
         self._paged = rt.kv_layout == "paged"
         self._attn_impl = self._resolved_attn_impl()
         self._ssm_impl = self._resolved_ssm_impl()
+        self._chunk_attn_impl = self._resolved_chunk_attn_impl()
         if self._paged:
             from calfkit_tpu.inference.paged import PageAllocator
             from calfkit_tpu.inference.sharding import pool_sharding
@@ -1525,8 +1574,11 @@ class InferenceEngine:
 
     # ------------------------------------------------------------ jit build
     def _resolved_attn_impl(self) -> str:
-        """Which implementation the PAGED DECODE READ uses: the one
-        attention computation that has a kernel.  Decided HERE and nowhere
+        """Which implementation the PAGED DECODE READ uses: one of the two
+        attention computations that have a kernel (the other, a window
+        stack's prefill chunks, is chosen beside it in
+        :meth:`_resolved_chunk_attn_impl`; three kernels in all: two bodies
+        of this read and the chunk's).  Decided HERE and nowhere
         else, once at construction (``self._attn_impl``), from what the
         engine can observe (PERF.md section 6, PRs 25, 28 and 32: measured
         on the v5e).  Under "auto": the Pallas kernel that reads each row's
@@ -1577,6 +1629,44 @@ class InferenceEngine:
                 ': use "auto" or "xla"'
             )
         return impl
+
+    def _resolved_chunk_attn_impl(self) -> str:
+        """Which implementation a WINDOW STACK's prefill and prefill-chunk
+        attention uses: the third computation that has a kernel.  Decided
+        HERE, once at construction (``self._chunk_attn_impl``), under the
+        same ``attention_impl`` values as the paged decode read, and handed
+        to ``model.forward`` as a static argument.  Under "auto": the Pallas
+        kernel that keeps a query tile's scores in VMEM and walks each
+        tile's own key blocks (``pallas_attention.chunk_attention_pallas``)
+        when the backend is a TPU, one device holds the model, the model
+        has window layers beside global ones (``config.windowed``: the one
+        stack whose chunks run ``model.blocked_attention``) and every
+        forward the engine will build is inside the kernel's rule
+        (:func:`pallas_attention.chunk_attention_ok`: a head of whole lane
+        tiles, ``prefill_chunk`` whole query tiles, a scratch of whole key
+        blocks: a scratch is a bucket, a multiple of ``prefill_chunk`` or
+        ``max_seq_len`` itself); else ``blocked_attention``, the XLA
+        reference and the CPU path (PERF.md section 6, PR 39: measured on
+        the v5e).
+
+        "pallas" / "pallas_interpret" waive the platform test alone; they
+        NAME the decode read's kernel, so a chunk outside this rule is
+        served by XLA and not refused (as ``_resolved_ssm_impl`` has it)."""
+        impl = self.runtime.attention_impl
+        c, rt = self.config, self.runtime
+        if not c.windowed or impl == "xla" or (
+            impl == "auto" and jax.devices()[0].platform != "tpu"
+        ):
+            return "xla"
+        from calfkit_tpu.inference.pallas_attention import chunk_attention_ok
+
+        in_rule = self.mesh.size == 1 and all(
+            chunk_attention_ok(c.head_dim, rt.prefill_chunk, scratch, c.dtype)
+            for scratch in (rt.prefill_chunk, rt.max_seq_len)
+        )
+        if not in_rule:
+            return "xla"
+        return "pallas" if impl == "auto" else impl
 
     def _resolved_ssm_impl(self) -> str:
         """Which implementation a Mamba layer's DECODE STEP uses for its
@@ -1981,7 +2071,7 @@ class InferenceEngine:
         fn = self._prefill_jits.get((bucket, rows, sampled))
         if fn is not None:
             return fn
-        cfg = self.config
+        cfg, chunk_attn_impl = self.config, self._chunk_attn_impl
 
         def prefill(
             params, k, v, last, lens, tokens, slots, true_lens,
@@ -2002,6 +2092,7 @@ class InferenceEngine:
                     **({} if state is None else {"state": make_recurrent_state(cfg, R)}),
                     **({} if moe is None else {"moe": moe}),
                     **({} if state is None and moe is None else {"n_valid": true_lens}),
+                    chunk_attn_impl=chunk_attn_impl,
                 )
             if moe is not None:  # the wave's expert counters leave last
                 moe = wstate.pop()
@@ -2043,7 +2134,7 @@ class InferenceEngine:
         ``start`` against a scratch holding the chunk itself (the
         per-row positions/lens ARE the (kind, start, q_len, kv_len)
         descriptor, serialized as arrays)."""
-        cfg = self.config
+        cfg, chunk_attn_impl = self.config, self._chunk_attn_impl
 
         @jax.named_scope("chunk_loop")
         def chunk_step(params, sk, sv, tokens_chunk, offset,
@@ -2065,6 +2156,7 @@ class InferenceEngine:
                 **({} if wmoe is None else {"moe": wmoe}),
                 **({} if wstate is None and wmoe is None else {
                     "n_valid": jnp.clip(true_lens - offset, 0, chunk)}),
+                chunk_attn_impl=chunk_attn_impl,
             )
             return (sk, sv, logits, *wstate)  # logits [R, chunk, V]
 
@@ -3996,6 +4088,23 @@ class InferenceEngine:
             return []
         return [inf["wstate"], jnp.asarray(inf["arrays"]["true_lens"])]
 
+    def _note_chunk_attention(self, offset: int, chunk: int, bucket: int, true_lens: Any) -> None:
+        """Count a launched chunk's attention (a model with window layers;
+        host arithmetic from shapes and the rows' true lengths, no sync):
+        ``EngineStats.chunk_attn_*``."""
+        if not self._windowed:
+            return
+        from calfkit_tpu.inference.pallas_attention import chunk_attention_work
+
+        cfg = self.config
+        pairs_w, pairs_g, visited, dense = chunk_attention_work(
+            offset, chunk, bucket, true_lens, cfg.sliding_window,
+            cfg.n_window_layers, cfg.n_global_layers)
+        self.stats.chunk_attn_pairs_window += pairs_w
+        self.stats.chunk_attn_pairs_global += pairs_g
+        self.stats.chunk_attn_key_blocks_visited += visited
+        self.stats.chunk_attn_key_blocks_dense += dense
+
     def _moe_kw(self, inf: "dict | None" = None, decode: bool = True) -> dict:
         """A program's expert-counter arguments, by name (a hybrid's state
         goes by place before them): zeroed counters for its decode steps
@@ -4222,6 +4331,7 @@ class InferenceEngine:
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
             *landed,
         ) = fn(*args, **self._state_kw(), **({"moe": self._moe_zero} if self._moe else {}))
+        self._note_chunk_attention(0, bucket, bucket, arrays["true_lens"])
         seq = self._enq_seq
         moe = landed.pop() if self._moe else None
         self._note_state_landed(landed)
@@ -4323,6 +4433,7 @@ class InferenceEngine:
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk),
             *self._wave_state_args(inf), **self._moe_kw(inf, decode=False),
         )
+        self._note_chunk_attention(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
         inf["scratch"] = (sk, sv)
         if self._recurrent:
             inf["wstate"] = wstate.pop(0)
@@ -4544,6 +4655,7 @@ class InferenceEngine:
             *_some(self._state), *self._wave_state_args(inf), **self._moe_kw(inf),
         ))
         seq = self._note_launch("ragged", program, steps, started, queued, R, R * chunk)
+        self._note_chunk_attention(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
         if self._moe:
             inf["wmoe"] = res.pop()
         if self._recurrent:

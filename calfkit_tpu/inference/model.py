@@ -1000,10 +1000,17 @@ def _window_stack(
 
 
 def _window_forward(params, config, tokens, positions, kv_cache, seq_lens, insert_at,
-                    stats, n_valid):
+                    stats, n_valid, chunk_attn_impl="xla"):
     """``forward`` for a window stack: a prefill or a chunk against the
     wave's scratch ``[L, B, K, P, hd]`` (every layer, every position: a
-    window layer's ring is filled from it when the wave lands)."""
+    window layer's ring is filled from it when the wave lands).
+
+    ``chunk_attn_impl`` (static; ``InferenceEngine._resolved_chunk_attn_impl``
+    chose it) names what computes a layer's attention: :func:`blocked_attention`
+    ("xla", the reference) or the Pallas kernel that is the same flash law
+    with the scores kept in VMEM (``pallas_attention.chunk_attention_pallas``),
+    which takes a row's positions as CONSECUTIVE from its first: a prefill's
+    and a chunk's are (offset + 0 .. S - 1)."""
     x = params["embed"][tokens]
     valid = None
     if n_valid is not None:
@@ -1013,10 +1020,16 @@ def _window_forward(params, config, tokens, positions, kv_cache, seq_lens, inser
         k_all, v_all = cache
         k_page = _insert_chunk(lax.dynamic_index_in_dim(k_all, il, 0, keepdims=False), k, insert_at)
         v_page = _insert_chunk(lax.dynamic_index_in_dim(v_all, il, 0, keepdims=False), v, insert_at)
+        window = config.sliding_window if kind == WINDOW else 0
         with jax.named_scope("attention"), _kind_scope(kind):
-            attn = blocked_attention(
-                q, k_page, v_page, positions, seq_lens,
-                window=config.sliding_window if kind == WINDOW else 0)
+            if chunk_attn_impl.startswith("pallas"):
+                from calfkit_tpu.inference.pallas_attention import chunk_attention_pallas
+
+                attn = chunk_attention_pallas(
+                    q, k_page, v_page, positions[:, 0], seq_lens, window=window,
+                    interpret=chunk_attn_impl == "pallas_interpret")
+            else:
+                attn = blocked_attention(q, k_page, v_page, positions, seq_lens, window=window)
         return (lax.dynamic_update_index_in_dim(k_all, k_page, il, 0),
                 lax.dynamic_update_index_in_dim(v_all, v_page, il, 0)), attn
 
@@ -1134,6 +1147,7 @@ def forward(
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv) of the rows
     n_valid: jax.Array | None = None,  # hybrid: [B] positions of the chunk that are the row's own
     moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters (moe.py)
+    chunk_attn_impl: str = "xla",  # static; a window stack's chunk attention (_window_forward)
 ) -> Any:
     """Run the decoder over a token chunk, updating the cache functionally.
 
@@ -1169,7 +1183,7 @@ def forward(
                                insert_at, moe, n_valid)
     if config.windowed:
         return _window_forward(params, config, tokens, positions, kv_cache, seq_lens,
-                               insert_at, moe, n_valid)
+                               insert_at, moe, n_valid, chunk_attn_impl)
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = _positions_tables(config, positions)

@@ -1,4 +1,5 @@
-"""The Pallas TPU kernels for paged single-query decode attention.
+"""The Pallas TPU attention kernels: paged single-query decode attention,
+and a window stack's prefill chunk.
 
 Why kernels: the XLA paged read copies every row's whole window out of the
 pool before attending it (``model.gather_window_paged``), whatever the row
@@ -8,8 +9,8 @@ of the pool in HBM, bf16 operands into the MXU, mask + softmax statistics +
 weighted sum fused.  Its work follows the row lengths, not the window
 bucket.
 
-There are TWO bodies, because the two kinds of pool ask for different
-things of one page:
+The decode read has TWO bodies, because the two kinds of pool ask for
+different things of one page:
 
 - :func:`_paged_decode_kernel` reads K and V pairs of kv heads under a
   block-diagonal head mask (a dense or hybrid model's pool).  A head that
@@ -23,15 +24,32 @@ things of one page:
   side is read as it lies; the narrow rope side through
   :func:`latent_rope_view`, made once a dispatch.
 
-Every other attention computation (prefill, prefill chunks, speculative
-verify, dense decode, the long-context lane) is the XLA source in
-``model.py``: the kernels that once stood beside these ran in no measured
-cell or lost where they were measured (PERF.md section 6, PRs 25 and 29).
+The THIRD kernel is a multi-query one (PR 39): a WINDOW STACK's prefill and
+prefill-chunk attention, :func:`_chunk_attention_kernel`.  There the XLA
+source, ``model.blocked_attention``, is a loop over key blocks whose scores
+(128 heads x 2,048 queries x 512 keys in float32 at command-a-plus's
+widths: 537 MB a block) cross HBM several times a block; the kernel is the
+same flash law with a query tile's scores, maximum, sum and output kept in
+VMEM across the key axis, the G query heads of a KV head riding one program
+so that a key block copied once serves them all, and each tile's first and
+last key block (the window's lower bound, the causal bound and the row's
+length) prefetched as scalars, so blocks outside are neither copied nor
+computed.  It reads one layer's prefill scratch ``[B, K, P, hd]`` as it
+lies.
 
-Both entry points (:func:`paged_decode_attention_pallas`,
+Every other attention computation (prefill and prefill chunks of every
+model WITHOUT window layers, speculative verify, dense decode, the
+long-context lane) is the XLA source in ``model.py``: the kernels that once
+stood beside these ran in no measured cell or lost where they were measured
+(PERF.md section 6, PRs 25 and 29: ``attention_xla`` was 3% of the Mistral
+cell's device time; a window stack's chunks were 41% of theirs).
+
+The two decode entry points (:func:`paged_decode_attention_pallas`,
 :func:`latent_decode_attention_pallas`) return *unnormalized* output plus
 the softmax statistics ``(m, z)`` so the caller can fold in the fresh-token
-ring (tiny, plain XLA) with the logsumexp merge the XLA path uses.
+ring (tiny, plain XLA) with the logsumexp merge the XLA path uses;
+:func:`chunk_attention_pallas` returns the normalized output, as
+``blocked_attention`` does.
 
 What the TPU lowering demands, and how the kernels meet it: per-row scalars
 (lengths, block tables, the layer index) ride ``PrefetchScalarGridSpec``
@@ -41,16 +59,22 @@ stays in HBM (``memory_space=pl.ANY``) and a page slab is copied whole, so
 a slab is whole tiles (:func:`paged_decode_in_place_ok`,
 :func:`latent_decode_in_place_ok`).
 
-Who chooses them: ``InferenceEngine._resolved_attn_impl``, once at
-construction, and nowhere else; WHICH body follows from what the engine
-observes in its model, ``config.latent``.  ``attention_impl="auto"``
-selects the kernel on a TPU, paged KV, one device, a shape its rule takes;
-else the XLA read, which is the reference.  ``"pallas"`` /
-``"pallas_interpret"`` exist for tests and bring-up: they waive the
-platform test alone, and an engine outside the rest of the rule is refused
-with :class:`PallasShapeError`.
+Who chooses them: ``InferenceEngine._resolved_attn_impl`` (the decode
+read) and ``_resolved_chunk_attn_impl`` (a window stack's chunks), once at
+construction, and nowhere else; WHICH decode body follows from what the
+engine observes in its model, ``config.latent``.  ``attention_impl="auto"``
+selects a kernel on a TPU, one device, a shape its rule takes (the decode
+read: paged KV too; the chunks: ``config.windowed``); else the XLA source,
+which is the reference.  ``"pallas"`` / ``"pallas_interpret"`` exist for
+tests and bring-up: they waive the platform test alone; they NAME the
+decode read, so an engine outside THAT rule is refused with
+:class:`PallasShapeError`, and a chunk outside its own is served by XLA.
 
-Status: both AOT-compile for a described v5e, the first at TinyLlama-1.1B's,
+Status: the chunk kernel AOT-compiles for a described v5e at
+command-a-plus's widths in both its forms (with and without a lower bound)
+and agrees with ``blocked_attention`` in interpret mode
+(``tests/test_chunk_attention.py``); PERF.md section 6, PR 39, has the
+chip's numbers.  The two decode bodies AOT-compile for a described v5e, the first at TinyLlama-1.1B's,
 Llama-3-8B's, Mistral-7B's, InternLM2-1.8B's and granite-4.0-h-micro's
 widths, the second at Kimi-VL-A3B's (``tests/test_tpu_compile.py``), and
 agree with interpret mode and the XLA path on CPU; PERF.md section 6, PRs
@@ -62,9 +86,11 @@ from __future__ import annotations
 import collections
 import functools
 import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -734,3 +760,254 @@ def merged_latent_decode_attention_pallas(
     )
     source2 = mla_ring_attention_source(q_lat, q_rope, ring, t, scale)
     return logsumexp_merge((o1, m1[..., None], z1[..., None]), source2)[:, None]
+
+
+# --------------------------------------------------------------------------- #
+# a window stack's prefill chunk: the scores stay in VMEM
+# --------------------------------------------------------------------------- #
+
+# query positions a program holds at once (x the G query heads of its KV head)
+# and keys a block: the scores of one grid step are [G * tile, block] float32,
+# 8 MB at 16 heads (tuned on the chip, PERF.md section 6, PR 39: a block of
+# 1,024 walks more keys past a window's edges than one of 512 and is still a
+# fifth faster, for the statistics and the output tile are rescaled once a
+# step).  A chunk or a scratch that is not a multiple takes the largest tile
+# that divides it (:func:`chunk_attention_tiles`).
+CHUNK_ATTN_QUERY_TILE = 128
+CHUNK_ATTN_KEY_BLOCK = 1024
+# the kernel's VMEM: scores, ``p`` and their temporaries at G * tile = 2,048
+# rows are past the 16 MiB a kernel gets by default (a v5e core has 128 MiB)
+CHUNK_ATTN_VMEM_BYTES = 64 * 1024 * 1024
+
+
+def chunk_attention_tiles(chunk: int, scratch: int) -> tuple[int, int]:
+    """(query tile, key block) the chunk kernel runs a chunk of ``chunk``
+    queries against a scratch of ``scratch`` positions with: the module's
+    tile sizes, or the largest divisor of them that divides the shape (as
+    ``model.blocked_attention`` takes its key block)."""
+    return math.gcd(chunk, CHUNK_ATTN_QUERY_TILE), math.gcd(scratch, CHUNK_ATTN_KEY_BLOCK)
+
+
+def chunk_attention_ok(head_dim: int, chunk: int, scratch: int, dtype) -> bool:
+    """Whether :func:`_chunk_attention_kernel` can take these shapes on a
+    TPU: a head of whole lane tiles (128), a query tile of whole sublane
+    tiles of the cache's dtype (the G heads of a tile are stacked along
+    them), a key block of whole lane tiles (it is the scores' minor
+    dimension).  What fails this runs ``model.blocked_attention``."""
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    tile, block = chunk_attention_tiles(chunk, scratch)
+    return head_dim % 128 == 0 and tile % sublanes == 0 and block % 128 == 0
+
+
+def chunk_attention_bounds(
+    q_start: jax.Array,  # [B] the position of each row's first query
+    seq_lens: jax.Array,  # [B] valid keys a row
+    *, chunk: int, scratch: int, window: int, tile: int, block: int, xp: Any = jnp,
+) -> tuple[Any, Any]:
+    """The key blocks each query tile walks -> (first [B, nQ], count [B, nQ]).
+
+    The first block holds the window's lower bound of the tile's FIRST query
+    (``q - window + 1``; block 0 without a window), the last the causal bound
+    of the tile's LAST query or the row's last valid key, whichever is lower.
+    Blocks outside are neither copied nor computed; the blocks on an edge
+    are masked by position in the kernel.  ``xp``: numpy for the host's own
+    count of the walk (:func:`chunk_attention_work`)."""
+    q_first = q_start[:, None] + tile * xp.arange(chunk // tile, dtype=xp.int32)[None, :]
+    last_key = xp.minimum(q_first + tile - 1, seq_lens[:, None] - 1)
+    first = xp.maximum(q_first - window + 1, 0) // block if window else xp.zeros_like(q_first)
+    first = xp.minimum(first, scratch // block - 1)
+    count = xp.maximum(last_key // block - first + 1, 0)  # (a floor: -1 // block is -1)
+    steps = chunk_attention_key_steps(chunk, scratch, window, tile, block)
+    return first.astype(xp.int32), xp.minimum(count, steps).astype(xp.int32)
+
+
+def chunk_attention_key_steps(chunk: int, scratch: int, window: int, tile: int, block: int) -> int:
+    """The grid's key axis: the most blocks one tile can need.  Without a
+    window that is every block of the scratch; with one, the blocks a span of
+    ``window + tile - 1`` consecutive keys can touch wherever it starts."""
+    blocks = scratch // block
+    if not window:
+        return blocks
+    return min(blocks, (window + tile - 1 + block - 2) // block + 1)
+
+
+def chunk_attention_work(
+    offset: int, chunk: int, scratch: int, true_lens: np.ndarray, window: int,
+    window_layers: int, global_layers: int,
+) -> tuple[int, int, int, int]:
+    """What a launched chunk's attention HAS to compute and what walks it,
+    on the host (numpy, no device work) -> (pairs a window layer needs x
+    window layers, pairs a global layer needs x global layers, (query tile,
+    key block) steps the kernel's bounds walk, the same steps as
+    ``model.blocked_attention``'s loop walks them; both over every layer).
+
+    The pairs are those of the rows' OWN positions, ``offset <= q <
+    min(offset + chunk, true_len)``: ``sum_q min(q + 1, window)`` and
+    ``sum_q (q + 1)``: the needed work, never the visited.  The walks are
+    over the chunk as the programs run it (every row to ``offset + chunk``,
+    padding included), with the tiles of :func:`chunk_attention_tiles`."""
+    lens = true_lens.astype(np.int64)  # the host's own array: no device value comes here
+    end = np.clip(lens, offset, offset + chunk)  # own positions: [offset, end), none where equal
+
+    def below(n):  # sum of (q + 1) over q < n
+        return n * (n + 1) // 2
+
+    pairs_global = int(np.sum(below(end) - below(offset)))
+    # a query under the window attends q + 1 keys, every later one the window
+    under, first = np.minimum(end, window), min(offset, window)
+    pairs_window = int(np.sum(
+        below(under) - below(first) + (end - offset - (under - first)) * window))
+    tile, block = chunk_attention_tiles(chunk, scratch)
+    at = np.full((1,), offset, np.int64)  # every row of a wave runs the same walk
+    visited = dense = 0
+    for w, layers in ((window, window_layers), (0, global_layers)):
+        first_block, count = chunk_attention_bounds(
+            at, at + chunk, chunk=chunk, scratch=scratch, window=w, tile=tile, block=block, xp=np)
+        loop = -(-(offset + chunk) // block) - int(first_block[0, 0])  # the chunk's span
+        visited += layers * len(lens) * int(count.sum())
+        dense += layers * len(lens) * (chunk // tile) * loop
+    return pairs_window * window_layers, pairs_global * global_layers, visited, dense
+
+
+def _chunk_attention_kernel(
+    first_ref, count_ref, lens_ref, starts_ref,  # scalar-prefetch (SMEM)
+    q_ref,  # [1, 1, G, tile, hd]
+    k_ref, v_ref,  # [1, 1, block, hd]: key block first + kj of this row and kv head
+    o_ref,  # [1, 1, G, tile, hd]
+    m_sc, z_sc, acc_sc,  # VMEM scratch, carried across the key axis
+    *, window: int, scale: float,
+):
+    """One (row, kv head, query tile) of a chunk's attention, a KEY BLOCK a
+    grid step: the G query heads of the kv head stacked over the tile's
+    positions score the block in ONE product ``[G * tile, hd] x [block, hd]``,
+    so a key block copied once serves them all.  The running maximum, the sum
+    and the output tile live in VMEM scratch across the key axis (the flash
+    law of ``model.blocked_attention``, with its roundings: bf16 operands,
+    float32 accumulation, scores and statistics float32, ``p`` in the cache's
+    type before the PV product, ``z`` from the rounded ``p``); the scores
+    never leave VMEM.
+
+    Steps past the tile's ``count`` do nothing: their index map names the
+    block already there, so nothing is copied either.  A row's queries are
+    consecutive positions from ``starts[b]``."""
+    b, qi, kj = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    G, tile, hd = q_ref.shape[2:]
+    block = k_ref.shape[2]
+    rows = G * tile
+
+    @pl.when(kj == 0)
+    def _init():
+        # the -1e29 floor of a fully masked query is where m starts
+        m_sc[...] = jnp.full(m_sc.shape, -1e29, jnp.float32)
+        z_sc[...] = jnp.zeros(z_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(kj < count_ref[b, qi])
+    def _block():
+        q = q_ref[0, 0].reshape(rows, hd)
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        s = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [rows, block]
+        q_pos = starts_ref[b] + qi * tile + lax.broadcasted_iota(jnp.int32, (tile, block), 0)
+        k_pos = (first_ref[b, qi] + kj) * block + lax.broadcasted_iota(
+            jnp.int32, (tile, block), 1)
+        valid = (k_pos <= q_pos) & (k_pos < lens_ref[b])
+        if window:
+            valid = valid & (k_pos > q_pos - window)
+        # one mask a position, whatever the head
+        s = jnp.where(valid[None], s.reshape(G, tile, block), -1e30).reshape(rows, block)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new).astype(v.dtype)
+        z_sc[...] = z_sc[...] * alpha + jnp.sum(p.astype(jnp.float32), axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _out():
+        out = acc_sc[...] / jnp.maximum(z_sc[...], 1e-30)
+        o_ref[0, 0] = out.reshape(G, tile, hd).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "tile", "block"))
+def chunk_attention_pallas(
+    q: jax.Array,  # [B, S, H, hd]
+    k_cache: jax.Array,  # [B, K, P, hd] ONE layer's prefill scratch as it lies
+    v_cache: jax.Array,
+    q_start: jax.Array,  # [B] the position of each row's first query
+    seq_lens: jax.Array,  # [B] valid keys a row
+    *,
+    window: int = 0,  # > 0: query i sees key j iff i - window < j <= i
+    interpret: bool = False,
+    tile: int = 0,  # 0: chunk_attention_tiles
+    block: int = 0,
+) -> jax.Array:
+    """A prefill chunk's GQA attention -> [B, S, H, hd] in ``q``'s type:
+    ``model.blocked_attention`` as ONE kernel (:func:`_chunk_attention_kernel`).
+
+    A row's S queries are CONSECUTIVE positions from ``q_start[b]`` (a
+    chunk's are: offset + 0 .. S - 1), which is what lets a tile's bounds be
+    two scalars (:func:`chunk_attention_bounds`).  The grid is (row, kv head,
+    query tile, key block), the key block innermost and sequential; the two
+    static forms are with and without a lower bound."""
+    B, S, H, hd = q.shape
+    K, P = k_cache.shape[1:3]
+    G = H // K
+    auto = chunk_attention_tiles(S, P)
+    tile, block = tile or auto[0], block or auto[1]
+    if S % tile or P % block or not (interpret or chunk_attention_ok(hd, S, P, k_cache.dtype)):
+        raise PallasShapeError(
+            f"the chunk attention kernel takes a head of whole lane tiles, a chunk of "
+            f"whole query tiles and a scratch of whole key blocks: head_dim={hd}, "
+            f"{S} queries in tiles of {tile}, {P} keys in blocks of {block}, "
+            f"{k_cache.dtype} (chunk_attention_ok)"
+        )
+    _note_trace("chunk_attention", interpret)
+    q_start, seq_lens = q_start.astype(jnp.int32), seq_lens.astype(jnp.int32)
+    first, count = chunk_attention_bounds(
+        q_start, seq_lens, chunk=S, scratch=P, window=window, tile=tile, block=block)
+    steps = chunk_attention_key_steps(S, P, window, tile, block)
+
+    def tile_map(b, k, qi, kj, *_refs):
+        return (b, k, 0, qi, 0)
+
+    def key_map(b, k, qi, kj, first_ref, count_ref, *_refs):
+        # a step past the tile's count names the block already there
+        live = jnp.minimum(kj, jnp.maximum(count_ref[b, qi] - 1, 0))
+        return (b, k, first_ref[b, qi] + live, 0)
+
+    rows = G * tile
+    out = pl.pallas_call(
+        functools.partial(_chunk_attention_kernel, window=window, scale=1.0 / math.sqrt(hd)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, K, S // tile, steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, tile, hd), tile_map),
+                pl.BlockSpec((1, 1, block, hd), key_map),
+                pl.BlockSpec((1, 1, block, hd), key_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, tile, hd), tile_map),
+            scratch_shapes=[
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, S, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=CHUNK_ATTN_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="chunk_attention",
+    )(
+        first, count, seq_lens, q_start,
+        # the G query heads of a kv head side by side over the positions
+        q.reshape(B, S, K, G, hd).transpose(0, 2, 3, 1, 4).astype(k_cache.dtype),
+        k_cache, v_cache,
+    )
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

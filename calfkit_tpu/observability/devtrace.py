@@ -25,6 +25,13 @@ two things the program puts on the record (ISSUE 24):
   of a program it had not (``drained``: the host was late), and gives
   ``dispatches``: one row a program, by its number.
 
+Beside the reduction, ``capture`` gives ``chunk_attention``: what the
+engines' four ``chunk_attn_*`` counters grew by over the window (ISSUE 39):
+the (query, key) pairs the window's prefill chunks HAD to attend, by layer
+kind, and the key-block steps walked.  ``4 x heads x head_dim x pairs``
+over a chip's peak, over the seconds under ``chunk_loop/.../attention``, is
+the chunk attention's share of its roofline.
+
 A program loaded from a persistent compile cache that another build
 filled carries THAT build's scope names (JAX leaves operation metadata
 out of the cache key): after an upgrade from a build without names,
@@ -42,6 +49,7 @@ import glob
 import os
 import re
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -120,16 +128,23 @@ def capture(seconds: float) -> dict:
     try:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0  # host spans only where annotated
+        # the needed (query, key) pairs of the window's prefill chunks, to
+        # set beside the device seconds under chunk_loop/.../attention (only
+        # a process that built an engine has the module)
+        engines = sys.modules.get("calfkit_tpu.inference.engine")
+        pairs = engines.chunk_attention_of_all_engines if engines else dict
         t0 = clock()
         try:
             jax.profiler.start_trace(trace_dir, profiler_options=options)
         except RuntimeError as exc:  # "Only one profile may be run at a time."
             return {"captured": False, "reason": str(exc)}
         t1 = clock()
+        before = pairs()
         try:
             time.sleep(seconds)
         finally:
             t2 = clock()
+            after = pairs()
             jax.profiler.stop_trace()
         t3 = clock()
         paths = sorted(glob.glob(
@@ -144,6 +159,7 @@ def capture(seconds: float) -> dict:
         out["took_s"] = {"start": t1 - t0, "stop": t3 - t2, "read": t4 - t3,
                          "reduce": clock() - t4}
         out["trace_bytes"] = os.path.getsize(paths[-1])
+        out["chunk_attention"] = {f: after[f] - before[f] for f in after}
         return {"captured": True, **out}
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)

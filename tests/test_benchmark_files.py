@@ -715,6 +715,48 @@ def test_the_window_readers_read_what_their_files_say_and_nothing_elsewhere():
     assert read["swa_device_pct"](untraced) is None and read["swa_cache_roofline"](untraced) is None
 
 
+def test_the_chunk_attention_reader_reads_its_share_and_nothing_without_its_sources():
+    """PR 39's one metric: its file, its reader and its ``workloads`` list load; on a
+    made-up run it reads the needed pairs' least time over the seconds under the two
+    chunk scopes; nothing without the counters or the scopes (the parent's side, every
+    other cell); under 100 where the measured time is above the least."""
+    from types import SimpleNamespace
+
+    entry = MANIFEST["per_layer"][-1]
+    assert entry == {"name": "chunk_attn_roofline", "unit": "%", "better": "higher",
+                     "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
+                     "workloads": [COHERE_CELL]}
+    cell = M.resolve_cell(MANIFEST, COHERE_CELL, M.ROOT)
+    metric = next(m for m in cell.per_layer if m.name == "chunk_attn_roofline")
+    assert metric.layer == "kernels" and callable(metric.read)
+    read, config = M.load_reader("chunk_attn_roofline"), config_file(COHERE)
+    # a chunk of 2,048 at offset 8,192: three window layers at 4,096 keys a query, the
+    # global layer at 8,193 .. 10,240
+    pairs_w = 3 * 2048 * 4096
+    pairs_g = sum(range(8193, 10241))
+    counters = {"chunk_attn_pairs_window": 10 * pairs_w, "chunk_attn_pairs_global": 10 * pairs_g,
+                "decode_tokens": 400}
+    by_scope = {"chunk_loop/attention/window": 0.17, "chunk_loop/attention/global": 0.10,
+                "decode_loop/attention/window": 5.0, "chunk_loop/mlp/moe/experts": 3.0,
+                "prefill/attention/window": 7.0}
+    run = SimpleNamespace(
+        trace_reduced={"busy_s": 8.0, "by_scope": by_scope, "own_by_op": {}},
+        trace_counters=counters, config=config, chips=1, peaks=M.load_peaks("TPU v5 lite"))
+    least = 4 * 128 * 128 * 10 * (pairs_w + pairs_g) / 197e12
+    assert read(run) == pytest.approx(100 * least / 0.27)
+    assert 0 < read(run) < 100
+    slower = SimpleNamespace(**{**vars(run), "trace_reduced": {
+        **run.trace_reduced, "by_scope": {**by_scope, "chunk_loop/attention/window": 1.7}}})
+    assert 0 < read(slower) < read(run)
+    for missing in (
+        {"trace_counters": {"decode_tokens": 400}},  # the parent: no such counter
+        {"trace_counters": {**counters, "chunk_attn_pairs_window": 0, "chunk_attn_pairs_global": 0}},
+        {"trace_reduced": {"busy_s": 8.0, "by_scope": {"decode_loop/attention/window": 5.0}}},
+        {"trace_reduced": None}, {"trace_counters": None},
+    ):
+        assert read(SimpleNamespace(**{**vars(run), **missing})) is None, missing
+
+
 def test_the_manifest_carries_command_a_plus_s_cell_and_its_two_metrics():
     cell = M.resolve_cell(MANIFEST, COHERE_CELL, M.ROOT)
     assert cell.chips == 1 and cell.params == {"callers": 32}
@@ -730,11 +772,12 @@ def test_the_manifest_carries_command_a_plus_s_cell_and_its_two_metrics():
     assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "setup_s"}
     own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [COHERE_CELL]}
     assert {n: own[n]["moves"] for n in own} == {
-        "swa_device_pct": "tpot_p95_ms", "swa_cache_roofline": "tpot_p95_ms"}
+        "swa_device_pct": "tpot_p95_ms", "swa_cache_roofline": "tpot_p95_ms",
+        "chunk_attn_roofline": "tpot_p95_ms"}  # (the third: PR 39)
     registered = {m.name for m in cell.per_layer}
     assert {*own, "moe_device_pct", "moe_expert_roofline", "dispatch_roofline"} <= registered
     assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline"} & registered
-    assert [m["name"] for m in MANIFEST["per_layer"]][-2:] == list(own)
+    assert [m["name"] for m in MANIFEST["per_layer"]][-3:] == list(own)
     assert MANIFEST["workloads"][-1]["name"] == COHERE_CELL and len(MANIFEST["workloads"]) == 5
     entry = MANIFEST["configs"][-1]
     assert entry["name"] == COHERE and len(MANIFEST["configs"]) == 5

@@ -1,12 +1,13 @@
-"""AOT compiles of the three Pallas kernels for a DESCRIBED v5e.
+"""AOT compiles of the four Pallas kernels for a DESCRIBED v5e.
 
 The TPU compiler is installed without a chip: it compiles for a topology
 that is described, not attached (on-chip-measurement guide, section 2).
 Interpret mode cannot see what the Mosaic lowering refuses — block shapes,
-SMEM scalars, VMEM limits — so the three kernels the serving path can
+SMEM scalars, VMEM limits — so the four kernels the serving path can
 select, the paged decode read in place (of K and V pairs, and of a latent
-pool in the absorbed form) and the Mamba-2 decode step's pass over the SSM
-state, are compiled here at the widths of every preset and benchmark
+pool in the absorbed form), the Mamba-2 decode step's pass over the SSM
+state and a window stack's chunk attention with its scores in VMEM, are
+compiled here at the widths of every preset and benchmark
 configuration that takes them, alone and inside the dispatch programs of
 the benchmark's cells.  Nothing runs; a pass is a compile, never a chip run.
 
@@ -490,7 +491,64 @@ def _latent_decode_entry(shape):
             lambda *a: PA.latent_decode_attention_pallas(*a, **static), args)
 
 
-@pytest.mark.parametrize("kernel", ["paged_decode", "ssm_step", "latent_decode"])
+# a window stack's chunk attention at the command-a-plus cell's own widths: 8 KV heads
+# of 16 query heads of 128, a chunk of 2,048 against the widest scratch, one row a wave
+CHUNK_ATTENTION_WIDTHS = dict(K=8, G=16, hd=128, chunk=2048, scratch=18432, window=4096)
+
+
+def _chunk_attention_args(shape, rows=1):
+    import jax.numpy as jnp
+
+    w = CHUNK_ATTENTION_WIDTHS
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return (
+        shape((rows, w["chunk"], w["K"] * w["G"], w["hd"]), bf16),
+        *(shape((rows, w["K"], w["scratch"], w["hd"]), bf16),) * 2,
+        shape((rows,), i32), shape((rows,), i32),
+    )
+
+
+def _chunk_attention_entry(shape):
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    window = CHUNK_ATTENTION_WIDTHS["window"]
+    return (PA.chunk_attention_pallas,
+            lambda *a: PA.chunk_attention_pallas(*a, window=window), _chunk_attention_args(shape))
+
+
+@pytest.mark.parametrize("window", [CHUNK_ATTENTION_WIDTHS["window"], 0])
+def test_chunk_attention_compiles_for_v5e(window, one_chip, no_persistent_cache):
+    """The chunk attention kernel alone, in its two static forms (a window
+    layer's lower bound, a global layer's none): a tile of 128 positions x 16
+    heads against key blocks of 1,024, 8 MB of scores that never leave VMEM;
+    the only float32 the program makes outside the kernel is none at all."""
+    import jax
+
+    from calfkit_tpu.inference import pallas_attention as PA
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    before = PA.KERNEL_TRACES["chunk_attention", "compiled"]
+    PA.chunk_attention_pallas.clear_cache()
+    compiled = jax.jit(
+        lambda *a: PA.chunk_attention_pallas(*a, window=window)
+    ).lower(*_chunk_attention_args(shape)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1 and "chunk_attention" in hlo
+    assert PA.KERNEL_TRACES["chunk_attention", "compiled"] == before + 1
+    w = CHUNK_ATTENTION_WIDTHS
+    assert PA.chunk_attention_tiles(w["chunk"], w["scratch"]) == (128, 1024)
+    # the key axis: every block of the scratch, or the six a window and a tile can touch
+    assert PA.chunk_attention_key_steps(w["chunk"], w["scratch"], window, 128, 1024) == (
+        6 if window else 18)
+    # q and o regrouped around the kernel (64 MB each at most), never a score buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2048 * 128 * 128 * 2 + 2 ** 20
+    assert " f32[" not in hlo.replace("f32[]", "")
+
+
+@pytest.mark.parametrize(
+    "kernel", ["paged_decode", "ssm_step", "latent_decode", "chunk_attention"])
 def test_kernel_bytes_do_not_depend_on_the_caller(
     kernel, one_chip, no_persistent_cache, monkeypatch
 ):
@@ -506,7 +564,8 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry,
-                      "latent_decode": _latent_decode_entry}[kernel](shape)
+                      "latent_decode": _latent_decode_entry,
+                      "chunk_attention": _chunk_attention_entry}[kernel](shape)
 
     def deep(*a, depth=4):
         if depth:
@@ -532,7 +591,7 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
 
 def test_entry_point_list_is_complete():
-    """The kernel modules' entry points are the five compiled above (a
+    """The kernel modules' entry points are the six compiled above (a
     merged read calls the plain one), ONE ``pallas_call`` a kernel body,
     and no other module of the package makes one: a kernel added without a
     compile of its own fails here."""
@@ -549,9 +608,10 @@ def test_entry_point_list_is_complete():
     assert entries(PA) == {
         "paged_decode_attention_pallas", "merged_paged_decode_attention_pallas",
         "latent_decode_attention_pallas", "merged_latent_decode_attention_pallas",
+        "chunk_attention_pallas",
     }
     assert entries(PS) == {"ssm_step_pallas"}
-    assert inspect.getsource(PA).count("pl.pallas_call(") == 2
+    assert inspect.getsource(PA).count("pl.pallas_call(") == 3
     assert inspect.getsource(PS).count("pl.pallas_call(") == 1
     with_kernels = sorted(
         os.path.basename(path)
@@ -841,7 +901,7 @@ def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persiste
 
 # Window layers beside global ones, pages by cache kind (command-a-plus-05-2026):
 # the decode read's WINDOW form over a ring of pages a row, and the cell's
-# dispatch programs with a chunk's attention a key block at a time
+# dispatch programs with a chunk's attention in the chunk kernel (PR 39)
 # ---------------------------------------------------------------------------
 
 
@@ -893,7 +953,7 @@ def _window_cell_engine(held: int | None = None):
         config = replace(config, n_routed_experts=held)
     engine = InferenceEngine(
         config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
-    assert engine._attn_impl == "pallas" and engine._ring_pages == 66
+    assert engine._attn_impl == engine._chunk_attn_impl == "pallas" and engine._ring_pages == 66
     return engine
 
 
@@ -936,9 +996,19 @@ def _window_cell_programs(engine, one_chip, buckets):
         assert "decode_loop/" in text and "chunk_loop/" in text and "ragged-dot" in text
         assert all(re.search(rf'chunk_loop/[^"]*attention/{kind}/', text)
                    for kind in ("window", "global"))
+        # the chunk's attention is the kernel, under the scopes swa_device_pct reads: the
+        # period's three window layers and its global one, beside the decode steps' reads
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        chunk_calls = [c for c in calls if "chunk_attention" in c]
+        assert len(chunk_calls) == 4 < len(calls), (len(chunk_calls), len(calls))
+        assert sum(bool(re.search(r"chunk_loop/[^\"]*attention/window/", c)) for c in chunk_calls) == 3
+        assert sum(bool(re.search(r"chunk_loop/[^\"]*attention/global/", c)) for c in chunk_calls) == 1
+        # and no key block's scores cross HBM: the loop made [1, 8, 16, 2048, 512] float32
+        assert not re.search(rf"f32\[1,{cfg.n_kv_heads},\d+,{chunk},\d+\]", text)
         memory = ragged.memory_analysis()
         report[f"ragged {bucket}"] = memory.temp_size_in_bytes
-        # a chunk's attention a key block at a time: no scores of 128 heads over a context
+        # no scores of 128 heads over a context, nor over a key block
         assert memory.temp_size_in_bytes < 2.5e9
         yield_text = text
     return report, yield_text
