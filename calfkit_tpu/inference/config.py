@@ -10,15 +10,17 @@ import math
 from dataclasses import dataclass, field, replace
 
 
-ATTENTION, MAMBA, GDN, WINDOW = "attention", "mamba", "gdn", "window"
-RECURRENT_KINDS = (MAMBA, GDN)
+ATTENTION, MAMBA, GDN, WINDOW, KDA = "attention", "mamba", "gdn", "window", "kda"
+RECURRENT_KINDS = (MAMBA, GDN, KDA)
+DELTA_RULE_KINDS = (GDN, KDA)  # the gated delta rule: a decay a head, or a key channel
 # What a layer of each kind leaves behind of a sequence, i.e. the cache kind
 # the engine has to hold for it: "global" = K and V of EVERY token, in pages
 # a row keeps for its whole life; "window" = K and V of the last
 # ``sliding_window`` tokens, in a ring of pages a row that it writes over
 # (gives back) as it grows; "state" = a recurrent state of fixed size a slot.
 # A new kind of layer is a row here and a mixer in model.py.
-CACHE_KINDS = {ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state"}
+CACHE_KINDS = {
+    ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state", KDA: "state"}
 
 
 class UnsupportedWithRecurrentLayers(ValueError):
@@ -78,6 +80,16 @@ class ModelConfig:
     ``parallel_block``), and the expert block as every layer's FFN, held by
     share as above, its shared experts summed or averaged
     (``shared_expert_combine``).
+    With ``"kda"`` among ``layer_types`` it is a Ling-3.0-style hybrid
+    (``bailing_hybrid``): Kimi Delta Attention (the gated delta rule with a
+    decay a key CHANNEL, bounded below by ``kda_lower_bound``; the ``gdn_*``
+    sizes, one gate a head on its output) beside LATENT attention
+    (``kv_lora_rank`` and its sizes: the one stack that has a recurrent
+    state and a latent pool; ``attn_output_gate``: one sigmoid gate a head
+    before ``W_o``), the first ``first_k_dense`` layers with a SwiGLU of
+    ``d_ff`` whatever their mixer, the expert block after them, its gate
+    choosing by GROUP (``n_group``, ``topk_group``) among all the experts it
+    scores and the experts held by share as above.
     """
 
     name: str = "debug"
@@ -166,17 +178,35 @@ class ModelConfig:
     # how the n_shared_experts' outputs meet: "sum" (one SwiGLU of n x moe_d_ff)
     # or "average" (that sum over n)
     shared_expert_combine: str = "sum"
+    # ---- Kimi Delta Attention beside latent attention (all defaults = as before) ----
+    # a "kda" layer's log-decay, one a key channel: kda_lower_bound x sigmoid(
+    # exp(A_log[head]) (a + dt_bias)), in [kda_lower_bound, 0) (FLA's safe gate)
+    kda_lower_bound: float = -5.0
+    # positions of a sub-block of the chunk form: inside one the decays factor
+    # into two products, whose exponents stay under 16 x 5 = 80 (gdn.py)
+    kda_sub_block: int = 16
+    # expert_swiglu_limit_list / share_expert_swiglu_limit_list of the layers
+    # HELD, one entry an expert layer (() = none published).  The clamp's form
+    # is not published: a nonzero entry is refused below, no guessed clamp ships
+    expert_swiglu_limits: tuple[float, ...] = ()
+    shared_expert_swiglu_limits: tuple[float, ...] = ()
 
     def __post_init__(self) -> None:
         if self.kv_lora_rank:
-            if self.layer_types:
-                raise ValueError("latent attention in a hybrid stack is not described")
+            if self.layer_types and KDA not in self.layer_types:
+                raise ValueError(
+                    "latent attention in a hybrid stack is described beside Kimi "
+                    'Delta Attention (layer_types with "kda") alone')
             if not (self.qk_nope_head_dim and self.qk_rope_head_dim and self.v_head_dim):
                 raise ValueError(
                     "latent attention needs qk_nope_head_dim/qk_rope_head_dim/v_head_dim"
                 )
             if self.qk_rope_head_dim % 2:
                 raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        elif KDA in self.layer_types:
+            raise ValueError(
+                "a Kimi Delta Attention hybrid's attention layers are latent "
+                "attention (kv_lora_rank): K and V per head are not described")
         elif self.n_routed_experts and not {GDN, WINDOW} & set(self.layer_types):
             raise ValueError(
                 "routed experts are described for the latent-attention stack "
@@ -197,20 +227,47 @@ class ModelConfig:
                     "scores with greedy selection are described"
                 )
             if (self.n_group, self.topk_group) != (1, 1):
-                raise ValueError("group-limited routing (n_group > 1) is not described")
+                scored = self.experts_scored
+                if (self.scoring_func, self.topk_method) != ("sigmoid", "noaux_tc"):
+                    raise ValueError(
+                        "group-limited routing (n_group > 1) is described for sigmoid "
+                        "scores with noaux_tc selection alone")
+                if not (1 <= self.topk_group <= self.n_group and scored % self.n_group == 0
+                        and scored // self.n_group >= 2
+                        and self.n_experts_per_tok <= self.topk_group * (scored // self.n_group)):
+                    raise ValueError(
+                        f"group-limited routing: {self.n_group} groups of the {scored} "
+                        f"experts scored, {self.topk_group} kept, "
+                        f"{self.n_experts_per_tok} a token do not fit (a group's score "
+                        "is the sum of its two largest)")
             if not 0 <= self.expert_first <= self.experts_scored - self.n_routed_experts:
                 raise ValueError(
                     f"experts held [{self.expert_first}, "
                     f"{self.expert_first + self.n_routed_experts}) are not among "
                     f"the {self.experts_scored} the gate scores"
                 )
-            if self.layer_types and self.first_k_dense:
-                raise ValueError("a hybrid stack's expert block is every layer's FFN")
+            if self.layer_types and self.first_k_dense and KDA not in self.layer_types:
+                raise ValueError(
+                    "a hybrid stack's expert block is every layer's FFN (leading dense "
+                    'layers are described for layer_types with "kda" alone)')
+            for name, limits in (("expert_swiglu_limits", self.expert_swiglu_limits),
+                                 ("shared_expert_swiglu_limits",
+                                  self.shared_expert_swiglu_limits)):
+                if limits and len(limits) != self.n_moe_layers:
+                    raise ValueError(
+                        f"{name} names {len(limits)} layers, the expert layers held "
+                        f"are {self.n_moe_layers}")
+                if any(limits):
+                    raise ValueError(
+                        f"{name} {limits}: a nonzero swiglu limit in a held layer is "
+                        "not described (the published configuration gives the limit "
+                        "and not the clamp's form: no guessed clamp is served)")
         elif (self.n_experts_total or self.expert_first or self.shared_expert_gate
+              or self.expert_swiglu_limits or self.shared_expert_swiglu_limits
               or self.shared_expert_combine != "sum"):
             raise ValueError(
-                "n_experts_total, expert_first, shared_expert_gate and "
-                "shared_expert_combine belong to routed experts (n_routed_experts)"
+                "n_experts_total, expert_first, shared_expert_gate, the swiglu limits "
+                "and shared_expert_combine belong to routed experts (n_routed_experts)"
             )
         if self.shared_expert_combine not in ("sum", "average"):
             raise ValueError(f"unknown shared_expert_combine {self.shared_expert_combine!r}")
@@ -230,7 +287,9 @@ class ModelConfig:
                     "dense decoder: leave it empty"
                 )
             if len(kinds) > 1:
-                raise ValueError("mamba and gdn layers in one stack are not described")
+                raise ValueError(
+                    "mamba, gdn and kda layers in one stack are not described: "
+                    "one recurrent kind a stack")
             if WINDOW in self.layer_types:
                 if kinds:
                     raise ValueError(
@@ -253,6 +312,13 @@ class ModelConfig:
                     raise ValueError("gdn layers need gdn_n_k_heads/n_v_heads/d_k/d_v")
                 if self.gdn_n_v_heads % self.gdn_n_k_heads:
                     raise ValueError("gdn_n_k_heads must divide gdn_n_v_heads")
+                if KDA in kinds and (self.gdn_n_k_heads != self.gdn_n_v_heads
+                                     or not self.kda_lower_bound < 0
+                                     or self.gdn_chunk_size % self.kda_sub_block):
+                    raise ValueError(
+                        "kda layers need as many key heads as value heads (a decay a "
+                        "key channel of its own head), kda_lower_bound < 0 and "
+                        "kda_sub_block dividing gdn_chunk_size")
                 if not self.n_routed_experts:
                     raise ValueError(
                         "a Gated DeltaNet hybrid's FFN is the expert block "
@@ -284,14 +350,15 @@ class ModelConfig:
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if GDN not in self.layer_types and (
-            self.qk_norm or self.attn_output_gate
+            self.qk_norm or (self.attn_output_gate and KDA not in self.layer_types)
             or self.norm_plus_one or self.partial_rotary_factor != 1.0
             or (self.attn_head_dim and WINDOW not in self.layer_types)
         ):
             raise ValueError(
                 "attn_head_dim, qk_norm, attn_output_gate, norm_plus_one and "
                 'partial_rotary_factor belong to the Gated DeltaNet hybrid '
-                '(layer_types with "gdn"; attn_head_dim to the window stack too)'
+                '(layer_types with "gdn"; attn_head_dim to the window stack too, '
+                'attn_output_gate to layer_types with "kda" too)'
             )
         if self.rotary_dim % 2:
             raise ValueError("the rotated part of a head must be even (rotary pairs)")
@@ -365,11 +432,19 @@ class ModelConfig:
 
     @property
     def gdn(self) -> bool:
-        """Are the recurrent layers Gated DeltaNet (else Mamba-2)?"""
-        return GDN in self.layer_types
+        """Are the recurrent layers a gated delta rule (Gated DeltaNet, or
+        Kimi Delta Attention: ``kda``), else Mamba-2?"""
+        return any(t in DELTA_RULE_KINDS for t in self.layer_types)
+
+    @property
+    def kda(self) -> bool:
+        """Does the delta rule forget by key CHANNEL (Kimi Delta Attention)?"""
+        return KDA in self.layer_types
 
     @property
     def recurrent_kind(self) -> str:
+        if self.kda:
+            return "Kimi Delta Attention"
         return "Gated DeltaNet" if self.gdn else "Mamba-2"
 
     @property
@@ -431,6 +506,24 @@ class ModelConfig:
         return types
 
     @property
+    def stack_plan(self) -> tuple[int, tuple[str, ...]]:
+        """(layers unrolled at the head of the stack, the period the rest
+        repeats): the leading dense layers are unrolled, and as many more as
+        leave the FEWEST layers to trace (head + period).  Without leading
+        dense layers: ``(0, layer_period)``."""
+        types = self.layer_types
+        if not (self.moe and self.first_k_dense):
+            return 0, self.layer_period
+        best = None
+        for head in range(self.first_k_dense, len(types)):
+            rest = types[head:]
+            period = next(rest[:p] for p in range(1, len(rest) + 1)
+                          if len(rest) % p == 0 and rest == rest[:p] * (len(rest) // p))
+            if best is None or head + len(period) < best[0] + len(best[1]):
+                best = (head, period)
+        return best
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_n_heads * self.mamba_d_head
 
@@ -458,7 +551,11 @@ class ModelConfig:
 
     @property
     def gdn_d_in_proj(self) -> int:
-        """Width of the fused input projection: q | k | v | z | b | a."""
+        """Width of the fused input projection: q | k | v | z | b | a; of a
+        Kimi Delta Attention layer q | k | v | z | b with ONE z a head (the
+        decay's ``a``, one a key channel, is a matrix of its own: ``w_alpha``)."""
+        if self.kda:
+            return self.gdn_conv_dim + 2 * self.gdn_n_v_heads
         return self.gdn_conv_dim + self.gdn_value_dim + 2 * self.gdn_n_v_heads
 
     def recurrent_state_shapes(self, rows: int) -> tuple[tuple[int, ...], tuple[int, ...]]:
@@ -487,6 +584,30 @@ class ModelConfig:
     def param_count(self) -> int:
         """Approximate parameter count (for memory planning)."""
         embed = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
+        if self.kda:
+            H, r, Hv = self.n_heads, self.kv_lora_rank, self.gdn_n_v_heads
+            mla = (
+                self.d_model * H * self.head_dim  # W_q
+                + self.d_model * (r + self.qk_rope_head_dim) + r  # W_kva, its norm
+                + r * H * (self.qk_nope_head_dim + self.v_head_dim)  # W_kvb
+                + H * self.v_head_dim * self.d_model  # W_o
+                + (self.d_model * H if self.attn_output_gate else 0)  # W_z
+            )
+            kda = (
+                self.d_model * self.gdn_d_in_proj + self.d_model * self.gdn_key_dim  # W_alpha
+                + self.gdn_value_dim * self.d_model + self.gdn_conv_dim * self.gdn_d_conv
+                + Hv + self.gdn_key_dim + self.gdn_d_v  # A_log, dt_bias, the gated norm
+            )
+            expert = 3 * self.d_model * self.moe_d_ff
+            moe = (
+                self.d_model * self.experts_scored + self.experts_scored  # gate, its bias
+                + (self.n_routed_experts + self.n_shared_experts) * expert
+            )
+            return (
+                embed + self.d_model + 2 * self.n_layers * self.d_model
+                + self.n_kv_layers * mla + self.n_recurrent_layers * kda
+                + self.n_dense_layers * 3 * self.d_model * self.d_ff + self.n_moe_layers * moe
+            )
         if self.latent:
             H, r = self.n_heads, self.kv_lora_rank
             attention = (
@@ -982,6 +1103,89 @@ PRESETS: dict[str, ModelConfig] = {
         scoring_func="sigmoid",
         topk_method="greedy",
         shared_expert_combine="average",
+    ),
+    # Ling-3.0-flash-VL's language decoder (HF: inclusionAI/Ling-3.0-flash-VL,
+    # bailing_hybrid): 35 Kimi Delta Attention layers and 7 latent-attention
+    # layers (layer_group_size 6: K K K K K M), two leading dense layers, then
+    # 512 sigmoid-routed experts chosen by group (8 groups, 4 kept, 8 a token)
+    # and one shared expert.  Text only: the tower is not described here, nor
+    # the extra prediction layer.  All 512 experts: what one device holds is a
+    # share (n_routed_experts / expert_first).  The published
+    # expert_swiglu_limit_list is nonzero from layer 34 on and the clamp's form
+    # is unpublished: this preset carries no limits (random weights); the loader
+    # hands a checkpoint's lists over (expert_swiglu_limits) and a nonzero one
+    # in a held layer is refused there with that reason.
+    "ling-3.0-flash-vl": ModelConfig(
+        name="ling-3.0-flash-vl",
+        vocab_size=157184,
+        d_model=2560,
+        n_layers=42,
+        n_heads=32,
+        n_kv_heads=32,
+        d_ff=6144,
+        rope_theta=6000000.0,
+        norm_eps=1e-6,
+        max_seq_len=131072,
+        layer_types=((KDA,) * 5 + (ATTENTION,)) * 7,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        attn_output_gate=True,
+        gdn_n_k_heads=32,
+        gdn_n_v_heads=32,
+        gdn_d_k=128,
+        gdn_d_v=128,
+        gdn_d_conv=4,
+        kda_lower_bound=-5.0,
+        n_routed_experts=512,
+        n_experts_per_tok=8,
+        n_shared_experts=1,
+        moe_d_ff=768,
+        first_k_dense=2,
+        routed_scaling_factor=2.5,
+        n_group=8,
+        topk_group=4,
+    ),
+    # the same kind at toy size, for the tests: two periods K K M
+    # (layer_group_size 3), the first layer's FFN dense (so the first period
+    # is the stack's unrolled head, the other its scan); 16 experts scored
+    # in 4 groups of which 2 are kept, this device holding group 1 (share 1 of 4)
+    "debug-kda-mla-moe": ModelConfig(
+        name="debug-kda-mla-moe",
+        vocab_size=128,
+        d_model=32,
+        n_layers=6,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=48,
+        rope_theta=6000000.0,
+        norm_eps=1e-6,
+        max_seq_len=256,
+        dtype="float32",
+        layer_types=(KDA, KDA, ATTENTION) * 2,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=4,
+        v_head_dim=8,
+        attn_output_gate=True,
+        gdn_n_k_heads=4,
+        gdn_n_v_heads=4,
+        gdn_d_k=8,
+        gdn_d_v=8,
+        gdn_d_conv=4,
+        gdn_chunk_size=8,
+        kda_sub_block=4,
+        n_routed_experts=4,
+        n_experts_total=16,
+        expert_first=4,
+        n_experts_per_tok=3,
+        n_shared_experts=1,
+        moe_d_ff=16,
+        first_k_dense=1,
+        routed_scaling_factor=2.5,
+        n_group=4,
+        topk_group=2,
     ),
 }
 

@@ -127,7 +127,8 @@ _LOCAL_FIELDS = (
     "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
     "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
     *CHUNK_ATTN_FIELDS, "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
-    "programs_built", "moe_assignments", "moe_assignments_absent", "moe_expert_tokens_max",
+    "programs_built", "moe_assignments", "moe_assignments_absent",
+    "moe_rows_in_held_groups", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
     "moe_dense_chunks", *_SECONDS_FIELDS,
 )
@@ -360,6 +361,13 @@ def _engine_metrics(
             "token-expert pairs of REAL tokens whose expert another device "
             "holds (experts held by share: the gate chose it, this device "
             "left its part out); 0 where every expert is held",
+        ),
+        moe_rows_in_held_groups=reg.counter(
+            "calfkit_engine_moe_rows_in_held_groups_total",
+            "REAL tokens, summed over expert layers, one of whose kept routing "
+            "groups lies among the experts held here (a gate that chooses by "
+            "group: the rows an exchange would send this device); 0 for a "
+            "gate without groups",
         ),
         moe_expert_tokens_max=reg.counter(
             "calfkit_engine_moe_expert_tokens_max_total",
@@ -816,6 +824,7 @@ class EngineStats:
     # latent (MLA) page pool.
     moe_assignments: int = 0
     moe_assignments_absent: int = 0
+    moe_rows_in_held_groups: int = 0  # a gate that chooses by group (moe.kept_groups)
     moe_expert_tokens_max: int = 0
     moe_expert_tokens_mean: float = 0.0
     moe_experts_hit: int = 0
@@ -4179,15 +4188,18 @@ class InferenceEngine:
         engine: a dispatch in flight holds the arrays donated."""
         return self._state
 
-    def _note_moe(self, counts: Any, hit: Any, absent: Any = 0, decode: bool = False) -> None:
+    def _note_moe(self, counts: Any, hit: Any, absent: Any = 0, in_held_groups: Any = 0,
+                  decode: bool = False) -> None:
         """Fold one dispatch's expert counters (already on their way to the
         host with what the landing syncs) into the stats; ``absent`` is
-        there where the experts are held by share."""
+        there where the experts are held by share, ``in_held_groups`` where
+        the gate chooses by group."""
         counts = np.asarray(counts)  # blocking-ok: computed before the sync that just landed
         self._moe_counts += counts
         stats = self.stats
         stats.moe_assignments += int(counts.sum())
         stats.moe_assignments_absent += int(absent)
+        stats.moe_rows_in_held_groups += int(in_held_groups)
         stats.moe_expert_tokens_max += int(counts.max(axis=1).sum())
         stats.moe_expert_tokens_mean += float(counts.mean(axis=1).sum())
         if decode:

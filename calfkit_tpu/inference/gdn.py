@@ -43,6 +43,31 @@ State (per slot, the pair the engine carries for Mamba-2 too): S [Lg, B, Hv,
 d_k, d_v] in ``config.state_dtype``; conv [Lg, d_conv - 1, B, C] in the
 activations' type, oldest input first.
 
+**Kimi Delta Attention** (``config.kda``; Kimi Linear, arXiv:2510.26692, as
+``bailing_hybrid`` selects it) is the same rule with the decay INSIDE the
+products: one log-decay a key CHANNEL, ``g`` [.., H, d_k],
+
+    S <- Diag(exp(g_t)) S;  u = S^T k_t;  S <- S + k_t (x) (beta_t (v_t - u));  o_t = S^T q_t
+
+with ``g = kda_lower_bound sigmoid(exp(A_log[head]) (a + dt_bias))`` in
+``[kda_lower_bound, 0)`` (the bounded gate), ``a = h W_alpha`` ONE full matrix
+(``w_alpha`` [Lg, H d_k, D], under the scope ``decay``), ``dt_bias`` [Lg, H,
+d_k], as many key heads as value heads, and ONE output gate a head:
+``rmsnorm(o) w sigmoid(z[head])``, ``z`` [.., H] (so ``w_in`` is q | k | v | z |
+b, ``C + 2 H`` wide).  The step is a scale by key ROW where it was a scale by
+head (:func:`delta_step_xla` takes either ``g``).  The chunk form
+(:func:`delta_chunks_by_channel`) is two-level: the weight of the pair ``(i,
+j)`` is ``sum_c k_ic k_jc exp(G_ic - G_jc)`` (``G`` the block's running sum of
+``g``), which is a matrix product only as ``(k_i exp(G_i - R)) . (k_j exp(R -
+G_j))`` for some reference ``R``, and ``exp(R - G_j)`` overflows float32 over
+a block of 64 at ``g`` down to -5 a position (e^320).  So ``R`` is the running
+sum at the FIRST position of ``i``'s sub-block of ``kda_sub_block`` (16)
+positions: for ``j`` in an earlier sub-block ``R - G_j <= 0`` is an exact
+decay, for ``j`` in the same one it is at most 15 x 5 = 75 (e^75 = 3.7e32 <
+3.4e38), and a later ``j`` is masked BEFORE the exponential.  One product a
+sub-block against the block's keys as that sub-block sees them: the FLOPs of
+the one-level form.
+
 Everything after the input projection is float32 arithmetic (the conv, the
 norms, the decays, both products with ``S``, the triangular solve, the gated
 norm); the two big matmuls take and give the activations' type.
@@ -81,7 +106,7 @@ def init_gdn_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
         jax.random.uniform(keys[3], (Lg, Hv), jnp.float32)
         * (math.log(0.1) - math.log(0.001)) + math.log(0.001)
     )
-    return {
+    out = {
         "w_in": normal(keys[0], (Lg, c.gdn_d_in_proj, D), D),
         "conv_w": normal(keys[1], (Lg, c.gdn_d_conv, c.gdn_conv_dim), c.gdn_d_conv),
         "A_log": jnp.log(jax.random.uniform(keys[2], (Lg, Hv), jnp.float32, 1e-3, 16.0)),
@@ -90,6 +115,16 @@ def init_gdn_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
         "w_out": normal(keys[4], (Lg, c.gdn_value_dim, D), c.gdn_value_dim),
         "mixer_norm": jnp.zeros((Lg, D), dtype) if c.norm_plus_one else jnp.ones((Lg, D), dtype),
     }
+    if c.kda:
+        # the bounded gate's argument exp(A_log) (a + dt_bias): A in 0.25-4 and a
+        # bias a CHANNEL in +-2 beside a's unit spread, so that sigmoid leaves
+        # neither end and the channels of one head forget at different rates
+        out["w_alpha"] = normal(jax.random.fold_in(key, 5), (Lg, c.gdn_key_dim, D), D)
+        out["A_log"] = jax.random.uniform(
+            keys[2], (Lg, Hv), jnp.float32, math.log(0.25), math.log(4.0))
+        out["dt_bias"] = jax.random.uniform(
+            jax.random.fold_in(key, 6), (Lg, Hv, c.gdn_d_k), jnp.float32, -2.0, 2.0)
+    return out
 
 
 def _in_proj(h: jax.Array, lp: Params, c: ModelConfig):
@@ -97,8 +132,21 @@ def _in_proj(h: jax.Array, lp: Params, c: ModelConfig):
     with jax.named_scope("in_proj"):
         out = jnp.einsum("...d,ed->...e", h, lp["w_in"])
         C, dv, Hv = c.gdn_conv_dim, c.gdn_value_dim, c.gdn_n_v_heads
+        if c.kda:  # z is ONE gate a head; a is w_alpha's product (_decay)
+            return out[..., :C], out[..., C:C + Hv], out[..., C + Hv:], None
         return (out[..., :C], out[..., C:C + dv], out[..., C + dv:C + dv + Hv],
                 out[..., C + dv + Hv:])
+
+
+def _decay(h: jax.Array, lp: Params, c: ModelConfig) -> jax.Array:
+    """Kimi Delta Attention's log-decay, one a key channel: [.., D] -> g [..,
+    H, d_k] float32 in ``[kda_lower_bound, 0)``."""
+    with jax.named_scope("decay"):
+        a = jnp.einsum("...d,ed->...e", h, lp["w_alpha"], preferred_element_type=jnp.float32)
+        a = a.reshape(*a.shape[:-1], c.gdn_n_v_heads, c.gdn_d_k)
+        rate = jnp.exp(lp["A_log"].astype(jnp.float32))[:, None]
+        return c.kda_lower_bound * jax.nn.sigmoid(
+            rate * (a + lp["dt_bias"].astype(jnp.float32)))
 
 
 def _l2(x: jax.Array) -> jax.Array:
@@ -108,7 +156,8 @@ def _l2(x: jax.Array) -> jax.Array:
 def _heads(qkv_act: jax.Array, b: jax.Array, a: jax.Array, lp: Params, c: ModelConfig):
     """Activated conv output [.., C] float32 -> q, k [.., Hv, d_k] (normalised,
     ``q`` over sqrt(d_k), a key head repeated for its value heads), v [.., Hv,
-    d_v], beta, g [.., Hv] float32."""
+    d_v], beta, g [.., Hv] float32.  ``a`` of a Kimi Delta Attention layer IS
+    its ``g`` [.., H, d_k] (:func:`_decay`)."""
     Hk, Hv, dk, dv = c.gdn_n_k_heads, c.gdn_n_v_heads, c.gdn_d_k, c.gdn_d_v
     lead = qkv_act.shape[:-1]
     kd = c.gdn_key_dim
@@ -118,18 +167,24 @@ def _heads(qkv_act: jax.Array, b: jax.Array, a: jax.Array, lp: Params, c: ModelC
     k = jnp.repeat(k, Hv // Hk, axis=-2)
     v = qkv_act[..., 2 * kd:].reshape(*lead, Hv, dv)
     beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    if c.kda:
+        return q, k, v, beta, a
     g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         a.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
     return q, k, v, beta, g
 
 
 def _gate_out(o: jax.Array, z: jax.Array, lp: Params, c: ModelConfig, out_dtype: Any):
-    """rmsnorm(o) * w * silu(z) per value head, then the output projection."""
+    """rmsnorm(o) * w * silu(z) per value head (Kimi Delta Attention: times
+    sigmoid(z), ONE z a head), then the output projection."""
     with jax.named_scope("gate_norm"):
         var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
         y = o * lax.rsqrt(var + c.norm_eps) * lp["norm"].astype(jnp.float32)
-        zh = z.astype(jnp.float32).reshape(o.shape)
-        y = (y * jax.nn.silu(zh)).reshape(*o.shape[:-2], c.gdn_value_dim).astype(out_dtype)
+        if c.kda:
+            gate = jax.nn.sigmoid(z.astype(jnp.float32))[..., None]
+        else:
+            gate = jax.nn.silu(z.astype(jnp.float32).reshape(o.shape))
+        y = (y * gate).reshape(*o.shape[:-2], c.gdn_value_dim).astype(out_dtype)
     with jax.named_scope("out_proj"):
         return jnp.einsum("...e,ed->...d", y, lp["w_out"])
 
@@ -141,14 +196,16 @@ def delta_step_xla(
     k: jax.Array,  # [B, Hv, d_k]
     v: jax.Array,  # [B, Hv, d_v]
     beta: jax.Array,  # [B, Hv]
-    g: jax.Array,  # [B, Hv] log decay, <= 0
+    g: jax.Array,  # [B, Hv] log decay, <= 0; or one a key channel [B, Hv, d_k]
     active: jax.Array | None,  # [B] bool; None: every row advances
 ) -> tuple[jax.Array, jax.Array]:
     """The decode step's pass over layer ``im``'s ``S``, in XLA -> (o [B,
     Hv, d_v] float32, the state): one reduction reads ``S`` for both
     products, one fusion reads it again and writes the update in place."""
     S_old = _layer_of(all_S, im)
-    S = S_old.astype(jnp.float32) * jnp.exp(g)[..., None, None]
+    by_channel = g.ndim == 3  # the decay scales S by key ROW, not by head
+    S = S_old.astype(jnp.float32) * (
+        jnp.exp(g)[..., None] if by_channel else jnp.exp(g)[..., None, None])
     both = jnp.einsum("bhkv,bhkj->bhjv", S, jnp.stack([k, q], axis=-1), precision=_HI)
     delta = (v - both[:, :, 0]) * beta[..., None]
     o = both[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * delta
@@ -173,6 +230,8 @@ def gdn_step(
     c = config
     all_S, all_conv = state
     qkv, z, b, a = _in_proj(h[:, 0], lp, c)
+    if c.kda:
+        a = _decay(h[:, 0], lp, c)
     with jax.named_scope("conv"):
         conv = _layer_of(all_conv, im)
         window = jnp.concatenate([conv, qkv[None].astype(conv.dtype)], axis=0)  # [d_conv, B, C]
@@ -237,6 +296,76 @@ def delta_chunks(
     return o.reshape(B, T, H, -1), S
 
 
+def delta_chunks_by_channel(
+    q: jax.Array,  # [B, T, H, d_k] float32, normalised and scaled
+    k: jax.Array,  # [B, T, H, d_k]
+    v: jax.Array,  # [B, T, H, d_v]
+    beta: jax.Array,  # [B, T, H], zero at padding
+    g: jax.Array,  # [B, T, H, d_k] log decay a key channel, zero at padding
+    S0: jax.Array,  # [B, H, d_k, d_v] float32
+    block: int,
+    sub: int,  # positions of a sub-block: sub x max |g| stays under float32's e^88
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`delta_chunks` with the decay a key CHANNEL (Kimi Delta
+    Attention): the two-level form of the module's text -> (o [B, T, H, d_v],
+    S_T).  ``T`` is padded to whole sub-blocks (to whole blocks past one
+    block) with positions that move nothing."""
+    B, T, H, dk = q.shape
+    unit = block if T > block else sub
+    Tp = -(-T // unit) * unit
+    if Tp != T:
+        pad = lambda x: jnp.pad(x, ((0, 0), (0, Tp - T)) + ((0, 0),) * (x.ndim - 2))  # noqa: E731
+        q, k, v, beta, g = pad(q), pad(k), pad(v), pad(beta), pad(g)
+    Q = min(block, Tp)
+    nc, ns = Tp // Q, Q // sub
+
+    def blocks(x):  # [B, Tp, H, ..] -> [nc, B, H, Q, ..]: a block's faces last
+        x = x.reshape(B, nc, Q, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, beta, g = blocks(q), blocks(k), blocks(v), blocks(beta), blocks(g)
+    gc = jnp.cumsum(g, axis=-2)  # [nc, B, H, Q, dk] inclusive: decay from the block's start
+    i, j = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
+    # R: the running sum at the first position of each sub-block [.., ns, dk]
+    ref = gc[..., ::sub, :]
+    # a position against its own sub-block's reference: exponent in (-sub |g|, 0]
+    to_ref = jnp.exp(gc - jnp.repeat(ref, sub, axis=-2))
+    # the block's keys as sub-block a sees them: exact decays for the earlier
+    # ones, at most e^((sub - 1) |g|) inside a, nothing of a later sub-block
+    seen = (jnp.arange(Q) // sub)[None, :] <= jnp.arange(ns)[:, None]  # [ns, Q]
+    from_ref = jnp.exp(jnp.where(
+        seen[..., None], ref[..., :, None, :] - gc[..., None, :, :], -jnp.inf))
+    k_seen = k[..., None, :, :] * from_ref  # [nc, B, H, ns, Q, dk]
+
+    def pairs(x):  # [.., Q, dk] -> sum_c x_ic k_jc exp(G_ic - G_jc) [.., Q, Q]
+        x = (x * to_ref).reshape(*x.shape[:-2], ns, sub, dk)
+        return jnp.einsum("cbhask,cbhajk->cbhasj", x, k_seen, precision=_HI).reshape(
+            *x.shape[:-3], Q, Q)
+
+    k_beta = k * beta[..., None]
+    A = jnp.where(i > j, pairs(k_beta), 0.0) + jnp.eye(Q)
+    into = jnp.exp(gc)  # a position against the block's start: S_in's decay
+    rhs = jnp.concatenate([v * beta[..., None], k_beta * into], axis=-1)
+    solved = lax.linalg.triangular_solve(
+        A, rhs, left_side=True, lower=True, unit_diagonal=True)
+    value, k_cum = solved[..., : v.shape[-1]], solved[..., v.shape[-1]:]
+    local = jnp.where(i >= j, pairs(q), 0.0)
+    k_end = k * jnp.exp(gc[..., -1:, :] - gc)  # a key as the block's end sees it
+
+    def over_blocks(S, inputs):
+        q_in, k_end_c, value_c, k_cum_c, local_c, end_c = inputs
+        u = value_c - jnp.einsum("bhik,bhkv->bhiv", k_cum_c, S, precision=_HI)
+        o = (jnp.einsum("bhik,bhkv->bhiv", q_in, S, precision=_HI)
+             + jnp.einsum("bhij,bhjv->bhiv", local_c, u, precision=_HI))
+        S = S * end_c[..., None] + jnp.einsum("bhik,bhiv->bhkv", k_end_c, u, precision=_HI)
+        return S, o
+
+    S, o = lax.scan(
+        over_blocks, S0, (q * into, k_end, value, k_cum, local, jnp.exp(gc[..., -1, :])))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)  # [B, nc, Q, H, d_v]
+    return o.reshape(B, Tp, H, -1)[:, :T], S
+
+
 def gdn_chunk(
     h: jax.Array,  # [B, T, D] the normed stream
     lp: Params,
@@ -252,6 +381,8 @@ def gdn_chunk(
     K = c.gdn_d_conv
     all_S, all_conv = state
     qkv, z, b, a = _in_proj(h, lp, c)
+    if c.kda:
+        a = _decay(h, lp, c)
     with jax.named_scope("conv"):
         conv = _layer_of(all_conv, im)
         ext = jnp.concatenate([jnp.swapaxes(conv, 0, 1), qkv.astype(conv.dtype)], axis=1)
@@ -268,7 +399,14 @@ def gdn_chunk(
         S_old = _layer_of(all_S, im)
         q, k, v, beta, g = _heads(act, b, a, lp, c)
         own = (jnp.arange(T, dtype=jnp.int32)[None, :] < n_valid[:, None])[..., None]
-        beta, g = jnp.where(own, beta, 0.0), jnp.where(own, g, 0.0)
-        o, S = delta_chunks(q, k, v, beta, g, S_old.astype(jnp.float32), c.gdn_chunk_size)
+        beta = jnp.where(own, beta, 0.0)
+        if c.kda:
+            o, S = delta_chunks_by_channel(
+                q, k, v, beta, jnp.where(own[..., None], g, 0.0), S_old.astype(jnp.float32),
+                c.gdn_chunk_size, c.kda_sub_block)
+        else:
+            o, S = delta_chunks(
+                q, k, v, beta, jnp.where(own, g, 0.0), S_old.astype(jnp.float32),
+                c.gdn_chunk_size)
         all_S = lax.dynamic_update_index_in_dim(all_S, S.astype(S_old.dtype), im, 0)
     return _gate_out(o, z, lp, c, h.dtype), (all_S, all_conv)

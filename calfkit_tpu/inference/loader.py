@@ -85,12 +85,35 @@ part (``rope_interleave``) and ``model.apply_rope`` pairs the two halves:
 the rope columns of ``W_q`` (each head's last ``dr``) and of ``W_kva`` (its
 last ``dr``) are permuted ONCE here, evens first, so that the same rotation
 gives the same scores.
+``model_type`` ``bailing_hybrid`` (Ling-3.0-flash, and Ling-3.0-flash-VL's
+text decoder: tensors outside ``model.`` and ``lm_head.``, a tower and its
+projector, skipped and counted with one :class:`VisionTowerSkipped` notice;
+the extra prediction layer ``model.layers.<num_hidden_layers>`` skipped and
+counted with one :class:`MtpSkipped` notice) loads into the Kimi Delta
+Attention hybrid's tree.  The names are taken as the family's earlier
+checkpoints have them (``model.word_embeddings``, ``attention.dense``,
+``mlp.gate.expert_bias``), the delta rule's as Kimi Linear's without its
+low-rank pairs (``no_kda_lora``), the latent layers' as DeepseekV3's:
+    attention.{q,k,v}_proj, g_proj [H, D], b_proj [H, D] (a KDA layer)
+                                                  → layers.gdn.w_in, ONE matrix q | k | v | z | b
+    attention.{q,k,v}_conv1d.weight [H d, 1, taps] → conv_w [taps, C]
+    attention.f_proj.weight [H dk, D]             → w_alpha (the decay's one full matrix)
+    attention.{A_log [H], dt_bias [H dk]}         → float32 leaves [H], [H, dk]
+    attention.o_norm.weight, dense.weight         → norm, w_out (transposed)
+    attention.q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, dense (a latent layer)
+                                                  → layers.attn.* as DeepseekV3's, the rope
+        dimensions from interleaved pairs to halves (``rope_interleave``)
+    attention.g_proj.weight [H, D] (a latent layer) → layers.attn.w_z [D, H]
+    mlp.gate.weight [E, D], mlp.gate.expert_bias  → layers.moe.router [D, E], router_bias (ALL the experts)
+    mlp.experts.{e}.*, mlp.shared_experts.*       → the HELD experts' stacks, the shared expert
+    mlp.{gate,up,down}_proj (the leading layers)  → layers.dense.*
 """
 
 from __future__ import annotations
 
 import json
 import logging
+import re
 from pathlib import Path
 from typing import Any
 
@@ -125,8 +148,11 @@ def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> 
         return _qwen3_next_config(raw, str(path), share)
     if raw.get("model_type") == "cohere2_moe":
         return _cohere2_moe_config(raw.get("text_config", raw), str(path), share)
+    if raw.get("model_type") == "bailing_hybrid":
+        return _bailing_hybrid_config(raw.get("text_config", raw), str(path), share)
     if share is not None:
-        raise ValueError(f"{path}: a share is described for qwen3_next and cohere2_moe alone")
+        raise ValueError(
+            f"{path}: a share is described for qwen3_next, cohere2_moe and bailing_hybrid alone")
     if raw.get("model_type") == "granitemoehybrid":
         return _granite_hybrid_config(raw, str(path))
     if raw.get("model_type") == "kimi_vl":
@@ -323,6 +349,70 @@ def _cohere2_moe_config(raw: dict, path: str, share: "tuple[int, int] | None") -
     )
 
 
+def _bailing_hybrid_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
+    """``bailing_hybrid``'s ``config.json`` (Ling-3.0-flash and its -VL's text
+    decoder) -> the Kimi Delta Attention hybrid's description.  A key that
+    selects a variant is held to the ONE reading described (module text); the
+    two swiglu limit lists go into the description, which refuses a nonzero
+    entry by name."""
+    from calfkit_tpu.inference.config import ATTENTION, KDA
+
+    for key, only in (("q_lora_rank", None), ("score_function", "sigmoid"),
+                      ("moe_router_enable_expert_bias", True), ("use_mla_nope", False),
+                      ("use_nGPT", False), ("scale_router_input", False), ("value_norm", False),
+                      ("up_proj_norm", False), ("group_norm_size", 1), ("linear_silu", True),
+                      ("no_kda_lora", True), ("use_kda_lora", False), ("kda_safe_gate", True),
+                      ("gated_attention_proj_granularity_type", "head_wise"),
+                      ("num_kv_heads_for_linear_attn", 0), ("rope_scaling", None)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    E, V, L = raw["num_experts"], raw["vocab_size"], raw["num_hidden_layers"]
+    rank, of = share or (0, 1)
+    if not 0 <= rank < of or E % of or V % of:
+        raise ValueError(f"{path}: share {share} does not divide {E} experts and {V} rows")
+    every, nd = raw.get("layer_group_size", 6), raw.get("first_k_dense_replace", 0)
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=V // of,
+        d_model=raw["hidden_size"],
+        n_layers=L,
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw.get("num_key_value_heads", raw["num_attention_heads"]),
+        d_ff=raw["intermediate_size"],
+        rope_theta=float(raw.get("rope_theta", 10000.0)),
+        norm_eps=float(raw.get("rms_norm_eps", 1e-6)),
+        kv_norm_eps=float(raw.get("rms_norm_eps", 1e-6)),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=raw.get("tie_word_embeddings", False),
+        layer_types=tuple(ATTENTION if (i + 1) % every == 0 else KDA for i in range(L)),
+        kv_lora_rank=raw["kv_lora_rank"],
+        qk_nope_head_dim=raw["qk_nope_head_dim"],
+        qk_rope_head_dim=raw["qk_rope_head_dim"],
+        v_head_dim=raw["v_head_dim"],
+        attn_output_gate=True,
+        gdn_n_k_heads=raw["num_attention_heads"], gdn_n_v_heads=raw["num_attention_heads"],
+        gdn_d_k=raw["head_dim"], gdn_d_v=raw["head_dim"],
+        gdn_d_conv=raw.get("short_conv_kernel_size", 4),
+        kda_lower_bound=float(raw.get("kda_lower_bound", -5.0)),
+        n_routed_experts=E // of,
+        n_experts_total=E if of > 1 else 0,
+        expert_first=rank * (E // of),
+        n_experts_per_tok=raw["num_experts_per_tok"],
+        n_shared_experts=(raw.get("moe_shared_expert_intermediate_size", 0)
+                          // raw["moe_intermediate_size"]),
+        moe_d_ff=raw["moe_intermediate_size"],
+        first_k_dense=nd,
+        routed_scaling_factor=float(raw.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func="sigmoid", topk_method="noaux_tc",
+        n_group=raw.get("n_group", 1), topk_group=raw.get("topk_group", 1),
+        expert_swiglu_limits=tuple(
+            float(v) for v in raw.get("expert_swiglu_limit_list", [])[nd:L]),
+        shared_expert_swiglu_limits=tuple(
+            float(v) for v in raw.get("share_expert_swiglu_limit_list", [])[nd:L]),
+    )
+
+
 def _open_safetensors(path: Path) -> dict[str, Any]:
     """name -> lazy tensor getter across all shards."""
     from safetensors import safe_open  # ships with transformers
@@ -382,6 +472,20 @@ def load_params(
             ), stacklevel=2)
 
     mtp = sum(1 for name in files if name.startswith(_MTP_PREFIX))
+    if config.kda:
+        # bailing_hybrid: the extra prediction layer follows the stack as
+        # model.layers.<num_hidden_layers>..., the tower lies outside model. / lm_head.
+        layer_of = re.compile(r"^model\.layers\.(\d+)\.")
+        mtp += sum(1 for name in files
+                   if (m := layer_of.match(name)) and int(m.group(1)) >= config.n_layers)
+        tower = sum(1 for name in files if not name.startswith(("model.", "lm_head.")))
+        if tower:
+            import warnings
+
+            warnings.warn(VisionTowerSkipped(
+                f"{path}: {tower} tensors outside 'model.' and 'lm_head.' (a vision tower "
+                "and its projector) were not loaded: the language decoder serves text alone"
+            ), stacklevel=2)
     if mtp:
         import warnings
 
@@ -413,6 +517,10 @@ def _build_params(
 
     D, H, K, hd = config.d_model, config.n_heads, config.n_kv_heads, config.head_dim
     L = config.n_layers
+    if config.kda:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with Kimi Delta Attention layers")
+        return _build_kda_params(config, shardings, get)
     if config.gdn:
         if quantize is not None:
             raise ValueError("no quantized load for a model with Gated DeltaNet layers")
@@ -668,6 +776,107 @@ def _build_gdn_params(config: ModelConfig, shardings: dict[str, Any], get: Any) 
                 "mlp_norm": stack(everywhere, "post_attention_layernorm.weight", lambda w: w),
             },
         },
+        "final_norm": get("model.norm.weight").astype(dtype),
+    }
+    if not c.tie_embeddings:
+        tree["lm_head"] = get("lm_head.weight")[rows].T.astype(dtype)
+    logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
+                c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
+                rows.start, rows.stop - 1)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_kda_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The Kimi Delta Attention hybrid's tree from ``bailing_hybrid`` names
+    (module text); of a share, the experts and the vocabulary rows it holds.
+    q | k | v | g (one a head) | b become ONE ``w_in``, the three depthwise
+    convs one ``conv_w``; the latent layers' rope dimensions go from
+    interleaved pairs to halves (``rope_interleave``), as DeepseekV3's do."""
+    import jax
+
+    from calfkit_tpu.inference.config import ATTENTION
+
+    c = config
+    D, H, r, dn, dr, dv = (c.d_model, c.n_heads, c.kv_lora_rank, c.qk_nope_head_dim,
+                           c.qk_rope_head_dim, c.v_head_dim)
+    Hv, dk = c.gdn_n_v_heads, c.gdn_d_k
+    dtype = np.dtype(c.dtype)
+    nd = c.first_k_dense
+    attn_at = [i for i, t in enumerate(c.layer_types) if t == ATTENTION]
+    kda_at = [i for i, t in enumerate(c.layer_types) if t != ATTENTION]
+    dense_at, moe_at = range(nd), range(nd, c.n_layers)
+    rank = c.expert_first // c.n_routed_experts
+    rows = slice(rank * c.vocab_size, (rank + 1) * c.vocab_size)
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])  # pairs -> halves
+
+    def rope_last(w: np.ndarray, start: int) -> np.ndarray:
+        return np.concatenate([w[..., :start], w[..., start:][..., halves]], axis=-1)
+
+    def stack(layers: Any, name: str, transform: Any, as_type: Any = dtype) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in layers]
+        ).astype(as_type)
+
+    def together(i: int, names: tuple, transform: Any) -> np.ndarray:
+        return np.concatenate(
+            [transform(get(f"model.layers.{i}.attention.{n}.weight")) for n in names])
+
+    def experts(name: str) -> np.ndarray:
+        held = range(c.expert_first, c.expert_first + c.n_routed_experts)
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T for e in held])
+            for i in moe_at
+        ]).astype(dtype)
+
+    kv_b = stack(attn_at, "attention.kv_b_proj.weight", lambda w: w.T.reshape(r, H, dn + dv))
+    layers: dict[str, Any] = {
+        "attn": {
+            "wq": stack(attn_at, "attention.q_proj.weight",
+                        lambda w: rope_last(w.T.reshape(D, H, dn + dr), dn)),
+            "w_kva": stack(attn_at, "attention.kv_a_proj_with_mqa.weight",
+                           lambda w: rope_last(w.T, r)),
+            "kv_norm": stack(attn_at, "attention.kv_a_layernorm.weight", lambda w: w),
+            "w_uk": np.ascontiguousarray(kv_b[..., :dn]),
+            "w_uv": np.ascontiguousarray(kv_b[..., dn:]),
+            "w_z": stack(attn_at, "attention.g_proj.weight", lambda w: w.T),
+            "wo": stack(attn_at, "attention.dense.weight", lambda w: w.T.reshape(H, dv, D)),
+            "attn_norm": stack(attn_at, "input_layernorm.weight", lambda w: w),
+        },
+        "gdn": {
+            "w_in": np.stack([together(i, ("q_proj", "k_proj", "v_proj", "g_proj", "b_proj"),
+                                       lambda w: w) for i in kda_at]).astype(dtype),
+            # a depthwise conv1d weight is [C, 1, d_conv]; ours is tap-major over q | k | v
+            "conv_w": np.stack([together(i, ("q_conv1d", "k_conv1d", "v_conv1d"),
+                                         lambda w: w[:, 0, :]).T for i in kda_at]).astype(dtype),
+            "w_alpha": stack(kda_at, "attention.f_proj.weight", lambda w: w),
+            "A_log": stack(kda_at, "attention.A_log", lambda w: w.reshape(Hv), np.float32),
+            "dt_bias": stack(kda_at, "attention.dt_bias", lambda w: w.reshape(Hv, dk),
+                             np.float32),
+            "norm": stack(kda_at, "attention.o_norm.weight", lambda w: w),
+            "w_out": stack(kda_at, "attention.dense.weight", lambda w: w.T),
+            "mixer_norm": stack(kda_at, "input_layernorm.weight", lambda w: w),
+        },
+        "dense": {
+            "w_gate": stack(dense_at, "mlp.gate_proj.weight", lambda w: w.T),
+            "w_up": stack(dense_at, "mlp.up_proj.weight", lambda w: w.T),
+            "w_down": stack(dense_at, "mlp.down_proj.weight", lambda w: w.T),
+            "mlp_norm": stack(dense_at, "post_attention_layernorm.weight", lambda w: w),
+        },
+        "moe": {
+            "router": stack(moe_at, "mlp.gate.weight", lambda w: w.T),
+            "router_bias": stack(moe_at, "mlp.gate.expert_bias", lambda w: w, np.float32),
+            "w_gate": experts("gate_proj"),
+            "w_up": experts("up_proj"),
+            "w_down": experts("down_proj"),
+            "s_gate": stack(moe_at, "mlp.shared_experts.gate_proj.weight", lambda w: w.T),
+            "s_up": stack(moe_at, "mlp.shared_experts.up_proj.weight", lambda w: w.T),
+            "s_down": stack(moe_at, "mlp.shared_experts.down_proj.weight", lambda w: w.T),
+            "mlp_norm": stack(moe_at, "post_attention_layernorm.weight", lambda w: w),
+        },
+    }
+    tree: dict[str, Any] = {
+        "embed": get("model.word_embeddings.weight")[rows].astype(dtype),
+        "layers": layers,
         "final_norm": get("model.norm.weight").astype(dtype),
     }
     if not c.tie_embeddings:

@@ -80,6 +80,7 @@ that code either.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 from typing import Any
@@ -130,16 +131,24 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
                          config.qk_rope_head_dim, config.v_head_dim)
         Ld = config.n_dense_layers
         akeys = jax.random.split(keys[1], 5)
+        # a Kimi Delta Attention hybrid: latent attention in its attention
+        # layers alone (one gate a head on their output), the delta rule's
+        # leaves beside them; else every layer's mixer is latent attention
+        La = config.n_kv_layers
         layers: Params = {
             "attn": {
-                "wq": norm_init(akeys[0], (L, D, H, dn + dr), D),
-                "w_kva": norm_init(akeys[1], (L, D, r + dr), D),
-                "kv_norm": jnp.ones((L, r), dtype),
-                "w_uk": norm_init(akeys[2], (L, r, H, dn), r),
-                "w_uv": norm_init(akeys[3], (L, r, H, dv), r),
-                "wo": norm_init(akeys[4], (L, H, dv, D), H * dv),
-                "attn_norm": jnp.ones((L, D), dtype),
+                "wq": norm_init(akeys[0], (La, D, H, dn + dr), D),
+                "w_kva": norm_init(akeys[1], (La, D, r + dr), D),
+                "kv_norm": jnp.ones((La, r), dtype),
+                "w_uk": norm_init(akeys[2], (La, r, H, dn), r),
+                "w_uv": norm_init(akeys[3], (La, r, H, dv), r),
+                "wo": norm_init(akeys[4], (La, H, dv, D), H * dv),
+                "attn_norm": jnp.ones((La, D), dtype),
+                **({"w_z": norm_init(jax.random.fold_in(keys[1], 5), (La, D, H), D)}
+                   if config.attn_output_gate else {}),
             },
+            **({"gdn": init_gdn_params(config, jax.random.split(keys[3])[0], dtype)}
+               if config.kda else {}),
             "dense": {
                 "w_gate": norm_init(keys[5], (Ld, D, F), D),
                 "w_up": norm_init(keys[6], (Ld, D, F), D),
@@ -534,46 +543,69 @@ def _hybrid_stack(
 
 
 def _gdn_hybrid_stack(config, layers, x, carry, attn_layer, gdn_layer, stats, valid):
-    """:func:`_hybrid_stack` for a Gated DeltaNet hybrid: the same one scan
-    over the period's repeats with its layers unrolled in the body; the
-    norms multiply by ``1 + w``, the recurrent mixer reads under ``gdn``, and
-    every layer's FFN is the expert block -> (x, carry, stats)."""
+    """:func:`_hybrid_stack` for a delta-rule hybrid (Gated DeltaNet, or Kimi
+    Delta Attention): the same one scan over the period's repeats with its
+    layers unrolled in the body; the norms multiply by ``1 + w`` where the
+    description says so, the recurrent mixer reads under ``gdn``, and every
+    layer's FFN is the expert block -> (x, carry, stats).  A stack with
+    leading dense layers (``config.stack_plan``) runs its head unrolled
+    before the scan, a leading layer's FFN the SwiGLU of ``d_ff``; its
+    attention layers are latent attention and read under ``mla``."""
     eps, plus = config.norm_eps, config.norm_plus_one
-    period = config.layer_period
+    head, period = config.stack_plan
     a_per = period.count(ATTENTION)
     m_per = len(period) - a_per
+    nd = config.first_k_dense
+
+    def layer(x, carry, stats, kind, il, ia, im, dense=False):
+        if kind == ATTENTION:
+            lp = _layer(layers["attn"], ia)
+            with jax.named_scope("mla") if config.latent else contextlib.nullcontext():
+                carry, attn = attn_layer(carry, x, lp, ia)
+                with jax.named_scope("attn_out"):
+                    x = x + jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
+        else:
+            lp = _layer(layers["gdn"], im)
+            with jax.named_scope("gdn"):
+                carry, y = gdn_layer(
+                    carry, rms_norm(x, lp["mixer_norm"], eps, plus), lp, im)
+                x = x + y
+        if dense:  # a leading layer: one SwiGLU of d_ff
+            return mlp_residual(x, _layer(layers["dense"], il), eps), carry, stats
+        m = il - nd if nd else il
+        lp = _layer(layers["moe"], m)
+        with jax.named_scope("mlp"):
+            y, stats = moe_ffn(
+                rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, m,
+                layers["moe"])
+        return x + y, carry, stats
+
+    ja = jm = 0
+    for il, kind in enumerate(config.layer_types[:head]):
+        x, carry, stats = layer(x, carry, stats, kind, il, ja, jm, dense=il < nd)
+        ja, jm = ja + (kind == ATTENTION), jm + (kind != ATTENTION)
+    a0, m0 = ja, jm
 
     def body(c, p):
         x, carry, stats = c
         ja = jm = 0
         for j, kind in enumerate(period):
-            il = p * len(period) + j
+            # the head's layers come first of every index; a stack without a head
+            # adds nothing (its traced program stays what it was before heads)
+            il = p * len(period) + j + head if head else p * len(period) + j
             if kind == ATTENTION:
-                ia = p * a_per + ja
-                lp = _layer(layers["attn"], ia)
-                carry, attn = attn_layer(carry, x, lp, ia)
-                with jax.named_scope("attn_out"):
-                    x = x + jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
+                ia = p * a_per + ja + a0 if a0 else p * a_per + ja
+                x, carry, stats = layer(x, carry, stats, kind, il, ia, None)
                 ja += 1
             else:
-                im = p * m_per + jm
-                lp = _layer(layers["gdn"], im)
-                with jax.named_scope("gdn"):
-                    carry, y = gdn_layer(
-                        carry, rms_norm(x, lp["mixer_norm"], eps, plus), lp, im)
-                    x = x + y
+                im = p * m_per + jm + m0 if m0 else p * m_per + jm
+                x, carry, stats = layer(x, carry, stats, kind, il, None, im)
                 jm += 1
-            lp = _layer(layers["moe"], il)
-            with jax.named_scope("mlp"):
-                y, stats = moe_ffn(
-                    rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, il,
-                    layers["moe"])
-                x = x + y
         return (x, carry, stats), None
 
     (x, carry, stats), _ = lax.scan(
         body, (x, carry, stats),
-        jnp.arange(config.n_layers // len(period), dtype=jnp.int32))
+        jnp.arange((config.n_layers - head) // len(period), dtype=jnp.int32))
     return x, carry, stats
 
 
@@ -642,6 +674,18 @@ def mla_absorb_out(o_lat: jax.Array, lp: Params) -> jax.Array:
     values → [B, S, H, dv]."""
     with jax.named_scope("absorb"):
         return jnp.einsum("bsnc,cnh->bsnh", o_lat.astype(lp["w_uv"].dtype), lp["w_uv"])
+
+
+def mla_head_gate(attn: jax.Array, x: jax.Array, lp: Params, config: ModelConfig) -> jax.Array:
+    """``o_head * sigmoid(h W_z)[head]`` before ``W_o``: ONE gate a head
+    (``attn_output_gate`` of a latent-attention layer), in float32; ``attn``
+    [B, S, H, dv] as it is without the leaf."""
+    if "w_z" not in lp:
+        return attn
+    with jax.named_scope("out_gate"):
+        h = rms_norm(x, lp["attn_norm"], config.norm_eps)
+        gate = _einsum_f32("bsd,dn->bsn", h, lp["w_z"])
+        return (attn.astype(jnp.float32) * jax.nn.sigmoid(gate)[..., None]).astype(attn.dtype)
 
 
 @jax.named_scope("attention")
@@ -754,6 +798,38 @@ def _rope_dim_tables(config: ModelConfig, positions: jax.Array):
     return rope_tables(positions, config.qk_rope_head_dim, config.rope_theta)
 
 
+def _mla_chunk_mixer(cache, x, lp, i, cos, sin, config, positions, seq_lens, W, insert_at):
+    """A latent layer over a chunk (the expanded algebra): the fresh latents
+    go into layer ``i``'s rows of ``cache`` (c side, rope side) -> (cache,
+    attn [B, S, H, dv])."""
+    q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
+    pages = tuple(
+        _insert_chunk(lax.dynamic_index_in_dim(side, i, 0, keepdims=False),
+                      part[:, :, None, :], insert_at)
+        for side, part in zip(cache, fresh))
+    attn = mla_attention_expanded(
+        q_nope, q_rope, pages[0][:, 0, :W], pages[1][:, 0, :W], lp, positions, seq_lens,
+        config)
+    cache = tuple(lax.dynamic_update_index_in_dim(side, page, i, 0)
+                  for side, page in zip(cache, pages))
+    return cache, attn
+
+
+def _mla_step_mixer(ring, x, lp, i, t, cos, sin, config, attn_source):
+    """A latent layer's decode step (the absorbed algebra): the fresh latent
+    goes to slot ``t`` of layer ``i``'s ring, ``attn_source`` reads (main
+    cache (+) ring) with ``c`` as key AND value -> (ring, out [B, 1, H, dv])."""
+    q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
+    ring = tuple(
+        lax.dynamic_update_slice(
+            side, part[:, 0].astype(side.dtype)[None, None, :, None, :], (i, t, 0, 0, 0))
+        for side, part in zip(ring, fresh))
+    o_lat = attn_source(
+        i, (mla_absorb_query(q_nope, lp), q_rope),
+        *(lax.dynamic_index_in_dim(side, i, 0, keepdims=False) for side in ring), None)
+    return ring, mla_absorb_out(o_lat, lp)
+
+
 def _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W, insert_at,
                     stats, n_valid):
     """``forward`` for a latent-attention stack (the expanded algebra)."""
@@ -765,17 +841,8 @@ def _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W, in
         valid = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] < n_valid[:, None]
 
     def mixer(cache, x, lp, i):
-        q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
-        pages = tuple(
-            _insert_chunk(lax.dynamic_index_in_dim(side, i, 0, keepdims=False),
-                          part[:, :, None, :], insert_at)
-            for side, part in zip(cache, fresh))
-        attn = mla_attention_expanded(
-            q_nope, q_rope, pages[0][:, 0, :W], pages[1][:, 0, :W], lp, positions, seq_lens,
-            config)
-        cache = tuple(lax.dynamic_update_index_in_dim(side, page, i, 0)
-                      for side, page in zip(cache, pages))
-        return cache, attn
+        return _mla_chunk_mixer(
+            cache, x, lp, i, cos, sin, config, positions, seq_lens, W, insert_at)
 
     x, cache, stats = _latent_stack(
         config, params["layers"], x, tuple(kv_cache), mixer, stats, valid)
@@ -797,15 +864,7 @@ def _latent_decode_step(params, config, tokens, ring, t, base_lens, attn_source,
     valid = None if active is None else active[:, None]
 
     def mixer(ring, x, lp, i):
-        q_nope, q_rope, *fresh = mla_project(x, lp, cos, sin, config)
-        ring = tuple(
-            lax.dynamic_update_slice(
-                side, part[:, 0].astype(side.dtype)[None, None, :, None, :], (i, t, 0, 0, 0))
-            for side, part in zip(ring, fresh))
-        o_lat = attn_source(
-            i, (mla_absorb_query(q_nope, lp), q_rope),
-            *(lax.dynamic_index_in_dim(side, i, 0, keepdims=False) for side in ring), None)
-        return ring, mla_absorb_out(o_lat, lp)
+        return _mla_step_mixer(ring, x, lp, i, t, cos, sin, config, attn_source)
 
     x, ring, stats = _latent_stack(
         config, params["layers"], x, tuple(ring), mixer, stats, valid)
@@ -1178,7 +1237,7 @@ def forward(
         insert_at = seq_lens - tokens.shape[1]  # where this chunk lands
     k_pages, v_pages = kv_cache  # [L, B, K, Smax, hd]
     W = attn_window or k_pages.shape[3]
-    if config.latent:
+    if config.latent and not config.layer_types:
         return _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W,
                                insert_at, moe, n_valid)
     if config.windowed:
@@ -1186,9 +1245,15 @@ def forward(
                                insert_at, moe, n_valid, chunk_attn_impl)
     if config.layer_types:
         x = _embed(params, config, tokens)
-        cos, sin = _positions_tables(config, positions)
+        cos, sin = (_rope_dim_tables if config.latent else _positions_tables)(config, positions)
         if n_valid is None:
             n_valid = jnp.full(tokens.shape[:1], tokens.shape[1], jnp.int32)
+
+        def latent_layer(carry, x, lp, ia):  # as _latent_forward's, the state riding along
+            *cache, st = carry
+            cache, attn = _mla_chunk_mixer(
+                cache, x, lp, ia, cos, sin, config, positions, seq_lens, W, insert_at)
+            return (*cache, st), mla_head_gate(attn, x, lp, config)
 
         def attn_layer(carry, x, lp, ia):
             k_all, v_all, st = carry
@@ -1215,7 +1280,7 @@ def forward(
             valid = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :] < n_valid[:, None]
         x, (new_k, new_v, state), moe = _hybrid_stack(
             config, params["layers"], x, (k_pages, v_pages, state),
-            attn_layer, mamba_layer, moe, valid,
+            latent_layer if config.latent else attn_layer, mamba_layer, moe, valid,
         )
         logits = lm_logits(x, params, eps, config.logits_scaling, config.norm_plus_one)
         return (logits, (new_k, new_v), state, *(() if moe is None else (moe,)))
@@ -1303,12 +1368,17 @@ def _decode_step_with_ring(
     eps = config.norm_eps
     positions = (base_lens + t)[:, None]  # [B, 1] absolute position
     ring_k, ring_v = ring
-    if config.latent:
+    if config.latent and not config.layer_types:
         return _latent_decode_step(
             params, config, tokens, ring, t, base_lens, attn_source, moe, active)
     if config.layer_types:
         x = _embed(params, config, tokens)
-        cos, sin = _positions_tables(config, positions)
+        cos, sin = (_rope_dim_tables if config.latent else _positions_tables)(config, positions)
+
+        def latent_layer(carry, x, lp, ia):  # as _latent_decode_step's, the state riding along
+            *ring, st = carry
+            ring, out = _mla_step_mixer(ring, x, lp, ia, t, cos, sin, config, attn_source)
+            return (*ring, st), mla_head_gate(out, x, lp, config)
 
         def attn_layer(carry, x, lp, ia):
             ring_k, ring_v, st = carry
@@ -1339,7 +1409,7 @@ def _decode_step_with_ring(
         valid = active[:, None] if config.moe and active is not None else None
         x, (ring_k, ring_v, state), moe = _hybrid_stack(
             config, params["layers"], x, (ring_k, ring_v, state),
-            attn_layer, mamba_layer, moe, valid,
+            latent_layer if config.latent else attn_layer, mamba_layer, moe, valid,
         )
         logits = lm_logits(x, params, eps, config.logits_scaling, config.norm_plus_one)
         return (logits, (ring_k, ring_v), state, *(() if moe is None else (moe,)))

@@ -99,11 +99,22 @@ the safe way round:
   it is listed in ``_DENSE_TO_THE_CROSSING``: its forms TIMED on the chip
   and its ragged program COMPILED at full depth without that copy.
 
+A Ling-3.0-style layer (``n_group`` > 1: DeepSeek-V3's group-limited choice)
+is the first gate with one step before its top-k, over ALL the experts
+scored whatever share is held: the ``s + b`` of ``n_group`` equal groups, a
+group's score the sum of its two largest, the ``topk_group`` best groups
+kept, the k largest of THEIR experts chosen; the weights are the unbiased
+``s`` of the chosen as before.  Held one group a device, a token's experts
+lie on at most ``topk_group`` devices, and a row reaches this one only if a
+held group is among its kept (``moe_rows_in_held_groups``).
+
 Counters (``stats``; what :class:`EngineStats` sums as ``moe_*``): a pair
 ``(counts [Lm, E] int32, hit [] int32)``, with a third ``absent [] int32``
 (the real tokens' assignments to experts held elsewhere) when the experts
 are held by share, that a dispatch carries through
-its steps and returns beside its tokens.  ``counts[m, e]`` is the tokens
+its steps and returns beside its tokens (and a fourth, ``in_held_groups`` []
+int32, where the gate chooses by group: the real rows, summed over layers,
+whose kept groups include one held here).  ``counts[m, e]`` is the tokens
 layer ``m`` sent to expert ``e``; ``hit`` the distinct experts a call had
 to read, summed over layers (and, by the caller, over steps).  Only REAL
 tokens count (``valid``): a padded position of a chunk and an inactive row
@@ -128,8 +139,13 @@ _HI = lax.Precision.HIGHEST  # the gate's float32 product: no bf16 passes
 # narrow chunks (the module's text)
 _DENSE_MAX_TOKENS = 512
 # (experts held, hidden, expert width) -> the limit of a shape that was timed
-# and compiled: the first table's crossing, between 1,024 and 2,048
-_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536}
+# and compiled: the first table's crossing, between 1,024 and 2,048; and 0 for
+# a shape whose DECODE program, compiled for the v5e at its cell's 128 rows,
+# copies the whole of w_gate and w_up into the dense product's layout (2 x 1.51
+# GB of temporaries and a layer's 252 MB again in the loop: 7.4 GB in all, a
+# program that does not fit the chip; PERF.md section 6, PR 40): every product
+# of that shape is grouped, which reads the experts hit where they lie
+_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
 
 
 def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
@@ -152,7 +168,7 @@ def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
         "mlp_norm": jnp.zeros((Lm, D), dtype) if c.norm_plus_one else jnp.ones((Lm, D), dtype),
     }
     if c.topk_method == "noaux_tc":
-        out["router_bias"] = jnp.zeros((Lm, E), jnp.float32)
+        out["router_bias"] = jnp.zeros((Lm, c.experts_scored), jnp.float32)
     if Fs:
         out.update(
             s_gate=mat(keys[5], (Lm, D, Fs), D),
@@ -168,7 +184,41 @@ def moe_stats_init(config: ModelConfig) -> tuple[jax.Array, ...]:
     """Zeroed counters of one dispatch (see the module's text)."""
     zero = jnp.zeros((), jnp.int32)
     counts = jnp.zeros((config.n_moe_layers, config.n_routed_experts), jnp.int32)
+    if config.n_group > 1:
+        return (counts, zero, zero, zero)
     return (counts, zero, zero) if config.expert_share else (counts, zero)
+
+
+def kept_groups(pick: jax.Array, config: ModelConfig) -> jax.Array:
+    """The group-limited step of the choice: ``pick`` [T, E scored] (the
+    scores the choice is made on) -> [T, n_group] bool, the ``topk_group``
+    groups whose two largest scores sum highest."""
+    with jax.named_scope("groups"):
+        T, E = pick.shape
+        grouped = pick.reshape(T, config.n_group, E // config.n_group)
+        best = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)  # [T, n_group]
+        _, kept = lax.top_k(best, config.topk_group)
+        return jnp.any(
+            kept[..., None] == jnp.arange(config.n_group, dtype=kept.dtype), axis=-2)
+
+
+def _logits(h: jax.Array, lp: Params) -> jax.Array:
+    """The gate's product [T, E scored]: float32 at HIGHEST precision."""
+    return jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
+        precision=_HI, preferred_element_type=jnp.float32,
+    )
+
+
+def rows_in_held_groups(h: jax.Array, lp: Params, config: ModelConfig) -> jax.Array:
+    """[T] bool: does one of the row's KEPT groups lie (in part) among the
+    experts held here?  The gate's scores again, which the compiler shares
+    with :func:`route`'s (the same product of the same operands)."""
+    c = config
+    kept = kept_groups(jax.nn.sigmoid(_logits(h, lp)) + lp["router_bias"].astype(jnp.float32), c)
+    per = c.experts_scored // c.n_group
+    first, last = c.expert_first // per, (c.expert_first + c.n_routed_experts - 1) // per
+    return jnp.any(kept[:, first:last + 1], axis=-1)
 
 
 def route(
@@ -179,10 +229,7 @@ def route(
     """The gate → (chosen [T, k] int32, weights [T, k] float32): the
     product, the scores and the top-k in float32, as published."""
     c = config
-    logits = jnp.einsum(
-        "td,de->te", h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-        precision=_HI, preferred_element_type=jnp.float32,
-    )
+    logits = _logits(h, lp)
     if c.scoring_func == "softmax":  # over ALL the experts scored; no bias, no scaling
         probs = jax.nn.softmax(logits, axis=-1)
         weights, chosen = lax.top_k(probs, c.n_experts_per_tok)
@@ -192,6 +239,10 @@ def route(
     scores = jax.nn.sigmoid(logits)
     # "greedy" (Cohere2-MoE): the k largest scores themselves, no bias on the choice
     pick = scores + lp["router_bias"].astype(jnp.float32) if "router_bias" in lp else scores
+    if c.n_group > 1:  # only the kept groups' experts stand for the top-k
+        pick = jnp.where(
+            jnp.repeat(kept_groups(pick, c), c.experts_scored // c.n_group, axis=-1),
+            pick, -jnp.inf)
     _, chosen = lax.top_k(pick, c.n_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)  # the UNBIASED scores
     if c.norm_topk_prob:
@@ -306,9 +357,15 @@ def moe_ffn(
                 tokens = jnp.sum(real, axis=(0, 1), dtype=jnp.int32)  # [E]
                 counts, hit, *absent = stats
                 stats = (counts.at[m].add(tokens), hit + jnp.sum(tokens > 0, dtype=jnp.int32))
-                if share:
+                by_group = config.n_group > 1
+                if share or by_group:
                     n_real = B * S if valid is None else jnp.sum(valid, dtype=jnp.int32)
                     stats = (*stats, absent[0] + n_real * chosen.shape[-1] - jnp.sum(tokens))
+                if by_group:
+                    reach = rows_in_held_groups(flat, lp, config)
+                    if valid is not None:
+                        reach = reach & valid.reshape(-1)
+                    stats = (*stats, absent[1] + jnp.sum(reach, dtype=jnp.int32))
         if dense_form(B * S, config):
             y = experts_dense(flat, onehot, weights, lp)
         else:

@@ -189,7 +189,8 @@ def quantize_params(
     layers = params["layers"]
     if "moe" in layers or "mamba" in layers or "gdn" in layers:
         raise ValueError(
-            "expert, latent-attention, Mamba and Gated DeltaNet leaves have no scales: only the "
+            "expert, latent-attention, Mamba and delta-rule (Gated DeltaNet, Kimi Delta "
+            "Attention) leaves have no scales: only the "
             "dense decoder's tree is quantized"
         )
     out: Params = {"embed": params["embed"], "final_norm": params["final_norm"]}

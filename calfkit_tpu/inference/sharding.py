@@ -33,6 +33,7 @@ MAMBA_LEAVES = ("w_in", "conv_w", "conv_b", "A_log", "D", "dt_bias", "norm", "w_
 LATENT_ATTN_LEAVES = ("wq", "w_kva", "kv_norm", "w_uk", "w_uv", "wo", "attn_norm")
 # the leaves of one stacked group of Gated DeltaNet layers (inference/gdn.py)
 GDN_LEAVES = ("w_in", "conv_w", "A_log", "dt_bias", "norm", "w_out", "mixer_norm")
+KDA_LEAVES = (*GDN_LEAVES, "w_alpha")  # Kimi Delta Attention: the decay's own matrix
 GATED_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
 MLP_LEAVES = ("w_gate", "w_up", "w_down", "mlp_norm")
 MOE_LEAVES = ("router", "router_bias", *MLP_LEAVES)
@@ -102,9 +103,12 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         # of more than one device until the experts are held by share
         whole = NamedSharding(mesh, P())
         layers = {
-            "attn": dict.fromkeys(LATENT_ATTN_LEAVES, whole),
+            "attn": dict.fromkeys(
+                LATENT_ATTN_LEAVES + (("w_z",) if config.attn_output_gate else ()), whole),
             "dense": dict.fromkeys(MLP_LEAVES, whole),
         }
+        if config.kda:  # Kimi Delta Attention beside the latent layers, replicated too
+            layers["gdn"] = dict.fromkeys(KDA_LEAVES, whole)
         if config.moe:
             layers["moe"] = dict.fromkeys(
                 MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole)

@@ -83,6 +83,10 @@ SCOPES = frozenset({
     # "state" for the delta rule's pass over S) and the gated attention's
     # own steps beside "qkv" and "attention"
     "gdn", "state", "qk_norm", "out_gate",
+    # Kimi Delta Attention's own step inside "gdn" (gdn.py: the decay's
+    # product and the bounded gate) and the group-limited step of a gate's
+    # choice inside "moe/router" (moe.py)
+    "decay", "groups",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

@@ -562,7 +562,9 @@ def test_the_manifest_carries_qwen_s_cell_and_its_two_metrics():
     assert cell.chips == 1 and cell.params == {"callers": 64}
     assert cell.traffic_name == "history-closed"
     assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
-    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [QWEN_CELL]}
+    # told by the FIRST cell of their list: later cells are appended to it (PR 40's)
+    own = {m["name"]: m for m in MANIFEST["per_layer"]
+           if m.get("workloads", [None])[0] == QWEN_CELL}
     assert set(own) == {"gdn_device_pct", "gdn_state_roofline"}
     assert {own[n]["moves"] for n in own} == {"tpot_p95_ms"}
     registered = {m.name for m in cell.per_layer}
@@ -722,7 +724,7 @@ def test_the_chunk_attention_reader_reads_its_share_and_nothing_without_its_sour
     other cell); under 100 where the measured time is above the least."""
     from types import SimpleNamespace
 
-    entry = MANIFEST["per_layer"][-1]
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == "chunk_attn_roofline")
     assert entry == {"name": "chunk_attn_roofline", "unit": "%", "better": "higher",
                      "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
                      "workloads": [COHERE_CELL]}
@@ -770,22 +772,25 @@ def test_the_manifest_carries_command_a_plus_s_cell_and_its_two_metrics():
     # here the prefill lane does (occupancy 44-47%), ~27 requests fall into a window, and
     # the driver's two sets of six runs spread 4.1% and 6.7%, past half the 10% bound
     assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "setup_s"}
-    own = {m["name"]: m for m in MANIFEST["per_layer"] if m.get("workloads") == [COHERE_CELL]}
+    own = {m["name"]: m for m in MANIFEST["per_layer"]
+           if m.get("workloads", [None])[0] == COHERE_CELL}  # told by the FIRST cell of their list
     assert {n: own[n]["moves"] for n in own} == {
         "swa_device_pct": "tpot_p95_ms", "swa_cache_roofline": "tpot_p95_ms",
         "chunk_attn_roofline": "tpot_p95_ms"}  # (the third: PR 39)
     registered = {m.name for m in cell.per_layer}
     assert {*own, "moe_device_pct", "moe_expert_roofline", "dispatch_roofline"} <= registered
     assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline"} & registered
-    assert [m["name"] for m in MANIFEST["per_layer"]][-3:] == list(own)
-    assert MANIFEST["workloads"][-1]["name"] == COHERE_CELL and len(MANIFEST["workloads"]) == 5
-    entry = MANIFEST["configs"][-1]
-    assert entry["name"] == COHERE and len(MANIFEST["configs"]) == 5
+    names = [m["name"] for m in MANIFEST["per_layer"]]  # appended in PRs 38 and 39, in order
+    at = names.index("swa_device_pct")
+    assert names[at:at + 3] == list(own)
+    assert MANIFEST["workloads"][4]["name"] == COHERE_CELL and len(MANIFEST["workloads"]) >= 5
+    entry = MANIFEST["configs"][4]
+    assert entry["name"] == COHERE and len(MANIFEST["configs"]) >= 5
     assert entry["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
     for listed in ("moe_device_pct", "moe_expert_roofline"):
         metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
-        assert metric["workloads"][-1] == COHERE_CELL, listed
+        assert COHERE_CELL in metric["workloads"], listed
 
 
 def test_what_moves_tokens_a_second_is_recorded_in_command_a_plus_s_cell_and_judges_nothing():
@@ -802,3 +807,301 @@ def test_what_moves_tokens_a_second_is_recorded_in_command_a_plus_s_cell_and_jud
         if metric["name"] == "out_tok_s_per_chip" or metric.get("moves") == "out_tok_s_per_chip":
             assert COHERE_CELL not in metric["workloads"], metric["name"]
     assert "kv_pages_given_back_pct" not in {m["name"] for m in MANIFEST["per_layer"]}
+
+
+# ------------------------------------------------ ling-3.0-flash-vl (PR 40)
+LING, LING_CELL = "ling-3.0-flash-vl", "ling-3.0-flash-vl.reason-closed"
+
+
+def test_ling_s_counts_are_what_a_hand_reckons():
+    """The cut's bytes as ISSUE 40 reckons them, recounted from the tree, and
+    the counts of THIS chip's share: the held experts hit, the channel-decay
+    state read and written, ONE latent a token, the chunk form's FLOPs."""
+    arch = M.load_architecture("bailing-kda-mla-moe")
+    config = config_file(LING)
+    assert arch.weight_bytes(config) == 2 * config["parameters"] == 5_607_690_112
+    assert arch.weight_bytes(config) == config["hbm"]["weights_bytes"]
+    expert = 3 * 2560 * 768
+    assert config["hbm"]["experts_bytes"] == 6 * 64 * expert * 2 == 4_529_848_320
+    assert config["hbm"]["kda_mixers_bytes"] == 6 * 52_592_640 * 2
+    assert config["hbm"]["mla_mixer_bytes"] == 31_965_184 * 2
+    assert arch.state_bytes_per_token(config) == config["hbm"]["kv_bytes_per_token"] == 1152
+    assert arch.recurrent_state_bytes(config, 1) == 13_025_280 == 6 * (2_097_152 + 73_728)
+    assert arch.recurrent_state_bytes(config, 128) == config["hbm"]["recurrent_state_bytes"]
+    step = arch.recurrent_state_step(config, 100)
+    assert step["bytes"] == 2 * 100 * 13_025_280 and step["flops"] == 8 * 100 * 6 * 32 * 128 * 128
+    assert arch.experts_hit(config, 128) == pytest.approx(64 * (1 - (1 - 8 / 512) ** 128))
+    assert 55 < arch.experts_hit(config, 128) < 56
+    layer = arch.expert_layer_step(config, 128, 55.0)
+    gate = 2560 * 512
+    assert layer["bytes"] == (55 * expert + expert + gate) * 2
+    assert layer["flops"] == 2 * 128 * (1.0 * expert + expert + gate)  # 8 x 64 / 512 lie here
+    # a head's token in a block of 64: 32 (2 dk + (dv + dk) + dv) + 3 dk dv multiply-adds
+    chunk = arch.recurrent_chunk(config, 1000)
+    assert chunk == {"flops": 2 * (32 * 5 * 128 + 3 * 128 * 128) * 32 * 6 * 1000, "bytes": 0.0}
+    whole = arch.decode_step(config, 128, 1100)
+    assert whole["bytes"] < arch.weight_bytes(config) + 2 * 128 * 13_025_280 + 1152 * 128 * 1100
+    assert whole["bytes"] > 0.6 * arch.weight_bytes(config) + 2 * 128 * 13_025_280
+    prefill = arch.prefill_chunk(config, 4, 1024, 0)
+    assert prefill["flops"] > 2 * 4096 * 6 * 2 * expert + chunk["flops"] and prefill["bytes"] > 0
+
+
+def test_the_program_s_description_of_ling_is_the_file_s():
+    arch = M.load_architecture("bailing-kda-mla-moe")
+    config = config_file(LING)
+    described, runtime = arch.model(config, False)
+    assert described.param_count == config["parameters"] == 2_803_845_056
+    assert config["published"] == {"num_hidden_layers": 42, "num_experts": 512,
+                                   "vocab_size": 157184}
+    assert config["published_layers"] == [0, 6, 7, 8, 9, 10, 11]
+    assert "8 chips share a layer" in config["deployment"]
+    assert sorted(config["reduced"]) == ["num_experts", "num_hidden_layers", "vocab_size"]
+    assert (described.n_routed_experts, described.experts_scored, described.expert_first,
+            described.n_experts_per_tok, described.n_group, described.topk_group) == (
+        64, 512, 0, 8, 8, 4)
+    assert described.layer_types == ("kda",) * 6 + ("attention",) and described.first_k_dense == 1
+    assert described.stack_plan == (1, ("kda",) * 5 + ("attention",))
+    assert (described.head_dim, described.cache_dims, described.n_kv_layers) == (192, (512, 64), 1)
+    assert (described.gdn_n_v_heads, described.gdn_d_k, described.gdn_d_v) == (32, 128, 128)
+    assert described.kda_lower_bound == -5.0 and described.attn_output_gate
+    assert described.expert_swiglu_limits == (0.0,) * 6 == described.shared_expert_swiglu_limits
+    assert described.state_dtype == "float32" and described.dtype == "bfloat16"
+    assert described.recurrent_state_bytes(128) == config["hbm"]["recurrent_state_bytes"]
+    assert (runtime.max_batch_size, runtime.max_seq_len, runtime.prefill_chunk,
+            runtime.max_prefill_wave, runtime.prefix_cache, runtime.window_buckets) == (
+        128, 4096, 1024, 4, False, (4096,))
+    assert runtime.pool_pages() * 64 * 1152 == 8193 * config["hbm"]["page_bytes"]
+    from calfkit_tpu.inference.config import preset
+
+    assert preset("ling-3.0-flash-vl").param_count == config["published_parameters"]
+    toy, _ = arch.model(config, True)
+    assert toy.expert_share and toy.layer_types == described.layer_types
+    # a HELD layer with a nonzero swiglu limit is refused by name; a variant not read, by its key
+    with pytest.raises(ValueError, match="swiglu limit"):
+        arch.model({**config, "published_layers": [0, 6, 7, 8, 9, 10, 35]}, False)
+    with pytest.raises(ValueError, match="kda_safe_gate"):
+        arch.model({**config, "kda_safe_gate": False}, False)
+    tied, _ = arch.model({**config, "agreement": {**config["agreement"], "routing_tie": 0.03}},
+                         False)
+    assert tied.routing_tie == 0.03 and described.routing_tie == config["agreement"]["routing_tie"]
+
+
+@pytest.mark.parametrize("fault", ["none", "jitter_inside_the_tie", "bias_left_out_of_the_choice",
+                                   "top_k_without_groups"])
+def test_ling_s_reference_follows_a_near_tie_and_catches_a_wrong_gate(fault):
+    """The tie rule at the file's rehearsal sizes, in float32: the walk goes
+    through delta-rule, latent and dense layers alike.  A program whose gate
+    sees scores off by LESS than the tie serves tokens the reference accepts
+    at every position it decides; one that leaves the bias out of the choice,
+    or takes the top k without the groups, serves tokens no admitted routing
+    gives, and fails.  (The bias added to the WEIGHTS moves a held expert's
+    weight by a few per cent and no token at this size: tests/test_kda_mla_moe.py
+    holds that control in the logits at 1e-4.)"""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.reference import agreement
+    from calfkit_tpu.inference import model as program
+    from calfkit_tpu.inference import moe
+    from calfkit_tpu.inference.config import RuntimeConfig
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+    from calfkit_tpu.inference.sharding import make_mesh
+
+    arch = M.load_architecture("bailing-kda-mla-moe")
+    toy, _ = arch.model(config_file(LING), True)
+    toy = dataclasses.replace(toy, dtype="float32", agreement_new_tokens=24, routing_tie=0.004,
+                              agreement_margin=0.25)
+    params = arch.params(toy, RuntimeConfig(), make_mesh(tp=1, dp=1, devices=jax.devices()[:1]), 5)
+    assert 0.9 < float(jnp.std(params["embed"])) < 1.1  # the token's own row at unit scale
+    assert float(jnp.abs(params["layers"]["moe"]["router_bias"]).mean()) > 0.01  # NOT zero
+    right, right_groups = moe.route, moe.kept_groups
+
+    def jitter(h, lp, c):  # the choice's scores off by up to 0.0015: under half the tie either way
+        noise = jax.random.uniform(jax.random.key(0), (c.experts_scored,), jnp.float32,
+                                   -0.0015, 0.0015)
+        return right(h, {**lp, "router_bias": lp["router_bias"] + noise}, c)
+
+    def unbiased(h, lp, c):
+        return right(h, {**lp, "router_bias": jnp.zeros_like(lp["router_bias"])}, c)
+
+    if fault == "jitter_inside_the_tie":
+        moe.route = jitter
+    elif fault == "bias_left_out_of_the_choice":
+        moe.route = unbiased
+    elif fault == "top_k_without_groups":
+        moe.kept_groups = lambda pick, c: jnp.ones((pick.shape[0], c.n_group), bool)
+    rng = np.random.default_rng(1)
+    prompts = [[int(t) for t in rng.integers(3, toy.vocab_size, n)] for n in (9, 14, 20, 27)]
+    S = 27 + 24
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (1, S))
+    try:
+        forward = jax.jit(lambda tokens: program.forward(
+            params, toy, tokens, pos, program.make_empty_cache(toy, 1, S),
+            jnp.full((1,), S, jnp.int32), state=make_recurrent_state(toy, 1))[0])
+        outs = []
+        for prompt in prompts:
+            seq = list(prompt)
+            for _ in range(24):  # causal: the padding after a position moves nothing before it
+                tokens = np.zeros((1, S), np.int32)
+                tokens[0, :len(seq)] = seq
+                seq.append(int(np.argmax(np.asarray(forward(jnp.asarray(tokens)))[0, len(seq) - 1])))
+            outs.append(seq[len(prompt):])
+    finally:
+        moe.route, moe.kept_groups = right, right_groups
+    result = agreement(arch.forward_top2, params, toy, prompts, outs, toy.agreement_margin, 8)
+    if fault in ("none", "jitter_inside_the_tie"):
+        assert result["ok"] and result["compared"] >= 24, result
+    else:
+        assert not result["ok"] and result["compared"] - result["equal"] >= 3, result
+
+
+@pytest.mark.parametrize("case,rows,held_chosen", [
+    ("held_group_in_doubt", 2, [[0, 1, 0, 0], [0, 0, 0, 0]]),  # kept or not: two streams
+    ("doubt_between_groups_held_elsewhere", 1, [[0, 0, 0, 0]]),  # the same held experts: one
+    ("no_doubt", 1, [[1, 0, 0, 0]]),
+])
+def test_ling_s_reference_follows_a_near_tie_between_groups(case, rows, held_chosen):
+    """The tie rule one level up (PR 40: a run of the cell was refused at a
+    position where the held group was kept by 0.00066): 4 groups of 4, 2 kept,
+    3 experts a token, this device holding group 1.  A group within the tie of
+    the last one kept opens a stream with the other choice of groups, unless
+    both choices name the same held experts."""
+    import numpy as np
+
+    arch = M.load_architecture("bailing-kda-mla-moe")
+    scored = np.full((1, 16), 0.1, np.float32)
+    scored[0, 0:2] = 0.9, 0.8  # group 0: 1.7, kept in every case
+    if case == "held_group_in_doubt":
+        scored[0, 4:6], scored[0, 8:10] = (0.5, 0.75), (0.7, 0.548)  # 1.25 against 1.248
+    elif case == "doubt_between_groups_held_elsewhere":
+        scored[0, 4:6], scored[0, 8:10], scored[0, 12:14] = (0.4, 0.4), (0.7, 0.6), (0.7, 0.598)
+    else:
+        scored[0, 4:6], scored[0, 8:10] = (0.75, 0.5), (0.7, 0.5)  # 1.25 against 1.2
+    parent, chosen, first, crowded = arch._choices(scored, 3, 0.004, 4, 2, (4, 4))
+    assert list(parent) == [0] * rows and list(first) == [True] + [False] * (rows - 1)
+    assert chosen[:, 4:8].tolist() == held_chosen and not crowded.any()
+    assert (chosen.sum(-1) == 3).all() and chosen[:, :2].all()  # group 0's two lead every choice
+
+
+def test_the_catalog_s_numbers_of_ling_are_the_file_s():
+    """Every number of the published config under its own key, but the three
+    cuts; the nested lists copied whole (the driver holds the file to the
+    catalog the same way)."""
+    config = config_file(LING)
+    published = {
+        "image_patch_token": 157157, "video_patch_token": 156909, "image_start_token": 157158,
+        "video_start_token": 157160, "hidden_size": 2560, "intermediate_size": 6144,
+        "first_k_dense_replace": 2, "max_position_embeddings": 131072,
+        "moe_intermediate_size": 768, "num_experts_per_tok": 8, "num_attention_heads": 32,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "num_key_value_heads": 32, "rope_theta": 6000000, "rms_norm_eps": 1e-06, "head_dim": 128,
+        "partial_rotary_factor": 0.5, "routed_scaling_factor": 2.5, "n_group": 8, "topk_group": 4,
+        "moe_shared_expert_intermediate_size": 768, "layer_group_size": 6,
+        "num_kv_heads_for_linear_attn": 0, "group_norm_size": 1, "rotary_dim": 64,
+        "short_conv_kernel_size": 4, "kda_lower_bound": -5,
+    }
+    assert {k: config[k] for k in published} == published
+    assert config["q_lora_rank"] is None and config["kda_safe_gate"] is True
+    assert len(config["expert_swiglu_limit_list"]) == 42 == len(
+        config["share_expert_swiglu_limit_list"])
+    assert config["expert_swiglu_limit_list"][35:] == [4] * 7
+    assert config["share_expert_swiglu_limit_list"][34:] == [5] * 6 + [7] * 2
+    assert (config["num_hidden_layers"], config["num_experts"], config["vocab_size"]) == (
+        7, 64, 19648)
+
+
+def test_the_gdn_and_moe_readers_take_ling_s_architecture_as_they_stand():
+    """``gdn_device_pct``, ``gdn_state_roofline`` and the three ``moe_*`` readers read
+    the new cell through the scopes and counters' names, unedited; the new
+    ``gdn_chunk_roofline`` reads the chunk form's share; each under 100%;
+    nothing without a trace, and nothing from a program without the scopes."""
+    from types import SimpleNamespace
+
+    read = {n: M.load_reader(n) for n in (
+        "gdn_device_pct", "gdn_state_roofline", "gdn_chunk_roofline", "moe_device_pct",
+        "moe_expert_roofline", "moe_expert_load_ratio", "ssm_state_roofline")}
+    arch, config = M.load_architecture("bailing-kda-mla-moe"), config_file(LING)
+    steps, rows = 40, 100
+    by_scope = {
+        "decode_loop/gdn/state": 0.40, "decode_loop/gdn/conv": 0.02, "decode_loop/gdn/decay": 0.03,
+        "decode_loop/gdn/in_proj": 0.05, "chunk_loop/gdn/state": 0.10,
+        "chunk_loop/gdn/decay": 0.01, "decode_loop/mlp/moe/experts": 0.5,
+        "decode_loop/mlp/moe/router/groups": 0.02, "decode_loop/mla/attention": 0.03,
+        "chunk_loop/mlp/moe/experts": 0.1}
+    run = SimpleNamespace(
+        trace_reduced={"busy_s": 2.0, "by_scope": by_scope, "own_by_op": {}},
+        trace_counters={"decode_tokens": rows * steps, "decode_dispatches": 5,
+                        "short_dispatches": 0, "moe_experts_hit": 50 * 6 * steps,
+                        "prefill_tokens": 20_000},
+        counters={"window": {"moe_expert_tokens_max": 300, "moe_expert_tokens_mean": 100.0}},
+        arch=arch, config=config, chips=1,
+        model_config=SimpleNamespace(n_moe_layers=6, n_layers=7),
+        runtime=SimpleNamespace(decode_steps_per_dispatch=8, page_size=64),
+        peaks=M.load_peaks("TPU v5 lite"))
+    assert read["gdn_device_pct"](run) == pytest.approx(100 * 0.61 / 2.0)
+    state = 2 * rows * 13_025_280 / 819e9  # bytes bound
+    assert read["gdn_state_roofline"](run) == pytest.approx(100 * state * steps / 0.42)
+    assert 0 < read["gdn_state_roofline"](run) < 100
+    least = arch.recurrent_chunk(config, 20_000)["flops"] / 197e12
+    assert read["gdn_chunk_roofline"](run) == pytest.approx(100 * least / 0.10)
+    assert 0 < read["gdn_chunk_roofline"](run) < 100
+    assert read["moe_device_pct"](run) == pytest.approx(100 * 0.62 / 2.0)
+    assert read["moe_expert_load_ratio"](run) == pytest.approx(3.0)
+    # the grouped decode products run in the compiler's own kernel, which keeps no scope:
+    # moe_device_pct adds it by its name, moe_expert_roofline cannot (not listed for the cell)
+    grouped = SimpleNamespace(**{**vars(run), "trace_reduced": {
+        **run.trace_reduced, "own_by_op": {"(unscoped) ragged-dot-none": 0.4}}})
+    assert read["moe_device_pct"](grouped) == pytest.approx(100 * 1.02 / 2.0)
+    assert read["moe_expert_roofline"](grouped) == read["moe_expert_roofline"](run)
+    assert read["ssm_state_roofline"](run) is None  # granite's own finds nothing of its
+    # the parent's side and every other cell: no chunk scope, no prompt tokens, no trace,
+    # an architecture without the count
+    for missing in (
+        {"trace_reduced": {"busy_s": 2.0, "by_scope": {"decode_loop/gdn/state": 0.4}}},
+        {"trace_counters": {**run.trace_counters, "prefill_tokens": 0}},
+        {"trace_reduced": None}, {"trace_counters": None},
+        {"arch": M.load_architecture("qwen3-next-gdn-moe")},
+    ):
+        assert read["gdn_chunk_roofline"](SimpleNamespace(**{**vars(run), **missing})) is None
+    untraced = SimpleNamespace(**{**vars(run), "trace_reduced": None, "trace_counters": None})
+    assert all(read[n](untraced) is None for n in (
+        "gdn_device_pct", "gdn_state_roofline", "gdn_chunk_roofline"))
+
+
+def test_the_manifest_carries_ling_s_cell_and_its_one_metric():
+    cell = M.resolve_cell(MANIFEST, LING_CELL, M.ROOT)
+    assert cell.chips == 1 and cell.params == {"callers": 128}
+    assert cell.traffic_name == "reason-closed" and cell.traffic["loop"] == "closed"
+    law = cell.traffic["prompt_tokens"]
+    assert (law["law"], law["median"], law["sigma"], law["min"], law["max"]) == (
+        "lognormal", 512, 0.6, 128, 1024)
+    assert cell.traffic["output_tokens"] == {
+        "law": "choice", "values": [768, 1024, 1536], "weights": [0.3, 0.4, 0.3]}
+    assert cell.traffic["sharing"] == "none"
+    assert {m.name for m in cell.end_to_end} >= {"tpot_p95_ms", "setup_s"}
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == "gdn_chunk_roofline")
+    assert entry == {"name": "gdn_chunk_roofline", "unit": "%", "better": "higher",
+                     "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
+                     "workloads": [LING_CELL]}
+    assert MANIFEST["per_layer"][-1] is entry  # appended
+    registered = {m.name for m in cell.per_layer}
+    assert {"gdn_chunk_roofline", "gdn_device_pct", "gdn_state_roofline", "moe_device_pct",
+            "moe_expert_load_ratio", "dispatch_roofline", "dispatch_step_ms"} <= registered
+    # mla_cache_roofline's reader would count one latent layer seven times over, and
+    # moe_expert_roofline's sums the seconds under decode_loop/.../moe, where this cell's
+    # decode products (the compiler's ragged-dot kernel) carry NO scope: it read 179% on the
+    # chip (PERF.md sections 6 and 7; moe_device_pct adds the unscoped kernel and reads right)
+    assert not {"mla_cache_roofline", "moe_expert_roofline"} & registered
+    for listed in ("gdn_device_pct", "gdn_state_roofline", "moe_device_pct",
+                   "moe_expert_load_ratio"):
+        metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
+        assert metric["workloads"][-1] == LING_CELL, listed
+    assert MANIFEST["workloads"][-1]["name"] == LING_CELL and len(MANIFEST["workloads"]) == 6
+    config = MANIFEST["configs"][-1]
+    assert config["name"] == LING and len(MANIFEST["configs"]) == 6
+    assert config["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
+    assert len(MANIFEST["workloads"][-1]["why"]) <= 200 and len(config["why"]) <= 200

@@ -209,7 +209,11 @@ def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen()
 def test_the_dense_form_s_limit_follows_the_shape_and_kimi_s_has_not_moved(monkeypatch):
     monkeypatch.undo()  # the measured limits, not the toy one
     kimi = preset("kimi-vl-a3b-instruct")
-    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536}  # timed AND compiled: Kimi's
+    # timed AND compiled: Kimi's; and (PR 40) a shape whose dense decode program copied both
+    # expert stacks whole when compiled for the v5e: no product of it is dense
+    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
+    ling = replace(preset("ling-3.0-flash-vl"), n_routed_experts=64, n_experts_total=512)
+    assert not moe.dense_form(1, ling) and not moe.dense_form(128, ling)
     assert moe.dense_form(1536, kimi) and not moe.dense_form(1537, kimi)
     # any other shape, this model's share or another, keeps the dense form to
     # the decode steps' rows and narrow chunks: no chunk of 1,024 beside them

@@ -557,7 +557,8 @@ def test_the_paged_decode_kernel_is_not_for_a_latent_pool(latent, page, built):
 @pytest.mark.parametrize("fields, message", [
     ({"kv_lora_rank": 0}, "latent-attention stack"),
     ({"scoring_func": "softmax"}, "sigmoid"),
-    ({"n_group": 2, "topk_group": 2}, "group-limited"),
+    # groups are described since PR 40 (tests/test_kda_mla_moe.py); ones that do not fit are not
+    ({"n_group": 3, "topk_group": 2}, "group-limited"),
     ({"layer_types": ("attention", "mamba", "mamba")}, "hybrid"),
     ({"qk_rope_head_dim": 0}, "qk_nope_head_dim/qk_rope_head_dim"),
     ({"first_k_dense": 3}, "at least one expert layer"),

@@ -1040,3 +1040,136 @@ def test_window_cell_programs_at_full_size_copy_no_expert_stack(one_chip, no_per
                    for line in text.splitlines())
     assert not _made_in_loops(text, ("bf16[16,4096,4096]",))
     print("temporaries, bytes:", report)
+
+
+# Kimi Delta Attention beside latent attention, a dense leading layer, the
+# experts chosen by group and held by share (ling-3.0-flash-vl): the latent
+# decode read in the period's ONE latent layer, the channel-decay state's pass
+# as XLA in place, the two-level chunk form, the expert stacks read where they lie
+# ---------------------------------------------------------------------------
+
+
+def _kda_cell_engine(held: int | None = None, slots: int | None = None):
+    """The engine of the cell's configuration at its published WIDTHS, its 7
+    layers and its runtime; ``held`` experts of the 512 and ``slots`` where
+    the test has no use for all 64 and all 128."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "ling-3.0-flash-vl.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    assert config.layer_types == ("kda",) * 6 + ("attention",)
+    assert config.stack_plan == (1, ("kda",) * 5 + ("attention",))
+    if held is not None:
+        config = replace(config, n_routed_experts=held)
+    if slots is not None:
+        runtime = replace(runtime, max_batch_size=slots, num_kv_pages=slots * 64 + 1)
+    engine = InferenceEngine(
+        config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "xla")
+    return engine
+
+
+def _kda_programs(engine, one_chip, rows_of_waves):
+    """The decode dispatch and the ragged programs (a wave of each of
+    ``rows_of_waves`` rows of one chunk), compiled for the described v5e ->
+    {name: compiled}."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    args, window, steps, sampled = engine._decode_args()
+    zero = engine._moe_zero
+    out = {"decode": engine._decode_jit(window, steps, sampled).lower(
+        *abstract(args), state=abstract(engine._state), moe=abstract(zero)).compile()}
+    chunk = rt.prefill_chunk
+    for rows in rows_of_waves:
+        scratch = [jax.ShapeDtypeStruct(
+            (cfg.n_kv_layers, rows, cfg.cache_heads, 2 * chunk, width), engine._k.dtype)
+            for width in cfg.cache_dims]
+        wave = [*scratch, jax.ShapeDtypeStruct((rows, chunk), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32)]
+        out[f"ragged x{rows}"] = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
+            *abstract((*args, *wave)), state=abstract(engine._state),
+            wstate=abstract(jax.eval_shape(lambda: make_recurrent_state(cfg, rows))),
+            true_lens=abstract(jax.ShapeDtypeStruct((rows,), jnp.int32)),
+            moe=abstract(zero), wmoe=abstract(zero)).compile()
+    return out
+
+
+def _kda_checks(engine, name, compiled):
+    """ONE Pallas kernel, the latent decode read of the one latent layer,
+    under ``decode_loop/.../mla/attention``; no window gathered in the decode
+    loop; the state's pass under ``gdn/state`` and the decay's under
+    ``gdn/decay``; the groups under ``moe/router/groups``; NO copy of an
+    expert stack; the stacked state and the pool go out where they came in."""
+    import re
+
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line and "pallas_call" in line]
+    assert len(kernels) == 1 and "latent_decode" in kernels[0], kernels
+    assert "/mla/" in kernels[0] and "decode_loop/" in kernels[0]
+    for scope in ("/gdn/state/", "/gdn/decay/", "/moe/router/groups", "/mla/kv_latent",
+                  "/mlp/moe/experts"):
+        assert scope in hlo, scope
+    cfg, rt = engine.config, engine.runtime
+    E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
+    stacks = re.compile(
+        rf"= bf16\[(?:{cfg.n_moe_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
+    assert not stacks.search(hlo), name
+    memory = compiled.memory_analysis()
+    state_bytes = cfg.recurrent_state_bytes(rt.max_batch_size)
+    pool_bytes = engine._k.nbytes + engine._v.nbytes
+    assert memory.alias_size_in_bytes >= state_bytes + pool_bytes
+    if name.startswith("ragged"):  # the chunk: grouped experts, the two-level delta rule
+        assert "chunk_loop/" in hlo and "ragged-dot" in hlo and "chunk_loop/" in hlo
+    return memory
+
+
+def test_kda_expert_cell_decode_program_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The decode dispatch of the new cell at its published widths and 7
+    layers, 8 of the 64 held experts and 16 of the 128 slots (the products'
+    shapes but not 4.5 GB of experts and 1.7 GB of state), compiled for the
+    described v5e (the ragged programs: the full-size test below)."""
+    engine = _kda_cell_engine(held=8, slots=16)
+    for name, compiled in _kda_programs(engine, one_chip, ()).items():
+        memory = _kda_checks(engine, name, compiled)
+        print("temporaries, bytes:", name, memory.temp_size_in_bytes)
+
+
+@pytest.mark.slow  # 5.6 GB of weights and four whole-program compiles on every core: the offline
+# lane runs it, as it runs the other expert cells'; PERF.md section 6, PR 40 has its readings
+def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persistent_cache):
+    """The decode dispatch and the ragged programs (a wave of 1, 2 and 4 rows
+    of 1,024) of the new cell at its FULL size: all 64 held experts of 6
+    layers, 128 slots.  No expert stack is copied, the decode step's
+    temporaries stay under two layers' state, and arguments and temporaries
+    together leave the 16 GB chip 3 GB of room."""
+    from calfkit_tpu.inference import moe
+
+    engine = _kda_cell_engine()
+    cfg, rt = engine.config, engine.runtime
+    # this shape's dense form copied both expert stacks whole in the DECODE program
+    # (2 x 1.51 GB, 7.4 GB of temporaries): every product of it is grouped (moe.py)
+    assert not moe.dense_form(rt.prefill_chunk, cfg) and not moe.dense_form(1, cfg)
+    report = {}
+    for name, compiled in _kda_programs(engine, one_chip, (1, 2, 4)).items():
+        memory = _kda_checks(engine, name, compiled)
+        report[name] = (memory.temp_size_in_bytes, memory.argument_size_in_bytes)
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9, (name, report)
+    per_layer = cfg.recurrent_state_bytes(rt.max_batch_size) // cfg.n_recurrent_layers
+    assert report["decode"][0] < 2 * per_layer + engine._k.nbytes + engine._v.nbytes, report
+    print("temporaries and arguments, bytes:", report)
