@@ -31,6 +31,7 @@ import os
 import ssl as ssl_module
 import struct
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Any, Awaitable, Callable, Mapping
 
@@ -38,6 +39,7 @@ from calfkit_tpu.mesh.connection import DEFAULT_MAX_MESSAGE_BYTES
 from calfkit_tpu.protocol import header_map as protocol_header_map
 from calfkit_tpu.mesh.dispatch import KeyOrderedDispatcher
 from calfkit_tpu.mesh.tables import TableReader, TableWriter
+from calfkit_tpu.observability.metrics import REGISTRY
 from calfkit_tpu.mesh.transport import (
     CallbackSubscription,
     MeshTransport,
@@ -47,6 +49,18 @@ from calfkit_tpu.mesh.transport import (
 )
 
 logger = logging.getLogger(__name__)
+
+# how often the producer's grouping engages: records ÷ requests is 1.0 on an
+# idle mesh and tens where the publishers outrun a round trip
+_PRODUCE_REQUESTS = REGISTRY.counter(
+    "calfkit_mesh_produce_requests_total",
+    "Produce requests the wire mesh's producers sent",
+)
+_PRODUCE_RECORDS = REGISTRY.counter(
+    "calfkit_mesh_produce_records_total",
+    "records those Produce requests carried",
+)
+
 
 def find_kafkad() -> str | None:
     """Locate the in-repo native broker binary ($CALFKIT_KAFKAD overrides)."""
@@ -774,6 +788,36 @@ ERR_NOT_LEADER = 6
 ERR_NOT_COORDINATOR = 16
 
 
+# one Produce request carries at most this much: half of kafkad's 64 MiB
+# frame limit, a third of a real broker's socket.request.max.bytes
+_PRODUCE_REQUEST_BYTES = 32 * 1024 * 1024
+
+
+class _Pending:
+    """One produce waiting for the request that will carry it.  ``payload``
+    is a record ``(key, value, headers)``, or a RecordBatch its caller
+    encoded, which rides alone in its partition."""
+
+    __slots__ = ("topic", "part", "payload", "size", "room", "future",
+                 "retried")
+
+    def __init__(self, topic: str, part: int, payload, size: int, room: int):
+        self.topic, self.part = topic, part
+        self.payload = payload
+        self.size = size
+        self.room = room
+        self.future: asyncio.Future = asyncio.get_running_loop().create_future()
+        self.retried = False
+
+    def fail(self, error: BaseException) -> None:
+        if not self.future.done():  # done: its publisher was cancelled
+            self.future.set_exception(error)
+
+
+def _closed_error() -> RuntimeError:
+    return RuntimeError("kafka-wire client closed before the broker's ack")
+
+
 class KafkaWireClient:
     """Typed API calls with metadata-driven per-partition leader routing.
 
@@ -784,7 +828,15 @@ class KafkaWireClient:
     route resolves to the bootstrap address and behavior is unchanged;
     against a spread-leader cluster each request lands on the right
     broker, with NOT_LEADER / NOT_COORDINATOR triggering a refresh +
-    single retry."""
+    single retry.
+
+    Produce groups what is pending: while a Produce request to a leader is
+    in flight, what is produced for that leader queues, and when the
+    response comes ALL of it goes in one request (a partition's records in
+    one RecordBatch), and so on until nothing is pending.  A produce that
+    finds the connection idle goes at once, alone; there is no linger and
+    no batch size to set.  The request in flight belongs to the sender
+    task, so a cancelled producer cancels its own wait alone."""
 
     def __init__(self, host: str, port: int, client_id: str = "calfkit",
                  security: WireSecurity = PLAINTEXT):
@@ -795,6 +847,13 @@ class KafkaWireClient:
         # routing state, refreshed from Metadata / FindCoordinator
         self._leaders: dict[tuple[str, int], tuple[str, int]] = {}
         self._coordinator: tuple[str, int] | None = None
+        # produce: what waits for each leader connection's next request, and
+        # the task that sends it (there only while something waits)
+        self._pending: dict[_Conn, deque[_Pending]] = {}
+        self._senders: dict[_Conn, asyncio.Task] = {}
+        self._closed = False
+        self.produce_requests = 0
+        self.produce_records = 0
 
     def _get_conn(self, host: str, port: int) -> _Conn:
         conn = self._conns.get((host, port))
@@ -814,6 +873,16 @@ class KafkaWireClient:
         )
 
     async def close(self) -> None:
+        # what still waits for an ack fails in its producer; nothing hangs
+        self._closed = True
+        senders, self._senders = self._senders, {}
+        for sender in senders.values():
+            sender.cancel()
+        await asyncio.gather(*senders.values(), return_exceptions=True)
+        pending, self._pending = self._pending, {}
+        for queue in pending.values():
+            for entry in queue:
+                entry.fail(_closed_error())
         for conn in self._conns.values():
             await conn.close()
 
@@ -892,44 +961,184 @@ class KafkaWireClient:
     async def produce(
         self, topic: str, partition: int, batch: bytes
     ) -> int:
+        """One RecordBatch the caller encoded → its base offset."""
+        return await self._produce(
+            _Pending(topic, partition, batch, len(batch), 0)
+        )
+
+    async def produce_record(
+        self,
+        topic: str,
+        partition: int,
+        record: "tuple[bytes | None, bytes | None, list[tuple[str, bytes]]]",
+        size: int,
+        max_batch_bytes: int,
+    ) -> int:
+        """One record ``(key, value, headers)`` of ``size`` payload bytes →
+        its offset, once the broker acknowledged (acks=all) the request that
+        carried it.  Records of one partition reach the log in call order;
+        those that wait for one request share a RecordBatch of at most
+        ``max_batch_bytes`` (a first record of any size goes alone)."""
+        return await self._produce(
+            _Pending(topic, partition, record, size, max_batch_bytes)
+        )
+
+    async def _produce(self, entry: _Pending) -> int:
+        if self._closed:
+            raise _closed_error()
+        self._enqueue(entry)
+        return await entry.future
+
+    def _enqueue(self, entry: _Pending, *, front: bool = False) -> None:
+        conn = self._leader_conn(entry.topic, entry.part)
+        queue = self._pending.setdefault(conn, deque())
+        if front:
+            queue.appendleft(entry)
+        else:
+            queue.append(entry)
+        if conn not in self._senders:
+            self._senders[conn] = asyncio.get_running_loop().create_task(
+                self._send_pending(conn, queue)
+            )
+
+    async def _send_pending(self, conn: _Conn, queue: "deque[_Pending]") -> None:
+        """Request after request until nothing waits for ``conn``."""
+        held: list[_Pending] = []
+        try:
+            while queue:
+                groups = self._take(queue)
+                held = [e for entries in groups.values() for e in entries]
+                if held:
+                    await self._send(conn, groups)
+                held = []
+        finally:
+            # records are held here only when close() cancelled the request
+            for entry in held:
+                entry.fail(_closed_error())
+            if self._senders.get(conn) is asyncio.current_task():
+                del self._senders[conn]
+
+    @staticmethod
+    def _take(
+        queue: "deque[_Pending]",
+    ) -> "dict[tuple[str, int], list[_Pending]]":
+        """What the next request carries: a partition's records in the order
+        they came, as far as the first one's room goes.  What does not fit
+        stays queued, and everything of its partition behind it."""
+        groups: dict[tuple[str, int], list[_Pending]] = {}
+        room: dict[tuple[str, int], int] = {}
+        full: set[tuple[str, int]] = set()
+        kept: list[_Pending] = []
+        budget = _PRODUCE_REQUEST_BYTES
+        while queue:
+            entry = queue.popleft()
+            if entry.future.done():
+                continue  # cancelled while it waited: never sent
+            tp = (entry.topic, entry.part)
+            first = tp not in groups
+            encoded = isinstance(entry.payload, bytes)
+            if tp in full or (groups and entry.size > budget) or (
+                not first and (encoded or entry.size > room[tp])
+            ):
+                full.add(tp)
+                kept.append(entry)
+                continue
+            if first:
+                groups[tp] = [entry]
+                room[tp] = entry.room - entry.size
+                if encoded:
+                    full.add(tp)
+            else:
+                groups[tp].append(entry)
+                room[tp] -= entry.size
+            budget -= entry.size
+        queue.extend(kept)
+        return groups
+
+    async def _send(
+        self, conn: _Conn, groups: "dict[tuple[str, int], list[_Pending]]"
+    ) -> None:
+        """One Produce request; every record's producer gets its own
+        partition's result.  NOT_LEADER, or a leader connection that died:
+        re-learn the topology and send those records once more."""
+        lost: Exception | None = None
+        results: dict[tuple[str, int], tuple[int, int]] = {}
+        try:
+            results = await self._produce_request(conn, groups)
+        except (OSError, EOFError) as e:
+            # EOFError covers the clean-close IncompleteReadError signature
+            lost = e
+        except Exception as e:  # noqa: BLE001 - a reply that cannot be read
+            for entries in groups.values():
+                for entry in entries:
+                    entry.fail(e)
+            return
+        retry: list[_Pending] = []
+        for tp, entries in groups.items():
+            err, base = results.get(tp, (-1, -1))
+            again = conn is not self.conn if lost else err == ERR_NOT_LEADER
+            for i, entry in enumerate(entries):
+                if not lost and not err:
+                    if not entry.future.done():
+                        entry.future.set_result(base + i)
+                elif again and not entry.retried:
+                    entry.retried = True
+                    retry.append(entry)
+                else:
+                    entry.fail(lost or KafkaWireError("produce", err))
+        if retry:
+            await self._refresh_leaders([entry.topic for entry in retry])
+            for entry in reversed(retry):  # ahead of what came after them
+                self._enqueue(entry, front=True)
+
+    async def _produce_request(
+        self, conn: _Conn, groups: "dict[tuple[str, int], list[_Pending]]"
+    ) -> "dict[tuple[str, int], tuple[int, int]]":
+        """→ {(topic, partition): (error, base offset)}"""
+        now_ms = int(time.time() * 1000)
+        by_topic: dict[str, list[tuple[int, bytes]]] = {}
+        for (topic, part), entries in groups.items():
+            batch = entries[0].payload
+            if not isinstance(batch, bytes):
+                records = [entry.payload for entry in entries]
+                if max(entry.size for entry in entries) > 65536:
+                    # the pure-Python crc32c over a multi-MiB payload would
+                    # stall the event loop (heartbeats, fetch long-polls);
+                    # encode a batch with a big record on a worker thread (many
+                    # small ones stay here: their cost is Python, and a thread
+                    # would only take the interpreter lock from the loop)
+                    batch = await asyncio.to_thread(
+                        encode_record_batch, records, now_ms
+                    )
+                else:
+                    batch = encode_record_batch(records, now_ms)
+            by_topic.setdefault(topic, []).append((part, batch))
         w = _W()
         w.string(None)  # transactional_id
         w.i16(-1)       # acks=all
         w.i32(10000)
-        w.i32(1)
-        w.string(topic)
-        w.i32(1)
-        w.i32(partition)
-        w.bytes_(batch)
-        body = w.done()
-        for attempt in (0, 1):
-            conn = self._leader_conn(topic, partition)
-            try:
-                r = await conn.request(0, 3, body)
-            except (OSError, EOFError):
-                # leader connection died (EOFError covers the clean-close
-                # IncompleteReadError signature): re-learn topology once
-                if attempt == 0 and conn is not self.conn:
-                    await self._refresh_leaders([topic])
-                    continue
-                raise
-            base = -1
-            err = 0
+        w.i32(len(by_topic))
+        for topic, parts in by_topic.items():
+            w.string(topic)
+            w.i32(len(parts))
+            for part, batch in parts:
+                w.i32(part)
+                w.bytes_(batch)
+        carried = sum(len(entries) for entries in groups.values())
+        self.produce_requests += 1
+        self.produce_records += carried
+        _PRODUCE_REQUESTS.inc()
+        _PRODUCE_RECORDS.inc(carried)
+        r = await conn.request(0, 3, w.done())
+        results = {}
+        for _ in range(r.i32()):
+            topic = r.string()
             for _ in range(r.i32()):
-                r.string()
-                for _ in range(r.i32()):
-                    r.i32()  # partition
-                    err = r.i16()
-                    base = r.i64()
-                    r.i64()  # log_append_time
-            if err == ERR_NOT_LEADER and attempt == 0:
-                await self._refresh_leaders([topic])
-                continue
-            if err:
-                raise KafkaWireError("produce", err)
-            return base
-        # unreachable: attempt 1 always returned or raised above
-        raise AssertionError("produce retry loop exhausted")
+                part = r.i32()
+                err = r.i16()
+                results[(topic, part)] = (err, r.i64())
+                r.i64()  # log_append_time
+        return results
 
     async def _fetch_on(
         self, conn: _Conn, wants: "list[tuple[str, int, int]]",
@@ -1724,6 +1933,16 @@ class KafkaWireMesh(MeshTransport):
     def profile(self):
         return self._profile
 
+    @property
+    def produce_requests(self) -> int:
+        """Produce requests this mesh's producer sent; ``produce_records``
+        over it is how many records shared a request (1.0: never)."""
+        return self._producer.produce_requests if self._producer else 0
+
+    @property
+    def produce_records(self) -> int:
+        return self._producer.produce_records if self._producer else 0
+
     async def start(self) -> None:
         if self._started:
             return
@@ -1806,29 +2025,25 @@ class KafkaWireMesh(MeshTransport):
                 f"key+headers of {len(key or b'') + header_bytes} bytes "
                 f"exceed the {KEY_HEADERS_CAP}-byte budget"
             )
-        if self._producer is None:
+        producer = self._producer
+        if producer is None:
             raise RuntimeError("mesh not started")
         # no mesh-wide lock: partition choice is synchronous, the metadata
-        # lookup caches after the first call per topic, and _Conn already
-        # serializes the wire — holding a lock across the produce RTT
-        # would cap the whole transport at one in-flight message
+        # lookup caches after the first call per topic, and the producer
+        # groups what arrives while a request is in flight — holding a lock
+        # across the produce RTT would cap the whole transport at one
+        # in-flight message
         n = await self._partitions_of(topic)
         part = partition_for(key, n, self._rr_counter)
-        records = [(
+        record = (
             key, value,
             [(hk, hv.encode("utf-8")) for hk, hv in (headers or {}).items()],
-        )]
-        now_ms = int(time.time() * 1000)
-        if value is not None and len(value) > 65536:
-            # the pure-Python crc32c over a multi-MiB payload would stall
-            # the event loop (heartbeats, fetch long-polls); encode big
-            # batches on a worker thread
-            batch = await asyncio.to_thread(
-                encode_record_batch, records, now_ms
-            )
-        else:
-            batch = encode_record_batch(records, now_ms)
-        await self._producer.produce(topic, part, batch)
+        )
+        await producer.produce_record(
+            topic, part, record,
+            len(key or b"") + len(value or b"") + header_bytes,
+            self._max_bytes,
+        )
 
     # -------------------------------------------------------------- consume
     async def subscribe(

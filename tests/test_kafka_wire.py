@@ -348,6 +348,200 @@ class TestWireBroker:
         await client_mesh.stop()
 
 
+class _Gate:
+    """Holds the producer connection's requests until opened, so that a
+    test decides what is pending while one is in flight."""
+
+    def __init__(self, mesh: KafkaWireMesh):
+        self._conn = mesh._producer.conn
+        self._request = self._conn.request
+        self._open = asyncio.Event()
+        self._conn.request = self._held
+
+    async def _held(self, api_key, version, body):
+        await self._open.wait()
+        return await self._request(api_key, version, body)
+
+    def open(self) -> None:
+        self._open.set()
+        self._conn.request = self._request
+
+
+async def _publish_behind_one_in_flight(mesh, topic: str, values) -> list:
+    """Tasks publishing ``values``: the first's request is in flight (held
+    by a closed gate) when the others are published, so they wait."""
+    tasks = []
+    for value in values:
+        tasks.append(asyncio.ensure_future(mesh.publish(topic, value, key=b"k")))
+        if len(tasks) == 1:
+            await asyncio.sleep(0.01)
+    await asyncio.sleep(0.01)
+    return tasks
+
+
+async def _values(port: int, topic: str, part: int = 0) -> list:
+    client = KafkaWireClient("127.0.0.1", port)
+    [(_t, _p, err, blob)] = await client.fetch([(topic, part, 0)], max_wait_ms=50)
+    await client.close()
+    assert err == 0
+    return [v for _o, _ts, _k, v, _h in decode_record_batches(blob)]
+
+
+async def _grouping_mesh(port: int, topic: str, **kwargs) -> KafkaWireMesh:
+    """A started mesh whose first record is in ``topic`` (its partition
+    count learned), with one partition so that order is one log's."""
+    mesh = KafkaWireMesh(f"127.0.0.1:{port}", default_partitions=1, **kwargs)
+    await mesh.start()
+    await mesh.ensure_topics([topic])
+    await mesh.publish(topic, b"first", key=b"k")
+    return mesh
+
+
+class TestProducerGroups:
+    """What is published while a Produce request is in flight rides the
+    next one together (ISSUE 41): order, acks, limits, cancellation."""
+
+    async def test_a_lone_publish_is_one_request_of_one_record(self, broker_port):
+        from calfkit_tpu.observability.metrics import metrics_text
+
+        def served(name: str) -> int:  # what /metrics serves, process-wide
+            [line] = [
+                line for line in metrics_text().splitlines()
+                if line.startswith(f"calfkit_mesh_produce_{name}_total ")
+            ]
+            return int(line.split()[1])
+
+        before = served("requests"), served("records")
+        mesh = await _grouping_mesh(broker_port, "grp-lone")
+        assert (mesh.produce_requests, mesh.produce_records) == (1, 1)
+        await mesh.publish("grp-lone", b"second", key=b"k")
+        assert (mesh.produce_requests, mesh.produce_records) == (2, 2)
+        assert (served("requests"), served("records")) == (
+            before[0] + 2, before[1] + 2,
+        )
+        await mesh.stop()
+
+    @pytest.mark.parametrize("n", [2, 128])
+    async def test_concurrent_publishes_share_a_request_in_call_order(
+        self, broker_port, n
+    ):
+        topic = f"grp-order-{n}"
+        mesh = await _grouping_mesh(broker_port, topic)
+        acked = []
+
+        async def publish(i: int) -> None:
+            await mesh.publish(topic, b"v%d" % i, key=b"k")
+            # the ack came first: the broker's log already holds the record
+            acked.append(i)
+
+        await asyncio.gather(*(publish(i) for i in range(n)))
+        assert mesh.produce_records == 1 + n
+        assert mesh.produce_requests <= 3  # ONE or two carried all n
+        assert sorted(acked) == list(range(n))
+        assert await _values(broker_port, topic) == (
+            [b"first"] + [b"v%d" % i for i in range(n)]
+        )
+        await mesh.stop()
+
+    async def test_each_partition_of_a_shared_request_gets_its_own_result(
+        self, broker_port
+    ):
+        """Several topics and partitions in ONE request; the partition the
+        broker refuses fails its own producers and nobody else's."""
+        from calfkit_tpu.mesh.kafka_wire import KafkaWireError
+
+        client = KafkaWireClient("127.0.0.1", broker_port)
+        await client.create_topics(["grp-a", "grp-b"], 2)
+        await client.metadata(["grp-a", "grp-b"])
+        wants = [("grp-a", 0), ("grp-a", 1), ("grp-b", 1), ("grp-b", 7),
+                 ("grp-a", 0), ("grp-b", 7)]
+        got = await asyncio.gather(*(
+            client.produce_record(topic, part, (None, b"r%d" % i, []), 2, 1000)
+            for i, (topic, part) in enumerate(wants)
+        ), return_exceptions=True)
+        assert (client.produce_requests, client.produce_records) == (1, 6)
+        assert got[:3] == [0, 0, 0] and got[4] == 1  # offsets: a/0 took two
+        for refused in (got[3], got[5]):  # kafkad: no partition 7
+            assert isinstance(refused, KafkaWireError) and refused.code == 3
+        assert await _values(broker_port, "grp-a", 0) == [b"r0", b"r4"]
+        assert await _values(broker_port, "grp-b", 1) == [b"r2"]
+        await client.close()
+
+    async def test_a_cancelled_publisher_cancels_its_own_wait_alone(
+        self, broker_port
+    ):
+        mesh = await _grouping_mesh(broker_port, "grp-cancel")
+        writer = mesh._producer.conn._writer
+        gate = _Gate(mesh)
+        tasks = await _publish_behind_one_in_flight(
+            mesh, "grp-cancel", (b"in-flight", b"queued", b"c", b"d")
+        )
+        tasks[0].cancel()  # its record is on the wire: it still lands
+        tasks[1].cancel()  # its record never leaves
+        gate.open()
+        await asyncio.gather(*tasks[2:])
+        assert tasks[0].cancelled() and tasks[1].cancelled()
+        await mesh.publish("grp-cancel", b"after", key=b"k")
+        # the request in flight was the sender's: nobody dropped the connection
+        assert mesh._producer.conn._writer is writer
+        assert await _values(broker_port, "grp-cancel") == [
+            b"first", b"in-flight", b"c", b"d", b"after",
+        ]
+        await mesh.stop()
+
+    async def test_a_partitions_batch_stops_at_max_message_bytes(
+        self, broker_port
+    ):
+        mesh = await _grouping_mesh(
+            broker_port, "grp-room", max_message_bytes=1000
+        )
+        gate = _Gate(mesh)
+        values = [bytes([65 + i]) * 400 for i in range(6)]
+        tasks = await _publish_behind_one_in_flight(mesh, "grp-room", values)
+        gate.open()
+        await asyncio.gather(*tasks)
+        # one alone in flight, then 2 + 2 + 1: 401 bytes each, 1,000 a batch
+        assert (mesh.produce_requests, mesh.produce_records) == (1 + 4, 1 + 6)
+        assert await _values(broker_port, "grp-room") == [b"first"] + values
+        with pytest.raises(ValueError):  # the per-record limit stands
+            await mesh.publish("grp-room", b"x" * 1001, key=b"k")
+        await mesh.stop()
+
+    async def test_a_request_stops_at_its_bytes_and_keeps_a_partitions_order(self):
+        """kafkad drops a frame over 64 MiB: a request carries at most half
+        of that, and what it leaves waits IN ORDER behind what it left."""
+        from collections import deque
+
+        from calfkit_tpu.mesh.kafka_wire import _Pending
+
+        mib = 1024 * 1024
+        queue = deque(
+            _Pending("t", part, (None, b"", []), size, 64 * mib)
+            for part, size in ((0, 20 * mib), (1, 20 * mib), (1, 1), (2, 1))
+        )
+        second_of_1 = queue[2]
+        taken = KafkaWireClient._take(queue)
+        assert {tp: [e.size for e in es] for tp, es in taken.items()} == {
+            ("t", 0): [20 * mib], ("t", 2): [1],
+        }
+        assert [e.size for e in queue] == [20 * mib, 1]
+        assert queue[1] is second_of_1
+        assert list(KafkaWireClient._take(queue)) == [("t", 1)] and not queue
+
+    async def test_stop_fails_what_is_still_pending(self, broker_port):
+        mesh = await _grouping_mesh(broker_port, "grp-stop")
+        _Gate(mesh)  # never opened
+        tasks = await _publish_behind_one_in_flight(
+            mesh, "grp-stop", (b"in-flight", b"queued")
+        )
+        await mesh.stop()
+        for outcome in await asyncio.gather(*tasks, return_exceptions=True):
+            assert isinstance(outcome, RuntimeError)
+        assert await _values(broker_port, "grp-stop") == [b"first"]
+        with pytest.raises(RuntimeError):
+            await mesh.publish("grp-stop", b"late", key=b"k")
+
+
 class TestConfig4MultiAgent:
     """BASELINE config 4 over the REAL wire broker: 3 Agent nodes on
     shared topics with parallel tool calls, driven concurrently

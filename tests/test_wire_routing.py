@@ -258,6 +258,31 @@ class TestLeaderRouting:
 
         asyncio.run(run())
 
+    def test_a_record_is_sent_again_once_and_then_fails_its_publisher(self):
+        """The grouped path (``produce_record``) keeps the once-only retry:
+        a move is followed, a second NOT_LEADER is the publisher's error."""
+
+        async def run() -> None:
+            async with _FakeCluster() as cluster:
+                cluster.leaders = {("t", 0): 0}
+                client = KafkaWireClient("127.0.0.1", cluster.brokers[0].port)
+                record = (b"k", b"v", [])
+                try:
+                    await client.metadata(["t"])
+                    cluster.leaders[("t", 0)] = 1  # leadership moves
+                    await client.produce_record("t", 0, record, 2, 1000)
+                    assert len(cluster.brokers[1].produced) == 1
+                    assert client.produce_requests == 2
+                    cluster.leaders[("t", 0)] = 7  # to a broker nobody knows
+                    with pytest.raises(KafkaWireError) as refused:
+                        await client.produce_record("t", 0, record, 2, 1000)
+                    assert refused.value.code == ERR_NOT_LEADER
+                    assert client.produce_requests == 4  # twice, not more
+                finally:
+                    await client.close()
+
+        asyncio.run(run())
+
     def test_unrouted_produce_refreshes_and_succeeds(self):
         async def run() -> None:
             async with _FakeCluster() as cluster:
