@@ -1678,43 +1678,48 @@ class InferenceEngine:
         return "pallas" if impl == "auto" else impl
 
     def _resolved_ssm_impl(self) -> str:
-        """Which implementation a Mamba layer's DECODE STEP uses for its
-        pass over the SSM state: the other computation that has a kernel.
-        Decided HERE, once at construction (``self._ssm_impl``), under the
-        same ``attention_impl`` values as the paged decode read.  Under
-        "auto": the Pallas kernel that updates the state and reads ``y``
-        out of it in one pass, in place, when the backend is a TPU, one
-        device holds the model, the model has Mamba layers and its state is
-        the kernel's (:func:`pallas_ssm.ssm_step_in_place_ok`: float32,
-        ``mamba_d_state`` whole lane tiles, ``mamba_d_head`` whole sublane
-        tiles); else the XLA body of ``mamba.mamba_step``, the reference
-        (PERF.md section 6, PR 30: measured on the v5e).
+        """Which implementation a recurrent layer's DECODE STEP uses for its
+        pass over the per-slot state: the other computation that has a
+        kernel.  Decided HERE, once at construction (``self._ssm_impl``),
+        under the same ``attention_impl`` values as the paged decode read.
+        Under "auto": the Pallas kernel that reads and writes the active
+        rows' state once, in place, when the backend is a TPU, one device
+        holds the model and the state is the kernel's; else the XLA body,
+        the reference (PERF.md section 6: measured on the v5e).
+
+        - Mamba-2 layers: ``pallas_ssm.ssm_step_pallas`` (PR 30: the update
+          and ``y`` out of one pass) where :func:`pallas_ssm.ssm_step_in_place_ok`
+          holds (float32, ``mamba_d_state`` whole lane tiles, ``mamba_d_head``
+          whole sublane tiles); else the XLA body of ``mamba.mamba_step``.
+        - Delta-rule layers (``config.gdn``: Gated DeltaNet and Kimi Delta
+          Attention): ``pallas_gdn.delta_step_pallas`` (PR 42: the reduction
+          ``S^T [k | q]`` and the update on one read of ``S``) where
+          :func:`pallas_gdn.delta_step_in_place_ok` holds (float32, ``gdn_d_v``
+          whole lane tiles, ``gdn_d_k`` whole sublane tiles); else
+          ``gdn.delta_step_xla``.
 
         "pallas" / "pallas_interpret" waive the platform test alone, as
         they do for the read; they NAME the attention kernel, so a state
-        outside the rule is served by XLA and not refused.
-
-        A Gated DeltaNet layer's state (``config.gdn``) is ALWAYS "xla":
-        the kernel computes Mamba-2's step (``S = a S + x (x) B``, ``y = S
-        C``), not the delta rule, whose update needs ``S^T k`` of the
-        decayed state before it can write.  Its pass is
-        ``gdn.delta_step_xla`` under the ``gdn/state`` scope, where a
-        kernel of its own would land."""
+        outside the rule is served by XLA and not refused."""
         impl = self.runtime.attention_impl
         c = self.config
-        if c.gdn:
-            return "xla"
         if not self._recurrent or impl == "xla" or (
             impl == "auto" and jax.devices()[0].platform != "tpu"
         ):
             return "xla"
-        from calfkit_tpu.inference.pallas_ssm import ssm_step_in_place_ok
+        if c.gdn:
+            from calfkit_tpu.inference.pallas_gdn import delta_step_in_place_ok
 
-        in_rule = self.mesh.size == 1 and ssm_step_in_place_ok(
-            c.mamba_n_heads, c.mamba_n_groups, c.mamba_d_head, c.mamba_d_state,
-            c.state_dtype,
-        )
-        if not in_rule:
+            in_rule = delta_step_in_place_ok(
+                c.gdn_n_v_heads, c.gdn_d_k, c.gdn_d_v, c.state_dtype)
+        else:
+            from calfkit_tpu.inference.pallas_ssm import ssm_step_in_place_ok
+
+            in_rule = ssm_step_in_place_ok(
+                c.mamba_n_heads, c.mamba_n_groups, c.mamba_d_head, c.mamba_d_state,
+                c.state_dtype,
+            )
+        if self.mesh.size != 1 or not in_rule:
             return "xla"
         return "pallas" if impl == "auto" else impl
 

@@ -10,11 +10,17 @@ with ``q, k`` L2-normalised per head, ``q`` over ``sqrt(d_k)``, ``beta =
 sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``.
 
 - :func:`gdn_step` is the one-token recurrence of ``decode_loop``, on the
-  rows' carried conv tail and ``S``.  Its pass over ``S`` is ONE read and one
-  write a layer (:func:`delta_step_xla`): ``(exp(g) S)^T [k | q]`` in one
-  reduction, then ``S' = exp(g) S + k (x) delta`` in place, and ``o =
-  (exp(g) S)^T q + (k . q) delta``, which is ``S'^T q`` without reading ``S'``
-  again.  Rows that are not ``active`` keep both states bit for bit.
+  rows' carried conv tail and ``S``.  Its pass over ``S`` is
+  ``(exp(g) S)^T [k | q]`` in one reduction, then ``S' = exp(g) S + k (x)
+  delta`` in place, and ``o = (exp(g) S)^T q + (k . q) delta``, which is
+  ``S'^T q`` without reading ``S'`` again.  :func:`delta_step_xla` is that
+  pass in XLA, the reference and what a CPU runs: the reduction has to see
+  all of a head's ``S`` before the update can write, so XLA reads ``S``
+  twice and writes it, over every slot.  ``pallas_gdn.delta_step_pallas`` is
+  the same arithmetic with a head's tile held in VMEM between the two: ONE
+  read and one write of the ACTIVE rows (chosen by
+  ``InferenceEngine._resolved_ssm_impl``).  Rows that are not ``active``
+  keep both states bit for bit.
 - :func:`gdn_chunk` is the chunkwise form of ``chunk_loop`` and ``prefill``:
   blocks of ``gdn_chunk_size`` positions, inside a block the unit lower
   triangular system ``(I + tril(diag(beta) K K^T . decay, -1)) X = [beta v |
@@ -222,11 +228,14 @@ def gdn_step(
     im: jax.Array,  # which DeltaNet layer this is: its slice of ``state``
     active: jax.Array | None,  # [B] bool; None: every row advances
     config: ModelConfig,
+    ssm_impl: str = "xla",  # InferenceEngine._resolved_ssm_impl: "xla" | "pallas" | "pallas_interpret"
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """One token through the mixer -> (out [B, 1, D], state).  The layer's
     slice of the stacked state is read and rewritten INSIDE the ``conv`` and
     ``state`` scopes: the device time of touching the state has to read
-    under those names, whatever implements the pass."""
+    under those names, whatever implements the pass: :func:`delta_step_xla`,
+    which is the reference, or the kernel that reads and writes the active
+    rows' ``S`` once (``pallas_gdn.delta_step_pallas``)."""
     c = config
     all_S, all_conv = state
     qkv, z, b, a = _in_proj(h[:, 0], lp, c)
@@ -242,7 +251,14 @@ def gdn_step(
             new_conv = jnp.where(active[None, :, None], new_conv, conv)
         all_conv = lax.dynamic_update_index_in_dim(all_conv, new_conv, im, 0)
     with jax.named_scope("state"):
-        o, all_S = delta_step_xla(all_S, im, *_heads(act, b, a, lp, c), active)
+        heads = _heads(act, b, a, lp, c)
+        if ssm_impl.startswith("pallas"):
+            from calfkit_tpu.inference.pallas_gdn import delta_step_pallas
+
+            o, all_S = delta_step_pallas(
+                all_S, im, *heads, active, interpret=ssm_impl == "pallas_interpret")
+        else:
+            o, all_S = delta_step_xla(all_S, im, *heads, active)
     return _gate_out(o, z, lp, c, h.dtype)[:, None], (all_S, all_conv)
 
 

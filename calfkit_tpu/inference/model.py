@@ -1337,7 +1337,7 @@ def _decode_step_with_ring(
     scan_xs: Any,  # extra per-layer scan inputs threaded to attn_source
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     active: jax.Array | None = None,  # hybrid: rows whose state advances
-    ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
+    ssm_impl: str = "xla",  # hybrid: the state's pass (mamba.mamba_step, gdn.gdn_step)
     moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
 ) -> Any:
     """The shared decode-step transformer body (ring-buffer scheme).
@@ -1400,8 +1400,8 @@ def _decode_step_with_ring(
 
         def mamba_layer(carry, h, lp, im):
             ring_k, ring_v, st = carry
-            if config.gdn:  # its pass over the state is XLA (engine._resolved_ssm_impl)
-                y, st = gdn_step(h, lp, st, im, active, config)
+            if config.gdn:
+                y, st = gdn_step(h, lp, st, im, active, config, ssm_impl)
             else:
                 y, st = mamba_step(h, lp, st, im, active, config, ssm_impl)
             return (ring_k, ring_v, st), y
@@ -1947,7 +1947,7 @@ def decode_step_ring_paged(
     attn_impl: str = "xla",
     active: jax.Array | None = None,  # [B] bool; None: every row reads
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
-    ssm_impl: str = "xla",  # hybrid: the SSM state's pass (mamba.mamba_step)
+    ssm_impl: str = "xla",  # hybrid: the state's pass (mamba.mamba_step, gdn.gdn_step)
     moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
 ) -> Any:
     """One decode step reading KV through the block tables.
@@ -1997,7 +1997,7 @@ def decode_step_ring_paged(
     if config.latent:
         return _decode_step_with_ring(
             params, config, tokens, ring, t, base_lens, latent_source, None,
-            state, active, moe=moe,
+            state, active, ssm_impl, moe,
         )
 
     def attn_source(i, q, rk, rv, extra):

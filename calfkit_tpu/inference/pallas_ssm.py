@@ -78,6 +78,16 @@ def ssm_step_in_place_ok(n_heads: int, n_groups: int, d_head: int, d_state: int,
     )
 
 
+def active_rows_first(active: jax.Array | None, rows: int) -> tuple[jax.Array, jax.Array]:
+    """(order [rows] int32, n [1] int32): the ACTIVE rows first, in their
+    order, and how many they are: what a kernel that walks them prefetches
+    (this one and ``pallas_gdn.py``'s).  None: every row."""
+    if active is None:
+        return jnp.arange(rows, dtype=jnp.int32), jnp.full((1,), rows, jnp.int32)
+    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(jnp.int32)
+    return order, jnp.sum(active, dtype=jnp.int32).reshape(1)
+
+
 def _ssm_step_kernel(
     layer_ref, order_ref, n_ref,  # scalar-prefetch (SMEM)
     _state_in,  # the state in HBM: the SAME buffer as ``state`` below
@@ -222,11 +232,7 @@ def ssm_step_pallas(
     piece = max(d for d in range(1, _PIECE_CHUNKS + 1) if chunks % d == 0)
     unroll = max(d for d in range(1, _UNROLL + 1) if piece % d == 0)
     f32 = jnp.float32
-    if active is None:
-        order, n = jnp.arange(B, dtype=jnp.int32), jnp.full((1,), B, jnp.int32)
-    else:  # the active rows first, in their order
-        order = jnp.argsort(jnp.logical_not(active), stable=True).astype(jnp.int32)
-        n = jnp.sum(active, dtype=jnp.int32).reshape(1)
+    order, n = active_rows_first(active, B)
     in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     slots = 2 * _READS_AHEAD

@@ -145,6 +145,44 @@ def serve(engine_args: tuple, requests, sequential: bool = True, params=None):
     return asyncio.run(run())
 
 
+def served_in_three_phases(spy_of, engine_args: tuple, params, prompt_of):
+    """ONE spied engine for the tests that would each build the same one (its
+    programs compile once): a prompt of 37 with 21 new tokens, then three
+    requests one after another, then two of them at once -> what each phase
+    served, what ``lm_logits`` computed in the first two, the counters as
+    those two left them and the metrics text after the first.  ``spy_of`` is
+    the test module's own ``Spy`` (this model's or the Kimi Delta one's)."""
+    from calfkit_tpu.observability.metrics import metrics_text
+
+    requests = [(prompt_of(21, seed=s), 6) for s in (1, 2, 3)]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "_DENSE_MAX_TOKENS", 8)  # both_forms_at_toy_size, which is a test's
+        spy = spy_of(patch)
+
+        async def run():
+            engine = InferenceEngine(*engine_args, seed=3, params=params)
+            await engine.start()
+            try:
+                async def one(prompt, n):
+                    return [t async for t in engine.generate(prompt, max_new_tokens=n)]
+
+                first = await one(prompt_of(37), 21)
+                marks, counted, text = [len(spy.seen)], [engine.stats.counters()], metrics_text()
+                alone = [await one(p, n) for p, n in requests]
+                marks.append(len(spy.seen))
+                counted.append(engine.stats.counters())
+                together = list(await asyncio.gather(*[one(p, n) for p, n in requests[:2]]))
+                return SimpleNamespace(
+                    first=first, alone=alone, together=together, requests=requests,
+                    params=engine.params, counters=counted, metrics=text,
+                    seen=[SimpleNamespace(seen=spy.seen[a:b])
+                          for a, b in zip([0] + marks, marks)])
+            finally:
+                await engine.stop()
+
+        return asyncio.run(run())  # the patches are undone: no other test's engine is spied
+
+
 def reference_logits(params, config: ModelConfig, seq: list[int]) -> np.ndarray:
     tokens = np.asarray([seq], np.int32)
     return ARCH.forward_logits(params, config, tokens, np.asarray([len(seq)], np.int32))[0]

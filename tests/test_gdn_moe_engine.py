@@ -31,8 +31,14 @@ from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
 from tests._gdn_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
     ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, forward, prompt_of, reference_logits,
-    runtime, seeded, serve,
+    runtime, seeded, serve, served_in_three_phases,
 )
+
+
+@pytest.fixture(scope="module")
+def one_engine():
+    """``(TOY, runtime())`` served once for the tests that would each build it."""
+    return served_in_three_phases(Spy, (TOY, runtime()), seeded(TOY), prompt_of)
 
 
 # ------------------------------------------------ (b) the program against the reference
@@ -61,16 +67,15 @@ def test_full_forward_agrees_with_the_reference(monkeypatch, form):
     assert 0.3 < int(counts.sum()) / ((40 + 27) * 3 * 8) < 0.7  # about half are held here
 
 
-def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch):
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(one_engine):
     """Pages of 8, chunks of 16 under a prompt of 37 (a padded tail), blocks
     of 8; 21 generated tokens cross five dispatches of four steps and two
     windows.  Every generated position's logits (the one-pass step on the
     carried state, the paged read, the dense expert form) against the
     reference's full forward of prompt + output."""
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
-    (out,), params, counters = serve((TOY, runtime()), [(prompt, 21)])
-    got = spy.of_request(prompt, out, 16)
+    spy, prompt = one_engine.seen[0], prompt_of(37)
+    out, params, counters = one_engine.first, one_engine.params, one_engine.counters[0]
+    got = Spy.of_request(spy, prompt, out, 16)
     want = reference_logits(params, TOY, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
@@ -85,7 +90,7 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
 
 
 @pytest.mark.parametrize("form", ["grouped", "dense"])
-def test_every_chunk_of_a_served_prompt_is_counted_by_its_form(monkeypatch, form):
+def test_every_chunk_of_a_served_prompt_is_counted_by_its_form(monkeypatch, request, form):
     """Three chunks of 16 under a prompt of 37: past the limit (8 tokens at
     toy size) each counts as grouped, as every chunk of the Qwen3-Next cell
     does; under a limit of 4,096 as dense.  Counted at enqueue from shapes."""
@@ -93,29 +98,30 @@ def test_every_chunk_of_a_served_prompt_is_counted_by_its_form(monkeypatch, form
 
     if form == "dense":
         monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
-    _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+        _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+        text = metrics_text()
+    else:  # the shared engine's first request is that prompt
+        shared = request.getfixturevalue("one_engine")
+        counters, text = shared.counters[0], shared.metrics
     assert (counters["moe_grouped_chunks"], counters["moe_dense_chunks"]) == (
         (3, 0) if form == "grouped" else (0, 3))
-    text = metrics_text()
     assert "calfkit_engine_moe_grouped_chunks_total" in text
     assert "calfkit_engine_moe_dense_chunks_total" in text
 
 
-def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(monkeypatch):
-    """Three requests one after another through two slots (the third lands
-    in a slot the first left), then two at once: each served as alone."""
-    spy = Spy(monkeypatch)
-    requests = [(prompt_of(21, seed=s), 6) for s in (1, 2, 3)]
-    outs, params, counters = serve((TOY, runtime()), requests)
-    for prompt, out in zip((p for p, _ in requests), outs):
-        got = spy.of_request(prompt, out, 16)
+def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(one_engine):
+    """Three requests one after another through two slots (every one lands
+    in a slot another request left), then two at once: each served as alone."""
+    spy, params = one_engine.seen[1], one_engine.params
+    for prompt, out in zip((p for p, _ in one_engine.requests), one_engine.alone):
+        got = Spy.of_request(spy, prompt, out, 16)
         want = reference_logits(params, TOY, prompt + out)
         assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     # one after another: each wave lands on an engine with no active rows, by a sync of its own
-    assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (3, 0)
-    alone = outs[:2]
-    together, _, _ = serve((TOY, runtime()), requests[:2], sequential=False)
-    assert together == alone
+    before, after = one_engine.counters
+    assert (after["pipeline_drains_wave"] - before["pipeline_drains_wave"],
+            after["wave_landings_deferred"]) == (3, 0)
+    assert one_engine.together == one_engine.alone[:2]
 
 
 def test_single_shot_prefill_serves_the_same_logits(monkeypatch):
@@ -216,22 +222,24 @@ def test_prefix_reuse_is_declined_and_counted():
 
 def test_the_paged_decode_kernel_reads_a_head_of_256_with_8_query_heads_a_kv_head(monkeypatch):
     """The published attention shape (16 query heads over 2 KV heads of 256)
-    is inside the kernel's rule: in interpret mode it serves what XLA
-    serves, and the state's pass stays XLA whatever is asked."""
+    is inside the decode read's rule and a value head of 128 inside the
+    delta step's: in interpret mode BOTH kernels serve what XLA serves."""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     wide = replace(TOY, attn_head_dim=256, n_heads=16, n_kv_heads=2, n_layers=4,
-                   layer_types=TOY.layer_types[:4])
+                   layer_types=TOY.layer_types[:4], gdn_d_v=128)
     params = seeded(wide)
     prompt = prompt_of(29, seed=9)
     (xla,), _, _ = serve((wide, runtime(attention_impl="xla")), [(prompt, 9)], params=params)
-    before = KERNEL_TRACES[("paged_decode", "interpreted")]
+    before = dict(KERNEL_TRACES)
     spy = Spy(monkeypatch)
     engine = InferenceEngine(wide, runtime(attention_impl="pallas_interpret"), params=params)
-    assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "xla")
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "pallas_interpret")
     (out,), _, _ = serve(
         (wide, runtime(attention_impl="pallas_interpret")), [(prompt, 9)], params=params)
-    assert out == xla and KERNEL_TRACES[("paged_decode", "interpreted")] > before
+    assert out == xla
+    for kernel in ("paged_decode", "delta_step"):
+        assert KERNEL_TRACES[(kernel, "interpreted")] > before.get((kernel, "interpreted"), 0)
     got = spy.of_request(prompt, out, 16)
     want = reference_logits(params, wide, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL

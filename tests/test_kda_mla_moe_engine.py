@@ -30,17 +30,24 @@ from tests._kda_mla_moe import (  # noqa: F401 - both_forms_at_toy_size is an au
 )
 
 
-def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch):
+@pytest.fixture(scope="module")
+def one_engine():
+    """``(TOY, runtime())`` served once for the tests that would each build it."""
+    from tests._gdn_moe import served_in_three_phases
+
+    return served_in_three_phases(Spy, (TOY, runtime()), seeded(TOY), prompt_of)
+
+
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(one_engine):
     """Pages of 8, chunks of 16 under a prompt of 37 (a padded tail), blocks
     of 8 in sub-blocks of 4; 21 generated tokens cross five dispatches of
     four steps and two windows.  Every generated position's logits (the
     one-pass step on the carried state, the absorbed read of the latent pool,
     the dense expert form) against the reference's full forward of prompt +
     output; one engine holds a latent pool AND a recurrent state."""
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
-    (out,), params, counters = serve((TOY, runtime()), [(prompt, 21)])
-    got = spy.of_request(prompt, out, 16)
+    spy, prompt = one_engine.seen[0], prompt_of(37)
+    out, params, counters = one_engine.first, one_engine.params, one_engine.counters[0]
+    got = Spy.of_request(spy, prompt, out, 16)
     want = reference_logits(params, TOY, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
@@ -55,33 +62,15 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
     assert counters["latent_cache_bytes"] == 2 * 33 * 8 * 20 * 4 > 0
 
 
-def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(monkeypatch):
-    """Three requests one after another through two slots (the third lands
-    in a slot the first left), then two at once: each served as alone."""
-    spy = Spy(monkeypatch)
-    requests = [(prompt_of(21, seed=s), 6) for s in (1, 2, 3)]
-
-    async def run():  # ONE engine (its programs compile once): one by one, then two at once
-        engine = InferenceEngine(TOY, runtime(), seed=3, params=seeded(TOY))
-        await engine.start()
-        try:
-            async def one(prompt, n):
-                return [t async for t in engine.generate(prompt, max_new_tokens=n)]
-
-            outs = [await one(p, n) for p, n in requests]
-            seen = len(spy.seen)
-            together = list(await asyncio.gather(*[one(p, n) for p, n in requests[:2]]))
-            return outs, together, engine.params, seen
-        finally:
-            await engine.stop()
-
-    outs, together, params, seen = asyncio.run(run())
-    del spy.seen[seen:]  # the logits of the three served alone
-    for prompt, out in zip((p for p, _ in requests), outs):
-        got = spy.of_request(prompt, out, 16)
+def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(one_engine):
+    """Three requests one after another through two slots (every one lands
+    in a slot another request left), then two at once: each served as alone."""
+    spy, params = one_engine.seen[1], one_engine.params  # the logits of the three served alone
+    for prompt, out in zip((p for p, _ in one_engine.requests), one_engine.alone):
+        got = Spy.of_request(spy, prompt, out, 16)
         want = reference_logits(params, TOY, prompt + out)
         assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
-    assert together == outs[:2]
+    assert one_engine.together == one_engine.alone[:2]
 
 
 @pytest.mark.slow  # a second lane of the same mixers (30 s: the offline lane runs it)
@@ -227,33 +216,34 @@ def test_what_the_engine_cannot_keep_right_is_refused_with_its_reason(option, re
 
 
 def test_the_latent_decode_kernel_reads_the_hybrid_s_pool(monkeypatch):
-    """A latent of 128 | 64 on pages of 16 is inside the kernel's rule: in
-    interpret mode the hybrid's ONE kind of attention layer serves what XLA
-    serves, and the state's pass stays XLA whatever is asked."""
+    """A latent of 128 | 64 on pages of 16 is inside the latent read's rule
+    and a value head of 128 inside the delta step's: in interpret mode the
+    hybrid's ONE kind of attention layer and the state's pass by key channel
+    serve what XLA serves."""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     wide = replace(TOY, kv_lora_rank=128, qk_rope_head_dim=64, n_layers=3,
-                   layer_types=TOY.layer_types[:3])
+                   layer_types=TOY.layer_types[:3], gdn_d_v=128)
     params = seeded(wide)
     prompt = prompt_of(29, seed=9)
     rt = dict(page_size=16, prefill_chunk=32)
     (xla,), _, _ = serve((wide, runtime(attention_impl="xla", **rt)), [(prompt, 9)],
                          params=params)
-    before = KERNEL_TRACES[("latent_decode", "interpreted")]
+    before = dict(KERNEL_TRACES)
     engine = InferenceEngine(wide, runtime(attention_impl="pallas_interpret", **rt),
                              params=params)
-    assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "xla")
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "pallas_interpret")
     (out,), _, _ = serve((wide, runtime(attention_impl="pallas_interpret", **rt)),
                          [(prompt, 9)], params=params)
-    assert out == xla and KERNEL_TRACES[("latent_decode", "interpreted")] > before
+    assert out == xla
+    for kernel in ("latent_decode", "delta_step"):
+        assert KERNEL_TRACES[(kernel, "interpreted")] > before.get((kernel, "interpreted"), 0)
 
 
-def test_the_new_counter_and_both_gauges_are_in_the_metrics_and_the_catalog():
+def test_the_new_counter_and_both_gauges_are_in_the_metrics_and_the_catalog(one_engine):
     from calfkit_tpu.observability.devtrace import SCOPES
-    from calfkit_tpu.observability.metrics import metrics_text
 
-    serve((TOY, runtime()), [(prompt_of(20), 3)])
-    text = metrics_text()
+    text = one_engine.metrics  # as the shared engine's first request left it
     for name in ("calfkit_engine_moe_rows_in_held_groups_total",
                  "calfkit_engine_moe_assignments_absent_total",
                  "calfkit_engine_recurrent_state_bytes", "calfkit_engine_latent_cache_bytes"):

@@ -483,6 +483,58 @@ def _ssm_step_entry(shape):
     return PS.ssm_step_pallas, lambda *a: PS.ssm_step_pallas(*a), args
 
 
+# the delta-rule cells' state: 6 layers of 32 heads of 128 x 128, (slots, a decay a key channel)
+DELTA_STEP_STATES = {"ling-3.0-flash-vl": (128, True), "qwen3-next-80b-a3b-instruct": (64, False)}
+
+
+def _delta_step_args(shape, rows=128, by_channel=True):
+    import jax.numpy as jnp
+
+    layers, H, dk, dv = 6, 32, 128, 128
+    f32 = jnp.float32
+    return (
+        shape((layers, rows, H, dk, dv), f32), shape((), jnp.int32),
+        shape((rows, H, dk), f32), shape((rows, H, dk), f32), shape((rows, H, dv), f32),
+        shape((rows, H), f32), shape((rows, H, dk) if by_channel else (rows, H), f32),
+        shape((rows,), jnp.bool_),
+    )
+
+
+def _delta_step_entry(shape):
+    from calfkit_tpu.inference import pallas_gdn as PG
+
+    return PG.delta_step_pallas, lambda *a: PG.delta_step_pallas(*a), _delta_step_args(shape)
+
+
+@pytest.mark.parametrize("cell", sorted(DELTA_STEP_STATES))
+def test_delta_step_compiles_for_v5e(cell, one_chip, no_persistent_cache):
+    """The delta step kernel alone at both delta-rule cells' state, the decay
+    by key channel and by head: ONE kernel, the whole stacked state goes out
+    where it came in, and nothing of a layer's size is made beside it."""
+    import jax
+
+    from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference import pallas_gdn as PG
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, by_channel = DELTA_STEP_STATES[cell]
+    args = _delta_step_args(shape, rows, by_channel)
+    before = PA.KERNEL_TRACES["delta_step", "compiled"]
+    PG.delta_step_pallas.clear_cache()
+    compiled = jax.jit(
+        lambda *a: PG.delta_step_pallas(*a), donate_argnums=0).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "jit(delta_step_pallas)/state/pallas_call" in hlo
+    assert PA.KERNEL_TRACES["delta_step", "compiled"] == before + 1
+    memory = compiled.memory_analysis()
+    state_bytes = 6 * rows * 32 * 128 * 128 * 4
+    assert memory.alias_size_in_bytes == state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // (6 * rows)  # under ONE row of a layer
+
+
 def _latent_decode_entry(shape):
     from calfkit_tpu.inference import pallas_attention as PA
 
@@ -548,7 +600,7 @@ def test_chunk_attention_compiles_for_v5e(window, one_chip, no_persistent_cache)
 
 
 @pytest.mark.parametrize(
-    "kernel", ["paged_decode", "ssm_step", "latent_decode", "chunk_attention"])
+    "kernel", ["paged_decode", "ssm_step", "latent_decode", "chunk_attention", "delta_step"])
 def test_kernel_bytes_do_not_depend_on_the_caller(
     kernel, one_chip, no_persistent_cache, monkeypatch
 ):
@@ -565,7 +617,8 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
     entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry,
                       "latent_decode": _latent_decode_entry,
-                      "chunk_attention": _chunk_attention_entry}[kernel](shape)
+                      "chunk_attention": _chunk_attention_entry,
+                      "delta_step": _delta_step_entry}[kernel](shape)
 
     def deep(*a, depth=4):
         if depth:
@@ -591,7 +644,7 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
 
 def test_entry_point_list_is_complete():
-    """The kernel modules' entry points are the six compiled above (a
+    """The kernel modules' entry points are the seven compiled above (a
     merged read calls the plain one), ONE ``pallas_call`` a kernel body,
     and no other module of the package makes one: a kernel added without a
     compile of its own fails here."""
@@ -599,6 +652,7 @@ def test_entry_point_list_is_complete():
     import inspect
 
     from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference import pallas_gdn as PG
     from calfkit_tpu.inference import pallas_ssm as PS
 
     def entries(module):
@@ -611,14 +665,16 @@ def test_entry_point_list_is_complete():
         "chunk_attention_pallas",
     }
     assert entries(PS) == {"ssm_step_pallas"}
+    assert entries(PG) == {"delta_step_pallas"}
     assert inspect.getsource(PA).count("pl.pallas_call(") == 3
     assert inspect.getsource(PS).count("pl.pallas_call(") == 1
+    assert inspect.getsource(PG).count("pl.pallas_call(") == 1
     with_kernels = sorted(
         os.path.basename(path)
         for path in glob.glob(os.path.join(os.path.dirname(PA.__file__), "*.py"))
         if "pl.pallas_call(" in open(path).read()
     )
-    assert with_kernels == ["pallas_attention.py", "pallas_ssm.py"]
+    assert with_kernels == ["pallas_attention.py", "pallas_gdn.py", "pallas_ssm.py"]
 
 
 # ---------------------------------------------------------------------------
@@ -785,24 +841,37 @@ def _gdn_cell_engine(held: int | None = None):
     )._attn_impl == "xla"  # "auto" on this process's CPU: the reference path
     engine = InferenceEngine(
         config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
-    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "xla")
+    # the described v5e holds a float32 delta-rule state of whole tiles
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "pallas")
     return engine
+
+
+def _delta_step_kernels(hlo: str) -> list[str]:
+    """The delta step kernel's calls (``pallas_gdn.py``), each under
+    ``decode_loop/.../gdn/state/``: where ``gdn_state_roofline`` reads."""
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "/jit(delta_step_pallas)/state/pallas_call" in line]
+    assert all("decode_loop/" in k and "/gdn/state/" in k for k in kernels), kernels
+    return kernels
 
 
 def _gdn_decode_checks(engine, compiled):
     """One paged decode read (the period's one attention layer, in the
     scan's body) under ``attention``; no window gathered; the state's pass
-    under ``gdn/state`` and no Pallas kernel of its own; the stacked state
-    and the pool go out where they came in; NO copy of an expert stack, of
-    a layer of it, or of the stacked state (the temporaries are under one
-    layer's state plus the pool's layout copy around the consolidation
-    scatter, which every paged cell pays)."""
+    the delta step kernel, once a DeltaNet layer of the period, under
+    ``gdn/state``; the stacked state and the pool go out where they came in;
+    NO copy of an expert stack, of a layer of it, or of the stacked state
+    (the temporaries are under one layer's state plus the pool's layout copy
+    around the consolidation scatter, which every paged cell pays: not
+    larger than they were under XLA's pass)."""
     import re
 
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 1 and "paged_decode_attention" in kernels[0], kernels
-    assert "/attention/" in kernels[0] and "decode_loop/" in kernels[0]
+    reads = [k for k in kernels if "paged_decode_attention" in k]
+    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 3 == len(kernels) - 1, kernels
+    assert "/attention/" in reads[0] and "decode_loop/" in reads[0]
     assert "gather_window" not in hlo and "/gdn/state/" in hlo and "/mlp/moe/experts" in hlo
     cfg, rt = engine.config, engine.runtime
     E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
@@ -1072,7 +1141,7 @@ def _kda_cell_engine(held: int | None = None, slots: int | None = None):
         runtime = replace(runtime, max_batch_size=slots, num_kv_pages=slots * 64 + 1)
     engine = InferenceEngine(
         config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
-    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "xla")
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "pallas")
     return engine
 
 
@@ -1110,18 +1179,21 @@ def _kda_programs(engine, one_chip, rows_of_waves):
 
 
 def _kda_checks(engine, name, compiled):
-    """ONE Pallas kernel, the latent decode read of the one latent layer,
-    under ``decode_loop/.../mla/attention``; no window gathered in the decode
-    loop; the state's pass under ``gdn/state`` and the decay's under
-    ``gdn/decay``; the groups under ``moe/router/groups``; NO copy of an
-    expert stack; the stacked state and the pool go out where they came in."""
+    """The latent decode read of the one latent layer, under
+    ``decode_loop/.../mla/attention``; no window gathered in the decode
+    loop; the state's pass the delta step kernel, once a delta-rule layer
+    (the leading dense layer's and the scan's five), under ``gdn/state``, and
+    the decay's under ``gdn/decay``; the groups under ``moe/router/groups``;
+    NO copy of an expert stack; the stacked state and the pool go out where
+    they came in."""
     import re
 
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line and "pallas_call" in line]
-    assert len(kernels) == 1 and "latent_decode" in kernels[0], kernels
-    assert "/mla/" in kernels[0] and "decode_loop/" in kernels[0]
+    reads = [k for k in kernels if "latent_decode" in k]
+    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 6 == len(kernels) - 1, kernels
+    assert "/mla/" in reads[0] and "decode_loop/" in reads[0]
     for scope in ("/gdn/state/", "/gdn/decay/", "/moe/router/groups", "/mla/kv_latent",
                   "/mlp/moe/experts"):
         assert scope in hlo, scope
