@@ -509,8 +509,7 @@ def _finalize_wave_math(
     sampling state, scatter the wave's last/lens rows, sample each row's
     first token from its last-position logits.  Runs inside jit (all
     callers trace it) — the last/lens scatter used to run eagerly on the
-    host, costing two XLA dispatches PER REQUEST at admission
-    (scripts/sched_overhead.py r4 found admission dominating host cost)."""
+    host, costing two XLA dispatches PER REQUEST at admission."""
     R = slots.shape[0]
     P = sk.shape[3]
     if paged:
@@ -5146,13 +5145,11 @@ class InferenceEngine:
             self.stats.short_dispatches += 1
         # fan tokens out with ONE event-loop marshal per dispatch: a
         # call_soon_threadsafe per token costs ~65 us of loop machinery
-        # each (scripts/sched_overhead.py found it dominating host cost at
-        # bs=128), so bookkeeping runs here on the decode thread and the
+        # each, so bookkeeping runs here on the decode thread and the
         # queue puts cross threads as a single batch.  The common case —
         # no stop token in the block, bound not yet reached — ships the
         # whole column as one C-level tolist() with no per-token Python
-        # loop (at bs=128 x steps=32 the per-token loop alone was ~1 ms
-        # of the dispatch budget; sched_overhead.py r4).
+        # loop.
         deliveries: list[tuple[asyncio.Queue, list]] = []
         block_cols = np.ascontiguousarray(block.T)  # [B, steps]
         for slot, request in list(self._active.items()):

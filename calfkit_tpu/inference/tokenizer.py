@@ -47,7 +47,7 @@ class ByteTokenizer:
 
 class IdTokenizer:
     """Renders EVERY generated id as visible text — for random-weights runs
-    (``bench.py``, ``chip_smoke.py``): such a model emits mostly ids the
+    (``chip_smoke.py``, ``benchmarks/run.py``): such a model emits mostly ids the
     byte tokenizer drops, the decoded text comes out empty, and no token
     step is ever streamed."""
 

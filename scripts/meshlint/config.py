@@ -48,7 +48,7 @@ def default_config(root: "Path | str") -> Config:
     root = Path(root)
     return Config(
         root=root,
-        scan=["calfkit_tpu", "bench.py", "scripts/perf_gate.py"],
+        scan=["calfkit_tpu", "scripts/perf_gate.py"],
         package_prefix="calfkit_tpu",
         queue_scope=[
             "calfkit_tpu.inference.engine",
@@ -115,10 +115,6 @@ def default_config(root: "Path | str") -> Config:
                 "perf_gate", "no_wallclock", 1,
                 "the gate's metric compare must never read host time "
                 "(ISSUE 11)",
-            ),
-            RequiredRoots(
-                "bench", "no_wallclock", 1,
-                "_perf_model's roofline math must never read host time",
             ),
         ],
     )

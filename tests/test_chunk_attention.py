@@ -206,32 +206,49 @@ def _rehearsal_engine_args(attention_impl):
 
 
 def _serve(attention_impl, requests):
-    from tests._window_moe import serve  # the architecture file's seeded tree, one engine
+    from tests.arch_harness import WINDOW_MOE  # the architecture file's seeded tree, one engine
 
-    return serve(_rehearsal_engine_args(attention_impl), requests)
+    return WINDOW_MOE.serve(_rehearsal_engine_args(attention_impl), requests, keep=True)
 
 
-def test_a_live_engine_serves_the_same_tokens_under_the_kernel_and_under_xla():
+def _requests():
+    rng = np.random.default_rng(0)
+    return [([int(t) for t in rng.integers(3, 500, n)], 12) for n in (200, 300)]
+
+
+@pytest.fixture(scope="module")
+def under_xla():
+    """The two requests served once under ``"xla"`` for both tests below: what came back,
+    the engine, its counters, the kernels traced meanwhile and the metrics text."""
+    from types import SimpleNamespace
+
+    from calfkit_tpu.observability.metrics import metrics_text
+
+    PA.chunk_attention_pallas.clear_cache()
+    PA.KERNEL_TRACES.clear()
+    outs, engine, counters = _serve("xla", _requests())
+    return SimpleNamespace(outs=outs, engine=engine, counters=counters,
+                           traces=dict(PA.KERNEL_TRACES), metrics=metrics_text())
+
+
+def test_a_live_engine_serves_the_same_tokens_under_the_kernel_and_under_xla(under_xla):
     """Prompts of 200 and 300 tokens (two and three chunks of 128 against a
     window of 64: the later chunks start past the window) and 12 tokens each:
     ``attention_impl="pallas_interpret"`` resolves the chunks to the kernel
     and serves what ``"xla"`` serves, and ``"xla"`` builds no kernel at all;
-    on a CPU ``"auto"`` is ``"xla"``."""
-    from calfkit_tpu.inference.engine import CHUNK_ATTN_FIELDS
+    on a CPU ``"auto"`` is ``"xla"`` (resolved at construction: nothing is served)."""
+    from calfkit_tpu.inference.engine import CHUNK_ATTN_FIELDS, InferenceEngine
 
-    rng = np.random.default_rng(0)
-    requests = [([int(t) for t in rng.integers(3, 500, n)], 12) for n in (200, 300)]
-    PA.chunk_attention_pallas.clear_cache()
-    PA.KERNEL_TRACES.clear()
-    want, engine, counters = _serve("xla", requests)
-    assert engine._chunk_attn_impl == "xla" and not PA.KERNEL_TRACES
+    requests = _requests()
+    want, engine, counters = under_xla.outs, under_xla.engine, under_xla.counters
+    assert engine._chunk_attn_impl == "xla" and not under_xla.traces
     assert all(len(out) == 12 for out in want)
     got, engine, counters_k = _serve("pallas_interpret", requests)
     assert engine._chunk_attn_impl == engine._attn_impl == "pallas_interpret"
     assert PA.KERNEL_TRACES["chunk_attention", "interpreted"] >= 2  # with and without a window
     assert ("chunk_attention", "compiled") not in PA.KERNEL_TRACES
     assert got == want
-    assert _serve("auto", requests[:1])[1]._chunk_attn_impl == "xla"
+    assert InferenceEngine(*_rehearsal_engine_args("auto"))._chunk_attn_impl == "xla"
     # the counters are the same arithmetic whatever computes the chunks: 2 window layers
     # a period of W W W G cut to 4 layers -> 3 window layers and 1 global layer
     W = engine.config.sliding_window
@@ -244,14 +261,11 @@ def test_a_live_engine_serves_the_same_tokens_under_the_kernel_and_under_xla():
     assert set(CHUNK_ATTN_FIELDS) <= set(counters)
 
 
-def test_the_four_counters_reach_metrics_and_the_profile(monkeypatch):
+def test_the_four_counters_reach_metrics_and_the_profile(monkeypatch, under_xla):
     from calfkit_tpu.inference import engine as E
     from calfkit_tpu.observability import devtrace
-    from calfkit_tpu.observability.metrics import metrics_text
 
-    rng = np.random.default_rng(1)
-    _, engine, counters = _serve("xla", [([int(t) for t in rng.integers(3, 500, 150)], 4)])
-    text = metrics_text()
+    counters, text = under_xla.counters, under_xla.metrics
     for field in E.CHUNK_ATTN_FIELDS:
         assert counters[field] > 0
         assert f"calfkit_engine_{field}_total" in text, field

@@ -2,7 +2,7 @@
 mathematics against the plain reference, each control FAILING the tolerance,
 the kernel's window form against the XLA read, the share test.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_window_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -21,9 +21,10 @@ from calfkit_tpu.inference.pallas_attention import (
     PallasShapeError,
     paged_decode_attention_pallas,
 )
-from tests._window_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, both_forms_at_toy_size, forward, seeded,
-)
+from tests.arch_harness import WINDOW_MOE as FAMILY
+from tests.arch_harness import both_forms_at_toy_size  # noqa: F401 - an autouse fixture
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 W = TOY.sliding_window
 
@@ -83,7 +84,7 @@ def test_the_stack_runs_the_block_the_description_names(fields):
     attention's residual add.  Against the reference's own pieces (its
     attention under each kind's mask, its expert block) put together by hand."""
     config = replace(TOY, n_layers=4, layer_types=TOY.layer_types[:4], **fields)
-    params = seeded(config, key=2)
+    params = FAMILY.seeded(config, key=2)
     if not config.parallel_block:  # a second norm a layer, off 1 like the first
         assert params["layers"]["moe"]["mlp_norm"].shape == (4, config.d_model)
         params["layers"]["moe"]["mlp_norm"] = params["layers"]["attn"]["attn_norm"][::-1] * 1.05
@@ -92,7 +93,7 @@ def test_the_stack_runs_the_block_the_description_names(fields):
     assert config.param_count - replace(config, parallel_block=True).param_count == (
         0 if config.parallel_block else 4 * config.d_model)
     tokens = _tokens()[:1, :48]
-    logits, _ = forward(params, config, tokens, np.asarray([48], np.int32))
+    logits, _ = FAMILY.forward(params, config, tokens, np.asarray([48], np.int32))
 
     def norm(x, w):
         x = x - jnp.mean(x, -1, keepdims=True) if config.norm == "layer" else x
@@ -145,9 +146,9 @@ def test_full_forward_agrees_with_the_reference(monkeypatch, form):
     if form == "dense":
         monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
     monkeypatch.setattr(M, "CHUNK_KEY_BLOCK", 16)  # four key blocks; the window spans two
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens, lens = _tokens(), np.asarray([64, 41], np.int32)
-    logits, (k, v), (counts, _, absent) = forward(
+    logits, (k, v), (counts, _, absent) = FAMILY.forward(
         params, TOY, tokens, lens, moe=moe.moe_stats_init(TOY))
     assert moe.dense_form(2 * 64, TOY) == (form == "dense")
     assert k.shape == v.shape == (8, 2, 2, 64, 8)  # every layer, every position: the scratch
@@ -205,9 +206,9 @@ def test_each_control_fails_the_tolerance(monkeypatch, name):
     """The same comparison with one thing wrong: 10 to 10,000 times the
     tolerance, so the tolerance tells each of them."""
     config = _control(monkeypatch, name)
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens, lens = _tokens(), np.asarray([64, 41], np.int32)
-    logits = forward(params, config, tokens, lens)[0]
+    logits = FAMILY.forward(params, config, tokens, lens)[0]
     assert _worst(logits, ARCH.forward_logits(params, TOY, tokens, lens), lens) > 10 * LOGIT_TOL
 
 
@@ -233,7 +234,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
     in what the FFN adds, so its parts are compared before the head)."""
     whole = replace(TOY, n_layers=1, layer_types=(WINDOW,), n_routed_experts=8,
                     n_experts_total=0, expert_first=0)
-    params = seeded(whole, key=5)
+    params = FAMILY.seeded(whole, key=5)
     lp = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
     h = jnp.asarray(np.random.default_rng(1).normal(size=(2, 24, 32)), jnp.float32)
     with jax.default_matmul_precision("highest"):

@@ -3,7 +3,7 @@ decode through the ring of pages past two wraps of it, mixed decode and chunk
 rows in one ragged dispatch, the page accounting by cache kind, the counters
 and the refusals.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_window_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -23,33 +23,35 @@ from calfkit_tpu.inference.config import (
 )
 from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.paged import PagesByKind
-from tests._window_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, prompt_of, reference_logits, runtime, seeded,
-    serve,
+from tests.arch_harness import WINDOW_MOE as FAMILY
+from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
+    Spy, both_forms_at_toy_size, collect, standing,
 )
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 RING = 5  # ceil((24 + 4) / 8) + 1 pages of 8: 40 positions
 
 
 def _holds(spy, prompt, out, params) -> float:
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     return float(np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max())
 
 
-def test_chunked_prefill_then_decode_through_the_ring_past_two_wraps(monkeypatch):
+def test_chunked_prefill_then_decode_through_the_ring_past_two_wraps(standing):
     """A prompt of 50 (four chunks of 16, a padded tail; two windows and more
     than the ring's 40 positions) and 60 generated tokens: positions 50 ..
     109 are written through a ring of 40, past its second wrap at 80.  Every
     generated position's logits (the ring read under the lower bound, the
     global read, the fresh tokens merged, the dense expert form) against the
     reference's full forward of prompt + output; every chunk's too."""
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(50)
-    (out,), engine, counters = serve((TOY, runtime()), [(prompt, 60)])
+    prompt = FAMILY.prompt_of(50)
+    served = standing.serve([(prompt, 60)])
+    (out,), spy, engine, counters = served.outs, served.spy, served.engine, served.added
     assert len(out) == 60
     assert _holds(spy, prompt, out, engine.params) < LOGIT_TOL
-    want = reference_logits(engine.params, TOY, prompt + out)
+    want = FAMILY.reference_logits(engine.params, TOY, prompt + out)
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
     assert np.abs(chunks - want[: len(prompt)]).max() < LOGIT_TOL
     # 8 layers x 3 experts a token x (50 prompt tokens + 59 decode steps run as 60)
@@ -63,41 +65,42 @@ def test_chunked_prefill_then_decode_through_the_ring_past_two_wraps(monkeypatch
     assert counters["decode_global_tokens_read"] == 2 * sum(
         4 * n for n in range(50, 114, 4))  # 2 global layers x rows x len x steps
     assert counters["decode_window_tokens_read"] == 6 * 24 * 64  # 6 window layers x min(len, W)
-    assert (counters["kv_pages_global_in_use"], counters["kv_pages_window_in_use"]) == (0, 0)
-    assert (counters["kv_pages_global_total"], counters["kv_pages_window_total"]) == (
-        32, 2 * RING)
+    gauges = served.counters
+    assert (gauges["kv_pages_global_in_use"], gauges["kv_pages_window_in_use"]) == (0, 0)
+    assert (gauges["kv_pages_global_total"], gauges["kv_pages_window_total"]) == (32, 2 * RING)
 
 
-def test_mixed_decode_and_chunk_rows_in_one_ragged_dispatch(monkeypatch):
+def test_mixed_decode_and_chunk_rows_in_one_ragged_dispatch(monkeypatch, standing):
     """Two requests at once through two slots, the second's prompt longer:
     its five chunks ride the first's decode steps in ragged dispatches
     (``unified_dispatches``).  Every chunk's logits and every served token
-    are the reference's, and each row is served as if it were alone."""
+    are the reference's, and each row is served as if it were alone.  (A wave
+    of one row at a time is another runtime: a build of its own.)"""
     spy = Spy(monkeypatch)
-    requests = [(prompt_of(21, seed=1), 30), (prompt_of(70, seed=2), 12)]
-    outs, engine, counters = serve(
-        (TOY, runtime(max_prefill_wave=1)), requests, sequential=False)
+    requests = [(FAMILY.prompt_of(21, seed=1), 30), (FAMILY.prompt_of(70, seed=2), 12)]
+    outs, engine, counters = FAMILY.serve(
+        (TOY, FAMILY.runtime(max_prefill_wave=1)), requests, sequential=False, keep=True)
     assert counters["unified_dispatches"] >= 4 and counters["prefill_absorbed_tokens"] >= 64
     chunks = [s[0] for s in spy.seen if s.shape[1] == 16]
     assert len(chunks) == 2 + 5
     for (prompt, _), out, mine in zip(requests, outs, (chunks[:2], chunks[2:])):
-        want = reference_logits(engine.params, TOY, prompt + out)
+        want = FAMILY.reference_logits(engine.params, TOY, prompt + out)
         assert np.abs(np.concatenate(mine)[: len(prompt)] - want[: len(prompt)]).max() < LOGIT_TOL
         served = want[len(prompt) - 1: len(prompt) - 1 + len(out)]
         assert [int(t) for t in np.argmax(served, -1)] == out
     # the second row's decode steps beside the first's: by the chain of its tokens
     steps = [s for s in spy.seen if s.shape[1] == 1]
     prompt, out = requests[1][0], outs[1]
-    want = reference_logits(engine.params, TOY, prompt + out)[len(prompt):]
+    want = FAMILY.reference_logits(engine.params, TOY, prompt + out)[len(prompt):]
     best = min(
         max(float(np.abs(steps[j + i][b, 0] - want[i]).max()) for i in range(len(out) - 1))
         for j in range(len(steps) - len(out) + 2) for b in range(2))
     assert best < LOGIT_TOL
-    alone = [serve((TOY, runtime()), [request])[0][0] for request in requests]
+    alone = [standing.serve([request]).outs[0] for request in requests]
     assert outs == alone
 
 
-def test_a_row_of_four_windows_never_holds_more_than_its_ring():
+def test_a_row_of_four_windows_never_holds_more_than_its_ring(standing):
     """96 + 30 tokens under a window of 24: the row's window pages stay 5
     (its ring) at every token, its global pages are its whole footprint, and
     retirement returns both kinds."""
@@ -107,7 +110,8 @@ def test_a_row_of_four_windows_never_holds_more_than_its_ring():
         seen.append((dict(engine._page_alloc.held_slots), engine.stats.kv_pages_window_in_use,
                      engine._ledger.pages_in_use))
 
-    (out,), engine, counters = serve((TOY, runtime()), [(prompt_of(96), 30)], probe=probe)
+    served = standing.serve([(FAMILY.prompt_of(96), 30)], probe=probe)
+    (out,), engine = served.outs, served.engine
     assert len(out) == 30 and seen
     for held, window_in_use, ledger in seen[:-1]:
         (n_global, n_window), = held.values()
@@ -120,10 +124,10 @@ def test_a_row_of_four_windows_never_holds_more_than_its_ring():
     assert all(a.free_pages == a.num_pages - 1 for a in engine._page_alloc.by_kind)
 
 
-def test_a_short_request_takes_a_shorter_ring():
+def test_a_short_request_takes_a_shorter_ring(standing):
     held = []
-    serve((TOY, runtime()), [(prompt_of(9), 6)],
-          probe=lambda e: held.append(dict(e._page_alloc.held_slots)))
+    standing.serve([(FAMILY.prompt_of(9), 6)],
+                   probe=lambda e: held.append(dict(e._page_alloc.held_slots)))
     assert set(held[0].values()) == {(2, 2)}  # 16 positions: two pages of each kind
 
 
@@ -148,18 +152,19 @@ def test_admission_waits_on_either_pool(short):
     """With one of the two pools nearly taken, a second request waits in the
     queue while the first lives (``alloc_stalls``, ``blocked_pages_s``),
     whichever pool it is that is short, and is served once the first's pages
-    of BOTH kinds are back."""
+    of BOTH kinds are back.  (It takes pages out of a pool by hand before
+    the engine starts: an engine of its own.)"""
     async def run():
-        engine = InferenceEngine(TOY, runtime(max_prefill_wave=1), seed=3, params=seeded())
+        engine = InferenceEngine(TOY, FAMILY.runtime(max_prefill_wave=1), seed=3, params=FAMILY.seeded())
         pool = engine._page_alloc.by_kind[0 if short == "global" else 1]
         # someone else holds most of one pool: the first request (7 global pages, a
         # ring of 5) fits beside them, the second (4 and 4) does not until it retires
         assert pool.alloc(99, 22 if short == "global" else 3) is not None
         await engine.start()
         try:
-            first = asyncio.ensure_future(_collect(engine, prompt_of(20, seed=1), 30))
+            first = asyncio.ensure_future(collect(engine, FAMILY.prompt_of(20, seed=1), 30))
             await asyncio.sleep(0)
-            second = asyncio.ensure_future(_collect(engine, prompt_of(20, seed=2), 5))
+            second = asyncio.ensure_future(collect(engine, FAMILY.prompt_of(20, seed=2), 5))
             outs = await asyncio.wait_for(asyncio.gather(first, second), 120)
             return outs, engine.stats.counters(), dict(engine._page_alloc.held_slots)
         finally:
@@ -171,20 +176,17 @@ def test_admission_waits_on_either_pool(short):
     assert set(held) == {99}  # retirement returned both kinds
 
 
-async def _collect(engine, prompt, n):
-    return [t async for t in engine.generate(prompt, max_new_tokens=n)]
-
-
 def test_a_request_no_pool_could_ever_serve_is_rejected():
+    """(A pool of 9 pages is another runtime: a build of its own.)"""
     from calfkit_tpu.exceptions import InferenceError
 
     async def run():
-        engine = InferenceEngine(TOY, runtime(num_kv_pages=9), seed=3, params=seeded())
+        engine = InferenceEngine(TOY, FAMILY.runtime(num_kv_pages=9), seed=3, params=FAMILY.seeded())
         await engine.start()
         try:
             with pytest.raises(InferenceError, match="KV pages"):
-                await _collect(engine, prompt_of(70), 8)
-            return await _collect(engine, prompt_of(40), 8)
+                await collect(engine, FAMILY.prompt_of(70), 8)
+            return await collect(engine, FAMILY.prompt_of(40), 8)
         finally:
             await engine.stop()
 
@@ -194,9 +196,10 @@ def test_a_request_no_pool_could_ever_serve_is_rejected():
 def test_prefix_reuse_is_declined_and_counted_for_a_model_with_window_layers():
     from calfkit_tpu.observability.metrics import metrics_text
 
-    prompt = prompt_of(40)
-    outs, engine, counters = serve(
-        (TOY, runtime(prefix_cache=True)), [(prompt, 4), (prompt, 4), (prompt_of(33, seed=4), 4)])
+    prompt = FAMILY.prompt_of(40)
+    outs, engine, counters = FAMILY.serve(  # the prefix cache on: another runtime, its own build
+        (TOY, FAMILY.runtime(prefix_cache=True)),
+        [(prompt, 4), (prompt, 4), (FAMILY.prompt_of(33, seed=4), 4)], keep=True)
     assert outs[0] == outs[1]
     assert counters["prefix_reuse_declined_window"] == 3 and counters["prefix_hits"] == 0
     assert engine._prefix is None  # nothing is ever registered
@@ -208,14 +211,14 @@ def test_prefix_reuse_is_declined_and_counted_for_a_model_with_window_layers():
         assert f"calfkit_engine_{name}" in text, name
 
 
-def test_the_dispatch_span_carries_the_two_page_counts():
+def test_the_dispatch_span_carries_the_two_page_counts(standing):
     from calfkit_tpu.observability.trace import TRACER
 
     TRACER.clear()
     was = TRACER.enabled
     TRACER.enabled = True
     try:
-        serve((TOY, runtime()), [(prompt_of(30), 9)])
+        standing.serve([(FAMILY.prompt_of(30), 9)])
         spans = [s for s in TRACER.finished() if s.name == "engine.dispatch"]
     finally:
         TRACER.enabled = was
@@ -237,11 +240,11 @@ def test_the_dispatch_span_carries_the_two_page_counts():
 ])
 def test_what_knows_no_lower_bound_is_refused_at_construction(option, kw):
     with pytest.raises(UnsupportedWithWindowLayers, match=option.split(" ")[0]):
-        InferenceEngine(TOY, runtime(**kw), seed=3)
+        InferenceEngine(TOY, FAMILY.runtime(**kw), seed=3)
 
 
 def test_the_programs_name_both_kinds_attention_scopes():
-    engine = InferenceEngine(TOY, runtime(attention_impl="xla"), seed=3, params=seeded())
+    engine = InferenceEngine(TOY, FAMILY.runtime(attention_impl="xla"), seed=3, params=FAMILY.seeded())
     args, window, steps, sampled = engine._decode_args()
     text = jax.make_jaxpr(engine._decode_fn_paged(window // 8, steps, sampled))(
         *args, moe=engine._moe_zero).pretty_print(name_stack=True)
@@ -255,8 +258,8 @@ def test_the_programs_name_both_kinds_attention_scopes():
         assert scope in chunk, scope
 
 
-def test_the_pools_come_by_cache_kind():
-    engine = InferenceEngine(TOY, runtime(), seed=3, params=seeded())
+def test_the_pools_come_by_cache_kind(standing):
+    engine = standing.engine
     (kg, kw), (vg, vw) = engine._k, engine._v
     assert kg.shape == vg.shape == (2, 33, 2, 8, 8)  # the 2 global layers, num_kv_pages
     assert kw.shape == vw.shape == (6, 2 * RING + 1, 2, 8, 8)  # 6 window layers, every slot's ring
@@ -267,8 +270,6 @@ def test_the_pools_come_by_cache_kind():
 
 def _left_in(engine, requests, outs, new):
     """The architecture file's two readings of what ``requests`` left in ``engine``."""
-    from tests._window_moe import ARCH
-
     seqs = [p + o for (p, _), o in zip(requests, outs)]
     lens = np.asarray([len(s) for s in seqs])
     left = [ARCH._walk(engine.params, TOY, np.pad(seq, (0, 128 - len(seq))), len(seq), left=True)[1]
@@ -286,9 +287,11 @@ def test_what_the_served_rows_leave_in_the_engine(monkeypatch, narrow):
     entry its position names, two rows past the ring's wrap; the global
     layers' pages: every position), and every layer's tokens to each held
     expert.  Keys kept in a narrower type than stated show in the first and
-    nowhere in the second."""
+    nowhere in the second.  (The expert counts are the engine's since its
+    start, and the narrower keys another program: a build of its own.)"""
     from calfkit_tpu.inference import model as M
 
+    requests = [(FAMILY.prompt_of(50, seed=1), 32), (FAMILY.prompt_of(21, seed=2), 60)]
     if narrow:
         qkv = M._window_qkv
 
@@ -296,8 +299,7 @@ def test_what_the_served_rows_leave_in_the_engine(monkeypatch, narrow):
             q, k, v = qkv(h, lp, cos, sin)
             return q, k.astype(jnp.bfloat16).astype(k.dtype), v
         monkeypatch.setattr(M, "_window_qkv", rounded)
-    requests = [(prompt_of(50, seed=1), 32), (prompt_of(21, seed=2), 60)]
-    outs, engine, _ = serve((TOY, runtime()), requests, sequential=False)
+    outs, engine, _ = FAMILY.serve((TOY, FAMILY.runtime()), requests, sequential=False, keep=True)
     gate, keys = _left_in(engine, requests, outs, 32)
     assert len(keys["ring_error_by_row"]) == 2 and sorted(keys["slots"]) == [0, 1]
     assert len(keys["keys_error_by_layer"]) == TOY.n_layers
@@ -306,7 +308,7 @@ def test_what_the_served_rows_leave_in_the_engine(monkeypatch, narrow):
     assert gate["gate_mismatch"] == 0.0 and (narrow or gate["gate_mismatch_later"] == 0.0)
     assert engine.window_ring(0).shape == (2, 2, 40, 8)
     assert engine.global_keys(0, 1).shape == (2, 128, 8)
-    plain = InferenceEngine(preset("debug"), runtime(), seed=1)
+    plain = InferenceEngine(preset("debug"), FAMILY.runtime(), seed=1)
     assert plain.window_ring() is None and plain.global_keys(0) is None
 
 
@@ -338,10 +340,12 @@ def test_a_later_layer_s_keys_tell_what_the_layers_below_added(monkeypatch, faul
     without its lower bound changes what that layer adds to the stream at the
     positions a decode step wrote, and with it the keys of every layer above;
     a rotation on the global layers shows in their own pages.  The first
-    layer's ring and the first layer's gate read the same either way."""
+    layer's ring and the first layer's gate read the same either way.  (Each
+    fault is another program: a build of its own.)"""
     fault(monkeypatch)
-    requests = [(prompt_of(50, seed=1), 24), (prompt_of(70, seed=2), 24)]
-    outs, engine, _ = serve((TOY, runtime()), requests, sequential=False)
+    requests = [(FAMILY.prompt_of(50, seed=1), 24), (FAMILY.prompt_of(70, seed=2), 24)]
+    outs, engine, _ = FAMILY.serve(
+        (TOY, FAMILY.runtime()), requests, sequential=False, keep=True)
     gate, keys = _left_in(engine, requests, outs, 24)
     assert keys["ring_error"] < 1e-5 and gate["gate_mismatch"] == 0.0
     assert keys["keys_error_later"] > 1e-2, keys
@@ -357,9 +361,11 @@ def test_a_retired_row_stands_while_other_slots_and_pages_are_free():
     """Slots and pages of both kinds are granted oldest-first: four rows
     served one after the other through four slots leave four rings and four
     tables standing, so a check that reads them back afterwards finds every
-    row (LIFO grants would have served all four in one slot's pages)."""
-    requests = [(prompt_of(30 + 9 * i, seed=i), 8) for i in range(4)]
-    outs, engine, _ = serve((TOY, runtime(max_batch_size=4)), requests, sequential=True)
+    row (LIFO grants would have served all four in one slot's pages).  (Four
+    slots: another runtime, a build of its own.)"""
+    requests = [(FAMILY.prompt_of(30 + 9 * i, seed=i), 8) for i in range(4)]
+    outs, engine, _ = FAMILY.serve(
+        (TOY, FAMILY.runtime(max_batch_size=4)), requests, sequential=True, keep=True)
     _, keys = _left_in(engine, requests, outs, 8)
     assert sorted(keys["slots"]) == [0, 1, 2, 3]
     assert keys["ring_error"] < 1e-5 and keys["keys_error_later"] < 1e-4
@@ -372,13 +378,13 @@ def test_a_retired_row_stands_while_other_slots_and_pages_are_free():
 @pytest.mark.parametrize("lane", [
     dict(chunked_prefill=False), dict(overlap_dispatch=False), dict(ragged_waves=False),
 ])
-def test_every_lane_serves_the_tokens_the_ragged_lane_serves(lane):
+def test_every_lane_serves_the_tokens_the_ragged_lane_serves(standing, lane):
     """The one-shot prefill (the whole bucket's queries at once), the
     lockstep tick and the legacy bifurcated schedule write and read the same
-    rings: the tokens are the ragged lane's."""
-    prompt = prompt_of(50)
-    (want,), _, _ = serve((TOY, runtime()), [(prompt, 40)])
-    (got,), _, _ = serve((TOY, runtime(**lane)), [(prompt, 40)])
+    rings: the tokens are the ragged lane's.  (Each lane is a build of its own.)"""
+    prompt = FAMILY.prompt_of(50)
+    (want,) = standing.serve([(prompt, 40)]).outs
+    (got,), _, _ = FAMILY.serve((TOY, FAMILY.runtime(**lane)), [(prompt, 40)])
     assert got == want and len(got) == 40
 
 
@@ -390,7 +396,8 @@ def test_the_cell_s_agreement_holds_what_the_rows_leave_in_the_engine(monkeypatc
     and holds the rings' keys and the first layer's expert counts to the
     reference's.  As stated both read (nearly) nothing; keys kept in a
     narrower type, and a gate taken in a lower precision, each FAILS its own limit
-    and with it the check, whatever the served tokens say."""
+    and with it the check, whatever the served tokens say.  (The file's
+    rehearsal sizes, and each fault another program: builds of its own.)"""
     import dataclasses
     import json
 
@@ -398,8 +405,6 @@ def test_the_cell_s_agreement_holds_what_the_rows_leave_in_the_engine(monkeypatc
     from benchmarks.reference import agreement
     from calfkit_tpu.inference import model as M
     from calfkit_tpu.inference import moe
-    from tests._window_moe import ARCH
-
     monkeypatch.undo()  # the file's own rehearsal sizes and the dense form's own limit
     with open(manifest.os.path.join(manifest.os.path.dirname(manifest.__file__), "configs",
                                     "command-a-plus-05-2026.json")) as f:
@@ -431,10 +436,10 @@ def test_the_cell_s_agreement_holds_what_the_rows_leave_in_the_engine(monkeypatc
 
     async def run():
         engine = InferenceEngine(toy, replace(rt, compilation_cache=False), seed=3,
-                                 params=seeded(toy, key=5))
+                                 params=FAMILY.seeded(toy, key=5))
         await engine.start()
         try:
-            return engine, list(await asyncio.gather(*[_collect(engine, p, 16) for p in prompts]))
+            return engine, list(await asyncio.gather(*[collect(engine, p, 16) for p in prompts]))
         finally:
             await engine.stop()
 

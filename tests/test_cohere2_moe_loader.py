@@ -2,7 +2,7 @@
 the window stack's tree, whole and as a share, the vision tower skipped and
 counted, the q and k columns from interleaved pairs to the tree's halves.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_window_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -19,9 +19,10 @@ import pytest
 from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference.config import ModelConfig
 from calfkit_tpu.inference.sharding import make_mesh
-from tests._window_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, both_forms_at_toy_size, forward, seeded,
-)
+from tests.arch_harness import WINDOW_MOE as FAMILY
+from tests.arch_harness import both_forms_at_toy_size  # noqa: F401 - an autouse fixture
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
 def _interleaved(w: np.ndarray) -> np.ndarray:
@@ -92,7 +93,7 @@ def test_a_fabricated_cohere2_moe_checkpoint_loads_whole_and_as_a_share(tmp_path
     from calfkit_tpu.inference.sharding import param_shardings
 
     whole = replace(TOY, n_routed_experts=8, n_experts_total=0, expert_first=0)
-    tree = jax.tree.map(np.asarray, seeded(whole, key=12))
+    tree = jax.tree.map(np.asarray, FAMILY.seeded(whole, key=12))
     _checkpoint(tmp_path, whole, tree)
     config = replace(config_from_hf(tmp_path, share), dtype="float32")
     rank, of = share or (0, 1)
@@ -117,7 +118,7 @@ def test_a_fabricated_cohere2_moe_checkpoint_loads_whole_and_as_a_share(tmp_path
     for (path, got), expected in zip(jax.tree.leaves_with_path(loaded), jax.tree.leaves(want)):
         assert np.array_equal(np.asarray(got), expected), path
     tokens = np.random.default_rng(1).integers(3, 128 // of, (1, 48)).astype(np.int32)
-    logits = forward(loaded, config, tokens)[0]
+    logits = FAMILY.forward(loaded, config, tokens)[0]
     reference = ARCH.forward_logits(loaded, config, tokens, np.asarray([48], np.int32))
     assert np.abs(np.asarray(logits) - reference).max() < LOGIT_TOL
 
@@ -143,7 +144,7 @@ def test_what_the_program_does_not_describe_is_refused_at_the_config(tmp_path):
     from calfkit_tpu.inference.loader import config_from_hf
 
     whole = replace(TOY, n_routed_experts=8, n_experts_total=0, expert_first=0)
-    _checkpoint(tmp_path, whole, jax.tree.map(np.asarray, seeded(whole, key=1)), tower=False)
+    _checkpoint(tmp_path, whole, jax.tree.map(np.asarray, FAMILY.seeded(whole, key=1)), tower=False)
     raw = json.loads((tmp_path / "config.json").read_text())
     for key, value in (("use_parallel_block", False), ("use_qk_norm", True),
                        ("first_k_dense_replace", 1), ("expert_selection_fn", "softmax"),

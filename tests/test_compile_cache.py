@@ -135,7 +135,7 @@ class TestCompileCacheRule:
     def test_no_other_call_site_sets_a_cache_directory(self):
         """One helper: nothing else in the program names the option."""
         hits = []
-        for top in ("calfkit_tpu", "scripts", "bench.py", "chip_smoke.py",
+        for top in ("calfkit_tpu", "scripts", "chip_smoke.py",
                     "__graft_entry__.py", "conftest.py", "examples"):
             path = os.path.join(REPO, top)
             files = [path] if os.path.isfile(path) else [

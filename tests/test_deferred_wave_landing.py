@@ -32,9 +32,10 @@ from calfkit_tpu.inference import model as M  # noqa: E402
 from calfkit_tpu.inference.config import RuntimeConfig, preset  # noqa: E402
 from calfkit_tpu.inference.engine import InferenceEngine  # noqa: E402
 from calfkit_tpu.sim import assert_engine_drained, settle, virtual_clock  # noqa: E402
-from tests._gdn_moe import TOY as GDN_MOE  # noqa: E402
-from tests._gdn_moe import both_forms_at_toy_size, seeded  # noqa: E402, F401 - an autouse fixture
-from tests.test_hybrid_mamba import TOY as HYBRID  # noqa: E402
+from tests.arch_harness import GDN_MOE as FAMILY  # noqa: E402
+from tests.arch_harness import HYBRID_MAMBA, both_forms_at_toy_size  # noqa: E402, F401 - an autouse fixture
+
+GDN_MOE, HYBRID = FAMILY.toy, HYBRID_MAMBA.toy
 
 DENSE = preset("debug")
 LONG = list(range(3, 23))  # 20 tokens: a bucket of 32, two chunks of 16
@@ -54,7 +55,7 @@ def _rt(**over) -> RuntimeConfig:
 MODELS = {
     "dense": (DENSE, lambda: M.init_params(DENSE, jax.random.key(0), dtype=jnp.float32)),
     "recurrent": (HYBRID, lambda: M.init_params(HYBRID, jax.random.key(1))),
-    "recurrent_expert": (GDN_MOE, lambda: seeded(GDN_MOE)),
+    "recurrent_expert": (GDN_MOE, lambda: FAMILY.seeded(GDN_MOE)),
 }
 _PARAMS: dict = {}
 

@@ -23,8 +23,9 @@ import pytest
 from calfkit_tpu.inference import gdn
 from calfkit_tpu.inference import pallas_attention as PA
 from calfkit_tpu.inference import pallas_gdn as PG
-from calfkit_tpu.inference.config import RuntimeConfig, preset
+from calfkit_tpu.inference.config import preset
 from calfkit_tpu.inference.engine import InferenceEngine
+from tests.arch_harness import GDN_MOE
 
 # (Hv, d_k, d_v): heads of one sublane tile, a piece of several heads and an
 # odd head count (no unrolling divides it), a head of two lane tiles
@@ -147,19 +148,11 @@ MODELS = {
 }
 
 
-def runtime(**kw) -> RuntimeConfig:
-    return RuntimeConfig(**{
-        "max_batch_size": 2, "max_seq_len": 128, "kv_layout": "paged", "page_size": 8,
-        "chunked_prefill": True, "prefill_chunk": 16, "window_buckets": (128,),
-        "compilation_cache": False, "decode_steps_per_dispatch": 4, **kw,
-    })
-
-
 @pytest.fixture(scope="module")
 def engine():
     """ONE engine: the selector reads the engine's config, runtime and mesh
     and the platform when it is ASKED, so a case only changes those."""
-    return InferenceEngine(MODELS["gdn"], runtime())
+    return InferenceEngine(MODELS["gdn"], GDN_MOE.runtime(window_buckets=(128,)))
 
 
 @pytest.mark.parametrize("model", sorted(MODELS))
@@ -211,7 +204,8 @@ def test_the_kernel_s_scope_path_lies_under_gdn_state():
 
     config = replace(MODELS["gdn"], attn_head_dim=128, n_layers=4,
                      layer_types=MODELS["gdn"].layer_types[:4])
-    engine = InferenceEngine(config, runtime(attention_impl="pallas_interpret", page_size=16))
+    engine = InferenceEngine(config, GDN_MOE.runtime(
+        window_buckets=(128,), attention_impl="pallas_interpret", page_size=16))
     assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "pallas_interpret")
     for jaxpr in _programs(engine).values():
         paths = [scope_path(op) for name, op in _kernels(jaxpr.jaxpr) if name == "state"]

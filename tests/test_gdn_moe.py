@@ -1,7 +1,7 @@
 """Gated DeltaNet hybrid (Qwen3-Next's kind): the delta rule's two forms, the share of a
 layer's experts, the description and its refusals, the benchmark's two new readers.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_gdn_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -29,11 +29,12 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
-from tests import _moe_stack
-from tests._gdn_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, forward, prompt_of, reference_logits,
-    runtime, seeded, serve,
+from tests.arch_harness import GDN_MOE as FAMILY
+from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
+    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size, stack_check,
 )
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
 # ------------------------------------------------ (a) the delta rule's two forms
@@ -105,7 +106,7 @@ def test_the_mixer_carries_its_states_from_chunk_to_chunk_and_into_the_steps():
     row's 45 tokens (the one for 44 is another: the agreement check holds a
     served row's slot to the nearer of the two)."""
     c = TOY
-    params = seeded()
+    params = FAMILY.seeded()
     lp = jax.tree.map(lambda a: a[1], params["layers"]["gdn"])
     x = jax.random.normal(jax.random.key(4), (1, 45, c.d_model))
     layer, _ = ARCH._gdn(c.gdn_n_k_heads, c.gdn_n_v_heads, c.gdn_d_k, c.gdn_d_v, c.gdn_d_conv,
@@ -168,7 +169,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer(monkeypatch, form)
 
 def test_a_share_s_two_forms_agree_with_each_other_and_the_reference(monkeypatch):
     c = replace(TOY, n_layers=1, layer_types=("gdn",))
-    params = seeded(c, key=8)
+    params = FAMILY.seeded(c, key=8)
     lp = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
     h = jax.random.normal(jax.random.key(9), (2, 24, c.d_model))
     dense = moe.moe_ffn(h, lp, c, None, None, 0)[0]
@@ -183,14 +184,14 @@ def test_a_share_s_two_forms_agree_with_each_other_and_the_reference(monkeypatch
     assert np.abs(np.asarray(moe.moe_ffn(normed, lp, c, None, None, 0)[0]) - np.asarray(want)).max() < 1e-5
 
 
-@pytest.mark.parametrize("m", range(_moe_stack.LAYERS))
-@pytest.mark.parametrize("case", [*_moe_stack.ROUTINGS, "every_pair_absent"])
+@pytest.mark.parametrize("m", range(STACK_LAYERS))
+@pytest.mark.parametrize("case", [*STACK_ROUTINGS, "every_pair_absent"])
 def test_a_share_s_grouped_experts_read_their_layer_out_of_the_stack(case, m):
     """The same over experts held by SHARE (4 of 8, from the fifth): the
     pairs whose expert is held elsewhere sort behind the LAST of the stack's
     groups, not behind this layer's, and no group's product reaches them;
     with every pair absent the routed part is exactly zero."""
-    _moe_stack.check(TOY, case, m)
+    stack_check(TOY, case, m)
 
 
 def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen():
@@ -276,7 +277,7 @@ def test_the_new_fields_belong_to_their_stack():
 ])
 def test_what_has_no_code_is_refused_at_construction_with_its_reason(option, kw, why):
     with pytest.raises(UnsupportedWithRecurrentLayers, match=why) as refused:
-        InferenceEngine(TOY, runtime(**kw))
+        InferenceEngine(TOY, FAMILY.runtime(**kw))
     assert option in str(refused.value) and "Gated DeltaNet" in str(refused.value)
 
 
@@ -285,7 +286,7 @@ def test_an_explicit_kernel_outside_its_rule_and_a_quantized_tree_are_refused():
     from calfkit_tpu.inference.quant import quantize_params
 
     with pytest.raises(PallasShapeError, match="head_dim=16"):
-        InferenceEngine(TOY, runtime(attention_impl="pallas"))
+        InferenceEngine(TOY, FAMILY.runtime(attention_impl="pallas"))
     with pytest.raises(ValueError, match="no scales"):
         quantize_params(M.init_params(TOY, jax.random.key(0)))
 
@@ -294,7 +295,7 @@ def test_the_new_counter_reaches_metrics_and_the_state_reaches_capacity():
     from calfkit_tpu.observability.capacity import recurrent_bytes_per_token
     from calfkit_tpu.observability.metrics import metrics_text
 
-    serve((TOY, runtime()), [(prompt_of(20), 3)])
+    FAMILY.serve((TOY, FAMILY.runtime()), [(FAMILY.prompt_of(20), 3)])
     text = metrics_text()
     for name in ("calfkit_engine_moe_assignments_total",
                  "calfkit_engine_moe_assignments_absent_total",

@@ -1,7 +1,7 @@
 """Gated DeltaNet hybrid (Qwen3-Next's kind): the program's logits against the plain reference
 through the engine, and the controls that each have to FAIL the tolerance.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_gdn_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -29,16 +29,16 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
-from tests._gdn_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, forward, prompt_of, reference_logits,
-    runtime, seeded, serve, served_in_three_phases,
-)
+from tests.arch_harness import GDN_MOE as FAMILY
+from tests.arch_harness import Spy, both_forms_at_toy_size, standing  # noqa: F401 - fixtures
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
 @pytest.fixture(scope="module")
-def one_engine():
-    """``(TOY, runtime())`` served once for the tests that would each build it."""
-    return served_in_three_phases(Spy, (TOY, runtime()), seeded(TOY), prompt_of)
+def one_engine(standing):
+    """What three suites read of the module's ONE engine."""
+    return FAMILY.served_in_three_phases(standing)
 
 
 # ------------------------------------------------ (b) the program against the reference
@@ -50,10 +50,10 @@ def test_full_forward_agrees_with_the_reference(monkeypatch, form):
     experts' assignments and the absent ones' apart."""
     if form == "dense":
         monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens = np.random.default_rng(2).integers(3, TOY.vocab_size, (2, 40)).astype(np.int32)
     lens = np.asarray([40, 27], np.int32)
-    logits, (k, v), (S, conv), (counts, _, absent) = forward(
+    logits, (k, v), (S, conv), (counts, _, absent) = FAMILY.forward(
         params, TOY, tokens, lens, moe=moe.moe_stats_init(TOY))
     assert moe.dense_form(2 * 40, TOY) == (form == "dense")
     # K and V of the 2 attention layers alone; the state pair of the 6 others
@@ -73,10 +73,10 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(one_en
     windows.  Every generated position's logits (the one-pass step on the
     carried state, the paged read, the dense expert form) against the
     reference's full forward of prompt + output."""
-    spy, prompt = one_engine.seen[0], prompt_of(37)
+    spy, prompt = one_engine.seen[0], FAMILY.prompt_of(37)
     out, params, counters = one_engine.first, one_engine.params, one_engine.counters[0]
     got = Spy.of_request(spy, prompt, out, 16)
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
     assert np.abs(chunks - want[: len(prompt)]).max() < LOGIT_TOL
@@ -98,7 +98,7 @@ def test_every_chunk_of_a_served_prompt_is_counted_by_its_form(monkeypatch, requ
 
     if form == "dense":
         monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
-        _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+        _, _, counters = FAMILY.serve((TOY, FAMILY.runtime()), [(FAMILY.prompt_of(37), 3)])
         text = metrics_text()
     else:  # the shared engine's first request is that prompt
         shared = request.getfixturevalue("one_engine")
@@ -115,7 +115,7 @@ def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(one_engine
     spy, params = one_engine.seen[1], one_engine.params
     for prompt, out in zip((p for p, _ in one_engine.requests), one_engine.alone):
         got = Spy.of_request(spy, prompt, out, 16)
-        want = reference_logits(params, TOY, prompt + out)
+        want = FAMILY.reference_logits(params, TOY, prompt + out)
         assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     # one after another: each wave lands on an engine with no active rows, by a sync of its own
     before, after = one_engine.counters
@@ -125,11 +125,12 @@ def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(one_engine
 
 
 def test_single_shot_prefill_serves_the_same_logits(monkeypatch):
+    """(Single-shot prefill is another lane: a build of its own.)"""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(23, seed=7)
-    (out,), params, _ = serve((TOY, runtime(chunked_prefill=False)), [(prompt, 7)])
+    prompt = FAMILY.prompt_of(23, seed=7)
+    (out,), params, _ = FAMILY.serve((TOY, FAMILY.runtime(chunked_prefill=False)), [(prompt, 7)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     for i in range(len(out) - 1):
         assert np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max() < LOGIT_TOL
@@ -144,7 +145,8 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
     the first layer's of each to the reference's.  As stated both read
     (nearly) nothing; a state STORED in bfloat16, and a gate TAKEN in
     bfloat16, each passes the margin rule's tokens or not, and FAILS its own
-    limit, through the harness's own comparison."""
+    limit, through the harness's own comparison.  (The configuration file's
+    rehearsal sizes, and each fault another program: builds of its own.)"""
     import asyncio
     import dataclasses
 
@@ -178,7 +180,7 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
 
     async def run():
         engine = InferenceEngine(served, replace(rt, compilation_cache=False), seed=3,
-                                 params=seeded(served, key=5))
+                                 params=FAMILY.seeded(served, key=5))
         await engine.start()
         try:
             async def one(p):
@@ -214,8 +216,9 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
 
 
 def test_prefix_reuse_is_declined_and_counted():
-    prompt = prompt_of(40, seed=5)
-    outs, _, counters = serve((TOY, runtime(prefix_cache=True)), [(prompt, 3), (prompt, 3)])
+    """(The prefix cache on is another runtime: a build of its own.)"""
+    prompt = FAMILY.prompt_of(40, seed=5)
+    outs, _, counters = FAMILY.serve((TOY, FAMILY.runtime(prefix_cache=True)), [(prompt, 3), (prompt, 3)])
     assert outs[0] == outs[1]
     assert counters["prefix_reuse_declined_recurrent"] >= 1 and counters["prefix_hits"] == 0
 
@@ -223,34 +226,35 @@ def test_prefix_reuse_is_declined_and_counted():
 def test_the_paged_decode_kernel_reads_a_head_of_256_with_8_query_heads_a_kv_head(monkeypatch):
     """The published attention shape (16 query heads over 2 KV heads of 256)
     is inside the decode read's rule and a value head of 128 inside the
-    delta step's: in interpret mode BOTH kernels serve what XLA serves."""
+    delta step's: in interpret mode BOTH kernels serve what XLA serves.
+    (Another configuration under two implementations: builds of its own.)"""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     wide = replace(TOY, attn_head_dim=256, n_heads=16, n_kv_heads=2, n_layers=4,
                    layer_types=TOY.layer_types[:4], gdn_d_v=128)
-    params = seeded(wide)
-    prompt = prompt_of(29, seed=9)
-    (xla,), _, _ = serve((wide, runtime(attention_impl="xla")), [(prompt, 9)], params=params)
+    params = FAMILY.seeded(wide)
+    prompt = FAMILY.prompt_of(29, seed=9)
+    (xla,), _, _ = FAMILY.serve((wide, FAMILY.runtime(attention_impl="xla")), [(prompt, 9)], params=params)
     before = dict(KERNEL_TRACES)
     spy = Spy(monkeypatch)
-    engine = InferenceEngine(wide, runtime(attention_impl="pallas_interpret"), params=params)
+    engine = InferenceEngine(wide, FAMILY.runtime(attention_impl="pallas_interpret"), params=params)
     assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "pallas_interpret")
-    (out,), _, _ = serve(
-        (wide, runtime(attention_impl="pallas_interpret")), [(prompt, 9)], params=params)
+    (out,), _, _ = FAMILY.serve(
+        (wide, FAMILY.runtime(attention_impl="pallas_interpret")), [(prompt, 9)], params=params)
     assert out == xla
     for kernel in ("paged_decode", "delta_step"):
         assert KERNEL_TRACES[(kernel, "interpreted")] > before.get((kernel, "interpreted"), 0)
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, wide, prompt + out)
+    want = FAMILY.reference_logits(params, wide, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
 
 
 # ------------------------------------------------ (d) the controls, each of which has to FAIL
 def _forward_error(config=TOY, params=None):
-    params = seeded(key=1) if params is None else params
+    params = FAMILY.seeded(key=1) if params is None else params
     tokens = np.random.default_rng(3).integers(3, TOY.vocab_size, (1, 40)).astype(np.int32)
     want = ARCH.forward_logits(params, TOY, tokens, np.asarray([40], np.int32))
-    return float(np.abs(np.asarray(forward(params, config, tokens)[0]) - want).max())
+    return float(np.abs(np.asarray(FAMILY.forward(params, config, tokens)[0]) - want).max())
 
 
 def _bfloat16_gate(monkeypatch):
@@ -322,11 +326,11 @@ def test_a_bfloat16_state_fails_the_reference(monkeypatch):
     decode steps' logits miss the tolerance that the float32 state passes
     (test_prefill_then_decode_through_the_engine...)."""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
+    prompt = FAMILY.prompt_of(37)
     rounded = replace(TOY, state_dtype="bfloat16")
-    (out,), params, _ = serve((rounded, runtime()), [(prompt, 9)])
+    (out,), params, _ = FAMILY.serve((rounded, FAMILY.runtime()), [(prompt, 9)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     worst = max(float(np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max())
                 for i in range(len(out) - 1))

@@ -1,7 +1,7 @@
 """Gated DeltaNet hybrid (Qwen3-Next's kind): the loader on a fabricated checkpoint, whole and
 as a share; and the older kinds' dispatch programs, which must be the parent commit's.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_gdn_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -29,10 +29,10 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
-from tests._gdn_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, forward, prompt_of, reference_logits,
-    runtime, seeded, serve,
-)
+from tests.arch_harness import GDN_MOE as FAMILY
+from tests.arch_harness import Spy, both_forms_at_toy_size  # noqa: F401 - an autouse fixture
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
 # ------------------------------------------------ (f) the loader
@@ -119,7 +119,7 @@ def test_a_fabricated_qwen3_next_checkpoint_loads_whole_and_as_a_share(tmp_path,
     from calfkit_tpu.inference.sharding import param_shardings
 
     whole = replace(TOY, n_routed_experts=8, n_experts_total=0, expert_first=0)
-    tree = jax.tree.map(np.asarray, seeded(whole, key=12))
+    tree = jax.tree.map(np.asarray, FAMILY.seeded(whole, key=12))
     _checkpoint(tmp_path, whole, tree)
     config = replace(config_from_hf(tmp_path, share), dtype="float32", gdn_chunk_size=8)
     rank, of = share or (0, 1)
@@ -142,7 +142,7 @@ def test_a_fabricated_qwen3_next_checkpoint_loads_whole_and_as_a_share(tmp_path,
     for (path, got), expected in zip(jax.tree.leaves_with_path(loaded), jax.tree.leaves(want)):
         assert np.array_equal(np.asarray(got), expected), path
     tokens = np.random.default_rng(1).integers(3, 128 // of, (1, 24)).astype(np.int32)
-    logits = forward(loaded, config, tokens)[0]
+    logits = FAMILY.forward(loaded, config, tokens)[0]
     reference = ARCH.forward_logits(loaded, config, tokens, np.asarray([24], np.int32))
     assert np.abs(np.asarray(logits) - reference).max() < LOGIT_TOL
 
@@ -220,7 +220,7 @@ def test_the_three_older_kinds_trace_the_programs_the_parent_traced(monkeypatch,
     three older cells run the parent's programs."""
     monkeypatch.undo()  # the measured limit of the dense form, as the parent had it
     config = {"dense": preset("debug"), "hybrid": HYBRID, "latent-moe": LATENT}[kind]
-    engine = InferenceEngine(config, runtime(attention_impl="xla"))
+    engine = InferenceEngine(config, FAMILY.runtime(attention_impl="xla"))
     for name, jaxpr in _programs(engine).items():
         text = str(jaxpr)
         assert "gdn" not in text and "out_gate" not in text
@@ -240,7 +240,7 @@ TRACED_SINCE_PR_34 = {
 @pytest.mark.parametrize("kind", sorted(TRACED_SINCE_PR_34))
 def test_a_grouped_chunk_s_ragged_program_is_what_pr_34_traced(kind):
     config = {"gdn-moe": TOY, "latent-moe": LATENT}[kind]
-    engine = InferenceEngine(config, runtime(attention_impl="xla"))
+    engine = InferenceEngine(config, FAMILY.runtime(attention_impl="xla"))
     assert not moe.dense_form(2 * engine.runtime.prefill_chunk, config)
     text = str(_programs(engine)["ragged"])
     assert "ragged_dot" in text and "dynamic_update_slice" in text
@@ -249,7 +249,7 @@ def test_a_grouped_chunk_s_ragged_program_is_what_pr_34_traced(kind):
 
 def test_the_new_kind_s_programs_name_their_scopes():
     text = {name: jaxpr.pretty_print(name_stack=True) for name, jaxpr in _programs(
-        InferenceEngine(TOY, runtime(attention_impl="xla"))).items()}
+        InferenceEngine(TOY, FAMILY.runtime(attention_impl="xla"))).items()}
     for scope in ("gdn", "in_proj", "conv", "state", "gate_norm", "out_proj", "qk_norm",
                   "out_gate", "moe", "router", "experts", "combine", "shared"):
         assert f"{scope}" in text["decode"], scope

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.manifest import load_architecture
 from calfkit_tpu.inference import mamba as mm
 from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference.config import (
@@ -34,27 +33,10 @@ from calfkit_tpu.inference.config import (
     preset,
 )
 from calfkit_tpu.inference.engine import InferenceEngine
+from tests.arch_harness import HYBRID_MAMBA, Spy, standing  # noqa: F401 - a fixture
 
-ARCH = load_architecture("granite-hybrid")
-
-# two periods of (mamba, mamba, attention): every kind of layer twice, the
-# multipliers and the position rule of the real model, float32 throughout
-TOY = ModelConfig(
-    name="toy-hybrid", vocab_size=128, d_model=32, n_layers=6, n_heads=4, n_kv_heads=2,
-    d_ff=64, layer_types=("mamba", "mamba", "attention") * 2,
-    mamba_n_heads=4, mamba_d_head=16, mamba_d_state=16, mamba_n_groups=2, mamba_d_conv=4,
-    mamba_chunk_size=8, dtype="float32", position_embedding="none",
-    attention_multiplier=0.25, embedding_multiplier=12.0, residual_multiplier=0.22,
-    logits_scaling=8.0, tie_embeddings=True, max_seq_len=1024,
-)
-# float32 against float32: the two sides differ in the ORDER of sums (the
-# chunked scan against the recurrence, bucketed attention against whole
-# rows).  Two readings set the limit, over 512 generated positions of the
-# run in (e), logits up to 0.54 in size: the float32 state reads 2.4e-7 at
-# the worst position, the bfloat16 state 3.7e-3 (and passes 1e-4 at its
-# 27th step).  2e-5 stands a factor of 80 above the first and 180 below
-# the second.
-LOGIT_TOL = 2e-5
+FAMILY = HYBRID_MAMBA
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 # the toy inside BOTH kernels' rules, for the ``pallas_interpret`` cases: heads
 # of 64 on pages of 16 for the paged decode read, and a state of whole tiles
 # (32 heads of 8 in two groups, 128 lines a group, d_state 128) for the SSM step
@@ -81,76 +63,6 @@ def ssm_kernel_traces(fresh: bool = False) -> int:
     return KERNEL_TRACES["ssm_step", "interpreted"]
 
 
-def runtime(**kw) -> RuntimeConfig:
-    base = dict(
-        max_batch_size=2, max_seq_len=128, kv_layout="paged", page_size=8,
-        chunked_prefill=True, prefill_chunk=16, window_buckets=(32, 128),
-        compilation_cache=False, max_prefill_wave=2, decode_steps_per_dispatch=4,
-    )
-    base.update(kw)
-    return RuntimeConfig(**base)
-
-
-def prompt_of(n: int, seed: int = 0) -> list[int]:
-    return [int(t) for t in np.random.default_rng(seed).integers(3, TOY.vocab_size, n)]
-
-
-class Spy:
-    """Records every ``lm_logits`` a program computes, in order: the
-    engine gives out tokens, and these tests compare logits."""
-
-    def __init__(self, monkeypatch):
-        self.seen: list[np.ndarray] = []
-        original = M.lm_logits
-
-        def spied(x, params, eps, *rest):
-            logits = original(x, params, eps, *rest)
-            jax.debug.callback(lambda l: self.seen.append(np.asarray(l)), logits, ordered=True)
-            return logits
-
-        monkeypatch.setattr(M, "lm_logits", spied)
-
-    def of_request(self, prompt: list[int], out: list[int], chunk: int) -> np.ndarray:
-        """The logits that chose ``out``: the prompt's last position from
-        the chunk that held it, then one row of each decode step: the row
-        whose argmax chain is the served tokens."""
-        chunks = [s for s in self.seen if s.shape[1] == chunk]
-        steps = [s for s in self.seen if s.shape[1] == 1]
-        last = len(prompt) - 1
-        rows = [chunks[last // chunk][0, last % chunk]]
-        slot = next(
-            b for b in range(steps[0].shape[0])
-            if all(int(np.argmax(steps[i][b, 0])) == out[i + 1] for i in range(len(out) - 1))
-        )
-        rows += [steps[i][slot, 0] for i in range(len(out) - 1)]
-        return np.stack(rows)
-
-
-def serve(engine_args: tuple, requests: list[tuple[list[int], int]], sequential: bool = True):
-    """Outputs of ``requests`` (prompt, max_new_tokens) through one engine."""
-    async def run():
-        engine = InferenceEngine(*engine_args, seed=3)
-        await engine.start()
-        try:
-            async def one(prompt, n):
-                return [t async for t in engine.generate(prompt, max_new_tokens=n)]
-
-            if sequential:
-                outs = [await one(p, n) for p, n in requests]
-            else:
-                outs = list(await asyncio.gather(*[one(p, n) for p, n in requests]))
-            return outs, engine.params, engine.stats.counters()
-        finally:
-            await engine.stop()
-
-    return asyncio.run(run())
-
-
-def reference_logits(params, config: ModelConfig, seq: list[int]) -> np.ndarray:
-    tokens = np.asarray([seq], np.int32)
-    return ARCH.forward_logits(params, config, tokens, np.asarray([len(seq)], np.int32))[0]
-
-
 # ----------------------------------------------------------------- (a)
 def test_full_forward_agrees_with_the_reference():
     """The program's whole forward (one chunk, zero state) against the
@@ -173,20 +85,29 @@ def test_full_forward_agrees_with_the_reference():
 
 # ----------------------------------------------------------------- (b)
 @pytest.mark.parametrize("impl", sorted(KERNEL_CASES))
-def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch, impl):
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(
+        monkeypatch, request, impl):
     """Paged, chunked with a chunk (16) smaller than the prompt (37) and an
     SSD block (8) smaller than the chunk; 21 generated tokens cross five
     dispatches of four steps.  Every generated position's logits against
     the reference's full forward of prompt + output.  Under
     ``pallas_interpret`` the decode steps' pass over the SSM state is the
-    kernel's (and the paged decode read the other kernel's)."""
+    kernel's (and the paged decode read the other kernel's: the toy inside
+    both kernels' rules is another configuration, a build of its own)."""
     config, over = KERNEL_CASES[impl]
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
+    prompt = FAMILY.prompt_of(37)
     before = ssm_kernel_traces(fresh=True)
-    (out,), params, counters = serve((config, runtime(**over)), [(prompt, 21)])
+    if impl == "xla":
+        served = request.getfixturevalue("standing").serve([(prompt, 21)])
+        (out,), params, spy = served.outs, served.params, served.spy
+        counters = {**served.counters, **{n: served.added[n] for n in (
+            "pipeline_drains_wave", "wave_landings_deferred")}}
+    else:
+        spy = Spy(monkeypatch)
+        (out,), params, counters = FAMILY.serve(
+            (config, FAMILY.runtime(**over)), [(prompt, 21)])
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, config, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    want = FAMILY.reference_logits(params, config, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
     # one wave, onto an engine with no active rows: nothing to ride, its landing is a sync
@@ -195,14 +116,14 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
     assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
 
-def test_the_prompt_s_logits_agree_chunk_by_chunk(monkeypatch):
+def test_the_prompt_s_logits_agree_chunk_by_chunk(standing):
     """All 37 prompt positions, from the three chunks that carried the
     state between them."""
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(37, seed=5)
-    (out,), params, _ = serve((TOY, runtime()), [(prompt, 2)])
+    prompt = FAMILY.prompt_of(37, seed=5)
+    served = standing.serve([(prompt, 2)])
+    (out,), params, spy = served.outs, served.params, served.spy
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
-    want = reference_logits(params, TOY, prompt + out)[: len(prompt)]
+    want = FAMILY.reference_logits(params, TOY, prompt + out)[: len(prompt)]
     assert np.abs(chunks - want).max() < LOGIT_TOL
 
 
@@ -243,14 +164,16 @@ def test_chunked_scan_agrees_with_the_recurrence_on_ragged_rows():
 def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
     """One slot: the second request lands where the first one's state
     still lies.  Its logits are those of an engine that never served the
-    first: the landing overwrites the whole of a slot's state."""
-    first, second = prompt_of(29, seed=1), prompt_of(21, seed=2)
+    first: the landing overwrites the whole of a slot's state.  (One slot is
+    another runtime, and the fresh engine is what the reused one is held to:
+    two builds of its own.)"""
+    first, second = FAMILY.prompt_of(29, seed=1), FAMILY.prompt_of(21, seed=2)
     spy = Spy(monkeypatch)
-    (_, out), _, counters = serve((TOY, runtime(max_batch_size=1)), [(first, 9), (second, 9)])
+    (_, out), _, counters = FAMILY.serve((TOY, FAMILY.runtime(max_batch_size=1)), [(first, 9), (second, 9)])
     reused = [s for s in spy.seen][-8:]
     assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (2, 0)
     spy.seen.clear()
-    (fresh_out,), _, _ = serve((TOY, runtime(max_batch_size=1)), [(second, 9)])
+    (fresh_out,), _, _ = FAMILY.serve((TOY, FAMILY.runtime(max_batch_size=1)), [(second, 9)])
     fresh = spy.seen[-8:]
     assert out == fresh_out
     for a, b in zip(reused, fresh):
@@ -261,9 +184,10 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
 def test_a_frozen_row_s_state_is_bit_equal_across_a_dispatch(impl):
     """Row 1 is not active: a decode dispatch leaves its SSM and conv
     state bit for bit, while row 0's moves.  (The kernel neither reads nor
-    writes such a row.)"""
+    writes such a row.)  (It overwrites the engine's state by hand: an
+    engine of its own.)"""
     config, over = KERNEL_CASES[impl]
-    engine = InferenceEngine(config, runtime(**over), seed=3)
+    engine = InferenceEngine(config, FAMILY.runtime(**over), seed=3)
     assert engine._ssm_impl == impl
     ssm, conv = engine._state
     engine._state = (
@@ -287,22 +211,23 @@ def test_control_a_bfloat16_state_fails_the_tolerance(monkeypatch, impl):
     activations as before) the same comparison fails within 512 steps.
     Under ``pallas_interpret`` the float32 state takes the kernel and
     passes; the bfloat16 state is outside the kernel's rule (a float32 pass
-    or none), reads through XLA, and fails as it does there."""
+    or none), reads through XLA, and fails as it does there.  (A control and
+    512 steps in a longer window: builds of its own.)"""
     float32, over = KERNEL_CASES[impl]
     config = replace(float32, state_dtype="bfloat16")
     spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
-    rt = runtime(max_seq_len=1024, window_buckets=(128, 1024), **over)
+    prompt = FAMILY.prompt_of(37)
+    rt = FAMILY.runtime(max_seq_len=1024, window_buckets=(128, 1024), **over)
     assert InferenceEngine(config, rt)._ssm_impl == "xla"
-    (out,), params, _ = serve((config, rt), [(prompt, 512)])
+    (out,), params, _ = FAMILY.serve((config, rt), [(prompt, 512)])
     got = spy.of_request(prompt, out, 16)
-    want = reference_logits(params, float32, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
+    want = FAMILY.reference_logits(params, float32, prompt + out)[len(prompt) - 1: len(prompt) - 1 + len(out)]
     assert np.abs(got - want).max() > LOGIT_TOL
     spy.seen.clear()
     before = ssm_kernel_traces(fresh=True)
-    (out32,), params, _ = serve((float32, rt), [(prompt, 512)])  # and the stated precision passes
+    (out32,), params, _ = FAMILY.serve((float32, rt), [(prompt, 512)])  # and the stated precision passes
     got = spy.of_request(prompt, out32, 16)
-    want = reference_logits(params, float32, prompt + out32)[len(prompt) - 1:][: len(out32)]
+    want = FAMILY.reference_logits(params, float32, prompt + out32)[len(prompt) - 1:][: len(out32)]
     assert np.abs(got - want).max() < LOGIT_TOL
     assert (ssm_kernel_traces() > before) == (impl == "pallas_interpret")
 
@@ -375,7 +300,7 @@ def test_a_checkpoint_in_hf_names_loads_to_the_tree_the_reference_agrees_with(tm
     for got, want in zip(jax.tree.leaves(loaded), jax.tree.leaves(params)):
         assert got.dtype == want.dtype and np.array_equal(np.asarray(got), np.asarray(want))
     # and the tree that came through HF's fused layouts serves what the reference computes
-    tokens = np.asarray([prompt_of(24, seed=9)], np.int32)
+    tokens = np.asarray([FAMILY.prompt_of(24, seed=9)], np.int32)
     lens = np.asarray([24], np.int32)
     logits, _, _ = M.forward(
         loaded, config, jnp.asarray(tokens), jnp.arange(24, dtype=jnp.int32)[None],
@@ -395,7 +320,7 @@ def test_a_checkpoint_in_hf_names_loads_to_the_tree_the_reference_agrees_with(tm
 ])
 def test_what_cannot_keep_the_state_is_refused_at_construction(option, kwargs):
     with pytest.raises(UnsupportedWithRecurrentLayers, match=option):
-        InferenceEngine(TOY, runtime(**kwargs))
+        InferenceEngine(TOY, FAMILY.runtime(**kwargs))
 
 
 def test_routed_experts_are_refused_by_the_loader(tmp_path):
@@ -410,20 +335,18 @@ def test_routed_experts_are_refused_by_the_loader(tmp_path):
 def test_a_cached_prefix_is_declined_and_counted():
     """With the prefix cache on, a model with recurrent layers plans no
     reuse: the second, identical prompt is prefilled whole, counted once,
-    and answers what the first did."""
-    prompt = prompt_of(40, seed=3)
-    (a, b), _, counters = serve((TOY, runtime(prefix_cache=True)), [(prompt, 6), (prompt, 6)])
+    and answers what the first did.  (The prefix cache on is another
+    runtime: a build of its own.)"""
+    prompt = FAMILY.prompt_of(40, seed=3)
+    (a, b), _, counters = FAMILY.serve((TOY, FAMILY.runtime(prefix_cache=True)), [(prompt, 6), (prompt, 6)])
     assert a == b
     assert counters["prefix_reuse_declined_recurrent"] == 1
     assert counters["prefix_hits"] == 0 and counters["prefix_reused_tokens"] == 0
     assert (counters["pipeline_drains_wave"], counters["wave_landings_deferred"]) == (2, 0)
 
 
-def test_the_new_counters_reach_metrics():
-    from calfkit_tpu.observability.metrics import metrics_text
-
-    serve((TOY, runtime()), [(prompt_of(20), 3)])
-    text = metrics_text()
+def test_the_new_counters_reach_metrics(standing):
+    text = standing.serve([(FAMILY.prompt_of(20), 3)]).metrics
     for name in ("calfkit_engine_pipeline_drains_wave_total",
                  "calfkit_engine_wave_landings_deferred_total",
                  "calfkit_engine_prefix_reuse_declined_recurrent_total",
@@ -433,26 +356,27 @@ def test_the_new_counters_reach_metrics():
 
 @pytest.mark.parametrize("layout, chunked", [("dense", False), ("dense", True), ("paged", False)])
 def test_the_other_layouts_serve_the_same_logits(monkeypatch, layout, chunked):
-    """The dense KV layout and single-shot prefill thread the state too."""
+    """The dense KV layout and single-shot prefill thread the state too
+    (each another lane: a build of its own)."""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(23, seed=7)
-    rt = runtime(kv_layout=layout, chunked_prefill=chunked)
-    (out,), params, _ = serve((TOY, rt), [(prompt, 7)])
+    prompt = FAMILY.prompt_of(23, seed=7)
+    rt = FAMILY.runtime(kv_layout=layout, chunked_prefill=chunked)
+    (out,), params, _ = FAMILY.serve((TOY, rt), [(prompt, 7)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     for i in range(len(out) - 1):
         assert np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max() < LOGIT_TOL
 
 
-def test_concurrent_rows_do_not_touch_each_other_s_state(monkeypatch):
+def test_concurrent_rows_do_not_touch_each_other_s_state(standing):
     """Two requests of different lengths decode side by side (one wave of
     two ragged rows, then one dispatch for both): each one's tokens are
     what it gets alone."""
-    a, b = prompt_of(37, seed=11), prompt_of(18, seed=12)
-    (alone_a,), _, _ = serve((TOY, runtime()), [(a, 10)])
-    (alone_b,), _, _ = serve((TOY, runtime()), [(b, 14)])
-    together, _, _ = serve((TOY, runtime()), [(a, 10), (b, 14)], sequential=False)
+    a, b = FAMILY.prompt_of(37, seed=11), FAMILY.prompt_of(18, seed=12)
+    (alone_a,) = standing.serve([(a, 10)]).outs
+    (alone_b,) = standing.serve([(b, 14)]).outs
+    together = standing.serve([(a, 10), (b, 14)], sequential=False).outs
     assert together == [alone_a, alone_b]
 
 
@@ -482,15 +406,15 @@ def test_heads_of_64_serve_the_same_tokens_through_the_kernel():
     requests than slots, so chunks ride live dispatches (the ragged
     program's decode loop) and rows decode on alone (the decode program);
     the tokens are equal and the kernel that reads live pages in place was
-    traced."""
+    traced.  (Heads of 64 under two implementations: two builds of its own.)"""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     rt = dict(page_size=16, window_buckets=(64, 128))
-    requests = [(prompt_of(9 + 13 * i, seed=20 + i), 6 + 5 * i) for i in range(4)]
-    want, _, _ = serve((TOY64, runtime(**rt)), requests, sequential=False)
+    requests = [(FAMILY.prompt_of(9 + 13 * i, seed=20 + i), 6 + 5 * i) for i in range(4)]
+    want, _, _ = FAMILY.serve((TOY64, FAMILY.runtime(**rt)), requests, sequential=False)
     before = KERNEL_TRACES["paged_decode", "interpreted"]
-    got, _, counters = serve(
-        (TOY64, runtime(attention_impl="pallas_interpret", **rt)), requests, sequential=False)
+    got, _, counters = FAMILY.serve(
+        (TOY64, FAMILY.runtime(attention_impl="pallas_interpret", **rt)), requests, sequential=False)
     assert got == want
     assert [len(out) for out in got] == [n for _, n in requests]
     assert KERNEL_TRACES["paged_decode", "interpreted"] > before
@@ -528,7 +452,7 @@ def test_the_paged_decode_read_is_selected_by_platform_and_shape(
     )
     answers = []
     for c in (config, dense):
-        engine = InferenceEngine(c, runtime(page_size=page, prefill_chunk=32, window_buckets=(128,)))
+        engine = InferenceEngine(c, FAMILY.runtime(page_size=page, prefill_chunk=32, window_buckets=(128,)))
         monkeypatch.setattr(engine, "mesh", SimpleNamespace(size=devices))
         monkeypatch.setattr(
             jax, "devices", lambda *a: [SimpleNamespace(platform=platform)] if not a else real)

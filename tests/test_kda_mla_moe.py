@@ -3,7 +3,7 @@ delta rule with a decay a key channel in its two forms, the group-limited
 gate, the share of an expert layer, the description, and the controls that
 each have to FAIL the tolerance.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_kda_mla_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -25,9 +25,10 @@ from calfkit_tpu.inference.config import (
     preset,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
-from tests._kda_mla_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, both_forms_at_toy_size, forward, seeded,
-)
+from tests.arch_harness import KDA_MLA_MOE as FAMILY
+from tests.arch_harness import both_forms_at_toy_size  # noqa: F401 - an autouse fixture
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -105,7 +106,7 @@ def test_padding_rows_move_neither_state():
     """Positions past a row's ``n_valid`` (g = 0, beta = 0 there): the state
     is the one its own positions left, and the conv tail its last inputs."""
     c = TOY
-    lp = jax.tree.map(lambda a: a[0], seeded(key=2)["layers"]["gdn"])
+    lp = jax.tree.map(lambda a: a[0], FAMILY.seeded(key=2)["layers"]["gdn"])
     h = jax.random.normal(jax.random.key(0), (2, 24, c.d_model))
     state = make_recurrent_state(c, 2)
     n = jnp.asarray([24, 13])
@@ -121,7 +122,7 @@ def test_a_chunk_then_steps_is_all_steps():
     steps, against 21 steps from zero state (the conv tail, the state, every
     output)."""
     c = TOY
-    lp = jax.tree.map(lambda a: a[0], seeded(key=4)["layers"]["gdn"])
+    lp = jax.tree.map(lambda a: a[0], FAMILY.seeded(key=4)["layers"]["gdn"])
     h = jax.random.normal(jax.random.key(1), (2, 21, c.d_model))
     im = jnp.int32(0)
     steps, state = [], make_recurrent_state(c, 2)
@@ -142,7 +143,7 @@ def test_the_gate_is_bounded_and_spans_its_range_by_channel():
     """``g`` lies in [kda_lower_bound, 0) and, as the architecture file seeds
     it, differs WITHIN a head by more than it differs between heads' means."""
     c = TOY
-    lp = jax.tree.map(lambda a: a[0], seeded(key=3)["layers"]["gdn"])
+    lp = jax.tree.map(lambda a: a[0], FAMILY.seeded(key=3)["layers"]["gdn"])
     h = jax.random.normal(jax.random.key(2), (4, 32, c.d_model))
     g = np.asarray(gdn._decay(h, lp, c))
     assert g.shape == (4, 32, c.gdn_n_v_heads, c.gdn_d_k)
@@ -160,10 +161,10 @@ def test_full_forward_agrees_with_the_reference(monkeypatch, form):
     the own positions alone."""
     if form == "dense":
         monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 4096)
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens = np.random.default_rng(2).integers(3, TOY.vocab_size, (2, 40)).astype(np.int32)
     lens = np.asarray([40, 27], np.int32)
-    logits, (c_side, r_side), (S, conv), (counts, _, absent, reach) = forward(
+    logits, (c_side, r_side), (S, conv), (counts, _, absent, reach) = FAMILY.forward(
         params, TOY, tokens, lens, moe=moe.moe_stats_init(TOY))
     assert moe.dense_form(2 * 40, TOY) == (form == "dense")
     # ONE latent a token in the 2 latent layers alone; the state pair of the 4 others
@@ -208,7 +209,7 @@ def test_route_with_groups_is_the_reference_s_choice():
     against the architecture file's ``_chosen`` and its weights, on 200
     tokens of the seeded gate (ties apart: there are none in float32)."""
     c = TOY
-    lp = jax.tree.map(lambda a: a[0], seeded(key=5)["layers"]["moe"])
+    lp = jax.tree.map(lambda a: a[0], FAMILY.seeded(key=5)["layers"]["moe"])
     h = jax.random.normal(jax.random.key(3), (200, c.d_model))
     chosen, weights = moe.route(h, lp, c)
     with jax.default_matmul_precision("highest"):
@@ -257,10 +258,10 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
 
 # ------------------------------------------------ (d) the controls, each of which has to FAIL
 def _forward_error(config=TOY, params=None):
-    params = seeded(key=1) if params is None else params
+    params = FAMILY.seeded(key=1) if params is None else params
     tokens = np.random.default_rng(3).integers(3, TOY.vocab_size, (1, 40)).astype(np.int32)
     want = ARCH.forward_logits(params, TOY, tokens, np.asarray([40], np.int32))
-    return float(np.abs(np.asarray(forward(params, config, tokens)[0]) - want).max())
+    return float(np.abs(np.asarray(FAMILY.forward(params, config, tokens)[0]) - want).max())
 
 
 def _decay_by_head(monkeypatch):

@@ -3,7 +3,7 @@ through the engine: prefill then decode on the latent pool and the per-slot
 state against the plain reference, what the served rows leave behind, what
 the engine refuses for this model and why.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_kda_mla_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -24,18 +24,16 @@ from calfkit_tpu.inference.config import (
     UnsupportedWithRecurrentLayers,
 )
 from calfkit_tpu.inference.engine import InferenceEngine
-from tests._kda_mla_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, Spy, both_forms_at_toy_size, prompt_of, reference_logits, runtime,
-    seeded, serve,
-)
+from tests.arch_harness import KDA_MLA_MOE as FAMILY
+from tests.arch_harness import Spy, both_forms_at_toy_size, standing  # noqa: F401 - fixtures
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
 @pytest.fixture(scope="module")
-def one_engine():
-    """``(TOY, runtime())`` served once for the tests that would each build it."""
-    from tests._gdn_moe import served_in_three_phases
-
-    return served_in_three_phases(Spy, (TOY, runtime()), seeded(TOY), prompt_of)
+def one_engine(standing):
+    """What three suites read of the module's ONE engine."""
+    return FAMILY.served_in_three_phases(standing)
 
 
 def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(one_engine):
@@ -45,10 +43,10 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(one_en
     one-pass step on the carried state, the absorbed read of the latent pool,
     the dense expert form) against the reference's full forward of prompt +
     output; one engine holds a latent pool AND a recurrent state."""
-    spy, prompt = one_engine.seen[0], prompt_of(37)
+    spy, prompt = one_engine.seen[0], FAMILY.prompt_of(37)
     out, params, counters = one_engine.first, one_engine.params, one_engine.counters[0]
     got = Spy.of_request(spy, prompt, out, 16)
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
     assert np.abs(chunks - want[: len(prompt)]).max() < LOGIT_TOL
@@ -68,18 +66,18 @@ def test_a_reused_slot_starts_from_zero_state_and_two_rows_do_not_mix(one_engine
     spy, params = one_engine.seen[1], one_engine.params  # the logits of the three served alone
     for prompt, out in zip((p for p, _ in one_engine.requests), one_engine.alone):
         got = Spy.of_request(spy, prompt, out, 16)
-        want = reference_logits(params, TOY, prompt + out)
+        want = FAMILY.reference_logits(params, TOY, prompt + out)
         assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < LOGIT_TOL
     assert one_engine.together == one_engine.alone[:2]
 
 
-@pytest.mark.slow  # a second lane of the same mixers (30 s: the offline lane runs it)
 def test_single_shot_prefill_serves_the_same_logits(monkeypatch):
+    """(Single-shot prefill is another lane: a build of its own.)"""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(23, seed=7)
-    (out,), params, _ = serve((TOY, runtime(chunked_prefill=False)), [(prompt, 7)])
+    prompt = FAMILY.prompt_of(23, seed=7)
+    (out,), params, _ = FAMILY.serve((TOY, FAMILY.runtime(chunked_prefill=False)), [(prompt, 7)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     for i in range(len(out) - 1):
         assert np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max() < LOGIT_TOL
@@ -89,25 +87,18 @@ def test_a_bfloat16_state_fails_the_reference(monkeypatch):
     """``S`` rounded to bfloat16 where a chunk or a step leaves it: the
     decode steps' logits miss the tolerance that the float32 state passes."""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
+    prompt = FAMILY.prompt_of(37)
     rounded = replace(TOY, state_dtype="bfloat16")
-    (out,), params, _ = serve((rounded, runtime()), [(prompt, 9)])
+    (out,), params, _ = FAMILY.serve((rounded, FAMILY.runtime()), [(prompt, 9)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     worst = max(float(np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max())
                 for i in range(len(out) - 1))
     assert worst > 10 * LOGIT_TOL
 
 
-@pytest.mark.parametrize("fault", [
-    "none",
-    # the two faults through a live engine cost 75 s of a tier-1 run that PR 40 left 90 s
-    # under its limit: the offline lane runs them; tier-1 holds a bfloat16 state through the
-    # engine below and a bfloat16 gate in tests/test_kda_mla_moe.py
-    pytest.param("state_in_bfloat16", marks=pytest.mark.slow),
-    pytest.param("gate_in_bfloat16", marks=pytest.mark.slow),
-])
+@pytest.mark.parametrize("fault", ["none", "state_in_bfloat16", "gate_in_bfloat16"])
 def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypatch, capsys, fault):
     """The architecture file's second check, at the configuration file's
     rehearsal sizes in float32: it finds the engine that serves the tree it
@@ -115,7 +106,9 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
     their slots and the tokens each held expert was sent, and holds the
     first layer's of each to the reference's.  As stated both read (nearly)
     nothing; a state STORED in bfloat16, and a gate TAKEN in bfloat16, each
-    FAILS its own limit, through the harness's own comparison."""
+    FAILS its own limit, through the harness's own comparison.  (The
+    configuration file's rehearsal sizes, and each fault another program:
+    builds of its own.)"""
     import dataclasses
 
     from benchmarks.reference import agreement
@@ -152,7 +145,7 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
 
     async def run():
         engine = InferenceEngine(served, replace(rt, compilation_cache=False), seed=3,
-                                 params=seeded(served, key=5))
+                                 params=FAMILY.seeded(served, key=5))
         await engine.start()
         try:
             async def one(p):
@@ -194,9 +187,10 @@ def test_what_the_served_rows_leave_in_the_engine_is_held_to_its_limits(monkeypa
 
 def test_prefix_reuse_is_declined_and_counted():
     """Pages hold no recurrent state at their edge: reuse is declined, the
-    second request prefills whole and serves the same tokens."""
-    prompt = prompt_of(40, seed=5)
-    outs, _, counters = serve((TOY, runtime(prefix_cache=True)), [(prompt, 3), (prompt, 3)])
+    second request prefills whole and serves the same tokens.  (The prefix
+    cache on is another runtime: a build of its own.)"""
+    prompt = FAMILY.prompt_of(40, seed=5)
+    outs, _, counters = FAMILY.serve((TOY, FAMILY.runtime(prefix_cache=True)), [(prompt, 3), (prompt, 3)])
     assert outs[0] == outs[1]
     assert counters["prefix_reuse_declined_recurrent"] >= 1 and counters["prefix_hits"] == 0
 
@@ -211,7 +205,7 @@ def test_prefix_reuse_is_declined_and_counted():
 ], ids=["speculative", "tp", "dp", "quantization", "long_context", "dense_layout"])
 def test_what_the_engine_cannot_keep_right_is_refused_with_its_reason(option, reason):
     with pytest.raises(UnsupportedWithRecurrentLayers, match=reason) as raised:
-        InferenceEngine(TOY, runtime(**option))
+        InferenceEngine(TOY, FAMILY.runtime(**option))
     assert "Kimi Delta Attention" in str(raised.value)
 
 
@@ -219,21 +213,22 @@ def test_the_latent_decode_kernel_reads_the_hybrid_s_pool(monkeypatch):
     """A latent of 128 | 64 on pages of 16 is inside the latent read's rule
     and a value head of 128 inside the delta step's: in interpret mode the
     hybrid's ONE kind of attention layer and the state's pass by key channel
-    serve what XLA serves."""
+    serve what XLA serves.  (Another configuration under two
+    implementations: builds of its own.)"""
     from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
 
     wide = replace(TOY, kv_lora_rank=128, qk_rope_head_dim=64, n_layers=3,
                    layer_types=TOY.layer_types[:3], gdn_d_v=128)
-    params = seeded(wide)
-    prompt = prompt_of(29, seed=9)
+    params = FAMILY.seeded(wide)
+    prompt = FAMILY.prompt_of(29, seed=9)
     rt = dict(page_size=16, prefill_chunk=32)
-    (xla,), _, _ = serve((wide, runtime(attention_impl="xla", **rt)), [(prompt, 9)],
+    (xla,), _, _ = FAMILY.serve((wide, FAMILY.runtime(attention_impl="xla", **rt)), [(prompt, 9)],
                          params=params)
     before = dict(KERNEL_TRACES)
-    engine = InferenceEngine(wide, runtime(attention_impl="pallas_interpret", **rt),
+    engine = InferenceEngine(wide, FAMILY.runtime(attention_impl="pallas_interpret", **rt),
                              params=params)
     assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "pallas_interpret")
-    (out,), _, _ = serve((wide, runtime(attention_impl="pallas_interpret", **rt)),
+    (out,), _, _ = FAMILY.serve((wide, FAMILY.runtime(attention_impl="pallas_interpret", **rt)),
                          [(prompt, 9)], params=params)
     assert out == xla
     for kernel in ("latent_decode", "delta_step"):

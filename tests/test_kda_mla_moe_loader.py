@@ -3,7 +3,7 @@ loader takes into the Kimi Delta Attention hybrid's tree, whole and as a
 share, the tower and the extra prediction layer skipped and counted, the
 latent layers' rope columns from interleaved pairs to the tree's halves.
 
-The toy model, its seeding, the tolerance and its reason: ``tests/_kda_mla_moe.py``.
+The toy model, its seeding, the tolerance and its reason: ``tests/arch_harness.py``.
 """
 
 from __future__ import annotations
@@ -18,9 +18,10 @@ import pytest
 
 from calfkit_tpu.inference.config import ModelConfig
 from calfkit_tpu.inference.sharding import make_mesh
-from tests._kda_mla_moe import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    ARCH, LOGIT_TOL, TOY, both_forms_at_toy_size, forward, seeded,
-)
+from tests.arch_harness import KDA_MLA_MOE as FAMILY
+from tests.arch_harness import both_forms_at_toy_size  # noqa: F401 - an autouse fixture
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 WHOLE = replace(TOY, n_routed_experts=16, n_experts_total=0, expert_first=0)
 
@@ -138,7 +139,7 @@ def test_a_fabricated_bailing_hybrid_checkpoint_loads_whole_and_as_a_share(tmp_p
     )
     from calfkit_tpu.inference.sharding import param_shardings
 
-    tree = jax.tree.map(np.asarray, seeded(WHOLE, key=12))
+    tree = jax.tree.map(np.asarray, FAMILY.seeded(WHOLE, key=12))
     _checkpoint(tmp_path, WHOLE, tree)
     config = replace(config_from_hf(tmp_path, share), dtype="float32", gdn_chunk_size=8,
                      kda_sub_block=4)
@@ -164,7 +165,7 @@ def test_a_fabricated_bailing_hybrid_checkpoint_loads_whole_and_as_a_share(tmp_p
     for (path, got), expected in zip(jax.tree.leaves_with_path(loaded), jax.tree.leaves(want)):
         assert np.array_equal(np.asarray(got), expected), path
     tokens = np.random.default_rng(1).integers(3, 128 // of, (1, 40)).astype(np.int32)
-    logits = forward(loaded, config, tokens)[0]
+    logits = FAMILY.forward(loaded, config, tokens)[0]
     reference = ARCH.forward_logits(loaded, config, tokens, np.asarray([40], np.int32))
     assert np.abs(np.asarray(logits) - reference).max() < LOGIT_TOL
 
@@ -172,7 +173,7 @@ def test_a_fabricated_bailing_hybrid_checkpoint_loads_whole_and_as_a_share(tmp_p
 def test_what_the_program_does_not_describe_is_refused_at_the_config(tmp_path):
     from calfkit_tpu.inference.loader import config_from_hf
 
-    _checkpoint(tmp_path, WHOLE, jax.tree.map(np.asarray, seeded(WHOLE, key=1)), extras=False)
+    _checkpoint(tmp_path, WHOLE, jax.tree.map(np.asarray, FAMILY.seeded(WHOLE, key=1)), extras=False)
     raw = json.loads((tmp_path / "config.json").read_text())
     for key, value in (("kda_safe_gate", False), ("no_kda_lora", False), ("q_lora_rank", 64),
                        ("score_function", "softmax"), ("use_mla_nope", True),

@@ -29,17 +29,11 @@ from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference import pallas_attention as PA
 from calfkit_tpu.inference.engine import InferenceEngine
 
-from test_mla_moe import (  # the toy stack, its reference and its limit
-    LOGIT_TOL,
-    TOY,
-    Spy,
-    both_forms_at_toy_size,  # noqa: F401 - autouse here too
-    generated,
-    prompt_of,
-    reference_logits,
-    runtime,
-    serve,
-)
+from test_mla_moe import generated
+from tests.arch_harness import MLA_MOE as FAMILY  # the toy stack, its reference and its limit
+from tests.arch_harness import Spy, both_forms_at_toy_size  # noqa: F401 - autouse here too
+
+LOGIT_TOL, TOY = FAMILY.logit_tol, FAMILY.toy
 
 # name -> (heads, r, dr, page, dtype)
 WIDTHS = {
@@ -276,7 +270,7 @@ IN_RULE = replace(TOY, name="toy-mla-moe-in-rule", kv_lora_rank=128, qk_rope_hea
 
 
 def in_rule_runtime(**kw):
-    return runtime(page_size=16, **kw)
+    return FAMILY.runtime(page_size=16, **kw)
 
 
 @pytest.fixture
@@ -306,46 +300,60 @@ def test_prefill_then_decode_through_the_kernel_agrees_with_the_reference(
     """(b) of tests/test_mla_moe.py with the decode read in the kernel:
     chunks of 16 under a prompt of 37, pages of 16, 21 generated tokens
     across five dispatches of four steps and two windows; every generated
-    position's logits against the float32 reference's expanded forward."""
+    position's logits against the float32 reference's expanded forward.
+    (A latent inside the kernel's rule under two implementations: builds of
+    its own.)"""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
+    prompt = FAMILY.prompt_of(37)
     rt = in_rule_runtime(attention_impl="pallas_interpret")
-    (out,), params, counters = serve((IN_RULE, rt), [(prompt, 21)])
+    (out,), params, counters = FAMILY.serve((IN_RULE, rt), [(prompt, 21)])
     assert kernel_traces()["latent_decode", "interpreted"] >= 1
     assert counters["decode_pages_live"] > 0
     got = spy.of_request(prompt, out, 16)
-    want = generated(reference_logits(params, IN_RULE, prompt + out), prompt, out)
+    want = generated(FAMILY.reference_logits(params, IN_RULE, prompt + out), prompt, out)
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
     # and the XLA read serves the same tokens from the same weights
-    (xla_out,), _, _ = serve((IN_RULE, in_rule_runtime(attention_impl="xla")), [(prompt, 21)])
+    (xla_out,), _, _ = FAMILY.serve((IN_RULE, in_rule_runtime(attention_impl="xla")), [(prompt, 21)])
     assert out == xla_out
+
+
+ONE_SLOT = dict(attention_impl="pallas_interpret", max_batch_size=1)
+
+
+@pytest.fixture(scope="module")
+def cold():
+    """One slot under the kernel, built once: each case's second request served by an
+    engine that never saw the first."""
+    with FAMILY.standing((IN_RULE, in_rule_runtime(**ONE_SLOT))) as engine:
+        yield engine
 
 
 @pytest.mark.parametrize("reused", ["prefix", "slot"])
 def test_a_reused_prefix_or_slot_through_the_kernel_gives_the_reference_logits(
-        monkeypatch, reused):
+        monkeypatch, cold, reused):
     """ONE slot, two requests in turn, so the second decodes where the
     first did.  "prefix": it shares 32 tokens (two latent pages) with the
     first and seeds its scratch from the cached pages; "slot": it shares
     nothing.  Either way its logits are the reference's, whatever the slot
-    and its pages held before, and those of a cold engine."""
-    shared = prompt_of(32, seed=1)
-    first = shared + prompt_of(9, seed=2)
-    second = shared + prompt_of(13, seed=3) if reused == "prefix" else prompt_of(21, seed=4)
+    and its pages held before, and those of a cold engine.  (What a slot held
+    before is the case: the engine that serves both is a build of its own.)"""
+    shared = FAMILY.prompt_of(32, seed=1)
+    first = shared + FAMILY.prompt_of(9, seed=2)
+    second = shared + FAMILY.prompt_of(13, seed=3) if reused == "prefix" else FAMILY.prompt_of(21, seed=4)
     spy = Spy(monkeypatch)
-    rt = in_rule_runtime(attention_impl="pallas_interpret", max_batch_size=1)
-    (_, out), params, counters = serve((IN_RULE, rt), [(first, 5), (second, 9)])
+    (_, out), params, counters = FAMILY.serve(
+        (IN_RULE, in_rule_runtime(**ONE_SLOT)), [(first, 5), (second, 9)])
     assert counters["prefix_hits"] == (reused == "prefix")
     assert counters["prefix_reused_tokens"] == (32 if reused == "prefix" else 0)
     warm = spy.of_request(second, out, 16)
-    want = generated(reference_logits(params, IN_RULE, second + out), second, out)
+    want = generated(FAMILY.reference_logits(params, IN_RULE, second + out), second, out)
     assert np.abs(warm - want).max() < LOGIT_TOL
-    spy.seen.clear()
-    (cold_out,), _, _ = serve((IN_RULE, rt), [(second, 9)])
-    assert cold_out == out
+    served = cold.serve([(second, 9)])
+    (cold_out,) = served.outs
+    assert cold_out == out and served.added["prefix_hits"] == 0
     # the same programs on the same numbers but for the chunks skipped
-    assert np.abs(warm - spy.of_request(second, cold_out, 16)).max() < 1e-6
+    assert np.abs(warm - served.spy.of_request(second, cold_out, 16)).max() < 1e-6
 
 
 # --------------------------------------------------------------------------- #
@@ -394,7 +402,7 @@ def test_a_pool_of_k_and_v_pairs_traces_the_kernel_it_traced_before(kind):
             mamba_d_conv=4, mamba_chunk_size=8, dtype="float32",
             position_embedding="none", attention_multiplier=0.125, max_seq_len=256)
     )
-    engine = InferenceEngine(config, runtime(
+    engine = InferenceEngine(config, FAMILY.runtime(
         prefix_cache=False, attention_impl="pallas", page_size=32, prefill_chunk=32))
     assert engine._attn_impl == "pallas"
     for name, jaxpr in _programs(engine).items():

@@ -689,7 +689,6 @@ def tree_copy(tmp_path_factory):
         ignore=shutil.ignore_patterns("__pycache__"),
     )
     (root / "scripts").mkdir()
-    shutil.copy(REPO / "bench.py", root / "bench.py")
     shutil.copy(REPO / "scripts" / "perf_gate.py",
                 root / "scripts" / "perf_gate.py")
     _seed_violation(root)

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.manifest import load_architecture
 from calfkit_tpu.inference import model as M
 from calfkit_tpu.inference import moe
 from calfkit_tpu.inference.config import (
@@ -40,139 +39,23 @@ from calfkit_tpu.inference.config import (
     preset,
 )
 from calfkit_tpu.inference.engine import InferenceEngine
-from tests import _moe_stack
-
-ARCH = load_architecture("deepseek-mla-moe")
-
-TOY = ModelConfig(
-    name="toy-mla-moe", vocab_size=128, d_model=32, n_layers=3, n_heads=4, n_kv_heads=4,
-    d_ff=64, rope_theta=800000.0, max_seq_len=256, dtype="float32",
-    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
-    n_routed_experts=8, n_experts_per_tok=2, n_shared_experts=1, moe_d_ff=16,
-    first_k_dense=1, routed_scaling_factor=2.446,
+from tests.arch_harness import MLA_MOE as FAMILY
+from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
+    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size, stack_check, standing,
 )
-# float32 against float32: the two sides differ in the ORDER of sums (the
-# absorbed read against the expanded one, grouped experts against every
-# expert masked, bucketed windows against whole rows) and in nothing else: a
-# choice of experts is decided by float32 scores on both sides.  Two
-# readings set the limit, over the 21 generated positions of (b) and (f),
-# logits up to 3.6 in size: the stated precision reads 2.2e-6 at the worst
-# position (4.5e-6 over 96 positions); a bfloat16 latent pool 8.7e-3 (0.61
-# over 96: somewhere an expert flips) and a bfloat16 router product 5.6e-4
-# (the rounded weights of the same experts; a flipped expert would move the
-# logits by its whole weighted output), each past 1e-4 at the first generated
-# position.  1e-4 stands a factor of 22 above the stated precision's
-# largest reading and 5.6 below the nearer of the other two.  (Readings of
-# the first session's tree, gate x2 and a bias of +-0.1; ``seeded`` below
-# draws the same two leaves, and every case holds the limit on its side.)
-LOGIT_TOL = 1e-4
+
+ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
 
-@pytest.fixture(autouse=True)
-def both_forms_at_toy_size(monkeypatch):
-    """The two forms of the expert products cross at 2,048 tokens at the
-    published widths (moe.py); at toy size the limit is two tokens an
-    expert, so that a decode step takes the dense form and a chunk of
-    more than 16 tokens the grouped one, as they do at the real size."""
-    monkeypatch.setattr(moe, "_DENSE_MAX_TOKENS", 2 * TOY.n_routed_experts)
-
-
-def runtime(**kw) -> RuntimeConfig:
-    base = dict(
-        max_batch_size=2, max_seq_len=128, kv_layout="paged", page_size=8,
-        chunked_prefill=True, prefill_chunk=16, window_buckets=(32, 128),
-        compilation_cache=False, max_prefill_wave=2, decode_steps_per_dispatch=4,
-        prefix_cache=True,
-    )
-    base.update(kw)
-    return RuntimeConfig(**base)
-
-
-def seeded(config: ModelConfig = TOY, key: int = 3):
-    """The program's random tree (every matrix at 1/sqrt(fan_in), the bias
-    zero) with the two leaves that decide the routing seeded as the
-    benchmark's architecture file seeds them: the gate at twice that, so the
-    scores spread, and ``e_score_correction_bias`` at some hundredths, NOT
-    zero, so that a program that gets the bias wrong disagrees."""
-    params = M.init_params(config, jax.random.key(key))
-    experts = params["layers"]["moe"]
-    experts["router"] = experts["router"] * 2.0
-    experts["router_bias"] = jax.random.uniform(
-        jax.random.key(key + 100), experts["router_bias"].shape, jnp.float32, -0.1, 0.1)
-    return params
-
-
-def prompt_of(n: int, seed: int = 0) -> list[int]:
-    return [int(t) for t in np.random.default_rng(seed).integers(3, TOY.vocab_size, n)]
-
-
-class Spy:
-    """Records every ``lm_logits`` a program computes, in order: the
-    engine gives out tokens, and these tests compare logits."""
-
-    def __init__(self, monkeypatch):
-        self.seen: list[np.ndarray] = []
-        original = M.lm_logits
-
-        def spied(x, params, eps, *rest):
-            logits = original(x, params, eps, *rest)
-            jax.debug.callback(lambda l: self.seen.append(np.asarray(l)), logits, ordered=True)
-            return logits
-
-        monkeypatch.setattr(M, "lm_logits", spied)
-
-    def of_request(self, prompt: list[int], out: list[int], chunk: int) -> np.ndarray:
-        """The logits that chose ``out``: the prompt's last position from
-        the LAST chunk seen, then one row of each decode step: the first run
-        of steps and the row whose argmax chain is the served tokens (an
-        earlier request's steps may come before it)."""
-        chunks = [s for s in self.seen if s.shape[1] == chunk]
-        steps = [s for s in self.seen if s.shape[1] == 1]
-        last, n = len(prompt) - 1, len(out) - 1
-        first, slot = next(
-            (i0, b) for i0 in range(len(steps) - n + 1) for b in range(steps[0].shape[0])
-            if all(int(np.argmax(steps[i0 + i][b, 0])) == out[i + 1] for i in range(n))
-        )
-        rows = [chunks[-1][0, last % chunk]] + [steps[first + i][slot, 0] for i in range(n)]
-        return np.stack(rows)
-
-
-def serve(engine_args: tuple, requests: list[tuple[list[int], int]], sequential: bool = True,
-          params=None):
-    """Outputs of ``requests`` (prompt, max_new_tokens) through one engine."""
-    async def run():
-        engine = InferenceEngine(
-            *engine_args, seed=3, params=seeded(engine_args[0]) if params is None else params)
-        await engine.start()
-        try:
-            async def one(prompt, n):
-                return [t async for t in engine.generate(prompt, max_new_tokens=n)]
-
-            if sequential:
-                outs = [await one(p, n) for p, n in requests]
-            else:
-                outs = list(await asyncio.gather(*[one(p, n) for p, n in requests]))
-            return outs, engine.params, engine.stats.counters()
-        finally:
-            await engine.stop()
-
-    return asyncio.run(run())
-
-
-def reference_logits(params, config: ModelConfig, seq: list[int]) -> np.ndarray:
-    tokens = np.asarray([seq], np.int32)
-    return ARCH.forward_logits(params, config, tokens, np.asarray([len(seq)], np.int32))[0]
+@pytest.fixture(scope="module")
+def first_served(standing):
+    """The prompt of 37 with 21 new tokens, the first thing the standing engine serves
+    (its prefix cache is ON: a prompt served twice is a hit the second time)."""
+    return standing.serve([(FAMILY.prompt_of(37), 21)])
 
 
 def generated(want: np.ndarray, prompt: list[int], out: list[int]) -> np.ndarray:
     return want[len(prompt) - 1: len(prompt) - 1 + len(out)]
-
-
-def forward(params, config, tokens, **kw):
-    B, S = tokens.shape
-    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    return M.forward(params, config, jnp.asarray(tokens), pos, M.make_empty_cache(config, B, S),
-                     jnp.full((B,), S, jnp.int32), **kw)
 
 
 # ----------------------------------------------------------------- (a)
@@ -180,10 +63,10 @@ def test_full_forward_agrees_with_the_reference():
     """The program's whole forward (one chunk: the expanded algebra, the
     grouped expert products) against the reference, at every own position
     of two ragged rows; the counters count the own positions alone."""
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens = np.random.default_rng(2).integers(3, TOY.vocab_size, (2, 40)).astype(np.int32)
     lens = np.asarray([40, 27], np.int32)
-    logits, (c, k_rope), (counts, _) = forward(
+    logits, (c, k_rope), (counts, _) = FAMILY.forward(
         params, TOY, tokens, moe=moe.moe_stats_init(TOY), n_valid=jnp.asarray(lens))
     # 32 + 8 numbers a token a layer and nothing else: no K or V per head
     assert c.shape == (3, 2, 1, 40, 32) and k_rope.shape == (3, 2, 1, 40, 8)
@@ -195,23 +78,23 @@ def test_full_forward_agrees_with_the_reference():
 
 
 # ----------------------------------------------------------------- (b)
-def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkeypatch):
+def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(first_served):
     """Paged latent pool, chunked with a chunk (16) smaller than the prompt
     (37), pages of 8; 21 generated tokens cross five dispatches of four
     steps and two windows.  Every generated position's logits (the absorbed
     algebra over latent pages, the dense expert form) against the
     reference's expanded full forward of prompt + output."""
-    spy = Spy(monkeypatch)
-    prompt = prompt_of(37)
-    (out,), params, counters = serve((TOY, runtime()), [(prompt, 21)])
+    prompt = FAMILY.prompt_of(37)
+    (out,), params, spy = first_served.outs, first_served.params, first_served.spy
+    counters = {**first_served.added, "latent_cache_bytes": first_served.counters["latent_cache_bytes"]}
     got = spy.of_request(prompt, out, 16)
-    want = generated(reference_logits(params, TOY, prompt + out), prompt, out)
+    want = generated(FAMILY.reference_logits(params, TOY, prompt + out), prompt, out)
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
     # and all 37 prompt positions, from the three chunks that attended the
     # latents the chunks before them left in the scratch
     chunks = np.concatenate([s[0] for s in spy.seen if s.shape[1] == 16])[: len(prompt)]
-    assert np.abs(chunks - reference_logits(params, TOY, prompt + out)[: len(prompt)]).max() < LOGIT_TOL
+    assert np.abs(chunks - FAMILY.reference_logits(params, TOY, prompt + out)[: len(prompt)]).max() < LOGIT_TOL
     # 2 expert layers x 2 experts a token x (37 prompt tokens + 20 decode
     # steps run: five dispatches of four, the row active in all of them)
     assert counters["moe_assignments"] == 2 * 2 * (37 + 20)
@@ -221,12 +104,13 @@ def test_prefill_then_decode_through_the_engine_agrees_with_the_reference(monkey
 
 
 def test_single_shot_prefill_serves_the_same_logits(monkeypatch):
+    """(Single-shot prefill without the prefix cache is another lane: a build of its own.)"""
     spy = Spy(monkeypatch)
-    prompt = prompt_of(23, seed=7)
-    (out,), params, counters = serve(
-        (TOY, runtime(chunked_prefill=False, prefix_cache=False)), [(prompt, 7)])
+    prompt = FAMILY.prompt_of(23, seed=7)
+    (out,), params, counters = FAMILY.serve(
+        (TOY, FAMILY.runtime(chunked_prefill=False, prefix_cache=False)), [(prompt, 7)])
     steps = [s for s in spy.seen if s.shape[1] == 1]
-    want = reference_logits(params, TOY, prompt + out)
+    want = FAMILY.reference_logits(params, TOY, prompt + out)
     slot = next(b for b in range(2) if int(np.argmax(steps[0][b, 0])) == out[1])
     for i in range(len(out) - 1):
         assert np.abs(steps[i][slot, 0] - want[len(prompt) + i]).max() < LOGIT_TOL
@@ -235,7 +119,7 @@ def test_single_shot_prefill_serves_the_same_logits(monkeypatch):
 
 # ----------------------------------------------------------------- (c)
 def _layer(skewed: bool):
-    lp = jax.tree.map(lambda a: a[0], seeded(key=6)["layers"]["moe"])
+    lp = jax.tree.map(lambda a: a[0], FAMILY.seeded(key=6)["layers"]["moe"])
     if skewed:  # a gate that sends most tokens to experts 2 and 5
         lp["router_bias"] = lp["router_bias"].at[jnp.asarray([2, 5])].add(3.0)
     return lp
@@ -271,14 +155,14 @@ def test_grouped_experts_equal_every_expert_masked(monkeypatch, skewed):
     assert np.abs(np.asarray(grouped).reshape(96, -1) - np.asarray(plain)).max() < 1e-5
 
 
-@pytest.mark.parametrize("m", range(_moe_stack.LAYERS))
-@pytest.mark.parametrize("case", _moe_stack.ROUTINGS)
+@pytest.mark.parametrize("m", range(STACK_LAYERS))
+@pytest.mark.parametrize("case", STACK_ROUTINGS)
 def test_grouped_experts_read_their_layer_out_of_the_stack(case, m):
     """The grouped products take the STACKED leaves and the layer's index
     (every expert held): each layer of three gives what the parent's form
     gave on the sliced layer, bit for bit, and what the dense form gives;
     with a group of 0, and a first and a last group of every token."""
-    _moe_stack.check(replace(TOY, n_layers=1 + _moe_stack.LAYERS), case, m)
+    stack_check(replace(TOY, n_layers=1 + STACK_LAYERS), case, m)
 
 
 def test_padding_and_inactive_rows_are_computed_and_not_counted():
@@ -318,7 +202,7 @@ def replace_bias(lp, value):
 
 @pytest.mark.parametrize("fault", ["bias_in_the_weights", "bias_left_out_of_the_choice"])
 def test_a_program_that_gets_the_bias_wrong_fails_the_reference(monkeypatch, fault):
-    params = seeded(key=1)
+    params = FAMILY.seeded(key=1)
     tokens = np.random.default_rng(3).integers(3, TOY.vocab_size, (1, 40)).astype(np.int32)
     want = ARCH.forward_logits(params, TOY, tokens, np.asarray([40], np.int32))
     right = moe.route
@@ -331,42 +215,44 @@ def test_a_program_that_gets_the_bias_wrong_fails_the_reference(monkeypatch, fau
             jax.nn.sigmoid(h @ lp["router"]) + lp["router_bias"], chosen, axis=-1)
         return chosen, biased / biased.sum(-1, keepdims=True) * config.routed_scaling_factor
 
-    logits, _ = forward(params, TOY, tokens)
+    logits, _ = FAMILY.forward(params, TOY, tokens)
     assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
     monkeypatch.setattr(moe, "route", wrong)
-    logits, _ = forward(params, TOY, tokens)
+    logits, _ = FAMILY.forward(params, TOY, tokens)
     assert np.abs(np.asarray(logits) - want).max() > LOGIT_TOL
 
 
 # ----------------------------------------------------------------- (e)
-def test_a_reused_latent_prefix_gives_the_logits_of_a_cold_engine(monkeypatch):
+def test_a_reused_latent_prefix_gives_the_logits_of_a_cold_engine(monkeypatch, standing):
     """The second request shares 32 tokens (four latent pages) with the
     first: it seeds its scratch from the cached pages and starts at the
     reused offset.  Its logits are those of an engine that prefilled the
-    whole prompt itself."""
-    shared = prompt_of(32, seed=1)
-    first, second = shared + prompt_of(9, seed=2), shared + prompt_of(13, seed=3)
-    spy = Spy(monkeypatch)
-    (_, out), params, counters = serve((TOY, runtime()), [(first, 4), (second, 9)])
+    whole prompt itself (the cold engine is a build of its own)."""
+    shared = FAMILY.prompt_of(32, seed=1)
+    first, second = shared + FAMILY.prompt_of(9, seed=2), shared + FAMILY.prompt_of(13, seed=3)
+    served = standing.serve([(first, 4), (second, 9)])
+    (_, out), params, counters = served.outs, served.params, served.added
     assert counters["prefix_hits"] == 1 and counters["prefix_reused_tokens"] == 32
-    warm = spy.of_request(second, out, 16)
-    spy.seen.clear()
-    (cold_out,), _, cold_counters = serve((TOY, runtime()), [(second, 9)])
+    warm = served.spy.of_request(second, out, 16)
+    spy = Spy(monkeypatch)
+    (cold_out,), _, cold_counters = FAMILY.serve((TOY, FAMILY.runtime()), [(second, 9)])
     assert cold_counters["prefix_hits"] == 0 and out == cold_out
     cold = spy.of_request(second, cold_out, 16)
     # the same programs on the same numbers but for the chunks skipped
     assert np.abs(warm - cold).max() < 1e-6
-    want = generated(reference_logits(params, TOY, second + out), second, out)
+    want = generated(FAMILY.reference_logits(params, TOY, second + out), second, out)
     assert np.abs(warm - want).max() < LOGIT_TOL
 
 
 def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(monkeypatch):
-    first, second = prompt_of(29, seed=1), prompt_of(21, seed=2)
+    """(One slot is another runtime, and the fresh engine is what the reused one is held
+    to: two builds of its own.)"""
+    first, second = FAMILY.prompt_of(29, seed=1), FAMILY.prompt_of(21, seed=2)
     spy = Spy(monkeypatch)
-    (_, out), _, _ = serve((TOY, runtime(max_batch_size=1)), [(first, 9), (second, 9)])
+    (_, out), _, _ = FAMILY.serve((TOY, FAMILY.runtime(max_batch_size=1)), [(first, 9), (second, 9)])
     reused = list(spy.seen)[-8:]
     spy.seen.clear()
-    (fresh_out,), _, _ = serve((TOY, runtime(max_batch_size=1)), [(second, 9)])
+    (fresh_out,), _, _ = FAMILY.serve((TOY, FAMILY.runtime(max_batch_size=1)), [(second, 9)])
     assert out == fresh_out
     for a, b in zip(reused, spy.seen[-8:]):
         assert np.array_equal(a, b)  # the same program on the same numbers
@@ -379,9 +265,10 @@ def test_control_a_lower_precision_fails_the_tolerance(monkeypatch, lowered):
     states: float32 weights and activations as before, and the latent pool
     held in bfloat16, or the router's product taken in bfloat16, each FAILS
     (b)'s own run (the same prompt, 21 generated positions), which the
-    stated precision passes there."""
-    prompt = prompt_of(37)
-    rt = runtime()
+    stated precision passes there.  (Each control is another program: a
+    build of its own.)"""
+    prompt = FAMILY.prompt_of(37)
+    rt = FAMILY.runtime()
     if lowered == "latent_pool":
         made = M.make_page_pool
         monkeypatch.setattr(
@@ -395,9 +282,9 @@ def test_control_a_lower_precision_fails_the_tolerance(monkeypatch, lowered):
 
         monkeypatch.setattr(moe, "route", lowered_route)
     spy = Spy(monkeypatch)
-    (out,), params, _ = serve((TOY, rt), [(prompt, 21)])
+    (out,), params, _ = FAMILY.serve((TOY, rt), [(prompt, 21)])
     got = spy.of_request(prompt, out, 16)
-    want = generated(reference_logits(params, TOY, prompt + out), prompt, out)
+    want = generated(FAMILY.reference_logits(params, TOY, prompt + out), prompt, out)
     assert np.abs(got - want).max() > LOGIT_TOL
 
 
@@ -471,7 +358,7 @@ def test_a_checkpoint_in_hf_names_loads_to_the_tree_the_reference_agrees_with(tm
     from calfkit_tpu.inference.loader import VisionTowerSkipped, config_from_hf, load_params
     from calfkit_tpu.inference.sharding import make_mesh, param_shardings
 
-    params = seeded(key=4)
+    params = FAMILY.seeded(key=4)
     vl = model_type == "kimi_vl"
     tensors = hf_tensors(params, TOY, "language_model." if vl else "")
     if vl:
@@ -495,8 +382,8 @@ def test_a_checkpoint_in_hf_names_loads_to_the_tree_the_reference_agrees_with(tm
     # HF's q_proj really is in another column order than the tree's
     hf_q = tensors[("language_model." if vl else "") + "model.layers.0.self_attn.q_proj.weight"]
     assert not np.array_equal(hf_q.T.reshape(32, 4, 24), np.asarray(params["layers"]["attn"]["wq"][0]))
-    tokens = np.asarray([prompt_of(24, seed=9)], np.int32)
-    logits, _ = forward(loaded, config, tokens)
+    tokens = np.asarray([FAMILY.prompt_of(24, seed=9)], np.int32)
+    logits, _ = FAMILY.forward(loaded, config, tokens)
     want = ARCH.forward_logits(loaded, config, tokens, np.asarray([24], np.int32))
     assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
 
@@ -528,7 +415,7 @@ def test_rotating_adjacent_pairs_equals_rotating_the_halves_of_permuted_columns(
 ])
 def test_what_has_no_latent_or_expert_path_is_refused_at_construction(option, kwargs):
     with pytest.raises(UnsupportedWithLatentAttention, match=option):
-        InferenceEngine(TOY, runtime(**kwargs))
+        InferenceEngine(TOY, FAMILY.runtime(**kwargs))
 
 
 @pytest.mark.parametrize("latent, page, built", [
@@ -543,14 +430,14 @@ def test_the_paged_decode_kernel_is_not_for_a_latent_pool(latent, page, built):
     from calfkit_tpu.inference import pallas_attention as PA
 
     config = replace(TOY, kv_lora_rank=latent[0], qk_rope_head_dim=latent[1])
-    engine = InferenceEngine(config, runtime(page_size=page))
+    engine = InferenceEngine(config, FAMILY.runtime(page_size=page))
     assert (engine._attn_impl, engine._ssm_impl) == ("xla", "xla")
     if not built:
         with pytest.raises(PA.PallasShapeError, match="latent_decode_in_place_ok"):
-            InferenceEngine(config, runtime(page_size=page, attention_impl="pallas_interpret"))
+            InferenceEngine(config, FAMILY.runtime(page_size=page, attention_impl="pallas_interpret"))
         return
     # what it then builds and serves: tests/test_latent_decode_attention.py
-    engine = InferenceEngine(config, runtime(page_size=page, attention_impl="pallas_interpret"))
+    engine = InferenceEngine(config, FAMILY.runtime(page_size=page, attention_impl="pallas_interpret"))
     assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "xla")
 
 
@@ -631,7 +518,7 @@ def test_a_dense_and_a_hybrid_description_trace_what_they_traced_before(kind):
     """A description without the new fields builds the programs it built
     before latent attention and experts existed, letter for letter."""
     config = preset("debug") if kind == "dense" else HYBRID
-    engine = InferenceEngine(config, runtime(prefix_cache=False, attention_impl="xla"))
+    engine = InferenceEngine(config, FAMILY.runtime(prefix_cache=False, attention_impl="xla"))
     for name, jaxpr in _programs(engine).items():
         text = str(jaxpr)
         assert "mla" not in text and "moe" not in text
@@ -660,7 +547,7 @@ def test_a_chunk_dispatch_is_counted_by_the_form_its_shape_gives_it(rows, groupe
     wave's rows x the chunk's length alone; a decode dispatch counts nothing.
     At toy size the limit is 16 tokens: a one-row chunk of 16 is dense, as
     Kimi's one-row chunk of 1,024 is under its limit of 1,536."""
-    engine = InferenceEngine(TOY, runtime(max_batch_size=4, max_prefill_wave=4), seed=3, params=seeded())
+    engine = InferenceEngine(TOY, FAMILY.runtime(max_batch_size=4, max_prefill_wave=4), seed=3, params=FAMILY.seeded())
     inf = {"wave": [None] * rows, "chunk": 16, "wmoe": engine._moe_zero,
            "arrays": {"true_lens": np.full(rows, 16, np.int32)}}
     assert moe.dense_form(rows * 16, TOY) != grouped
@@ -672,22 +559,17 @@ def test_a_chunk_dispatch_is_counted_by_the_form_its_shape_gives_it(rows, groupe
         (2, 0) if grouped else (0, 2))
 
 
-def test_a_served_prompt_s_one_row_chunks_count_as_dense():
-    from calfkit_tpu.observability.metrics import metrics_text
-
-    _, _, counters = serve((TOY, runtime()), [(prompt_of(37), 3)])
+def test_a_served_prompt_s_one_row_chunks_count_as_dense(first_served):
+    counters, text = first_served.added, first_served.metrics
     assert (counters["moe_grouped_chunks"], counters["moe_dense_chunks"]) == (0, 3)  # 37 in chunks of 16
-    text = metrics_text()
     assert "calfkit_engine_moe_grouped_chunks_total" in text
     assert "calfkit_engine_moe_dense_chunks_total" in text
 
 
-def test_the_new_counters_reach_metrics_and_capacity():
+def test_the_new_counters_reach_metrics_and_capacity(standing):
     from calfkit_tpu.observability.capacity import hbm_constants
-    from calfkit_tpu.observability.metrics import metrics_text
 
-    serve((TOY, runtime()), [(prompt_of(20), 3)])
-    text = metrics_text()
+    text = standing.serve([(FAMILY.prompt_of(20), 3)]).metrics
     for name in ("calfkit_engine_moe_assignments_total",
                  "calfkit_engine_moe_expert_tokens_max_total",
                  "calfkit_engine_moe_expert_tokens_mean_total",

@@ -62,3 +62,36 @@ class TestPublicSurface:
         )
         assert out.returncode == 0, out.stderr
         assert "lazy ok" in out.stdout
+
+
+class TestTheTreeKeepsItsShape:
+    def test_one_architecture_harness_and_no_records_at_the_root(self):
+        """What PR 43 took out does not grow back unseen: the engine harness
+        of the architecture families lives in ``tests/arch_harness.py`` alone
+        (no other file under ``tests/`` defines or binds a top-level
+        ``runtime``, ``seeded``, ``serve`` or ``Spy``), and the root holds no
+        ``*.json`` but the benchmark's declaration and the three baselines
+        that the gates read (records of runs belong in the ledger)."""
+        import ast
+        import pathlib
+
+        root = pathlib.Path(__file__).resolve().parent.parent
+        harness = {"runtime", "seeded", "serve", "Spy"}
+        found = []
+        for path in sorted((root / "tests").glob("*.py")):
+            if path.name == "arch_harness.py":
+                continue
+            for node in ast.parse(path.read_text()).body:
+                names = set()
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
+                    names = {node.name}
+                elif isinstance(node, (ast.Assign, ast.AnnAssign)):
+                    targets = node.targets if isinstance(node, ast.Assign) else [node.target]
+                    names = {n.id for t in targets for n in ast.walk(t) if isinstance(n, ast.Name)}
+                found += [f"{path.name}:{node.lineno} {name}" for name in sorted(names & harness)]
+        assert not found, found
+        defined = {n.name for n in ast.parse((root / "tests" / "arch_harness.py").read_text()).body
+                   if isinstance(n, ast.ClassDef)}
+        assert {"Spy", "Family", "Standing"} <= defined
+        assert sorted(p.name for p in root.glob("*.json")) == [
+            "BASELINE.json", "BENCHMARK.json", "SIM.json", "SIM_BASELINE.json"]

@@ -24,8 +24,9 @@ import pytest
 from calfkit_tpu.inference import mamba as mm
 from calfkit_tpu.inference import pallas_attention as PA
 from calfkit_tpu.inference import pallas_ssm as PS
-from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
+from calfkit_tpu.inference.config import ModelConfig
 from calfkit_tpu.inference.engine import InferenceEngine
+from tests.arch_harness import HYBRID_MAMBA
 
 # (H, G, P, N): the toy of tests/test_hybrid_mamba.py (two groups, a chunk
 # is a group), the published head shape (granite-4.0-h-micro: a chunk is two
@@ -134,12 +135,8 @@ DENSE = ModelConfig(
 )
 
 
-def runtime(**kw) -> RuntimeConfig:
-    return RuntimeConfig(**{
-        "max_batch_size": 2, "max_seq_len": 128, "kv_layout": "paged", "page_size": 16,
-        "chunked_prefill": True, "prefill_chunk": 32, "window_buckets": (128,),
-        "compilation_cache": False, "decode_steps_per_dispatch": 4, **kw,
-    })
+# pages of 16 and chunks of 32: inside the paged decode read's rule at heads of 64
+IN_RULE = dict(page_size=16, prefill_chunk=32, window_buckets=(128,))
 
 
 @pytest.mark.parametrize(
@@ -171,7 +168,7 @@ def test_the_ssm_step_is_selected_by_platform_and_shape(
     the state's dtype and shape and whether the model has Mamba layers,
     under the ``attention_impl`` values that govern the paged decode read."""
     real = jax.devices()
-    engine = InferenceEngine(config, runtime(attention_impl=impl))
+    engine = InferenceEngine(config, HYBRID_MAMBA.runtime(**IN_RULE, attention_impl=impl))
     monkeypatch.setattr(engine, "mesh", SimpleNamespace(size=devices))
     monkeypatch.setattr(
         jax, "devices", lambda *a: [SimpleNamespace(platform=platform)] if not a else real)
@@ -226,7 +223,7 @@ def test_a_dense_model_s_programs_do_not_change_with_the_ssm_path():
     them is the attention read's."""
     texts = {}
     for ssm_impl in ("xla", "pallas_interpret"):
-        engine = InferenceEngine(DENSE, runtime(attention_impl="pallas_interpret"))
+        engine = InferenceEngine(DENSE, HYBRID_MAMBA.runtime(**IN_RULE, attention_impl="pallas_interpret"))
         assert engine._ssm_impl == "xla"
         engine._ssm_impl = ssm_impl  # what no resolution gives a dense model
         programs = _programs(engine)
@@ -242,7 +239,7 @@ def test_a_hybrid_model_s_programs_hold_the_kernel_once_a_mamba_layer(impl, kern
     """The same programs of a hybrid in the rule: one ``ssm`` kernel a
     Mamba layer of the period before the one attention read, under
     ``pallas_interpret``; none under ``xla``."""
-    engine = InferenceEngine(HYBRID, runtime(attention_impl=impl))
+    engine = InferenceEngine(HYBRID, HYBRID_MAMBA.runtime(**IN_RULE, attention_impl=impl))
     for jaxpr in _programs(engine).values():
         assert [name for name, _ in _kernels(jaxpr.jaxpr)] == kernels
 
@@ -256,7 +253,7 @@ def test_the_kernel_s_scope_path_ends_in_ssm():
     device time is read where the XLA fusions' was."""
     from benchmarks.trace_reduce import scope_path
 
-    engine = InferenceEngine(HYBRID, runtime(attention_impl="pallas_interpret"))
+    engine = InferenceEngine(HYBRID, HYBRID_MAMBA.runtime(**IN_RULE, attention_impl="pallas_interpret"))
     paths = {
         program: {scope_path(op_name) for _, op_name in _kernels(jaxpr.jaxpr)}
         for program, jaxpr in _programs(engine).items()
