@@ -10,17 +10,20 @@ import math
 from dataclasses import dataclass, field, replace
 
 
-ATTENTION, MAMBA, GDN, WINDOW, KDA = "attention", "mamba", "gdn", "window", "kda"
-RECURRENT_KINDS = (MAMBA, GDN, KDA)
+ATTENTION, MAMBA, GDN, WINDOW, KDA, CONV = "attention", "mamba", "gdn", "window", "kda", "conv"
+RECURRENT_KINDS = (MAMBA, GDN, KDA, CONV)
 DELTA_RULE_KINDS = (GDN, KDA)  # the gated delta rule: a decay a head, or a key channel
 # What a layer of each kind leaves behind of a sequence, i.e. the cache kind
 # the engine has to hold for it: "global" = K and V of EVERY token, in pages
 # a row keeps for its whole life; "window" = K and V of the last
 # ``sliding_window`` tokens, in a ring of pages a row that it writes over
-# (gives back) as it grows; "state" = a recurrent state of fixed size a slot.
+# (gives back) as it grows; "state" = a recurrent state of fixed size a slot
+# (of a "conv" layer, a gated short convolution, the conv tail and NOTHING
+# else: the pair's matrix side is empty).
 # A new kind of layer is a row here and a mixer in model.py.
 CACHE_KINDS = {
-    ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state", KDA: "state"}
+    ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state", KDA: "state",
+    CONV: "state"}
 
 
 class UnsupportedWithRecurrentLayers(ValueError):
@@ -90,6 +93,15 @@ class ModelConfig:
     ``d_ff`` whatever their mixer, the expert block after them, its gate
     choosing by GROUP (``n_group``, ``topk_group``) among all the experts it
     scores and the experts held by share as above.
+    With ``"conv"`` among ``layer_types`` it is an LFM2-style hybrid
+    (``lfm2_moe``): a gated short convolution (``B | C | x = h W_in``, a
+    causal depthwise conv of ``conv_L_cache`` taps over ``B x``, times ``C``,
+    ``W_out``: what a sequence leaves behind is the conv's last
+    ``conv_L_cache - 1`` inputs a channel and nothing else) beside rotary GQA
+    attention with normed heads (``qk_norm``), the first ``first_k_dense``
+    layers with a SwiGLU of ``d_ff``, the expert block after them with no
+    shared expert (``n_shared_experts`` 0) and the weights of a token's
+    experts normalised over ``sum + 1e-6`` (``topk_norm_eps``).
     """
 
     name: str = "debug"
@@ -144,6 +156,10 @@ class ModelConfig:
     first_k_dense: int = 0  # first_k_dense_replace: leading layers of d_ff
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    # what a sigmoid gate adds to the sum of a token's chosen scores before
+    # dividing by it, as each gate's family publishes it: 1e-20 in DeepSeek-V3
+    # and its descendants, 1e-6 in lfm2_moe (whoever describes that gate says so)
+    topk_norm_eps: float = 1e-20
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
     n_group: int = 1
@@ -190,6 +206,11 @@ class ModelConfig:
     # is not published: a nonzero entry is refused below, no guessed clamp ships
     expert_swiglu_limits: tuple[float, ...] = ()
     shared_expert_swiglu_limits: tuple[float, ...] = ()
+    # ---- a gated short convolution beside GQA attention (all defaults = as before) ----
+    # a "conv" layer's causal depthwise conv has conv_L_cache taps (HF names:
+    # conv_L_cache, conv_bias); its state is the last conv_L_cache - 1 inputs
+    conv_L_cache: int = 0
+    conv_bias: bool = False
 
     def __post_init__(self) -> None:
         if self.kv_lora_rank:
@@ -207,12 +228,13 @@ class ModelConfig:
             raise ValueError(
                 "a Kimi Delta Attention hybrid's attention layers are latent "
                 "attention (kv_lora_rank): K and V per head are not described")
-        elif self.n_routed_experts and not {GDN, WINDOW} & set(self.layer_types):
+        elif self.n_routed_experts and not {GDN, WINDOW, CONV} & set(self.layer_types):
             raise ValueError(
                 "routed experts are described for the latent-attention stack "
                 "(kv_lora_rank), for the Gated DeltaNet hybrid (layer_types "
-                'with "gdn") and for the window stack (layer_types with '
-                '"window") alone'
+                'with "gdn"), for the window stack (layer_types with '
+                '"window") and for the short-convolution hybrid (layer_types '
+                'with "conv") alone'
             )
         if self.n_routed_experts:
             if not (0 < self.n_experts_per_tok <= self.experts_scored and self.moe_d_ff):
@@ -246,10 +268,11 @@ class ModelConfig:
                     f"{self.expert_first + self.n_routed_experts}) are not among "
                     f"the {self.experts_scored} the gate scores"
                 )
-            if self.layer_types and self.first_k_dense and KDA not in self.layer_types:
+            if self.layer_types and self.first_k_dense and not (
+                    {KDA, CONV} & set(self.layer_types)):
                 raise ValueError(
                     "a hybrid stack's expert block is every layer's FFN (leading dense "
-                    'layers are described for layer_types with "kda" alone)')
+                    'layers are described for layer_types with "kda" or "conv" alone)')
             for name, limits in (("expert_swiglu_limits", self.expert_swiglu_limits),
                                  ("shared_expert_swiglu_limits",
                                   self.shared_expert_swiglu_limits)):
@@ -283,12 +306,12 @@ class ModelConfig:
             kinds = set(self.layer_types) & set(RECURRENT_KINDS)
             if not kinds and WINDOW not in self.layer_types:
                 raise ValueError(
-                    "layer_types without a mamba, gdn or window layer is the "
-                    "dense decoder: leave it empty"
+                    "layer_types without a mamba, gdn, kda, conv or window layer is "
+                    "the dense decoder: leave it empty"
                 )
             if len(kinds) > 1:
                 raise ValueError(
-                    "mamba, gdn and kda layers in one stack are not described: "
+                    "mamba, gdn, kda and conv layers in one stack are not described: "
                     "one recurrent kind a stack")
             if WINDOW in self.layer_types:
                 if kinds:
@@ -306,6 +329,20 @@ class ModelConfig:
                     raise ValueError("mamba layers need mamba_n_heads/d_head/d_state")
                 if self.mamba_n_heads % self.mamba_n_groups:
                     raise ValueError("mamba_n_groups must divide mamba_n_heads")
+            elif CONV in kinds:
+                if self.conv_L_cache < 2:
+                    raise ValueError(
+                        "conv layers need conv_L_cache >= 2 (the taps of the causal "
+                        "depthwise conv: its state is the last conv_L_cache - 1 inputs)")
+                if self.conv_bias:
+                    raise ValueError(
+                        "conv_bias: biases on a conv layer's in_proj, conv and out_proj "
+                        "are not described (the published model has none)")
+                if not self.n_routed_experts:
+                    raise ValueError(
+                        "a short-convolution hybrid's FFN after its leading dense layers "
+                        "is the expert block (n_routed_experts): one SwiGLU in every "
+                        "layer is not described")
             else:
                 if not (self.gdn_n_k_heads and self.gdn_n_v_heads and self.gdn_d_k
                         and self.gdn_d_v):
@@ -350,7 +387,8 @@ class ModelConfig:
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if GDN not in self.layer_types and (
-            self.qk_norm or (self.attn_output_gate and KDA not in self.layer_types)
+            (self.qk_norm and CONV not in self.layer_types)
+            or (self.attn_output_gate and KDA not in self.layer_types)
             or self.norm_plus_one or self.partial_rotary_factor != 1.0
             or (self.attn_head_dim and WINDOW not in self.layer_types)
         ):
@@ -358,8 +396,12 @@ class ModelConfig:
                 "attn_head_dim, qk_norm, attn_output_gate, norm_plus_one and "
                 'partial_rotary_factor belong to the Gated DeltaNet hybrid '
                 '(layer_types with "gdn"; attn_head_dim to the window stack too, '
-                'attn_output_gate to layer_types with "kda" too)'
+                'attn_output_gate to layer_types with "kda" too, qk_norm to '
+                'layer_types with "conv" too)'
             )
+        if CONV not in self.layer_types and (self.conv_L_cache or self.conv_bias):
+            raise ValueError(
+                'conv_L_cache and conv_bias belong to layer_types with "conv"')
         if self.rotary_dim % 2:
             raise ValueError("the rotated part of a head must be even (rotary pairs)")
 
@@ -442,9 +484,25 @@ class ModelConfig:
         return KDA in self.layer_types
 
     @property
+    def shortconv(self) -> bool:
+        """Are the recurrent layers gated short convolutions (``conv``: a
+        state that is the conv tail alone)?"""
+        return CONV in self.layer_types
+
+    @property
+    def expert_hybrid(self) -> bool:
+        """Is this the hybrid stack whose FFN is the expert block, its
+        attention layers' heads normed where ``qk_norm`` says so: a delta
+        rule or a short convolution as the recurrent mixer
+        (``model._expert_hybrid_stack``)?"""
+        return self.gdn or self.shortconv
+
+    @property
     def recurrent_kind(self) -> str:
         if self.kda:
             return "Kimi Delta Attention"
+        if self.shortconv:
+            return "gated short convolution"
         return "Gated DeltaNet" if self.gdn else "Mamba-2"
 
     @property
@@ -562,7 +620,14 @@ class ModelConfig:
         """Shapes of the state pair ``rows`` sequences carry: the matrix
         state ``[L, rows, heads, d_head, d_state]`` (Mamba-2's SSM state, or
         the delta rule's ``S`` of d_k x d_v a value head) and the conv tail
-        ``[L, d_conv - 1, rows, channels]``."""
+        ``[L, d_conv - 1, rows, channels]``.  A short-convolution layer's
+        pair has an EMPTY matrix side (no number a layer): its state is the
+        tail of ``d_model`` channels alone."""
+        if self.shortconv:
+            return (
+                (self.n_recurrent_layers, rows, 0),
+                (self.n_recurrent_layers, self.conv_L_cache - 1, rows, self.d_model),
+            )
         if self.gdn:
             return (
                 (self.n_recurrent_layers, rows, self.gdn_n_v_heads, self.gdn_d_k, self.gdn_d_v),
@@ -633,6 +698,18 @@ class ModelConfig:
             + self.n_heads * self.head_dim * self.d_model
             + (2 * self.head_dim if self.qk_norm else 0)
         )
+        if self.shortconv:
+            mixer = 4 * self.d_model * self.d_model + self.d_model * self.conv_L_cache
+            moe = (
+                self.d_model * self.experts_scored + self.experts_scored  # gate, its bias
+                + (self.n_routed_experts + self.n_shared_experts)
+                * 3 * self.d_model * self.moe_d_ff
+            )
+            return (
+                embed + self.d_model + 2 * self.n_layers * self.d_model
+                + self.n_kv_layers * attention + self.n_recurrent_layers * mixer
+                + self.n_dense_layers * 3 * self.d_model * self.d_ff + self.n_moe_layers * moe
+            )
         if self.windowed:
             expert = 3 * self.d_model * self.moe_d_ff
             ffn = (
@@ -1186,6 +1263,60 @@ PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=2.5,
         n_group=4,
         topk_group=2,
+    ),
+    # LFM2-8B-A1B (HF: LiquidAI/LFM2-8B-A1B, lfm2_moe): 18 gated short
+    # convolutions (3 taps: two numbers a channel of state) and 6 rotary GQA
+    # layers with normed heads, two leading dense layers of 7,168, then 32
+    # sigmoid-routed experts of 1,792 with 4 a token, a bias on the choice alone
+    # and NO shared expert; the embedding tied.
+    "lfm2-8b-a1b": ModelConfig(
+        name="lfm2-8b-a1b",
+        vocab_size=65536,
+        d_model=2048,
+        n_layers=24,
+        n_heads=32,
+        n_kv_heads=8,
+        d_ff=7168,
+        rope_theta=1000000.0,
+        norm_eps=1e-5,
+        max_seq_len=128000,
+        tie_embeddings=True,
+        layer_types=tuple(
+            ATTENTION if i in (2, 6, 10, 14, 18, 21) else CONV for i in range(24)),
+        conv_L_cache=3,
+        qk_norm=True,
+        n_routed_experts=32,
+        n_experts_per_tok=4,
+        moe_d_ff=1792,
+        first_k_dense=2,
+        routed_scaling_factor=1.0,
+        topk_norm_eps=1e-6,
+    ),
+    # the same kind at toy size, for the tests: the published pattern cut as
+    # the cell cuts it (c c A c | c c A c | c c A c: a head of 4, two periods in
+    # the scan), two dense layers, 8 experts with 3 a token, float32
+    "debug-lfm2-moe": ModelConfig(
+        name="debug-lfm2-moe",
+        vocab_size=128,
+        d_model=32,
+        n_layers=12,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=48,
+        rope_theta=1000000.0,
+        norm_eps=1e-5,
+        max_seq_len=256,
+        dtype="float32",
+        tie_embeddings=True,
+        layer_types=(CONV, CONV, ATTENTION, CONV) * 3,
+        conv_L_cache=3,
+        qk_norm=True,
+        n_routed_experts=8,
+        n_experts_per_tok=3,
+        moe_d_ff=16,
+        first_k_dense=2,
+        routed_scaling_factor=1.0,
+        topk_norm_eps=1e-6,
     ),
 }
 

@@ -544,6 +544,7 @@ def _finalize_wave_math(
         return out
     # the wave's recurrent state lands in its slots as its pages do: the
     # whole of a slot's state is overwritten, nothing of the last tenant stays
+    # (a short convolution's matrix side is empty: its scatter moves no byte)
     with jax.named_scope("state_land"):
         (ssm, conv), (w_ssm, w_conv) = state, wstate
         state = (ssm.at[:, slots].set(w_ssm), conv.at[:, :, slots].set(w_conv))
@@ -1088,7 +1089,7 @@ class InferenceEngine:
                 "long_context": (rt.long_context,
                                  "the sequence-parallel lane carries no recurrent state"),
             }
-            if config.gdn:  # experts in the hybrid stack, held by share or whole
+            if config.expert_hybrid:  # experts in the hybrid stack, held by share or whole
                 refused = {
                     "dp > 1": (rt.dp > 1,
                                "the expert leaves and the per-slot state have no sharding "
@@ -1696,13 +1697,17 @@ class InferenceEngine:
           :func:`pallas_gdn.delta_step_in_place_ok` holds (float32, ``gdn_d_v``
           whole lane tiles, ``gdn_d_k`` whole sublane tiles); else
           ``gdn.delta_step_xla``.
+        - Gated short convolutions (``config.shortconv``): XLA.  The state is
+          the conv tail alone, two numbers a channel, and the step is a weight
+          stream that XLA fuses: there is no matrix state for a kernel to pass
+          over (``shortconv.shortconv_step``).
 
         "pallas" / "pallas_interpret" waive the platform test alone, as
         they do for the read; they NAME the attention kernel, so a state
         outside the rule is served by XLA and not refused."""
         impl = self.runtime.attention_impl
         c = self.config
-        if not self._recurrent or impl == "xla" or (
+        if not self._recurrent or c.shortconv or impl == "xla" or (
             impl == "auto" and jax.devices()[0].platform != "tpu"
         ):
             return "xla"

@@ -107,6 +107,23 @@ low-rank pairs (``no_kda_lora``), the latent layers' as DeepseekV3's:
     mlp.gate.weight [E, D], mlp.gate.expert_bias  → layers.moe.router [D, E], router_bias (ALL the experts)
     mlp.experts.{e}.*, mlp.shared_experts.*       → the HELD experts' stacks, the shared expert
     mlp.{gate,up,down}_proj (the leading layers)  → layers.dense.*
+``model_type`` ``lfm2_moe`` (LFM2-8B-A1B) loads into the short-convolution
+hybrid's tree (layer N is the n-th attention or conv layer by ``layer_types``;
+the embedding tied: no ``lm_head``).  The names are taken as the published
+``lfm2_moe`` implementation has them, UNVERIFIED against the published files.
+A description of fewer layers than the checkpoint has (a pipeline stage: the
+leading ``n_layers``) loads those and leaves the rest on disk, counted with
+one :class:`LayersSkipped` notice:
+    model.embed_tokens.weight, model.embedding_norm.weight → embed, final_norm
+    operator_norm.weight, ffn_norm.weight         → attn_norm or mixer_norm, mlp_norm
+    conv.in_proj.weight [3 D, D] (B | C | x)      → layers.conv.w_in (as it is)
+    conv.conv.weight [D, 1, taps]                 → conv_w [taps, D]
+    conv.out_proj.weight                          → w_out (transposed)
+    self_attn.{q,k,v}_proj, out_proj.weight       → layers.attn.wq, wk, wv, wo
+    self_attn.{q,k}_layernorm.weight              → q_norm, k_norm
+    feed_forward.{w1,w3,w2}.weight (the leading layers) → layers.dense.w_gate, w_up, w_down
+    feed_forward.gate.weight [E, D], .expert_bias → layers.moe.router [D, E], router_bias
+    feed_forward.experts.{e}.{w1,w3,w2}.weight    → layers.moe.w_gate, w_up, w_down [E, ..]
 """
 
 from __future__ import annotations
@@ -134,6 +151,12 @@ class MtpSkipped(UserWarning):
     disk: no program here drafts from it."""
 
 
+class LayersSkipped(UserWarning):
+    """A checkpoint's layers past the description's ``n_layers`` (the later
+    stages of a pipeline) were left on disk: this device serves the leading
+    layers alone."""
+
+
 # tensor names of a multimodal checkpoint's language decoder start with this
 _LANGUAGE_PREFIX = "language_model."
 _MTP_PREFIX = "mtp."
@@ -153,6 +176,8 @@ def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> 
     if share is not None:
         raise ValueError(
             f"{path}: a share is described for qwen3_next, cohere2_moe and bailing_hybrid alone")
+    if raw.get("model_type") == "lfm2_moe":
+        return _lfm2_moe_config(raw, str(path))
     if raw.get("model_type") == "granitemoehybrid":
         return _granite_hybrid_config(raw, str(path))
     if raw.get("model_type") == "kimi_vl":
@@ -413,6 +438,41 @@ def _bailing_hybrid_config(raw: dict, path: str, share: "tuple[int, int] | None"
     )
 
 
+def _lfm2_moe_config(raw: dict, path: str) -> ModelConfig:
+    """Lfm2Moe's ``config.json`` -> the short-convolution hybrid's description."""
+    from calfkit_tpu.inference.config import ATTENTION, CONV
+
+    for key, only in (("use_expert_bias", True), ("conv_bias", False),
+                      ("tie_embedding", True), ("rope_scaling", None)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    kinds = {"conv": CONV, "full_attention": ATTENTION}
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=raw["vocab_size"],
+        d_model=raw["hidden_size"],
+        n_layers=raw["num_hidden_layers"],
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"],
+        d_ff=raw["intermediate_size"],
+        rope_theta=float(raw.get("rope_theta", 1000000.0)),
+        norm_eps=raw.get("norm_eps", 1e-5),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=True,
+        layer_types=tuple(kinds[t] for t in raw["layer_types"]),
+        conv_L_cache=raw["conv_L_cache"],
+        qk_norm=True,
+        n_routed_experts=raw["num_experts"],
+        n_experts_per_tok=raw["num_experts_per_tok"],
+        moe_d_ff=raw["moe_intermediate_size"],
+        first_k_dense=raw["num_dense_layers"],
+        routed_scaling_factor=float(raw.get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func="sigmoid", topk_method="noaux_tc",
+        topk_norm_eps=1e-6,  # Lfm2MoeSparseMoeBlock's own constant: not a key of the file
+    )
+
+
 def _open_safetensors(path: Path) -> dict[str, Any]:
     """name -> lazy tensor getter across all shards."""
     from safetensors import safe_open  # ships with transformers
@@ -486,6 +546,20 @@ def load_params(
                 f"{path}: {tower} tensors outside 'model.' and 'lm_head.' (a vision tower "
                 "and its projector) were not loaded: the language decoder serves text alone"
             ), stacklevel=2)
+    if config.shortconv:
+        # a pipeline stage of an lfm2_moe checkpoint: the leading n_layers
+        layer_of = re.compile(r"^model\.layers\.(\d+)\.")
+        later = {int(m.group(1)) for name in files
+                 if (m := layer_of.match(name)) and int(m.group(1)) >= config.n_layers}
+        if later:
+            import warnings
+
+            warnings.warn(LayersSkipped(
+                f"{path}: layers {min(later)}-{max(later)} ({len(later)} of "
+                f"{config.n_layers + len(later)}) were not loaded: the description keeps the "
+                f"leading {config.n_layers} (a pipeline's first stage, with the final norm "
+                "and the tied head so that tokens come out)"
+            ), stacklevel=2)
     if mtp:
         import warnings
 
@@ -525,6 +599,10 @@ def _build_params(
         if quantize is not None:
             raise ValueError("no quantized load for a model with Gated DeltaNet layers")
         return _build_gdn_params(config, shardings, get)
+    if config.shortconv:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with short-convolution layers")
+        return _build_shortconv_params(config, shardings, get)
     if config.windowed:
         if quantize is not None:
             raise ValueError("no quantized load for a model with window layers and experts")
@@ -783,6 +861,76 @@ def _build_gdn_params(config: ModelConfig, shardings: dict[str, Any], get: Any) 
     logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
                 c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
                 rows.start, rows.stop - 1)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_shortconv_params(
+        config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The short-convolution hybrid's tree from ``lfm2_moe`` names (module
+    text): the leading ``n_layers`` of the checkpoint, every expert."""
+    import jax
+
+    from calfkit_tpu.inference.config import ATTENTION
+
+    c = config
+    D, H, K, hd = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    dtype = np.dtype(c.dtype)
+    nd = c.first_k_dense
+    attn_at = [i for i, t in enumerate(c.layer_types) if t == ATTENTION]
+    conv_at = [i for i, t in enumerate(c.layer_types) if t != ATTENTION]
+    dense_at, moe_at = range(nd), range(nd, c.n_layers)
+
+    def stack(layers: Any, name: str, transform: Any, as_type: Any = dtype) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in layers]
+        ).astype(as_type)
+
+    def experts(name: str) -> np.ndarray:
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.feed_forward.experts.{e}.{name}.weight").T
+                      for e in range(c.n_routed_experts)])
+            for i in moe_at
+        ]).astype(dtype)
+
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight").astype(dtype),
+        "layers": {
+            "attn": {
+                "wq": stack(attn_at, "self_attn.q_proj.weight", lambda w: w.T.reshape(D, H, hd)),
+                "wk": stack(attn_at, "self_attn.k_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wv": stack(attn_at, "self_attn.v_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wo": stack(attn_at, "self_attn.out_proj.weight",
+                            lambda w: w.T.reshape(H, hd, D)),
+                "attn_norm": stack(attn_at, "operator_norm.weight", lambda w: w),
+                "q_norm": stack(attn_at, "self_attn.q_layernorm.weight", lambda w: w),
+                "k_norm": stack(attn_at, "self_attn.k_layernorm.weight", lambda w: w),
+            },
+            "conv": {
+                "w_in": stack(conv_at, "conv.in_proj.weight", lambda w: w),
+                # HF's depthwise conv weight is [D, 1, taps]; ours is tap-major
+                "conv_w": stack(conv_at, "conv.conv.weight", lambda w: w[:, 0, :].T),
+                "w_out": stack(conv_at, "conv.out_proj.weight", lambda w: w.T),
+                "mixer_norm": stack(conv_at, "operator_norm.weight", lambda w: w),
+            },
+            "dense": {
+                "w_gate": stack(dense_at, "feed_forward.w1.weight", lambda w: w.T),
+                "w_up": stack(dense_at, "feed_forward.w3.weight", lambda w: w.T),
+                "w_down": stack(dense_at, "feed_forward.w2.weight", lambda w: w.T),
+                "mlp_norm": stack(dense_at, "ffn_norm.weight", lambda w: w),
+            },
+            "moe": {
+                "router": stack(moe_at, "feed_forward.gate.weight", lambda w: w.T),
+                "router_bias": stack(moe_at, "feed_forward.expert_bias", lambda w: w, np.float32),
+                "w_gate": experts("w1"),
+                "w_up": experts("w3"),
+                "w_down": experts("w2"),
+                "mlp_norm": stack(moe_at, "ffn_norm.weight", lambda w: w),
+            },
+        },
+        "final_norm": get("model.embedding_norm.weight").astype(dtype),
+    }
+    logger.info("loaded %s params (layers 0-%d, %d experts a layer)", c.name,
+                c.n_layers - 1, c.n_routed_experts)
     return jax.tree.map(jax.device_put, tree, shardings)
 
 

@@ -98,6 +98,11 @@ from calfkit_tpu.inference.mamba import (
 )
 from calfkit_tpu.inference.moe import init_moe_params, moe_ffn
 from calfkit_tpu.inference.quant import dequant as _w
+from calfkit_tpu.inference.shortconv import (
+    init_shortconv_params,
+    shortconv_chunk,
+    shortconv_step,
+)
 
 Params = dict[str, Any]
 
@@ -187,9 +192,12 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
                 "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
         }
 
-    if config.gdn:
-        # a Gated DeltaNet hybrid: norms that multiply by (1 + w) start at
-        # w = 0; W_q gives q | gate a head when the output is gated
+    if config.expert_hybrid:
+        # a hybrid whose FFN is the expert block (a Kimi Delta Attention one
+        # took the latent branch above): norms that multiply by (1 + w) start
+        # at w = 0; W_q gives q | gate a head when the output is gated.  Beside
+        # the attention layers the Gated DeltaNet group, or the short
+        # convolutions' with the leading dense layers' SwiGLU
         La = config.n_kv_layers
         one = jnp.zeros if config.norm_plus_one else jnp.ones
         q_out = hd * (2 if config.attn_output_gate else 1)
@@ -203,9 +211,20 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
                 **({"q_norm": one((La, hd), dtype), "k_norm": one((La, hd), dtype)}
                    if config.qk_norm else {}),
             },
-            "gdn": init_gdn_params(config, jax.random.split(keys[1])[0], dtype),
             "moe": init_moe_params(config, keys[5], dtype),
         }
+        mixer_key = jax.random.split(keys[1])[0]
+        if config.shortconv:
+            Ld = config.n_dense_layers
+            layers["conv"] = init_shortconv_params(config, mixer_key, dtype)
+            layers["dense"] = {
+                "w_gate": norm_init(keys[6], (Ld, D, F), D),
+                "w_up": norm_init(keys[7], (Ld, D, F), D),
+                "w_down": norm_init(jax.random.split(keys[7])[0], (Ld, F, D), F),
+                "mlp_norm": jnp.ones((Ld, D), dtype),
+            }
+        else:
+            layers["gdn"] = init_gdn_params(config, mixer_key, dtype)
         return {
             "embed": norm_init(keys[0], (V, D), D),
             "layers": layers,
@@ -410,7 +429,7 @@ def _q_scale(config: ModelConfig) -> float | None:
 
 def _hybrid_qkv(x, lp, cos, sin, config: ModelConfig):
     """A hybrid stack's attention front half -> (q, k, v, gate or None)."""
-    if config.gdn:
+    if config.expert_hybrid:
         return gated_attn_qkv(x, lp, cos, sin, config)
     return (*attn_qkv(x, lp, cos, sin, config.norm_eps, _q_scale(config)), None)
 
@@ -512,8 +531,8 @@ def _hybrid_stack(
     n = config.n_layers // len(period)
     a_per = period.count(ATTENTION)
     m_per = len(period) - a_per
-    if config.gdn:
-        return _gdn_hybrid_stack(
+    if config.expert_hybrid:
+        return _expert_hybrid_stack(
             config, layers, x, carry, attn_layer, mamba_layer, stats, valid)
 
     def body(c, p):
@@ -542,15 +561,26 @@ def _hybrid_stack(
     return x, carry, stats
 
 
-def _gdn_hybrid_stack(config, layers, x, carry, attn_layer, gdn_layer, stats, valid):
-    """:func:`_hybrid_stack` for a delta-rule hybrid (Gated DeltaNet, or Kimi
-    Delta Attention): the same one scan over the period's repeats with its
-    layers unrolled in the body; the norms multiply by ``1 + w`` where the
-    description says so, the recurrent mixer reads under ``gdn``, and every
-    layer's FFN is the expert block -> (x, carry, stats).  A stack with
-    leading dense layers (``config.stack_plan``) runs its head unrolled
-    before the scan, a leading layer's FFN the SwiGLU of ``d_ff``; its
-    attention layers are latent attention and read under ``mla``."""
+def _recurrent_mixer(config: ModelConfig):
+    """An expert hybrid's recurrent kind -> (the scope its mixer reads under,
+    the group its leaves stand in)."""
+    if config.shortconv:
+        return jax.named_scope("shortconv"), "conv"
+    return jax.named_scope("gdn"), "gdn"
+
+
+def _expert_hybrid_stack(config, layers, x, carry, attn_layer, recurrent_layer, stats, valid):
+    """:func:`_hybrid_stack` for a hybrid whose FFN is the expert block
+    (``config.expert_hybrid``: a delta rule, Gated DeltaNet or Kimi Delta
+    Attention, or a gated short convolution as the recurrent mixer): the same
+    one scan over the period's repeats with its layers unrolled in the body;
+    the norms multiply by ``1 + w`` where the description says so, the
+    recurrent mixer reads under the scope and from the group of leaves its
+    kind names (:func:`_recurrent_mixer`), and every layer's FFN is the
+    expert block -> (x, carry, stats).  A stack with leading dense layers
+    (``config.stack_plan``) runs its head unrolled before the scan, a leading
+    layer's FFN the SwiGLU of ``d_ff``; a stack with a latent pool reads its
+    attention layers under ``mla``."""
     eps, plus = config.norm_eps, config.norm_plus_one
     head, period = config.stack_plan
     a_per = period.count(ATTENTION)
@@ -565,9 +595,10 @@ def _gdn_hybrid_stack(config, layers, x, carry, attn_layer, gdn_layer, stats, va
                 with jax.named_scope("attn_out"):
                     x = x + jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
         else:
-            lp = _layer(layers["gdn"], im)
-            with jax.named_scope("gdn"):
-                carry, y = gdn_layer(
+            scope, group = _recurrent_mixer(config)
+            lp = _layer(layers[group], im)
+            with scope:
+                carry, y = recurrent_layer(
                     carry, rms_norm(x, lp["mixer_norm"], eps, plus), lp, im)
                 x = x + y
         if dense:  # a leading layer: one SwiGLU of d_ff
@@ -1271,7 +1302,8 @@ def forward(
 
         def mamba_layer(carry, h, lp, im):
             k_all, v_all, st = carry
-            chunk = gdn_chunk if config.gdn else mamba_chunk
+            chunk = (shortconv_chunk if config.shortconv else
+                     gdn_chunk if config.gdn else mamba_chunk)
             y, st = chunk(h, lp, st, im, n_valid, config)
             return (k_all, v_all, st), y
 
@@ -1400,7 +1432,9 @@ def _decode_step_with_ring(
 
         def mamba_layer(carry, h, lp, im):
             ring_k, ring_v, st = carry
-            if config.gdn:
+            if config.shortconv:
+                y, st = shortconv_step(h, lp, st, im, active)
+            elif config.gdn:
                 y, st = gdn_step(h, lp, st, im, active, config, ssm_impl)
             else:
                 y, st = mamba_step(h, lp, st, im, active, config, ssm_impl)

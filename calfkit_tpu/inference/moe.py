@@ -2,7 +2,9 @@
 
     s = sigmoid(float32(h) W_g)                    E scores a token, float32
     chosen = top-k of (s + b)                      b: e_score_correction_bias
-    w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor
+    w = s[chosen] / (sum s[chosen] + eps) * routed_scaling_factor
+                                                   eps: 1e-20 here, 1e-6 as lfm2_moe
+                                                   publishes it (config.topk_norm_eps)
     y = sum_e w_e E_e(h) + Shared(h)               every E_e a SwiGLU of moe_d_ff
 
 The bias moves the CHOICE and never the weights; the weights are the
@@ -144,8 +146,17 @@ _DENSE_MAX_TOKENS = 512
 # copies the whole of w_gate and w_up into the dense product's layout (2 x 1.51
 # GB of temporaries and a layer's 252 MB again in the loop: 7.4 GB in all, a
 # program that does not fit the chip; PERF.md section 6, PR 40): every product
-# of that shape is grouped, which reads the experts hit where they lie
-_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
+# of that shape is grouped, which reads the experts hit where they lie.  (32, 2048,
+# 1792) likewise: its decode program at 128 rows and 10 expert layers, compiled for
+# the v5e, copies both stacks whole in the dense form (2 copies, 6.38 GB of
+# temporaries on 9.58 GB of arguments: 15.96 GB, no room on a 16 GB chip), and holds
+# 1.69 GB grouped (2.53 GB in a ragged program of four rows; PERF.md section 6, PR 44).
+# Both 0 rows are ONE compiler artefact and no property of their shapes: the einsum
+# "td,edf->etf" nested in a dispatch's loop over steps hoists a whole-stack transpose;
+# three spellings without the copy are timed (ROADMAP S14).  Respelling experts_dense
+# changes three other cells' decode programs, so it is a perf_opt PR of its own, which
+# deletes these rows: add no fourth
+_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0, (32, 2048, 1792): 0}
 
 
 def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
@@ -246,7 +257,7 @@ def route(
     _, chosen = lax.top_k(pick, c.n_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)  # the UNBIASED scores
     if c.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + c.topk_norm_eps)
     return chosen.astype(jnp.int32), weights * c.routed_scaling_factor
 
 

@@ -187,10 +187,10 @@ def quantize_params(
         raise ValueError(f"bits must be 4 or 8, got {bits}")
     qt = quantize_tensor if bits == 8 else quantize_tensor4
     layers = params["layers"]
-    if "moe" in layers or "mamba" in layers or "gdn" in layers:
+    if "moe" in layers or "mamba" in layers or "gdn" in layers or "conv" in layers:
         raise ValueError(
-            "expert, latent-attention, Mamba and delta-rule (Gated DeltaNet, Kimi Delta "
-            "Attention) leaves have no scales: only the "
+            "expert, latent-attention, Mamba, delta-rule (Gated DeltaNet, Kimi Delta "
+            "Attention) and short-convolution leaves have no scales: only the "
             "dense decoder's tree is quantized"
         )
     out: Params = {"embed": params["embed"], "final_norm": params["final_norm"]}

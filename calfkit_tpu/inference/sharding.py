@@ -34,6 +34,8 @@ LATENT_ATTN_LEAVES = ("wq", "w_kva", "kv_norm", "w_uk", "w_uv", "wo", "attn_norm
 # the leaves of one stacked group of Gated DeltaNet layers (inference/gdn.py)
 GDN_LEAVES = ("w_in", "conv_w", "A_log", "dt_bias", "norm", "w_out", "mixer_norm")
 KDA_LEAVES = (*GDN_LEAVES, "w_alpha")  # Kimi Delta Attention: the decay's own matrix
+# the leaves of one stacked group of gated short convolutions (inference/shortconv.py)
+SHORTCONV_LEAVES = ("w_in", "conv_w", "w_out", "mixer_norm")
 GATED_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
 MLP_LEAVES = ("w_gate", "w_up", "w_down", "mlp_norm")
 MOE_LEAVES = ("router", "router_bias", *MLP_LEAVES)
@@ -125,6 +127,20 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
                 ("router", "w_gate", "w_up", "w_down")
                 + (() if config.parallel_block else ("mlp_norm",))
                 + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole),
+        }
+    elif config.shortconv:
+        # a short-convolution hybrid (see model.py): every leaf replicated, as
+        # the delta-rule hybrid's are and for its reason (the engine refuses such
+        # a model on a mesh of more than one device: the conv leaves, the experts
+        # and the per-slot tail have no layout over tp)
+        whole = NamedSharding(mesh, P())
+        layers = {
+            "attn": dict.fromkeys(
+                GATED_ATTN_LEAVES[: None if config.qk_norm else -2], whole),
+            "conv": dict.fromkeys(SHORTCONV_LEAVES, whole),
+            "dense": dict.fromkeys(MLP_LEAVES, whole),
+            "moe": dict.fromkeys(
+                MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole),
         }
     elif config.gdn:
         # a Gated DeltaNet hybrid (see model.py): every leaf replicated, as

@@ -87,6 +87,9 @@ SCOPES = frozenset({
     # product and the bounded gate) and the group-limited step of a gate's
     # choice inside "moe/router" (moe.py)
     "decay", "groups",
+    # a gated short convolution's mixer (shortconv.py), in decode steps and
+    # chunks: "in_proj", "conv" and "out_proj" inside it are Mamba-2's names
+    "shortconv",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

@@ -413,6 +413,25 @@ WINDOW_MOE = Family(
     forward_takes=("n_valid",),
 )
 
+# A gated short convolution (a state that is the conv tail alone) beside rotary
+# GQA attention with normed heads, two leading dense layers, then bias-routed
+# experts with no shared one (LFM2-8B-A1B's kind; preset ``debug-lfm2-moe``:
+# the cell's cut ``c c A c`` x 3, a head of 4 layers and two periods in the
+# scan; 4 query heads over 2 KV heads of 8; 8 experts with 3 a token).
+LFM2_MOE = Family(
+    arch_name="lfm2-conv-gqa-moe",
+    toy=preset("debug-lfm2-moe"),
+    # float32 against float32: the two sides differ in the ORDER of sums (the
+    # tail and a chunk's window against one padded sum over the row, grouped
+    # experts against every expert masked, paged windows against whole rows)
+    # and in nothing else.  The stated program reads 1.5e-5 at the worst
+    # position over 12 layers and logits up to 5 in size; the nearest control
+    # (the taps summed in bfloat16) reads 4e-3 and the others more (their tests
+    # assert each).  1e-4 as ``GDN_MOE`` holds its own.
+    logit_tol=1e-4,
+    dense_max_tokens=8,
+)
+
 
 # ----------------------------------------------------------------------------
 # The grouped expert products over the STACK (PR 34): one reading shared by

@@ -210,9 +210,12 @@ def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen()
 def test_the_dense_form_s_limit_follows_the_shape_and_kimi_s_has_not_moved(monkeypatch):
     monkeypatch.undo()  # the measured limits, not the toy one
     kimi = preset("kimi-vl-a3b-instruct")
-    # timed AND compiled: Kimi's; and (PR 40) a shape whose dense decode program copied both
-    # expert stacks whole when compiled for the v5e: no product of it is dense
-    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
+    # timed AND compiled: Kimi's; and (PRs 40 and 44) two shapes whose dense decode program
+    # copied both expert stacks whole when compiled for the v5e: no product of them is dense
+    assert moe._DENSE_TO_THE_CROSSING == {
+        (64, 2048, 1408): 1536, (64, 2560, 768): 0, (32, 2048, 1792): 0}
+    lfm2 = preset("lfm2-8b-a1b")
+    assert not moe.dense_form(1, lfm2) and not moe.dense_form(128, lfm2)
     ling = replace(preset("ling-3.0-flash-vl"), n_routed_experts=64, n_experts_total=512)
     assert not moe.dense_form(1, ling) and not moe.dense_form(128, ling)
     assert moe.dense_form(1536, kimi) and not moe.dense_form(1537, kimi)

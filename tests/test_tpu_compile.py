@@ -1245,3 +1245,113 @@ def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persist
     per_layer = cfg.recurrent_state_bytes(rt.max_batch_size) // cfg.n_recurrent_layers
     assert report["decode"][0] < 2 * per_layer + engine._k.nbytes + engine._v.nbytes, report
     print("temporaries and arguments, bytes:", report)
+
+
+# ---------------------------------------------------------------------------
+# a gated short convolution beside rotary GQA attention with normed heads, two
+# dense layers, then 32 bias-routed experts (lfm2-8b-a1b): the paged decode
+# read at granite's 32 / 8 heads of 64, the conv tail read and rewritten in
+# place under ``shortconv/conv`` (no matrix state, no kernel of its own), the
+# expert stacks read where they lie (the grouped form at every size)
+# ---------------------------------------------------------------------------
+
+
+def _lfm2_cell_engine(monkeypatch, held: int | None = None, slots: int | None = None):
+    """The engine of the cell's configuration at its published WIDTHS, its 12
+    layers and its runtime; ``held`` experts of the 32 and ``slots`` where the
+    test has no use for 7 GB of experts and all 128 (the products then take
+    the form the cell's shape takes: grouped)."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference import moe
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "lfm2-8b-a1b.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    assert config.layer_types == ("conv", "conv", "attention", "conv") * 3
+    assert config.stack_plan == (4, ("conv", "conv", "attention", "conv"))
+    shape = (config.n_routed_experts, config.d_model, config.moe_d_ff)
+    assert moe._DENSE_TO_THE_CROSSING[shape] == 0
+    if held is not None:
+        config = replace(config, n_routed_experts=held)
+        monkeypatch.setitem(  # for the test alone: the table is the program's
+            moe._DENSE_TO_THE_CROSSING, (held, config.d_model, config.moe_d_ff), 0)
+    if slots is not None:
+        runtime = replace(runtime, max_batch_size=slots, num_kv_pages=slots * 33 + 1)
+    engine = InferenceEngine(
+        config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
+    # the state's pass is XLA whatever is asked: there is no matrix state to pass over
+    assert (engine._attn_impl, engine._ssm_impl) == ("pallas", "xla")
+    return engine, described
+
+
+def _lfm2_checks(engine, name, compiled):
+    """ONE paged decode read a dispatch loop's attention layer kind (the head
+    unrolls one, the scan's period holds one), under ``decode_loop/.../attention``,
+    and no other kernel; the mixer under ``shortconv`` with its three scopes,
+    in a ragged program under ``chunk_loop`` too; NO copy of an expert stack;
+    the tails and the pool go out where they came in."""
+    import re
+
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line and "pallas_call" in line]
+    assert len(kernels) == 2 and all("paged_decode" in k and "decode_loop/" in k
+                                     for k in kernels), kernels
+    for scope in ("/shortconv/in_proj", "/shortconv/conv", "/shortconv/out_proj", "/qk_norm",
+                  "/mlp/moe/router", "ragged-dot"):
+        assert scope in hlo, scope
+    cfg, rt = engine.config, engine.runtime
+    E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
+    stacks = re.compile(
+        rf"= bf16\[(?:{cfg.n_moe_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
+    assert not stacks.search(hlo), name
+    memory = compiled.memory_analysis()
+    tails = cfg.recurrent_state_bytes(rt.max_batch_size)
+    assert memory.alias_size_in_bytes >= tails + engine._k.nbytes + engine._v.nbytes
+    if name.startswith("ragged"):
+        assert "chunk_loop/" in hlo and "/shortconv/" in hlo.split("chunk_loop/", 1)[1]
+    return memory
+
+
+def test_shortconv_expert_cell_decode_program_compiles_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The decode dispatch of the new cell at its published widths and 12
+    layers, 8 of the 32 experts and 16 of the 128 slots (the products' shapes
+    and form but not 7 GB of experts), compiled for the described v5e (the
+    ragged programs and the temporaries: the full-size test below)."""
+    engine, _ = _lfm2_cell_engine(monkeypatch, held=8, slots=16)
+    for name, compiled in _kda_programs(engine, one_chip, ()).items():
+        memory = _lfm2_checks(engine, name, compiled)
+        print("temporaries, bytes:", name, memory.temp_size_in_bytes)
+
+
+@pytest.mark.slow  # 7.9 GB of weights and three whole-program compiles on every core (2 min): the
+# offline lane runs it, as it runs the other expert cells'; PERF.md section 6, PR 44 has its readings
+def test_shortconv_expert_cell_programs_at_full_size_fit_the_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The decode dispatch and the ragged programs (a wave of 1 and 4 rows of
+    1,024) of the new cell at its FULL size: all 32 experts of 10 layers, 128
+    slots.  No expert stack is copied (the DENSE form at 128 rows copied both:
+    6.38 GB of temporaries, a program that does not fit), the temporaries stay
+    under what ``hbm`` states, and arguments and temporaries together leave the
+    16 GB chip 3 GB of room."""
+    from calfkit_tpu.inference import moe
+
+    engine, described = _lfm2_cell_engine(monkeypatch)
+    cfg, rt = engine.config, engine.runtime
+    assert not moe.dense_form(rt.prefill_chunk, cfg) and not moe.dense_form(1, cfg)
+    stated = described["hbm"]["temporaries_bytes"]
+    report = {}
+    for name, compiled in _kda_programs(engine, one_chip, (1, 4)).items():
+        memory = _lfm2_checks(engine, name, compiled)
+        report[name] = (memory.temp_size_in_bytes, memory.argument_size_in_bytes)
+        assert memory.temp_size_in_bytes < stated["decode" if name == "decode" else "ragged"], (
+            name, report)
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9, (name, report)
+    print("temporaries and arguments, bytes:", report)
