@@ -76,30 +76,50 @@ above; PERF.md section 6, PRs 33 and 34):
 Without the copy the two cross near 512 tokens at this shape and near
 1,024 at the first (the limits below were set on the PR 31 and 33 rows and
 are not moved by this reading: a change of form is a change of program,
-timed in its cell).  And a dense CHUNK has a cost that no timing of the
-product alone shows.  Compiled for
-the v5e, this model's ragged program (64 rows in its decode steps, a chunk
-of 1,024 beside them) with the chunk in the dense form copies the WHOLE of
-``w_gate`` and ``w_up``, every layer, into the chunk product's layout (D
-minor: ``bf16[8,128,2048,512]{2,3,1,0}``, in the entry computation) once a
-dispatch: 2 x 2.15 GB of temporaries and ~10 ms of traffic, against the
-1.2 ms a layer the dense form saves at 1,024.  The first model's program at
-the same two token counts copies nothing (its one-row ragged program
-compiled at all 7 layers: 2.44 GB of temporaries, no array of a stack's
-shape but the scan's slice of a layer).  Which of the two a shape gets is
-the compiler's choice and shows in a compile alone, so the limit is set
-the safe way round:
+timed in its cell).
+
+The dense products are spelled WEIGHTS FIRST (``"edf,td->etf"``), and the
+spelling is part of the program (PR 45).  ``jnp.einsum`` lowers the two
+spellings to opposite ``dot_general``s: rows first (``"td,edf->etf"``,
+through PR 44) to the experts by the rows, ``[E, Fe, T]`` and a transpose,
+weights first to the rows by the experts, ``[T, E, Fe]`` and a transpose.
+The first the TPU compiler, from 128 rows on (a whole lane tile of rows;
+at 64 and 96 it does not, at any shape), compiles as it stands: the experts
+its input and the ROWS its kernel, the result with the rows minor, and it
+wants the experts with the hidden size minor (``bf16[E,2048,Fe]{1,2,0}``).
+Inside a dispatch the layer's experts are a slice of a stack that no step
+changes: the change of layout is hoisted out of the scan over layers AND
+the loop over steps, into one COPY OF THE WHOLE STACK a dispatch.  That is
+what three PRs met as a property of a shape: Qwen3-Next's ragged program with a dense chunk of
+1,024 copied ``w_gate`` and ``w_up`` whole (PR 33: 2 x 2.15 GB of
+temporaries, ~10 ms of traffic a dispatch), Ling's and LFM2's DECODE
+programs at their 128 rows did (PRs 40 and 44: 7.4 and 6.4 GB of
+temporaries, programs that do not fit the chip), and Kimi's, Qwen3-Next's
+and command-a-plus's decode programs never did, at 64, 64 and 32 rows.
+Weights first, the compiled product keeps the rows as its input and the
+experts as its kernel in the layout they are stored in (hidden size in,
+expert width out; the result ``[E, T, Fe]``): no decode or ragged program
+of the five held shapes holds an array of a stack's shape among its
+temporaries (``tests/test_tpu_compile.py`` compiles the contrast for the
+described v5e at every held shape at 128 rows, so the spelling is not
+tidied back; a raw ``dot_general`` of the experts by the rows that keeps
+``[E, Fe, T]`` copies as the old einsum did).  Alone on a v5e, nested as a
+dispatch nests them, ms a layer at 128 rows of (32, 2048, 1792): rows first
+1.04 with its copy, weights first 0.95, the grouped form 2.59; reading the
+layer's 0.70 GB takes 0.86.  At 64 and 32 rows the two spellings compile to
+one product and time the same (1.478 / 1.477 at Kimi's shape, 1.076 / 1.077
+at Qwen3-Next's, 2.255 / 2.255 at command-a-plus's; PERF.md section 6, PR 45).
+
+Which form a shape's products take is then a matter of timing alone:
 
 - to ``_DENSE_MAX_TOKENS`` (512) every shape takes the dense form: from 256
   rows to 512 it wins in both tables, at a decode step's 64 rows it wins
   (2.5x) or ties, and it is every decode step's form (at 8 rows the grouped
   form without its copy reads few experts and is the faster: not used, a
   decode step has all its slots' rows);
-- from there to where the two forms' times crossed when the limits were set
-  (the grouped form with its layer copy) the dense form may cost a copy of
-  two stacks, so a shape takes it only if
-  it is listed in ``_DENSE_TO_THE_CROSSING``: its forms TIMED on the chip
-  and its ragged program COMPILED at full depth without that copy.
+- beyond it a shape takes the dense form only to the limit its row of
+  ``_DENSE_TO_THE_CROSSING`` gives: its two forms TIMED on the chip, in its
+  cell, and its programs COMPILED at full depth.
 
 A Ling-3.0-style layer (``n_group`` > 1: DeepSeek-V3's group-limited choice)
 is the first gate with one step before its top-k, over ALL the experts
@@ -140,23 +160,20 @@ _HI = lax.Precision.HIGHEST  # the gate's float32 product: no bf16 passes
 # the dense form's limit in tokens for ANY shape: the decode steps' rows and
 # narrow chunks (the module's text)
 _DENSE_MAX_TOKENS = 512
-# (experts held, hidden, expert width) -> the limit of a shape that was timed
-# and compiled: the first table's crossing, between 1,024 and 2,048; and 0 for
-# a shape whose DECODE program, compiled for the v5e at its cell's 128 rows,
-# copies the whole of w_gate and w_up into the dense product's layout (2 x 1.51
-# GB of temporaries and a layer's 252 MB again in the loop: 7.4 GB in all, a
-# program that does not fit the chip; PERF.md section 6, PR 40): every product
-# of that shape is grouped, which reads the experts hit where they lie.  (32, 2048,
-# 1792) likewise: its decode program at 128 rows and 10 expert layers, compiled for
-# the v5e, copies both stacks whole in the dense form (2 copies, 6.38 GB of
-# temporaries on 9.58 GB of arguments: 15.96 GB, no room on a 16 GB chip), and holds
-# 1.69 GB grouped (2.53 GB in a ragged program of four rows; PERF.md section 6, PR 44).
-# Both 0 rows are ONE compiler artefact and no property of their shapes: the einsum
-# "td,edf->etf" nested in a dispatch's loop over steps hoists a whole-stack transpose;
-# three spellings without the copy are timed (ROADMAP S14).  Respelling experts_dense
-# changes three other cells' decode programs, so it is a perf_opt PR of its own, which
-# deletes these rows: add no fourth
-_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0, (32, 2048, 1792): 0}
+# (experts held, hidden, expert width) -> the limit of a shape whose two forms were
+# TIMED on the chip as its cell runs them.  (64, 2048, 1408): the first table's
+# crossing, between 1,024 and 2,048.  (64, 2560, 768): 0, a crossing timed at 0 and
+# no compile artefact (PR 40 set it on one: the dense form, then spelled rows first,
+# copied both stacks whole in the decode program; the module's text).  Its cell's
+# group-limited gate sends this chip about half of a step's 128 rows and ~10 of the
+# 64 held experts a layer, and the grouped form reads the experts hit alone: timed
+# alone at 128 rows under that routing, ms a layer, dense (weights first) 1.03
+# whatever the routing, grouped 0.52 at 10 experts hit, 0.77 at 16, 1.32 at 29, 2.04
+# at 46, 2.79 at all 64 (PERF.md section 6, PR 45).  A shape without a row takes the
+# default, LFM2's (32, 2048, 1792) among them (every expert is hit every step there:
+# dense 0.95, grouped 2.59; at a chunk's 512 tokens 2.00 / 2.91, at 1,024 4.19 /
+# 3.27, so the default's 512 is its crossing too)
+_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
 
 
 def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
@@ -280,8 +297,10 @@ def experts_dense(h: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Param
     with jax.named_scope("group"):
         gates = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E] float32
     with jax.named_scope("experts"):
-        g = jnp.einsum("td,edf->etf", h, lp["w_gate"])
-        u = jnp.einsum("td,edf->etf", h, lp["w_up"])
+        # the WEIGHTS first: rows first, from 128 rows on, a dispatch copies the whole
+        # stack (the module's text; tests/test_tpu_compile.py compiles the contrast)
+        g = jnp.einsum("edf,td->etf", lp["w_gate"], h)
+        u = jnp.einsum("edf,td->etf", lp["w_up"], h)
         act = jax.nn.silu(g) * u  # [E, T, Fe]
     with jax.named_scope("combine"):
         act = (act.astype(jnp.float32) * gates.T[:, :, None]).astype(h.dtype)

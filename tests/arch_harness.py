@@ -444,6 +444,19 @@ LFM2_MOE = Family(
 # routings are made by hand, so that a group is exactly as empty or as full as
 # the case says.
 STACK_LAYERS, STACK_TOKENS = 3, 40
+def experts_dense_rows_first(h, onehot, weights, lp):
+    """``moe.experts_dense`` as it was spelled through PR 44, the ROWS first
+    (``"td,edf->etf"``): the reference the respelled form is held to in
+    float32 (``tests/test_gdn_moe.py``), and what the TPU compiler copies a
+    whole expert stack for from 128 rows on (``tests/test_tpu_compile.py``)."""
+    gates = jnp.sum(onehot * weights[..., None], axis=1)
+    g = jnp.einsum("td,edf->etf", h, lp["w_gate"])
+    u = jnp.einsum("td,edf->etf", h, lp["w_up"])
+    act = jax.nn.silu(g) * u
+    act = (act.astype(jnp.float32) * gates.T[:, :, None]).astype(h.dtype)
+    return jnp.einsum("etf,efd->td", act, lp["w_down"])
+
+
 STACK_ROUTINGS = ("even", "one_expert_empty", "first_expert_all", "last_expert_all")
 
 

@@ -1180,8 +1180,10 @@ def test_the_program_s_description_of_lfm2_is_the_file_s():
     from calfkit_tpu.inference.config import preset
 
     assert preset("lfm2-8b-a1b").param_count == config["published_parameters"]
-    # the shape's products are always grouped: compiled and timed (moe.py)
-    assert not moe.dense_form(1, described) and not moe.dense_form(128, described)
+    # since PR 45 the shape takes the default (moe.py): its decode steps' 128 rows dense,
+    # every chunk of the cell (a row of 1,024 at the least) grouped
+    assert moe.dense_form(runtime.max_batch_size, described)
+    assert not moe.dense_form(runtime.prefill_chunk, described)
     toy, toy_runtime = arch.model(config, True)
     assert toy.layer_types == described.layer_types and toy.first_k_dense == 2
     assert toy_runtime.max_batch_size == 8 and toy.tail_error_limit == 0.0

@@ -31,7 +31,8 @@ from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
 from tests.arch_harness import GDN_MOE as FAMILY
 from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size, stack_check,
+    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size, experts_dense_rows_first,
+    stack_check,
 )
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
@@ -210,12 +211,13 @@ def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen()
 def test_the_dense_form_s_limit_follows_the_shape_and_kimi_s_has_not_moved(monkeypatch):
     monkeypatch.undo()  # the measured limits, not the toy one
     kimi = preset("kimi-vl-a3b-instruct")
-    # timed AND compiled: Kimi's; and (PRs 40 and 44) two shapes whose dense decode program
-    # copied both expert stacks whole when compiled for the v5e: no product of them is dense
-    assert moe._DENSE_TO_THE_CROSSING == {
-        (64, 2048, 1408): 1536, (64, 2560, 768): 0, (32, 2048, 1792): 0}
+    # both rows TIMED on the chip: Kimi's crossing, and Ling's at 0 (PR 45: its
+    # group-limited gate sends a step ~10 of the 64 held experts, which the grouped
+    # form reads alone).  LFM2's shape has NO row since PR 45: the default
+    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
     lfm2 = preset("lfm2-8b-a1b")
-    assert not moe.dense_form(1, lfm2) and not moe.dense_form(128, lfm2)
+    assert moe.dense_form(1, lfm2) and moe.dense_form(128, lfm2) and moe.dense_form(512, lfm2)
+    assert not moe.dense_form(1024, lfm2)
     ling = replace(preset("ling-3.0-flash-vl"), n_routed_experts=64, n_experts_total=512)
     assert not moe.dense_form(1, ling) and not moe.dense_form(128, ling)
     assert moe.dense_form(1536, kimi) and not moe.dense_form(1537, kimi)
@@ -228,6 +230,78 @@ def test_the_dense_form_s_limit_follows_the_shape_and_kimi_s_has_not_moved(monke
         assert not moe.dense_form(1024, share)
     assert moe._DENSE_MAX_TOKENS == 512
     assert not moe.dense_form(1024, replace(kimi, moe_d_ff=1024))
+
+
+# toy widths (1/64) of the five shapes a cell holds, each with its gate: Kimi's (the
+# biased sigmoid, two shared experts summed), Qwen3-Next's (a softmax over 512, 128 held
+# from the 129th, a gated shared expert), command-a-plus's (the greedy sigmoid, 16 of 128
+# held, four shared experts averaged), Ling's (the choice by group, 64 of 512 held: one
+# group), LFM2's (the biased sigmoid with its 1e-6, NO shared expert)
+_HELD_SHAPES = {
+    "kimi": dict(n_routed_experts=64, d_model=32, moe_d_ff=22, n_experts_per_tok=6,
+                 n_shared_experts=2, routed_scaling_factor=2.446, topk_method="noaux_tc"),
+    "qwen3-next": dict(n_routed_experts=128, experts_scored=512, expert_first=128, d_model=32,
+                       moe_d_ff=8, n_experts_per_tok=10, n_shared_experts=1,
+                       shared_expert_gate=True, scoring_func="softmax"),
+    "command-a-plus": dict(n_routed_experts=16, experts_scored=128, expert_first=32, d_model=64,
+                           moe_d_ff=64, n_experts_per_tok=8, n_shared_experts=4,
+                           shared_expert_combine="average"),
+    "ling": dict(n_routed_experts=64, experts_scored=512, expert_first=192, d_model=40,
+                 moe_d_ff=12, n_experts_per_tok=8, n_shared_experts=1, n_group=8, topk_group=4,
+                 routed_scaling_factor=2.5, topk_method="noaux_tc"),
+    "lfm2": dict(n_routed_experts=32, d_model=32, moe_d_ff=28, n_experts_per_tok=4,
+                 topk_method="noaux_tc", topk_norm_eps=1e-6),
+}
+
+
+def _held_layer(fields: dict, key: int):
+    """(what ``moe`` reads of a configuration, one expert layer's float32 leaves)."""
+    c = SimpleNamespace(**{
+        "experts_scored": fields["n_routed_experts"], "expert_first": 0, "n_shared_experts": 0,
+        "shared_expert_gate": False, "shared_expert_combine": "sum", "scoring_func": "sigmoid",
+        "topk_method": "greedy", "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": 1.0, "topk_norm_eps": 1e-20, "n_moe_layers": 1, "norm_plus_one": False,
+        **fields})
+    c.expert_share = c.experts_scored != c.n_routed_experts
+    lp = jax.tree.map(lambda a: a[0], moe.init_moe_params(c, jax.random.key(key), jnp.float32))
+    lp["router"] = lp["router"] * 4.0  # scores that spread: the choice is no near-tie
+    if "router_bias" in lp:
+        lp["router_bias"] = jax.random.uniform(
+            jax.random.key(key + 1), lp["router_bias"].shape, jnp.float32, -0.1, 0.1)
+    return c, lp
+
+
+@pytest.mark.parametrize("shape", sorted(_HELD_SHAPES))
+def test_the_respelled_dense_form_is_the_old_product_and_the_grouped_one(monkeypatch, shape):
+    """PR 45 changed the dense form's SPELLING (the weights first) and nothing
+    of its mathematics: at toy widths of each held shape, under its own gate
+    (a share, a choice by group, a shared expert or none), the products equal
+    the old einsum's and the grouped form's on the same routing, and the
+    whole layer through either form is one layer."""
+    c, lp = _held_layer(_HELD_SHAPES[shape], key=45)
+    E = c.n_routed_experts
+    h = jax.random.normal(jax.random.key(46), (48, c.d_model), jnp.float32)
+    chosen, weights = moe.route(h, lp, c)
+    onehot = chosen[..., None] == jnp.arange(E, dtype=jnp.int32) + c.expert_first
+    held = float(jnp.mean(jnp.any(onehot, axis=-1)))
+    # a share holds SOME of the pairs (Ling's one group: a row's kept groups or not)
+    assert (0.05 < held < 0.9) if c.expert_share else held == 1.0
+    want = experts_dense_rows_first(h, onehot, weights, lp)
+    assert float(jnp.abs(want).max()) > 0.05
+    dense = moe.experts_dense(h, onehot, weights, lp)
+    # float32 sums in two orders, outputs of order 0.1-1
+    assert np.abs(np.asarray(dense) - np.asarray(want)).max() < 1e-4
+    stack = {n: lp[n][None] for n in ("w_gate", "w_up", "w_down")}
+    grouped = moe.experts_grouped(h, chosen, onehot, weights, stack, 0, c.expert_share)
+    assert np.abs(np.asarray(dense) - np.asarray(grouped)).max() < 1e-4
+    # and the layer (the shared expert, its gate, its mean) through either form
+    layer = {}
+    for form in (True, False):
+        monkeypatch.setattr(moe, "dense_form", lambda tokens, config, form=form: form)
+        layer[form] = moe.moe_ffn(h.reshape(2, 24, -1), lp, c, None, None, 0)[0]
+    assert np.abs(np.asarray(layer[True]) - np.asarray(layer[False])).max() < 1e-4
+    routed_only = np.abs(np.asarray(layer[True]).reshape(48, -1) - np.asarray(dense)).max()
+    assert (routed_only > 1e-2) if c.n_shared_experts else (routed_only < 1e-6)
 
 
 # ------------------------------------------------ (e) the description and its refusals

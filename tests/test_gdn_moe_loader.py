@@ -179,14 +179,18 @@ LATENT = ModelConfig(
 # traced them for a dense model (Mistral's kind), a Mamba-2 hybrid
 # (granite's) and a latent-attention stack with routed experts (Kimi's),
 # recorded there with this file's ``_programs``.  A PR that changes these
-# programs on purpose records anew.
+# programs on purpose records anew: PR 45 did for the latent-moe pair (the
+# dense expert products spelled weights first: in each program the two up
+# products' ``dot_general`` takes the rows and then the experts, ``[T, E, Fe]``
+# with a transpose ``(1, 0, 2)`` behind it, where it took the experts and then
+# the rows, ``[E, Fe, T]`` and ``(0, 2, 1)``; nothing else differs).
 TRACED_AT_THE_PARENT = {
     "dense": {"decode": "fbe15a7ac753ad608e6a4fe90dd49d4d20b04f4b7986c5c3eea5f8f6133579b0",
               "ragged": "0b045d92906ae9f97054d4eb9ee84a80148519119f4a785773ed8c280baa3111"},
     "hybrid": {"decode": "cbbf613956e99afb03cf792e8ed6783ae5c5b580dcf8928dfdf6330805332103",
                "ragged": "c63f9f1057a6fed2dcf53c0855ff1f8e424554878243361d3ef91e0ed54a3418"},
-    "latent-moe": {"decode": "d267c92cfabaeb3d1072b12f11b06605f6b551fc0bb5cc9ceab8bfed599eb46b",
-                   "ragged": "3cff1bc67e9cd5fcb58ea80590500e7a612c154c6ff4ebc008d98260b7318fa5"},
+    "latent-moe": {"decode": "c450ecc6585ea3023970a319a82ba9c07ccf93ba6c8e54755ee42699fcce23c2",
+                   "ragged": "8f851918146afc61f4a5f9cfccc3f043c690cde1af61e6aa5df5c2bccf9d3d08"},
 }
 
 
@@ -231,9 +235,11 @@ def test_the_three_older_kinds_trace_the_programs_the_parent_traced(monkeypatch,
 # (32 tokens past the toy limit of 8), as PR 34 traces them: the products
 # over the flattened stack, the layer as the one run of groups that is not
 # empty.  The parent's differed (a ragged product on the sliced layer).
+# Recorded anew in PR 45: the decode steps BESIDE the chunk are dense, and
+# their up products' ``dot_general`` changed as above; the chunk's did not.
 TRACED_SINCE_PR_34 = {
-    "gdn-moe": "880c5825c14e8a6051add5a4ba15d6376112a0cdb92f743851084ff89e080ef7",
-    "latent-moe": "ffac121351b5c1c15d2b5dfd2dc8d70087a0dfa28ad726deb8fd12c43a0ae3d3",
+    "gdn-moe": "3ca11ce00f61f0e4e8907e35d7faefe7f4ae9bef45c5c656f36adb3673e277cf",
+    "latent-moe": "5afea74116c954c553c2f4ebda6e68bc0b470d669af1bf0cbe1443913bfbdf15",
 }
 
 

@@ -217,6 +217,41 @@ def _made_in_loops(hlo: str, shapes: tuple[str, ...]) -> dict[str, list[str]]:
     return {name: ops for name, ops in made.items() if ops}
 
 
+def _expert_stack_copies(hlo: str, config) -> list[str]:
+    """The ``copy`` operations whose result is an array of a whole expert
+    stack's shape (``[layers, E, D, Fe]`` or ``[layers, E, Fe, D]``) or of one
+    layer's: a change of layout hoisted out of a dispatch's loops, which is
+    what the dense products spelled rows first compiled to from 128 rows on
+    (``moe.py``; PR 45)."""
+    import re
+
+    E, D, F = config.n_routed_experts, config.d_model, config.moe_d_ff
+    stacks = re.compile(rf"= bf16\[(?:\d+,)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
+    return [line.strip()[:160] for line in hlo.splitlines() if stacks.search(line)]
+
+
+# The five expert configurations' full-size programs' temporaries at PR 44, the parent of
+# PR 45 (which respelled the dense products): the slow tests below on that tree, bytes
+_TEMPORARIES_AT_PR_44 = {
+    "kimi": {"decode": 1_282_386_432, "ragged x2": 1_198_626_816, "ragged x1": 1_195_691_520},
+    "qwen3-next": {"decode": 543_565_312, "ragged x1": 553_725_952, "ragged x2": 669_483_520,
+                   "ragged x4": 1_391_255_552},
+    "command-a-plus": {"decode": 1_149_901_312, "ragged 6144": 1_158_707_200,
+                       "ragged 16384": 1_158_739_456},
+    "ling": {"decode": 641_782_272, "ragged x1": 657_845_760, "ragged x2": 1_251_969_024,
+             "ragged x4": 2_493_971_968},
+    # the decode program held 1,691,838,976 with its products GROUPED; dense since PR 45 it
+    # holds 162,304 bytes more (the pool's layout copies are its temporaries, not the products)
+    "lfm2": {"decode": 1_692_001_280, "ragged x1": 2_523_130_880, "ragged x4": 2_528_257_024},
+}
+
+
+def _no_larger_than_at_pr_44(cell: str, report: dict) -> None:
+    want = _TEMPORARIES_AT_PR_44[cell]
+    assert set(report) == set(want), (report, want)
+    assert all(report[name] <= want[name] for name in want), (cell, report, want)
+
+
 def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
     one_chip, no_persistent_cache
 ):
@@ -754,6 +789,7 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     assert len(kernels) == 2 and all("/mla/attention/" in line for line in kernels), kernels
     assert all("decode_loop/" in line for line in kernels)
     assert "gather_window" not in hlo and "/mlp/moe/experts" in hlo
+    assert not _expert_stack_copies(hlo, cfg) and "ragged-dot" not in hlo
     # the rope side's view: once a dispatch, in the entry computation; the c
     # side: no operation of a loop's body makes an array of its size or of
     # ONE LAYER's (the XLA read's dynamic-slice copy)
@@ -789,8 +825,7 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
     # their operand: the two layers' stack, flattened, never a slice of it
     assert all("bf16[128,2048,1408]" in line or "bf16[128,1408,2048]" in line for line in grouped)
     assert not _made_in_loops(hlo, ("bf16[64,2048,1408]", "bf16[64,1408,2048]"))
-    # the parent's program at this size (PR 33's tree, this test at 3 layers): 1,198,723,584 B
-    assert ragged.memory_analysis().temp_size_in_bytes <= 1_198_723_584
+    assert not _expert_stack_copies(hlo, cfg)
     assert "decode_loop/" in hlo and "chunk_loop/" in hlo
     assert len(own_kernels(hlo)) == 2
     # the chunk still reads its rows' windows through XLA (the reference
@@ -804,9 +839,83 @@ def test_latent_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persi
         moe=abstract(zero), wmoe=abstract(zero),
         true_lens=abstract(jax.ShapeDtypeStruct((1,), jnp.int32))).compile()
     assert "ragged-dot" not in narrow.as_text()  # 1,024 tokens: the dense form
-    print("temporaries, bytes: decode", decode.memory_analysis().temp_size_in_bytes,
-          "ragged x2", ragged.memory_analysis().temp_size_in_bytes,
-          "ragged x1", narrow.memory_analysis().temp_size_in_bytes)
+    assert not _expert_stack_copies(narrow.as_text(), cfg)
+    assert not _made_in_loops(narrow.as_text(), ("bf16[64,2048,1408]", "bf16[64,1408,2048]"))
+    report = {"decode": decode.memory_analysis().temp_size_in_bytes,
+              "ragged x2": ragged.memory_analysis().temp_size_in_bytes,
+              "ragged x1": narrow.memory_analysis().temp_size_in_bytes}
+    _no_larger_than_at_pr_44("kimi", report)
+    print("temporaries, bytes:", report)
+
+
+# The dense expert products' SPELLING (PR 45): what PR 44's "20 lines" showed, kept
+# ---------------------------------------------------------------------------
+
+# (experts held, hidden, expert width): Kimi's, Qwen3-Next's, command-a-plus's,
+# Ling's and LFM2's, the five shapes a cell holds
+HELD_EXPERT_SHAPES = [
+    (64, 2048, 1408), (128, 2048, 512), (16, 4096, 4096), (64, 2560, 768), (32, 2048, 1792)]
+
+
+@pytest.mark.parametrize("shape", HELD_EXPERT_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_the_dense_products_nested_in_a_dispatch_copy_no_stack_on_v5e(
+        shape, one_chip, no_persistent_cache):
+    """The dense form as a decode dispatch nests it: a scan over the expert
+    layers inside a loop over steps, at 128 rows (a whole lane tile of rows:
+    from there on, at ANY of the five shapes, the TPU compiler takes the
+    rows-first einsum as ``jnp.einsum`` lowers it, the experts by the rows,
+    the experts its input and the rows its kernel, and wants the experts
+    with the hidden size minor; at 64 or 96 rows it does not).  Spelled ``td,edf->etf`` the stack is invariant in the outer
+    loop, so that change of layout is hoisted out of both loops as a COPY OF
+    THE WHOLE STACK (PRs 40 and 44 met it as 7.4 and 6.4 GB of temporaries
+    and sent two shapes' every product to the grouped form).  Spelled weights
+    first (``edf,td->etf``), as ``moe.experts_dense`` is, the experts are
+    read where they lie: nothing of a stack's size among the temporaries,
+    and nothing of a layer's made in a loop.  So the spelling cannot be
+    tidied back."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from calfkit_tpu.inference import moe
+    from tests.arch_harness import experts_dense_rows_first
+
+    E, D, F = shape
+    Lm, T, k, steps = 3, 128, 4, 8
+
+    def struct(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def dispatch(dense):
+        def run(stack, x, chosen, weights):
+            onehot = chosen[..., None] == jnp.arange(E, dtype=jnp.int32)
+
+            def layer(x, m):
+                lp = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, m, 0, keepdims=False), stack)
+                return x + dense(x, onehot, weights, lp), None
+
+            def step(_, x):
+                return lax.scan(layer, x, jnp.arange(Lm, dtype=jnp.int32))[0]
+
+            return lax.fori_loop(0, steps, step, x)
+        return run
+
+    stack = {"w_gate": struct((Lm, E, D, F)), "w_up": struct((Lm, E, D, F)),
+             "w_down": struct((Lm, E, F, D))}
+    args = (stack, struct((T, D)), struct((T, k), jnp.int32), struct((T, k), jnp.float32))
+    a_stack = re.compile(rf"= bf16\[{Lm},{E},(?:{D},{F}|{F},{D})\]\S* (copy|transpose|fusion)\(")
+    one_stack = Lm * E * D * F * 2
+    old = jax.jit(dispatch(experts_dense_rows_first)).lower(*args).compile()
+    assert a_stack.search(old.as_text())
+    assert old.memory_analysis().temp_size_in_bytes >= one_stack
+    new = jax.jit(dispatch(moe.experts_dense)).lower(*args).compile()
+    hlo = new.as_text()
+    assert not a_stack.search(hlo)
+    assert not _made_in_loops(hlo, (f"bf16[{E},{D},{F}]", f"bf16[{E},{F},{D}]"))
+    assert new.memory_analysis().temp_size_in_bytes < one_stack // Lm // 8  # far under ONE layer
 
 
 # Gated DeltaNet beside gated attention, the experts held by share
@@ -865,8 +974,6 @@ def _gdn_decode_checks(engine, compiled):
     (the temporaries are under one layer's state plus the pool's layout copy
     around the consolidation scatter, which every paged cell pays: not
     larger than they were under XLA's pass)."""
-    import re
-
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     reads = [k for k in kernels if "paged_decode_attention" in k]
@@ -874,10 +981,7 @@ def _gdn_decode_checks(engine, compiled):
     assert "/attention/" in reads[0] and "decode_loop/" in reads[0]
     assert "gather_window" not in hlo and "/gdn/state/" in hlo and "/mlp/moe/experts" in hlo
     cfg, rt = engine.config, engine.runtime
-    E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
-    stacks = re.compile(
-        rf"= bf16\[(?:{cfg.n_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
-    assert not stacks.search(hlo)
+    assert not _expert_stack_copies(hlo, cfg)
     memory = compiled.memory_analysis()
     state_bytes = cfg.recurrent_state_bytes(rt.max_batch_size)
     pool_bytes = engine._k.nbytes + engine._v.nbytes
@@ -956,15 +1060,12 @@ def test_gdn_expert_cell_dispatch_programs_compile_for_v5e(one_chip, no_persiste
         hlo = ragged.as_text()
         assert "decode_loop/" in hlo and "chunk_loop/" in hlo and "ragged-dot" in hlo
         assert "chunk_loop/" in hlo and "/gdn/state/" in hlo
-        assert not any("copy(" in line and "bf16[8,128,2048,512]" in line.split("copy(")[0]
-                       for line in hlo.splitlines())
+        assert not _expert_stack_copies(hlo, cfg)
         assert not _made_in_loops(hlo, ("bf16[128,2048,512]", "bf16[128,512,2048]"))
         memory = ragged.memory_analysis()
         report[f"ragged x{rows}"] = memory.temp_size_in_bytes
-        # the parent's programs at this size (PR 33's tree, this test)
-        assert memory.temp_size_in_bytes <= {
-            1: 2_765_331_968, 2: 2_825_830_912, 4: 3_188_042_752}[rows]
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9
+    _no_larger_than_at_pr_44("qwen3-next", report)
     print("temporaries, bytes:", report)
 
 
@@ -1049,6 +1150,7 @@ def _window_cell_programs(engine, one_chip, buckets):
     assert sum("/attention/window/" in k for k in kernels) == 3
     assert sum("/attention/global/" in k for k in kernels) == 1
     assert "gather_window" not in hlo and "/mlp/moe/experts" in hlo
+    assert not _expert_stack_copies(hlo, cfg) and "ragged-dot" not in hlo
     pools = sum(a.nbytes for a in jax.tree.leaves((engine._k, engine._v)))
     assert decode.memory_analysis().alias_size_in_bytes >= pools
     report = {"decode": decode.memory_analysis().temp_size_in_bytes}
@@ -1063,6 +1165,7 @@ def _window_cell_programs(engine, one_chip, buckets):
             moe=abstract(zero), wmoe=abstract(zero)).compile()
         text = ragged.as_text()
         assert "decode_loop/" in text and "chunk_loop/" in text and "ragged-dot" in text
+        assert not _expert_stack_copies(text, cfg)
         assert all(re.search(rf'chunk_loop/[^"]*attention/{kind}/', text)
                    for kind in ("window", "global"))
         # the chunk's attention is the kernel, under the scopes swa_device_pct reads: the
@@ -1105,9 +1208,8 @@ def test_window_cell_programs_at_full_size_copy_no_expert_stack(one_chip, no_per
     and arguments and temporaries together fit the 16 GB chip."""
     engine = _window_cell_engine()
     report, text = _window_cell_programs(engine, one_chip, (6144, 16384))
-    assert not any("copy(" in line and "bf16[4,16,4096,4096]" in line.split("copy(")[0]
-                   for line in text.splitlines())
     assert not _made_in_loops(text, ("bf16[16,4096,4096]",))
+    _no_larger_than_at_pr_44("command-a-plus", report)
     print("temporaries, bytes:", report)
 
 
@@ -1147,8 +1249,9 @@ def _kda_cell_engine(held: int | None = None, slots: int | None = None):
 
 def _kda_programs(engine, one_chip, rows_of_waves):
     """The decode dispatch and the ragged programs (a wave of each of
-    ``rows_of_waves`` rows of one chunk), compiled for the described v5e ->
-    {name: compiled}."""
+    ``rows_of_waves`` rows of one chunk; a pair ``(rows, chunk)`` is a wave of
+    a bucket NARROWER than ``prefill_chunk``, whose chunk is its bucket),
+    compiled for the described v5e -> {name: compiled}."""
     import jax
     import jax.numpy as jnp
 
@@ -1163,14 +1266,19 @@ def _kda_programs(engine, one_chip, rows_of_waves):
     zero = engine._moe_zero
     out = {"decode": engine._decode_jit(window, steps, sampled).lower(
         *abstract(args), state=abstract(engine._state), moe=abstract(zero)).compile()}
-    chunk = rt.prefill_chunk
-    for rows in rows_of_waves:
+    for wave_of in rows_of_waves:
+        if isinstance(wave_of, tuple):
+            (rows, chunk), name = wave_of, "ragged {}x{}".format(*wave_of)
+            bucket = chunk
+        else:
+            rows, chunk, name = wave_of, rt.prefill_chunk, f"ragged x{wave_of}"
+            bucket = 2 * chunk
         scratch = [jax.ShapeDtypeStruct(
-            (cfg.n_kv_layers, rows, cfg.cache_heads, 2 * chunk, width), engine._k.dtype)
+            (cfg.n_kv_layers, rows, cfg.cache_heads, bucket, width), engine._k.dtype)
             for width in cfg.cache_dims]
         wave = [*scratch, jax.ShapeDtypeStruct((rows, chunk), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32)]
-        out[f"ragged x{rows}"] = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
+        out[name] = engine._ragged_jit(window, steps, sampled, chunk, rows).lower(
             *abstract((*args, *wave)), state=abstract(engine._state),
             wstate=abstract(jax.eval_shape(lambda: make_recurrent_state(cfg, rows))),
             true_lens=abstract(jax.ShapeDtypeStruct((rows,), jnp.int32)),
@@ -1186,8 +1294,6 @@ def _kda_checks(engine, name, compiled):
     the decay's under ``gdn/decay``; the groups under ``moe/router/groups``;
     NO copy of an expert stack; the stacked state and the pool go out where
     they came in."""
-    import re
-
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line and "pallas_call" in line]
@@ -1198,10 +1304,7 @@ def _kda_checks(engine, name, compiled):
                   "/mlp/moe/experts"):
         assert scope in hlo, scope
     cfg, rt = engine.config, engine.runtime
-    E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
-    stacks = re.compile(
-        rf"= bf16\[(?:{cfg.n_moe_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
-    assert not stacks.search(hlo), name
+    assert not _expert_stack_copies(hlo, cfg), name
     memory = compiled.memory_analysis()
     state_bytes = cfg.recurrent_state_bytes(rt.max_batch_size)
     pool_bytes = engine._k.nbytes + engine._v.nbytes
@@ -1234,8 +1337,9 @@ def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persist
 
     engine = _kda_cell_engine()
     cfg, rt = engine.config, engine.runtime
-    # this shape's dense form copied both expert stacks whole in the DECODE program
-    # (2 x 1.51 GB, 7.4 GB of temporaries): every product of it is grouped (moe.py)
+    # every product of this shape is grouped: its group gate sends a step few of the held
+    # experts, a crossing TIMED at 0 (moe.py, PR 45; PR 40 set it because the dense form, then
+    # spelled rows first, copied both stacks whole in the DECODE program: 7.4 GB of temporaries)
     assert not moe.dense_form(rt.prefill_chunk, cfg) and not moe.dense_form(1, cfg)
     report = {}
     for name, compiled in _kda_programs(engine, one_chip, (1, 2, 4)).items():
@@ -1244,6 +1348,7 @@ def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persist
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9, (name, report)
     per_layer = cfg.recurrent_state_bytes(rt.max_batch_size) // cfg.n_recurrent_layers
     assert report["decode"][0] < 2 * per_layer + engine._k.nbytes + engine._v.nbytes, report
+    _no_larger_than_at_pr_44("ling", {name: temp for name, (temp, _) in report.items()})
     print("temporaries and arguments, bytes:", report)
 
 
@@ -1252,15 +1357,18 @@ def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persist
 # dense layers, then 32 bias-routed experts (lfm2-8b-a1b): the paged decode
 # read at granite's 32 / 8 heads of 64, the conv tail read and rewritten in
 # place under ``shortconv/conv`` (no matrix state, no kernel of its own), the
-# expert stacks read where they lie (the grouped form at every size)
+# expert stacks read where they lie: by the DENSE form in the decode steps
+# (since PR 45: 128 rows, every expert hit every step) and by the grouped form
+# in the chunks, which are a bucket of 1,024 a row here and so always wide
 # ---------------------------------------------------------------------------
 
 
-def _lfm2_cell_engine(monkeypatch, held: int | None = None, slots: int | None = None):
+def _lfm2_cell_engine(held: int | None = None, slots: int | None = None):
     """The engine of the cell's configuration at its published WIDTHS, its 12
     layers and its runtime; ``held`` experts of the 32 and ``slots`` where the
-    test has no use for 7 GB of experts and all 128 (the products then take
-    the form the cell's shape takes: grouped)."""
+    test has no use for 7 GB of experts and all 128 (the products take the
+    form the cell's shape takes: it has no row in ``moe``'s table, so the
+    default every shape has)."""
     import json
     from dataclasses import replace
 
@@ -1275,12 +1383,10 @@ def _lfm2_cell_engine(monkeypatch, held: int | None = None, slots: int | None = 
     config, runtime = arch.model(described, False)
     assert config.layer_types == ("conv", "conv", "attention", "conv") * 3
     assert config.stack_plan == (4, ("conv", "conv", "attention", "conv"))
-    shape = (config.n_routed_experts, config.d_model, config.moe_d_ff)
-    assert moe._DENSE_TO_THE_CROSSING[shape] == 0
+    assert (config.n_routed_experts, config.d_model, config.moe_d_ff) not in (
+        moe._DENSE_TO_THE_CROSSING)
     if held is not None:
         config = replace(config, n_routed_experts=held)
-        monkeypatch.setitem(  # for the test alone: the table is the program's
-            moe._DENSE_TO_THE_CROSSING, (held, config.d_model, config.moe_d_ff), 0)
     if slots is not None:
         runtime = replace(runtime, max_batch_size=slots, num_kv_pages=slots * 33 + 1)
     engine = InferenceEngine(
@@ -1294,9 +1400,15 @@ def _lfm2_checks(engine, name, compiled):
     """ONE paged decode read a dispatch loop's attention layer kind (the head
     unrolls one, the scan's period holds one), under ``decode_loop/.../attention``,
     and no other kernel; the mixer under ``shortconv`` with its three scopes,
-    in a ragged program under ``chunk_loop`` too; NO copy of an expert stack;
-    the tails and the pool go out where they came in."""
+    in a ragged program under ``chunk_loop`` too; the decode steps' expert
+    products under ``decode_loop/.../moe/experts`` (the dense form: the decode
+    program holds NO ``ragged-dot``), a chunk's the compiler's ``ragged-dot``
+    kernel where it is wider than ``moe.dense_form``'s limit and none where it
+    is not; NO copy of an expert stack, and no array of a layer's experts made
+    in a loop; the tails and the pool go out where they came in."""
     import re
+
+    from calfkit_tpu.inference import moe
 
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines()
@@ -1304,48 +1416,57 @@ def _lfm2_checks(engine, name, compiled):
     assert len(kernels) == 2 and all("paged_decode" in k and "decode_loop/" in k
                                      for k in kernels), kernels
     for scope in ("/shortconv/in_proj", "/shortconv/conv", "/shortconv/out_proj", "/qk_norm",
-                  "/mlp/moe/router", "ragged-dot"):
+                  "/mlp/moe/router", "/mlp/moe/experts", "/mlp/moe/combine"):
         assert scope in hlo, scope
+    assert re.search(r'decode_loop/[^"]*/mlp/moe/experts', hlo), name
     cfg, rt = engine.config, engine.runtime
+    assert moe.dense_form(rt.max_batch_size, cfg)
     E, D, F = cfg.n_routed_experts, cfg.d_model, cfg.moe_d_ff
-    stacks = re.compile(
-        rf"= bf16\[(?:{cfg.n_moe_layers},)?{E},(?:{D},{F}|{F},{D})\]\S* copy\(")
-    assert not stacks.search(hlo), name
+    assert not _expert_stack_copies(hlo, cfg), name
+    assert not _made_in_loops(hlo, (f"bf16[{E},{D},{F}]", f"bf16[{E},{F},{D}]")), name
     memory = compiled.memory_analysis()
     tails = cfg.recurrent_state_bytes(rt.max_batch_size)
     assert memory.alias_size_in_bytes >= tails + engine._k.nbytes + engine._v.nbytes
     if name.startswith("ragged"):
         assert "chunk_loop/" in hlo and "/shortconv/" in hlo.split("chunk_loop/", 1)[1]
+        rows, _, chunk = name.split()[1].partition("x")  # "x4": 4 rows of prefill_chunk
+        rows, chunk = (int(rows), int(chunk)) if rows else (int(chunk), rt.prefill_chunk)
+        assert ("ragged-dot" in hlo) == (not moe.dense_form(rows * chunk, cfg)), name
+    else:
+        assert "ragged-dot" not in hlo, name
     return memory
 
 
-def test_shortconv_expert_cell_decode_program_compiles_for_v5e(
-        one_chip, no_persistent_cache, monkeypatch):
+def test_shortconv_expert_cell_decode_program_compiles_for_v5e(one_chip, no_persistent_cache):
     """The decode dispatch of the new cell at its published widths and 12
     layers, 8 of the 32 experts and 16 of the 128 slots (the products' shapes
-    and form but not 7 GB of experts), compiled for the described v5e (the
-    ragged programs and the temporaries: the full-size test below)."""
-    engine, _ = _lfm2_cell_engine(monkeypatch, held=8, slots=16)
-    for name, compiled in _kda_programs(engine, one_chip, ()).items():
+    and form but not 7 GB of experts), and two ragged programs beside it: a
+    wave of one row of 1,024 (the cell's narrowest: grouped) and one of two rows
+    of a bucket of 256 (no key of the cell: a ``prefill_chunk`` of 512 or less
+    would make it; 512 tokens, dense), compiled for the described v5e (the
+    temporaries at the cell's 128 rows: the full-size test below)."""
+    engine, _ = _lfm2_cell_engine(held=8, slots=16)
+    for name, compiled in _kda_programs(engine, one_chip, (1, (2, 256))).items():
         memory = _lfm2_checks(engine, name, compiled)
         print("temporaries, bytes:", name, memory.temp_size_in_bytes)
 
 
 @pytest.mark.slow  # 7.9 GB of weights and three whole-program compiles on every core (2 min): the
-# offline lane runs it, as it runs the other expert cells'; PERF.md section 6, PR 44 has its readings
-def test_shortconv_expert_cell_programs_at_full_size_fit_the_chip(
-        one_chip, no_persistent_cache, monkeypatch):
+# offline lane runs it, as it runs the other expert cells'; PERF.md section 6, PRs 44-45 have its readings
+def test_shortconv_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persistent_cache):
     """The decode dispatch and the ragged programs (a wave of 1 and 4 rows of
     1,024) of the new cell at its FULL size: all 32 experts of 10 layers, 128
-    slots.  No expert stack is copied (the DENSE form at 128 rows copied both:
-    6.38 GB of temporaries, a program that does not fit), the temporaries stay
-    under what ``hbm`` states, and arguments and temporaries together leave the
-    16 GB chip 3 GB of room."""
+    slots.  The decode steps' products are DENSE (PR 45) and no expert stack is
+    copied: spelled ``td,edf->etf`` the same program copied both stacks whole
+    (6.38 GB of temporaries on 9.58 GB of arguments: no room), spelled weights
+    first it holds LESS than the grouped program PR 44 ran (1.69 GB); the
+    temporaries stay under what ``hbm`` states, and arguments and temporaries
+    together leave the 16 GB chip 3 GB of room."""
     from calfkit_tpu.inference import moe
 
-    engine, described = _lfm2_cell_engine(monkeypatch)
+    engine, described = _lfm2_cell_engine()
     cfg, rt = engine.config, engine.runtime
-    assert not moe.dense_form(rt.prefill_chunk, cfg) and not moe.dense_form(1, cfg)
+    assert not moe.dense_form(rt.prefill_chunk, cfg) and moe.dense_form(rt.max_batch_size, cfg)
     stated = described["hbm"]["temporaries_bytes"]
     report = {}
     for name, compiled in _kda_programs(engine, one_chip, (1, 4)).items():
@@ -1354,4 +1475,5 @@ def test_shortconv_expert_cell_programs_at_full_size_fit_the_chip(
         assert memory.temp_size_in_bytes < stated["decode" if name == "decode" else "ragged"], (
             name, report)
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9, (name, report)
+    _no_larger_than_at_pr_44("lfm2", {name: temp for name, (temp, _) in report.items()})
     print("temporaries and arguments, bytes:", report)
