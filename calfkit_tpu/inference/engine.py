@@ -1988,7 +1988,7 @@ class InferenceEngine:
                 top_p, sampled=sampled,
             )
             emitted = jnp.where(active, emitted, 0)
-            # inactive rows scatter to the trash page; writes past a
+            # inactive rows write to the trash page; writes past a
             # row's reservation hit its table row's trash padding —
             # shared (prefix-cache) pages are never touched because the
             # chunk starts at lens >= prompt_len, past every registered

@@ -2065,48 +2065,89 @@ def consolidate_ring_paged(
     ring: tuple[jax.Array, jax.Array],  # [L, T, B, K, hd]
     tables: jax.Array,  # [B, Pmax]
     base_lens: jax.Array,  # [B]
-    active: jax.Array,  # [B] bool — inactive rows scatter to the trash page
+    active: jax.Array,  # [B] bool — inactive rows write to the trash page
     layer_kinds: Any = None,  # pages by kind: (global_layer_ids, window_layer_ids)
 ) -> tuple[jax.Array, jax.Array]:
     """Write the dispatch's ring tokens through the block tables.
 
-    One scatter per dispatch.  Inactive rows are redirected to page 0 (the
-    trash page): a retired slot's pages may already belong to a NEW request,
-    so letting its stale row write through its old table entries would
-    corrupt a neighbor — the dense layout tolerated garbage-beyond-length,
-    the paged layout must not.  Overlapped execution leans on the same
-    redirect: a row that retired inside the previous, still-in-flight
-    dispatch reaches this one masked inactive (device-side done chain),
-    so its writes land in the trash page even though the host hasn't
-    freed its pages yet (one-dispatch-late retirement frees them only
-    after this dispatch lands).
+    Window updates in place, two a row (:func:`_write_windows`): a row's
+    ``T`` new positions lie in the page it is in and the page it runs on
+    into.  Inactive rows are redirected to page 0 (the trash page): a
+    retired slot's pages may already belong to a NEW request, so letting
+    its stale row write through its old table entries would corrupt a
+    neighbor — the dense layout tolerated garbage-beyond-length, the paged
+    layout must not.  Overlapped execution leans on the same redirect: a
+    row that retired inside the previous, still-in-flight dispatch reaches
+    this one masked inactive (device-side done chain), so its writes land
+    in the trash page even though the host hasn't freed its pages yet
+    (one-dispatch-late retirement frees them only after this dispatch
+    lands).  So do the positions past a row's table (a dispatch can
+    overshoot a retiring row's cap), and the second window of a row that
+    stays inside one page.
     """
     if isinstance(tables, tuple):  # pages by cache kind: (global, window)
         return _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds)
-    T = ring[0].shape[1]
+    return _write_windows(pool, ring, tables, base_lens, active)
+
+
+def _write_windows(pool, ring, tables, base_lens, active, wraps=False):
+    """``ring`` [L, T, B, K, w] x 2 into ``pool`` [L, N, K, page, w] x 2 at each
+    row's positions ``base_len .. base_len + T - 1``: a loop over the rows
+    on the donated pool, two turns a row, each a read-modify-write of a
+    ``[L, 1, K, T, w]`` window of ONE page.  The first window starts at
+    ``min(offset, page - T)`` of the row's page so that it never leaves it;
+    the second at 0 of the next table entry (the table a ring of pages where
+    it ``wraps``), or of the trash page where the row does not straddle, is
+    not ``active`` or has run past its table.  Positions of a window that
+    take no token keep the bits they had (a first window that starts before
+    the row's offset covers live tokens).  Not one scatter over (page,
+    offset): those are not the pool's major dimensions, and the TPU compiler
+    copied each pool side into a layout with the offset above the KV heads
+    and back around it, every dispatch (PERF.md section 6, PR 46).  A ring
+    longer than a page goes in as several of at most a page."""
+    T, B = ring[0].shape[1:3]
     page = pool[0].shape[3]
+    if T > page:
+        for at in range(0, T, page):
+            pool = _write_windows(
+                pool, tuple(r[:, at:at + page] for r in ring), tables, base_lens + at, active,
+                wraps)
+        return pool
+    entries = tables.shape[1]
 
-    pos = base_lens[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    logical = pos // page  # which table entry
-    pmax = tables.shape[1]
-    in_range = logical < pmax  # a dispatch can overshoot a retiring row's cap
-    page_ids = jnp.take_along_axis(
-        tables, jnp.minimum(logical, pmax - 1), axis=1
-    )  # [B, T]
-    page_ids = jnp.where(active[:, None] & in_range, page_ids, 0)
-    offsets = pos % page  # [B, T]
+    def page_of(entry, live):  # [B] table entry -> [B] page id, the trash page if not live
+        entry = entry % entries if wraps else entry
+        ids = jnp.take_along_axis(tables, jnp.minimum(entry, entries - 1)[:, None], axis=1)[:, 0]
+        return jnp.where(live & (entry < entries), ids, 0)
 
-    return (_write_tokens(pool[0], ring[0], page_ids, offsets),
-            _write_tokens(pool[1], ring[1], page_ids, offsets))
+    entry, offset = base_lens // page, base_lens % page
+    start = jnp.minimum(offset, page - T)
+    shift = offset - start  # the window's first `shift` positions hold older tokens
+    first = page_of(entry, active)
+    second = page_of(entry + 1, active & (shift > 0))
+    where = jnp.arange(T)[:, None]  # a window's positions, against [.., T, w]
 
+    def write(side, r):
+        size = (side.shape[0], 1, side.shape[2], T, side.shape[4])
 
-def _write_tokens(pool_side, r, page_ids, offsets):
-    """``r`` [L, T, B, K, hd] into ``pool_side`` at (page, offset) [B, T].
-    Advanced indexing: pool[:, idx, :, off] with idx/off of shape [B, T]: the
-    index arrays are NON-adjacent, so numpy semantics move their broadcast
-    dims to the FRONT: values must be [B, T, L, K, hd]."""
-    vals = jnp.transpose(r, (2, 1, 0, 3, 4)).astype(pool_side.dtype)
-    return pool_side.at[:, page_ids, :, offsets].set(vals)
+        def row(b, side):
+            # [L, T, 1, K, w] -> [L, 1, K, T, w], token j at window position
+            # (j + shift) % T: the head of the ring at `shift` of the first
+            # window, what ran over the page's end at 0 of the second
+            vals = jnp.transpose(lax.dynamic_slice_in_dim(r, b, 1, axis=2), (0, 2, 3, 1, 4))
+            vals = jnp.roll(vals.astype(side.dtype), shift[b], axis=3)
+            for page_id, at, takes in ((first[b], start[b], where >= shift[b]),
+                                       (second[b], 0, where < shift[b])):
+                corner = (0, page_id, 0, at, 0)
+                old = lax.dynamic_slice(side, corner, size)
+                side = lax.dynamic_update_slice(side, jnp.where(takes, vals, old), corner)
+            return side
+
+        return lax.fori_loop(0, B, row, side)
+
+    # a loop a side: at heads narrower than a lane tile the compiler holds the
+    # side it loops over row-major, and one loop over both would hold both
+    return write(pool[0], ring[0]), write(pool[1], ring[1])
 
 
 def _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds):
@@ -2119,13 +2160,8 @@ def _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds):
     gl, wl = (jnp.asarray(ids, jnp.int32) for ids in layer_kinds)
     rk, rv = ring
     kg, vg = consolidate_ring_paged((kg, vg), (rk[gl], rv[gl]), tg, base_lens, active)
-    T, page, R = rk.shape[1], kw.shape[3], tw.shape[1]
-    pos = base_lens[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    page_ids = jnp.take_along_axis(tw, (pos // page) % R, axis=1)
-    page_ids = jnp.where(active[:, None], page_ids, 0)
-    offsets = pos % page
-    return ((kg, _write_tokens(kw, rk[wl], page_ids, offsets)),
-            (vg, _write_tokens(vw, rv[wl], page_ids, offsets)))
+    kw, vw = _write_windows((kw, vw), (rk[wl], rv[wl]), tw, base_lens, active, wraps=True)
+    return (kg, kw), (vg, vw)
 
 
 @jax.named_scope("kv_write")

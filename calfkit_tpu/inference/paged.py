@@ -29,7 +29,7 @@ what they keep of a sequence:
 Design decisions:
 
 - **Page 0 is the trash page** of every pool.  Never allocated.  Block-table rows start
-  as zeros, and consolidation scatters from *inactive* batch rows into page
+  as zeros, and consolidation writes the tokens of *inactive* batch rows into page
   0 — a retired slot's stale row can keep "writing" harmlessly even after
   its real pages were reused by another request.
 - **Reserve at admission.**  A request's full worst-case footprint

@@ -183,14 +183,17 @@ LATENT = ModelConfig(
 # dense expert products spelled weights first: in each program the two up
 # products' ``dot_general`` takes the rows and then the experts, ``[T, E, Fe]``
 # with a transpose ``(1, 0, 2)`` behind it, where it took the experts and then
-# the rows, ``[E, Fe, T]`` and ``(0, 2, 1)``; nothing else differs).
+# the rows, ``[E, Fe, T]`` and ``(0, 2, 1)``; nothing else differs);
+# PR 46 did for all of them, here and below (every program ends in the paged write's loop of
+# window updates: with PR 45's scatter, tests/test_kv_write.py's reference, put back as
+# ``consolidate_ring_paged``, each traced to the hash pinned before, letter for letter).
 TRACED_AT_THE_PARENT = {
-    "dense": {"decode": "fbe15a7ac753ad608e6a4fe90dd49d4d20b04f4b7986c5c3eea5f8f6133579b0",
-              "ragged": "0b045d92906ae9f97054d4eb9ee84a80148519119f4a785773ed8c280baa3111"},
-    "hybrid": {"decode": "cbbf613956e99afb03cf792e8ed6783ae5c5b580dcf8928dfdf6330805332103",
-               "ragged": "c63f9f1057a6fed2dcf53c0855ff1f8e424554878243361d3ef91e0ed54a3418"},
-    "latent-moe": {"decode": "c450ecc6585ea3023970a319a82ba9c07ccf93ba6c8e54755ee42699fcce23c2",
-                   "ragged": "8f851918146afc61f4a5f9cfccc3f043c690cde1af61e6aa5df5c2bccf9d3d08"},
+    "dense": {"decode": "6f3974d73e2b667aace9dd64f5c9d415cf02293d44858013c4406ec21fe28920",
+              "ragged": "fca501f0667c6ddc88f13b2129a11e002cbd23600be1ff7a6eaf9196b326dada"},
+    "hybrid": {"decode": "2a78532baaf81aaf37e67fef237b26b3576565d404af13faf57d59edc2aeea83",
+               "ragged": "3692da3834602bc1b4c3e4821f7df499e01ed8843a6fe664a338db0ecc2d651a"},
+    "latent-moe": {"decode": "34d24d67febc3db9e62b8e29db53c9d8acedc19ebeb115d5fdec1ff5e8edd77a",
+                   "ragged": "0c7e5dd78606dff42c740cbd5e28f856fc30835ee5e90bef83948aafbc0b94c1"},
 }
 
 
@@ -238,8 +241,8 @@ def test_the_three_older_kinds_trace_the_programs_the_parent_traced(monkeypatch,
 # Recorded anew in PR 45: the decode steps BESIDE the chunk are dense, and
 # their up products' ``dot_general`` changed as above; the chunk's did not.
 TRACED_SINCE_PR_34 = {
-    "gdn-moe": "3ca11ce00f61f0e4e8907e35d7faefe7f4ae9bef45c5c656f36adb3673e277cf",
-    "latent-moe": "5afea74116c954c553c2f4ebda6e68bc0b470d669af1bf0cbe1443913bfbdf15",
+    "gdn-moe": "11e4f435f43718b91384bd66356e2cbed477451f29c928078c8816aae3e53086",
+    "latent-moe": "e915d8a7dcb29db89c1bfaa5cc0c21b03de7dcd87daca7af975c92b7b18791c0",
 }
 
 

@@ -304,16 +304,29 @@ def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
     assert not any(made.values()), made  # never in a loop's body
 
 
-# a benchmark cell's configuration -> layers kept (granite: one period of
-# its stack, nine Mamba-2 layers around one attention layer)
-CELL_LAYERS = {"mistral-7b-v0.3-int8": 2, "granite-4.0-h-micro": 10}
+# a benchmark cell's configuration -> (layers kept, experts held): the depth cut
+# (granite: one period of its stack, nine Mamba-2 layers around one attention
+# layer; Kimi: the dense layer and two expert layers; Qwen3-Next: one period;
+# None: the cell's own depth, command-a-plus's W W W G and LFM2's twelve with
+# their three attention layers) and, where a test has no use for GBs of
+# experts, a few of them (the gate keeps its outputs)
+CELL_CUTS = {
+    "mistral-7b-v0.3-int8": (2, None),
+    "granite-4.0-h-micro": (10, None),
+    "command-a-plus-05-2026": (None, 2),
+    "kimi-vl-a3b-instruct": (3, 8),
+    "qwen3-next-80b-a3b-instruct": (4, 8),
+    "lfm2-8b-a1b": (None, 8),
+}
 
 
 @pytest.fixture(scope="module")
 def cell_engine():
     """name -> the engine of a benchmark configuration at its published
-    WIDTHS and its cell's runtime, depth cut; the kernel asked for by name,
-    as "auto" resolves it on a chip (this process sees a CPU)."""
+    WIDTHS and its cell's runtime (its pools as the cell holds them, by cache
+    kind where the model has window layers), cut by ``CELL_CUTS``; the kernel
+    asked for by name, as "auto" resolves it on a chip (this process sees a
+    CPU)."""
     import json
     from dataclasses import replace
 
@@ -324,18 +337,22 @@ def cell_engine():
 
     def build(name):
         if name not in built:
+            built.clear()  # one engine at a time: their pools and weights are GBs of this host
             here = os.path.dirname(manifest.__file__)
             with open(os.path.join(here, "configs", name + ".json")) as f:
                 described = json.load(f)
             arch = manifest.load_architecture(
                 described.get("architecture", "dense-gqa"), here)
             config, runtime = arch.model(described, False)
-            layers = CELL_LAYERS[name]
-            config = replace(
-                config, n_layers=layers,
-                **({"layer_types": config.layer_types[:layers]}
-                   if config.layer_types else {}),
-            )
+            layers, held = CELL_CUTS[name]
+            if layers is not None:
+                config = replace(
+                    config, n_layers=layers,
+                    **({"layer_types": config.layer_types[:layers]}
+                       if config.layer_types else {}),
+                )
+            if held is not None:
+                config = replace(config, n_routed_experts=held)
             built[name] = InferenceEngine(config, replace(
                 runtime, compilation_cache=False, attention_impl="pallas"))
         return built[name]
@@ -417,6 +434,95 @@ def test_cell_dispatch_program_holds_its_kernels_on_v5e(
         assert memory.alias_size_in_bytes >= state_bytes
         if program == "decode":
             assert memory.temp_size_in_bytes < 2 * state_bytes // engine.config.n_mamba_layers
+
+
+def _whole_side_copies(hlo: str, side) -> tuple[list[str], list[str]]:
+    """(copies, scatters) of a compiled program over an array of one pool
+    side's SIZE, in whatever shape and layout (the scatter's operand had the
+    page offset above the KV heads; the kernel's view of a head of 64 folds
+    two positions a row): the ``copy`` / ``copy-start`` operations outside
+    fusions, and every ``scatter``."""
+    import re
+    from math import prod
+
+    made = re.compile(r"= (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(")
+    copies, scatters = [], []
+    for name, lines in _computations(hlo).items():
+        for line in lines:
+            m = made.search(line)
+            if not m or prod(map(int, m.group(2).split(","))) != side.size:
+                continue
+            if m.group(3) in ("copy", "copy-start") and "fused_computation" not in name:
+                copies.append(line.strip()[:120])
+            elif m.group(3) == "scatter":
+                scatters.append(line.strip()[:120])
+    return copies, scatters
+
+
+# The whole-side copies a dispatch program makes of a pool side narrower than
+# a lane tile (PR 46's count, and the parent's: two into the kernel's view,
+# lane_dense_pool or latent_rope_view, one into the layout the write's loop
+# runs on and one back, a side).  The pool STORED lane-dense ends them
+# (ROADMAP S10): this pins what it has to remove.
+_NARROW_SIDE_COPIES_AT_PR_46 = {
+    "granite-4.0-h-micro": 6, "lfm2-8b-a1b": 6, "kimi-vl-a3b-instruct": 4}
+
+
+@pytest.mark.parametrize(
+    "cell,program",
+    [
+        ("mistral-7b-v0.3-int8", "decode"),
+        ("mistral-7b-v0.3-int8", "ragged"),
+        ("command-a-plus-05-2026", "decode"),
+        ("kimi-vl-a3b-instruct", "decode"),
+        ("qwen3-next-80b-a3b-instruct", "decode"),
+        ("granite-4.0-h-micro", "decode"),
+        ("lfm2-8b-a1b", "decode"),
+    ],
+)
+def test_cell_dispatch_program_writes_its_tokens_in_place_on_v5e(
+    cell, program, cell_engine, one_chip, no_persistent_cache
+):
+    """A dispatch program of a benchmark cell, compiled for the described
+    v5e, ends in ``consolidate_ring_paged``'s loop of window updates on the
+    donated pool: NO scatter over a pool side, the pools go out where they
+    came in, and no side 128 numbers wide or wider (heads of 128 and 256,
+    Kimi's 512-wide ``c`` side, both kinds of command-a-plus's pools) is
+    copied whole: through PR 45 the scatter cost each such side a copy into a
+    layout with the page offset above the KV heads and one back, every
+    dispatch (PERF.md section 6, PR 46).  A side of heads of 64 (and Kimi's
+    rope side) is still held another way by the device than by the loop and
+    the kernel: not more copies than PR 46 counted."""
+    import jax
+
+    engine = cell_engine(cell)
+    if program == "ragged":
+        fn, args = _dispatch_programs(engine, one_chip)[program]
+        compiled = fn.lower(*args).compile()
+    else:
+        def abstract(tree):
+            return jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+        args, window, steps, sampled = engine._decode_args()
+        carried = {"state": engine._state} if engine._recurrent else {}
+        if engine._moe_zero is not None:
+            carried["moe"] = engine._moe_zero
+        compiled = engine._decode_jit(window, steps, sampled).lower(
+            *abstract(args), **abstract(carried)).compile()
+    hlo = compiled.as_text()
+    assert "/kv_write/while/body" in hlo
+    sides = jax.tree.leaves((engine._k, engine._v))
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(side.nbytes for side in sides)
+    narrow = 0
+    for side in {side.shape: side for side in sides}.values():
+        copies, scatters = _whole_side_copies(hlo, side)
+        assert not scatters, scatters
+        if side.shape[-1] >= 128:
+            assert not copies, (side.shape, copies)
+        narrow += len(copies)
+    assert narrow <= _NARROW_SIDE_COPIES_AT_PR_46.get(cell, 0), (cell, narrow)
+    print("whole-side copies of narrow sides:", cell, program, narrow)
 
 
 @pytest.mark.parametrize("ssm_impl", ["xla", "pallas"])
@@ -971,9 +1077,9 @@ def _gdn_decode_checks(engine, compiled):
     the delta step kernel, once a DeltaNet layer of the period, under
     ``gdn/state``; the stacked state and the pool go out where they came in;
     NO copy of an expert stack, of a layer of it, or of the stacked state
-    (the temporaries are under one layer's state plus the pool's layout copy
-    around the consolidation scatter, which every paged cell pays: not
-    larger than they were under XLA's pass)."""
+    (the temporaries are under one layer's state: since PR 46 the dispatch's
+    tokens go into the pool in place, and the layout copy of the pool that
+    stood around the consolidation scatter is gone)."""
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     reads = [k for k in kernels if "paged_decode_attention" in k]
@@ -986,7 +1092,7 @@ def _gdn_decode_checks(engine, compiled):
     state_bytes = cfg.recurrent_state_bytes(rt.max_batch_size)
     pool_bytes = engine._k.nbytes + engine._v.nbytes
     assert memory.alias_size_in_bytes >= state_bytes + pool_bytes
-    assert memory.temp_size_in_bytes < state_bytes // cfg.n_recurrent_layers + pool_bytes
+    assert memory.temp_size_in_bytes < state_bytes // cfg.n_recurrent_layers
     return memory
 
 
