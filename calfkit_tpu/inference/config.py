@@ -48,6 +48,55 @@ class UnsupportedWithLatentAttention(ValueError):
 
 
 @dataclass(frozen=True)
+class RopeScaling:
+    """How a kind of layer's rotary frequencies differ from the plain law
+    ``theta^(-2i/d)`` (HF ``rope_parameters`` of one layer kind).  ``yarn`` as
+    ``transformers``' ``_compute_yarn_parameters`` has it: the pairs that turn
+    more than ``beta_fast`` times over ``original_max_position_embeddings``
+    keep their frequency, those that turn less than ``beta_slow`` times have
+    it divided by ``factor``, a linear ramp between; cos and sin are both
+    multiplied by ``attention_factor`` (None: ``0.1 ln(factor) + 1``)."""
+
+    rope_type: str = "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+    def __post_init__(self) -> None:
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError(
+                f"rope_type {self.rope_type!r}: only 'default' and 'yarn' are described")
+        if self.rope_type == "yarn" and not (
+                self.factor >= 1.0 and self.original_max_position_embeddings > 0
+                and self.beta_fast > self.beta_slow > 0):
+            raise ValueError(
+                "yarn needs factor >= 1, original_max_position_embeddings and "
+                "beta_fast > beta_slow > 0")
+
+    @property
+    def scale(self) -> float:
+        """What cos and sin are both multiplied by."""
+        if self.rope_type == "default":
+            return 1.0
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 0.1 * math.log(self.factor) + 1.0
+
+    def correction_range(self, dim: int, theta: float) -> tuple[int, int]:
+        """``(low, high)``: the pairs of a head of ``dim`` between which the
+        ramp runs (below ``low`` the plain frequency, above ``high`` the
+        interpolated one), kept in ``[0, dim - 1]``."""
+        def pair_of(turns: float) -> float:
+            return (dim * math.log(self.original_max_position_embeddings / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        return (max(math.floor(pair_of(self.beta_fast)), 0),
+                min(math.ceil(pair_of(self.beta_slow)), dim - 1))
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """A decoder architecture description.
 
@@ -79,9 +128,11 @@ class ModelConfig:
     keys; their K and V live in a RING of pages a row, ``CACHE_KINDS``)
     beside global ones, the position rule by kind
     (``position_embedding="rope_window"``: rotary on the window layers, none
-    on the global), the block the description names (``norm``,
-    ``parallel_block``), and the expert block as every layer's FFN, held by
-    share as above, its shared experts summed or averaged
+    on the global; ``"rope"``: both kinds rotate, the window layers by the
+    plain law, the global ones by it too or, with ``rope_scaling_global``, by
+    that record's: a Mellum-2-style stack), the block the description names
+    (``norm``, ``parallel_block``), and the expert block as every layer's
+    FFN, held by share as above, its shared experts summed or averaged
     (``shared_expert_combine``).
     With ``"kda"`` among ``layer_types`` it is a Ling-3.0-style hybrid
     (``bailing_hybrid``): Kimi Delta Attention (the gated delta rule with a
@@ -194,6 +245,9 @@ class ModelConfig:
     # how the n_shared_experts' outputs meet: "sum" (one SwiGLU of n x moe_d_ff)
     # or "average" (that sum over n)
     shared_expert_combine: str = "sum"
+    # how the GLOBAL layers' rotary frequencies differ from the window layers'
+    # plain law (position_embedding "rope" in a window stack); None = they do not
+    rope_scaling_global: RopeScaling | None = None
     # ---- Kimi Delta Attention beside latent attention (all defaults = as before) ----
     # a "kda" layer's log-decay, one a key channel: kda_lower_bound x sigmoid(
     # exp(A_log[head]) (a + dt_bias)), in [kda_lower_bound, 0) (FLA's safe gate)
@@ -384,6 +438,11 @@ class ModelConfig:
                 "position_embedding='rope_window' belong to the window stack "
                 '(layer_types with "window")'
             )
+        if self.rope_scaling_global is not None and not (
+                WINDOW in self.layer_types and self.position_embedding == "rope"):
+            raise ValueError(
+                "rope_scaling_global belongs to a window stack whose kinds both "
+                'rotate (layer_types with "window", position_embedding "rope")')
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if GDN not in self.layer_types and (
@@ -1317,6 +1376,68 @@ PRESETS: dict[str, ModelConfig] = {
         first_k_dense=2,
         routed_scaling_factor=1.0,
         topk_norm_eps=1e-6,
+    ),
+    # Mellum2-12B-A2.5B-Instruct (HF: JetBrains/Mellum2-12B-A2.5B-Instruct,
+    # mellum): 21 sliding-window layers (window 1,024, the plain rotation) and 7
+    # global layers whose rotation is YaRN (factor 16 over 8,192 positions),
+    # period W W W G, a sequential RMSNorm block with two norms a layer, 64
+    # softmax-routed experts with 8 a token and no shared one in EVERY layer,
+    # an untied head.  The rotation pairs a head's halves (rotate_half).  Its
+    # multi-token-prediction head is not described (skipped at load).
+    "mellum2-12b-a2.5b-instruct": ModelConfig(
+        name="mellum2-12b-a2.5b-instruct",
+        vocab_size=98304,
+        d_model=2304,
+        n_layers=28,
+        n_heads=32,
+        n_kv_heads=4,
+        d_ff=896,
+        rope_theta=500000.0,
+        norm_eps=1e-6,
+        max_seq_len=131072,
+        tie_embeddings=False,
+        layer_types=((WINDOW,) * 3 + (ATTENTION,)) * 7,
+        position_embedding="rope",
+        rope_scaling_global=RopeScaling(
+            rope_type="yarn", factor=16.0, original_max_position_embeddings=8192,
+            beta_fast=32.0, beta_slow=1.0, attention_factor=1.2772588722239782),
+        attn_head_dim=128,
+        sliding_window=1024,
+        n_routed_experts=64,
+        n_experts_per_tok=8,
+        moe_d_ff=896,
+        scoring_func="softmax",
+        topk_method="greedy",
+    ),
+    # the same kind at toy size, for the tests: 2 periods of W W W G, a window
+    # of 24 (shorter than the tests' chunk), 8 experts with 3 a token, ALL held,
+    # an untied head, YaRN over an original context of 64 (shorter than
+    # max_seq_len, so the tests stand on both sides of it)
+    "debug-mellum": ModelConfig(
+        name="debug-mellum",
+        vocab_size=128,
+        d_model=32,
+        n_layers=8,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=16,
+        rope_theta=10000.0,
+        norm_eps=1e-6,
+        max_seq_len=256,
+        dtype="float32",
+        tie_embeddings=False,
+        layer_types=((WINDOW,) * 3 + (ATTENTION,)) * 2,
+        position_embedding="rope",
+        rope_scaling_global=RopeScaling(
+            rope_type="yarn", factor=4.0, original_max_position_embeddings=64,
+            beta_fast=8.0, beta_slow=1.0),
+        attn_head_dim=8,
+        sliding_window=24,
+        n_routed_experts=8,
+        n_experts_per_tok=3,
+        moe_d_ff=16,
+        scoring_func="softmax",
+        topk_method="greedy",
     ),
 }
 

@@ -126,7 +126,8 @@ _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
     "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
-    *CHUNK_ATTN_FIELDS, "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
+    *CHUNK_ATTN_FIELDS, "chunk_tokens", "chunk_tokens_padding",
+    "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
     "programs_built", "moe_assignments", "moe_assignments_absent",
     "moe_rows_in_held_groups", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
@@ -293,6 +294,17 @@ def _engine_metrics(
             "the same steps as the key-block loop of model.blocked_attention "
             "walks them (every tile from the chunk's first block to its last): "
             "visited / dense is what the per-tile bounds save",
+        ),
+        chunk_tokens=reg.counter(
+            "calfkit_engine_chunk_tokens_total",
+            "positions the launched prefill chunks computed: rows x chunk, the "
+            "wave's bucket and its rows' padding included (host arithmetic at launch)",
+        ),
+        chunk_tokens_padding=reg.counter(
+            "calfkit_engine_chunk_tokens_padding_total",
+            "of those, the positions that held no prompt token (past a row's own "
+            "length in its bucket): padding / chunk_tokens is what grouping a "
+            "wave's rows by length would save",
         ),
         window_pages_given_back=reg.counter(
             "calfkit_engine_window_pages_given_back_total",
@@ -798,7 +810,7 @@ class EngineStats:
     decode_global_tokens_read: int = 0
     window_pages_given_back: int = 0
     # a window stack's prefill chunks (0 for every other model), host
-    # arithmetic at launch (``_note_chunk_attention``): the (query, key)
+    # arithmetic at launch (``_note_chunk``): the (query, key)
     # pairs the chunk's OWN positions must attend, ``sum_q min(q + 1, W)`` a
     # window layer and ``sum_q (q + 1)`` a global one, over that kind's
     # layers: the needed work, whatever computes it; and the (query tile,
@@ -808,6 +820,11 @@ class EngineStats:
     chunk_attn_pairs_global: int = 0
     chunk_attn_key_blocks_visited: int = 0
     chunk_attn_key_blocks_dense: int = 0
+    # every model's prefill chunks (``_note_chunk``): the positions a launched
+    # chunk computed (rows x chunk) and those of them past their row's own
+    # prompt: what a wave's bucket and its widest row cost the others
+    chunk_tokens: int = 0
+    chunk_tokens_padding: int = 0
     kv_pages_global_in_use: int = 0
     kv_pages_window_in_use: int = 0
     kv_pages_global_total: int = 0
@@ -4106,10 +4123,15 @@ class InferenceEngine:
             return []
         return [inf["wstate"], jnp.asarray(inf["arrays"]["true_lens"])]
 
-    def _note_chunk_attention(self, offset: int, chunk: int, bucket: int, true_lens: Any) -> None:
-        """Count a launched chunk's attention (a model with window layers;
-        host arithmetic from shapes and the rows' true lengths, no sync):
-        ``EngineStats.chunk_attn_*``."""
+    def _note_chunk(self, offset: int, chunk: int, bucket: int, true_lens: Any) -> None:
+        """Count a launched chunk (host arithmetic from shapes and the rows'
+        true lengths, no sync): the positions it computes and those of them
+        that hold no prompt token (``EngineStats.chunk_tokens*``) and, for a
+        model with window layers, its attention (``chunk_attn_*``)."""
+        lens = true_lens.astype(np.int64)  # the host's own array: no device value comes here
+        self.stats.chunk_tokens += lens.size * chunk
+        self.stats.chunk_tokens_padding += int(
+            lens.size * chunk - np.clip(lens - offset, 0, chunk).sum())
         if not self._windowed:
             return
         from calfkit_tpu.inference.pallas_attention import chunk_attention_work
@@ -4352,7 +4374,7 @@ class InferenceEngine:
             self._slot_keys, self._temp, self._top_k, self._top_p, firsts,
             *landed,
         ) = fn(*args, **self._state_kw(), **({"moe": self._moe_zero} if self._moe else {}))
-        self._note_chunk_attention(0, bucket, bucket, arrays["true_lens"])
+        self._note_chunk(0, bucket, bucket, arrays["true_lens"])
         seq = self._enq_seq
         moe = landed.pop() if self._moe else None
         self._note_state_landed(landed)
@@ -4454,7 +4476,7 @@ class InferenceEngine:
             self.params, sk, sv, tok_chunk, jnp.int32(idx * chunk),
             *self._wave_state_args(inf), **self._moe_kw(inf, decode=False),
         )
-        self._note_chunk_attention(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
+        self._note_chunk(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
         inf["scratch"] = (sk, sv)
         if self._recurrent:
             inf["wstate"] = wstate.pop(0)
@@ -4676,7 +4698,7 @@ class InferenceEngine:
             *_some(self._state), *self._wave_state_args(inf), **self._moe_kw(inf),
         ))
         seq = self._note_launch("ragged", program, steps, started, queued, R, R * chunk)
-        self._note_chunk_attention(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
+        self._note_chunk(idx * chunk, chunk, inf["bucket"], inf["arrays"]["true_lens"])
         if self._moe:
             inf["wmoe"] = res.pop()
         if self._recurrent:

@@ -85,6 +85,23 @@ part (``rope_interleave``) and ``model.apply_rope`` pairs the two halves:
 the rope columns of ``W_q`` (each head's last ``dr``) and of ``W_kva`` (its
 last ``dr``) are permuted ONCE here, evens first, so that the same rotation
 gives the same scores.
+``model_type`` ``mellum`` (Mellum2-12B-A2.5B) loads into the window
+stack's tree too, the SEQUENTIAL block's (two norms a layer, an untied head;
+``_build_mellum_params``).  The names are taken as the sibling expert
+checkpoints have them, UNVERIFIED against the published files; the rotation
+pairs a head's halves as ``model.apply_rope`` does (``rotate_half``), so no
+column is permuted; the published config names no q/k norm, and a checkpoint
+layer that HOLDS ``self_attn.{q,k}_norm`` tensors is refused (never skipped:
+the scores would be another model's); its multi-token-prediction head
+(tensors under ``mtp.`` / ``model.mtp``) is skipped and counted with one
+:class:`MtpSkipped` notice, layers past the description's ``n_layers`` (a
+pipeline's later stages) with one :class:`LayersSkipped` notice:
+    self_attn.{q,k,v,o}_proj.weight               → layers.attn.wq, wk, wv, wo (as they are)
+    input_layernorm, post_attention_layernorm     → attn_norm, layers.moe.mlp_norm
+    mlp.gate.weight [E, D]                        → layers.moe.router [D, E] (ALL the experts)
+    mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [held, ..]
+    model.norm, model.embed_tokens, lm_head       → final_norm, embed, lm_head [D, V]
+A share ``(r, s)`` is accepted as for ``cohere2_moe``.
 ``model_type`` ``bailing_hybrid`` (Ling-3.0-flash, and Ling-3.0-flash-VL's
 text decoder: tensors outside ``model.`` and ``lm_head.``, a tower and its
 projector, skipped and counted with one :class:`VisionTowerSkipped` notice;
@@ -159,7 +176,7 @@ class LayersSkipped(UserWarning):
 
 # tensor names of a multimodal checkpoint's language decoder start with this
 _LANGUAGE_PREFIX = "language_model."
-_MTP_PREFIX = "mtp."
+_MTP_PREFIXES = ("mtp.", "model.mtp")
 
 
 def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> ModelConfig:
@@ -173,9 +190,12 @@ def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> 
         return _cohere2_moe_config(raw.get("text_config", raw), str(path), share)
     if raw.get("model_type") == "bailing_hybrid":
         return _bailing_hybrid_config(raw.get("text_config", raw), str(path), share)
+    if raw.get("model_type") == "mellum":
+        return _mellum_config(raw, str(path), share)
     if share is not None:
         raise ValueError(
-            f"{path}: a share is described for qwen3_next, cohere2_moe and bailing_hybrid alone")
+            f"{path}: a share is described for qwen3_next, cohere2_moe, bailing_hybrid and "
+            "mellum alone")
     if raw.get("model_type") == "lfm2_moe":
         return _lfm2_moe_config(raw, str(path))
     if raw.get("model_type") == "granitemoehybrid":
@@ -374,6 +394,61 @@ def _cohere2_moe_config(raw: dict, path: str, share: "tuple[int, int] | None") -
     )
 
 
+def _mellum_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
+    """Mellum's ``config.json`` -> the window stack's description: the
+    sequential RMSNorm block, the rotation by layer kind (``rope_parameters``:
+    the plain law on the sliding layers, the full layers' own), softmax-routed
+    experts in every layer, an untied head.  ``max_window_layers`` 0 with
+    ``use_sliding_window`` is read as "``layer_types`` decides"."""
+    from calfkit_tpu.inference.config import ATTENTION, WINDOW, RopeScaling
+
+    for key, only in (("attention_bias", False), ("hidden_act", "silu"),
+                      ("tie_word_embeddings", False), ("use_sliding_window", True),
+                      ("max_window_layers", 0)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    E, V, L = raw["num_experts"], raw["vocab_size"], raw["num_hidden_layers"]
+    if set(raw.get("mlp_layer_types", ["sparse"])[:L]) != {"sparse"}:
+        raise ValueError(f"{path}: a layer whose FFN is not sparse is not supported")
+    rank, of = share or (0, 1)
+    if not 0 <= rank < of or E % of or V % of:
+        raise ValueError(f"{path}: share {share} does not divide {E} experts and {V} rows")
+    rope = raw["rope_parameters"]
+    plain, scaled = dict(rope["sliding_attention"]), dict(rope["full_attention"])
+    if plain.get("rope_type", "default") != "default":
+        raise ValueError(
+            f"{path}: rope_type {plain['rope_type']!r} on the sliding layers is not supported")
+    if scaled.pop("rope_theta") != plain["rope_theta"]:
+        raise ValueError(f"{path}: the two kinds of layer rotate from ONE rope_theta here")
+    kinds = {"sliding_attention": WINDOW, "full_attention": ATTENTION}
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=V // of,
+        d_model=raw["hidden_size"],
+        n_layers=L,
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"],
+        d_ff=raw["moe_intermediate_size"],
+        rope_theta=float(plain["rope_theta"]),
+        norm_eps=float(raw.get("rms_norm_eps", 1e-6)),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=False,
+        layer_types=tuple(kinds[t] for t in raw["layer_types"][:L]),
+        position_embedding="rope",
+        # RopeScaling refuses any rope_type but default and yarn
+        rope_scaling_global=RopeScaling(**scaled),
+        attn_head_dim=raw["head_dim"],
+        sliding_window=raw["sliding_window"],
+        n_routed_experts=E // of,
+        n_experts_total=E if of > 1 else 0,
+        expert_first=rank * (E // of),
+        n_experts_per_tok=raw["num_experts_per_tok"],
+        moe_d_ff=raw["moe_intermediate_size"],
+        norm_topk_prob=bool(raw.get("norm_topk_prob", True)),
+        scoring_func="softmax", topk_method="greedy",
+    )
+
+
 def _bailing_hybrid_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
     """``bailing_hybrid``'s ``config.json`` (Ling-3.0-flash and its -VL's text
     decoder) -> the Kimi Delta Attention hybrid's description.  A key that
@@ -531,7 +606,7 @@ def load_params(
                 "projector) were not loaded: the language decoder serves text alone"
             ), stacklevel=2)
 
-    mtp = sum(1 for name in files if name.startswith(_MTP_PREFIX))
+    mtp = sum(1 for name in files if name.startswith(_MTP_PREFIXES))
     if config.kda:
         # bailing_hybrid: the extra prediction layer follows the stack as
         # model.layers.<num_hidden_layers>..., the tower lies outside model. / lm_head.
@@ -546,8 +621,17 @@ def load_params(
                 f"{path}: {tower} tensors outside 'model.' and 'lm_head.' (a vision tower "
                 "and its projector) were not loaded: the language decoder serves text alone"
             ), stacklevel=2)
-    if config.shortconv:
-        # a pipeline stage of an lfm2_moe checkpoint: the leading n_layers
+    if config.windowed:
+        normed = sorted(name for name in files
+                        if re.search(r"\.self_attn\.[qk]_norm\.", name))
+        if normed:
+            raise ValueError(
+                f"{path}: {len(normed)} q/k-norm tensors ({normed[0]} ..): the window stack "
+                "normalises no query or key head (no key of the published config names such "
+                "a norm), and a checkpoint that holds one is another model: refused, not "
+                "skipped")
+    if config.shortconv or config.windowed:
+        # a pipeline stage of such a checkpoint: the leading n_layers
         layer_of = re.compile(r"^model\.layers\.(\d+)\.")
         later = {int(m.group(1)) for name in files
                  if (m := layer_of.match(name)) and int(m.group(1)) >= config.n_layers}
@@ -558,13 +642,13 @@ def load_params(
                 f"{path}: layers {min(later)}-{max(later)} ({len(later)} of "
                 f"{config.n_layers + len(later)}) were not loaded: the description keeps the "
                 f"leading {config.n_layers} (a pipeline's first stage, with the final norm "
-                "and the tied head so that tokens come out)"
+                "and the head so that tokens come out)"
             ), stacklevel=2)
     if mtp:
         import warnings
 
         warnings.warn(MtpSkipped(
-            f"{path}: {mtp} tensors under {_MTP_PREFIX!r} (the multi-token-prediction "
+            f"{path}: {mtp} tensors under {_MTP_PREFIXES[0]!r} (the multi-token-prediction "
             "module) were not loaded: no program drafts from it"
         ), stacklevel=2)
 
@@ -606,7 +690,10 @@ def _build_params(
     if config.windowed:
         if quantize is not None:
             raise ValueError("no quantized load for a model with window layers and experts")
-        return _build_window_params(config, shardings, get)
+        # the window stack's two checkpoint families differ by their block: the
+        # parallel one is cohere2_moe's names, the sequential one mellum's
+        build = _build_window_params if config.parallel_block else _build_mellum_params
+        return build(config, shardings, get)
     if config.layer_types:
         if quantize is not None:
             raise ValueError("no quantized load for a model with Mamba layers")
@@ -1035,6 +1122,28 @@ def _build_kda_params(config: ModelConfig, shardings: dict[str, Any], get: Any) 
     return jax.tree.map(jax.device_put, tree, shardings)
 
 
+def _layer_stackers(config: ModelConfig, get: Any) -> tuple[Any, Any]:
+    """What both of the window stack's tree builders stack a layer at a time:
+    ``stack(name, transform)`` over every layer of the description, and
+    ``experts(name)``, the HELD experts' matrices transposed, ``[L, held, ..]``."""
+    c = config
+    dtype = np.dtype(c.dtype)
+    everywhere = range(c.n_layers)
+
+    def stack(name: str, transform: Any) -> np.ndarray:
+        return np.stack(
+            [transform(get(f"model.layers.{i}.{name}")) for i in everywhere]).astype(dtype)
+
+    def experts(name: str) -> np.ndarray:
+        held = range(c.expert_first, c.expert_first + c.n_routed_experts)
+        return np.stack([
+            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T for e in held])
+            for i in everywhere
+        ]).astype(dtype)
+
+    return stack, experts
+
+
 def _build_window_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
     """The window stack's tree from HF Cohere2-MoE names (module text); of a
     share, the experts and the rows of the tied vocabulary it holds."""
@@ -1047,17 +1156,7 @@ def _build_window_params(config: ModelConfig, shardings: dict[str, Any], get: An
     rank = c.expert_first // c.n_routed_experts
     rows = slice(rank * c.vocab_size, (rank + 1) * c.vocab_size)
     halves = np.concatenate([np.arange(0, hd, 2), np.arange(1, hd, 2)])  # pairs -> halves
-
-    def stack(name: str, transform: Any) -> np.ndarray:
-        return np.stack(
-            [transform(get(f"model.layers.{i}.{name}")) for i in everywhere]).astype(dtype)
-
-    def experts(name: str) -> np.ndarray:
-        held = range(c.expert_first, c.expert_first + c.n_routed_experts)
-        return np.stack([
-            np.stack([get(f"model.layers.{i}.mlp.experts.{e}.{name}.weight").T for e in held])
-            for i in everywhere
-        ]).astype(dtype)
+    stack, experts = _layer_stackers(c, get)
 
     def shared(name: str, axis: int) -> np.ndarray:
         # n modules -> the ONE SwiGLU of n x moe_d_ff: gate and up columns side by side, down rows stacked
@@ -1091,6 +1190,47 @@ def _build_window_params(config: ModelConfig, shardings: dict[str, Any], get: An
             },
         },
         "final_norm": get("model.norm.weight").astype(dtype),
+    }
+    logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
+                c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
+                rows.start, rows.stop - 1)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_mellum_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The window stack's tree, its sequential block, from HF Mellum names
+    (module text); of a share, the experts and the rows of the embedding and
+    the columns of the head it holds.  No column is permuted: the published
+    rotation pairs a head's halves, as ``model.apply_rope`` does."""
+    import jax
+
+    c = config
+    D, H, K, hd = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    dtype = np.dtype(c.dtype)
+    rank = c.expert_first // c.n_routed_experts
+    rows = slice(rank * c.vocab_size, (rank + 1) * c.vocab_size)
+    stack, experts = _layer_stackers(c, get)
+
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight")[rows].astype(dtype),
+        "layers": {
+            "attn": {
+                "wq": stack("self_attn.q_proj.weight", lambda w: w.T.reshape(D, H, hd)),
+                "wk": stack("self_attn.k_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wv": stack("self_attn.v_proj.weight", lambda w: w.T.reshape(D, K, hd)),
+                "wo": stack("self_attn.o_proj.weight", lambda w: w.T.reshape(H, hd, D)),
+                "attn_norm": stack("input_layernorm.weight", lambda w: w),
+            },
+            "moe": {
+                "router": stack("mlp.gate.weight", lambda w: w.T),
+                "w_gate": experts("gate_proj"),
+                "w_up": experts("up_proj"),
+                "w_down": experts("down_proj"),
+                "mlp_norm": stack("post_attention_layernorm.weight", lambda w: w),
+            },
+        },
+        "final_norm": get("model.norm.weight").astype(dtype),
+        "lm_head": get("lm_head.weight")[rows].T.astype(dtype),
     }
     logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
                 c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,

@@ -39,11 +39,16 @@ multiplies by ``1 + w``, and EVERY layer's FFN is the expert block, its
 counters threaded through the scan as ``_latent_stack`` threads them.
 
 A window stack (``"window"`` among ``layer_types``: Cohere2-MoE,
-command-a-plus) keeps ``layers = {"attn": wq wk wv wo attn_norm [L, ..],
-"moe": see moe.py [L, ..]}`` and runs under ONE ``lax.scan`` over the period's
-repeats (``_window_stack``): ONE LayerNorm a layer, whose output the
-attention and the expert block both read (the parallel block), rotary on the
-window layers and no position at all on the global ones.  Its caches come BY
+command-a-plus; Mellum 2) keeps ``layers = {"attn": wq wk wv wo attn_norm [L,
+..], "moe": see moe.py [L, ..]}`` and runs under ONE ``lax.scan`` over the
+period's repeats (``_window_stack``), the block the description names:
+command-a-plus's ONE LayerNorm a layer, whose output the attention and the
+expert block both read (the parallel block), rotary on the window layers and
+no position at all on the global ones; Mellum 2's sequential RMSNorm block
+with its second norm (``mlp_norm``), an untied head, and the rotation BY
+KIND: the plain law on the window layers and the global layers' own
+(``config.rope_scaling_global``: YaRN), a cos/sin table a kind, each built
+once a step under ``rope/window`` and ``rope/global``.  Its caches come BY
 KIND (``config.CACHE_KINDS``): every paged thing of such a model is a pair
 ``(global, window)``: each side of the pool ``([Lg, Ng, K, page, hd], [Lw,
 Nw, K, page, hd])``, the block tables ``([B, Pg], [B, R])``, a wave's
@@ -314,15 +319,31 @@ def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (x32 * lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_tables(
-    positions: jax.Array, head_dim: int, theta: float
-) -> tuple[jax.Array, jax.Array]:
-    """cos/sin tables for ``positions`` [..., seq] → [..., seq, hd/2]."""
+def rope_frequencies(head_dim: int, theta: float, scaling: Any = None) -> tuple[jax.Array, float]:
+    """A rotation law as ``(inv_freq [hd/2] float32, scale)``: the plain law
+    ``theta^(-2i/hd)`` with scale 1, or under ``scaling`` (a
+    ``config.RopeScaling``) YaRN's: the plain frequency below the ramp, that
+    over ``factor`` above it, and cos and sin both times ``scaling.scale``."""
     freqs = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    if scaling is None or scaling.rope_type == "default":
+        return freqs, 1.0
+    low, high = scaling.correction_range(head_dim, theta)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return (1.0 - ramp) * freqs + ramp * (freqs / scaling.factor), scaling.scale
+
+
+def rope_tables(
+    positions: jax.Array, freqs: jax.Array, scale: float = 1.0
+) -> tuple[jax.Array, jax.Array]:
+    """cos/sin tables for ``positions`` [..., seq] → [..., seq, hd/2] of the
+    law ``(freqs, scale)`` (:func:`rope_frequencies`)."""
     angles = positions[..., None].astype(jnp.float32) * freqs
-    return jnp.cos(angles), jnp.sin(angles)
+    if scale == 1.0:
+        return jnp.cos(angles), jnp.sin(angles)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -437,7 +458,7 @@ def _hybrid_qkv(x, lp, cos, sin, config: ModelConfig):
 def _positions_tables(config: ModelConfig, positions: jax.Array):
     if config.position_embedding == "none":
         return None, None
-    return rope_tables(positions, config.rotary_dim, config.rope_theta)
+    return rope_tables(positions, *rope_frequencies(config.rotary_dim, config.rope_theta))
 
 
 def _embed(params: Params, config: ModelConfig, tokens: jax.Array) -> jax.Array:
@@ -826,7 +847,7 @@ def _latent_stack(
 
 
 def _rope_dim_tables(config: ModelConfig, positions: jax.Array):
-    return rope_tables(positions, config.qk_rope_head_dim, config.rope_theta)
+    return rope_tables(positions, *rope_frequencies(config.qk_rope_head_dim, config.rope_theta))
 
 
 def _mla_chunk_mixer(cache, x, lp, i, cos, sin, config, positions, seq_lens, W, insert_at):
@@ -1059,8 +1080,17 @@ def _window_stack(
     eps = config.norm_eps
     period = config.layer_period
     norm = {"layer": layer_norm, "rms": rms_norm}[config.norm]
-    cos, sin = rope_tables(positions, config.rotary_dim, config.rope_theta)
-    rotary = {WINDOW: True, ATTENTION: config.position_embedding == "rope"}
+    # the rotation BY KIND: each kind that rotates gets its own table, built
+    # once a step outside the scan (the window layers by the plain law; the
+    # global ones, where they rotate, by theirs: config.rope_scaling_global)
+    laws = {WINDOW: None}
+    if config.position_embedding == "rope":
+        laws[ATTENTION] = config.rope_scaling_global
+    tables = {WINDOW: (None, None), ATTENTION: (None, None)}
+    for kind, scaling in laws.items():
+        with jax.named_scope("rope"), _kind_scope(kind):
+            tables[kind] = rope_tables(
+                positions, *rope_frequencies(config.rotary_dim, config.rope_theta, scaling))
 
     def body(c, p):
         x, carry, stats = c
@@ -1071,7 +1101,7 @@ def _window_stack(
             seen[kind] += 1
             lp, mp = _layer(layers["attn"], il), _layer(layers["moe"], il)
             h = norm(x, lp["attn_norm"], eps)
-            q, k, v = _window_qkv(h, lp, *((cos, sin) if rotary[kind] else (None, None)))
+            q, k, v = _window_qkv(h, lp, *tables[kind])
             carry, attn = attn_layer(carry, kind, q, k, v, il, ik)
             with jax.named_scope("attn_out"):
                 a = jnp.einsum("bsnh,nhd->bsd", attn, lp["wo"])
@@ -1318,7 +1348,7 @@ def forward(
         return (logits, (new_k, new_v), state, *(() if moe is None else (moe,)))
 
     x = params["embed"][tokens]  # [B, S, D] gather
-    cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
+    cos, sin = rope_tables(positions, *rope_frequencies(config.head_dim, config.rope_theta))
     layer_params = params["layers"]
 
     def layer_math(x, lp, k_page, v_page):
@@ -1449,7 +1479,7 @@ def _decode_step_with_ring(
         return (logits, (ring_k, ring_v), state, *(() if moe is None else (moe,)))
 
     x = params["embed"][tokens]
-    cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
+    cos, sin = rope_tables(positions, *rope_frequencies(config.head_dim, config.rope_theta))
 
     def layer_body(carry, inputs):
         x, ring_k, ring_v, i = carry
@@ -1614,7 +1644,7 @@ def _verify_step_with_ring(
     B, S = tokens.shape
     positions = base_lens[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     x = params["embed"][tokens]
-    cos, sin = rope_tables(positions, config.head_dim, config.rope_theta)
+    cos, sin = rope_tables(positions, *rope_frequencies(config.head_dim, config.rope_theta))
     ring_shape = (config.n_layers, S, B, config.n_kv_heads, config.head_dim)
     ring_k = jnp.zeros(ring_shape, ring_dtype)
     ring_v = jnp.zeros(ring_shape, ring_dtype)

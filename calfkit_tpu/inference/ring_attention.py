@@ -222,7 +222,7 @@ def _build_prefill_sp(config, mesh: Mesh, axis: str):
     def fn(params, tokens, positions, seq_lens):
         S = tokens.shape[1]
         x = params["embed"][tokens]  # [B, S, D] sequence-sharded (gather)
-        cos, sin = M.rope_tables(positions, config.head_dim, config.rope_theta)
+        cos, sin = M.rope_tables(positions, *M.rope_frequencies(config.head_dim, config.rope_theta))
 
         def layer_body(x, lp):
             q, k, v = M.attn_qkv(x, lp, cos, sin, eps)
@@ -398,7 +398,7 @@ def _build_decode_sp(config, mesh: Mesh, axis: str, steps: int, B: int,
             t = t0 + i  # global fresh index: carries across dispatches
             positions = (prefix_lens + t)[:, None]
             x = params["embed"][token[:, None]]
-            cos, sin = M.rope_tables(positions, hd, config.rope_theta)
+            cos, sin = M.rope_tables(positions, *M.rope_frequencies(hd, config.rope_theta))
 
             def layer_body(x, inputs):
                 lp, kp, vp, fk, fv = inputs

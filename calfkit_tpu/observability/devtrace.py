@@ -78,6 +78,9 @@ SCOPES = frozenset({
     # a window stack's attention cores by layer kind, inside "attention"
     # (model.py's _kind_scope): the sliding-window layers' and the global ones'
     "window", "global",
+    # a window stack's cos/sin tables, one a kind that rotates, built once a
+    # step outside the scan: "rope/window" and "rope/global" (model.py)
+    "rope",
     # gdn.py (inside "gdn", which model.py opens around a Gated DeltaNet
     # mixer: "in_proj", "conv", "gate_norm", "out_proj" as Mamba-2's, and
     # "state" for the delta rule's pass over S) and the gated attention's

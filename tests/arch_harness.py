@@ -1,5 +1,5 @@
 """ONE harness for the per-architecture test families (tests/test_hybrid_mamba,
-test_mla_moe*, test_gdn_moe*, test_cohere2_moe*, test_kda_mla_moe* and their
+test_mla_moe*, test_gdn_moe*, test_cohere2_moe*, test_mellum_moe*, test_kda_mla_moe* and their
 kernels' files): a ``Family`` record a kind of model, and what every family's
 tests do with it.
 
@@ -409,6 +409,25 @@ WINDOW_MOE = Family(
     # nearest control reads 1e-2 and the others more (their tests assert each).
     logit_tol=1e-4,
     runtime_over=dict(window_buckets=(128,)),
+    dense_max_tokens=8,
+    forward_takes=("n_valid",),
+)
+
+# Sliding-window layers beside global ones, each kind rotating by its own law
+# (the plain one; YaRN), the sequential RMSNorm block, softmax-routed experts
+# ALL held and no shared one, an untied head (Mellum 2's kind; preset
+# ``debug-mellum``): two periods ``W W W G``, a window of 24 on pages of 8
+# SHORTER than the chunk of 32, 8 query heads over 2 KV heads, 8 experts with 3 a
+# token, YaRN over an original context of 64 where the rows run to 128.
+MELLUM_MOE = Family(
+    arch_name="mellum-moe-swa",
+    toy=preset("debug-mellum"),
+    # float32 against float32, as ``WINDOW_MOE`` holds its own and for its
+    # reasons: the two sides differ in the ORDER of sums and in nothing else.  The
+    # stated program reads 4e-5 at the worst position over 8 layers and logits up
+    # to 6 in size; the nearest control reads over 1e-3 (their tests assert each).
+    logit_tol=1e-4,
+    runtime_over=dict(window_buckets=(128,), prefill_chunk=32),
     dense_max_tokens=8,
     forward_takes=("n_valid",),
 )

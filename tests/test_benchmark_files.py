@@ -727,7 +727,8 @@ def test_the_chunk_attention_reader_reads_its_share_and_nothing_without_its_sour
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == "chunk_attn_roofline")
     assert entry == {"name": "chunk_attn_roofline", "unit": "%", "better": "higher",
                      "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
-                     "workloads": [COHERE_CELL]}
+                     # (the second window stack's cell joined the list in PR 47)
+                     "workloads": [COHERE_CELL, "mellum2-12b-a2.5b-instruct.mixed-lengths-closed"]}
     cell = M.resolve_cell(MANIFEST, COHERE_CELL, M.ROOT)
     metric = next(m for m in cell.per_layer if m.name == "chunk_attn_roofline")
     assert metric.layer == "kernels" and callable(metric.read)
@@ -1359,12 +1360,14 @@ def test_the_manifest_carries_lfm2_s_cell_and_its_two_metrics():
         8, 60, 120)  # ISSUE 44's 40 s of drain was tried and failed a request: drain_why
     assert cell.traffic["sharing"] == "none"
     assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
-    assert [m["name"] for m in MANIFEST["per_layer"]][-3:] == [
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    at = names.index("shortconv_device_pct")
+    assert names[at:at + 3] == [
         "shortconv_device_pct", "shortconv_mixer_roofline",
-        "moe_grouped_roofline"]  # appended, in order
-    for entry in MANIFEST["per_layer"][-3:]:
-        assert entry["workloads"] == [LFM2_CELL] and entry["moves"] == "tpot_p95_ms"
-    assert MANIFEST["configs"][-1]["name"] == LFM2 and MANIFEST["workloads"][-1]["name"] == LFM2_CELL
+        "moe_grouped_roofline"]  # appended in PR 44, in order
+    for entry in MANIFEST["per_layer"][at:at + 3]:  # told by the FIRST cell of their list
+        assert entry["workloads"][0] == LFM2_CELL and entry["moves"] == "tpot_p95_ms"
+    assert MANIFEST["configs"][6]["name"] == LFM2 and MANIFEST["workloads"][6]["name"] == LFM2_CELL
     registered = {m.name for m in cell.per_layer}
     assert {"shortconv_device_pct", "shortconv_mixer_roofline", "moe_grouped_roofline", "moe_device_pct",
             "moe_expert_load_ratio", "dispatch_roofline", "dispatch_step_ms", "hbm_peak_gb",
@@ -1377,4 +1380,224 @@ def test_the_manifest_carries_lfm2_s_cell_and_its_two_metrics():
                 "mla_cache_roofline"} & registered
     for listed in ("out_tok_s_per_chip",):
         metric = next(m for m in MANIFEST["end_to_end"] if m["name"] == listed)
-        assert metric["workloads"][-1] == LFM2_CELL
+        assert LFM2_CELL in metric["workloads"][-2:]  # last, until PR 47's cell joined
+
+
+# ------------------------------------------------ mellum2-12b-a2.5b-instruct (PR 47)
+MELLUM, MELLUM_CELL = ("mellum2-12b-a2.5b-instruct",
+                       "mellum2-12b-a2.5b-instruct.mixed-lengths-closed")
+
+
+def test_mellum_s_counts_are_what_a_hand_reckons():
+    """The cut's bytes as ISSUE 47's table reckons them, and the counts of
+    THIS chip: all 64 experts of 8 layers, the embedding AND the untied head,
+    a window layer's keys and values at min(context, 1,024) tokens."""
+    arch = M.load_architecture("mellum-moe-swa")
+    config = config_file(MELLUM)
+    expert = 3 * 2304 * 896
+    attn = 2 * 2304 * 32 * 128 + 2 * 2304 * 4 * 128
+    layer = attn + 64 * expert + 2304 * 64 + 2 * 2304
+    assert (attn, expert, layer) == (21_233_664, 6_193_152, 417_747_456)
+    hbm = config["hbm"]
+    assert config["parameters"] == 8 * layer + 2 * 98304 * 2304 + 2304 == 3_794_966_784
+    assert config["published_parameters"] == 28 * layer + 2 * 98304 * 2304 + 2304 == 12_149_915_904
+    assert arch.weight_bytes(config) == 2 * config["parameters"] == hbm["weights_bytes"]
+    assert round(hbm["weights_bytes"] / 1e9, 2) == 7.59
+    assert hbm["experts_bytes"] == 8 * 64 * expert * 2 and hbm["attention_bytes"] == 8 * attn * 2
+    assert hbm["embedding_bytes"] == hbm["head_bytes"] == 98304 * 2304 * 2
+    assert hbm["gate_and_norms_bytes"] == (8 * (2304 * 64 + 2 * 2304) + 2304) * 2
+    assert sum(hbm[k] for k in ("experts_bytes", "attention_bytes", "gate_and_norms_bytes",
+                                "embedding_bytes", "head_bytes")) == hbm["weights_bytes"]
+    assert hbm["kv_bytes_per_token_per_layer"] == 2048 and hbm["page_bytes_per_layer"] == 64 * 2048
+    assert arch.state_bytes_per_token(config) == 2 * 2048  # the two global layers keep a token
+    assert hbm["window_ring_pages_per_slot"] == 18 and hbm["window_pool_pages"] == 64 * 18
+    assert hbm["window_pool_bytes"] == 6 * (64 * 18 + 1) * 64 * 2048
+    assert hbm["global_pool_bytes"] == 2 * config["runtime"]["num_kv_pages"] * 64 * 2048
+    assert hbm["global_pool_tokens"] == 14336 * 64 == 917_504  # ISSUE 47's 8,192 pages, grown
+    assert hbm["prefill_scratch_bytes_per_row"] == 8 * 16384 * 2048
+    assert hbm["chunk_logits_bytes"] == 2048 * 98304 * 2
+    assert hbm["one_pool_for_every_layer_bytes"] > 16e9  # what does not fit beside anything
+    before_temporaries = sum(hbm[k] for k in (
+        "weights_bytes", "window_pool_bytes", "global_pool_bytes",
+        "prefill_scratch_bytes_per_row", "chunk_logits_bytes"))
+    assert 12.8e9 < before_temporaries < 13.0e9 and hbm["weights_bytes"] > 0.25 * 16e9
+    # every expert is held and, at 64 rows, hit: 8 rows an expert a step, the deployment's own
+    assert arch.experts_hit(config, 64) == pytest.approx(64 * (1 - (1 - 8 / 64) ** 64))
+    assert 63.9 < arch.experts_hit(config, 64) < 64
+    step = arch.expert_layer_step(config, 64, 64.0)
+    gate = 2304 * 64
+    assert step["bytes"] == (64 * expert + gate) * 2  # 0.79 GB a layer
+    assert step["flops"] == 2 * 64 * (8 * expert + gate)
+    ring = arch.window_layers_step(config, 64, 6 * 64 * 1024.0)
+    assert ring["bytes"] == 6 * 64 * 1024 * 2048 and ring["flops"] == 4 * 32 * 128 * 6 * 64 * 1024
+    pages = arch.global_layers_step(config, 64, 2 * 64 * 3000.0)
+    assert pages["bytes"] == 2 * 64 * 3000 * 2048 and pages["flops"] == 4 * 32 * 128 * 2 * 64 * 3000
+    short, long = (arch.decode_step(config, 64, n) for n in (1024, 12000))
+    # past the window only the TWO global layers' keys and values grow
+    assert long["bytes"] - short["bytes"] == pytest.approx(2 * 64 * (12000 - 1024) * 2048)
+    assert long["flops"] - short["flops"] == pytest.approx(4 * 32 * 128 * 2 * 64 * (12000 - 1024))
+    assert 8.0e9 < short["bytes"] < 9.0e9  # ISSUE 47: ~8.5 GB a step, 7.1 of them weights
+    early, late = (arch.prefill_chunk(config, 1, 2048, at) for at in (2048, 12288))
+    assert late["flops"] - early["flops"] == pytest.approx(
+        4 * 32 * 128 * 2 * 2048 * (12288 - 2048))
+    assert 1.1e9 < early["flops"] / 2048 < 1.6e9  # a prompt token: 1.13 GFLOP of products + attention
+
+
+def test_the_program_s_description_of_mellum_is_the_file_s():
+    arch = M.load_architecture("mellum-moe-swa")
+    config = config_file(MELLUM)
+    described, runtime = arch.model(config, False)
+    assert described.param_count == config["parameters"]
+    assert config["published"] == {"num_hidden_layers": 28} and config["reduced"] == [
+        "num_hidden_layers"]
+    assert "four-stage pipeline" in config["deployment"] and "stage 0" in config["deployment"]
+    assert len(config["layer_types"]) == len(config["mlp_layer_types"]) == 28  # kept whole
+    assert described.layer_types == ("window", "window", "window", "attention") * 2
+    assert (described.n_routed_experts, described.experts_scored, described.expert_first,
+            described.n_experts_per_tok, described.n_shared_experts, described.expert_share) == (
+        64, 64, 0, 8, 0, False)
+    assert (described.head_dim, described.rotary_dim, described.n_heads, described.n_kv_heads,
+            described.sliding_window, described.moe_d_ff, described.vocab_size) == (
+        128, 128, 32, 4, 1024, 896, 98304)
+    assert (described.norm, described.parallel_block, described.position_embedding,
+            described.scoring_func, described.topk_method, described.tie_embeddings,
+            described.norm_topk_prob) == ("rms", False, "rope", "softmax", "greedy", False, True)
+    scaling = described.rope_scaling_global
+    assert (scaling.rope_type, scaling.factor, scaling.original_max_position_embeddings,
+            scaling.beta_fast, scaling.beta_slow, scaling.attention_factor) == (
+        "yarn", 16, 8192, 32, 1, 1.2772588722239782)
+    assert (runtime.max_batch_size, runtime.max_seq_len, runtime.prefill_chunk,
+            runtime.max_prefill_wave, runtime.prefix_cache, runtime.window_buckets,
+            runtime.decode_steps_per_dispatch) == (64, 18432, 2048, 1, False, (18432,), 4)
+    assert described.sliding_window < runtime.prefill_chunk  # the first cell of which that is true
+    assert described.window_ring_pages(runtime.page_size, runtime.decode_steps_per_dispatch) == 18
+    from calfkit_tpu.inference.config import preset
+
+    published = preset("mellum2-12b-a2.5b-instruct")
+    assert published.param_count == config["published_parameters"]
+    for field in ("d_model", "n_heads", "n_kv_heads", "head_dim", "moe_d_ff", "sliding_window",
+                  "n_experts_per_tok", "n_routed_experts", "vocab_size", "rope_theta", "norm_eps",
+                  "rope_scaling_global", "tie_embeddings", "norm", "parallel_block"):
+        assert getattr(described, field) == getattr(published, field), field
+    assert published.layer_types[:8] == described.layer_types
+    toy, toy_runtime = arch.model(config, True)
+    assert toy.layer_types == described.layer_types and not toy.expert_share
+    assert toy.sliding_window < toy_runtime.prefill_chunk
+    for key, value in (("tie_word_embeddings", True), ("attention_bias", True),
+                       ("max_window_layers", 14)):
+        with pytest.raises(ValueError, match=key):
+            arch.model({**config, key: value}, False)
+    with pytest.raises(ValueError, match="not sparse"):
+        arch.model({**config, "mlp_layer_types": ["dense"] * 28}, False)
+    with pytest.raises(ValueError, match="only 'default' and 'yarn'"):
+        arch.model({**config, "rope_parameters": {
+            **config["rope_parameters"], "full_attention": {
+                **config["rope_parameters"]["full_attention"], "rope_type": "llama3"}}}, False)
+
+
+def test_the_catalog_s_numbers_of_mellum_are_the_file_s():
+    """Every number of the published config under its own key, but the one
+    cut, and ``rope_parameters`` whole (the driver holds the file to the
+    catalog the same way)."""
+    config = config_file(MELLUM)
+    published = {
+        "head_dim": 128, "hidden_size": 2304, "intermediate_size": 7168,
+        "max_position_embeddings": 131072, "max_window_layers": 0, "moe_intermediate_size": 896,
+        "num_attention_heads": 32, "num_experts": 64, "num_experts_per_tok": 8,
+        "num_key_value_heads": 4, "rms_norm_eps": 1e-06, "sliding_window": 1024,
+        "vocab_size": 98304, "model_type": "mellum", "hidden_act": "silu",
+        "attention_bias": False, "norm_topk_prob": True, "tie_word_embeddings": False,
+        "use_sliding_window": True,
+        "rope_parameters": {
+            "full_attention": {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                               "original_max_position_embeddings": 8192, "beta_fast": 32,
+                               "beta_slow": 1, "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 500000}},
+    }
+    assert {k: config[k] for k in published} == published
+    assert config["num_hidden_layers"] == 8
+    assert config["layer_types"] == (["sliding_attention"] * 3 + ["full_attention"]) * 7
+    assert config["mlp_layer_types"] == ["sparse"] * 28
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        with open(catalog) as f:
+            row = next(r for r in map(json.loads, f) if r["name"] == "Mellum2-12B-A2.5B-Instruct")
+        assert config["source"] == row["source_url"]
+        assert {k: config[k] for k in row["config"] if k != "num_hidden_layers"} == {
+            k: v for k, v in row["config"].items() if k != "num_hidden_layers"}
+
+
+def test_the_mixed_lengths_traffic_is_the_issue_s():
+    """Short and long prompts in one queue: 87.5% of 256-2,048 (mean 976),
+    12.5% of 8,448-16,000 (mean 11,888), every long one past YaRN's original
+    context; five prefill buckets at a chunk of 2,048; outputs of mean 589."""
+    from benchmarks.traffic import Traffic, quantiles
+
+    cell = M.resolve_cell(MANIFEST, MELLUM_CELL, M.ROOT)
+    assert cell.chips == 1 and cell.params == {"callers": 64}
+    assert cell.traffic_name == "mixed-lengths-closed" and cell.traffic["loop"] == "closed"
+    assert (cell.traffic["drain_s"], cell.traffic["request_timeout_s"],
+            cell.traffic["trace_s"], cell.traffic["sharing"]) == (80, 300, 8, "none")
+    law = cell.traffic["prompt_tokens"]
+    assert law["law"] == "choice" and law["weights"] == [7] * 8 + [1] * 8
+    short, long = law["values"][:8], law["values"][8:]
+    assert short == [256, 384, 512, 768, 1024, 1280, 1536, 2048]
+    assert long == [8448, 9216, 10240, 11264, 12288, 13312, 14336, 16000]
+    assert sum(short) / 8 == 976 and sum(long) / 8 == 11888 and min(long) > 8192
+    sizes = quantiles(law, 4096)
+    assert sum(n > 2048 for n in sizes) / 4096 == 0.125
+    assert round(sum(sizes) / 4096) == 2340
+    assert 0.63 < sum(n for n in sizes if n > 2048) / sum(sizes) < 0.64  # ISSUE 47: "63%"
+    chunk = cell.config["runtime"]["prefill_chunk"]
+    assert sorted({-(-n // chunk) * chunk for n in sizes}) == [2048, 10240, 12288, 14336, 16384]
+    out = cell.traffic["output_tokens"]
+    assert out == {"law": "choice", "values": [256, 384, 512, 768, 1024],
+                   "weights": [1, 1, 1, 1, 1]}
+    assert round(sum(out["values"]) / 5) == 589
+    traffic = Traffic(cell.traffic, cell.params, seed=1)
+    assert sorted(a.max_tokens for a in traffic.agents()) == out["values"]  # an Agent a budget
+    assert traffic.prompt_range() == (256, 16000) and traffic.callers() == 64
+    # the longest request fits a slot, and the agreement's prompts stand on both sides of
+    # the window, of the ring's first wrap and of the original context
+    assert 16000 + 1024 + 25 <= cell.config["runtime"]["max_seq_len"]
+    prompts = cell.config["agreement"]["prompt_tokens"]
+    for edge in (1024, 18 * 64, 8192):
+        assert min(prompts) < edge < max(prompts)
+        assert any(edge - 200 < n < edge for n in prompts) and any(edge < n <= edge + 200 for n in prompts)
+
+
+def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
+    cell = M.resolve_cell(MANIFEST, MELLUM_CELL, M.ROOT)
+    entry = MANIFEST["configs"][-1]
+    assert entry["name"] == MELLUM and entry["reduced"] == ["num_hidden_layers"]
+    assert entry["source"] == ("https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/"
+                               "blob/main/config.json")
+    assert MANIFEST["workloads"][-1]["name"] == MELLUM_CELL and len(MANIFEST["workloads"]) == 8
+    assert len(MANIFEST["workloads"][-1]["why"]) <= 200 and len(entry["why"]) <= 200
+    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
+    own = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [MELLUM_CELL]]
+    assert own == MANIFEST["per_layer"][-2:] and [m["name"] for m in own] == [
+        "global_cache_roofline", "chunk_padding_pct"]
+    assert own[0] == {"name": "global_cache_roofline", "unit": "%", "better": "higher",
+                      "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
+                      "workloads": [MELLUM_CELL]}
+    assert own[1] == {"name": "chunk_padding_pct", "unit": "%", "better": "lower",
+                      "source": "program_counter", "layer": "admission and batching",
+                      "moves": "tpot_p95_ms", "workloads": [MELLUM_CELL]}
+    registered = {m.name for m in cell.per_layer}
+    for listed in ("swa_cache_roofline", "swa_device_pct", "chunk_attn_roofline",
+                   "moe_device_pct", "moe_expert_roofline", "moe_grouped_roofline"):
+        metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
+        assert metric["workloads"][-1] == MELLUM_CELL and listed in registered, listed
+    assert {"global_cache_roofline", "chunk_padding_pct", "dispatch_roofline",
+            "device_idle_closed_pct"} <= registered
+    # delivered tokens/s judges the cell: six untraced seeds of the final tree spread it 1.1%
+    # and the slots bound it (occupancy 93.9-97.0%): PERF.md section 2's rule, ISSUE 47's too
+    assert {m.name for m in cell.end_to_end} == {"tpot_p95_ms", "out_tok_s_per_chip", "setup_s"}
+    for listed in ("batch_occupancy_pct", "empty_slot_queued_pct", "kv_pages_peak_pct",
+                   "hbm_peak_gb", "moe_expert_load_ratio"):
+        metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
+        assert metric["workloads"][-1] == MELLUM_CELL and listed in registered, listed
+    assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline",
+                "shortconv_mixer_roofline"} & registered
+    assert "kv_pages_given_back_pct" not in {m["name"] for m in MANIFEST["per_layer"]}

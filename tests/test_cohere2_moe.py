@@ -21,6 +21,7 @@ from calfkit_tpu.inference.pallas_attention import (
     PallasShapeError,
     paged_decode_attention_pallas,
 )
+from tests.arch_harness import MELLUM_MOE
 from tests.arch_harness import WINDOW_MOE as FAMILY
 from tests.arch_harness import both_forms_at_toy_size  # noqa: F401 - an autouse fixture
 
@@ -226,21 +227,36 @@ def test_blocked_attention_is_the_one_pass_attention_without_a_window():
 
 
 # ------------------------------------------------ the share test
-def test_the_shares_parts_add_up_to_the_uncut_layer():
+def _shared_leaves(lp):
+    return {n: w for n, w in lp.items() if n.startswith("s_")}
+
+
+@pytest.mark.parametrize("family", [FAMILY, MELLUM_MOE], ids=["command-a-plus", "mellum"])
+def test_the_shares_parts_add_up_to_the_uncut_layer(family):
     """What the 2 shares of the experts give (each the routed sum over ITS
-    held experts, weights not renormalised), the shared experts and the
-    attention counted ONCE, add up to the uncut layer: in the program
-    (``moe_ffn``) and in the reference (a one-layer model's logits are linear
-    in what the FFN adds, so its parts are compared before the head)."""
-    whole = replace(TOY, n_layers=1, layer_types=(WINDOW,), n_routed_experts=8,
+    held experts, weights not renormalised), the shared experts (where the
+    model has any) and the attention counted ONCE, add up to the uncut layer:
+    in the program (``moe_ffn``) and in the reference (a one-layer model's
+    logits are linear in what the FFN adds, so its parts are compared before
+    the head).  Both window stacks: command-a-plus's sigmoid gate with its
+    shared experts averaged, and Mellum 2's softmax gate with none, whose cell
+    holds every expert and whose toy is held by halves here."""
+    arch, toy = family.arch, family.toy
+    whole = replace(toy, n_layers=1, layer_types=(WINDOW,), n_routed_experts=8,
                     n_experts_total=0, expert_first=0)
-    params = FAMILY.seeded(whole, key=5)
+    params = family.seeded(whole, key=5)
     lp = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
     h = jnp.asarray(np.random.default_rng(1).normal(size=(2, 24, 32)), jnp.float32)
+    n_shared = toy.n_shared_experts
+    # the reference's expert block: (k, renormalise, first held[, shared experts])
+    block = (lambda first: arch._expert_ffn(3, True, first, n_shared)) if n_shared else (
+        lambda first: arch._expert_ffn(3, True, first))
     with jax.default_matmul_precision("highest"):
         full, _ = moe.moe_ffn(h, lp, whole)
-        shared = full - moe.moe_ffn(h, {n: w for n, w in lp.items() if not n.startswith("s_")},
-                                    whole)[0]
+        shared = full - moe.moe_ffn(
+            h, {n: w for n, w in lp.items() if n not in _shared_leaves(lp)},
+            replace(whole, n_shared_experts=0, shared_expert_combine="sum"))[0]
+        assert bool(n_shared) == bool(float(jnp.abs(shared).max()) > 1e-3)
         parts = []
         for first in (0, 4):
             share = replace(whole, n_routed_experts=4, n_experts_total=8, expert_first=first)
@@ -250,10 +266,10 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
     assert float(jnp.abs(sum(parts) + shared - full).max()) < 1e-5
     assert float(jnp.abs(parts[0]).max()) > 1e-2 < float(jnp.abs(parts[1]).max())
     # the reference, through its own expert block
-    ref = {first: ARCH._expert_ffn(3, True, first, 2)(
+    ref = {first: block(first)(
         h, {n: (w[:, first:first + 4] if n in ("w_gate", "w_up", "w_down") else w)
             for n, w in params["layers"]["moe"].items()}, jnp.int32(0)) for first in (0, 4)}
-    ref_whole = ARCH._expert_ffn(3, True, 0, 2)(h, params["layers"]["moe"], jnp.int32(0))
+    ref_whole = block(0)(h, params["layers"]["moe"], jnp.int32(0))
     ref_shared = ref[0] - parts[0]  # its shared part, by the program's routed part
     assert float(jnp.abs(ref[0] + ref[4] - ref_shared - ref_whole).max()) < 1e-4
     assert float(jnp.abs(ref_whole - full).max()) < 1e-4

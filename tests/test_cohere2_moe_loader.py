@@ -131,7 +131,7 @@ def test_the_loader_s_permutation_is_the_one_the_two_rotations_differ_by():
     hf_q, hf_k = (rng.normal(size=(6, 2, 8)).astype(np.float32) for _ in range(2))  # [S, heads, hd]
     pos = jnp.arange(6)
     halves = np.concatenate([np.arange(0, 8, 2), np.arange(1, 8, 2)])
-    cos, sin = M.rope_tables(pos[None], 8, 50000.0)
+    cos, sin = M.rope_tables(pos[None], *M.rope_frequencies(8, 50000.0))
     tree = [np.asarray(M.apply_rope(jnp.asarray(x[None][..., halves]), cos, sin))[0]
             for x in (hf_q, hf_k)]
     published = [np.asarray(ARCH._rotate_pairs(jnp.asarray(x), pos, 50000.0)) for x in (hf_q, hf_k)]

@@ -394,7 +394,7 @@ def test_rotating_adjacent_pairs_equals_rotating_the_halves_of_permuted_columns(
     permuted vector; scores are dot products, which no permutation moves."""
     dr = 8
     x = np.random.default_rng(0).normal(size=(1, 5, 1, dr)).astype(np.float32)
-    cos, sin = M.rope_tables(jnp.arange(5)[None], dr, 800000.0)
+    cos, sin = M.rope_tables(jnp.arange(5)[None], *M.rope_frequencies(dr, 800000.0))
     halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
     ours = np.asarray(M.apply_rope(jnp.asarray(x[..., halves]), cos, sin))
     c, s = np.asarray(cos)[0, :, None, :], np.asarray(sin)[0, :, None, :]

@@ -1208,11 +1208,12 @@ def test_paged_decode_window_form_compiles_for_v5e(one_chip, no_persistent_cache
     assert PA.KERNEL_TRACES["paged_decode", "compiled"] == before + 1
 
 
-def _window_cell_engine(held: int | None = None):
-    """The engine of the cell's configuration at its published WIDTHS, its 4
-    layers (one period W W W G) and its runtime; ``held`` experts of the 16
-    where the test has no use for 6.4 GB of them (the gate keeps its 128
-    outputs)."""
+def _window_cell_engine(held: int | None = None, name: str = "command-a-plus-05-2026",
+                        ring: int = 66):
+    """The engine of a window stack's cell at its published WIDTHS, its
+    layers (whole periods W W W G) and its runtime; ``held`` experts of those
+    the file holds where the test has no use for gigabytes of them (the gate
+    keeps its outputs)."""
     import json
     from dataclasses import replace
 
@@ -1220,16 +1221,17 @@ def _window_cell_engine(held: int | None = None):
     from calfkit_tpu.inference.engine import InferenceEngine
 
     here = os.path.dirname(manifest.__file__)
-    with open(os.path.join(here, "configs", "command-a-plus-05-2026.json")) as f:
+    with open(os.path.join(here, "configs", f"{name}.json")) as f:
         described = json.load(f)
     arch = manifest.load_architecture(described["architecture"], here)
     config, runtime = arch.model(described, False)
-    assert config.layer_types == ("window", "window", "window", "attention")
+    assert config.layer_period == ("window", "window", "window", "attention")
     if held is not None:
-        config = replace(config, n_routed_experts=held)
+        config = replace(config, n_routed_experts=held,
+                         n_experts_total=config.experts_scored)
     engine = InferenceEngine(
         config, replace(runtime, compilation_cache=False, attention_impl="pallas"))
-    assert engine._attn_impl == engine._chunk_attn_impl == "pallas" and engine._ring_pages == 66
+    assert engine._attn_impl == engine._chunk_attn_impl == "pallas" and engine._ring_pages == ring
     return engine
 
 
@@ -1288,8 +1290,40 @@ def _window_cell_programs(engine, one_chip, buckets):
         report[f"ragged {bucket}"] = memory.temp_size_in_bytes
         # no scores of 128 heads over a context, nor over a key block
         assert memory.temp_size_in_bytes < 2.5e9
+        # what the program holds at once: arguments (weights, pools, scratch), outputs that
+        # alias none of them (a chunk's logits), temporaries
+        held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+                - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+        assert held < 16e9, (bucket, held)
         yield_text = text
     return report, yield_text
+
+
+def test_mellum_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
+    """Mellum 2's cell at its published widths, 8 layers (two periods in the
+    scan) and 64 slots, 2 of the 64 experts held: the decode dispatch (a ring
+    of 18 pages a slot, the rotation by kind outside the scan) and the ragged
+    programs of the narrowest and the widest bucket, whose chunk of 2,048 is
+    LONGER than the window of 1,024: the period's four decode reads and four
+    chunk kernels under both kinds' scopes, both kinds' rope tables, the pools
+    out where they came in."""
+    engine = _window_cell_engine(held=2, name="mellum2-12b-a2.5b-instruct", ring=18)
+    assert engine.config.sliding_window < engine.runtime.prefill_chunk
+    report, text = _window_cell_programs(engine, one_chip, (2048, 16384))
+    assert "/rope/window/" in text and "/rope/global/" in text
+    print("temporaries, bytes (2 held experts):", report)
+
+
+@pytest.mark.slow  # 7.6 GB of weights and three whole-program compiles on every core: the offline
+# lane runs it, as it runs the other expert cells'; PERF.md section 4 has its readings
+def test_mellum_cell_programs_at_full_size_fit_the_chip(one_chip, no_persistent_cache):
+    """The same at the cell's FULL size, all 64 experts of 12.4 MB in 8
+    layers: no stack is copied into another layout, and arguments and
+    temporaries together fit the 16 GB chip."""
+    engine = _window_cell_engine(name="mellum2-12b-a2.5b-instruct", ring=18)
+    report, text = _window_cell_programs(engine, one_chip, (2048, 16384))
+    assert not _made_in_loops(text, ("bf16[64,2304,896]",))
+    print("temporaries, bytes:", report)
 
 
 def test_window_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
