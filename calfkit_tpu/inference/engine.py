@@ -489,6 +489,27 @@ def _layer_kinds(cfg: ModelConfig) -> "tuple | None":
     return (cfg.global_layer_ids, cfg.window_layer_ids) if cfg.windowed else None
 
 
+def _pool_dtype(side: Any) -> Any:
+    """The cache's dtype, from one side of it (a pair by cache kind, or the
+    array): what a ring or a scratch beside it is made in."""
+    return jax.tree.leaves(side)[0].dtype
+
+
+def _stored_layout(cfg: ModelConfig, k: Any, v: Any) -> str:
+    """Each side of the page pool AS IT IS STORED, by cache kind, with ``f``,
+    the positions of a head that share a stored row (``model.make_page_pool``
+    defines the form): what says, once at start-up, whether a head narrower
+    than a lane tile lies the way the decode kernel and the write's loop
+    take it (``f`` > 1) or as declared."""
+    names = ("c", "k_rope") if cfg.latent else ("K", "V")
+    kinds = zip(("global ", "window "), k, v) if cfg.windowed else (("", k, v),)
+    return "; ".join(
+        kind + ", ".join(
+            f"{name} {side.dtype.name}{list(side.shape)} f={side.shape[-1] // width}"
+            for name, side, width in zip(names, sides, cfg.cache_dims))
+        for kind, *sides in kinds)
+
+
 def _some(x: Any) -> tuple:
     """``(x,)``, or ``()`` for None: an optional argument or result that a
     program without it never sees."""
@@ -1330,9 +1351,10 @@ class InferenceEngine:
 
                 self._prefix = PrefixCache()
             logger.info(
-                "paged %s pool: %d pages x %d tokens (%.2f GB)",
+                "paged %s pool: %d pages x %d tokens (%.2f GB), stored %s",
                 "latent" if config.latent else "KV", n_pages, rt.page_size,
                 sum(side.nbytes for side in jax.tree.leaves((self._k, self._v))) / 1e9,
+                _stored_layout(config, self._k, self._v),
             )
         else:
             self._prefix = None
@@ -1821,7 +1843,7 @@ class InferenceEngine:
             B = last.shape[0]
             kw = k[:, :, :, :window]
             vw = v[:, :, :, :window]
-            ring = M.sides_like((k, v), (cfg.n_kv_layers, steps, B, cfg.cache_heads))
+            ring = M.cache_sides(cfg, (cfg.n_kv_layers, steps, B, cfg.cache_heads), k.dtype)
 
             def step(carry, t):
                 ring, last, *st = carry
@@ -1879,7 +1901,7 @@ class InferenceEngine:
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
         attn_impl, ssm_impl = self._attn_impl, self._ssm_impl
-        from calfkit_tpu.inference.pallas_attention import lane_dense_pool, latent_rope_view
+        from calfkit_tpu.inference.pallas_attention import latent_rope_view
 
         @jax.named_scope("decode_loop")
         def decode(params, k, v, tables, last, lens, active, done_prev,
@@ -1890,18 +1912,15 @@ class InferenceEngine:
             # to the trash page) by the device-side done-mask chain
             active = active & jnp.logical_not(done_prev)
             B = last.shape[0]
-            ring = M.sides_like((k, v), (cfg.n_kv_layers, steps, B, cfg.cache_heads))
+            ring = M.cache_sides(
+                cfg, (cfg.n_kv_layers, steps, B, cfg.cache_heads), _pool_dtype(k))
             pool = (k, v)
-            if attn_impl.startswith("pallas"):
-                # the kernel's view of a pool of heads narrower than a lane
-                # tile is a relayout of both sides, and of a latent pool one
-                # of its narrow rope side alone: made HERE, once a dispatch
-                # (the pool is a constant of the step loop and of the layer
-                # scan), never per step.  Other heads: the pool.
-                pool = (
-                    (k, latent_rope_view(v)) if cfg.latent
-                    else (jax.tree.map(lane_dense_pool, k), jax.tree.map(lane_dense_pool, v))
-                )
+            if cfg.latent and attn_impl.startswith("pallas"):
+                # the kernel's view of a latent pool's narrow rope side is a
+                # relayout of that side: made HERE, once a dispatch (the pool
+                # is a constant of the step loop and of the layer scan),
+                # never per step.  K and V pairs are read as they are stored.
+                pool = (k, latent_rope_view(v))
 
             def step(carry, t):
                 ring, last, *st = carry
@@ -2118,7 +2137,7 @@ class InferenceEngine:
         ):
             # tokens: [R, bucket]; slots/true_lens: [R]
             R, P = tokens.shape
-            scratch = M.sides_like((k, v), (cfg.n_kv_layers, R, cfg.cache_heads, P))
+            scratch = M.cache_sides(cfg, (cfg.n_kv_layers, R, cfg.cache_heads, P), _pool_dtype(k))
             pos = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (R, P))
             with jax.named_scope("prefill"):
                 logits, (sk, sv), *wstate = M.forward(
@@ -2279,14 +2298,16 @@ class InferenceEngine:
         @jax.named_scope("seed_scratch")
         def seed(pool_k, pool_v, ids):
             def gather(pool_side):
-                g = pool_side[:, ids]  # [L, R, n, K, page, hd]
-                L, R, n, K, ps, hd = g.shape
+                # the pages as stored, f positions a row: taken apart in the
+                # gathered rows (a row-major reshape of the result)
+                g = pool_side[:, ids]  # [L, R, n, K, page / f, f * hd]
+                L, R, n, K = g.shape[:4]
                 return g.transpose(0, 1, 3, 2, 4, 5).reshape(
-                    L, R, K, n * ps, hd
+                    L, R, K, n * page, -1
                 )
 
-            sk, sv = M.sides_like(
-                (pool_k, pool_v), (cfg.n_kv_layers, rows, cfg.cache_heads, bucket))
+            sk, sv = M.cache_sides(
+                cfg, (cfg.n_kv_layers, rows, cfg.cache_heads, bucket), pool_k.dtype)
             sk = sk.at[:, :, :, : n_pages * page].set(gather(pool_k))
             sv = sv.at[:, :, :, : n_pages * page].set(gather(pool_v))
             return sk, sv
@@ -4194,21 +4215,27 @@ class InferenceEngine:
         taken again, which is after the other free slots and pages have gone
         round (both are granted oldest-first): what a check of what the
         served rows LEFT BEHIND reads
-        (``benchmarks/architectures/cohere2-moe-swa.py``)."""
+        (``benchmarks/architectures/cohere2-moe-swa.py``).  Read through
+        ``model.gather_window_paged``, which takes the pool's stored rows
+        (``model.make_page_pool``) apart: positions by ``hd``, whatever the
+        stored form."""
         if not self._windowed:
             return None
-        return M.gather_window_paged(self._k[1][layer], self._tables[1], self._ring_pages)
+        return M.gather_window_paged(
+            self._k[1][layer], self._tables[1], self._ring_pages, self.config.head_dim)
 
     def global_keys(self, slot: int, layer: int = 0) -> "jax.Array | None":
         """The keys of ONE global layer (its index among the global layers) as
         ONE slot's global pages hold them now, ``[K, pages a sequence x page,
         hd]``, position ``p`` at entry ``p``; None for a model without window
         layers.  One slot at a time: every slot's would be a copy of the whole
-        pool.  Stands after retirement as ``window_ring`` does."""
+        pool.  Stands after retirement as ``window_ring`` does, and is read
+        out of the stored pool the same way."""
         if not self._windowed:
             return None
         table = self._tables[0][slot:slot + 1]
-        return M.gather_window_paged(self._k[0][layer], table, table.shape[1])[0]
+        return M.gather_window_paged(
+            self._k[0][layer], table, table.shape[1], self.config.head_dim)[0]
 
     def recurrent_state(self) -> "tuple[jax.Array, jax.Array] | None":
         """The slots' recurrent state as it stands, ``(matrix [layers, slots,
@@ -4441,7 +4468,7 @@ class InferenceEngine:
             self.stats.prefix_hits += len(wave)
             self.stats.prefix_reused_tokens += reuse * len(wave)
         else:
-            scratch = M.sides_like((self._k, self._v), scratch_shape)
+            scratch = M.cache_sides(self.config, scratch_shape, _pool_dtype(self._k))
         self._inflight = dict(
             wave=wave, bucket=bucket, chunk=chunk,
             n_chunks=-(-bucket // chunk), idx=reuse // chunk,

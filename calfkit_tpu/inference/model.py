@@ -50,8 +50,9 @@ KIND: the plain law on the window layers and the global layers' own
 (``config.rope_scaling_global``: YaRN), a cos/sin table a kind, each built
 once a step under ``rope/window`` and ``rope/global``.  Its caches come BY
 KIND (``config.CACHE_KINDS``): every paged thing of such a model is a pair
-``(global, window)``: each side of the pool ``([Lg, Ng, K, page, hd], [Lw,
-Nw, K, page, hd])``, the block tables ``([B, Pg], [B, R])``, a wave's
+``(global, window)``: each side of the pool ``([Lg, Ng, K, page / f, f *
+hd], [Lw, Nw, K, page / f, f * hd])`` (as stored, :func:`positions_per_row`:
+``f`` = 1 at heads of 128), the block tables ``([B, Pg], [B, R])``, a wave's
 destination pages.  A row's window table is a RING of ``R`` pages: position
 ``p`` of a window layer lies in table entry ``(p // page) % R``, so a row
 that grows writes over (gives back) what its window has left behind, and a
@@ -74,7 +75,15 @@ pool, decode ring, prefill scratch) is a pair of arrays of the layout
 ``[.., heads, .., width]``: K and V per head, or, for a latent model, the
 latent's two parts ``(c, k_rope)`` with heads 1 (apart, so that the wide
 part is whole lane tiles), and the functions that move cache bytes map over
-the pair whatever its widths.  The mixer has two algebras that
+the pair whatever its widths.  The PAGE POOL of K and V pairs alone is
+STORED ``[.., heads, page / f, f * width]`` where the head is narrower than
+a lane tile (:func:`positions_per_row`, ``f`` positions side by side in a
+row: the same numbers in the same order, the form both the decode kernel
+and the write's loop take as it lies on a TPU); its readers and writers
+(:func:`gather_window_paged`, the paged decode kernel,
+:func:`consolidate_ring_paged`, :func:`write_prefill_pages`) read ``f`` off
+the pool's lanes and the head's width, which the ring, the scratch, the
+queries or ``config.cache_dims`` carry.  The mixer has two algebras that
 must agree: *expanded* in ``forward`` (prefill and chunks: ``k_nope`` and
 ``v`` expanded from the window's ``c``, the published form) and *absorbed* in
 the decode step (``W_uk`` folded into the query and ``W_uv`` applied after
@@ -1208,7 +1217,7 @@ def _window_decode_step_paged(params, config, tokens, pool, tables, ring, t, bas
     row's RING: every entry that holds a position inside ``(q - W, q]``."""
     (kg, kw), (vg, vw) = pool
     tg, tw = tables
-    W = config.sliding_window
+    W, hd = config.sliding_window, config.head_dim
     pallas, interpret = attn_impl.startswith("pallas"), attn_impl == "pallas_interpret"
     read_lens = base_lens if active is None else jnp.where(active, base_lens, 0)
     q_pos = base_lens + t
@@ -1229,17 +1238,17 @@ def _window_decode_step_paged(params, config, tokens, pool, tables, ring, t, bas
         elif kind == WINDOW:
             def main(qg):
                 k_ring = gather_window_paged(
-                    lax.dynamic_index_in_dim(kw, ik, 0, keepdims=False), tw, tw.shape[1])
+                    lax.dynamic_index_in_dim(kw, ik, 0, keepdims=False), tw, tw.shape[1], hd)
                 v_ring = gather_window_paged(
-                    lax.dynamic_index_in_dim(vw, ik, 0, keepdims=False), tw, tw.shape[1])
+                    lax.dynamic_index_in_dim(vw, ik, 0, keepdims=False), tw, tw.shape[1], hd)
                 return masked_attention_source(
                     qg, k_ring, v_ring, _window_ring_valid(k_ring.shape[2], base_lens, q_pos, W))
         else:
             def main(qg):
                 k_win = gather_window_paged(
-                    lax.dynamic_index_in_dim(kg, ik, 0, keepdims=False), tg, wpages)
+                    lax.dynamic_index_in_dim(kg, ik, 0, keepdims=False), tg, wpages, hd)
                 v_win = gather_window_paged(
-                    lax.dynamic_index_in_dim(vg, ik, 0, keepdims=False), tg, wpages)
+                    lax.dynamic_index_in_dim(vg, ik, 0, keepdims=False), tg, wpages, hd)
                 return masked_attention_source(
                     qg, k_win, v_win, jnp.arange(k_win.shape[2])[None, :] < base_lens[:, None])
         return _kind_decode_attention(kind, q, main, rk, rv, t)
@@ -1741,7 +1750,7 @@ def ragged_attention_xla(
 
 def ragged_attention_paged_xla(
     q: jax.Array,  # [B, S, H, hd]
-    pool_layer_k: jax.Array,  # [N, K, page, hd] one layer's pages
+    pool_layer_k: jax.Array,  # [N, K, page / f, f * hd] one layer's pages, as stored
     pool_layer_v: jax.Array,
     tables: jax.Array,  # [B, Pmax]
     q_starts: jax.Array,  # [B]
@@ -1755,8 +1764,8 @@ def ragged_attention_paged_xla(
     call."""
     return ragged_attention_xla(
         q,
-        gather_window_paged(pool_layer_k, tables, wpages),
-        gather_window_paged(pool_layer_v, tables, wpages),
+        gather_window_paged(pool_layer_k, tables, wpages, q.shape[-1]),
+        gather_window_paged(pool_layer_v, tables, wpages, q.shape[-1]),
         q_starts, kv_lens,
     )
 
@@ -1843,7 +1852,7 @@ def verify_step_ring_paged(
     params: Params,
     config: ModelConfig,
     tokens: jax.Array,  # [B, S]
-    pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] READ-ONLY here
+    pool: tuple[jax.Array, jax.Array],  # as stored (make_page_pool); READ-ONLY here
     tables: jax.Array,  # [B, Pmax]
     base_lens: jax.Array,  # [B]
     wpages: int,  # static: window bucket in pages
@@ -1857,8 +1866,8 @@ def verify_step_ring_paged(
         vl = lax.dynamic_index_in_dim(pool_v, i, 0, keepdims=False)
         return _verify_merged_attention(
             q,
-            gather_window_paged(kl, tables, wpages),
-            gather_window_paged(vl, tables, wpages),
+            gather_window_paged(kl, tables, wpages, config.head_dim),
+            gather_window_paged(vl, tables, wpages, config.head_dim),
             rk, rv, base_lens,
         )
 
@@ -1918,19 +1927,16 @@ def _insert_chunk(
     return jax.vmap(one)(cache, chunk, offsets)
 
 
-def cache_sides(config: ModelConfig, lead: tuple, dtype: Any) -> tuple[jax.Array, jax.Array]:
+def cache_sides(
+    config: ModelConfig, lead: tuple, dtype: Any, per_row: int = 1
+) -> tuple[jax.Array, jax.Array]:
     """A zeroed cache ``[*lead, width]``: the pair (K, V), or the latent's
-    two parts (c, k_rope) for a model whose token leaves one latent behind."""
-    k, v = config.cache_dims
-    return jnp.zeros((*lead, k), dtype), jnp.zeros((*lead, v), dtype)
-
-
-def sides_like(cache: tuple, lead: tuple) -> tuple[jax.Array, jax.Array]:
-    """Zeroed arrays ``[*lead, width]``, one for each side of ``cache``, of
-    that side's width and type (a decode ring or a prefill scratch beside
-    a pool)."""
-    k, v = (side[0] if isinstance(side, tuple) else side for side in cache)  # by kind: any
-    return (jnp.zeros((*lead, k.shape[-1]), k.dtype), jnp.zeros((*lead, v.shape[-1]), v.dtype))
+    two parts (c, k_rope) for a model whose token leaves one latent behind.
+    ``per_row`` positions share a row (a page pool's stored form,
+    :func:`positions_per_row`): ``[*lead[:-1], lead[-1] / per_row, per_row *
+    width]``, the same numbers in the same order."""
+    *lead, n = lead
+    return tuple(jnp.zeros((*lead, n // per_row, per_row * w), dtype) for w in config.cache_dims)
 
 
 def make_empty_cache(
@@ -1946,39 +1952,86 @@ def make_empty_cache(
 # --------------------------------------------------------------------------- #
 
 
+LANES = 128  # a TPU lane tile: the minor dimension of every tiled array
+
+
+def lane_pack(width: int) -> int:
+    """How many vectors of ``width`` numbers fill one 128-lane row: ``128 /
+    width`` where that is whole, else 1 (whole lane tiles, or a width no
+    packing helps)."""
+    return LANES // width if LANES % width == 0 else 1
+
+
+def sublane_tile(dtype: Any) -> int:
+    """Rows of one (sublane, lane) tile of ``dtype``: 8 of 32 bits, 16 of
+    bfloat16, 32 of an 8-bit type."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def positions_per_row(width: int, page: int, dtype: Any) -> int:
+    """``f``: how many positions of one kv head share a stored row of a K/V
+    page pool.  THE rule of the pool's stored form, for every reader and
+    writer on every platform.
+
+    A head that divides a lane tile (64, 32) is stored ``f = 128 / width``
+    positions a row, row ``r`` of a page holding positions ``f * r .. f * r +
+    f - 1`` side by side, where that leaves a page whole sublane tiles of the
+    cache's dtype (``page / f`` rows: 16 a tile in bfloat16); every other
+    head, and every page the packing would leave a partial tile, is stored as
+    declared, ``f = 1``.  Why: a ``[.., page, 64]`` array does not lie
+    row-major and lane-dense in a TPU's HBM (compiled for the v5e it is held
+    with the page INDEX minor-most), so the paged decode kernel, which copies
+    a page slab whole, and the write's loop of window updates each made their
+    own copy of the whole side, six a dispatch (PERF.md section 6, PRs 28, 46
+    and 49).  ``[.., page / f, 128]`` is held as it is declared and both take
+    it as it lies."""
+    f = lane_pack(width)
+    return f if page % (f * sublane_tile(dtype)) == 0 else 1
+
+
 def make_page_pool(
     config: ModelConfig, num_pages: int, page_size: int, dtype: Any = None,
     window_pages: int = 0,
 ) -> tuple[Any, Any]:
     """The page pools, BY CACHE KIND, as a pair of sides; page 0 of every
-    pool is its trash page.
+    pool is its trash page.  ``f`` is :func:`positions_per_row` of the head,
+    the page and the dtype (1 for a head of 128 or wider: the pool as
+    declared):
 
     - K and V of every token (a dense or hybrid model's attention layers):
-      ``[L, N, K, page, hd]`` x 2.
+      ``[L, N, K, page / f, f * hd]`` x 2.
     - one latent a token (MLA): ``[L, N, 1, page, r]`` and ``[L, N, 1, page,
-      dr]``, the two parts of the latent, and no K or V per head at all.
+      dr]``, the two parts of the latent, and no K or V per head at all;
+      stored as declared (the decode kernel reads the rope side through
+      ``pallas_attention.latent_rope_view``, another arrangement).
     - a model with window layers (``config.windowed``): each side is a pair
-      ``(global [Lg, N, K, page, hd], window [Lw, window_pages, K, page,
-      hd])``: ``num_pages`` pages for the layers that keep every token,
-      ``window_pages`` for the layers that keep a ring of pages a row."""
+      ``(global [Lg, N, K, page / f, f * hd], window [Lw, window_pages, K,
+      page / f, f * hd])``: ``num_pages`` pages for the layers that keep every
+      token, ``window_pages`` for the layers that keep a ring of pages a row.
+
+    A reader cannot tell ``hd`` from the array: it takes the head's width
+    from ``config.cache_dims`` (or from the queries, the ring or the scratch
+    it holds beside the pool)."""
     dtype = dtype or jnp.dtype(config.dtype)
+    f = 1 if config.latent else positions_per_row(config.head_dim, page_size, dtype)
     if config.windowed:
         kg, vg = cache_sides(
-            config, (config.n_global_layers, num_pages, config.cache_heads, page_size), dtype)
+            config, (config.n_global_layers, num_pages, config.cache_heads, page_size), dtype, f)
         kw, vw = cache_sides(
-            config, (config.n_window_layers, window_pages, config.cache_heads, page_size), dtype)
+            config, (config.n_window_layers, window_pages, config.cache_heads, page_size), dtype, f)
         return (kg, kw), (vg, vw)
     return cache_sides(
-        config, (config.n_kv_layers, num_pages, config.cache_heads, page_size), dtype)
+        config, (config.n_kv_layers, num_pages, config.cache_heads, page_size), dtype, f)
 
 
 @jax.named_scope("gather_window")
 def gather_window_paged(
-    pool_layer: jax.Array,  # [N, K, page, hd] one layer's pages
+    pool_layer: jax.Array,  # [N, K, page / f, f * width] one layer's pages, as stored
     tables: jax.Array,  # [B, Pmax] int32 block tables
     wpages: int,  # static: pages per attention window
+    width: int,  # static: the head's width (``config.cache_dims`` of this side)
 ) -> jax.Array:
-    """Materialize each row's window from its pages → [B, K, wp·page, hd].
+    """Materialize each row's window from its pages → [B, K, wp·page, width].
 
     The XLA read path: one gather per (layer, step) of EVERY row's whole
     window bucket, used or not, occupied slot or not — read, written and
@@ -1986,23 +2039,26 @@ def gather_window_paged(
     and for the two sides of a latent pool alike: the CPU path, the
     ``tp > 1`` path, the verify and ragged S > 1 programs, and the parity
     reference of the two Pallas decode kernels, which read each row's live
-    pages in place instead.  No decode step of a benchmark cell runs it on
-    a chip since PR 32 (on the v5e the gather was 48% of device time in
-    the Mistral cell, a quarter in granite's, and with the layer slice a
-    third in Kimi's: PERF.md section 6, PRs 25, 28 and 32).
+    pages in place instead.  The pool's stored rows (``f`` positions side by
+    side, :func:`positions_per_row`) come apart in the GATHERED rows, a
+    row-major reshape of the result: nothing of the pool is copied for it.
+    No decode step of a benchmark cell runs it on a chip since PR 32 (on the
+    v5e the gather was 48% of device time in the Mistral cell, a quarter in
+    granite's, and with the layer slice a third in Kimi's: PERF.md section
+    6, PRs 25, 28 and 32).
     """
     B = tables.shape[0]
-    page = pool_layer.shape[2]
-    gathered = pool_layer[tables[:, :wpages]]  # [B, wp, K, page, hd]
+    positions = wpages * pool_layer.shape[2] * (pool_layer.shape[3] // width)
+    gathered = pool_layer[tables[:, :wpages]]  # [B, wp, K, page / f, f * width]
     gathered = jnp.transpose(gathered, (0, 2, 1, 3, 4))
-    return gathered.reshape(B, pool_layer.shape[1], wpages * page, -1)
+    return gathered.reshape(B, pool_layer.shape[1], positions, -1)
 
 
 def decode_step_ring_paged(
     params: Params,
     config: ModelConfig,
     tokens: jax.Array,  # [B, 1]
-    pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] READ-ONLY here
+    pool: tuple[jax.Array, jax.Array],  # as stored (make_page_pool); READ-ONLY here
     tables: jax.Array,  # [B, Pmax] block tables
     ring: tuple[jax.Array, jax.Array],  # [L, T, B, K, hd]
     t: jax.Array,  # scalar step index
@@ -2027,10 +2083,13 @@ def decode_step_ring_paged(
     discarded by the caller) is given length 0 there and costs no page, and
     no layer is sliced out of the pool; the XLA read slices the layer,
     gathers every row's window whatever it holds and takes no notice of
-    ``active``.  For a Pallas read ``pool`` may be the kernel's view of the
-    pool (:func:`pallas_attention.lane_dense_pool`, or for a latent pool
-    :func:`pallas_attention.latent_rope_view` of its rope side), which a
-    caller that loops over steps makes once, outside its loop.
+    ``active``.  Both take the pool of K and V pairs AS IT IS STORED
+    (:func:`make_page_pool`: ``f`` positions a row for a head narrower than
+    a lane tile; the kernel copies a page's stored rows whole, the XLA read
+    takes its gathered rows apart).  For a Pallas read of a LATENT pool the
+    rope side may be the kernel's view of it
+    (:func:`pallas_attention.latent_rope_view`), which a caller that loops
+    over steps makes once, outside its loop.
     """
     if config.windowed:
         return _window_decode_step_paged(
@@ -2054,8 +2113,8 @@ def decode_step_ring_paged(
             )
         window = tuple(
             gather_window_paged(
-                lax.dynamic_index_in_dim(side, i, 0, keepdims=False), tables, wpages)
-            for side in pool)
+                lax.dynamic_index_in_dim(side, i, 0, keepdims=False), tables, wpages, width)
+            for side, width in zip(pool, config.cache_dims))
         return mla_merged_decode_attention(*q, window, (ring_c, ring_r), base_lens, t, scale)
 
     if config.latent:
@@ -2078,8 +2137,8 @@ def decode_step_ring_paged(
         vl = lax.dynamic_index_in_dim(pool_v, i, 0, keepdims=False)
         return _merged_decode_attention(
             q,
-            gather_window_paged(kl, tables, wpages),
-            gather_window_paged(vl, tables, wpages),
+            gather_window_paged(kl, tables, wpages, config.head_dim),
+            gather_window_paged(vl, tables, wpages, config.head_dim),
             rk, rv, base_lens, t,
         )
 
@@ -2091,7 +2150,7 @@ def decode_step_ring_paged(
 
 @jax.named_scope("kv_write")
 def consolidate_ring_paged(
-    pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] (donated)
+    pool: tuple[jax.Array, jax.Array],  # as stored (make_page_pool); donated
     ring: tuple[jax.Array, jax.Array],  # [L, T, B, K, hd]
     tables: jax.Array,  # [B, Pmax]
     base_lens: jax.Array,  # [B]
@@ -2102,7 +2161,8 @@ def consolidate_ring_paged(
 
     Window updates in place, two a row (:func:`_write_windows`): a row's
     ``T`` new positions lie in the page it is in and the page it runs on
-    into.  Inactive rows are redirected to page 0 (the trash page): a
+    into, in whole stored rows of each.  Inactive rows are redirected to
+    page 0 (the trash page): a
     retired slot's pages may already belong to a NEW request, so letting
     its stale row write through its old table entries would corrupt a
     neighbor — the dense layout tolerated garbage-beyond-length, the paged
@@ -2121,22 +2181,29 @@ def consolidate_ring_paged(
 
 
 def _write_windows(pool, ring, tables, base_lens, active, wraps=False):
-    """``ring`` [L, T, B, K, w] x 2 into ``pool`` [L, N, K, page, w] x 2 at each
-    row's positions ``base_len .. base_len + T - 1``: a loop over the rows
-    on the donated pool, two turns a row, each a read-modify-write of a
-    ``[L, 1, K, T, w]`` window of ONE page.  The first window starts at
-    ``min(offset, page - T)`` of the row's page so that it never leaves it;
-    the second at 0 of the next table entry (the table a ring of pages where
-    it ``wraps``), or of the trash page where the row does not straddle, is
-    not ``active`` or has run past its table.  Positions of a window that
-    take no token keep the bits they had (a first window that starts before
-    the row's offset covers live tokens).  Not one scatter over (page,
-    offset): those are not the pool's major dimensions, and the TPU compiler
-    copied each pool side into a layout with the offset above the KV heads
-    and back around it, every dispatch (PERF.md section 6, PR 46).  A ring
-    longer than a page goes in as several of at most a page."""
+    """``ring`` [L, T, B, K, w] x 2 into ``pool`` [L, N, K, page / f, f * w] x 2
+    (its stored form, :func:`positions_per_row`) at each row's positions
+    ``base_len .. base_len + T - 1``: a loop over the rows on the donated
+    pool, two turns a row, each a read-modify-write of a window of stored
+    ROWS of ONE page, ``[L, 1, K, rows, f * w]``.  ``rows`` is what ``T``
+    positions can touch at any offset, ``ceil((T + f - 1) / f)`` (``T`` at
+    ``f = 1``; ``T / f + 1`` otherwise, taken up to whole groups of 8 rows),
+    a page at most.  The first window starts at row ``min(offset // f, page
+    / f - rows)`` of the row's page so that it never leaves it; the second
+    at 0 of the next table entry (the table a ring of pages where it
+    ``wraps``), or of the trash page where the row does not straddle, is not
+    ``active`` or has run past its table.
+    Positions of a window that take no token keep the bits they had (a first
+    window that starts before the row's offset covers live tokens; at ``f >
+    1`` so does its tail past the row's last token): the mask is taken over
+    (row, lane block).  Not one scatter over (page, offset): those are not the
+    pool's major dimensions, and the TPU compiler copied each pool side into a
+    layout with the offset above the KV heads and back around it, every
+    dispatch (PERF.md section 6, PR 46).  A ring longer than a page goes in as
+    several of at most a page."""
     T, B = ring[0].shape[1:3]
-    page = pool[0].shape[3]
+    f = pool[0].shape[4] // ring[0].shape[4]
+    page = pool[0].shape[3] * f
     if T > page:
         for at in range(0, T, page):
             pool = _write_windows(
@@ -2144,6 +2211,15 @@ def _write_windows(pool, ring, tables, base_lens, active, wraps=False):
                 wraps)
         return pool
     entries = tables.shape[1]
+    rows = -(-(T + f - 1) // f)  # what T positions can touch at any offset: T at f = 1
+    if f > 1:
+        # whole groups of 8 rows: for such a window the v5e's compiler keeps the
+        # side in the loop as it is stored, and for one of 5 rows it relays the
+        # whole side into a layout of its own and back, every dispatch
+        # (compiled for the described v5e and on the chip, PERF.md section 6, PR 49)
+        rows = -(-rows // 8) * 8
+    rows = min(rows, page // f)
+    slack = rows * f - T  # positions of a window that no token of T can take: 0 at f = 1
 
     def page_of(entry, live):  # [B] table entry -> [B] page id, the trash page if not live
         entry = entry % entries if wraps else entry
@@ -2151,32 +2227,54 @@ def _write_windows(pool, ring, tables, base_lens, active, wraps=False):
         return jnp.where(live & (entry < entries), ids, 0)
 
     entry, offset = base_lens // page, base_lens % page
-    start = jnp.minimum(offset, page - T)
-    shift = offset - start  # the window's first `shift` positions hold older tokens
+    # the first window's first row, and how many of its first positions hold older tokens
+    # (f = 1 is the same arithmetic spelled without its divisions: a pool stored as
+    # declared traces to the program it was, operation for operation)
+    if f == 1:
+        at = jnp.minimum(offset, page - T)
+        shift = offset - at
+    else:
+        at = jnp.minimum(offset // f, page // f - rows)
+        shift = offset - at * f
+    over = shift - slack if slack else shift  # tokens that run over the window's end
     first = page_of(entry, active)
-    second = page_of(entry + 1, active & (shift > 0))
-    where = jnp.arange(T)[:, None]  # a window's positions, against [.., T, w]
+    second = page_of(entry + 1, active & (over > 0))
+    where = jnp.arange(T + slack)[:, None]  # a window's positions, against [.., T + slack, w]
+
+    def head(b):  # the first window's positions that take a token of row b
+        takes = where >= shift[b]
+        # at f > 1 the window's tail past the row's last token keeps its bits too
+        return takes & (where < shift[b] + T) if slack else takes
 
     def write(side, r):
-        size = (side.shape[0], 1, side.shape[2], T, side.shape[4])
+        size = (side.shape[0], 1, side.shape[2], rows, side.shape[4])
+
+        def stored(x):  # [.., rows * f, w or 1] -> the window's stored rows [.., rows, f * w]
+            return jnp.broadcast_to(x, (*x.shape[:-1], r.shape[4])).reshape(
+                *x.shape[:-2], *size[3:]) if f > 1 else x
 
         def row(b, side):
-            # [L, T, 1, K, w] -> [L, 1, K, T, w], token j at window position
-            # (j + shift) % T: the head of the ring at `shift` of the first
-            # window, what ran over the page's end at 0 of the second
+            # [L, T, 1, K, w] -> [L, 1, K, T + slack, w], token j at window
+            # position (j + shift) % (T + slack): the head of the ring at
+            # `shift` of the first window, what ran over the page's end at 0
+            # of the second
             vals = jnp.transpose(lax.dynamic_slice_in_dim(r, b, 1, axis=2), (0, 2, 3, 1, 4))
-            vals = jnp.roll(vals.astype(side.dtype), shift[b], axis=3)
-            for page_id, at, takes in ((first[b], start[b], where >= shift[b]),
-                                       (second[b], 0, where < shift[b])):
-                corner = (0, page_id, 0, at, 0)
+            vals = vals.astype(side.dtype)
+            if slack:
+                vals = jnp.pad(vals, ((0, 0),) * 3 + ((0, slack), (0, 0)))
+            vals = stored(jnp.roll(vals, shift[b], axis=3))
+            for page_id, row0, takes in ((first[b], at[b], head(b)),
+                                         (second[b], 0, where < over[b])):
+                corner = (0, page_id, 0, row0, 0)
                 old = lax.dynamic_slice(side, corner, size)
-                side = lax.dynamic_update_slice(side, jnp.where(takes, vals, old), corner)
+                side = lax.dynamic_update_slice(
+                    side, jnp.where(stored(takes), vals, old), corner)
             return side
 
         return lax.fori_loop(0, B, row, side)
 
-    # a loop a side: at heads narrower than a lane tile the compiler holds the
-    # side it loops over row-major, and one loop over both would hold both
+    # a loop a side: one loop over both sides held both in the loop's layout
+    # at once where that was not the stored one (PERF.md section 6, PR 46)
     return write(pool[0], ring[0]), write(pool[1], ring[1])
 
 
@@ -2196,12 +2294,16 @@ def _consolidate_by_kind(pool, ring, tables, base_lens, active, layer_kinds):
 
 @jax.named_scope("kv_write")
 def write_prefill_pages(
-    pool: tuple[jax.Array, jax.Array],  # [L, N, K, page, hd] (donated)
+    pool: tuple[jax.Array, jax.Array],  # as stored (make_page_pool); donated
     scratch: tuple[jax.Array, jax.Array],  # [L, R, K, P, hd] prefill K/V
     page_ids: jax.Array,  # [R, P // page] int32 destination pages
     layer_kinds: Any = None,  # pages by kind: (global_layer_ids, window_layer_ids)
 ) -> tuple[jax.Array, jax.Array]:
     """Scatter whole prefill pages into the pool (page-granular writes).
+    Each page block of the SCRATCH is packed into the pool's stored rows
+    first (:func:`make_page_pool`: ``[.., page, hd]`` -> ``[.., page / f, f *
+    hd]``, a row-major reshape of the wave's rows), so the set lands on the
+    donated pool as it lies and nothing of the pool is relaid.
     Pools by kind take ``page_ids`` as a pair too: the global layers' pages
     of the scratch go to the row's global pages, the window layers' to its
     ring, where the caller has named the trash page for every page of the
@@ -2213,14 +2315,17 @@ def write_prefill_pages(
         kw, vw = write_prefill_pages((kw, vw), (scratch[0][wl], scratch[1][wl]), page_ids[1])
         return (kg, kw), (vg, vw)
     L, R, K, P, _ = scratch[0].shape
-    page = pool[0].shape[3]
-    npg = P // page
 
     def write(pool_side: jax.Array, s: jax.Array) -> jax.Array:
         # [L, R, K, np*page, hd] -> [L, R, np, K, page, hd] -> [L, R*np, ...]
+        # and each page block into the pool's stored rows, f positions side by
+        # side: a row-major reshape of the wave's scratch, never of the pool
         hd = s.shape[-1]
+        rows, lanes = pool_side.shape[3:]
+        page = rows * (lanes // hd)
+        npg = P // page
         blocks = s.reshape(L, R, K, npg, page, hd).transpose(0, 1, 3, 2, 4, 5)
-        blocks = blocks.reshape(L, R * npg, K, page, hd).astype(pool_side.dtype)
+        blocks = blocks.reshape(L, R * npg, K, rows, lanes).astype(pool_side.dtype)
         return pool_side.at[:, page_ids.reshape(-1)].set(blocks)
 
     return write(pool[0], scratch[0]), write(pool[1], scratch[1])

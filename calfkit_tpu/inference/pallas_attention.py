@@ -15,8 +15,8 @@ different things of one page:
 - :func:`_paged_decode_kernel` reads K and V pairs of kv heads under a
   block-diagonal head mask (a dense or hybrid model's pool).  A head that
   divides a lane tile (64) is read ``f = 128 / hd`` positions a lane row,
-  through a lane-dense view of the pool (:func:`lane_dense_pool`) that a
-  caller in a loop makes once.
+  which is how such a pool is STORED (``model.positions_per_row``,
+  ``model.make_page_pool``): the kernel takes every pool as it lies.
 - :func:`_latent_decode_kernel` reads a LATENT (MLA) pool in the absorbed
   form: every head scores ONE key a token, ``q_lat . c + q_rope . k_rope``,
   and the value is ``c`` again, taken from the buffer the scores just read,
@@ -113,47 +113,19 @@ class PallasShapeError(ValueError):
     a shape.  A kernel request is never quietly served by another path."""
 
 
-def paged_decode_lane_pack(head_dim: int) -> int:
-    """Positions of one kv head that share a 128-lane row in the paged decode
-    kernel's view of the pool: ``128 / head_dim`` for a head that divides a
-    lane tile, 1 for every other head (whole lane tiles read as they lie)."""
-    return 128 // head_dim if 128 % head_dim == 0 else 1
-
-
 def paged_decode_in_place_ok(head_dim: int, page: int, dtype) -> bool:
-    """Whether :func:`_paged_decode_kernel` can take these shapes on a TPU:
-    a page slab is copied and flattened whole, so a slab row is whole lane
-    tiles (128: a head of whole tiles, or ``f`` positions of a head that
-    divides one, :func:`paged_decode_lane_pack`) and the page, ``f``
-    positions a row, whole sublane tiles of the cache's dtype (8 rows of 32
+    """Whether :func:`_paged_decode_kernel` can take the pool of these shapes
+    on a TPU: a page slab is copied and flattened whole out of the pool AS IT
+    IS STORED (``model.make_page_pool``: ``f = model.positions_per_row``
+    positions a row), so a stored row is whole lane tiles (128: a head of
+    whole tiles, or ``f`` positions of a head that divides one) and a page's
+    ``page / f`` rows whole sublane tiles of the cache's dtype (8 rows of 32
     bits: 16 for bf16).  What fails this reads through XLA under ``"auto"``
     and is refused under an explicit ``"pallas"``."""
-    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    f = paged_decode_lane_pack(head_dim)
-    return (f * head_dim) % 128 == 0 and page % (f * sublanes) == 0
+    from calfkit_tpu.inference.model import LANES, positions_per_row, sublane_tile
 
-
-def lane_dense_pool(pool_side: jax.Array) -> jax.Array:
-    """One side of the pool as the paged decode kernel reads it:
-    ``[L, N, K, page / f, f * hd]``, row r of a page holding positions
-    ``f * r .. f * r + f - 1`` side by side.
-
-    At ``f == 1``, and for shapes outside :func:`paged_decode_in_place_ok`,
-    this IS the pool (the same array, no operation).  For a narrower head it
-    is a relayout.  A ``[L, N, K, page, 64]`` array does not lie row-major
-    and lane-dense in a TPU's HBM: compiled for the v5e, the engine's
-    programs hold the pool with the page INDEX minor-most, the row-major
-    layout Mosaic asks of an operand pads the last dimension to 128 lanes,
-    and a ``[K, page, 64]`` slab cannot be sliced out of that (PERF.md
-    section 6, PR 28).  The copy reads and writes the whole side, so a
-    caller in a loop makes it ONCE, outside: the engine does, per dispatch
-    (``InferenceEngine._decode_fn_paged``).  No scope of its own: XLA's copy
-    keeps none, and a device trace lists it under ``(unscoped) copy``."""
-    L, N, K, page, hd = pool_side.shape
-    f = paged_decode_lane_pack(hd)
-    if f == 1 or not paged_decode_in_place_ok(hd, page, pool_side.dtype):
-        return pool_side
-    return pool_side.reshape(L, N, K, page // f, f * hd)
+    f = positions_per_row(head_dim, page, dtype)
+    return (f * head_dim) % LANES == 0 and (page // f) % sublane_tile(dtype) == 0
 
 
 # pages of one row folded per compute block of the paged decode kernel
@@ -180,8 +152,9 @@ def _paged_decode_kernel(
     head.  The masked columns weigh exactly 0 in ``p``, so the PV product
     over the same flattened axis is each head's own weighted sum.
 
-    ``pack`` (f) > 1 is a head narrower than a lane tile, read through
-    :func:`lane_dense_pool`: a slab row holds f positions side by side, and
+    ``pack`` (f) > 1 is a head narrower than a lane tile, whose pool is
+    stored so (``model.positions_per_row``): a slab row holds f positions
+    side by side, and
     the row's queries come f times over, copy j with q in lane block j and
     zeros elsewhere.  Copy j's scores are then those of positions
     ``f * c + j`` and lane block j of its PV rows their weighted sum (the
@@ -331,8 +304,8 @@ def _lane_block_copies(q: jax.Array, f: int) -> jax.Array:
 )
 def paged_decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
-    pool_k: jax.Array,  # [L, N, K, page, hd] the WHOLE pool (no slicing),
-    pool_v: jax.Array,  # or its lane_dense_pool view
+    pool_k: jax.Array,  # [L, N, K, page / f, f * hd] the WHOLE pool (no
+    pool_v: jax.Array,  # slicing), as stored: model.make_page_pool
     layer: jax.Array,  # scalar int32 — which layer's pages to read
     tables: jax.Array,  # [B, Pmax] int32 block tables
     base_lens: jax.Array,  # [B]
@@ -357,19 +330,22 @@ def paged_decode_attention_pallas(
     static upper bound, so one kernel serves every window bucket.  K, V
     and q meet the MXU in the cache's dtype with float32 accumulation.
 
-    A head narrower than a lane tile is read through
-    :func:`lane_dense_pool`.  A caller in a loop passes that view, made
-    outside the loop; a pool passed as it lies is viewed here, per call."""
+    The pool comes as it is stored, ``f`` positions of a head narrower
+    than a lane tile side by side in a row: ``f`` is read off the pool's
+    lanes and the queries' width, and nothing of the pool is relaid."""
+    from calfkit_tpu.inference.model import positions_per_row
+
     B, K, G, hd = q.shape
     H = K * G
-    pool_k, pool_v = lane_dense_pool(pool_k), lane_dense_pool(pool_v)
     rows, lanes = pool_k.shape[3:]
     f = lanes // hd
-    if not paged_decode_in_place_ok(hd, rows * f, pool_k.dtype):
+    if (lanes % hd or f != positions_per_row(hd, rows * f, pool_k.dtype)
+            or not paged_decode_in_place_ok(hd, rows * f, pool_k.dtype)):
         raise PallasShapeError(
             f"the paged decode kernel copies a page slab whole: a head of "
-            f"{hd} on pages of {rows * f} {pool_k.dtype} positions is not "
-            "whole (8, 128) tiles (paged_decode_in_place_ok)"
+            f"{hd} in stored rows of {lanes} on pages of {rows} {pool_k.dtype} "
+            "rows is not whole (8, 128) tiles of the pool's stored form "
+            "(paged_decode_in_place_ok)"
         )
     _note_trace("paged_decode", interpret)
     P = max(1, min(pages_per_block, wpages))
@@ -432,7 +408,7 @@ def paged_decode_attention_pallas(
 @jax.named_scope("attention")
 def merged_paged_decode_attention_pallas(
     q: jax.Array,  # [B, 1, H, hd]
-    pool_k: jax.Array,  # [L, N, K, page, hd]
+    pool_k: jax.Array,  # [L, N, K, page / f, f * hd], as stored
     pool_v: jax.Array,
     layer: jax.Array,  # scalar int32
     tables: jax.Array,  # [B, Pmax]
@@ -473,17 +449,18 @@ def latent_decode_in_place_ok(kv_lora_rank: int, rope_dim: int, page: int, dtype
     a page's ``c`` slab ``[page, r]`` is copied whole as it lies, so ``r`` is
     whole lane tiles; its rope slab is copied out of
     :func:`latent_rope_view`, ``f`` parts of the page side by side
-    (:func:`paged_decode_lane_pack` of the rope width: 2 at 64), so a row of
+    (``model.lane_pack`` of the rope width: 2 at 64), so a row of
     the view is whole lane tiles and a PART of the page, ``page / f``
     positions, whole sublane tiles of the cache's dtype: the kernel slices
     the ``c`` slab into the same parts.  What fails this reads through XLA
     under ``"auto"`` and is refused under an explicit ``"pallas"``."""
-    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    f = paged_decode_lane_pack(rope_dim)
+    from calfkit_tpu.inference.model import lane_pack, sublane_tile
+
+    f = lane_pack(rope_dim)
     return (
         kv_lora_rank % 128 == 0
         and (f * rope_dim) % 128 == 0
-        and page % (f * sublanes) == 0
+        and page % (f * sublane_tile(dtype)) == 0
     )
 
 
@@ -491,19 +468,22 @@ def latent_rope_view(rope_side: jax.Array) -> jax.Array:
     """The rope side of a latent pool as the latent decode kernel reads it:
     ``[L, N, 1, page / f, f * dr]``, row r of a page holding positions
     ``r, r + page / f, ..`` side by side: PART j of the page in lane block
-    j.  (Not :func:`lane_dense_pool`'s neighbours side by side: the kernel
-    meets part j with rows ``j * page / f ..`` of the ``c`` slab, a slice
-    of whole tiles, where neighbours would ask for every f-th row.)
+    j.  (Not a K/V pool's stored rows, neighbours side by side
+    (``model.positions_per_row``): the kernel meets part j with rows ``j *
+    page / f ..`` of the ``c`` slab, a slice of whole tiles, where
+    neighbours would ask for every f-th row.)
 
     A ``[.., page, 64]`` array does not lie row-major and lane-dense in a
-    TPU's HBM (:func:`lane_dense_pool` has why), so this is a copy of the
+    TPU's HBM (``model.positions_per_row`` has why), so this is a copy of the
     whole side, an eighth of the pool, and a caller in a loop makes it ONCE,
     outside: the engine does, per dispatch
     (``InferenceEngine._decode_fn_paged``).  The ``c`` side, eight ninths of
     the pool, is never relaid.  At ``f == 1``, and for shapes outside the
     rule, this IS the side."""
+    from calfkit_tpu.inference.model import lane_pack
+
     L, N, one, page, dr = rope_side.shape
-    f = paged_decode_lane_pack(dr)
+    f = lane_pack(dr)
     if f == 1 or page % f:
         return rope_side
     parts = rope_side.reshape(L, N, one, f, page // f, dr)
@@ -794,9 +774,10 @@ def chunk_attention_ok(head_dim: int, chunk: int, scratch: int, dtype) -> bool:
     tiles of the cache's dtype (the G heads of a tile are stacked along
     them), a key block of whole lane tiles (it is the scores' minor
     dimension).  What fails this runs ``model.blocked_attention``."""
-    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    from calfkit_tpu.inference.model import sublane_tile
+
     tile, block = chunk_attention_tiles(chunk, scratch)
-    return head_dim % 128 == 0 and tile % sublanes == 0 and block % 128 == 0
+    return head_dim % 128 == 0 and tile % sublane_tile(dtype) == 0 and block % 128 == 0
 
 
 def chunk_attention_bounds(

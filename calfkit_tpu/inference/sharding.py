@@ -197,7 +197,7 @@ def cache_sharding(config: ModelConfig, mesh: Mesh, batch: int) -> NamedSharding
 
 
 def pool_sharding(config: ModelConfig, mesh: Mesh) -> NamedSharding:
-    """Paged KV pool [L, N, K, page, hd]: kv heads over tp.
+    """Paged KV pool [L, N, K, page / f, f * hd] (as stored): kv heads over tp.
 
     Pages are NOT split over dp — block tables address the whole pool, and
     proving page locality to GSPMD isn't worth it at current dp targets

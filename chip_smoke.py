@@ -389,17 +389,19 @@ def kernels_phase(seed: int, interpret: bool) -> dict:
         worst[name] = max(worst.get(name, 0.0), err)
 
     # the paged decode read in place at Mistral-7B's widths (32 rows) and
-    # granite-4.0-h-micro's (64 rows, heads of 64: two positions a lane
-    # row): the 2048 window, row lengths around page edges, a row that
-    # reads nothing
+    # granite-4.0-h-micro's (64 rows, heads of 64: the pool stored two
+    # positions a row, model.positions_per_row): the 2048 window, row
+    # lengths around page edges, a row that reads nothing
     for name, (K, G, hd, rows) in {
         "": (8, 4, 128, 32), "/hd64": (8, 4, 64, 64),
     }.items():
         B, W = (4, 128) if interpret else (rows, 2048)
         wpages = W // page
         key = jax.random.split(jax.random.key(seed + 7), 3)
-        pool_k = jax.random.normal(key[0], (2, 1 + B * wpages, K, page, hd), bf)
-        pool_v = jax.random.normal(key[1], (2, 1 + B * wpages, K, page, hd), bf)
+        f = M.positions_per_row(hd, page, bf)  # the pool as make_page_pool stores it
+        stored = (2, 1 + B * wpages, K, page // f, f * hd)
+        pool_k = jax.random.normal(key[0], stored, bf)
+        pool_v = jax.random.normal(key[1], stored, bf)
         tables = jnp.asarray(
             1 + np.arange(B * wpages, dtype=np.int32).reshape(B, wpages))
         lens = rng.integers(1, W, size=B)
@@ -410,8 +412,8 @@ def kernels_phase(seed: int, interpret: bool) -> dict:
             q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=wpages,
             interpret=interpret)
         o_x, m_x, z_x = M.masked_attention_source(
-            q, M.gather_window_paged(pool_k[1], tables, wpages),
-            M.gather_window_paged(pool_v[1], tables, wpages),
+            q, M.gather_window_paged(pool_k[1], tables, wpages, hd),
+            M.gather_window_paged(pool_v[1], tables, wpages, hd),
             jnp.arange(W)[None, :] < lens[:, None])
         close(f"paged-decode-in-place{name}",
               o / jnp.maximum(z[..., None], 1e-30),

@@ -308,7 +308,7 @@ def test_the_decode_kernel_s_window_form_agrees_with_the_xla_read(length, t):
     o, m, z = paged_decode_attention_pallas(
         q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=_KERNEL["ring"], interpret=True,
         window_starts=jnp.maximum(q_pos - W + 1, 0))
-    k_ring, v_ring = (M.gather_window_paged(side[1], tables, _KERNEL["ring"])
+    k_ring, v_ring = (M.gather_window_paged(side[1], tables, _KERNEL["ring"], _KERNEL["hd"])
                       for side in (pool_k, pool_v))
     valid = M._window_ring_valid(k_ring.shape[2], lens, q_pos, W)
     assert int(valid[0].sum()) == max(0, min(length, W - 1 - t))

@@ -4,7 +4,8 @@
 - The paged decode ATTENTION kernel's corners (PRs 25 and 28;
   ``pallas_attention._paged_decode_kernel``, interpret mode): row lengths
   around a page edge, rows that read nothing, shared tables, the proof
-  that a dead page is never read, the shape rule, the lane-dense view.
+  that a dead page is never read, the shape rule, the pool's stored form
+  (``model.positions_per_row``: f = 1, 2 and 4 positions a row).
 - The selector (``InferenceEngine._resolved_attn_impl``, PR 29): an
   explicit kernel request outside the rule is refused at construction,
   and under ``pallas_interpret`` no program of a paged engine builds any
@@ -32,11 +33,13 @@ from calfkit_tpu.inference.engine import InferenceEngine  # noqa: E402
 # --------------------------------------------------------------------------- #
 # the paged decode attention kernel (pallas_attention._paged_decode_kernel),
 # interpret mode, at the head shapes of Mistral (K 8, G 4, hd 128), granite
-# (K 8, G 4, hd 64) and TinyLlama (K 4, G 8, hd 64); page 64.  Heads of 64
-# are read two positions a lane row (pallas_attention.lane_dense_pool).
+# (K 8, G 4, hd 64) and TinyLlama (K 4, G 8, hd 64), and at a head of 32;
+# page 64.  The pool comes as it is STORED (model.positions_per_row): heads
+# of 64 two positions a row, heads of 32 four, heads of 128 as declared.
 # --------------------------------------------------------------------------- #
 
-PD_WIDTHS = {"mistral": (8, 4, 128), "granite": (8, 4, 64), "tinyllama": (4, 8, 64)}
+PD_WIDTHS = {"mistral": (8, 4, 128), "granite": (8, 4, 64), "tinyllama": (4, 8, 64),
+             "heads-of-32": (4, 4, 32)}
 PD_PAGE, PD_WPAGES, PD_PMAX = 64, 4, 6
 PD_WINDOW = PD_WPAGES * PD_PAGE
 
@@ -93,9 +96,19 @@ def _paged_decode_case(name: str, dtype, seed: int = 0, widths: str = "mistral")
     )
 
 
+def _stored(pool_side):
+    """A pool side declared ``[L, N, K, page, hd]`` as ``make_page_pool``
+    stores it: ``[L, N, K, page / f, f * hd]``, the same numbers in the same
+    order (``f`` = 1: the array itself)."""
+    *lead, page, hd = pool_side.shape
+    f = M.positions_per_row(hd, page, pool_side.dtype)
+    return pool_side.reshape(*lead, page // f, f * hd)
+
+
 def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
-    """The kernel (layer 1 of the whole pool) beside the XLA law it
-    replaces: ``masked_attention_source`` over ``gather_window_paged``."""
+    """The kernel (layer 1 of the whole pool AS STORED) beside the XLA law
+    it replaces: ``masked_attention_source`` over each row's window, taken
+    out of the DECLARED pool by plain indexing."""
     import jax.numpy as jnp
 
     from calfkit_tpu.inference import model as M
@@ -104,14 +117,16 @@ def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
     )
 
     got = paged_decode_attention_pallas(
-        q, pool_k, pool_v, jnp.int32(1), tables, lens, wpages=PD_WPAGES,
+        q, _stored(pool_k), _stored(pool_v), jnp.int32(1), tables, lens, wpages=PD_WPAGES,
         interpret=True, pages_per_block=pages_per_block,
     )
     valid = jnp.arange(PD_WINDOW)[None, :] < lens[:, None]
-    o, m, z = M.masked_attention_source(
-        q, M.gather_window_paged(pool_k[1], tables, PD_WPAGES),
-        M.gather_window_paged(pool_v[1], tables, PD_WPAGES), valid,
-    )
+
+    def window(side):  # [N, K, page, hd] -> [B, K, wp * page, hd]
+        rows = jnp.moveaxis(side[tables[:, :PD_WPAGES]], 2, 1)
+        return rows.reshape(*rows.shape[:2], PD_WINDOW, -1)
+
+    o, m, z = M.masked_attention_source(q, window(pool_k[1]), window(pool_v[1]), valid)
     return got, (o, m[..., 0], z[..., 0])
 
 
@@ -229,42 +244,73 @@ class TestPagedDecodeKernelCorners:
         assert paged_decode_in_place_ok(head_dim, page, dtype) is ok
 
     @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_a_view_made_by_the_caller_is_read_as_it_lies(self, widths):
-        """The engine makes ``lane_dense_pool`` once a dispatch and hands it
-        down: the kernel's result is bit for bit that of the pool as it
-        lies.  For whole lane tiles, and outside the shape rule, the view
-        IS the pool: the same array, no operation."""
+    def test_the_kernel_on_the_stored_pool_is_the_xla_read_of_it(self, widths):
+        """``make_page_pool`` stores a head narrower than a lane tile ``f``
+        positions a row (f = 1, 2, 2, 4 at these widths), the same numbers
+        in the same order; the kernel copies a page's stored rows whole and
+        ``gather_window_paged`` takes its gathered rows apart: one pool,
+        two readers, one result.  Nothing of the pool is relaid by either:
+        the kernel's operand IS the stored array."""
         import jax.numpy as jnp
         import numpy as np
 
         from calfkit_tpu.inference import pallas_attention as PA
 
+        K, G, hd = PD_WIDTHS[widths]
+        f = {128: 1, 64: 2, 32: 4}[hd]
+        assert M.positions_per_row(hd, PD_PAGE, jnp.bfloat16) == f
+        config = replace(preset("debug"), d_model=K * G * hd, n_heads=K * G, n_kv_heads=K,
+                         dtype="bfloat16")
+        made = M.make_page_pool(config, 5, PD_PAGE)
+        assert all(side.shape == (config.n_layers, 5, K, PD_PAGE // f, f * hd) for side in made)
+        # what the engine's start-up line says of it: each side's stored shape and f
+        from calfkit_tpu.inference.engine import _stored_layout
+
+        side = f"bfloat16[{config.n_layers}, 5, {K}, {PD_PAGE // f}, {f * hd}] f={f}"
+        assert _stored_layout(config, *made) == f"K {side}, V {side}"
+
         q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
             "mixed", jnp.bfloat16, widths=widths
         )
-        pool_k = jnp.asarray(pool_k, jnp.bfloat16)
-        pool_v = jnp.asarray(pool_v, jnp.bfloat16)
-        view_k, view_v = PA.lane_dense_pool(pool_k), PA.lane_dense_pool(pool_v)
-        hd = pool_k.shape[-1]
-        if hd % 128 == 0:
-            assert view_k is pool_k and view_v is pool_v
-        else:
-            f = PA.paged_decode_lane_pack(hd)
-            assert view_k.shape == (*pool_k.shape[:3], PD_PAGE // f, 128)
-            # row r of a page: positions f * r .. f * r + f - 1 side by side
-            np.testing.assert_array_equal(
-                np.asarray(view_k[1, 2, 3, 5], np.float32),
-                np.asarray(pool_k[1, 2, 3, 5 * f:(5 + 1) * f], np.float32).ravel(),
-            )
-        kw = dict(wpages=PD_WPAGES, interpret=True)
+        pool_k, pool_v = (jnp.asarray(side, jnp.bfloat16) for side in (pool_k, pool_v))
+        stored_k, stored_v = _stored(pool_k), _stored(pool_v)
+        # row r of a stored page: positions f * r .. f * r + f - 1 side by side
+        np.testing.assert_array_equal(
+            np.asarray(stored_k[1, 2, 3, 5], np.float32),
+            np.asarray(pool_k[1, 2, 3, 5 * f:(5 + 1) * f], np.float32).ravel(),
+        )
+        # the XLA read of the stored pool is plain indexing of the declared one
+        gathered = M.gather_window_paged(stored_k[1], tables, PD_WPAGES, hd)
+        rows = jnp.moveaxis(pool_k[1][tables[:, :PD_WPAGES]], 2, 1)
+        np.testing.assert_array_equal(
+            np.asarray(gathered, np.float32),
+            np.asarray(rows.reshape(*rows.shape[:2], PD_WINDOW, hd), np.float32))
+        # and the kernel's read of it is the XLA read's, at bf16's 8 bits
         got = PA.paged_decode_attention_pallas(
-            q, view_k, view_v, jnp.int32(1), tables, lens, **kw)
-        want = PA.paged_decode_attention_pallas(
-            q, pool_k, pool_v, jnp.int32(1), tables, lens, **kw)
-        for g, w in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-        small = jnp.zeros((1, 3, 2, 16, 64), jnp.bfloat16)  # outside the rule
-        assert PA.lane_dense_pool(small) is small
+            q, stored_k, stored_v, jnp.int32(1), tables, lens, wpages=PD_WPAGES, interpret=True)
+        valid = jnp.arange(PD_WINDOW)[None, :] < lens[:, None]
+        o, m, z = M.masked_attention_source(
+            q, gathered, M.gather_window_paged(stored_v[1], tables, PD_WPAGES, hd), valid)
+        norm = lambda o, z: np.asarray(o / jnp.maximum(z[..., None], 1e-30))
+        np.testing.assert_allclose(norm(got[0], got[2]), norm(o, z[..., 0]), atol=2e-2)
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(m[..., 0]), rtol=1e-5)
+
+    def test_a_pool_outside_the_rule_is_stored_as_declared(self):
+        """A page the packing would leave a partial sublane tile (a bf16 page
+        of 16 at a head of 64: 8 rows), a head that does not divide a lane
+        tile, and a latent pool's two parts: ``f`` = 1, the pool as declared."""
+        import jax.numpy as jnp
+
+        assert M.positions_per_row(64, 16, jnp.bfloat16) == 1
+        assert M.positions_per_row(64, 16, jnp.float32) == 2
+        assert M.positions_per_row(96, 64, jnp.bfloat16) == 1
+        assert M.positions_per_row(256, 64, jnp.bfloat16) == 1
+        assert M.positions_per_row(32, 64, jnp.float8_e4m3fn) == 1  # 16 rows under a tile of 32
+        assert M.positions_per_row(32, 128, jnp.float8_e4m3fn) == 4
+        small = M.make_page_pool(preset("debug"), 3, 16)  # heads of 16 on a page of 16
+        assert small[0].shape == (2, 3, 2, 16, 16)
+        latent = M.make_page_pool(preset("kimi-vl-a3b-instruct"), 3, 64)  # the rope side: 64 wide
+        assert [side.shape[2:] for side in latent] == [(1, 64, 512), (1, 64, 64)]
 
     @pytest.mark.parametrize(
         "head_dim,page,dtype",
@@ -272,11 +318,14 @@ class TestPagedDecodeKernelCorners:
             (64, 8, "float32"),  # a packed page of 4 rows, under a sublane tile
             (96, 64, "bfloat16"),  # a head that neither is nor divides a lane tile
             (128, 8, "bfloat16"),  # half a packed sublane tile
+            (64, 64, "bfloat16"),  # a pool handed over as DECLARED where it is stored packed
         ],
     )
     def test_other_shapes_are_refused(self, head_dim, page, dtype):
         """Outside the shape rule there is no kernel, and a direct call
-        says so while it is traced: nothing is built, nothing else runs."""
+        says so while it is traced: nothing is built, nothing else runs.
+        Nor is a pool relaid for the kernel: one that does not come in its
+        stored form is refused."""
         import jax.numpy as jnp
 
         from calfkit_tpu.inference import pallas_attention as PA
@@ -470,3 +519,69 @@ async def test_only_the_paged_decode_read_builds_a_kernel(wide_params, program):
         {("paged_decode", "interpreted")} if decodes else set()
     )
     assert ran(xla) and ran(pal), f"{program} never ran"
+
+
+# --------------------------------------------------------------------------- #
+# the pool's stored form (PR 49): a head narrower than a lane tile is stored
+# f positions a row, and no request can tell
+# --------------------------------------------------------------------------- #
+
+# heads -> (the debug preset at that head, page, f): float32 pages of 8 stored rows
+PACKED = {
+    64: (_debug_config(name="debug-64", dtype="float32", **HEADS[64]), 16, 2),
+    32: (_debug_config(name="debug-32", dtype="float32", d_model=128, n_heads=4, n_kv_heads=2),
+         32, 4),
+}
+
+
+@pytest.fixture(scope="module")
+def packed_params():
+    return {hd: M.init_params(cfg, jax.random.key(2), dtype=jnp.float32)
+            for hd, (cfg, _, _) in PACKED.items()}
+
+
+@pytest.mark.parametrize("hd,program", [
+    *[(64, program) for program in sorted(PROGRAMS)],
+    (32, "ragged-dispatch-with-chunk"), (32, "prefix-cache-hit"), (32, "ngram-speculation"),
+])
+async def test_a_stored_pool_serves_what_the_declared_pool_served(
+        monkeypatch, packed_params, hd, program):
+    """Every program that reads or writes the pool (decode dispatches, ragged
+    dispatches, the wave's landing, the prefix cache's seed, verify), on a
+    pool stored ``f`` positions a row: token for token what the SAME engine
+    serves with the rule switched off (``f`` = 1: the pool as declared, the
+    parent's), through the XLA read and through the kernel in interpret
+    mode on the stored pool."""
+    config, page, f = PACKED[hd]
+    over, jobs, ran = PROGRAMS[program]
+    over = {**over, "page_size": page, "prefill_chunk": 32}
+
+    async def serve(impl):
+        rt = RuntimeConfig(**{
+            "max_batch_size": 4, "max_seq_len": 128, "decode_steps_per_dispatch": 4,
+            "kv_layout": "paged", "chunked_prefill": True, "attention_impl": impl,
+            **{k: v for k, v in over.items() if k != "sequential"}})
+        engine = InferenceEngine(config, rt, params=packed_params[hd])
+
+        async def one(prompt, n, kw):
+            return [t async for t in engine.generate(prompt, max_new_tokens=n, **kw)]
+
+        await engine.start()
+        try:
+            if over.get("sequential"):
+                return [await one(*job) for job in jobs], engine
+            return await asyncio.gather(*[one(*job) for job in jobs]), engine
+        finally:
+            await engine.stop()
+
+    with monkeypatch.context() as declared:
+        declared.setattr(M, "positions_per_row", lambda width, page, dtype: 1)
+        want, parent = await serve("xla")
+    assert parent._k.shape[3:] == (page, hd)
+    got, xla = await serve("xla")
+    assert xla._k.shape[3:] == (page // f, f * hd) == (8, 128)
+    assert got == want
+    PA.paged_decode_attention_pallas.clear_cache()
+    kernel, pal = await serve("pallas_interpret")
+    assert pal._attn_impl == "pallas_interpret" and kernel == want
+    assert ran(parent) and ran(xla) and ran(pal), f"{program} never ran"

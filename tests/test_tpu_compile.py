@@ -34,7 +34,7 @@ PAGE = 64
 DECODE_PAGED_WIDTHS = {
     "mistral-7b-v0.3": (8, 4, 128, 32, 2048, 513, 32),
     "internlm2-1.8b": (8, 2, 128, 64, 4096, 897, 24),
-    # heads of 64: two positions a lane row (pallas_attention.lane_dense_pool)
+    # heads of 64: stored two positions a row (model.positions_per_row)
     "granite-4.0-h-micro": (8, 4, 64, 64, 2048, 1281, 4),
     "tinyllama-1.1b": (4, 8, 64, 64, 1024, 257, 2),
     # a head of 256 (two lane tiles) with 8 query heads a KV head: the two
@@ -94,10 +94,14 @@ def test_paged_decode_in_place_compiles_for_v5e(
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
+    from calfkit_tpu.inference.model import positions_per_row
+
     K, G, hd, rows, window, pages, layers = DECODE_PAGED_WIDTHS[widths]
     assert PA.paged_decode_in_place_ok(hd, PAGE, jnp.bfloat16)
     bf16, i32 = jnp.bfloat16, jnp.int32
-    pool = (shape((layers, pages, K, PAGE, hd), bf16),) * 2
+    f = positions_per_row(hd, PAGE, bf16)  # the pool as make_page_pool stores it
+    assert f == max(1, 128 // hd)
+    pool = (shape((layers, pages, K, PAGE // f, f * hd), bf16),) * 2
     ring = (shape((8, rows, K, hd), bf16),) * 2
     before = PA.KERNEL_TRACES["paged_decode", "compiled"]
     compiled = jax.jit(
@@ -257,11 +261,9 @@ def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
 ):
     """The decode DISPATCH program (``_decode_fn_paged``, 8 steps) of a toy
     hybrid at heads of 64, compiled for the described v5e: the kernel is in
-    it, no ``gather_window`` scope is, and the lane-dense view of the pool
-    is made at most once a side, in the entry computation: outside the
-    step loop and the layer scan, where the pool is a constant."""
-    import re
-
+    it, no ``gather_window`` scope is, and the pool, STORED two positions a
+    row, is the kernel's operand as it lies: no array of a side's size is
+    copied, reshaped or transposed anywhere in the program."""
     import jax
 
     from calfkit_tpu.inference.config import ModelConfig, RuntimeConfig
@@ -293,15 +295,9 @@ def test_decode_dispatch_of_narrow_heads_gathers_no_window_on_v5e(
     ).lower(*abstract).compile().as_text()
     assert "tpu_custom_call" in hlo and "paged_decode_attention" in hlo
     assert "gather_window" not in hlo
-    L, N, K, _, hd = engine._k.shape
-    view = re.compile(rf"= bf16\[{L},{N},{K},{page * hd // 128},128\]\S* (\w[\w\-]*)\(")
-    made = {
-        name: [m.group(1) for m in map(view.search, lines)
-               if m and m.group(1) not in ("bitcast", "parameter", "get-tuple-element")]
-        for name, lines in _computations(hlo).items()
-    }
-    assert 1 <= len(made.pop("ENTRY")) <= 2  # K and V, once a dispatch
-    assert not any(made.values()), made  # never in a loop's body
+    assert engine._k.shape[3:] == (page // 2, 128)  # stored two positions a row
+    copies, scatters = _whole_side_copies(hlo, engine._k, ("copy", "copy-start", "reshape", "transpose"))
+    assert not copies and not scatters, (copies, scatters)
 
 
 # a benchmark cell's configuration -> (layers kept, experts held): the depth cut
@@ -436,12 +432,13 @@ def test_cell_dispatch_program_holds_its_kernels_on_v5e(
             assert memory.temp_size_in_bytes < 2 * state_bytes // engine.config.n_mamba_layers
 
 
-def _whole_side_copies(hlo: str, side) -> tuple[list[str], list[str]]:
+def _whole_side_copies(hlo: str, side, kinds=("copy", "copy-start")) -> tuple[list[str], list[str]]:
     """(copies, scatters) of a compiled program over an array of one pool
     side's SIZE, in whatever shape and layout (the scatter's operand had the
-    page offset above the KV heads; the kernel's view of a head of 64 folds
-    two positions a row): the ``copy`` / ``copy-start`` operations outside
-    fusions, and every ``scatter``."""
+    page offset above the KV heads; through PR 47 the kernel's view of a
+    head of 64 folded two positions a row): the ``copy`` / ``copy-start``
+    operations outside fusions (or the ``kinds`` asked for), and every
+    ``scatter``."""
     import re
     from math import prod
 
@@ -452,20 +449,88 @@ def _whole_side_copies(hlo: str, side) -> tuple[list[str], list[str]]:
             m = made.search(line)
             if not m or prod(map(int, m.group(2).split(","))) != side.size:
                 continue
-            if m.group(3) in ("copy", "copy-start") and "fused_computation" not in name:
+            if m.group(3) in kinds and "fused_computation" not in name:
                 copies.append(line.strip()[:120])
             elif m.group(3) == "scatter":
                 scatters.append(line.strip()[:120])
     return copies, scatters
 
 
-# The whole-side copies a dispatch program makes of a pool side narrower than
-# a lane tile (PR 46's count, and the parent's: two into the kernel's view,
-# lane_dense_pool or latent_rope_view, one into the layout the write's loop
-# runs on and one back, a side).  The pool STORED lane-dense ends them
-# (ROADMAP S10): this pins what it has to remove.
-_NARROW_SIDE_COPIES_AT_PR_46 = {
-    "granite-4.0-h-micro": 6, "lfm2-8b-a1b": 6, "kimi-vl-a3b-instruct": 4}
+# The whole-side copies a dispatch program still makes of a pool side.  A K/V
+# side of heads of 64 is STORED two positions a row since PR 49
+# (model.positions_per_row): the kernel and the write's loop take it as it lies,
+# and the six copies a dispatch PR 46 counted in granite's and LFM2's programs
+# are gone.  What stays is a LATENT pool's rope side, 64 wide and stored as
+# declared: two copies into the kernel's view (latent_rope_view, another
+# arrangement), one into the layout the write's loop runs on and one back
+# (ROADMAP S10, the part left open).
+_WHOLE_SIDE_COPIES_LEFT = {
+    "granite-4.0-h-micro": 0, "lfm2-8b-a1b": 0, "kimi-vl-a3b-instruct": 4}
+# LFM2's decode program held two row-major copies of a 0.83 GB side through
+# PR 47 (1.675 GB of temporaries at the cell's full size; 0.85 at this cut)
+_LFM2_DECODE_TEMPORARIES_GB = 0.2
+
+
+def _finalize_program(engine, sharding, rows=2):
+    """(jitted fn, abstract args, abstract keyword args) of the wave's
+    landing, ``jit_finalize``: a two-row wave of two chunks goes into the
+    pool page by page (``write_prefill_pages``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference.mamba import make_recurrent_state
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+    rt, cfg = engine.runtime, engine.config
+    bucket = 2 * rt.prefill_chunk
+    i32, f32 = jnp.int32, jnp.float32
+    S = jax.ShapeDtypeStruct
+    scratch = S((cfg.n_kv_layers, rows, cfg.cache_heads, bucket, cfg.head_dim), engine._k.dtype)
+    args = [
+        engine._k, engine._v, scratch, scratch, engine._last, engine._lens,
+        S((rows,), i32), S((rows,), i32), S((rows, rt.prefill_chunk, cfg.vocab_size), f32),
+        engine._slot_keys, engine._temp, engine._top_k, engine._top_p,
+        S((rows,), jnp.uint32), S((rows,), f32), S((rows,), i32), S((rows,), f32),
+        engine._tables, S((rows, rt.pages_per_seq()), i32), S((rows, bucket // rt.page_size), i32),
+    ]
+    kw = {}
+    if engine._recurrent:
+        kw = {"state": engine._state,
+              "wstate": jax.eval_shape(lambda: make_recurrent_state(cfg, rows))}
+    return engine._finalize_jit(bucket, rows, False), abstract(args), abstract(kw)
+
+
+def test_a_pool_of_whole_lane_tiles_lowers_to_the_program_it_was(cell_engine, one_chip, monkeypatch):
+    """The decode dispatch of a cell whose heads are whole lane tiles
+    (Mistral's, 128) is outside the stored form's reach: ``positions_per_row``
+    returns 1, the pool is the declared one, and the program lowers to the
+    same text with the rule consulted as with a rule that can only say 1."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import model as M
+
+    engine = cell_engine("mistral-7b-v0.3-int8")
+    cfg, page = engine.config, engine.runtime.page_size
+    assert M.positions_per_row(cfg.head_dim, page, engine._k.dtype) == 1
+    assert engine._k.shape[3:] == (page, cfg.head_dim)
+
+    def lowered():
+        jax.clear_caches()  # trace anew: the rule is consulted while the kernel's call is traced
+        args, window, steps, sampled = engine._decode_args()
+        abstract = lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+        return engine._decode_jit(window, steps, sampled).lower(*abstract(args)).as_text()
+
+    with_the_rule = lowered()
+    consulted = []
+    monkeypatch.setattr(M, "positions_per_row", lambda *a: consulted.append(a) or 1)
+    assert lowered() == with_the_rule
+    assert (cfg.head_dim, page, jnp.dtype(engine._k.dtype)) in [
+        (w, p, jnp.dtype(d)) for w, p, d in consulted]
 
 
 @pytest.mark.parametrize(
@@ -477,7 +542,11 @@ _NARROW_SIDE_COPIES_AT_PR_46 = {
         ("kimi-vl-a3b-instruct", "decode"),
         ("qwen3-next-80b-a3b-instruct", "decode"),
         ("granite-4.0-h-micro", "decode"),
+        ("granite-4.0-h-micro", "ragged"),
+        ("granite-4.0-h-micro", "finalize"),
         ("lfm2-8b-a1b", "decode"),
+        ("lfm2-8b-a1b", "ragged"),
+        ("lfm2-8b-a1b", "finalize"),
     ],
 )
 def test_cell_dispatch_program_writes_its_tokens_in_place_on_v5e(
@@ -486,17 +555,24 @@ def test_cell_dispatch_program_writes_its_tokens_in_place_on_v5e(
     """A dispatch program of a benchmark cell, compiled for the described
     v5e, ends in ``consolidate_ring_paged``'s loop of window updates on the
     donated pool: NO scatter over a pool side, the pools go out where they
-    came in, and no side 128 numbers wide or wider (heads of 128 and 256,
-    Kimi's 512-wide ``c`` side, both kinds of command-a-plus's pools) is
-    copied whole: through PR 45 the scatter cost each such side a copy into a
-    layout with the page offset above the KV heads and one back, every
-    dispatch (PERF.md section 6, PR 46).  A side of heads of 64 (and Kimi's
-    rope side) is still held another way by the device than by the loop and
-    the kernel: not more copies than PR 46 counted."""
+    came in, and no side whose STORED rows are 128 numbers wide or wider
+    (heads of 128 and 256, Kimi's 512-wide ``c`` side, both kinds of
+    command-a-plus's pools and, since PR 49, the heads of 64 of granite and
+    LFM2, stored two positions a row) is copied whole: through PR 45 the
+    scatter cost each such side a copy into a layout with the page offset
+    above the KV heads and one back, every dispatch, and through PR 47 a
+    side of heads of 64 kept six copies a dispatch (PERF.md section 6, PRs
+    46 and 49).  Kimi's rope side, stored as declared, is still held another
+    way by the device than by the loop and the kernel: not more copies than
+    ``_WHOLE_SIDE_COPIES_LEFT`` records.  The wave's landing (``finalize``:
+    ``write_prefill_pages``, a page-granular set) copies no side either."""
     import jax
 
     engine = cell_engine(cell)
-    if program == "ragged":
+    if program == "finalize":
+        fn, args, kw = _finalize_program(engine, one_chip)
+        compiled = fn.lower(*args, **kw).compile()
+    elif program == "ragged":
         fn, args = _dispatch_programs(engine, one_chip)[program]
         compiled = fn.lower(*args).compile()
     else:
@@ -511,18 +587,101 @@ def test_cell_dispatch_program_writes_its_tokens_in_place_on_v5e(
         compiled = engine._decode_jit(window, steps, sampled).lower(
             *abstract(args), **abstract(carried)).compile()
     hlo = compiled.as_text()
-    assert "/kv_write/while/body" in hlo
+    assert ("/kv_write/" in hlo) if program == "finalize" else ("/kv_write/while/body" in hlo)
     sides = jax.tree.leaves((engine._k, engine._v))
-    assert compiled.memory_analysis().alias_size_in_bytes >= sum(side.nbytes for side in sides)
-    narrow = 0
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(side.nbytes for side in sides)
+    left = 0
     for side in {side.shape: side for side in sides}.values():
         copies, scatters = _whole_side_copies(hlo, side)
-        assert not scatters, scatters
+        # the landing's page-granular set IS a scatter, in place on the donated side
+        assert program == "finalize" or not scatters, scatters
         if side.shape[-1] >= 128:
             assert not copies, (side.shape, copies)
-        narrow += len(copies)
-    assert narrow <= _NARROW_SIDE_COPIES_AT_PR_46.get(cell, 0), (cell, narrow)
-    print("whole-side copies of narrow sides:", cell, program, narrow)
+        left += len(copies)
+    assert left <= _WHOLE_SIDE_COPIES_LEFT.get(cell, 0), (cell, left)
+    if (cell, program) == ("lfm2-8b-a1b", "decode"):
+        assert memory.temp_size_in_bytes < _LFM2_DECODE_TEMPORARIES_GB * 1e9
+    print("whole-side copies left:", cell, program, left,
+          "temporaries GB:", round(memory.temp_size_in_bytes / 1e9, 3))
+
+
+# the write ALONE at a pool's full shape: (L, N, K, page, head, rows, T, table entries)
+WRITE_ALONE_SHAPES = {
+    "lfm2-8b-a1b": (3, 4225, 8, 64, 64, 128, 8, 33),
+    "granite-4.0-h-micro": (4, 1281, 8, 64, 64, 64, 8, 32),
+    "heads-of-32": (3, 4225, 8, 64, 32, 128, 8, 33),
+    "mistral-7b-v0.3-int8": (32, 513, 8, 64, 128, 32, 8, 32),
+    "command-a-plus-05-2026-window": (3, 2113, 8, 64, 128, 32, 4, 66),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(WRITE_ALONE_SHAPES))
+def test_the_write_alone_keeps_a_stored_side_in_place_on_v5e(shape, one_chip, no_persistent_cache):
+    """``consolidate_ring_paged`` alone on a donated pool at a cell's FULL
+    pool shape (every attention layer: the cut engines above keep one of
+    granite's four), compiled for the described v5e: no operation makes an
+    array of a side's size but the loop's own updates in place, and the
+    program's temporaries are a window's, not a side's.  At heads of 64 and
+    32 that holds for windows of whole groups of 8 stored rows
+    (``model._write_windows``): a window of 5 rows made the compiler relay
+    the side into a layout of its own and back (PERF.md section 6, PR 49)."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import model as M
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, N, K, page, hd, B, T, entries = WRITE_ALONE_SHAPES[shape]
+    f = M.positions_per_row(hd, page, jnp.bfloat16)
+    assert f == 128 // min(hd, 128)
+    pool = (S((L, N, K, page // f, f * hd), jnp.bfloat16),) * 2
+    ring = (S((L, T, B, K, hd), jnp.bfloat16),) * 2
+    compiled = jax.jit(M.consolidate_ring_paged, donate_argnums=0).lower(
+        pool, ring, S((B, entries), jnp.int32), S((B,), jnp.int32), S((B,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    copies, scatters = _whole_side_copies(hlo, pool[0], ("copy", "copy-start", "transpose", "reshape"))
+    assert not copies and not scatters, (copies, scatters)
+    assert "/kv_write/while/body" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("shape", ["lfm2-8b-a1b", "granite-4.0-h-micro", "heads-of-32"])
+def test_the_landing_alone_sets_its_pages_in_a_stored_side_on_v5e(shape, one_chip, no_persistent_cache):
+    """``write_prefill_pages`` alone (what ``jit_finalize`` does to the pool)
+    on a donated pool at a cell's FULL pool shape, a wave of four rows of
+    1,024 tokens, compiled for the described v5e: the wave's scratch is
+    packed into stored rows, never the pool, so nothing copies, reshapes or
+    transposes an array of a side's size, the pools go out where they came in
+    and the program's temporaries are the wave's, not a side's (through PR 47
+    each side of heads of 64 was copied in and out around the set: 1.66 GB of
+    temporaries at LFM2's shape, 19 ms a landing in its cell).  What sets the
+    pages is ONE scatter a side over the PAGE axis, in place on the donated
+    side in its stored layout."""
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference import model as M
+
+    def S(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, N, K, page, hd, _, _, _ = WRITE_ALONE_SHAPES[shape]
+    f = M.positions_per_row(hd, page, jnp.bfloat16)
+    rows, tokens = 4, 1024
+    pool = (S((L, N, K, page // f, f * hd), jnp.bfloat16),) * 2
+    scratch = (S((L, rows, K, tokens, hd), jnp.bfloat16),) * 2
+    compiled = jax.jit(M.write_prefill_pages, donate_argnums=0).lower(
+        pool, scratch, S((rows, tokens // page), jnp.int32)).compile()
+    copies, scatters = _whole_side_copies(
+        compiled.as_text(), pool[0], ("copy", "copy-start", "transpose", "reshape"))
+    assert not copies, copies
+    assert len(scatters) == 2 and all("{4,3,2,1,0" in line for line in scatters), scatters
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * L * N * K * page * hd * 2
+    assert memory.temp_size_in_bytes < 4e6
 
 
 @pytest.mark.parametrize("ssm_impl", ["xla", "pallas"])
