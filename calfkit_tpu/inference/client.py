@@ -689,6 +689,21 @@ class JaxLocalModelClient(ModelClient):
                 emitter=gen_span.emitter,
             )
 
+        # the stream's stage account: the engine's side books each block it
+        # hands over (its dispatch's landing, the wait since), this side the
+        # seconds making a text delta (``emit``) and the seconds suspended in
+        # the consumer at the yield (``backpressure``), one clock read an
+        # event a stage.  The totals go to the engine's ``stream_*`` counters
+        # whether or not anyone traces; the request's own are what its
+        # ``engine.decode`` span ends with
+        import jax
+
+        from calfkit_tpu.inference.engine import EMIT, StreamAccount
+
+        account = StreamAccount()
+        stats = self._engine.stats
+        events = 0
+        emit_s = backpressure_s = wait_before_s = 0.0
         started = time.perf_counter()
         generated: list[int] = []
         # a resumed stream's deltas begin past the already-delivered
@@ -730,6 +745,7 @@ class JaxLocalModelClient(ModelClient):
             # the queue wait is measured where it happens: the engine ends
             # an engine.queue span under this request's prefill span
             trace=prefill_span.context if prefill_span is not None else None,
+            account=account,
         )
         stream_exc: BaseException | None = None
         try:
@@ -738,7 +754,9 @@ class JaxLocalModelClient(ModelClient):
                 if len(generated) == 1:
                     # the first token IS the TTFT moment — right after
                     # prefill; the decode phase starts here
-                    ttft_ms = (time.perf_counter() - started) * 1000.0
+                    decode_started = time.perf_counter()
+                    ttft_ms = (decode_started - started) * 1000.0
+                    wait_before_s = account.block_wait_s  # the first block's: prefill
                     if prefill_span is not None:
                         prefill_span.end(ttft_ms=round(ttft_ms, 3))
                         prefill_span = None
@@ -748,6 +766,7 @@ class JaxLocalModelClient(ModelClient):
                             # the dispatches that carried this request's
                             # decode are those numbered past this one
                             attrs={"first_seq": getattr(self._engine, "proved_seq", 0)},
+                            at=decode_started,
                         )
                 # the first token is emitted immediately; later ones batch
                 # on the re-decode cadence
@@ -757,14 +776,21 @@ class JaxLocalModelClient(ModelClient):
                 # replacement char may be a multi-byte sequence completing
                 # (resume: the full text includes the prefilled prefix so
                 # stop sequences spanning the resume boundary still cut)
-                text = tokenizer.decode(resume_tokens + generated).rstrip("�")
-                if stops:
-                    stopped_at = first_stop(text)
-                    if stopped_at != -1:
-                        break
-                    text = text[: len(text) - holdback] if holdback else text
-                if len(text) > emitted:
-                    yield TextDelta(text[emitted:])
+                emit_at = time.perf_counter()
+                with jax.profiler.TraceAnnotation(EMIT):
+                    text = tokenizer.decode(resume_tokens + generated).rstrip("�")
+                    if stops:
+                        stopped_at = first_stop(text)
+                        if stopped_at != -1:
+                            break
+                        text = text[: len(text) - holdback] if holdback else text
+                    delta = TextDelta(text[emitted:]) if len(text) > emitted else None
+                yield_at = time.perf_counter()
+                emit_s += yield_at - emit_at
+                if delta is not None:
+                    yield delta
+                    events += 1
+                    backpressure_s += time.perf_counter() - yield_at
                     emitted = len(text)
         except BaseException as exc:
             # captured locally, NOT via sys.exc_info() in the finally:
@@ -793,10 +819,25 @@ class JaxLocalModelClient(ModelClient):
             )
             if prefill_span is not None:  # zero tokens: no decode phase
                 prefill_span.end(status=status)
+            stats.stream_events += events
+            stats.stream_emit_s += emit_s
+            stats.stream_backpressure_s += backpressure_s
             if decode_span is not None:
+                # the three stages are the span's children in all but name:
+                # its duration less them is what the per-token iteration
+                # costs.  The landings are offsets from the span's start (the
+                # first block landed BEFORE its first token was consumed)
                 decode_span.end(
                     status=status, at=ended, generated_tokens=len(generated),
                     last_seq=last_seq,
+                    blocks=account.blocks, events=events,
+                    first_landed_ms=round((account.first_landed - decode_started) * 1e3, 3),
+                    last_landed_ms=round((account.last_landed - decode_started) * 1e3, 3),
+                    deliver_wait_ms=round(account.deliver_wait_s * 1e3, 3),
+                    deliver_wait_max_ms=round(account.deliver_wait_max_s * 1e3, 3),
+                    block_wait_ms=round((account.block_wait_s - wait_before_s) * 1e3, 3),
+                    emit_ms=round(emit_s * 1e3, 3),
+                    backpressure_ms=round(backpressure_s * 1e3, 3),
                 )
             if gen_span is not None:
                 gen_span.end(

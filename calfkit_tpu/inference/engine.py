@@ -105,12 +105,41 @@ _SECONDS_HELP = {
     "empty_slot_queued_s": "slot-seconds: free slots while a request was queued",
     "program_build_s": "calls in which JAX built a program (a jit key's first use, or "
                        "arguments of another kind under it): their seconds",
+    "stream_deliver_wait_s": "token stream: from a dispatch's landing to the request's "
+                             "consumer taking its block (the loop's turn), summed over blocks",
+    "stream_emit_s": "token stream: detokenize, stop search and the delta's making, "
+                     "summed over events",
+    "stream_backpressure_s": "token stream: the stream suspended in its consumer (the "
+                             "node's step, the publish, its acknowledgement), summed over events",
+    "loop_stall_s": "event loop: lateness of the engine's 20 ms heartbeat, where it "
+                    "passed 20 ms (something held the loop)",
+    "phase_long_s": "dispatch loop: the whole seconds of phases that outlasted what the "
+                    "two-deep device queue hides (a host phase one dispatch's wall time, "
+                    "a sync four)",
 }
 _SECONDS_FIELDS = tuple(_SECONDS_HELP)
-PHASES = tuple(f for f in _SECONDS_FIELDS if f.startswith("phase_"))
+PHASES = tuple(f for f in _SECONDS_FIELDS if f.startswith("phase_") and f != "phase_long_s")
 REAP, ADMIT, HANDOFF, PREP, ENQUEUE, SYNC, FANOUT, IDLE = PHASES
 # "phase_reap_s" -> "engine.reap": the host annotation on the profiler's clock
 _PHASE_ANNOTATION = {p: "engine." + p[len("phase_"):-len("_s")] for p in PHASES}
+# the event loop's work between a dispatch's landing and the stream's
+# consumer, on the same clock: synchronous stretches, none a phase's name
+DELIVER, EMIT = "engine.deliver", "engine.emit"
+# the engine's heartbeat on its loop: a beat that comes later than its own
+# period is a stall of the loop (``loop_stall_s``, EV_LOOP_STALL)
+HEARTBEAT_S = 0.020
+# a phase is LONG where it outlasts what the two-deep device queue hides: a
+# host phase ONE dispatch's wall time (``dispatch_ewma_ms``: while the host
+# works, the device has the program it runs and one behind it), a ``sync``
+# FOUR (it may wait for the program in front of its own too, and the EWMA is
+# of the MEAN dispatch where a dispatch of the longest kind, eight steps and a
+# chunk, is about twice that: at two, Mistral's cell read ten ordinary syncs
+# of 227-296 ms a window long, 5.2% of it, and Qwen3-Next's three, on a device
+# that never idled: PERF.md section 6, PR 52); ``idle`` never.  The floor
+# stands in for an unprimed EWMA and keeps a toy engine's millisecond
+# dispatches from reading every hiccup as a stall
+LONG_FLOOR_S = 0.100
+SYNC_DISPATCHES = 4.0
 BLOCKED = tuple(f for f in _SECONDS_FIELDS if f.startswith("blocked_"))
 NO_SLOT, NO_PAGES, WAVE_IN_FLIGHT, OVER_BUDGET = BLOCKED
 # a window stack's prefill chunks, by what their attention HAS to compute and
@@ -131,7 +160,8 @@ _LOCAL_FIELDS = (
     "programs_built", "moe_assignments", "moe_assignments_absent",
     "moe_rows_in_held_groups", "moe_expert_tokens_max",
     "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
-    "moe_dense_chunks", *_SECONDS_FIELDS,
+    "moe_dense_chunks", "stream_blocks", "stream_events", "loop_stalls",
+    "phase_longs", *_SECONDS_FIELDS,
 )
 _SYNCED_FIELDS = (
     "decode_tokens", "prefill_tokens", "spec_proposed", "spec_accepted",
@@ -161,6 +191,13 @@ def chunk_attention_of_all_engines() -> dict:
     return {
         f: sum(getattr(e.stats, f) for e in list(_ENGINES)) for f in CHUNK_ATTN_FIELDS
     }
+
+
+def restamp_all_engines() -> None:
+    """``EngineStats.restamp`` on every live engine of the process: what
+    ``devtrace.capture`` calls at its window's two edges."""
+    for e in list(_ENGINES):
+        e.stats.restamp()
 
 
 def programs_of_all_engines() -> "list[dict]":
@@ -351,6 +388,24 @@ def _engine_metrics(
             "the dispatch that carried their last chunk, a later dispatch "
             "already queued behind it: no drain",
         ),
+        stream_blocks=reg.counter(
+            "calfkit_engine_stream_blocks_total",
+            "token stream: blocks (one dispatch's tokens for one request) "
+            "taken by their consumers",
+        ),
+        stream_events=reg.counter(
+            "calfkit_engine_stream_events_total",
+            "token stream: text deltas handed to the stream's consumer",
+        ),
+        loop_stalls=reg.counter(
+            "calfkit_engine_loop_stalls_total",
+            "event loop: heartbeats of the engine's that came more than 20 ms late",
+        ),
+        phase_longs=reg.counter(
+            "calfkit_engine_phase_longs_total",
+            "dispatch loop: phases that outlasted what the device queue hides "
+            "(a host phase one dispatch's wall time, a sync four)",
+        ),
         programs_built=reg.counter(
             "calfkit_engine_programs_built_total",
             "calls in which JAX built a program: a jit key's first use, or "
@@ -426,15 +481,18 @@ def _engine_metrics(
 
 
 @hotpath
-def _deliver_batch(deliveries: "list[tuple[asyncio.Queue, list]]") -> None:
+def _deliver_batch(deliveries: "list[tuple[asyncio.Queue, tuple[float, list]]]") -> None:
     """Event-loop side of the batched cross-thread token fan-out.
 
     Each request's whole dispatch-worth of tokens lands as ONE queue item
-    (a list, possibly ending in _DONE): one consumer wakeup per dispatch
+    (a block: the moment its dispatch landed and the list of its tokens,
+    possibly ending in _DONE): one consumer wakeup per dispatch
     instead of one per token — at 32-step dispatches that is 32x less
-    event-loop churn on the serving hot path."""
-    for queue, items in deliveries:
-        queue.put_nowait(items)
+    event-loop churn on the serving hot path.  ``engine.deliver`` on the
+    profiler's clock: the loop's work, beside the tick's phases."""
+    with jax.profiler.TraceAnnotation(DELIVER):
+        for queue, block in deliveries:
+            queue.put_nowait(block)
 
 
 class _Program:
@@ -681,6 +739,46 @@ class GenRequest:
     # cleared at retirement so the heap stops pinning this object's
     # prompt/queue memory (r3 advisor finding)
     heap_entry: Any = None
+    # the caller's account of this request's stream (``generate``'s
+    # ``account``), booked a block by ``_consume``; None: the totals only
+    account: "StreamAccount | None" = None
+
+
+class StreamAccount:
+    """One request's blocks as its consumer took them: how many, the moments
+    the first and the last one's dispatch LANDED (``perf_counter``: the
+    engine's own time for the stream, free of every consumer), the wait
+    from a landing to the take (the loop's turn: the hop from the tick
+    thread, ``_deliver_batch``, the task's wake-up) and the time suspended
+    waiting for a block.  One ``perf_counter`` read a block a stage,
+    nothing a token."""
+
+    __slots__ = ("blocks", "first_landed", "last_landed", "deliver_wait_s",
+                 "deliver_wait_max_s", "block_wait_s")
+
+    def __init__(self) -> None:
+        self.blocks = 0
+        self.first_landed = self.last_landed = 0.0
+        self.deliver_wait_s = self.deliver_wait_max_s = self.block_wait_s = 0.0
+
+    @hotpath
+    def take(self, landed: float, waited: float, suspended: float) -> None:
+        if not self.blocks:
+            self.first_landed = landed
+        self.blocks += 1
+        self.last_landed = landed
+        self.deliver_wait_s += waited
+        if waited > self.deliver_wait_max_s:
+            self.deliver_wait_max_s = waited
+        self.block_wait_s += suspended
+
+
+def _annotation(phase: str, seq: "int | None") -> Any:
+    """The ``engine.<phase>`` annotation of the phase clock, ``seq`` riding
+    it as metadata where the phase has one."""
+    name = _PHASE_ANNOTATION[phase]
+    return (jax.profiler.TraceAnnotation(name) if seq is None
+            else jax.profiler.TraceAnnotation(name, seq=seq))
 
 
 @dataclass
@@ -799,6 +897,26 @@ class EngineStats:
     # is the table
     programs_built: int = 0
     program_build_s: float = 0.0
+    # a token's road from its dispatch's landing to the stream's consumer,
+    # process totals booked on the event loop where the work happens: blocks
+    # (one dispatch's tokens for one request) taken and their wait since the
+    # landing (``_consume``); text deltas handed over, the seconds making
+    # them and the seconds the stream stood suspended in its consumer
+    # (``JaxLocalModelClient.request_stream``, which holds the engine)
+    stream_blocks: int = 0
+    stream_events: int = 0
+    stream_deliver_wait_s: float = 0.0
+    stream_emit_s: float = 0.0
+    stream_backpressure_s: float = 0.0
+    # the loop's heartbeat (``_heartbeat``): beats that came more than their
+    # own period late, and their lateness: a stall with no sync in it
+    loop_stalls: int = 0
+    loop_stall_s: float = 0.0
+    # the tick's side of a stall (``enter``): phases other than ``idle`` that
+    # outlasted ``long_after`` and their WHOLE seconds, booked where the
+    # phase closes; ``counters()`` counts an open one up to now
+    phase_longs: int = 0
+    phase_long_s: float = 0.0
     # the admission-blocked ledger: seconds the head of the queue waited,
     # by what held it, and the free-slot integral while anyone queued
     blocked_slots_s: float = 0.0  # no free slot
@@ -875,9 +993,14 @@ class EngineStats:
     _window: Any = field(default=None, repr=False, compare=False)
     # the open intervals of the three clocks above, each ONE tuple so a
     # reader on another thread never sees a name without its start:
-    # (phase field, since, annotation), (blocked field, since),
+    # (phase field, since, annotation, seq), (blocked field, since),
     # (free slots, since)
     _phase: Any = field(default=None, repr=False, compare=False)
+    # ``enter`` (the one thread of control) against ``restamp`` (the loop's
+    # heartbeat): the least that keeps an annotation from ending twice
+    _switch: Any = field(default_factory=threading.Lock, repr=False, compare=False)
+    # the engine's flight recorder, for ``PHASE_LONG`` (None: not journalled)
+    journal: Any = field(default=None, repr=False, compare=False)
     _blocked: Any = field(default=None, repr=False, compare=False)
     _empty: Any = field(default=None, repr=False, compare=False)
 
@@ -930,26 +1053,79 @@ class EngineStats:
         number of the first program an ``enqueue`` is about to put on the
         device's queue, or of the program a ``sync`` waits for, which is
         what joins the host's clock to the device's module runs
-        (``devtrace.reduce_trace``)."""
+        (``devtrace.reduce_trace``).  A phase that closes LONG
+        (:meth:`long_after`) is booked whole (``phase_longs``,
+        ``phase_long_s``) and journalled (``PHASE_LONG``).  The lock is
+        for :meth:`restamp`, which the loop's heartbeat calls while the
+        tick thread may switch."""
         now = time.perf_counter()
-        prev = self._phase
-        if prev is not None:
-            name, since, annotation = prev
-            if name == phase:
-                return now
-            setattr(self, name, getattr(self, name) + (now - since))
-            annotation.__exit__(None, None, None)
-        if phase is None:
-            self._phase = None
-        else:
-            name = _PHASE_ANNOTATION[phase]
-            annotation = (
-                jax.profiler.TraceAnnotation(name) if seq is None
-                else jax.profiler.TraceAnnotation(name, seq=seq)
-            )
-            annotation.__enter__()
-            self._phase = (phase, now, annotation)
+        with self._switch:
+            prev = self._phase
+            if prev is not None:
+                name, since, annotation, began_seq = prev
+                if name == phase:
+                    return now
+                took = now - since
+                setattr(self, name, getattr(self, name) + took)
+                annotation.__exit__(None, None, None)
+                if self._is_long(name, took):
+                    self.phase_longs += 1
+                    self.phase_long_s += took
+                    if self.journal is not None:
+                        self.journal.append(
+                            flightrec.EV_PHASE_LONG, None, -1, int(took * 1000.0),
+                            -1 if began_seq is None else began_seq, _PHASE_ANNOTATION[name])
+            if phase is None:
+                self._phase = None
+            else:
+                annotation = _annotation(phase, seq)
+                annotation.__enter__()
+                self._phase = (phase, now, annotation, seq)
         return now
+
+    def long_after(self, phase: str) -> float:
+        """The seconds past which ``phase`` is long: what the two-deep
+        device queue hides, one dispatch's wall time for a host phase and
+        ``SYNC_DISPATCHES`` of them for a ``sync``, the dispatch's wall time
+        being ``dispatch_ewma_ms`` with ``LONG_FLOOR_S`` under it; ``idle``
+        is never long."""
+        if phase == IDLE:
+            return math.inf
+        bound = max(LONG_FLOOR_S, self.dispatch_ewma_ms / 1000.0)
+        return SYNC_DISPATCHES * bound if phase == SYNC else bound
+
+    def _is_long(self, phase: str, seconds: float) -> bool:
+        # (the floor first: one comparison on the ordinary path)
+        return seconds > LONG_FLOOR_S and seconds > self.long_after(phase)
+
+    def restamp(self) -> None:
+        """End the OPEN phase's annotation and begin another under the same
+        name and ``seq``, the phase's counter and its start untouched.  The
+        profiler records an annotation when it ENDS, and drops one whose end
+        finds no capture running: a phase still open when a capture stops
+        (the phase that holds a stall reaching the capture's end) is never
+        written.  After this call what lay before it is.  The engine's
+        heartbeat calls it for a phase older than :meth:`long_after`
+        (:meth:`restamp_if_long`), a capture before it stops the profiler;
+        ``devtrace.read_trace`` joins the pieces again."""
+        with self._switch:
+            prev = self._phase
+            if prev is not None:
+                phase, since, annotation, seq = prev
+                annotation.__exit__(None, None, None)
+                annotation = _annotation(phase, seq)
+                annotation.__enter__()
+                self._phase = (phase, since, annotation, seq)
+
+    def restamp_if_long(self, now: float) -> None:
+        """A beat of the engine's heartbeat: :meth:`restamp` where the open
+        phase is older than :meth:`long_after` gives it, so a phase of
+        ordinary length is never split.  (``idle`` is never long, but a
+        capture of an idle engine wants its name too: a host phase's bound.)"""
+        phase = self._phase
+        if phase is not None and self._is_long(
+                HANDOFF if phase[0] == IDLE else phase[0], now - phase[1]):
+            self.restamp()
 
     def note_blocked(
         self, reason: "str | None", free_slots: int, now: float,
@@ -1002,7 +1178,11 @@ class EngineStats:
         now = time.perf_counter()
         blocked, empty = self._blocked, self._empty
         if phase is not None:
-            out[phase[0]] += now - phase[1]
+            open_s = now - phase[1]
+            out[phase[0]] += open_s
+            if self._is_long(phase[0], open_s):
+                out["phase_longs"] += 1
+                out["phase_long_s"] += open_s
         if blocked is not None:
             out[blocked[0]] += now - blocked[1]
         if empty is not None:
@@ -1558,6 +1738,7 @@ class InferenceEngine:
         self._journal = flightrec.FlightRecorder(
             rt.flightrec_events, label=config.name
         )
+        self.stats.journal = self._journal  # ``PHASE_LONG``, from the phase clock
         # capacity observatory (ISSUE 19): the occupancy timeline ring —
         # one sample per dispatch landing, flightrec's ring discipline
         # (capacity_samples=0 makes append a single attribute check).
@@ -2436,6 +2617,7 @@ class InferenceEngine:
         lease: "tuple[str, float] | None" = None,
         priority: "str | None" = None,
         trace: "TraceContext | None" = None,
+        account: "StreamAccount | None" = None,
     ) -> AsyncIterator[int]:
         """Submit a prompt; yields generated token ids as they decode.
 
@@ -2477,6 +2659,11 @@ class InferenceEngine:
         engine records an ``engine.queue`` span, child of that context,
         from this submit to the moment the request is granted its slot
         (attrs ``blocked_on``, ``bucket``, ``wave_rows``).
+
+        ``account`` is the caller's :class:`StreamAccount`: the consumer's
+        side of the stream books each block it takes there (its dispatch's
+        landing, the wait since, the time suspended for it), for the caller
+        to end its own span with.  The ``stream_*`` totals count either way.
         """
         req_priority = qos.resolve_priority(priority)
         if not self._running:
@@ -2538,6 +2725,7 @@ class InferenceEngine:
             run=run,
             deadline=deadline,
             priority=req_priority,
+            account=account,
         )
         if lease is not None:
             request.lease_id, request.lease_ttl = lease
@@ -3203,8 +3391,10 @@ class InferenceEngine:
         """Drain a queued request's tokens; abandoning the iterator flags
         cancellation for the scheduler to reap (both lanes share this)."""
         done = False
+        stats, account = self.stats, request.account
         try:
             while True:
+                asked = time.perf_counter()
                 item = await request.out.get()
                 if queue_span is not None:
                     self._end_queue_span(queue_span, request)
@@ -3213,7 +3403,15 @@ class InferenceEngine:
                     done = True
                     self._raise_terminal(request)
                     return
-                if type(item) is list:  # one dispatch's token block
+                if type(item) is tuple:  # one dispatch's token block
+                    landed, item = item
+                    # (booked, not annotated: the take is a microsecond, and
+                    # ``_deliver_batch`` carries ``engine.deliver``)
+                    taken = time.perf_counter()
+                    stats.stream_blocks += 1
+                    stats.stream_deliver_wait_s += taken - landed
+                    if account is not None:
+                        account.take(landed, taken - landed, taken - asked)
                     for token in item:
                         if token is _DONE:
                             done = True
@@ -3236,6 +3434,8 @@ class InferenceEngine:
         # the loop's own spans (one a dispatch) belong to no hop: were the
         # engine started inside one, its sink must not collect them for ever
         detach_spans()
+        self._beat = self._loop.call_later(
+            HEARTBEAT_S, self._heartbeat, time.perf_counter() + HEARTBEAT_S)
         try:
             while self._running:
                 stats.enter(REAP)
@@ -3308,6 +3508,28 @@ class InferenceEngine:
         finally:
             # the loop has ended: close the open phase and the ledger
             stats.note_blocked(None, 0, stats.enter(None))
+            self._beat.cancel()
+
+    @hotpath
+    def _heartbeat(self, due: float) -> None:
+        """The engine's beat on its loop, every ``HEARTBEAT_S`` while
+        ``_serve`` runs: one that comes more than a period late books its
+        lateness (``loop_stall_s``, ``loop_stalls``) and journals it, so a
+        timeline shows what held the loop beside the dispatches it held
+        up: a stall with no sync in it, which no phase and no gap class
+        can name.  And the tick's side: a phase open for longer than
+        ``long_after`` gives has its annotation re-stamped at every beat
+        (``EngineStats.restamp_if_long``), so a capture that ends inside the
+        stall holds the phase up to its last beat."""
+        now = time.perf_counter()
+        late = now - due
+        stats = self.stats
+        if late > HEARTBEAT_S:
+            stats.loop_stalls += 1
+            stats.loop_stall_s += late
+            self._journal.append(flightrec.EV_LOOP_STALL, None, -1, int(late * 1000.0))
+        stats.restamp_if_long(now)
+        self._beat = self._loop.call_later(HEARTBEAT_S, self._heartbeat, now + HEARTBEAT_S)
 
     async def _offload(self, tick: Any, *args: Any) -> Any:
         """Run one tick on a worker thread.  The phase clock follows the
@@ -3905,7 +4127,7 @@ class InferenceEngine:
         self._observe("ttft_ms", ttft_ms)
         # the long lane's wait is everything before its prefill started
         self._observe("queue_wait_ms", max(0.0, ttft_ms - request.prefill_ms))
-        if self._emit_long(request, first):
+        if self._emit_long(request, first, time.perf_counter()):
             return
         cfg = self.config
         cap = self._long_fresh_cap()
@@ -4068,7 +4290,7 @@ class InferenceEngine:
         self.stats.decode_time_s += now - start
         done = False
         for token in block:
-            done = self._emit_long(request, int(token))
+            done = self._emit_long(request, int(token), now)
             if done:
                 break
         inflight = state.get("pend")
@@ -4086,14 +4308,14 @@ class InferenceEngine:
             self._loop.call_soon_threadsafe(request.out.put_nowait, _DONE)
             self._long = None
 
-    def _emit_long(self, request: GenRequest, token: int) -> bool:
+    def _emit_long(self, request: GenRequest, token: int, landed: float) -> bool:
         """Record one long-lane token (runs on the to_thread worker);
         returns True when the request retired."""
         items: list = []
         done = self._record_token(request, token, items, long=True)
         if items:
             self._loop.call_soon_threadsafe(
-                _deliver_batch, [(request.out, items)]
+                _deliver_batch, [(request.out, (landed, items))]
             )
         return done
 
@@ -4316,7 +4538,7 @@ class InferenceEngine:
 
     def _land_wave(
         self, wave: list[GenRequest], true_lens: np.ndarray,
-        firsts: np.ndarray, elapsed_ms: float,
+        firsts: np.ndarray, elapsed_ms: float, landed: float,
     ) -> None:
         """Host side of the wave landing: stats and the first-token
         emission — batched into ONE event-loop marshal for the whole wave.
@@ -4326,8 +4548,9 @@ class InferenceEngine:
         dispatch the wave's rows are already active and in the NEXT
         dispatch: a row that retires here (a first token that is a stop,
         ``max_new_tokens == 1``) takes ``_retire_slot``'s deferred path,
-        and that dispatch's column for it is discarded."""
-        deliveries: list[tuple[asyncio.Queue, list]] = []
+        and that dispatch's column for it is discarded.  ``landed``: the
+        moment ``_landed`` booked the sync, which rides each block."""
+        deliveries: list = []
         self._note_progress()  # a wave landing is watchdog progress
         self._observe("prefill_ms", elapsed_ms)
         self._journal.append(
@@ -4348,7 +4571,7 @@ class InferenceEngine:
             items: list = []
             self._record_token(request, int(firsts[r]), items)
             if items:
-                deliveries.append((request.out, items))
+                deliveries.append((request.out, (landed, items)))
         if deliveries:
             self._loop.call_soon_threadsafe(_deliver_batch, deliveries)
 
@@ -4372,7 +4595,7 @@ class InferenceEngine:
             self._note_moe(*wmoe)
         wave = landing["wave"]
         self._land_wave(
-            wave, landing["true_lens"], firsts, (now - landing["started"]) * 1000.0)
+            wave, landing["true_lens"], firsts, (now - landing["started"]) * 1000.0, now)
         if self._prefix is not None:
             for request in wave:
                 self._register_prefix_pages(request)
@@ -4414,8 +4637,8 @@ class InferenceEngine:
         firsts = np.asarray(firsts)
         if moe is not None:
             self._note_moe(*moe)
-        elapsed_ms = (self._landed(seq, wave=True) - started) * 1000.0
-        self._land_wave(wave, arrays["true_lens"], firsts, elapsed_ms)
+        landed = self._landed(seq, wave=True)
+        self._land_wave(wave, arrays["true_lens"], firsts, (landed - started) * 1000.0, landed)
 
     # --------------------------------------------------- chunked admission
     async def _admit_chunked(self) -> bool:
@@ -5124,7 +5347,7 @@ class InferenceEngine:
             now - start, steps,
             n_rows=len(pend["participants"]) + pend.get("extra_rows", 0),
         )
-        deliveries: list[tuple[asyncio.Queue, list]] = []
+        deliveries: list = []  # (queue, block): a block is (its landing, its tokens)
         block_cols = np.ascontiguousarray(block.T)  # [B, steps]
         wasted = 0
         for slot, request in pend["participants"]:
@@ -5142,7 +5365,7 @@ class InferenceEngine:
                 self._retire_slot(request)
                 items.append(_DONE)
             if items:
-                deliveries.append((request.out, items))
+                deliveries.append((request.out, (now, items)))
         if wasted:
             self.stats.overlap_wasted_tokens += wasted
         self._journal.append(
@@ -5204,7 +5427,8 @@ class InferenceEngine:
         # no stop token in the block, bound not yet reached — ships the
         # whole column as one C-level tolist() with no per-token Python
         # loop.
-        deliveries: list[tuple[asyncio.Queue, list]] = []
+        deliveries: list = []
+        landed = self._last_sync_t
         block_cols = np.ascontiguousarray(block.T)  # [B, steps]
         for slot, request in list(self._active.items()):
             toks: list = block_cols[slot].tolist()
@@ -5215,7 +5439,7 @@ class InferenceEngine:
                 if bound > steps:
                     request.generated += steps
                     self.stats.decode_tokens += steps
-                    deliveries.append((request.out, toks))
+                    deliveries.append((request.out, (landed, toks)))
                 else:
                     # bound falls inside this block: deliver up to it, retire
                     items = toks[:bound]
@@ -5223,7 +5447,7 @@ class InferenceEngine:
                     self.stats.decode_tokens += len(items)
                     self._retire_slot(request)
                     items.append(_DONE)
-                    deliveries.append((request.out, items))
+                    deliveries.append((request.out, (landed, items)))
                 continue
             # a stop token is present: per-token authority loop
             items = []
@@ -5231,7 +5455,7 @@ class InferenceEngine:
                 if self._record_token(request, token, items):
                     break
             if items:
-                deliveries.append((request.out, items))
+                deliveries.append((request.out, (landed, items)))
         if not self._active:
             self._last_sync_t = None
         if deliveries:
@@ -5429,7 +5653,8 @@ class InferenceEngine:
             flightrec.EV_SPEC_TICK, None, -1, int(ndraft.sum()),
             int(emitted.sum()),
         )
-        deliveries: list[tuple[asyncio.Queue, list]] = []
+        deliveries: list = []
+        landed = self._last_sync_t
         for slot, request in list(self._active.items()):
             count = int(emitted[slot])
             self._host_lens[slot] += count
@@ -5450,7 +5675,7 @@ class InferenceEngine:
                 self._retire_slot(request)
                 items.append(_DONE)
             if items:
-                deliveries.append((request.out, items))
+                deliveries.append((request.out, (landed, items)))
         if not self._active:
             self._last_sync_t = None
         if deliveries:

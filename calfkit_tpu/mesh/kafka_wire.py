@@ -39,6 +39,7 @@ from calfkit_tpu.mesh.connection import DEFAULT_MAX_MESSAGE_BYTES
 from calfkit_tpu.protocol import header_map as protocol_header_map
 from calfkit_tpu.mesh.dispatch import KeyOrderedDispatcher
 from calfkit_tpu.mesh.tables import TableReader, TableWriter
+from calfkit_tpu.observability.devtrace import annotate
 from calfkit_tpu.observability.metrics import REGISTRY
 from calfkit_tpu.mesh.transport import (
     CallbackSubscription,
@@ -1111,25 +1112,30 @@ class KafkaWireClient:
                         encode_record_batch, records, now_ms
                     )
                 else:
-                    batch = encode_record_batch(records, now_ms)
+                    # (``mesh.produce``: the loop's work on the profiler's
+                    # clock, here and around the frame below; no await inside)
+                    with annotate("mesh.produce"):
+                        batch = encode_record_batch(records, now_ms)
             by_topic.setdefault(topic, []).append((part, batch))
-        w = _W()
-        w.string(None)  # transactional_id
-        w.i16(-1)       # acks=all
-        w.i32(10000)
-        w.i32(len(by_topic))
-        for topic, parts in by_topic.items():
-            w.string(topic)
-            w.i32(len(parts))
-            for part, batch in parts:
-                w.i32(part)
-                w.bytes_(batch)
-        carried = sum(len(entries) for entries in groups.values())
-        self.produce_requests += 1
-        self.produce_records += carried
-        _PRODUCE_REQUESTS.inc()
-        _PRODUCE_RECORDS.inc(carried)
-        r = await conn.request(0, 3, w.done())
+        with annotate("mesh.produce"):
+            w = _W()
+            w.string(None)  # transactional_id
+            w.i16(-1)       # acks=all
+            w.i32(10000)
+            w.i32(len(by_topic))
+            for topic, parts in by_topic.items():
+                w.string(topic)
+                w.i32(len(parts))
+                for part, batch in parts:
+                    w.i32(part)
+                    w.bytes_(batch)
+            carried = sum(len(entries) for entries in groups.values())
+            self.produce_requests += 1
+            self.produce_records += carried
+            _PRODUCE_REQUESTS.inc()
+            _PRODUCE_RECORDS.inc(carried)
+            body = w.done()
+        r = await conn.request(0, 3, body)
         results = {}
         for _ in range(r.i32()):
             topic = r.string()

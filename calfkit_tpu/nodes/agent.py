@@ -443,6 +443,7 @@ class BaseAgentNodeDef(BaseNodeDef):
                 prompt_tokens=outcome.usage.input_tokens,
                 generated_tokens=outcome.usage.output_tokens,
                 tool_calls=len(outcome.tool_calls),
+                **(model.stages() if isinstance(model, _TokenTap) else {}),
             )
             current_context.reset(turn_token)
         facts.append(
@@ -814,6 +815,11 @@ class _TokenTap(ModelClient):
         # carries across turns — the pre-ISSUE-10 behavior.
         self._offset = 0
         self._stamp = False
+        # the turn's token events by stage (``agent.turn`` ends with them):
+        # building the step's wire message, and the publish to its
+        # acknowledgement.  One clock read an event a stage
+        self.token_events = 0
+        self.step_build_s = self.publish_s = self.publish_max_s = 0.0
 
     @property
     def model_name(self) -> str:
@@ -829,24 +835,45 @@ class _TokenTap(ModelClient):
             self._offset += len(text)
         from calfkit_tpu.models.step import StepMessage, TokenStep
         from calfkit_tpu.nodes.steps import publish_step_message
+        from calfkit_tpu.observability.devtrace import annotate
 
+        began = built = time.perf_counter()
         try:
+            with annotate("node.publish"):  # on the profiler's clock: no await inside
+                message = StepMessage(
+                    steps=[
+                        TokenStep(text=text, author=self._node.name, offset=offset)
+                    ],
+                    emitter=self._node.emitter,
+                )
+                wire = message.to_wire()
+            built = time.perf_counter()
             await publish_step_message(
                 self._node.transport,
                 self._ctx.root_topic,
-                StepMessage(
-                    steps=[
-                        TokenStep(
-                            text=text, author=self._node.name, offset=offset
-                        )
-                    ],
-                    emitter=self._node.emitter,
-                ),
+                message,
                 correlation_id=self._ctx.correlation_id,
                 task_id=self._ctx.task_id,
+                wire=wire,
             )
         except Exception:  # noqa: BLE001 - token telemetry never faults a run
             pass
+        waited = time.perf_counter() - built
+        self.token_events += 1
+        self.step_build_s += built - began
+        self.publish_s += waited
+        if waited > self.publish_max_s:
+            self.publish_max_s = waited
+
+    def stages(self) -> dict:
+        """What ``agent.turn`` ends with: the turn's token events and
+        their two stages, in ms."""
+        return {
+            "token_events": self.token_events,
+            "step_build_ms": round(self.step_build_s * 1e3, 3),
+            "publish_ms": round(self.publish_s * 1e3, 3),
+            "publish_max_ms": round(self.publish_max_s * 1e3, 3),
+        }
 
     async def request(self, messages, settings=None, params=None):
         from calfkit_tpu.engine.model_client import (

@@ -217,10 +217,13 @@ async def publish_step_message(
     *,
     correlation_id: str | None,
     task_id: str | None,
+    wire: bytes | None = None,
 ) -> None:
     """The ONE way a wire StepMessage reaches the step stream — used by the
     hop ledger's flush and by live token streaming, so headers/keying can
-    never diverge."""
+    never diverge.  ``wire``: ``message.to_wire()`` where the caller made it
+    already (the token tap times the message's making apart from the
+    publish)."""
     headers = {protocol.HDR_WIRE: "step", protocol.HDR_EMITTER: message.emitter}
     if correlation_id:
         headers[protocol.HDR_CORRELATION] = correlation_id
@@ -228,7 +231,7 @@ async def publish_step_message(
         headers[protocol.HDR_TASK] = task_id
     await transport.publish(
         root_topic,
-        message.to_wire(),
+        message.to_wire() if wire is None else wire,
         key=partition_key(task_id) if task_id else None,
         headers=headers,
     )

@@ -45,6 +45,7 @@ Python over them.
 from __future__ import annotations
 
 import bisect
+import contextlib
 import glob
 import os
 import re
@@ -56,7 +57,7 @@ import time
 from collections import defaultdict
 from typing import Any, Iterator
 
-__all__ = ["SCOPES", "CaptureBusy", "capture", "read_trace", "reduce_trace"]
+__all__ = ["SCOPES", "CaptureBusy", "annotate", "capture", "read_trace", "reduce_trace"]
 
 # every jax.named_scope the program opens (tests/test_devtrace.py holds
 # the sources to this list); anything else in an operation's path is
@@ -97,6 +98,23 @@ SCOPES = frozenset({
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"
 HOST_PREFIX = "engine."
+# what the EVENT LOOP was doing, beside the tick's exclusive phases (ISSUE
+# 52): synchronous stretches of the stream's road from a dispatch's landing
+# to the broker, annotated where the work happens.  None takes a phase's
+# name, none spans an await (the profiler nests annotations per thread, and
+# tasks interleave): ``engine.deliver`` (``_deliver_batch``, one a fan-out:
+# a consumer's take is booked, not annotated),
+# ``engine.emit`` (detokenize and the text delta),
+# ``node.publish`` (the step's wire message), ``mesh.produce`` (a Produce
+# request's encoding).  ``gap_loop_s`` reads them.
+LOOP_SIDE = ("engine.deliver", "engine.emit", "node.publish", "mesh.produce")
+# a phase's annotation may come in PIECES (``EngineStats.restamp``: the
+# profiler records an annotation when it ends, so a long phase is ended and
+# begun again under the same name and ``seq`` at the heartbeat's beats).  The
+# phases are exclusive and ``enter`` never opens the open one again, so two
+# neighbours of one name and ``seq`` are one phase; those that carry no
+# ``seq`` join only across the moment a re-stamp takes
+PIECE_GAP_NS = 1_000_000
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 MAX_SECONDS = 60.0
@@ -111,6 +129,19 @@ QUEUED, DRAINED, UNJOINED = "queued", "drained", "unjoined"
 Op = tuple  # (plane, name, scope path, start_ns, duration_ns)
 Module = tuple  # (plane, name, start_ns, duration_ns)
 Host = tuple  # (name, start_ns, duration_ns[, seq])
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotate(name: str) -> Any:
+    """A synchronous stretch of host work on the profiler's clock, for the
+    layers that do not import JAX themselves (the nodes, the mesh): a
+    ``jax.profiler.TraceAnnotation`` where this process has loaded JAX (a
+    no-op with no profile running, as the engine's phase annotations are),
+    else nothing.  Never around an ``await``."""
+    jax = sys.modules.get("jax")
+    return _NO_ANNOTATION if jax is None else jax.profiler.TraceAnnotation(name)
 
 
 class CaptureBusy(RuntimeError):
@@ -143,18 +174,24 @@ def capture(seconds: float) -> dict:
         # a process that built an engine has the module)
         engines = sys.modules.get("calfkit_tpu.inference.engine")
         pairs = engines.chunk_attention_of_all_engines if engines else dict
+        # the profiler records an annotation that BEGAN and ENDED inside the
+        # capture: the phase open at either edge is re-stamped there, or a
+        # stall that reaches the edge has no name (``EngineStats.restamp``)
+        restamp = engines.restamp_all_engines if engines else (lambda: None)
         t0 = clock()
         try:
             jax.profiler.start_trace(trace_dir, profiler_options=options)
         except RuntimeError as exc:  # "Only one profile may be run at a time."
             return {"captured": False, "reason": str(exc)}
         t1 = clock()
+        restamp()
         before = pairs()
         try:
             time.sleep(seconds)
         finally:
             t2 = clock()
             after = pairs()
+            restamp()
             jax.profiler.stop_trace()
         t3 = clock()
         paths = sorted(glob.glob(
@@ -230,7 +267,8 @@ def scope_path(tf_op: str) -> str:
 
 def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
     """Device operations with their scope paths, device modules, and the
-    host's ``engine.*`` annotations, from one ``.xplane.pb``.
+    host's annotations (the engine's ``engine.*`` and the loop-side
+    stretches, ``LOOP_SIDE``), from one ``.xplane.pb``.
 
     XSpace.planes=1; XPlane: name=2 lines=3 event_metadata=4
     stat_metadata=5; XLine: name=2 timestamp_ns=3 events=4; XEvent:
@@ -275,7 +313,7 @@ def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
                     if stat.get(1) in tf_op:
                         scope = scope_path(
                             _text(stat[5]) if 5 in stat else stat_names.get(stat.get(7), ""))
-            if device or ev_name.startswith(HOST_PREFIX):
+            if device or ev_name.startswith(HOST_PREFIX) or ev_name in LOOP_SIDE:
                 events[key] = (ev_name, scope)
         if not events:
             continue  # a plane with nothing of ours
@@ -306,7 +344,22 @@ def read_trace(path: str) -> tuple[list[Op], list[Module], list[Host]]:
                     ops.append((name, known[0], known[1], start, duration))
                 else:
                     modules.append((name, known[0], start, duration))
-    return ops, modules, host
+    return ops, modules, join_pieces(host)
+
+
+def join_pieces(host: list[Host]) -> list[Host]:
+    """The host's annotations with the pieces of a re-stamped phase joined
+    into one interval again, from the first piece's start to the last one's
+    end; the loop-side stretches as they are."""
+    joined: list[Host] = []
+    for h in sorted((h for h in host if h[0] not in LOOP_SIDE), key=lambda h: h[1]):
+        last = joined[-1] if joined else None
+        if (last is not None and last[0] == h[0] and last[3:] == h[3:]
+                and (len(h) > 3 or h[1] - (last[1] + last[2]) <= PIECE_GAP_NS)):
+            joined[-1] = (h[0], last[1], max(last[2], h[1] + h[2] - last[1]), *h[3:])
+        else:
+            joined.append(h)
+    return [h for h in host if h[0] in LOOP_SIDE] + joined
 
 
 def _seq_of(event: Any, seq_stat: set) -> "int | None":
@@ -408,9 +461,9 @@ def _join_programs(modules: list[Module], host: list[Host]) -> "list[tuple[int, 
 
 def _dispatch_rows(
     joined: "list[tuple[int, Module]]", gaps: list[tuple[int, int]], host: list[Host],
-) -> "tuple[list[dict], dict[str, float], dict[str, float]]":
+) -> "tuple[list[dict], dict[str, list], dict[str, float]]":
     """One row a program run (its number, module, device seconds, the idle
-    gap in front of it and that gap's class), the idle seconds by class,
+    gap in front of it and that gap's class), the idle gaps by class,
     and the ``drained`` ones by ``engine.<phase>``.  A gap is ``queued``
     where the ``enqueue`` phase that put the next program on the queue had
     ENDED when the gap began, ``drained`` where it had not (the host had
@@ -419,8 +472,7 @@ def _dispatch_rows(
     phases = sorted((h[3], h[1] + h[2]) for h in host if len(h) > 3 and h[0] == ENQUEUE)
     firsts = [seq for seq, _ in phases]
     starts = [m[2] for _, m in joined]
-    by_class: dict[str, float] = defaultdict(float)
-    drained: list[tuple[int, int]] = []
+    by_class: dict[str, list] = defaultdict(list)
     before: dict[int, list] = {}  # seq -> [idle seconds in front of it, class of their first]
     for a, b in gaps:
         i = bisect.bisect_left(starts, a)  # the next numbered program to start
@@ -433,15 +485,13 @@ def _dispatch_rows(
             # an eager operation of the host's (a fresh scratch's zeros) may
             # split the idle in front of a program: the pieces add up
             before.setdefault(seq, [0.0, kind])[0] += (b - a) / 1e9
-        by_class[kind] += (b - a) / 1e9
-        if kind == DRAINED:
-            drained.append((a, b))
+        by_class[kind].append((a, b))
     rows = []
     for seq, m in joined:
         idle_s, kind = before.get(seq, (0.0, None))
         rows.append({"seq": seq, "module": m[1].split("(", 1)[0].strip(),
                      "device_s": m[3] / 1e9, "gap_before_s": idle_s, "gap": kind})
-    return rows, dict(by_class), _gaps_by_phase(drained, host)
+    return rows, dict(by_class), _gaps_by_phase(by_class.get(DRAINED, []), host)
 
 
 def _sorted(acc: dict[str, float]) -> dict[str, float]:
@@ -471,9 +521,20 @@ def reduce_trace(ops: list[Op], modules: list[Module], host: list[Host], window_
     by_module: dict[str, float] = defaultdict(float)
     for m in modules:
         by_module[m[1].split("(", 1)[0].strip()] += m[3] / 1e9 / len(planes)
+    # the tick's phases are exclusive and split a gap exactly; the loop's
+    # stretches run on another thread beside them and are read apart
+    host = join_pieces(host)  # (a host list that did not come through ``read_trace``)
+    loop = [h for h in host if h[0] in LOOP_SIDE]
+    host = [h for h in host if h[0] not in LOOP_SIDE]
     gap_s = _gaps_by_phase(first_gaps, host)
-    dispatches, gap_class_s, gap_drained_s = _dispatch_rows(
+    dispatches, gaps_by_class, gap_drained_s = _dispatch_rows(
         _join_programs([m for m in modules if m[0] == planes[0]], host), first_gaps, host)
+    gap_class_s = {k: sum(b - a for a, b in v) / 1e9 for k, v in gaps_by_class.items()}
+    gap_loop_s = {}
+    for kind, gaps in gaps_by_class.items():
+        covered = _gaps_by_phase(gaps, loop)
+        covered.pop(UNATTRIBUTED, None)
+        gap_loop_s[kind] = _sorted(covered)
     out.update(
         busy_s=busy_s,
         idle_pct=100.0 * (1.0 - busy_s / window_s) if window_s > 0 else None,
@@ -488,6 +549,10 @@ def reduce_trace(ops: list[Op], modules: list[Module], host: list[Host], window_
         # drained ones by phase, and one row a numbered program run
         gap_class_s=_sorted(gap_class_s),
         gap_drained_s=_sorted(gap_drained_s),
+        # each class's idle seconds that a loop-side stretch overlapped, by
+        # its name (``LOOP_SIDE``): what the event loop ran while the device
+        # stood idle, where the phases say only where the tick stood
+        gap_loop_s=gap_loop_s,
         dispatches=dispatches,
     )
     return out

@@ -85,6 +85,8 @@ EV_EXPIRE = 20  # deadline passed (submit/queue/active) a=overdue_ms
 EV_RAGGED_WAVE = 21  # unified dispatch: decode+chunk  a=decode_rows b=chunk_rows
 EV_WEDGE = 22  # dispatch-progress watchdog tripped  a=stalled_ms b=pending
 EV_ORPHAN = 23  # caller lease lapsed; run reaped    a=lapsed_ms
+EV_LOOP_STALL = 24  # the engine's loop heartbeat came late  a=late_ms
+EV_PHASE_LONG = 25  # a dispatch-loop phase outlasted the queue  a=took_ms b=seq note=engine.<phase>
 
 EVENT_NAMES: tuple[str, ...] = (
     "SUBMIT",
@@ -111,6 +113,8 @@ EVENT_NAMES: tuple[str, ...] = (
     "RAGGED_WAVE",
     "WEDGE",
     "ORPHAN",
+    "LOOP_STALL",
+    "PHASE_LONG",
 )
 
 # per-event meaning of the two int payload fields (the dump stays compact
@@ -140,6 +144,8 @@ ARG_LABELS: dict[str, tuple[str, str]] = {
     "RAGGED_WAVE": ("decode_rows", "chunk_rows"),
     "WEDGE": ("stalled_ms", "pending"),
     "ORPHAN": ("lapsed_ms", ""),
+    "LOOP_STALL": ("late_ms", ""),
+    "PHASE_LONG": ("took_ms", "seq"),
 }
 
 # batch-scoped events a request's timeline borrows from its active window
@@ -155,6 +161,8 @@ _BATCH_EVENTS = {
     "RAGGED_WAVE",
     "PAGE_EVICT",
     "FAULT",
+    "LOOP_STALL",
+    "PHASE_LONG",
 }
 # slot-scoped events included when their slot matches the request's
 _SLOT_EVENTS = {"PAGE_FREE", "SLOT_FREE"}

@@ -1576,7 +1576,8 @@ def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
     assert len(MANIFEST["workloads"][-1]["why"]) <= 200 and len(entry["why"]) <= 200
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
     own = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [MELLUM_CELL]]
-    assert own == MANIFEST["per_layer"][-2:] and [m["name"] for m in own] == [
+    # (the last two until PR 52's eight, in every cell, were appended behind them)
+    assert own == MANIFEST["per_layer"][-10:-8] and [m["name"] for m in own] == [
         "global_cache_roofline", "chunk_padding_pct"]
     assert own[0] == {"name": "global_cache_roofline", "unit": "%", "better": "higher",
                       "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
@@ -1601,3 +1602,54 @@ def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
     assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline",
                 "shortconv_mixer_roofline"} & registered
     assert "kv_pages_given_back_pct" not in {m["name"] for m in MANIFEST["per_layer"]}
+
+
+# ------------- the stream's road, stage by stage, and the stall no phase names (PR 52)
+STREAM_METRICS = {
+    "engine_tpot_landed_p95_ms": ("ms", "program_span", "admission and batching"),
+    "stream_deliver_wait_p95_ms": ("ms", "program_span", "admission and batching"),
+    "loop_stall_pct": ("%", "program_counter", "admission and batching"),
+    "phase_long_pct": ("%", "program_counter", "admission and batching"),
+    "stream_emit_ms_per_event": ("ms", "program_counter", "node and agent"),
+    "stream_backpressure_p95_ms": ("ms", "program_span", "node and agent"),
+    "publish_ack_p95_ms": ("ms", "program_span", "client and mesh"),
+    "stream_path_p95_ms": ("ms", "program_span", "client and mesh"),
+}
+
+
+def test_the_manifest_holds_the_eight_stream_metrics_appended_in_every_cell():
+    """Appended behind everything that was there, with no ``workloads`` key
+    (the manifest's spelling of "every cell"), each moving ``tpot_p95_ms``,
+    each with its file and a reader; nothing that was there moved."""
+    last = MANIFEST["per_layer"][-8:]
+    assert [m["name"] for m in last] == list(STREAM_METRICS)
+    for m in last:
+        unit, source, layer = STREAM_METRICS[m["name"]]
+        assert m == {"name": m["name"], "unit": unit, "better": "lower", "source": source,
+                     "layer": layer, "moves": "tpot_p95_ms"}
+    assert len(MANIFEST["per_layer"]) == 39 and len(MANIFEST["workloads"]) == 8
+    for row in MANIFEST["workloads"]:
+        cell = M.resolve_cell(MANIFEST, row["name"], M.ROOT)
+        registered = {m.name: m for m in cell.per_layer}
+        assert set(STREAM_METRICS) <= set(registered), row["name"]
+        assert all(callable(registered[name].read) for name in STREAM_METRICS)
+
+
+@pytest.mark.parametrize("name", sorted(STREAM_METRICS))
+def test_a_stream_reader_reads_nothing_from_a_program_without_the_account(name):
+    """The driver lays these files over the parent's checkout too: there a
+    reader returns None and does not raise, and the line leaves it out."""
+    from types import SimpleNamespace
+
+    span = SimpleNamespace(name="engine.decode", trace_id="a", status="ok", start_s=0.0,
+                           duration_ms=9e3, span_id="s", parent_span_id="p",
+                           attrs={"generated_tokens": 101, "first_seq": 1, "last_seq": 9})
+    turn = SimpleNamespace(name="agent.turn", trace_id="a", status="ok", start_s=0.0,
+                           duration_ms=9e3, span_id="t", parent_span_id="h",
+                           attrs={"generated_tokens": 101})
+    sample = SimpleNamespace(correlation_id="a", events=[(1.0, 1), (9.0, 100)])
+    parent = SimpleNamespace(
+        spans=[span, turn], samples=[sample], trace_reduced={"window_s": 8.0},
+        trace_counters={"decode_dispatches": 16, "starved_s": 0.0}, t0=0.0, t_end=51.0,
+        counters={"window": {"decode_dispatches": 99, "phase_sync_s": 40.0}}, seconds=51.0)
+    assert M.load_reader(name)(parent) is None

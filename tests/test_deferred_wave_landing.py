@@ -317,10 +317,10 @@ async def test_a_cancel_between_the_enqueue_and_the_landing(alone, when, prefix_
         hook(engine, _flag_cancelled(engine))
         landed = engine._land_wave
 
-        def land_wave(wave, true_lens, firsts, elapsed_ms):
+        def land_wave(wave, *landing):
             # by its landing the cancelled row holds no slot: nothing recorded
             assert all(r.slot == -1 for r in wave if r.cancelled)
-            landed(wave, true_lens, firsts, elapsed_ms)
+            landed(wave, *landing)
 
         engine._land_wave = land_wave
 
