@@ -59,7 +59,7 @@ from calfkit_tpu.inference.config import (
     UnsupportedWithWindowLayers,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
-from calfkit_tpu.inference.moe import dense_form, moe_stats_init
+from calfkit_tpu.inference.moe import _STEP_MAX_TOKENS, dense_form, moe_stats_init
 from calfkit_tpu.observability import capacity, flightrec
 from calfkit_tpu.observability.trace import TRACER, Span, TraceContext, detach_spans
 from calfkit_tpu.observability.metrics import (
@@ -159,7 +159,7 @@ _LOCAL_FIELDS = (
     "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
     "programs_built", "moe_assignments", "moe_assignments_absent",
     "moe_rows_in_held_groups", "moe_expert_tokens_max",
-    "moe_expert_tokens_mean", "moe_experts_hit", "moe_grouped_chunks",
+    "moe_expert_tokens_mean", "moe_experts_hit", "moe_step_kernel_steps", "moe_grouped_chunks",
     "moe_dense_chunks", "stream_blocks", "stream_events", "loop_stalls",
     "phase_longs", *_SECONDS_FIELDS,
 )
@@ -451,6 +451,11 @@ def _engine_metrics(
             "distinct experts a decode step had to read, summed over expert "
             "layers and steps",
         ),
+        moe_step_kernel_steps=reg.counter(
+            "calfkit_engine_moe_step_kernel_steps_total",
+            "decode steps whose routed-expert products took the step kernel "
+            "(pallas_moe: the experts the step's real rows hit, read in place)",
+        ),
         moe_grouped_chunks=reg.counter(
             "calfkit_engine_moe_grouped_chunks_total",
             "chunk dispatches whose expert products took the grouped form "
@@ -574,15 +579,16 @@ def _some(x: Any) -> tuple:
     return () if x is None else (x,)
 
 
-def _carried_kw(state: Any, moe: Any, carried: list, ssm_impl: str) -> dict:
+def _carried_kw(state: Any, moe: Any, carried: list, ssm_impl: str, moe_step_impl: str) -> dict:
     """What a decode step takes besides the cache, out of its scan's carry:
     a hybrid's recurrent state first, then a routed-expert model's counters
-    (each only where the program was given one)."""
+    (each only where the program was given one, with the implementation
+    the engine resolved for what steps it)."""
     carried, kw = list(carried), {}
     if state is not None:
         kw.update(state=carried.pop(0), ssm_impl=ssm_impl)
     if moe is not None:
-        kw["moe"] = carried.pop(0)
+        kw.update(moe=carried.pop(0), moe_step_impl=moe_step_impl)
     return kw
 
 
@@ -984,6 +990,7 @@ class EngineStats:
     moe_expert_tokens_max: int = 0
     moe_expert_tokens_mean: float = 0.0
     moe_experts_hit: int = 0
+    moe_step_kernel_steps: int = 0  # decode steps that read the hit alone (pallas_moe)
     moe_grouped_chunks: int = 0
     moe_dense_chunks: int = 0
     latent_cache_bytes: int = 0
@@ -1462,6 +1469,16 @@ class InferenceEngine:
         self._attn_impl = self._resolved_attn_impl()
         self._ssm_impl = self._resolved_ssm_impl()
         self._chunk_attn_impl = self._resolved_chunk_attn_impl()
+        self._moe_step_impl = self._resolved_moe_step_impl()
+        if self._moe:
+            logger.info(
+                "routed experts: %d held of %d scored a layer; a decode step's products take %s",
+                config.n_routed_experts, config.experts_scored,
+                "the step kernel over the experts its rows hit (pallas_moe)"
+                if self._moe_step_impl != "xla"
+                else "the dense form" if dense_form(rt.max_batch_size, config)
+                else "the grouped form",
+            )
         if self._paged:
             from calfkit_tpu.inference.paged import PageAllocator
             from calfkit_tpu.inference.sharding import pool_sharding
@@ -1947,6 +1964,44 @@ class InferenceEngine:
             return "xla"
         return "pallas" if impl == "auto" else impl
 
+    def _resolved_moe_step_impl(self) -> str:
+        """Which implementation the ROUTED EXPERTS of a decode step use: the
+        sixth computation that has a kernel.  Decided HERE, once at
+        construction (``self._moe_step_impl``), under the same
+        ``attention_impl`` values as the paged decode read, and handed to
+        the decode step as ``ssm_impl`` is.  Under "auto": the Pallas kernel
+        that reads the experts the step's real rows HIT, in place out of the
+        stack (``pallas_moe.moe_step_pallas``), when the backend is a TPU,
+        one device holds the model, the experts are held by SHARE
+        (``config.expert_share``: a step's rows spread over every expert
+        scored and hit only some of the held; experts held whole are hit
+        whole, and the dense form's one stream of them all is the faster:
+        PERF.md section 6, PR 53), the slots' rows are at most the kernel's
+        (``moe._STEP_MAX_TOKENS``) and the matrices are whole lane tiles
+        (:func:`pallas_moe.moe_step_ok`); else the form ``moe.dense_form``
+        gives, the dense one being the reference.  Chunks keep that form
+        under any value.
+
+        "pallas" / "pallas_interpret" waive the platform test alone; they
+        NAME the attention kernel, so experts outside the rule are served
+        by XLA and not refused (as ``_resolved_ssm_impl`` has it)."""
+        impl = self.runtime.attention_impl
+        c = self.config
+        if not (self._moe and c.expert_share) or impl == "xla" or (
+            impl == "auto" and jax.devices()[0].platform != "tpu"
+        ):
+            return "xla"
+        from calfkit_tpu.inference.pallas_moe import moe_step_ok
+
+        in_rule = (
+            self._paged and self.mesh.size == 1
+            and self.runtime.max_batch_size <= _STEP_MAX_TOKENS
+            and moe_step_ok(c.d_model, c.moe_d_ff, c.dtype)
+        )
+        if not in_rule:
+            return "xla"
+        return "pallas" if impl == "auto" else impl
+
     def _keep_program(self, cache: dict, family: str, key: tuple, fn: Any) -> "_Program":
         program = cache[key] = _Program(self, family, key, fn)
         return program
@@ -2081,7 +2136,7 @@ class InferenceEngine:
         """The paged decode dispatch body (untraced) — see
         :meth:`_decode_fn_dense` for why the body builder is separate."""
         cfg = self.config
-        attn_impl, ssm_impl = self._attn_impl, self._ssm_impl
+        attn_impl, ssm_impl, moe_step_impl = self._attn_impl, self._ssm_impl, self._moe_step_impl
         from calfkit_tpu.inference.pallas_attention import latent_rope_view
 
         @jax.named_scope("decode_loop")
@@ -2108,7 +2163,7 @@ class InferenceEngine:
                 logits, ring, *st = M.decode_step_ring_paged(
                     params, cfg, last[:, None], pool, tables, ring, t,
                     lens, wpages=wpages, attn_impl=attn_impl, active=active,
-                    **_carried_kw(state, moe, st, ssm_impl),
+                    **_carried_kw(state, moe, st, ssm_impl, moe_step_impl),
                 )
                 if sampled:
                     subs = jax.vmap(jax.random.fold_in)(slot_keys, lens + t + 1)
@@ -4469,11 +4524,12 @@ class InferenceEngine:
         return self._state
 
     def _note_moe(self, counts: Any, hit: Any, absent: Any = 0, in_held_groups: Any = 0,
-                  decode: bool = False) -> None:
+                  decode_steps: int = 0) -> None:
         """Fold one dispatch's expert counters (already on their way to the
         host with what the landing syncs) into the stats; ``absent`` is
         there where the experts are held by share, ``in_held_groups`` where
-        the gate chooses by group."""
+        the gate chooses by group, ``decode_steps`` the steps of a decode
+        dispatch (a wave's chunks: 0)."""
         counts = np.asarray(counts)  # blocking-ok: computed before the sync that just landed
         self._moe_counts += counts
         stats = self.stats
@@ -4482,8 +4538,10 @@ class InferenceEngine:
         stats.moe_rows_in_held_groups += int(in_held_groups)
         stats.moe_expert_tokens_max += int(counts.max(axis=1).sum())
         stats.moe_expert_tokens_mean += float(counts.mean(axis=1).sum())
-        if decode:
+        if decode_steps:
             stats.moe_experts_hit += int(hit)
+            if self._moe_step_impl != "xla":
+                stats.moe_step_kernel_steps += decode_steps
 
     def _sampling_state_args(self, arrays: dict) -> list:
         return [
@@ -5325,7 +5383,7 @@ class InferenceEngine:
             seq = landing["seq"]
         block, n_valid, done, *rest = self._sync_host(arrays, seq)
         if moe_dev:
-            self._note_moe(*rest[:len(moe_dev)], decode=True)
+            self._note_moe(*rest[:len(moe_dev)], decode_steps=pend["steps"])
         now = self._landed(seq, wave=landing is not None)
         if landing is not None:
             firsts, *wmoe = rest[len(moe_dev):]
@@ -5413,7 +5471,7 @@ class InferenceEngine:
             self._host_lens[slot] += steps
         block = self._sync_host(toks, seq)  # [steps, B] — THE host sync per dispatch
         if moe is not None:
-            self._note_moe(*moe, decode=True)
+            self._note_moe(*moe, decode_steps=steps)
         self._last_sync_t = self._landed(seq)
         elapsed = self._last_sync_t - started
         self._note_dispatch(elapsed, steps)

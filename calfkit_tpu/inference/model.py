@@ -547,6 +547,7 @@ def _hybrid_stack(
     mamba_layer: Any,  # (carry, h, lp, im) -> (carry, y [B, S, D])
     stats: Any = None,  # moe.py's counters (a stack with routed experts), or None
     valid: jax.Array | None = None,  # [B, S] bool: the tokens that are real
+    moe_step_impl: str = "xla",  # a decode step's routed products (moe.moe_ffn)
 ) -> tuple[jax.Array, Any, Any]:
     """Run a hybrid stack: ONE ``lax.scan`` over the repeats of the layer
     period, the period's layers unrolled in its body, so compile time
@@ -563,7 +564,7 @@ def _hybrid_stack(
     m_per = len(period) - a_per
     if config.expert_hybrid:
         return _expert_hybrid_stack(
-            config, layers, x, carry, attn_layer, mamba_layer, stats, valid)
+            config, layers, x, carry, attn_layer, mamba_layer, stats, valid, moe_step_impl)
 
     def body(c, p):
         x, carry = c
@@ -599,7 +600,8 @@ def _recurrent_mixer(config: ModelConfig):
     return jax.named_scope("gdn"), "gdn"
 
 
-def _expert_hybrid_stack(config, layers, x, carry, attn_layer, recurrent_layer, stats, valid):
+def _expert_hybrid_stack(config, layers, x, carry, attn_layer, recurrent_layer, stats, valid,
+                         moe_step_impl="xla"):
     """:func:`_hybrid_stack` for a hybrid whose FFN is the expert block
     (``config.expert_hybrid``: a delta rule, Gated DeltaNet or Kimi Delta
     Attention, or a gated short convolution as the recurrent mixer): the same
@@ -638,7 +640,7 @@ def _expert_hybrid_stack(config, layers, x, carry, attn_layer, recurrent_layer, 
         with jax.named_scope("mlp"):
             y, stats = moe_ffn(
                 rms_norm(x, lp["mlp_norm"], eps, plus), lp, config, stats, valid, m,
-                layers["moe"])
+                layers["moe"], moe_step_impl)
         return x + y, carry, stats
 
     ja = jm = 0
@@ -817,6 +819,7 @@ def _latent_stack(
     mixer: Any,  # (carry, x, lp, i) -> (carry, attn [B, S, H, dv])
     stats: Any,  # moe.py's counters, or None
     valid: jax.Array | None,  # [B, S] bool: the tokens that are real
+    moe_step_impl: str = "xla",  # a decode step's routed products (moe.moe_ffn)
 ) -> tuple[jax.Array, Any, Any]:
     """Run a latent-attention stack: the leading dense layers unrolled,
     then ONE ``lax.scan`` over the expert layers, so compile time does not
@@ -846,7 +849,8 @@ def _latent_stack(
         lp = _layer(layers["moe"], m)
         with jax.named_scope("mlp"):
             y, stats = moe_ffn(
-                rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m, layers["moe"])
+                rms_norm(x, lp["mlp_norm"], eps), lp, config, stats, valid, m, layers["moe"],
+                moe_step_impl)
         return (x + y, carry, stats), None
 
     (x, carry, stats), _ = lax.scan(
@@ -914,7 +918,7 @@ def _latent_forward(params, config, tokens, positions, kv_cache, seq_lens, W, in
 
 
 def _latent_decode_step(params, config, tokens, ring, t, base_lens, attn_source, stats,
-                        active):
+                        active, moe_step_impl="xla"):
     """One decode step of a latent-attention stack (the absorbed algebra):
     the fresh latent goes to the ring, ``attn_source`` reads (main cache ⊕
     ring) with ``c`` as key AND value."""
@@ -928,7 +932,7 @@ def _latent_decode_step(params, config, tokens, ring, t, base_lens, attn_source,
         return _mla_step_mixer(ring, x, lp, i, t, cos, sin, config, attn_source)
 
     x, ring, stats = _latent_stack(
-        config, params["layers"], x, tuple(ring), mixer, stats, valid)
+        config, params["layers"], x, tuple(ring), mixer, stats, valid, moe_step_impl)
     logits = lm_logits(x, params, config.norm_eps)
     if stats is None:
         return logits, ring
@@ -1076,6 +1080,7 @@ def _window_stack(
     positions: jax.Array,  # [B, S]
     stats: Any,
     valid: jax.Array | None,
+    moe_step_impl: str = "xla",  # a decode step's routed products (moe.moe_ffn)
 ) -> tuple[jax.Array, Any, Any]:
     """Run a window stack: ONE ``lax.scan`` over the repeats of the layer
     period (W W W G), its layers unrolled in the body.  The block is the one
@@ -1118,7 +1123,8 @@ def _window_stack(
                 x = x + a
                 h = norm(x, mp["mlp_norm"], eps)
             with jax.named_scope("mlp"):
-                y, stats = moe_ffn(h, mp, config, stats, valid, il, layers["moe"])
+                y, stats = moe_ffn(
+                    h, mp, config, stats, valid, il, layers["moe"], moe_step_impl)
             x = x + a + y if config.parallel_block else x + y
         return (x, carry, stats), None
 
@@ -1170,7 +1176,8 @@ def _window_forward(params, config, tokens, positions, kv_cache, seq_lens, inser
     return logits, cache, stats
 
 
-def _window_decode_step(params, config, tokens, ring, t, base_lens, attn_source, stats, active):
+def _window_decode_step(params, config, tokens, ring, t, base_lens, attn_source, stats, active,
+                        moe_step_impl="xla"):
     """One decode step of a window stack: the fresh K and V go to the
     dispatch's ring of fresh tokens (all layers, stack order), and
     ``attn_source(kind, ik, q, ring_k_i, ring_v_i)`` reads (that kind's pages
@@ -1192,7 +1199,8 @@ def _window_decode_step(params, config, tokens, ring, t, base_lens, attn_source,
         return (ring_k, ring_v), attn
 
     x, ring, stats = _window_stack(
-        config, params["layers"], x, tuple(ring), attn_layer, positions, stats, valid)
+        config, params["layers"], x, tuple(ring), attn_layer, positions, stats, valid,
+        moe_step_impl)
     logits = lm_logits(x, params, config.norm_eps, norm=config.norm)
     if stats is None:
         return logits, ring
@@ -1210,7 +1218,7 @@ def _kind_decode_attention(kind, q, main_source, ring_k, ring_v, t):
 
 
 def _window_decode_step_paged(params, config, tokens, pool, tables, ring, t, base_lens,
-                              wpages, attn_impl, active, moe):
+                              wpages, attn_impl, active, moe, moe_step_impl="xla"):
     """:func:`decode_step_ring_paged` for a window stack: the pool's sides
     and the tables are pairs by kind.  A global layer reads its row's pages
     ``0 .. ceil(len / page)`` as every model does; a window layer reads its
@@ -1254,7 +1262,7 @@ def _window_decode_step_paged(params, config, tokens, pool, tables, ring, t, bas
         return _kind_decode_attention(kind, q, main, rk, rv, t)
 
     return _window_decode_step(
-        params, config, tokens, ring, t, base_lens, attn_source, moe, active)
+        params, config, tokens, ring, t, base_lens, attn_source, moe, active, moe_step_impl)
 
 
 
@@ -1410,6 +1418,7 @@ def _decode_step_with_ring(
     active: jax.Array | None = None,  # hybrid: rows whose state advances
     ssm_impl: str = "xla",  # hybrid: the state's pass (mamba.mamba_step, gdn.gdn_step)
     moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
+    moe_step_impl: str = "xla",  # routed experts: the step's products (moe.moe_ffn)
 ) -> Any:
     """The shared decode-step transformer body (ring-buffer scheme).
 
@@ -1441,7 +1450,7 @@ def _decode_step_with_ring(
     ring_k, ring_v = ring
     if config.latent and not config.layer_types:
         return _latent_decode_step(
-            params, config, tokens, ring, t, base_lens, attn_source, moe, active)
+            params, config, tokens, ring, t, base_lens, attn_source, moe, active, moe_step_impl)
     if config.layer_types:
         x = _embed(params, config, tokens)
         cos, sin = (_rope_dim_tables if config.latent else _positions_tables)(config, positions)
@@ -1483,6 +1492,7 @@ def _decode_step_with_ring(
         x, (ring_k, ring_v, state), moe = _hybrid_stack(
             config, params["layers"], x, (ring_k, ring_v, state),
             latent_layer if config.latent else attn_layer, mamba_layer, moe, valid,
+            moe_step_impl,
         )
         logits = lm_logits(x, params, eps, config.logits_scaling, config.norm_plus_one)
         return (logits, (ring_k, ring_v), state, *(() if moe is None else (moe,)))
@@ -2069,6 +2079,7 @@ def decode_step_ring_paged(
     state: tuple[jax.Array, jax.Array] | None = None,  # hybrid: (ssm, conv)
     ssm_impl: str = "xla",  # hybrid: the state's pass (mamba.mamba_step, gdn.gdn_step)
     moe: tuple[jax.Array, jax.Array] | None = None,  # routed experts: their counters
+    moe_step_impl: str = "xla",  # routed experts: the step's products (moe.moe_ffn)
 ) -> Any:
     """One decode step reading KV through the block tables.
 
@@ -2094,7 +2105,7 @@ def decode_step_ring_paged(
     if config.windowed:
         return _window_decode_step_paged(
             params, config, tokens, pool, tables, ring, t, base_lens, wpages, attn_impl,
-            active, moe)
+            active, moe, moe_step_impl)
     pool_k, pool_v = pool
 
     def read_lens():  # what a Pallas read walks: nothing of a row not active
@@ -2120,7 +2131,7 @@ def decode_step_ring_paged(
     if config.latent:
         return _decode_step_with_ring(
             params, config, tokens, ring, t, base_lens, latent_source, None,
-            state, active, ssm_impl, moe,
+            state, active, ssm_impl, moe, moe_step_impl,
         )
 
     def attn_source(i, q, rk, rv, extra):
@@ -2144,7 +2155,7 @@ def decode_step_ring_paged(
 
     return _decode_step_with_ring(
         params, config, tokens, ring, t, base_lens, attn_source, None,
-        state, active, ssm_impl, moe,
+        state, active, ssm_impl, moe, moe_step_impl,
     )
 
 

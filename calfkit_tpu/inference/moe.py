@@ -13,7 +13,8 @@ once.  Every token routed to an expert is computed by it: there is no
 capacity and no dropped assignment, under any imbalance.
 
 The expert products take one of two forms, chosen from the shapes alone
-(:func:`dense_form`), and the two must agree.  Each wins on its own side:
+(:func:`dense_form`), and the two must agree (a decode step's take a THIRD
+where the engine resolved it: the step kernel, below).  Each wins on its own side:
 one scan over the 6 expert layers of 64 experts of 2048 x 1408, 6 a token,
 on a v5e, in ms a layer (the gate, the products and the shared expert;
 PERF.md section 6, PRs 31 and 34):
@@ -114,12 +115,48 @@ Which form a shape's products take is then a matter of timing alone:
 
 - to ``_DENSE_MAX_TOKENS`` (512) every shape takes the dense form: from 256
   rows to 512 it wins in both tables, at a decode step's 64 rows it wins
-  (2.5x) or ties, and it is every decode step's form (at 8 rows the grouped
-  form without its copy reads few experts and is the faster: not used, a
-  decode step has all its slots' rows);
+  (2.5x) or ties, and it is every decode step's form where the step kernel
+  is not (at 8 rows the grouped form without its copy reads few experts and
+  is the faster: not used, a decode step has all its slots' rows);
 - beyond it a shape takes the dense form only to the limit its row of
   ``_DENSE_TO_THE_CROSSING`` gives: its two forms TIMED on the chip, in its
   cell, and its programs COMPILED at full depth.
+
+*The step kernel* (:func:`experts_step`, ``pallas_moe.moe_step_pallas``; PR
+53) is the dense form's sum over the experts a decode step's REAL rows HIT,
+read in place out of the stack.  Of experts held by share a step's rows
+spread over every expert the gate scores and hit only some of the held
+(in their cells command-a-plus ~2.5 of 16 at ~16 active rows, its seeded routing
+skewed; Qwen3-Next ~84 of 128; Ling ~27
+of 64 behind its group gate), the dense form streams them ALL, and the
+grouped form reads the hit alone behind a sort, a gather, a scatter and a
+fixed cost.  The kernel walks the hit list (``stats``' ``tokens > 0``, from
+the ``valid`` rows alone: an inactive slot's choices cost no read) by
+scalar-prefetched ids and streams each hit expert's matrices in width tiles
+through VMEM, every row through every hit expert, masked by its weight (to
+128 rows a weight is under the chip's ridge, so the rows are free).  Alone
+on a v5e, nested as a dispatch nests them, ms a layer (PERF.md section 6, PR
+53; ``hit`` at 819 GB/s is the least time to read the hit experts):
+
+    shape, rows                 hit   dense  grouped   step   hit at 819 GB/s
+    (16, 4096, 4096), 32          3   2.158     -     0.438   0.369
+                                  9   2.202   1.863   1.224   1.106
+                                 16   2.178     -     2.138   1.967
+    (128, 2048, 512), 64         85   1.077   0.940   0.724   0.653
+                                128   1.077     -     1.077   0.983
+    (64, 2560, 768), 128         10   1.041   0.518   0.184   0.144
+                                 29   1.033   1.322   0.475   0.418
+                                 64   1.025   2.799   1.012   0.922
+
+It streams at 87-92% of the peak at any hit count and ties the dense form
+with EVERY expert hit, so its cost follows the hit count by itself.  Who
+takes it is decided once, at construction
+(``InferenceEngine._resolved_moe_step_impl``, under the one
+``attention_impl``): a TPU, one device, experts held by SHARE, at most
+``_STEP_MAX_TOKENS`` slots, matrices of whole lane tiles; ``moe_ffn`` takes
+the answer as ``step_impl`` from the decode step alone, so chunks keep the
+form ``dense_form`` gives them.  Experts held whole are hit whole (Kimi 59
+of 64, LFM2 and Mellum2 all) and keep the dense form.
 
 A Ling-3.0-style layer (``n_group`` > 1: DeepSeek-V3's group-limited choice)
 is the first gate with one step before its top-k, over ALL the experts
@@ -160,20 +197,17 @@ _HI = lax.Precision.HIGHEST  # the gate's float32 product: no bf16 passes
 # the dense form's limit in tokens for ANY shape: the decode steps' rows and
 # narrow chunks (the module's text)
 _DENSE_MAX_TOKENS = 512
+# the step kernel's limit in rows: under the chip's ridge (197 TFLOP/s / 819 GB/s =
+# 240 rows a weight) every row goes through every hit expert for the price of the
+# weights' stream; timed to 128 rows, the most a cell's slots hold (PR 53)
+_STEP_MAX_TOKENS = 128
 # (experts held, hidden, expert width) -> the limit of a shape whose two forms were
 # TIMED on the chip as its cell runs them.  (64, 2048, 1408): the first table's
-# crossing, between 1,024 and 2,048.  (64, 2560, 768): 0, a crossing timed at 0 and
-# no compile artefact (PR 40 set it on one: the dense form, then spelled rows first,
-# copied both stacks whole in the decode program; the module's text).  Its cell's
-# group-limited gate sends this chip about half of a step's 128 rows and ~10 of the
-# 64 held experts a layer, and the grouped form reads the experts hit alone: timed
-# alone at 128 rows under that routing, ms a layer, dense (weights first) 1.03
-# whatever the routing, grouped 0.52 at 10 experts hit, 0.77 at 16, 1.32 at 29, 2.04
-# at 46, 2.79 at all 64 (PERF.md section 6, PR 45).  A shape without a row takes the
-# default, LFM2's (32, 2048, 1792) among them (every expert is hit every step there:
-# dense 0.95, grouped 2.59; at a chunk's 512 tokens 2.00 / 2.91, at 1,024 4.19 /
-# 3.27, so the default's 512 is its crossing too)
-_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
+# crossing, between 1,024 and 2,048.  A shape without a row takes the default,
+# LFM2's (32, 2048, 1792) among them (every expert is hit every step there: dense
+# 0.95, grouped 2.59; at a chunk's 512 tokens 2.00 / 2.91, at 1,024 4.19 / 3.27, so
+# the default's 512 is its crossing too; PERF.md section 6, PR 45)
+_DENSE_TO_THE_CROSSING = {(64, 2048, 1408): 1536}
 
 
 def init_moe_params(config: ModelConfig, key: jax.Array, dtype: Any) -> Params:
@@ -307,6 +341,23 @@ def experts_dense(h: jax.Array, onehot: jax.Array, weights: jax.Array, lp: Param
         return jnp.einsum("etf,efd->td", act, lp["w_down"])
 
 
+def experts_step(
+    h: jax.Array, onehot: jax.Array, weights: jax.Array, valid: jax.Array | None,
+    stack: Params, m: Any, interpret: bool,
+) -> jax.Array:
+    """A decode step's rows through the experts its REAL rows hit, read in
+    place out of the stack (``pallas_moe.moe_step_pallas``) -> [T, D]: the
+    dense form's sum with a row that is not ``valid`` weighted zero, so its
+    choices cost no read (its output is discarded by the caller)."""
+    from calfkit_tpu.inference.pallas_moe import moe_step_pallas
+
+    with jax.named_scope("group"):
+        real = onehot if valid is None else onehot & valid.reshape(-1, 1, 1)
+        gates = jnp.sum(real * weights[..., None], axis=1)  # [T, E] float32
+        hit = jnp.any(real, axis=(0, 1))  # [E]: what ``stats`` counts as hit
+    return moe_step_pallas(h, gates, hit, stack, m, interpret=interpret)  # named ``experts``
+
+
 def _stack_dot(x: jax.Array, stack: jax.Array, sizes_all: jax.Array) -> jax.Array:
     """One ragged product over the FLATTENED stack ``[Lm E, ., .]``: the
     reshape is free and nothing is sliced, so the kernel reads the experts
@@ -367,10 +418,12 @@ def moe_ffn(
     valid: jax.Array | None = None,  # [B, S] bool: the real tokens
     m: Any = 0,  # this layer's index among the expert layers (traced)
     stack: Params | None = None,  # the STACKED group ``lp`` is layer ``m`` of
+    step_impl: str = "xla",  # a decode step's products (InferenceEngine._resolved_moe_step_impl)
 ) -> tuple[jax.Array, Any]:
     """``sum_e w_e E_e(h) + Shared(h)`` → ([B, S, D], stats).  The grouped
-    products read ``stack`` at ``m`` where it lies (:func:`experts_grouped`);
-    without one, ``lp`` is a stack of one layer."""
+    products and the step kernel read ``stack`` at ``m`` where it lies
+    (:func:`experts_grouped`, :func:`experts_step`); without one, ``lp`` is
+    a stack of one layer."""
     B, S, D = h.shape
     E = config.n_routed_experts
     share = config.expert_share
@@ -396,7 +449,10 @@ def moe_ffn(
                     if valid is not None:
                         reach = reach & valid.reshape(-1)
                     stats = (*stats, absent[1] + jnp.sum(reach, dtype=jnp.int32))
-        if dense_form(B * S, config):
+        if step_impl.startswith("pallas") and stack is not None and B * S <= _STEP_MAX_TOKENS:
+            y = experts_step(
+                flat, onehot, weights, valid, stack, m, step_impl == "pallas_interpret")
+        elif dense_form(B * S, config):
             y = experts_dense(flat, onehot, weights, lp)
         else:
             if stack is None:

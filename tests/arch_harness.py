@@ -259,6 +259,54 @@ class Family:
             metrics=first.metrics, seen=[first.spy, alone.spy])
 
 
+def check_the_step_kernel_serves_what_xla_serves(family, wide, monkeypatch, chunk=16, **rt):
+    """The engine-level case of the decode step's expert kernel (PR 53) for a
+    family whose experts are held by share: ``wide`` (the toy with experts of
+    one lane tile a side, inside every kernel's rule the engine will name)
+    under ``attention_impl="pallas_interpret"`` serves the tokens "xla"
+    serves and the reference's logits, counts the same experts hit, and
+    counts every decode step run as a step of the kernel; under "xla" none."""
+    from calfkit_tpu.inference.pallas_attention import KERNEL_TRACES
+
+    params = family.seeded(wide)
+    prompt = family.prompt_of(29, seed=9)
+    (xla,), _, base = family.serve(
+        (wide, family.runtime(attention_impl="xla", **rt)), [(prompt, 9)], params=params)
+    built = KERNEL_TRACES["moe_step", "interpreted"]
+    spy = Spy(monkeypatch)
+    (out,), engine, counters = family.serve(
+        (wide, family.runtime(attention_impl="pallas_interpret", **rt)), [(prompt, 9)],
+        params=params, keep=True)
+    assert engine._moe_step_impl == "pallas_interpret" and out == xla
+    assert KERNEL_TRACES["moe_step", "interpreted"] > built
+    assert counters["moe_experts_hit"] == base["moe_experts_hit"] > 0
+    assert counters["moe_assignments"] == base["moe_assignments"]
+    steps = counters["decode_dispatches"] * engine.runtime.decode_steps_per_dispatch
+    assert (base["moe_step_kernel_steps"], counters["moe_step_kernel_steps"]) == (0, steps)
+    assert steps > 0 and counters["short_dispatches"] == 0
+    got = spy.of_request(prompt, out, chunk)
+    want = family.reference_logits(params, wide, prompt + out)
+    assert np.abs(got - want[len(prompt) - 1: len(prompt) - 1 + len(out)]).max() < family.logit_tol
+
+
+def check_the_step_kernel_is_not_taken(engine, monkeypatch, platform: str, under: tuple) -> None:
+    """``engine`` (a module's standing one, built under "auto" on this
+    process's CPU, after it has served) ran no step of the expert kernel, and
+    on ``platform`` takes it under none of ``under``: experts held WHOLE
+    under no value on a TPU, experts held by share not under "auto" on a CPU."""
+    from dataclasses import replace
+
+    counters = engine.stats.counters()
+    assert engine._moe_step_impl == "xla"
+    assert counters["moe_step_kernel_steps"] == 0 < counters["moe_experts_hit"]
+    real = jax.devices()
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [SimpleNamespace(platform=platform)] if not a else real)
+    for impl in under:
+        monkeypatch.setattr(engine, "runtime", replace(engine.runtime, attention_impl=impl))
+        assert engine._resolved_moe_step_impl() == "xla", impl
+
+
 @pytest.fixture(autouse=True)
 def both_forms_at_toy_size(request, monkeypatch):
     """Imported by a family's test file: every test of it runs with the dense

@@ -25,7 +25,8 @@ from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.paged import PagesByKind
 from tests.arch_harness import WINDOW_MOE as FAMILY
 from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    Spy, both_forms_at_toy_size, collect, standing,
+    Spy, both_forms_at_toy_size, check_the_step_kernel_is_not_taken,
+    check_the_step_kernel_serves_what_xla_serves, collect, standing,
 )
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
@@ -460,3 +461,15 @@ def test_the_cell_s_agreement_holds_what_the_rows_leave_in_the_engine(monkeypatc
     assert must <= set(over) <= must | may, line
     assert result["ok"] == (not over) and result["compared"] >= 8, result
     assert printed.err.count("FAIL") == len(over) and printed.err.count("(limit <= ") == 4
+
+
+def test_a_decode_step_s_experts_through_the_step_kernel(monkeypatch, standing):
+    """Experts held by share (4 of 8 scored) of one lane tile a side, heads of
+    64 on pages of 16 (inside the decode read's rule too): the step kernel in
+    interpret mode serves what XLA serves.  Under "auto" on this CPU the
+    module's engine ran none of its steps.  (Another configuration under two
+    implementations: builds of its own.)"""
+    wide = replace(TOY, d_model=128, moe_d_ff=128, attn_head_dim=64)
+    check_the_step_kernel_serves_what_xla_serves(FAMILY, wide, monkeypatch, page_size=16)
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(standing.engine, monkeypatch, "cpu", ("auto",))

@@ -211,15 +211,17 @@ def test_the_gate_is_a_softmax_over_all_the_experts_normalised_over_the_chosen()
 def test_the_dense_form_s_limit_follows_the_shape_and_kimi_s_has_not_moved(monkeypatch):
     monkeypatch.undo()  # the measured limits, not the toy one
     kimi = preset("kimi-vl-a3b-instruct")
-    # both rows TIMED on the chip: Kimi's crossing, and Ling's at 0 (PR 45: its
-    # group-limited gate sends a step ~10 of the 64 held experts, which the grouped
-    # form reads alone).  LFM2's shape has NO row since PR 45: the default
-    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536, (64, 2560, 768): 0}
+    # ONE row TIMED on the chip: Kimi's crossing.  LFM2's shape has NO row since PR 45,
+    # Ling's none since PR 53 (its row of 0 sent its decode steps to the grouped form,
+    # which read the experts a step hits alone, ~27 of 64 in its cell; the step kernel
+    # reads them now, and an engine without it runs the dense form, the reference)
+    assert moe._DENSE_TO_THE_CROSSING == {(64, 2048, 1408): 1536}
     lfm2 = preset("lfm2-8b-a1b")
     assert moe.dense_form(1, lfm2) and moe.dense_form(128, lfm2) and moe.dense_form(512, lfm2)
     assert not moe.dense_form(1024, lfm2)
     ling = replace(preset("ling-3.0-flash-vl"), n_routed_experts=64, n_experts_total=512)
-    assert not moe.dense_form(1, ling) and not moe.dense_form(128, ling)
+    assert moe.dense_form(1, ling) and moe.dense_form(128, ling) and moe.dense_form(512, ling)
+    assert not moe.dense_form(1024, ling)
     assert moe.dense_form(1536, kimi) and not moe.dense_form(1537, kimi)
     # any other shape, this model's share or another, keeps the dense form to
     # the decode steps' rows and narrow chunks: no chunk of 1,024 beside them

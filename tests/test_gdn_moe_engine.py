@@ -30,7 +30,10 @@ from calfkit_tpu.inference.engine import InferenceEngine
 from calfkit_tpu.inference.mamba import make_recurrent_state
 from calfkit_tpu.inference.sharding import make_mesh
 from tests.arch_harness import GDN_MOE as FAMILY
-from tests.arch_harness import Spy, both_forms_at_toy_size, standing  # noqa: F401 - fixtures
+from tests.arch_harness import (  # noqa: F401 - fixtures
+    Spy, both_forms_at_toy_size, check_the_step_kernel_is_not_taken,
+    check_the_step_kernel_serves_what_xla_serves, standing,
+)
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
@@ -337,3 +340,14 @@ def test_a_bfloat16_state_fails_the_reference(monkeypatch):
     assert worst > 10 * LOGIT_TOL
 
 
+def test_a_decode_step_s_experts_through_the_step_kernel(monkeypatch, standing):
+    """Experts held by share of one lane tile a side, beside an attention head
+    of 256 and a value head of 128 (inside the decode read's and the delta
+    step's rules too): the step kernel in interpret mode serves what XLA
+    serves.  Under "auto" on this CPU the module's engine ran none of its
+    steps.  (Another configuration under two implementations: builds of its own.)"""
+    wide = replace(TOY, attn_head_dim=256, n_heads=16, n_kv_heads=2, n_layers=4,
+                   layer_types=TOY.layer_types[:4], gdn_d_v=128, d_model=128, moe_d_ff=128)
+    check_the_step_kernel_serves_what_xla_serves(FAMILY, wide, monkeypatch)
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(standing.engine, monkeypatch, "cpu", ("auto",))

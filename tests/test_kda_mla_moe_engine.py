@@ -25,7 +25,10 @@ from calfkit_tpu.inference.config import (
 )
 from calfkit_tpu.inference.engine import InferenceEngine
 from tests.arch_harness import KDA_MLA_MOE as FAMILY
-from tests.arch_harness import Spy, both_forms_at_toy_size, standing  # noqa: F401 - fixtures
+from tests.arch_harness import (  # noqa: F401 - fixtures
+    Spy, both_forms_at_toy_size, check_the_step_kernel_is_not_taken,
+    check_the_step_kernel_serves_what_xla_serves, standing,
+)
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
@@ -248,3 +251,18 @@ def test_the_new_counter_and_both_gauges_are_in_the_metrics_and_the_catalog(one_
         catalog = f.read()
     for name in ("moe_rows_in_held_groups", "gdn/decay", "moe/router/groups"):
         assert name in catalog, name
+
+
+def test_a_decode_step_s_experts_through_the_step_kernel(monkeypatch, standing):
+    """Experts held by share behind a gate that chooses by group, of one lane
+    tile a side, beside a latent of 128 | 64 on pages of 16 and a value head
+    of 128 (inside the latent read's and the delta step's rules too): the
+    step kernel in interpret mode serves what XLA serves.  Under "auto" on
+    this CPU the module's engine ran none of its steps.  (Another
+    configuration under two implementations: builds of its own.)"""
+    wide = replace(TOY, kv_lora_rank=128, qk_rope_head_dim=64, n_layers=3,
+                   layer_types=TOY.layer_types[:3], gdn_d_v=128, d_model=128, moe_d_ff=128)
+    check_the_step_kernel_serves_what_xla_serves(
+        FAMILY, wide, monkeypatch, chunk=32, page_size=16, prefill_chunk=32)
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(standing.engine, monkeypatch, "cpu", ("auto",))

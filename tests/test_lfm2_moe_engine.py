@@ -24,7 +24,9 @@ from calfkit_tpu.inference.config import (
 )
 from calfkit_tpu.inference.engine import InferenceEngine
 from tests.arch_harness import LFM2_MOE as FAMILY
-from tests.arch_harness import Spy, both_forms_at_toy_size, standing  # noqa: F401 - fixtures
+from tests.arch_harness import (  # noqa: F401 - fixtures
+    Spy, both_forms_at_toy_size, check_the_step_kernel_is_not_taken, standing,
+)
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
 
@@ -250,3 +252,12 @@ def test_the_quantizer_and_the_state_kernel_s_rule_say_no_by_name():
     engine = InferenceEngine(wide, FAMILY.runtime(
         attention_impl="pallas_interpret", page_size=16, prefill_chunk=32))
     assert (engine._attn_impl, engine._ssm_impl) == ("pallas_interpret", "xla")
+
+
+def test_experts_held_whole_keep_the_dense_form_under_any_value(monkeypatch, standing):
+    """The step kernel (PR 53) is for experts held by SHARE: these are held
+    whole and hit whole, so on a TPU the engine takes it under no value of
+    ``attention_impl``, and the module's engine ran none of its steps."""
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(
+        standing.engine, monkeypatch, "tpu", ("auto", "pallas", "pallas_interpret", "xla"))

@@ -22,7 +22,7 @@ from calfkit_tpu.inference.config import SpecConfig, UnsupportedWithWindowLayers
 from calfkit_tpu.inference.engine import InferenceEngine
 from tests.arch_harness import MELLUM_MOE as FAMILY
 from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    Spy, both_forms_at_toy_size, collect, standing,
+    Spy, both_forms_at_toy_size, check_the_step_kernel_is_not_taken, collect, standing,
 )
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
@@ -243,3 +243,12 @@ def test_the_cell_s_agreement_holds_the_rotation_by_what_the_rows_leave(monkeypa
         assert result["ok"] == (not over) and result["compared"] >= 8, result
         assert printed.err.count("(limit <= ") == 4
         del engine
+
+
+def test_experts_held_whole_keep_the_dense_form_under_any_value(monkeypatch, standing):
+    """The step kernel (PR 53) is for experts held by SHARE: these are held
+    whole and hit whole, so on a TPU the engine takes it under no value of
+    ``attention_impl``, and the module's engine ran none of its steps."""
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(
+        standing.engine, monkeypatch, "tpu", ("auto", "pallas", "pallas_interpret", "xla"))

@@ -41,7 +41,8 @@ from calfkit_tpu.inference.config import (
 from calfkit_tpu.inference.engine import InferenceEngine
 from tests.arch_harness import MLA_MOE as FAMILY
 from tests.arch_harness import (  # noqa: F401 - both_forms_at_toy_size is an autouse fixture
-    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size, stack_check, standing,
+    STACK_LAYERS, STACK_ROUTINGS, Spy, both_forms_at_toy_size,
+    check_the_step_kernel_is_not_taken, stack_check, standing,
 )
 
 ARCH, LOGIT_TOL, TOY = FAMILY.arch, FAMILY.logit_tol, FAMILY.toy
@@ -580,3 +581,12 @@ def test_the_new_counters_reach_metrics_and_capacity(standing):
         assert name in text
     # the page accounting reads the description, not n_kv_heads x head_dim
     assert hbm_constants(replace(preset("kimi-vl-a3b-instruct"), n_layers=7))[1] == 8064.0
+
+
+def test_experts_held_whole_keep_the_dense_form_under_any_value(monkeypatch, standing):
+    """The step kernel (PR 53) is for experts held by SHARE: these are held
+    whole and hit whole, so on a TPU the engine takes it under no value of
+    ``attention_impl``, and the module's engine ran none of its steps."""
+    standing.serve([(FAMILY.prompt_of(20), 5)])
+    check_the_step_kernel_is_not_taken(
+        standing.engine, monkeypatch, "tpu", ("auto", "pallas", "pallas_interpret", "xla"))

@@ -835,6 +835,80 @@ def test_delta_step_compiles_for_v5e(cell, one_chip, no_persistent_cache):
     assert memory.temp_size_in_bytes < state_bytes // (6 * rows)  # under ONE row of a layer
 
 
+# the three shapes held by SHARE (experts held, hidden, expert width) with their cells'
+# slots: command-a-plus's, Qwen3-Next's, Ling's
+MOE_STEP_SHAPES = {
+    "command-a-plus-05-2026": ((16, 4096, 4096), 32),
+    "qwen3-next-80b-a3b-instruct": ((128, 2048, 512), 64),
+    "ling-3.0-flash-vl": ((64, 2560, 768), 128),
+}
+
+
+def _moe_step_args(shape, cell="ling-3.0-flash-vl", layers=3):
+    import jax.numpy as jnp
+
+    (E, D, F), rows = MOE_STEP_SHAPES[cell]
+    bf16 = jnp.bfloat16
+    stack = {"w_gate": shape((layers, E, D, F), bf16), "w_up": shape((layers, E, D, F), bf16),
+             "w_down": shape((layers, E, F, D), bf16)}
+    return (shape((rows, D), bf16), shape((rows, E), jnp.float32), shape((E,), jnp.bool_),
+            stack, shape((), jnp.int32))
+
+
+def _moe_step_entry(shape):
+    from calfkit_tpu.inference import pallas_moe as PM
+
+    return PM.moe_step_pallas, lambda *a: PM.moe_step_pallas(*a), _moe_step_args(shape)
+
+
+@pytest.mark.parametrize("cell", sorted(MOE_STEP_SHAPES))
+def test_moe_step_compiles_for_v5e(cell, one_chip, no_persistent_cache):
+    """The expert step kernel at the three shapes held by share, nested as a
+    decode dispatch nests it (a scan over the stack's layers inside a loop
+    over steps, the layer a traced index): ONE kernel, under the caller's
+    scope and named ``experts``; the stack is read where it lies: no array
+    of a stack's or of a layer's experts' shape among the temporaries or made
+    in a loop, and the temporaries are under ONE expert's matrices."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from calfkit_tpu.inference import pallas_attention as PA
+    from calfkit_tpu.inference import pallas_moe as PM
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    (E, D, F), rows = MOE_STEP_SHAPES[cell]
+    Lm, steps = 3, 4
+    h, gates, hit, stack, _ = _moe_step_args(shape, cell, Lm)
+
+    def dispatch(stack, x, gates, hit):
+        def layer(x, m):
+            with jax.named_scope("moe"):
+                return x + PM.moe_step_pallas(x, gates, hit, stack, m), None
+
+        def step(_, x):
+            return lax.scan(layer, x, jnp.arange(Lm, dtype=jnp.int32))[0]
+
+        return lax.fori_loop(0, steps, step, x)
+
+    before = PA.KERNEL_TRACES["moe_step", "compiled"]
+    PM.moe_step_pallas.clear_cache()
+    compiled = jax.jit(dispatch).lower(stack, h, gates, hit).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe/jit(moe_step_pallas)/experts/pallas_call" in hlo
+    assert PA.KERNEL_TRACES["moe_step", "compiled"] == before + 1
+    a_stack = re.compile(
+        rf"= bf16\[(?:{Lm},)?{E},(?:{D},{F}|{F},{D})\]\S* (copy|transpose|fusion)\(")
+    assert not a_stack.search(hlo)
+    assert not _made_in_loops(hlo, (f"bf16[{E},{D},{F}]", f"bf16[{E},{F},{D}]"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * D * F * 2  # ONE expert's matrices
+
+
 def _latent_decode_entry(shape):
     from calfkit_tpu.inference import pallas_attention as PA
 
@@ -900,7 +974,8 @@ def test_chunk_attention_compiles_for_v5e(window, one_chip, no_persistent_cache)
 
 
 @pytest.mark.parametrize(
-    "kernel", ["paged_decode", "ssm_step", "latent_decode", "chunk_attention", "delta_step"])
+    "kernel",
+    ["paged_decode", "ssm_step", "latent_decode", "chunk_attention", "delta_step", "moe_step"])
 def test_kernel_bytes_do_not_depend_on_the_caller(
     kernel, one_chip, no_persistent_cache, monkeypatch
 ):
@@ -918,7 +993,8 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
     entry, f, args = {"paged_decode": _paged_decode_entry, "ssm_step": _ssm_step_entry,
                       "latent_decode": _latent_decode_entry,
                       "chunk_attention": _chunk_attention_entry,
-                      "delta_step": _delta_step_entry}[kernel](shape)
+                      "delta_step": _delta_step_entry,
+                      "moe_step": _moe_step_entry}[kernel](shape)
 
     def deep(*a, depth=4):
         if depth:
@@ -944,7 +1020,7 @@ def test_kernel_bytes_do_not_depend_on_the_caller(
 
 
 def test_entry_point_list_is_complete():
-    """The kernel modules' entry points are the seven compiled above (a
+    """The kernel modules' entry points are the eight compiled above (a
     merged read calls the plain one), ONE ``pallas_call`` a kernel body,
     and no other module of the package makes one: a kernel added without a
     compile of its own fails here."""
@@ -953,6 +1029,7 @@ def test_entry_point_list_is_complete():
 
     from calfkit_tpu.inference import pallas_attention as PA
     from calfkit_tpu.inference import pallas_gdn as PG
+    from calfkit_tpu.inference import pallas_moe as PM
     from calfkit_tpu.inference import pallas_ssm as PS
 
     def entries(module):
@@ -966,15 +1043,18 @@ def test_entry_point_list_is_complete():
     }
     assert entries(PS) == {"ssm_step_pallas"}
     assert entries(PG) == {"delta_step_pallas"}
+    assert entries(PM) == {"moe_step_pallas"}
     assert inspect.getsource(PA).count("pl.pallas_call(") == 3
     assert inspect.getsource(PS).count("pl.pallas_call(") == 1
     assert inspect.getsource(PG).count("pl.pallas_call(") == 1
+    assert inspect.getsource(PM).count("pl.pallas_call(") == 1
     with_kernels = sorted(
         os.path.basename(path)
         for path in glob.glob(os.path.join(os.path.dirname(PA.__file__), "*.py"))
         if "pl.pallas_call(" in open(path).read()
     )
-    assert with_kernels == ["pallas_attention.py", "pallas_gdn.py", "pallas_ssm.py"]
+    assert with_kernels == [
+        "pallas_attention.py", "pallas_gdn.py", "pallas_moe.py", "pallas_ssm.py"]
 
 
 # ---------------------------------------------------------------------------
@@ -1230,11 +1310,36 @@ def _delta_step_kernels(hlo: str) -> list[str]:
     return kernels
 
 
+def _under_moe_experts(hlo: str) -> bool:
+    """Does a decode step's expert product stand under ``.../mlp/moe/experts``:
+    the dense form's fusions, or the step kernel (a jit of its own, which
+    ``trace_reduce.scope_path`` leaves out of the path)?"""
+    import re
+
+    return bool(re.search(r"/mlp/moe/(?:jit\(moe_step_pallas\)/)?experts", hlo))
+
+
+def _moe_step_kernels(hlo: str, engine, a_loop: int) -> list[str]:
+    """The expert step kernel's calls (``pallas_moe.py``) in a program of
+    ``engine``: ``a_loop`` of them (the expert layers the decode loop's
+    bodies unroll) where the engine resolved the kernel, each under
+    ``decode_loop/.../mlp/moe/`` and named ``experts``, where
+    ``moe_expert_roofline`` reads; none where it did not."""
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and "/jit(moe_step_pallas)/experts/pallas_call" in line]
+    assert all("decode_loop/" in k and "/mlp/moe/" in k for k in kernels), kernels
+    assert len(kernels) == (a_loop if engine._moe_step_impl == "pallas" else 0), kernels
+    return kernels
+
+
 def _gdn_decode_checks(engine, compiled):
     """One paged decode read (the period's one attention layer, in the
     scan's body) under ``attention``; no window gathered; the state's pass
     the delta step kernel, once a DeltaNet layer of the period, under
     ``gdn/state``; the stacked state and the pool go out where they came in;
+    the routed experts of each of the period's four layers the step kernel
+    (PR 53: experts held by share), under ``mlp/moe``;
     NO copy of an expert stack, of a layer of it, or of the stacked state
     (the temporaries are under one layer's state: since PR 46 the dispatch's
     tokens go into the pool in place, and the layout copy of the pool that
@@ -1242,9 +1347,11 @@ def _gdn_decode_checks(engine, compiled):
     hlo = compiled.as_text()
     kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     reads = [k for k in kernels if "paged_decode_attention" in k]
-    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 3 == len(kernels) - 1, kernels
+    experts = _moe_step_kernels(hlo, engine, 4)
+    assert engine._moe_step_impl == "pallas" and "ragged-dot" not in hlo
+    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 3 == len(kernels) - 5, kernels
     assert "/attention/" in reads[0] and "decode_loop/" in reads[0]
-    assert "gather_window" not in hlo and "/gdn/state/" in hlo and "/mlp/moe/experts" in hlo
+    assert "gather_window" not in hlo and "/gdn/state/" in hlo and _under_moe_experts(hlo)
     cfg, rt = engine.config, engine.runtime
     assert not _expert_stack_copies(hlo, cfg)
     memory = compiled.memory_analysis()
@@ -1412,11 +1519,15 @@ def _window_cell_programs(engine, one_chip, buckets):
         *abstract(args), moe=abstract(zero)).compile()
     hlo = decode.as_text()
     kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    # the experts of the period's four layers through the step kernel, where they are held
+    # by share (PR 53; a test that holds FEWER than the file's makes a share of any)
+    experts = _moe_step_kernels(hlo, engine, 4)
+    kernels = [k for k in kernels if k not in experts]
     # the period's four decode reads in the scan's body: three of the window form, one global
     assert len(kernels) == 4 and all("paged_decode_attention" in k for k in kernels)
     assert sum("/attention/window/" in k for k in kernels) == 3
     assert sum("/attention/global/" in k for k in kernels) == 1
-    assert "gather_window" not in hlo and "/mlp/moe/experts" in hlo
+    assert "gather_window" not in hlo and _under_moe_experts(hlo)
     assert not _expert_stack_copies(hlo, cfg) and "ragged-dot" not in hlo
     pools = sum(a.nbytes for a in jax.tree.leaves((engine._k, engine._v)))
     assert decode.memory_analysis().alias_size_in_bytes >= pools
@@ -1597,11 +1708,15 @@ def _kda_checks(engine, name, compiled):
     kernels = [line for line in hlo.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line and "pallas_call" in line]
     reads = [k for k in kernels if "latent_decode" in k]
-    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 6 == len(kernels) - 1, kernels
+    # the experts through the step kernel (PR 53): one in the head's unrolled layers, the
+    # scan's period of four; no decode step's product is the compiler's ragged-dot any more
+    experts = _moe_step_kernels(hlo, engine, len(kernels) - 7)
+    assert engine._moe_step_impl == "pallas" and len(experts) >= 4
+    assert len(reads) == 1 and len(_delta_step_kernels(hlo)) == 6, kernels
     assert "/mla/" in reads[0] and "decode_loop/" in reads[0]
-    for scope in ("/gdn/state/", "/gdn/decay/", "/moe/router/groups", "/mla/kv_latent",
-                  "/mlp/moe/experts"):
+    for scope in ("/gdn/state/", "/gdn/decay/", "/moe/router/groups", "/mla/kv_latent"):
         assert scope in hlo, scope
+    assert _under_moe_experts(hlo)
     cfg, rt = engine.config, engine.runtime
     assert not _expert_stack_copies(hlo, cfg), name
     memory = compiled.memory_analysis()
@@ -1610,6 +1725,8 @@ def _kda_checks(engine, name, compiled):
     assert memory.alias_size_in_bytes >= state_bytes + pool_bytes
     if name.startswith("ragged"):  # the chunk: grouped experts, the two-level delta rule
         assert "chunk_loop/" in hlo and "ragged-dot" in hlo and "chunk_loop/" in hlo
+    else:
+        assert "ragged-dot" not in hlo
     return memory
 
 
@@ -1636,10 +1753,12 @@ def test_kda_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_persist
 
     engine = _kda_cell_engine()
     cfg, rt = engine.config, engine.runtime
-    # every product of this shape is grouped: its group gate sends a step few of the held
-    # experts, a crossing TIMED at 0 (moe.py, PR 45; PR 40 set it because the dense form, then
-    # spelled rows first, copied both stacks whole in the DECODE program: 7.4 GB of temporaries)
-    assert not moe.dense_form(rt.prefill_chunk, cfg) and not moe.dense_form(1, cfg)
+    # a chunk's products are grouped; a decode step's take the step kernel (PR 53: its group
+    # gate sends a step ~27 of the 64 held experts, which the kernel reads alone; through PR 52
+    # they were grouped too, ``moe._DENSE_TO_THE_CROSSING``'s row of 0, PRs 40 and 45) and
+    # what the row left to an engine WITHOUT the kernel is the dense form, the reference
+    assert not moe.dense_form(rt.prefill_chunk, cfg) and moe.dense_form(rt.max_batch_size, cfg)
+    assert engine._moe_step_impl == "pallas"
     report = {}
     for name, compiled in _kda_programs(engine, one_chip, (1, 2, 4)).items():
         memory = _kda_checks(engine, name, compiled)
