@@ -11,6 +11,7 @@ from dataclasses import dataclass, field, replace
 
 
 ATTENTION, MAMBA, GDN, WINDOW, KDA, CONV = "attention", "mamba", "gdn", "window", "kda", "conv"
+EVA = "eva"  # EVA attention: an exact ALIGNED window beside pooled summaries of what lies behind it
 RECURRENT_KINDS = (MAMBA, GDN, KDA, CONV)
 DELTA_RULE_KINDS = (GDN, KDA)  # the gated delta rule: a decay a head, or a key channel
 # What a layer of each kind leaves behind of a sequence, i.e. the cache kind
@@ -19,11 +20,16 @@ DELTA_RULE_KINDS = (GDN, KDA)  # the gated delta rule: a decay a head, or a key 
 # ``sliding_window`` tokens, in a ring of pages a row that it writes over
 # (gives back) as it grows; "state" = a recurrent state of fixed size a slot
 # (of a "conv" layer, a gated short convolution, the conv tail and NOTHING
-# else: the pair's matrix side is empty).
+# else: the pair's matrix side is empty); "window+summaries" = K and V of the
+# CURRENT aligned window of ``window_size`` tokens (a ring of pages a row that
+# the next window writes over: it empties at the window's edge, it does not
+# slide) AND one pooled key and value for every ``chunk_size`` tokens behind
+# it, in pages a row keeps for its whole life, computed from the exact ones
+# (eva.py) and read with them under one softmax.
 # A new kind of layer is a row here and a mixer in model.py.
 CACHE_KINDS = {
     ATTENTION: "global", WINDOW: "window", MAMBA: "state", GDN: "state", KDA: "state",
-    CONV: "state"}
+    CONV: "state", EVA: "window+summaries"}
 
 
 class UnsupportedWithRecurrentLayers(ValueError):
@@ -38,6 +44,13 @@ class UnsupportedWithWindowLayers(ValueError):
     layers) cannot be served under yet.  Raised when the engine is built,
     never later: there is no silent fallback to a path that knows no lower
     bound and would attend, or keep, what the window has left behind."""
+
+
+class UnsupportedWithEvaLayers(UnsupportedWithWindowLayers):
+    """A runtime option that a model with EVA layers (an aligned window ring
+    beside summary pages that are COMPUTED from it) cannot be served under
+    yet.  Raised when the engine is built, never later: there is no silent
+    fallback to a path that would read a summary as a key, or keep none."""
 
 
 class UnsupportedWithLatentAttention(ValueError):
@@ -153,6 +166,14 @@ class ModelConfig:
     layers with a SwiGLU of ``d_ff``, the expert block after them with no
     shared expert (``n_shared_experts`` 0) and the weights of a token's
     experts normalised over ``sum + 1e-6`` (``topk_norm_eps``).
+    With ``"eva"`` as EVERY entry of ``layer_types`` it is an EvaByte-style
+    decoder (``evabyte``): the pre-norm RMSNorm (``norm_plus_one``) + SwiGLU
+    block of ``d_ff`` with a float32 residual stream, whose mixer is EVA
+    attention (``eva.py``): a query sees the keys of its OWN aligned window of
+    ``window_size`` positions exactly and, of every window before it, one
+    pooled key and value a chunk of ``chunk_size`` positions, under one
+    softmax; the head has ``num_pred_heads x vocab_size`` rows, of which the
+    served token is drawn from the first ``vocab_size``.
     """
 
     name: str = "debug"
@@ -265,6 +286,15 @@ class ModelConfig:
     # conv_L_cache, conv_bias); its state is the last conv_L_cache - 1 inputs
     conv_L_cache: int = 0
     conv_bias: bool = False
+    # ---- EVA attention (all defaults = as before) ----
+    # an "eva" layer's query at position t sees the keys of window t // window_size
+    # exactly (window_size * (t // window_size) <= j <= t) and one pooled key and
+    # value for every chunk of chunk_size positions of the windows before it
+    window_size: int = 0
+    chunk_size: int = 0
+    # the head's rows are num_pred_heads x vocab_size, head h predicting token
+    # t + 1 + h; the served token is drawn from head 0
+    num_pred_heads: int = 1
 
     def __post_init__(self) -> None:
         if self.kv_lora_rank:
@@ -358,16 +388,28 @@ class ModelConfig:
             if unknown:
                 raise ValueError(f"unknown layer types {sorted(unknown)}")
             kinds = set(self.layer_types) & set(RECURRENT_KINDS)
-            if not kinds and WINDOW not in self.layer_types:
+            if not kinds and not {WINDOW, EVA} & set(self.layer_types):
                 raise ValueError(
-                    "layer_types without a mamba, gdn, kda, conv or window layer is "
+                    "layer_types without a mamba, gdn, kda, conv, window or eva layer is "
                     "the dense decoder: leave it empty"
                 )
             if len(kinds) > 1:
                 raise ValueError(
                     "mamba, gdn, kda and conv layers in one stack are not described: "
                     "one recurrent kind a stack")
-            if WINDOW in self.layer_types:
+            if EVA in self.layer_types:
+                if set(self.layer_types) != {EVA}:
+                    raise ValueError(
+                        "eva layers beside layers of another kind are not described")
+                if not (self.chunk_size >= 1 and self.window_size >= self.chunk_size
+                        and self.window_size % self.chunk_size == 0):
+                    raise ValueError(
+                        "eva layers need chunk_size >= 1 dividing window_size")
+                if self.n_routed_experts or self.tie_embeddings or self.num_pred_heads < 1:
+                    raise ValueError(
+                        "an eva stack's FFN is one SwiGLU of d_ff a layer and its head "
+                        "an untied matrix of num_pred_heads x vocab_size rows")
+            elif WINDOW in self.layer_types:
                 if kinds:
                     raise ValueError(
                         "window layers beside recurrent layers are not described")
@@ -445,16 +487,22 @@ class ModelConfig:
                 'rotate (layer_types with "window", position_embedding "rope")')
         if self.norm not in ("rms", "layer"):
             raise ValueError(f"unknown norm {self.norm!r}")
+        if EVA not in self.layer_types and (
+                self.window_size or self.chunk_size or self.num_pred_heads != 1):
+            raise ValueError(
+                'window_size, chunk_size and num_pred_heads belong to layer_types with "eva"')
         if GDN not in self.layer_types and (
             (self.qk_norm and CONV not in self.layer_types)
             or (self.attn_output_gate and KDA not in self.layer_types)
-            or self.norm_plus_one or self.partial_rotary_factor != 1.0
+            or (self.norm_plus_one and EVA not in self.layer_types)
+            or self.partial_rotary_factor != 1.0
             or (self.attn_head_dim and WINDOW not in self.layer_types)
         ):
             raise ValueError(
                 "attn_head_dim, qk_norm, attn_output_gate, norm_plus_one and "
                 'partial_rotary_factor belong to the Gated DeltaNet hybrid '
                 '(layer_types with "gdn"; attn_head_dim to the window stack too, '
+                'norm_plus_one to layer_types with "eva" too, '
                 'attn_output_gate to layer_types with "kda" too, qk_norm to '
                 'layer_types with "conv" too)'
             )
@@ -579,19 +627,29 @@ class ModelConfig:
         return self.n_layers - self.n_recurrent_layers
 
     @property
+    def eva(self) -> bool:
+        """Is every layer's mixer EVA attention (an aligned window ring AND
+        summary pages a layer)?"""
+        return EVA in self.layer_types
+
+    @property
     def windowed(self) -> bool:
-        """Does the stack have sliding-window layers (pages by cache kind)?"""
-        return WINDOW in self.layer_types
+        """Are the pages kept BY CACHE KIND (a ring of pages a row beside
+        pages it keeps): sliding-window layers, or EVA layers?"""
+        return WINDOW in self.layer_types or self.eva
 
     @property
     def window_layer_ids(self) -> tuple[int, ...]:
-        """The window layers' indices among the layers that keep K and V
-        (where their rows lie in the prefill scratch and the decode ring)."""
+        """The indices, among the layers that keep K and V, of those with a
+        RING of pages a row (where their rows lie in the prefill scratch and
+        the decode ring): the window layers; every layer of an EVA stack."""
         kv = [t for t in self.layer_types if t not in RECURRENT_KINDS]
-        return tuple(i for i, t in enumerate(kv) if t == WINDOW)
+        return tuple(i for i, t in enumerate(kv) if t in (WINDOW, EVA))
 
     @property
     def global_layer_ids(self) -> tuple[int, ...]:
+        """Those with pages a row keeps for its whole life: the global
+        layers; every layer of an EVA stack too (its summaries)."""
         kv = [t for t in self.layer_types if t not in RECURRENT_KINDS]
         return tuple(i for i, t in enumerate(kv) if t != WINDOW)
 
@@ -601,8 +659,19 @@ class ModelConfig:
 
     @property
     def n_global_layers(self) -> int:
-        """Layers whose K and V of EVERY token are kept."""
-        return self.n_kv_layers - self.n_window_layers
+        """Layers with pages a row keeps: K and V of EVERY token, or an EVA
+        layer's summaries."""
+        return len(self.global_layer_ids)
+
+    @property
+    def attention_window(self) -> int:
+        """Positions a ring has to hold: the sliding or the aligned window."""
+        return self.window_size if self.eva else self.sliding_window
+
+    def summary_entries(self, tokens: int) -> int:
+        """Summary entries a row of ``tokens`` positions can come to hold in
+        an EVA layer: one a COMPLETE chunk."""
+        return tokens // self.chunk_size
 
     def window_ring_pages(self, page_size: int, ahead: int) -> int:
         """Pages in a row's ring of a window layer: the window, what one
@@ -610,7 +679,7 @@ class ModelConfig:
         steps; a prompt's chunks stay in the prefill scratch until they land),
         and one page more, because the window's oldest key and the newest
         write each lie anywhere in their page."""
-        return -(-(self.sliding_window + ahead) // page_size) + 1
+        return -(-(self.attention_window + ahead) // page_size) + 1
 
     @property
     def layer_period(self) -> tuple[str, ...]:
@@ -769,6 +838,13 @@ class ModelConfig:
                 + self.n_kv_layers * attention + self.n_recurrent_layers * mixer
                 + self.n_dense_layers * 3 * self.d_model * self.d_ff + self.n_moe_layers * moe
             )
+        if self.eva:
+            # the seven matrices, the two norms, phi and mu a layer; the final
+            # norm, the embedding and the head's num_pred_heads x vocab_size rows
+            layer = (attention + 3 * self.d_model * self.d_ff + 2 * self.d_model
+                     + 2 * self.n_kv_heads * self.head_dim)
+            return (self.n_layers * layer + self.d_model + self.vocab_size * self.d_model
+                    + self.num_pred_heads * self.vocab_size * self.d_model)
         if self.windowed:
             expert = 3 * self.d_model * self.moe_d_ff
             ffn = (
@@ -1438,6 +1514,51 @@ PRESETS: dict[str, ModelConfig] = {
         moe_d_ff=16,
         scoring_func="softmax",
         topk_method="greedy",
+    ),
+    # EvaByte (HF: EvaByte/EvaByte, model_type evabyte): a byte-level decoder of
+    # 32 layers whose every mixer is EVA attention (Zheng et al., arXiv:2302.04542):
+    # an exact aligned window of 2,048 bytes beside one pooled key and value for
+    # every 16 bytes behind it; 32 heads of 128 (one query a KV head), SwiGLU of
+    # 11,008, norms that multiply by 1 + w, a vocabulary of 320 and a head of
+    # 8 x 320 rows (head h predicts byte t + 1 + h; head 0 is served)
+    "evabyte": ModelConfig(
+        name="evabyte",
+        vocab_size=320,
+        d_model=4096,
+        n_layers=32,
+        n_heads=32,
+        n_kv_heads=32,
+        d_ff=11008,
+        rope_theta=100000.0,
+        norm_eps=1e-5,
+        max_seq_len=32768,
+        tie_embeddings=False,
+        layer_types=(EVA,) * 32,
+        norm_plus_one=True,
+        window_size=2048,
+        chunk_size=16,
+        num_pred_heads=8,
+    ),
+    # the same kind at toy size, for the tests: windows of 32 in chunks of 4
+    # (8 summaries a window), 2 prediction heads, contexts of 4+ windows
+    "debug-evabyte": ModelConfig(
+        name="debug-evabyte",
+        vocab_size=64,
+        d_model=32,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=48,
+        rope_theta=100000.0,
+        norm_eps=1e-5,
+        max_seq_len=256,
+        dtype="float32",
+        tie_embeddings=False,
+        layer_types=(EVA,) * 3,
+        norm_plus_one=True,
+        window_size=32,
+        chunk_size=4,
+        num_pred_heads=2,
     ),
 }
 

@@ -56,6 +56,7 @@ from calfkit_tpu.inference.config import (
     RuntimeConfig,
     UnsupportedWithLatentAttention,
     UnsupportedWithRecurrentLayers,
+    UnsupportedWithEvaLayers,
     UnsupportedWithWindowLayers,
 )
 from calfkit_tpu.inference.mamba import make_recurrent_state
@@ -148,6 +149,11 @@ CHUNK_ATTN_FIELDS = (
     "chunk_attn_pairs_window", "chunk_attn_pairs_global",
     "chunk_attn_key_blocks_visited", "chunk_attn_key_blocks_dense",
 )
+# an EVA stack's two caches at work (EngineStats has what each counts)
+EVA_FIELDS = (
+    "decode_eva_window_tokens_read", "decode_eva_summaries_read", "eva_chunks_pooled",
+    "eva_windows_closed", "chunk_attn_pairs_eva_window", "chunk_attn_pairs_eva_summary",
+)
 # the EngineStats fields folded into /metrics counters once a dispatch
 # counters that go to /metrics and ``counters()`` only, never on the
 # heartbeat advert's window
@@ -155,7 +161,7 @@ _LOCAL_FIELDS = (
     "decode_pages_live", "decode_pages_window",
     "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
     "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
-    *CHUNK_ATTN_FIELDS, "chunk_tokens", "chunk_tokens_padding",
+    *EVA_FIELDS, *CHUNK_ATTN_FIELDS, "chunk_tokens", "chunk_tokens_padding",
     "pipeline_drains", "pipeline_drains_wave", "wave_landings_deferred",
     "programs_built", "moe_assignments", "moe_assignments_absent",
     "moe_rows_in_held_groups", "moe_expert_tokens_max",
@@ -310,6 +316,42 @@ def _engine_metrics(
             "calfkit_engine_decode_global_tokens_read_total",
             "decode steps: rows x len x global layers, of a model with window "
             "layers beside them",
+        ),
+        decode_eva_window_tokens_read=reg.counter(
+            "calfkit_engine_decode_eva_window_tokens_read_total",
+            "decode steps of a model with EVA layers: rows x the exact keys of the "
+            "query's own aligned window (q mod window_size, + 1 for itself) x layers, "
+            "summed over a dispatch's steps",
+        ),
+        decode_eva_summaries_read=reg.counter(
+            "calfkit_engine_decode_eva_summaries_read_total",
+            "the same for the pooled entries: rows x (window_size / chunk_size) x "
+            "(q // window_size) x layers, summed over steps",
+        ),
+        eva_chunks_pooled=reg.counter(
+            "calfkit_engine_eva_chunks_pooled_total",
+            "chunks of the rows' own tokens pooled into a summary entry, x layers: "
+            "by a prefill chunk, or by the decode dispatch whose tokens completed them",
+        ),
+        eva_windows_closed=reg.counter(
+            "calfkit_engine_eva_windows_closed_total",
+            "aligned windows the rows left behind (a prompt's complete windows at "
+            "its wave's landing; a decode dispatch that crosses an edge)",
+        ),
+        chunk_attn_pairs_eva_window=reg.counter(
+            "calfkit_engine_chunk_attn_pairs_eva_window_total",
+            "prefill chunks of a model with EVA layers: the (query, exact key) pairs "
+            "the chunk's own positions must attend inside their window, x layers",
+        ),
+        chunk_attn_pairs_eva_summary=reg.counter(
+            "calfkit_engine_chunk_attn_pairs_eva_summary_total",
+            "the same for (query, pooled entry) pairs: own positions x the summaries "
+            "of the windows before the chunk, x layers",
+        ),
+        eva_summary_cache_bytes=reg.gauge(
+            "calfkit_engine_eva_summary_cache_bytes",
+            "device bytes reserved for the summary pages of a model with EVA layers "
+            "(the last engine built; 0 without them)",
         ),
         chunk_attn_pairs_window=reg.counter(
             "calfkit_engine_chunk_attn_pairs_window_total",
@@ -608,12 +650,12 @@ def _finalize_wave_math(
     callers trace it) — the last/lens scatter used to run eagerly on the
     host, costing two XLA dispatches PER REQUEST at admission."""
     R = slots.shape[0]
-    P = sk.shape[3]
     if paged:
         k, v = M.write_prefill_pages((k, v), (sk, sv), scatter_ids, _layer_kinds(cfg))
         # (pools by kind: the tables and the wave's rows are pairs)
         tables = jax.tree.map(lambda table, rows: table.at[slots].set(rows), tables, page_rows)
     else:
+        P = sk.shape[3]
         for r in range(R):  # R is small & static: unrolled row scatter
             k = lax.dynamic_update_slice_in_dim(
                 k, lax.dynamic_slice_in_dim(sk, r, 1, axis=1)[:, :, :, :P],
@@ -994,6 +1036,17 @@ class EngineStats:
     moe_grouped_chunks: int = 0
     moe_dense_chunks: int = 0
     latent_cache_bytes: int = 0
+    # an EVA stack (0 without one): the exact keys of their own window and the
+    # pooled entries behind it that the decode steps' rows had to read (rows x
+    # entries x layers, summed over steps); chunks pooled and windows closed;
+    # the pairs its prefill chunks had to attend; a gauge, the summary pool's bytes
+    decode_eva_window_tokens_read: int = 0
+    decode_eva_summaries_read: int = 0
+    eva_chunks_pooled: int = 0
+    eva_windows_closed: int = 0
+    chunk_attn_pairs_eva_window: int = 0
+    chunk_attn_pairs_eva_summary: int = 0
+    eva_summary_cache_bytes: int = 0
     # snapshot_and_delta state: the previous window's counter values +
     # timestamp.  Single-consumer by design (the heartbeat advert) — two
     # delta readers would steal each other's intervals.
@@ -1179,6 +1232,7 @@ class EngineStats:
         out["occupancy_hist"] = list(self.occupancy_hist)
         out["recurrent_state_bytes"] = self.recurrent_state_bytes  # a gauge
         out["latent_cache_bytes"] = self.latent_cache_bytes  # a gauge
+        out["eva_summary_cache_bytes"] = self.eva_summary_cache_bytes  # a gauge
         for gauge in ("kv_pages_global_in_use", "kv_pages_window_in_use",
                       "kv_pages_global_total", "kv_pages_window_total"):
             out[gauge] = getattr(self, gauge)
@@ -1359,6 +1413,9 @@ class InferenceEngine:
         # bound; what knows neither yet is refused HERE, with its reason
         self._windowed = config.windowed
         if self._windowed:
+            kind, unsupported = (
+                ("EVA layers", UnsupportedWithEvaLayers) if config.eva
+                else ("sliding-window layers", UnsupportedWithWindowLayers))
             refused = {
                 "tp > 1 / dp > 1": (rt.tp > 1 or rt.dp > 1 or self.mesh.size > 1,
                                     "the two pools and the expert leaves have no sharding "
@@ -1377,15 +1434,46 @@ class InferenceEngine:
             }
             for option, (asked, why) in refused.items():
                 if asked:
-                    raise UnsupportedWithWindowLayers(
-                        f"{config.name} has sliding-window layers: RuntimeConfig "
+                    raise unsupported(
+                        f"{config.name} has {kind}: RuntimeConfig "
                         f"{option} is not supported with them ({why})"
                     )
-            if rt.decode_steps_per_dispatch > config.sliding_window:
+            if rt.decode_steps_per_dispatch > config.attention_window:
                 raise UnsupportedWithWindowLayers(
                     f"{config.name}: decode_steps_per_dispatch "
                     f"({rt.decode_steps_per_dispatch}) is more than the window "
-                    f"({config.sliding_window}): a dispatch's fresh tokens are read whole")
+                    f"({config.attention_window}): a dispatch's fresh tokens are read whole")
+        # an EVA stack keeps, beside the ring, summary pages that are COMPUTED
+        # from it: what cannot hold with that is refused HERE too, by name
+        if config.eva:
+            from calfkit_tpu.inference.model import positions_per_row
+
+            W, c = config.window_size, config.chunk_size
+            refused = {
+                "prefix_cache": (rt.prefix_cache,
+                                 "a summary page holds what a row pooled of its own ring: "
+                                 "no page of either pool outlives its row for a later "
+                                 "prompt to share"),
+                "chunked_prefill=False": (not rt.chunked_prefill,
+                                          "a prompt is prefilled a window at a time against "
+                                          "the summaries of the windows before it"),
+                f"prefill_chunk={rt.prefill_chunk}": (
+                    rt.prefill_chunk != W,
+                    f"a prefill chunk is ONE window ({W}): its own part is the causal block "
+                    "the chunk kernel computes, everything before it summaries"),
+                f"page_size={rt.page_size}": (
+                    rt.page_size % c != 0 or (W // c) % rt.page_size != 0
+                    or positions_per_row(config.head_dim, rt.page_size, config.dtype) != 1,
+                    f"a chunk ({c}) lies in one page, a window's summaries ({W // c}) fill "
+                    "whole pages, and the ring is read by position (a head of whole lane "
+                    "tiles, or pages too small to pack a narrower one)"),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise UnsupportedWithEvaLayers(
+                        f"{config.name} has EVA layers: RuntimeConfig {option} is not "
+                        f"supported with them ({why})"
+                    )
         shardings = param_shardings(config, self.mesh)
         if params is None:
             logger.info(
@@ -1496,6 +1584,15 @@ class InferenceEngine:
                     f"({rt.page_size} vs {rt.max_seq_len})"
                 )
             n_pages = rt.pool_pages()
+            # entries of a row's table of pages it keeps: a page a page_size
+            # tokens, or (an EVA stack's summary pages) a page_size CHUNKS
+            self._pages_per_seq = rt.pages_per_seq()
+            if config.eva:
+                from calfkit_tpu.inference.paged import pages_needed
+
+                self._pages_per_seq = pages_needed(
+                    config.summary_entries(rt.max_seq_len), rt.page_size)
+                n_pages = rt.num_kv_pages or B * self._pages_per_seq + 1
             pool_sh = pool_sharding(config, self.mesh)
             # born sharded, like the params: never whole on one device
             # the pool is a pair: K and V, or the two parts (c, k_rope) of the
@@ -1517,7 +1614,7 @@ class InferenceEngine:
                         config, n_pages, rt.page_size, window_pages=n_window),
                     out_shardings=((pool_sh, pool_sh), (pool_sh, pool_sh)),
                 )()
-                self._tables = (jnp.zeros((B, rt.pages_per_seq()), jnp.int32),
+                self._tables = (jnp.zeros((B, self._pages_per_seq), jnp.int32),
                                 jnp.zeros((B, self._ring_pages), jnp.int32))
                 # ONE ledger for both pools, in pages of equal bytes: a
                 # LAYER's page (a global page is n_global_layers of them, a
@@ -1737,6 +1834,8 @@ class InferenceEngine:
         if self._windowed:
             self.stats.kv_pages_global_total, self.stats.kv_pages_window_total = (
                 a.num_pages - 1 for a in self._page_alloc.by_kind)
+        if config.eva:
+            self.stats.eva_summary_cache_bytes = self._k[0].nbytes + self._v[0].nbytes
         # a dispatch of a model with routed experts carries their counters
         # beside whatever state it carries (moe.py): zeros in, the
         # dispatch's counts out, read at the landing's one sync
@@ -1787,6 +1886,7 @@ class InferenceEngine:
         self.metrics = _engine_metrics()
         self.metrics["recurrent_state_bytes"].set(self.stats.recurrent_state_bytes)
         self.metrics["latent_cache_bytes"].set(self.stats.latent_cache_bytes)
+        self.metrics["eva_summary_cache_bytes"].set(self.stats.eva_summary_cache_bytes)
         self.metrics["kv_pages_global_total"].set(self.stats.kv_pages_global_total)
         self.metrics["kv_pages_window_total"].set(self.stats.kv_pages_window_total)
         # per-ENGINE latency histograms: the advert's percentiles must
@@ -2030,6 +2130,13 @@ class InferenceEngine:
         first and last token, to join the ``engine.dispatch`` spans between."""
         return self._done_seq
 
+    def _wpages(self, window: int) -> int:
+        """A decode context bucket of ``window`` tokens in pages of the pool
+        the rows keep: of tokens, or (an EVA stack) of a summary a chunk."""
+        if self.config.eva:
+            window = self.config.summary_entries(window)
+        return max(1, -(-window // self.runtime.page_size))
+
     def _window_bucket(self, needed: int) -> int:
         """Smallest configured window ≥ needed (cap max_seq): the decode
         attention scan only reads this prefix of the cache, and each bucket
@@ -2120,8 +2227,7 @@ class InferenceEngine:
     ) -> Any:
         """Decode dispatch reading/writing KV through the block tables."""
         steps = steps or self.runtime.decode_steps_per_dispatch
-        page = self.runtime.page_size
-        wpages = -(-window // page)
+        wpages = self._wpages(window)
         fn = self._decode_jits.get((wpages, steps, sampled, "paged"))
         if fn is not None:
             return fn
@@ -2177,9 +2283,14 @@ class InferenceEngine:
             (ring, last, *st), toks = lax.scan(
                 step, (ring, last, *_some(state), *_some(moe)), jnp.arange(steps)
             )
-            k2, v2 = M.consolidate_ring_paged(
-                (k, v), ring, tables, lens, active, _layer_kinds(cfg)
-            )
+            if cfg.eva:  # the ring's tokens, then the chunks they completed, pooled
+                from calfkit_tpu.inference.eva import consolidate as eva_consolidate
+
+                k2, v2 = eva_consolidate(params, cfg, (k, v), ring, tables, lens, active)
+            else:
+                k2, v2 = M.consolidate_ring_paged(
+                    (k, v), ring, tables, lens, active, _layer_kinds(cfg)
+                )
             new_lens = jnp.where(active, lens + steps, lens)
             n_valid, done = retire_mask_slots(
                 toks.T, stop_table, hard_end - lens, active
@@ -2464,8 +2575,7 @@ class InferenceEngine:
         dispatch pair.  Both halves trace the SAME body builders as their
         standalone jits, so ragged-on output is structurally identical to
         ragged-off."""
-        page = self.runtime.page_size
-        wkey = -(-window // page) if self._paged else window
+        wkey = self._wpages(window) if self._paged else window
         key = ("ragged", wkey, steps, sampled, chunk, rows)
         fn = self._decode_jits.get(key)
         if fn is not None:
@@ -3735,6 +3845,12 @@ class InferenceEngine:
             ),
             rt.pages_per_seq(),
         )
+        if self.config.eva:
+            # the pages it keeps hold a summary a chunk: of the bucket (a prefill
+            # lands whole pages of them) or of its whole life
+            entries = self.config.summary_entries
+            need = min(pages_needed(max(entries(bucket), entries(total)), rt.page_size),
+                       self._pages_per_seq)
         if self._windowed:
             # (a prefill lands whole pages of the row's OWN tokens alone in a
             # ring, so the bucket does not count here)
@@ -4432,9 +4548,19 @@ class InferenceEngine:
             lens.size * chunk - np.clip(lens - offset, 0, chunk).sum())
         if not self._windowed:
             return
+        cfg = self.config
+        if cfg.eva:
+            # the rows' own positions of this window: each attends the keys of the
+            # window up to itself and every summary of the windows before; their
+            # complete chunks are pooled
+            own = np.clip(lens - offset, 0, chunk)
+            self.stats.chunk_attn_pairs_eva_window += int((own * (own + 1) // 2).sum()) * cfg.n_layers
+            self.stats.chunk_attn_pairs_eva_summary += (
+                int(own.sum()) * cfg.summary_entries(offset) * cfg.n_layers)
+            self.stats.eva_chunks_pooled += int((own // cfg.chunk_size).sum()) * cfg.n_layers
+            return
         from calfkit_tpu.inference.pallas_attention import chunk_attention_work
 
-        cfg = self.config
         pairs_w, pairs_g, visited, dense = chunk_attention_work(
             offset, chunk, bucket, true_lens, cfg.sliding_window,
             cfg.n_window_layers, cfg.n_global_layers)
@@ -4483,7 +4609,7 @@ class InferenceEngine:
         scalar ``moe_*`` counters are sums over it."""
         return None if self._moe_counts is None else self._moe_counts.copy()
 
-    def window_ring(self, layer: int = 0) -> "jax.Array | None":
+    def window_ring(self, layer: int = 0, values: bool = False) -> "jax.Array | None":
         """The keys of ONE window layer as every slot's ring of pages holds
         them now, ``[slots, K, ring pages x page, hd]`` (entry ``r`` of a row:
         the newest position ``p = r`` mod the ring's tokens that the row has
@@ -4498,21 +4624,24 @@ class InferenceEngine:
         stored form."""
         if not self._windowed:
             return None
+        side = self._v if values else self._k  # (``values``: the V side in place of the K)
         return M.gather_window_paged(
-            self._k[1][layer], self._tables[1], self._ring_pages, self.config.head_dim)
+            side[1][layer], self._tables[1], self._ring_pages, self.config.head_dim)
 
-    def global_keys(self, slot: int, layer: int = 0) -> "jax.Array | None":
+    def global_keys(self, slot: int, layer: int = 0, values: bool = False) -> "jax.Array | None":
         """The keys of ONE global layer (its index among the global layers) as
         ONE slot's global pages hold them now, ``[K, pages a sequence x page,
         hd]``, position ``p`` at entry ``p``; None for a model without window
-        layers.  One slot at a time: every slot's would be a copy of the whole
-        pool.  Stands after retirement as ``window_ring`` does, and is read
+        layers.  An EVA stack's pages of this kind hold its SUMMARIES: entry
+        ``j`` the pooled key (``values``: the pooled value) of chunk ``j``.
+        One slot at a time: every slot's would be a copy of the whole pool.  Stands after retirement as ``window_ring`` does, and is read
         out of the stored pool the same way."""
         if not self._windowed:
             return None
         table = self._tables[0][slot:slot + 1]
+        side = self._v if values else self._k
         return M.gather_window_paged(
-            self._k[0][layer], table, table.shape[1], self.config.head_dim)[0]
+            side[0][layer], table, table.shape[1], self.config.head_dim)[0]
 
     def recurrent_state(self) -> "tuple[jax.Array, jax.Array] | None":
         """The slots' recurrent state as it stands, ``(matrix [layers, slots,
@@ -4560,8 +4689,12 @@ class InferenceEngine:
 
         R = len(wave)
         page = self.runtime.page_size
-        pmax = self.runtime.pages_per_seq()
-        npg = bucket // page
+        pmax = self._pages_per_seq
+        eva = self.config.eva
+        # pages of the bucket's tokens, or (an EVA stack) every page of summaries
+        # a row can hold: its scratch is sized for the longest prompt, and what
+        # lies past the row's reservation goes to the trash page
+        npg = pmax if eva else bucket // page
         page_rows = np.zeros((R, pmax), np.int32)
         scatter_ids = np.zeros((R, npg), np.int32)
         for r, request in enumerate(wave):
@@ -4582,15 +4715,20 @@ class InferenceEngine:
         # prompt in entry j % ring, and only the last ``ring`` pages that hold
         # the row's own tokens (an older page would be written over by a
         # newer one in the same scatter; a page past the prompt is padding)
+        # (an EVA stack's scratch holds the exact keys of the prompt's LAST chunk
+        # alone: its pages from ``first`` on, and its windows before are closed)
         ring = self._ring_pages
+        first = (bucket - self.config.window_size) // page if eva else 0
         ring_rows = np.zeros((R, ring), np.int32)
-        ring_ids = np.full((R, npg), TRASH_PAGE, np.int32)
+        ring_ids = np.full((R, bucket // page - first), TRASH_PAGE, np.int32)
         for r, request in enumerate(wave):
             ring_rows[r] = table_row(request.ring_pages, ring)
             last = (len(request.prompt) - 1) // page
             self.stats.window_pages_given_back += max(0, last + 1 - ring)
-            landing = np.arange(max(0, last - len(request.ring_pages) + 1), last + 1)
-            ring_ids[r, landing] = ring_rows[r, landing % ring]
+            landing = np.arange(max(first, last - len(request.ring_pages) + 1), last + 1)
+            ring_ids[r, landing - first] = ring_rows[r, landing % ring]
+            if eva:
+                self.stats.eva_windows_closed += len(request.prompt) // self.config.window_size
         return [self._tables, (jnp.asarray(page_rows), jnp.asarray(ring_rows)),
                 (jnp.asarray(scatter_ids), jnp.asarray(ring_ids))]
 
@@ -4748,6 +4886,12 @@ class InferenceEngine:
             )
             self.stats.prefix_hits += len(wave)
             self.stats.prefix_reused_tokens += reuse * len(wave)
+        elif cfg.eva:  # the prompt's summaries and ONE chunk's exact keys (eva.py)
+            from calfkit_tpu.inference.eva import make_scratch
+
+            # (sized for the longest prompt whatever the bucket: the chunk programs
+            # have ONE shape, and a chunk walks the key blocks its row has)
+            scratch = make_scratch(cfg, R, self.runtime.max_seq_len, _pool_dtype(self._k))
         else:
             scratch = M.cache_sides(self.config, scratch_shape, _pool_dtype(self._k))
         self._inflight = dict(
@@ -5128,6 +5272,20 @@ class InferenceEngine:
         # blocking-ok: THE designated sync point (see docstring)
         return np.asarray(arrays)
 
+    def _note_eva_steps(self, steps: int) -> None:
+        """Count a decode dispatch of an EVA stack at launch (host arithmetic
+        over the active rows' lengths, no sync): what each step's query has to
+        read of its row's two caches, the chunks the dispatch's tokens complete
+        and the windows they close."""
+        cfg, stats = self.config, self.stats
+        W, c = cfg.window_size, cfg.chunk_size
+        lens = self._host_lens[list(self._active)].astype(np.int64)
+        q = lens[:, None] + np.arange(steps)[None, :]  # [rows, steps] the queries' positions
+        stats.decode_eva_window_tokens_read += int((q % W + 1).sum()) * cfg.n_layers
+        stats.decode_eva_summaries_read += int((q // W).sum()) * (W // c) * cfg.n_layers
+        stats.eva_chunks_pooled += int(((lens + steps) // c - lens // c).sum()) * cfg.n_layers
+        stats.eva_windows_closed += int(((lens + steps) // W - lens // W).sum())
+
     def _decode_args(self) -> "tuple[list, int, int, bool]":
         """Assemble one decode dispatch's host-side inputs (shared by the
         overlap launch and the lockstep tick): returns (args, window,
@@ -5141,7 +5299,7 @@ class InferenceEngine:
             active_mask[slot] = True
             needed = max(needed, self._host_lens[slot])
             live_pages += -(-int(self._host_lens[slot]) // page)
-            if self._windowed:
+            if self._windowed and not self.config.eva:
                 global_tokens += int(self._host_lens[slot])
                 window_tokens += min(int(self._host_lens[slot]), self.config.sliding_window)
         # the ring covers in-dispatch growth; the window only needs to cover
@@ -5169,7 +5327,9 @@ class InferenceEngine:
             self.stats.decode_pages_window += (
                 self.runtime.max_batch_size * -(-window // page) * steps
             )
-            if self._windowed:  # (a step later reads a token more a row: not counted)
+            if self.config.eva:
+                self._note_eva_steps(steps)
+            elif self._windowed:  # (a step later reads a token more a row: not counted)
                 cfg = self.config
                 self.stats.decode_window_tokens_read += window_tokens * steps * cfg.n_window_layers
                 self.stats.decode_global_tokens_read += global_tokens * steps * cfg.n_global_layers

@@ -102,6 +102,21 @@ pipeline's later stages) with one :class:`LayersSkipped` notice:
     mlp.experts.{e}.{gate,up,down}_proj.weight    → layers.moe.w_gate/w_up/w_down [held, ..]
     model.norm, model.embed_tokens, lm_head       → final_norm, embed, lm_head [D, V]
 A share ``(r, s)`` is accepted as for ``cohere2_moe``.
+``model_type`` ``evabyte`` (EvaByte; ``attention_class`` ``eva``) loads into
+the EVA stack's tree (``_build_eva_params``; ``eva.py`` has the layer).  The
+tensor names are ASSUMED (the Llama family's, with the two learned vectors
+beside the projections) and unverified against the published files;
+``adaptive_phi`` and ``adaptive_mu_k`` come as ``[1, H, 1, 1, hd]`` and are
+held ``[H, hd]`` a layer; the head's ``num_pred_heads x vocab_size`` rows are
+kept in their order (head-major: head 0, the served one, is the first
+``vocab_size``); the rotation pairs a head's halves, so no column is
+permuted; layers past the description's ``n_layers`` are skipped and counted
+(:class:`LayersSkipped`):
+    self_attn.{q,k,v,o}_proj.weight               → layers.wq, wk, wv [L, D, H hd], wo [L, H hd, D]
+    self_attn.adaptive_phi, adaptive_mu_k         → layers.phi, mu [L, H, hd]
+    input_layernorm, post_attention_layernorm     → layers.attn_norm, mlp_norm (g of 1 + g)
+    mlp.{gate,up,down}_proj.weight                → layers.w_gate, w_up, w_down
+    model.norm, model.embed_tokens, lm_head [P V, D] → final_norm, embed, lm_head [D, P V]
 ``model_type`` ``bailing_hybrid`` (Ling-3.0-flash, and Ling-3.0-flash-VL's
 text decoder: tensors outside ``model.`` and ``lm_head.``, a tower and its
 projector, skipped and counted with one :class:`VisionTowerSkipped` notice;
@@ -196,6 +211,8 @@ def config_from_hf(path: str | Path, share: "tuple[int, int] | None" = None) -> 
         raise ValueError(
             f"{path}: a share is described for qwen3_next, cohere2_moe, bailing_hybrid and "
             "mellum alone")
+    if raw.get("model_type") == "evabyte":
+        return _evabyte_config(raw, str(path))
     if raw.get("model_type") == "lfm2_moe":
         return _lfm2_moe_config(raw, str(path))
     if raw.get("model_type") == "granitemoehybrid":
@@ -449,6 +466,38 @@ def _mellum_config(raw: dict, path: str, share: "tuple[int, int] | None") -> Mod
     )
 
 
+def _evabyte_config(raw: dict, path: str) -> ModelConfig:
+    """EvaByte's ``config.json`` -> the EVA stack's description.  A key that
+    selects a variant is held to the ONE reading described (``eva.py``)."""
+    from calfkit_tpu.inference.config import EVA
+
+    for key, only in (("attention_class", "eva"), ("attention_bias", False),
+                      ("hidden_act", "silu"), ("tie_word_embeddings", False),
+                      ("rope_scaling", None), ("norm_add_unit_offset", True),
+                      ("fp32_skip_add", True), ("num_chunks", None)):
+        if raw.get(key, only) != only:
+            raise ValueError(f"{path}: {key} = {raw[key]!r} is not supported")
+    L = raw["num_hidden_layers"]
+    return ModelConfig(
+        name=raw.get("_name_or_path", path),
+        vocab_size=raw["vocab_size"],
+        d_model=raw["hidden_size"],
+        n_layers=L,
+        n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"],
+        d_ff=raw["intermediate_size"],
+        rope_theta=float(raw["rope_theta"]),
+        norm_eps=float(raw.get("rms_norm_eps", 1e-5)),
+        max_seq_len=raw.get("max_position_embeddings", 2048),
+        tie_embeddings=False,
+        layer_types=(EVA,) * L,
+        norm_plus_one=True,
+        window_size=raw["window_size"],
+        chunk_size=raw["chunk_size"],
+        num_pred_heads=raw.get("num_pred_heads", 1),
+    )
+
+
 def _bailing_hybrid_config(raw: dict, path: str, share: "tuple[int, int] | None") -> ModelConfig:
     """``bailing_hybrid``'s ``config.json`` (Ling-3.0-flash and its -VL's text
     decoder) -> the Kimi Delta Attention hybrid's description.  A key that
@@ -621,7 +670,7 @@ def load_params(
                 f"{path}: {tower} tensors outside 'model.' and 'lm_head.' (a vision tower "
                 "and its projector) were not loaded: the language decoder serves text alone"
             ), stacklevel=2)
-    if config.windowed:
+    if config.windowed and not config.eva:
         normed = sorted(name for name in files
                         if re.search(r"\.self_attn\.[qk]_norm\.", name))
         if normed:
@@ -687,6 +736,10 @@ def _build_params(
         if quantize is not None:
             raise ValueError("no quantized load for a model with short-convolution layers")
         return _build_shortconv_params(config, shardings, get)
+    if config.eva:
+        if quantize is not None:
+            raise ValueError("no quantized load for a model with EVA layers")
+        return _build_eva_params(config, shardings, get)
     if config.windowed:
         if quantize is not None:
             raise ValueError("no quantized load for a model with window layers and experts")
@@ -1235,6 +1288,50 @@ def _build_mellum_params(config: ModelConfig, shardings: dict[str, Any], get: An
     logger.info("loaded %s params (experts %d-%d of %d, vocabulary rows %d-%d)", c.name,
                 c.expert_first, c.expert_first + c.n_routed_experts - 1, c.experts_scored,
                 rows.start, rows.stop - 1)
+    return jax.tree.map(jax.device_put, tree, shardings)
+
+
+def _build_eva_params(config: ModelConfig, shardings: dict[str, Any], get: Any) -> dict[str, Any]:
+    """The EVA stack's tree from the ASSUMED EvaByte names (module text).  No
+    column is permuted (the rotation pairs a head's halves); the head keeps
+    its ``num_pred_heads x vocab_size`` rows in their order."""
+    import jax
+
+    c = config
+    D, H, hd = c.d_model, c.n_heads, c.head_dim
+    dtype = np.dtype(c.dtype)
+    stack, _ = _layer_stackers(c, get)
+    head = get("lm_head.weight")
+    if head.shape != (c.num_pred_heads * c.vocab_size, D):
+        raise ValueError(
+            f"lm_head.weight is {head.shape}: the description's head has num_pred_heads x "
+            f"vocab_size = {c.num_pred_heads} x {c.vocab_size} rows of {D}")
+
+    def a_head(w: np.ndarray) -> np.ndarray:  # [1, H, 1, 1, hd] -> [H, hd]
+        if w.size != H * hd:
+            raise ValueError(f"a learned vector of {w.shape}: one of {hd} a head ({H}) is described")
+        return w.reshape(H, hd)
+
+    tree: dict[str, Any] = {
+        "embed": get("model.embed_tokens.weight").astype(dtype),
+        "layers": {
+            "wq": stack("self_attn.q_proj.weight", lambda w: w.T),
+            "wk": stack("self_attn.k_proj.weight", lambda w: w.T),
+            "wv": stack("self_attn.v_proj.weight", lambda w: w.T),
+            "wo": stack("self_attn.o_proj.weight", lambda w: w.T),
+            "phi": stack("self_attn.adaptive_phi", a_head),
+            "mu": stack("self_attn.adaptive_mu_k", a_head),
+            "attn_norm": stack("input_layernorm.weight", lambda w: w),
+            "mlp_norm": stack("post_attention_layernorm.weight", lambda w: w),
+            "w_gate": stack("mlp.gate_proj.weight", lambda w: w.T),
+            "w_up": stack("mlp.up_proj.weight", lambda w: w.T),
+            "w_down": stack("mlp.down_proj.weight", lambda w: w.T),
+        },
+        "final_norm": get("model.norm.weight").astype(dtype),
+        "lm_head": head.T.astype(dtype),
+    }
+    logger.info("loaded %s params (%d EVA layers, a head of %d x %d rows)", c.name, c.n_layers,
+                c.num_pred_heads, c.vocab_size)
     return jax.tree.map(jax.device_put, tree, shardings)
 
 

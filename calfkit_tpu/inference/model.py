@@ -185,6 +185,18 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Any = None) -> Param
                 "lm_head": norm_init(jax.random.split(keys[0])[0], (D, V), D)}),
         }
 
+    if config.eva:
+        # an EvaByte-style stack (eva.py): a head of num_pred_heads x vocab rows
+        from calfkit_tpu.inference.eva import init_eva_params
+
+        return {
+            "embed": norm_init(keys[0], (V, D), D),
+            "layers": init_eva_params(config, keys[1], dtype),
+            "final_norm": (jnp.zeros if config.norm_plus_one else jnp.ones)((D,), dtype),
+            "lm_head": norm_init(
+                jax.random.split(keys[0])[0], (D, config.num_pred_heads * V), D),
+        }
+
     if config.windowed:
         layers = {
             "attn": {
@@ -1313,6 +1325,10 @@ def forward(
         # explicit insert_at serves RAGGED chunks (speculative draft
         # catch-up: per-row valid lengths shorter than the padded width)
         insert_at = seq_lens - tokens.shape[1]  # where this chunk lands
+    if config.eva:  # one window against the wave's scratch of summaries (eva.py)
+        from calfkit_tpu.inference.eva import eva_forward
+
+        return eva_forward(params, config, tokens, positions, kv_cache, chunk_attn_impl)
     k_pages, v_pages = kv_cache  # [L, B, K, Smax, hd]
     W = attn_window or k_pages.shape[3]
     if config.latent and not config.layer_types:
@@ -2018,6 +2034,8 @@ def make_page_pool(
       ``(global [Lg, N, K, page / f, f * hd], window [Lw, window_pages, K,
       page / f, f * hd])``: ``num_pages`` pages for the layers that keep every
       token, ``window_pages`` for the layers that keep a ring of pages a row.
+      An EVA stack's layers are of BOTH kinds: the first pool holds every
+      layer's summaries, the second every layer's ring (eva.py).
 
     A reader cannot tell ``hd`` from the array: it takes the head's width
     from ``config.cache_dims`` (or from the queries, the ring or the scratch
@@ -2102,6 +2120,11 @@ def decode_step_ring_paged(
     (:func:`pallas_attention.latent_rope_view`), which a caller that loops
     over steps makes once, outside its loop.
     """
+    if config.eva:  # an aligned window ring beside summary pages (eva.py)
+        from calfkit_tpu.inference.eva import eva_decode_step_paged
+
+        return eva_decode_step_paged(
+            params, config, tokens, pool, tables, ring, t, base_lens, wpages, attn_impl, active)
     if config.windowed:
         return _window_decode_step_paged(
             params, config, tokens, pool, tables, ring, t, base_lens, wpages, attn_impl,
@@ -2319,6 +2342,10 @@ def write_prefill_pages(
     of the scratch go to the row's global pages, the window layers' to its
     ring, where the caller has named the trash page for every page of the
     scratch but the last ``R`` that hold the row's own tokens."""
+    if isinstance(scratch[0], tuple):  # an EVA stack's scratch of summaries (eva.py)
+        from calfkit_tpu.inference.eva import write_prefill_pages as write_eva_pages
+
+        return write_eva_pages(pool, scratch, page_ids)
     if isinstance(page_ids, tuple):
         (kg, kw), (vg, vw) = pool
         gl, wl = (jnp.asarray(ids, jnp.int32) for ids in layer_kinds)

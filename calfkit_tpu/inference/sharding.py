@@ -40,6 +40,7 @@ GATED_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "attn_norm", "q_norm", "k_norm")
 MLP_LEAVES = ("w_gate", "w_up", "w_down", "mlp_norm")
 MOE_LEAVES = ("router", "router_bias", *MLP_LEAVES)
 SHARED_EXPERT_LEAVES = ("s_gate", "s_up", "s_down")
+EVA_LEAVES = ("wq", "wk", "wv", "wo", "phi", "mu", "attn_norm", *MLP_LEAVES)
 
 
 def make_mesh(
@@ -114,6 +115,11 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         if config.moe:
             layers["moe"] = dict.fromkeys(
                 MOE_LEAVES + (SHARED_EXPERT_LEAVES if config.n_shared_experts else ()), whole)
+    elif config.eva:
+        # an EVA stack (eva.py): every leaf replicated (the engine refuses such a
+        # model on a mesh of more than one device: the summary pool, which is
+        # COMPUTED from the ring, has no exchange over tp)
+        layers = dict.fromkeys(EVA_LEAVES, NamedSharding(mesh, P()))
     elif config.windowed:
         # a window stack (see model.py): every leaf replicated, as the latent
         # stack's are and for its reason (one device holds its SHARE of the
@@ -175,7 +181,7 @@ def param_shardings(config: ModelConfig, mesh: Mesh) -> Params:
         "final_norm": ns([(D, None)]),
     }
     if not config.tie_embeddings:
-        shardings["lm_head"] = ns([(D, None), (V, "tp")])
+        shardings["lm_head"] = ns([(D, None), (V * config.num_pred_heads, "tp")])
     return shardings
 
 

@@ -94,6 +94,14 @@ SCOPES = frozenset({
     # a gated short convolution's mixer (shortconv.py), in decode steps and
     # chunks: "in_proj", "conv" and "out_proj" inside it are Mamba-2's names
     "shortconv",
+    # an EVA layer's mixer (eva.py), in decode steps and chunks: "qkv",
+    # "attention" and "attn_out" inside it are the dense decoder's names;
+    # a decode step's two reads are "attention/window" (the ring under the
+    # aligned bound and the fresh tokens) and "attention/summary" (the summary
+    # pages, and past an edge the dispatch crossed the chunks it completed
+    # itself, pooled), their union under one softmax "merge", the chunk pooling
+    # "pool" (under "eva" in a chunk, under "kv_write" where a dispatch lands)
+    "eva", "summary", "merge", "pool",
 })
 UNSCOPED = "(unscoped)"
 UNATTRIBUTED = "unattributed"

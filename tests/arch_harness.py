@@ -1,5 +1,6 @@
 """ONE harness for the per-architecture test families (tests/test_hybrid_mamba,
-test_mla_moe*, test_gdn_moe*, test_cohere2_moe*, test_mellum_moe*, test_kda_mla_moe* and their
+test_mla_moe*, test_gdn_moe*, test_cohere2_moe*, test_mellum_moe*, test_kda_mla_moe*,
+test_evabyte* and their
 kernels' files): a ``Family`` record a kind of model, and what every family's
 tests do with it.
 
@@ -499,6 +500,30 @@ LFM2_MOE = Family(
     dense_max_tokens=8,
 )
 
+# EVA attention in every layer (EvaByte's kind; preset ``debug-evabyte``): an
+# exact ALIGNED window of 32 beside one pooled key and value for every 4
+# positions behind it, 4 heads of 8 (one query a KV head), a float32 residual
+# stream, norms that multiply by 1 + g, a head of 2 x 64 rows of which head 0 is
+# served.  A prefill chunk is one window; a dispatch of 8 steps crosses a
+# window's edge with up to two chunks of its own completed behind it.
+EVABYTE = Family(
+    arch_name="evabyte-eva",
+    toy=preset("debug-evabyte"),
+    # float32 against float32: the two sides differ in the ORDER of sums (the
+    # ring, the summary pages and the fresh tokens merged by their maxima
+    # against one softmax over a whole row, the chunk's keys behind the
+    # summaries in one blocked pass) and in nothing else.  Two readings set the
+    # limit, over 3 layers and logits up to 4.2 in size: the stated program reads
+    # 2.1e-6 at the worst position of a forward of four windows at both heads
+    # and 1.8e-6 through the engine across three edges; the nearest control, a
+    # pooling softmax taken in bfloat16, 5.3e-3 (uniform weights 0.52, no mu
+    # 0.61; their tests assert each).  1e-4 stands a factor of 48 above the
+    # first and 53 below the second.
+    logit_tol=1e-4,
+    runtime_over=dict(max_seq_len=256, window_buckets=(256,), prefill_chunk=32,
+                      decode_steps_per_dispatch=8),
+    forward_takes=(),
+)
 
 # ----------------------------------------------------------------------------
 # The grouped expert products over the STACK (PR 34): one reading shared by

@@ -1380,7 +1380,7 @@ def test_the_manifest_carries_lfm2_s_cell_and_its_two_metrics():
                 "mla_cache_roofline"} & registered
     for listed in ("out_tok_s_per_chip",):
         metric = next(m for m in MANIFEST["end_to_end"] if m["name"] == listed)
-        assert LFM2_CELL in metric["workloads"][-2:]  # last, until PR 47's cell joined
+        assert LFM2_CELL in metric["workloads"][-3:]  # last, until PR 47's and PR 54's cells joined
 
 
 # ------------------------------------------------ mellum2-12b-a2.5b-instruct (PR 47)
@@ -1568,16 +1568,16 @@ def test_the_mixed_lengths_traffic_is_the_issue_s():
 
 def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
     cell = M.resolve_cell(MANIFEST, MELLUM_CELL, M.ROOT)
-    entry = MANIFEST["configs"][-1]
+    entry = MANIFEST["configs"][7]  # the eighth configuration, appended at PR 47
     assert entry["name"] == MELLUM and entry["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == ("https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/"
                                "blob/main/config.json")
-    assert MANIFEST["workloads"][-1]["name"] == MELLUM_CELL and len(MANIFEST["workloads"]) == 8
-    assert len(MANIFEST["workloads"][-1]["why"]) <= 200 and len(entry["why"]) <= 200
+    assert MANIFEST["workloads"][7]["name"] == MELLUM_CELL and len(MANIFEST["workloads"]) >= 8
+    assert len(MANIFEST["workloads"][7]["why"]) <= 200 and len(entry["why"]) <= 200
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 0
     own = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [MELLUM_CELL]]
     # (the last two until PR 52's eight, in every cell, were appended behind them)
-    assert own == MANIFEST["per_layer"][-10:-8] and [m["name"] for m in own] == [
+    assert own == MANIFEST["per_layer"][29:31] and [m["name"] for m in own] == [
         "global_cache_roofline", "chunk_padding_pct"]
     assert own[0] == {"name": "global_cache_roofline", "unit": "%", "better": "higher",
                       "source": "device_trace", "layer": "kernels", "moves": "tpot_p95_ms",
@@ -1589,7 +1589,8 @@ def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
     for listed in ("swa_cache_roofline", "swa_device_pct", "chunk_attn_roofline",
                    "moe_device_pct", "moe_expert_roofline", "moe_grouped_roofline"):
         metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
-        assert metric["workloads"][-1] == MELLUM_CELL and listed in registered, listed
+        # (appended last at PR 47; what a later PR's cell joined stands behind it)
+        assert MELLUM_CELL in metric["workloads"][-2:] and listed in registered, listed
     assert {"global_cache_roofline", "chunk_padding_pct", "dispatch_roofline",
             "device_idle_closed_pct"} <= registered
     # delivered tokens/s judges the cell: six untraced seeds of the final tree spread it 1.1%
@@ -1598,7 +1599,8 @@ def test_the_manifest_carries_mellum_s_cell_and_its_two_metrics():
     for listed in ("batch_occupancy_pct", "empty_slot_queued_pct", "kv_pages_peak_pct",
                    "hbm_peak_gb", "moe_expert_load_ratio"):
         metric = next(m for m in MANIFEST["per_layer"] if m["name"] == listed)
-        assert metric["workloads"][-1] == MELLUM_CELL and listed in registered, listed
+        # (appended last at PR 47; what a later PR's cell joined stands behind it)
+        assert MELLUM_CELL in metric["workloads"][-2:] and listed in registered, listed
     assert not {"ssm_state_roofline", "gdn_state_roofline", "mla_cache_roofline",
                 "shortconv_mixer_roofline"} & registered
     assert "kv_pages_given_back_pct" not in {m["name"] for m in MANIFEST["per_layer"]}
@@ -1621,13 +1623,13 @@ def test_the_manifest_holds_the_eight_stream_metrics_appended_in_every_cell():
     """Appended behind everything that was there, with no ``workloads`` key
     (the manifest's spelling of "every cell"), each moving ``tpot_p95_ms``,
     each with its file and a reader; nothing that was there moved."""
-    last = MANIFEST["per_layer"][-8:]
+    last = MANIFEST["per_layer"][31:39]  # (what later PRs appended stands behind them)
     assert [m["name"] for m in last] == list(STREAM_METRICS)
     for m in last:
         unit, source, layer = STREAM_METRICS[m["name"]]
         assert m == {"name": m["name"], "unit": unit, "better": "lower", "source": source,
                      "layer": layer, "moves": "tpot_p95_ms"}
-    assert len(MANIFEST["per_layer"]) == 39 and len(MANIFEST["workloads"]) == 8
+    assert len(MANIFEST["per_layer"]) >= 39 and len(MANIFEST["workloads"]) >= 8
     for row in MANIFEST["workloads"]:
         cell = M.resolve_cell(MANIFEST, row["name"], M.ROOT)
         registered = {m.name: m for m in cell.per_layer}

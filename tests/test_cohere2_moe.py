@@ -42,7 +42,8 @@ def _worst(logits, want, lens) -> float:
 # ------------------------------------------------ the description
 def test_the_description_lists_layer_kinds_with_their_cache_kinds():
     assert CACHE_KINDS == {"attention": "global", "window": "window", "mamba": "state",
-                           "gdn": "state", "kda": "state", "conv": "state"}
+                           "gdn": "state", "kda": "state", "conv": "state",
+                           "eva": "window+summaries"}
     assert TOY.layer_period == (WINDOW, WINDOW, WINDOW, ATTENTION)
     assert (TOY.n_window_layers, TOY.n_global_layers, TOY.n_kv_layers) == (6, 2, 8)
     assert TOY.window_layer_ids == (0, 1, 2, 4, 5, 6) and TOY.global_layer_ids == (3, 7)

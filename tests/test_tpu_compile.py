@@ -1895,3 +1895,92 @@ def test_shortconv_expert_cell_programs_at_full_size_fit_the_chip(one_chip, no_p
         assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9, (name, report)
     _no_larger_than_at_pr_44("lfm2", {name: temp for name, (temp, _) in report.items()})
     print("temporaries and arguments, bytes:", report)
+
+
+def _eva_cell_engine(layers: int = 2, slots: int = 4):
+    """An engine of the EvaByte cell's widths, runtime and kernels on the CPU,
+    with ``layers`` of its 8 layers (ONE scan over them: the body is traced
+    once whatever their number) and ``slots`` of its 16 slots (the kernels'
+    grid is the rows: the body is the same), so that the test holds 2 GB and
+    not 12."""
+    import json
+    from dataclasses import replace
+
+    from benchmarks import manifest
+    from calfkit_tpu.inference.engine import InferenceEngine
+
+    here = os.path.dirname(manifest.__file__)
+    with open(os.path.join(here, "configs", "evabyte.json")) as f:
+        described = json.load(f)
+    arch = manifest.load_architecture(described["architecture"], here)
+    config, runtime = arch.model(described, False)
+    assert config.layer_period == ("eva",) and config.n_layers == 8
+    assert (config.d_model, config.n_heads, config.head_dim, config.d_ff) == (4096, 32, 128, 11008)
+    config = replace(config, n_layers=layers, layer_types=("eva",) * layers)
+    engine = InferenceEngine(config, replace(
+        runtime, max_batch_size=slots, compilation_cache=False, attention_impl="pallas"))
+    assert engine._attn_impl == engine._chunk_attn_impl == "pallas"
+    assert (engine._ring_pages, engine._pages_per_seq) == (34, 28)
+    return engine
+
+
+def test_eva_cell_dispatch_programs_compile_for_v5e(one_chip, no_persistent_cache):
+    """EvaByte's cell at its published widths (2 of its 8 layers, 4 of its 16
+    slots): the decode dispatch holds the paged decode kernel TWICE a layer,
+    its window form over the ring under ``eva/attention/window`` and its
+    global form over the summary pages under ``eva/attention/summary``,
+    gathers no window, and gives both pools out where they came in; no pool
+    side is copied for the pooling's reads (a loop of window reads) or its
+    writes; the ragged program holds the chunk kernel under
+    ``chunk_loop/.../eva/attention`` over the summaries with the chunk's keys
+    behind them, and no scores of a chunk cross HBM."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from calfkit_tpu.inference.eva import make_scratch
+
+    engine = _eva_cell_engine()
+    rt, cfg = engine.runtime, engine.config
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    args, window, steps, sampled = engine._decode_args()
+    assert window == 28672 == rt.max_seq_len and steps == 8
+    decode = engine._decode_jit(window, steps, sampled).lower(*abstract(args)).compile()
+    hlo = decode.as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2 and all("paged_decode_attention" in k for k in kernels), kernels
+    assert sum("/eva/attention/window/" in k for k in kernels) == 1
+    assert sum("/eva/attention/summary/" in k for k in kernels) == 1
+    assert "gather_window" not in hlo
+    pools = sum(a.nbytes for a in jax.tree.leaves((engine._k, engine._v)))
+    side = max(a.nbytes for a in jax.tree.leaves((engine._k, engine._v)))
+    memory = decode.memory_analysis()
+    assert memory.alias_size_in_bytes >= pools
+    # nothing the size of a pool side is made: the tail's and the pooling's reads
+    # of the ring and the summaries' write are windows of the stored pool
+    assert memory.temp_size_in_bytes < side / 2, (memory.temp_size_in_bytes, side)
+    report = {"decode": memory.temp_size_in_bytes}
+    chunk = rt.prefill_chunk
+    scratch = make_scratch(cfg, 1, rt.max_seq_len, jnp.bfloat16)
+    wave = [*scratch, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32)]
+    ragged = engine._ragged_jit(window, steps, sampled, chunk, 1).lower(
+        *abstract((*args, *wave))).compile()
+    text = ragged.as_text()
+    assert "decode_loop/" in text and "chunk_loop/" in text
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    chunk_calls = [c for c in calls if "chunk_attention" in c]
+    assert len(chunk_calls) == 1 and len(calls) == 3, (len(chunk_calls), len(calls))
+    assert re.search(r'chunk_loop/[^"]*eva/attention/', chunk_calls[0])
+    assert re.search(r'chunk_loop/[^"]*eva/pool/', text)
+    # no scores of 32 heads over a key block in HBM (the loop made [1, 32, 1, 2048, 512] float32)
+    assert not re.search(rf"f32\[1,{cfg.n_heads},\d+,{chunk},\d+\]", text)
+    memory = ragged.memory_analysis()
+    report["ragged"] = memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 2.5e9
+    print("temporaries, bytes (2 layers, 4 slots):", report)
