@@ -158,7 +158,7 @@ EVA_FIELDS = (
 # counters that go to /metrics and ``counters()`` only, never on the
 # heartbeat advert's window
 _LOCAL_FIELDS = (
-    "decode_pages_live", "decode_pages_window",
+    "decode_pages_live", "decode_pages_window", "decode_rows_live",
     "prefix_reuse_declined_recurrent", "prefix_reuse_declined_window",
     "decode_window_tokens_read", "decode_global_tokens_read", "window_pages_given_back",
     *EVA_FIELDS, *CHUNK_ATTN_FIELDS, "chunk_tokens", "chunk_tokens_padding",
@@ -298,6 +298,11 @@ def _engine_metrics(
             "calfkit_engine_decode_pages_window_total",
             "paged decode steps: rows in the program x the window bucket's "
             "pages (what the XLA window gather copies)",
+        ),
+        decode_rows_live=reg.counter(
+            "calfkit_engine_decode_rows_live_total",
+            "paged decode steps: the active rows that hold a page (the walks "
+            "a read in place makes a layer; pages_live over it is a mean walk)",
         ),
         prefix_reuse_declined_window=reg.counter(
             "calfkit_engine_prefix_reuse_declined_window_total",
@@ -976,9 +981,13 @@ class EngineStats:
     # of the row lengths (no device sync): the pages the active rows hold,
     # ceil(len / page) each, beside rows in the program x the window
     # bucket's pages.  live / window by difference is the share of the XLA
-    # window gather's bytes that a read in place still moves.
+    # window gather's bytes that a read in place still moves.  The rows that
+    # hold a page, summed the same way, are the walks the paged decode kernel
+    # makes a layer: pages_live over rows_live is the mean walk in pages
+    # (the kernel's copy pipeline runs from one row's walk into the next).
     decode_pages_live: int = 0
     decode_pages_window: int = 0
+    decode_rows_live: int = 0
     # a second kind of per-sequence state (models with recurrent layers):
     # requests whose cached prefix went unused because reused pages carry
     # no state at their boundary; and, a gauge, the device bytes the slots'
@@ -5293,12 +5302,14 @@ class InferenceEngine:
         active_mask = np.zeros((self.runtime.max_batch_size,), bool)
         needed = 1
         page = self.runtime.page_size
-        live_pages = 0
+        live_pages = live_rows = 0
         window_tokens = global_tokens = 0
         for slot in self._active:
             active_mask[slot] = True
             needed = max(needed, self._host_lens[slot])
-            live_pages += -(-int(self._host_lens[slot]) // page)
+            pages = -(-int(self._host_lens[slot]) // page)
+            live_pages += pages
+            live_rows += pages > 0
             if self._windowed and not self.config.eva:
                 global_tokens += int(self._host_lens[slot])
                 window_tokens += min(int(self._host_lens[slot]), self.config.sliding_window)
@@ -5324,6 +5335,7 @@ class InferenceEngine:
         )
         if self._paged:
             self.stats.decode_pages_live += live_pages * steps
+            self.stats.decode_rows_live += live_rows * steps
             self.stats.decode_pages_window += (
                 self.runtime.max_batch_size * -(-window // page) * steps
             )

@@ -4,10 +4,12 @@ and a window stack's prefill chunk.
 Why kernels: the XLA paged read copies every row's whole window out of the
 pool before attending it (``model.gather_window_paged``), whatever the row
 holds.  A kernel reads each row's LIVE pages in place: one program a row,
-a loop over that row's own pages with double-buffered whole-slab copies out
-of the pool in HBM, bf16 operands into the MXU, mask + softmax statistics +
-weighted sum fused.  Its work follows the row lengths, not the window
-bucket.
+a loop over that row's own pages with whole-slab copies out of the pool in
+HBM, bf16 operands into the MXU, mask + softmax statistics + weighted sum
+fused.  Its work follows the row lengths, not the window bucket.  The K/V
+body's copies run in ONE pipeline over the rows of a step (PR 55): a
+program starts, past its own row's last block, the first blocks of the
+next row that has pages.
 
 The decode read has TWO bodies, because the two kinds of pool ask for
 different things of one page:
@@ -78,7 +80,15 @@ chip's numbers.  The two decode bodies AOT-compile for a described v5e, the firs
 Llama-3-8B's, Mistral-7B's, InternLM2-1.8B's and granite-4.0-h-micro's
 widths, the second at Kimi-VL-A3B's (``tests/test_tpu_compile.py``), and
 agree with interpret mode and the XLA path on CPU; PERF.md section 6, PRs
-25, 28 and 32, has the chip's numbers.
+25, 28 and 32, has the chip's numbers.  Since PR 55 the K/V body keeps 2 to
+4 slots of a block from the slab's bytes (:func:`paged_decode_slots`) and
+hands a row's first copies to the row before it; under the TPU interpreter
+(copies that land when waited for, NaN where none wrote, an error for a
+copy left unwaited) it is the parent's result bit for bit on every order
+of empty and live rows (``tests/test_paged_decode_attention.py``), and on
+the chip alone at seven cells' shapes (PERF.md section 6, PR 55).  The
+latent body is as it was: one block of 8 pages ahead, started by its own
+row.
 """
 
 from __future__ import annotations
@@ -132,6 +142,21 @@ def paged_decode_in_place_ok(head_dim: int, page: int, dtype) -> bool:
 # (tuned on the chip, PERF.md section 6): the scores of a block are one
 # [H, PAGES_PER_BLOCK * K * page] product
 PAGED_DECODE_PAGES_PER_BLOCK = 2
+# the bytes of K and V the paged decode kernel keeps IN FLIGHT, the block it
+# waits for among them (the chip's copy latency x its stream: on a v5e one
+# block ahead of 256-512 KB left a row's walk at half the peak, PERF.md
+# section 6, PR 55): the buffer gets as many slots of a block as hold them
+PAGED_DECODE_BYTES_IN_FLIGHT = 3 * 512 * 1024
+# a column no bound admits: another kv head's, in the stored column positions
+_NO_COLUMN = 1 << 30
+
+
+def paged_decode_slots(block_bytes: int) -> int:
+    """Slots of one block (its K and V, ``block_bytes`` together) in the
+    paged decode kernel's buffer: what holds ``PAGED_DECODE_BYTES_IN_FLIGHT``,
+    2 (the double buffer every slab had, and a slab of 1 MB a page keeps) to
+    4.  The one thing about the kernel that follows the slab's width."""
+    return min(4, max(2, -(-PAGED_DECODE_BYTES_IN_FLIGHT // block_bytes)))
 
 
 def _paged_decode_kernel(
@@ -141,9 +166,22 @@ def _paged_decode_kernel(
 ):
     """One ROW of a paged decode step: a loop over that row's own live
     pages, ``ceil(len / page)`` of them, each fetched as its whole
-    ``[K, page / f, f * hd]`` slab (contiguous in the pool) by a
-    double-buffered async copy.  Nothing past the row's length is read or
-    computed; a row of length 0 starts no copy at all.
+    ``[K, page / f, f * hd]`` slab (contiguous in the pool) by an async
+    copy.  Nothing past the row's length is read or computed; a row of
+    length 0 starts no copy at all, waits for none and is stepped over.
+
+    The rows of a step follow one another in ONE copy pipeline (PR 55).  The
+    buffer is a ring of S slots of a block that lives across the grid's
+    programs, and a cursor in SMEM (``ahead``: row, block, slot) names the
+    next block nobody has started: the first program finds the first row
+    that has pages and starts S - 1 blocks, and every block computed starts
+    one more, its own row's next or, past the row's last, the FIRST blocks of
+    the next row that has pages.  So a row's first block is in flight before
+    the row's program begins, S - 1 blocks are always on their way, and each
+    block is still waited for by the program of the row it belongs to, in the
+    slot the order of blocks gives it.  What does not depend on the row (the
+    head mask and every column's position in a block, ``cols``) is built by
+    the first program and read by the rest.
 
     All K heads of a block are scored in ONE product: q is the row's
     ``[H, hd]`` (H = K * G, a whole sublane tile where G alone is not),
@@ -171,77 +209,111 @@ def _paged_decode_kernel(
     """
     if ring:
         starts_ref, *refs = refs
-    q_ref, pool_k, pool_v, o_ref, m_ref, z_ref, kbuf, vbuf, sems = refs
+    q_ref, pool_k, pool_v, o_ref, m_ref, z_ref, kbuf, vbuf, sems, cols, ahead = refs
     b = pl.program_id(0)
-    P, K, rows, lanes = kbuf.shape[1:]  # a slab: rows of pack positions
+    B = tables_ref.shape[0]
+    S, P, K, rows, lanes = kbuf.shape  # slots of a block; a slab: rows of pack positions
     page = rows * pack
     H = q_ref.shape[1]  # pack copies of the row's query heads
     heads = H // pack
     C = P * K * rows
     layer = layer_ref[0]
-    kv_len = lens_ref[b]
-    if ring:
+
+    def walk(r):
+        """Row r's walk: (the table entry it starts at, its pages)."""
+        if not ring:
+            return 0, jnp.minimum(lax.div(lens_ref[r] + (page - 1), page), wpages)
         # positions seen: [start, len); the walk and the mask count from the
         # first page of them
-        first = starts_ref[b] // page
-        start = starts_ref[b] - first * page
-        kv_len = kv_len - first * page
-    n_pages = jnp.minimum(pl.cdiv(kv_len, page), wpages)
-    n_blocks = pl.cdiv(n_pages, P)
+        first = lax.div(starts_ref[r], page)
+        return first, jnp.minimum(
+            lax.div(lens_ref[r] - first * page + (page - 1), page), wpages)
 
-    def copies(blk, slot, i):
-        n = tables_ref[b, (first + blk * P + i) % wpages if ring else blk * P + i]
+    def next_slot(slot):
+        return jnp.where(slot + 1 == S, 0, slot + 1)
+
+    def row_with_pages(r):
+        """The first row at or after r that has pages; B where none has."""
+        return lax.while_loop(
+            lambda r: (r < B) & (walk(jnp.minimum(r, B - 1))[1] == 0), lambda r: r + 1, r)
+
+    def copies(slot, i, n):
+        """Page n of the layer into page i of a slot: its K copy, its V copy."""
         return (
-            pltpu.make_async_copy(
-                pool_k.at[layer, n], kbuf.at[slot, i], sems.at[0, slot]
-            ),
-            pltpu.make_async_copy(
-                pool_v.at[layer, n], vbuf.at[slot, i], sems.at[1, slot]
-            ),
+            pltpu.make_async_copy(pool_k.at[layer, n], kbuf.at[slot, i], sems.at[0, slot]),
+            pltpu.make_async_copy(pool_v.at[layer, n], vbuf.at[slot, i], sems.at[1, slot]),
         )
 
-    def for_live_pages(blk, slot, act):
-        for i in range(P):  # static: a partial last block skips its tail
-            @pl.when(blk * P + i < n_pages)
+    def start_ahead():
+        """Start the copies of the next block nobody has started, whichever
+        row's it is, and move the cursor past it; nothing once no row is left."""
+        r, blk, slot = ahead[0], ahead[1], ahead[2]
+
+        @pl.when(r < B)
+        def _():
+            first, n_pages = walk(r)
+            for i in range(P):  # static: a partial last block skips its tail
+                @pl.when(blk * P + i < n_pages)
+                def _():
+                    at = blk * P + i
+                    n = tables_ref[r, lax.rem(first + at, wpages) if ring else at]
+                    for dma in copies(slot, i, n):
+                        dma.start()
+
+            last = (blk + 1) * P >= n_pages
+            ahead[1] = jnp.where(last, 0, blk + 1)
+            ahead[2] = next_slot(slot)
+
+            @pl.when(last)
             def _():
-                for dma in copies(blk, slot, i):
-                    act(dma)
+                ahead[0] = row_with_pages(r + 1)
 
-    if P > 1:
-        # a partial last block leaves buffer pages no copy ever wrote:
-        # their columns are masked (p = 0), and 0 x garbage must stay 0
-        @pl.when(b == 0)
-        def _clear():
+    @pl.when(b == 0)
+    def _open():
+        if P > 1:
+            # a partial last block leaves buffer pages no copy ever wrote:
+            # their columns are masked (p = 0), and 0 x garbage must stay 0
             vbuf[...] = jnp.zeros_like(vbuf)
+        col = lax.broadcasted_iota(jnp.int32, (H, C), 1)
+        row = lax.broadcasted_iota(jnp.int32, (H, C), 0)
+        own_head = lax.rem(lax.div(col, rows), K) == lax.div(
+            row if pack == 1 else lax.rem(row, heads), group)
+        col_pos = lax.div(col, K * rows) * rows + lax.rem(col, rows)  # slab row in block
+        if pack > 1:
+            col_pos = col_pos * pack + lax.div(row, heads)  # copy j: positions f*c + j
+        cols[...] = jnp.where(own_head, col_pos, _NO_COLUMN)
+        ahead[0] = row_with_pages(0)
+        ahead[1] = ahead[2] = ahead[3] = 0
 
-    @pl.when(n_blocks > 0)
-    def _first():
-        for_live_pages(0, 0, lambda dma: dma.start())
+        def prime(_, carry):
+            start_ahead()
+            return carry
 
+        lax.fori_loop(0, S - 1, prime, 0)
+
+    first, n_pages = walk(b)
+    n_blocks = lax.div(n_pages + (P - 1), P)
+    kv_len = lens_ref[b] - first * page
+    if ring:
+        start = starts_ref[b] - first * page
     q = q_ref[0]  # [H, lanes], the cache's dtype
     scale = 1.0 / math.sqrt(lanes // pack)  # the law of the REAL head
-    col = lax.broadcasted_iota(jnp.int32, (H, C), 1)
-    row = lax.broadcasted_iota(jnp.int32, (H, C), 0)
-    own_head = (col // rows) % K == (row if pack == 1 else row % heads) // group
-    col_pos = (col // (K * rows)) * rows + col % rows  # slab row in block
-    if pack > 1:
-        col_pos = col_pos * pack + row // heads  # copy j: positions f*c + j
 
     def block(blk, carry):
-        m_prev, z_prev, acc = carry
-        slot = blk % 2
-
-        @pl.when(blk + 1 < n_blocks)
-        def _next():
-            for_live_pages(blk + 1, 1 - slot, lambda dma: dma.start())
-
-        for_live_pages(blk, slot, lambda dma: dma.wait())
+        m_prev, z_prev, acc, slot = carry
+        start_ahead()
+        for i in range(P):  # this block's own copies, started a while ago
+            @pl.when(blk * P + i < n_pages)
+            def _():
+                for dma in copies(slot, i, 0):  # a wait asks the slot and the size alone
+                    dma.wait()
         k = kbuf[slot].reshape(C, lanes)
         v = vbuf[slot].reshape(C, lanes)
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [H, C]
-        mask = own_head & (col_pos < kv_len - blk * (P * page))
+        col_pos = cols[...]
+        mask = col_pos < kv_len - blk * (P * page)
         if ring:
             mask = mask & (col_pos >= start - blk * (P * page))
         s = jnp.where(mask, s, -1e30)
@@ -256,15 +328,16 @@ def _paged_decode_kernel(
         acc = acc * alpha + lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        return m_new, z_new, acc
+        return m_new, z_new, acc, next_slot(slot)
 
-    m, z, acc = lax.fori_loop(
+    m, z, acc, ahead[3] = lax.fori_loop(
         0, n_blocks, block,
         (
             # the -1e29 floor of a fully masked row is where m starts
             jnp.full((H, 1), -1e29, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
             jnp.zeros((H, lanes), jnp.float32),
+            ahead[3],  # the slot of this row's first block: where the last row's walk ended
         ),
     )
     if pack > 1:
@@ -300,7 +373,7 @@ def _lane_block_copies(q: jax.Array, f: int) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("wpages", "interpret", "pages_per_block")
+    jax.jit, static_argnames=("wpages", "interpret", "pages_per_block", "slots")
 )
 def paged_decode_attention_pallas(
     q: jax.Array,  # [B, K, G, hd]
@@ -313,6 +386,7 @@ def paged_decode_attention_pallas(
     wpages: int,
     interpret: bool = False,
     pages_per_block: int = PAGED_DECODE_PAGES_PER_BLOCK,
+    slots: int = 0,  # 0: paged_decode_slots of a block's bytes
     window_starts: jax.Array | None = None,  # [B]: the WINDOW form (see below)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Paged decode attention → (o [B,K,G,hd] f32 unnormalized, m [B,K,G]
@@ -349,6 +423,7 @@ def paged_decode_attention_pallas(
         )
     _note_trace("paged_decode", interpret)
     P = max(1, min(pages_per_block, wpages))
+    S = slots or paged_decode_slots(2 * P * K * rows * lanes * pool_k.dtype.itemsize)
     ring = window_starts is not None
     kernel = functools.partial(
         _paged_decode_kernel, wpages=wpages, group=G, pack=f, **({"ring": True} if ring else {})
@@ -371,9 +446,11 @@ def paged_decode_attention_pallas(
             pl.BlockSpec((1, H, 1), row_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, P, K, rows, lanes), pool_k.dtype),
-            pltpu.VMEM((2, P, K, rows, lanes), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((S, P, K, rows, lanes), pool_k.dtype),
+            pltpu.VMEM((S, P, K, rows, lanes), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, S)),
+            pltpu.VMEM((f * H, P * K * rows), jnp.int32),  # cols: made by the first row
+            pltpu.SMEM((4,), jnp.int32),  # ahead: the copies' cursor, the walk's slot
         ],
     )
     o, m, z = pl.pallas_call(
@@ -385,7 +462,8 @@ def paged_decode_attention_pallas(
             jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
-            # rows in order: the scratch cleared by row 0 serves them all
+            # rows in order: the scratch row 0 makes serves them all, and a
+            # row's first copies are started while the row before it runs
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -380,13 +380,17 @@ KV_PAIR_MODELS = {
 # "dense-64" and "hybrid" (float32 heads of 64 on pages of 32: ``[L, N, K, 16, 128]``) were
 # recorded anew there on purpose (the kernel's operand is the pool itself, no reshape a dispatch,
 # and the write's windows are groups of 8 stored rows); "dense-128", stored as declared, traces what it traced.
+# Since PR 55 the kernel's rows follow one another in one copy pipeline (a cursor in SMEM, slots of
+# a block by the slab's bytes, the columns' mask made once): all three recorded anew on purpose; with
+# the parent's ``paged_decode_attention_pallas`` put back each traced to the hash pinned before
+# (4e46cc9b.. / 116bac2e.., d1a95305.. / aa41a91b.., 8e005985.. / 65a836dc..), letter for letter.
 TRACED_BEFORE = {
-    "dense-64": {"decode": "4e46cc9b12a37250cc23ddd7b466655497e470d054772064fb10f4f19400f771",
-                 "ragged": "116bac2e50c7b18059bb7d01b267fdd5e435d41983c3324e4fcbb07e072b9feb"},
-    "dense-128": {"decode": "d1a95305a9ec10a31b03d88894be17ade7ad6cf87967ec363752ad8c36df3d33",
-                  "ragged": "aa41a91bbab333771dd66406ca29f9f53b12d58a3579b6f105280d615a0cf3fc"},
-    "hybrid": {"decode": "8e005985d0151cb15d32c08a04e92aabb4f15e3a7e9afca2bc34e403c7d3adbe",
-               "ragged": "65a836dc73d227b494e40e8e295fb66e84017febc3548474d403ef08d2861aa3"},
+    "dense-64": {"decode": "0af28ebd1f14e386dea81dd69cd068322fb453ab974a6f90b3033da7ebf37493",
+                 "ragged": "7bbbc5b7eca9d50da97a9e7fe8858c32a7bfc7347aa60b3c906bae85da328568"},
+    "dense-128": {"decode": "7140b89da19cc8696d258dd6a68fc9230f697eb58de99158110dadba721cf0c0",
+                  "ragged": "3f67339dc13eacb87705442a0baea64ce64fc4374d8a8aa50a3727438dc53cb6"},
+    "hybrid": {"decode": "0d19b1ecc4055025e580834b08c627afb553790388b0af872666d8c6508a46e5",
+               "ragged": "3fd80cce6121b667c3f881437ed7cd98f307fcf58a3a8accce7d0a58b9d3ff24"},
 }
 
 

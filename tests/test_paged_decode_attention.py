@@ -6,6 +6,13 @@
   around a page edge, rows that read nothing, shared tables, the proof
   that a dead page is never read, the shape rule, the pool's stored form
   (``model.positions_per_row``: f = 1, 2 and 4 positions a row).
+- The ORDERS OF ROWS its one copy pipeline can get wrong (PR 55: a row's
+  first block is started by the row before it): empty rows first, last,
+  between and everywhere, one row alone, walks of one whole block and of a
+  partial last one, in the plain form and in the window form on wrapped
+  rings; and the same orders under the TPU interpreter, where a buffer no
+  copy wrote is NaN, a copy lands only when it is waited for, a copy left
+  unwaited is an error and a wait for one never started is a time-out.
 - The selector (``InferenceEngine._resolved_attn_impl``, PR 29): an
   explicit kernel request outside the rule is refused at construction,
   and under ``pallas_interpret`` no program of a paged engine builds any
@@ -55,37 +62,70 @@ PAGED_DECODE_CASES = {
     "mixed": ([0, 1, PD_PAGE - 1, PD_PAGE, PD_PAGE + 1, PD_WINDOW, 130, 17], (), None),
     "inactive-row-on-trash-page": ([70, 100, 9], (1,), None),
     "shared-table": ([150, 150, 40], (), (0, 1)),
+    "one-row-partial-last-block": ([150], (), None),
+    **{name: (lens, (), None) for name, lens in {
+        # the orders of rows the copy pipeline walks (PR 55), four rows each
+        "zero-between-live": [130, 0, 0, 70],
+        "zero-first": [0, 0, 200, 64],
+        "zero-last": [256, 10, 0, 0],
+        "every-row-zero": [0, 0, 0, 0],
+        "one-live-of-four": [0, 0, 129, 0],
+        "walks-of-one-block": [128, 128, 100, 128],  # two pages: a block of 2 exactly
+        "partial-last-blocks": [192, 65, 192, 129],
+        "walks-of-one-page": [1, 64, 33, 2],  # the first copies reach three rows ahead
+    }.items()},
 }
+# the cases of four rows: one pool size, so one program a (widths, block, form)
+ROW_ORDERS = sorted(name for name, (lens, _, _) in PAGED_DECODE_CASES.items() if len(lens) == 4)
+# the WINDOW form's law in these tests: a ring of PD_WPAGES entries, every live
+# row wrapped (its length + RING_WRAP keys written), a window of RING_W
+RING_W, RING_WRAP = 141, 300
+FORMS = [(case, "plain") for case in sorted(PAGED_DECODE_CASES)] + [
+    (case, "ring") for case in ROW_ORDERS]
 
 
-def _paged_decode_case(name: str, dtype, seed: int = 0, widths: str = "mistral"):
+def _paged_decode_case(name: str, dtype, seed: int = 0, widths: str = "mistral",
+                       form: str = "plain"):
     """(q, pool_k, pool_v, tables, lens, live) for one named case: every
     row's pages are its own (page 0 is the trash page), an inactive row's
     table is all trash and its length 0 as ``decode_step_ring_paged``
-    hands it down, a shared table is one row's copied to the other."""
+    hands it down, a shared table is one row's copied to the other.
+
+    ``form="ring"``: a live row's table is a RING of ``PD_WPAGES`` pages of
+    its own, wrapped (``RING_WRAP`` keys more than the case's length were
+    written), and ``live`` are the pages a window of ``RING_W`` reaches."""
     import jax.numpy as jnp
     import numpy as np
 
     K, G, hd = PD_WIDTHS[widths]
     lens, inactive, shared = PAGED_DECODE_CASES[name]
-    lens = list(lens)
+    lens = [0 if b in inactive else n for b, n in enumerate(lens)]
     B = len(lens)
     rng = np.random.default_rng(seed)
-    n_pages = 1 + sum(-(-n // PD_PAGE) for n in lens) + 3  # + unused pages
+    ring = form == "ring"
+    if ring:
+        lens = [n + RING_WRAP if n else 0 for n in lens]
+    if B == 4:  # ROW_ORDERS: every ring whole, whatever the lengths
+        n_pages = 1 + B * PD_WPAGES + 3
+    else:
+        n_pages = 1 + sum(-(-n // PD_PAGE) for n in lens) + 3  # + unused pages
     tables = np.zeros((B, PD_PMAX), np.int32)
+    live = np.zeros((n_pages,), bool)
     nxt = 1
     for b, n in enumerate(lens):
-        if b in inactive:
-            lens[b] = 0
+        if n == 0:
             continue
-        for p in range(-(-n // PD_PAGE)):
-            tables[b, p] = nxt
-            nxt += 1
+        own = PD_WPAGES if ring else -(-n // PD_PAGE)
+        tables[b, :own] = np.arange(nxt, nxt + own)
+        nxt += own
     if shared is not None:
         tables[shared[1]] = tables[shared[0]]
-    live = np.zeros((n_pages,), bool)
     for b, n in enumerate(lens):
-        live[tables[b, : -(-n // PD_PAGE)]] = True
+        if ring and n:  # the ring's entries that hold [n - RING_W + 1, n)
+            reached = np.arange(max(n - RING_W + 1, 0) // PD_PAGE, -(-n // PD_PAGE))
+            live[tables[b, reached % PD_WPAGES]] = True
+        else:
+            live[tables[b, : -(-n // PD_PAGE)]] = True
     shape = (2, n_pages, K, PD_PAGE, hd)
     pool_k = rng.standard_normal(shape).astype(np.float32)
     pool_v = rng.standard_normal(shape).astype(np.float32)
@@ -105,10 +145,12 @@ def _stored(pool_side):
     return pool_side.reshape(*lead, page // f, f * hd)
 
 
-def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
+def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block, form="plain",
+                       interpret=True):
     """The kernel (layer 1 of the whole pool AS STORED) beside the XLA law
     it replaces: ``masked_attention_source`` over each row's window, taken
-    out of the DECLARED pool by plain indexing."""
+    out of the DECLARED pool by plain indexing (``form="ring"``: over the
+    row's whole ring, under ``_window_ring_valid``)."""
     import jax.numpy as jnp
 
     from calfkit_tpu.inference import model as M
@@ -116,11 +158,16 @@ def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
         paged_decode_attention_pallas,
     )
 
+    ring = form == "ring"
     got = paged_decode_attention_pallas(
-        q, _stored(pool_k), _stored(pool_v), jnp.int32(1), tables, lens, wpages=PD_WPAGES,
-        interpret=True, pages_per_block=pages_per_block,
+        q, _stored(pool_k), _stored(pool_v), jnp.int32(1), tables[:, :PD_WPAGES] if ring else tables,
+        lens, wpages=PD_WPAGES, interpret=interpret, pages_per_block=pages_per_block,
+        **({"window_starts": jnp.maximum(lens - RING_W + 1, 0)} if ring else {}),
     )
-    valid = jnp.arange(PD_WINDOW)[None, :] < lens[:, None]
+    if ring:
+        valid = M._window_ring_valid(PD_WINDOW, lens, lens, RING_W)
+    else:
+        valid = jnp.arange(PD_WINDOW)[None, :] < lens[:, None]
 
     def window(side):  # [N, K, page, hd] -> [B, K, wp * page, hd]
         rows = jnp.moveaxis(side[tables[:, :PD_WPAGES]], 2, 1)
@@ -132,18 +179,18 @@ def _paged_decode_both(q, pool_k, pool_v, tables, lens, *, pages_per_block):
 
 class TestPagedDecodeKernelCorners:
     @pytest.mark.parametrize("pages_per_block", [1, 2])
-    @pytest.mark.parametrize("case", sorted(PAGED_DECODE_CASES))
+    @pytest.mark.parametrize("case,form", FORMS)
     @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_matches_gathered_window(self, widths, case, pages_per_block):
+    def test_matches_gathered_window(self, widths, case, form, pages_per_block):
         import jax.numpy as jnp
         import numpy as np
 
         q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            case, jnp.float32, widths=widths
+            case, jnp.float32, widths=widths, form=form
         )
         got, want = _paged_decode_both(
             q, jnp.asarray(pool_k), jnp.asarray(pool_v), tables, lens,
-            pages_per_block=pages_per_block,
+            pages_per_block=pages_per_block, form=form,
         )
         for name, g, w in zip("omz", got, want):
             # one pass over the window against a block at a time: the
@@ -184,19 +231,23 @@ class TestPagedDecodeKernelCorners:
 
     @pytest.mark.parametrize("pages_per_block", [1, 2, 4])
     @pytest.mark.parametrize(
-        "case", ["mixed", "inactive-row-on-trash-page", "shared-table"]
+        "case,form",
+        [(case, "plain") for case in ("mixed", "inactive-row-on-trash-page", "shared-table")]
+        + [(case, form) for case in ROW_ORDERS for form in ("plain", "ring")],
     )
     @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_dead_pages_are_never_read(self, widths, case, pages_per_block):
+    def test_dead_pages_are_never_read(self, widths, case, form, pages_per_block):
         """Every page no row's length reaches — the trash page, the tail of
-        each table, the unused pages of the pool, both layers' — is NaN;
-        the result is finite and equal to the clean pool's.  The XLA
+        each table, the unused pages of the pool, both layers', in the window
+        form the entries of a ring behind the window's lower bound — is NaN;
+        the result is finite and equal to the clean pool's: a first block
+        started on the wrong row's behalf would bring one in.  The XLA
         gather reads them all (and masks them), so it gets the clean pool."""
         import jax.numpy as jnp
         import numpy as np
 
         q, pool_k, pool_v, tables, lens, live = _paged_decode_case(
-            case, jnp.float32, seed=5, widths=widths
+            case, jnp.float32, seed=5, widths=widths, form=form
         )
         dirty_k, dirty_v = pool_k.copy(), pool_v.copy()
         dirty_k[:, ~live] = np.nan
@@ -204,11 +255,11 @@ class TestPagedDecodeKernelCorners:
         dirty_k[0] = dirty_v[0] = np.nan  # another layer's pages
         got, _ = _paged_decode_both(
             q, jnp.asarray(dirty_k), jnp.asarray(dirty_v), tables, lens,
-            pages_per_block=pages_per_block,
+            pages_per_block=pages_per_block, form=form,
         )
         clean, want = _paged_decode_both(
             q, jnp.asarray(pool_k), jnp.asarray(pool_v), tables, lens,
-            pages_per_block=pages_per_block,
+            pages_per_block=pages_per_block, form=form,
         )
         for g, c, w in zip(got, clean, want):
             assert np.isfinite(np.asarray(g)).all()
@@ -216,6 +267,71 @@ class TestPagedDecodeKernelCorners:
             np.testing.assert_allclose(
                 np.asarray(g), np.asarray(w), rtol=2e-5, atol=2e-5
             )
+
+    @pytest.mark.parametrize("slots", [0, 2])
+    @pytest.mark.parametrize("form", ["plain", "ring"])
+    @pytest.mark.parametrize("case", ROW_ORDERS)
+    def test_rows_follow_one_another_in_one_copy_pipeline(self, case, form, slots):
+        """The orders of rows under the TPU interpreter (``pltpu.InterpretParams``),
+        which keeps the copies' books as a chip does: a buffer slot no copy
+        wrote is NaN, a copy LANDS only when it is waited for (so a block
+        computed without its own wait reads NaN or the slot's last tenant),
+        two accesses no wait orders are a race, a copy started and never
+        waited for fails the call at its end, and a wait for a copy nobody
+        started never returns, which is a time-out here and not a hang.
+        Dead pages are NaN as above.  Heads of 128 on pages of 16, two pages
+        a block: four slots of a block by the rule, and two."""
+        import threading
+
+        import jax.numpy as jnp
+        import numpy as np
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu_interpreter
+        from jax.experimental.pallas import tpu as pltpu
+
+        page, K, G, hd = 16, 2, 2, 128
+        scale = PD_PAGE // page  # the cases' lengths are in pages of 64
+        q, pool_k, pool_v, tables, lens, live = _paged_decode_case(
+            case, jnp.float32, seed=7, form=form)
+        rng = np.random.default_rng(11)
+        pool_k, pool_v = (rng.standard_normal((2, len(live), K, page, hd)).astype(np.float32)
+                          for _ in range(2))
+        pool_k[:, ~live] = pool_v[:, ~live] = np.nan
+        pool_k[0] = pool_v[0] = np.nan
+        q = q[:, :K, :G]
+        lens = -(-lens // scale)  # the same walks in pages, a quarter the keys
+        window = -(-RING_W // scale)
+        more = {"window_starts": jnp.maximum(lens - window + 1, 0)} if form == "ring" else {}
+        if form == "ring":  # the pages the shorter window reaches are among the case's
+            reached = np.zeros_like(live)
+            for b, n in enumerate(np.asarray(lens)):
+                if n:
+                    at = np.arange(max(n - window + 1, 0) // page, -(-n // page))
+                    reached[np.asarray(tables)[b, at % PD_WPAGES]] = True
+            assert not (reached & ~live).any()
+        call = lambda interpret: PA.paged_decode_attention_pallas(
+            q, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.int32(1), tables[:, :PD_WPAGES],
+            lens, wpages=PD_WPAGES, interpret=interpret, slots=slots, **more)
+        assert PA.paged_decode_slots(2 * 2 * K * page * hd * 4) == 4
+        want = call(True)
+        got = []
+
+        def on_the_interpreter():
+            PA.paged_decode_attention_pallas.clear_cache()
+            with pltpu.force_tpu_interpret_mode(pltpu.InterpretParams(
+                    dma_execution_mode="on_wait", detect_races=True)):
+                got.extend(np.asarray(x) for x in call(False))
+
+        tpu_interpreter.reset_tpu_interpret_mode_state()
+        runner = threading.Thread(target=on_the_interpreter, daemon=True)
+        runner.start()
+        runner.join(60)
+        PA.paged_decode_attention_pallas.clear_cache()
+        assert not runner.is_alive(), "a wait for a copy that was never started"
+        assert len(got) == 3, "the interpreter refused the call: a copy left unwaited?"
+        assert not tpu_interpreter.races.races_found
+        for g, w in zip(got, want):
+            assert np.isfinite(g).all()
+            np.testing.assert_array_equal(g, np.asarray(w))
 
     @pytest.mark.parametrize(
         "head_dim,page,dtype,ok",
@@ -243,8 +359,14 @@ class TestPagedDecodeKernelCorners:
 
         assert paged_decode_in_place_ok(head_dim, page, dtype) is ok
 
+    # (not "walks-of-one-page": the maximum over a row of ONE or two keys is a
+    # score near 0, and the PARENT's kernel is 1.4e-5 of it off the XLA sum
+    # there in bfloat16, past this test's relative limit; the float32 tests
+    # above hold that case, and the kernel is the parent's bit for bit on it)
+    @pytest.mark.parametrize(
+        "case", ["mixed", *(c for c in ROW_ORDERS if c != "walks-of-one-page")])
     @pytest.mark.parametrize("widths", sorted(PD_WIDTHS))
-    def test_the_kernel_on_the_stored_pool_is_the_xla_read_of_it(self, widths):
+    def test_the_kernel_on_the_stored_pool_is_the_xla_read_of_it(self, widths, case):
         """``make_page_pool`` stores a head narrower than a lane tile ``f``
         positions a row (f = 1, 2, 2, 4 at these widths), the same numbers
         in the same order; the kernel copies a page's stored rows whole and
@@ -270,7 +392,7 @@ class TestPagedDecodeKernelCorners:
         assert _stored_layout(config, *made) == f"K {side}, V {side}"
 
         q, pool_k, pool_v, tables, lens, _ = _paged_decode_case(
-            "mixed", jnp.bfloat16, widths=widths
+            case, jnp.bfloat16, widths=widths
         )
         pool_k, pool_v = (jnp.asarray(side, jnp.bfloat16) for side in (pool_k, pool_v))
         stored_k, stored_v = _stored(pool_k), _stored(pool_v)

@@ -486,12 +486,14 @@ class TestPagedDecodeInPlace:
             steps = 4 * (mid["decode_dispatches"] - zero["decode_dispatches"])
             assert steps >= 12
             assert mid["decode_pages_live"] == 2 * steps
+            assert mid["decode_rows_live"] == steps  # one row walks: a mean walk of 2 pages
             assert mid["decode_pages_window"] == 4 * 4 * steps
             # a longer row in a wider window, by difference
             await _gen(engine, list(range(1, 71)), 5)  # 70 tokens: 5 pages
             end = engine.stats.counters()
             steps = 4 * (end["decode_dispatches"] - mid["decode_dispatches"])
             assert end["decode_pages_live"] - mid["decode_pages_live"] == 5 * steps
+            assert end["decode_rows_live"] - mid["decode_rows_live"] == steps
             assert (
                 end["decode_pages_window"] - mid["decode_pages_window"]
                 == 4 * 8 * steps
